@@ -370,7 +370,7 @@ def main():
                     args.load, args, start_iteration, consumed_samples,
                     stream=getattr(telemetry, "stream", None))
     if params is None:
-        params = model.init(jax.random.PRNGKey(args.seed))
+        params = sh.init_params(model, jax.random.PRNGKey(args.seed))
 
     # interleaved VPP trains with the layer stack in stage-major order;
     # checkpoints stay in natural order (see pipeline.permute_layer_stack)

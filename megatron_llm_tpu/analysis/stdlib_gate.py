@@ -1,7 +1,7 @@
 """Checker ``stdlib``: the stdlib-only contract for report/bench tools.
 
 ``serve_bench.py``, ``serve_report.py``, ``trace_report.py``,
-``telemetry_report.py``, ``health_report.py``, ``tpu_sweep.py`` and
+``telemetry_report.py``, ``health_report.py`` and
 ``serve_router.py`` are documented to run anywhere — a laptop reading
 a JSONL dump, a CI box without jax — so a ``jax`` (or ``numpy``, or
 ``requests``) import sneaking into one of them breaks the contract
@@ -37,7 +37,6 @@ GATED_TOOLS = frozenset((
     "tools/telemetry_report.py",
     "tools/trace_report.py",
     "tools/health_report.py",
-    "tools/tpu_sweep.py",
     "tools/graft_lint.py",
 ))
 

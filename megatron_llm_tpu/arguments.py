@@ -276,7 +276,11 @@ def _add_distributed_args(parser):
                         "rescue save (default: 17 when --num_slices > 1 so "
                         "the fleet supervisor restarts the job, else 0 for "
                         "single-job backward compatibility)")
-    g.add_argument("--device", default="tpu", choices=["tpu", "cpu"])
+    g.add_argument("--device", default="tpu", choices=["tpu", "cpu"],
+                   help="'cpu' pins JAX to the CPU; 'tpu' requires a chip "
+                        "(no TPU is an error, not a CPU run) unless "
+                        "JAX_PLATFORMS=cpu asks for the CPU "
+                        "(initialize.select_platform)")
 
 
 def _add_validation_args(parser):

@@ -9,109 +9,126 @@ The shared object is built on demand by ``make`` the first time it's needed.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
+import shutil
 import subprocess
 from typing import Optional
 
 import numpy as np
 
+logger = logging.getLogger("megatron_llm_tpu")
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_HERE, "libhelpers.so")
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 
-def _needs_build(src: str) -> bool:
-    stale = (os.path.exists(_SO) and os.path.exists(src)
-             and os.path.getmtime(src) > os.path.getmtime(_SO))
-    return not os.path.exists(_SO) or stale
+def _so_path() -> str:
+    """Where the library built from THIS tree's source lives.  The name
+    carries a hash of ``helpers.cpp`` + ``Makefile``, so a file left by
+    an older source or copied in from another checkout (where file times
+    mean nothing) can never be the one that is loaded."""
+    h = hashlib.sha256()
+    for name in ("helpers.cpp", "Makefile"):
+        with open(os.path.join(_HERE, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"libhelpers.{h.hexdigest()[:16]}.so")
 
 
-def _build(src: str) -> None:
-    """Rebuild libhelpers.so safely under concurrency: an exclusive file
-    lock serializes builders across processes, and the compile goes to a
-    temp name + atomic os.replace so a concurrent loader can never dlopen
-    a partially written .so."""
+def _build(so: str) -> None:
+    """Build ``so`` safely under concurrency: an exclusive file lock
+    serializes builders across processes, and the compile goes to a temp
+    name + atomic os.replace so a concurrent loader can never dlopen a
+    partially written .so.  Libraries of other source versions go."""
     import fcntl
 
     with open(os.path.join(_HERE, ".helpers.build.lock"), "w") as lk:
         fcntl.flock(lk, fcntl.LOCK_EX)
-        if not _needs_build(src):   # another process built it while we waited
+        if os.path.exists(so):      # another process built it while we waited
             return
-        tmp = f"{_SO}.tmp.{os.getpid()}"
+        tmp = f"{so}.tmp.{os.getpid()}"
         try:
             subprocess.run(
                 ["make", "-C", _HERE, "-B", f"SO={os.path.basename(tmp)}"],
                 check=True, capture_output=True, timeout=120)
-            os.replace(tmp, _SO)
+            os.replace(tmp, so)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                "building the native dataset helpers failed:\n"
+                + e.stderr.decode(errors="replace")) from e
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
+        for old in glob.glob(os.path.join(_HERE, "libhelpers*.so")):
+            if old != so:
+                os.unlink(old)
 
 
 def _load() -> Optional[ctypes.CDLL]:
+    """The native library, built on first use; None (the numpy
+    fallbacks) only where there is no compiler to build it with."""
     global _LIB, _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    src = os.path.join(_HERE, "helpers.cpp")
-    if _needs_build(src):
-        try:
-            _build(src)
-        except Exception:
-            if not os.path.exists(_SO):
-                return None
-    try:
-        lib = ctypes.CDLL(_SO)
-        lib.build_sample_idx.restype = ctypes.c_int64
-        lib.build_sample_idx.argtypes = [
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64,
-            ctypes.c_int32,
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64),
-        ]
-        lib.build_blending_indices.restype = None
-        lib.build_blending_indices.argtypes = [
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int32,
-            ctypes.c_int64,
-            ctypes.c_int32,
-        ]
-        lib.build_mapping.restype = ctypes.c_int64
-        lib.build_mapping.argtypes = [
-            ctypes.POINTER(ctypes.c_int64),   # docs
-            ctypes.c_int64,                   # num_docs + 1
-            ctypes.POINTER(ctypes.c_int32),   # sizes
-            ctypes.c_int32,                   # num_epochs
-            ctypes.c_int64,                   # max_num_samples
-            ctypes.c_int32,                   # max_seq_length
-            ctypes.c_double,                  # short_seq_prob
-            ctypes.c_int32,                   # seed
-            ctypes.c_int32,                   # min_num_sent
-            ctypes.POINTER(ctypes.c_int64),   # out (NULL => count only)
-        ]
-        lib.build_blocks_mapping.restype = ctypes.c_int64
-        lib.build_blocks_mapping.argtypes = [
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),   # title_sizes
-            ctypes.c_int32,
-            ctypes.c_int64,
-            ctypes.c_int32,
-            ctypes.c_int32,                   # seed
-            ctypes.c_int32,                   # use_one_sent_blocks
-            ctypes.POINTER(ctypes.c_int64),
-        ]
-        _LIB = lib
-    except (OSError, AttributeError):
-        # AttributeError: a stale .so missing newly added symbols — fall
-        # back to the numpy implementations rather than crash
-        _LIB = None
+    so = _so_path()
+    if not os.path.exists(so):
+        cxx = os.environ.get("CXX", "g++")
+        if shutil.which("make") is None or shutil.which(cxx) is None:
+            logger.warning(
+                "no compiler (make + %s) to build the native dataset "
+                "helpers: using the slower numpy index builders", cxx)
+            return None
+        _build(so)
+    lib = ctypes.CDLL(so)
+    lib.build_sample_idx.restype = ctypes.c_int64
+    lib.build_sample_idx.argtypes = [
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+        ctypes.c_int32,
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64),
+    ]
+    lib.build_blending_indices.restype = None
+    lib.build_blending_indices.argtypes = [
+        ctypes.POINTER(ctypes.c_uint8),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int32,
+        ctypes.c_int64,
+        ctypes.c_int32,
+    ]
+    lib.build_mapping.restype = ctypes.c_int64
+    lib.build_mapping.argtypes = [
+        ctypes.POINTER(ctypes.c_int64),   # docs
+        ctypes.c_int64,                   # num_docs + 1
+        ctypes.POINTER(ctypes.c_int32),   # sizes
+        ctypes.c_int32,                   # num_epochs
+        ctypes.c_int64,                   # max_num_samples
+        ctypes.c_int32,                   # max_seq_length
+        ctypes.c_double,                  # short_seq_prob
+        ctypes.c_int32,                   # seed
+        ctypes.c_int32,                   # min_num_sent
+        ctypes.POINTER(ctypes.c_int64),   # out (NULL => count only)
+    ]
+    lib.build_blocks_mapping.restype = ctypes.c_int64
+    lib.build_blocks_mapping.argtypes = [
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),   # title_sizes
+        ctypes.c_int32,
+        ctypes.c_int64,
+        ctypes.c_int32,
+        ctypes.c_int32,                   # seed
+        ctypes.c_int32,                   # use_one_sent_blocks
+        ctypes.POINTER(ctypes.c_int64),
+    ]
+    _LIB = lib
     return _LIB
 
 

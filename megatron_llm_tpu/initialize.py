@@ -11,12 +11,48 @@ is just recording it in args.
 
 from __future__ import annotations
 
+import os
 from typing import Callable, Optional
 
 import jax
 
 from megatron_llm_tpu import arguments, global_vars, topology
 from megatron_llm_tpu.timers import Timers
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    ``JAX_COMPILATION_CACHE_DIR`` places it from outside (JAX reads the
+    variable itself, so no directory is set here); otherwise it lives at
+    the fixed ``<checkout>/.jax_cache`` — the path is part of the cache
+    key, so a directory that moves between runs never hits."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_CHECKOUT, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+
+
+def select_platform(device: str = "tpu") -> str:
+    """Pin the CPU when it was asked for; otherwise insist on a TPU.
+
+    The CPU is used only on request: ``--device=cpu``, or a
+    ``JAX_PLATFORMS`` (``jax_platforms``) that names it first.  Without
+    such a request a default backend other than ``tpu`` means JAX found
+    no chip and fell back by itself — an error, never a run."""
+    if device == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+    asked_cpu = (jax.config.jax_platforms or "").split(",")[0] == "cpu"
+    backend = jax.default_backend()
+    if backend != "tpu" and not asked_cpu:
+        raise SystemExit(
+            f"no TPU found: JAX's default backend is {backend!r}.  This "
+            f"program does not fall back to the CPU by itself; to run on "
+            f"the CPU ask for it with --device=cpu or JAX_PLATFORMS=cpu.")
+    return backend
 
 
 def initialize_megatron(
@@ -30,11 +66,13 @@ def initialize_megatron(
         extra_args_provider, args_defaults, ignore_unknown_args, args_list
     )
 
-    if args.device == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
-    # multi-host bootstrap over DCN (no-op single host)
+    # multi-host bootstrap over DCN (no-op single host); it must precede
+    # the first backend query, which select_platform makes
     topology.initialize_distributed()
+    if select_platform(args.device) != "cpu":
+        # an accelerator's programs take minutes to compile; XLA:CPU's
+        # are cheap, and cached ones are tied to the host's CPU features
+        enable_compile_cache()
 
     args = arguments.validate_args(args)
 

@@ -221,17 +221,7 @@ def vocab_parallel_lookup_manual(table: jax.Array,
         h = jnp.where(valid[..., None], h, 0)
         return jax.lax.psum(h, tp_axis)
 
-    if tp_axis in manual:
-        # tp is ALREADY manual in the enclosing region (pre-0.6 jax,
-        # where topology.shard_map full-manualizes): the table arrives
-        # replicated and no GSPMD partitioner runs inside a fully-manual
-        # region, so the plain one-hot lookup is legal — and collective-
-        # free, which matters because psum under check_rep=False
-        # transposes to another psum and would scale the table cotangent
-        # by tp
-        return scatter_free_lookup(table, tokens)
-
-    return topology.shard_map(
+    return jax.shard_map(
         local,
         mesh=am,
         in_specs=(P(tp_axis, None), P()),

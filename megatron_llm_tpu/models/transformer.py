@@ -327,7 +327,13 @@ def _paged_attention_path(cfg: TransformerConfig, n: int) -> str:
       ``paged_prefill_kernel`` (``--serve_prefill_kernel``) allows the
       Pallas chunked-prefill kernel;
     * ``'xla'`` — everything else (mode 'off', oversized query blocks,
-      CPU without interpret mode, meshed runs under 'auto').
+      CPU without interpret mode).
+
+    'auto' asks only whether the kernel can run on this backend.  Traced
+    code cannot see where its arrays live, so whoever jits this for more
+    than one device pins the mode (the serving engine resolves it from
+    its own arrays' devices); a Mosaic call left in a partitioned
+    program is a lowering error, never a quiet XLA run.
 
     The same n-aware seam is the forward door for a speculative
     K+1-token verify step: it is just another small-n 'prefill' call.
@@ -348,12 +354,7 @@ def _paged_attention_path(cfg: TransformerConfig, n: int) -> str:
         return path
     from megatron_llm_tpu.ops.pallas import paged_attention
 
-    # under a multi-device mesh the Mosaic call would need an explicit
-    # shard_map (GSPMD cannot auto-partition it); serving is
-    # single-device today, so 'auto' simply bails
-    if getattr(paged_attention, avail_name)() and jax.device_count() == 1:
-        return path
-    return "xla"
+    return path if getattr(paged_attention, avail_name)() else "xla"
 
 
 def attention(

@@ -98,11 +98,12 @@ def hierarchical_allreduce(x: jax.Array, mesh=None) -> jax.Array:
     parity checks is ``flat_allreduce``."""
     mesh = mesh or topology.get_mesh()
     ici = tuple(a for a in (topology.DP_AXIS,) if mesh.shape[a] >= 1)
-    fn = topology.shard_map(
+    fn = jax.shard_map(
         lambda xs: hierarchical_psum(xs.sum(axis=0), ici),
         mesh=mesh,
         in_specs=P((SLICE_AXIS, topology.DP_AXIS)),
         out_specs=P(),
+        check_vma=False,
     )
     return jax.jit(fn)(x)
 
@@ -111,12 +112,13 @@ def flat_allreduce(x: jax.Array, mesh=None) -> jax.Array:
     """Single flat psum over ``('slice', 'dp')`` — the reduction the
     hierarchical path must be checksum-identical to."""
     mesh = mesh or topology.get_mesh()
-    fn = topology.shard_map(
+    fn = jax.shard_map(
         lambda xs: jax.lax.psum(xs.sum(axis=0),
                                 (SLICE_AXIS, topology.DP_AXIS)),
         mesh=mesh,
         in_specs=P((SLICE_AXIS, topology.DP_AXIS)),
         out_specs=P(),
+        check_vma=False,
     )
     return jax.jit(fn)(x)
 

@@ -484,15 +484,14 @@ def _bwd_fused_call(q, k, v, do, lse, delta, *, scale, causal, window,
 
 
 # The fused single-pass backward is the default; the two-kernel structure
-# below is kept as the fallback (bench.py kernel smoke degrades to it if
-# the fused kernel fails to lower on some libtpu, and partial trailing
-# blocks only support it).
+# below takes what it cannot: partial trailing blocks, and sequences whose
+# dq slab outgrows FUSED_BWD_MAX_SLAB_BYTES.
 FUSED_BACKWARD = True
 # The fused kernel keeps the whole [sq, d] fp32 dq slab VMEM-resident; the
 # round-3 tile sweep put 1024x1024 score tiles near the scoped-vmem limit,
 # so cap the slab (4 MB = seq 8192 at d 128) and route longer sequences to
 # the two-kernel structure instead of risking a compile-time OOM at
-# exactly the long-context lengths the fallback ladder protects.
+# exactly the long-context lengths.
 FUSED_BWD_MAX_SLAB_BYTES = 4 << 20
 # The fused kernel's own block sizes.  They are SMALLER than the
 # two-kernel 1024 defaults because its scoped-vmem working set carries
@@ -776,7 +775,7 @@ def sharded_flash_attention(
     # when those axes are size 1 / unused.  Unmentioned manual axes mean
     # "replicated", which matches the activation layout here (and inside
     # an enclosing pp/cp-manual region, matches per-group locality).
-    return topology.shard_map(
+    return jax.shard_map(
         lambda ql, kl, vl: flash_attention(ql, kl, vl, **kw),
         mesh=mesh,
         in_specs=(qspec, kvspec, kvspec),

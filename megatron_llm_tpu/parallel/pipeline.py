@@ -372,14 +372,9 @@ def build_pipeline_loss_fn(
                 block, policy=jax.checkpoint_policies.nothing_saveable
             )
             act0 = jnp.zeros((mb, s, cfg.hidden_size), cfg.compute_jnp_dtype)
-            # the CE/token accumulators stay (1,)-shaped through this region:
-            # a SCALAR residual of this shard_map trips a transpose bug on
-            # pre-0.6 jax (it evades _promote_scalar_residuals and fails the
-            # in_names check with {0: all_axes} on a rank-0 aval)
             (act_f, ce_sum, tok_sum, aux_sum), _ = lax.scan(
                 block_fn,
-                (act0, jnp.zeros((1,), jnp.float32),
-                 jnp.zeros((1,), jnp.float32),
+                (act0, jnp.float32(0.0), jnp.float32(0.0),
                  jnp.zeros((2,), jnp.float32)),
                 jnp.arange(n_blocks),
             )
@@ -394,7 +389,7 @@ def build_pipeline_loss_fn(
         rep = jax.tree_util.tree_map(lambda _: P(), emb_p)
         fnorm_spec = jax.tree_util.tree_map(lambda _: P(),
                                             trans["final_norm"])
-        ce_tot, tok_tot, aux_tot = topology.shard_map(
+        ce_tot, tok_tot, aux_tot = jax.shard_map(
             shmap_fn,
             mesh=mesh,
             in_specs=(layer_in_spec, rep, P(), fnorm_spec, P(), P(), P(), P()),
@@ -404,7 +399,7 @@ def build_pipeline_loss_fn(
         )(trans["layers"], _pipeline_embedding_layout(emb_p, mesh), head_w,
           trans["final_norm"], tokens, labels, loss_mask, rng_key)
 
-        loss = (ce_tot / jnp.maximum(tok_tot, 1.0))[0]
+        loss = ce_tot / jnp.maximum(tok_tot, 1.0)
         if moe_on:
             # mean routing aux per microbatch enters the objective with the
             # configured coefficients; (loss, aux) is reported for logging
@@ -647,7 +642,7 @@ def build_pipeline_grad_fn(
         # routing-aux cotangent: d(scale * coeff . mean-per-microbatch aux)
         aux_seed = (jnp.float32(scale) / M) * jnp.asarray(
             [cfg.moe_aux_loss_coeff, cfg.moe_z_loss_coeff], jnp.float32)
-        g_lay, g_emb, g_head, g_norm, ce_tot, tok_tot_, aux_tot = topology.shard_map(
+        g_lay, g_emb, g_head, g_norm, ce_tot, tok_tot_, aux_tot = jax.shard_map(
             shmap_fn,
             mesh=mesh,
             in_specs=(layer_in_spec, rep_emb, P(), fnorm_spec,

@@ -170,15 +170,6 @@ def context_parallel_attention(
             "scope (callers gate on get_context_parallel_world_size() > 1; "
             "an enclosing custom mesh without a cp axis cannot host ring "
             "attention)")
-    if topology.CP_AXIS in manual:
-        # cp is ALREADY manual in the enclosing region (pre-0.6 jax,
-        # where topology.shard_map full-manualizes): q/k/v arrive
-        # replicated over cp, so plain local attention is exact and a
-        # nested cp-manual region is neither legal nor needed
-        from megatron_llm_tpu.ops.pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal,
-                               sliding_window=sliding_window,
-                               softmax_scale=softmax_scale)
     fn = partial(
         ring_self_attention,
         axis_name=topology.CP_AXIS,
@@ -188,7 +179,7 @@ def context_parallel_attention(
         q_chunk_size=q_chunk_size,
     )
     spec = P(None, topology.CP_AXIS, None, None)
-    return topology.shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

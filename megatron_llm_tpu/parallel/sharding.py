@@ -86,31 +86,13 @@ def _mesh() -> Optional[Mesh]:
     return topology._MESH
 
 
-def _strip_manual_axes(spec: P) -> P:
-    """Drop mesh axes an enclosing manual region already bound (pre-0.6
-    jax, where ``topology.shard_map`` full-manualizes): constraining a
-    manual axis is a ValueError, and the array is device-local along it
-    anyway, so the constraint is meaningless there."""
-    bound = topology._bound_manual_axis_sizes()
-    if not bound:
-        return spec
-
-    def keep(a):
-        if isinstance(a, (tuple, list)):
-            kept = tuple(x for x in a if x not in bound)
-            return kept if kept else None
-        return None if a in bound else a
-
-    return P(*(keep(a) for a in spec))
-
-
 def constrain(x: jax.Array, *logical_axes: Optional[str], rules=None) -> jax.Array:
     """``with_sharding_constraint`` by logical axis names; no-op when no mesh
     is initialized (pure single-device runs and numpy-golden tests)."""
     mesh = _mesh()
     if mesh is None or all(a is None for a in logical_axes):
         return x
-    spec = _strip_manual_axes(logical_to_mesh(logical_axes, rules))
+    spec = logical_to_mesh(logical_axes, rules)
     if all(a is None for a in spec):
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
@@ -123,7 +105,7 @@ def with_logical_constraint(tree, specs, rules=None):
     if mesh is None:
         return tree
     def one(x, s):
-        spec = _strip_manual_axes(logical_to_mesh(s, rules))
+        spec = logical_to_mesh(s, rules)
         if all(a is None for a in spec):
             return x
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
@@ -145,6 +127,18 @@ def make_shardings(specs, rules=None, mesh: Optional[Mesh] = None):
         specs,
         is_leaf=lambda v: isinstance(v, tuple) or v is None,
     )
+
+
+def init_params(model, key):
+    """``model.init(key)`` with every leaf born on its own shards.
+
+    An eager init materializes the whole model on the default device
+    (layer by layer, then once more for the stack) before
+    ``shard_params`` spreads it; under ``jit`` with the output shardings
+    of ``model.param_specs`` no device ever holds more than its share."""
+    abstract = jax.eval_shape(model.init, key)
+    shardings = make_shardings(model.param_specs(abstract))
+    return jax.jit(model.init, out_shardings=shardings)(key)
 
 
 def shard_params(params, specs, rules=None, mesh: Optional[Mesh] = None):

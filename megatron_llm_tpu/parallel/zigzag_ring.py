@@ -231,14 +231,6 @@ def zigzag_context_attention(
         raise RuntimeError(
             "zigzag_context_attention called with no usable 'cp' axis in "
             "scope (callers gate on get_context_parallel_world_size() > 1)")
-    if topology.CP_AXIS in manual:
-        # cp already manual in the enclosing region (pre-0.6 jax full-
-        # manual fallback): inputs are replicated over cp, plain local
-        # attention is exact (see ring_attention.context_parallel_attention)
-        from megatron_llm_tpu.ops.pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal,
-                               sliding_window=sliding_window,
-                               softmax_scale=softmax_scale)
     fn = partial(
         zigzag_self_attention,
         axis_name=topology.CP_AXIS,
@@ -248,7 +240,7 @@ def zigzag_context_attention(
         q_chunk_size=q_chunk_size,
     )
     spec = P(None, topology.CP_AXIS, None, None)
-    return topology.shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -250,10 +250,18 @@ class InferenceEngine:
         from megatron_llm_tpu.ops.pallas.paged_attention import (
             decode_kernel_available, prefill_kernel_available,
         )
+        # a Mosaic call cannot be partitioned by GSPMD, so 'auto' takes
+        # the kernel only where the jitted programs run on ONE device —
+        # which is where this engine's own arrays live, not how many
+        # chips the host happens to have (a one-chip replica on a
+        # four-chip host keeps its kernels)
+        one_device = len({d for leaf in jax.tree_util.tree_leaves(params)
+                          if isinstance(leaf, jax.Array)
+                          for d in leaf.devices()}) <= 1
         self.paged_kernel = (
             "pallas" if cfg.paged_kernel != "off"
             and decode_kernel_available()
-            and (cfg.paged_kernel == "on" or jax.device_count() == 1)
+            and (cfg.paged_kernel == "on" or one_device)
             else "xla")
         self._decode_cfg = mcfg.replace(
             paged_attention_kernel=(
@@ -267,7 +275,7 @@ class InferenceEngine:
         self.prefill_kernel = (
             "pallas" if cfg.prefill_kernel != "off"
             and prefill_kernel_available()
-            and (cfg.prefill_kernel == "on" or jax.device_count() == 1)
+            and (cfg.prefill_kernel == "on" or one_device)
             else "xla")
         self._prefill_cfg = mcfg.replace(
             paged_attention_kernel="off",

@@ -252,10 +252,7 @@ def initialize_distributed(
     # gloo cross-host collectives backend selected before the CPU client
     # is created; without it every cross-process computation fails with
     # "Multiprocess computations aren't implemented on the CPU backend".
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass    # option absent on this jaxlib: TPU-only build
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -267,74 +264,21 @@ def initialize_distributed(
 # Sharding constructors.
 # ---------------------------------------------------------------------------
 
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=False):
-    """``jax.shard_map`` with the modern keyword API, bridged to
-    ``jax.experimental.shard_map`` on pre-0.6 jax (where the stable alias
-    does not exist and partial manualization is spelled ``auto=`` instead
-    of ``axis_names=``, and ``check_vma`` is ``check_rep``)."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma, **kw)
-    # Pre-0.6 fallback: partial manualization (auto=...) mislowers
-    # named-axis collectives to PartitionId on this jax, so manualize
-    # EVERY axis instead.  Unmentioned spec axes then mean "replicated",
-    # which keeps the math identical and only forgoes sharding the
-    # region over the axes the caller left auto.
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def _bound_manual_axis_sizes() -> dict:
-    """``{axis_name: size}`` for axes bound by an enclosing manual region
-    (shard_map/pmap), read from the tracing axis env.  Empty outside any
-    manual region, or when this jax version hides the axis env."""
-    try:
-        from jax._src.core import get_axis_env
-        env = get_axis_env()
-        sizes = getattr(env, "axis_sizes", None)
-        return dict(sizes) if sizes else {}
-    except Exception:
-        return {}
-
-
 def current_mesh_and_manual():
     """(governing mesh, already-Manual axis names) for building a
     shard_map that may nest inside another manual region — the abstract
     context mesh when one is active (inside jit/manual regions jax
     requires it plus re-declaration of every already-Manual axis), else
     the concrete global mesh.  ``(None, set())`` when no mesh governs."""
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is None:
-        # older jax spells it jax._src.mesh.get_abstract_mesh
-        from jax._src.mesh import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or not getattr(mesh, "axis_names", None):
-        # Pre-0.5 jax never sets the abstract-mesh context during a
-        # shard_map trace, but the axis env still records which axes the
-        # enclosing manual region bound (and their sizes).
-        bound = _bound_manual_axis_sizes()
-        if bound:
-            if _MESH is not None and all(
-                    _MESH.shape.get(n) == s for n, s in bound.items()):
-                # manual region over the global mesh: nested shard_maps
-                # must re-declare these already-Manual axes
-                return _MESH, set(bound)
-            # manual region over some OTHER mesh: there is no safe
-            # global fallback (see nesting_mesh)
-            return None, set(bound)
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         # not inside any mesh context: the concrete global mesh governs
         mesh = _MESH
     if mesh is None:
         return None, set()
-    # axis_types is None on jax builds where every axis is still Auto
-    axis_types = getattr(mesh, "axis_types", None) or ()
     manual = {
-        name for name, t in zip(mesh.axis_names, axis_types)
-        if "Manual" in str(t)
+        name for name, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == jax.sharding.AxisType.Manual
     }
     return mesh, manual
 

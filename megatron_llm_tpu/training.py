@@ -366,7 +366,7 @@ def pretrain(
     recorder) off the device-sync path except at log boundaries.
     """
     from megatron_llm_tpu import checkpointing
-    from megatron_llm_tpu.telemetry import Telemetry
+    from megatron_llm_tpu.telemetry import Telemetry, device_memory_stats
     from megatron_llm_tpu.timers import Timers
 
     if timers is None:
@@ -689,7 +689,7 @@ def pretrain(
                         use_writer.add_scalar("world-size",
                                               jax.device_count(), iteration)
                     if log_memory:
-                        stats = jax.local_devices()[0].memory_stats() or {}
+                        stats = device_memory_stats()
                         use_writer.add_scalar(
                             "mem-bytes-in-use",
                             stats.get("bytes_in_use", 0), iteration)
@@ -749,7 +749,6 @@ def pretrain(
                     straggler.check(gathered, iteration)
                 if stream is not None:
                     from megatron_llm_tpu.resilience import recovery_counters
-                    from megatron_llm_tpu.telemetry import device_memory_stats
                     rec = {
                         "iteration": iteration,
                         "train_iters": train_cfg.train_iters,

@@ -110,7 +110,7 @@ def test_orqa_load_qa_pairs_no_eval(tmp_path):
 def test_helpers_concurrent_build():
     from megatron_llm_tpu.data import helpers
 
-    so = helpers._SO
+    so = helpers._so_path()
     if os.path.exists(so):
         os.unlink(so)
     code = ("from megatron_llm_tpu.data import helpers; "

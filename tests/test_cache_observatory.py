@@ -11,7 +11,7 @@ Also covered: per-prefix heat attribution + salted-key privacy,
 eviction forensics (capacity vs churn) and the evicted-then-wanted
 regret counter, heat-table bounding, fleet heat merge, the periodic
 cache_stats emission cadence, and the <2% dispatch-overhead gate
-(slow; run by tools/tpu_sweep.py's serve_cache_overhead step).
+(slow tier).
 """
 
 import json
@@ -372,7 +372,7 @@ def test_pool_reset_keeps_ghost_residency():
 
 
 # ---------------------------------------------------------------------------
-# overhead gate (slow; run by tools/tpu_sweep.py's serve_cache_overhead)
+# overhead gate (slow tier)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow

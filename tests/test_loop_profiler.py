@@ -319,7 +319,7 @@ def test_finish_survives_broken_telemetry(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# overhead gate (slow; run by tools/tpu_sweep.py's serve_loop_overhead)
+# overhead gate (slow tier)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow

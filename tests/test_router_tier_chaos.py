@@ -1,5 +1,4 @@
-"""Sharded-front-door chaos e2e (slow tier; tools/tpu_sweep.py runs
-this file as the wave-2 ``router_kill_chaos`` step).
+"""Sharded-front-door chaos e2e (slow tier).
 
 Real processes all the way down: 2 tiny-model engine replicas
 (tests/_serve_replica.py) behind 2 ``tools/serve_router.py --dynamic``

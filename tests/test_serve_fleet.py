@@ -1,5 +1,4 @@
-"""Fleet supervisor chaos e2e (slow tier; tools/tpu_sweep.py runs this
-file as the wave-2 ``serve_fleet_chaos`` step).
+"""Fleet supervisor chaos e2e (slow tier).
 
 Real tiny-model engine subprocesses (tests/_serve_replica.py) under a
 live :class:`FleetSupervisor`:

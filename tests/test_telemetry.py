@@ -34,6 +34,7 @@ from megatron_llm_tpu.telemetry import (
     ThroughputCalculator,
     build_telemetry,
     peak_flops_for_kind,
+    peak_flops_for_local_device,
 )
 from megatron_llm_tpu.timers import Timers
 from megatron_llm_tpu.training import pretrain, training_log
@@ -95,10 +96,13 @@ def test_peak_flops_lookup():
     assert peak_flops_for_kind("TPU v5 lite") == 197e12
     assert peak_flops_for_kind("TPU v5p chip") == 459e12
     assert peak_flops_for_kind("TPU v6e") == 918e12
-    # unknown TPU spelling: conservative v5e default, never None
-    assert peak_flops_for_kind("TPU v9 mega") == 197e12
-    assert peak_flops_for_kind("cpu") is None
-    assert peak_flops_for_kind("cpu", assume_tpu=True) == 197e12
+    # a kind the table lacks is an error, never a default peak
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        peak_flops_for_kind("TPU v9 mega")
+    with pytest.raises(ValueError):
+        peak_flops_for_kind("cpu")
+    # the CPU backend asks for no MFU at all
+    assert peak_flops_for_local_device() is None
 
 
 def test_mfu_arithmetic_matches_hand_computed_flops():

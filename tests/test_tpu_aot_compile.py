@@ -1,0 +1,174 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e that is described,
+not attached (on-chip-measurement guide, section 2.3).
+
+The TPU's own compiler is installed with jaxlib and needs no chip: it
+refuses what interpret mode lets through (a slice off the tiling, too
+much fast memory).  Every case compiles one kernel at Mistral-7B widths
+— 32 query heads, 8 kv heads, head_dim 128, hidden 4096 — and asserts
+that the result holds a Mosaic call (``tpu_custom_call``), i.e. that the
+kernel was taken and not its jnp reference.  Compiles, not chip runs:
+they say nothing about results or speed.
+
+All cases compile in ONE child process (this file run as a script): the
+compiler library admits one process at a time and is held until that
+process ends, so loading it into the pytest process would lock out every
+later test that compiles this way.  The child sets no compilation cache
+(an entry written for a described device cannot be read back).
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BF16 = jnp.bfloat16
+HEADS, KV_HEADS, HEAD_DIM, HIDDEN, SEQ = 32, 8, 128, 4096, 4096
+SLOTS, MAX_LEN = 8, 4096        # the engine's default decode batch
+
+
+def _flash(window=None, grad=False):
+    from megatron_llm_tpu.ops.pallas.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, sliding_window=window)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    return fn, [((1, SEQ, HEADS, HEAD_DIM), BF16),
+                ((1, SEQ, KV_HEADS, HEAD_DIM), BF16),
+                ((1, SEQ, KV_HEADS, HEAD_DIM), BF16)]
+
+
+def _rms_norm(rows):
+    from megatron_llm_tpu.ops.pallas.rmsnorm import fused_rms_norm
+
+    def loss(x, scale):
+        return fused_rms_norm(x, scale).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1)), [((rows, HIDDEN), BF16),
+                                            ((HIDDEN,), BF16)]
+
+
+def _layer_norm(rows):
+    from megatron_llm_tpu.ops.pallas.layernorm import fused_layer_norm
+
+    def loss(x, scale, bias):
+        return fused_layer_norm(x, scale, bias).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), [
+        ((rows, HIDDEN), BF16), ((HIDDEN,), BF16), ((HIDDEN,), BF16)]
+
+
+def _paged(q_tokens, slots, page=16, int8=False, window=None):
+    """Decode (q_tokens=1), a prefill chunk or the K+1 verify step over a
+    pool sized as the engine sizes it: full backing for 8 slots of 4096
+    tokens plus the garbage page."""
+    from megatron_llm_tpu.ops.pallas import paged_attention as pa
+
+    pages = SLOTS * (MAX_LEN // page) + 1
+    pool = ((pages, page, KV_HEADS, HEAD_DIM), jnp.int8 if int8 else BF16)
+    scale = ((pages, page, KV_HEADS), jnp.float32)
+    shapes = [pool, pool, ((slots, MAX_LEN // page), jnp.int32),
+              ((slots,), jnp.int32)] + ([scale, scale] if int8 else [])
+
+    def fn(q, k_pages, v_pages, tables, lens, k_scales=None, v_scales=None):
+        kw = dict(k_scales=k_scales, v_scales=v_scales,
+                  sliding_window=window)
+        if q_tokens == 1:
+            return pa.paged_attention_decode(q[:, 0], k_pages, v_pages,
+                                             tables, lens, **kw)
+        return pa.paged_attention_prefill(q, k_pages, v_pages, tables, lens,
+                                          **kw)
+
+    return fn, [((slots, q_tokens, HEADS, HEAD_DIM), BF16)] + shapes
+
+
+CASES = {
+    "flash_fwd": lambda: _flash(),
+    "flash_fwd_window_1024": lambda: _flash(window=1024),
+    "flash_fused_bwd": lambda: _flash(grad=True),
+    "flash_fused_bwd_window_1024": lambda: _flash(window=1024, grad=True),
+    "rms_norm_1_row": lambda: _rms_norm(1),
+    "rms_norm_8_rows": lambda: _rms_norm(8),
+    "rms_norm_64_rows": lambda: _rms_norm(64),
+    "rms_norm_4096_rows": lambda: _rms_norm(4096),
+    "layer_norm_8_rows": lambda: _layer_norm(8),
+    "layer_norm_4096_rows": lambda: _layer_norm(4096),
+    "paged_decode_bf16": lambda: _paged(1, SLOTS),
+    "paged_decode_bf16_page_32": lambda: _paged(1, SLOTS, page=32),
+    "paged_decode_int8": lambda: _paged(1, SLOTS, int8=True),
+    "paged_decode_window_4096": lambda: _paged(1, SLOTS, window=4096),
+    "paged_decode_int8_window_4096":
+        lambda: _paged(1, SLOTS, int8=True, window=4096),
+    "paged_prefill_chunk_64": lambda: _paged(64, 1),
+    "paged_prefill_chunk_64_page_32": lambda: _paged(64, 1, page=32),
+    "paged_prefill_chunk_64_int8": lambda: _paged(64, 1, int8=True),
+    "paged_verify_k_plus_1": lambda: _paged(5, SLOTS),
+    "paged_verify_k_plus_1_window_4096":
+        lambda: _paged(5, SLOTS, window=4096),
+}
+
+
+def _compile_all():
+    """The child: compile every case for one described v5e device and
+    print ``{case: true | false | "error"}`` (or ``{"skip": why}`` where
+    this jaxlib cannot describe the topology)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - any failure means "cannot"
+        print(json.dumps({"skip": f"{type(e).__name__}: {e}"[:300]}))
+        return
+    chip = SingleDeviceSharding(topo.devices[0])
+    found = {}
+    for case in sorted(CASES):
+        fn, shapes = CASES[case]()
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                for shape, dtype in shapes]
+        try:
+            text = jax.jit(fn).lower(*args).compile().as_text()
+            found[case] = "tpu_custom_call" in text
+        except Exception as e:  # noqa: BLE001 - the compiler's refusal
+            found[case] = f"{type(e).__name__}: {e}"[:2000]
+    print(json.dumps(found))
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    # code that asks jax.default_backend() sees the CPU under such a
+    # compile and would take its jnp branch; steer it here, in the test
+    env = dict(os.environ, JAX_PLATFORMS="cpu", MLT_FORCE_PALLAS="1",
+               PYTHONPATH=ROOT)
+    env.setdefault("TPU_LOG_DIR", "disabled")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    proc = subprocess.run([sys.executable, os.path.abspath(__file__)],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0 and lines, proc.stderr[-3000:]
+    found = json.loads(lines[-1])
+    if "skip" in found:
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: "
+                    f"{found['skip']}")
+    return found
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, compiled):
+    assert compiled[case] is True, (
+        f"{case}: compiled without its Mosaic kernel" if not compiled[case]
+        else f"{case}: the TPU compiler refused it: {compiled[case]}")
+
+
+if __name__ == "__main__":
+    _compile_all()

@@ -52,7 +52,7 @@ def test_zigzag_redistribution_round_trip(utils):
 
     mesh = topology.get_mesh()
     spec = P(None, "cp", None, None)
-    back, oks = jax.jit(topology.shard_map(
+    back, oks = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=spec,
         out_specs=(spec, P("cp")), check_vma=False))(x)
     assert bool(jnp.all(oks)), np.asarray(oks)

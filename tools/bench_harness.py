@@ -4,8 +4,8 @@ One place for the model/optimizer/train-step/batch construction and the
 persistent-compile-cache setup, so the batch contract ([num_micro, mb,
 seq] tokens/labels/loss_mask) and TrainConfig defaults cannot drift
 between tools.  bench.py deliberately does NOT import this: the driver
-artifact must stay self-contained (it is run by an external harness and
-has its own deadline/fallback machinery).
+artifact must stay self-contained (it is run by an external harness
+under its own deadline).
 """
 
 import os
@@ -16,14 +16,9 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-def enable_compile_cache():
-    """Persistent XLA compile cache under ROOT/.jax_cache (same knobs as
-    bench.py), so iterate loops don't pay the full compile each run."""
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(ROOT, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+# the one persistent-compile-cache helper (JAX_COMPILATION_CACHE_DIR, else
+# <checkout>/.jax_cache), re-exported for the tools that import this harness
+from megatron_llm_tpu.initialize import enable_compile_cache  # noqa: E402,F401
 
 
 # the on-chip bench shape (docs/perf_tpu.md): ~650M llama, MXU-aligned

@@ -34,8 +34,11 @@ def extra_args(parser):
     return parser
 
 
-def main():
-    args = initialize_megatron(extra_args_provider=extra_args)
+def build_server(args, argv):
+    """Model, weights, engine (warmed up and started) and the HTTP server
+    for parsed ``args``; ``argv`` is the command line they came from
+    (the model presets fill only flags it does not carry).  Returns the
+    ``MegatronServer``, ready to ``run``."""
     # serving observability: --structured_log_dir streams request_done
     # JSONL (analyze offline with tools/serve_report.py), --trace_dir
     # records Chrome spans with per-request trace ids (merge with the
@@ -52,7 +55,7 @@ def main():
     # rmsnorm/no-bias; gemma gets its sqrt(hidden) embedding scale)
     from finetune import MODEL_DEFAULTS, _apply_model_defaults, model_provider
     if args.model_name in MODEL_DEFAULTS:
-        _apply_model_defaults(args, sys.argv[1:])
+        _apply_model_defaults(args, argv)
         model = model_provider(args)
     else:
         model = MODEL_REGISTRY[args.model_name](
@@ -62,7 +65,7 @@ def main():
         params, _, _ = checkpointing.load_checkpoint(args.load, finetune=True)
     else:
         print(" no --load given: serving a randomly initialized model")
-        params = model.init(jax.random.PRNGKey(args.seed))
+        params = sh.init_params(model, jax.random.PRNGKey(args.seed))
     specs = model.param_specs(params)
     if args.int8_weights:
         from megatron_llm_tpu.quantization import (
@@ -127,7 +130,12 @@ def main():
                             structured_log_dir=args.structured_log_dir,
                             alert_rules=args.alert_rules,
                             alert_webhook=args.alert_webhook)
-    server.run(args.host, args.port)
+    return server
+
+
+def main():
+    args = initialize_megatron(extra_args_provider=extra_args)
+    build_server(args, sys.argv[1:]).run(args.host, args.port)
 
 
 if __name__ == "__main__":

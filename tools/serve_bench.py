@@ -118,8 +118,7 @@ JSON_SCHEMA_KEYS = (
 # Exit codes: 0 = all requests succeeded; 1 = at least one request
 # failed; 2 = argparse/usage error; 3 = --slo_gate given and the joint
 # SLO attainment (min across arms under --ab) fell below the gate.
-# tools/tpu_sweep.py and CI read these — renumbering is a breaking
-# change.
+# CI reads these — renumbering is a breaking change.
 
 
 def parse_rate_schedule(spec: str):
