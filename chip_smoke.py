@@ -446,7 +446,8 @@ def phase_serve(args):
             startup_secs=round(startup, 1),
             decode_secs=stats["decode_secs"],
             prefill_secs=stats["prefill_secs"],
-            # host clocks around dispatch + fetch, by phase of the loop
+            # host clocks by phase of the loop (dispatch + fetch is
+            # what the host waited, never device time)
             loop_phase_secs=stats["loop"]["phase_secs"],
             peak_bytes_in_use=device_memory_stats().get(
                 "peak_bytes_in_use"))

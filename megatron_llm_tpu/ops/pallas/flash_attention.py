@@ -174,6 +174,7 @@ def _fwd_call(q, k, v, *, scale, causal, window, block_q, block_k):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(b, nh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bb, h, qi, ki: (bb, h, qi, 0),
@@ -436,6 +437,7 @@ def _bwd_fused_call(q, k, v, do, lse, delta, *, scale, causal, window,
               window=window, kv_len=sk, q_len=sq)
     dq, dk_h, dv_h = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, **kw),
+        name="flash_attention_bwd_fused",
         grid=(b, nh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bb, h, ki, qi: (bb, h, qi, 0),
@@ -538,6 +540,7 @@ def _bwd_call(q, k, v, o, lse, do, *, scale, causal, window,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
+        name="flash_attention_bwd_dq",
         grid=(b, nh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bb, h, qi, ki: (bb, h, qi, 0),
@@ -568,6 +571,7 @@ def _bwd_call(q, k, v, o, lse, do, *, scale, causal, window,
     # dk/dv per query head, group-summed afterwards (GQA)
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),
+        name="flash_attention_bwd_dkv",
         grid=(b, nh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bb, h, ki, qi: (bb, h, qi, 0),

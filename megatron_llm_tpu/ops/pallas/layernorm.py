@@ -96,6 +96,7 @@ def _fwd_call(x2d, scale, bias, eps):
     rows = _pick_rows(n, h, x2d.dtype.itemsize)
     y, mu, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="layernorm_fwd",
         grid=(pl.cdiv(n, rows),),
         in_specs=[
             pl.BlockSpec((rows, h), lambda i: (i, 0),
@@ -126,6 +127,7 @@ def _bwd_call(x2d, scale, g2d, mu, rstd, eps):
     rows = _pick_rows(n, h, x2d.dtype.itemsize)
     dx, dg, db = pl.pallas_call(
         functools.partial(_bwd_kernel, n=n, rows=rows),
+        name="layernorm_bwd",
         grid=(pl.cdiv(n, rows),),
         in_specs=[
             pl.BlockSpec((rows, h), lambda i: (i, 0),

@@ -220,9 +220,10 @@ def _ragged_kernel_quant(bt, cl, q, k, ks, v, vs, o, m, l, acc, **kw):
 
 
 def _ragged_call(q, k_pages, v_pages, block_tables, context_lens,
-                 k_scales, v_scales, *, scale, window, block_q):
+                 k_scales, v_scales, *, scale, window, block_q, name):
     """Shared pallas_call scaffold: q [S, C, nh, d] with block_q | C.
-    Decode is the C == block_q == 1 instance."""
+    Decode is the C == block_q == 1 instance.  ``name`` is the kernel's
+    name in a profile (``_quant`` appended for the int8 pools)."""
     S, C, nh, d = q.shape
     bs, g = k_pages.shape[1], k_pages.shape[2]
     M = block_tables.shape[1]
@@ -279,6 +280,7 @@ def _ragged_call(q, k_pages, v_pages, block_tables, context_lens,
     return pl.pallas_call(
         functools.partial(kernel, scale=scale, block_size=bs,
                           block_q=bq, window=window, qpg=qpg),
+        name=name + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, d), q.dtype),
         interpret=_INTERPRET,
@@ -320,7 +322,7 @@ def paged_attention_decode(
     return _ragged_call(
         q[:, None], k_pages, v_pages, block_tables, context_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
-        block_q=1)[:, 0]
+        block_q=1, name="paged_attention_decode")[:, 0]
 
 
 def paged_attention_prefill(
@@ -362,4 +364,4 @@ def paged_attention_prefill(
     return _ragged_call(
         q, k_pages, v_pages, block_tables, context_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
-        block_q=bq)
+        block_q=bq, name="paged_attention_prefill")

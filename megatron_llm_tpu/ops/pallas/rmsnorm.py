@@ -97,6 +97,7 @@ def _fwd_call(x2d, scale, eps):
     grid = (pl.cdiv(n, rows),)
     y, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="rmsnorm_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows, h), lambda i: (i, 0),
@@ -124,6 +125,7 @@ def _bwd_call(x2d, scale, g2d, rstd, eps):
     nblocks = pl.cdiv(n, rows)
     dx, ds = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps, n=n, rows=rows),
+        name="rmsnorm_bwd",
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((rows, h), lambda i: (i, 0),

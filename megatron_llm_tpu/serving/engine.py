@@ -102,6 +102,7 @@ from megatron_llm_tpu.serving.request import (
 from megatron_llm_tpu.serving.loop_profiler import (
     DispatchRecord,
     LoopProfiler,
+    RequestSpan,
 )
 from megatron_llm_tpu.serving.resilience import (
     EngineWatchdog,
@@ -162,6 +163,16 @@ class EngineConfig:
     # swap matched cold prefixes back with one fixed-shape host→device
     # scatter compiled at warmup.
     host_cache_bytes: int = 0
+
+
+def _program(fn, name: str):
+    """``fn`` jitted under a stable name: the XLA module is
+    ``jit_<name>`` in every profile and compile log, whatever the
+    method behind it is called."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
 
 
 def _key_from_seed(seed: int) -> np.ndarray:
@@ -325,17 +336,20 @@ class InferenceEngine:
             self._st.blocks.attach_host_cache(self.host_cache)
             self.host_cache.start()
 
-        self._decode_step = jax.jit(self._decode_impl)
-        self._verify_step = jax.jit(self._verify_impl)
-        self._prefill_step = jax.jit(self._prefill_impl)
-        self._sample_first = jax.jit(self._sample_first_impl)
-        self._cow_copy = jax.jit(self._cow_copy_impl)
+        self._decode_step = _program(self._decode_impl, "engine_decode")
+        self._verify_step = _program(self._verify_impl, "engine_verify")
+        self._prefill_step = _program(self._prefill_impl, "engine_prefill")
+        self._sample_first = _program(self._sample_first_impl,
+                                      "engine_sample_first")
+        self._cow_copy = _program(self._cow_copy_impl, "engine_cow_copy")
         # host-tier device programs: one fixed-shape whole-page gather
         # (device→host spill source) and one whole-page scatter
         # (host→device swap-in), both over traced int32 block indices —
         # compiled once at warmup, zero steady-state recompiles
-        self._fetch_block = jax.jit(self._fetch_block_impl)
-        self._host_load = jax.jit(self._host_load_impl)
+        self._fetch_block = _program(self._fetch_block_impl,
+                                     "engine_fetch_block")
+        self._host_load = _program(self._host_load_impl,
+                                   "engine_host_load")
 
         # counters (read by stats()/the HTTP /metrics endpoint)
         self.decode_steps = 0
@@ -356,9 +370,10 @@ class InferenceEngine:
         self.engine_restarts = 0
         self.slots_evicted_nonfinite = 0
         self.fault_injector = ServingFaultInjector.from_spec(cfg.fault_spec)
-        # engine-loop goodput attribution (serving/loop_profiler.py):
-        # host-phase vs device time per dispatch, surfaced as the 'loop'
-        # block of stats() and periodic engine_loop_stats JSONL records.
+        # the serve loop's span stream (serving/loop_profiler.py): one
+        # record per launch with its phases' absolute times, surfaced as
+        # the 'loop' block of stats(), periodic engine_loop_stats JSONL
+        # records and loop.<phase> annotations on the profiler's clock.
         # Engine-lifetime (like the counters above): restarts swap the
         # state object, not the loop accounting.
         self.loop_profiler = LoopProfiler()
@@ -834,6 +849,7 @@ class InferenceEngine:
             self._dispatches += 1
             if inj is not None:
                 inj.before_dispatch(self._dispatches)
+            d.kind = "prefill"
             d.mark("schedule")
             self._run_prefill_chunk(st, arg, d)
             return True
@@ -841,12 +857,20 @@ class InferenceEngine:
             self._dispatches += 1
             if inj is not None:
                 inj.before_dispatch(self._dispatches)
+            # one decode path: with speculation on EVERY decode step is
+            # the [S, K+1] verify program — draft-less and sampled slots
+            # ride it masked (vlen = 1), so the plain decode program is
+            # never dispatched and cannot cause a late first compile
+            d.kind = "verify" if self.speculative else "decode"
             d.mark("schedule")
-            self._run_decode(st, arg, d)
+            if self.speculative:
+                self._run_verify(st, arg, d)
+            else:
+                self._run_decode(st, arg, d)
             return True
-        # no action: not a dispatch, and the wait for new work must not
+        # no action: not a launch, and the wait for new work must not
         # read as a dispatch gap
-        self.loop_profiler.idle()
+        self.loop_profiler.idle(d)
         return False
 
     # -- admission ------------------------------------------------------
@@ -988,7 +1012,6 @@ class InferenceEngine:
 
     def _run_prefill_chunk(self, st: _EngineState, req: Request,
                            d: DispatchRecord) -> None:
-        d.kind = "prefill"
         if self.host_cache is not None:
             # consume pending host-tier swap-ins first (no-op after the
             # slot's first chunk); accounted to the build_inputs bucket
@@ -1007,33 +1030,35 @@ class InferenceEngine:
         for bi in range(start // bs, (start + valid - 1) // bs + 1):
             self._writable(st, req.slot, bi)
         table = st.blocks.tables[req.slot:req.slot + 1].copy()
+        d.start, d.valid = start, valid
+        d.cached_tokens = req.cached_prompt_tokens
+        d.requests = (req.id,)
+        d.traces = (req.trace_id,) if req.trace_id else ()
         d.mark("build_inputs")
-        t0 = time.perf_counter()
         finite = True
-        with tracing.span("prefill_chunk", "serve", request=req.id,
-                          trace=req.trace_id, tokens=valid,
-                          cached_tokens=req.cached_prompt_tokens):
-            last_logits, st.pages = self._prefill_step(
-                self.params, st.pages, toks, np.int32(start),
-                np.int32(valid), table)
-            done = start + valid >= len(ptoks)
-            if done:
-                tok, new_key, finite = self._sample_first(
-                    last_logits, st.keys[req.slot],
-                    st.top_ks[req.slot], st.top_ps[req.slot],
-                    st.temps[req.slot], st.ban_a[req.slot],
-                    st.ban_b[req.slot],
-                    np.int32(ptoks[-1]))
-                tok = int(tok)
-                finite = bool(finite)
-                st.keys[req.slot] = np.asarray(new_key)
-            else:
-                jax.block_until_ready(st.pages[0])
-        d.mark("device")
+        last_logits, st.pages = self._prefill_step(
+            self.params, st.pages, toks, np.int32(start),
+            np.int32(valid), table)
+        done = start + valid >= len(ptoks)
+        if done:
+            tok, new_key, finite = self._sample_first(
+                last_logits, st.keys[req.slot],
+                st.top_ks[req.slot], st.top_ps[req.slot],
+                st.temps[req.slot], st.ban_a[req.slot],
+                st.ban_b[req.slot],
+                np.int32(ptoks[-1]))
+            d.mark("dispatch")
+            tok = int(tok)
+            finite = bool(finite)
+            st.keys[req.slot] = np.asarray(new_key)
+        else:
+            d.mark("dispatch")
+            jax.block_until_ready(st.pages[0])
+        d.mark("fetch")
         if st is not self._st:
             self.loop_profiler.finish(d)
             return          # engine restarted mid-dispatch: stale state
-        chunk_secs = time.perf_counter() - t0
+        chunk_secs = d.wait_secs
         self.prefill_secs += chunk_secs
         req.prefill_compute_secs += chunk_secs
         self.prefill_chunks += 1
@@ -1063,40 +1088,40 @@ class InferenceEngine:
 
     # -- decode ---------------------------------------------------------
 
+    @staticmethod
+    def _note_batch(st: _EngineState, d: DispatchRecord, slots: List[int],
+                    decoding: List[Request]) -> None:
+        """What a decode/verify launch works on, for its record."""
+        d.rows = len(slots)
+        d.context_tokens = int(st.context_lens[slots].sum())
+        d.requests = tuple(r.id for r in decoding)
+        d.traces = tuple(sorted({r.trace_id for r in decoding
+                                 if r.trace_id}))
+
     def _run_decode(self, st: _EngineState, slots: List[int],
                     d: DispatchRecord) -> None:
-        if self.speculative:
-            # one decode path: with speculation on EVERY decode step is
-            # the [S, K+1] verify program — draft-less and sampled slots
-            # ride it masked (vlen = 1), so the plain decode program is
-            # never dispatched and cannot cause a late first compile
-            self._run_verify(st, slots, d)
-            return
-        d.kind = "decode"
         bs = self.config.block_size
         for s in slots:
             self._writable(st, s, int(st.context_lens[s]) // bs)
         decoding = [r for r in (st.scheduler.active.get(s) for s in slots)
                     if r is not None and r.state == RequestState.DECODE]
-        traces = sorted({r.trace_id for r in decoding if r.trace_id})
+        self._note_batch(st, d, slots, decoding)
         d.mark("build_inputs")
-        t0 = time.perf_counter()
-        with tracing.span("decode_step", "serve", batch=len(slots),
-                          traces=traces):
-            next_tokens, st.pages, new_keys, finite = self._decode_step(
-                self.params, st.pages, st.last_tokens,
-                st.context_lens, st.blocks.tables.copy(),
-                st.active, st.temps, st.top_ks, st.top_ps,
-                st.ban_a, st.ban_b, st.keys)
-            next_tokens = np.asarray(next_tokens)
+        next_tokens, st.pages, new_keys, finite = self._decode_step(
+            self.params, st.pages, st.last_tokens,
+            st.context_lens, st.blocks.tables.copy(),
+            st.active, st.temps, st.top_ks, st.top_ps,
+            st.ban_a, st.ban_b, st.keys)
+        d.mark("dispatch")
+        next_tokens = np.asarray(next_tokens)
+        new_keys = np.asarray(new_keys)
+        finite = np.asarray(finite).copy()
+        d.mark("fetch")
         # key chains advance ONLY for decoding slots: a slot mid-prefill
         # keeps its admission-time seed key, so a request's sample stream
         # depends on its seed alone, not on batch-mates' decode traffic
-        new_keys = np.asarray(new_keys)
-        finite = np.asarray(finite).copy()
         for s in slots:
             st.keys[s] = new_keys[s]
-        d.mark("device")
         if st is not self._st:
             self.loop_profiler.finish(d)
             return          # engine restarted mid-dispatch: stale state
@@ -1107,7 +1132,7 @@ class InferenceEngine:
             # slot: device state is untouched, so batch-mates are
             # trivially token-identical to an uninjected run
             finite[min(slots)] = False
-        step_secs = time.perf_counter() - t0
+        step_secs = d.wait_secs
         self.decode_secs += step_secs
         self.decode_steps += 1
         self.occupancy_sum += len(slots)
@@ -1145,7 +1170,6 @@ class InferenceEngine:
         1..K+1 tokens per slot with rejected drafts rolled back by a
         cursor decrement (the pages are per-slot append-only; the next
         step's scatter overwrites the stale tail)."""
-        disp.kind = "verify"
         cfg = self.config
         K = self.draft_k
         bs = cfg.block_size
@@ -1179,25 +1203,23 @@ class InferenceEngine:
             last = ctx + max(int(vlens[s]), 1) - 1
             for bi in range(ctx // bs, last // bs + 1):
                 self._writable(st, s, bi)
-        traces = sorted({r.trace_id for r in decoding if r.trace_id})
+        self._note_batch(st, disp, slots, decoding)
+        disp.drafted = int(draft_lens.sum())
         disp.mark("build_inputs")
-        t0 = time.perf_counter()
-        with tracing.span("decode_step", "serve", batch=len(slots),
-                          traces=traces,
-                          drafted=int(draft_lens.sum())):
-            emit, st.pages, new_keys, finite = self._verify_step(
-                self.params, st.pages, verify_tokens, st.context_lens,
-                st.blocks.tables.copy(), vlens, st.temps, st.top_ks,
-                st.top_ps, st.ban_a, st.ban_b, st.keys)
-            emit = np.asarray(emit)
+        emit, st.pages, new_keys, finite = self._verify_step(
+            self.params, st.pages, verify_tokens, st.context_lens,
+            st.blocks.tables.copy(), vlens, st.temps, st.top_ks,
+            st.top_ps, st.ban_a, st.ban_b, st.keys)
+        disp.mark("dispatch")
+        emit = np.asarray(emit)
+        new_keys = np.asarray(new_keys)
+        finite = np.asarray(finite).copy()
+        disp.mark("fetch")
         # same key discipline as the plain decode step: exactly one
         # split per decoding slot per step, so a sampled slot's stream
         # is bit-identical spec-on vs spec-off
-        new_keys = np.asarray(new_keys)
-        finite = np.asarray(finite).copy()
         for s in slots:
             st.keys[s] = new_keys[s]
-        disp.mark("device")
         if st is not self._st:
             self.loop_profiler.finish(disp)
             return          # engine restarted mid-dispatch: stale state
@@ -1205,7 +1227,7 @@ class InferenceEngine:
         if slots and inj is not None \
                 and inj.poison_nonfinite(self._dispatches):
             finite[min(slots)] = False
-        step_secs = time.perf_counter() - t0
+        step_secs = disp.wait_secs
         self.decode_secs += step_secs
         self.decode_steps += 1
         self.occupancy_sum += len(slots)
@@ -1303,14 +1325,20 @@ class InferenceEngine:
             n_written = 0   # poisoned KV: register nothing for reuse
         st.scheduler.evict(req, token_ids=req.tokens, n_written=n_written)
         self._count_finish(req.finish_reason)
+        # the request's own span, on the launches' clock: kept beside
+        # them always, and written to the SpanTracer when one is there
+        span = RequestSpan(
+            req.id, req.trace_id, req._pc_submit, req._pc_admit,
+            req._pc_first_token, time.perf_counter(),
+            len(req.prompt_tokens), len(req.out_tokens), req.finish_reason)
+        self.loop_profiler.record_request(span)
         tracer = tracing.get_tracer()
-        pc0 = getattr(req, "_pc_submit", None)
-        if tracer is not None and pc0 is not None:
+        if tracer is not None:
             tracer.completed(
-                "request", "serve", pc0, time.perf_counter() - pc0,
+                "request", "serve", span.submit, span.finish - span.submit,
                 request=req.id, trace=req.trace_id,
-                prompt_tokens=len(req.prompt_tokens),
-                new_tokens=len(req.out_tokens),
+                prompt_tokens=span.prompt_tokens,
+                new_tokens=span.answer_tokens,
                 finish_reason=req.finish_reason)
         bstats = st.blocks.stats()
         tpot = req.tpot_secs()

@@ -1,51 +1,88 @@
-"""Engine-loop goodput profiler: per-dispatch host/device attribution.
+"""Engine-loop span stream: one record per launch, on two clocks.
 
-PR 9 attributes *per-request* phases; this module attributes the serve
-loop's own wall-clock.  Every dispatch of the engine's jitted programs
-(prefill chunk / decode step / verify step) is accounted into host
-phases —
+Every launch of the engine's jitted programs (prefill chunk / decode
+step / verify step) is one ``DispatchRecord``: its number (``seq``), its
+``kind``, what it worked on, and the absolute ``time.perf_counter``
+stamp of ``begin`` and of every phase boundary.  The phases tile the
+launch —
 
-* ``schedule``     admission + preemption + slot bookkeeping,
+* ``schedule``     deadline sweep, admission, preemption, the decision,
 * ``draft``        prompt-lookup proposals (speculative only),
-* ``build_inputs`` traced host-numpy array assembly + COW barriers,
-* ``device``       dispatch -> block on the fetched outputs,
-* ``emit``         token commits, stream writes, telemetry,
+* ``build_inputs`` host-numpy array assembly + COW barriers + swap-ins,
+* ``dispatch``     the jitted call, until it RETURNS: argument upload
+                   and enqueue (a last prefill chunk's first-token
+                   sampler call included),
+* ``fetch``        until the last blocking read of the outputs returns,
+* ``emit``         token commits, stream writes, retirement, telemetry,
 
-— so ``device_busy_pct`` / ``host_bubble_pct`` say where the loop's
-time actually goes, which is the before/after baseline any
-double-buffering of the host loop must beat (ROADMAP "Raw speed").
+— and ``gap`` is the time between one launch's ``finish`` and the next
+``begin``.  ``dispatch`` + ``fetch`` is what the host WAITED
+(``wait_secs`` / ``wait_pct``); it is a host clock and is never called
+device time.  ``host_bubble_pct`` = 100 - ``wait_pct`` is the share of
+the loop the device cannot be running the engine's work, which is the
+before/after baseline any double-buffering of the loop must beat.
+
+The record is the serve loop's ONE span source:
+
+* a bounded ring (``RING_SIZE`` launches) holds the records themselves,
+  so a reader can cut them to a window or lay them against a profiler
+  trace; ``live_profilers()`` reaches it without the engine;
+* each phase is also a ``jax.profiler.TraceAnnotation`` named
+  ``loop.<phase>`` (``loop.gap`` between launches), entered and left at
+  the marks on the engine thread with ``seq`` (and ``kind`` once the
+  scheduler has decided) as arguments: inert with no profiler
+  recording, and with one (``--profile_dir``, TensorBoard) the loop's
+  phases sit on the host line above the device's operations, on the
+  profiler's clock;
+* with a ``tracing.SpanTracer`` installed (``--trace_dir``) ``finish``
+  writes the same record once more as Chrome-trace spans: the
+  ``loop.<phase>`` sub-spans and the enclosing ``decode_step`` /
+  ``prefill_chunk`` span (start of ``dispatch`` to end of ``fetch``)
+  that ``tools/serve_report.py`` joins a request's lifecycle on;
+* ``record_request`` keeps each retired request's own span beside the
+  launches (submit, admit, first token, finish on the same clock).
 
 Everything here is host-side python: the profiler never touches a
 traced value, so the zero-steady-state-recompile invariant holds with
 it on (guarded by ``test_engine_zero_recompiles_after_warmup``).
 
-Surfaces:
-
-* bounded ring of per-dispatch records + cumulative per-phase seconds
-  (``stats()`` — embedded in the engine block of ``/metrics``; the
-  phase histograms ride the PR 9 mergeable-Histogram shape, so the
-  Prometheus exposition and the router's bucket-wise fleet merge get
-  them for free),
-* windowed rollups over the ring (recent ``device_busy_pct``),
-* a periodic ``engine_loop_stats`` JSONL record (telemetry schema 10),
-* SpanTracer ``loop.<phase>`` sub-spans on the Perfetto timeline,
-* a dispatch-gap detector: a gap between consecutive busy dispatches
-  beyond ``stall_threshold_secs`` is a loop stall — counted and
-  written to the flight recorder (armed after warmup so compile gaps
-  never count).
+Other surfaces, as before: cumulative per-phase seconds and mergeable
+phase histograms (``stats()``, the engine block of ``/metrics``), a
+rollup over the last ``WINDOW_DISPATCHES`` launches, the periodic
+``engine_loop_stats`` JSONL record, and the dispatch-gap stall detector
+(armed after warmup so compile gaps never count).
 """
 
 from __future__ import annotations
 
 import threading
 import time
+from bisect import bisect_left
 from collections import deque
-from typing import Any, Dict, List, Optional
+from itertools import islice
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from megatron_llm_tpu import telemetry, tracing
 
-# Canonical phase order (also the order the sub-spans tile a dispatch).
-LOOP_PHASES = ("schedule", "draft", "build_inputs", "device", "emit")
+# Canonical phase order: the order the marks tile a launch in.
+LOOP_PHASES = ("schedule", "draft", "build_inputs", "dispatch", "fetch",
+               "emit")
+_INDEX = {p: i for i, p in enumerate(LOOP_PHASES)}
+_DISPATCH, _FETCH = _INDEX["dispatch"], _INDEX["fetch"]
+_NOTE_NAMES = tuple("loop." + p for p in LOOP_PHASES)
+# the phase that starts when phase i ends (``draft`` only in a verify
+# launch; the tail after an ``emit`` mark stays emit's)
+_NEXT = {True: (1, 2, 3, 4, 5, 5), False: (2, 2, 3, 4, 5, 5)}
+
+# launches kept: a 45 s window, its lead-in, a traced stretch and a 90 s
+# drain at 80 launches a second fit several times over
+RING_SIZE = 65536
+REQUEST_RING_SIZE = 16384
+# the "recent" rollup of stats() (and the alert rule on it) and the
+# postmortem bundle look at this many launches, whatever the ring holds
+WINDOW_DISPATCHES = 512
 
 # Host phases run far below DEFAULT_LATENCY_BUCKETS' 1 ms floor, so the
 # loop histograms get their own fixed bounds (fleet-mergeable: fixed
@@ -55,59 +92,183 @@ LOOP_PHASE_BUCKETS = (
     0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
 )
 
+# the enclosing Chrome-trace span of a launch, by its kind
+_SPAN_NAME = {"prefill": "prefill_chunk", "decode": "decode_step",
+              "verify": "decode_step"}
+
+
+class RequestSpan(NamedTuple):
+    """One retired request on the launches' clock (``perf_counter``);
+    ``admit`` / ``first_token`` are None for a request that never got
+    that far."""
+    request: int
+    trace_id: Optional[str]
+    submit: float
+    admit: Optional[float]
+    first_token: Optional[float]
+    finish: float
+    prompt_tokens: int
+    answer_tokens: int
+    finish_reason: Optional[str]
+
 
 class DispatchRecord:
-    """One dispatch's accounting, owned by the engine thread until
-    ``LoopProfiler.finish``.  ``mark(phase)`` attributes everything
-    since the previous mark to ``phase``, so the marks tile
-    ``[start, finish]`` exactly and the phase times sum to the
-    dispatch wall-clock by construction."""
+    """One launch, owned by the engine thread until
+    ``LoopProfiler.finish`` and read-only in the ring after it.
 
-    __slots__ = ("kind", "start", "gap_secs", "phases", "_last", "_clock")
+    ``t[0]`` is ``begin`` and ``t[i + 1]`` the end of phase
+    ``LOOP_PHASES[i]``, all absolute ``perf_counter`` stamps: the phases
+    tile ``[t[0], t[-1]]`` exactly and sum to the launch's wall-clock by
+    construction.  ``mark(phase)`` ends ``phase`` now; marks come in
+    canonical order, a repeated mark extends its phase, and one out of
+    order is attributed to the latest phase marked."""
 
-    def __init__(self, clock, start: float, gap_secs: float):
-        self.kind = "decode"
-        self.start = start
+    kind = "decode"
+    # what it worked on (class defaults; a launch sets what it has):
+    # decode/verify rows and the sum of their context tokens; for a
+    # prefill chunk its start and valid (its request is ``requests[0]``)
+    rows = 0
+    context_tokens = 0
+    start = 0
+    valid = 0
+    cached_tokens = 0
+    drafted = 0
+    # the requests (and their trace ids) this launch served: the spans
+    # that caused it
+    requests: Tuple[int, ...] = ()
+    traces: Tuple[str, ...] = ()
+    _at = 0                     # index of the last phase marked
+
+    def __init__(self, clock, seq: int, begin: float, gap_secs: float):
+        self.seq = seq
         self.gap_secs = gap_secs
-        self.phases: Dict[str, float] = {}
-        self._last = start
+        self.t = [begin] * (len(LOOP_PHASES) + 1)
         self._clock = clock
+        self._note = note = TraceAnnotation("loop.schedule", seq=seq)
+        note.__enter__()
 
     def mark(self, phase: str) -> None:
         now = self._clock()
-        self.phases[phase] = (self.phases.get(phase, 0.0)
-                              + max(now - self._last, 0.0))
-        self._last = now
+        i = _INDEX[phase]
+        if i < self._at:
+            i = self._at
+        else:
+            self._at = i
+        self.t[i + 1] = now
+        self._note.__exit__(None, None, None)
+        self._note = note = TraceAnnotation(
+            _NOTE_NAMES[_NEXT[self.kind == "verify"][i]], seq=self.seq,
+            kind=self.kind)
+        note.__enter__()
+
+    def _close(self, now: float) -> None:
+        """End the launch at ``now``: the tail goes to ``emit``, and a
+        phase never marked takes no time."""
+        self._note.__exit__(None, None, None)
+        self._note = None
+        t = self.t
+        t[-1] = now
+        for i in range(1, len(t)):
+            if t[i] < t[i - 1]:
+                t[i] = t[i - 1]
+
+    # -- reading (any thread, after finish) -----------------------------
+
+    @property
+    def request(self) -> Optional[int]:
+        """The one request a prefill chunk belongs to."""
+        return (self.requests[0] if self.kind == "prefill" and self.requests
+                else None)
+
+    @property
+    def begin(self) -> float:
+        return self.t[0]
+
+    @property
+    def end(self) -> float:
+        return self.t[-1]
+
+    @property
+    def wall_secs(self) -> float:
+        return self.t[-1] - self.t[0]
+
+    @property
+    def wait_secs(self) -> float:
+        """``dispatch`` + ``fetch``: what the host waited."""
+        return self.t[_FETCH + 1] - self.t[_DISPATCH]
+
+    def phase_start(self, phase: str) -> float:
+        return self.t[_INDEX[phase]]
+
+    def phase_end(self, phase: str) -> float:
+        return self.t[_INDEX[phase] + 1]
+
+    def phase_secs(self, phase: str) -> float:
+        i = _INDEX[phase]
+        return self.t[i + 1] - self.t[i]
+
+    def as_dict(self) -> Dict[str, Any]:
+        """JSON-able copy (postmortem bundles)."""
+        return {
+            "seq": self.seq, "kind": self.kind, "begin": self.t[0],
+            "wall_secs": self.wall_secs, "gap_secs": self.gap_secs,
+            "wait_secs": self.wait_secs,
+            "phases": {p: self.phase_secs(p) for p in LOOP_PHASES},
+            "rows": self.rows, "context_tokens": self.context_tokens,
+            "request": self.request, "start": self.start,
+            "valid": self.valid, "requests": list(self.requests),
+            "traces": list(self.traces),
+        }
+
+
+# The profilers of this process's newest engines, so that a reader which
+# was handed no engine (the benchmark's metric sources receive only their
+# ``run``, and read after the engine has stopped) reaches the spans.  A
+# profiler holds no reference to its engine; it stays readable here until
+# ``_LIVE.maxlen`` newer ones have pushed it out.
+_LIVE: deque = deque(maxlen=4)
+_LIVE_LOCK = threading.Lock()
+
+
+def live_profilers() -> List["LoopProfiler"]:
+    """The profilers of the newest engines of this process, the one
+    with the most launches first."""
+    with _LIVE_LOCK:
+        found = list(_LIVE)
+    return sorted(found, key=lambda p: -p.launches())
 
 
 class LoopProfiler:
-    """Per-dispatch host/device accounting for the engine loop.
+    """Per-launch accounting for the engine loop.
 
     ``clock`` is injectable (the GoodputAccounter pattern) so tests
     script exact phase durations.  All mutation happens on the engine
-    loop thread; ``stats()`` is read from HTTP handler threads, so the
-    cumulative counters and the ring live under ``_lock``.
+    loop thread; ``stats()`` and the ring are read from HTTP handler
+    threads, so the cumulative counters and the rings live under
+    ``_lock``.
     """
 
     # lint-enforced (graft-race TH001): the rollup counters are written
     # by the engine loop (finish) and read by /metrics handler threads
-    # (stats), so every access goes through _lock.  _last_end and
-    # stall_armed are engine-loop/warmup-thread only (single writer,
-    # never read across roots).
+    # (stats), so every access goes through _lock.  _last_end, _seq,
+    # _gap_note and stall_armed are engine-loop/warmup-thread only
+    # (single writer, never read across roots).
     _lock_protected_ = {
         "dispatches": "_lock",
         "dispatches_by_kind": "_lock",
         "wall_secs": "_lock",
         "gap_secs": "_lock",
-        "device_secs": "_lock",
         "phase_secs": "_lock",
+        "_phase_counts": "_lock",
+        "_phase_launches": "_lock",
         "stalls": "_lock",
         "_ring": "_lock",
+        "_requests": "_lock",
         "_emitted_at_dispatches": "_lock",
         "_emitted_at_time": "_lock",
     }
 
-    def __init__(self, ring_size: int = 512,
+    def __init__(self, ring_size: int = RING_SIZE,
                  stall_threshold_secs: float = 0.5,
                  emit_every_dispatches: int = 256,
                  emit_interval_secs: float = 15.0,
@@ -118,49 +279,60 @@ class LoopProfiler:
         self.emit_interval_secs = float(emit_interval_secs)
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=max(int(ring_size), 1))
-        self._hist = {p: telemetry.Histogram(LOOP_PHASE_BUCKETS)
-                      for p in LOOP_PHASES}
+        self._requests: deque = deque(maxlen=REQUEST_RING_SIZE)
         self.dispatches = 0
         self.dispatches_by_kind = {"prefill": 0, "decode": 0, "verify": 0}
-        self.wall_secs = 0.0        # sum of dispatch wall-clocks
-        self.gap_secs = 0.0         # between consecutive busy dispatches
-        self.device_secs = 0.0
+        self.wall_secs = 0.0        # sum of launch wall-clocks
+        self.gap_secs = 0.0         # between consecutive busy launches
         self.phase_secs = {p: 0.0 for p in LOOP_PHASES}
+        # the phase histograms' raw counts (a phase that took no time in
+        # a launch is not in them), kept under _lock with the rest and
+        # given the mergeable snapshot shape in stats()
+        self._phase_counts = [[0] * (len(LOOP_PHASE_BUCKETS) + 1)
+                              for _ in LOOP_PHASES]
+        self._phase_launches = [0] * len(LOOP_PHASES)
         self.stalls = 0
         # armed by the engine after warmup(): compile-time gaps between
         # warmup dispatches are expected, not stalls
         self.stall_armed = False
         self._last_end: Optional[float] = None
+        self._seq = 0
+        self._gap_note: Optional[TraceAnnotation] = None
         self._emitted_at_dispatches = 0
         self._emitted_at_time = self._clock()
+        with _LIVE_LOCK:
+            _LIVE.append(self)
 
-    # -- per-dispatch protocol (engine loop thread only) ----------------
+    # -- per-launch protocol (engine loop thread only) ------------------
 
     def begin(self) -> DispatchRecord:
-        """Open a dispatch record; the gap since the previous dispatch's
+        """Open a launch record; the gap since the previous launch's
         finish is the loop's dead time (zero when ``idle()`` broke the
         chain — an empty engine is not a stall)."""
         now = self._clock()
+        note = self._gap_note
+        if note is not None:
+            self._gap_note = None
+            note.__exit__(None, None, None)
         last = self._last_end
         gap = max(now - last, 0.0) if last is not None else 0.0
-        return DispatchRecord(self._clock, now, gap)
+        return DispatchRecord(self._clock, self._seq, now, gap)
 
-    def idle(self) -> None:
-        """The scheduler had no action: break the gap chain so the wait
-        for new work never reads as a dispatch gap."""
+    def idle(self, d: Optional[DispatchRecord] = None) -> None:
+        """The scheduler had no action: ``d`` is no launch, and the
+        chain breaks so the wait for new work never reads as a gap."""
+        if d is not None:
+            d._note.__exit__(None, None, None)
         self._last_end = None
 
-    def finish(self, d: DispatchRecord, final_phase: str = "emit") -> None:
+    def finish(self, d: DispatchRecord) -> None:
         """Close the record: the tail since the last mark goes to
-        ``final_phase``, rollups update, and the stall / sub-span /
-        periodic-emission side effects fire.  Never raises — the engine
-        loop must survive any telemetry trouble."""
+        ``emit``, the record enters the ring, rollups update, and the
+        stall / tracer / periodic-emission side effects fire.  Never
+        raises — the engine loop must survive any telemetry trouble."""
         now = self._clock()
-        d.phases[final_phase] = (d.phases.get(final_phase, 0.0)
-                                 + max(now - d._last, 0.0))
-        d._last = now
-        wall = max(now - d.start, 0.0)
-        device = d.phases.get("device", 0.0)
+        d._close(now)
+        t = d.t
         stalled = (self.stall_armed
                    and d.gap_secs > self.stall_threshold_secs)
         with self._lock:
@@ -168,25 +340,23 @@ class LoopProfiler:
             n = self.dispatches
             self.dispatches_by_kind[d.kind] = (
                 self.dispatches_by_kind.get(d.kind, 0) + 1)
-            self.wall_secs += wall
+            self.wall_secs += t[-1] - t[0]
             self.gap_secs += d.gap_secs
-            self.device_secs += device
-            for p, v in d.phases.items():
-                self.phase_secs[p] = self.phase_secs.get(p, 0.0) + v
+            secs = self.phase_secs
+            for i, p in enumerate(LOOP_PHASES):
+                v = t[i + 1] - t[i]
+                if v > 0.0:
+                    secs[p] += v
+                    self._phase_counts[i][
+                        bisect_left(LOOP_PHASE_BUCKETS, v)] += 1
+                    self._phase_launches[i] += 1
             if stalled:
                 self.stalls += 1
-            self._ring.append({
-                "kind": d.kind,
-                "wall_secs": wall,
-                "gap_secs": d.gap_secs,
-                "device_secs": device,
-                "phases": dict(d.phases),
-            })
+            self._ring.append(d)
+        self._seq = d.seq + 1
         self._last_end = now
-        for p, v in d.phases.items():
-            h = self._hist.get(p)
-            if h is not None:
-                h.observe(v)
+        self._gap_note = TraceAnnotation("loop.gap", seq=self._seq)
+        self._gap_note.__enter__()
         if stalled:
             try:
                 fr = telemetry.get_flight_recorder()
@@ -202,34 +372,72 @@ class LoopProfiler:
         tracer = tracing.get_tracer()
         if tracer is not None:
             try:
-                t = d.start
-                for p in LOOP_PHASES:
-                    v = d.phases.get(p, 0.0)
-                    if v > 0.0:
-                        tracer.completed(f"loop.{p}", "serve_loop",
-                                         start=t, dur_secs=v, kind=d.kind)
-                        t += v
+                self._export(tracer, d)
             except Exception:   # noqa: BLE001
                 pass
         self.maybe_emit(now=now)
 
-    # -- rollups --------------------------------------------------------
+    @staticmethod
+    def _export(tracer, d: DispatchRecord) -> None:
+        """The record as Chrome-trace spans: the sub-spans that tile it,
+        and the enclosing launch span the lifecycle report joins on."""
+        t = d.t
+        for i, p in enumerate(LOOP_PHASES):
+            if t[i + 1] > t[i]:
+                tracer.completed(f"loop.{p}", "serve_loop", start=t[i],
+                                 dur_secs=t[i + 1] - t[i], kind=d.kind,
+                                 seq=d.seq)
+        if d.kind == "prefill":
+            attrs = {"request": d.request,
+                     "trace": d.traces[0] if d.traces else None,
+                     "tokens": d.valid, "cached_tokens": d.cached_tokens}
+        else:
+            attrs = {"batch": d.rows, "traces": list(d.traces)}
+            if d.kind == "verify":
+                attrs["drafted"] = d.drafted
+        tracer.completed(_SPAN_NAME[d.kind], "serve", start=t[_DISPATCH],
+                         dur_secs=d.wait_secs, seq=d.seq, **attrs)
+
+    def record_request(self, span: RequestSpan) -> None:
+        """A retired request's own span, kept beside the launches
+        whether or not a SpanTracer is installed."""
+        with self._lock:
+            self._requests.append(span)
+
+    # -- reading --------------------------------------------------------
+
+    def launches(self) -> int:
+        with self._lock:
+            return self.dispatches
+
+    def records(self, last: Optional[int] = None) -> List[DispatchRecord]:
+        """The ring, oldest first (its last ``last`` launches): the
+        records themselves, read-only."""
+        with self._lock:
+            if last is None or last >= len(self._ring):
+                return list(self._ring)
+            return list(islice(reversed(self._ring), last))[::-1]
+
+    def request_spans(self) -> List[RequestSpan]:
+        with self._lock:
+            return list(self._requests)
+
+    def ring_records(self, last: int = WINDOW_DISPATCHES
+                     ) -> List[Dict[str, Any]]:
+        """JSON-able copy of the newest launches — the raw material
+        postmortem bundles freeze when an alert fires
+        (serving/alerts.py)."""
+        return [d.as_dict() for d in self.records(last)]
 
     @staticmethod
-    def _busy_pcts(device: float, wall: float, gap: float):
-        """(device_busy_pct, host_bubble_pct) over a busy window of
+    def _wait_pcts(wait: float, wall: float, gap: float):
+        """(wait_pct, host_bubble_pct) over a busy window of
         ``wall + gap`` seconds; (None, None) on an empty window."""
         busy = wall + gap
         if busy <= 0.0:
             return None, None
-        dev = 100.0 * min(device / busy, 1.0)
-        return round(dev, 3), round(100.0 - dev, 3)
-
-    def ring_records(self) -> List[Dict[str, Any]]:
-        """Copy of the per-dispatch ring — the raw material postmortem
-        bundles freeze when an alert fires (serving/alerts.py)."""
-        with self._lock:
-            return list(self._ring)
+        pct = 100.0 * min(wait / busy, 1.0)
+        return round(pct, 3), round(100.0 - pct, 3)
 
     def stats(self) -> Dict[str, Any]:
         """JSON-able rollup for the engine's ``/metrics`` block.  The
@@ -238,20 +446,24 @@ class LoopProfiler:
         histogram series and the router's fleet merge bucket-sums
         them."""
         with self._lock:
-            ring: List[Dict[str, Any]] = list(self._ring)
             dispatches = self.dispatches
             by_kind = dict(self.dispatches_by_kind)
             wall = self.wall_secs
             gap = self.gap_secs
-            device = self.device_secs
             phase_secs = dict(self.phase_secs)
             stalls = self.stalls
-        dev_pct, bubble_pct = self._busy_pcts(device, wall, gap)
-        w_wall = sum(r["wall_secs"] for r in ring)
-        w_gap = sum(r["gap_secs"] for r in ring)
-        w_dev = sum(r["device_secs"] for r in ring)
-        w_dev_pct, w_bubble_pct = self._busy_pcts(w_dev, w_wall, w_gap)
-        snaps = {p: h.snapshot() for p, h in self._hist.items()}
+            counts = [list(c) for c in self._phase_counts]
+            timed = list(self._phase_launches)
+        snaps = {p: telemetry.histogram_snapshot(
+                     LOOP_PHASE_BUCKETS, counts[i], timed[i], phase_secs[p])
+                 for i, p in enumerate(LOOP_PHASES)}
+        recent = self.records(WINDOW_DISPATCHES)
+        wait = phase_secs["dispatch"] + phase_secs["fetch"]
+        wait_pct, bubble_pct = self._wait_pcts(wait, wall, gap)
+        w_wall = sum(r.wall_secs for r in recent)
+        w_gap = sum(r.gap_secs for r in recent)
+        w_wait = sum(r.wait_secs for r in recent)
+        w_wait_pct, w_bubble_pct = self._wait_pcts(w_wait, w_wall, w_gap)
         p50 = {p: telemetry.histogram_percentile(s, 0.50)
                for p, s in snaps.items()}
         p95 = {p: telemetry.histogram_percentile(s, 0.95)
@@ -261,17 +473,17 @@ class LoopProfiler:
             "dispatches_by_kind": by_kind,
             "wall_secs": round(wall, 6),
             "gap_secs": round(gap, 6),
-            "device_secs": round(device, 6),
-            "host_secs": round(max(wall - device, 0.0), 6),
+            "wait_secs": round(wait, 6),
+            "host_secs": round(max(wall - wait, 0.0), 6),
             "phase_secs": {p: round(v, 6) for p, v in phase_secs.items()},
-            "device_busy_pct": dev_pct,
+            "wait_pct": wait_pct,
             "host_bubble_pct": bubble_pct,
             "stalls": stalls,
             "stall_threshold_secs": self.stall_threshold_secs,
             "window": {
-                "dispatches": len(ring),
+                "dispatches": len(recent),
                 "wall_secs": round(w_wall, 6),
-                "device_busy_pct": w_dev_pct,
+                "wait_pct": w_wait_pct,
                 "host_bubble_pct": w_bubble_pct,
             },
             "phase_p50_secs": p50,
@@ -280,8 +492,8 @@ class LoopProfiler:
         }
 
     def loop_stats_record(self) -> Dict[str, Any]:
-        """The periodic ``engine_loop_stats`` JSONL record (schema 10):
-        the ``stats()`` rollup minus the bulky histogram snapshots —
+        """The periodic ``engine_loop_stats`` JSONL record: the
+        ``stats()`` rollup minus the bulky histogram snapshots —
         scalar p50/p95 travel instead."""
         s = self.stats()
         s.pop("histograms", None)

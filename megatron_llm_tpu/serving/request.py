@@ -128,6 +128,7 @@ class Request:
         # as the request rides prefill chunks / decode steps)
         self._pc_submit = time.perf_counter()
         self._pc_admit: Optional[float] = None
+        self._pc_first_token: Optional[float] = None
         self.queue_wait_secs: Optional[float] = None
         self.admission_secs = 0.0
         self.prefill_compute_secs = 0.0
@@ -150,6 +151,7 @@ class Request:
     def _emit_token(self, token: int) -> None:
         if self.t_first_token is None:
             self.t_first_token = time.monotonic()
+            self._pc_first_token = time.perf_counter()
         self.out_tokens.append(int(token))
         if self._events is not None:
             t0 = time.perf_counter()

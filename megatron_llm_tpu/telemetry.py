@@ -367,10 +367,17 @@ class Histogram:
         with self._lock:
             counts = list(self._counts)
             total, s = self._count, self._sum
-        buckets = {_bucket_label(b): counts[i]
-                   for i, b in enumerate(self.bounds)}
-        buckets[_INF_LABEL] = counts[-1]
-        return {"buckets": buckets, "count": total, "sum": round(s, 9)}
+        return histogram_snapshot(self.bounds, counts, total, s)
+
+
+def histogram_snapshot(bounds, counts, total: int, s: float
+                       ) -> Dict[str, Any]:
+    """The mergeable snapshot shape from raw per-bucket counts
+    (``len(bounds) + 1`` of them, the last the +Inf bucket) — for a
+    writer that keeps its counts under a lock of its own."""
+    buckets = {_bucket_label(b): counts[i] for i, b in enumerate(bounds)}
+    buckets[_INF_LABEL] = counts[-1]
+    return {"buckets": buckets, "count": total, "sum": round(s, 9)}
 
 
 def is_histogram_snapshot(d: Any) -> bool:
@@ -543,11 +550,10 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    slot/url and the dispatch-p95/in-flight readings behind scaling
 #    decisions) — see serving/supervisor.py's sharded front door
 # 10: + kind="serve" event="engine_loop_stats" records (periodic
-#    engine-loop goodput rollups: per-phase schedule / draft /
-#    build_inputs / device / emit seconds, device_busy_pct /
-#    host_bubble_pct, dispatch-gap stall count, windowed recents and
-#    phase p50/p95) — see serving/loop_profiler.py and
-#    tools/serve_report.py's loop-goodput section
+#    engine-loop goodput rollups: per-phase seconds, host_bubble_pct,
+#    dispatch-gap stall count, windowed recents and phase p50/p95) —
+#    see serving/loop_profiler.py and tools/serve_report.py's
+#    loop-goodput section
 # 11: + kind="serve" event="cache_stats" records (periodic KV
 #    prefix-cache observatory rollups: salted-digest heat top-K,
 #    miss-cause taxonomy cold/evicted, capacity-vs-churn eviction
@@ -569,7 +575,13 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    window_secs / since_unix / bundle (the postmortem bundle directory
 #    captured on firing) — see serving/alerts.py and
 #    tools/serve_report.py's incident timeline
-TELEMETRY_SCHEMA_VERSION = 13
+# 14: engine_loop_stats says what its clocks are: the phase ``device``
+#    (a host clock around dispatch and three fetches) is split into
+#    ``dispatch`` (until the jitted call returns) and ``fetch`` (until
+#    the last blocking read returns); device_secs / device_busy_pct
+#    become wait_secs / wait_pct (their sum, what the host waited);
+#    host_bubble_pct = 100 - wait_pct keeps its name and meaning
+TELEMETRY_SCHEMA_VERSION = 14
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -834,6 +834,8 @@ def build_server_alerts(server, engine=None, structured_log_dir=None,
                 parts["thread_stacks"] = f"capture failed: {exc}"
             if engine is not None:
                 try:
+                    # the newest launches' spans (the ring itself holds
+                    # far more than a bundle should carry)
                     parts["loop_ring"] = engine.loop_profiler.ring_records()
                 except Exception as exc:
                     parts["loop_ring"] = {"error": str(exc)}

@@ -378,7 +378,12 @@ def pretrain(
     trace = getattr(telemetry, "tracing", None)
     if trace is not None:
         tracing.install_tracing(trace)
-    recompile = trace.recompile if trace is not None else None
+        recompile = trace.recompile
+    else:
+        # compiles are heard whether or not --trace_dir asks for the
+        # span file: ``recompiles`` is in every training log
+        recompile = tracing.RecompileDetector()
+        tracing.install_detector(recompile)
     straggler = trace.straggler if trace is not None else None
     skip_iters = frozenset(skip_iters or ())
 
@@ -539,10 +544,9 @@ def pretrain(
                     metrics = {"lm loss": jnp.float32(float("nan")),
                                "skipped_iter": 1}
                 else:
-                    if recompile is not None:
-                        # the forward-only program's first compile is
-                        # expected — it must not count as a recompile
-                        recompile.pause()
+                    # the forward-only program's first compile is
+                    # expected — it must not count as a recompile
+                    recompile.pause()
                     if skip_step is None:
                         # eval_step is the same forward-only program; reuse
                         # its compilation when available
@@ -553,8 +557,7 @@ def pretrain(
                     # previous step must not masquerade as this iteration's
                     metrics = {"lm loss": skip_step(params, batch, step_key),
                                "skipped_iter": 1}
-                    if recompile is not None:
-                        recompile.resume()
+                    recompile.resume()
             else:
                 timers("train-step", log_level=1).start()
                 t_step0 = time.perf_counter()
@@ -564,17 +567,16 @@ def pretrain(
                     )
                 step_secs = time.perf_counter() - t_step0
                 timers("train-step").stop()
-                if recompile is not None:
-                    # a compile that ran inside the dispatch span is not
-                    # productive step time — reattribute it to 'compile'
-                    _, csecs = recompile.drain()
-                    if csecs > 0.0 and trace is not None:
-                        trace.tracer.goodput.move("step", "compile", csecs)
-                    recompile.observe_step_time(step_secs)
+                # a compile that ran inside the dispatch span is not
+                # productive step time — reattribute it to 'compile'
+                _, csecs = recompile.drain()
+                if csecs > 0.0 and trace is not None:
+                    trace.tracer.goodput.move("step", "compile", csecs)
+                recompile.observe_step_time(step_secs)
             if watchdog is not None:
                 watchdog.resume()   # (re)arms; first arm is post-compile
             iteration += 1
-            if recompile is not None and iteration == start_iteration + 1:
+            if iteration == start_iteration + 1:
                 # the train-step program exists now; any later backend
                 # compile is a recompile (shape/layout leak in the loop)
                 recompile.mark_steady()
@@ -651,13 +653,11 @@ def pretrain(
                         metrics["params norm"] = health.derived_params_norm(
                             ls_host)
                     else:
-                        if recompile is not None:
-                            # first use compiles the cached standalone
-                            # reduction — expected, not a recompile
-                            recompile.pause()
+                        # first use compiles the cached standalone
+                        # reduction — expected, not a recompile
+                        recompile.pause()
                         metrics["params norm"] = _params_norm_jit(params)
-                        if recompile is not None:
-                            recompile.resume()
+                        recompile.resume()
                 timers("train-step-sync", log_level=1).start()
                 with tracing.span("step_sync", "step", iteration=iteration):
                     jax.block_until_ready(metrics["lm loss"])
@@ -772,10 +772,9 @@ def pretrain(
                             k: round(v, 4) if isinstance(v, (int, float))
                             else v
                             for k, v in g.items()}
-                        rec["recompiles"] = int(
-                            counters.get("recompiles", 0))
                         rec["straggler_events"] = int(
                             counters.get("straggler_events", 0))
+                    rec["recompiles"] = int(counters.get("recompiles", 0))
                     if slice_map is not None and gathered:
                         from megatron_llm_tpu import multislice
                         per_host = gathered.get("train-step")
@@ -807,10 +806,9 @@ def pretrain(
             if eval_step is not None and eval_interval and iteration % eval_interval == 0:
                 if watchdog is not None:
                     watchdog.pause()    # eval has its own duration budget
-                if recompile is not None:
-                    # eval's forward-only program compiles on first use —
-                    # an expected compile, not a recompile
-                    recompile.pause()
+                # eval's forward-only program compiles on first use —
+                # an expected compile, not a recompile
+                recompile.pause()
                 t_eval0 = time.perf_counter()
                 with tracing.span("eval", "eval", iteration=iteration):
                     timers("eval-time", log_level=0).start()
@@ -821,8 +819,7 @@ def pretrain(
                             float(eval_step(params, eval_batch, None)))
                     timers("eval-time").stop()
                 non_train[0] += time.perf_counter() - t_eval0
-                if recompile is not None:
-                    recompile.resume()
+                recompile.resume()
                 if watchdog is not None:
                     watchdog.resume()
                 val = sum(losses) / len(losses)
@@ -889,6 +886,8 @@ def pretrain(
         # SystemExit), or an exception — flushes in-flight async
         # saves so a durable checkpoint always gets its tracker
         root_span.__exit__(None, None, None)
+        if trace is None:
+            tracing.install_detector(None)
         if watchdog is not None:
             watchdog.stop()
         if profiler is not None:

@@ -226,15 +226,19 @@ def test_derived_params_norm_partitions_global_norm():
 # In-loop: zero recompiles, JSONL schema, nan@k localization
 # ---------------------------------------------------------------------------
 
-def test_pretrain_layer_stats_zero_recompiles(utils, tmp_path):
+@pytest.mark.parametrize("traced", [True, False])
+def test_pretrain_layer_stats_zero_recompiles(utils, tmp_path, traced):
     """The acceptance run: stats on (interval 2), --log_params_norm
     derived from the partition, eval mixed in — after warmup the step
     never recompiles, and the JSONL stream carries the per-group record
-    exactly at stats boundaries."""
+    exactly at stats boundaries.  Compiles are heard, and ``recompiles``
+    is in every log record, with ``--trace_dir`` or without it."""
     model, params, it = _setup(utils)
     d = str(tmp_path)
     tel = build_telemetry(
-        _telemetry_args(structured_log_dir=d, trace_dir=d), model)
+        _telemetry_args(structured_log_dir=d,
+                        trace_dir=d if traced else None), model)
+    assert (tel.tracing is not None) == traced
     seen = {}
     try:
         pretrain(model, params, _tc(6), ParallelConfig(), it(),

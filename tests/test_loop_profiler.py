@@ -37,8 +37,8 @@ class _Clock:
 
 
 def _dispatch(prof, clock, kind="decode",
-              schedule=0.001, build=0.002, device=0.010, emit=0.0005,
-              draft=None):
+              schedule=0.001, build=0.002, dispatch=0.004, fetch=0.006,
+              emit=0.0005, draft=None):
     d = prof.begin()
     d.kind = kind
     clock.tick(schedule)
@@ -48,10 +48,13 @@ def _dispatch(prof, clock, kind="decode",
         d.mark("draft")
     clock.tick(build)
     d.mark("build_inputs")
-    clock.tick(device)
-    d.mark("device")
+    clock.tick(dispatch)
+    d.mark("dispatch")
+    clock.tick(fetch)
+    d.mark("fetch")
     clock.tick(emit)
     prof.finish(d)
+    return d
 
 
 def test_scripted_clock_exact_phase_accounting():
@@ -66,7 +69,8 @@ def test_scripted_clock_exact_phase_accounting():
     assert prof.phase_secs["schedule"] == pytest.approx(0.002)
     assert prof.phase_secs["draft"] == pytest.approx(0.003)
     assert prof.phase_secs["build_inputs"] == pytest.approx(0.004)
-    assert prof.phase_secs["device"] == pytest.approx(0.020)
+    assert prof.phase_secs["dispatch"] == pytest.approx(0.008)
+    assert prof.phase_secs["fetch"] == pytest.approx(0.012)
     assert prof.phase_secs["emit"] == pytest.approx(0.001)
     # marks tile [begin, finish]: the phases sum to wall EXACTLY, far
     # inside the 5% acceptance bound
@@ -76,12 +80,14 @@ def test_scripted_clock_exact_phase_accounting():
     assert prof.gap_secs == 0.0
 
     s = prof.stats()
-    assert s["device_secs"] == pytest.approx(0.020)
+    # dispatch + fetch is what the host WAITED: never device time
+    assert s["wait_secs"] == pytest.approx(0.020)
     assert s["host_secs"] == pytest.approx(s["wall_secs"] - 0.020)
-    want_busy = 100.0 * 0.020 / s["wall_secs"]
-    assert s["device_busy_pct"] == pytest.approx(want_busy, abs=1e-3)
-    assert s["host_bubble_pct"] == pytest.approx(100 - want_busy,
+    want_wait = 100.0 * 0.020 / s["wall_secs"]
+    assert s["wait_pct"] == pytest.approx(want_wait, abs=1e-3)
+    assert s["host_bubble_pct"] == pytest.approx(100 - want_wait,
                                                  abs=1e-3)
+    assert "device_secs" not in s and "device_busy_pct" not in s
 
 
 def test_gap_idle_and_stall_semantics(tmp_path):
@@ -132,7 +138,7 @@ def test_finish_tail_folds_into_emit_and_double_mark_accumulates():
     prof = LoopProfiler(clock=clock)
     d = prof.begin()
     clock.tick(0.001)
-    d.mark("device")
+    d.mark("fetch")
     clock.tick(0.002)
     d.mark("emit")          # explicit emit mark ...
     clock.tick(0.003)
@@ -202,7 +208,7 @@ def test_tracer_subspans_tile_the_dispatch():
            if str(e.get("name", "")).startswith("loop.")]
     assert [e["name"] for e in evs] == [
         "loop.schedule", "loop.draft", "loop.build_inputs",
-        "loop.device", "loop.emit"]
+        "loop.dispatch", "loop.fetch", "loop.emit"]
     assert all(e["cat"] == "serve_loop" for e in evs)
     # sub-spans tile: no overlap, no double counting — each starts where
     # the previous ended and durations sum to the dispatch wall-clock
@@ -216,7 +222,7 @@ def test_tracer_subspans_tile_the_dispatch():
 def test_surfaces_agree_stats_jsonl_serve_report(tmp_path):
     """Acceptance: ``/metrics`` (stats()), the final ``engine_loop_stats``
     JSONL record, and serve_report's loop-goodput section report the
-    same ``device_busy_pct``."""
+    same ``wait_pct``."""
     stream = telemetry.TelemetryStream(str(tmp_path))
     telemetry.install_stream(stream)
     clock = _Clock()
@@ -225,7 +231,7 @@ def test_surfaces_agree_stats_jsonl_serve_report(tmp_path):
     try:
         for i in range(7):
             _dispatch(prof, clock, kind="decode" if i % 2 else "prefill",
-                      device=0.005 * (1 + i % 3))
+                      fetch=0.005 * (1 + i % 3))
             clock.tick(0.01)        # a little inter-dispatch gap
         prof.maybe_emit(force=True)     # what engine.stop() does
         stats = prof.stats()
@@ -237,14 +243,13 @@ def test_surfaces_agree_stats_jsonl_serve_report(tmp_path):
     assert loops, "no engine_loop_stats records written"
     final = loops[-1]
     assert final["dispatches"] == stats["dispatches"] == 7
-    assert final["device_busy_pct"] == stats["device_busy_pct"]
+    assert final["wait_pct"] == stats["wait_pct"]
     assert final["host_bubble_pct"] == stats["host_bubble_pct"]
 
     report = serve_report.analyze([str(tmp_path)])
     lp = report["loop"]
     assert lp["dispatches"] == 7
-    assert lp["device_busy_pct"] == pytest.approx(
-        stats["device_busy_pct"], abs=1e-3)
+    assert lp["wait_pct"] == pytest.approx(stats["wait_pct"], abs=1e-3)
     assert lp["stalls"] == stats["stalls"] == 0
     # phase shares cover the whole dispatch wall-clock
     assert sum(lp["phase_share"].values()) == pytest.approx(1.0, rel=1e-6)
@@ -252,7 +257,7 @@ def test_surfaces_agree_stats_jsonl_serve_report(tmp_path):
     # and the rendering carries the section
     text = serve_report.render(report)
     assert "engine loop goodput" in text
-    assert "device busy" in text
+    assert "dispatch+fetch wait" in text
 
 
 def test_serve_report_unchanged_on_pre_schema_10_logs(tmp_path):
@@ -277,23 +282,23 @@ def test_stats_shape_and_histograms():
     _dispatch(prof, clock)
     s = prof.stats()
     for key in ("dispatches", "dispatches_by_kind", "wall_secs",
-                "gap_secs", "device_secs", "host_secs", "phase_secs",
-                "device_busy_pct", "host_bubble_pct", "stalls",
+                "gap_secs", "wait_secs", "host_secs", "phase_secs",
+                "wait_pct", "host_bubble_pct", "stalls",
                 "stall_threshold_secs", "window", "phase_p50_secs",
                 "phase_p95_secs", "histograms"):
         assert key in s
     assert set(s["histograms"]) == {f"loop_{p}_secs" for p in LOOP_PHASES}
-    snap = s["histograms"]["loop_device_secs"]
+    snap = s["histograms"]["loop_fetch_secs"]
     assert snap["count"] == 1
     # the mergeable Histogram shape rides the Prometheus exposition
     text = telemetry.prometheus_exposition({"loop": s["histograms"]})
-    assert "megatron_serve_loop_loop_device_secs_bucket" in text
-    assert "megatron_serve_loop_loop_device_secs_count 1" in text
+    assert "megatron_serve_loop_loop_fetch_secs_bucket" in text
+    assert "megatron_serve_loop_loop_fetch_secs_count 1" in text
     # empty profiler: percentages are None, never a ZeroDivisionError
     empty = LoopProfiler(clock=clock).stats()
-    assert empty["device_busy_pct"] is None
+    assert empty["wait_pct"] is None
     assert empty["host_bubble_pct"] is None
-    assert empty["window"]["device_busy_pct"] is None
+    assert empty["window"]["wait_pct"] is None
 
 
 def test_finish_survives_broken_telemetry(monkeypatch):
@@ -316,6 +321,298 @@ def test_finish_survives_broken_telemetry(monkeypatch):
     _dispatch(prof, clock)          # stall + emit paths both throw inside
     assert prof.dispatches == 2
     assert prof.stalls == 1
+
+
+# ---------------------------------------------------------------------------
+# a record is a span: absolute times, what it worked on, a bounded ring,
+# reachable without the engine, and on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def test_marks_tile_begin_to_finish_with_absolute_times():
+    clock = _Clock()
+    prof = LoopProfiler(clock=clock)
+    clock.tick(0.25)
+    t0 = clock.t
+    d = _dispatch(prof, clock, kind="verify", draft=0.003)
+    # boundaries are stamps of the injected clock itself, not durations
+    assert d.begin == t0
+    assert d.phase_start("schedule") == d.begin
+    assert d.phase_end("schedule") == pytest.approx(t0 + 0.001)
+    assert d.phase_start("dispatch") == pytest.approx(t0 + 0.006)
+    assert d.phase_end("dispatch") == pytest.approx(t0 + 0.010)
+    assert d.phase_end("fetch") == pytest.approx(t0 + 0.016)
+    assert d.end == clock.t
+    # each phase starts where the one before it ended, first to last
+    for a, b in zip(LOOP_PHASES, LOOP_PHASES[1:]):
+        assert d.phase_start(b) == d.phase_end(a)
+    assert sum(d.phase_secs(p) for p in LOOP_PHASES) == pytest.approx(
+        d.wall_secs, rel=1e-12)
+    assert d.wait_secs == pytest.approx(0.010)
+    # a phase that was never marked takes no time and breaks no tiling
+    d2 = _dispatch(prof, clock, kind="decode")
+    assert d2.phase_secs("draft") == 0.0
+    assert d2.phase_start("build_inputs") == d2.phase_end("schedule")
+
+
+def test_ring_keeps_seq_kind_work_and_requests_and_stays_bounded():
+    clock = _Clock()
+    prof = LoopProfiler(clock=clock, ring_size=8)
+    for i in range(20):
+        d = prof.begin()
+        d.kind = "prefill" if i % 2 else "decode"
+        d.mark("schedule")
+        if d.kind == "prefill":
+            d.start, d.valid = 64 * i, 64
+            d.requests, d.traces = (100 + i,), (f"t{i}",)
+        else:
+            d.rows, d.context_tokens = 3, 300 + i
+            d.requests, d.traces = (1, 2, 3), ("a", "b")
+        d.mark("build_inputs")
+        clock.tick(0.001)
+        d.mark("dispatch")
+        clock.tick(0.002)
+        d.mark("fetch")
+        prof.finish(d)
+        # the scheduler found nothing: no launch, no number used up
+        prof.idle(prof.begin())
+    recs = prof.records()
+    assert len(recs) == 8 and prof.dispatches == 20
+    assert [r.seq for r in recs] == list(range(12, 20))
+    assert [r.seq for r in prof.records(last=3)] == [17, 18, 19]
+    last = recs[-1]
+    assert (last.kind, last.request, last.start, last.valid) == (
+        "prefill", 119, 64 * 19, 64)
+    assert last.requests == (119,) and last.traces == ("t19",)
+    dec = recs[-2]
+    assert (dec.kind, dec.rows, dec.context_tokens) == ("decode", 3, 318)
+    assert dec.requests == (1, 2, 3)
+    # the postmortem bundle's copy is JSON and carries the same span
+    row = prof.ring_records(last=1)[0]
+    json.dumps(row)
+    assert row["seq"] == 19 and row["phases"]["fetch"] == pytest.approx(
+        0.002)
+    assert row["begin"] == last.begin
+    # the default ring holds a window, its lead-in, a traced stretch and
+    # a drain several times over
+    assert LoopProfiler()._ring.maxlen >= 65536
+
+
+def _tiny_engine(**kw):
+    import jax
+
+    from megatron_llm_tpu.models.llama import LlamaModel, llama_config
+    from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
+
+    cfg = llama_config("tiny", num_layers=2, seq_length=64,
+                       max_position_embeddings=64, padded_vocab_size=64,
+                       use_flash_attn=False)
+    model = LlamaModel(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, EngineConfig(
+        num_slots=4, block_size=8, prefill_chunk=16, max_model_len=64,
+        max_queue_depth=32, default_deadline_secs=0.0, **kw))
+    eng.warmup()
+    return eng
+
+
+def _serve(eng, n=3, prompt=20, new=6):
+    from megatron_llm_tpu.serving import SamplingParams
+
+    eng.start()
+    try:
+        reqs = [eng.submit([1 + i] + list(range(2, prompt + 1)),
+                           SamplingParams(max_new_tokens=new,
+                                          temperature=0.0, eod_id=63),
+                           trace_id=f"trace{i}")
+                for i in range(n)]
+        for r in reqs:
+            r.result(timeout=180)
+    finally:
+        eng.stop()
+    return reqs
+
+
+@pytest.mark.parametrize("speculative", [False, True])
+def test_one_timer_per_launch_counters_are_the_records(speculative):
+    """``decode_secs`` / ``prefill_secs`` are the summed ``dispatch`` +
+    ``fetch`` of the launch records, not a clock of their own; every
+    record says what it worked on; the request's span sits beside
+    them."""
+    from megatron_llm_tpu.serving.loop_profiler import live_profilers
+
+    eng = _tiny_engine(speculative=speculative)
+    reqs = _serve(eng)
+    # reachable with no reference to the engine
+    prof = next(p for p in live_profilers() if p is eng.loop_profiler)
+    recs = prof.records()
+    assert [r.seq for r in recs] == list(range(len(recs)))
+    decode = [r for r in recs if r.kind in ("decode", "verify")]
+    prefill = [r for r in recs if r.kind == "prefill"]
+    assert {r.kind for r in decode} == {"verify" if speculative
+                                        else "decode"}
+    assert eng.decode_secs == pytest.approx(
+        sum(r.wait_secs for r in decode), rel=1e-9)
+    assert eng.prefill_secs == pytest.approx(
+        sum(r.wait_secs for r in prefill), rel=1e-9)
+    assert len(decode) == eng.decode_steps
+    assert len(prefill) == eng.prefill_chunks
+    ids = {r.id for r in reqs}
+    for r in prefill[2:]:                   # the first two are warm-up's
+        assert r.request in ids and r.requests == (r.request,)
+        assert 0 < r.valid <= 16 and r.start % 16 == 0
+        assert r.traces and r.traces[0].startswith("trace")
+    served = [r for r in decode if set(r.requests) & ids]
+    assert served and all(r.rows >= len(r.requests) > 0 for r in served)
+    assert all(r.context_tokens >= 20 * len(r.requests) for r in served)
+    # a request's own prefill launches: 20 prompt tokens are two chunks
+    for q in reqs:
+        own = [r for r in prefill if r.request == q.id]
+        assert [r.start for r in own] == [0, 16]
+        assert sum(r.valid for r in own) == 20
+    spans = {s.request: s for s in prof.request_spans()}
+    for q in reqs:
+        s = spans[q.id]
+        assert s.trace_id == q.trace_id
+        assert s.submit <= s.admit <= s.first_token <= s.finish
+        assert (s.prompt_tokens, s.answer_tokens) == (20, 6)
+        assert s.finish_reason == "length"
+        # the first token left inside its last prefill chunk's launch
+        last = [r for r in prefill if r.request == q.id][-1]
+        assert last.phase_start("dispatch") < s.first_token <= last.end
+
+
+def test_registry_yields_the_live_profiler_without_the_engine():
+    from megatron_llm_tpu.serving.loop_profiler import live_profilers
+
+    clock = _Clock()
+    busy = LoopProfiler(clock=clock)
+    quiet = LoopProfiler(clock=clock)
+    _dispatch(busy, clock)
+    found = live_profilers()
+    assert busy in found and quiet in found
+    assert found.index(busy) < found.index(quiet)   # most launches first
+    # a stopped engine's spans stay readable (the benchmark reads them
+    # after engine.stop()), and the registry stays bounded: newer
+    # profilers push the oldest out
+    for _ in range(8):
+        LoopProfiler(clock=clock)
+    found = live_profilers()
+    assert len(found) == 4 and busy not in found and quiet not in found
+
+
+def test_loop_phases_are_on_the_profilers_clock(tmp_path):
+    """The shared clock: under ``jax.profiler.start_trace`` every phase
+    is a ``loop.<phase>`` event on the engine thread's line, carrying
+    ``seq`` and ``kind``, and the events' starts equal the ring's
+    ``perf_counter`` stamps up to ONE constant offset."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _tiny_engine()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        _serve(eng)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    records = {r.seq: r for r in eng.loop_profiler.records()}
+    events = {}                 # (phase, seq) -> (start seconds, stats)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("loop."):
+                    stats = dict(ev.stats)
+                    # an idle poll opens loop.schedule under the number
+                    # of the launch to come: the last one is the launch's
+                    events[(ev.name[5:], int(stats["seq"]))] = (
+                        ev.start_ns * 1e-9, stats)
+    assert not any(name.startswith("bench.") for name, _ in events)
+    offsets = []
+    for phase in ("dispatch", "fetch"):
+        found = [(seq, at) for (p, seq), (at, st) in events.items()
+                 if p == phase and st.get("kind") == "decode"]
+        assert len(found) >= 5, f"no loop.{phase} events of kind decode"
+        offsets += [at - records[seq].phase_start(phase)
+                    for seq, at in found]
+    assert max(offsets) - min(offsets) < 1e-3, (min(offsets), max(offsets))
+    # the other phases ride the same offset, and kind is known from
+    # build_inputs on (the scheduler has not decided before)
+    seq, (at, st) = next((s, v) for (p, s), v in events.items()
+                         if p == "build_inputs")
+    assert at - records[seq].phase_start("build_inputs") == pytest.approx(
+        offsets[0], abs=1e-3)
+    assert st["kind"] == records[seq].kind
+    assert "kind" not in next(v for (p, _), v in events.items()
+                              if p == "schedule")[1]
+    assert any(p == "gap" for p, _ in events)
+
+
+KERNEL_NAMES = {
+    "paged_attention_decode", "paged_attention_prefill",
+    "paged_attention_decode_quant", "paged_attention_prefill_quant",
+    "flash_attention_fwd", "flash_attention_bwd_fused",
+    "flash_attention_bwd_dq", "flash_attention_bwd_dkv",
+    "rmsnorm_fwd", "rmsnorm_bwd", "layernorm_fwd", "layernorm_bwd",
+}
+PROGRAM_NAMES = {
+    "_decode_step": "engine_decode", "_verify_step": "engine_verify",
+    "_prefill_step": "engine_prefill",
+    "_sample_first": "engine_sample_first", "_cow_copy": "engine_cow_copy",
+    "_fetch_block": "engine_fetch_block", "_host_load": "engine_host_load",
+}
+
+
+def test_every_kernel_and_program_carries_its_stable_name():
+    """Names are a contract with whoever reads a profile: every
+    ``pallas_call`` under ``ops/pallas`` passes ``name=`` and the names
+    that can come out are exactly ``KERNEL_NAMES``; the engine's jitted
+    programs and the train step are called what the issue calls them."""
+    import ast
+    import glob
+    import inspect
+
+    from megatron_llm_tpu import training
+    from megatron_llm_tpu.parallel import pipeline
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = set()
+    calls = 0
+    for path in glob.glob(os.path.join(root, "megatron_llm_tpu", "ops",
+                                       "pallas", "*.py")):
+        tree = ast.parse(open(path).read())
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                continue
+            calls += 1
+            kw = {k.arg: k.value for k in node.keywords}
+            assert "name" in kw, f"{path}:{node.lineno}: pallas_call " \
+                                 f"with no name="
+            if isinstance(kw["name"], ast.Constant):
+                names.add(kw["name"].value)
+        # the paged scaffold takes its name from its two public entries
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Name)
+                    and node.func.id == "_ragged_call"):
+                kw = {k.arg: k.value for k in node.keywords}
+                names |= {kw["name"].value, kw["name"].value + "_quant"}
+    assert calls == 9
+    assert names == KERNEL_NAMES
+
+    eng = _tiny_engine()
+    for attr, name in PROGRAM_NAMES.items():
+        assert getattr(eng, attr).__name__ == name
+    for build in (training.build_train_step,
+                  pipeline.build_pipeline_train_step):
+        assert "def train_step(" in inspect.getsource(build)
 
 
 # ---------------------------------------------------------------------------
@@ -371,7 +668,8 @@ def test_serve_loop_overhead_under_2pct():
             d.mark("schedule")
             d.mark("draft")
             d.mark("build_inputs")
-            d.mark("device")
+            d.mark("dispatch")
+            d.mark("fetch")
             prof.finish(d)
         cost_per_dispatch = (time.perf_counter() - t0) / n
     finally:

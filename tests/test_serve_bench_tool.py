@@ -70,13 +70,13 @@ def _start_stub(paged_kernel="xla", prefill_kernel="xla"):
                     "accepted_tokens": 2 * n,
                     "paged_kernel": paged_kernel,
                     "prefill_kernel": prefill_kernel,
-                    # loop-goodput counters: 64% device busy by
+                    # loop-goodput counters: 64% dispatch+fetch wait by
                     # construction (0.008 / (0.010 + 0.0025))
                     "loop": {
                         "dispatches": 5 * n,
                         "wall_secs": 0.010 * n,
                         "gap_secs": 0.0025 * n,
-                        "device_secs": 0.008 * n,
+                        "wait_secs": 0.008 * n,
                     },
                     # observatory + host spill tier: 2 host-rescued
                     # blocks and 3 device->host spills per request
@@ -257,11 +257,11 @@ def test_bench_reports_host_tier_deltas(stub_server):
 
 
 def test_bench_reports_loop_goodput_delta(stub_server):
-    """device_busy_pct / host_bubble_pct come from the engine's loop
+    """wait_pct / host_bubble_pct come from the engine's loop
     counter deltas over the bench window (never from deltaing the
     server's own percentages)."""
     r = serve_bench.run_bench(stub_server, clients=2, requests=4, tokens=3)
-    assert r["device_busy_pct"] == pytest.approx(64.0, abs=0.01)
+    assert r["wait_pct"] == pytest.approx(64.0, abs=0.01)
     assert r["host_bubble_pct"] == pytest.approx(36.0, abs=0.01)
 
 

@@ -44,7 +44,7 @@ def _replica_snap(requests=10, tokens=500, bubble=None):
     }
     if bubble is not None:
         snap["engine"]["loop"] = {
-            "device_busy_pct": round(100.0 - bubble, 3),
+            "wait_pct": round(100.0 - bubble, 3),
             "host_bubble_pct": bubble, "stalls": 2,
         }
     return snap
@@ -318,9 +318,9 @@ def test_serve_top_once_json_live_router_tier():
         assert served, "no replica reports traffic"
         for row in served:
             assert row["occupancy"] is not None
-            assert row["device_busy_pct"] is not None
+            assert row["wait_pct"] is not None
             assert row["host_bubble_pct"] == pytest.approx(
-                100.0 - row["device_busy_pct"], abs=0.01)
+                100.0 - row["wait_pct"], abs=0.01)
             assert row["engine_restarts"] == 0
     finally:
         for srv in servers:

@@ -347,7 +347,7 @@ def test_request_done_schema_golden(engine, tmp_path):
     the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 13
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 14
     captured = []
     engine.request_done_hook = captured.append
     stream = telemetry.TelemetryStream(str(tmp_path))
@@ -423,17 +423,17 @@ def test_engine_stats_shape(engine):
     assert loop["dispatches"] > 0
     assert loop["dispatches_by_kind"]["prefill"] > 0
     assert loop["dispatches_by_kind"]["decode"] > 0
-    assert set(loop["phase_secs"]) == {"schedule", "draft",
-                                       "build_inputs", "device", "emit"}
-    assert loop["device_secs"] > 0
+    assert set(loop["phase_secs"]) == {"schedule", "draft", "build_inputs",
+                                       "dispatch", "fetch", "emit"}
+    assert loop["wait_secs"] > 0
     # marks tile each dispatch: phases sum to dispatch wall-clock
     assert sum(loop["phase_secs"].values()) == \
         pytest.approx(loop["wall_secs"], rel=0.05)
-    assert 0.0 <= loop["device_busy_pct"] <= 100.0
-    assert loop["device_busy_pct"] + loop["host_bubble_pct"] == \
+    assert 0.0 <= loop["wait_pct"] <= 100.0
+    assert loop["wait_pct"] + loop["host_bubble_pct"] == \
         pytest.approx(100.0, abs=0.01)
     assert loop["window"]["dispatches"] > 0
-    assert "loop_device_secs" in loop["histograms"]
+    assert "loop_fetch_secs" in loop["histograms"]
     # the cache observatory block (cache_observatory.py) rides along too
     cache = s["cache"]
     assert cache["probes"] == cache["hits"] + cache["misses"]
@@ -601,7 +601,7 @@ def test_engine_paged_kernel_token_identity(model_and_params):
                     # loop profiler accounted the kernel-path dispatches
                     loop = eng.stats()["loop"]
                     assert loop["dispatches_by_kind"]["decode"] > 0
-                    assert loop["device_secs"] > 0
+                    assert loop["wait_secs"] > 0
             finally:
                 eng.stop()
                 if det is not None:

@@ -91,9 +91,10 @@ JSON_SCHEMA_KEYS = (
     "drafted_tokens", "accepted_tokens", "accept_rate",
     "accepted_tokens_per_sec",
     # engine-loop goodput over the run (loop_profiler counter deltas):
-    # device-busy vs host-bubble share of the loop's busy time — the
-    # before/after line a host/device-overlap A/B reads
-    "device_busy_pct", "host_bubble_pct",
+    # the share of the loop's busy time the host waited in dispatch +
+    # fetch against its own work — the before/after line a
+    # host/device-overlap A/B reads
+    "wait_pct", "host_bubble_pct",
     # cache observatory (engine cache block deltas over the run):
     # skewed-popularity workload knobs, the miss-cause split, eviction
     # forensics, and per-ghost-tier projected hit rates ({"x2": ...})
@@ -497,7 +498,7 @@ def run_bench(base_url: str, clients: int = 4, requests: int = 16,
         "accept_rate": None,
         "accepted_tokens_per_sec": None,
         # engine-loop goodput (loop_profiler deltas over the run)
-        "device_busy_pct": None,
+        "wait_pct": None,
         "host_bubble_pct": None,
         # cache observatory (engine cache block deltas over the run):
         # miss-cause split, eviction forensics, and per-ghost-tier
@@ -658,14 +659,14 @@ def run_bench(base_url: str, clients: int = 4, requests: int = 16,
                                 and isinstance(b, (int, float)):
                             return b - a
                         return None
-                    dev = loop_delta("device_secs")
+                    wait = loop_delta("wait_secs")
                     busy = loop_delta("wall_secs")
                     gap = loop_delta("gap_secs")
-                    if dev is not None and busy is not None:
+                    if wait is not None and busy is not None:
                         busy += gap or 0.0
                         if busy > 0:
-                            pct = 100.0 * min(dev / busy, 1.0)
-                            out["device_busy_pct"] = round(pct, 3)
+                            pct = 100.0 * min(wait / busy, 1.0)
+                            out["wait_pct"] = round(pct, 3)
                             out["host_bubble_pct"] = round(100.0 - pct, 3)
     return out
 
@@ -725,9 +726,9 @@ def print_table(r: dict) -> None:
     if r.get("prefill_tokens_per_sec") is not None:
         rows += [("prefill throughput",
                   _fmt(r["prefill_tokens_per_sec"], " tok/s"))]
-    if r.get("device_busy_pct") is not None:
-        rows += [("loop device busy / host bubble",
-                  f"{_fmt(r['device_busy_pct'], '%')} / "
+    if r.get("wait_pct") is not None:
+        rows += [("loop dispatch+fetch wait / host bubble",
+                  f"{_fmt(r['wait_pct'], '%')} / "
                   f"{_fmt(r['host_bubble_pct'], '%')}")]
     if r.get("drafted_tokens") is not None:
         rows += [
@@ -919,13 +920,13 @@ def main(argv=None):
                       f"{on['prefill_tokens_per_sec']:.3f} / "
                       f"{off['prefill_tokens_per_sec']:.3f} tok/s "
                       f"({on['prefill_tokens_per_sec'] / off['prefill_tokens_per_sec']:.2f}x)")
-            if on.get("device_busy_pct") is not None or \
-                    off.get("device_busy_pct") is not None:
+            if on.get("wait_pct") is not None or \
+                    off.get("wait_pct") is not None:
                 # the loop-overlap A/B readout: did the flag move the
                 # host bubble, and did tokens/sec follow?
-                print(f"A/B loop device busy on/off: "
-                      f"{_fmt(on.get('device_busy_pct'), '%')} / "
-                      f"{_fmt(off.get('device_busy_pct'), '%')} "
+                print(f"A/B loop dispatch+fetch wait on/off: "
+                      f"{_fmt(on.get('wait_pct'), '%')} / "
+                      f"{_fmt(off.get('wait_pct'), '%')} "
                       f"(host bubble "
                       f"{_fmt(on.get('host_bubble_pct'), '%')} / "
                       f"{_fmt(off.get('host_bubble_pct'), '%')})")

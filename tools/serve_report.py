@@ -26,8 +26,9 @@ trace_id, per-request phase attribution, tpot_secs) and prints:
   quantifying what the PR 6 prefix cache is worth end-to-end
 * engine-loop goodput — ``engine_loop_stats`` rollups (telemetry
   schema >= 10, serving/loop_profiler.py): per-phase share of dispatch
-  wall-clock (schedule / draft / build_inputs / device / emit),
-  device-busy vs host-bubble percent, the windowed bubble trend, and
+  wall-clock (schedule / draft / build_inputs / dispatch / fetch /
+  emit), the share the host waited in dispatch + fetch vs the
+  host-bubble percent, the windowed bubble trend, and
   the dispatch-gap stall count — the offline twin of ``/metrics``'
   ``engine.loop`` block; absent (and the report unchanged) on logs
   written before schema 10
@@ -78,7 +79,8 @@ PHASE_KEYS = ("queue_secs", "admission_secs", "prefill_secs",
 
 # engine-loop host phases; mirrors loop_profiler.LOOP_PHASES (this tool
 # must not import jax-adjacent modules)
-LOOP_PHASE_KEYS = ("schedule", "draft", "build_inputs", "device", "emit")
+LOOP_PHASE_KEYS = ("schedule", "draft", "build_inputs", "dispatch", "fetch",
+                   "emit")
 
 
 RESILIENCE_EVENTS = ("engine_restart", "preemption", "drain")
@@ -289,14 +291,15 @@ def speculative_summary(records: List[Dict]) -> Dict:
 
 def loop_goodput_summary(per_path: List[List[Dict]]) -> Dict:
     """Engine-loop goodput from ``engine_loop_stats`` rollups: where
-    dispatch wall-clock went per host phase, device-busy vs host-bubble
-    percent, the windowed bubble trend, and dispatch-gap stall count.
+    dispatch wall-clock went per host phase, the dispatch + fetch wait
+    vs host-bubble percent, the windowed bubble trend, and dispatch-gap
+    stall count.
 
     Rollups are cumulative per engine lifetime, so totals come from
     each log's final record; the trend samples every record's recent
     window (``window.host_bubble_pct``)."""
     totals = {"dispatches": 0, "wall_secs": 0.0, "gap_secs": 0.0,
-              "device_secs": 0.0, "stalls": 0}
+              "wait_secs": 0.0, "stalls": 0}
     phase_secs = {k: 0.0 for k in LOOP_PHASE_KEYS}
     for recs in per_path:
         if not recs:
@@ -311,8 +314,8 @@ def loop_goodput_summary(per_path: List[List[Dict]]) -> Dict:
             if isinstance(ph.get(key), (int, float)):
                 phase_secs[key] += ph[key]
     busy = totals["wall_secs"] + totals["gap_secs"]
-    device_busy = (100.0 * min(totals["device_secs"] / busy, 1.0)
-                   if busy > 0 else None)
+    wait_pct = (100.0 * min(totals["wait_secs"] / busy, 1.0)
+                if busy > 0 else None)
     out: Dict[str, object] = {
         **totals,
         "phase_secs": phase_secs,
@@ -320,9 +323,9 @@ def loop_goodput_summary(per_path: List[List[Dict]]) -> Dict:
             key: (phase_secs[key] / totals["wall_secs"]
                   if totals["wall_secs"] > 0 else None)
             for key in LOOP_PHASE_KEYS},
-        "device_busy_pct": device_busy,
-        "host_bubble_pct": (100.0 - device_busy
-                            if device_busy is not None else None),
+        "wait_pct": wait_pct,
+        "host_bubble_pct": (100.0 - wait_pct
+                            if wait_pct is not None else None),
     }
     # windowed host-bubble trend, chronological across all logs
     samples = []
@@ -756,11 +759,11 @@ def render(report: Dict) -> str:
 
     lp = report.get("loop")
     if lp:
-        db, hb = lp.get("device_busy_pct"), lp.get("host_bubble_pct")
+        db, hb = lp.get("wait_pct"), lp.get("host_bubble_pct")
         lines.append(f"\nengine loop goodput "
                      f"({lp['dispatches']} dispatches, "
                      f"{lp['stalls']} stall(s)):")
-        lines.append("  device busy "
+        lines.append("  dispatch+fetch wait "
                      + (f"{db:.1f}%" if db is not None else "-")
                      + "  host bubble "
                      + (f"{hb:.1f}%" if hb is not None else "-"))

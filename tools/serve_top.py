@@ -100,7 +100,7 @@ def _replica_row(name: str, url, snap) -> dict:
         "ghost_x10_hit_rate": None,
         "cache_host_hits": None, "host_spills": None,
         "host_hit_rate_window": None, "host_spills_per_sec": None,
-        "device_busy_pct": None, "host_bubble_pct": None,
+        "wait_pct": None, "host_bubble_pct": None,
         "loop_stalls": None, "engine_restarts": None,
         "draining": False,
         "alerts_firing": None, "alert_rules": [],
@@ -133,7 +133,7 @@ def _replica_row(name: str, url, snap) -> dict:
         misses = _num(eng, "prefix_cache_misses") or 0
         if hits + misses > 0:
             row["cache_hit_rate"] = round(hits / (hits + misses), 4)
-        row["device_busy_pct"] = _num(eng, "loop", "device_busy_pct")
+        row["wait_pct"] = _num(eng, "loop", "wait_pct")
         row["host_bubble_pct"] = _num(eng, "loop", "host_bubble_pct")
         row["loop_stalls"] = _num(eng, "loop", "stalls")
         row["engine_restarts"] = _num(eng, "engine_restarts")
