@@ -398,11 +398,12 @@ def attention(
         # sized for aggregate traffic, not num_slots x max_len — the
         # ragged-paged-attention memory model (arXiv:2604.15464).
         # Scatter-on-write always; the read side is the single dispatch
-        # seam: decode-shaped calls go to the Pallas ragged kernel
-        # (ops/pallas/paged_attention.py, walks each slot's block table
-        # reading only its live pages) when --serve_paged_kernel allows,
-        # everything else gathers the dense [b, M*bs] view and runs
-        # plain masked attention.  Shapes are fixed by the pool and
+        # seam: decode-shaped calls and prefill chunks go to the Pallas
+        # ragged kernel (ops/pallas/paged_attention.py: a loop over each
+        # slot's live pages, whose time follows what is live and not the
+        # table) when --serve_paged_kernel / --serve_prefill_kernel
+        # allow, everything else gathers the dense [b, M*bs] view and
+        # runs plain masked attention.  Shapes are fixed by the pool and
         # table geometry, so a jitted step never recompiles as requests
         # come and go.
         #
@@ -433,7 +434,11 @@ def attention(
         if path != "xla":
             from megatron_llm_tpu.ops.pallas import paged_attention as _pa
 
+            # a row with valid_lens 0 has no token in this call (an idle
+            # slot): the kernel fetches nothing for it and nobody reads
+            # its output
             kernel_kw = dict(
+                valid_lens=vlen,
                 k_scales=new_cache.get("k_pages_scale"),
                 v_scales=new_cache.get("v_pages_scale"),
                 softmax_scale=1.0 / math.sqrt(d),

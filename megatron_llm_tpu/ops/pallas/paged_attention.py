@@ -1,14 +1,13 @@
-"""Ragged paged-attention kernels for the serving engine (Pallas Mosaic
-TPU) — decode (one query token per slot) and chunked prefill (a [C]-token
-query block per slot) share one kernel body.
+"""Ragged paged-attention kernel for the serving engine (Pallas Mosaic
+TPU): decode (one query token per slot), chunked prefill (a [C]-token
+query block per slot) and the speculative verify step are one walk.
 
 The XLA paged branch in ``models/transformer.py`` gathers every slot's
-FULL block table into a dense ``[b, M*bs, g, d]`` view (dequantizing
-every int8 page) before masked attention — each call moves the
-worst-case context for every slot.  These kernels walk each slot's block
-table directly in the grid instead, reading only the pages the slot
-actually owns (arXiv:2604.15464 is the blueprint; paged-KV HBM traffic
-is the serving throughput ceiling, arXiv:2605.25645).
+block table into a dense ``[b, M*bs, g, d]`` view (dequantizing every
+int8 page) before masked attention.  This kernel leaves the pools in HBM
+and fetches, slot by slot, only the pages that slot attends
+(arXiv:2604.15464 is the blueprint; paged-KV HBM traffic is the serving
+throughput ceiling, arXiv:2605.25645).
 
 Shape contract (the serving engine's paged programs):
 
@@ -27,21 +26,48 @@ Shape contract (the serving engine's paged programs):
   inclusive; prefill row ``j`` attends ``0..context_lens[s]+j`` (causal
   within the chunk on top of the full paged history).  A sliding window
   additionally drops ``key_pos <= query_pos - window``.
+* ``valid_lens`` — ``[S]`` int32: this call's real tokens per slot.  A
+  slot with 0 is skipped: no fetch, a row of zeros.
 
-Kernel structure: grid ``(slot, q-block, page)`` with the page dimension
-innermost — sequential on TPU, so fp32 scratch (m, l, acc) carries the
-online-softmax state across a (slot, q-block)'s pages.  The page index
-map clamps out-of-range grid steps to the nearest live page: Mosaic
-skips the DMA when consecutive grid steps map a block to the same index,
-so a slot with 3 live pages out of M=128 moves exactly 3 pages of KV per
-q-block.  All query heads ride in one block per grid step (GQA groups
-are a static in-kernel loop), so each page is fetched once, not once per
-head.  Decode is the ``C == block_q == 1`` instance of the same body —
-one scaffold, two entry points.
+Kernel structure: grid ``(slot, q-block)``; NO grid axis runs over the
+table.  A (slot, q-block) attends pages ``first .. last`` of its row
+(``last`` from the newest query position, ``first`` from the sliding
+window, 0 without one) and loops over them with a dynamic trip count in
+compute blocks of ``kp`` pages (16 pages = 256 keys for bf16 pages of
+``[16, 8, 128]``, worked out from the shapes so that two blocks of K and
+two of V are 2 MiB of VMEM).  Each page of a block is one
+``make_async_copy`` from the pool into a double buffer, block j+1 in
+flight while block j is computed; the last block fetches only up to
+``last`` and masks the rest by position.  Table entries outside
+``first .. last`` are never read.  fp32 online softmax (m, l, acc in
+VMEM scratch) carries across blocks.
+
+One fetch serves every query head.  Decode multiplies all ``nh`` heads
+against all ``(key, kv group)`` pairs of a block in one matmul
+``[nh, d] x [d, 256 * g]`` and masks, for each head, the lanes of the
+other groups: the pages are used as they lie in the pool, each key
+crosses the MXU once either way, and the ``g``-fold exponentials are of
+a ``[32, 2048]`` block.  A chunk's rows would make that waste real, so
+with ``block_q > 1`` each group's rows ``[block_q * nh/g, d]`` meet that
+group's keys ``[256, d]``.
+
+What it costs (TPU v5e, bf16, ``[32 slots, 528 pages]``, window 4096;
+one chip run of PR 25, the kernel alone, 16 calls chained): time follows
+the live pages and not the table.  Decode: 63 us a call for 14 rows
+holding 346 pages (22 MB of keys and values: 27 us at 819 GB/s), 239 us
+for 32 rows holding 2,053 pages (131 MB: 160 us), 61 us for two rows of
+6,000 and 3,000 tokens; the same at 1,024 pages a row (69 / 242 / 62).
+That is about 0.1 us a live page on top of some 25 us a call (in the
+dense chat cell's trace a call reads 24 us at 4 rows of 500 tokens),
+where the grid over ``(slot, q-block, page)`` that this replaced took
+0.15-0.17 us for every entry of the table, live or dead (3.7 ms a call
+at 528 pages, 6.9 ms at 1,024), and 0.6 us for a live one.  A 64-token
+chunk: 16 / 30 / 69 / 137 us at 0 / 192 / 1,984 / 6,016 tokens of
+context (76 / 123 / 560 / 1,076 before).
 
 Dispatch mirrors ``flash_attention.py``: TPU backend -> kernel;
 otherwise -> jnp reference math (the same dense-gather computation as
-the transformer's XLA branch).  Interpret-mode tests run the kernels on
+the transformer's XLA branch).  Interpret-mode tests run the kernel on
 CPU via the module-level ``_INTERPRET`` flag.
 """
 
@@ -127,165 +153,248 @@ def _reference_paged_attention(q, k_pages, v_pages, block_tables,
 
 
 # ---------------------------------------------------------------------------
-# shared ragged kernel body (decode == block_q 1)
+# masking and online softmax: one body for every block of the walk
 # ---------------------------------------------------------------------------
 
-def _ragged_body(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                 m_scr, l_scr, acc_scr,
-                 *, ks_ref, vs_ref, scale, block_size, block_q, window, qpg):
-    s = pl.program_id(0)
-    qi = pl.program_id(1)
-    pi = pl.program_id(2)
-    npi = pl.num_programs(2)
-    bs = block_size
-    bq = block_q
-    g = k_ref.shape[2]
-    d = k_ref.shape[3]
-    # scratch rows per GQA group: the q-block's [bq, qpg, d] query slice
-    # flattened to [R, d] so scores stay 2-D for the MXU; flat row r is
-    # (chunk row r // qpg, in-group head r % qpg)
-    R = bq * qpg
+def _valid_keys(key_pos, pos, window):
+    """Causal and sliding-window validity of key positions against query
+    positions (broadcastable int32 arrays)."""
+    valid = key_pos <= pos
+    if window is not None:
+        valid &= key_pos > pos - window
+    return valid
 
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+def _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, rows):
+    """One online-softmax update: fp32 scores ``sq`` [R, T] with their
+    validity and the fp32 values ``v`` [T, d] folded into the running
+    (m, l, acc) at scratch ``rows``."""
+    sq = jnp.where(valid, sq, NEG_INF)
+    m_prev = m_scr[rows]                              # [R, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(sq, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(sq - m_new), 0.0)
+    l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_scr[rows] = m_new
+
+
+def _softmax_finish(l_scr, acc_scr, rows):
+    """The attention output of scratch ``rows`` (zeros where no key was
+    attended)."""
+    l = l_scr[rows]                                   # [R, 1]
+    return acc_scr[rows] / jnp.where(l == 0.0, 1.0, l)
+
+
+# ---------------------------------------------------------------------------
+# the walk: grid over (slot, q-block), an in-kernel loop over live pages
+# ---------------------------------------------------------------------------
+
+# bytes of one compute block of K (or V) in VMEM.  Two of each are held
+# (double buffer): 2 MiB, beside some 3 MiB of fp32 temporaries, of the
+# 16 MiB a kernel may use on a v5e
+_BLOCK_BYTES = 512 * 1024
+_BLOCK_TOKENS = 512          # and never more tokens than this
+
+
+def _pages_per_block(block_size, g, d, dtype, M):
+    """Pages of one compute block, from the pool's shapes alone: 16 pages
+    (256 tokens) for bf16 pages of [16, 8, 128]."""
+    page_bytes = block_size * g * d * jnp.dtype(dtype).itemsize
+    return max(1, min(M, _BLOCK_BYTES // page_bytes,
+                      _BLOCK_TOKENS // block_size))
+
+
+def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
+               quantized, scale, window, qpg):
+    """One (slot, q-block): walk pages ``first .. last`` of the slot's
+    table in blocks of ``kp`` pages, block j+1 on its way from HBM while
+    block j is computed.  Nothing of the table outside that range is
+    read."""
+    n_pool = 4 if quantized else 2
+    hbm = refs[:n_pool]                   # K, V[, K scales, V scales]
+    o_ref = refs[n_pool]
+    bufs = refs[n_pool + 1:2 * n_pool + 1]
+    sem, m_scr, l_scr, acc_scr = refs[2 * n_pool + 1:]
+    s = pl.program_id(0)
+    _, kp, bs, g, d = bufs[0].shape
+    _, bq, nh, _ = q_ref.shape
+    T = kp * bs                           # keys of a block
+    lanes = T * g                         # (page, position, group) triples
+    R = bq * qpg                          # query rows of one kv group
 
     ctx = cl_ref[s]                       # keys cached before this call
-    q0 = qi * bq                          # first chunk row of this q-block
-    last = (ctx + q0 + bq - 1) // bs      # newest page any row attends
+    q0 = pl.program_id(1) * bq            # first chunk row of the q-block
+    # newest key any row attends (padded tail rows of a chunk may point
+    # past the table: it ends where the table ends)
+    top = jnp.minimum(ctx + q0 + bq, bt_ref.shape[1] * bs) - 1
+    last = top // bs
     if window is None:
         first = 0
     else:
         first = jnp.maximum(ctx + q0 - window + 1, 0) // bs
+    # a slot with no token in this call walks nothing: no fetch, zeros out
+    nblk = jnp.where(vl_ref[s] > 0, (last - first) // kp + 1, 0)
 
-    @pl.when((pi >= first) & (pi <= last))
-    def _compute():
-        k = k_ref[0].astype(jnp.float32)              # [bs, g, d]
-        v = v_ref[0].astype(jnp.float32)
-        if ks_ref is not None:
-            k = k * ks_ref[0][:, :, None]             # [bs, g] scales
-            v = v * vs_ref[0][:, :, None]
-        qh = q_ref[0].astype(jnp.float32)             # [bq, nh, d]
-        key_pos = pi * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (R, bs), 1)
-        # per-row causal bound: flat row r belongs to chunk row r // qpg
-        pos = ctx + q0 + jax.lax.broadcasted_iota(
-            jnp.int32, (R, bs), 0) // qpg
-        valid = key_pos <= pos
-        if window is not None:
-            valid &= key_pos > pos - window
-        # one page DMA serves every query head: GQA groups are a static
-        # unrolled loop over the head block's row slices
+    def block_dma(j, slot, start):
+        p0 = first + j * kp
+
+        def page_dma(i, carry):
+            page = bt_ref[s, p0 + i]
+            for which in range(n_pool):
+                # a page of keys or values; of scales, one row
+                src, dst = ((hbm[which].at[page], bufs[which].at[slot, i])
+                            if which < 2 else
+                            (hbm[which].at[pl.ds(page, 1)],
+                             bufs[which].at[slot, pl.ds(i, 1)]))
+                cp = pltpu.make_async_copy(src, dst, sem.at[which, slot])
+                cp.start() if start else cp.wait()
+            return carry
+
+        # the last block stops at the last live page
+        jax.lax.fori_loop(0, jnp.minimum(kp, last - p0 + 1), page_dma, 0)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(nblk > 0)
+    def _first_block():
+        block_dma(0, 0, True)
+
+    def iota(shape, dim):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+    # bf16 queries on bf16 pools multiply as they are (a product of two
+    # bf16 is exact in the fp32 accumulator); anything else goes to fp32
+    native = bq == 1 and not quantized and bufs[0].dtype == q_ref.dtype
+    q = q_ref[0] if native else q_ref[0].astype(jnp.float32)  # [bq, nh, d]
+
+    def dequantized(x, sc):
+        """int8 [kp, bs, g, d] times its scales [kp, bs * g] (a page's
+        in the order of its rows), as fp32 [lanes, d]."""
+        x = x.astype(jnp.float32).reshape(kp, bs * g, d)
+        col = sc.T                                      # [bs * g, kp]
+        return jnp.concatenate(
+            [x[i] * col[:, i:i + 1] for i in range(kp)], axis=0)
+
+    def block(j, carry):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < nblk)
+        def _next_block():
+            block_dma(j + 1, 1 - slot, True)
+
+        block_dma(j, slot, False)
+        if quantized:
+            k = dequantized(bufs[0][slot], bufs[2][slot])
+            v = dequantized(bufs[1][slot], bufs[3][slot])
+        else:
+            k = bufs[0][slot].reshape(lanes, d)
+            v = bufs[1][slot].reshape(lanes, d).astype(jnp.float32)
+            if not native:
+                k = k.astype(jnp.float32)
+        base = (first + j * kp) * bs                    # block's first key
+        # buffer pages past the last live one hold what an earlier block
+        # left there: their scores are masked below, and their values
+        # zeroed here so that 0 x (whatever they are) adds nothing
+        v = jnp.where(
+            base + jax.lax.div(iota((lanes, 1), 0), g) <= top, v, 0.0)
+        if bq == 1:
+            # decode: all nh heads against all (key, group) pairs of the
+            # block in ONE matmul.  Lane c of the scores is key c // g of
+            # the block and kv group c % g, and head h keeps the lanes of
+            # its own group: the pages are used as they lie, each key
+            # crosses the MXU once as it would a group at a time, and the
+            # mask costs g times the exponentials of a tiny block
+            lane = iota((nh, lanes), 1)
+            own = jax.lax.rem(lane, g) == jax.lax.div(iota((nh, lanes), 0),
+                                                      qpg)
+            valid = own & _valid_keys(base + jax.lax.div(lane, g), ctx,
+                                      window)
+            sq = jax.lax.dot_general(
+                q[0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [nh, lanes]
+            _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, slice(None))
+            return carry
+        # a chunk: the rows of one kv group [R, d] against that group's
+        # keys [T, d]; flat row r is chunk row r // qpg, head r % qpg
+        k, v = k.reshape(T, g, d), v.reshape(T, g, d)
+        valid = _valid_keys(base + iota((R, T), 1),
+                            ctx + q0 + jax.lax.div(iota((R, T), 0), qpg),
+                            window)
         for grp in range(g):
-            rows = slice(grp * R, (grp + 1) * R)
-            q2 = qh[:, grp * qpg:(grp + 1) * qpg, :].reshape(R, d)
+            q2 = q[:, grp * qpg:(grp + 1) * qpg, :].reshape(R, d)
             sq = jax.lax.dot_general(
                 q2, k[:, grp, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                 # [R, bs]
-            sq = jnp.where(valid, sq, NEG_INF)
-            m_prev = m_scr[rows]                      # [R, 1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(sq, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(valid, jnp.exp(sq - m_new), 0.0)
-            l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=-1,
-                                                        keepdims=True)
-            acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot(
-                p, v[:, grp, :], preferred_element_type=jnp.float32)
-            m_scr[rows] = m_new
+                preferred_element_type=jnp.float32) * scale     # [R, T]
+            _softmax_block(sq, valid, v[:, grp, :], m_scr, l_scr, acc_scr,
+                           slice(grp * R, (grp + 1) * R))
+        return carry
 
-    @pl.when(pi == npi - 1)
-    def _finish():
-        outs = []
-        for grp in range(g):
-            rows = slice(grp * R, (grp + 1) * R)
-            l = l_scr[rows]                           # [R, 1]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            outs.append((acc_scr[rows] / l_safe).reshape(bq, qpg, d))
+    jax.lax.fori_loop(0, nblk, block, 0)
+    if bq == 1:
+        out = _softmax_finish(l_scr, acc_scr, slice(None))[None]
+    else:
+        outs = [_softmax_finish(l_scr, acc_scr,
+                                slice(grp * R, (grp + 1) * R)
+                                ).reshape(bq, qpg, d) for grp in range(g)]
         out = outs[0] if g == 1 else jnp.concatenate(outs, axis=1)
-        o_ref[0] = out.astype(o_ref.dtype)            # [bq, nh, d]
+    o_ref[0] = out.astype(o_ref.dtype)                  # [bq, nh, d]
 
 
-def _ragged_kernel_plain(bt, cl, q, k, v, o, m, l, acc, **kw):
-    _ragged_body(bt, cl, q, k, v, o, m, l, acc,
-                 ks_ref=None, vs_ref=None, **kw)
-
-
-def _ragged_kernel_quant(bt, cl, q, k, ks, v, vs, o, m, l, acc, **kw):
-    _ragged_body(bt, cl, q, k, v, o, m, l, acc,
-                 ks_ref=ks, vs_ref=vs, **kw)
-
-
-def _ragged_call(q, k_pages, v_pages, block_tables, context_lens,
-                 k_scales, v_scales, *, scale, window, block_q, name):
-    """Shared pallas_call scaffold: q [S, C, nh, d] with block_q | C.
-    Decode is the C == block_q == 1 instance.  ``name`` is the kernel's
-    name in a profile (``_quant`` appended for the int8 pools)."""
+def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
+               valid_lens, k_scales, v_scales, *, scale, window, block_q,
+               name):
+    """q [S, C, nh, d] with block_q | C (decode is C == block_q == 1);
+    the pools stay in HBM and the kernel fetches pages itself.  ``name``
+    is the kernel's name in a profile (``_quant`` appended for the int8
+    pools); ``valid_lens`` None = every slot has tokens in this call."""
+    if valid_lens is None:
+        valid_lens = jnp.ones_like(context_lens)
     S, C, nh, d = q.shape
     bs, g = k_pages.shape[1], k_pages.shape[2]
     M = block_tables.shape[1]
-    qpg = nh // g
     bq = block_q
     assert C % bq == 0, (C, bq)
-    nq = C // bq
     quantized = k_scales is not None
+    kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
+    pools = [k_pages, v_pages]
+    bufs = [pltpu.VMEM((2, kp, bs, g, d), k_pages.dtype)] * 2
+    if quantized:
+        # scales as [P, bs * g] rows, the order of a page's rows
+        pools += [x.astype(jnp.float32).reshape(-1, bs * g)
+                  for x in (k_scales, v_scales)]
+        bufs += [pltpu.VMEM((2, kp, bs * g), jnp.float32)] * 2
 
-    def page_map(s, qi, pi, bt_ref, cl_ref):
-        # clamp out-of-range grid steps to the nearest page this
-        # (slot, q-block) attends: Mosaic skips the block copy when
-        # consecutive steps map to the same index, so only the live
-        # pages up to ceil((ctx + (qi+1)*bq)/bs) (minus any fully
-        # outside the sliding window) are fetched
-        hi = jnp.minimum((cl_ref[s] + (qi + 1) * bq - 1) // bs, M - 1)
-        lo = (jnp.maximum(cl_ref[s] + qi * bq - window + 1, 0) // bs
-              if window is not None else 0)
-        return (bt_ref[s, jnp.clip(pi, lo, hi)], 0, 0, 0)
-
-    def scale_map(s, qi, pi, bt_ref, cl_ref):
-        return page_map(s, qi, pi, bt_ref, cl_ref)[:3]
-
-    def q_map(s, qi, pi, bt_ref, cl_ref):
+    def q_map(s, qi, bt_ref, cl_ref, vl_ref):
         return (s, qi, 0, 0)
 
-    q_spec = pl.BlockSpec((1, bq, nh, d), q_map, memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, bs, g, d), page_map,
-                           memory_space=pltpu.VMEM)
-    sc_spec = pl.BlockSpec((1, bs, g), scale_map,
-                           memory_space=pltpu.VMEM)
-    if quantized:
-        kernel = _ragged_kernel_quant
-        in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
-        operands = (q, k_pages, k_scales.astype(jnp.float32),
-                    v_pages, v_scales.astype(jnp.float32))
-    else:
-        kernel = _ragged_kernel_plain
-        in_specs = [q_spec, kv_spec, kv_spec]
-        operands = (q, k_pages, v_pages)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, nq, M),
-        in_specs=in_specs,
+        num_scalar_prefetch=3,
+        grid=(S, C // bq),
+        in_specs=[pl.BlockSpec((1, bq, nh, d), q_map,
+                               memory_space=pltpu.VMEM)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec((1, bq, nh, d), q_map,
                                memory_space=pltpu.VMEM),
-        scratch_shapes=[
+        scratch_shapes=bufs + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
             pltpu.VMEM((bq * nh, d), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale, block_size=bs,
-                          block_q=bq, window=window, qpg=qpg),
+        functools.partial(_walk_body, quantized=quantized, scale=scale,
+                          window=window, qpg=nh // g),
         name=name + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, d), q.dtype),
         interpret=_INTERPRET,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      *operands)
+      valid_lens.astype(jnp.int32), q, *pools)
 
 
 # ---------------------------------------------------------------------------
@@ -299,6 +408,7 @@ def paged_attention_decode(
     block_tables: jax.Array,
     context_lens: jax.Array,
     *,
+    valid_lens: Optional[jax.Array] = None,
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
     softmax_scale: Optional[float] = None,
@@ -309,7 +419,9 @@ def paged_attention_decode(
     ``q``: [S, nh, d]; pools: [P, bs, g, d] (GQA when g < nh; pass the
     int8 pools plus ``k_scales``/``v_scales`` [P, bs, g] for in-kernel
     dequant); ``block_tables``: [S, M]; ``context_lens``: [S] query
-    positions.  Returns [S, nh, d] in ``q.dtype``."""
+    positions; ``valid_lens``: [S], 0 for a slot that is not decoding
+    (its pages are not touched and its output row is unspecified; None
+    = every slot decodes).  Returns [S, nh, d] in ``q.dtype``."""
     assert q.ndim == 3 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     assert q.shape[0] == block_tables.shape[0] == context_lens.shape[0]
     assert (k_scales is None) == (v_scales is None)
@@ -319,8 +431,8 @@ def paged_attention_decode(
         return _reference_paged_attention(
             q, k_pages, v_pages, block_tables, context_lens,
             k_scales, v_scales, softmax_scale, sliding_window)
-    return _ragged_call(
-        q[:, None], k_pages, v_pages, block_tables, context_lens,
+    return _walk_call(
+        q[:, None], k_pages, v_pages, block_tables, context_lens, valid_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
         block_q=1, name="paged_attention_decode")[:, 0]
 
@@ -332,6 +444,7 @@ def paged_attention_prefill(
     block_tables: jax.Array,
     context_lens: jax.Array,
     *,
+    valid_lens: Optional[jax.Array] = None,
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
     softmax_scale: Optional[float] = None,
@@ -347,7 +460,10 @@ def paged_attention_prefill(
     history plus its own causal prefix of the chunk; padded tail rows of
     a short final chunk compute garbage-in-garbage-out exactly like the
     XLA branch (the engine only reads the last valid row's logits).
-    Returns [S, C, nh, d] in ``q.dtype``."""
+    ``valid_lens`` [S]: real tokens of each slot's chunk; a slot with 0
+    (an idle row of the speculative verify step) is skipped as in
+    :func:`paged_attention_decode`.  Returns [S, C, nh, d] in
+    ``q.dtype``."""
     assert q.ndim == 4 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     assert q.shape[0] == block_tables.shape[0] == context_lens.shape[0]
     assert (k_scales is None) == (v_scales is None)
@@ -361,7 +477,7 @@ def paged_attention_prefill(
     bq = min(block_q or _PREFILL_BLOCK_Q, C)
     while C % bq:       # q-blocks must tile the chunk exactly; static
         bq -= 1         # (power-of-two chunks keep the full block size)
-    return _ragged_call(
-        q, k_pages, v_pages, block_tables, context_lens,
+    return _walk_call(
+        q, k_pages, v_pages, block_tables, context_lens, valid_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
         block_q=bq, name="paged_attention_prefill")

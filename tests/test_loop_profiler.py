@@ -597,11 +597,11 @@ def test_every_kernel_and_program_carries_its_stable_name():
                                  f"with no name="
             if isinstance(kw["name"], ast.Constant):
                 names.add(kw["name"].value)
-        # the paged scaffold takes its name from its two public entries
+        # the paged walk takes its name from its two public entries
         for node in ast.walk(tree):
             if (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Name)
-                    and node.func.id == "_ragged_call"):
+                    and node.func.id == "_walk_call"):
                 kw = {k.arg: k.value for k in node.keywords}
                 names |= {kw["name"].value, kw["name"].value + "_quant"}
     assert calls == 9

@@ -1,11 +1,15 @@
-"""Ragged paged-attention Pallas kernels (decode and chunked prefill),
-run in interpret mode on CPU: kernel vs the XLA dense-gather reference
-vs a per-slot numpy oracle, across ragged context lengths, GQA group
-counts, sliding window, and int8 KV quantization — plus model-level
-parity of the transformer's paged branch with the kernels forced on vs
-off.  Prefill cases cover the ragged edges: chunks straddling page
-boundaries, context 0, cached-prefix tail chunks starting mid-page,
-windows shorter than the chunk, and multi-q-block grids."""
+"""The ragged paged-attention Pallas kernel (decode and chunked prefill
+are one walk over each slot's live pages), run in interpret mode on CPU:
+kernel vs the XLA dense-gather reference vs a per-slot numpy oracle,
+across ragged context lengths, GQA group counts, sliding window, and
+int8 KV quantization — plus model-level parity of the transformer's
+paged branch with the kernels forced on vs off.  Decode cases cover a
+table far longer than what is live, contexts around a compute-block
+boundary, windows that start inside a block, slots that are not
+decoding, and the serving cells' own shapes.  Prefill cases cover the
+ragged edges: chunks straddling page and block boundaries, context 0,
+cached-prefix tail chunks starting mid-page, windows shorter than the
+chunk, and multi-q-block grids."""
 
 import math
 
@@ -119,6 +123,163 @@ def test_kernel_int8_dequant(window):
     want = _oracle(q, k_lin, v_lin, LENS, scale, window)
     drift = np.max(np.abs(got - want)) / (np.std(want) + 1e-6)
     assert drift < 0.2, drift
+
+
+# ---------------------------------------------------------------------------
+# decode: the walk follows what is live, not the table
+# ---------------------------------------------------------------------------
+
+LONG_M = 64               # a table far longer than anything live
+
+
+def _decode(q, kp, vp, bt, lens, **kw):
+    return np.asarray(pa.paged_attention_decode(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+        jnp.asarray(lens), **kw))
+
+
+def _with_dead_entries(bt, lens, bs, page):
+    """The table with every entry past a slot's last live page pointed
+    at ``page``."""
+    dead = np.arange(bt.shape[1])[None, :] > (np.asarray(lens) // bs)[:, None]
+    return np.where(dead, page, bt).astype(np.int32)
+
+
+@pytest.fixture
+def two_page_blocks(monkeypatch):
+    """Compute blocks of two 8-token pages, so that a few dozen tokens
+    cross block boundaries."""
+    monkeypatch.setattr(pa, "_BLOCK_TOKENS", 2 * BS)
+    assert pa._pages_per_block(BS, 2, D, np.float32, LONG_M) == 2
+
+
+@pytest.mark.parametrize("window", [None, 12])
+@pytest.mark.parametrize("live_pages", [1, 2, 3, 5])
+def test_decode_long_table_never_reads_dead_entries(live_pages, window,
+                                                    two_page_blocks):
+    """M = 64 with 1-5 live pages a slot.  Dead entries pointing at a
+    page of huge values, at a page of NaN and at the last pool page give
+    bit-equal outputs that match the oracle: nothing of the table
+    outside first..last is fetched."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(100 + live_pages + (window or 0))
+    lens = np.asarray([live_pages * BS - 1, (live_pages - 1) * BS,
+                       live_pages * BS - 3, (live_pages - 1) * BS + 2],
+                      np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, S, LONG_M, BS, g, nh, D,
+                                              lens)
+    P = kp.shape[0]
+    huge, nan = P - 3, P - 2          # unowned pages (S * LONG_M >> live)
+    kp[huge], vp[huge] = 1e30, -1e30
+    kp[nan], vp[nan] = np.nan, np.nan
+    outs = [_decode(q, kp, vp, _with_dead_entries(bt, lens, BS, page), lens,
+                    sliding_window=window) for page in (huge, nan, P - 1)]
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(D), window)
+    np.testing.assert_allclose(outs[0], want, atol=2e-5, rtol=2e-5)
+    assert np.array_equal(outs[0], outs[1])
+    assert np.array_equal(outs[0], outs[2])
+
+
+@pytest.mark.parametrize("ctx", [2 * BS - 2, 2 * BS - 1, 2 * BS,
+                                 4 * BS - 2, 4 * BS - 1, 4 * BS])
+def test_decode_context_at_block_boundaries(ctx, two_page_blocks):
+    """A query position just before, on and just after the last key of a
+    two-page block, at the first and the second boundary."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(200 + ctx)
+    lens = np.asarray([ctx, 0, ctx, 3], np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, S, LONG_M, BS, g, nh, D,
+                                              lens)
+    got = _decode(q, kp, vp, bt, lens)
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(D), None)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window", [5, 12, 20, 37])
+def test_decode_window_starts_mid_block(window, two_page_blocks):
+    """The window's first key lies inside a page and inside a block: the
+    walk starts at that page and masks the keys before it by position."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(300 + window)
+    lens = np.asarray([50, 45, 61, 38], np.int32)
+    assert all((int(c) - window + 1) % BS for c in lens)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, S, LONG_M, BS, g, nh, D,
+                                              lens)
+    kp[0], vp[0] = np.nan, np.nan     # nobody's page: never fetched
+    got = _decode(q, kp, vp, _with_dead_entries(bt, lens, BS, 0), lens,
+                  sliding_window=window)
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(D), window)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("decoding", [(1, 0, 1, 0), (0, 1, 1, 0),
+                                      (0, 0, 0, 1), (1, 1, 0, 1)])
+def test_decode_skips_slots_that_are_not_decoding(decoding, two_page_blocks):
+    """Slots with ``valid_lens`` 0 between live ones: the live rows are
+    exact, the others come back as zeros though their tables point at
+    NaN pages, and nothing anywhere is NaN."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(400 + sum(decoding))
+    lens = np.asarray([40, 17, 33, 9], np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, S, LONG_M, BS, g, nh, D,
+                                              lens)
+    vlen = np.asarray(decoding, np.int32)
+    for s in np.flatnonzero(vlen == 0):
+        kp[bt[s, 0]], vp[bt[s, 0]] = np.nan, np.nan
+    got = _decode(q, kp, vp, bt, lens, valid_lens=jnp.asarray(vlen))
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(D), None)
+    live = vlen > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    assert np.isfinite(got).all()
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("window", [None, 12])
+def test_decode_int8_long_table(window, two_page_blocks):
+    """int8 pools over several blocks of a long table: the scales ride
+    on the scores and the probabilities, and match dequantizing first."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(500 + (window or 0))
+    lens = np.asarray([0, 15, 16, 37], np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, S, LONG_M, BS, g, nh, D,
+                                              lens)
+    kq, ks = absmax_quantize_int8(jnp.asarray(kp), axis=-1)
+    vq, vs = absmax_quantize_int8(jnp.asarray(vp), axis=-1)
+    got = _decode(q, kq, vq, bt, lens, k_scales=ks, v_scales=vs,
+                  sliding_window=window)
+    ref = np.asarray(pa._reference_paged_attention(
+        jnp.asarray(q), kq, vq, jnp.asarray(bt), jnp.asarray(lens),
+        ks, vs, 1.0 / math.sqrt(D), window))
+    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=2e-5)
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(D), window)
+    drift = np.max(np.abs(got - want)) / (np.std(want) + 1e-6)
+    assert drift < 0.2, drift
+
+
+@pytest.mark.parametrize("dtype,block_tokens", [(np.float32, 128),
+                                                (jnp.bfloat16, 256)])
+def test_decode_at_the_cells_shapes(dtype, block_tokens):
+    """Pages of [16, 8, 128] under 32 query heads, the serving cells'
+    shapes: blocks of 8 pages in fp32 and 16 in bf16, contexts around
+    the first block boundary, one slot not decoding."""
+    bs, g, nh, d, M = 16, 8, 32, 128, LONG_M
+    assert pa._pages_per_block(bs, g, d, dtype, M) * bs == block_tokens
+    rng = np.random.default_rng(600 + block_tokens)
+    lens = np.asarray([block_tokens - 2, block_tokens - 1, block_tokens, 70],
+                      np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, 4, M, bs, g, nh, d, lens)
+    cast = lambda x: np.asarray(jnp.asarray(x, dtype).astype(jnp.float32))
+    q, k_lin, v_lin = cast(q), cast(k_lin), cast(v_lin)
+    vlen = np.asarray([1, 1, 1, 0], np.int32)
+    got = np.asarray(pa.paged_attention_decode(
+        jnp.asarray(q, dtype), jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+        jnp.asarray(_with_dead_entries(bt, lens, bs, kp.shape[0] - 1)),
+        jnp.asarray(lens), valid_lens=jnp.asarray(vlen),
+        sliding_window=4096).astype(jnp.float32))
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(d), 4096)
+    tol = 2e-5 if dtype == np.float32 else 1e-2
+    np.testing.assert_allclose(got[:3], want[:3], atol=tol, rtol=tol)
+    assert not got[3].any()
 
 
 def test_availability_tracks_backend(monkeypatch):
@@ -241,6 +402,60 @@ def test_prefill_kernel_int8_dequant(window):
     want = _prefill_oracle(q, k_lin, v_lin, CTX, scale, window)
     drift = np.max(np.abs(got - want)) / (np.std(want) + 1e-6)
     assert drift < 0.2, drift
+
+
+@pytest.mark.parametrize("block_q", [None, 8])
+@pytest.mark.parametrize("window", [None, 5, 20])
+def test_prefill_chunk_crosses_blocks(window, block_q, two_page_blocks):
+    """A 16-token chunk over up to five pages walked two pages at a
+    time: the running softmax carries across blocks, window 20 starts
+    inside a block for some rows and before the table for others, and
+    the last block stops short of its second page."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(700 + (window or 0) + (block_q or 0))
+    q, k_lin, v_lin, kp, vp, bt = _build_prefill_case(
+        rng, len(CTX), MP, BS, g, nh, D, CTX, C)
+    got = np.asarray(pa.paged_attention_prefill(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(bt), jnp.asarray(CTX), sliding_window=window,
+        block_q=block_q))
+    want = _prefill_oracle(q, k_lin, v_lin, CTX, 1.0 / math.sqrt(D), window)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_prefill_skips_slots_with_no_token(quantized, two_page_blocks):
+    """The verify step's idle rows: ``valid_lens`` 0 gives a row of
+    zeros and touches no page (theirs hold NaN), the others are exact."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(800 + quantized)
+    q, k_lin, v_lin, kp, vp, bt = _build_prefill_case(
+        rng, len(CTX), MP, BS, g, nh, D, CTX, C)
+    vlen = np.asarray([C, 0, 0, 7], np.int32)
+    kw = {}
+    if quantized:
+        kp, ks = absmax_quantize_int8(jnp.asarray(kp), axis=-1)
+        vp, vs = absmax_quantize_int8(jnp.asarray(vp), axis=-1)
+        kw = dict(k_scales=np.array(ks), v_scales=np.array(vs))
+        for s in (1, 2):
+            kw["k_scales"][bt[s, :3]] = np.nan
+            kw["v_scales"][bt[s, :3]] = np.nan
+    else:
+        for s in (1, 2):
+            kp[bt[s, :3]], vp[bt[s, :3]] = np.nan, np.nan
+    got = np.asarray(pa.paged_attention_prefill(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+        jnp.asarray(CTX), valid_lens=jnp.asarray(vlen),
+        **{k: jnp.asarray(v) for k, v in kw.items()}))
+    want = _prefill_oracle(q, k_lin, v_lin, CTX, 1.0 / math.sqrt(D), None)
+    assert np.isfinite(got).all()
+    assert not got[1:3].any()
+    if quantized:
+        drift = np.max(np.abs(got[[0, 3]] - want[[0, 3]])) / np.std(want)
+        assert drift < 0.2, drift
+    else:
+        np.testing.assert_allclose(got[[0, 3]], want[[0, 3]],
+                                   atol=2e-5, rtol=2e-5)
 
 
 def test_prefill_decode_consistency():
