@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -71,6 +71,12 @@ MODEL_DEFAULTS = {
                     use_rms_norm=True, use_bias=False, tie_embed_logits=False,
                     num_experts=8, moe_top_k=2, rope_theta=1e6,
                     hidden_dropout=0.0, attention_dropout=0.0),
+    # 64 small experts, 8 a token, gates as the router gives them, QK-norm
+    "olmoe": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                  use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                  num_experts=64, moe_top_k=8, norm_topk_prob=0,
+                  qk_norm=True, rope_theta=10000.0,
+                  hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,
@@ -264,6 +270,9 @@ _CKPT_ARG_MAP = {
     "moe_top_k": "moe_top_k",
     "moe_capacity_factor": "moe_capacity_factor",
     "moe_min_capacity": "moe_min_capacity",
+    "norm_topk_prob": "norm_topk_prob",
+    # olmoe's QK-norm adds two scale vectors a layer to the param tree
+    "qk_norm": "qk_norm",
     # qwen2's QKV-only bias changes the param tree like the MoE fields do
     "add_qkv_bias": "add_qkv_bias",
     # gemma's embedding normalizer changes forward math, not the tree

@@ -120,6 +120,13 @@ def _add_network_size_args(parser):
     g.add_argument("--sliding_window_size", type=int, default=None)
     g.add_argument("--add_qkv_bias", action="store_true",
                    help="bias on the QKV projection only (Qwen2-style)")
+    g.add_argument("--qk_norm", action="store_true",
+                   help="RMSNorm on the whole query and key projections "
+                        "before the rotary embedding (OLMoE)")
+    g.add_argument("--norm_topk_prob", type=int, default=1, choices=[0, 1],
+                   help="renormalise the chosen experts' gates to sum to "
+                        "1 (Mixtral); 0 uses the softmax over all experts "
+                        "as it is (OLMoE)")
     g.add_argument("--embedding_multiplier", type=float, default=None,
                    help="scale embedding output (Gemma: sqrt(hidden))")
     g.add_argument("--rotary_percent", type=float, default=1.0,
@@ -888,20 +895,21 @@ def validate_args(args, world_size: Optional[int] = None):
     if args.sequence_parallel and args.tensor_model_parallel_size == 1:
         args.sequence_parallel = False
 
-    # Dropless-style capacity (c >= s*k/E, i.e. factor >= E/top_k) is what
-    # convert_mixtral records so converted models reproduce HF logits; for
-    # TRAINING the dispatch/combine one-hots are O(b*s*k*E*c) fp32 — at
-    # factor E/k that is O(b*s^2*k) per microbatch and an easy OOM at long
-    # seq.  Warn here (validate_args runs after --use_checkpoint_args
-    # adoption) rather than silently training into it.
+    # The capacity factor shapes only the TRAINING path (inference is
+    # dropless whatever it says, models/moe.py).  At c >= s*k/E, i.e.
+    # factor >= E/top_k, training drops nothing either, but the
+    # dispatch/combine one-hots are O(b*s*k*E*c) fp32 — at factor E/k that
+    # is O(b*s^2*k) per microbatch and an easy OOM at long seq.  Warn here
+    # (validate_args runs after --use_checkpoint_args adoption) rather
+    # than silently training into it.
     if getattr(args, "num_experts", 0) and args.num_experts > 1:
         dropless = args.num_experts / max(args.moe_top_k, 1)
         if args.moe_capacity_factor >= dropless:
             print(
                 f" > WARNING: moe_capacity_factor "
                 f"({args.moe_capacity_factor:g}) >= num_experts/top_k "
-                f"({dropless:g}) is a DROPLESS (inference-exact) setting; "
-                f"the MoE dispatch buffers scale O(seq^2) with it at "
+                f"({dropless:g}) is a DROPLESS setting of the training "
+                f"path; its dispatch buffers scale O(seq^2) with it at "
                 f"seq_length={args.seq_length}.  For training, "
                 f"--moe_capacity_factor 1.25 (the default) is the usual "
                 f"choice.", flush=True,
@@ -965,6 +973,8 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         embedding_multiplier=getattr(args, "embedding_multiplier", None),
         rotary_percent=getattr(args, "rotary_percent", 1.0),
         gelu_variant=getattr(args, "gelu_variant", "tanh"),
+        qk_norm=bool(getattr(args, "qk_norm", False)),
+        norm_topk_prob=bool(getattr(args, "norm_topk_prob", True)),
     )
 
 

@@ -217,6 +217,15 @@ class TransformerConfig:
     # param-spec time and trace time cannot disagree if the mesh changes in
     # between (round-3 advisor finding); "expert" / "replicated" force it.
     moe_expert_axis: str = "auto"
+    # renormalise the chosen experts' gates to sum to 1 (Mixtral: the
+    # softmax over the chosen ones); False uses the softmax over all
+    # experts as it is (OLMoE), so a token's gates sum to less than 1
+    norm_topk_prob: bool = True
+
+    # RMSNorm on the query and key projections before the rotary
+    # embedding, over the WHOLE projection (all heads together) with a
+    # learned scale of the projection's width (OLMoE's q_norm / k_norm)
+    qk_norm: bool = False
 
     # QKV-projection-only bias (Qwen2-style: attention in-projections
     # carry biases while every other linear is bias-free)

@@ -9,6 +9,7 @@ from megatron_llm_tpu.models.llama import LlamaModel, llama_config
 from megatron_llm_tpu.models.falcon import FalconModel, falcon_config
 from megatron_llm_tpu.models.mistral import MistralModel, mistral_config
 from megatron_llm_tpu.models.mixtral import MixtralModel, mixtral_config
+from megatron_llm_tpu.models.olmoe import OlmoeModel, olmoe_config
 from megatron_llm_tpu.models.qwen2 import Qwen2Model, qwen2_config
 from megatron_llm_tpu.models.gemma import GemmaModel, gemma_config
 from megatron_llm_tpu.models.gpt_neox import GPTNeoXModel, gpt_neox_config
@@ -29,6 +30,7 @@ MODEL_REGISTRY = {
     "falcon": FalconModel,
     "mistral": MistralModel,
     "mixtral": MixtralModel,
+    "olmoe": OlmoeModel,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,
     "gpt_neox": GPTNeoXModel,

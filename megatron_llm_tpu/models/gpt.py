@@ -48,6 +48,12 @@ class GPTModel:
         # pin the MoE expert-dim placement to the mesh as it stands NOW, so
         # spec time and trace time agree even across a mesh re-init
         self.cfg = resolve_expert_axis(cfg)
+        # QK-norm takes its mean square over the WHOLE projection, which
+        # tensor parallelism splits by heads: refused, not reduced
+        if cfg.qk_norm and not _vocab_unsharded():
+            raise ValueError(
+                "qk_norm normalises over the whole query/key projection "
+                "and is not implemented under tensor parallelism (tp > 1)")
 
     # -- params ------------------------------------------------------------
     def init(self, key) -> dict:

@@ -116,6 +116,10 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
             }
         ),
     }
+    for name in ("q_norm", "k_norm"):
+        if name in layers["attention"]:
+            layer_specs["attention"][name] = _norm_spec(
+                layers["attention"][name], stacked)
     if "post_attention_norm" in layers:
         layer_specs["post_attention_norm"] = _norm_spec(
             layers["post_attention_norm"], stacked

@@ -1,27 +1,51 @@
-"""Mixture-of-experts MLP with expert parallelism (TPU-native extension).
+"""Mixture-of-experts MLP: a dropless path for inference, a capacity
+einsum for training (TPU-native extension).
 
 The reference has no MoE (SURVEY §2.2: expert parallelism "absent"); this
-module goes beyond parity.  Design follows the GShard/Switch dispatch
-formulation as adapted by the public TPU MoE stacks (t5x/flaxformer,
-MaxText): routing and dispatch are pure einsums over one-hot masks, so
-GSPMD can pattern-match the token->expert reshuffle into all-to-alls over
-ICI instead of host gathers.
+module goes beyond parity.  Two paths; ``models/transformer.py`` takes one
+or the other by its ``train`` argument and nothing else (no flag):
 
+* **Dropless, whenever the model runs for inference**
+  (``moe_mlp_dropless``, ``train=False``: every engine program — prefill
+  chunk, decode step, verify step — and the plain forward).  The step's tokens are flattened, the ``tokens x k``
+  assignments are sorted by expert, the expert matrices run as grouped
+  matrix multiplications over the sorted rows (the Pallas kernel of
+  ``ops/pallas/grouped_matmul.py`` on a TPU, ``jax.lax.ragged_dot``
+  elsewhere) and the results are gathered back and weighted by the gates.  No
+  token is dropped at any routing; a token that is not live (a slot
+  that is not decoding, the padding of a short chunk: ``live`` false) is
+  sent to no expert; the groups of experts nobody chose are empty.  The
+  published sparse models (Mixtral, OLMoE) are dropless, so this is what
+  agrees with their references.  It also returns the histogram of live
+  assignments ``[E]`` that the serving engine's routing counters read.
+* **Capacity einsum, for training** (``moe_mlp``, ``train=True``): the
+  GShard/Switch dispatch formulation as adapted by the public TPU stacks
+  (t5x/flaxformer, MaxText).  Routing and dispatch are pure einsums over
+  one-hot masks, so GSPMD can pattern-match the token->expert reshuffle
+  into all-to-alls over ICI instead of host gathers.  Tokens route
+  within their batch row ([b, s, h] -> groups of s tokens) with a
+  per-group capacity ``c = max(min_capacity, ceil(s * top_k / E *
+  capacity_factor))`` — bounds the dispatch mask at [b, s*k, E, c]
+  instead of the unmanageable global [N, E, C].  Tokens over capacity are
+  dropped (their MLP contribution is zero and the residual stream carries
+  them unchanged) — standard capacity-style MoE semantics.  It stays
+  until experts train over several chips on the dropless path too
+  (ROADMAP R1, second half), and as a reference in the tests.
+
+Shared by both:
+
+* **The router** in fp32: softmax over all experts, the ``top_k``
+  largest.  With ``cfg.norm_topk_prob`` (Mixtral: the softmax over the
+  chosen experts) the chosen gates are renormalised to sum to 1; without
+  it (OLMoE) they are used as the softmax gives them.
 * **Expert placement**: expert-stacked weights ``[E, ...]`` carry the
   ``'expert'`` logical axis, which the sharding rules map onto the ``dp``
   mesh axis (EP folded into dp, ``parallel/sharding.py``); the per-expert
   FFN dims keep the usual ``'ffn'`` -> tp sharding, so one expert's GEMMs
   are tensor-parallel exactly like the dense MLP's.
-* **Grouping**: tokens route within their batch row ([b, s, h] -> groups
-  of s tokens) with a per-group capacity ``c = max(min_capacity,
-  ceil(s * top_k / E * capacity_factor))`` — bounds the dispatch mask at
-  [b, s*k, E, c] instead of the unmanageable global [N, E, C].
 * **Load balance**: Switch-style aux loss ``E * sum_e(frac_e * prob_e)``
   plus router z-loss, returned unweighted as a ``[lb, z]`` fp32 vector;
   the trainer adds ``moe_aux_loss_coeff * lb + moe_z_loss_coeff * z``.
-* Tokens over capacity are dropped (their MLP contribution is zero and
-  the residual stream carries them unchanged) — standard capacity-style
-  MoE semantics.
 * **Composes with the pipeline engines** (``parallel/pipeline.py``): the
   ``[lb, z]`` aux rides the tick carry of both schedules and the manual
   1F1B backward seeds its cotangent on every stage, so tp x pp x dp(=ep)
@@ -133,6 +157,132 @@ def moe_mlp_specs(params, stacked: bool = True, cfg=None) -> dict:
     }
 
 
+def _route(x: jax.Array, params, cfg: TransformerConfig):
+    """x [..., h] -> (logits, probs [..., E], gates, idx [..., k]): the
+    router in fp32, the ``top_k`` largest of the softmax over all
+    experts, renormalised over the chosen ones only where the family
+    does (``cfg.norm_topk_prob``)."""
+    wr = params["router"]["kernel"].astype(jnp.float32)
+    logits = jnp.einsum("...h,he->...e", x.astype(jnp.float32), wr)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, idx = jax.lax.top_k(probs, cfg.moe_top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(
+            jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    return logits, probs, gates, idx
+
+
+def _aux_losses(logits, probs, frac):
+    """Unweighted [load-balance, z] (fp32) — the trainer applies
+    moe_aux_loss_coeff / moe_z_loss_coeff.  Switch load balance:
+    E * sum_e(assignment-fraction_e * mean-prob_e); == 1 at a perfectly
+    uniform router."""
+    E = probs.shape[-1]
+    mean_prob = jnp.mean(probs.reshape(-1, E), axis=0)
+    lb = E * jnp.sum(frac * mean_prob)
+    z = jax.nn.logsumexp(logits, axis=-1)
+    return jnp.stack([lb, jnp.mean(z * z)])
+
+
+def _grouped_matmul(rows: jax.Array, weights: jax.Array,
+                    group_sizes: jax.Array) -> jax.Array:
+    """rows [m, k] sorted by group, weights [G, k, n], group_sizes [G] ->
+    [m, n]: group g's rows times weights[g] (products in the rows'
+    dtype, fp32 accumulation).  Rows past ``sum(group_sizes)`` belong to
+    no group and what comes back for them is not used.
+
+    On one TPU device the Pallas kernel (``ops/pallas/grouped_matmul.py``
+    says why); elsewhere — the CPU, or a mesh of several devices, across
+    which a Mosaic call cannot be partitioned — XLA's ragged dot."""
+    from megatron_llm_tpu import topology
+    from megatron_llm_tpu.ops.pallas import grouped_matmul as gm
+
+    one_device = (not topology.model_parallel_is_initialized()
+                  or topology.get_mesh().size == 1)
+    if gm.kernel_available() and one_device:
+        return gm.grouped_matmul(rows, weights, group_sizes)
+    return jax.lax.ragged_dot(rows, weights, group_sizes)
+
+
+def _layer_of_stacked(experts, layer: int, counts: jax.Array, cdtype):
+    """(experts, group sizes) of layer ``layer`` of a model's stacked
+    experts ``[L, E, ...]``.
+
+    A grouped matmul is a kernel call and wants its operand whole:
+    handed ``stacked[layer]`` it would first copy the layer's every
+    expert (805 MB a layer at OLMoE's widths).  So weights that are
+    already the matmul's operand (the compute dtype) stay stacked,
+    reshaped to ``[L * E, ...]`` (the same bytes, no copy), and the group
+    sizes are padded so that only this layer's groups are not empty.
+    Weights that are dequantized first are a new array either way: they
+    are sliced."""
+    if "w_in" in experts and experts["w_in"].dtype == cdtype:
+        L, E = experts["w_in"].shape[:2]
+        merged = {k: w.reshape((L * E,) + w.shape[2:])
+                  for k, w in experts.items()}
+        return merged, jnp.pad(counts, (layer * E, (L - 1 - layer) * E))
+    return jax.tree_util.tree_map(lambda w: w[layer], experts), counts
+
+
+def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
+                     live: jax.Array = None, layer: int = None):
+    """x [b, s, h] -> (out [b, s, h], aux [2] fp32, counts [E] int32).
+
+    ``live`` [b, s] bool (None: every token) marks the tokens of the step
+    that are real; the others are routed nowhere, add nothing to
+    ``counts`` and get a zero output.  ``counts[e]`` is the number of
+    live (token, choice) assignments expert e received.
+
+    With ``layer`` (a static index: the paged-cache loop of
+    ``transformer_stack``, so every engine program) ``params['experts']``
+    holds the weights of EVERY layer, stacked ``[L, E, ...]`` as the model
+    keeps them, and this is layer ``layer`` of them (``_layer_of_stacked``
+    decides how it is taken).
+    """
+    E, k = cfg.num_experts, cfg.moe_top_k
+    b, s, h = x.shape
+    T = b * s
+    cdtype = cfg.compute_jnp_dtype
+    xf = x.reshape(T, h)
+
+    with jax.named_scope("moe_route"):
+        logits, probs, gates, idx = _route(xf, params, cfg)    # [T, k]
+        if live is not None:
+            alive = live.reshape(T, 1)
+            # E is no expert: it sorts behind every real assignment
+            idx = jnp.where(alive, idx, E)
+            gates = jnp.where(alive, gates, 0.0)
+        flat = idx.reshape(T * k)
+        counts = jnp.sum(
+            flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
+            axis=0, dtype=jnp.int32)                           # [E]
+
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(flat, stable=True)                 # [T*k]
+        rows = xf.astype(cdtype)[order // k]                   # [T*k, h]
+
+    with jax.named_scope("moe_experts"):
+        experts, sizes = params["experts"], counts
+        if layer is not None:
+            experts, sizes = _layer_of_stacked(experts, layer, counts, cdtype)
+        w_in = dequantize_weight(experts, "w_in", cdtype)
+        w_out = dequantize_weight(experts, "w_out", cdtype)
+        mid = apply_mlp_activation(_grouped_matmul(rows, w_in, sizes), cfg)
+        y = _grouped_matmul(mid, w_out, sizes)                 # [T*k, h]
+
+    with jax.named_scope("moe_combine"):
+        slot = jnp.arange(T * k, dtype=order.dtype)
+        routed = slot < jnp.sum(counts)
+        y = jnp.where(routed[:, None], y.astype(jnp.float32), 0.0)
+        back = jnp.zeros_like(order).at[order].set(slot)       # inverse
+        y = y[back].reshape(T, k, h)
+        out = jnp.einsum("tkh,tk->th", y, gates)
+
+    total = jnp.maximum(jnp.sum(counts), 1).astype(jnp.float32)
+    aux = _aux_losses(logits, probs, counts.astype(jnp.float32) / total)
+    return out.reshape(b, s, h).astype(x.dtype), aux, counts
+
+
 def moe_mlp(
     x: jax.Array,
     params,
@@ -150,13 +300,7 @@ def moe_mlp(
     c = moe_capacity(cfg, s)
     cdtype = cfg.compute_jnp_dtype
 
-    # --- router (fp32 for numerics) ---
-    wr = params["router"]["kernel"].astype(jnp.float32)
-    logits = jnp.einsum("bsh,he->bse", x.astype(jnp.float32), wr)
-    probs = jax.nn.softmax(logits, axis=-1)                    # [b, s, E]
-    gates, idx = jax.lax.top_k(probs, k)                       # [b, s, k]
-    gates = gates / jnp.maximum(
-        jnp.sum(gates, axis=-1, keepdims=True), 1e-9)          # renormalize
+    logits, probs, gates, idx = _route(x, params, cfg)         # [b, s, k]
 
     # --- position-in-expert over flattened (s, k) slots, token-major so
     # earlier tokens win the buffer (Switch priority) ---
@@ -193,14 +337,7 @@ def moe_mlp(
     # --- combine (weighted un-dispatch) ---
     out = jnp.einsum("ebch,bsec->bsh", expert_out, combine.astype(cdtype))
 
-    # --- aux losses, unweighted [load-balance, z] (fp32) — the trainer
-    # applies moe_aux_loss_coeff / moe_z_loss_coeff ---
-    # Switch load balance: E * sum_e(assignment-fraction_e * mean-prob_e);
-    # == 1 at a perfectly uniform router.
     frac = jnp.mean(oh.reshape(-1, E), axis=0)                 # [E], sums to 1
-    mean_prob = jnp.mean(probs.reshape(-1, E), axis=0)
-    lb = E * jnp.sum(frac * mean_prob)
-    z = jax.nn.logsumexp(logits, axis=-1)
-    aux = jnp.stack([lb, jnp.mean(z * z)])
+    aux = _aux_losses(logits, probs, frac)
 
     return out.astype(x.dtype), aux

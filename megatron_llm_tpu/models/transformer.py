@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from megatron_llm_tpu.config import TransformerConfig, PositionEmbeddingType
 from megatron_llm_tpu.ops.activations import apply_mlp_activation
-from megatron_llm_tpu.models.moe import moe_mlp
+from megatron_llm_tpu.models.moe import moe_mlp, moe_mlp_dropless
 from megatron_llm_tpu.ops.layernorm import apply_norm, init_norm_params
 from megatron_llm_tpu.ops.rope import apply_rotary_emb, precompute_freqs_cis
 from megatron_llm_tpu.ops.softmax import (
@@ -69,7 +69,7 @@ def init_attention_params(key, cfg: TransformerConfig, dtype):
         if cfg.use_scaled_init_method
         else init
     )
-    return {
+    params = {
         # packed grouped-QKV column-parallel projection
         # (reference: transformer.py:334-365); add_qkv_bias gives the
         # in-projection a bias even in an otherwise bias-free model
@@ -85,6 +85,13 @@ def init_attention_params(key, cfg: TransformerConfig, dtype):
             bias=cfg.add_bias_linear, init_method=out_init, dtype=dtype,
         ),
     }
+    if cfg.qk_norm:
+        # one learned scale over the WHOLE query / key projection
+        params["q_norm"] = {"scale": jnp.ones(
+            (cfg.num_attention_heads * cfg.head_dim,), dtype)}
+        params["k_norm"] = {"scale": jnp.ones(
+            (cfg.num_query_groups * cfg.head_dim,), dtype)}
+    return params
 
 
 def init_cross_attention_params(key, cfg: TransformerConfig, dtype):
@@ -202,6 +209,18 @@ def _split_qkv(mixed: jax.Array, cfg: TransformerConfig):
     k = mixed[:, :, :, qpg, :]
     v = mixed[:, :, :, qpg + 1, :]
     return q, k, v
+
+
+def _projection_rms_norm(x: jax.Array, scale: jax.Array, eps: float):
+    """x [b, s, heads, d]: RMSNorm over the whole projection (all heads
+    together, the mean square over heads * d), then the learned scale of
+    that width; in fp32, back to x's dtype."""
+    b, s, n, d = x.shape
+    flat = x.reshape(b, s, n * d).astype(jnp.float32)
+    flat = flat * jax.lax.rsqrt(
+        jnp.mean(flat * flat, axis=-1, keepdims=True) + eps)
+    return (flat * scale.astype(jnp.float32)).astype(x.dtype).reshape(
+        b, s, n, d)
 
 
 def core_attention(
@@ -381,6 +400,13 @@ def attention(
         compute_dtype=cfg.compute_jnp_dtype,
     )
     q, k, v = _split_qkv(mixed, cfg)
+
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _projection_rms_norm(q, params["q_norm"]["scale"],
+                                     cfg.layernorm_epsilon)
+            k = _projection_rms_norm(k, params["k_norm"]["scale"],
+                                     cfg.layernorm_epsilon)
 
     if cfg.position_embedding_type == PositionEmbeddingType.rotary and freqs is not None:
         cos, sin = freqs
@@ -786,6 +812,7 @@ def transformer_layer(
     kv_cache=None,
     encoder_output: Optional[jax.Array] = None,
     enc_dec_mask: Optional[jax.Array] = None,
+    moe_layer: Optional[int] = None,
 ):
     """One decoder layer (reference ``ParallelTransformerLayer``,
     transformer.py:612-846), supporting:
@@ -799,7 +826,9 @@ def transformer_layer(
 
     Returns the fixed-arity triple ``(out, new_cache, moe_aux)`` —
     ``new_cache`` is None when ``kv_cache`` is None, ``moe_aux`` is None
-    for dense (non-MoE) configs.
+    for dense (non-MoE) configs.  With ``moe_layer`` the experts' weights
+    in ``params`` are every layer's, stacked, and this layer is that one
+    of them (``moe_mlp_dropless``).
     """
     is_decoder = "inter_attention" in params and encoder_output is not None
     if is_decoder and cfg.parallel_attn:
@@ -846,16 +875,27 @@ def transformer_layer(
             new_cache = None
 
     # MoE (num_experts > 1) replaces the dense MLP and adds a routing aux
-    # loss threaded up through the stack scan (models/moe.py)
+    # loss threaded up through the stack scan (models/moe.py): the
+    # capacity einsum when training, the dropless path otherwise.  Under
+    # the paged cache the step's live tokens are the first valid_lens of
+    # each row; the others are routed nowhere, and the histogram of live
+    # assignments rides the layer's cache for the engine's counters.
     def run_mlp(inp):
         with jax.named_scope("mlp"):
-            if cfg.num_experts > 1:
+            if cfg.num_experts <= 1:
+                return mlp(inp, params["mlp"], cfg,
+                           sequence_parallel=sequence_parallel), None
+            if train:
                 return moe_mlp(inp, params["mlp"], cfg)
-            return (
-                mlp(inp, params["mlp"], cfg,
-                    sequence_parallel=sequence_parallel),
-                None,
-            )
+            live = None
+            if new_cache is not None and "valid_lens" in new_cache:
+                live = (jnp.arange(inp.shape[1])[None, :]
+                        < new_cache["valid_lens"][:, None])
+            out, aux, counts = moe_mlp_dropless(inp, params["mlp"], cfg,
+                                                live, moe_layer)
+            if live is not None:
+                new_cache["moe_counts"] = counts
+            return out, aux
 
     if cfg.parallel_attn:
         # Falcon: mlp feeds from the same (or its own) LN output; single
@@ -981,13 +1021,23 @@ def transformer_stack(
         # (MoE aux, when present, is irrelevant at decode time and dropped)
         new_caches = []
         h = x
+        # a sparse model's experts go in whole, as the model stacks them,
+        # with the layer's index: models/moe.py says why, and decides
+        # what to do with them
+        sliced = layers
+        if moe_on:
+            sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items()
+                                        if k != "experts"}}
         for i in range(L):
-            layer_p = jax.tree_util.tree_map(lambda p: p[i], layers)
+            layer_p = jax.tree_util.tree_map(lambda p: p[i], sliced)
+            if moe_on:
+                layer_p["mlp"]["experts"] = layers["mlp"]["experts"]
             h, c, _ = transformer_layer(
                 h, layer_p, cfg,
                 freqs=freqs, attention_mask=attention_mask,
                 position_ids=position_ids, rng_key=None, train=False,
                 sequence_parallel=sequence_parallel, kv_cache=kv_caches[i],
+                moe_layer=i if moe_on else None,
             )
             new_caches.append(c)
         h = apply_norm(

@@ -100,6 +100,7 @@ from megatron_llm_tpu.serving.request import (
     SamplingParams,
 )
 from megatron_llm_tpu.serving.loop_profiler import (
+    MOE_FIELDS,
     DispatchRecord,
     LoopProfiler,
     RequestSpan,
@@ -361,6 +362,10 @@ class InferenceEngine:
         self.occupancy_sum = 0          # sum of active slots over decode steps
         self.drafted_tokens = 0         # prompt-lookup proposals verified
         self.accepted_tokens = 0        # proposals committed by verify
+        # routing of a sparse model, summed over launches (loop_profiler's
+        # MOE_FIELDS: the same four are on every launch's record)
+        for f in MOE_FIELDS:
+            setattr(self, f, 0)
         self.prefill_secs = 0.0
         self.decode_secs = 0.0
         self.finished: Dict[str, int] = {}
@@ -473,6 +478,15 @@ class InferenceEngine:
         return [{k: v for k, v in c.items() if "pages" in k}
                 for c in new_caches]
 
+    @staticmethod
+    def _moe_counts(new_caches):
+        """[layers, E] int32 live assignments of a sparse model's step
+        (``models/moe.py`` leaves each layer's histogram on its cache);
+        None for a dense model."""
+        if "moe_counts" not in new_caches[0]:
+            return None
+        return jnp.stack([c["moe_counts"] for c in new_caches])
+
     def _decode_impl(self, params, pages, last_tokens, context_lens,
                      block_tables, active, temps, top_ks, top_ps,
                      ban_a, ban_b, keys):
@@ -502,7 +516,8 @@ class InferenceEngine:
         sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [S, 2, 2]
         next_tokens = sample_batched(logits, sub[:, 0], top_ks, top_ps,
                                      temps)
-        return next_tokens, self._strip_pages(new_caches), sub[:, 1], finite
+        return (next_tokens, self._strip_pages(new_caches), sub[:, 1], finite,
+                self._moe_counts(new_caches))
 
     def _verify_impl(self, params, pages, tokens, context_lens,
                      block_tables, vlens, temps, top_ks, top_ps,
@@ -546,7 +561,8 @@ class InferenceEngine:
                                top_ps, temps)
         emit = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         emit = emit.at[:, 0].set(first.astype(jnp.int32))
-        return emit, self._strip_pages(new_caches), sub[:, 1], finite
+        return (emit, self._strip_pages(new_caches), sub[:, 1], finite,
+                self._moe_counts(new_caches))
 
     def _prefill_impl(self, params, pages, tokens, start_pos, valid_len,
                       block_table):
@@ -564,7 +580,8 @@ class InferenceEngine:
             rng_key=None, train=False, kv_caches=caches)
         last = jax.lax.dynamic_index_in_dim(
             logits[0], valid_len - 1, axis=0, keepdims=False)
-        return last.astype(jnp.float32), self._strip_pages(new_caches)
+        return (last.astype(jnp.float32), self._strip_pages(new_caches),
+                self._moe_counts(new_caches))
 
     def _cow_copy_impl(self, pages, src, dst):
         # duplicate physical page src into dst across every layer's pool
@@ -1036,9 +1053,10 @@ class InferenceEngine:
         d.traces = (req.trace_id,) if req.trace_id else ()
         d.mark("build_inputs")
         finite = True
-        last_logits, st.pages = self._prefill_step(
+        last_logits, st.pages, routing = self._prefill_step(
             self.params, st.pages, toks, np.int32(start),
             np.int32(valid), table)
+        self._start_routing_copy(routing)
         done = start + valid >= len(ptoks)
         if done:
             tok, new_key, finite = self._sample_first(
@@ -1054,7 +1072,10 @@ class InferenceEngine:
         else:
             d.mark("dispatch")
             jax.block_until_ready(st.pages[0])
+        if routing is not None:
+            routing = np.asarray(routing)
         d.mark("fetch")
+        self._note_routing(d, routing)
         if st is not self._st:
             self.loop_profiler.finish(d)
             return          # engine restarted mid-dispatch: stale state
@@ -1089,6 +1110,23 @@ class InferenceEngine:
     # -- decode ---------------------------------------------------------
 
     @staticmethod
+    def _start_routing_copy(counts) -> None:
+        """A sparse model's histogram sets out for the host as its launch
+        returns, so the read after the launch's own reads finds it there
+        and waits for nothing."""
+        if counts is not None:
+            counts.copy_to_host_async()
+
+    def _note_routing(self, d: DispatchRecord, counts) -> None:
+        """A sparse model's launch: its routing on the record and in the
+        running totals."""
+        if counts is None:
+            return
+        d.note_routing(counts)
+        for f in MOE_FIELDS:
+            setattr(self, f, getattr(self, f) + getattr(d, f))
+
+    @staticmethod
     def _note_batch(st: _EngineState, d: DispatchRecord, slots: List[int],
                     decoding: List[Request]) -> None:
         """What a decode/verify launch works on, for its record."""
@@ -1107,16 +1145,20 @@ class InferenceEngine:
                     if r is not None and r.state == RequestState.DECODE]
         self._note_batch(st, d, slots, decoding)
         d.mark("build_inputs")
-        next_tokens, st.pages, new_keys, finite = self._decode_step(
+        next_tokens, st.pages, new_keys, finite, routing = self._decode_step(
             self.params, st.pages, st.last_tokens,
             st.context_lens, st.blocks.tables.copy(),
             st.active, st.temps, st.top_ks, st.top_ps,
             st.ban_a, st.ban_b, st.keys)
         d.mark("dispatch")
+        self._start_routing_copy(routing)
         next_tokens = np.asarray(next_tokens)
         new_keys = np.asarray(new_keys)
         finite = np.asarray(finite).copy()
+        if routing is not None:
+            routing = np.asarray(routing)
         d.mark("fetch")
+        self._note_routing(d, routing)
         # key chains advance ONLY for decoding slots: a slot mid-prefill
         # keeps its admission-time seed key, so a request's sample stream
         # depends on its seed alone, not on batch-mates' decode traffic
@@ -1206,15 +1248,19 @@ class InferenceEngine:
         self._note_batch(st, disp, slots, decoding)
         disp.drafted = int(draft_lens.sum())
         disp.mark("build_inputs")
-        emit, st.pages, new_keys, finite = self._verify_step(
+        emit, st.pages, new_keys, finite, routing = self._verify_step(
             self.params, st.pages, verify_tokens, st.context_lens,
             st.blocks.tables.copy(), vlens, st.temps, st.top_ks,
             st.top_ps, st.ban_a, st.ban_b, st.keys)
         disp.mark("dispatch")
+        self._start_routing_copy(routing)
         emit = np.asarray(emit)
         new_keys = np.asarray(new_keys)
         finite = np.asarray(finite).copy()
+        if routing is not None:
+            routing = np.asarray(routing)
         disp.mark("fetch")
+        self._note_routing(disp, routing)
         # same key discipline as the plain decode step: exactly one
         # split per decoding slot per step, so a sampled slot's stream
         # is bit-identical spec-on vs spec-off
@@ -1468,6 +1514,7 @@ class InferenceEngine:
             "draft_k": self.draft_k,
             "drafted_tokens": self.drafted_tokens,
             "accepted_tokens": self.accepted_tokens,
+            **{f: getattr(self, f) for f in MOE_FIELDS},
             "engine_restarts": self.engine_restarts,
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,
             "loop": self.loop_profiler.stats(),

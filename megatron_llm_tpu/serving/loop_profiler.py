@@ -92,6 +92,11 @@ LOOP_PHASE_BUCKETS = (
     0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
 )
 
+# a launch's routing counts (DispatchRecord.note_routing), which the
+# engine also keeps running totals of
+MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
+              "moe_busiest_expert_assignments")
+
 # the enclosing Chrome-trace span of a launch, by its kind
 _SPAN_NAME = {"prefill": "prefill_chunk", "decode": "decode_step",
               "verify": "decode_step"}
@@ -133,6 +138,14 @@ class DispatchRecord:
     valid = 0
     cached_tokens = 0
     drafted = 0
+    # routing of a sparse model's launch, summed over its layers (all 0
+    # for a dense model): live (token, choice) assignments; experts that
+    # received at least one; experts there are (layers x E); and each
+    # layer's largest count of assignments to one expert
+    moe_assignments = 0
+    moe_experts_touched = 0
+    moe_expert_slots = 0
+    moe_busiest_expert_assignments = 0
     # the requests (and their trace ids) this launch served: the spans
     # that caused it
     requests: Tuple[int, ...] = ()
@@ -218,7 +231,16 @@ class DispatchRecord:
             "request": self.request, "start": self.start,
             "valid": self.valid, "requests": list(self.requests),
             "traces": list(self.traces),
+            **{f: getattr(self, f) for f in MOE_FIELDS},
         }
+
+    def note_routing(self, counts) -> None:
+        """``counts`` [layers, E]: the launch's histogram of live
+        assignments, as its program returned it."""
+        self.moe_assignments = int(counts.sum())
+        self.moe_experts_touched = int((counts > 0).sum())
+        self.moe_expert_slots = int(counts.size)
+        self.moe_busiest_expert_assignments = int(counts.max(axis=1).sum())
 
 
 # The profilers of this process's newest engines, so that a reader which

@@ -581,7 +581,14 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    the last blocking read returns); device_secs / device_busy_pct
 #    become wait_secs / wait_pct (their sum, what the host waited);
 #    host_bubble_pct = 100 - wait_pct keeps its name and meaning
-TELEMETRY_SCHEMA_VERSION = 14
+# 15: a sparse model's routing: engine stats() / the engine block of
+#    /metrics gain moe_assignments, moe_experts_touched, moe_expert_slots
+#    and moe_busiest_expert_assignments (live token x choice assignments,
+#    experts with at least one, layers x experts, each layer's largest
+#    count; summed over launches, all 0 for a dense model), and every
+#    launch record of the loop profiler's ring and of a postmortem
+#    bundle carries the same four for that launch
+TELEMETRY_SCHEMA_VERSION = 15
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -559,6 +559,7 @@ KERNEL_NAMES = {
     "flash_attention_fwd", "flash_attention_bwd_fused",
     "flash_attention_bwd_dq", "flash_attention_bwd_dkv",
     "rmsnorm_fwd", "rmsnorm_bwd", "layernorm_fwd", "layernorm_bwd",
+    "moe_experts",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -604,7 +605,7 @@ def test_every_kernel_and_program_carries_its_stable_name():
                     and node.func.id == "_walk_call"):
                 kw = {k.arg: k.value for k in node.keywords}
                 names |= {kw["name"].value, kw["name"].value + "_quant"}
-    assert calls == 9
+    assert calls == 10
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

@@ -4,7 +4,8 @@ not attached (on-chip-measurement guide, section 2.3).
 The TPU's own compiler is installed with jaxlib and needs no chip: it
 refuses what interpret mode lets through (a slice off the tiling, too
 much fast memory).  Every case compiles one kernel at Mistral-7B widths
-— 32 query heads, 8 kv heads, head_dim 128, hidden 4096 — and asserts
+— 32 query heads, 8 kv heads, head_dim 128, hidden 4096 — or, for the
+experts' grouped matmul, at OLMoE's and Mixtral's, and asserts
 that the result holds a Mosaic call (``tpu_custom_call``), i.e. that the
 kernel was taken and not its jnp reference.  Compiles, not chip runs:
 they say nothing about results or speed.
@@ -66,15 +67,17 @@ def _layer_norm(rows):
         ((rows, HIDDEN), BF16), ((HIDDEN,), BF16), ((HIDDEN,), BF16)]
 
 
-def _paged(q_tokens, slots, page=16, int8=False, window=None):
+def _paged(q_tokens, slots, page=16, int8=False, window=None,
+           heads=HEADS, kv_heads=KV_HEADS):
     """Decode (q_tokens=1), a prefill chunk or the K+1 verify step over a
     pool sized as the engine sizes it: full backing for 8 slots of 4096
-    tokens plus the garbage page."""
+    tokens plus the garbage page.  ``heads`` / ``kv_heads`` 16 / 16 is
+    OLMoE's layout: one query head a key-value group."""
     from megatron_llm_tpu.ops.pallas import paged_attention as pa
 
     pages = SLOTS * (MAX_LEN // page) + 1
-    pool = ((pages, page, KV_HEADS, HEAD_DIM), jnp.int8 if int8 else BF16)
-    scale = ((pages, page, KV_HEADS), jnp.float32)
+    pool = ((pages, page, kv_heads, HEAD_DIM), jnp.int8 if int8 else BF16)
+    scale = ((pages, page, kv_heads), jnp.float32)
     shapes = [pool, pool, ((slots, MAX_LEN // page), jnp.int32),
               ((slots,), jnp.int32)] + ([scale, scale] if int8 else [])
 
@@ -87,7 +90,24 @@ def _paged(q_tokens, slots, page=16, int8=False, window=None):
         return pa.paged_attention_prefill(q, k_pages, v_pages, tables, lens,
                                           **kw)
 
-    return fn, [((slots, q_tokens, HEADS, HEAD_DIM), BF16)] + shapes
+    return fn, [((slots, q_tokens, heads, HEAD_DIM), BF16)] + shapes
+
+
+def _experts(rows, experts, hidden, ffn, layers=8):
+    """The dropless expert layer's two grouped matmuls over a model's
+    stacked experts (``models/moe.py``): OLMoE's 64 experts of 1024 and
+    Mixtral's 8 of 14336, at a decode step's, a chunk's and a verify
+    step's rows."""
+    from megatron_llm_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    def fn(x, w_in, w_out, sizes):
+        mid = grouped_matmul(x, w_in, sizes)
+        mid = jax.nn.silu(mid[:, :ffn]) * mid[:, ffn:]
+        return grouped_matmul(mid, w_out, sizes)
+
+    groups = layers * experts
+    return fn, [((rows, hidden), BF16), ((groups, hidden, 2 * ffn), BF16),
+                ((groups, ffn, hidden), BF16), ((groups,), jnp.int32)]
 
 
 CASES = {
@@ -113,6 +133,19 @@ CASES = {
     "paged_verify_k_plus_1": lambda: _paged(5, SLOTS),
     "paged_verify_k_plus_1_window_4096":
         lambda: _paged(5, SLOTS, window=4096),
+    "paged_decode_16_kv_heads":
+        lambda: _paged(1, 64, heads=16, kv_heads=16),
+    "paged_prefill_chunk_64_16_kv_heads":
+        lambda: _paged(64, 1, heads=16, kv_heads=16),
+    "moe_experts_olmoe_512_rows": lambda: _experts(512, 64, 2048, 1024),
+    "moe_experts_olmoe_verify_320_rows":
+        lambda: _experts(8 * 5 * 8, 64, 2048, 1024),
+    "moe_experts_mixtral_64_rows":
+        lambda: _experts(64, 8, 4096, 14336, layers=3),
+    "moe_experts_mixtral_chunk_128_rows":
+        lambda: _experts(128, 8, 4096, 14336, layers=3),
+    "moe_experts_mixtral_verify_80_rows":
+        lambda: _experts(80, 8, 4096, 14336, layers=3),
 }
 
 
