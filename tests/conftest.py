@@ -7,23 +7,49 @@ expose 8 virtual CPU devices, so every TP/PP/DP/SP test runs in CI with no
 hardware.
 """
 
+import faulthandler
 import os
+
+# Every test gets this long for its setup, call and teardown together;
+# `@pytest.mark.time_limit(seconds)` gives a named test another limit.
+# pytest-timeout is not in the installation, and a main thread stuck
+# inside an XLA call never returns to run a Python signal handler or to
+# release the interpreter lock to a Python timer, so the limit is the
+# fault handler's own C thread: at expiry it writes every thread's stack
+# to the real stderr and ends the process with _exit(1).  Under xdist
+# that is one crashed worker: the test running in it fails by name, the
+# worker is replaced, and the rest of its file runs in the new one.  In
+# a one-process run the stacks (the test's file, line and function among
+# them) are the last thing printed and the run ends there.
+TIME_LIMIT_SECS = 300
 
 # Must happen before jax initializes its backends.  The collective-call
 # rendezvous timeouts default to 20s/40s; on a loaded or few-core CI box
 # the 8 virtual device threads can legitimately take longer to converge
 # (compilation runs on the same cores), and the default *aborts the
-# process*.  Raise them — slow is fine, SIGABRT mid-suite is not.
+# process*.  Raise them, but keep the abort under TIME_LIMIT_SECS: XLA
+# then ends a stuck rendezvous first, and names it and the participants
+# that never arrived.
 _flags = os.environ.get("XLA_FLAGS", "")
 for _f in (
     "--xla_force_host_platform_device_count=8",
-    "--xla_cpu_collective_call_warn_stuck_timeout_seconds=300",
-    "--xla_cpu_collective_call_terminate_timeout_seconds=7200",
+    "--xla_cpu_collective_call_warn_stuck_timeout_seconds=60",
+    "--xla_cpu_collective_call_terminate_timeout_seconds=240",
 ):
     if _f.lstrip("-").split("=")[0] not in _flags:
         _flags = (_flags + " " + _f).strip()
 os.environ["XLA_FLAGS"] = _flags
 os.environ["JAX_PLATFORMS"] = "cpu"
+# XLA's CPU client runs each in-flight collective of each virtual device
+# on a thread of one pool, where it blocks until its peers arrive, and
+# sizes that pool by the box's cores, never under the device count: 8
+# here.  A pp 2 x tp 2 program keeps two or three collectives in flight
+# on a device, so eight waiters (two in one permute, four in another, two
+# in tp all-reduces) can hold every thread while the participants they
+# wait for have none to arrive on: test_pipeline.py's
+# test_manual_1f1b_matches_unpipelined[2-2] hung so about one run in
+# three.  PJRT_NPROC is the client's own knob for the pool's size.
+os.environ["PJRT_NPROC"] = "32"
 
 import jax  # noqa: E402
 
@@ -41,6 +67,59 @@ def pytest_configure(config):
         "markers", "chaos: serving fault-injection tests (fast chaos "
                    "units run in tier-1; the multi-process fleet e2e is "
                    "additionally marked slow)")
+    config.addinivalue_line(
+        "markers", "time_limit(seconds): this test's own limit in place "
+                   "of TIME_LIMIT_SECS, which every other test has")
+    # output capture is suspended while plugins are configured, so this
+    # is the terminal (or the driver's log), not a capture file
+    fd = config.stash[_REAL_STDERR] = os.dup(2)
+    config.add_cleanup(lambda: os.close(fd))
+
+
+_REAL_STDERR = pytest.StashKey[int]()
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item):
+    marker = item.get_closest_marker("time_limit")
+    limit = marker.args[0] if marker else TIME_LIMIT_SECS
+    faulthandler.dump_traceback_later(
+        limit, exit=True, file=item.config.stash[_REAL_STDERR])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """xdist 3.8.0's file-wise scheduler puts a crashed worker's files
+    back in the queue whole, the test that ended the worker included
+    (``LoadScopeScheduling.remove_node``), so a test that overruns every
+    time would end one replacement after another until xdist gives up
+    with the rest of the suite unrun.  That test has failed: count it
+    done, and hand on only what follows it."""
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class CrashedTestRunsOnce(LoadFileScheduling):
+        def remove_node(self, node):
+            workload = self.assigned_work.pop(node)
+            crashitem = None
+            for scope, unit in workload.items():
+                pending = [n for n, done in unit.items() if not done]
+                if pending and crashitem is None:
+                    crashitem = pending.pop(0)
+                    unit[crashitem] = True
+                if pending:
+                    self.workqueue[scope] = unit
+            if crashitem is not None:
+                for other in self.assigned_work:
+                    self._reschedule(other)
+            return crashitem
+
+    return CrashedTestRunsOnce(config, log)
 
 
 class Utils:

@@ -274,10 +274,43 @@ def test_nan_injection_triggers_rewind_and_run_completes(utils):
     assert np.all(np.isfinite(_flat(p)))
 
 
-def test_watchdog_rescue_save_in_pretrain(utils, tmp_path):
+class _StallClock:
+    """Stands in for the ``time`` module inside ``resilience``: the
+    watchdog's clock moves only when the injected stall advances it, so
+    however slowly a loaded box runs the steps before, the watchdog
+    cannot fire early; and the stall itself (``FaultInjector``'s
+    ``time.sleep``) lasts until ``until()`` holds, so however slowly the
+    watchdog thread is scheduled, the step does not resume before it."""
+
+    def __init__(self, until):
+        self._now = 0.0
+        self._until = until
+
+    def monotonic(self):
+        return self._now
+
+    def sleep(self, secs):
+        self._now += secs
+        deadline = time.monotonic() + 120.0
+        while not self._until():
+            assert time.monotonic() < deadline, "the watchdog never fired"
+            time.sleep(0.01)
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_watchdog_rescue_save_in_pretrain(utils, tmp_path, monkeypatch):
     """A step stalled past the watchdog budget rescue-saves the latest host
     snapshot (hard_exit off so the test can inspect the aftermath)."""
+    from megatron_llm_tpu import resilience
+
     model, params, it = _setup(utils)
+    tracker = checkpointing.get_checkpoint_tracker_filename(str(tmp_path))
+    # the stall ends when the rescue checkpoint is whole on disk: the
+    # tracker is written last
+    monkeypatch.setattr(resilience, "time", _StallClock(
+        lambda: os.path.isfile(tracker) and os.path.getsize(tracker) > 0))
     wd = HangWatchdog(timeout_secs=0.5, hard_exit=False,
                       poll_interval=0.05, printer=lambda s: None)
     rm = ResilienceManager(

@@ -25,6 +25,7 @@ from megatron_llm_tpu.serving import (
     SamplingParams,
     derive_num_blocks,
 )
+from megatron_llm_tpu.serving.drafter import lookup_draft
 from megatron_llm_tpu.serving.kv_blocks import GARBAGE_BLOCK
 from megatron_llm_tpu.text_generation.api import generate_and_post_process
 from megatron_llm_tpu.text_generation.sampling import (
@@ -511,6 +512,21 @@ def test_engine_speculative_greedy_parity_cobatched(engine, spec_engine):
     assert s["speculative"] is True and s["draft_k"] == 4
 
 
+def _drafting_prompt(eng, seed_prompt):
+    """A prompt whose first decode step drafts by construction, whatever
+    the model answers: ``seed_prompt`` continued by the model's own
+    greedy tokens as far as the first one that closes a bigram the
+    history has seen before.  Greedy decoding repeats itself, so that
+    token is the first this prompt samples, and the drafter's lookup at
+    the step after it hits."""
+    out = eng.submit(seed_prompt, SamplingParams(
+        max_new_tokens=32, temperature=0.0)).result(timeout=180).out_tokens
+    for n in range(1, len(out) + 1):
+        if lookup_draft(list(seed_prompt) + out[:n], 1):
+            return list(seed_prompt) + out[:n - 1]
+    raise AssertionError(f"no bigram of {seed_prompt} + {out} repeats")
+
+
 def test_engine_speculative_zero_recompiles(spec_engine, tmp_path):
     """The zero-recompile guard with speculation on: mixed drafting /
     non-drafting / sampled traffic all rides the one [S, K+1] verify
@@ -519,6 +535,11 @@ def test_engine_speculative_zero_recompiles(spec_engine, tmp_path):
     carry the accept attribution."""
     from megatron_llm_tpu import telemetry
 
+    seeds = [[1 + i, 2, 1 + i, 2, 1 + i] if i % 2 == 0
+             else list(range(1, 2 + (i % 7))) for i in range(10)]
+    sampled = (2, 5, 8)         # draft K=0 by design
+    drafting = {i: _drafting_prompt(spec_engine, seeds[i])
+                for i in (0, 4, 6)}
     tracer = tracing.SpanTracer()
     det = tracing.RecompileDetector(tracer)
     tracing.install_tracing(tracing.Tracing(tracer=tracer, recompile=det))
@@ -528,14 +549,15 @@ def test_engine_speculative_zero_recompiles(spec_engine, tmp_path):
         det.mark_steady()
         reqs = []
         for i in range(10):
-            if i % 3 == 2:      # sampled: drafts K=0 by design
+            if i in sampled:
                 sp = SamplingParams(max_new_tokens=3 + (i % 5),
                                     temperature=0.8, top_k=5 + i,
                                     seed=i, eod_id=63)
-            else:
-                sp = SamplingParams(max_new_tokens=3 + (i % 5), **GREEDY)
-            prompt = ([1 + i, 2, 1 + i, 2, 1 + i] if i % 2 == 0
-                      else list(range(1, 2 + (i % 7))))
+            else:   # a drafting prompt's first token may be the stop token
+                sp = SamplingParams(max_new_tokens=3 + (i % 5),
+                                    temperature=0.0,
+                                    eod_id=None if i in drafting else 63)
+            prompt = drafting.get(i, seeds[i])
             reqs.append(spec_engine.submit(prompt, sp,
                                            trace_id=f"{i:016x}"))
         for r in reqs:
