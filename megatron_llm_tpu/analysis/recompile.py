@@ -24,8 +24,8 @@ every reachable function:
   ``isinstance()`` tests are static and exempt)
 * ``RC005`` — reading ``self.config.*`` / ``self.cfg.*`` /
   ``self.args.*`` inside a jit-reachable method: mutable config must
-  be resolved ONCE at ``__init__`` into frozen attributes (the
-  ``_decode_cfg``/``_prefill_cfg`` pattern), or every config change —
+  be resolved ONCE at ``__init__`` into frozen attributes (the engine's
+  ``paged_kernel``/``prefill_kernel`` pattern), or every config change —
   and every dict-ordering accident — is a retrace.
 
 Parameters are treated as *static* (not traced) when they are ``self``/
@@ -194,7 +194,7 @@ def _exempt_names_in_test(test: ast.AST) -> Set[str]:
                     exempt.add(operand.id)
         elif isinstance(node, ast.Compare) and any(
                 isinstance(op, (ast.In, ast.NotIn)) for op in node.ops):
-            # `"k_pages_q" in pages`: dict membership on a pytree is a
+            # `"key" in pool`: dict membership on a pytree is a
             # structure check, resolved at trace time
             for operand in node.comparators:
                 if isinstance(operand, ast.Name):
@@ -327,8 +327,8 @@ def _check_function(mod: _Module, fn: ast.AST,
                         f"{label}/self.{inner.attr}.{node.attr}",
                         f"'self.{inner.attr}.{node.attr}' read inside "
                         f"jit-reachable '{label}': mutable config must "
-                        f"be resolved once at __init__ (the _decode_cfg "
-                        f"pattern), not at trace time"))
+                        f"be resolved once at __init__ into a frozen "
+                        f"attribute, not at trace time"))
 
 
 def check(repo: Repo, baseline=None) -> List[Violation]:

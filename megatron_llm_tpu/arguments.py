@@ -3,7 +3,7 @@
 Reference: ``megatron/arguments.py`` (1,103 LoC, 225 flags across 16
 ``_add_*_args`` groups, ~350 lines of ``validate_args`` cross-derivation).
 The flag *names* are kept so reference launch scripts carry over with
-``--device=tpu`` (BASELINE.json north star); the grouping/derivations are
+``--device=tpu``; the grouping/derivations are
 re-written for this framework.  Flags that are CUDA-implementation details
 (``--masked_softmax_fusion``, ``--gradient_accumulation_fusion``, nvFuser
 toggles, ``CUDA_DEVICE_MAX_CONNECTIONS`` checks, arguments.py:337-347) are
@@ -187,9 +187,10 @@ def _add_training_args(parser):
     g.add_argument("--use_flash_attn", action="store_true", default=True)
     g.add_argument("--no_flash_attn", action="store_false",
                    dest="use_flash_attn")
-    # chunked head+CE: off by default at 32k vocab (docs/perf_tpu.md
-    # records the measured tie), auto-ON at >= 128k vocab where the
-    # compile-level evidence is decisive (2.1x temp memory, 1.3x HBM
+    # chunked head+CE: off by default at 32k vocab (a measured tie on
+    # one v5e at commit `128e754`, not re-measured), auto-ON at >= 128k
+    # vocab where the compile-level evidence is decisive (2.1x temp
+    # memory, 1.3x HBM
     # traffic — docs/scale_aot.md); default=None distinguishes
     # "unspecified" from an explicit choice so validate_args can
     # auto-enable without overriding the user
@@ -383,8 +384,7 @@ def _add_telemetry_args(parser):
     g.add_argument("--profile", action="store_true",
                    help="capture a jax.profiler trace of iterations "
                         "[profile_step_start, profile_step_end] during "
-                        "training (in-loop analogue of "
-                        "tools/profile_step.py)")
+                        "training")
     g.add_argument("--profile_step_start", type=int, default=10,
                    help="first iteration inside the profiler trace "
                         "(leave warmup/compile outside the window)")
@@ -732,8 +732,9 @@ def _add_unimplemented_compat_args(parser):
 def apply_fused_ce_policy(args, vocab=None):
     """Decide ``fused_lm_cross_entropy`` from the best-known vocab size.
 
-    Policy (VERDICT r4 #7): off below 64k (the measured on-chip tie at
-    32k, docs/perf_tpu.md), advisory note at 64k-128k, AUTO-ON at
+    Policy (VERDICT r4 #7): off below 64k (a measured on-chip tie at
+    32k, commit `128e754`, not re-measured), advisory note at 64k-128k,
+    AUTO-ON at
     >= 128k where the compile-level evidence is decisive (temp memory
     3.20->1.51 GB, HBM traffic 25.5->20.1 GB, docs/scale_aot.md) — but
     only with an unsharded vocab: under tp>1 the fused path is inert

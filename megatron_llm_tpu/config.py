@@ -169,19 +169,6 @@ class TransformerConfig:
     attention_softmax_in_fp32: bool = True
     # divide qk^T by sqrt(head_dim) (standard)
     use_flash_attn: bool = True         # Pallas flash-attention kernel
-    # Pallas ragged paged-attention decode kernel (serving engine paged
-    # branch; --serve_paged_kernel): 'auto' = on for decode-shaped calls
-    # when the Pallas backend is available, 'on' forces it, 'off' keeps
-    # the XLA gather branch everywhere (docs/guide/serving.md)
-    paged_attention_kernel: str = "auto"
-    # Pallas ragged paged-attention *prefill* kernel (chunked-prefill
-    # paged branch; --serve_prefill_kernel): same auto/on/off semantics,
-    # applied to multi-token (1 < n <= paged_prefill_max_q) query calls
-    paged_prefill_kernel: str = "auto"
-    # largest multi-token query length routed to the prefill kernel;
-    # longer (legacy full-prompt) paged calls keep the XLA gather branch.
-    # The serving engine overrides this with its --serve_prefill_chunk.
-    paged_prefill_max_q: int = 512
     use_fused_rmsnorm: bool = True      # Pallas fused RMSNorm kernel
     use_fused_layernorm: bool = True    # Pallas fused LayerNorm kernel
     # chunked head-matmul + CE (never materializes [tokens, vocab] logits);
@@ -268,18 +255,6 @@ class TransformerConfig:
             raise ValueError(
                 f"context_parallel_algo must be ring|ulysses|zigzag, got "
                 f"{self.context_parallel_algo!r}")
-        if self.paged_attention_kernel not in ("auto", "on", "off"):
-            raise ValueError(
-                f"paged_attention_kernel must be auto|on|off, got "
-                f"{self.paged_attention_kernel!r}")
-        if self.paged_prefill_kernel not in ("auto", "on", "off"):
-            raise ValueError(
-                f"paged_prefill_kernel must be auto|on|off, got "
-                f"{self.paged_prefill_kernel!r}")
-        if self.paged_prefill_max_q < 2:
-            raise ValueError(
-                f"paged_prefill_max_q must be >= 2 (n == 1 is the decode "
-                f"kernel's), got {self.paged_prefill_max_q}")
         if self.num_experts > 1:
             if self.add_bias_linear:
                 raise ValueError("MoE experts do not support linear biases "

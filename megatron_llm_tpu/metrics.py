@@ -60,8 +60,8 @@ METRICS: Dict[str, Callable[[MetricInput], Dict[str, jnp.ndarray]]] = {
 
 def recovery_counters() -> Dict[str, int]:
     """Host-side fault-tolerance counters (rewinds, save_retries,
-    watchdog_fires, signal_saves) — merged into the training log /
-    TB/W&B stream and the bench.py artifacts.  Re-exported here so
+    watchdog_fires, signal_saves) — merged into the training log and
+    the TB/W&B stream.  Re-exported here so
     metrics consumers need not import resilience."""
     from megatron_llm_tpu.resilience import recovery_counters as rc
 

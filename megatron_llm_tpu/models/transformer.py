@@ -24,6 +24,7 @@ TPU re-design highlights:
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Optional
 
@@ -34,6 +35,7 @@ from megatron_llm_tpu.config import TransformerConfig, PositionEmbeddingType
 from megatron_llm_tpu.ops.activations import apply_mlp_activation
 from megatron_llm_tpu.models.moe import moe_mlp, moe_mlp_dropless
 from megatron_llm_tpu.ops.layernorm import apply_norm, init_norm_params
+from megatron_llm_tpu.ops.paged_kv import PagedKVCache
 from megatron_llm_tpu.ops.rope import apply_rotary_emb, precompute_freqs_cis
 from megatron_llm_tpu.ops.softmax import (
     causal_mask,
@@ -268,114 +270,6 @@ def core_attention(
     return ctx.reshape(b, sq, nh, d)
 
 
-# ---------------------------------------------------------------------------
-# paged KV cache (serving engine)
-# ---------------------------------------------------------------------------
-
-def _paged_scatter(kv_cache: dict, k: jax.Array, v: jax.Array,
-                   dest: jax.Array) -> dict:
-    """Write the chunk's K/V rows into the page pool at flat positions
-    ``dest`` ([b, n] indices into the [P*bs] position axis) — one body
-    for the int8 and full-precision pools.  int8 pools quantize on write
-    with per-(position, group) absmax scales.  Returns the pages-only
-    cache dict (the caller re-attaches tables/lengths)."""
-    quantized = "k_pages_q" in kv_cache
-    if quantized:
-        from megatron_llm_tpu.quantization import absmax_quantize_int8
-
-        kq, ks = absmax_quantize_int8(k, axis=-1)
-        vq, vs = absmax_quantize_int8(v, axis=-1)
-        writes = {"k_pages_q": kq, "k_pages_scale": ks,
-                  "v_pages_q": vq, "v_pages_scale": vs}
-    else:
-        writes = {"k_pages": k, "v_pages": v}
-    out = {}
-    for name, val in writes.items():
-        pool = kv_cache[name]
-        P, bs = pool.shape[:2]
-        flat = pool.reshape((P * bs,) + pool.shape[2:])
-        out[name] = flat.at[dest].set(val).reshape(pool.shape)
-    return out
-
-
-def _paged_gather(pages: dict, bt: jax.Array, cdt,
-                  live_lens: Optional[jax.Array] = None) -> tuple:
-    """Dense read view: gather every slot's block table into
-    ``[b, M*bs, g, d]`` K/V (dequantizing int8 pages) — the XLA
-    fallback; the Pallas kernels read pages ragged instead.
-
-    ``live_lens`` ([b] tokens live per slot) bounds the gather to each
-    slot's live page range: table entries whose page starts at or beyond
-    the live range are redirected to the reserved garbage block 0, so
-    the fallback's distinct-page HBM traffic is ``ceil(live/bs)`` pages
-    per slot instead of the full worst-case table (the shapes stay
-    static — only the gathered indices collapse).  Correctness is
-    untouched: every key position the causal mask admits lies below
-    ``live_lens``, and garbage-block reads were already masked."""
-    b, M = bt.shape
-    if live_lens is not None:
-        bs0 = (pages["k_pages_q"] if "k_pages_q" in pages
-               else pages["k_pages"]).shape[1]
-        page_start = jnp.arange(M)[None, :] * bs0
-        bt = jnp.where(page_start < live_lens[:, None], bt, 0)
-    if "k_pages_q" in pages:
-        bs, g, d = pages["k_pages_q"].shape[1:]
-
-        def gather(qname, sname):
-            vals = pages[qname][bt]              # [b, M, bs, g, d]
-            scales = pages[sname][bt]            # [b, M, bs, g]
-            return (vals.astype(cdt)
-                    * scales[..., None].astype(cdt)).reshape(
-                        b, M * bs, g, d)
-
-        return (gather("k_pages_q", "k_pages_scale"),
-                gather("v_pages_q", "v_pages_scale"))
-    bs, g, d = pages["k_pages"].shape[1:]
-    return (pages["k_pages"][bt].reshape(b, M * bs, g, d),
-            pages["v_pages"][bt].reshape(b, M * bs, g, d))
-
-
-def _paged_attention_path(cfg: TransformerConfig, n: int) -> str:
-    """Query-length-aware paged-attention dispatch — the widened
-    ``_paged_kernel_enabled`` seam.  Returns which read path the paged
-    branch takes for an n-query-token call:
-
-    * ``'decode'`` — n == 1 and ``paged_attention_kernel``
-      (``--serve_paged_kernel``) allows the Pallas decode kernel;
-    * ``'prefill'`` — 1 < n <= ``paged_prefill_max_q`` and
-      ``paged_prefill_kernel`` (``--serve_prefill_kernel``) allows the
-      Pallas chunked-prefill kernel;
-    * ``'xla'`` — everything else (mode 'off', oversized query blocks,
-      CPU without interpret mode).
-
-    'auto' asks only whether the kernel can run on this backend.  Traced
-    code cannot see where its arrays live, so whoever jits this for more
-    than one device pins the mode (the serving engine resolves it from
-    its own arrays' devices); a Mosaic call left in a partitioned
-    program is a lowering error, never a quiet XLA run.
-
-    The same n-aware seam is the forward door for a speculative
-    K+1-token verify step: it is just another small-n 'prefill' call.
-    """
-    if n == 1:
-        mode = getattr(cfg, "paged_attention_kernel", "auto")
-        avail_name = "decode_kernel_available"
-        path = "decode"
-    else:
-        mode = getattr(cfg, "paged_prefill_kernel", "auto")
-        avail_name = "prefill_kernel_available"
-        path = "prefill"
-        if n > getattr(cfg, "paged_prefill_max_q", 512):
-            return "xla"
-    if mode == "off":
-        return "xla"
-    if mode == "on":
-        return path
-    from megatron_llm_tpu.ops.pallas import paged_attention
-
-    return path if getattr(paged_attention, avail_name)() else "xla"
-
-
 def attention(
     x: jax.Array,
     params,
@@ -387,12 +281,14 @@ def attention(
     dropout_key: Optional[jax.Array],
     train: bool,
     sequence_parallel: bool = False,
-    kv_cache: Optional[dict] = None,
+    kv_cache=None,
 ) -> jax.Array:
     """Full attention block (reference ``ParallelAttention``,
     transformer.py:280-560): column-parallel QKV, RoPE, core/flash attention,
-    row-parallel dense.  ``kv_cache`` (dict with 'k','v','index') enables
-    incremental decoding (reference inference path :412-505)."""
+    row-parallel dense.  ``kv_cache`` enables incremental decoding
+    (reference inference path :412-505): a ``PagedKVCache`` (the serving
+    engine) or one of the legacy decode stack's dicts with 'k','v','index'
+    (``text_generation/generation.py::init_kv_caches``)."""
     mixed = column_parallel_linear(
         x, params["query_key_value"],
         out_logical="heads",
@@ -415,86 +311,12 @@ def attention(
 
     new_cache = None
     paged_ctx = None
-    if kv_cache is not None and ("k_pages" in kv_cache
-                                 or "k_pages_q" in kv_cache):
-        # PAGED cache (serving engine, serving/kv_blocks.py): one shared
-        # pool of [num_blocks, block_size] pages per layer; each batch row
-        # (a serving *slot*) owns a block table mapping its logical
-        # positions to pool blocks.  All slots share the pool, so HBM is
-        # sized for aggregate traffic, not num_slots x max_len — the
-        # ragged-paged-attention memory model (arXiv:2604.15464).
-        # Scatter-on-write always; the read side is the single dispatch
-        # seam: decode-shaped calls and prefill chunks go to the Pallas
-        # ragged kernel (ops/pallas/paged_attention.py: a loop over each
-        # slot's live pages, whose time follows what is live and not the
-        # table) when --serve_paged_kernel / --serve_prefill_kernel
-        # allow, everything else gathers the dense [b, M*bs] view and
-        # runs plain masked attention.  Shapes are fixed by the pool and
-        # table geometry, so a jitted step never recompiles as requests
-        # come and go.
-        #
-        # Keys: (k_pages|k_pages_q[, k_pages_scale]) [P, bs, g, d],
-        # same for v; block_tables [b, M] int32 (entries beyond a slot's
-        # allocation = 0, the reserved garbage block); context_lens [b]
-        # tokens already in cache; valid_lens [b] real tokens in this
-        # chunk (padded/inactive rows write to the garbage block).
-        bt = kv_cache["block_tables"]
-        ctx_lens = kv_cache["context_lens"]
-        vlen = kv_cache["valid_lens"]
-        quantized = "k_pages_q" in kv_cache
-        pages_k = kv_cache["k_pages_q"] if quantized else kv_cache["k_pages"]
-        P, bs = pages_k.shape[:2]
-        M = bt.shape[1]
-        n = k.shape[1]
-        d = k.shape[3]
-        j = jnp.arange(n)[None, :]
-        pos = ctx_lens[:, None] + j                          # [b, n] abs pos
-        blk = jnp.take_along_axis(bt, jnp.clip(pos // bs, 0, M - 1), axis=1)
-        real = j < vlen[:, None]
-        # padded / inactive tokens land in garbage block 0 (duplicate
-        # scatter indices there are fine — nobody reads it unmasked)
-        dest = jnp.where(real, blk * bs + pos % bs, pos % bs)
-        dest = jnp.clip(dest, 0, P * bs - 1)
-        new_cache = _paged_scatter(kv_cache, k, v, dest)
-        path = _paged_attention_path(cfg, n)
-        if path != "xla":
-            from megatron_llm_tpu.ops.pallas import paged_attention as _pa
-
-            # a row with valid_lens 0 has no token in this call (an idle
-            # slot): the kernel fetches nothing for it and nobody reads
-            # its output
-            kernel_kw = dict(
-                valid_lens=vlen,
-                k_scales=new_cache.get("k_pages_scale"),
-                v_scales=new_cache.get("v_pages_scale"),
-                softmax_scale=1.0 / math.sqrt(d),
-                sliding_window=cfg.sliding_window_size,
-            )
-            kp = new_cache["k_pages_q" if quantized else "k_pages"]
-            vp = new_cache["v_pages_q" if quantized else "v_pages"]
-            if path == "decode":
-                paged_ctx = _pa.paged_attention_decode(
-                    q[:, 0], kp, vp, bt, ctx_lens,   # [b, nh, d] query
-                    **kernel_kw)[:, None]            # -> [b, 1, nh, d]
-            else:
-                # chunked prefill: the chunk's own K/V just scattered at
-                # ctx_lens..ctx_lens+n-1, so the kernel's causal walk
-                # covers history AND the in-flight chunk; padded tail
-                # rows (j >= valid_lens) are garbage either way
-                paged_ctx = _pa.paged_attention_prefill(
-                    q, kp, vp, bt, ctx_lens, **kernel_kw)
-        else:
-            k, v = _paged_gather(new_cache, bt, k.dtype,
-                                 live_lens=ctx_lens + vlen)
-            key_pos = jnp.arange(M * bs)
-            valid = key_pos[None, None, :] <= pos[:, :, None]  # [b, sq, sk]
-            if cfg.sliding_window_size is not None:
-                valid &= key_pos[None, None, :] > (pos[:, :, None]
-                                                   - cfg.sliding_window_size)
-            attention_mask = ~valid[:, None]                 # [b, 1, sq, sk]
-        new_cache.update({"block_tables": bt,
-                          "context_lens": ctx_lens + vlen,
-                          "valid_lens": vlen})
+    if isinstance(kv_cache, PagedKVCache):
+        # the serving engine's paged cache (ops/paged_kv.py owns it):
+        # scatter this call's K/V into the pool, attend through the
+        # path the cache carries
+        paged_ctx, new_cache = kv_cache.attend(q, k, v,
+                                               cfg.sliding_window_size)
     elif kv_cache is not None and "rolling" in kv_cache:
         # ROLLING cache (sliding-window models): a ring buffer of exactly
         # window slots — decode memory O(window), not O(total).  Slot
@@ -605,8 +427,6 @@ def attention(
     use_ring = cp_size > 1 and flash_eligible
     use_flash = cfg.use_flash_attn and flash_eligible
     if paged_ctx is not None:
-        # the ragged paged-attention kernel already produced the
-        # attention context for this decode step
         ctx = paged_ctx
     elif use_ring:
         from megatron_llm_tpu.parallel.ring_attention import (
@@ -672,10 +492,10 @@ def attention(
         )
 
         # long-context XLA fallback: the [s, s] score tensor of the plain
-        # path fails to compile at seq >= 4096 on this stack
-        # (docs/perf_tpu.md), which would turn a flash-kernel degradation
-        # into a dead run exactly when the fallback matters; the q-chunked
-        # path is exact and bounds score memory per chunk
+        # path failed to compile at seq >= 4096 on one v5e (commit
+        # `128e754`, not re-measured), which would turn a flash-kernel
+        # degradation into a dead run exactly when the fallback matters;
+        # the q-chunked path is exact and bounds score memory per chunk
         if flash_eligible and q.shape[1] >= CHUNKED_ATTENTION_MIN_SEQ:
             ctx = chunked_causal_attention(
                 q, k, v,
@@ -879,22 +699,23 @@ def transformer_layer(
     # capacity einsum when training, the dropless path otherwise.  Under
     # the paged cache the step's live tokens are the first valid_lens of
     # each row; the others are routed nowhere, and the histogram of live
-    # assignments rides the layer's cache for the engine's counters.
+    # assignments goes out in the cache's declared field for the engine's
+    # counters.
     def run_mlp(inp):
+        nonlocal new_cache
         with jax.named_scope("mlp"):
             if cfg.num_experts <= 1:
                 return mlp(inp, params["mlp"], cfg,
                            sequence_parallel=sequence_parallel), None
             if train:
                 return moe_mlp(inp, params["mlp"], cfg)
-            live = None
-            if new_cache is not None and "valid_lens" in new_cache:
-                live = (jnp.arange(inp.shape[1])[None, :]
-                        < new_cache["valid_lens"][:, None])
+            paged = isinstance(new_cache, PagedKVCache)
+            live = new_cache.live(inp.shape[1]) if paged else None
             out, aux, counts = moe_mlp_dropless(inp, params["mlp"], cfg,
                                                 live, moe_layer)
-            if live is not None:
-                new_cache["moe_counts"] = counts
+            if paged:
+                new_cache = dataclasses.replace(new_cache,
+                                                moe_counts=counts)
             return out, aux
 
     if cfg.parallel_attn:

@@ -1,10 +1,10 @@
 """Q-chunked exact attention — the long-context XLA fallback.
 
 On this stack plain XLA attention cannot compile at seq >= 4096: the
-[b, heads, s, s] fp32 score tensor crashes the remote compiler
-(docs/perf_tpu.md).  When the Pallas flash kernel is unavailable
-(degraded by bench.py's kernel smoke, or ``use_flash_attn=False``), the
-naive fallback therefore dies exactly where a fallback is needed most.
+[b, heads, s, s] fp32 score tensor crashed the TPU compiler (seen at
+commit `128e754`, not re-measured).  When the Pallas flash kernel is
+unavailable (``use_flash_attn=False``, or no Pallas backend), the naive
+fallback therefore dies exactly where a fallback is needed most.
 
 This op processes Q in row chunks (the same inner-chunk structure as
 ``parallel/ring_attention.ring_self_attention``, minus the ring): each

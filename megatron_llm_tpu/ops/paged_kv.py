@@ -1,0 +1,220 @@
+"""The paged KV cache: its layout, its writes and reads, and which kernel
+reads it.  The one owner: nothing else in the package knows what a pool
+looks like.
+
+A *pool* is one layer's pages, shared by every request the serving
+engine holds (``serving/kv_blocks.py`` hands the pages out): K and V as
+``[num_blocks, block_size, groups, head_dim]``, or int8 with
+per-(block, position, group) fp32 absmax scales.  Block 0 is the
+reserved garbage block: padded chunk tokens and idle slots write there
+and nobody reads it unmasked.  All slots share the pool, so HBM is
+sized for aggregate traffic, not ``num_slots x max_len`` (the ragged
+paged-attention memory model, arXiv:2604.15464).  The engine keeps the
+pools (a list, one a layer) as an opaque pytree; ``init_pools``,
+``block_bytes`` and the three page programs' bodies (``copy_page``,
+``fetch_page``, ``load_page``) are all it needs of them.
+
+A :class:`PagedKVCache` is what the model is handed for one step of one
+layer: the pool plus the step's state (block tables, context lengths,
+valid lengths) and, as STATIC data, the path that reads the pool:
+``'pallas'`` (``ops/pallas/paged_attention.py``: a walk over each
+slot's live pages, whose time follows what is live and not the table)
+or ``'xla'`` (that file's dense reference: gather every slot's table
+and mask; what runs on the CPU and on a mesh of several devices, and
+what the kernel's tests compare against).  A jitted program is static
+in the path without any config field; ``resolve_kernel`` is where a
+requested ``auto|on|off`` becomes a path, once.  Shapes are fixed by
+the pool and table geometry, so a jitted step never recompiles as
+requests come and go.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import List, Optional
+
+import jax
+import jax.numpy as jnp
+
+KERNEL_MODES = ("auto", "on", "off")
+
+
+def resolve_kernel(requested: str, one_device: bool) -> str:
+    """``auto|on|off`` (``--serve_paged_kernel``, ``--serve_prefill_kernel``)
+    -> ``'pallas' | 'xla'``.  The kernel runs where Pallas can (a TPU, or
+    interpret mode in tests).  A Mosaic call cannot be partitioned by
+    GSPMD, so ``auto`` takes it only for a program that runs on ONE
+    device (the caller knows where its arrays live; traced code cannot
+    see that); ``on`` insists, and a Mosaic call left in a partitioned
+    program is then a lowering error, never a quiet XLA run."""
+    if requested not in KERNEL_MODES:
+        raise ValueError(f"paged kernel mode must be auto|on|off, got "
+                         f"{requested!r}")
+    from megatron_llm_tpu.ops.pallas import paged_attention as _pa
+
+    if (requested != "off" and _pa.kernel_available()
+            and (requested == "on" or one_device)):
+        return "pallas"
+    return "xla"
+
+
+def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
+               quantized: bool = False) -> List[dict]:
+    """One pool a layer for a model of config ``cfg``: the compute dtype,
+    or int8 with fp32 scales when ``quantized`` (halves the KV bytes a
+    decode step reads, against bf16)."""
+    dtype = dtype or cfg.compute_jnp_dtype
+    shape = (num_blocks, block_size, cfg.num_query_groups, cfg.head_dim)
+
+    def pool():
+        if quantized:
+            return {"k_pages_q": jnp.zeros(shape, jnp.int8),
+                    "k_pages_scale": jnp.ones(shape[:3], jnp.float32),
+                    "v_pages_q": jnp.zeros(shape, jnp.int8),
+                    "v_pages_scale": jnp.ones(shape[:3], jnp.float32)}
+        return {"k_pages": jnp.zeros(shape, dtype),
+                "v_pages": jnp.zeros(shape, dtype)}
+
+    return [pool() for _ in range(cfg.num_layers)]
+
+
+def _arrays(pool: dict):
+    """(K, V, K scales, V scales) of a pool; the scales None unless int8."""
+    if "k_pages_q" in pool:
+        return (pool["k_pages_q"], pool["v_pages_q"],
+                pool["k_pages_scale"], pool["v_pages_scale"])
+    return pool["k_pages"], pool["v_pages"], None, None
+
+
+def block_bytes(pools) -> int:
+    """Bytes of one block across every layer's pool."""
+    return sum(math.prod(a.shape[1:]) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(pools))
+
+
+def fetch_page(pools, src):
+    """Physical page ``src`` of every array of every layer's pool."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, src, axis=0,
+                                               keepdims=False), pools)
+
+
+def load_page(pools, page, dst):
+    """The pools with ``page`` (what ``fetch_page`` returns) written at
+    physical page ``dst``."""
+    return jax.tree_util.tree_map(
+        lambda a, p: jax.lax.dynamic_update_index_in_dim(a, p, dst, axis=0),
+        pools, page)
+
+
+def copy_page(pools, src, dst):
+    """The pools with physical page ``src`` duplicated into ``dst``."""
+    return load_page(pools, fetch_page(pools, src), dst)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class PagedKVCache:
+    """One layer's pool with one step's state.
+
+    ``block_tables`` [b, M] int32 maps each row's logical pages to pool
+    blocks (entries beyond a row's allocation are 0, the garbage block);
+    ``context_lens`` [b] counts the tokens already in the cache;
+    ``valid_lens`` [b] the real tokens of this call (0: an idle row,
+    which writes to the garbage block and is not read for).
+    ``moe_counts`` is an OUTPUT a sparse model's layer leaves for the
+    engine's counters: ``[E]`` live (token, choice) assignments the
+    layer's router made this step; None on the way in and for a dense
+    model.  ``kernel`` is static: ``'pallas' | 'xla'``."""
+
+    pool: dict
+    block_tables: jax.Array
+    context_lens: jax.Array
+    valid_lens: jax.Array
+    kernel: str = dataclasses.field(metadata=dict(static=True))
+    moe_counts: Optional[jax.Array] = None
+
+    def live(self, n: int) -> jax.Array:
+        """[b, n] bool: which of this call's n tokens a row are real."""
+        return jnp.arange(n)[None, :] < self.valid_lens[:, None]
+
+    def attend(self, q: jax.Array, k: jax.Array, v: jax.Array,
+               sliding_window: Optional[int]):
+        """Write this call's keys and values ``[b, n, g, d]`` at
+        ``context_lens ..``, then attend ``q`` [b, n, nh, d] over the
+        row's history and the chunk's own causal prefix.  Returns the
+        context ``[b, n, nh, d]`` and the cache as the step leaves it
+        (``context_lens`` advanced by ``valid_lens``).  Rows past
+        ``valid_lens`` are garbage in, garbage out on either path."""
+        from megatron_llm_tpu.ops.pallas import paged_attention as _pa
+
+        bt, ctx_lens, vlen = (self.block_tables, self.context_lens,
+                              self.valid_lens)
+        n, d = k.shape[1], k.shape[3]
+        quantized = "k_pages_q" in self.pool
+        P, bs = _arrays(self.pool)[0].shape[:2]
+        M = bt.shape[1]
+        j = jnp.arange(n)[None, :]
+        pos = ctx_lens[:, None] + j                          # [b, n] abs pos
+        blk = jnp.take_along_axis(bt, jnp.clip(pos // bs, 0, M - 1), axis=1)
+        # padded / inactive tokens (not live) land in garbage block 0
+        # (duplicate scatter indices there are fine)
+        dest = jnp.where(j < vlen[:, None], blk * bs + pos % bs, pos % bs)
+        dest = jnp.clip(dest, 0, P * bs - 1)
+        if quantized:
+            from megatron_llm_tpu.quantization import absmax_quantize_int8
+
+            kq, ks = absmax_quantize_int8(k, axis=-1)
+            vq, vs = absmax_quantize_int8(v, axis=-1)
+            writes = {"k_pages_q": kq, "k_pages_scale": ks,
+                      "v_pages_q": vq, "v_pages_scale": vs}
+        else:
+            writes = {"k_pages": k, "v_pages": v}
+        pool = {}
+        for name, val in writes.items():
+            a = self.pool[name]
+            flat = a.reshape((P * bs,) + a.shape[2:])
+            pool[name] = flat.at[dest].set(val).reshape(a.shape)
+        kp, vp, k_scales, v_scales = _arrays(pool)
+        scale = 1.0 / math.sqrt(d)
+        if self.kernel == "pallas":
+            # the chunk's own K/V were just scattered, so the kernel's
+            # causal walk covers history AND the in-flight chunk
+            kw = dict(valid_lens=vlen, k_scales=k_scales, v_scales=v_scales,
+                      softmax_scale=scale, sliding_window=sliding_window)
+            if n == 1:
+                ctx = _pa.paged_attention_decode(
+                    q[:, 0], kp, vp, bt, ctx_lens, **kw)[:, None]
+            else:
+                ctx = _pa.paged_attention_prefill(
+                    q, kp, vp, bt, ctx_lens, **kw)
+        else:
+            ctx = _pa.dense_paged_attention(
+                q, kp, vp, bt, ctx_lens, vlen, k_scales, v_scales,
+                scale, sliding_window)
+        return ctx, dataclasses.replace(self, pool=pool,
+                                        context_lens=ctx_lens + vlen)
+
+
+def step_caches(pools, block_tables, context_lens, valid_lens,
+                kernel: Optional[str] = None) -> List[PagedKVCache]:
+    """Every layer's cache for one step over ``pools``.  ``kernel`` None
+    is what ``auto`` means for a program on one device: the kernel where
+    it can run (whoever jits for several devices says ``'xla'``)."""
+    kernel = kernel or resolve_kernel("auto", one_device=True)
+    return [PagedKVCache(p, block_tables, context_lens, valid_lens,
+                         kernel=kernel) for p in pools]
+
+
+def pools_of(caches: List[PagedKVCache]) -> List[dict]:
+    """The pools as a step left them."""
+    return [c.pool for c in caches]
+
+
+def routing_of(caches: List[PagedKVCache]) -> Optional[jax.Array]:
+    """[layers, E] int32 live assignments of a sparse model's step; None
+    for a dense model."""
+    if caches[0].moe_counts is None:
+        return None
+    return jnp.stack([c.moe_counts for c in caches])

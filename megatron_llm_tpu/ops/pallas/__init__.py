@@ -16,7 +16,7 @@ def pallas_backend_available() -> bool:
     MLT_FORCE_PALLAS: AOT compiles (jax.experimental.topologies) run
     with a CPU default backend while lowering FOR a TPU topology —
     without the override they'd silently compile the XLA fallbacks
-    (tools/aot_memcheck.py and tools/compile_stats.py set it).
+    (tools/aot_memcheck.py sets it).
     """
     return (jax.default_backend() == "tpu"
             or os.environ.get("MLT_FORCE_PALLAS") == "1")

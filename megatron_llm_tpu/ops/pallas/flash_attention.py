@@ -350,8 +350,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     """Single-pass backward: one sweep of the (ki, qi) block grid computes
     dq, dk, dv together, sharing the s = q k^T recompute and the
     dp = do v^T matmul that the two-kernel structure (below) performs
-    twice — 5 block matmuls instead of 7 (the round-3 'known headroom',
-    docs/perf_tpu.md).
+    twice — 5 block matmuls instead of 7.
 
     dq accumulation: the dq output block is the FULL [sq, d] fp32 slab
     per (b, h), whose index map ignores (ki, qi) — consecutive revisits
@@ -499,11 +498,12 @@ FUSED_BWD_MAX_SLAB_BYTES = 4 << 20
 # two-kernel 1024 defaults because its scoped-vmem working set carries
 # four bq x bk fp32 score-tile intermediates (s, p, dp, ds) PLUS the
 # full-seq dq slab: at 1024x1024 that is ~15 MB of tiles before the slab
-# and the real compiler rejects it (verified via tools/compile_stats.py
-# — 16.05 MB needed vs the 16 MB scoped-vmem limit at seq 2048, worse at
-# longer seq).  512x512 tiles cost 4 MB total, leaving room for the slab
-# at every supported length.  Whether fused@512 beats two-kernel@1024
-# on-chip is exactly what `tools/mfu_sweep.py fusedbwd` measures.
+# and the real compiler rejects it (16.05 MB needed vs the 16 MB
+# scoped-vmem limit at seq 2048, worse at longer seq; compiled at
+# commit `128e754`, not re-measured).  512x512 tiles cost 4 MB total,
+# leaving room for the slab at every supported length.  On one v5e
+# fused@512 tied two-kernel@1024 at seq 2048 (commit `128e754`, not
+# re-measured).
 FUSED_BLOCK_Q = 512
 FUSED_BLOCK_K = 512
 

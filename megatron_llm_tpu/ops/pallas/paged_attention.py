@@ -2,7 +2,7 @@
 TPU): decode (one query token per slot), chunked prefill (a [C]-token
 query block per slot) and the speculative verify step are one walk.
 
-The XLA paged branch in ``models/transformer.py`` gathers every slot's
+The dense path (``dense_paged_attention`` below) gathers every slot's
 block table into a dense ``[b, M*bs, g, d]`` view (dequantizing every
 int8 page) before masked attention.  This kernel leaves the pools in HBM
 and fetches, slot by slot, only the pages that slot attends
@@ -66,9 +66,10 @@ chunk: 16 / 30 / 69 / 137 us at 0 / 192 / 1,984 / 6,016 tokens of
 context (76 / 123 / 560 / 1,076 before).
 
 Dispatch mirrors ``flash_attention.py``: TPU backend -> kernel;
-otherwise -> jnp reference math (the same dense-gather computation as
-the transformer's XLA branch).  Interpret-mode tests run the kernel on
-CPU via the module-level ``_INTERPRET`` flag.
+otherwise -> the dense reference.  Interpret-mode tests run the kernel
+on CPU via the module-level ``_INTERPRET`` flag.  ``ops/paged_kv.py``
+owns the pool these entries read and decides which of the two a
+program takes.
 """
 
 from __future__ import annotations
@@ -95,36 +96,40 @@ def _use_pallas() -> bool:
     return _INTERPRET or pallas_backend_available()
 
 
-def decode_kernel_available() -> bool:
-    """True when ``paged_attention_decode`` would run the Pallas kernel
-    (TPU backend, or interpret mode in tests) — the transformer's
-    ``--serve_paged_kernel auto`` predicate and the engine's
-    ``paged_kernel: pallas|xla`` attribution both key off this."""
-    return _use_pallas()
-
-
-def prefill_kernel_available() -> bool:
-    """Same gate for ``paged_attention_prefill`` (the kernels share a
-    backend, so today this equals :func:`decode_kernel_available`; kept
-    separate so ``--serve_prefill_kernel auto`` and the engine's
-    ``prefill_kernel`` attribution have their own seam)."""
+def kernel_available() -> bool:
+    """True when the public entries would run the Pallas kernel (TPU
+    backend, or interpret mode in tests): what ``auto`` asks
+    (``ops/paged_kv.py::resolve_kernel``)."""
     return _use_pallas()
 
 
 # ---------------------------------------------------------------------------
-# reference math (non-TPU fallback; identical to the XLA paged branch)
+# the dense reference: what runs where the kernel cannot (the CPU, a mesh
+# of several devices) and what the kernel's tests compare against
 # ---------------------------------------------------------------------------
 
-def _reference_paged_prefill(q, k_pages, v_pages, block_tables,
-                             context_lens, k_scales, v_scales,
-                             scale, window):
-    """Dense-gather chunked prefill: q [S, C, nh, d], row ``j`` of slot
-    ``s`` attends key positions ``0..context_lens[s]+j`` (minus the
-    sliding window) — the same math as the transformer's XLA branch."""
+def dense_paged_attention(q, k_pages, v_pages, block_tables,
+                          context_lens, valid_lens, k_scales, v_scales,
+                          scale, window):
+    """Gather every slot's block table into dense ``[S, M*bs, g, d]``
+    keys and values (dequantizing int8 pages) and run masked attention
+    in fp32: q [S, C, nh, d], row ``j`` of slot ``s`` attends key
+    positions ``0..context_lens[s]+j`` (minus the sliding window).
+
+    ``valid_lens`` [S] (None: all C rows of every slot are real) bounds
+    the gather to each slot's live pages: table entries whose page
+    starts at or beyond ``context_lens + valid_lens`` are read from the
+    garbage block 0 instead, so the distinct pages fetched are
+    ``ceil(live/bs)`` a slot and not the whole table (the shapes stay
+    static, only the gathered indices collapse).  Every key position a
+    real row's mask admits lies below that bound."""
     S, C, nh, d = q.shape
     bs, g = k_pages.shape[1], k_pages.shape[2]
     M = block_tables.shape[1]
     qpg = nh // g
+    live = context_lens + (C if valid_lens is None else valid_lens)
+    block_tables = jnp.where(
+        jnp.arange(M)[None, :] * bs < live[:, None], block_tables, 0)
     k = k_pages[block_tables].reshape(S, M * bs, g, d).astype(jnp.float32)
     v = v_pages[block_tables].reshape(S, M * bs, g, d).astype(jnp.float32)
     if k_scales is not None:
@@ -141,15 +146,6 @@ def _reference_paged_prefill(q, k_pages, v_pages, block_tables,
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bgpst,btgd->bsgpd", probs, v)
     return out.reshape(S, C, nh, d).astype(q.dtype)
-
-
-def _reference_paged_attention(q, k_pages, v_pages, block_tables,
-                               context_lens, k_scales, v_scales,
-                               scale, window):
-    """Decode reference — the C == 1 instance of the prefill reference."""
-    return _reference_paged_prefill(
-        q[:, None], k_pages, v_pages, block_tables, context_lens,
-        k_scales, v_scales, scale, window)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -428,9 +424,10 @@ def paged_attention_decode(
     if softmax_scale is None:
         softmax_scale = 1.0 / math.sqrt(q.shape[-1])
     if not _use_pallas():
-        return _reference_paged_attention(
-            q, k_pages, v_pages, block_tables, context_lens,
-            k_scales, v_scales, softmax_scale, sliding_window)
+        return dense_paged_attention(
+            q[:, None], k_pages, v_pages, block_tables, context_lens,
+            valid_lens, k_scales, v_scales, softmax_scale,
+            sliding_window)[:, 0]
     return _walk_call(
         q[:, None], k_pages, v_pages, block_tables, context_lens, valid_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
@@ -455,11 +452,11 @@ def paged_attention_prefill(
 
     ``q``: [S, C, nh, d] — C query tokens per slot sitting at absolute
     positions ``context_lens[s] .. context_lens[s]+C-1`` (their K/V must
-    already be scattered into the pools, as the transformer's paged
-    branch does before the read).  Row ``j`` attends the full paged
+    already be scattered into the pools, as ``PagedKVCache.attend``
+    does before the read).  Row ``j`` attends the full paged
     history plus its own causal prefix of the chunk; padded tail rows of
     a short final chunk compute garbage-in-garbage-out exactly like the
-    XLA branch (the engine only reads the last valid row's logits).
+    dense path (the engine only reads the last valid row's logits).
     ``valid_lens`` [S]: real tokens of each slot's chunk; a slot with 0
     (an idle row of the speculative verify step) is skipped as in
     :func:`paged_attention_decode`.  Returns [S, C, nh, d] in
@@ -470,8 +467,8 @@ def paged_attention_prefill(
     if softmax_scale is None:
         softmax_scale = 1.0 / math.sqrt(q.shape[-1])
     if not _use_pallas():
-        return _reference_paged_prefill(
-            q, k_pages, v_pages, block_tables, context_lens,
+        return dense_paged_attention(
+            q, k_pages, v_pages, block_tables, context_lens, valid_lens,
             k_scales, v_scales, softmax_scale, sliding_window)
     C = q.shape[1]
     bq = min(block_q or _PREFILL_BLOCK_Q, C)

@@ -39,8 +39,8 @@ is the other half, wrapped around the already-jitted train step:
 
 Recovery counters (``rewinds``, ``save_retries``, ``watchdog_fires``,
 ``signal_saves``) accumulate in the global counters dict
-(``global_vars.get_counters``) and surface in the training log, the
-TB/W&B writer, and ``bench.py`` artifacts.
+(``global_vars.get_counters``) and surface in the training log and the
+TB/W&B writer.
 """
 
 from __future__ import annotations

@@ -1,8 +1,9 @@
 """Continuous-batching inference engine.
 
 One background thread drives two jitted, fixed-shape device programs over
-a single paged KV pool (text_generation/generation.py
-``init_paged_kv_caches`` + the paged branch in models/transformer.py):
+a single paged KV pool (``ops/paged_kv.py`` owns its layout, its reads
+and writes and which kernel reads it; this module holds the pools as an
+opaque pytree):
 
 * ``decode_step`` — ``[num_slots]`` rows, one token each.  Every live
   request occupies a slot; empty slots ride along masked (their KV
@@ -14,8 +15,7 @@ a single paged KV pool (text_generation/generation.py
   ``[num_slots, draft_k + 1]`` forward that verifies host-proposed
   draft tokens (serving/drafter.py prompt-lookup) for every slot at
   once.  It rides the same paged pool through the scatter-before-read
-  prefill path (n = K+1 <= paged_prefill_max_q in the verify-only
-  config override), with per-slot draft tokens and valid counts as
+  prefill path (n = K+1), with per-slot draft tokens and valid counts as
   traced inputs — a slot with no usable draft degenerates to a masked
   plain decode row, so mixed drafting/non-drafting/sampled batches
   stay zero-recompile.  Verification is exact-greedy (accepted tokens
@@ -81,6 +81,7 @@ import numpy as np
 
 from megatron_llm_tpu import telemetry, tracing
 from megatron_llm_tpu.models.language_model import language_model_forward
+from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.serving.cache_observatory import CacheObservatory
 from megatron_llm_tpu.serving.drafter import draft_budget, lookup_draft
 from megatron_llm_tpu.serving.kv_blocks import (
@@ -110,7 +111,6 @@ from megatron_llm_tpu.serving.resilience import (
     ServingFaultInjector,
 )
 from megatron_llm_tpu.serving.scheduler import Scheduler
-from megatron_llm_tpu.text_generation.generation import init_paged_kv_caches
 from megatron_llm_tpu.text_generation.sampling import NEG_INF, sample_batched
 
 
@@ -125,16 +125,12 @@ class EngineConfig:
     default_deadline_secs: float = 120.0  # 0 = no deadline
     int8_kv_cache: bool = False
     prefix_cache: bool = True       # share KV pages across equal prefixes
-    # Pallas ragged paged-attention decode kernel (--serve_paged_kernel):
-    # 'auto' = on when the Pallas backend is available (TPU, or interpret
-    # mode in tests), 'on' forces it, 'off' keeps the XLA gather branch.
-    # The resolved path is reported as stats()['paged_kernel'].
+    # which path reads the pool, auto|on|off, for the decode program
+    # (--serve_paged_kernel) and for the [1, C] chunked-prefill and the
+    # verify programs (--serve_prefill_kernel).  Resolved once at
+    # __init__ by paged_kv.resolve_kernel; the resolved paths are
+    # stats()['paged_kernel'] and stats()['prefill_kernel'].
     paged_kernel: str = "auto"
-    # Pallas ragged paged-attention prefill kernel for the [1, C]
-    # chunked-prefill program (--serve_prefill_kernel): same auto/on/off
-    # semantics as paged_kernel.  Resolved once at __init__ into a static
-    # prefill config override (so the jitted prefill program never
-    # recompiles) and reported as stats()['prefill_kernel'].
     prefill_kernel: str = "auto"
     # in-engine speculative decoding (--serve_speculative /
     # --serve_draft_k): host-side prompt-lookup drafting + a fixed-shape
@@ -248,68 +244,30 @@ class InferenceEngine:
             cfg.num_blocks or None)
         self.queue = RequestQueue(cfg.max_queue_depth)
 
-        # resolve the decode attention path ONCE (it is a static config
-        # field of the jitted decode step, so flipping it later would
-        # recompile): 'pallas' when the kernel can actually run here,
-        # else the XLA gather branch.  The resolved value — not the
-        # requested mode — is what /metrics and request_done report.
-        if cfg.paged_kernel not in ("auto", "on", "off"):
-            raise ValueError(f"paged_kernel must be auto|on|off, got "
-                             f"{cfg.paged_kernel!r}")
-        if cfg.prefill_kernel not in ("auto", "on", "off"):
-            raise ValueError(f"prefill_kernel must be auto|on|off, got "
-                             f"{cfg.prefill_kernel!r}")
-        from megatron_llm_tpu.ops.pallas.paged_attention import (
-            decode_kernel_available, prefill_kernel_available,
-        )
-        # a Mosaic call cannot be partitioned by GSPMD, so 'auto' takes
-        # the kernel only where the jitted programs run on ONE device —
-        # which is where this engine's own arrays live, not how many
-        # chips the host happens to have (a one-chip replica on a
-        # four-chip host keeps its kernels)
+        # which path reads the pool is resolved ONCE, per program: it is
+        # static data of the caches the programs hand the model, so
+        # flipping it later would recompile.  'auto' takes the kernel
+        # only where the programs run on ONE device, which is where this
+        # engine's own arrays live, not how many chips the host happens
+        # to have (a one-chip replica on a four-chip host keeps its
+        # kernels).  The resolved value, not the requested mode, is what
+        # /metrics and request_done report.
         one_device = len({d for leaf in jax.tree_util.tree_leaves(params)
                           if isinstance(leaf, jax.Array)
                           for d in leaf.devices()}) <= 1
-        self.paged_kernel = (
-            "pallas" if cfg.paged_kernel != "off"
-            and decode_kernel_available()
-            and (cfg.paged_kernel == "on" or one_device)
-            else "xla")
-        self._decode_cfg = mcfg.replace(
-            paged_attention_kernel=(
-                "on" if self.paged_kernel == "pallas" else "off"),
-            paged_prefill_kernel="off")     # decode program is n == 1
-        # same resolve-once pattern for the chunked-prefill program: the
-        # override pins both kernel modes (the [1, C] call is n == C, so
-        # the decode field is moot, but static is static) and widens
-        # paged_prefill_max_q to this engine's chunk so the n-aware
-        # dispatch in the transformer routes it
-        self.prefill_kernel = (
-            "pallas" if cfg.prefill_kernel != "off"
-            and prefill_kernel_available()
-            and (cfg.prefill_kernel == "on" or one_device)
-            else "xla")
-        self._prefill_cfg = mcfg.replace(
-            paged_attention_kernel="off",
-            paged_prefill_kernel=(
-                "on" if self.prefill_kernel == "pallas" else "off"),
-            paged_prefill_max_q=max(cfg.prefill_chunk, 2))
-        # speculative verify step, resolved ONCE like the kernel paths:
-        # the [S, K+1] verify forward is just another small-n "prefill"
-        # call through the scatter-before-read paged branch, so it rides
-        # the resolved *prefill* attention path with paged_prefill_max_q
-        # widened to K+1.  draft_k is a compiled shape — flipping it
-        # later would recompile, so it is pinned here.
+        self.paged_kernel = paged_kv.resolve_kernel(cfg.paged_kernel,
+                                                    one_device)
+        self.prefill_kernel = paged_kv.resolve_kernel(cfg.prefill_kernel,
+                                                      one_device)
+        # the speculative [S, K+1] verify forward is another small-n
+        # prefill call, so it rides the resolved PREFILL path.  draft_k
+        # is a compiled shape: flipping it later would recompile, so it
+        # is pinned here.
         if cfg.speculative and cfg.draft_k < 1:
             raise ValueError(f"speculative decoding needs draft_k >= 1, "
                              f"got {cfg.draft_k}")
         self.speculative = bool(cfg.speculative)
         self.draft_k = int(cfg.draft_k) if self.speculative else 0
-        self._verify_cfg = mcfg.replace(
-            paged_attention_kernel="off",
-            paged_prefill_kernel=(
-                "on" if self.prefill_kernel == "pallas" else "off"),
-            paged_prefill_max_q=max(self.draft_k + 1, 2))
 
         # cache observatory (serving/cache_observatory.py): per-prefix
         # heat, eviction forensics, ghost capacity tiers.  Engine-
@@ -320,18 +278,15 @@ class InferenceEngine:
             ghost_multiples=cfg.cache_ghost_multiples)
 
         # host spill tier (serving/host_cache.py): constructed after the
-        # first state so the per-block byte size can be read off the
-        # actual page arrays (dtype- and quantization-aware), then wired
-        # into the manager + observatory.  Engine-lifetime like both.
+        # first state so the per-block byte size is that of the actual
+        # pools (dtype- and quantization-aware), then wired into the
+        # manager + observatory.  Engine-lifetime like both.
         self.host_cache = None
         self._st = self._new_state(gen=0)
         if cfg.host_cache_bytes > 0 and cfg.prefix_cache:
             from megatron_llm_tpu.serving.host_cache import HostKVCache
-            block_bytes = sum(
-                int(np.prod(v.shape[1:])) * v.dtype.itemsize
-                for p in self._st.pages for v in p.values())
             self.host_cache = HostKVCache(
-                cfg.host_cache_bytes, block_bytes,
+                cfg.host_cache_bytes, paged_kv.block_bytes(self._st.pages),
                 fetch=self._spill_fetch)
             self.cache_observatory.attach_host(self.host_cache)
             self._st.blocks.attach_host_cache(self.host_cache)
@@ -342,15 +297,14 @@ class InferenceEngine:
         self._prefill_step = _program(self._prefill_impl, "engine_prefill")
         self._sample_first = _program(self._sample_first_impl,
                                       "engine_sample_first")
-        self._cow_copy = _program(self._cow_copy_impl, "engine_cow_copy")
-        # host-tier device programs: one fixed-shape whole-page gather
-        # (device→host spill source) and one whole-page scatter
-        # (host→device swap-in), both over traced int32 block indices —
-        # compiled once at warmup, zero steady-state recompiles
-        self._fetch_block = _program(self._fetch_block_impl,
+        # the three page programs: copy-on-write, the host tier's
+        # device→host spill source and its host→device swap-in.  src /
+        # dst are traced int32 scalars and a host page has fixed shapes,
+        # so one compile each (at warmup) covers every event
+        self._cow_copy = _program(paged_kv.copy_page, "engine_cow_copy")
+        self._fetch_block = _program(paged_kv.fetch_page,
                                      "engine_fetch_block")
-        self._host_load = _program(self._host_load_impl,
-                                   "engine_host_load")
+        self._host_load = _program(paged_kv.load_page, "engine_host_load")
 
         # counters (read by stats()/the HTTP /metrics endpoint)
         self.decode_steps = 0
@@ -440,9 +394,9 @@ class InferenceEngine:
             gen=gen,
             blocks=blocks,
             scheduler=sched,
-            pages=init_paged_kv_caches(self.model.cfg, self._num_blocks,
-                                       cfg.block_size,
-                                       quantized=cfg.int8_kv_cache),
+            pages=paged_kv.init_pools(self.model.cfg, self._num_blocks,
+                                      cfg.block_size,
+                                      quantized=cfg.int8_kv_cache),
             last_tokens=np.zeros(S, np.int32),
             context_lens=np.zeros(S, np.int32),
             active=np.zeros(S, np.int32),
@@ -468,38 +422,15 @@ class InferenceEngine:
     # jitted device programs (fixed shapes; everything traced)
     # ------------------------------------------------------------------
 
-    def _layer_caches(self, pages, block_tables, context_lens, valid_lens):
-        return [dict(p, block_tables=block_tables,
-                     context_lens=context_lens, valid_lens=valid_lens)
-                for p in pages]
-
-    @staticmethod
-    def _strip_pages(new_caches):
-        return [{k: v for k, v in c.items() if "pages" in k}
-                for c in new_caches]
-
-    @staticmethod
-    def _moe_counts(new_caches):
-        """[layers, E] int32 live assignments of a sparse model's step
-        (``models/moe.py`` leaves each layer's histogram on its cache);
-        None for a dense model."""
-        if "moe_counts" not in new_caches[0]:
-            return None
-        return jnp.stack([c["moe_counts"] for c in new_caches])
-
     def _decode_impl(self, params, pages, last_tokens, context_lens,
                      block_tables, active, temps, top_ks, top_ps,
                      ban_a, ban_b, keys):
-        # decode-only config override routes the paged branch to the
-        # resolved attention path (prefill chunks carry their own
-        # override — see _prefill_impl)
-        cfg = self._decode_cfg
         tokens = last_tokens[:, None]                       # [S, 1]
         positions = context_lens[:, None]                   # [S, 1]
-        caches = self._layer_caches(pages, block_tables, context_lens,
-                                    active)
+        caches = paged_kv.step_caches(pages, block_tables, context_lens,
+                                      active, self.paged_kernel)
         logits, new_caches = language_model_forward(
-            params, tokens, positions, None, cfg,
+            params, tokens, positions, None, self.model.cfg,
             rng_key=None, train=False, kv_caches=caches)
         logits = logits[:, 0, :].astype(jnp.float32)        # [S, V]
         # non-finite sentinel: per-slot health of the raw model logits,
@@ -516,8 +447,8 @@ class InferenceEngine:
         sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [S, 2, 2]
         next_tokens = sample_batched(logits, sub[:, 0], top_ks, top_ps,
                                      temps)
-        return (next_tokens, self._strip_pages(new_caches), sub[:, 1], finite,
-                self._moe_counts(new_caches))
+        return (next_tokens, paged_kv.pools_of(new_caches), sub[:, 1],
+                finite, paged_kv.routing_of(new_caches))
 
     def _verify_impl(self, params, pages, tokens, context_lens,
                      block_tables, vlens, temps, top_ks, top_ps,
@@ -535,13 +466,12 @@ class InferenceEngine:
         only exact-greedy slots draft, and argmax of row j is exact
         whenever drafts 1..j all matched (the host accept rule commits
         no further)."""
-        cfg = self._verify_cfg
         K1 = tokens.shape[1]
         positions = context_lens[:, None] + jnp.arange(K1)[None, :]
-        caches = self._layer_caches(pages, block_tables, context_lens,
-                                    vlens)
+        caches = paged_kv.step_caches(pages, block_tables, context_lens,
+                                      vlens, self.prefill_kernel)
         logits, new_caches = language_model_forward(
-            params, tokens, positions, None, cfg,
+            params, tokens, positions, None, self.model.cfg,
             rng_key=None, train=False, kv_caches=caches)
         logits = logits.astype(jnp.float32)             # [S, K+1, V]
         # per-slot sentinel over the VALID rows only — padded rows
@@ -561,62 +491,23 @@ class InferenceEngine:
                                top_ps, temps)
         emit = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         emit = emit.at[:, 0].set(first.astype(jnp.int32))
-        return (emit, self._strip_pages(new_caches), sub[:, 1], finite,
-                self._moe_counts(new_caches))
+        return (emit, paged_kv.pools_of(new_caches), sub[:, 1], finite,
+                paged_kv.routing_of(new_caches))
 
     def _prefill_impl(self, params, pages, tokens, start_pos, valid_len,
                       block_table):
-        # prefill-only config override routes the [1, C] chunk to the
-        # resolved prefill path (Pallas ragged prefill kernel or the
-        # bounded XLA gather) — static, so one compile covers every chunk
-        cfg = self._prefill_cfg
         C = tokens.shape[1]
         positions = (start_pos + jnp.arange(C))[None, :]    # [1, C]
-        caches = self._layer_caches(
+        caches = paged_kv.step_caches(
             pages, block_table, jnp.full((1,), start_pos, jnp.int32),
-            jnp.full((1,), valid_len, jnp.int32))
+            jnp.full((1,), valid_len, jnp.int32), self.prefill_kernel)
         logits, new_caches = language_model_forward(
-            params, tokens, positions, None, cfg,
+            params, tokens, positions, None, self.model.cfg,
             rng_key=None, train=False, kv_caches=caches)
         last = jax.lax.dynamic_index_in_dim(
             logits[0], valid_len - 1, axis=0, keepdims=False)
-        return (last.astype(jnp.float32), self._strip_pages(new_caches),
-                self._moe_counts(new_caches))
-
-    def _cow_copy_impl(self, pages, src, dst):
-        # duplicate physical page src into dst across every layer's pool
-        # arrays (k/v, or the int8 quant+scale pairs).  src/dst are traced
-        # int32 scalars so one compile covers all copy-on-write events.
-        out = []
-        for p in pages:
-            q = {}
-            for k, v in p.items():
-                page = jax.lax.dynamic_index_in_dim(v, src, axis=0,
-                                                    keepdims=False)
-                q[k] = jax.lax.dynamic_update_index_in_dim(v, page, dst,
-                                                           axis=0)
-            out.append(q)
-        return out
-
-    def _fetch_block_impl(self, pages, src):
-        # whole physical page src across every layer's pool arrays, as a
-        # [per-layer dict] pytree — the spill thread device_gets this to
-        # host RAM.  src is a traced int32 scalar: one compile (at
-        # warmup) covers every spill.
-        return [{k: jax.lax.dynamic_index_in_dim(v, src, axis=0,
-                                                 keepdims=False)
-                 for k, v in p.items()} for p in pages]
-
-    def _host_load_impl(self, pages, host_block, dst):
-        # scatter one host page pytree (the _fetch_block_impl layout)
-        # into physical page dst — the swap-in path.  dst is a traced
-        # int32 scalar, host_block arrays are traced inputs of fixed
-        # per-layer shapes: one compile covers every swap-in.
-        out = []
-        for p, h in zip(pages, host_block):
-            out.append({k: jax.lax.dynamic_update_index_in_dim(
-                v, h[k], dst, axis=0) for k, v in p.items()})
-        return out
+        return (last.astype(jnp.float32), paged_kv.pools_of(new_caches),
+                paged_kv.routing_of(new_caches))
 
     def _spill_fetch(self, manager, block: int):
         """host_cache spill-thread callback: device→host copy of one

@@ -2,7 +2,7 @@
 
 The engine owns ONE fixed-shape pool of KV pages per layer
 (``[num_blocks, block_size, groups, head_dim]``, allocated by
-``text_generation.generation.init_paged_kv_caches``).  This module is the
+``ops.paged_kv.init_pools``).  This module is the
 host-side bookkeeping over that pool: which *slot* (batch row of the
 jitted decode step) is live, which pool blocks each slot owns, and the
 ``[num_slots, max_blocks_per_slot]`` block-table array the paged

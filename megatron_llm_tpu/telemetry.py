@@ -7,16 +7,16 @@ telemetry in a structured stream, in-situ profiler capture, and a flight
 recorder consulted on failure.  The reference Megatron-LM computes a
 throughput estimate inside ``training_log`` (arXiv:2104.04473;
 training.py:591-609) but has no machine-readable stream and no profiler
-integration; ``bench.py`` here measures MFU out-of-band only.  This
-module puts that layer *in* the training loop:
+integration.  This module puts that layer *in* the training loop (the
+benchmark under ``benchmarks/`` measures from outside, with its own
+peak table):
 
 * **ThroughputCalculator** — tokens/sec, tokens/sec/device, achieved
   TFLOPs/device and MFU from the model-level ``flops_per_token()`` and
-  the per-chip peak-FLOPs table (shared with ``bench.py`` — one source
-  of truth).  MFU carries the same > ``MFU_SANITY_LIMIT`` fabrication
-  guard the bench uses: a physically impossible number means the timing
-  failed to sync with the device, and is reported as null, never as a
-  value.
+  the per-chip peak-FLOPs table ``PEAK_FLOPS``.  MFU carries the
+  > ``MFU_SANITY_LIMIT`` fabrication guard: a physically impossible
+  number means the timing failed to sync with the device, and is
+  reported as null, never as a value.
 
 * **TelemetryStream** (``--structured_log_dir``) — one JSONL record per
   log boundary: iteration, losses, grad_norm, lr, step time, throughput
@@ -33,8 +33,8 @@ module puts that layer *in* the training loop:
 
 * **ProfilerSession** (``--profile --profile_step_start N
   --profile_step_end M --profile_dir D``) — wraps the chosen step window
-  in ``jax.profiler`` trace capture during real training (subsuming
-  ``tools/profile_step.py``'s one-shot flow); ``--profiler_port`` starts
+  in ``jax.profiler`` trace capture during real training;
+  ``--profiler_port`` starts
   ``jax.profiler.start_server`` for live TensorBoard capture.
   ``jax.named_scope`` annotations on the embedding / transformer layers
   / pipeline stages make the resulting xplane legible.
@@ -67,7 +67,10 @@ from megatron_llm_tpu.global_vars import get_counters
 # bf16 peak per chip (Google Cloud TPU documentation, the page of each
 # generation), keyed by device_kind substrings; spellings vary across
 # libtpu versions (v5e reports "TPU v5 lite" or "TPU v5e").
-# Single source of truth — bench.py and tools/mfu_sweep.py read it.
+# This is the PROGRAM's table: the training log's MFU and its > 0.95
+# guard read it.  The benchmark keeps its own
+# (benchmarks/harness/peaks.json), so that no PR to the program can move
+# a number the benchmark reports.
 PEAK_FLOPS = {
     "TPU v5 lite": 197e12,
     "TPU v5e": 197e12,
@@ -78,9 +81,8 @@ PEAK_FLOPS = {
 }
 
 # MFU above this is physically impossible — the timing loop failed to
-# sync with the device (bench.py round-3 caught a 1380-MFU "measurement"
-# this way).  Shared by bench.py (which aborts) and the runtime stream
-# (which reports null).
+# sync with the device (a 1380-MFU "measurement" was caught this way).
+# The runtime stream reports null instead.
 MFU_SANITY_LIMIT = 0.95
 
 

@@ -128,8 +128,8 @@ def generate(
     ``cache_len``: minimum KV-cache allocation (slots); decode masks
     the unused tail, outputs are identical
     (tests/test_generation.py::test_cache_len_padding_is_invisible).
-    Decouples per-step attention cost from max_new_tokens (used by
-    tools/decode_bench.py).  Does not by itself avoid recompiles —
+    Decouples per-step attention cost from max_new_tokens.  Does not
+    by itself avoid recompiles —
     the jit keys on prompt shape and tokens_to_generate.
 
     ``batch_times_seqlen_threshold``: micro-batch the prefill forward

@@ -77,42 +77,6 @@ def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
     ]
 
 
-def init_paged_kv_caches(cfg: TransformerConfig, num_blocks: int,
-                         block_size: int, dtype=None,
-                         quantized: bool = False):
-    """Per-layer PAGED decode pools for the serving engine
-    (serving/kv_blocks.py): ``[num_blocks, block_size, groups, head_dim]``
-    K/V pages shared by every active request, addressed through per-slot
-    block tables (models/transformer.py paged branch).  Block 0 is the
-    reserved garbage block — padded chunk tokens and inactive slots write
-    there.  Same dtype handling as ``init_kv_caches``: compute dtype for
-    the plain pools, int8 + per-(block, position, group) fp32 absmax
-    scales when ``quantized`` (halves decode KV HBM traffic vs bf16)."""
-    dtype = dtype or cfg.compute_jnp_dtype
-    ng, d = cfg.num_query_groups, cfg.head_dim
-    if quantized:
-        return [
-            {
-                "k_pages_q": jnp.zeros((num_blocks, block_size, ng, d),
-                                       jnp.int8),
-                "k_pages_scale": jnp.ones((num_blocks, block_size, ng),
-                                          jnp.float32),
-                "v_pages_q": jnp.zeros((num_blocks, block_size, ng, d),
-                                       jnp.int8),
-                "v_pages_scale": jnp.ones((num_blocks, block_size, ng),
-                                          jnp.float32),
-            }
-            for _ in range(cfg.num_layers)
-        ]
-    return [
-        {
-            "k_pages": jnp.zeros((num_blocks, block_size, ng, d), dtype),
-            "v_pages": jnp.zeros((num_blocks, block_size, ng, d), dtype),
-        }
-        for _ in range(cfg.num_layers)
-    ]
-
-
 def _forward_with_cache(model, params, tokens, caches, start_pos):
     """Run the model over ``tokens`` [b, n] writing KV at ``start_pos``;
     returns (logits [b, n, V], new caches)."""
@@ -181,8 +145,8 @@ def generate_tokens(
     (>= prompt + max_new_tokens).  Decode masks cache positions beyond
     the current index, so results are identical; per-step attention
     cost then depends on the allocation, not on max_new_tokens — which
-    is what lets benchmarks difference two generation lengths at equal
-    per-step cost (tools/decode_bench.py).  NOTE this alone does NOT
+    is what lets a measurement difference two generation lengths at
+    equal per-step cost.  NOTE this alone does NOT
     make compiles reusable across request shapes: the jit still keys
     on the prompt array shape and the static max_new_tokens — a server
     wanting few compiles must pad prompts to bucket widths and fix

@@ -20,8 +20,8 @@ layer — host-side only, nothing enters the jitted step:
   span closes (outermost goodput-category span wins, so nesting never
   double-counts).  ``goodput_pct`` = productive step seconds over total
   wall seconds — MegaScale's headline reliability metric — and surfaces
-  in the JSONL stream, ``run_summary()``, the wandb/TB finish summary,
-  and ``bench.py``'s BENCH json.
+  in the JSONL stream, ``run_summary()`` and the wandb/TB finish
+  summary.
 
 * **RecompileDetector** — a ``jax.monitoring`` duration-event listener
   on ``/jax/core/compile/backend_compile_duration``: every XLA compile

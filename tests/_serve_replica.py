@@ -98,7 +98,7 @@ def main():
         tracing.start_trace_flusher(bundle, interval_secs=0.5)
     if args.paged_kernel == "on" or args.prefill_kernel == "on":
         # no TPU in the test environment: run the Pallas kernels in
-        # interpret mode so *_kernel_available() is true on CPU
+        # interpret mode so kernel_available() is true on CPU
         from megatron_llm_tpu.ops.pallas import paged_attention
         paged_attention._INTERPRET = True
     cfg = llama_config("tiny", num_layers=2, seq_length=64,

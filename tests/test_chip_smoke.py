@@ -105,7 +105,7 @@ def test_compile_cache_dir_from_outside_is_left_alone(cache_config,
 def test_compile_cache_dir_is_set_in_one_place():
     hits = subprocess.run(
         ["grep", "-rln", "--include=*.py", "jax_compilation_cache_dir",
-         "megatron_llm_tpu", "tools", "tasks", "bench.py", "chip_smoke.py",
+         "megatron_llm_tpu", "tools", "tasks", "chip_smoke.py",
          "finetune.py", "__graft_entry__.py"],
         cwd=ROOT, capture_output=True, text=True).stdout.split()
     assert hits == ["megatron_llm_tpu/initialize.py"]

@@ -417,8 +417,7 @@ def test_microbatched_prefill_matches_monolithic(model_and_params):
 def test_cache_len_padding_is_invisible(model_and_params):
     """A padded KV cache (cache_len > prompt+max_new) is masked out:
     tokens, lengths, and log-probs match the exact-size cache bit for
-    bit (the knob behind tools/decode_bench.py's equal-cost
-    differencing)."""
+    bit."""
     from megatron_llm_tpu.text_generation.generation import generate_tokens
     model, params = model_and_params
     toks = jnp.array([[3, 5, 7, 9], [2, 4, 0, 0]], jnp.int32)

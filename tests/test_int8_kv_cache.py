@@ -105,13 +105,10 @@ def test_paged_int8_sliding_window_drift_bounded(model_and_params):
     dequant compose."""
     from megatron_llm_tpu.models.language_model import language_model_forward
     from megatron_llm_tpu.models.llama import LlamaModel
-    from megatron_llm_tpu.text_generation.generation import (
-        init_paged_kv_caches,
-    )
+    from megatron_llm_tpu.ops import paged_kv
 
     model, params = model_and_params
-    wcfg = model.cfg.replace(sliding_window_size=8,
-                             paged_attention_kernel="off")
+    wcfg = model.cfg.replace(sliding_window_size=8)
     toks = jnp.asarray([[3, 5, 7, 9, 11, 13, 2, 4, 6, 8, 10, 12]],
                        jnp.int32)                  # 12 tokens > window 8
     nxt = jnp.asarray([[2]], jnp.int32)
@@ -125,22 +122,19 @@ def test_paged_int8_sliding_window_drift_bounded(model_and_params):
     # int8 paged pools: prefill then one decode step through the paged
     # branch (block table covers 13 tokens at block_size 8 -> 2 pages)
     bs, M = 8, 2
-    pages = init_paged_kv_caches(wcfg, 1 + M, bs, quantized=True)
+    pages = paged_kv.init_pools(wcfg, 1 + M, bs, quantized=True)
     bt = jnp.asarray(np.arange(1, M + 1)[None, :], jnp.int32)
-    caches = [dict(p, block_tables=bt,
-                   context_lens=jnp.zeros((1,), jnp.int32),
-                   valid_lens=jnp.asarray([toks.shape[1]], jnp.int32))
-              for p in pages]
+    caches = paged_kv.step_caches(
+        pages, bt, jnp.zeros((1,), jnp.int32),
+        jnp.asarray([toks.shape[1]], jnp.int32), "xla")
     positions = jnp.arange(toks.shape[1])[None, :]
     _, caches = language_model_forward(params, toks, positions, None,
                                        wcfg, rng_key=None, train=False,
                                        kv_caches=caches)
-    pages2 = [{k: v for k, v in c.items() if "pages" in k}
-              for c in caches]
-    caches = [dict(p, block_tables=bt,
-                   context_lens=jnp.asarray([toks.shape[1]], jnp.int32),
-                   valid_lens=jnp.ones((1,), jnp.int32))
-              for p in pages2]
+    caches = paged_kv.step_caches(
+        paged_kv.pools_of(caches), bt,
+        jnp.asarray([toks.shape[1]], jnp.int32),
+        jnp.ones((1,), jnp.int32), "xla")
     logits_q, _ = language_model_forward(
         params, nxt, jnp.asarray([[toks.shape[1]]], jnp.int32), None,
         wcfg, rng_key=None, train=False, kv_caches=caches)

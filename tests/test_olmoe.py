@@ -20,7 +20,7 @@ from megatron_llm_tpu.models import transformer as tfm
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.models.mixtral import MixtralModel, mixtral_config
 from megatron_llm_tpu.models.olmoe import OlmoeModel, olmoe_config
-from megatron_llm_tpu.text_generation.generation import init_paged_kv_caches
+from megatron_llm_tpu.ops import paged_kv
 
 REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "reference")
@@ -114,18 +114,15 @@ def _paged_step(model, params, pages, toks, start, valid, bt):
     [S, n], each with ``valid[s]`` real tokens appended at ``start[s]``.
     Returns (logits, the pages, each layer's histogram of assignments)."""
     S, n = toks.shape
-    caches = [dict(p, block_tables=bt,
-                   context_lens=jnp.asarray(start, jnp.int32),
-                   valid_lens=jnp.asarray(valid, jnp.int32)) for p in pages]
+    caches = paged_kv.step_caches(
+        pages, bt, jnp.asarray(start, jnp.int32),
+        jnp.asarray(valid, jnp.int32), "xla")
     positions = jnp.asarray(start, jnp.int32)[:, None] + jnp.arange(n)[None]
-    cfg = model.cfg.replace(paged_attention_kernel="off",
-                            paged_prefill_kernel="off")
     logits, caches = language_model_forward(
-        params, jnp.asarray(toks, jnp.int32), positions, None, cfg,
+        params, jnp.asarray(toks, jnp.int32), positions, None, model.cfg,
         rng_key=None, train=False, kv_caches=caches)
-    counts = np.stack([np.asarray(c["moe_counts"]) for c in caches])
-    pages = [{k: v for k, v in c.items() if "pages" in k} for c in caches]
-    return np.asarray(logits), pages, counts
+    return (np.asarray(logits), paged_kv.pools_of(caches),
+            np.asarray(paged_kv.routing_of(caches)))
 
 
 def test_chunked_prefill_then_decode_matches_one_full_forward(family):
@@ -138,7 +135,7 @@ def test_chunked_prefill_then_decode_matches_one_full_forward(family):
     k, E, L = model.cfg.moe_top_k, model.cfg.num_experts, model.cfg.num_layers
     toks = _tokens(40, seed=5)
     want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    pages = init_paged_kv_caches(model.cfg, 1 + 2 * M, BS)
+    pages = paged_kv.init_pools(model.cfg, 1 + 2 * M, BS)
     bt = jnp.asarray(np.arange(1, 1 + 2 * M).reshape(2, M), jnp.int32)
     got = []
     for start in range(0, 37, CHUNK):
@@ -364,3 +361,97 @@ def test_engine_counts_live_assignments_only():
         assert stats[f] == sum(getattr(r, f) for r in records) > 0
     # a dense model routes nothing
     assert all(getattr(type(records[0]), f) == 0 for f in MOE_FIELDS)
+
+
+def _reference_routing(ref, weights, cfg, tokens):
+    """[positions, layers, E] bool: the experts the REFERENCE's router
+    chooses at every position of ``tokens``, layer by layer, from its own
+    gate logits (``forward_logits``'s loop, kept to the routing)."""
+    x = weights.embedding()[jnp.asarray(tokens, jnp.int32)].astype(
+        jnp.float32)
+    eps, chosen = float(cfg["rms_norm_eps"]), []
+    for i in range(int(cfg["num_hidden_layers"])):
+        w = weights.layer(i)
+        x = ref.attention_block(
+            x, w, n_heads=int(cfg["num_attention_heads"]),
+            n_kv=int(cfg["num_key_value_heads"]),
+            theta=float(cfg["rope_theta"]), eps=eps)
+        hn, dense, _ = ref.moe_gates(
+            x, w["ffn_norm"], w["gate"], jnp.zeros(x.shape[0], bool),
+            eps=eps, top_k=int(cfg["num_experts_per_tok"]))
+        chosen.append(np.asarray(dense) > 0)
+        for e in range(int(cfg["num_local_experts"])):
+            ew = weights.expert(i, e)
+            x = x + ref.expert_out(hn, dense[:, e], ew["w1"], ew["w2"],
+                                   ew["w3"])
+    return np.stack(chosen, axis=1)
+
+
+@pytest.mark.parametrize("speculative", [False, True])
+def test_engine_histogram_is_the_routers_own(speculative):
+    """The ``[layers, E]`` histogram every engine program returns (through
+    the cache's declared field) against the reference's router on the same
+    step's tokens: prefill chunks, decode steps and, with speculation on,
+    verify steps whose rejected drafts are routed and counted too."""
+    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
+                                          SamplingParams)
+
+    model = OlmoeModel(olmoe_config("tiny", use_flash_attn=False))
+    params = model.init(jax.random.PRNGKey(0))
+    cfg = _ref_cfg(model.cfg)
+    ref = _load("olmoe")
+    weights = _load("olmoe_from_program").ProgramWeights(params, cfg)
+    eng = InferenceEngine(model, params, EngineConfig(
+        num_slots=2, block_size=16, max_model_len=64, prefill_chunk=16,
+        prefix_cache=False, speculative=speculative, draft_k=2))
+    launches = []
+
+    def recording(name, toks_of):
+        program = getattr(eng, name)
+
+        def run(*args):
+            out = program(*args)
+            launches.append((name, toks_of(*args), np.asarray(out[-1])))
+            return out
+        setattr(eng, name, run)
+
+    # (row 0's context length, its real tokens of this launch)
+    recording("_prefill_step", lambda p, pg, toks, start, valid, bt:
+              (int(start), toks[0, :int(valid)].tolist()))
+    recording("_decode_step", lambda p, pg, last, ctx, bt, active, *r:
+              (int(ctx[0]), [int(last[0])]))
+    recording("_verify_step", lambda p, pg, toks, ctx, bt, vlens, *r:
+              (int(ctx[0]), toks[0, :int(vlens[0])].tolist()))
+    prompt = _tokens(21, seed=9)
+    if speculative:
+        # a prompt whose decode steps draft: the model's own greedy
+        # continuation as far as the first token that closes a bigram the
+        # history has seen (greedy decoding repeats itself)
+        from megatron_llm_tpu.serving.drafter import lookup_draft
+        first = eng.submit(prompt, SamplingParams(max_new_tokens=32,
+                                                  temperature=0.0))
+        while first.finish_reason is None:
+            assert eng.step()
+        out = first.out_tokens
+        n = next(n for n in range(1, len(out) + 1)
+                 if lookup_draft(prompt + out[:n], 1))
+        prompt = prompt + out[:n - 1]
+        launches.clear()
+    req = eng.submit(prompt, SamplingParams(max_new_tokens=6,
+                                            temperature=0.0))
+    while req.finish_reason is None:
+        assert eng.step()
+    committed = req.context_tokens()
+    kinds = [name for name, _, _ in launches]
+    chunks = -(-len(prompt) // 16)
+    assert kinds[:chunks] == ["_prefill_step"] * chunks
+    assert set(kinds[chunks:]) == {"_verify_step" if speculative
+                                   else "_decode_step"}
+    for name, (ctx, toks), got in launches:
+        chosen = _reference_routing(ref, weights, cfg,
+                                    committed[:ctx] + toks)
+        want = chosen[ctx:].sum(axis=0)                    # [layers, E]
+        assert got.tolist() == want.tolist(), (name, ctx, toks)
+    if speculative:
+        assert max(len(t) for n, (_, t), _ in launches
+                   if n == "_verify_step") > 1

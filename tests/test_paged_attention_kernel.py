@@ -11,6 +11,7 @@ ragged edges: chunks straddling page and block boundaries, context 0,
 cached-prefix tail chunks starting mid-page, windows shorter than the
 chunk, and multi-q-block grids."""
 
+import dataclasses
 import math
 
 import jax
@@ -20,9 +21,9 @@ import pytest
 
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.models.llama import LlamaModel, llama_config
+from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.ops.pallas import paged_attention as pa
 from megatron_llm_tpu.quantization import absmax_quantize_int8
-from megatron_llm_tpu.text_generation.generation import init_paged_kv_caches
 
 
 @pytest.fixture(autouse=True)
@@ -31,6 +32,14 @@ def _interpret_mode():
     pa._INTERPRET = True
     yield
     pa._INTERPRET = old
+
+
+def _dense(q, kp, vp, bt, lens, ks, vs, scale, window):
+    """The dense reference, every row real; q [S, nh, d] is decode."""
+    q4 = q if q.ndim == 4 else q[:, None]
+    out = pa.dense_paged_attention(q4, kp, vp, bt, lens, None, ks, vs,
+                                   scale, window)
+    return out if q.ndim == 4 else out[:, 0]
 
 
 def _build_case(rng, S, M, bs, g, nh, d, lens):
@@ -93,7 +102,7 @@ def test_kernel_matches_oracle_and_reference(g, nh, window):
     got = np.asarray(pa.paged_attention_decode(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(bt), jnp.asarray(LENS), sliding_window=window))
-    ref = np.asarray(pa._reference_paged_attention(
+    ref = np.asarray(_dense(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(bt), jnp.asarray(LENS), None, None, scale, window))
     want = _oracle(q, k_lin, v_lin, LENS, scale, window)
@@ -116,7 +125,7 @@ def test_kernel_int8_dequant(window):
     got = np.asarray(pa.paged_attention_decode(
         jnp.asarray(q), kq, vq, jnp.asarray(bt), jnp.asarray(LENS),
         k_scales=ks, v_scales=vs, sliding_window=window))
-    ref = np.asarray(pa._reference_paged_attention(
+    ref = np.asarray(_dense(
         jnp.asarray(q), kq, vq, jnp.asarray(bt), jnp.asarray(LENS),
         ks, vs, scale, window))
     np.testing.assert_allclose(got, ref, atol=2e-5, rtol=2e-5)
@@ -247,7 +256,7 @@ def test_decode_int8_long_table(window, two_page_blocks):
     vq, vs = absmax_quantize_int8(jnp.asarray(vp), axis=-1)
     got = _decode(q, kq, vq, bt, lens, k_scales=ks, v_scales=vs,
                   sliding_window=window)
-    ref = np.asarray(pa._reference_paged_attention(
+    ref = np.asarray(_dense(
         jnp.asarray(q), kq, vq, jnp.asarray(bt), jnp.asarray(lens),
         ks, vs, 1.0 / math.sqrt(D), window))
     np.testing.assert_allclose(got, ref, atol=2e-5, rtol=2e-5)
@@ -283,13 +292,11 @@ def test_decode_at_the_cells_shapes(dtype, block_tokens):
 
 
 def test_availability_tracks_backend(monkeypatch):
-    assert pa.decode_kernel_available()   # interpret fixture is on
-    assert pa.prefill_kernel_available()
+    assert pa.kernel_available()          # interpret fixture is on
     monkeypatch.setattr(pa, "_INTERPRET", False)
     monkeypatch.delenv("MLT_FORCE_PALLAS", raising=False)
     if jax.default_backend() != "tpu":
-        assert not pa.decode_kernel_available()
-        assert not pa.prefill_kernel_available()
+        assert not pa.kernel_available()
 
 
 # ---------------------------------------------------------------------------
@@ -375,7 +382,7 @@ def test_prefill_kernel_matches_oracle_and_reference(g, nh, window,
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(bt), jnp.asarray(CTX), sliding_window=window,
         block_q=block_q))
-    ref = np.asarray(pa._reference_paged_prefill(
+    ref = np.asarray(_dense(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(bt), jnp.asarray(CTX), None, None, scale, window))
     want = _prefill_oracle(q, k_lin, v_lin, CTX, scale, window)
@@ -395,7 +402,7 @@ def test_prefill_kernel_int8_dequant(window):
     got = np.asarray(pa.paged_attention_prefill(
         jnp.asarray(q), kq, vq, jnp.asarray(bt), jnp.asarray(CTX),
         k_scales=ks, v_scales=vs, sliding_window=window, block_q=8))
-    ref = np.asarray(pa._reference_paged_prefill(
+    ref = np.asarray(_dense(
         jnp.asarray(q), kq, vq, jnp.asarray(bt), jnp.asarray(CTX),
         ks, vs, scale, window))
     np.testing.assert_allclose(got, ref, atol=2e-5, rtol=2e-5)
@@ -488,21 +495,20 @@ def model_and_params():
     return model, params
 
 
-def _prefilled_pages(model, params, cfg_off, bt, lens, quantized):
-    """XLA-branch prefill (multi-token calls never take the kernel)
-    filling the shared pools through the block tables."""
+def _prefilled_pages(model, params, bt, lens, quantized):
+    """A prefill on the dense path filling the shared pools through the
+    block tables."""
     Sl, C = bt.shape[0], 16
-    pages = init_paged_kv_caches(model.cfg, 1 + int(bt.max()), BS,
-                                 quantized=quantized)
+    pages = paged_kv.init_pools(model.cfg, 1 + int(bt.max()), BS,
+                                quantized=quantized)
     toks = jnp.asarray(np.arange(Sl * C).reshape(Sl, C) % 60 + 1, jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(C)[None, :], (Sl, C))
-    caches = [dict(p, block_tables=bt,
-                   context_lens=jnp.zeros((Sl,), jnp.int32),
-                   valid_lens=lens) for p in pages]
+    caches = paged_kv.step_caches(pages, bt, jnp.zeros((Sl,), jnp.int32),
+                                  lens, "xla")
     _, caches = language_model_forward(params, toks, positions, None,
-                                       cfg_off, rng_key=None, train=False,
+                                       model.cfg, rng_key=None, train=False,
                                        kv_caches=caches)
-    return [{k: v for k, v in c.items() if "pages" in k} for c in caches]
+    return paged_kv.pools_of(caches)
 
 
 @pytest.mark.parametrize("quantized", [False, True])
@@ -511,21 +517,18 @@ def test_transformer_paged_kernel_parity(model_and_params, quantized):
     forced on (interpret) produces the same logits as the XLA gather
     branch, on plain and int8 pools."""
     model, params = model_and_params
-    cfg_off = model.cfg.replace(paged_attention_kernel="off")
-    cfg_on = model.cfg.replace(paged_attention_kernel="on")
     Sl = 2
     bt = jnp.asarray(
         np.arange(1, 1 + Sl * M).reshape(Sl, M), jnp.int32)
     lens = jnp.asarray([5, 9], jnp.int32)
-    pages = _prefilled_pages(model, params, cfg_off, bt, lens, quantized)
+    pages = _prefilled_pages(model, params, bt, lens, quantized)
     nxt = jnp.asarray([[7], [11]], jnp.int32)
     outs = []
-    for cfg in (cfg_off, cfg_on):
-        caches = [dict(p, block_tables=bt, context_lens=lens,
-                       valid_lens=jnp.ones((Sl,), jnp.int32))
-                  for p in pages]
+    for kernel in ("xla", "pallas"):
+        caches = paged_kv.step_caches(pages, bt, lens,
+                                      jnp.ones((Sl,), jnp.int32), kernel)
         logits, _ = language_model_forward(params, nxt, lens[:, None],
-                                           None, cfg, rng_key=None,
+                                           None, model.cfg, rng_key=None,
                                            train=False, kv_caches=caches)
         outs.append(np.asarray(logits[:, 0], np.float32))
     np.testing.assert_allclose(outs[1], outs[0], atol=1e-4, rtol=1e-4)
@@ -540,10 +543,7 @@ def test_transformer_prefill_kernel_parity(model_and_params, quantized):
     Padded tail rows (j >= valid_lens) are garbage in both paths and
     excluded."""
     model, params = model_and_params
-    cfg_off = model.cfg.replace(paged_attention_kernel="off",
-                                paged_prefill_kernel="off")
-    cfg_on = model.cfg.replace(paged_attention_kernel="off",
-                               paged_prefill_kernel="on")
+    cfg = model.cfg
     Sl, Cc = 2, 16
     bt = jnp.asarray(np.arange(1, 1 + Sl * M).reshape(Sl, M), jnp.int32)
     v0 = jnp.asarray([5, 16], jnp.int32)     # ragged first chunk
@@ -553,18 +553,17 @@ def test_transformer_prefill_kernel_parity(model_and_params, quantized):
     toks1 = jnp.asarray((np.arange(Sl * Cc).reshape(Sl, Cc) * 3) % 60 + 1,
                         jnp.int32)
     outs = []
-    for cfg in (cfg_off, cfg_on):
-        pages = init_paged_kv_caches(model.cfg, 1 + int(bt.max()), BS,
-                                     quantized=quantized)
-        caches = [dict(p, block_tables=bt,
-                       context_lens=jnp.zeros((Sl,), jnp.int32),
-                       valid_lens=v0) for p in pages]
+    for kernel in ("xla", "pallas"):
+        pages = paged_kv.init_pools(cfg, 1 + int(bt.max()), BS,
+                                    quantized=quantized)
+        caches = paged_kv.step_caches(
+            pages, bt, jnp.zeros((Sl,), jnp.int32), v0, kernel)
         pos0 = jnp.broadcast_to(jnp.arange(Cc)[None, :], (Sl, Cc))
         lg0, caches = language_model_forward(params, toks0, pos0, None,
                                              cfg, rng_key=None,
                                              train=False,
                                              kv_caches=caches)
-        caches = [dict(c, valid_lens=v1) for c in caches]
+        caches = [dataclasses.replace(c, valid_lens=v1) for c in caches]
         pos1 = v0[:, None] + jnp.arange(Cc)[None, :]
         lg1, _ = language_model_forward(params, toks1, pos1, None, cfg,
                                         rng_key=None, train=False,

@@ -1,0 +1,132 @@
+"""``ops/paged_kv.py`` owns the paged KV cache: which kernel reads it is
+resolved in one function, the engine's programs are static in the
+result through the cache they hand the model, and no other module knows
+the pool's layout."""
+
+import os
+import re
+import subprocess
+
+import jax
+import numpy as np
+import pytest
+
+from megatron_llm_tpu.models.llama import LlamaModel, llama_config
+from megatron_llm_tpu.ops import paged_kv
+from megatron_llm_tpu.ops.pallas import paged_attention as pa
+from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# what --serve_paged_kernel / --serve_prefill_kernel have always meant:
+# (requested, kernel available, programs on one device) -> path
+RESOLUTION = [
+    ("auto", True, True, "pallas"),
+    ("auto", True, False, "xla"),      # several devices: GSPMD cannot
+    ("auto", False, True, "xla"),      # partition a Mosaic call
+    ("on", True, True, "pallas"),
+    ("on", True, False, "pallas"),     # 'on' insists
+    ("on", False, True, "xla"),        # nothing to insist on
+    ("off", True, True, "xla"),
+    ("off", True, False, "xla"),
+]
+
+
+@pytest.mark.parametrize("requested,available,one_device,want", RESOLUTION)
+def test_resolve_kernel(monkeypatch, requested, available, one_device, want):
+    monkeypatch.setattr(pa, "_INTERPRET", available)
+    monkeypatch.delenv("MLT_FORCE_PALLAS", raising=False)
+    assert paged_kv.resolve_kernel(requested, one_device) == want
+
+
+def test_resolve_kernel_refuses_another_word():
+    with pytest.raises(ValueError, match="auto|on|off"):
+        paged_kv.resolve_kernel("maybe", True)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = llama_config("tiny", num_layers=2, seq_length=64,
+                       max_position_embeddings=64, padded_vocab_size=64,
+                       use_flash_attn=False)
+    model = LlamaModel(cfg)
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _engine(tiny, monkeypatch, **kw):
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    model, params = tiny
+    return InferenceEngine(model, params, EngineConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16,
+        speculative=True, draft_k=2, **kw))
+
+
+@pytest.mark.parametrize("decode,prefill", [("on", "off"), ("off", "on")])
+def test_the_engine_resolves_each_program_once(tiny, monkeypatch, decode,
+                                               prefill):
+    """The two flags resolve independently, the decode program takes the
+    first, the prefill and verify programs the second, and each traced
+    program holds a ``pallas_call`` exactly when its path says so: the
+    path reaches the model as static data of the cache, through no
+    config field."""
+    eng = _engine(tiny, monkeypatch, paged_kernel=decode,
+                  prefill_kernel=prefill)
+    want = {"on": "pallas", "off": "xla"}
+    assert (eng.paged_kernel, eng.prefill_kernel) == (want[decode],
+                                                      want[prefill])
+    stats = eng.stats()
+    assert (stats["paged_kernel"], stats["prefill_kernel"]) == (
+        want[decode], want[prefill])
+    st, S, i32 = eng._st, 2, np.int32
+    tables = np.zeros((S, eng._max_blocks_per_slot), i32)
+    knobs = (np.ones(S, np.float32), np.zeros(S, i32),
+             np.zeros(S, np.float32), np.full(S, -1, i32),
+             np.full(S, -1, i32), np.zeros((S, 2), np.uint32))
+    jaxprs = {
+        "decode": jax.make_jaxpr(eng._decode_step)(
+            eng.params, st.pages, np.zeros(S, i32), np.zeros(S, i32),
+            tables, np.ones(S, i32), *knobs),
+        "verify": jax.make_jaxpr(eng._verify_step)(
+            eng.params, st.pages, np.zeros((S, 3), i32), np.zeros(S, i32),
+            tables, np.ones(S, i32), *knobs),
+        "prefill": jax.make_jaxpr(eng._prefill_step)(
+            eng.params, st.pages, np.zeros((1, 16), i32), i32(0), i32(16),
+            tables[:1]),
+    }
+    has_kernel = {k: "pallas_call" in str(j) for k, j in jaxprs.items()}
+    assert has_kernel == {"decode": decode == "on",
+                          "verify": prefill == "on",
+                          "prefill": prefill == "on"}
+
+
+def test_a_callers_default_is_auto(monkeypatch):
+    """A caller outside the engine that names no path gets what ``auto``
+    means on one device: the kernel where it can run."""
+    pools = [{}]
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    assert paged_kv.step_caches(pools, None, None, None)[0].kernel == "pallas"
+    monkeypatch.setattr(pa, "_INTERPRET", False)
+    monkeypatch.delenv("MLT_FORCE_PALLAS", raising=False)
+    if jax.default_backend() != "tpu":
+        assert paged_kv.step_caches(pools, None, None, None)[0].kernel == "xla"
+
+
+def _grep(pattern, *paths):
+    return subprocess.run(
+        ["grep", "-rlE", "--include=*.py", pattern, *paths],
+        cwd=ROOT, capture_output=True, text=True).stdout.split()
+
+
+def test_the_pool_has_one_owner():
+    """The pool's key names occur in one module of the package, the
+    engine takes nothing from the legacy decode stack, and the model
+    carries no kernel choice in its config."""
+    assert _grep(r"[\"'][kv]_pages", "megatron_llm_tpu") == [
+        "megatron_llm_tpu/ops/paged_kv.py"]
+    engine = open(os.path.join(
+        ROOT, "megatron_llm_tpu", "serving", "engine.py")).read()
+    assert not re.search(r"text_generation\.generation|\"pages\" in", engine)
+    from megatron_llm_tpu.config import TransformerConfig
+    assert not [f for f in TransformerConfig.__dataclass_fields__
+                if "paged" in f]

@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""AOT scale-proof for the BASELINE.md milestone configs (VERDICT r3 #3).
+"""AOT scale-proof for the milestone configs of docs/scale_aot.md
+(VERDICT r3 #3).
 
 The 16-GB single v5e cannot *run* a 7B+ training step, but JAX + libtpu
 can AOT-compile one against a **virtual TPU topology**
