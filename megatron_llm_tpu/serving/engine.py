@@ -111,7 +111,11 @@ from megatron_llm_tpu.serving.resilience import (
     ServingFaultInjector,
 )
 from megatron_llm_tpu.serving.scheduler import Scheduler
-from megatron_llm_tpu.text_generation.sampling import NEG_INF, sample_batched
+from megatron_llm_tpu.text_generation.sampling import (
+    NEG_INF,
+    rows_asking,
+    sample_batched,
+)
 
 
 @dataclass
@@ -308,6 +312,10 @@ class InferenceEngine:
 
         # counters (read by stats()/the HTTP /metrics endpoint)
         self.decode_steps = 0
+        # of those, the steps whose sampler drew (a live row not greedy)
+        # and sorted (such a row with an active top-k or top-p)
+        self.sample_draw_steps = 0
+        self.sample_sort_steps = 0
         self.prefill_chunks = 0
         self.tokens_generated = 0
         self.prefill_tokens_submitted = 0   # prompt tokens admitted
@@ -446,7 +454,7 @@ class InferenceEngine:
         logits = jnp.where(banned[:, None] & hit, NEG_INF, logits)
         sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [S, 2, 2]
         next_tokens = sample_batched(logits, sub[:, 0], top_ks, top_ps,
-                                     temps)
+                                     temps, active > 0)
         return (next_tokens, paged_kv.pools_of(new_caches), sub[:, 1],
                 finite, paged_kv.routing_of(new_caches))
 
@@ -488,7 +496,7 @@ class InferenceEngine:
         logits = jnp.where(banned[:, :, None] & hit, NEG_INF, logits)
         sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
         first = sample_batched(logits[:, 0, :], sub[:, 0], top_ks,
-                               top_ps, temps)
+                               top_ps, temps, vlens > 0)
         emit = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         emit = emit.at[:, 0].set(first.astype(jnp.int32))
         return (emit, paged_kv.pools_of(new_caches), sub[:, 1], finite,
@@ -533,7 +541,7 @@ class InferenceEngine:
         logits = jnp.where(banned & hit, NEG_INF, logits)
         sub = jax.random.split(key, 2)
         tok = sample_batched(logits, sub[0][None], top_k[None],
-                             top_p[None], temp[None])
+                             top_p[None], temp[None], jnp.ones(1, bool))
         return tok[0], sub[1], finite
 
     # ------------------------------------------------------------------
@@ -1017,15 +1025,23 @@ class InferenceEngine:
         for f in MOE_FIELDS:
             setattr(self, f, getattr(self, f) + getattr(d, f))
 
-    @staticmethod
-    def _note_batch(st: _EngineState, d: DispatchRecord, slots: List[int],
-                    decoding: List[Request]) -> None:
-        """What a decode/verify launch works on, for its record."""
+    def _note_batch(self, st: _EngineState, d: DispatchRecord,
+                    slots: List[int], decoding: List[Request]) -> None:
+        """What a decode/verify launch works on, for its record.  The
+        sampler's rows are a record of what ``sample_batched`` decides
+        from the same arrays on the device, never its input."""
         d.rows = len(slots)
         d.context_tokens = int(st.context_lens[slots].sum())
         d.requests = tuple(r.id for r in decoding)
         d.traces = tuple(sorted({r.trace_id for r in decoding
                                  if r.trace_id}))
+        _, drawn, filtered = rows_asking(
+            st.top_ks[slots], st.top_ps[slots], st.temps[slots], True,
+            self.model.cfg.padded_vocab_size)
+        d.sampler_rows_drawn = int(drawn.sum())
+        d.sampler_rows_filtered = int(filtered.sum())
+        self.sample_draw_steps += d.sampler_rows_drawn > 0
+        self.sample_sort_steps += d.sampler_rows_filtered > 0
 
     def _run_decode(self, st: _EngineState, slots: List[int],
                     d: DispatchRecord) -> None:
@@ -1389,6 +1405,8 @@ class InferenceEngine:
         dec = max(self.decode_steps, 1)
         s.update({
             "decode_steps": self.decode_steps,
+            "sample_draw_steps": self.sample_draw_steps,
+            "sample_sort_steps": self.sample_sort_steps,
             "prefill_chunks": self.prefill_chunks,
             "tokens_generated": self.tokens_generated,
             "prefill_tokens_submitted": self.prefill_tokens_submitted,

@@ -146,6 +146,11 @@ class DispatchRecord:
     moe_experts_touched = 0
     moe_expert_slots = 0
     moe_busiest_expert_assignments = 0
+    # the sampler's work in a decode/verify launch: live rows that are
+    # not greedy (any makes the step draw), and of those the rows with an
+    # active top-k or top-p (any makes the step sort)
+    sampler_rows_drawn = 0
+    sampler_rows_filtered = 0
     # the requests (and their trace ids) this launch served: the spans
     # that caused it
     requests: Tuple[int, ...] = ()
@@ -232,6 +237,8 @@ class DispatchRecord:
             "valid": self.valid, "requests": list(self.requests),
             "traces": list(self.traces),
             **{f: getattr(self, f) for f in MOE_FIELDS},
+            "sampler_rows_drawn": self.sampler_rows_drawn,
+            "sampler_rows_filtered": self.sampler_rows_filtered,
         }
 
     def note_routing(self, counts) -> None:

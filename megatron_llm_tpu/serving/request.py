@@ -47,7 +47,15 @@ class SamplingParams:
     """Per-request decode knobs.  All of these ride the jitted decode
     step as per-slot *arrays* (text_generation/sampling.py
     ``sample_batched``), so two requests with different settings co-batch
-    without recompiling."""
+    without recompiling.
+
+    What a setting costs a step, which every request decoding in that
+    step pays: a greedy request (``temperature`` 0 or ``top_k`` 1) an
+    argmax; one that samples (the default, ``temperature`` 1.0) a draw
+    over ``[slots, vocabulary]``; one that samples with ``0 < top_k <
+    vocabulary`` or ``0 < top_p < 1`` also one sort of that array, the
+    largest piece of the sampler (``stats()``: ``sample_draw_steps``,
+    ``sample_sort_steps``)."""
 
     max_new_tokens: int = 64
     temperature: float = 1.0    # 0 = greedy (argmax), like sampling.sample

@@ -590,7 +590,14 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    count; summed over launches, all 0 for a dense model), and every
 #    launch record of the loop profiler's ring and of a postmortem
 #    bundle carries the same four for that launch
-TELEMETRY_SCHEMA_VERSION = 15
+# 16: the sampler's work: engine stats() / the engine block of /metrics
+#    gain sample_draw_steps and sample_sort_steps (of decode_steps, the
+#    steps in which a live row was not greedy, and those in which such a
+#    row had an active top-k or top-p: text_generation/sampling.py
+#    ``sample_batched`` draws and sorts in those steps and no others),
+#    and every decode/verify launch record carries sampler_rows_drawn
+#    and sampler_rows_filtered (those rows, counted)
+TELEMETRY_SCHEMA_VERSION = 16
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

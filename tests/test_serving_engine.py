@@ -123,7 +123,8 @@ def test_sample_batched_greedy_rows_argmax():
     out = sample_batched(logits, keys,
                          jnp.asarray([0, 1], jnp.int32),
                          jnp.asarray([0.0, 0.0], jnp.float32),
-                         jnp.asarray([0.0, 1.0], jnp.float32))
+                         jnp.asarray([0.0, 1.0], jnp.float32),
+                         jnp.ones(2, bool))
     assert out.tolist() == [1, 0]
 
 
@@ -348,7 +349,7 @@ def test_request_done_schema_golden(engine, tmp_path):
     the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 15
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 16
     captured = []
     engine.request_done_hook = captured.append
     stream = telemetry.TelemetryStream(str(tmp_path))

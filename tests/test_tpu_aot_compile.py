@@ -8,7 +8,9 @@ much fast memory).  Every case compiles one kernel at Mistral-7B widths
 experts' grouped matmul, at OLMoE's and Mixtral's, and asserts
 that the result holds a Mosaic call (``tpu_custom_call``), i.e. that the
 kernel was taken and not its jnp reference.  Compiles, not chip runs:
-they say nothing about results or speed.
+they say nothing about results or speed.  One more program rides the
+same child: the engine's sampler at OLMoE's 64 slots x 50,304 logits,
+whose vocabulary-wide sort must be one and sit under a conditional.
 
 All cases compile in ONE child process (this file run as a script): the
 compiler library admits one process at a time and is held until that
@@ -149,6 +151,9 @@ CASES = {
 }
 
 
+SAMPLER = "sampler_64_slots_50304_logits"
+
+
 def _compile_all():
     """The child: compile every case for one described v5e device and
     print ``{case: true | false | "error"}`` (or ``{"skip": why}`` where
@@ -173,7 +178,27 @@ def _compile_all():
             found[case] = "tpu_custom_call" in text
         except Exception as e:  # noqa: BLE001 - the compiler's refusal
             found[case] = f"{type(e).__name__}: {e}"[:2000]
+    found[SAMPLER] = _sampler_sorts(chip)
     print(json.dumps(found))
+
+
+def _sampler_sorts(chip):
+    """``sample_batched`` as the TPU's compiler leaves it: for each
+    ``sort`` of the compiled text, whether a conditional guards it."""
+    from _hlo_text import sorts_and_their_guards
+
+    from megatron_llm_tpu.text_generation.sampling import sample_batched
+
+    S, V = 64, 50304
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in (((S, V), jnp.float32), ((S, 2), jnp.uint32),
+                                 ((S,), jnp.int32), ((S,), jnp.float32),
+                                 ((S,), jnp.float32), ((S,), jnp.bool_))]
+    try:
+        return sorts_and_their_guards(
+            jax.jit(sample_batched).lower(*args).compile().as_text())
+    except Exception as e:      # noqa: BLE001 - the compiler's refusal
+        return f"{type(e).__name__}: {e}"[:2000]
 
 
 @pytest.fixture(scope="module")
@@ -201,6 +226,13 @@ def test_kernel_compiles_for_v5e(case, compiled):
     assert compiled[case] is True, (
         f"{case}: compiled without its Mosaic kernel" if not compiled[case]
         else f"{case}: the TPU compiler refused it: {compiled[case]}")
+
+
+def test_sampler_compiles_to_one_guarded_sort_for_v5e(compiled):
+    """What the decode program pays in an all-greedy step is decided by
+    this: the TPU's compiler keeps the sampler's one sort inside the
+    conditional's branch (it neither hoists nor duplicates it)."""
+    assert compiled[SAMPLER] == [True], compiled[SAMPLER]
 
 
 if __name__ == "__main__":
