@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -77,6 +77,15 @@ MODEL_DEFAULTS = {
                   num_experts=64, moe_top_k=8, norm_topk_prob=0,
                   qk_norm=True, rope_theta=10000.0,
                   hidden_dropout=0.0, attention_dropout=0.0),
+    # Keye-VL-2.0's language model: 128 small experts, 8 a token with
+    # gates renormalised, per-head QK-norm, a sparse-attention indexer
+    "keye": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                 use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                 num_experts=128, moe_top_k=8, norm_topk_prob=1,
+                 qk_norm_per_head=True, kv_channels=128, rope_theta=1e7,
+                 layernorm_epsilon=1e-6, rope_sections=[16, 24, 24],
+                 dsa_index_heads=16, dsa_index_head_dim=64, dsa_topk=2048,
+                 hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,
@@ -268,11 +277,18 @@ _CKPT_ARG_MAP = {
     # vice versa) fails orbax restore on the param-tree mismatch
     "num_experts": "num_experts",
     "moe_top_k": "moe_top_k",
+    "moe_ffn_hidden_size": "moe_ffn_hidden_size",
     "moe_capacity_factor": "moe_capacity_factor",
     "moe_min_capacity": "moe_min_capacity",
     "norm_topk_prob": "norm_topk_prob",
     # olmoe's QK-norm adds two scale vectors a layer to the param tree
     "qk_norm": "qk_norm",
+    # keye's per-head QK-norm and indexer change the param tree too
+    "qk_norm_per_head": "qk_norm_per_head",
+    "dsa_index_heads": "dsa_index_heads",
+    "dsa_index_head_dim": "dsa_index_head_dim",
+    "dsa_topk": "dsa_topk",
+    "rope_sections": "rope_sections",
     # qwen2's QKV-only bias changes the param tree like the MoE fields do
     "add_qkv_bias": "add_qkv_bias",
     # gemma's embedding normalizer changes forward math, not the tree

@@ -84,6 +84,9 @@ def _add_network_size_args(parser):
     # mixture-of-experts (TPU-native extension; reference has no MoE)
     g.add_argument("--num_experts", type=int, default=0)
     g.add_argument("--moe_top_k", type=int, default=2)
+    g.add_argument("--moe_ffn_hidden_size", type=int, default=None,
+                   help="an expert's width where it is not "
+                        "--ffn_hidden_size (moe_intermediate_size)")
     g.add_argument("--moe_capacity_factor", type=float, default=1.25)
     g.add_argument("--moe_min_capacity", type=int, default=4)
     g.add_argument("--moe_aux_loss_coeff", type=float, default=1e-2)
@@ -123,6 +126,19 @@ def _add_network_size_args(parser):
     g.add_argument("--qk_norm", action="store_true",
                    help="RMSNorm on the whole query and key projections "
                         "before the rotary embedding (OLMoE)")
+    g.add_argument("--qk_norm_per_head", action="store_true",
+                   help="RMSNorm on each query and key head before the "
+                        "rotary embedding, one scale of head_dim a layer "
+                        "for each (Qwen3, Keye)")
+    g.add_argument("--dsa_index_heads", type=int, default=0,
+                   help="heads of the sparse-attention indexer; with any, "
+                        "a query attends only its --dsa_topk best keys")
+    g.add_argument("--dsa_index_head_dim", type=int, default=64)
+    g.add_argument("--dsa_topk", type=int, default=2048,
+                   help="keys a query attends under the indexer's choice")
+    g.add_argument("--rope_sections", type=int, nargs="+", default=None,
+                   help="rotary frequency pairs dealt to position streams "
+                        "(mrope_section, e.g. 16 24 24)")
     g.add_argument("--norm_topk_prob", type=int, default=1, choices=[0, 1],
                    help="renormalise the chosen experts' gates to sum to "
                         "1 (Mixtral); 0 uses the softmax over all experts "
@@ -965,6 +981,7 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         fused_ce_chunk_size=args.fused_ce_chunk_size,
         num_experts=args.num_experts,
         moe_top_k=args.moe_top_k,
+        moe_ffn_hidden_size=getattr(args, "moe_ffn_hidden_size", None),
         moe_capacity_factor=args.moe_capacity_factor,
         moe_min_capacity=args.moe_min_capacity,
         moe_aux_loss_coeff=args.moe_aux_loss_coeff,
@@ -976,6 +993,12 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         gelu_variant=getattr(args, "gelu_variant", "tanh"),
         qk_norm=bool(getattr(args, "qk_norm", False)),
         norm_topk_prob=bool(getattr(args, "norm_topk_prob", True)),
+        qk_norm_per_head=bool(getattr(args, "qk_norm_per_head", False)),
+        dsa_index_heads=int(getattr(args, "dsa_index_heads", 0) or 0),
+        dsa_index_head_dim=int(getattr(args, "dsa_index_head_dim", 64)),
+        dsa_topk=int(getattr(args, "dsa_topk", 2048)),
+        rope_sections=(tuple(args.rope_sections)
+                       if getattr(args, "rope_sections", None) else None),
     )
 
 

@@ -208,11 +208,35 @@ class TransformerConfig:
     # softmax over the chosen ones); False uses the softmax over all
     # experts as it is (OLMoE), so a token's gates sum to less than 1
     norm_topk_prob: bool = True
+    # an expert's width where it is not ``ffn_hidden_size`` (a config
+    # that publishes a dense ``intermediate_size`` beside the experts'
+    # ``moe_intermediate_size``; with every layer sparse the dense width
+    # shapes nothing).  None: ``ffn_hidden_size``
+    moe_ffn_hidden_size: Optional[int] = None
 
     # RMSNorm on the query and key projections before the rotary
     # embedding, over the WHOLE projection (all heads together) with a
     # learned scale of the projection's width (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
+    # the per-head form: each head's head_dim values are normalised by
+    # themselves, with ONE learned scale of head_dim a layer for the
+    # queries and one for the keys (Qwen3's and Keye's q_norm / k_norm)
+    qk_norm_per_head: bool = False
+
+    # learned sparse attention (DeepSeek Sparse Attention, Keye's
+    # ``sa_config``): a small indexer of ``dsa_index_heads`` heads of
+    # ``dsa_index_head_dim`` and ONE key head scores every earlier
+    # position for each query, and the query attends only the
+    # ``dsa_topk`` best (every position while there are no more than
+    # that).  0 heads: no indexer, every key attended.  There is no
+    # switch that leaves the indexer in and the selection out.
+    dsa_index_heads: int = 0
+    dsa_index_head_dim: int = 64
+    dsa_topk: int = 2048
+    # rotary frequency pairs dealt to several position streams in
+    # sections (``mrope_section``: temporal, height, width); a text
+    # token's positions coincide and the embedding is the plain one
+    rope_sections: Optional[Tuple[int, ...]] = None
 
     # QKV-projection-only bias (Qwen2-style: attention in-projections
     # carry biases while every other linear is bias-free)
@@ -255,6 +279,26 @@ class TransformerConfig:
             raise ValueError(
                 f"context_parallel_algo must be ring|ulysses|zigzag, got "
                 f"{self.context_parallel_algo!r}")
+        if self.dsa_index_heads > 0:
+            # what the selection does not support is refused by name
+            if self.dsa_topk < 1 or self.dsa_index_head_dim % 2:
+                raise ValueError(
+                    f"sparse attention needs dsa_topk >= 1 and an even "
+                    f"dsa_index_head_dim, got {self.dsa_topk} and "
+                    f"{self.dsa_index_head_dim}")
+            if self.sliding_window_size is not None:
+                raise ValueError("sparse attention (dsa_index_heads > 0) "
+                                 "is not implemented with a sliding window")
+            if self.position_embedding_type != PositionEmbeddingType.rotary:
+                raise ValueError("sparse attention (dsa_index_heads > 0) "
+                                 "needs the rotary position embedding")
+        if self.qk_norm and self.qk_norm_per_head:
+            raise ValueError("qk_norm (over the whole projection) and "
+                             "qk_norm_per_head are two forms of one norm: "
+                             "choose one")
+        if self.rope_sections is not None:
+            object.__setattr__(self, "rope_sections",
+                               tuple(int(x) for x in self.rope_sections))
         if self.num_experts > 1:
             if self.add_bias_linear:
                 raise ValueError("MoE experts do not support linear biases "
@@ -272,6 +316,10 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.kv_channels
+
+    @property
+    def expert_hidden_size(self) -> int:
+        return self.moe_ffn_hidden_size or self.ffn_hidden_size
 
     @property
     def num_query_groups(self) -> int:

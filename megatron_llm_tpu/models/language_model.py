@@ -120,6 +120,14 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
         if name in layers["attention"]:
             layer_specs["attention"][name] = _norm_spec(
                 layers["attention"][name], stacked)
+    if "indexer" in layers["attention"]:
+        # the sparse-attention indexer is replicated (tp is refused)
+        ix = layers["attention"]["indexer"]
+        layer_specs["attention"]["indexer"] = {
+            **{name: _linear_spec(ix[name], None, None, stacked)
+               for name in ("query", "key", "weights")},
+            "key_norm": _norm_spec(ix["key_norm"], stacked),
+        }
     if "post_attention_norm" in layers:
         layer_specs["post_attention_norm"] = _norm_spec(
             layers["post_attention_norm"], stacked
@@ -395,7 +403,9 @@ def flops_per_token(cfg: TransformerConfig, seq_len: Optional[int] = None) -> fl
     mlp_p = h * ffn * mult + ffn * h
     if cfg.num_experts > 1:
         # MoE: top_k experts touched per token + the router matmul
-        mlp_p = cfg.moe_top_k * mlp_p + h * cfg.num_experts
+        ffn = cfg.expert_hidden_size
+        mlp_p = (cfg.moe_top_k * (h * ffn * mult + ffn * h)
+                 + h * cfg.num_experts)
     dense = L * (qkv + proj + mlp_p)
     emb = cfg.padded_vocab_size * h
     # fwd = 2 flops/param/token, bwd = 4, attention = 2*2*s*nh*d per layer fwd

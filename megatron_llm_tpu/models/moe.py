@@ -133,7 +133,7 @@ def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
         if cfg.use_scaled_init_method
         else init
     )
-    E, H, F = cfg.num_experts, cfg.hidden_size, cfg.ffn_hidden_size
+    E, H, F = cfg.num_experts, cfg.hidden_size, cfg.expert_hidden_size
     mult = 2 if cfg.glu_activation else 1
     return {
         "router": {"kernel": init(k_r, (H, E), dtype)},

@@ -34,9 +34,11 @@ import jax.numpy as jnp
 from megatron_llm_tpu.config import TransformerConfig, PositionEmbeddingType
 from megatron_llm_tpu.ops.activations import apply_mlp_activation
 from megatron_llm_tpu.models.moe import moe_mlp, moe_mlp_dropless
-from megatron_llm_tpu.ops.layernorm import apply_norm, init_norm_params
+from megatron_llm_tpu.ops.layernorm import (apply_norm, init_norm_params,
+                                             layer_norm, rms_norm)
 from megatron_llm_tpu.ops.paged_kv import PagedKVCache
-from megatron_llm_tpu.ops.rope import apply_rotary_emb, precompute_freqs_cis
+from megatron_llm_tpu.ops.rope import (apply_rotary_at, apply_rotary_emb,
+                                        precompute_freqs_cis)
 from megatron_llm_tpu.ops.softmax import (
     causal_mask,
     fused_scale_mask_softmax,
@@ -93,6 +95,29 @@ def init_attention_params(key, cfg: TransformerConfig, dtype):
             (cfg.num_attention_heads * cfg.head_dim,), dtype)}
         params["k_norm"] = {"scale": jnp.ones(
             (cfg.num_query_groups * cfg.head_dim,), dtype)}
+    elif cfg.qk_norm_per_head:
+        # one learned scale of a head's width, shared by the heads
+        params["q_norm"] = {"scale": jnp.ones((cfg.head_dim,), dtype)}
+        params["k_norm"] = {"scale": jnp.ones((cfg.head_dim,), dtype)}
+    if cfg.dsa_index_heads > 0:
+        # the sparse-attention indexer reads the layer's normed input
+        # through three bias-free projections: its heads' queries, its
+        # one key head (LayerNorm'd), a weight a head
+        ki = jax.random.split(k1, 4)[1:]
+        hi, di = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+        params["indexer"] = {
+            "query": init_linear_params(ki[0], cfg.hidden_size, hi * di,
+                                        bias=False, init_method=init,
+                                        dtype=dtype),
+            "key": init_linear_params(ki[1], cfg.hidden_size, di,
+                                      bias=False, init_method=init,
+                                      dtype=dtype),
+            "weights": init_linear_params(ki[2], cfg.hidden_size, hi,
+                                          bias=False, init_method=init,
+                                          dtype=dtype),
+            "key_norm": {"scale": jnp.ones((di,), dtype),
+                         "bias": jnp.zeros((di,), dtype)},
+        }
     return params
 
 
@@ -225,6 +250,30 @@ def _projection_rms_norm(x: jax.Array, scale: jax.Array, eps: float):
         b, s, n, d)
 
 
+def indexer_projections(x: jax.Array, params, cfg: TransformerConfig,
+                        positions: jax.Array):
+    """The sparse-attention indexer's query heads ``[b, s, Hi, di]`` and
+    one key head ``[b, s, di]`` (both rotated at ``positions`` [b, s],
+    compute dtype) and its head weights ``[b, s, Hi]`` (fp32, with the
+    two scale factors ``Hi^-1/2`` and ``di^-1/2`` folded in) from the
+    layer's normed input ``x`` [b, s, h]; and, with them, the top-k: what
+    ``PagedKVCache.attend`` takes as ``index``."""
+    b, s, _ = x.shape
+    hi, di = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+    cd = cfg.compute_jnp_dtype
+    xc = x.astype(cd)
+    iq = (xc @ params["query"]["kernel"].astype(cd)).reshape(b, s, hi, di)
+    ik = layer_norm(xc @ params["key"]["kernel"].astype(cd),
+                    params["key_norm"]["scale"], params["key_norm"]["bias"],
+                    eps=cfg.layernorm_epsilon)
+    iw = (xc @ params["weights"]["kernel"].astype(cd)).astype(jnp.float32)
+    iw = iw * (hi ** -0.5) * (di ** -0.5)
+    iq = apply_rotary_at(iq, positions, cfg.rope_theta, cfg.rope_sections)
+    ik = apply_rotary_at(ik[:, :, None, :], positions, cfg.rope_theta,
+                         cfg.rope_sections)[:, :, 0, :]
+    return iq, ik, iw, cfg.dsa_topk
+
+
 def core_attention(
     q: jax.Array,
     k: jax.Array,
@@ -304,7 +353,30 @@ def attention(
             k = _projection_rms_norm(k, params["k_norm"]["scale"],
                                      cfg.layernorm_epsilon)
 
-    if cfg.position_embedding_type == PositionEmbeddingType.rotary and freqs is not None:
+    elif cfg.qk_norm_per_head:
+        with jax.named_scope("qk_norm"):
+            # each head by itself: the norm over the last axis
+            q = rms_norm(q, params["q_norm"]["scale"],
+                         eps=cfg.layernorm_epsilon)
+            k = rms_norm(k, params["k_norm"]["scale"],
+                         eps=cfg.layernorm_epsilon)
+
+    index = None
+    if cfg.rope_sections is not None or cfg.dsa_index_heads > 0:
+        # positions are taken as given, with no table: [b, s], or
+        # [streams, b, s] for the sectioned embedding (a text token's
+        # streams coincide and this is the plain embedding)
+        positions = position_ids
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(q.shape[1])[None],
+                                         q.shape[:2])
+        q = apply_rotary_at(q, positions, cfg.rope_theta, cfg.rope_sections)
+        k = apply_rotary_at(k, positions, cfg.rope_theta, cfg.rope_sections)
+        if cfg.dsa_index_heads > 0:
+            with jax.named_scope("dsa_indexer"):
+                index = indexer_projections(x, params["indexer"], cfg,
+                                            positions)
+    elif cfg.position_embedding_type == PositionEmbeddingType.rotary and freqs is not None:
         cos, sin = freqs
         q = apply_rotary_emb(q, cos, sin, position_ids)
         k = apply_rotary_emb(k, cos, sin, position_ids)
@@ -313,10 +385,21 @@ def attention(
     paged_ctx = None
     if isinstance(kv_cache, PagedKVCache):
         # the serving engine's paged cache (ops/paged_kv.py owns it):
-        # scatter this call's K/V into the pool, attend through the
-        # path the cache carries
-        paged_ctx, new_cache = kv_cache.attend(q, k, v,
-                                               cfg.sliding_window_size)
+        # scatter this call's K/V (and the indexer's keys) into the
+        # pool, attend through the path the cache carries
+        paged_ctx, new_cache = kv_cache.attend(
+            q, k, v, cfg.sliding_window_size, index=index)
+    elif index is not None:
+        # the cache-less forward selects too: what tier-1 holds the
+        # paged programs against
+        if kv_cache is not None or attention_mask is not None:
+            raise NotImplementedError(
+                "sparse attention (dsa_index_heads > 0) runs through the "
+                "paged cache or the plain causal forward, not the legacy "
+                "decode caches nor an explicit attention mask")
+        from megatron_llm_tpu.ops.dsa import causal_selected_attention
+
+        paged_ctx = causal_selected_attention(q, k, v, *index)
     elif kv_cache is not None and "rolling" in kv_cache:
         # ROLLING cache (sliding-window models): a ring buffer of exactly
         # window slots — decode memory O(window), not O(total).  Slot

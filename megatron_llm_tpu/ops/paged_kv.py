@@ -5,7 +5,16 @@ looks like.
 A *pool* is one layer's pages, shared by every request the serving
 engine holds (``serving/kv_blocks.py`` hands the pages out): K and V as
 ``[num_blocks, block_size, groups, head_dim]``, or int8 with
-per-(block, position, group) fp32 absmax scales.  Block 0 is the
+per-(block, position, group) fp32 absmax scales; for a model with
+learned sparse attention (``cfg.dsa_index_heads``) a third array, the
+indexer's one key head, ``[num_blocks, block_size, index_width]``,
+written beside K and V at the same place (``index_width`` is the
+indexer's head size filled up with zeros to the TPU's 128 lanes: a
+narrower last dimension is laid out, and fetched, at that width
+anyway, and a slice of it cannot be addressed).  Whatever a pool holds, a page
+of it is a page of every array: the page programs below are
+``tree_map``s, so copy-on-write, the prefix cache's adoption and the
+host tier carry a page's indexer keys with its keys and values.  Block 0 is the
 reserved garbage block: padded chunk tokens and idle slots write there
 and nobody reads it unmasked.  All slots share the pool, so HBM is
 sized for aggregate traffic, not ``num_slots x max_len`` (the ragged
@@ -38,6 +47,12 @@ import jax
 import jax.numpy as jnp
 
 KERNEL_MODES = ("auto", "on", "off")
+_LANES = 128
+
+
+def _to_width(x: jax.Array, width: int) -> jax.Array:
+    """x with zeros appended to its last dimension up to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
 def resolve_kernel(requested: str, one_device: bool) -> str:
@@ -61,11 +76,16 @@ def resolve_kernel(requested: str, one_device: bool) -> str:
 
 def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                quantized: bool = False) -> List[dict]:
-    """One pool a layer for a model of config ``cfg``: the compute dtype,
-    or int8 with fp32 scales when ``quantized`` (halves the KV bytes a
-    decode step reads, against bf16)."""
+    """One pool a layer for a model of config ``cfg``: keys and values in
+    the compute dtype, or int8 with fp32 scales when ``quantized`` (halves
+    the KV bytes a decode step reads, against bf16); with a
+    sparse-attention indexer, its keys beside them (``index_pages``)."""
     dtype = dtype or cfg.compute_jnp_dtype
     shape = (num_blocks, block_size, cfg.num_query_groups, cfg.head_dim)
+    indexed = cfg.dsa_index_heads > 0
+    if indexed and quantized:
+        raise ValueError("sparse attention (dsa_index_heads > 0) is not "
+                         "implemented over the int8 KV pool")
 
     def pool():
         if quantized:
@@ -73,8 +93,13 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                     "k_pages_scale": jnp.ones(shape[:3], jnp.float32),
                     "v_pages_q": jnp.zeros(shape, jnp.int8),
                     "v_pages_scale": jnp.ones(shape[:3], jnp.float32)}
-        return {"k_pages": jnp.zeros(shape, dtype),
-                "v_pages": jnp.zeros(shape, dtype)}
+        kv = {"k_pages": jnp.zeros(shape, dtype),
+              "v_pages": jnp.zeros(shape, dtype)}
+        if indexed:
+            kv["index_pages"] = jnp.zeros(
+                shape[:2] + (-(-cfg.dsa_index_head_dim // _LANES) * _LANES,),
+                dtype)
+        return kv
 
     return [pool() for _ in range(cfg.num_layers)]
 
@@ -140,14 +165,25 @@ class PagedKVCache:
         return jnp.arange(n)[None, :] < self.valid_lens[:, None]
 
     def attend(self, q: jax.Array, k: jax.Array, v: jax.Array,
-               sliding_window: Optional[int]):
+               sliding_window: Optional[int], index=None):
         """Write this call's keys and values ``[b, n, g, d]`` at
         ``context_lens ..``, then attend ``q`` [b, n, nh, d] over the
         row's history and the chunk's own causal prefix.  Returns the
         context ``[b, n, nh, d]`` and the cache as the step leaves it
         (``context_lens`` advanced by ``valid_lens``).  Rows past
-        ``valid_lens`` are garbage in, garbage out on either path."""
+        ``valid_lens`` are garbage in, garbage out on either path.
+
+        ``index`` (a pool with ``index_pages`` needs it, no other takes
+        it): the sparse-attention indexer's ``(queries [b, n, Hi, di],
+        key [b, n, di], head weights [b, n, Hi], topk)``.  The key is
+        written beside K and V, and each query then attends only the
+        ``topk`` keys its indexer scores highest over the same range
+        (``ops/dsa.py`` says exactly which)."""
         from megatron_llm_tpu.ops.pallas import paged_attention as _pa
+
+        if (index is not None) != ("index_pages" in self.pool):
+            raise ValueError("a pool with index_pages, and only such a "
+                             "pool, is attended through an indexer")
 
         bt, ctx_lens, vlen = (self.block_tables, self.context_lens,
                               self.valid_lens)
@@ -171,6 +207,9 @@ class PagedKVCache:
                       "v_pages_q": vq, "v_pages_scale": vs}
         else:
             writes = {"k_pages": k, "v_pages": v}
+        if index is not None:
+            writes["index_pages"] = _to_width(
+                index[1], self.pool["index_pages"].shape[-1])
         pool = {}
         for name, val in writes.items():
             a = self.pool[name]
@@ -178,7 +217,9 @@ class PagedKVCache:
             pool[name] = flat.at[dest].set(val).reshape(a.shape)
         kp, vp, k_scales, v_scales = _arrays(pool)
         scale = 1.0 / math.sqrt(d)
-        if self.kernel == "pallas":
+        if index is not None:
+            ctx = self._attend_selected(q, pool, index, scale)
+        elif self.kernel == "pallas":
             # the chunk's own K/V were just scattered, so the kernel's
             # causal walk covers history AND the in-flight chunk
             kw = dict(valid_lens=vlen, k_scales=k_scales, v_scales=v_scales,
@@ -195,6 +236,38 @@ class PagedKVCache:
                 scale, sliding_window)
         return ctx, dataclasses.replace(self, pool=pool,
                                         context_lens=ctx_lens + vlen)
+
+
+    def _attend_selected(self, q, pool, index, scale):
+        """``attend``'s read for a pool with an indexer: scores over the
+        row's live pages of ``index_pages``, the choice, attention over
+        the chosen keys.  The kernels walk the pages; the dense path
+        gathers every row's table (live pages only) and masks."""
+        iq, _, iw, topk = index
+        iq = _to_width(iq, pool["index_pages"].shape[-1])
+        bt, ctx_lens, vlen = (self.block_tables, self.context_lens,
+                              self.valid_lens)
+        kp, vp, ip = pool["k_pages"], pool["v_pages"], pool["index_pages"]
+        if self.kernel == "pallas":
+            from megatron_llm_tpu.ops.pallas import dsa_attention as _dsa
+
+            return _dsa.paged_selected_attention(
+                q, iq, iw, kp, vp, ip, bt, ctx_lens, vlen, topk=topk,
+                softmax_scale=scale)
+        from megatron_llm_tpu.ops import dsa as _dsa
+
+        S, n = q.shape[:2]
+        bs, M = kp.shape[1], bt.shape[1]
+        live = ctx_lens + vlen
+        bt = jnp.where(jnp.arange(M)[None, :] * bs < live[:, None], bt, 0)
+
+        def gathered(pages):
+            return pages[bt].reshape((S, M * bs) + pages.shape[2:])
+
+        pos = ctx_lens[:, None] + jnp.arange(n)[None, :]
+        return _dsa.selected_attention(
+            q, gathered(kp), gathered(vp), iq, gathered(ip), iw, pos, topk,
+            scale)
 
 
 def step_caches(pools, block_tables, context_lens, valid_lens,

@@ -1,0 +1,455 @@
+"""Learned sparse attention over the paged pool (Pallas Mosaic TPU): the
+indexer's scores, the exact choice of each query's keys, and paged
+attention under that choice, for the decode step and the prefill chunk.
+
+``ops/dsa.py`` says what is computed (and is the dense path these
+kernels are tested against); ``ops/paged_kv.py::PagedKVCache.attend``
+is the one caller.  Three kernels, five names on a profile's
+``XLA Ops`` line:
+
+* ``dsa_index_scores_decode`` / ``dsa_index_scores_prefill``: a walk
+  over each row's LIVE pages of the indexer's pool (``index_pages``
+  ``[P, bs, di]``), in blocks of ``TB`` keys, block j+1 on its way from
+  HBM while block j is scored: ``sum_h w[h] * relu(qI[h] . kI)`` for all
+  of the row's queries against the block, written out as
+  ``[row, block, query, TB]`` fp32.  Blocks past the row's last live
+  page are never written; nobody reads them unmasked.
+* ``dsa_select_decode`` / ``dsa_select_prefill``: for 8 queries at a
+  time, the ``topk`` largest scores over the positions the query may see
+  (``s <= t``), exactly and with no sort: the ``topk``-th largest value
+  found by building its bit pattern from the top bit down (32 counts
+  over the row, held in VMEM), equal scores taken from the earliest
+  position (17 more counts, over positions).  Out comes an additive
+  mask, 0 for a chosen key and ``NEG_INF`` for any other, in the
+  scores' layout.  Never ``approx_max_k``.
+* ``paged_attention_sparse_decode`` / ``paged_attention_prefill_masked``:
+  ``paged_attention.py``'s walk over the row's live pages of K and V
+  with the mask applied to the scores of every block before the online
+  softmax, so the query attends the chosen keys and no other.
+
+Why the decode step walks every live page of K and V and masks, rather
+than gathering the chosen tokens out of the pages: a token's keys and
+values are 1 KB each at four KV heads of 128, and the walk's cost is its
+DMAs, not its bytes (PR 25 read 0.1 us a live PAGE of 16 KB).  2,048
+chosen tokens are 4,096 DMAs of 1 KB a row a layer; a context of 18,000
+tokens is 2,250 DMAs of 16 KB.  The walk issues fewer, reads each key
+once for all 32 heads, and is the kernel the other serving cells
+already trust; the mathematics are the same (``PERF.md`` section 6 has
+what the chip read).  Prefill attends densely under the mask for the
+reason the issue gives: gathering per query token would move 4 MB a
+token a layer.
+
+Interpret-mode tests run these on the CPU via ``paged_attention``'s
+module-level ``_INTERPRET`` flag.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from megatron_llm_tpu.ops import dsa as _dsa
+from megatron_llm_tpu.ops.pallas import paged_attention as _pa
+from megatron_llm_tpu.ops.pallas.paged_attention import (
+    NEG_INF, _pages_per_block, _softmax_block, _softmax_finish)
+
+_SELECT_ROWS = 8             # queries a select step holds in VMEM
+_PREFILL_BLOCK_Q = 128       # query rows of a masked-prefill step
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _params():
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
+# ---------------------------------------------------------------------------
+# the indexer's scores: a walk over the live pages of index_pages
+# ---------------------------------------------------------------------------
+
+def _scores_body(bt_ref, cl_ref, vl_ref, iq_ref, iw_ref, ip_hbm, out_hbm,
+                 kbuf, obuf, sem_in, sem_out):
+    """One row: blocks 0 .. its last live one; block j scored while block
+    j+1 is fetched and block j-1's scores are on their way out."""
+    s = pl.program_id(0)
+    _, kp, bs, di = kbuf.shape
+    _, hi, R, _ = iq_ref.shape
+    TB = kp * bs
+    ctx, n = cl_ref[s], vl_ref[s]
+    top = jnp.minimum(ctx + jnp.maximum(n, 1), bt_ref.shape[1] * bs) - 1
+    last = top // bs
+    nblk = jnp.where(n > 0, last // kp + 1, 0)
+
+    def fetch(j, slot, start):
+        p0 = j * kp
+
+        def page(i, carry):
+            cp = pltpu.make_async_copy(
+                ip_hbm.at[bt_ref[s, p0 + i]], kbuf.at[slot, i],
+                sem_in.at[slot])
+            cp.start() if start else cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(kp, last - p0 + 1), page, 0)
+
+    def store(j, slot):
+        return pltpu.make_async_copy(obuf.at[slot], out_hbm.at[s, j],
+                                     sem_out.at[slot])
+
+    @pl.when(nblk > 0)
+    def _first_block():
+        fetch(0, 0, True)
+
+    w_all = iw_ref[0]                                     # [R, hi] fp32
+
+    def block(j, carry):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < nblk)
+        def _next_block():
+            fetch(j + 1, 1 - slot, True)
+
+        fetch(j, slot, False)
+        k = kbuf[slot].reshape(TB, di)
+        acc = jnp.zeros((R, TB), jnp.float32)
+        for h in range(hi):
+            sc = jax.lax.dot_general(
+                iq_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [R, TB]
+            acc = acc + w_all[:, h:h + 1] * jnp.maximum(sc, 0.0)
+
+        @pl.when(j >= 2)
+        def _buffer_free():
+            store(j - 2, slot).wait()
+
+        obuf[slot] = acc
+        store(j, slot).start()
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+
+    @pl.when(nblk >= 2)
+    def _drain_one():
+        store(nblk - 2, jax.lax.rem(nblk, 2)).wait()
+
+    @pl.when(nblk >= 1)
+    def _drain_last():
+        store(nblk - 1, jax.lax.rem(nblk + 1, 2)).wait()
+
+
+def _index_scores(iq, iw, index_pages, block_tables, context_lens,
+                  valid_lens, *, kp, name):
+    """iq [S, R, hi, di], iw [S, R, hi] -> scores [S, nblk, R, TB] fp32
+    (blocks past a row's live pages unwritten)."""
+    S, R, hi, di = iq.shape
+    bs = index_pages.shape[1]
+    M = block_tables.shape[1]
+    nblk = -(-M // kp)
+    TB = kp * bs
+    iq_t = jnp.transpose(iq, (0, 2, 1, 3))                # [S, hi, R, di]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, hi, R, di), lambda s, *_: (s, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, R, hi), lambda s, *_: (s, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, kp, bs, di), index_pages.dtype),
+            pltpu.VMEM((2, R, TB), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        _scores_body, name=name, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, nblk, R, TB), jnp.float32),
+        compiler_params=_params(), interpret=_pa._INTERPRET,
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      valid_lens.astype(jnp.int32), iq_t, iw.astype(jnp.float32),
+      index_pages)
+
+
+# ---------------------------------------------------------------------------
+# the choice: the topk largest of a row, exactly, with no sort
+# ---------------------------------------------------------------------------
+
+def _select_body(pos_ref, s_ref, o_ref, *, topk, pos_bits):
+    """8 queries: ``s_ref`` [1, nblk, 8, TB] scores, ``pos_ref`` [1, 8, 1]
+    each query's own position (-1: a dead row, which chooses nothing).
+    Key (block j, lane c) lies at position j * TB + c and is open to the
+    query at or after it."""
+    x = s_ref[0]                                          # [nblk, 8, TB]
+    TB = x.shape[-1]
+    kpos = _iota(x.shape, 0) * TB + _iota(x.shape, 2)
+    valid = kpos <= pos_ref[0][None]                      # [nblk, 8, TB]
+
+    def count(cond):
+        # over a query's row: blocks (axis 0) and lanes; fp32 counts are
+        # exact far beyond a row's entries
+        c = jnp.sum(jnp.where(cond, 1.0, 0.0), axis=0)    # [8, TB]
+        return jnp.sum(c, axis=-1, keepdims=True)[None]   # [1, 8, 1]
+
+    chosen = _dsa.choose(_dsa.ordered_bits(x), valid, kpos, topk, pos_bits,
+                         count)
+    o_ref[0] = jnp.where(chosen, 0.0, NEG_INF)
+
+
+def _select(scores, row_pos, *, topk, name):
+    """scores [G, nblk, N, TB], row_pos [G, N] -> additive mask
+    [G, nblk, N, TB] fp32 (0 chosen, NEG_INF not)."""
+    G, nblk, N, TB = scores.shape
+    rows = _SELECT_ROWS
+    assert N % rows == 0, (N, rows)
+    pos_bits = max(1, math.ceil(math.log2(nblk * TB + 1)))
+    spec = pl.BlockSpec((1, nblk, rows, TB), lambda g, i: (g, 0, i, 0),
+                        memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_select_body, topk=topk, pos_bits=pos_bits),
+        name=name, grid=(G, N // rows),
+        in_specs=[pl.BlockSpec((1, rows, 1), lambda g, i: (g, i, 0),
+                               memory_space=pltpu.VMEM), spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(scores.shape, jnp.float32),
+        compiler_params=_params(), interpret=_pa._INTERPRET,
+    )(row_pos.astype(jnp.int32)[..., None], scores)
+
+
+# ---------------------------------------------------------------------------
+# paged attention under the mask: paged_attention.py's walk, plus a mask
+# ---------------------------------------------------------------------------
+
+def _masked_walk_body(bt_ref, cl_ref, vl_ref, q_ref, mask_ref, k_hbm, v_hbm,
+                      o_ref, kbuf, vbuf, mbuf, sem, m_scr, l_scr, acc_scr,
+                      *, scale, decode):
+    """One (row, q-block): walk pages 0 .. last of the row's table in
+    blocks of ``kp`` pages, block j+1 on its way while block j is
+    computed.  Decode: ``q_ref`` [1, 1, nh, d] and the row's whole mask
+    ``mask_ref`` [1, nblk, TB * g] in VMEM (lane c of a block is key
+    c // g, group c % g).  A chunk: ``q_ref`` [1, g, qpg, bq, d], and the
+    mask stays in HBM ``[S, nblk, C, TB]``, its block fetched with the
+    pages."""
+    s = pl.program_id(0)
+    _, kp, bs, g, d = kbuf.shape
+    T = kp * bs
+    lanes = T * g
+    if decode:
+        bq, (nh, qpg) = 1, (q_ref.shape[2], q_ref.shape[2] // g)
+    else:
+        _, _, qpg, bq, _ = q_ref.shape
+    R = bq * qpg
+    ctx = cl_ref[s]
+    q0 = pl.program_id(1) * bq
+    top = jnp.minimum(ctx + q0 + bq, bt_ref.shape[1] * bs) - 1
+    last = top // bs
+    nblk = jnp.where(vl_ref[s] > 0, last // kp + 1, 0)
+
+    def block_dma(j, slot, start):
+        p0 = j * kp
+
+        def page_dma(i, carry):
+            page = bt_ref[s, p0 + i]
+            for which, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(hbm.at[page], buf.at[slot, i],
+                                           sem.at[which, slot])
+                cp.start() if start else cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(kp, last - p0 + 1), page_dma, 0)
+        if not decode:
+            cp = pltpu.make_async_copy(
+                mask_ref.at[s, j, pl.ds(q0, bq)], mbuf.at[slot],
+                sem.at[2, slot])
+            cp.start() if start else cp.wait()
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(nblk > 0)
+    def _first_block():
+        block_dma(0, 0, True)
+
+    native = decode and kbuf.dtype == q_ref.dtype
+    q = q_ref[0] if native else q_ref[0].astype(jnp.float32)
+
+    def block(j, carry):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < nblk)
+        def _next_block():
+            block_dma(j + 1, 1 - slot, True)
+
+        block_dma(j, slot, False)
+        k = kbuf[slot].reshape(lanes, d)
+        v = vbuf[slot].reshape(lanes, d).astype(jnp.float32)
+        if not native:
+            k = k.astype(jnp.float32)
+        base = j * kp * bs
+        # pages of the buffer past the last live one hold what an earlier
+        # block left there: masked below, and zeroed here so that
+        # 0 x (whatever they are) adds nothing
+        v = jnp.where(base + jax.lax.div(_iota((lanes, 1), 0), g) <= top,
+                      v, 0.0)
+        if decode:
+            lane = _iota((nh, lanes), 1)
+            own = jax.lax.rem(lane, g) == jax.lax.div(_iota((nh, lanes), 0),
+                                                      qpg)
+            chosen = mask_ref[0, pl.ds(j, 1), :] > 0.5 * NEG_INF
+            valid = own & chosen & (base + jax.lax.div(lane, g) <= ctx)
+            sq = jax.lax.dot_general(
+                q[0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, slice(None))
+            return carry
+        # a chunk: the rows of one kv group [qpg * bq, d], head-major
+        # (flat row f is head f // bq of the group, chunk row f % bq),
+        # against that group's keys [T, d]
+        k, v = k.reshape(T, g, d), v.reshape(T, g, d)
+        chosen = jnp.concatenate([mbuf[slot]] * qpg,
+                                 axis=0) > 0.5 * NEG_INF         # [R, T]
+        valid = chosen & (base + _iota((R, T), 1)
+                          <= ctx + q0 + jax.lax.rem(_iota((R, T), 0), bq))
+        for grp in range(g):
+            sq = jax.lax.dot_general(
+                q[grp].reshape(R, d), k[:, grp, :],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale     # [R, T]
+            _softmax_block(sq, valid, v[:, grp, :], m_scr, l_scr, acc_scr,
+                           slice(grp * R, (grp + 1) * R))
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+    if decode:
+        o_ref[0] = _softmax_finish(l_scr, acc_scr, slice(None))[None].astype(
+            o_ref.dtype)
+    else:
+        for grp in range(g):
+            o_ref[0, grp] = _softmax_finish(
+                l_scr, acc_scr, slice(grp * R, (grp + 1) * R)
+            ).reshape(qpg, bq, d).astype(o_ref.dtype)
+
+
+def _masked_walk(q, mask, k_pages, v_pages, block_tables, context_lens,
+                 valid_lens, *, kp, scale, name):
+    """Decode: q [S, 1, nh, d], mask [S, nblk, TB * g].  A chunk: q
+    [S, C, nh, d], mask [S, nblk, C, TB].  Returns q's shape."""
+    S, C, nh, d = q.shape
+    bs, g = k_pages.shape[1], k_pages.shape[2]
+    qpg = nh // g
+    decode = C == 1
+    T = kp * bs
+    if decode:
+        bq = 1
+        q_in, q_block = q, (1, 1, nh, d)
+        q_map = lambda s, qi, *_: (s, qi, 0, 0)
+        mask_spec = pl.BlockSpec((1,) + mask.shape[1:],
+                                 lambda s, qi, *_: (s, 0, 0),
+                                 memory_space=pltpu.VMEM)
+        out_shape = q.shape
+    else:
+        bq = min(_PREFILL_BLOCK_Q, C)
+        while C % bq:
+            bq -= 1
+        # [S, g, qpg, C, d]: a group's rows head-major, so that a block's
+        # [qpg, bq, d] is [qpg * bq, d] as it lies
+        q_in = jnp.transpose(q.reshape(S, C, g, qpg, d), (0, 2, 3, 1, 4))
+        q_block = (1, g, qpg, bq, d)
+        q_map = lambda s, qi, *_: (s, 0, 0, qi, 0)
+        mask_spec = pl.BlockSpec(memory_space=pl.ANY)
+        out_shape = q_in.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, C // bq),
+        in_specs=[pl.BlockSpec(q_block, q_map, memory_space=pltpu.VMEM),
+                  mask_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(q_block, q_map, memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, kp, bs, g, d), k_pages.dtype),
+            pltpu.VMEM((2, kp, bs, g, d), v_pages.dtype),
+            pltpu.VMEM((2, bq, T), jnp.float32),
+            pltpu.SemaphoreType.DMA((3, 2)),
+            pltpu.VMEM((bq * nh, 1), jnp.float32),
+            pltpu.VMEM((bq * nh, 1), jnp.float32),
+            pltpu.VMEM((bq * nh, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_masked_walk_body, scale=scale, decode=decode),
+        name=name, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        compiler_params=_params(), interpret=_pa._INTERPRET,
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      valid_lens.astype(jnp.int32), q_in, mask, k_pages, v_pages)
+    if decode:
+        return out
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(S, C, nh, d)
+
+
+# ---------------------------------------------------------------------------
+# the public entry: scores, choice, attention
+# ---------------------------------------------------------------------------
+
+def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
+                             block_tables, context_lens, valid_lens, *,
+                             topk, softmax_scale):
+    """``q`` [S, n, nh, d] at positions ``context_lens[s] ..`` over the
+    pool (this call's keys, values and indexer keys already written),
+    each query attending the ``topk`` keys its indexer (``iq``
+    [S, n, Hi, di], ``iw`` [S, n, Hi]) scores highest over the positions
+    at or before its own.  ``n`` 1 is the decode step, whose rows are the
+    batch; ``n`` > 1 a chunk a row.  Returns [S, n, nh, d]."""
+    S, n, nh, d = q.shape
+    bs, g = k_pages.shape[1], k_pages.shape[2]
+    M = block_tables.shape[1]
+    kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
+    tables = (block_tables, context_lens, valid_lens)
+    live = jnp.arange(n)[None, :] < valid_lens[:, None]           # [S, n]
+    row_pos = jnp.where(live, context_lens[:, None] + jnp.arange(n)[None, :],
+                        -1)
+    rows = _SELECT_ROWS
+    if n == 1:
+        # the rows' one query each, padded to a tile of 8 for the scores
+        # and gathered into one batch of rows for the choice
+        pad = rows - 1
+        scores = _index_scores(
+            jnp.pad(iq, ((0, 0), (0, pad), (0, 0), (0, 0))),
+            jnp.pad(iw, ((0, 0), (0, pad), (0, 0))), index_pages, *tables,
+            kp=kp, name="dsa_index_scores_decode")[:, :, 0, :]    # [S, nb, TB]
+        Sp = -S % rows
+        scores = jnp.pad(jnp.transpose(scores, (1, 0, 2)),
+                         ((0, 0), (0, Sp), (0, 0)))[None]         # [1, nb, S+, TB]
+        mask = _select(scores, jnp.pad(row_pos[:, 0], (0, Sp),
+                                       constant_values=-1)[None],
+                       topk=topk, name="dsa_select_decode")[0, :, :S]
+        mask = jnp.repeat(jnp.transpose(mask, (1, 0, 2)), g, axis=-1)
+        return _masked_walk(q, mask, k_pages, v_pages, *tables, kp=kp,
+                            scale=softmax_scale,
+                            name="paged_attention_sparse_decode")
+    pad = -n % rows
+    if pad:
+        q, iq, iw = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                     for a in (q, iq, iw))
+        row_pos = jnp.pad(row_pos, ((0, 0), (0, pad)), constant_values=-1)
+    scores = _index_scores(iq, iw, index_pages, *tables, kp=kp,
+                           name="dsa_index_scores_prefill")
+    mask = _select(scores, row_pos, topk=topk, name="dsa_select_prefill")
+    out = _masked_walk(q, mask, k_pages, v_pages, *tables, kp=kp,
+                       scale=softmax_scale,
+                       name="paged_attention_prefill_masked")
+    return out[:, :n]

@@ -17,7 +17,7 @@ converter's rotary permutation in ``weights_conversion/hf_to_megatron.py:
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +92,6 @@ def apply_rotary_emb(
       (reference supports non-monotonic ids for packed sequences,
       positional_embeddings.py:33-44).
     """
-    orig_dtype = x.dtype
     *lead, s, h, d = x.shape
     rot_d = 2 * cos.shape[-1]
     if rot_d < d:
@@ -110,6 +109,14 @@ def apply_rotary_emb(
         sn = sin[position_ids]
         c = c[..., :, None, :]
         sn = sn[..., :, None, :]
+    return rotate_pairs(x, c, sn)
+
+
+def rotate_pairs(x: jax.Array, c: jax.Array, sn: jax.Array) -> jax.Array:
+    """x [..., s, heads, d] with its interleaved pairs (2i, 2i+1) turned
+    by the angles whose cosines and sines are ``c`` / ``sn``
+    [..., s, 1, d/2] (fp32); back in x's dtype."""
+    *lead, s, h, d = x.shape
     xf = x.astype(jnp.float32).reshape(*lead, s, h, d // 2, 2)
     x_even = xf[..., 0]
     x_odd = xf[..., 1]
@@ -117,4 +124,46 @@ def apply_rotary_emb(
     out_even = x_even * c - x_odd * sn
     out_odd = x_even * sn + x_odd * c
     out = jnp.stack([out_even, out_odd], axis=-1).reshape(*lead, s, h, d)
-    return out.astype(orig_dtype)
+    return out.astype(x.dtype)
+
+
+def section_streams(sections: Sequence[int], pairs: int) -> jax.Array:
+    """[pairs] int32: the position stream each frequency pair follows
+    when ``sections`` (``mrope_section``: so many pairs to the first
+    stream, so many to the second, ...) are dealt over ``pairs`` pairs.
+    Sections that sum to another number of pairs (the indexer's head is
+    narrower than the attention's) keep their proportions."""
+    total = sum(sections)
+    bounds, acc = [], 0
+    for n in sections:
+        acc += n
+        bounds.append(round(acc * pairs / total))
+    idx = jnp.arange(pairs)
+    return sum((idx >= b).astype(jnp.int32) for b in bounds[:-1])
+
+
+def apply_rotary_at(
+    x: jax.Array,
+    position_ids: jax.Array,
+    theta: float,
+    sections: Optional[Sequence[int]] = None,
+) -> jax.Array:
+    """Rotate the interleaved pairs of ``x`` [..., s, heads, d] at
+    explicit positions, with no table: pair i turns by
+    ``position * theta^(-2i/d)``.  ``position_ids`` [..., s], or
+    ``[streams, ..., s]`` with ``sections``: pair i then follows the
+    stream its section names (the sectioned rotary embedding of
+    multimodal models).  Streams that coincide, as a text token's do,
+    give the plain embedding."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    pos = position_ids.astype(jnp.float32)
+    if sections is not None and position_ids.ndim == x.ndim - 1:
+        # [streams, ..., s] -> each pair's own stream's position
+        stream = section_streams(sections, d // 2)               # [d/2]
+        pos = jnp.moveaxis(pos, 0, -1)[..., stream]              # [..., s, d/2]
+        ang = pos * inv
+    else:
+        ang = pos[..., None] * inv                               # [..., s, d/2]
+    return rotate_pairs(x, jnp.cos(ang)[..., None, :],
+                        jnp.sin(ang)[..., None, :])

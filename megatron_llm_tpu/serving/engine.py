@@ -272,6 +272,15 @@ class InferenceEngine:
                              f"got {cfg.draft_k}")
         self.speculative = bool(cfg.speculative)
         self.draft_k = int(cfg.draft_k) if self.speculative else 0
+        # learned sparse attention: each query attends its indexer's
+        # top-k, which the [S, K+1] verify step's reads do not implement
+        # (nor the int8 pool: ops/paged_kv.py::init_pools refuses it)
+        self._dsa_topk = (int(mcfg.dsa_topk) if mcfg.dsa_index_heads > 0
+                          else 0)
+        if self._dsa_topk and self.speculative:
+            raise ValueError(
+                "sparse attention (dsa_index_heads > 0) is not implemented "
+                "for the speculative verify step")
 
         # cache observatory (serving/cache_observatory.py): per-prefix
         # heat, eviction forensics, ghost capacity tiers.  Engine-
@@ -328,6 +337,10 @@ class InferenceEngine:
         # MOE_FIELDS: the same four are on every launch's record)
         for f in MOE_FIELDS:
             setattr(self, f, 0)
+        # learned sparse attention, summed over launches (the record's
+        # two fields of the same names)
+        self.dsa_keys_live = 0
+        self.dsa_keys_selected = 0
         self.prefill_secs = 0.0
         self.decode_secs = 0.0
         self.finished: Dict[str, int] = {}
@@ -950,6 +963,7 @@ class InferenceEngine:
         d.cached_tokens = req.cached_prompt_tokens
         d.requests = (req.id,)
         d.traces = (req.trace_id,) if req.trace_id else ()
+        self._note_selection(d, start + 1 + np.arange(valid))
         d.mark("build_inputs")
         finite = True
         last_logits, st.pages, routing = self._prefill_step(
@@ -1025,6 +1039,16 @@ class InferenceEngine:
         for f in MOE_FIELDS:
             setattr(self, f, getattr(self, f) + getattr(d, f))
 
+    def _note_selection(self, d: DispatchRecord, sees) -> None:
+        """A launch of a model with a sparse-attention indexer: the keys
+        its live queries see and attend, on the record and in the
+        running totals."""
+        if not self._dsa_topk:
+            return
+        d.note_selection(sees, self._dsa_topk, self.model.cfg.num_layers)
+        self.dsa_keys_live += d.dsa_keys_live
+        self.dsa_keys_selected += d.dsa_keys_selected
+
     def _note_batch(self, st: _EngineState, d: DispatchRecord,
                     slots: List[int], decoding: List[Request]) -> None:
         """What a decode/verify launch works on, for its record.  The
@@ -1040,6 +1064,7 @@ class InferenceEngine:
             self.model.cfg.padded_vocab_size)
         d.sampler_rows_drawn = int(drawn.sum())
         d.sampler_rows_filtered = int(filtered.sum())
+        self._note_selection(d, st.context_lens[slots] + 1)
         self.sample_draw_steps += d.sampler_rows_drawn > 0
         self.sample_sort_steps += d.sampler_rows_filtered > 0
 
@@ -1424,6 +1449,8 @@ class InferenceEngine:
             "drafted_tokens": self.drafted_tokens,
             "accepted_tokens": self.accepted_tokens,
             **{f: getattr(self, f) for f in MOE_FIELDS},
+            "dsa_keys_live": self.dsa_keys_live,
+            "dsa_keys_selected": self.dsa_keys_selected,
             "engine_restarts": self.engine_restarts,
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,
             "loop": self.loop_profiler.stats(),

@@ -151,6 +151,12 @@ class DispatchRecord:
     # active top-k or top-p (any makes the step sort)
     sampler_rows_drawn = 0
     sampler_rows_filtered = 0
+    # learned sparse attention (a model with an indexer; 0 otherwise),
+    # summed over the launch's live queries and the layers: the keys each
+    # query sees (its context and itself), and the same with each term
+    # cut at the top-k, which is what it attends
+    dsa_keys_live = 0
+    dsa_keys_selected = 0
     # the requests (and their trace ids) this launch served: the spans
     # that caused it
     requests: Tuple[int, ...] = ()
@@ -239,7 +245,16 @@ class DispatchRecord:
             **{f: getattr(self, f) for f in MOE_FIELDS},
             "sampler_rows_drawn": self.sampler_rows_drawn,
             "sampler_rows_filtered": self.sampler_rows_filtered,
+            "dsa_keys_live": self.dsa_keys_live,
+            "dsa_keys_selected": self.dsa_keys_selected,
         }
+
+    def note_selection(self, sees, topk: int, layers: int) -> None:
+        """``sees``: for each live query of the launch the keys it sees
+        (positions 0..its own), an int array the host made from what it
+        hands the program."""
+        self.dsa_keys_live = layers * int(sees.sum())
+        self.dsa_keys_selected = layers * int(sees.clip(max=topk).sum())
 
     def note_routing(self, counts) -> None:
         """``counts`` [layers, E]: the launch's histogram of live

@@ -560,6 +560,11 @@ KERNEL_NAMES = {
     "flash_attention_bwd_dq", "flash_attention_bwd_dkv",
     "rmsnorm_fwd", "rmsnorm_bwd", "layernorm_fwd", "layernorm_bwd",
     "moe_experts",
+    # learned sparse attention through the paged pool (PR 30): the
+    # program is in the name, because a trace's operation carries none
+    "dsa_index_scores_decode", "dsa_index_scores_prefill",
+    "dsa_select_decode", "dsa_select_prefill",
+    "paged_attention_sparse_decode", "paged_attention_prefill_masked",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -605,7 +610,15 @@ def test_every_kernel_and_program_carries_its_stable_name():
                     and node.func.id == "_walk_call"):
                 kw = {k.arg: k.value for k in node.keywords}
                 names |= {kw["name"].value, kw["name"].value + "_quant"}
-    assert calls == 10
+            # the sparse-attention kernels take theirs from the one entry
+            # that calls them for the decode step and for a chunk
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Name)
+                    and node.func.id in ("_index_scores", "_select",
+                                         "_masked_walk")):
+                kw = {k.arg: k.value for k in node.keywords}
+                names.add(kw["name"].value)
+    assert calls == 13
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

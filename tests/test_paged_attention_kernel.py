@@ -576,3 +576,137 @@ def test_transformer_prefill_kernel_parity(model_and_params, quantized):
                                    atol=2e-4, rtol=2e-4)
         np.testing.assert_allclose(b1[s, :int(v1[s])], a1[s, :int(v1[s])],
                                    atol=2e-4, rtol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention: scores, choice and attention under it
+# (ops/pallas/dsa_attention.py) against the dense gather-and-mask path
+# (ops/dsa.py, kernel='xla')
+# ---------------------------------------------------------------------------
+
+def _selected_case(rng, S, n, M, bs, g, nh, d, hi, ctx, valid, ties):
+    """A pool of K, V and indexer keys (128 wide, as the pool holds
+    them) with every row's context scattered through ragged tables, and
+    this call's queries.  ``ties``: the indexer's keys and queries take
+    few distinct values, so equal scores are everywhere."""
+    P = 1 + S * M
+    kp = (rng.standard_normal((P, bs, g, d))).astype(np.float32)
+    vp = (rng.standard_normal((P, bs, g, d))).astype(np.float32)
+    draw = ((lambda *sh: rng.integers(-1, 2, sh).astype(np.float32))
+            if ties else
+            (lambda *sh: rng.standard_normal(sh).astype(np.float32)))
+    ip = np.zeros((P, bs, 128), np.float32)
+    ip[..., :16] = draw(P, bs, 16)
+    iq = np.zeros((S, n, hi, 128), np.float32)
+    iq[..., :16] = draw(S, n, hi, 16)
+    iw = draw(S, n, hi) if ties else rng.standard_normal(
+        (S, n, hi)).astype(np.float32)
+    bt = np.zeros((S, M), np.int32)
+    order = rng.permutation(np.arange(1, P))
+    for s in range(S):
+        live = -(-(ctx[s] + valid[s]) // bs)
+        bt[s, :live] = order[s * M:s * M + live]
+    q = rng.standard_normal((S, n, nh, d)).astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (q, iq, iw, kp, vp, ip, bt)) + (
+        jnp.asarray(ctx, jnp.int32), jnp.asarray(valid, jnp.int32))
+
+
+def _selected_both(case, topk, d):
+    from megatron_llm_tpu.ops.pallas import dsa_attention
+
+    q, iq, iw, kp, vp, ip, bt, ctx, valid = case
+    pool = {"k_pages": kp, "v_pages": vp, "index_pages": ip}
+    index = (iq, None, iw, topk)
+    scale = 1.0 / math.sqrt(d)
+    dense = paged_kv.PagedKVCache(pool, bt, ctx, valid, kernel="xla")
+    want = dense._attend_selected(q, pool, index, scale)
+    got = dsa_attention.paged_selected_attention(
+        q, iq, iw, kp, vp, ip, bt, ctx, valid, topk=topk,
+        softmax_scale=scale)
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("topk", [8, 40])
+def test_selected_decode_kernels_match_the_dense_path(topk, ties,
+                                                      two_page_blocks):
+    """The decode step: five rows at contexts under the top-k, at it,
+    past it, across several compute blocks, and one row that is not
+    decoding; with equal scores everywhere (``ties``) the earlier
+    position wins on both paths."""
+    rng = np.random.default_rng(3)
+    ctx, valid = [3, topk - 1, topk, 150, 60], [1, 1, 1, 1, 0]
+    case = _selected_case(rng, 5, 1, 12, 16, 2, 4, 32, 4, ctx, valid, ties)
+    got, want = _selected_both(case, topk, 32)
+    live = np.asarray(valid) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=1e-5)
+    assert np.abs(got[~live]).max() == 0.0
+
+
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("ctx,valid", [(0, 24), (5, 24), (70, 11),
+                                       (150, 24)])
+def test_selected_prefill_kernels_match_the_dense_path(ctx, valid, ties,
+                                                       two_page_blocks):
+    """A chunk of 24 rows: at context 0 (its rows straddle the top-k of
+    8), mid-page, short and padded, and far past the top-k across compute
+    blocks."""
+    rng = np.random.default_rng(4)
+    case = _selected_case(rng, 1, 24, 12, 16, 2, 4, 32, 4, [ctx], [valid],
+                          ties)
+    got, want = _selected_both(case, 8, 32)
+    np.testing.assert_allclose(got[0, :valid], want[0, :valid], atol=2e-5,
+                               rtol=1e-5)
+
+
+def test_selected_attention_is_not_dense_attention(two_page_blocks):
+    """Past the top-k the chosen keys are fewer than the context and the
+    output differs from the plain walk's by whole tenths; under it they
+    are the same."""
+    rng = np.random.default_rng(5)
+    case = _selected_case(rng, 2, 1, 12, 16, 2, 4, 32, 4, [5, 150], [1, 1],
+                          False)
+    got, _ = _selected_both(case, 8, 32)
+    q, _, _, kp, vp, _, bt, ctx, valid = case
+    dense = np.asarray(pa.paged_attention_decode(
+        q[:, 0], kp, vp, bt, ctx, valid_lens=valid))
+    np.testing.assert_allclose(got[0, 0], dense[0], atol=2e-5)
+    assert np.abs(got[1, 0] - dense[1]).max() > 0.1
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf, -np.inf, 3e38])
+@pytest.mark.parametrize("n", [1, 24])
+def test_selection_never_reads_scores_nobody_wrote(n, poison, monkeypatch,
+                                                   two_page_blocks):
+    """The scores kernel writes a row's live blocks and no other: blocks
+    past its last live page, an idle row's every block, and the lanes of
+    its last block past the newest key hold whatever the chip's memory
+    held (in interpret mode: zeros, which hides it).  Filled with NaN,
+    infinities or huge numbers they change nothing: the choice counts
+    only what a query may see."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention
+
+    real = dsa_attention._index_scores
+
+    def poisoned(iq, iw, ip, bt, ctx, valid, **kw):
+        out = real(iq, iw, ip, bt, ctx, valid, **kw)
+        nblk, tb = out.shape[1], out.shape[3]
+        newest = jnp.where(valid > 0, ctx + valid - 1, -1)
+        kpos = (jnp.arange(nblk)[:, None] * tb + jnp.arange(tb)[None, :])
+        dead = kpos[None] > newest[:, None, None]            # [S, nblk, TB]
+        return jnp.where(dead[:, :, None, :], poison, out)
+
+    rng = np.random.default_rng(6)
+    if n == 1:
+        ctx, valid = [3, 150, 60, 40], [1, 1, 0, 1]
+    else:
+        ctx, valid = [70], [11]
+    case = _selected_case(rng, len(ctx), n, 12, 16, 2, 4, 32, 4, ctx, valid,
+                          False)
+    clean, want = _selected_both(case, 8, 32)
+    monkeypatch.setattr(dsa_attention, "_index_scores", poisoned)
+    got, _ = _selected_both(case, 8, 32)
+    for s, v in enumerate(valid):
+        np.testing.assert_array_equal(got[s, :v], clean[s, :v])
+        np.testing.assert_allclose(got[s, :v], want[s, :v], atol=2e-5,
+                                   rtol=1e-5)

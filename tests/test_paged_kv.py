@@ -130,3 +130,73 @@ def test_the_pool_has_one_owner():
     from megatron_llm_tpu.config import TransformerConfig
     assert not [f for f in TransformerConfig.__dataclass_fields__
                 if "paged" in f]
+
+
+# ---------------------------------------------------------------------------
+# a pool with a third array: the sparse-attention indexer's keys
+# ---------------------------------------------------------------------------
+
+def _keye_cfg():
+    from megatron_llm_tpu.models.keye import keye_config
+
+    return keye_config("tiny", use_flash_attn=False)
+
+
+def test_an_indexed_pool_holds_the_indexers_keys_beside_k_and_v():
+    """``init_pools`` for a model with an indexer: three arrays a layer,
+    the third 128 wide whatever the indexer's head size; its name occurs
+    in the one module that owns the pool; a dense model's pool is as it
+    was."""
+    import jax.numpy as jnp
+
+    cfg = _keye_cfg()
+    pools = paged_kv.init_pools(cfg, 5, 8, dtype=jnp.bfloat16)
+    assert len(pools) == cfg.num_layers
+    assert {k: (v.shape, v.dtype.name) for k, v in pools[0].items()} == {
+        "k_pages": ((5, 8, 2, 32), "bfloat16"),
+        "v_pages": ((5, 8, 2, 32), "bfloat16"),
+        "index_pages": ((5, 8, 128), "bfloat16")}
+    assert paged_kv.block_bytes(pools) == cfg.num_layers * 8 * (
+        2 * 2 * 32 + 128) * 2
+    assert _grep(r"[\"']index_pages", "megatron_llm_tpu") == [
+        "megatron_llm_tpu/ops/paged_kv.py"]
+    dense = paged_kv.init_pools(llama_config("tiny"), 5, 8)
+    assert set(dense[0]) == {"k_pages", "v_pages"}
+
+
+@pytest.mark.parametrize("n,ctx", [(1, 0), (1, 13), (6, 0), (6, 11)])
+def test_attend_writes_the_indexers_key_where_it_writes_k_and_v(n, ctx):
+    """The indexer's key lands at the same (page, offset) as the call's
+    keys and values, zero-filled to the pool's width; a padded row goes
+    to the garbage block; nothing else of the pool moves."""
+    import jax.numpy as jnp
+
+    cfg = _keye_cfg()
+    pool = paged_kv.init_pools(cfg, 6, 8)[0]
+    bt = jnp.asarray([[3, 1, 4, 0]], jnp.int32)
+    valid = n if n == 1 else n - 2
+    cache = paged_kv.step_caches([pool], bt, jnp.asarray([ctx], jnp.int32),
+                                 jnp.asarray([valid], jnp.int32), "xla")[0]
+    key = jax.random.PRNGKey(n + ctx)
+    q = jax.random.normal(key, (1, n, 4, 32))
+    k = jax.random.normal(key, (1, n, 2, 32)) + 1.0
+    iq = jax.random.normal(key, (1, n, 4, 16))
+    ik = jax.random.normal(key, (1, n, 16)) + 2.0
+    iw = jax.random.normal(key, (1, n, 4))
+    _, new = cache.attend(q, k, k, None, index=(iq, ik, iw, 8))
+    table = np.asarray(bt[0])
+    written = set()
+    for j in range(valid):
+        page, off = table[(ctx + j) // 8], (ctx + j) % 8
+        written.add((int(page), int(off)))
+        got = np.asarray(new.pool["index_pages"][page, off])
+        assert (got[:16] == np.asarray(ik[0, j])).all()
+        assert (got[16:] == 0).all()
+        assert (np.asarray(new.pool["k_pages"][page, off])
+                == np.asarray(k[0, j])).all()
+    for page in range(1, 6):
+        for off in range(8):
+            if (page, off) not in written:
+                assert not np.asarray(
+                    new.pool["index_pages"][page, off]).any()
+    assert int(new.context_lens[0]) == ctx + valid

@@ -99,3 +99,68 @@ def test_llama3_scale_freqs_matches_hf():
     assert (np.isclose(ratio, 1 / 8.0)).any(), "no /factor low-freq band"
     assert ((ratio > 1 / 8.0 + 1e-3) & (ratio < 1.0 - 1e-3)).any(), \
         "no interpolation band"
+
+
+# ---------------------------------------------------------------------------
+# the sectioned rotary embedding (mrope_section): frequency pairs dealt to
+# several position streams
+# ---------------------------------------------------------------------------
+
+def test_rotary_at_positions_is_the_tables_rotation():
+    """``apply_rotary_at`` computes its angles from the positions it is
+    given; on one stream it is ``apply_rotary_emb`` over the table."""
+    from megatron_llm_tpu.ops.rope import apply_rotary_at
+
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (2, 9, 3, 16)), jnp.float32)
+    pos = jnp.asarray([[0, 1, 2, 3, 4, 5, 6, 7, 8],
+                       [40, 41, 42, 43, 44, 45, 46, 47, 48]])
+    cos, sin = precompute_freqs_cis(16, 64, theta=1e4)
+    want = apply_rotary_emb(x, cos, sin, pos)
+    np.testing.assert_allclose(
+        np.asarray(apply_rotary_at(x, pos, 1e4)), np.asarray(want),
+        atol=1e-5)
+
+
+@pytest.mark.parametrize("d,sections", [(16, (2, 3, 3)), (128, (16, 24, 24)),
+                                        (64, (16, 24, 24))])
+def test_sectioned_rotary_is_the_plain_one_for_text(d, sections):
+    """Three position streams that coincide (a text token's) give the
+    plain embedding, whatever the sections; a head narrower than the
+    sections sum to (the indexer's 64 under 16 + 24 + 24 pairs) deals
+    them in proportion."""
+    from megatron_llm_tpu.ops.rope import apply_rotary_at, section_streams
+
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (1, 7, 2, d)), jnp.float32)
+    pos = jnp.arange(7)[None] + 3
+    plain = apply_rotary_at(x, pos, 1e7)
+    text = apply_rotary_at(x, jnp.stack([pos, pos, pos]), 1e7, sections)
+    assert (np.asarray(text) == np.asarray(plain)).all()
+    streams = np.asarray(section_streams(sections, d // 2))
+    assert streams.tolist() == sorted(streams.tolist())
+    assert set(streams.tolist()) == {0, 1, 2}
+    want = np.asarray(sections) * (d // 2) // sum(sections)
+    assert np.bincount(streams).tolist() == want.tolist()
+
+
+def test_sectioned_rotary_differs_where_the_streams_differ():
+    """Where the streams part (an image patch's height and width), pair i
+    follows ITS stream: each section's pairs equal the plain embedding at
+    that stream's position, and no other's."""
+    from megatron_llm_tpu.ops.rope import apply_rotary_at, section_streams
+
+    sections, d = (2, 3, 3), 16
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (1, 5, 2, d)), jnp.float32)
+    t = jnp.arange(5)[None]
+    streams3 = jnp.stack([t, t + 10, t + 20])
+    got = np.asarray(apply_rotary_at(x, streams3, 1e4, sections))
+    of = np.asarray(section_streams(sections, d // 2))
+    for stream in range(3):
+        plain = np.asarray(apply_rotary_at(x, streams3[stream], 1e4))
+        cols = np.flatnonzero(np.repeat(of == stream, 2))
+        np.testing.assert_allclose(got[..., cols], plain[..., cols],
+                                   atol=1e-6)
+        others = np.flatnonzero(np.repeat(of != stream, 2))
+        assert np.abs(got[..., others] - plain[..., others]).max() > 1e-2

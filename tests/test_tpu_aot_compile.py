@@ -112,7 +112,37 @@ def _experts(rows, experts, hidden, ffn, layers=8):
                 ((groups, ffn, hidden), BF16), ((groups,), jnp.int32)]
 
 
+def _selected(q_tokens, slots, tokens=33792, page=16):
+    """Keye's sparse attention through the paged pool (scores, choice,
+    attention under it: ``ops/pallas/dsa_attention.py``) at the cell's
+    shapes: 32 query and 4 kv heads of 128, an indexer of 16 heads of 64
+    (held 128 wide, as the pool holds its keys),
+    top-k 2,048, 8 slots of up to 33,792 tokens over a pool of 8,193
+    pages; the decode step and the [1, 512] chunk."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention as dsa
+
+    pages, table = 8193, tokens // page
+    pool = ((pages, page, 4, HEAD_DIM), BF16)
+
+    def fn(q, iq, iw, k_pages, v_pages, index_pages, tables, lens, valid):
+        return dsa.paged_selected_attention(
+            q, iq, iw, k_pages, v_pages, index_pages, tables, lens, valid,
+            topk=2048, softmax_scale=HEAD_DIM ** -0.5)
+
+    return fn, [((slots, q_tokens, HEADS, HEAD_DIM), BF16),
+                ((slots, q_tokens, 16, 128), BF16),
+                ((slots, q_tokens, 16), jnp.float32), pool, pool,
+                ((pages, page, 128), BF16), ((slots, table), jnp.int32),
+                ((slots,), jnp.int32), ((slots,), jnp.int32)]
+
+
 CASES = {
+    "dsa_selected_decode_8_slots": lambda: _selected(1, 8),
+    "dsa_selected_prefill_chunk_512": lambda: _selected(512, 1),
+    "moe_experts_keye_8_rows": lambda: _experts(8 * 8, 128, 2048, 768,
+                                                layers=7),
+    "moe_experts_keye_chunk_512_rows":
+        lambda: _experts(512 * 8, 128, 2048, 768, layers=7),
     "flash_fwd": lambda: _flash(),
     "flash_fwd_window_1024": lambda: _flash(window=1024),
     "flash_fused_bwd": lambda: _flash(grad=True),
