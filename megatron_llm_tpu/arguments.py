@@ -1053,9 +1053,11 @@ def parallel_config_from_args(args) -> ParallelConfig:
 
 
 def _resolve_hierarchical(args) -> bool:
-    """Explicit ICI-then-DCN staging is on for pure-DP multi-slice runs
-    unless --multislice_flat_reduce opts out; in-slice model parallelism
-    (tp/pp/cp > 1) always takes the flat ('slice','dp') reduction."""
+    """Explicit ICI-then-DCN staging of the step's one gradient reduction
+    is on for pure-DP multi-slice runs unless --multislice_flat_reduce opts
+    out; with in-slice model parallelism (tp/pp/cp > 1), where the staged
+    sum is not yet held to the flat one by a test, the reduction is one
+    flat psum over ('slice','dp')."""
     if (getattr(args, "num_slices", 1) or 1) <= 1:
         return False
     if getattr(args, "multislice_flat_reduce", False):

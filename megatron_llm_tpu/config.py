@@ -69,11 +69,11 @@ class ParallelConfig:
     # num_slices * data_parallel_size (data_parallel_size stays the
     # *per-slice* dp, matching the mesh's dp axis).
     num_slices: int = 1
-    # Stage the gradient all-reduce ICI-first/DCN-second via the explicit
-    # slice-vmap forward (multislice.sliced_forward). Resolved at arg
-    # validation: on for pure-DP multi-slice runs, off (flat GSPMD
-    # reduction over ('slice','dp')) when in-slice model parallelism is
-    # active or --multislice_flat_reduce is passed.
+    # Stage the train step's one gradient reduction ICI-first/DCN-second
+    # (multislice.hierarchical_psum). Resolved at arg validation: on for
+    # pure-DP multi-slice runs, off (one flat psum over ('slice','dp'))
+    # when in-slice model parallelism is active or
+    # --multislice_flat_reduce is passed.
     multislice_hierarchical: bool = False
 
     @property

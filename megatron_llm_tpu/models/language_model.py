@@ -218,9 +218,8 @@ def vocab_parallel_lookup_manual(table: jax.Array,
 
     tp_axis = topology.TP_AXIS
     # the call site sits inside a pp-manual shard_map: the nested region
-    # must use the *context* (abstract) mesh and re-declare every
-    # already-manual axis alongside the newly manualized tp
-    am, manual = topology.nesting_mesh(tp_axis)
+    # must use the *context* (abstract) mesh, and names tp alone
+    am, _ = topology.nesting_mesh(tp_axis)
     if am is None:
         return scatter_free_lookup(table, tokens)
 
@@ -238,7 +237,7 @@ def vocab_parallel_lookup_manual(table: jax.Array,
         mesh=am,
         in_specs=(P(tp_axis, None), P()),
         out_specs=P(),
-        axis_names=manual | {tp_axis},
+        axis_names={tp_axis},
         check_vma=False,
     )(table, tokens)
 

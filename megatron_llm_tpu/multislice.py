@@ -10,20 +10,15 @@ attribution so a slow slice is named the way a NaN layer is
 
 Four pieces:
 
-1. **Hierarchical gradient all-reduce** — ICI first, DCN second.  Under
-   GSPMD the dp gradient reduction is implicit (inserted where the loss
-   mean crosses the batch axis), and a batch spanning ``('slice', 'dp')``
-   would fold both hops into one flat collective.  ``sliced_forward``
-   instead gives the computation an *explicit* slice dimension: the batch
-   reshapes to ``[slice, batch/slice, ...]``, the params broadcast to a
-   per-slice leading axis, and the model runs under ``jax.vmap(...,
-   spmd_axis_name='slice')``.  The per-slice parameter-gradient
-   contraction then reduces over in-slice axes only (ICI all-reduce), and
-   the broadcast's transpose sums the per-slice gradients over the
-   ``slice`` axis (a separate DCN all-reduce) — two staged collectives,
-   per-slice math unchanged.  The explicit manual-region primitive
-   (``hierarchical_psum``) backs the CPU integration tests that check the
-   staged reduction is checksum-identical to a flat all-reduce.
+1. **Hierarchical gradient all-reduce** — ICI first, DCN second.  The
+   train step (``training.build_train_step``) runs its microbatch scan
+   manual over the data axes ``('slice', 'dp')``: every replica
+   accumulates the fp32 gradient of its own rows, and the one reduction
+   after the scan is ``hierarchical_psum``: a psum over the in-slice
+   ``dp`` axis (ICI), then a second psum over ``slice`` (DCN) — two
+   staged collectives a step where a flat psum over both axes would fold
+   the hops into one.  The CPU integration tests check the staged
+   reduction is checksum-identical to a flat all-reduce.
 
 2. **Elastic resume** — ``run_shape.json`` written next to checkpoints
    records the shape that produced them; on load the resume path detects
@@ -59,8 +54,7 @@ import os
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from megatron_llm_tpu import topology
 
@@ -121,83 +115,6 @@ def flat_allreduce(x: jax.Array, mesh=None) -> jax.Array:
         check_vma=False,
     )
     return jax.jit(fn)(x)
-
-
-# Trace-time flag: truthy while ``sliced_forward`` is tracing the model
-# under its slice-vmap.  ``parallel/sharding.py`` consults it so logical
-# 'batch' constraints inside the model stay plain 'dp' there (the vmap's
-# spmd_axis_name supplies the 'slice' entry); outside the vmap a
-# multi-slice batch constraint spans ('slice', 'dp').
-_HIER_TRACE_DEPTH = 0
-
-
-def hierarchical_forward_active() -> bool:
-    return _HIER_TRACE_DEPTH > 0
-
-
-def supports_hierarchical(parallel_cfg) -> bool:
-    """The explicit slice-vmap forward is used for pure-DP slices: with
-    in-slice model parallelism (tp/pp/cp > 1) the model forward nests its
-    own shard_maps, which do not compose with an outer vmap on this jax —
-    those configs keep the batch spanning ``('slice', 'dp')`` and defer
-    the DCN staging to the compiler's collective lowering."""
-    return (getattr(parallel_cfg, "num_slices", 1) > 1
-            and parallel_cfg.tensor_model_parallel_size == 1
-            and parallel_cfg.pipeline_model_parallel_size == 1
-            and parallel_cfg.context_parallel_size == 1)
-
-
-def sliced_forward(model, params, micro: Dict[str, Any], rng_key,
-                   num_slices: int, *, train: bool,
-                   sequence_parallel: bool, extra: Dict[str, Any]):
-    """Run the model with an explicit slice dimension (see module
-    docstring, piece 1).  Returns what ``model(...)`` returns, with the
-    per-slice leading axis merged back: per-token outputs reshape to the
-    flat global microbatch; per-slice scalars (MoE aux losses) mean over
-    slices (equal-sized slices, so that IS the global mean)."""
-    global _HIER_TRACE_DEPTH
-    S = num_slices
-    mesh = topology.get_mesh()
-
-    def split(x):
-        # [b, ...] -> [S, b/S, ...]; dim0 spans ('slice', 'dp') coming in,
-        # so the split is a relabeling, not a reshard
-        return x.reshape((S, x.shape[0] // S) + x.shape[1:])
-
-    def bcast(p):
-        # per-slice parameter replicas: logically [S, ...], physically one
-        # copy per slice (dim0 pinned to the slice axis; trailing dims
-        # replicated — the gated regime has no in-slice model parallelism).
-        # The broadcast's transpose is the explicit DCN gradient stage.
-        pb = jnp.broadcast_to(p[None], (S,) + p.shape)
-        return jax.lax.with_sharding_constraint(
-            pb, NamedSharding(mesh, P(SLICE_AXIS)))
-
-    p_s = jax.tree_util.tree_map(bcast, params)
-    tokens = split(micro["tokens"])
-    labels = split(micro["labels"])
-    extra_s = {k: split(v) for k, v in extra.items()}
-    sidx = jnp.arange(S)
-
-    def one_slice(p, tok, lab, i, ex):
-        key = None if rng_key is None else jax.random.fold_in(rng_key, i)
-        return model(p, tok, labels=lab, rng_key=key, train=train,
-                     sequence_parallel=sequence_parallel, **ex)
-
-    _HIER_TRACE_DEPTH += 1
-    try:
-        out = jax.vmap(one_slice, in_axes=(0, 0, 0, 0, 0),
-                       spmd_axis_name=SLICE_AXIS)(
-            p_s, tokens, labels, sidx, extra_s)
-    finally:
-        _HIER_TRACE_DEPTH -= 1
-
-    def merge(a):
-        if a.ndim >= 2:
-            return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-        return a.mean(axis=0)
-
-    return jax.tree_util.tree_map(merge, out)
 
 
 # ---------------------------------------------------------------------------

@@ -702,8 +702,8 @@ def sharded_flash_attention(
     q-heads-per-group ratio.  Falls back to the plain call when no mesh
     axis actually shards the inputs.  Nests inside the pipeline engines'
     pp-manual regions the same way ring attention does
-    (``topology.nesting_mesh`` semantics: abstract mesh + re-declared
-    manual axes).
+    (``topology.nesting_mesh`` semantics: the abstract mesh, naming only
+    the axes still automatic).
     """
     kw = dict(causal=causal, sliding_window=sliding_window,
               softmax_scale=softmax_scale, block_q=block_q,
@@ -773,17 +773,19 @@ def sharded_flash_attention(
 
     qspec = P(dp, None, tp_q, None)
     kvspec = P(dp, None, tp_kv, None)
-    # ALL mesh axes go manual, not just the ones in the specs: with a
-    # subset, the Mosaic call still sits inside an auto-sharding region
-    # for the remaining axes and the GSPMD partitioner refuses it even
-    # when those axes are size 1 / unused.  Unmentioned manual axes mean
-    # "replicated", which matches the activation layout here (and inside
-    # an enclosing pp/cp-manual region, matches per-group locality).
+    # ALL mesh axes still under GSPMD go manual, not just the ones in the
+    # specs: with a subset, the Mosaic call still sits inside an
+    # auto-sharding region for the remaining axes and the GSPMD partitioner
+    # refuses it even when those axes are size 1 / unused.  An axis an
+    # enclosing region already made manual (the train step's dp ranks, a
+    # pipeline's pp) is NOT named again: naming it without a spec entry
+    # says "the same on every rank of it", and the backward would then
+    # average dq/dk/dv over ranks that hold different rows.
     return jax.shard_map(
         lambda ql, kl, vl: flash_attention(ql, kl, vl, **kw),
         mesh=mesh,
         in_specs=(qspec, kvspec, kvspec),
         out_specs=qspec,
-        axis_names=set(mesh.axis_names),
+        axis_names=set(mesh.axis_names) - manual,
         check_vma=False,
     )(q, k, v)

@@ -161,9 +161,9 @@ def context_parallel_attention(
 
     Nests under the pipeline engine's pp-manual shard_map: inside a manual
     region jax requires the *abstract* context mesh (whose pp axis is
-    already Manual) and the re-declaration of its manual axes
-    (``topology.nesting_mesh``)."""
-    mesh, manual = topology.nesting_mesh(topology.CP_AXIS)
+    already Manual; ``topology.nesting_mesh``), and this region names cp
+    alone."""
+    mesh, _ = topology.nesting_mesh(topology.CP_AXIS)
     if mesh is None:
         raise RuntimeError(
             "context_parallel_attention called with no usable 'cp' axis in "
@@ -184,6 +184,6 @@ def context_parallel_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        axis_names=manual | {topology.CP_AXIS},
+        axis_names={topology.CP_AXIS},
         check_vma=False,
     )(q, k, v)

@@ -57,16 +57,8 @@ DEFAULT_RULES = {
 
 
 def _batch_axes():
-    """Mesh axes for the logical 'batch' dim, resolved at trace time.
-
-    Multi-slice runs span the batch over ('slice', 'dp') — except inside
-    the hierarchical slice-vmap forward (multislice.sliced_forward),
-    where the vmap's spmd_axis_name supplies the 'slice' entry and the
-    model-internal constraint must stay plain 'dp'."""
-    from megatron_llm_tpu import multislice
-
-    if multislice.hierarchical_forward_active():
-        return topology.DP_AXIS
+    """Mesh axes for the logical 'batch' dim, resolved at trace time:
+    ('slice', 'dp') in a multi-slice mesh, plain 'dp' otherwise."""
     axes = topology.data_axes()
     return axes if len(axes) > 1 else axes[0]
 
@@ -86,13 +78,30 @@ def _mesh() -> Optional[Mesh]:
     return topology._MESH
 
 
+def _auto_axes_of(spec: P) -> P:
+    """``spec`` without the mesh axes that are Manual where this is traced:
+    inside a manual region (the train step's data-parallel ranks, a
+    pipeline stage) a dimension is already this device's own part along
+    those axes, and a constraint may name only what GSPMD still places."""
+    _, manual = topology.current_mesh_and_manual()
+    if not manual:
+        return spec
+
+    def auto(entry):
+        if isinstance(entry, tuple):
+            return tuple(a for a in entry if a not in manual) or None
+        return None if entry in manual else entry
+
+    return P(*(auto(e) for e in spec))
+
+
 def constrain(x: jax.Array, *logical_axes: Optional[str], rules=None) -> jax.Array:
     """``with_sharding_constraint`` by logical axis names; no-op when no mesh
     is initialized (pure single-device runs and numpy-golden tests)."""
     mesh = _mesh()
     if mesh is None or all(a is None for a in logical_axes):
         return x
-    spec = logical_to_mesh(logical_axes, rules)
+    spec = _auto_axes_of(logical_to_mesh(logical_axes, rules))
     if all(a is None for a in spec):
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
@@ -105,7 +114,7 @@ def with_logical_constraint(tree, specs, rules=None):
     if mesh is None:
         return tree
     def one(x, s):
-        spec = logical_to_mesh(s, rules)
+        spec = _auto_axes_of(logical_to_mesh(s, rules))
         if all(a is None for a in spec):
             return x
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

@@ -226,7 +226,7 @@ def zigzag_context_attention(
     global arrays with the sequence axis contiguously sharded over cp;
     nests under the pipeline engines' manual regions via
     ``topology.nesting_mesh``."""
-    mesh, manual = topology.nesting_mesh(topology.CP_AXIS)
+    mesh, _ = topology.nesting_mesh(topology.CP_AXIS)
     if mesh is None:
         raise RuntimeError(
             "zigzag_context_attention called with no usable 'cp' axis in "
@@ -245,6 +245,6 @@ def zigzag_context_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        axis_names=manual | {topology.CP_AXIS},
+        axis_names={topology.CP_AXIS},
         check_vma=False,
     )(q, k, v)

@@ -597,7 +597,13 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    ``sample_batched`` draws and sorts in those steps and no others),
 #    and every decode/verify launch record carries sampler_rows_drawn
 #    and sampler_rows_filtered (those rows, counted)
-TELEMETRY_SCHEMA_VERSION = 16
+# 17: + the train_step_program event: once a run, when the train step is
+#    compiled, what its compiled text says of the data-parallel gradient
+#    reduction (num_microbatches, dp, dp_grad_reductions_per_step,
+#    dp_grad_reductions_in_loops, dp_grad_reduction_bytes_per_step,
+#    dp_grad_reduction_dtypes) — see training.py ``_ReadStep`` and
+#    hlo_collectives.py; an event is left out of the stream's step means
+TELEMETRY_SCHEMA_VERSION = 17
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 
@@ -635,6 +641,8 @@ class TelemetryStream:
         if self.status_server is not None:
             self.status_server.update(rec)
         self.flight_recorder.record(rec)
+        if rec["kind"] != "log":
+            return rec      # an event, not a step: the means leave it out
         s = self._sums
         s["steps"] += 1
         s["step_time"] += float(rec.get("step_time_secs") or 0.0)

@@ -268,8 +268,11 @@ def current_mesh_and_manual():
     """(governing mesh, already-Manual axis names) for building a
     shard_map that may nest inside another manual region — the abstract
     context mesh when one is active (inside jit/manual regions jax
-    requires it plus re-declaration of every already-Manual axis), else
-    the concrete global mesh.  ``(None, set())`` when no mesh governs."""
+    requires it), else the concrete global mesh.  ``(None, set())`` when
+    no mesh governs.  A nested shard_map names only the axes it makes
+    manual ITSELF: an already-Manual axis named again with no spec entry
+    reads as "replicated over it", and the transpose then averages the
+    inputs' cotangents over ranks whose data differ."""
     mesh = jax.sharding.get_abstract_mesh()
     if not mesh.axis_names:
         # not inside any mesh context: the concrete global mesh governs
@@ -300,7 +303,10 @@ def nesting_mesh(required_axis: str):
 
     Returns ``(mesh, manual_axes)``, or ``(None, None)`` when
     ``required_axis`` is absent or size 1 in the governing mesh — the
-    caller should fall back to its unsharded path.  NOTE: when an
+    caller should fall back to its unsharded path.  The nested shard_map
+    names ``required_axis`` alone (see ``current_mesh_and_manual``); the
+    manual axes are there for a caller that sizes its specs by what is
+    still automatic.  NOTE: when an
     abstract mesh is active but lacks the axis we must NOT silently
     switch to the global mesh (a nested shard_map over a different mesh
     than the enclosing context fails with an opaque jax error —

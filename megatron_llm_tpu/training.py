@@ -8,15 +8,26 @@ microbatch loop (schedules.py) each issuing fwd/bwd, then three grad-sync
 phases, then the optimizer.  Here the *entire* step — microbatch
 accumulation loop, loss scaling, grad clip, inf check, Adam, master->param
 cast — is one jitted function: ``lax.scan`` over the microbatch axis, then
-the functional optimizer.  GSPMD turns the dp-sharded batch into data
-parallelism (grad psum over dp is inserted where the loss mean crosses the
-batch axis), so ``reduce_model_grads``/``allreduce_gradients``
-(optimizer.py:280-302, distributed.py:202) have no hand-written analogue.
+the functional optimizer.
+
+Data parallelism is the reference's own shape (``allreduce_gradients`` once
+a step, distributed.py:202), written as a manual region: the scan runs
+under ``shard_map`` over the data axes (``_DataRanks``; tp / cp stay with
+GSPMD inside it).  Every dp rank accumulates, in fp32, the gradient of ITS
+rows of each microbatch; nothing parameter-shaped crosses dp inside the
+microbatch scan nor inside the layer scans under it, and after the scan
+every leaf is summed over dp exactly once, in fp32 (``_DataRanks.run``),
+before the optimizer.  Left to GSPMD, the sum sits where the gradient is
+produced — in the backward layer scan, once a layer a microbatch, in the
+parameters' dtype (PERF.md section 6, PR 31).  ``loss_func`` still sees the
+global microbatch: the ranks exchange the per-token losses (a few KB), not
+gradients.  ``_ReadStep`` counts the reductions in the compiled program.
 """
 
 from __future__ import annotations
 
 import logging
+import math
 import sys
 import time
 from functools import partial
@@ -24,14 +35,20 @@ from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from megatron_llm_tpu.config import TrainConfig, TransformerConfig, ParallelConfig
 from megatron_llm_tpu.optimizer import MegatronOptimizer, OptimizerParamScheduler
 from megatron_llm_tpu.optimizer.optimizer import global_grad_norm
 from megatron_llm_tpu import health
+from megatron_llm_tpu import hlo_collectives
 from megatron_llm_tpu import random as mrandom
+from megatron_llm_tpu import telemetry
+from megatron_llm_tpu import topology
 from megatron_llm_tpu import tracing
 from megatron_llm_tpu.global_vars import get_counters
+from megatron_llm_tpu.parallel.sharding import logical_to_mesh
+from megatron_llm_tpu.topology import SLICE_AXIS
 
 logger = logging.getLogger("megatron_llm_tpu")
 
@@ -51,6 +68,191 @@ def default_loss_func(loss_tok: jax.Array, loss_mask: jax.Array):
     """Masked token-mean loss (reference: finetune.py:201-218)."""
     loss_mask = loss_mask.astype(jnp.float32)
     return jnp.sum(loss_tok * loss_mask) / jnp.maximum(jnp.sum(loss_mask), 1.0)
+
+
+def _live_data_axes():
+    """(mesh, the data axes more than one device wide); (None, ()) with no
+    mesh."""
+    mesh = topology._MESH
+    if mesh is None:
+        return None, ()
+    return mesh, tuple(a for a in topology.data_axes() if mesh.shape[a] > 1)
+
+
+class _DataRanks:
+    """The data-parallel ranks one train step runs on, and what crosses them.
+
+    With ``axes`` the step's microbatch scan is manual over those mesh axes
+    (``shard_map``; tp / cp / pp stay with GSPMD): a rank holds its rows of
+    every microbatch and a whole copy of the parameters.  Without (no mesh,
+    one rank, rows that do not divide, or parameters that are themselves
+    sharded over the data axes: MoE experts folded into dp, which need every
+    rank's tokens) every method is the identity and the step is one global
+    program for GSPMD to partition."""
+
+    def __init__(self, mesh=None, axes=()):
+        self.mesh, self.axes = mesh, tuple(axes)
+        self.n = math.prod(mesh.shape[a] for a in self.axes)
+
+    @classmethod
+    def of(cls, model, params, batch):
+        ranks = cls(*_live_data_axes())
+        # every batch entry is [num_micro, rows, ...]; an entry with no rows
+        # axis, or rows that do not divide, leaves the split to GSPMD
+        rows = {x.shape[1] if x.ndim > 1 else 1
+                for x in jax.tree_util.tree_leaves(batch)}
+        if (ranks.n == 1 or any(r % ranks.n for r in rows)
+                or ranks._hold_shards_of(model, params)):
+            return cls()
+        return ranks
+
+    def _hold_shards_of(self, model, params) -> bool:
+        specs = getattr(model, "param_specs", None)
+        if specs is None:
+            return False
+        return any(
+            a in self.axes
+            for spec in jax.tree_util.tree_leaves(
+                specs(params), is_leaf=lambda v: isinstance(v, tuple))
+            for entry in logical_to_mesh(spec)
+            for a in (entry if isinstance(entry, tuple) else (entry,)))
+
+    def run(self, fn, staged: bool = False):
+        """``fn(params, batch, rng_key, scale, ranks)`` -> (fp32 gradients,
+        losses, aux losses) on every rank, and then the step's ONE
+        reduction: every gradient leaf summed over the ranks.
+
+        The ranks' gradients leave the manual region side by side on a new
+        leading axis and are summed outside it, so the reduction is the
+        partitioner's own ``all-reduce`` (one a leaf, f32, after every
+        loop), under the name a trace's reader knows.  ``staged`` (a
+        multi-slice mesh) sums inside the region instead, in-slice axes
+        first and then across slices, as two collectives
+        (``multislice.hierarchical_psum``): left to the partitioner the
+        two hops fold into one."""
+        fn = partial(fn, ranks=self)
+        if not self.axes:
+            return fn
+        staged = staged and SLICE_AXIS in self.axes
+
+        def leave(g):
+            if not staged:
+                return g[None]
+            from megatron_llm_tpu.multislice import hierarchical_psum
+            return hierarchical_psum(
+                g, tuple(a for a in self.axes if a != SLICE_AXIS))
+
+        def on_a_rank(*args):
+            grads, losses, auxes = fn(*args)
+            return jax.tree_util.tree_map(leave, grads), losses, auxes
+
+        region = jax.shard_map(
+            on_a_rank, mesh=self.mesh,
+            in_specs=(P(), P(None, self.axes), P(), P()),
+            out_specs=(P() if staged else P(self.axes), P(), P()),
+            axis_names=set(self.axes), check_vma=False)
+        if staged:
+            return region
+
+        def whole(*args):
+            grads, losses, auxes = region(*args)
+            return (jax.tree_util.tree_map(lambda g: g.sum(axis=0), grads),
+                    losses, auxes)
+
+        return whole
+
+    def fold_in(self, key):
+        if not self.axes:
+            return key
+        return jax.random.fold_in(key, jax.lax.axis_index(self.axes))
+
+    def rows_of_all(self, tree):
+        """Every rank's rows (leading axis), on every rank."""
+        if not self.axes:
+            return tree
+        return jax.tree_util.tree_map(
+            lambda x: jax.lax.all_gather(x, self.axes, axis=0, tiled=True),
+            tree)
+
+    def own_rows(self, tree):
+        """Of a cotangent for ``rows_of_all``'s result, this rank's part."""
+        if not self.axes:
+            return tree
+        i = jax.lax.axis_index(self.axes)
+
+        def own(c):
+            rows = c.shape[0] // self.n
+            return jax.lax.dynamic_slice_in_dim(c, i * rows, rows, axis=0)
+
+        return jax.tree_util.tree_map(own, tree)
+
+    def mean(self, x):
+        """A per-rank statistic (the MoE routing losses), averaged."""
+        if not self.axes or x is None:
+            return x
+        return jax.lax.pmean(x, self.axes)
+
+    def share(self, ct):
+        """Of a cotangent for ``mean``'s result, this rank's part."""
+        return ct if not self.axes or ct is None else ct / self.n
+
+
+class _ReadStep:
+    """The jitted train step, compiled ahead of its first call (once: the
+    calls run that executable) so that the compiled program's own text can
+    say where its data-parallel gradient reductions sit.  One
+    ``train_step_program`` record goes to the structured log
+    (``--structured_log_dir``): ``dp_grad_reductions_per_step`` is counted
+    from the text (``hlo_collectives``), not asserted from this file."""
+
+    def __init__(self, jitted, num_microbatches: int):
+        self.jitted, self.num_microbatches = jitted, num_microbatches
+        self.compiled = None
+        self.program: Dict = {}
+
+    def lower(self, *args, **kwargs):
+        return self.jitted.lower(*args, **kwargs)
+
+    def __call__(self, *args):
+        if self.compiled is None:
+            self.compiled = self.jitted.lower(*args).compile()
+            self._read()
+        try:
+            return self.compiled(*args)
+        except (TypeError, ValueError):
+            # other shapes, dtypes or placements than the first call's:
+            # jit compiles for them, and the trainer counts a recompile
+            return self.jitted(*args)
+
+    def _read(self):
+        mesh, axes = _live_data_axes()
+        self.program = {"kind": "train_step_program",
+                        "num_microbatches": self.num_microbatches,
+                        "dp": math.prod(mesh.shape[a] for a in axes)}
+        try:
+            rows = hlo_collectives.collectives(self.compiled.as_text())
+            # over dp, over slice, or (the flat multi-slice sum) over both
+            over = [axes[k:j + 1] for k in range(len(axes))
+                    for j in range(k, len(axes))]
+            found = [r for sub in over
+                     for r in hlo_collectives.reductions_over(
+                         rows, hlo_collectives.mesh_groups(
+                             dict(mesh.shape), sub))]
+            self.program.update(
+                dp_grad_reductions_per_step=sum(r["calls"] for r in found),
+                dp_grad_reductions_in_loops=sum(
+                    r["calls"] for r in found if r["loops"]),
+                dp_grad_reduction_bytes_per_step=sum(
+                    r["bytes"] * r["calls"] for r in found),
+                dp_grad_reduction_dtypes=sorted(
+                    {d for r in found for d in r["dtypes"]}))
+        except Exception as e:  # noqa: BLE001 - a reading, never a failure
+            logger.warning("train step's collectives not read: %s", e)
+        if jax.process_index() == 0:
+            print(f" train step program: {self.program}", flush=True)
+        stream = telemetry.get_stream()
+        if stream is not None:
+            stream.emit(self.program)
 
 
 def build_train_step(
@@ -74,17 +276,15 @@ def build_train_step(
     # MoE models return (per-token loss, [lb, z] routing aux) — static on
     # the model config, so BERT/T5's own tuple returns are unaffected
     moe_on = getattr(getattr(model, "cfg", None), "num_experts", 0) > 1
-    # multi-slice hierarchical (ICI-then-DCN) gradient staging: run the
-    # forward under multislice.sliced_forward's explicit slice-vmap so the
-    # dp gradient all-reduce stays in-slice and the cross-slice sum is a
-    # separate DCN collective.  Per-slice math is unchanged — loss_func
-    # still sees the merged global-microbatch per-token losses.
-    num_slices = getattr(parallel_cfg, "num_slices", 1) or 1
-    hierarchical = (num_slices > 1
+    # multi-slice hierarchical (ICI-then-DCN) gradient staging: the one
+    # reduction after the microbatch scan sums in-slice first, then across
+    # slices, as two collectives (multislice.hierarchical_psum)
+    hierarchical = ((getattr(parallel_cfg, "num_slices", 1) or 1) > 1
                     and getattr(parallel_cfg, "multislice_hierarchical",
                                 False))
 
-    def microbatch_loss(params, micro, rng_key, scale):
+    def forward(params, micro, rng_key):
+        """The model on one microbatch: (outputs, MoE routing aux or None)."""
         # every batch key beyond the canonical trio is forwarded as a model
         # kwarg (tokentype_ids / sentence_order for BERT, encoder inputs for
         # T5 — mirroring the per-arch get_batch of the reference entry points)
@@ -92,30 +292,25 @@ def build_train_step(
             k: v for k, v in micro.items()
             if k not in ("tokens", "labels", "loss_mask")
         }
-        if hierarchical:
-            from megatron_llm_tpu import multislice
-            loss_tok = multislice.sliced_forward(
-                model, params, micro, rng_key, num_slices,
-                train=not forward_only, sequence_parallel=sp, extra=extra,
-            )
-        else:
-            loss_tok = model(
-                params,
-                micro["tokens"],
-                labels=micro["labels"],
-                rng_key=rng_key,
-                train=not forward_only,
-                sequence_parallel=sp,
-                **extra,
-            )
-        moe_aux = None
-        if moe_on:
-            loss_tok, moe_aux = loss_tok
-        out = loss_func(loss_tok, micro["loss_mask"])
+        out = model(
+            params,
+            micro["tokens"],
+            labels=micro["labels"],
+            rng_key=rng_key,
+            train=not forward_only,
+            sequence_parallel=sp,
+            **extra,
+        )
+        return out if moe_on else (out, None)
+
+    def objective(out, moe_aux, loss_mask, scale):
+        """What a microbatch adds to the step's loss, from the model's
+        outputs for the GLOBAL microbatch."""
+        res = loss_func(out, loss_mask)
         # loss_func may return (total, {metric: scalar}) to log components
         # separately (reference logs a loss dict per arch, e.g. BERT's
         # {'lm loss', 'sop loss'} — pretrain_bert.py loss_func)
-        loss, aux = out if isinstance(out, tuple) else (out, {})
+        loss, aux = res if isinstance(res, tuple) else (res, {})
         total = loss
         if moe_aux is not None:
             # the routing losses enter the optimized objective; the logged
@@ -136,7 +331,9 @@ def build_train_step(
 
         def eval_step(params, batch, rng_key):
             def body(carry, micro):
-                _, (loss, _aux) = microbatch_loss(params, micro, None, 1.0)
+                out, moe_aux = forward(params, micro, None)
+                _, (loss, _aux) = objective(out, moe_aux,
+                                            micro["loss_mask"], 1.0)
                 return carry, loss
 
             _, losses = jax.lax.scan(body, 0, batch)
@@ -144,26 +341,47 @@ def build_train_step(
 
         return jax.jit(eval_step)
 
-    def train_step(params, opt_state, batch, rng_key, lr, wd):
-        scale = opt_state.grad_scaler.scale
-        zeros = jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params
-        )
+    def accumulate(params, batch, rng_key, scale, ranks):
+        """fp32 gradients of the whole step, its losses and aux losses.
 
-        def body(carry, scanned):
-            grads_acc = carry
+        With ``ranks`` this runs once on every data-parallel rank, on that
+        rank's rows of each microbatch: the rank accumulates the gradient
+        of ITS rows and nothing parameter-shaped crosses ``ranks.axes``
+        in here (``ranks.run`` sums the ranks' results, once).  Without,
+        on the global batch."""
+
+        def body(grads_acc, scanned):
             micro, idx = scanned
-            mkey = jax.random.fold_in(rng_key, idx)
-            grad_fn = jax.value_and_grad(microbatch_loss, has_aux=True)
-            (_, (loss, aux)), g = grad_fn(params, micro, mkey, scale)
+            mkey = ranks.fold_in(jax.random.fold_in(rng_key, idx))
+            (out, moe_aux), pullback = jax.vjp(
+                lambda p: forward(p, micro, mkey), params)
+            # loss_func sees the global microbatch (a masked mean's
+            # denominator, in-batch negatives); every rank differentiates it
+            # with respect to all rows and pulls back its own rows'
+            # cotangent, so no collective carries a gradient in here
+            (_, (loss, aux)), ct = jax.value_and_grad(
+                objective, argnums=(0, 1), has_aux=True)(
+                ranks.rows_of_all(out), ranks.mean(moe_aux),
+                ranks.rows_of_all(micro["loss_mask"]), scale)
+            (g,) = pullback((ranks.own_rows(ct[0]), ranks.share(ct[1])))
             grads_acc = jax.tree_util.tree_map(
                 lambda a, b: a + b.astype(jnp.float32), grads_acc, g
             )
             return grads_acc, (loss, aux)
 
+        zeros = jax.tree_util.tree_map(
+            lambda p: jnp.zeros(p.shape, jnp.float32), params
+        )
         grads, (losses, auxes) = jax.lax.scan(
             body, zeros, (batch, jnp.arange(num_microbatches))
         )
+        return grads, losses, auxes
+
+    def train_step(params, opt_state, batch, rng_key, lr, wd):
+        scale = opt_state.grad_scaler.scale
+        ranks = _DataRanks.of(model, params, batch)
+        grads, losses, auxes = ranks.run(accumulate, staged=hierarchical)(
+            params, batch, rng_key, scale)
         new_params, new_opt_state, stats = optimizer.step(
             params, grads, opt_state, lr, wd, layer_stats=log_layer_stats
         )
@@ -188,7 +406,8 @@ def build_train_step(
         metrics.update({k: jnp.mean(v) for k, v in auxes.items()})
         return new_params, new_opt_state, metrics
 
-    return jax.jit(train_step, donate_argnums=(0, 1))
+    return _ReadStep(jax.jit(train_step, donate_argnums=(0, 1)),
+                     num_microbatches)
 
 
 # ---------------------------------------------------------------------------

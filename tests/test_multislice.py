@@ -98,7 +98,7 @@ def _global_batch(mesh, num_micro=2, gb=8, seed=0):
 
 
 def test_train_step_parity_hierarchical_vs_flat(utils):
-    """The staged slice-vmap forward must reproduce the flat GSPMD
+    """The staged (ICI-then-DCN) reduction must reproduce the flat
     reduction: same loss, same grad norm, same updated params (up to
     reduction-order float noise)."""
     utils.initialize_model_parallel(num_slices=2)   # slice=2 x dp=4

@@ -223,6 +223,11 @@ def test_structured_stream_schema_and_profiler_xplane(utils, tmp_path):
 
     lines = open(os.path.join(log_dir, "telemetry.jsonl")).readlines()
     records = [json.loads(l) for l in lines]
+    # the compiled step says once what it counted in its own text (PR 31);
+    # every other record is a log boundary
+    (program,) = [r for r in records if r["kind"] == "train_step_program"]
+    assert program["dp_grad_reductions_in_loops"] == 0
+    records = [r for r in records if r["kind"] == "log"]
     assert [r["iteration"] for r in records] == [1, 2, 3, 4]
     golden_keys = {"schema", "kind", "time_unix", "iteration",
                    "train_iters", "lm_loss", "grad_norm", "loss_scale",

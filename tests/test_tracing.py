@@ -462,6 +462,9 @@ def test_pretrain_trace_acceptance(utils, tmp_path):
 
     records = [json.loads(l) for l in
                open(os.path.join(d, "telemetry.jsonl"))]
+    # one event from the compiled step (PR 31), then the log boundaries
+    assert records[0]["kind"] == "train_step_program"
+    records = [r for r in records if r["kind"] == "log"]
     assert [r["iteration"] for r in records] == [1, 2, 3, 4, 5, 6]
     for r in records:
         assert 0.0 < r["goodput_pct"] <= 100.0

@@ -19,6 +19,7 @@ training configs in /root/reference/docs/guide/getting_started.md.
 Usage:
   python tools/aot_memcheck.py [config ...]     # default: all
   python tools/aot_memcheck.py --list
+  python tools/aot_memcheck.py --hlo_dir=DIR config   # + DIR/<config>.hlo.txt
 
 Each config runs in a forced-CPU subprocess (AOT needs only the local
 libtpu compiler, no chip).
@@ -64,6 +65,15 @@ CONFIGS = {
         family="llama2", size="7B", topology="v5e:4x4", accel="v5litepod-16",
         hbm_gb=16, tp=8, pp=1, vpp=None, seq=4096, micro_batch=1,
         num_micro=8, zero1=True, recompute="full",
+    ),
+    # the benchmark's training cell (mistral-7b-train-tp2dp2.pretrain-4k):
+    # 5 of 32 layers on the four chips of one v5e host, tp 2 (sequence
+    # parallel) x dp 2, 4 accumulated micro-batches; with --hlo_dir its
+    # text is what docs/guide/collective_placement.md reads
+    "mistral-7b-5l-tp2dp2": dict(
+        family="mistral", size="7B", layers=5, topology="v5e:2x2",
+        accel="v5litepod-4", hbm_gb=16, tp=2, pp=1, vpp=None, seq=4096,
+        micro_batch=1, num_micro=4, zero1=False, recompute="selective",
     ),
     # milestone 4: Falcon-40B TP=8 x PP=4 (32 x v5p, 95 GB HBM/chip)
     "falcon-40b-tp8pp4": dict(
@@ -133,6 +143,8 @@ def _model_for(spec):
         use_fused_rmsnorm=False,
         fused_lm_cross_entropy=spec.get("fused_ce", False),
     )
+    if "layers" in spec:
+        common["num_layers"] = spec["layers"]
     if spec["family"] == "gpt":
         from megatron_llm_tpu.models.gpt import GPTModel
         from megatron_llm_tpu.models.gpt2 import gpt2_config
@@ -185,7 +197,7 @@ def _abstract_with_shardings(tree, specs, mesh):
         one, tree, specs, is_leaf=lambda s: isinstance(s, tuple))
 
 
-def run_config(name: str) -> dict:
+def run_config(name: str, hlo_dir: str = "") -> dict:
     spec = CONFIGS[name]
     # off-GCP the metadata server 403s and libtpu retries each variable
     # 30x with backoff before the topology init can proceed — skip it
@@ -297,6 +309,10 @@ def run_config(name: str) -> dict:
     colls = {}
     try:
         txt = compiled.as_text()
+        if hlo_dir:
+            os.makedirs(hlo_dir, exist_ok=True)
+            with open(os.path.join(hlo_dir, name + ".hlo.txt"), "w") as f:
+                f.write(txt)
         if txt and len(txt) < 400 << 20:
             for op in ("all-reduce", "all-gather", "reduce-scatter",
                        "collective-permute", "all-to-all"):
@@ -328,8 +344,11 @@ def main(argv):
     if "--list" in argv:
         print("\n".join(CONFIGS))
         return 0
+    hlo_dir = next((os.path.abspath(a.split("=", 1)[1]) for a in argv
+                    if a.startswith("--hlo_dir=")), "")
+    argv = [a for a in argv if not a.startswith("--hlo_dir=")]
     if argv and argv[0] == "--child":
-        return 0 if run_config(argv[1]).get("fits") else 1
+        return 0 if run_config(argv[1], hlo_dir).get("fits") else 1
 
     names = [a for a in argv if not a.startswith("-")] or list(CONFIGS)
     env = dict(os.environ)
@@ -346,7 +365,8 @@ def main(argv):
         e = dict(env)
         e["TPU_ACCELERATOR_TYPE"] = CONFIGS[name]["accel"]
         r = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--child", name],
+            [sys.executable, os.path.abspath(__file__), "--child", name]
+            + ([f"--hlo_dir={hlo_dir}"] if hlo_dir else []),
             env=e, cwd=REPO)
         rc |= r.returncode
     return rc
