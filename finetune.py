@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -86,6 +86,18 @@ MODEL_DEFAULTS = {
                  layernorm_epsilon=1e-6, rope_sections=[16, 24, 24],
                  dsa_index_heads=16, dsa_index_head_dim=64, dsa_topk=2048,
                  hidden_dropout=0.0, attention_dropout=0.0),
+    # Mellum 2: three sliding-window layers of 1,024 to each full layer,
+    # YaRN on the full layers only; 64 small experts, 8 a token
+    "mellum": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                   use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                   num_experts=64, moe_top_k=8, norm_topk_prob=1,
+                   kv_channels=128, rope_theta=500000.0,
+                   layernorm_epsilon=1e-6, sliding_window_size=1024,
+                   layer_types=["sliding", "sliding", "sliding", "full"],
+                   rope_yarn_scaling=[16.0, 8192.0, 32.0, 1.0,
+                                      1.2772588722239782],
+                   rope_yarn_layer_types=["full"],
+                   hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,
@@ -289,6 +301,10 @@ _CKPT_ARG_MAP = {
     "dsa_index_head_dim": "dsa_index_head_dim",
     "dsa_topk": "dsa_topk",
     "rope_sections": "rope_sections",
+    # forward-math fields of a model with a layer type per layer
+    "layer_types": "layer_types",
+    "rope_yarn_scaling": "rope_yarn_scaling",
+    "rope_yarn_layer_types": "rope_yarn_layer_types",
     # qwen2's QKV-only bias changes the param tree like the MoE fields do
     "add_qkv_bias": "add_qkv_bias",
     # gemma's embedding normalizer changes forward math, not the tree

@@ -108,6 +108,14 @@ def _add_network_size_args(parser):
                    help="Llama-3.1 NTK-by-parts rope remap: factor "
                         "low_freq_factor high_freq_factor "
                         "original_max_position (e.g. 8 1 4 8192)")
+    g.add_argument("--rope_yarn_scaling", type=float, nargs=5, default=None,
+                   metavar=("FACTOR", "ORIG_MAX", "BETA_FAST", "BETA_SLOW",
+                            "ATTENTION_FACTOR"),
+                   help="YaRN rope remap; the last number multiplies cos "
+                        "and sin (e.g. 16 8192 32 1 1.2772588722239782)")
+    g.add_argument("--rope_yarn_layer_types", type=str, nargs="+",
+                   default=None,
+                   help="the --layer_types YaRN applies to (default: all)")
     g.add_argument("--layernorm_epsilon", type=float, default=1e-5)
     g.add_argument("--use_rms_norm", action="store_true")
     g.add_argument("--use_post_ln", action="store_true")
@@ -121,6 +129,10 @@ def _add_network_size_args(parser):
     g.add_argument("--parallel_attn", action="store_true")
     g.add_argument("--parallel_layernorm", action="store_true")
     g.add_argument("--sliding_window_size", type=int, default=None)
+    g.add_argument("--layer_types", type=str, nargs="+", default=None,
+                   help="one period of layer types, repeated over the "
+                        "depth: sliding (--sliding_window_size keys) or "
+                        "full (e.g. sliding sliding sliding full)")
     g.add_argument("--add_qkv_bias", action="store_true",
                    help="bias on the QKV projection only (Qwen2-style)")
     g.add_argument("--qk_norm", action="store_true",
@@ -957,6 +969,14 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         rope_llama3_scaling=(tuple(args.rope_llama3_scaling)
                              if getattr(args, "rope_llama3_scaling", None)
                              else None),
+        rope_yarn_scaling=(tuple(args.rope_yarn_scaling)
+                           if getattr(args, "rope_yarn_scaling", None)
+                           else None),
+        rope_yarn_layer_types=(tuple(args.rope_yarn_layer_types)
+                               if getattr(args, "rope_yarn_layer_types", None)
+                               else None),
+        layer_types=(tuple(args.layer_types)
+                     if getattr(args, "layer_types", None) else None),
         tie_embed_logits=args.tie_embed_logits,
         normalization="rmsnorm" if args.use_rms_norm else "layernorm",
         layernorm_epsilon=args.layernorm_epsilon,

@@ -87,6 +87,10 @@ class ParallelConfig:
         )
 
 
+# what a layer type may be (TransformerConfig.layer_types)
+LAYER_TYPES = ("sliding", "full")
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     """Architecture hyper-parameters.
@@ -119,6 +123,13 @@ class TransformerConfig:
     # original_max_position) — a tuple so the config stays hashable
     # (it rides jit static args).
     rope_llama3_scaling: Optional[Tuple[float, float, float, int]] = None
+    # YaRN rope remap (HF rope_type 'yarn'): (factor,
+    # original_max_position, beta_fast, beta_slow, attention_factor); the
+    # last multiplies cos and sin.  ``rope_yarn_layer_types`` names the
+    # layer types it applies to (None: every layer); the others keep the
+    # plain embedding
+    rope_yarn_scaling: Optional[Tuple[float, int, float, float, float]] = None
+    rope_yarn_layer_types: Optional[Tuple[str, ...]] = None
     # reference: --no_tie_embed_logits -> untied lm_head
     # (megatron/model/language_model.py:436-457)
     tie_embed_logits: bool = True
@@ -145,8 +156,14 @@ class TransformerConfig:
     parallel_attn: bool = False
     # Falcon-40B parallel layernorm (reference: transformer.py:804-845)
     parallel_layernorm: bool = False
-    # Mistral sliding-window attention (reference: transformer.py:528-537)
+    # Mistral sliding-window attention (reference: transformer.py:528-537).
+    # With ``layer_types`` it is the window of the 'sliding' layers only
     sliding_window_size: Optional[int] = None
+    # a layer type per layer, as data: ONE period of types, repeated over
+    # the depth ('sliding': a query attends the sliding_window_size keys
+    # up to itself; 'full': every key up to itself).  None: the stack is
+    # one period of one type, the window (if any) on every layer
+    layer_types: Optional[Tuple[str, ...]] = None
 
     # --- dropout / init ---
     hidden_dropout: float = 0.1
@@ -292,6 +309,33 @@ class TransformerConfig:
             if self.position_embedding_type != PositionEmbeddingType.rotary:
                 raise ValueError("sparse attention (dsa_index_heads > 0) "
                                  "needs the rotary position embedding")
+        if self.layer_types is not None:
+            types = tuple(str(t) for t in self.layer_types)
+            object.__setattr__(self, "layer_types", types)
+            if not types or set(types) - set(LAYER_TYPES):
+                raise ValueError(f"layer_types are one period of "
+                                 f"{'|'.join(LAYER_TYPES)}, got {types!r}")
+            if self.num_layers % len(types):
+                raise ValueError(
+                    f"num_layers ({self.num_layers}) must be whole periods "
+                    f"of the {len(types)} layer_types")
+            if "sliding" in types and self.sliding_window_size is None:
+                raise ValueError("a 'sliding' layer type needs "
+                                 "sliding_window_size")
+            if self.dsa_index_heads > 0 or self.rope_sections is not None:
+                raise ValueError("layer_types are not implemented with "
+                                 "sparse attention or sectioned rope")
+        if self.rope_yarn_scaling is not None:
+            f, orig, fast, slow, att = self.rope_yarn_scaling
+            object.__setattr__(self, "rope_yarn_scaling", (
+                float(f), int(orig), float(fast), float(slow), float(att)))
+        if self.rope_yarn_layer_types is not None:
+            object.__setattr__(self, "rope_yarn_layer_types", tuple(
+                str(t) for t in self.rope_yarn_layer_types))
+            if self.layer_types is None or (
+                    set(self.rope_yarn_layer_types) - set(self.layer_types)):
+                raise ValueError("rope_yarn_layer_types names types of "
+                                 "layer_types")
         if self.qk_norm and self.qk_norm_per_head:
             raise ValueError("qk_norm (over the whole projection) and "
                              "qk_norm_per_head are two forms of one norm: "
@@ -320,6 +364,30 @@ class TransformerConfig:
     @property
     def expert_hidden_size(self) -> int:
         return self.moe_ffn_hidden_size or self.ffn_hidden_size
+
+    @property
+    def layer_period(self) -> Tuple[Optional[str], ...]:
+        """One period of the stack's layer types: layer i is of type
+        ``layer_period[i % len(layer_period)]``; ``(None,)`` for a stack
+        of one type."""
+        return self.layer_types or (None,)
+
+    def attention_of(self, layer_type: Optional[str]):
+        """(window, YaRN scaling) of a layer of ``layer_type``, each None
+        where the type has none: what ``attention()`` computes by.  For a
+        stack of one type (``layer_type`` None) these are the config's
+        own fields."""
+        if self.layer_types is None:
+            return self.sliding_window_size, self.rope_yarn_scaling
+        if layer_type not in self.layer_types:
+            raise ValueError(
+                f"a model with layer_types {self.layer_types} was run "
+                f"through a path that gives its layers no type "
+                f"(got {layer_type!r})")
+        yarn_on = self.rope_yarn_layer_types
+        return (self.sliding_window_size if layer_type == "sliding" else None,
+                self.rope_yarn_scaling
+                if yarn_on is None or layer_type in yarn_on else None)
 
     @property
     def num_query_groups(self) -> int:

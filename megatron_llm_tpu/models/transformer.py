@@ -282,11 +282,14 @@ def core_attention(
     attention_mask: Optional[jax.Array],
     dropout_key: Optional[jax.Array],
     train: bool,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Unfused attention (reference ``CoreAttention``, transformer.py:144-277):
     scaled QK^T -> scale-mask-softmax -> dropout -> PV.  GQA contracts
     group-shared K/V without materialising the head broadcast
-    (the reference broadcasts K/V to all Q heads, :458-465)."""
+    (the reference broadcasts K/V to all Q heads, :458-465).  With no
+    ``attention_mask`` the mask is causal, within ``window`` keys if
+    given."""
     b, sq, nh, d = q.shape
     ng = k.shape[2]
     qpg = nh // ng
@@ -298,8 +301,8 @@ def core_attention(
     scores = jnp.einsum("bsgpd,btgd->bgpst", qg, k)
 
     if attention_mask is None:
-        if cfg.sliding_window_size is not None:
-            mask = sliding_window_mask(sq, sk, cfg.sliding_window_size)
+        if window is not None:
+            mask = sliding_window_mask(sq, sk, window)
         else:
             mask = causal_mask(sq, sk)
         mask = mask[None, None, None]  # [1,1,1,sq,sk]
@@ -331,13 +334,17 @@ def attention(
     train: bool,
     sequence_parallel: bool = False,
     kv_cache=None,
+    layer_type: Optional[str] = None,
 ) -> jax.Array:
     """Full attention block (reference ``ParallelAttention``,
     transformer.py:280-560): column-parallel QKV, RoPE, core/flash attention,
     row-parallel dense.  ``kv_cache`` enables incremental decoding
     (reference inference path :412-505): a ``PagedKVCache`` (the serving
     engine) or one of the legacy decode stack's dicts with 'k','v','index'
-    (``text_generation/generation.py::init_kv_caches``)."""
+    (``text_generation/generation.py::init_kv_caches``).  ``layer_type``:
+    this layer's, of a model with ``cfg.layer_types``: it decides the
+    window and the rotary variant (``cfg.attention_of``)."""
+    window, yarn = cfg.attention_of(layer_type)
     mixed = column_parallel_linear(
         x, params["query_key_value"],
         out_logical="heads",
@@ -362,16 +369,20 @@ def attention(
                          eps=cfg.layernorm_epsilon)
 
     index = None
-    if cfg.rope_sections is not None or cfg.dsa_index_heads > 0:
+    if (cfg.rope_sections is not None or cfg.dsa_index_heads > 0
+            or cfg.layer_types is not None):
         # positions are taken as given, with no table: [b, s], or
         # [streams, b, s] for the sectioned embedding (a text token's
-        # streams coincide and this is the plain embedding)
+        # streams coincide and this is the plain embedding); a layer
+        # type's own variant (YaRN or none) with no table a type
         positions = position_ids
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(q.shape[1])[None],
                                          q.shape[:2])
-        q = apply_rotary_at(q, positions, cfg.rope_theta, cfg.rope_sections)
-        k = apply_rotary_at(k, positions, cfg.rope_theta, cfg.rope_sections)
+        q = apply_rotary_at(q, positions, cfg.rope_theta, cfg.rope_sections,
+                            yarn)
+        k = apply_rotary_at(k, positions, cfg.rope_theta, cfg.rope_sections,
+                            yarn)
         if cfg.dsa_index_heads > 0:
             with jax.named_scope("dsa_indexer"):
                 index = indexer_projections(x, params["indexer"], cfg,
@@ -387,8 +398,7 @@ def attention(
         # the serving engine's paged cache (ops/paged_kv.py owns it):
         # scatter this call's K/V (and the indexer's keys) into the
         # pool, attend through the path the cache carries
-        paged_ctx, new_cache = kv_cache.attend(
-            q, k, v, cfg.sliding_window_size, index=index)
+        paged_ctx, new_cache = kv_cache.attend(q, k, v, window, index=index)
     elif index is not None:
         # the cache-less forward selects too: what tier-1 holds the
         # paged programs against
@@ -423,9 +433,8 @@ def attention(
         pos = idx + jnp.arange(n)                # query positions
         key_pos = jnp.concatenate([cache_pos, pos])
         valid = (key_pos[None, :] >= 0) & (key_pos[None, :] <= pos[:, None])
-        window = cfg.sliding_window_size
         assert window is not None, \
-            "rolling KV caches require a sliding-window model"
+            "rolling KV caches require a sliding window on every layer"
         valid &= key_pos[None, :] > pos[:, None] - window
         mask = ~valid[None, None]
         # write the chunk into the ring AFTER the read view is formed; for
@@ -465,8 +474,8 @@ def attention(
         sk = ckq.shape[1]
         pos = idx + jnp.arange(k.shape[1])
         valid = jnp.arange(sk)[None, :] <= pos[:, None]  # [sq, sk]
-        if cfg.sliding_window_size is not None:
-            valid &= jnp.arange(sk)[None, :] > pos[:, None] - cfg.sliding_window_size
+        if window is not None:
+            valid &= jnp.arange(sk)[None, :] > pos[:, None] - window
         mask = ~valid[None, None]  # [1,1,sq,sk]
         cdt = k.dtype
         k = ckq.astype(cdt) * cks[..., None].astype(cdt)
@@ -483,8 +492,8 @@ def attention(
         sk = ck.shape[1]
         pos = idx + jnp.arange(k.shape[1])
         valid = jnp.arange(sk)[None, :] <= pos[:, None]  # [sq, sk]
-        if cfg.sliding_window_size is not None:
-            valid &= jnp.arange(sk)[None, :] > pos[:, None] - cfg.sliding_window_size
+        if window is not None:
+            valid &= jnp.arange(sk)[None, :] > pos[:, None] - window
         mask = ~valid[None, None]  # [1,1,sq,sk]
         k, v = ck, cv
         attention_mask = jnp.broadcast_to(mask, (x.shape[0],) + mask.shape[1:])
@@ -534,7 +543,7 @@ def attention(
             ctx = ulysses_context_attention(
                 q, k, v,
                 causal=True,
-                sliding_window=cfg.sliding_window_size,
+                sliding_window=window,
                 softmax_scale=1.0 / math.sqrt(cfg.head_dim),
             )
         elif algo == "zigzag" and (q.shape[1] // cp_size) % 2 == 0:
@@ -545,14 +554,14 @@ def attention(
             ctx = zigzag_context_attention(
                 q, k, v,
                 causal=True,
-                sliding_window=cfg.sliding_window_size,
+                sliding_window=window,
                 softmax_scale=1.0 / math.sqrt(cfg.head_dim),
             )
         else:
             ctx = context_parallel_attention(
                 q, k, v,
                 causal=True,
-                sliding_window=cfg.sliding_window_size,
+                sliding_window=window,
                 softmax_scale=1.0 / math.sqrt(cfg.head_dim),
             )
     elif use_flash:
@@ -565,7 +574,7 @@ def attention(
         ctx = sharded_flash_attention(
             q, k, v,
             causal=True,
-            sliding_window=cfg.sliding_window_size,
+            sliding_window=window,
             softmax_scale=1.0 / math.sqrt(cfg.head_dim),
         )
     else:
@@ -583,12 +592,12 @@ def attention(
             ctx = chunked_causal_attention(
                 q, k, v,
                 causal=True,
-                sliding_window=cfg.sliding_window_size,
+                sliding_window=window,
                 softmax_scale=1.0 / math.sqrt(cfg.head_dim),
             )
         else:
             ctx = core_attention(q, k, v, cfg, attention_mask, dropout_key,
-                                 train)
+                                 train, window)
 
     b, s = ctx.shape[:2]
     ctx = ctx.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
@@ -716,6 +725,7 @@ def transformer_layer(
     encoder_output: Optional[jax.Array] = None,
     enc_dec_mask: Optional[jax.Array] = None,
     moe_layer: Optional[int] = None,
+    layer_type: Optional[str] = None,
 ):
     """One decoder layer (reference ``ParallelTransformerLayer``,
     transformer.py:612-846), supporting:
@@ -731,7 +741,8 @@ def transformer_layer(
     ``new_cache`` is None when ``kv_cache`` is None, ``moe_aux`` is None
     for dense (non-MoE) configs.  With ``moe_layer`` the experts' weights
     in ``params`` are every layer's, stacked, and this layer is that one
-    of them (``moe_mlp_dropless``).
+    of them (``moe_mlp_dropless``).  ``layer_type``: the layer's, of a
+    model with ``cfg.layer_types`` (``attention`` says what it decides).
     """
     is_decoder = "inter_attention" in params and encoder_output is not None
     if is_decoder and cfg.parallel_attn:
@@ -766,7 +777,7 @@ def transformer_layer(
     attn_kw = dict(
         freqs=freqs, attention_mask=attention_mask, position_ids=position_ids,
         dropout_key=k_attn_drop, train=train, sequence_parallel=sequence_parallel,
-        kv_cache=kv_cache,
+        kv_cache=kv_cache, layer_type=layer_type,
     )
     # named_scope: trace-time profiler annotation (telemetry.py --profile)
     with jax.named_scope("attention"):
@@ -881,9 +892,16 @@ def transformer_stack(
     ``ParallelTransformer.forward``, transformer.py:1188-1282) and apply the
     final norm.  Recompute policy per cfg.recompute_granularity
     (:1110-1176): 'uniform'/'block' -> full per-layer remat; 'selective' ->
-    save-nothing-but-matmul-free recompute of core attention via policy."""
+    save-nothing-but-matmul-free recompute of core attention via policy.
+
+    A model with a layer type per layer (``cfg.layer_types``: one period
+    of types) scans over PERIODS with a period's layers unrolled in the
+    body, each of its own type, so the trace holds one period whatever
+    the depth; a model of one type is one period of one layer."""
     layers = stack_params["layers"]
     L = cfg.num_layers
+    period = cfg.layer_period
+    P = len(period)
     # Per-layer dropout rates are traced (scanned) only for lima dropout;
     # otherwise the static config rate short-circuits at trace time.
     dropout_rates = _lima_dropout_rates(cfg) if cfg.lima_dropout else None
@@ -896,22 +914,28 @@ def transformer_stack(
     @jax.named_scope("transformer_layer")
     def body(carry, scanned):
         h, aux_acc = carry if moe_on else (carry, None)
-        if dropout_rates is not None:
-            layer_p, key, rate = scanned
-        else:
-            layer_p, key = scanned
-            rate = None
-        out, _, moe_aux = transformer_layer(
-            h, layer_p, cfg,
-            freqs=freqs, attention_mask=attention_mask, position_ids=position_ids,
-            rng_key=key if rng_key is not None else None,
-            train=train, sequence_parallel=sequence_parallel,
-            hidden_dropout=rate,
-            encoder_output=encoder_output, enc_dec_mask=enc_dec_mask,
-        )
-        if moe_on:
-            return (out, aux_acc + moe_aux), None
-        return out, None
+        for j, layer_type in enumerate(period):
+            # a period's layer j: scanned leaves are [P, ...] there
+            one = (scanned if P == 1 else
+                   jax.tree_util.tree_map(lambda a: a[j], scanned))
+            if dropout_rates is not None:
+                layer_p, key, rate = one
+            else:
+                layer_p, key = one
+                rate = None
+            h, _, moe_aux = transformer_layer(
+                h, layer_p, cfg,
+                freqs=freqs, attention_mask=attention_mask,
+                position_ids=position_ids,
+                rng_key=key if rng_key is not None else None,
+                train=train, sequence_parallel=sequence_parallel,
+                hidden_dropout=rate,
+                encoder_output=encoder_output, enc_dec_mask=enc_dec_mask,
+                layer_type=layer_type,
+            )
+            if moe_on:
+                aux_acc = aux_acc + moe_aux
+        return ((h, aux_acc) if moe_on else h), None
 
     if cfg.recompute_granularity in ("uniform", "block", "full"):
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
@@ -941,7 +965,7 @@ def transformer_stack(
                 freqs=freqs, attention_mask=attention_mask,
                 position_ids=position_ids, rng_key=None, train=False,
                 sequence_parallel=sequence_parallel, kv_cache=kv_caches[i],
-                moe_layer=i if moe_on else None,
+                moe_layer=i if moe_on else None, layer_type=period[i % P],
             )
             new_caches.append(c)
         h = apply_norm(
@@ -955,6 +979,10 @@ def transformer_stack(
         if dropout_rates is not None
         else (layers, layer_keys)
     )
+    if P > 1:
+        # [L, ...] -> [L / P, P, ...]: the scan's step is a period
+        scanned = jax.tree_util.tree_map(
+            lambda a: a.reshape((L // P, P) + a.shape[1:]), scanned)
     init_carry = (x, jnp.zeros((2,), jnp.float32)) if moe_on else x
     carry, _ = jax.lax.scan(body, init_carry, scanned)
     h, moe_aux = carry if moe_on else (carry, None)
@@ -966,7 +994,11 @@ def transformer_stack(
 
 
 def rotary_freqs(cfg: TransformerConfig, seq_len: Optional[int] = None):
-    if cfg.position_embedding_type != PositionEmbeddingType.rotary:
+    """The stack's rotary table (cos, sin); None where there is none: no
+    rotary embedding, or a layer type per layer, whose layers rotate at
+    the given positions by their own type's variant (``attention``)."""
+    if (cfg.position_embedding_type != PositionEmbeddingType.rotary
+            or cfg.layer_types is not None):
         return None
     rot_d = int(cfg.head_dim * cfg.rotary_percent)
     rot_d -= rot_d % 2
@@ -979,4 +1011,5 @@ def rotary_freqs(cfg: TransformerConfig, seq_len: Optional[int] = None):
         llama3_scaling=(dict(zip(
             ("factor", "low_freq_factor", "high_freq_factor",
              "original_max_position"), l3)) if l3 else None),
+        yarn=cfg.rope_yarn_scaling,
     )

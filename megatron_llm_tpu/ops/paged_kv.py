@@ -23,6 +23,16 @@ pools (a list, one a layer) as an opaque pytree; ``init_pools``,
 ``block_bytes`` and the three page programs' bodies (``copy_page``,
 ``fetch_page``, ``load_page``) are all it needs of them.
 
+A model with a layer type per layer (``cfg.layer_types``) has TWO GROUPS
+of pools: its ``full`` layers keep a request's pages for its whole
+length, its ``sliding`` layers (group ``window``) only the pages a
+future query's window can still reach, so the window group has its own,
+smaller, number of blocks, its own free list and its own block table a
+slot (``serving/kv_blocks.py``).  A page index means a page of every
+layer OF ONE GROUP; ``layer_groups`` says which layer is of which.  A
+model of one layer type has one group and its pools are as they always
+were, whatever its window.
+
 A :class:`PagedKVCache` is what the model is handed for one step of one
 layer: the pool plus the step's state (block tables, context lengths,
 valid lengths) and, as STATIC data, the path that reads the pool:
@@ -74,20 +84,52 @@ def resolve_kernel(requested: str, one_device: bool) -> str:
     return "xla"
 
 
+FULL, WINDOW = "full", "window"
+
+
+def layer_groups(cfg) -> Optional[tuple]:
+    """The pool group of each layer of a model with a layer type per
+    layer (``FULL`` | ``WINDOW``); None for a model of one type, which
+    has one group."""
+    if cfg.layer_types is None:
+        return None
+    period = cfg.layer_period
+    return tuple(WINDOW if period[i % len(period)] == "sliding" else FULL
+                 for i in range(cfg.num_layers))
+
+
+def window_pages_bound(window: int, chunk: int, block_size: int) -> int:
+    """The most pages a request holds in the window group: the window,
+    the tokens one launch writes (``chunk``) and one page, since neither
+    end of that span need lie on a page's edge."""
+    return -(-(window + chunk) // block_size) + 1
+
+
 def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
-               quantized: bool = False) -> List[dict]:
+               quantized: bool = False,
+               window_blocks: Optional[int] = None) -> List[dict]:
     """One pool a layer for a model of config ``cfg``: keys and values in
     the compute dtype, or int8 with fp32 scales when ``quantized`` (halves
     the KV bytes a decode step reads, against bf16); with a
-    sparse-attention indexer, its keys beside them (``index_pages``)."""
+    sparse-attention indexer, its keys beside them (``index_pages``).  A
+    layer's pool is sized by its group: ``window_blocks`` for the window
+    group of a model with a layer type per layer, ``num_blocks`` for
+    every other layer."""
     dtype = dtype or cfg.compute_jnp_dtype
-    shape = (num_blocks, block_size, cfg.num_query_groups, cfg.head_dim)
     indexed = cfg.dsa_index_heads > 0
+    groups = layer_groups(cfg)
     if indexed and quantized:
         raise ValueError("sparse attention (dsa_index_heads > 0) is not "
                          "implemented over the int8 KV pool")
+    if groups is not None and quantized:
+        raise ValueError("a layer type per layer (layer_types) is not "
+                         "implemented over the int8 KV pool")
+    if groups is not None and WINDOW in groups and not window_blocks:
+        raise ValueError("a model with sliding layers among its "
+                         "layer_types needs window_blocks")
 
-    def pool():
+    def pool(blocks):
+        shape = (blocks, block_size, cfg.num_query_groups, cfg.head_dim)
         if quantized:
             return {"k_pages_q": jnp.zeros(shape, jnp.int8),
                     "k_pages_scale": jnp.ones(shape[:3], jnp.float32),
@@ -101,7 +143,8 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                 dtype)
         return kv
 
-    return [pool() for _ in range(cfg.num_layers)]
+    return [pool(window_blocks if groups and groups[i] == WINDOW
+                 else num_blocks) for i in range(cfg.num_layers)]
 
 
 def _arrays(pool: dict):
@@ -113,7 +156,9 @@ def _arrays(pool: dict):
 
 
 def block_bytes(pools) -> int:
-    """Bytes of one block across every layer's pool."""
+    """Bytes of one block across every layer's pool (of a model with two
+    groups: across the layers of ``pools``, which the caller picks by
+    group)."""
     return sum(math.prod(a.shape[1:]) * a.dtype.itemsize
                for a in jax.tree_util.tree_leaves(pools))
 
@@ -151,7 +196,12 @@ class PagedKVCache:
     ``moe_counts`` is an OUTPUT a sparse model's layer leaves for the
     engine's counters: ``[E]`` live (token, choice) assignments the
     layer's router made this step; None on the way in and for a dense
-    model.  ``kernel`` is static: ``'pallas' | 'xla'``."""
+    model.  ``kernel`` is static: ``'pallas' | 'xla'``; so is ``group``,
+    the layer's pool group (``FULL`` for every layer of a model of one
+    type): ``block_tables`` is that group's table, and the window
+    group's walk launches under its own kernel names
+    (``paged_attention_decode_window``, ``paged_attention_prefill_window``)
+    so that a trace tells the two kinds of attention apart."""
 
     pool: dict
     block_tables: jax.Array
@@ -159,6 +209,7 @@ class PagedKVCache:
     valid_lens: jax.Array
     kernel: str = dataclasses.field(metadata=dict(static=True))
     moe_counts: Optional[jax.Array] = None
+    group: str = dataclasses.field(default=FULL, metadata=dict(static=True))
 
     def live(self, n: int) -> jax.Array:
         """[b, n] bool: which of this call's n tokens a row are real."""
@@ -223,7 +274,8 @@ class PagedKVCache:
             # the chunk's own K/V were just scattered, so the kernel's
             # causal walk covers history AND the in-flight chunk
             kw = dict(valid_lens=vlen, k_scales=k_scales, v_scales=v_scales,
-                      softmax_scale=scale, sliding_window=sliding_window)
+                      softmax_scale=scale, sliding_window=sliding_window,
+                      name_suffix="_window" if self.group == WINDOW else "")
             if n == 1:
                 ctx = _pa.paged_attention_decode(
                     q[:, 0], kp, vp, bt, ctx_lens, **kw)[:, None]
@@ -271,13 +323,20 @@ class PagedKVCache:
 
 
 def step_caches(pools, block_tables, context_lens, valid_lens,
-                kernel: Optional[str] = None) -> List[PagedKVCache]:
+                kernel: Optional[str] = None,
+                groups: Optional[tuple] = None) -> List[PagedKVCache]:
     """Every layer's cache for one step over ``pools``.  ``kernel`` None
     is what ``auto`` means for a program on one device: the kernel where
-    it can run (whoever jits for several devices says ``'xla'``)."""
+    it can run (whoever jits for several devices says ``'xla'``).  With
+    ``groups`` (``layer_groups``) ``block_tables`` is a dict of a table a
+    group and each layer carries its own group's."""
     kernel = kernel or resolve_kernel("auto", one_device=True)
-    return [PagedKVCache(p, block_tables, context_lens, valid_lens,
-                         kernel=kernel) for p in pools]
+    if groups is None:
+        return [PagedKVCache(p, block_tables, context_lens, valid_lens,
+                             kernel=kernel) for p in pools]
+    return [PagedKVCache(p, block_tables[g], context_lens, valid_lens,
+                         kernel=kernel, group=g)
+            for p, g in zip(pools, groups)]
 
 
 def pools_of(caches: List[PagedKVCache]) -> List[dict]:

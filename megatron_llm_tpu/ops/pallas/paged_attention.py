@@ -88,6 +88,13 @@ NEG_INF = -1e30
 # default prefill q-block rows (clipped to the chunk; kept MXU-sized so
 # the fp32 scratch [block_q*nh, d] stays well inside VMEM)
 _PREFILL_BLOCK_Q = 128
+# and no more (q-block row, head) pairs than this: the fp32 scratch
+# [block_q * nh, d] and a group's scores [block_q * qpg, T] grow with
+# them, and at 128 rows of 32 heads of 128 the compiler refuses the
+# kernel (21.5 MB of the 16 MB a kernel may use on a v5e).  A chunk of
+# 64 rows of 32 heads, the most any chunk had until [1, 512] chunks came
+# here, is exactly this many
+_PREFILL_BLOCK_ROWS = 2048
 
 
 def _use_pallas() -> bool:
@@ -342,11 +349,13 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
 
 def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
                valid_lens, k_scales, v_scales, *, scale, window, block_q,
-               name):
+               name, name_suffix=""):
     """q [S, C, nh, d] with block_q | C (decode is C == block_q == 1);
     the pools stay in HBM and the kernel fetches pages itself.  ``name``
-    is the kernel's name in a profile (``_quant`` appended for the int8
-    pools); ``valid_lens`` None = every slot has tokens in this call."""
+    is the kernel's name in a profile (``name_suffix``, the caller's
+    ``_window`` for a window group's walk, and ``_quant`` for the int8
+    pools appended); ``valid_lens`` None = every slot has tokens in this
+    call."""
     if valid_lens is None:
         valid_lens = jnp.ones_like(context_lens)
     S, C, nh, d = q.shape
@@ -385,7 +394,7 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
     return pl.pallas_call(
         functools.partial(_walk_body, quantized=quantized, scale=scale,
                           window=window, qpg=nh // g),
-        name=name + ("_quant" if quantized else ""),
+        name=name + name_suffix + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, d), q.dtype),
         interpret=_INTERPRET,
@@ -409,6 +418,7 @@ def paged_attention_decode(
     v_scales: Optional[jax.Array] = None,
     softmax_scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
+    name_suffix: str = "",
 ) -> jax.Array:
     """Ragged paged attention for one decode token per slot.
 
@@ -417,7 +427,9 @@ def paged_attention_decode(
     dequant); ``block_tables``: [S, M]; ``context_lens``: [S] query
     positions; ``valid_lens``: [S], 0 for a slot that is not decoding
     (its pages are not touched and its output row is unspecified; None
-    = every slot decodes).  Returns [S, nh, d] in ``q.dtype``."""
+    = every slot decodes).  ``name_suffix`` is appended to the kernel's
+    name in a profile (one walk, told apart by who launches it).
+    Returns [S, nh, d] in ``q.dtype``."""
     assert q.ndim == 3 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     assert q.shape[0] == block_tables.shape[0] == context_lens.shape[0]
     assert (k_scales is None) == (v_scales is None)
@@ -431,7 +443,8 @@ def paged_attention_decode(
     return _walk_call(
         q[:, None], k_pages, v_pages, block_tables, context_lens, valid_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
-        block_q=1, name="paged_attention_decode")[:, 0]
+        block_q=1, name="paged_attention_decode",
+        name_suffix=name_suffix)[:, 0]
 
 
 def paged_attention_prefill(
@@ -447,6 +460,7 @@ def paged_attention_prefill(
     softmax_scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
     block_q: Optional[int] = None,
+    name_suffix: str = "",
 ) -> jax.Array:
     """Ragged paged attention for one prefill chunk per slot.
 
@@ -459,8 +473,8 @@ def paged_attention_prefill(
     dense path (the engine only reads the last valid row's logits).
     ``valid_lens`` [S]: real tokens of each slot's chunk; a slot with 0
     (an idle row of the speculative verify step) is skipped as in
-    :func:`paged_attention_decode`.  Returns [S, C, nh, d] in
-    ``q.dtype``."""
+    :func:`paged_attention_decode`; ``name_suffix`` as there.  Returns
+    [S, C, nh, d] in ``q.dtype``."""
     assert q.ndim == 4 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     assert q.shape[0] == block_tables.shape[0] == context_lens.shape[0]
     assert (k_scales is None) == (v_scales is None)
@@ -471,10 +485,11 @@ def paged_attention_prefill(
             q, k_pages, v_pages, block_tables, context_lens, valid_lens,
             k_scales, v_scales, softmax_scale, sliding_window)
     C = q.shape[1]
-    bq = min(block_q or _PREFILL_BLOCK_Q, C)
+    bq = min(block_q or max(1, min(_PREFILL_BLOCK_Q,
+                                   _PREFILL_BLOCK_ROWS // q.shape[2])), C)
     while C % bq:       # q-blocks must tile the chunk exactly; static
         bq -= 1         # (power-of-two chunks keep the full block size)
     return _walk_call(
         q, k_pages, v_pages, block_tables, context_lens, valid_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
-        block_q=bq, name="paged_attention_prefill")
+        block_q=bq, name="paged_attention_prefill", name_suffix=name_suffix)

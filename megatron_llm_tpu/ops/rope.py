@@ -17,6 +17,7 @@ converter's rotary permutation in ``weights_conversion/hf_to_megatron.py:
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -50,22 +51,77 @@ def llama3_scale_freqs(
     return jnp.where(in_band, interp, out)
 
 
+def yarn_scale_freqs(
+    freqs: jax.Array,
+    theta: float,
+    factor: float,
+    original_max_position: int,
+    beta_fast: float = 32.0,
+    beta_slow: float = 1.0,
+) -> jax.Array:
+    """YaRN's frequency remap (the published scheme, as in HF
+    ``modeling_rope_utils._compute_yarn_parameters``) of the ``dim / 2``
+    pair frequencies ``theta^(-2i/dim)``: pair i keeps its frequency up
+    to ``low`` (it turns more than ``beta_fast`` times within the
+    original context), is divided by ``factor`` from ``high`` on (fewer
+    than ``beta_slow`` turns), and goes linearly from the one to the
+    other between; ``c(b) = dim ln(original_max / (2 pi b)) / (2 ln
+    theta)`` is the pair that turns b times, ``low = floor(c(beta_fast))``
+    and ``high = ceil(c(beta_slow))``, both within [0, dim - 1]."""
+    dim = 2 * freqs.shape[0]
+
+    def pair_turning(b):
+        return (dim * math.log(original_max_position / (b * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001               # as published: no division by zero
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return (freqs / factor) * ramp + freqs * (1.0 - ramp)
+
+
+def _yarn(freqs, theta, yarn):
+    """``freqs`` remapped by ``yarn`` = (factor, original_max_position,
+    beta_fast, beta_slow, attention_factor), and the factor cos and sin
+    are multiplied by; (freqs, 1.0) for None."""
+    if yarn is None:
+        return freqs, 1.0
+    factor, orig, fast, slow, attention_factor = yarn
+    return (yarn_scale_freqs(freqs, theta, factor, orig, fast, slow),
+            attention_factor)
+
+
 def precompute_freqs_cis(
     dim: int,
     end: int,
     theta: float = 10000.0,
     scaling_factor: float = 1.0,
     llama3_scaling: dict | None = None,
+    yarn: Optional[Sequence[float]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (cos, sin), each [end, dim // 2], fp32.
 
     reference: positional_embeddings.py:7-14 (including ``t /= scaling_factor``).
     ``llama3_scaling``: optional kwargs for :func:`llama3_scale_freqs`
     (Llama-3.1+ checkpoints; mutually exclusive with linear scaling).
+    ``yarn``: (factor, original_max_position, beta_fast, beta_slow,
+    attention_factor) for :func:`yarn_scale_freqs`; cos and sin come
+    multiplied by the last (so q.k carries its square, and the softmax
+    scale is left alone).
     """
     freqs = 1.0 / (
         theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)[: dim // 2] / dim)
     )
+    if yarn is not None:
+        if scaling_factor != 1.0 or llama3_scaling:
+            raise ValueError("rope yarn scaling excludes the linear and "
+                             "the llama3 scaling")
+        freqs, mult = _yarn(freqs, theta, yarn)
+        ang = jnp.outer(jnp.arange(end, dtype=jnp.float32), freqs)
+        return jnp.cos(ang) * mult, jnp.sin(ang) * mult
     if llama3_scaling:
         if scaling_factor != 1.0:
             raise ValueError(
@@ -147,6 +203,7 @@ def apply_rotary_at(
     position_ids: jax.Array,
     theta: float,
     sections: Optional[Sequence[int]] = None,
+    yarn: Optional[Sequence[float]] = None,
 ) -> jax.Array:
     """Rotate the interleaved pairs of ``x`` [..., s, heads, d] at
     explicit positions, with no table: pair i turns by
@@ -154,9 +211,11 @@ def apply_rotary_at(
     ``[streams, ..., s]`` with ``sections``: pair i then follows the
     stream its section names (the sectioned rotary embedding of
     multimodal models).  Streams that coincide, as a text token's do,
-    give the plain embedding."""
+    give the plain embedding.  ``yarn``: as :func:`precompute_freqs_cis`
+    takes it."""
     d = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv, mult = _yarn(inv, theta, yarn)
     pos = position_ids.astype(jnp.float32)
     if sections is not None and position_ids.ndim == x.ndim - 1:
         # [streams, ..., s] -> each pair's own stream's position
@@ -165,5 +224,5 @@ def apply_rotary_at(
         ang = pos * inv
     else:
         ang = pos[..., None] * inv                               # [..., s, d/2]
-    return rotate_pairs(x, jnp.cos(ang)[..., None, :],
-                        jnp.sin(ang)[..., None, :])
+    return rotate_pairs(x, (jnp.cos(ang) * mult)[..., None, :],
+                        (jnp.sin(ang) * mult)[..., None, :])

@@ -78,6 +78,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from megatron_llm_tpu import telemetry, tracing
 from megatron_llm_tpu.models.language_model import language_model_forward
@@ -86,6 +87,7 @@ from megatron_llm_tpu.serving.cache_observatory import CacheObservatory
 from megatron_llm_tpu.serving.drafter import draft_budget, lookup_draft
 from megatron_llm_tpu.serving.kv_blocks import (
     BlockManager,
+    WindowGroup,
     derive_num_blocks,
 )
 from megatron_llm_tpu.serving.request import (
@@ -101,6 +103,7 @@ from megatron_llm_tpu.serving.request import (
     SamplingParams,
 )
 from megatron_llm_tpu.serving.loop_profiler import (
+    KV_FIELDS,
     MOE_FIELDS,
     DispatchRecord,
     LoopProfiler,
@@ -281,6 +284,32 @@ class InferenceEngine:
             raise ValueError(
                 "sparse attention (dsa_index_heads > 0) is not implemented "
                 "for the speculative verify step")
+        # a layer type per layer: two groups of pools (ops/paged_kv.py),
+        # the window group sized for every slot at its bound.  What the
+        # second group is not built for is refused by name, and a prefix
+        # is not adopted: its window pages are not kept
+        self._layer_groups = paged_kv.layer_groups(mcfg)
+        self._window_bound = self._window_blocks = 0
+        if self._layer_groups is not None:
+            for on, what in ((self.speculative, "the speculative verify "
+                              "step"), (cfg.int8_kv_cache, "the int8 KV "
+                              "pool"), (cfg.host_cache_bytes > 0, "the "
+                              "host KV tier")):
+                if on:
+                    raise ValueError("a layer type per layer (layer_types) "
+                                     f"is not implemented for {what}")
+            if cfg.prefix_cache:
+                print(" * a layer type per layer: the prefix cache adopts "
+                      "nothing (a prefix's window pages are not kept)",
+                      flush=True)
+                cfg.prefix_cache = False
+            if paged_kv.WINDOW in self._layer_groups:
+                self._window_bound = paged_kv.window_pages_bound(
+                    int(mcfg.sliding_window_size), cfg.prefill_chunk,
+                    cfg.block_size)
+                self._window_blocks = (
+                    cfg.num_slots * min(self._window_bound,
+                                        self._max_blocks_per_slot) + 1)
 
         # cache observatory (serving/cache_observatory.py): per-prefix
         # heat, eviction forensics, ghost capacity tiers.  Engine-
@@ -341,6 +370,16 @@ class InferenceEngine:
         # two fields of the same names)
         self.dsa_keys_live = 0
         self.dsa_keys_selected = 0
+        # the two groups of a model with a layer type per layer, summed
+        # over launches (the record's fields of the same names), and a
+        # block's bytes over the layers of each group (full, window)
+        for f in KV_FIELDS:
+            setattr(self, f, 0)
+        groups = self._layer_groups or ()
+        self._group_block_bytes = tuple(
+            paged_kv.block_bytes([p for p, g in zip(self._st.pages, groups)
+                                  if g == which])
+            for which in (paged_kv.FULL, paged_kv.WINDOW))
         self.prefill_secs = 0.0
         self.decode_secs = 0.0
         self.finished: Dict[str, int] = {}
@@ -385,11 +424,17 @@ class InferenceEngine:
                 # queued spills reference the abandoned pool; resident
                 # host entries and counters survive the restart
                 self.host_cache.on_pool_reset()
+        window = None
+        if self._window_blocks:
+            window = WindowGroup(
+                self._window_blocks, cfg.block_size, cfg.num_slots,
+                self._max_blocks_per_slot,
+                int(self.model.cfg.sliding_window_size), self._window_bound)
         blocks = BlockManager(self._num_blocks, cfg.block_size,
                               cfg.num_slots, self._max_blocks_per_slot,
                               prefix_cache=cfg.prefix_cache,
                               observatory=self.cache_observatory,
-                              host_cache=self.host_cache)
+                              host_cache=self.host_cache, window=window)
         sched = Scheduler(self.queue, blocks, cfg.max_model_len,
                           draft_k=self.draft_k)
         if carry is not None:
@@ -417,7 +462,8 @@ class InferenceEngine:
             scheduler=sched,
             pages=paged_kv.init_pools(self.model.cfg, self._num_blocks,
                                       cfg.block_size,
-                                      quantized=cfg.int8_kv_cache),
+                                      quantized=cfg.int8_kv_cache,
+                                      window_blocks=self._window_blocks),
             last_tokens=np.zeros(S, np.int32),
             context_lens=np.zeros(S, np.int32),
             active=np.zeros(S, np.int32),
@@ -449,7 +495,8 @@ class InferenceEngine:
         tokens = last_tokens[:, None]                       # [S, 1]
         positions = context_lens[:, None]                   # [S, 1]
         caches = paged_kv.step_caches(pages, block_tables, context_lens,
-                                      active, self.paged_kernel)
+                                      active, self.paged_kernel,
+                                      self._layer_groups)
         logits, new_caches = language_model_forward(
             params, tokens, positions, None, self.model.cfg,
             rng_key=None, train=False, kv_caches=caches)
@@ -490,7 +537,8 @@ class InferenceEngine:
         K1 = tokens.shape[1]
         positions = context_lens[:, None] + jnp.arange(K1)[None, :]
         caches = paged_kv.step_caches(pages, block_tables, context_lens,
-                                      vlens, self.prefill_kernel)
+                                      vlens, self.prefill_kernel,
+                                      self._layer_groups)
         logits, new_caches = language_model_forward(
             params, tokens, positions, None, self.model.cfg,
             rng_key=None, train=False, kv_caches=caches)
@@ -521,7 +569,8 @@ class InferenceEngine:
         positions = (start_pos + jnp.arange(C))[None, :]    # [1, C]
         caches = paged_kv.step_caches(
             pages, block_table, jnp.full((1,), start_pos, jnp.int32),
-            jnp.full((1,), valid_len, jnp.int32), self.prefill_kernel)
+            jnp.full((1,), valid_len, jnp.int32), self.prefill_kernel,
+            self._layer_groups)
         logits, new_caches = language_model_forward(
             params, tokens, positions, None, self.model.cfg,
             rng_key=None, train=False, kv_caches=caches)
@@ -891,6 +940,38 @@ class InferenceEngine:
 
     # -- prefill --------------------------------------------------------
 
+    def _tables(self, st: _EngineState, rows=slice(None)):
+        """What a program takes as ``block_tables``: rows ``rows`` of the
+        slots' table, or of each group's where there are two."""
+        full = st.blocks.tables[rows].copy()
+        if self._layer_groups is None:
+            return full
+        tables = {paged_kv.FULL: full}
+        if st.blocks.window is not None:
+            tables[paged_kv.WINDOW] = st.blocks.window.tables[rows].copy()
+        return tables
+
+    def _window_advance(self, st: _EngineState, d: DispatchRecord,
+                        writes) -> None:
+        """Before a launch of a model with a window group: for each
+        (slot, start, n) of ``writes`` take the window pages the launch
+        writes and give back those behind its window
+        (``WindowGroup.advance_locked``); then what the pool holds against the
+        tokens it holds them for, on the record and in the totals."""
+        if st.blocks.window is None:
+            return
+        with TraceAnnotation("loop.kv_release", seq=d.seq):
+            (d.kv_window_pages_returned, d.kv_window_pages_spanned,
+             d.kv_full_pages_held, held) = st.blocks.window_advance(writes)
+            full_bytes, window_bytes = self._group_block_bytes
+            d.kv_held_bytes = (d.kv_full_pages_held * full_bytes
+                               + held * window_bytes)
+            d.kv_live_tokens = sum(
+                max(int(st.context_lens[r.slot]), r.prefill_pos)
+                for r in st.scheduler.active.values())
+        for f in KV_FIELDS:
+            setattr(self, f, getattr(self, f) + getattr(d, f))
+
     def _writable(self, st: _EngineState, slot: int, block_idx: int) -> None:
         """Copy-on-write barrier before a device write into a slot's
         logical page: if the block manager swaps in a private copy,
@@ -958,7 +1039,8 @@ class InferenceEngine:
         bs = self.config.block_size
         for bi in range(start // bs, (start + valid - 1) // bs + 1):
             self._writable(st, req.slot, bi)
-        table = st.blocks.tables[req.slot:req.slot + 1].copy()
+        self._window_advance(st, d, [(req.slot, start, valid)])
+        table = self._tables(st, slice(req.slot, req.slot + 1))
         d.start, d.valid = start, valid
         d.cached_tokens = req.cached_prompt_tokens
         d.requests = (req.id,)
@@ -1076,10 +1158,12 @@ class InferenceEngine:
         decoding = [r for r in (st.scheduler.active.get(s) for s in slots)
                     if r is not None and r.state == RequestState.DECODE]
         self._note_batch(st, d, slots, decoding)
+        self._window_advance(
+            st, d, [(s, int(st.context_lens[s]), 1) for s in slots])
         d.mark("build_inputs")
         next_tokens, st.pages, new_keys, finite, routing = self._decode_step(
             self.params, st.pages, st.last_tokens,
-            st.context_lens, st.blocks.tables.copy(),
+            st.context_lens, self._tables(st),
             st.active, st.temps, st.top_ks, st.top_ps,
             st.ban_a, st.ban_b, st.keys)
         d.mark("dispatch")
@@ -1182,7 +1266,7 @@ class InferenceEngine:
         disp.mark("build_inputs")
         emit, st.pages, new_keys, finite, routing = self._verify_step(
             self.params, st.pages, verify_tokens, st.context_lens,
-            st.blocks.tables.copy(), vlens, st.temps, st.top_ks,
+            self._tables(st), vlens, st.temps, st.top_ks,
             st.top_ps, st.ban_a, st.ban_b, st.keys)
         disp.mark("dispatch")
         self._start_routing_copy(routing)
@@ -1451,6 +1535,7 @@ class InferenceEngine:
             **{f: getattr(self, f) for f in MOE_FIELDS},
             "dsa_keys_live": self.dsa_keys_live,
             "dsa_keys_selected": self.dsa_keys_selected,
+            **{f: getattr(self, f) for f in KV_FIELDS},
             "engine_restarts": self.engine_restarts,
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,
             "loop": self.loop_profiler.stats(),

@@ -48,6 +48,21 @@ Prefix caching (``prefix_cache=True``):
   Reserved-but-unwritten pages of a slot released mid-prefill go back to
   the free list immediately — they hold no reusable KV.
 
+Two groups of pages (``window``, a :class:`WindowGroup`): a model with a
+layer type per layer keeps a request's pages for its whole length on its
+full layers only (everything above).  Its sliding-window layers have
+pools, a free list and a block table a slot of their own, and there a
+request holds only the pages a future query's window can still reach:
+``advance_locked`` (through ``BlockManager.window_advance``, which the
+engine calls before every launch) takes the pages the
+launch's tokens need and gives back, to the free list, the pages wholly
+behind the window of the launch's first query.  A page so returned is
+handed out again at once, to this request or another.  Admission counts
+both groups: the window group reserves a request's bound (the window,
+one launch's tokens and a page; fewer for a short request), so a running
+request never finds it empty.  A model of one layer type has no window
+group and holds its pages as above, whatever its window.
+
 Hierarchical tier (``host_cache``, serving/host_cache.py): with a host
 spill tier attached, registrations and parkings additionally enqueue an
 asynchronous device→host page copy, and the admission match extends its
@@ -127,9 +142,108 @@ def prompt_affinity_digest(prompt: str, max_chars: int = 256,
     return prev.hex()
 
 
+class WindowGroup:
+    """The pages of a patterned model's sliding-window layers: their own
+    free list and block table a slot, and the bound a request holds.
+    Plain bookkeeping with no lock of its own: :class:`BlockManager`
+    calls its ``*_locked`` methods under its lock."""
+
+    def __init__(self, num_blocks: int, block_size: int, num_slots: int,
+                 max_blocks_per_slot: int, window: int, bound: int):
+        assert num_blocks >= 2 and window >= 1 and bound >= 1
+        self.num_blocks = int(num_blocks)
+        self.block_size = int(block_size)
+        self.window = int(window)
+        # the most pages a slot may hold (ops.paged_kv.window_pages_bound)
+        self.bound = int(bound)
+        self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+        # slot -> {logical page: block}, and the pages it may yet hold
+        self._held: Dict[int, Dict[int, int]] = {}
+        self._reserved: Dict[int, int] = {}
+        self.tables = np.full((num_slots, max_blocks_per_slot),
+                              GARBAGE_BLOCK, np.int32)
+        # pages given back while their request ran, and pages handed out
+        # (each logical page of a context once: what ONE table a slot
+        # would have kept until the request ended)
+        self.pages_returned = 0
+        self.pages_spanned = 0
+
+    def reservation(self, n_blocks: int) -> int:
+        return min(int(n_blocks), self.bound)
+
+    def available(self) -> int:
+        """Free pages no admitted request may still ask for."""
+        owed = sum(r - len(self._held[s])
+                   for s, r in self._reserved.items())
+        return self.pages_free() - owed
+
+    def pages_held(self) -> int:
+        return sum(len(h) for h in self._held.values())
+
+    def pages_free(self) -> int:
+        return len(self._free)
+
+    def admit_locked(self, slot: int, n_blocks: int) -> None:
+        self._reserved[slot] = self.reservation(n_blocks)
+        self._held[slot] = {}
+        self.tables[slot, :] = GARBAGE_BLOCK
+
+    def advance_locked(self, slot: int, start: int, n: int) -> int:
+        """Before a launch that writes ``n`` tokens of ``slot`` at
+        ``start ..``: give back the pages wholly behind the window of the
+        query at ``start`` (no later query reaches further back), take
+        the pages the launch writes.  Returns the pages given back."""
+        held = self._held[slot]
+        bs = self.block_size
+        first = max(start - self.window + 1, 0) // bs
+        gone = [i for i in held if i < first]
+        for i in gone:
+            self._free.append(held.pop(i))
+            self.tables[slot, i] = GARBAGE_BLOCK
+        for i in range(start // bs, (start + max(n, 1) - 1) // bs + 1):
+            if i not in held:
+                held[i] = b = self._free.pop()
+                self.tables[slot, i] = b
+                self.pages_spanned += 1
+        assert len(held) <= self._reserved[slot], (
+            f"slot {slot} holds {len(held)} window pages, reserved "
+            f"{self._reserved[slot]}")
+        self.pages_returned += len(gone)
+        return len(gone)
+
+    def release_locked(self, slot: int) -> None:
+        held = self._held.pop(slot, None)
+        if held is None:
+            return
+        self._free.extend(held.values())
+        del self._reserved[slot]
+        self.tables[slot, :] = GARBAGE_BLOCK
+
+    def check_invariants(self) -> None:
+        free = set(self._free)
+        assert len(free) == len(self._free), "window page free twice"
+        owned: Dict[int, int] = {}
+        for slot, held in self._held.items():
+            assert len(held) <= self._reserved[slot] <= self.bound, \
+                f"slot {slot}: {len(held)} window pages beyond its bound"
+            row = np.full_like(self.tables[slot], GARBAGE_BLOCK)
+            for i, b in held.items():
+                assert b not in owned, \
+                    f"window page {b} held by slots {owned[b]} and {slot}"
+                owned[b] = slot
+                row[i] = b
+            assert (self.tables[slot] == row).all()
+        assert not free & set(owned), "window page both free and held"
+        assert free | set(owned) == set(range(1, self.num_blocks)), \
+            "leaked/duplicated window pages"
+        assert self.available() >= 0, "window group over-reserved"
+        assert set(self._held) == set(self._reserved)
+
+
 class BlockManager:
     """Allocates slots and pool blocks; owns the block-table array and
-    (optionally) the refcounted prefix cache over the pool."""
+    (optionally) the refcounted prefix cache over the pool; with a
+    ``window`` group, that group's pages beside them."""
 
     # lint-enforced (graft-lint locks/LD002): the engine thread and the
     # HTTP front-end both allocate/free; all pool state mutates under
@@ -141,15 +255,21 @@ class BlockManager:
         "_block_epoch", "host_cache",
         "prefix_cache_hits", "prefix_cache_misses",
         "prefix_cache_evictions", "prefix_cache_hit_tokens",
-        "prefix_cache_host_hits", "cow_copies",
+        "prefix_cache_host_hits", "cow_copies", "window",
     )
 
     def __init__(self, num_blocks: int, block_size: int, num_slots: int,
                  max_blocks_per_slot: int, prefix_cache: bool = False,
                  observatory: Optional[CacheObservatory] = None,
-                 host_cache=None):
+                 host_cache=None, window: Optional[WindowGroup] = None):
         assert num_blocks >= 2, "need at least one block beyond the garbage"
         assert block_size >= 1 and num_slots >= 1
+        if window is not None and (prefix_cache or host_cache is not None):
+            # a prefix is whole only with the window group's pages of its
+            # last window, which are not kept: nothing is adopted
+            raise ValueError("a window group adopts no prefix: it needs "
+                             "prefix_cache off and no host tier")
+        self.window = window
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_slots = int(num_slots)
@@ -217,7 +337,31 @@ class BlockManager:
         with self._lock:
             avail = len(self._free_blocks) + len(self._lru)
             return (bool(self._free_slots) and n <= avail
-                    and n <= self.max_blocks_per_slot)
+                    and n <= self.max_blocks_per_slot
+                    and self._window_admits_locked(n))
+
+    def _window_admits_locked(self, n_blocks: int) -> bool:
+        w = self.window
+        return w is None or w.reservation(n_blocks) <= w.available()
+
+    def window_advance(self, writes) -> Tuple[int, int, int, int]:
+        """Before a launch that writes ``n`` tokens of ``slot`` at
+        ``start ..`` for each ``(slot, start, n)`` of ``writes``:
+        ``WindowGroup.advance_locked`` for every live slot among them.  Returns
+        (window pages given back, window pages taken, pages in use in
+        the full group, in the window group) as the launch begins; all 0
+        without a window group."""
+        with self._lock:
+            w = self.window
+            if w is None:
+                return 0, 0, 0, 0
+            spanned = w.pages_spanned
+            returned = sum(w.advance_locked(slot, start, n)
+                           for slot, start, n in writes
+                           if slot in self._slot_blocks)
+            full = self.num_blocks - 1 - len(self._free_blocks) - len(
+                self._lru)
+            return returned, w.pages_spanned - spanned, full, w.pages_held()
 
     # -- alloc / free ---------------------------------------------------
 
@@ -327,15 +471,21 @@ class BlockManager:
             # after bumping matched refcounts would leak those blocks)
             avail = (len(self._free_blocks) + len(self._lru)
                      - sum(1 for b in matched if b in self._lru))
-            if not self._free_slots or n_fresh > avail:
+            if (not self._free_slots or n_fresh > avail
+                    or not self._window_admits_locked(n)):
                 if host_digests:
                     # the pinned host entries will not be consumed —
                     # release them before the retry path gives up
                     self.host_cache.unpin(host_digests)
                 raise NoCapacity(
                     f"no capacity: {len(self._free_slots)} free slots, "
-                    f"{avail} free/evictable blocks, need {n_fresh}")
+                    f"{avail} free/evictable blocks, need {n_fresh}"
+                    + ("" if self.window is None else
+                       f"; window group {self.window.available()} free, "
+                       f"need {self.window.reservation(n)}"))
             slot = self._free_slots.pop()
+            if self.window is not None:
+                self.window.admit_locked(slot, n)
             adopted_rcs: List[int] = []
             for b in matched:
                 rc = self._refcounts.get(b, 0)
@@ -559,6 +709,8 @@ class BlockManager:
                     self._free_blocks.append(b)
             if self.prefix_cache_enabled:
                 self.observatory.record_free(slot)
+            if self.window is not None:
+                self.window.release_locked(slot)
             self._free_slots.append(slot)
             self._slot_cached.pop(slot, None)
             self._slot_miss_causes.pop(slot, None)
@@ -591,6 +743,12 @@ class BlockManager:
                 "prefix_cache_hit_tokens": self.prefix_cache_hit_tokens,
                 "prefix_cache_host_hits": self.prefix_cache_host_hits,
                 "cow_copies": self.cow_copies,
+                **({} if self.window is None else {
+                    "window_blocks_total": self.window.num_blocks - 1,
+                    "window_blocks_in_use": self.window.pages_held(),
+                    "window_blocks_free": self.window.pages_free(),
+                    "window_pages_returned": self.window.pages_returned,
+                    "window_pages_spanned": self.window.pages_spanned}),
             }
 
     def cache_stats(self) -> Dict[str, object]:
@@ -604,8 +762,14 @@ class BlockManager:
         """Debug/test hook: every usable block is in exactly one of
         {free list, LRU reusable, owned-by-some-slot}; refcounts equal
         the number of owning slots; the digest registry is bijective and
-        only covers live (owned or reusable) blocks."""
+        only covers live (owned or reusable) blocks.  With a window
+        group: the same of its pages (free or held by ONE slot, a slot's
+        within its bound), and its slots are the live slots."""
         with self._lock:
+            if self.window is not None:
+                self.window.check_invariants()
+                assert set(self.window._held) == set(self._slot_blocks), \
+                    "window group and full group disagree on live slots"
             free = set(self._free_blocks)
             lru = set(self._lru)
             owned: Dict[int, int] = {}

@@ -97,6 +97,12 @@ LOOP_PHASE_BUCKETS = (
 MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
               "moe_busiest_expert_assignments")
 
+# a launch's account of the two page groups of a model with a layer type
+# per layer (DispatchRecord's fields; all 0 for a model of one type),
+# which the engine also keeps running totals of
+KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
+             "kv_held_bytes", "kv_full_pages_held", "kv_live_tokens")
+
 # the enclosing Chrome-trace span of a launch, by its kind
 _SPAN_NAME = {"prefill": "prefill_chunk", "decode": "decode_step",
               "verify": "decode_step"}
@@ -157,6 +163,17 @@ class DispatchRecord:
     # cut at the top-k, which is what it attends
     dsa_keys_live = 0
     dsa_keys_selected = 0
+    # a model with a layer type per layer (0 otherwise): window-group
+    # pages given back to the allocator before this launch and pages it
+    # took (each logical page of a context once: what one table a slot
+    # would have kept); bytes of the pages the running requests hold in
+    # both groups, the full group's pages among them, and the tokens
+    # those requests have in the cache, all as the launch begins
+    kv_window_pages_returned = 0
+    kv_window_pages_spanned = 0
+    kv_held_bytes = 0
+    kv_full_pages_held = 0
+    kv_live_tokens = 0
     # the requests (and their trace ids) this launch served: the spans
     # that caused it
     requests: Tuple[int, ...] = ()
@@ -247,6 +264,7 @@ class DispatchRecord:
             "sampler_rows_filtered": self.sampler_rows_filtered,
             "dsa_keys_live": self.dsa_keys_live,
             "dsa_keys_selected": self.dsa_keys_selected,
+            **{f: getattr(self, f) for f in KV_FIELDS},
         }
 
     def note_selection(self, sees, topk: int, layers: int) -> None:

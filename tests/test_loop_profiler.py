@@ -565,6 +565,9 @@ KERNEL_NAMES = {
     "dsa_index_scores_decode", "dsa_index_scores_prefill",
     "dsa_select_decode", "dsa_select_prefill",
     "paged_attention_sparse_decode", "paged_attention_prefill_masked",
+    # the same walk launched for the window group of a model with a layer
+    # type per layer (PR 32), which has no int8 pool
+    "paged_attention_decode_window", "paged_attention_prefill_window",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -609,7 +612,8 @@ def test_every_kernel_and_program_carries_its_stable_name():
                     and isinstance(node.func, ast.Name)
                     and node.func.id == "_walk_call"):
                 kw = {k.arg: k.value for k in node.keywords}
-                names |= {kw["name"].value, kw["name"].value + "_quant"}
+                names |= {kw["name"].value + suffix
+                          for suffix in ("", "_quant", "_window")}
             # the sparse-attention kernels take theirs from the one entry
             # that calls them for the decode step and for a chunk
             if (isinstance(node, ast.Call)

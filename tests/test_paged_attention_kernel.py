@@ -710,3 +710,96 @@ def test_selection_never_reads_scores_nobody_wrote(n, poison, monkeypatch,
         np.testing.assert_array_equal(got[s, :v], clean[s, :v])
         np.testing.assert_allclose(got[s, :v], want[s, :v], atol=2e-5,
                                    rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the window group's walk (a model with a layer type per layer): the same
+# walk under its own names, over a table whose pages behind the window
+# have gone back to the allocator
+# ---------------------------------------------------------------------------
+
+def _behind_the_window_gone(bt, first_query, window, bs, page):
+    """The table with every page wholly behind the window of each slot's
+    first query pointed at ``page``: what ``WindowGroup.advance_locked``
+    leaves (it points them at the garbage block)."""
+    first = np.maximum(np.asarray(first_query) - window + 1, 0) // bs
+    gone = np.arange(bt.shape[1])[None, :] < first[:, None]
+    return np.where(gone, page, bt).astype(np.int32)
+
+
+@pytest.mark.parametrize("window", [5, 16, 19])
+def test_window_walk_never_reads_a_page_given_back(window, two_page_blocks):
+    """Decode rows at contexts of several windows, and a 16-token chunk:
+    the pages behind the window of the launch's first query point at a
+    page of NaN, at a page of huge values and at the garbage block, and
+    the outputs are bit-equal and the oracle's.  The walk launches under
+    the window group's names."""
+    g, nh = 2, 4
+    rng = np.random.default_rng(900 + window)
+    lens = np.asarray([5 * BS - 1, 3 * BS, 7 * BS - 3, 2], np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, S, LONG_M, BS, g, nh, D,
+                                              lens)
+    P = kp.shape[0]
+    kp[P - 3], vp[P - 3] = 1e30, -1e30
+    kp[P - 2], vp[P - 2] = np.nan, np.nan
+    outs = [_decode(q, kp, vp,
+                    _behind_the_window_gone(bt, lens, window, BS, page),
+                    lens, sliding_window=window, name_suffix="_window")
+            for page in (P - 2, P - 3, 0)]
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(D), window)
+    np.testing.assert_allclose(outs[0], want, atol=2e-5, rtol=2e-5)
+    assert np.array_equal(outs[0], outs[1])
+    assert np.array_equal(outs[0], outs[2])
+    # a chunk: the first query of each slot stands at its context
+    ctx = np.asarray([0, 3, 24, 33], np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_prefill_case(
+        rng, len(ctx), 8, BS, g, nh, D, ctx, C)
+    P = kp.shape[0]
+    kp[P - 2], vp[P - 2] = np.nan, np.nan
+    outs = [np.asarray(pa.paged_attention_prefill(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(_behind_the_window_gone(bt, ctx, window, BS, page)),
+        jnp.asarray(ctx), sliding_window=window, name_suffix="_window"))
+        for page in (P - 2, 0)]
+    want = _prefill_oracle(q, k_lin, v_lin, ctx, 1.0 / math.sqrt(D), window)
+    np.testing.assert_allclose(outs[0], want, atol=2e-5, rtol=2e-5)
+    assert np.array_equal(outs[0], outs[1])
+
+
+def test_window_groups_kernel_names_reach_the_lowered_program():
+    rng = np.random.default_rng(5)
+    lens = np.asarray([20, 3], np.int32)
+    q, _, _, kp, vp, bt = _build_case(rng, 2, 4, BS, 2, 4, D, lens)
+
+    def lowered(suffix):
+        return jax.jit(lambda q, kp, vp, bt, lens: pa.paged_attention_decode(
+            q, kp, vp, bt, lens, sliding_window=8, name_suffix=suffix)
+        ).lower(q, kp, vp, bt, lens).as_text(debug_info=True)
+
+    assert "paged_attention_decode_window" in lowered("_window")
+    assert "paged_attention_decode_window" not in lowered("")
+
+
+def test_prefill_q_block_is_held_to_the_scratch_it_needs():
+    """A chunk of 512 rows of 32 heads takes q-blocks of 64 rows (2,048
+    (row, head) pairs: more is refused by the TPU's compiler, which
+    tests/test_tpu_aot_compile.py holds); a chunk of 64 rows keeps its
+    one block of 64 at 32 heads and at 16."""
+    seen = []
+    real = pa._walk_call
+
+    def spy(*args, **kw):
+        seen.append(kw["block_q"])
+        return jnp.zeros(args[0].shape, args[0].dtype)
+
+    pa._walk_call = spy
+    try:
+        for C_, nh_ in ((512, 32), (64, 32), (64, 16), (5, 32), (512, 8)):
+            q = jnp.zeros((1, C_, nh_, 128), jnp.bfloat16)
+            pools = jnp.zeros((4, 16, 4, 128), jnp.bfloat16)
+            pa.paged_attention_prefill(q, pools, pools,
+                                       jnp.zeros((1, 2), jnp.int32),
+                                       jnp.zeros((1,), jnp.int32))
+    finally:
+        pa._walk_call = real
+    assert seen == [64, 64, 64, 5, 128]

@@ -200,3 +200,62 @@ def test_attend_writes_the_indexers_key_where_it_writes_k_and_v(n, ctx):
                 assert not np.asarray(
                     new.pool["index_pages"][page, off]).any()
     assert int(new.context_lens[0]) == ctx + valid
+
+
+# ---------------------------------------------------------------------------
+# a model of one layer type keeps ONE group, whatever its window
+# ---------------------------------------------------------------------------
+
+def test_a_one_type_window_model_allocates_attends_and_frees_as_before():
+    """Mistral's tiny shape (its window on EVERY layer, here 16 so that a
+    context passes it): no window group, one table a slot that keeps the
+    request's pages for its whole length, the walk under its plain names,
+    no page given back before the request ends, and the answer the
+    cache-less forward's."""
+    import jax.numpy as jnp
+
+    from megatron_llm_tpu.models.gpt import GPTModel
+    from megatron_llm_tpu.models.mistral import mistral_config
+    from megatron_llm_tpu.serving import SamplingParams
+    from megatron_llm_tpu.serving.loop_profiler import KV_FIELDS
+
+    cfg = mistral_config("tiny", sliding_window_size=16, seq_length=128,
+                         max_position_embeddings=128, padded_vocab_size=256,
+                         use_flash_attn=False)
+    assert cfg.layer_types is None and paged_kv.layer_groups(cfg) is None
+    assert cfg.attention_of(None) == (16, None)
+    model = GPTModel(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, EngineConfig(
+        num_slots=2, block_size=8, max_model_len=128, prefill_chunk=16,
+        prefix_cache=False))
+    assert eng.blocks.window is None and eng._layer_groups is None
+    assert len({p["k_pages"].shape for p in eng._st.pages}) == 1
+    prompt = np.random.default_rng(0).integers(1, 255, 70).tolist()
+    req = eng.submit(prompt, SamplingParams(max_new_tokens=6,
+                                            temperature=0.0))
+    held = []
+    while req.finish_reason is None:
+        assert eng.step()
+        eng.blocks.check_invariants()
+        tables = eng._tables(eng._st)
+        assert isinstance(tables, np.ndarray)
+        assert (tables == eng.blocks.tables).all()
+        if req.finish_reason is None:
+            held.append(eng.blocks.stats()["blocks_in_use"])
+    # the request's worst case (70 + 6 tokens: 10 pages) from admission on
+    assert set(held) == {10}
+    stats = eng.stats()
+    assert stats["blocks_in_use"] == 0
+    assert not [k for k in stats if k.startswith("window_")]
+    assert all(stats[f] == 0 for f in KV_FIELDS)
+    toks = list(prompt)
+    for _ in range(6):
+        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
+        toks.append(int(jnp.argmax(logits[0, -1])))
+    assert toks[70:] == list(req.out_tokens)
+    # and the caches the programs hand the model carry the plain names
+    caches = paged_kv.step_caches(eng._st.pages, eng.blocks.tables,
+                                  np.zeros(2, np.int32), np.ones(2, np.int32),
+                                  "xla", eng._layer_groups)
+    assert {c.group for c in caches} == {paged_kv.FULL}

@@ -164,3 +164,99 @@ def test_sectioned_rotary_differs_where_the_streams_differ():
                                    atol=1e-6)
         others = np.flatnonzero(np.repeat(of != stream, 2))
         assert np.abs(got[..., others] - plain[..., others]).max() > 1e-2
+
+
+# ---------------------------------------------------------------------------
+# YaRN (rope_type 'yarn'): the frequencies and the factor on cos and sin
+# ---------------------------------------------------------------------------
+
+# rope_parameters.full_attention of Mellum2-12B-A2.5B-Instruct, head 128
+YARN = (16.0, 8192, 32.0, 1.0, 1.2772588722239782)
+THETA = 500000.0
+
+
+def _yarn_by_the_formula(d=128, theta=THETA, yarn=YARN):
+    """theta'_i = (theta_i / f)(1 - g_i) + theta_i g_i, g_i = 1 -
+    clip((i - low) / (high - low), 0, 1), low = floor(c(beta_fast)), high =
+    ceil(c(beta_slow)), c(b) = d ln(orig / (2 pi b)) / (2 ln theta),
+    in float64."""
+    import math
+
+    f, orig, fast, slow, _ = yarn
+    c = lambda b: d * math.log(orig / (2 * math.pi * b)) / (2 * math.log(theta))
+    low, high = max(math.floor(c(fast)), 0), min(math.ceil(c(slow)), d - 1)
+    i = np.arange(d // 2, dtype=np.float64)
+    freq = theta ** (-2.0 * i / d)
+    g = 1.0 - np.clip((i - low) / (high - low), 0.0, 1.0)
+    return low, high, (freq / f) * (1 - g) + freq * g
+
+
+def test_yarn_frequencies_at_the_published_numbers():
+    from megatron_llm_tpu.ops.rope import yarn_scale_freqs
+
+    low, high, want = _yarn_by_the_formula()
+    assert (low, high) == (18, 35)
+    plain = 1.0 / (THETA ** (jnp.arange(0, 128, 2, dtype=jnp.float32) / 128))
+    got = np.asarray(yarn_scale_freqs(plain, THETA, *YARN[:4]))
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    ratio = got / np.asarray(plain)
+    np.testing.assert_allclose(ratio[:19], 1.0, rtol=1e-6)       # kept
+    np.testing.assert_allclose(ratio[35:], 1 / 16, rtol=1e-6)    # divided
+    assert (np.diff(ratio[18:36]) < 0).all()                     # between
+    # the factor is 0.1 ln(16) + 1
+    np.testing.assert_allclose(YARN[4], 0.1 * np.log(16.0) + 1, rtol=1e-12)
+
+
+@pytest.mark.parametrize("positions", [[0, 1, 2, 3], [5, 1023, 8191, 100_000]])
+def test_yarn_table_and_rotation_at_positions_agree_with_the_formula(
+        positions):
+    """The precomputed table and ``apply_rotary_at`` both turn pair i at
+    position p by p * theta'_i and multiply cos and sin by the attention
+    factor (so a rotated vector is 1.277 times as long)."""
+    from megatron_llm_tpu.ops.rope import apply_rotary_at
+
+    _, _, freq = _yarn_by_the_formula()
+    pos = np.asarray(positions)
+    ang = pos[:, None].astype(np.float64) * freq[None, :]
+    cos, sin = precompute_freqs_cis(128, 100_001, theta=THETA, yarn=YARN)
+    # fp32 angles at 1e5 radians carry some 1e-2 of error: compare where
+    # the table itself is exact enough, and the two paths with each other
+    small = ang < 100.0
+    np.testing.assert_allclose(np.asarray(cos)[pos][small],
+                               (YARN[4] * np.cos(ang))[small], atol=2e-5)
+    np.testing.assert_allclose(np.asarray(sin)[pos][small],
+                               (YARN[4] * np.sin(ang))[small], atol=2e-5)
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(1, len(pos), 2, 128).astype(np.float32))
+    by_table = apply_rotary_emb(x, cos, sin, jnp.asarray(pos)[None])
+    at = apply_rotary_at(x, jnp.asarray(pos)[None], THETA, yarn=YARN)
+    np.testing.assert_allclose(np.asarray(at), np.asarray(by_table),
+                               atol=1e-5)
+    np.testing.assert_allclose(
+        np.linalg.norm(np.asarray(at), axis=-1),
+        YARN[4] * np.linalg.norm(np.asarray(x), axis=-1), rtol=1e-5)
+    plain = apply_rotary_at(x, jnp.asarray(pos)[None], THETA)
+    if pos.max() > 100:
+        assert np.abs(np.asarray(at) / YARN[4] - np.asarray(plain)).max() > 0.1
+
+
+def test_yarn_excludes_the_other_scalings():
+    with pytest.raises(ValueError, match="excludes"):
+        precompute_freqs_cis(128, 16, theta=THETA, scaling_factor=2.0,
+                             yarn=YARN)
+
+
+def test_a_one_type_model_with_yarn_builds_its_table():
+    """``rope_yarn_scaling`` without ``layer_types``: the stack's one
+    table carries it; a patterned model has no one table."""
+    from megatron_llm_tpu.models.llama import llama_config
+    from megatron_llm_tpu.models.mellum import mellum_config
+    from megatron_llm_tpu.models.transformer import rotary_freqs
+
+    cfg = llama_config("tiny", rope_theta=THETA, rope_yarn_scaling=YARN,
+                       seq_length=32, max_position_embeddings=32)
+    cos, _ = rotary_freqs(cfg)
+    want, _ = precompute_freqs_cis(cfg.head_dim, 32, theta=THETA, yarn=YARN)
+    np.testing.assert_array_equal(np.asarray(cos), np.asarray(want))
+    assert float(cos[0, 0]) == pytest.approx(YARN[4])
+    assert rotary_freqs(mellum_config("tiny")) is None

@@ -136,7 +136,33 @@ def _selected(q_tokens, slots, tokens=33792, page=16):
                 ((slots,), jnp.int32), ((slots,), jnp.int32)]
 
 
+def _paged_window(q_tokens, slots, tokens=33792, page=16):
+    """Mellum's window group at the cell's shapes: 32 query and 4 kv heads
+    of 128, a window of 1,024, 32 slots of up to 33,792 tokens (a table of
+    2,112 entries) over the group's 32 x 97 + 1 pages; the decode step
+    and the [1, 512] chunk, under the window group's kernel names."""
+    from megatron_llm_tpu.ops.pallas import paged_attention as pa
+
+    pool = ((32 * 97 + 1, page, 4, HEAD_DIM), BF16)
+
+    def fn(q, k_pages, v_pages, tables, lens):
+        kw = dict(sliding_window=1024, name_suffix="_window")
+        if q_tokens == 1:
+            return pa.paged_attention_decode(q[:, 0], k_pages, v_pages,
+                                             tables, lens, **kw)
+        return pa.paged_attention_prefill(q, k_pages, v_pages, tables, lens,
+                                          **kw)
+
+    return fn, [((slots, q_tokens, 32, HEAD_DIM), BF16), pool, pool,
+                ((slots, tokens // page), jnp.int32), ((slots,), jnp.int32)]
+
+
 CASES = {
+    "paged_window_decode_32_slots": lambda: _paged_window(1, 32),
+    "paged_window_prefill_chunk_512": lambda: _paged_window(512, 1),
+    "moe_experts_mellum_32_rows": lambda: _experts(32 * 8, 64, 2304, 896),
+    "moe_experts_mellum_chunk_512_rows":
+        lambda: _experts(512 * 8, 64, 2304, 896),
     "dsa_selected_decode_8_slots": lambda: _selected(1, 8),
     "dsa_selected_prefill_chunk_512": lambda: _selected(512, 1),
     "moe_experts_keye_8_rows": lambda: _experts(8 * 8, 128, 2048, 768,
