@@ -32,6 +32,17 @@ them (visits of one tile are consecutive, so its output block stays in
 VMEM between them).  bf16 products, fp32 accumulation, one rounding to
 the output's dtype: the precision of the einsum it replaces.
 
+Which ``[tk, tn]``: ``tiles`` takes, of the multiples of 128 that divide
+each width, the pair that makes the fewest grid steps a visit and fits
+``_VMEM_BUDGET`` by ``vmem_bytes``' count of what the call holds.  A
+grid step has a fixed cost of about 0.3 us, and a block under some
+half a MiB pays that and not its DMA: 8 layers' chunk at Mellum's 2304
+/ 1792 / 896 takes 38.3 ms at blocks of 256 x 256 and 128 x 256 (126
+steps a visit, what halving from 1024 / 2048 gave widths that are no
+multiples of 512) and 12.4-12.7 ms at any blocks of 0.7 to 3.9 MiB (6
+to 2 steps a visit; my chip run, PR 33).  So a block is as large as
+fits, and the choice follows the operands' shapes and nothing else.
+
 Rows that belong to no group come back undefined; the caller masks them.
 Interpret-mode tests run the kernel on the CPU via ``_INTERPRET``.
 """
@@ -48,9 +59,17 @@ from jax.experimental.pallas import tpu as pltpu
 from megatron_llm_tpu.ops.pallas import pallas_backend_available
 
 _INTERPRET = False
-# rows a visit multiplies: the MXU's height; more wastes products on rows
-# of other groups, fewer leaves the weights' stream waiting on grid steps
+# rows a visit multiplies.  128 stay: a visit reads its group's whole
+# matrix whatever rows it owns, so taller tiles buy nothing for a group
+# of a few rows (a decode step's every group, most of a chunk's) and
+# multiply every group's matrix by more rows of other groups, while
+# shorter ones make more visits, each a read of the matrix again
 _TILE_ROWS = 128
+# what a call may hold in VMEM by ``vmem_bytes``' count.  The chip's
+# compiler grants a kernel 16 MiB by default (a v5e's scoped limit, not
+# raised here); a quarter of it stays for what Mosaic itself needs (the
+# epilogue's fp32 temporaries)
+_VMEM_BUDGET = 12 * 2 ** 20
 
 
 def kernel_available() -> bool:
@@ -59,18 +78,54 @@ def kernel_available() -> bool:
     return _INTERPRET or pallas_backend_available()
 
 
-def _largest_tile(size: int, limit: int) -> int:
-    """The largest of ``limit``, ``limit / 2`` ... 128 that divides
-    ``size``; the whole of it when none does (a test's tiny width)."""
-    t = limit
-    while t >= 128:
-        if size % t == 0:
-            return t
-        t //= 2
-    return size
+def vmem_bytes(tm: int, tk: int, tn: int, dtype) -> int:
+    """What a call holds in VMEM at these tiles: the pipeline's two
+    buffers each of the ``[tk, tn]`` weight block, the ``[tm, tk]`` rows
+    block and the ``[tm, tn]`` output block, and the fp32 accumulator."""
+    item = jnp.dtype(dtype).itemsize
+    return 2 * item * (tk * tn + tm * tk + tm * tn) + 4 * tm * tn
 
 
-def _visits(group_sizes: jax.Array, tiles: int, tm: int, rows: int):
+def _divisors(size: int) -> list[int]:
+    """The multiples of 128 that divide ``size``; the whole of it when
+    none does (a test's tiny width)."""
+    return [t for t in range(128, size + 1, 128) if size % t == 0] or [size]
+
+
+def tiles(k: int, n: int, rows: int, dtype) -> tuple[int, int, int]:
+    """(tm, tk, tn) of ``grouped_matmul`` for ``rows`` x [k, n] matrices.
+
+    ``tm`` is ``_TILE_ROWS``, or fewer rows rounded up to a sublane tile.
+    ``(tk, tn)`` is the pair of divisors that makes the fewest grid steps
+    a visit, ``(k / tk) x (n / tn)``, under ``_VMEM_BUDGET``; of pairs
+    that tie (their blocks are as large) the wider ``tn``: longer runs
+    of the matrix's rows in a block, and the visit list walked fewer
+    times.  (Where a tie's other pair is ONE k tile the chip reads it
+    up to 8% faster in a chunk, a straddling group's block not being
+    fetched twice: ROADMAP S15 says why that waits.)  The budget is
+    counted at the full row tile whatever ``rows`` is, so a model's
+    every program (a decode step of few rows, a chunk) takes one
+    tiling."""
+    tm = _TILE_ROWS if rows >= _TILE_ROWS else -(-rows // 16) * 16
+    ks, ns = _divisors(k), _divisors(n)
+    fits = [(tk, tn) for tk in ks for tn in ns
+            if vmem_bytes(_TILE_ROWS, tk, tn, dtype) <= _VMEM_BUDGET]
+    # nothing fits only where a width has no divisor and is huge
+    tk, tn = min(fits or [(ks[0], ns[0])],
+                 key=lambda t: ((k // t[0]) * (n // t[1]), -t[1]))
+    return tm, tk, tn
+
+
+def describe(k: int, n: int, dtype) -> dict:
+    """``tiles``' choice for [k, n] matrices as the serving engine's
+    ``stats()['moe_expert_tiles']`` report it."""
+    _, tk, tn = tiles(k, n, _TILE_ROWS, dtype)
+    return {"k": k, "n": n, "tk": tk, "tn": tn,
+            "steps_per_visit": (k // tk) * (n // tn),
+            "vmem_bytes": vmem_bytes(_TILE_ROWS, tk, tn, dtype)}
+
+
+def _visits(group_sizes: jax.Array, row_tiles: int, tm: int, rows: int):
     """(offsets [G + 1], group of each visit [V], row tile of each visit
     [V], number of visits): the (group, row tile) pairs in which the
     group owns at least one row, in row order."""
@@ -80,7 +135,7 @@ def _visits(group_sizes: jax.Array, tiles: int, tm: int, rows: int):
     first = starts // tm
     per = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
     visit_end = jnp.cumsum(per)
-    V = tiles + min(G, rows) - 1
+    V = row_tiles + min(G, rows) - 1
     v = jnp.arange(V, dtype=jnp.int32)
     # every visit against every group: a few hundred thousand compares in
     # one fused operation, where a binary search is a loop of launches
@@ -88,7 +143,7 @@ def _visits(group_sizes: jax.Array, tiles: int, tm: int, rows: int):
         jnp.searchsorted(visit_end, v, side="right", method="compare_all"),
         G - 1).astype(jnp.int32)
     tile = first[group] + v - (visit_end[group] - per[group])
-    tile = jnp.clip(tile, 0, tiles - 1).astype(jnp.int32)
+    tile = jnp.clip(tile, 0, row_tiles - 1).astype(jnp.int32)
     offsets = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends])
     return (offsets.astype(jnp.int32), group, tile,
             jnp.maximum(visit_end[-1], 1).astype(jnp.int32))
@@ -123,14 +178,12 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array,
     in ``rows``' dtype (module docstring)."""
     m, k = rows.shape
     G, _, n = weights.shape
-    tm = _TILE_ROWS if m >= _TILE_ROWS else -(-m // 16) * 16
+    tm, tk, tn = tiles(k, n, m, rows.dtype)
     padded = -(-m // tm) * tm
     if padded != m:
         rows = jnp.pad(rows, ((0, padded - m), (0, 0)))
-    tk, tn = _largest_tile(k, 1024), _largest_tile(n, 2048)
-    tiles = padded // tm
     offsets, group, tile, visits = _visits(
-        group_sizes.astype(jnp.int32), tiles, tm, padded)
+        group_sizes.astype(jnp.int32), padded // tm, tm, padded)
     out = pl.pallas_call(
         functools.partial(_body, tm=tm, k_tiles=k // tk),
         name="moe_experts",

@@ -83,6 +83,7 @@ from jax.profiler import TraceAnnotation
 from megatron_llm_tpu import telemetry, tracing
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.ops import paged_kv
+from megatron_llm_tpu.ops.pallas import grouped_matmul
 from megatron_llm_tpu.serving.cache_observatory import CacheObservatory
 from megatron_llm_tpu.serving.drafter import draft_budget, lookup_draft
 from megatron_llm_tpu.serving.kv_blocks import (
@@ -119,6 +120,21 @@ from megatron_llm_tpu.text_generation.sampling import (
     rows_asking,
     sample_batched,
 )
+
+
+def moe_expert_tiles(mcfg) -> Optional[Dict[str, Dict[str, int]]]:
+    """The blocks the experts' grouped matmul takes at a sparse model's
+    widths (``ops/pallas/grouped_matmul.py::tiles``, a function of the
+    operands' shapes): for ``w_in`` [H, (2x)F] and ``w_out`` [F, H] the
+    widths, the block and the grid steps a visit.  None for a dense
+    model."""
+    if mcfg.num_experts <= 1:
+        return None
+    H, F = mcfg.hidden_size, mcfg.expert_hidden_size
+    wide = (2 if mcfg.glu_activation else 1) * F
+    dtype = mcfg.compute_jnp_dtype
+    return {"w_in": grouped_matmul.describe(H, wide, dtype),
+            "w_out": grouped_matmul.describe(F, H, dtype)}
 
 
 @dataclass
@@ -266,6 +282,9 @@ class InferenceEngine:
                                                     one_device)
         self.prefill_kernel = paged_kv.resolve_kernel(cfg.prefill_kernel,
                                                       one_device)
+        # a sparse model's expert blocks: static like the two above
+        # (stats()['moe_expert_tiles'], left out for a dense model)
+        self.moe_expert_tiles = moe_expert_tiles(mcfg)
         # the speculative [S, K+1] verify forward is another small-n
         # prefill call, so it rides the resolved PREFILL path.  draft_k
         # is a compiled shape: flipping it later would recompile, so it
@@ -1533,6 +1552,8 @@ class InferenceEngine:
             "drafted_tokens": self.drafted_tokens,
             "accepted_tokens": self.accepted_tokens,
             **{f: getattr(self, f) for f in MOE_FIELDS},
+            **({"moe_expert_tiles": self.moe_expert_tiles}
+               if self.moe_expert_tiles else {}),
             "dsa_keys_live": self.dsa_keys_live,
             "dsa_keys_selected": self.dsa_keys_selected,
             **{f: getattr(self, f) for f in KV_FIELDS},

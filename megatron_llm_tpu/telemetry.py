@@ -603,7 +603,14 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    dp_grad_reductions_in_loops, dp_grad_reduction_bytes_per_step,
 #    dp_grad_reduction_dtypes) — see training.py ``_ReadStep`` and
 #    hlo_collectives.py; an event is left out of the stream's step means
-TELEMETRY_SCHEMA_VERSION = 17
+# 18: a sparse model's expert blocks: engine stats() / the engine block
+#    of /metrics gain moe_expert_tiles, {w_in, w_out: {k, n, tk, tn,
+#    steps_per_visit, vmem_bytes}}: the block the experts' grouped matmul
+#    takes at each of its two matrices' widths, the grid steps a visit
+#    (one group's rows in one 128-row tile) costs, and the VMEM the call
+#    holds by the kernel's own count (ops/pallas/grouped_matmul.py
+#    ``tiles``); static per model, absent for a dense one
+TELEMETRY_SCHEMA_VERSION = 18
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -359,6 +359,13 @@ def test_engine_counts_live_assignments_only():
     stats = eng.stats()
     for f in MOE_FIELDS:
         assert stats[f] == sum(getattr(r, f) for r in records) > 0
+    # beside them the static choice: the two expert matrices' blocks
+    H, F = model.cfg.hidden_size, model.cfg.expert_hidden_size
+    blocks = stats["moe_expert_tiles"]
+    assert {m: (b["k"], b["n"]) for m, b in blocks.items()} == {
+        "w_in": (H, 2 * F), "w_out": (F, H)}
+    assert all(b["steps_per_visit"] == (b["k"] // b["tk"])
+               * (b["n"] // b["tn"]) for b in blocks.values())
     # a dense model routes nothing
     assert all(getattr(type(records[0]), f) == 0 for f in MOE_FIELDS)
 

@@ -349,7 +349,7 @@ def test_request_done_schema_golden(engine, tmp_path):
     the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 17
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 18
     captured = []
     engine.request_done_hook = captured.append
     stream = telemetry.TelemetryStream(str(tmp_path))
@@ -419,6 +419,7 @@ def test_engine_stats_shape(engine):
     assert s["paged_kernel"] in ("pallas", "xla")
     assert s["prefill_kernel"] in ("pallas", "xla")
     assert s["speculative"] is False and s["draft_k"] == 0
+    assert "moe_expert_tiles" not in s      # a sparse model's alone
     # the engine-loop goodput block (loop_profiler.py) rides along,
     # populated by the traffic the earlier tests pushed through
     loop = s["loop"]

@@ -5,7 +5,7 @@ The TPU's own compiler is installed with jaxlib and needs no chip: it
 refuses what interpret mode lets through (a slice off the tiling, too
 much fast memory).  Every case compiles one kernel at Mistral-7B widths
 — 32 query heads, 8 kv heads, head_dim 128, hidden 4096 — or, for the
-experts' grouped matmul, at OLMoE's and Mixtral's, and asserts
+experts' grouped matmul, at each served sparse model's, and asserts
 that the result holds a Mosaic call (``tpu_custom_call``), i.e. that the
 kernel was taken and not its jnp reference.  Compiles, not chip runs:
 they say nothing about results or speed.  One more program rides the
@@ -97,9 +97,12 @@ def _paged(q_tokens, slots, page=16, int8=False, window=None,
 
 def _experts(rows, experts, hidden, ffn, layers=8):
     """The dropless expert layer's two grouped matmuls over a model's
-    stacked experts (``models/moe.py``): OLMoE's 64 experts of 1024 and
-    Mixtral's 8 of 14336, at a decode step's, a chunk's and a verify
-    step's rows."""
+    stacked experts (``models/moe.py``) at the four served widths
+    (OLMoE's 64 experts of 1024, Mixtral's 8 of 14336, Keye's 128 of 768,
+    Mellum's 64 of 896) and a decode step's, a chunk's and a verify
+    step's rows.  The compiler's own VMEM check is the guard of the
+    kernel's budget (``grouped_matmul._VMEM_BUDGET``): the blocks
+    ``tiles`` chooses must fit beside what Mosaic itself needs."""
     from megatron_llm_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
     def fn(x, w_in, w_out, sizes):
