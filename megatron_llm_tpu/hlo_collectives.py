@@ -1,15 +1,24 @@
-"""Where the collectives of a compiled program sit.
+"""What a compiled program is made of, read from its own text.
 
 ``compiled.as_text()`` (optimised HLO, after the SPMD partitioner) names
-every collective with its replica groups, its shapes and the computation
-that holds it; a ``while`` names its body and, where the compiler knows it,
-its trip count.  ``collectives`` reads that text into one row a collective:
-family, device groups, dtypes, bytes, the trip counts of the loops around
-it, and so the calls it makes each time the program runs.  ``mesh_groups``
-gives the groups a mesh axis makes, to hold a row's groups against.
+every instruction with its opcode, its shapes, its operands, the
+computation that holds it and the ``op_name`` the trace left on it; a
+``while`` names its body and, where the compiler knows it, its trip count;
+a collective names its replica groups.  ``instructions`` reads that text
+into one row an instruction, under the name a profiler's trace prints for
+it, so that a device operation can be looked up in the program that ran
+it.  ``collectives`` is the rows that are collectives: family, device
+groups, dtypes, bytes, the trip counts of the loops around it, and so the
+calls it makes each time the program runs.  ``mesh_groups`` gives the
+groups a mesh axis makes, to hold a row's groups against, and
+``ProgramTable`` is the rows of one program with a ``role`` each, given
+by whoever owns the shapes (the engine its pool's, the trainer its
+mesh's).
 
 ``python -m megatron_llm_tpu.hlo_collectives step.hlo.txt`` prints the
-table (docs/guide/collective_placement.md).  Standard library only.
+collectives' table, with ``--instructions`` the instructions by role
+and opcode (docs/guide/collective_placement.md,
+docs/guide/observability.md).  Standard library only.
 """
 
 from __future__ import annotations
@@ -19,7 +28,7 @@ import json
 import math
 import re
 import sys
-from typing import Dict, FrozenSet, List, Sequence, Tuple
+from typing import Dict, FrozenSet, Iterable, List, Optional, Sequence, Tuple
 
 FAMILIES = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
             "collective-permute")
@@ -28,7 +37,7 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
              "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
 
 _HEADER = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.*\{\s*$")
-_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*?)\s"
+_INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s"
                     r"([a-z][a-z\-]*)\(")
 _SHAPE = re.compile(r"\b([a-z]+[0-9]*[a-z0-9]*)\[([0-9,]*)\]")
 _CALLED = re.compile(r"\b(?:body|condition|calls|to_apply|true_computation|"
@@ -108,12 +117,110 @@ def mesh_groups(mesh_shape: Dict[str, int], axes: Sequence[str]) -> Groups:
     return frozenset(tuple(g) for g in groups.values())
 
 
-def collectives(hlo_text: str) -> List[dict]:
-    """One row a collective instruction of a compiled module's text:
-    ``family``, ``groups``, ``dtypes``, ``bytes`` (of its result: what an
-    all-reduce moves in, an all-gather out), ``computation``, ``loops``
-    (trip counts of the enclosing ``while`` bodies, outermost first; None
-    for a count the compiler does not state) and ``calls`` a run."""
+# the named scopes the programs' sources open (``jax.named_scope``), which
+# arrive in an instruction's ``op_name`` as path components; a row's
+# ``scope`` is the innermost of these its ``op_name`` holds
+SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
+          "moe_combine", "qk_norm", "dsa_indexer", "attention", "mlp",
+          "embedding", "lm_head", "transformer_layer")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_SCOPE_CORE = re.compile(r"^(?:\w+\()*([\w.\-]+)\)*$")
+_NAME = re.compile(r"^[A-Za-z_][\w.\-]*$")
+# an instruction that moves its operand and computes nothing
+COPIES = ("copy", "copy-done", "slice-done")
+_POOL_MOVES = COPIES + ("copy-start", "slice-start", "dynamic-update-slice")
+
+
+def scope_of(op_name: str, scopes: Sequence[str] = SCOPES) -> str:
+    """The innermost of ``scopes`` among the path components of an
+    ``op_name`` (``jit(step)/transpose(jvp(attention))/mul`` is in
+    ``attention``); ``""`` where it holds none."""
+    for part in reversed(op_name.split("/")):
+        m = _SCOPE_CORE.match(part)
+        if m and m.group(1) in scopes:
+            return m.group(1)
+    return ""
+
+
+def _operands(line: str, at: int) -> List[str]:
+    """Names between the parenthesis at ``line[at]`` and its partner."""
+    depth, j = 0, at
+    for j in range(at, len(line)):
+        depth += {"(": 1, ")": -1}.get(line[j], 0)
+        if depth == 0:
+            break
+    inside = line[at + 1:j]
+    if "%" in inside:
+        return re.findall(r"%([\w.\-]+)", inside)
+    last = [part.split()[-1] for part in inside.split(",") if part.strip()]
+    return [t for t in last if _NAME.match(t)]
+
+
+def _nbytes(shapes) -> int:
+    return sum(_ITEMSIZE.get(d, 4) * math.prod(dims) for d, dims in shapes)
+
+
+def _parse(line: str, scopes: Sequence[str]) -> Optional[dict]:
+    m = _INSTR.match(line)
+    if not m:
+        return None
+    shapes = [(d, tuple(_ints(dims)))
+              for d, dims in _SHAPE.findall(m.group(3))]
+    op = _OP_NAME.search(line)
+    op_name = op.group(1).replace("\\'", "'") if op else ""
+    return {
+        "name": m.group(2), "opcode": m.group(4), "is_root": bool(m.group(1)),
+        "dtype": shapes[0][0] if shapes else "",
+        "shape": shapes[0][1] if shapes else (),
+        "shapes": shapes, "bytes": _nbytes(shapes),
+        "operands": _operands(line, m.end() - 1),
+        "op_name": op_name, "scope": scope_of(op_name, scopes),
+    }
+
+
+def _fused_scope(body: List[dict], own: str) -> str:
+    """A fusion's scope: the one that most of its body's instructions
+    carry, among those that carry one (the compiler's own converts and
+    broadcasts carry no ``op_name``, and what the source left outside
+    every scope, a residual add fused into the matmul before it, carries
+    none either: neither votes); the ROOT's where two scopes tie; the
+    fusion's own ``op_name``'s where no body instruction carries one."""
+    votes: Dict[str, int] = {}
+    for r in body:
+        if r["scope"]:
+            votes[r["scope"]] = votes.get(r["scope"], 0) + 1
+    if not votes:
+        return own
+    best = max(votes.values())
+    leaders = [s for s, n in votes.items() if n == best]
+    root = next((r["scope"] for r in body if r["is_root"]), own)
+    return root if root in leaders else sorted(leaders)[0]
+
+
+def instructions(hlo_text: str,
+                 scopes: Sequence[str] = SCOPES) -> List[dict]:
+    """One row an instruction of a compiled module's text, in the text's
+    order: ``name`` (as a profiler's trace prints it, without ``%``),
+    ``opcode``, the result's ``dtype`` / ``shape`` (a tuple's first) with
+    every result in ``shapes`` and their ``bytes`` together, ``operands``
+    (names), ``computation``, ``under`` (the opcodes of the call sites
+    around its computation, outermost first), ``loops`` (trip counts of
+    the enclosing ``while`` bodies, outermost first; None for a count the
+    compiler does not state), ``calls`` a run, ``op_name`` (the metadata
+    string, ``""`` where the compiler made the instruction itself) and
+    ``scope`` (``scope_of`` it).  A collective's row has ``family``,
+    ``groups`` and ``dtypes`` besides.
+
+    An instruction inside a fusion's body is no row (a trace has no event
+    for it, nor for a reducer's ``to_apply``); the fusion's row carries
+    ``root`` (its body's ROOT opcode; any other row's own opcode) and the
+    scope ``_fused_scope`` gives it: the scope most of its body's scoped
+    instructions carry, the ROOT's on a tie.  A loaded executable prints
+    an asynchronous slice or copy as ``async-start`` / ``async-done``
+    around a computation that holds the one instruction: such a row's
+    ``root`` is that instruction's opcode with ``-start`` / ``-done``
+    (``slice-done``), as the compiler's own name for it reads, and the
+    ``async-start`` says in ``wraps`` which instruction it is."""
     comps: Dict[str, List[str]] = {}
     entry = name = None
     for line in hlo_text.splitlines():
@@ -126,12 +233,24 @@ def collectives(hlo_text: str) -> List[dict]:
         elif name is not None and "=" in line:
             comps[name].append(line)
 
-    # computation -> the loops around its (first) call site
-    loops: Dict[str, Tuple] = {entry: ()}
+    def body_of(comp):
+        return [r for r in (_parse(ln, scopes) for ln in comps.get(comp, ()))
+                if r is not None]
+
+    # computation -> (trip counts, opcodes) of the call sites around its
+    # (first) call site; a fusion's body and a reducer are not walked
+    around: Dict[str, Tuple[Tuple, Tuple]] = {entry: ((), ())}
+    parsed: Dict[str, List[dict]] = {}
     todo = [entry]
     while todo:
         comp = todo.pop()
+        parsed[comp] = rows = []
         for line in comps.get(comp, ()):
+            row = _parse(line, scopes)
+            if row is None:
+                continue
+            row["_line"] = line
+            rows.append(row)
             called = _CALLED.findall(line)
             b = _BRANCHES.search(line)
             if b:
@@ -139,43 +258,200 @@ def collectives(hlo_text: str) -> List[dict]:
                            for c in b.group(1).split(",")]
             if not called:
                 continue
-            inner = loops[comp]
+            op = row["opcode"]
+            if op == "fusion":
+                body = body_of(called[0])
+                row["root"] = next((r["opcode"] for r in body
+                                    if r["is_root"]), op)
+                row["scope"] = _fused_scope(body, row["scope"])
+                continue
+            if op == "async-start":
+                inner = next((r for r in body_of(called[0])
+                              if r["is_root"]), None)
+                if inner is not None:
+                    row["root"] = inner["opcode"] + "-start"
+                    row["wraps"] = inner["name"]
+            loops, under = around[comp]
             body = re.search(r"\bbody=%?([\w.\-]+)", line)
+            applied = re.search(r"\bto_apply=%?([\w.\-]+)", line)
             for c in called:
-                if c in loops or c not in comps:
+                if c in around or c not in comps:
+                    continue
+                if applied and c == applied.group(1) and op != "call":
                     continue
                 if body and c == body.group(1):
-                    loops[c] = inner + (_trips(line, comps),)
+                    around[c] = (loops + (_trips(line, comps),),
+                                 under + (op,))
                 else:
-                    loops[c] = inner
+                    around[c] = (loops, under + (op,))
                 todo.append(c)
 
-    rows = []
-    for comp, lines in comps.items():
-        if comp not in loops:
-            continue
-        for line in lines:
-            m = _INSTR.match(line)
-            if not m:
-                continue
-            op = m.group(2)
+    out = []
+    for comp, lines in comps.items():       # the text's order
+        for row in parsed.get(comp, ()):
+            loops, under = around[comp]
+            row.update(computation=comp, loops=loops, under=under,
+                       calls=math.prod(t or 1 for t in loops))
+            row.setdefault("root", row["opcode"])
+            del row["is_root"]
+            line = row.pop("_line")
+            op = row["opcode"]
             family = op[:-len("-start")] if op.endswith("-start") else op
-            if family not in FAMILIES:
+            if family in FAMILIES:
+                shapes = row["shapes"]
+                if op == "all-gather-start" and len(shapes) > 1:
+                    shapes = shapes[len(shapes) // 2:]  # (operands, results)
+                row.update(
+                    family=family, groups=_groups(line),
+                    dtypes=sorted({d for d, _ in shapes}),
+                    bytes=_nbytes(shapes))
+            out.append(row)
+    by_name = {r["name"]: r for r in out}
+    for row in out:         # an asynchronous pair's second half
+        if row["opcode"] in ("async-done", "async-update"):
+            start = by_name.get(row["operands"][0] if row["operands"]
+                                else "", {})
+            while start.get("opcode") == "async-update":
+                start = by_name.get(start["operands"][0], {})
+            if start.get("root", "").endswith("-start"):
+                row["root"] = start["root"][:-len("start")] + "done"
+    return out
+
+
+def collectives(hlo_text: str) -> List[dict]:
+    """One row a collective instruction of a compiled module's text (the
+    rows of ``instructions`` that have a ``family``): ``family``,
+    ``groups``, ``dtypes``, ``bytes`` (of its result: what an all-reduce
+    moves in, an all-gather out), ``computation``, ``loops`` and ``calls``
+    a run."""
+    return [r for r in instructions(hlo_text) if "family" in r]
+
+
+_HLO_DTYPE = {"bool": "pred", "int8": "s8", "uint8": "u8", "int16": "s16",
+              "uint16": "u16", "int32": "s32", "uint32": "u32",
+              "int64": "s64", "uint64": "u64", "float16": "f16",
+              "bfloat16": "bf16", "float32": "f32", "float64": "f64",
+              "float8_e4m3fn": "f8e4m3fn", "float8_e5m2": "f8e5m2"}
+
+
+def edge_of(groups: Groups, mesh_shape: Dict[str, int]) -> str:
+    """The mesh axes a collective's ``groups`` run over, joined by ``+``
+    in the mesh's order: the fewest axes whose ``mesh_groups`` hold every
+    group whole (a permute's pairs lie inside its ring's groups); no
+    groups stated is every device, hence every axis; ``""`` where each
+    group is one device."""
+    names = [a for a in mesh_shape if mesh_shape[a] > 1]
+    if not groups:
+        return "+".join(names)
+    for n in range(len(names) + 1):
+        for axes in itertools.combinations(names, n):
+            held = mesh_groups(mesh_shape, axes)
+            if all(any(set(g) <= set(h) for h in held) for g in groups):
+                return "+".join(axes)
+    return ""
+
+
+class ProgramTable:
+    """The rows of one compiled program (``instructions`` of its text),
+    each with its ``program``'s name, and a ``role`` and an ``edge`` from
+    whoever owns the shapes:
+
+    * ``kv_pool``: an instruction that only moves data (a copy, an
+      asynchronous copy's or slice's halves, a dynamic-update-slice, or a
+      fusion with one of these at its root) and whose result (of an
+      asynchronous slice: whose operand, which the ``-start``'s result
+      keeps) has exactly the dtype and shape of one of ``pool``'s arrays
+      (``(numpy dtype name, shape)`` each): decided from the text and the
+      shapes, never from a name;
+    * else its ``scope`` (``""`` where it has none);
+    * ``edge``: for a collective, ``edge_of`` its groups on ``mesh_shape``
+      (an ordered ``{axis: size}``), so that an all-reduce the compiler
+      named ``psum.7`` is an all-reduce over ``dp`` because its replica
+      groups say so (a ``-done`` has its ``-start``'s); ``""`` for any
+      other row, and with no mesh."""
+
+    def __init__(self, name: str, rows: List[dict],
+                 pool: Iterable[Tuple[str, Tuple[int, ...]]] = (),
+                 mesh_shape: Optional[Dict[str, int]] = None):
+        self.name, self.rows = name, rows
+        self._by_name = {r["name"]: r for r in rows}
+        pool = frozenset((_HLO_DTYPE.get(d, d), tuple(sh)) for d, sh in pool)
+        for r in rows:
+            r["program"] = name
+            r["edge"] = (edge_of(r["groups"], mesh_shape)
+                         if mesh_shape and "family" in r else "")
+            r["role"] = ("kv_pool" if pool and self._moves(r, pool)
+                         else r["scope"])
+        for r in rows:      # an asynchronous collective's two halves
+            if "wraps" in r:
+                r["edge"] = self._by_name.get(r["wraps"], r)["edge"]
+        for r in rows:
+            if r["opcode"].endswith(("-done", "-update")) and r["operands"]:
+                r["edge"] = self._by_name.get(r["operands"][0],
+                                              r).get("edge", "")
+
+    def _moves(self, row: dict, pool: frozenset) -> bool:
+        if row["root"] not in _POOL_MOVES:
+            return False
+        seen = list(row["shapes"])
+        if row["opcode"].endswith("-done") and row["operands"]:
+            # the piece an asynchronous slice takes is the pool's because
+            # its -start's operand (kept in the -start's result) is
+            seen += self._by_name.get(row["operands"][0], row)["shapes"]
+        return any(s in pool for s in seen)
+
+    def get(self, name: str) -> Optional[dict]:
+        """The row of the instruction a trace's event names
+        (``%copy.12``, with or without the ``%``)."""
+        return self._by_name.get(name.lstrip("%").split(" = ")[0])
+
+    def kv_pool_copy_bytes(self) -> int:
+        """Bytes the program's ``kv_pool`` copies move each time it runs
+        (an asynchronous pair counted at its ``-done``)."""
+        return sum(r["bytes"] * r["calls"] for r in self.rows
+                   if r["role"] == "kv_pool" and r["root"] in COPIES)
+
+    def collectives_by_edge(self) -> Dict[str, Dict[str, int]]:
+        """``{edge: {family: {calls, bytes}}}`` a run of the program."""
+        out: Dict[str, Dict[str, Dict[str, int]]] = {}
+        for r in self.rows:
+            if "family" in r:
+                m = out.setdefault(r["edge"], {}).setdefault(
+                    r["family"], {"calls": 0, "bytes": 0})
+                m["calls"] += r["calls"]
+                m["bytes"] += r["bytes"] * r["calls"]
+        return out
+
+    def summary(self) -> Dict[str, object]:
+        """What an operator reads of a program (``stats()['programs']``):
+        its instructions, the bytes its pool copies move a launch, and
+        the named scopes with the instructions each holds."""
+        scopes: Dict[str, int] = {}
+        for r in self.rows:
+            if r["scope"]:
+                scopes[r["scope"]] = scopes.get(r["scope"], 0) + 1
+        return {"instructions": len(self.rows),
+                "kv_pool_copy_bytes_per_launch": self.kv_pool_copy_bytes(),
+                "scopes": dict(sorted(scopes.items()))}
+
+    def table(self) -> str:
+        """The rows grouped by (role, edge, opcode), most bytes first."""
+        merged: Dict[tuple, dict] = {}
+        for r in self.rows:
+            if r["opcode"] in ("parameter", "constant", "tuple",
+                               "get-tuple-element", "bitcast"):
                 continue
-            shapes = _SHAPE.findall(m.group(1))
-            if op == "all-gather-start" and len(shapes) > 1:
-                shapes = shapes[len(shapes) // 2:]   # (operands, results)
-            rows.append({
-                "family": family,
-                "groups": _groups(line),
-                "dtypes": sorted({d for d, _ in shapes}),
-                "bytes": sum(_ITEMSIZE.get(d, 4) * math.prod(_ints(dims))
-                             for d, dims in shapes),
-                "computation": comp,
-                "loops": loops[comp],
-                "calls": math.prod(t or 1 for t in loops[comp]),
-            })
-    return rows
+            m = merged.setdefault((r["role"], r["edge"], r["root"]),
+                                  {"n": 0, "per_run": 0})
+            m["n"] += 1
+            m["per_run"] += r["bytes"] * r["calls"]
+        out = [f"{self.name}: role | edge | opcode (a fusion's root) | "
+               "instructions | MB a run (results)"]
+        for (role, edge, op), m in sorted(merged.items(),
+                                          key=lambda kv: -kv[1]["per_run"]):
+            out.append(" | ".join([role or "-", edge or "-", op, str(m["n"]),
+                                   f"{m['per_run'] / 1e6:.2f}"]))
+        return "\n".join(out)
 
 
 def reductions_over(rows: List[dict], groups: Groups,
@@ -210,5 +486,9 @@ def table(rows: List[dict]) -> str:
 
 
 if __name__ == "__main__":
-    with open(sys.argv[1]) as f:
-        print(table(collectives(f.read())))
+    with open(sys.argv[-1]) as f:
+        text = f.read()
+    if "--instructions" in sys.argv[1:-1]:
+        print(ProgramTable(sys.argv[-1], instructions(text)).table())
+    else:
+        print(table(collectives(text)))

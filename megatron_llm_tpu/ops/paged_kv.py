@@ -163,6 +163,18 @@ def block_bytes(pools) -> int:
                for a in jax.tree_util.tree_leaves(pools))
 
 
+def array_shapes(pools) -> set:
+    """``(dtype name, shape)`` of every array of every layer's pool, as
+    allocated ``[blocks, block_size, ...]`` and as ``attend`` writes it,
+    one row a token ``[blocks * block_size, ...]``: what an instruction of
+    a compiled program is held against to say that it moves the pool."""
+    out = set()
+    for a in jax.tree_util.tree_leaves(pools):
+        out.add((a.dtype.name, tuple(a.shape)))
+        out.add((a.dtype.name, (a.shape[0] * a.shape[1],) + a.shape[2:]))
+    return out
+
+
 def fetch_page(pools, src):
     """Physical page ``src`` of every array of every layer's pool."""
     return jax.tree_util.tree_map(
@@ -262,10 +274,11 @@ class PagedKVCache:
             writes["index_pages"] = _to_width(
                 index[1], self.pool["index_pages"].shape[-1])
         pool = {}
-        for name, val in writes.items():
-            a = self.pool[name]
-            flat = a.reshape((P * bs,) + a.shape[2:])
-            pool[name] = flat.at[dest].set(val).reshape(a.shape)
+        with jax.named_scope("kv_write"):
+            for name, val in writes.items():
+                a = self.pool[name]
+                flat = a.reshape((P * bs,) + a.shape[2:])
+                pool[name] = flat.at[dest].set(val).reshape(a.shape)
         kp, vp, k_scales, v_scales = _arrays(pool)
         scale = 1.0 / math.sqrt(d)
         if index is not None:

@@ -80,7 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from megatron_llm_tpu import telemetry, tracing
+from megatron_llm_tpu import hlo_collectives, telemetry, tracing
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.ops.pallas import grouped_matmul
@@ -193,6 +193,17 @@ def _program(fn, name: str):
         return fn(*args)
     program.__name__ = program.__qualname__ = name
     return jax.jit(program)
+
+
+def _abstract(x) -> jax.ShapeDtypeStruct:
+    """An argument as a compile saw it: shape, dtype and, of an array
+    that was placed (committed), where it lies.  Lowering from these hits
+    jit's own cache: the executable that ran, with no compile."""
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return x
+    placed = isinstance(x, jax.Array) and x.committed
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                sharding=x.sharding if placed else None)
 
 
 def _key_from_seed(seed: int) -> np.ndarray:
@@ -366,6 +377,20 @@ class InferenceEngine:
         self._fetch_block = _program(paged_kv.fetch_page,
                                      "engine_fetch_block")
         self._host_load = _program(paged_kv.load_page, "engine_host_load")
+        # for program_tables(): the jitted programs themselves by the
+        # names their XLA modules carry (the attributes may be wrapped),
+        # and the tables once somebody has asked
+        self._jitted = {
+            "engine_decode": self._decode_step,
+            "engine_verify": self._verify_step,
+            "engine_prefill": self._prefill_step,
+            "engine_sample_first": self._sample_first,
+            "engine_cow_copy": self._cow_copy,
+            "engine_fetch_block": self._fetch_block,
+            "engine_host_load": self._host_load}
+        self._program_tables: Optional[Dict[str, Any]] = None
+        self.kv_pool_bytes = sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(self._st.pages))
 
         # counters (read by stats()/the HTTP /metrics endpoint)
         self.decode_steps = 0
@@ -415,6 +440,7 @@ class InferenceEngine:
         # Engine-lifetime (like the counters above): restarts swap the
         # state object, not the loop accounting.
         self.loop_profiler = LoopProfiler()
+        self.loop_profiler.program_source = self.program_tables
         self._dispatches = 0            # prefill chunks + decode steps
         self._watchdog: Optional[EngineWatchdog] = None
         self._restart_lock = threading.Lock()
@@ -532,8 +558,9 @@ class InferenceEngine:
         hit = jnp.arange(V)[None, :] == jnp.clip(ban_b, 0, V - 1)[:, None]
         logits = jnp.where(banned[:, None] & hit, NEG_INF, logits)
         sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [S, 2, 2]
-        next_tokens = sample_batched(logits, sub[:, 0], top_ks, top_ps,
-                                     temps, active > 0)
+        with jax.named_scope("sampler"):
+            next_tokens = sample_batched(logits, sub[:, 0], top_ks, top_ps,
+                                         temps, active > 0)
         return (next_tokens, paged_kv.pools_of(new_caches), sub[:, 1],
                 finite, paged_kv.routing_of(new_caches))
 
@@ -575,8 +602,9 @@ class InferenceEngine:
                == jnp.clip(ban_b, 0, V - 1)[:, None, None])
         logits = jnp.where(banned[:, :, None] & hit, NEG_INF, logits)
         sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-        first = sample_batched(logits[:, 0, :], sub[:, 0], top_ks,
-                               top_ps, temps, vlens > 0)
+        with jax.named_scope("sampler"):
+            first = sample_batched(logits[:, 0, :], sub[:, 0], top_ks,
+                                   top_ps, temps, vlens > 0)
         emit = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         emit = emit.at[:, 0].set(first.astype(jnp.int32))
         return (emit, paged_kv.pools_of(new_caches), sub[:, 1], finite,
@@ -621,8 +649,9 @@ class InferenceEngine:
         hit = jnp.arange(V)[None, :] == jnp.clip(ban_b, 0, V - 1)
         logits = jnp.where(banned & hit, NEG_INF, logits)
         sub = jax.random.split(key, 2)
-        tok = sample_batched(logits, sub[0][None], top_k[None],
-                             top_p[None], temp[None], jnp.ones(1, bool))
+        with jax.named_scope("sampler"):
+            tok = sample_batched(logits, sub[0][None], top_k[None],
+                                 top_p[None], temp[None], jnp.ones(1, bool))
         return tok[0], sub[1], finite
 
     # ------------------------------------------------------------------
@@ -1514,6 +1543,82 @@ class InferenceEngine:
         self.loop_profiler.stall_armed = True
         tracing.instant("engine_warm", "serve")
 
+    def _program_arguments(self) -> Dict[str, tuple]:
+        """What each program warm-up compiled is launched with, as the
+        launches' call sites build it from the state (the shapes are
+        fixed for the engine's life): the programs by name, for
+        ``program_tables`` to lower from."""
+        st, cfg = self._st, self.config
+        S, zero = cfg.num_slots, np.int32(0)
+        per_slot = (st.temps, st.top_ks, st.top_ps, st.ban_a, st.ban_b,
+                    st.keys)
+        pages = _abstract(jax.tree_util.tree_leaves(st.pages)[0])
+        found = {
+            "engine_prefill": (
+                self.params, st.pages,
+                np.zeros((1, cfg.prefill_chunk), np.int32), zero, zero,
+                self._tables(st, slice(0, 1))),
+            # a chunk's last logits: the prefill program's output
+            "engine_sample_first": (
+                jax.ShapeDtypeStruct(
+                    (int(self.model.cfg.padded_vocab_size),), jnp.float32,
+                    sharding=pages.sharding),
+                st.keys[0], st.top_ks[0], st.top_ps[0], st.temps[0],
+                st.ban_a[0], st.ban_b[0], zero),
+            "engine_cow_copy": (st.pages, zero, zero),
+        }
+        if self.speculative:
+            found["engine_verify"] = (
+                self.params, st.pages,
+                np.zeros((S, self.draft_k + 1), np.int32), st.context_lens,
+                self._tables(st), st.active) + per_slot
+        else:
+            found["engine_decode"] = (
+                self.params, st.pages, st.last_tokens, st.context_lens,
+                self._tables(st), st.active) + per_slot
+        if self.host_cache is not None:
+            page = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                st.pages)
+            found["engine_fetch_block"] = (st.pages, zero)
+            found["engine_host_load"] = (st.pages, page, zero)
+        return found
+
+    def program_tables(self, sharding=None) -> Dict[str, Any]:
+        """An instruction table (``hlo_collectives.ProgramTable``) of each
+        program warm-up compiled, read from the program's own optimised
+        text: every instruction under the name a profiler's trace prints,
+        with its opcode, shapes, operands, loops, ``op_name``, scope and
+        role (``kv_pool`` for what moves an array of this engine's pool).
+        Built when first asked for and never before: each program is
+        lowered again from the abstract form of ``_program_arguments``
+        (a hit in jit's own cache, the executable that ran; a backend
+        compile where that cache has been dropped), so nothing here is on
+        a launch's path nor in warm-up, and ``stats()['programs']`` is
+        None until somebody asks.  The loop profiler holds the same
+        tables beside the launch ring
+        (``live_profilers()[0].program_tables()``).  ``sharding`` lowers
+        for another placement than the one that ran (a described chip)
+        and keeps nothing; an engine that has not warmed up has none."""
+        if self._program_tables is not None and sharding is None:
+            return self._program_tables
+        if not self.warmed_up:
+            return {}
+        pool = paged_kv.array_shapes(self._st.pages)
+        tables = {}
+        for name, args in self._program_arguments().items():
+            args = jax.tree_util.tree_map(_abstract, args)
+            if sharding is not None:
+                args = jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                   sharding=sharding), args)
+            text = self._jitted[name].lower(*args).compile().as_text()
+            tables[name] = hlo_collectives.ProgramTable(
+                name, hlo_collectives.instructions(text), pool=pool)
+        if sharding is None:
+            self._program_tables = self.loop_profiler.programs = tables
+        return tables
+
     def estimate_wait_secs(self) -> float:
         """Rough queue wait for a newly rejected request: queued depth
         times mean per-request engine time, divided across slots.  Cheap
@@ -1561,5 +1666,12 @@ class InferenceEngine:
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,
             "loop": self.loop_profiler.stats(),
             "cache": self.cache_observatory.stats(),
+            # the programs' instruction tables, once program_tables() has
+            # been asked for; beside them the pool's bytes, which
+            # kv_pool_copy_bytes_per_launch is held against
+            "kv_pool_bytes": self.kv_pool_bytes,
+            "programs": (None if self._program_tables is None else
+                         {name: t.summary()
+                          for name, t in self._program_tables.items()}),
         })
         return s

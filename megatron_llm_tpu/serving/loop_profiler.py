@@ -40,7 +40,16 @@ The record is the serve loop's ONE span source:
   ``prefill_chunk`` span (start of ``dispatch`` to end of ``fetch``)
   that ``tools/serve_report.py`` joins a request's lifecycle on;
 * ``record_request`` keeps each retired request's own span beside the
-  launches (submit, admit, first token, finish on the same clock).
+  launches (submit, admit, first token, finish on the same clock);
+* ``programs`` holds, beside the ring, an instruction table of each
+  compiled program behind the launches (``hlo_collectives.ProgramTable``,
+  by the program's name: ``LAUNCH_PROGRAMS`` says which a launch of each
+  kind runs), so that a device operation of a profiler's trace that
+  starts inside a launch can be looked up by its name and given a role
+  and, through the launch's record, the requests that caused it.  Empty
+  until ``program_tables()`` is asked for: building them reads each
+  program's text and is nobody's launch path.  A program with no launch
+  ring (the train step) registers its table under ``live_programs()``.
 
 Everything here is host-side python: the profiler never touches a
 traced value, so the zero-steady-state-recompile invariant holds with
@@ -60,7 +69,7 @@ import time
 from bisect import bisect_left
 from collections import deque
 from itertools import islice
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
 
@@ -102,6 +111,16 @@ MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
 # which the engine also keeps running totals of
 KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
              "kv_held_bytes", "kv_full_pages_held", "kv_live_tokens")
+
+# the compiled programs whose operations run inside a launch of each
+# kind (a last prefill chunk samples its first token in the same launch),
+# and the page programs, which run while the next launch's inputs are
+# built: the keys of ``LoopProfiler.programs``
+LAUNCH_PROGRAMS = {"prefill": ("engine_prefill", "engine_sample_first"),
+                   "decode": ("engine_decode",),
+                   "verify": ("engine_verify",)}
+PAGE_PROGRAMS = ("engine_cow_copy", "engine_fetch_block",
+                 "engine_host_load")
 
 # the enclosing Chrome-trace span of a launch, by its kind
 _SPAN_NAME = {"prefill": "prefill_chunk", "decode": "decode_step",
@@ -286,10 +305,15 @@ class DispatchRecord:
 # The profilers of this process's newest engines, so that a reader which
 # was handed no engine (the benchmark's metric sources receive only their
 # ``run``, and read after the engine has stopped) reaches the spans.  A
-# profiler holds no reference to its engine; it stays readable here until
-# ``_LIVE.maxlen`` newer ones have pushed it out.
+# profiler stays readable here until ``_LIVE.maxlen`` newer ones have
+# pushed it out, and through ``program_source`` so does what its engine's
+# tables are built from.
 _LIVE: deque = deque(maxlen=4)
 _LIVE_LOCK = threading.Lock()
+# programs that run with no launch ring (the train step), by name: a
+# function that builds the program's table, and the table once built
+_PROGRAM_SOURCES: Dict[str, Callable[[], Any]] = {}
+_PROGRAM_TABLES: Dict[str, Any] = {}
 
 
 def live_profilers() -> List["LoopProfiler"]:
@@ -298,6 +322,30 @@ def live_profilers() -> List["LoopProfiler"]:
     with _LIVE_LOCK:
         found = list(_LIVE)
     return sorted(found, key=lambda p: -p.launches())
+
+
+def register_program(name: str, build: Callable[[], Any]) -> None:
+    """``build()`` gives the instruction table of the program ``name``
+    of this process; it is called when ``live_programs()`` is first read
+    and not before."""
+    with _LIVE_LOCK:
+        _PROGRAM_SOURCES[name] = build
+        _PROGRAM_TABLES.pop(name, None)
+
+
+def live_programs() -> Dict[str, Any]:
+    """The tables of the registered programs, each built on first ask; a
+    program whose table cannot be built is left out."""
+    with _LIVE_LOCK:
+        todo = {n: b for n, b in _PROGRAM_SOURCES.items()
+                if n not in _PROGRAM_TABLES}
+    for name, build in todo.items():
+        table = build()
+        if table is not None:
+            with _LIVE_LOCK:
+                _PROGRAM_TABLES[name] = table
+    with _LIVE_LOCK:
+        return dict(_PROGRAM_TABLES)
 
 
 class LoopProfiler:
@@ -362,6 +410,10 @@ class LoopProfiler:
         self._gap_note: Optional[TraceAnnotation] = None
         self._emitted_at_dispatches = 0
         self._emitted_at_time = self._clock()
+        # the programs' instruction tables by program name, and what
+        # builds them (the engine's program_tables): see program_tables()
+        self.programs: Dict[str, Any] = {}
+        self.program_source: Optional[Callable[[], Dict[str, Any]]] = None
         with _LIVE_LOCK:
             _LIVE.append(self)
 
@@ -480,6 +532,14 @@ class LoopProfiler:
                 return list(self._ring)
             return list(islice(reversed(self._ring), last))[::-1]
 
+    def program_tables(self) -> Dict[str, Any]:
+        """``programs``, built first if nobody has asked yet (the
+        engine's ``program_tables``: a reader's cost, after the launches
+        it wants to explain)."""
+        if not self.programs and self.program_source is not None:
+            self.programs = self.program_source()
+        return self.programs
+
     def request_spans(self) -> List[RequestSpan]:
         with self._lock:
             return list(self._requests)
@@ -536,12 +596,10 @@ class LoopProfiler:
             "wall_secs": round(wall, 6),
             "gap_secs": round(gap, 6),
             "wait_secs": round(wait, 6),
-            "host_secs": round(max(wall - wait, 0.0), 6),
             "phase_secs": {p: round(v, 6) for p, v in phase_secs.items()},
             "wait_pct": wait_pct,
             "host_bubble_pct": bubble_pct,
             "stalls": stalls,
-            "stall_threshold_secs": self.stall_threshold_secs,
             "window": {
                 "dispatches": len(recent),
                 "wall_secs": round(w_wall, 6),

@@ -203,7 +203,11 @@ class _ReadStep:
     say where its data-parallel gradient reductions sit.  One
     ``train_step_program`` record goes to the structured log
     (``--structured_log_dir``): ``dp_grad_reductions_per_step`` is counted
-    from the text (``hlo_collectives``), not asserted from this file."""
+    from the text (``hlo_collectives``), not asserted from this file, and
+    ``collectives_by_edge`` gives every collective's calls and bytes a step
+    under the mesh axes its replica groups run over.  The instruction
+    table they are read from is registered beside the serve loop's
+    (``loop_profiler.live_programs()['train_step']``)."""
 
     def __init__(self, jitted, num_microbatches: int):
         self.jitted, self.num_microbatches = jitted, num_microbatches
@@ -230,7 +234,17 @@ class _ReadStep:
                         "num_microbatches": self.num_microbatches,
                         "dp": math.prod(mesh.shape[a] for a in axes)}
         try:
-            rows = hlo_collectives.collectives(self.compiled.as_text())
+            # every instruction of the step under the name a trace prints
+            # for it, a collective with the mesh axes its groups run over
+            # (its edge): ``live_programs()['train_step']``
+            table = hlo_collectives.ProgramTable(
+                "train_step",
+                hlo_collectives.instructions(self.compiled.as_text()),
+                mesh_shape=dict(mesh.shape) if mesh is not None else None)
+            from megatron_llm_tpu.serving import loop_profiler
+            # (the table alone: not this object and its executable)
+            loop_profiler.register_program("train_step", lambda: table)
+            rows = [r for r in table.rows if "family" in r]
             # over dp, over slice, or (the flat multi-slice sum) over both
             over = [axes[k:j + 1] for k in range(len(axes))
                     for j in range(k, len(axes))]
@@ -245,7 +259,8 @@ class _ReadStep:
                 dp_grad_reduction_bytes_per_step=sum(
                     r["bytes"] * r["calls"] for r in found),
                 dp_grad_reduction_dtypes=sorted(
-                    {d for r in found for d in r["dtypes"]}))
+                    {d for r in found for d in r["dtypes"]}),
+                collectives_by_edge=table.collectives_by_edge())
         except Exception as e:  # noqa: BLE001 - a reading, never a failure
             logger.warning("train step's collectives not read: %s", e)
         if jax.process_index() == 0:

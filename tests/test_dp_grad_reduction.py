@@ -102,6 +102,19 @@ def test_the_step_logs_what_its_compiled_text_counts(tmp_path):
     assert 1 <= rec["dp_grad_reductions_per_step"] <= len(
         jax.tree_util.tree_leaves(args[0]))
     assert rec["dp_grad_reduction_dtypes"] == ["f32"]
+    # every collective under the mesh axes its groups run over: the dp
+    # edge's all-reduces are the gradient's, and the step's table is
+    # registered where a reader with no trainer in hand finds it
+    by_edge = rec["collectives_by_edge"]
+    assert by_edge["dp"]["all-reduce"]["calls"] >= rec[
+        "dp_grad_reductions_per_step"]
+    assert by_edge["dp"]["all-reduce"]["bytes"] >= rec[
+        "dp_grad_reduction_bytes_per_step"]
+    assert "tp" in by_edge and set(by_edge) <= {"dp", "tp", "dp+tp", ""}
+    from megatron_llm_tpu.serving.loop_profiler import live_programs
+    table = live_programs()["train_step"]
+    assert table.collectives_by_edge() == by_edge
+    assert {r["edge"] for r in table.rows if "family" in r} == set(by_edge)
     # an event, not a step: the stream's means leave it out
     assert stream.summary()["log_boundaries"] == 0
 

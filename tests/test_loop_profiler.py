@@ -82,7 +82,12 @@ def test_scripted_clock_exact_phase_accounting():
     s = prof.stats()
     # dispatch + fetch is what the host WAITED: never device time
     assert s["wait_secs"] == pytest.approx(0.020)
-    assert s["host_secs"] == pytest.approx(s["wall_secs"] - 0.020)
+    # what the host did NOT wait is the wall less that; no field of its
+    # own (nothing read one)
+    assert s["wall_secs"] - s["wait_secs"] == pytest.approx(
+        sum(v for p, v in s["phase_secs"].items()
+            if p not in ("dispatch", "fetch")))
+    assert "host_secs" not in s and "stall_threshold_secs" not in s
     want_wait = 100.0 * 0.020 / s["wall_secs"]
     assert s["wait_pct"] == pytest.approx(want_wait, abs=1e-3)
     assert s["host_bubble_pct"] == pytest.approx(100 - want_wait,
@@ -282,9 +287,9 @@ def test_stats_shape_and_histograms():
     _dispatch(prof, clock)
     s = prof.stats()
     for key in ("dispatches", "dispatches_by_kind", "wall_secs",
-                "gap_secs", "wait_secs", "host_secs", "phase_secs",
+                "gap_secs", "wait_secs", "phase_secs",
                 "wait_pct", "host_bubble_pct", "stalls",
-                "stall_threshold_secs", "window", "phase_p50_secs",
+                "window", "phase_p50_secs",
                 "phase_p95_secs", "histograms"):
         assert key in s
     assert set(s["histograms"]) == {f"loop_{p}_secs" for p in LOOP_PHASES}

@@ -211,6 +211,7 @@ CASES = {
 
 
 SAMPLER = "sampler_64_slots_50304_logits"
+ENGINE_TABLES = "engine_tables_tiny_sparse_model"
 
 
 def _compile_all():
@@ -238,6 +239,7 @@ def _compile_all():
         except Exception as e:  # noqa: BLE001 - the compiler's refusal
             found[case] = f"{type(e).__name__}: {e}"[:2000]
     found[SAMPLER] = _sampler_sorts(chip)
+    found[ENGINE_TABLES] = _engine_tables(chip)
     print(json.dumps(found))
 
 
@@ -258,6 +260,44 @@ def _sampler_sorts(chip):
             jax.jit(sample_batched).lower(*args).compile().as_text())
     except Exception as e:      # noqa: BLE001 - the compiler's refusal
         return f"{type(e).__name__}: {e}"[:2000]
+
+
+def _engine_tables(chip):
+    """A tiny sparse engine warmed up here, on the CPU, and then its own
+    programs' instruction tables as the TPU's compiler leaves them
+    (``program_tables(sharding=)`` lowers the programs warm-up ran for
+    the described chip): for each program its summary and its kv_pool
+    copies, beside the bytes of the pool and of one of its arrays."""
+    from megatron_llm_tpu import hlo_collectives
+    from megatron_llm_tpu.models.olmoe import OlmoeModel, olmoe_config
+    from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
+
+    # what runs here runs on the CPU: no kernel until the tables
+    forced = os.environ.pop("MLT_FORCE_PALLAS", None)
+    try:
+        model = OlmoeModel(olmoe_config("tiny", use_flash_attn=False))
+        eng = InferenceEngine(
+            model, model.init(jax.random.PRNGKey(0)),
+            EngineConfig(num_slots=4, block_size=16, max_model_len=64,
+                         prefill_chunk=16))
+        eng.warmup()
+        tables = eng.program_tables(sharding=chip)
+        return {
+            "pool_bytes": eng.kv_pool_bytes,
+            "array_bytes": sorted({a.nbytes for a in
+                                   jax.tree_util.tree_leaves(
+                                       eng._st.pages)}),
+            "built_for_the_engine": eng.stats()["programs"],
+            "programs": {
+                name: dict(t.summary(), kv_pool_copies=sorted(
+                    {r["root"] for r in t.rows if r["role"] == "kv_pool"
+                     and r["root"] in hlo_collectives.COPIES}))
+                for name, t in tables.items()}}
+    except Exception as e:      # noqa: BLE001 - the compiler's refusal
+        return f"{type(e).__name__}: {e}"[:2000]
+    finally:
+        if forced is not None:
+            os.environ["MLT_FORCE_PALLAS"] = forced
 
 
 @pytest.fixture(scope="module")
@@ -292,6 +332,29 @@ def test_sampler_compiles_to_one_guarded_sort_for_v5e(compiled):
     this: the TPU's compiler keeps the sampler's one sort inside the
     conditional's branch (it neither hoists nor duplicates it)."""
     assert compiled[SAMPLER] == [True], compiled[SAMPLER]
+
+
+def test_engine_tables_of_programs_compiled_for_v5e(compiled):
+    """The engine's own programs as the TPU's compiler leaves them: the
+    decode step copies the pool it was handed (whole arrays: it is a
+    parameter that is not donated), and the sampler, the cache's write
+    and the three routing scopes reach the optimised instructions."""
+    found = compiled[ENGINE_TABLES]
+    assert isinstance(found, dict), found
+    # lowering for another placement keeps nothing
+    assert found["built_for_the_engine"] is None
+    programs = found["programs"]
+    assert {"engine_decode", "engine_prefill", "engine_sample_first",
+            "engine_cow_copy"} <= set(programs)
+    (array,) = found["array_bytes"]
+    for name in ("engine_decode", "engine_prefill"):
+        moved = programs[name]["kv_pool_copy_bytes_per_launch"]
+        assert moved >= found["pool_bytes"] and moved % array == 0, name
+        assert programs[name]["kv_pool_copies"], name
+        assert {"kv_write", "attention", "moe_route", "moe_dispatch",
+                "moe_combine"} <= set(programs[name]["scopes"]), name
+    assert "sampler" in programs["engine_decode"]["scopes"]
+    assert set(programs["engine_sample_first"]["scopes"]) == {"sampler"}
 
 
 if __name__ == "__main__":

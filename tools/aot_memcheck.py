@@ -19,7 +19,9 @@ training configs in /root/reference/docs/guide/getting_started.md.
 Usage:
   python tools/aot_memcheck.py [config ...]     # default: all
   python tools/aot_memcheck.py --list
-  python tools/aot_memcheck.py --hlo_dir=DIR config   # + DIR/<config>.hlo.txt
+  python tools/aot_memcheck.py --hlo_dir=DIR config   # + DIR/<config>.hlo.txt,
+      # the step's instruction table (role | mesh edge | opcode) on stderr
+      # and collectives_by_edge in the record
 
 Each config runs in a forced-CPU subprocess (AOT needs only the local
 libtpu compiler, no chip).
@@ -307,12 +309,23 @@ def run_config(name: str, hlo_dir: str = "") -> dict:
     hbm = spec["hbm_gb"] * GB
 
     colls = {}
+    by_edge = None
     try:
         txt = compiled.as_text()
         if hlo_dir:
             os.makedirs(hlo_dir, exist_ok=True)
             with open(os.path.join(hlo_dir, name + ".hlo.txt"), "w") as f:
                 f.write(txt)
+            # the step's instruction table as the trainer registers it
+            # (training.py::_ReadStep): instructions by role, mesh edge
+            # and opcode, and every collective's calls and bytes a step
+            # under the axes its replica groups run over
+            from megatron_llm_tpu import hlo_collectives
+            table = hlo_collectives.ProgramTable(
+                name, hlo_collectives.instructions(txt),
+                mesh_shape=dict(mesh.shape))
+            print(table.table(), file=sys.stderr, flush=True)
+            by_edge = table.collectives_by_edge()
         if txt and len(txt) < 400 << 20:
             for op in ("all-reduce", "all-gather", "reduce-scatter",
                        "collective-permute", "all-to-all"):
@@ -335,6 +348,7 @@ def run_config(name: str, hlo_dir: str = "") -> dict:
         "fits": total <= hbm,
         "headroom_gb": round((hbm - total) / GB, 2),
         "collectives": colls,
+        **({"collectives_by_edge": by_edge} if by_edge is not None else {}),
     }
     print(json.dumps(rec), flush=True)
     return rec
