@@ -24,9 +24,9 @@ bit down (32 counts over the row: a float's bits, with the sign folded,
 order as the floats do), which gives the mask of what lies above it;
 among scores EQUAL to it the earliest positions fill what is left, found
 the same way over positions.  The Pallas kernel runs the same function
-over its own layout.  ``jax.lax.top_k`` has the same tie rule (the lower
-index first) and is what the plain reference uses
-(``benchmarks/reference/keye.py``).
+over its own layout, and over a row's live blocks only.
+``jax.lax.top_k`` has the same tie rule (the lower index first) and is
+what the plain reference uses (``benchmarks/reference/keye.py``).
 """
 
 from __future__ import annotations
@@ -65,8 +65,11 @@ def choose(key, valid, pos, topk: int, pos_bits: int, count):
     ``pos``; every valid entry of a row that has no more than ``topk``.
     ``count(cond)`` counts a row's true entries (keeping the row's
     shape to broadcast against): the one thing that depends on how rows
-    are laid out, so the Pallas kernel hands in its own.  ``pos`` < 2 **
-    ``pos_bits``."""
+    are laid out, so the Pallas kernel hands in its own.  ``key``,
+    ``valid`` and ``pos`` are only compared and joined with ``& |`` here
+    and looked into by ``count`` alone, so the kernel hands in rows it
+    holds a few blocks at a time, and gets such a row back.  ``pos`` < 2
+    ** ``pos_bits``."""
     k = jnp.minimum(count(valid), topk)
     zero = jnp.zeros(k.shape, jnp.int32)
 

@@ -14,14 +14,24 @@ is the one caller.  Three kernels, five names on a profile's
   of the row's queries against the block, written out as
   ``[row, block, query, TB]`` fp32.  Blocks past the row's last live
   page are never written; nobody reads them unmasked.
-* ``dsa_select_decode`` / ``dsa_select_prefill``: for 8 queries at a
-  time, the ``topk`` largest scores over the positions the query may see
-  (``s <= t``), exactly and with no sort: the ``topk``-th largest value
-  found by building its bit pattern from the top bit down (32 counts
-  over the row, held in VMEM), equal scores taken from the earliest
-  position (17 more counts, over positions).  Out comes an additive
-  mask, 0 for a chosen key and ``NEG_INF`` for any other, in the
-  scores' layout.  Never ``approx_max_k``.
+* ``dsa_select_decode`` / ``dsa_select_prefill``: for 32 queries of a
+  chunk at a time (the decode step's batch: 8 rows), the ``topk``
+  largest scores over the positions the query may see (``s <= t``),
+  exactly and with no sort: the ``topk``-th largest value found by
+  building its bit pattern from the top bit down (32 counts over the
+  row), equal scores taken from the earliest position (17 more counts,
+  over positions).  A step holds its queries' whole rows of the scores
+  and of the mask in VMEM (the slot's table: Pallas moves them in and
+  out), but COUNTS over blocks ``0 .. n_live - 1`` only, a prefetched
+  scalar a step: for a chunk the blocks through its last live key, for
+  the decode step through its longest row, 0 for an idle slot.  Every
+  count is a loop over those blocks (``_Blocks``: ``dsa.choose`` runs
+  over an array it never holds at once), after one pass that leaves the
+  scores' ordered bits and each query's open positions in scratch.  Out
+  comes an additive mask, 0 for a chosen key and ``NEG_INF`` for any
+  other, in the scores' layout, on the counted blocks; past them the
+  mask holds whatever the buffer held, and the walks below never fetch
+  it.  Never ``approx_max_k``.
 * ``paged_attention_sparse_decode`` / ``paged_attention_prefill_masked``:
   ``paged_attention.py``'s walk over the row's live pages of K and V
   with the mask applied to the scores of every block before the online
@@ -47,6 +57,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +69,9 @@ from megatron_llm_tpu.ops.pallas import paged_attention as _pa
 from megatron_llm_tpu.ops.pallas.paged_attention import (
     NEG_INF, _pages_per_block, _softmax_block, _softmax_finish)
 
-_SELECT_ROWS = 8             # queries a select step holds in VMEM
+_TILE = 8                    # rows of a vector register
+_SELECT_ROWS = 32            # queries a chunk's select step holds in VMEM
+_SELECT_LOOP_BYTES = 64 * 1024   # scores one step of the choice's loops takes
 _PREFILL_BLOCK_Q = 128       # query rows of a masked-prefill step
 _VMEM_LIMIT = 64 * 1024 * 1024
 
@@ -186,45 +199,159 @@ def _index_scores(iq, iw, index_pages, block_tables, context_lens,
 # the choice: the topk largest of a row, exactly, with no sort
 # ---------------------------------------------------------------------------
 
-def _select_body(pos_ref, s_ref, o_ref, *, topk, pos_bits):
-    """8 queries: ``s_ref`` [1, nblk, 8, TB] scores, ``pos_ref`` [1, 8, 1]
-    each query's own position (-1: a dead row, which chooses nothing).
+class _Blocks:
+    """A ``[nblk, rows, TB]`` array held a few blocks at a time:
+    ``at(j, u)`` gives blocks ``j .. j + u - 1`` as ``[u, rows, TB]``.  A
+    comparison or ``& |`` with another such, or with a per-query
+    ``[rows, 1]`` (spread over the lanes once, before any loop), gives
+    another, so ``dsa.choose`` runs over it as over an array, and only
+    the blocks its ``count`` walks are ever computed."""
+
+    def __init__(self, at, lanes):
+        self.at, self.lanes = at, lanes
+
+    def _blockwise(op):
+        def apply(self, other):
+            if isinstance(other, _Blocks):
+                return _Blocks(lambda j, u: op(self.at(j, u), other.at(j, u)),
+                               self.lanes)
+            other = jnp.broadcast_to(other, other.shape[:-1] + (self.lanes,))
+            return _Blocks(lambda j, u: op(self.at(j, u), other), self.lanes)
+        return apply
+
+    __ge__, __gt__, __eq__, __lt__, __and__, __or__ = map(
+        _blockwise, (operator.ge, operator.gt, operator.eq, operator.lt,
+                     operator.and_, operator.or_))
+
+
+def _select_body(nl_ref, pos_ref, s_ref, o_ref, bits_ref, open_ref, *, topk,
+                 pos_bits, group):
+    """``rows`` queries: ``s_ref`` [1, nblk, rows, TB] scores, ``pos_ref``
+    [1, rows, 1] each query's own position (-1: a dead row, which chooses
+    nothing), ``nl_ref`` [G, steps] the blocks this step counts over.
     Key (block j, lane c) lies at position j * TB + c and is open to the
-    query at or after it."""
-    x = s_ref[0]                                          # [nblk, 8, TB]
-    TB = x.shape[-1]
-    kpos = _iota(x.shape, 0) * TB + _iota(x.shape, 2)
-    valid = kpos <= pos_ref[0][None]                      # [nblk, 8, TB]
+    query at or after it.  Blocks ``n ..`` of ``s_ref`` are never read and
+    of ``o_ref`` never written."""
+    _, nblk, rows, TB = s_ref.shape
+    n = jnp.minimum(nl_ref[pl.program_id(0), pl.program_id(1)], nblk)
+
+    def walk(body, carry):
+        """``body(j, u, carry)`` over blocks 0 .. n - 1, ``group`` at a
+        time (a loop step of a few vector registers is all branch) and
+        what is left one by one."""
+        whole = n // group
+        carry = jax.lax.fori_loop(
+            0, whole, lambda i, c: body(i * group, group, c), carry)
+        if group > 1:
+            carry = jax.lax.fori_loop(
+                whole * group, n, lambda j, c: body(j, 1, c), carry)
+        return carry
+
+    within = {u: _iota((u, rows, TB), 0) * TB + _iota((u, rows, TB), 2)
+              for u in {group, 1}}
+    kpos = _Blocks(lambda j, u: j * TB + within[u], TB)
+    pos_q = jnp.broadcast_to(pos_ref[0], (rows, TB))
+
+    def stage(j, u, carry):
+        # once a step: the scores' ordered bits and what each query may
+        # see, so that a count loads, compares and adds and does no more
+        at = pl.ds(j, u)
+        bits_ref[at] = _dsa.ordered_bits(s_ref[0, at])
+        open_ref[at] = (kpos.at(j, u) <= pos_q).astype(jnp.int32)
+        return carry
+
+    walk(stage, 0)
+    key = _Blocks(lambda j, u: bits_ref[pl.ds(j, u)], TB)
+    valid = _Blocks(lambda j, u: open_ref[pl.ds(j, u)] != 0, TB)
 
     def count(cond):
-        # over a query's row: blocks (axis 0) and lanes; fp32 counts are
-        # exact far beyond a row's entries
-        c = jnp.sum(jnp.where(cond, 1.0, 0.0), axis=0)    # [8, TB]
-        return jnp.sum(c, axis=-1, keepdims=True)[None]   # [1, 8, 1]
+        # over a query's row: the live blocks and their lanes; fp32 counts
+        # are exact far beyond a row's entries
+        acc = walk(lambda j, u, c: c + jnp.sum(
+            jnp.where(cond.at(j, u), 1.0, 0.0), axis=0),
+            jnp.zeros((rows, TB), jnp.float32))
+        return jnp.sum(acc, axis=-1, keepdims=True)       # [rows, 1]
 
-    chosen = _dsa.choose(_dsa.ordered_bits(x), valid, kpos, topk, pos_bits,
-                         count)
-    o_ref[0] = jnp.where(chosen, 0.0, NEG_INF)
+    chosen = _dsa.choose(key, valid, kpos, topk, pos_bits, count)
+
+    def write(j, u, carry):
+        o_ref[0, pl.ds(j, u)] = jnp.where(chosen.at(j, u), 0.0, NEG_INF)
+        return carry
+
+    walk(write, 0)
 
 
-def _select(scores, row_pos, *, topk, name):
-    """scores [G, nblk, N, TB], row_pos [G, N] -> additive mask
-    [G, nblk, N, TB] fp32 (0 chosen, NEG_INF not)."""
+def block_keys(block_size, groups, head_dim, dtype, table_pages):
+    """Keys of one compute block of a pool of such pages: what a step of
+    each walk takes, and the unit the choice counts in."""
+    return block_size * _pages_per_block(block_size, groups, head_dim, dtype,
+                                         table_pages)
+
+
+def select_rows(n):
+    """Queries a select step holds: a chunk's ``_SELECT_ROWS``, the
+    decode step's batch in tiles of 8."""
+    return _TILE if n == 1 else _SELECT_ROWS
+
+
+def select_blocks(context_lens, valid_lens, n, block_keys, xp=jnp):
+    """[G, steps] int: the blocks of ``block_keys`` keys each select step
+    of a call counts over (``n`` queries a slot, ``select_rows(n)`` a
+    step).  A chunk (``n`` > 1): for every step of slot s the blocks that
+    hold the slot's keys through the chunk's last live one, 0 for an
+    idle slot.  The decode step: its slots are the rows, and a step
+    counts through the longest of its rows.  ``xp``: ``numpy`` for the
+    host's own count of the same."""
+    rows = select_rows(n)
+    blocks = (valid_lens > 0) * (-(-(context_lens + valid_lens)
+                                   // block_keys))           # [S]
+    if n == 1:
+        return xp.pad(blocks, (0, -len(blocks) % rows)).reshape(
+            1, -1, rows).max(axis=-1)
+    return xp.broadcast_to(blocks[:, None], (len(blocks), -(-n // rows)))
+
+
+def _select(scores, row_pos, n_live, *, topk, name):
+    """scores [G, nblk, N, TB], row_pos [G, N], n_live [G, steps] (N /
+    steps queries a step) -> additive mask [G, nblk, N, TB] fp32 (0
+    chosen, NEG_INF not) on each step's blocks ``0 .. n_live - 1``; what
+    lies past them is whatever the buffer held."""
+    _, nblk, N, TB = scores.shape
+    rows = N // n_live.shape[1]
+    assert N == rows * n_live.shape[1] and rows % _TILE == 0, (N, n_live.shape)
+    return _select_call(
+        n_live.astype(jnp.int32), row_pos.astype(jnp.int32)[..., None],
+        scores, topk=topk, name=name, interpret=_pa._INTERPRET,
+        group=max(1, min(nblk, _SELECT_LOOP_BYTES // (rows * TB * 4))))
+
+
+# a jit of its own inside the engine's programs: the layers of a program
+# call it with one set of shapes, so the kernel is traced and lowered once
+# a program and not once a layer (its loops make it the family's longest
+# to trace: 0.1 s a layer a program, which is set-up)
+@functools.partial(jax.jit,
+                   static_argnames=("topk", "name", "interpret", "group"))
+def _select_call(n_live, row_pos, scores, *, topk, name, interpret, group):
     G, nblk, N, TB = scores.shape
-    rows = _SELECT_ROWS
-    assert N % rows == 0, (N, rows)
+    rows = N // n_live.shape[1]
     pos_bits = max(1, math.ceil(math.log2(nblk * TB + 1)))
-    spec = pl.BlockSpec((1, nblk, rows, TB), lambda g, i: (g, 0, i, 0),
+    spec = pl.BlockSpec((1, nblk, rows, TB), lambda g, i, *_: (g, 0, i, 0),
                         memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        functools.partial(_select_body, topk=topk, pos_bits=pos_bits),
-        name=name, grid=(G, N // rows),
-        in_specs=[pl.BlockSpec((1, rows, 1), lambda g, i: (g, i, 0),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(G, N // rows),
+        in_specs=[pl.BlockSpec((1, rows, 1), lambda g, i, *_: (g, i, 0),
                                memory_space=pltpu.VMEM), spec],
         out_specs=spec,
+        scratch_shapes=[pltpu.VMEM((nblk, rows, TB), jnp.int32)] * 2,
+    )
+    return pl.pallas_call(
+        functools.partial(_select_body, topk=topk, pos_bits=pos_bits,
+                          group=group),
+        name=name, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(scores.shape, jnp.float32),
-        compiler_params=_params(), interpret=_pa._INTERPRET,
-    )(row_pos.astype(jnp.int32)[..., None], scores)
+        compiler_params=_params(), interpret=interpret,
+    )(n_live, row_pos, scores)
 
 
 # ---------------------------------------------------------------------------
@@ -250,11 +377,13 @@ def _masked_walk_body(bt_ref, cl_ref, vl_ref, q_ref, mask_ref, k_hbm, v_hbm,
     else:
         _, _, qpg, bq, _ = q_ref.shape
     R = bq * qpg
-    ctx = cl_ref[s]
+    ctx, n = cl_ref[s], vl_ref[s]
     q0 = pl.program_id(1) * bq
-    top = jnp.minimum(ctx + q0 + bq, bt_ref.shape[1] * bs) - 1
+    # newest key a LIVE row of the q-block attends: the mask beyond its
+    # block was never written (``_select`` stops at the slot's live blocks)
+    top = jnp.minimum(ctx + jnp.minimum(q0 + bq, n), bt_ref.shape[1] * bs) - 1
     last = top // bs
-    nblk = jnp.where(vl_ref[s] > 0, last // kp + 1, 0)
+    nblk = jnp.where(n > q0, last // kp + 1, 0)
 
     def block_dma(j, slot, start):
         p0 = j * kp
@@ -419,10 +548,11 @@ def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
     M = block_tables.shape[1]
     kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
     tables = (block_tables, context_lens, valid_lens)
+    n_live = select_blocks(context_lens, valid_lens, n, kp * bs)
     live = jnp.arange(n)[None, :] < valid_lens[:, None]           # [S, n]
     row_pos = jnp.where(live, context_lens[:, None] + jnp.arange(n)[None, :],
                         -1)
-    rows = _SELECT_ROWS
+    rows = select_rows(n)
     if n == 1:
         # the rows' one query each, padded to a tile of 8 for the scores
         # and gathered into one batch of rows for the choice
@@ -435,8 +565,10 @@ def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
         scores = jnp.pad(jnp.transpose(scores, (1, 0, 2)),
                          ((0, 0), (0, Sp), (0, 0)))[None]         # [1, nb, S+, TB]
         mask = _select(scores, jnp.pad(row_pos[:, 0], (0, Sp),
-                                       constant_values=-1)[None],
+                                       constant_values=-1)[None], n_live,
                        topk=topk, name="dsa_select_decode")[0, :, :S]
+        # blocks past a step's ``n_live`` ride along unwritten; the walk
+        # stops at each row's own last live page
         mask = jnp.repeat(jnp.transpose(mask, (1, 0, 2)), g, axis=-1)
         return _masked_walk(q, mask, k_pages, v_pages, *tables, kp=kp,
                             scale=softmax_scale,
@@ -448,7 +580,8 @@ def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
         row_pos = jnp.pad(row_pos, ((0, 0), (0, pad)), constant_values=-1)
     scores = _index_scores(iq, iw, index_pages, *tables, kp=kp,
                            name="dsa_index_scores_prefill")
-    mask = _select(scores, row_pos, topk=topk, name="dsa_select_prefill")
+    mask = _select(scores, row_pos, n_live, topk=topk,
+                   name="dsa_select_prefill")
     out = _masked_walk(q, mask, k_pages, v_pages, *tables, kp=kp,
                        scale=softmax_scale,
                        name="paged_attention_prefill_masked")

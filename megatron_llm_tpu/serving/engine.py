@@ -83,7 +83,7 @@ from jax.profiler import TraceAnnotation
 from megatron_llm_tpu import hlo_collectives, telemetry, tracing
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.ops import paged_kv
-from megatron_llm_tpu.ops.pallas import grouped_matmul
+from megatron_llm_tpu.ops.pallas import dsa_attention, grouped_matmul
 from megatron_llm_tpu.serving.cache_observatory import CacheObservatory
 from megatron_llm_tpu.serving.drafter import draft_budget, lookup_draft
 from megatron_llm_tpu.serving.kv_blocks import (
@@ -104,6 +104,7 @@ from megatron_llm_tpu.serving.request import (
     SamplingParams,
 )
 from megatron_llm_tpu.serving.loop_profiler import (
+    DSA_FIELDS,
     KV_FIELDS,
     MOE_FIELDS,
     DispatchRecord,
@@ -310,6 +311,16 @@ class InferenceEngine:
         # (nor the int8 pool: ops/paged_kv.py::init_pools refuses it)
         self._dsa_topk = (int(mcfg.dsa_topk) if mcfg.dsa_index_heads > 0
                           else 0)
+        # for the launch records: the keys of a block the choice counts
+        # in, and the blocks of a slot's table
+        self._dsa_block_keys = self._dsa_table_blocks = 0
+        if self._dsa_topk:
+            self._dsa_block_keys = dsa_attention.block_keys(
+                cfg.block_size, mcfg.num_query_groups, mcfg.head_dim,
+                mcfg.compute_jnp_dtype, self._max_blocks_per_slot)
+            self._dsa_table_blocks = -(
+                -self._max_blocks_per_slot * cfg.block_size
+                // self._dsa_block_keys)
         if self._dsa_topk and self.speculative:
             raise ValueError(
                 "sparse attention (dsa_index_heads > 0) is not implemented "
@@ -411,9 +422,9 @@ class InferenceEngine:
         for f in MOE_FIELDS:
             setattr(self, f, 0)
         # learned sparse attention, summed over launches (the record's
-        # two fields of the same names)
-        self.dsa_keys_live = 0
-        self.dsa_keys_selected = 0
+        # fields of the same names)
+        for f in DSA_FIELDS:
+            setattr(self, f, 0)
         # the two groups of a model with a layer type per layer, summed
         # over launches (the record's fields of the same names), and a
         # block's bytes over the layers of each group (full, window)
@@ -1093,7 +1104,8 @@ class InferenceEngine:
         d.cached_tokens = req.cached_prompt_tokens
         d.requests = (req.id,)
         d.traces = (req.trace_id,) if req.trace_id else ()
-        self._note_selection(d, start + 1 + np.arange(valid))
+        self._note_selection(d, start + 1 + np.arange(valid),
+                             np.asarray([start]), np.asarray([valid]), C)
         d.mark("build_inputs")
         finite = True
         last_logits, st.pages, routing = self._prefill_step(
@@ -1169,15 +1181,21 @@ class InferenceEngine:
         for f in MOE_FIELDS:
             setattr(self, f, getattr(self, f) + getattr(d, f))
 
-    def _note_selection(self, d: DispatchRecord, sees) -> None:
+    def _note_selection(self, d: DispatchRecord, sees, context_lens,
+                        valid_lens, n: int) -> None:
         """A launch of a model with a sparse-attention indexer: the keys
-        its live queries see and attend, on the record and in the
-        running totals."""
+        its live queries see and attend and the blocks its choice counts
+        over (from the arrays the program is handed: ``n`` queries a
+        slot), on the record and in the running totals."""
         if not self._dsa_topk:
             return
-        d.note_selection(sees, self._dsa_topk, self.model.cfg.num_layers)
-        self.dsa_keys_live += d.dsa_keys_live
-        self.dsa_keys_selected += d.dsa_keys_selected
+        d.note_selection(
+            sees, self._dsa_topk, self.model.cfg.num_layers,
+            dsa_attention.select_blocks(context_lens, valid_lens, n,
+                                        self._dsa_block_keys, xp=np),
+            self._dsa_table_blocks)
+        for f in DSA_FIELDS:
+            setattr(self, f, getattr(self, f) + getattr(d, f))
 
     def _note_batch(self, st: _EngineState, d: DispatchRecord,
                     slots: List[int], decoding: List[Request]) -> None:
@@ -1194,7 +1212,8 @@ class InferenceEngine:
             self.model.cfg.padded_vocab_size)
         d.sampler_rows_drawn = int(drawn.sum())
         d.sampler_rows_filtered = int(filtered.sum())
-        self._note_selection(d, st.context_lens[slots] + 1)
+        self._note_selection(d, st.context_lens[slots] + 1,
+                             st.context_lens, st.active, 1)
         self.sample_draw_steps += d.sampler_rows_drawn > 0
         self.sample_sort_steps += d.sampler_rows_filtered > 0
 
@@ -1659,8 +1678,7 @@ class InferenceEngine:
             **{f: getattr(self, f) for f in MOE_FIELDS},
             **({"moe_expert_tiles": self.moe_expert_tiles}
                if self.moe_expert_tiles else {}),
-            "dsa_keys_live": self.dsa_keys_live,
-            "dsa_keys_selected": self.dsa_keys_selected,
+            **{f: getattr(self, f) for f in DSA_FIELDS},
             **{f: getattr(self, f) for f in KV_FIELDS},
             "engine_restarts": self.engine_restarts,
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,

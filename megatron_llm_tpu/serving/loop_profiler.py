@@ -106,6 +106,12 @@ LOOP_PHASE_BUCKETS = (
 MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
               "moe_busiest_expert_assignments")
 
+# a launch's account of learned sparse attention (DispatchRecord's fields;
+# all 0 for a model with no indexer), which the engine also keeps running
+# totals of
+DSA_FIELDS = ("dsa_keys_live", "dsa_keys_selected",
+              "dsa_select_blocks_counted", "dsa_select_blocks_table")
+
 # a launch's account of the two page groups of a model with a layer type
 # per layer (DispatchRecord's fields; all 0 for a model of one type),
 # which the engine also keeps running totals of
@@ -182,6 +188,12 @@ class DispatchRecord:
     # cut at the top-k, which is what it attends
     dsa_keys_live = 0
     dsa_keys_selected = 0
+    # the choice's work: blocks of keys its select steps count over (each
+    # step stops at its slot's last live block), summed over the launch's
+    # steps and the layers, and the same had every step counted its
+    # slot's whole table
+    dsa_select_blocks_counted = 0
+    dsa_select_blocks_table = 0
     # a model with a layer type per layer (0 otherwise): window-group
     # pages given back to the allocator before this launch and pages it
     # took (each logical page of a context once: what one table a slot
@@ -281,17 +293,21 @@ class DispatchRecord:
             **{f: getattr(self, f) for f in MOE_FIELDS},
             "sampler_rows_drawn": self.sampler_rows_drawn,
             "sampler_rows_filtered": self.sampler_rows_filtered,
-            "dsa_keys_live": self.dsa_keys_live,
-            "dsa_keys_selected": self.dsa_keys_selected,
+            **{f: getattr(self, f) for f in DSA_FIELDS},
             **{f: getattr(self, f) for f in KV_FIELDS},
         }
 
-    def note_selection(self, sees, topk: int, layers: int) -> None:
+    def note_selection(self, sees, topk: int, layers: int, steps,
+                       table_blocks: int) -> None:
         """``sees``: for each live query of the launch the keys it sees
-        (positions 0..its own), an int array the host made from what it
-        hands the program."""
+        (positions 0..its own); ``steps``: for each select step of one
+        layer the blocks it counts over, of the ``table_blocks`` a slot's
+        table holds: int arrays the host made from what it hands the
+        program."""
         self.dsa_keys_live = layers * int(sees.sum())
         self.dsa_keys_selected = layers * int(sees.clip(max=topk).sum())
+        self.dsa_select_blocks_counted = layers * int(steps.sum())
+        self.dsa_select_blocks_table = layers * steps.size * table_blocks
 
     def note_routing(self, counts) -> None:
         """``counts`` [layers, E]: the launch's histogram of live

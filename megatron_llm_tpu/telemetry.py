@@ -610,7 +610,16 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    (one group's rows in one 128-row tile) costs, and the VMEM the call
 #    holds by the kernel's own count (ops/pallas/grouped_matmul.py
 #    ``tiles``); static per model, absent for a dense one
-TELEMETRY_SCHEMA_VERSION = 18
+# 19: the sparse-attention choice's work: engine stats() / the engine
+#    block of /metrics gain dsa_select_blocks_counted and
+#    dsa_select_blocks_table beside dsa_keys_live / dsa_keys_selected
+#    (the blocks of keys the choice's select steps count over, each step
+#    stopping at its slot's last live block, and the same had every step
+#    counted its slot's whole table; summed over steps, layers and
+#    launches, both 0 for a model with no indexer), and every launch
+#    record carries the same two for that launch — see
+#    ops/pallas/dsa_attention.py ``select_blocks``
+TELEMETRY_SCHEMA_VERSION = 19
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -337,6 +337,121 @@ def test_engine_counts_the_keys_each_query_sees_and_selects(family):
                                              for r in records)
 
 
+def test_engine_counts_the_blocks_the_choice_counts_over(family, monkeypatch):
+    """``dsa_select_blocks_counted`` / ``dsa_select_blocks_table`` on every
+    launch record: for each select step of the launch the blocks of keys
+    it is given (``ops/pallas/dsa_attention.py::select_blocks``: a chunk's
+    steps count through the chunk's last live block, the decode step's
+    through its longest row), and the same with every step at the table's
+    blocks; summed over steps and layers, and over launches in
+    ``stats()``.  Blocks of 16 keys here, so a table of 64 tokens is 4."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention
+    from megatron_llm_tpu.ops.pallas import paged_attention as pa
+    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
+                                          SamplingParams)
+
+    monkeypatch.setattr(pa, "_BLOCK_TOKENS", 16)
+    model, params = family[:2]
+    L = model.cfg.num_layers
+    eng = InferenceEngine(model, params, EngineConfig(
+        num_slots=4, block_size=8, max_model_len=64, prefill_chunk=16,
+        prefix_cache=False))
+    assert eng._dsa_block_keys == 16
+    req = eng.submit(_tokens(40, seed=9),
+                     SamplingParams(max_new_tokens=3, temperature=0.0))
+    while req.finish_reason is None:
+        assert eng.step()
+    records = eng.loop_profiler.records()
+    assert [r.kind for r in records] == ["prefill"] * 3 + ["decode"] * 2
+    # chunks (0, 16), (16, 16), (32, 8): one select step each (16 queries
+    # pad to one step of 32) through keys 16, 32, 40; then the decode
+    # step's one step of 8 rows, its one live row at 41 and 42 keys
+    for r, newest in zip(records, [16, 32, 40, 41, 42]):
+        assert r.dsa_select_blocks_counted == L * -(-newest // 16)
+        assert r.dsa_select_blocks_table == L * 4
+        assert r.as_dict()["dsa_select_blocks_counted"] == \
+            r.dsa_select_blocks_counted
+    stats = eng.stats()
+    assert stats["dsa_select_blocks_counted"] == L * (1 + 2 + 3 + 3 + 3)
+    assert stats["dsa_select_blocks_table"] == L * 4 * 5
+    # the host's count is the kernel's own prefetched scalar
+    np.testing.assert_array_equal(
+        dsa_attention.select_blocks(np.asarray([40, 0, 7, 0]),
+                                    np.asarray([1, 0, 1, 0]), 1, 16, xp=np),
+        np.asarray(dsa_attention.select_blocks(
+            jnp.asarray([40, 0, 7, 0]), jnp.asarray([1, 0, 1, 0]), 1, 16)))
+    assert dsa_attention.select_blocks(
+        np.asarray([32]), np.asarray([8]), 70, 16, xp=np).tolist() == [[3] * 3]
+
+
+def test_a_model_that_selects_nothing_counts_no_blocks():
+    from megatron_llm_tpu.models.llama import LlamaModel, llama_config
+    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
+                                          SamplingParams)
+
+    model = LlamaModel(llama_config("tiny", use_flash_attn=False))
+    eng = InferenceEngine(model, model.init(jax.random.PRNGKey(0)),
+                          EngineConfig(num_slots=2, block_size=8,
+                                       max_model_len=32, prefill_chunk=16))
+    req = eng.submit(_tokens(9, seed=2, vocab=model.cfg.padded_vocab_size),
+                     SamplingParams(max_new_tokens=2, temperature=0.0))
+    while req.finish_reason is None:
+        assert eng.step()
+    for r in eng.loop_profiler.records():
+        assert (r.dsa_select_blocks_counted, r.dsa_select_blocks_table,
+                r.dsa_keys_live) == (0, 0, 0)
+    stats = eng.stats()
+    assert stats["dsa_select_blocks_counted"] == 0
+    assert stats["dsa_select_blocks_table"] == 0
+
+
+def test_the_benchmarks_counted_share_reads_the_records_two_fields(
+        monkeypatch):
+    """``benchmarks/layer_metrics/dsa_select_counted_pct.json`` (data: the
+    benchmark's own ``loop_record_ratio`` reads it) names two fields that
+    ``DispatchRecord`` has and ``as_dict()`` gives; a record without them
+    (the parent's) reads as nothing, so the parent's line lacks the
+    metric and the change's has it."""
+    import importlib
+    import json
+    import types
+
+    from megatron_llm_tpu.serving.loop_profiler import DispatchRecord
+
+    bench = os.path.dirname(REFERENCE)
+    with open(os.path.join(bench, "layer_metrics",
+                           "dsa_select_counted_pct.json")) as f:
+        metric = json.load(f)
+    assert metric["source"] == "loop_record_ratio"
+    params = metric["params"]
+    assert (params["numerator"], params["denominator"]) == (
+        "dsa_select_blocks_counted", "dsa_select_blocks_table")
+    assert params["kinds"] == ["prefill"] and params["scale"] == 100.0
+    root = json.load(open(os.path.join(os.path.dirname(bench),
+                                       "BENCHMARK.json")))
+    assert root["per_layer"][-1] == {
+        "name": "dsa_select_counted_pct", "unit": metric["unit"],
+        "better": "lower", "source": "program_counter",
+        "layer": metric["layer"], "moves": metric["moves"],
+        "workloads": metric["cells"]}
+
+    rec = DispatchRecord(lambda: 0.0, 0, 0.0, 0.0)
+    rec.note_selection(np.asarray([5]), TOPK, 2, np.asarray([[2, 2]]), 6)
+    for field in (params["numerator"], params["denominator"]):
+        assert field in rec.as_dict()
+    assert (rec.dsa_select_blocks_counted, rec.dsa_select_blocks_table) == (
+        8, 24)
+
+    monkeypatch.syspath_prepend(bench)
+    ratio = importlib.import_module("harness.spec").load_module(
+        "sources", "loop_record_ratio")
+    fields = (params["numerator"], params["denominator"])
+    assert ratio.sums([rec, rec], *fields) == (16, 48)
+    parents = types.SimpleNamespace(dsa_keys_live=7, dsa_keys_selected=5)
+    assert ratio.sums([parents], *fields) is None
+    assert ratio.sums([rec, parents], *fields) is None
+
+
 def test_what_the_selection_does_not_support_is_refused_by_name(family):
     from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
 

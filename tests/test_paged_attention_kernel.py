@@ -626,37 +626,124 @@ def _selected_both(case, topk, d):
     return np.asarray(got), np.asarray(want)
 
 
+@pytest.fixture
+def choices(monkeypatch):
+    """Every call of the choice, as it was made: (scores, row_pos, n_live,
+    mask)."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention
+
+    real, calls = dsa_attention._select, []
+
+    def recorded(scores, row_pos, n_live, **kw):
+        mask = real(scores, row_pos, n_live, **kw)
+        calls.append(tuple(np.asarray(a) for a in (scores, row_pos, n_live,
+                                                   mask)))
+        return mask
+
+    monkeypatch.setattr(dsa_attention, "_select", recorded)
+    return calls
+
+
+def _assert_the_choice_is_select_masks(calls, topk):
+    """On each step's live blocks the kernel's mask is ``dsa.select_mask``
+    of the same scores, bit for bit (0.0 chosen, NEG_INF not), and no
+    query may see a key past them."""
+    from megatron_llm_tpu.ops import dsa
+
+    assert calls
+    for scores, row_pos, n_live, mask in calls:
+        G, nblk, N, TB = scores.shape
+        rows = N // n_live.shape[1]
+        flat = scores.transpose(0, 2, 1, 3).reshape(G, N, nblk * TB)
+        valid = np.arange(nblk * TB)[None, None] <= row_pos[..., None]
+        want = np.asarray(dsa.select_mask(jnp.asarray(flat),
+                                          jnp.asarray(valid), topk))
+        got = mask.transpose(0, 2, 1, 3).reshape(G, N, nblk * TB)
+        for g in range(G):
+            for i, n in enumerate(n_live[g]):
+                step = slice(i * rows, (i + 1) * rows)
+                np.testing.assert_array_equal(
+                    got[g, step, :n * TB],
+                    np.where(want[g, step, :n * TB], np.float32(0.0),
+                             np.float32(pa.NEG_INF)))
+                assert not want[g, step, n * TB:].any()
+
+
+@pytest.fixture(params=[None, 3], ids=["loop_default", "loop_3_blocks"])
+def loop_blocks(request, monkeypatch):
+    """The choice's loop over live blocks takes as many a step as fit
+    ``_SELECT_LOOP_BYTES`` and what is left one by one; with 3 a step a
+    table of 12 blocks runs both loops."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention
+
+    if request.param:
+        monkeypatch.setattr(dsa_attention, "_SELECT_LOOP_BYTES",
+                            request.param * 8 * 16 * 4)
+
+
+DECODE_BATCHES = {
+    # contexts under the top-k, at it, past it, across several compute
+    # blocks, and one row that is not decoding
+    "five_rows": lambda topk: ([3, topk - 1, topk, 150, 60],
+                               [1, 1, 1, 1, 0]),
+    # 8 rows from one key to the whole table of 12 blocks of 16
+    "one_key_to_the_table": lambda topk: (
+        [0, 15, 16, 31, 47, 100, 175, 191], [1] * 8),
+    # one live block of many, an idle slot beside it
+    "one_live_block": lambda topk: ([100, 9], [0, 1]),
+    # a context ending on a block's edge and one key past it (the new key
+    # is the block's last, then the next block's first)
+    "a_blocks_edge": lambda topk: ([62, 63, 64, 0], [1, 1, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("batch", sorted(DECODE_BATCHES))
 @pytest.mark.parametrize("ties", [False, True])
 @pytest.mark.parametrize("topk", [8, 40])
-def test_selected_decode_kernels_match_the_dense_path(topk, ties,
-                                                      two_page_blocks):
-    """The decode step: five rows at contexts under the top-k, at it,
-    past it, across several compute blocks, and one row that is not
-    decoding; with equal scores everywhere (``ties``) the earlier
-    position wins on both paths."""
+def test_selected_decode_kernels_match_the_dense_path(topk, ties, batch,
+                                                      two_page_blocks,
+                                                      choices, loop_blocks):
+    """The decode step over a table of 12 blocks of 16 keys; with equal
+    scores everywhere (``ties``) the earlier position wins on both paths,
+    and the choice is ``select_mask``'s on every block it counted."""
     rng = np.random.default_rng(3)
-    ctx, valid = [3, topk - 1, topk, 150, 60], [1, 1, 1, 1, 0]
-    case = _selected_case(rng, 5, 1, 12, 16, 2, 4, 32, 4, ctx, valid, ties)
+    ctx, valid = DECODE_BATCHES[batch](topk)
+    case = _selected_case(rng, len(ctx), 1, 12, 16, 2, 4, 32, 4, ctx, valid,
+                          ties)
     got, want = _selected_both(case, topk, 32)
     live = np.asarray(valid) > 0
     np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=1e-5)
-    assert np.abs(got[~live]).max() == 0.0
+    assert np.abs(got[~live]).max(initial=0.0) == 0.0
+    _assert_the_choice_is_select_masks(choices, topk)
+    (_, _, n_live, _), = choices
+    newest = [c + v for c, v in zip(ctx, valid) if v]
+    assert n_live.tolist() == [[-(-max(newest) // 16)]]
 
 
 @pytest.mark.parametrize("ties", [False, True])
-@pytest.mark.parametrize("ctx,valid", [(0, 24), (5, 24), (70, 11),
-                                       (150, 24)])
+@pytest.mark.parametrize("ctx,valid", [
+    (0, 24), (5, 24), (70, 11), (150, 24),
+    (0, 7),               # one live block of twelve, valid short of the chunk
+    (8, 24), (9, 24),     # ends on a block's edge, and one key past it
+    (40, 24),             # ends on an edge, straddling two blocks before it
+    (168, 24),            # through the table's last key
+])
 def test_selected_prefill_kernels_match_the_dense_path(ctx, valid, ties,
-                                                       two_page_blocks):
-    """A chunk of 24 rows: at context 0 (its rows straddle the top-k of
-    8), mid-page, short and padded, and far past the top-k across compute
-    blocks."""
+                                                       two_page_blocks,
+                                                       choices):
+    """A chunk of 24 rows over a table of 12 blocks of 16 keys: at context
+    0 (its rows straddle the top-k of 8), mid-page, short and padded, far
+    past the top-k across compute blocks; every select step of the chunk
+    counts through the chunk's last live block and no further."""
     rng = np.random.default_rng(4)
     case = _selected_case(rng, 1, 24, 12, 16, 2, 4, 32, 4, [ctx], [valid],
                           ties)
     got, want = _selected_both(case, 8, 32)
     np.testing.assert_allclose(got[0, :valid], want[0, :valid], atol=2e-5,
                                rtol=1e-5)
+    _assert_the_choice_is_select_masks(choices, 8)
+    (_, _, n_live, _), = choices
+    assert n_live.tolist() == [[-(-(ctx + valid) // 16)]]
 
 
 def test_selected_attention_is_not_dense_attention(two_page_blocks):
@@ -708,6 +795,44 @@ def test_selection_never_reads_scores_nobody_wrote(n, poison, monkeypatch,
     got, _ = _selected_both(case, 8, 32)
     for s, v in enumerate(valid):
         np.testing.assert_array_equal(got[s, :v], clean[s, :v])
+        np.testing.assert_allclose(got[s, :v], want[s, :v], atol=2e-5,
+                                   rtol=1e-5)
+
+
+@pytest.mark.parametrize("poison", [0.0, np.nan, 3e38])
+@pytest.mark.parametrize("n,ctx,valid", [
+    (1, [3, 150, 60, 40], [1, 1, 0, 1]),
+    (1, [20, 21], [0, 0]),
+    (24, [70], [11]),
+    (24, [9], [24]),
+])
+def test_attention_never_reads_a_mask_block_the_choice_did_not_write(
+        n, ctx, valid, poison, monkeypatch, two_page_blocks):
+    """The choice writes each step's blocks ``0 .. n_live - 1`` of the
+    mask and no other; past them the mask holds whatever the buffer held
+    (in interpret mode: zeros, which read as "chosen").  Filled with 0.0,
+    NaN or a huge number between the choice and the walk they change
+    nothing, rows past ``valid`` included: a chunk's walk stops at its
+    last LIVE query's block, the decode step's at each row's own."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention
+
+    real = dsa_attention._select
+
+    def poisoned(scores, row_pos, n_live, **kw):
+        mask = real(scores, row_pos, n_live, **kw)
+        G, nblk, N, _ = mask.shape
+        per_row = jnp.repeat(n_live, N // n_live.shape[1], axis=1)  # [G, N]
+        dead = jnp.arange(nblk)[None, :, None] >= per_row[:, None, :]
+        return jnp.where(dead[..., None], poison, mask)
+
+    rng = np.random.default_rng(7)
+    case = _selected_case(rng, len(ctx), n, 12, 16, 2, 4, 32, 4, ctx, valid,
+                          False)
+    clean, want = _selected_both(case, 8, 32)
+    monkeypatch.setattr(dsa_attention, "_select", poisoned)
+    got, _ = _selected_both(case, 8, 32)
+    np.testing.assert_array_equal(got, clean)
+    for s, v in enumerate(valid):
         np.testing.assert_allclose(got[s, :v], want[s, :v], atol=2e-5,
                                    rtol=1e-5)
 

@@ -21,6 +21,7 @@ later test that compiles this way.  The child sets no compilation cache
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -211,6 +212,7 @@ CASES = {
 
 
 SAMPLER = "sampler_64_slots_50304_logits"
+SELECTION = "dsa_kernels_by_name"
 ENGINE_TABLES = "engine_tables_tiny_sparse_model"
 
 
@@ -236,6 +238,10 @@ def _compile_all():
         try:
             text = jax.jit(fn).lower(*args).compile().as_text()
             found[case] = "tpu_custom_call" in text
+            if case.startswith("dsa_"):
+                found.setdefault(SELECTION, {})[case] = sorted(re.findall(
+                    r"^\s*(?:ROOT )?%?((?:dsa|paged_attention)_\w+?)"
+                    r"(?:\.\d+)? = ", text, re.M))
         except Exception as e:  # noqa: BLE001 - the compiler's refusal
             found[case] = f"{type(e).__name__}: {e}"[:2000]
     found[SAMPLER] = _sampler_sorts(chip)
@@ -325,6 +331,20 @@ def test_kernel_compiles_for_v5e(case, compiled):
     assert compiled[case] is True, (
         f"{case}: compiled without its Mosaic kernel" if not compiled[case]
         else f"{case}: the TPU compiler refused it: {compiled[case]}")
+
+
+def test_selection_is_one_kernel_a_name_for_v5e(compiled):
+    """The decode step and the chunk each hold ONE scores walk, ONE
+    choice and ONE walk under it, under the names a profile's ``XLA Ops``
+    line (and the benchmark's ``dsa_*`` metrics) knows: the choice that
+    counts over a row's live blocks is one kernel, not a ladder of sizes."""
+    assert compiled[SELECTION] == {
+        "dsa_selected_decode_8_slots": [
+            "dsa_index_scores_decode", "dsa_select_decode",
+            "paged_attention_sparse_decode"],
+        "dsa_selected_prefill_chunk_512": [
+            "dsa_index_scores_prefill", "dsa_select_prefill",
+            "paged_attention_prefill_masked"]}
 
 
 def test_sampler_compiles_to_one_guarded_sort_for_v5e(compiled):
