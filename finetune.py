@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -97,6 +97,18 @@ MODEL_DEFAULTS = {
                    rope_yarn_scaling=[16.0, 8192.0, 32.0, 1.0,
                                       1.2772588722239782],
                    rope_yarn_layer_types=["full"],
+                   hidden_dropout=0.0, attention_dropout=0.0),
+    # kanana-2 (model_type deepseek_v3): latent attention, a sigmoid
+    # router with a choice bias and a scale, two shared experts, one
+    # leading dense layer
+    "kanana": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                   use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                   num_experts=128, moe_top_k=6, norm_topk_prob=1,
+                   moe_score_function="sigmoid", moe_choice_bias=1,
+                   moe_routed_scale=2.448, moe_shared_experts=2,
+                   moe_first_dense_layers=1, kv_lora_rank=512,
+                   qk_nope_head_dim=128, qk_rope_head_dim=64,
+                   v_head_dim=128, rope_theta=1e6, layernorm_epsilon=1e-6,
                    hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
@@ -301,6 +313,16 @@ _CKPT_ARG_MAP = {
     "dsa_index_head_dim": "dsa_index_head_dim",
     "dsa_topk": "dsa_topk",
     "rope_sections": "rope_sections",
+    # kanana's router, shared MLP, dense layers and latent attention
+    "moe_score_function": "moe_score_function",
+    "moe_choice_bias": "moe_choice_bias",
+    "moe_routed_scale": "moe_routed_scale",
+    "moe_shared_experts": "moe_shared_experts",
+    "moe_first_dense_layers": "moe_first_dense_layers",
+    "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim",
+    "v_head_dim": "v_head_dim",
     # forward-math fields of a model with a layer type per layer
     "layer_types": "layer_types",
     "rope_yarn_scaling": "rope_yarn_scaling",

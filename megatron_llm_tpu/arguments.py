@@ -87,6 +87,30 @@ def _add_network_size_args(parser):
     g.add_argument("--moe_ffn_hidden_size", type=int, default=None,
                    help="an expert's width where it is not "
                         "--ffn_hidden_size (moe_intermediate_size)")
+    g.add_argument("--moe_score_function", type=str, default="softmax",
+                   choices=["softmax", "sigmoid"],
+                   help="how the router scores the experts")
+    g.add_argument("--moe_choice_bias", type=int, default=0, choices=[0, 1],
+                   help="a bias an expert added to the scores for the "
+                        "choice only (e_score_correction_bias)")
+    g.add_argument("--moe_routed_scale", type=float, default=1.0,
+                   help="the chosen gates times this "
+                        "(routed_scaling_factor)")
+    g.add_argument("--moe_shared_experts", type=int, default=0,
+                   help="an ungated MLP of this many experts' width that "
+                        "every token passes through (n_shared_experts)")
+    g.add_argument("--moe_first_dense_layers", type=int, default=0,
+                   help="leading layers that keep a dense MLP of "
+                        "--ffn_hidden_size (first_k_dense_replace)")
+    g.add_argument("--moe_n_group", type=int, default=1)
+    g.add_argument("--moe_topk_group", type=int, default=1)
+    g.add_argument("--kv_lora_rank", type=int, default=None,
+                   help="latent attention: the width of the latent that "
+                        "keys and values are expanded from, and cached")
+    g.add_argument("--q_lora_rank", type=int, default=None)
+    g.add_argument("--qk_nope_head_dim", type=int, default=128)
+    g.add_argument("--qk_rope_head_dim", type=int, default=64)
+    g.add_argument("--v_head_dim", type=int, default=128)
     g.add_argument("--moe_capacity_factor", type=float, default=1.25)
     g.add_argument("--moe_min_capacity", type=int, default=4)
     g.add_argument("--moe_aux_loss_coeff", type=float, default=1e-2)
@@ -1019,6 +1043,19 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         dsa_topk=int(getattr(args, "dsa_topk", 2048)),
         rope_sections=(tuple(args.rope_sections)
                        if getattr(args, "rope_sections", None) else None),
+        moe_score_function=getattr(args, "moe_score_function", "softmax"),
+        moe_choice_bias=bool(getattr(args, "moe_choice_bias", 0)),
+        moe_routed_scale=float(getattr(args, "moe_routed_scale", 1.0)),
+        moe_shared_experts=int(getattr(args, "moe_shared_experts", 0) or 0),
+        moe_first_dense_layers=int(
+            getattr(args, "moe_first_dense_layers", 0) or 0),
+        moe_n_group=int(getattr(args, "moe_n_group", 1)),
+        moe_topk_group=int(getattr(args, "moe_topk_group", 1)),
+        kv_lora_rank=getattr(args, "kv_lora_rank", None),
+        q_lora_rank=getattr(args, "q_lora_rank", None),
+        qk_nope_head_dim=int(getattr(args, "qk_nope_head_dim", 128)),
+        qk_rope_head_dim=int(getattr(args, "qk_rope_head_dim", 64)),
+        v_head_dim=int(getattr(args, "v_head_dim", 128)),
     )
 
 

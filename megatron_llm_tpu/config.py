@@ -230,6 +230,41 @@ class TransformerConfig:
     # ``moe_intermediate_size``; with every layer sparse the dense width
     # shapes nothing).  None: ``ffn_hidden_size``
     moe_ffn_hidden_size: Optional[int] = None
+    # how the router scores the experts: 'softmax' over all of them, or
+    # an independent 'sigmoid' an expert (DeepSeek-V3's router)
+    moe_score_function: str = "softmax"
+    # a learned-by-balancing bias an expert (``e_score_correction_bias``:
+    # a buffer ``[E]`` a layer in the param tree) that is added to the
+    # scores for the CHOICE of the top-k only; the gates are the scores
+    moe_choice_bias: bool = False
+    # the chosen gates (after ``norm_topk_prob``) times this
+    moe_routed_scale: float = 1.0
+    # shared experts: ONE ungated MLP of ``moe_shared_experts`` times an
+    # expert's width that every token passes through, added to the routed
+    # sum.  0: none
+    moe_shared_experts: int = 0
+    # the first layers of a sparse model that keep a dense MLP of
+    # ``ffn_hidden_size`` (``first_k_dense_replace``); their parameters
+    # are stacked apart from the sparse layers' (``dense_layers``)
+    moe_first_dense_layers: int = 0
+
+    # latent attention (DeepSeek's MLA; on when ``kv_lora_rank`` is set):
+    # keys and values are expanded from ONE latent of ``kv_lora_rank`` a
+    # token (RMSNorm'd, its own scale) and one rotary key head of
+    # ``qk_rope_head_dim`` shared by every query head; a query head is
+    # ``qk_nope_head_dim + qk_rope_head_dim`` wide (only the rotary part
+    # rotates), a value head ``v_head_dim``.  The paged cache holds the
+    # latent and the rotary key, not heads (``ops/paged_kv.py``).
+    # ``q_lora_rank`` (a compressed query) and group-limited routing
+    # (``moe_n_group`` / ``moe_topk_group``) are named so that a
+    # published config that sets them is refused by name
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
 
     # RMSNorm on the query and key projections before the rotary
     # embedding, over the WHOLE projection (all heads together) with a
@@ -343,6 +378,56 @@ class TransformerConfig:
         if self.rope_sections is not None:
             object.__setattr__(self, "rope_sections",
                                tuple(int(x) for x in self.rope_sections))
+        if self.q_lora_rank is not None:
+            raise ValueError("q_lora_rank (a compressed query projection) "
+                             "is not implemented: leave it unset")
+        if self.moe_n_group != 1 or self.moe_topk_group != 1:
+            raise ValueError("group-limited routing (moe_n_group / "
+                             "moe_topk_group other than 1) is not "
+                             "implemented")
+        if self.moe_score_function not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_score_function must be softmax|sigmoid, got "
+                f"{self.moe_score_function!r}")
+        if self.num_experts <= 1 and (
+                self.moe_shared_experts or self.moe_first_dense_layers
+                or self.moe_choice_bias):
+            raise ValueError("moe_shared_experts, moe_first_dense_layers and "
+                             "moe_choice_bias need num_experts > 1")
+        if self.moe_first_dense_layers and not (
+                0 < self.moe_first_dense_layers < self.num_layers):
+            raise ValueError(
+                f"moe_first_dense_layers ({self.moe_first_dense_layers}) "
+                f"must leave a sparse layer of {self.num_layers}")
+        if self.moe_first_dense_layers and self.layer_types is not None:
+            raise ValueError("moe_first_dense_layers is not implemented "
+                             "with a layer type per layer (layer_types)")
+        if self.kv_lora_rank is not None:
+            # what latent attention is not made to work with, by name
+            if self.position_embedding_type != PositionEmbeddingType.rotary:
+                raise ValueError("latent attention (kv_lora_rank) needs "
+                                 "the rotary position embedding")
+            if self.qk_rope_head_dim % 2 or min(
+                    self.kv_lora_rank, self.qk_nope_head_dim,
+                    self.qk_rope_head_dim, self.v_head_dim) < 1:
+                raise ValueError("latent attention needs positive widths "
+                                 "and an even qk_rope_head_dim")
+            for on, what in (
+                    (self.sliding_window_size is not None,
+                     "a sliding window"),
+                    (self.layer_types is not None, "layer_types"),
+                    (self.dsa_index_heads > 0, "sparse attention"),
+                    (self.qk_norm or self.qk_norm_per_head, "QK-norm"),
+                    (self.rope_sections is not None, "sectioned rope"),
+                    (self.rope_yarn_scaling is not None
+                     or self.rope_llama3_scaling is not None
+                     or self.rope_scaling_factor != 1.0, "rope scaling"),
+                    (self.add_bias_linear or self.add_qkv_bias,
+                     "linear biases"),
+                    (self.parallel_attn, "parallel_attn")):
+                if on:
+                    raise ValueError("latent attention (kv_lora_rank) is "
+                                     f"not implemented with {what}")
         if self.num_experts > 1:
             if self.add_bias_linear:
                 raise ValueError("MoE experts do not support linear biases "
@@ -364,6 +449,24 @@ class TransformerConfig:
     @property
     def expert_hidden_size(self) -> int:
         return self.moe_ffn_hidden_size or self.ffn_hidden_size
+
+    @property
+    def num_sparse_layers(self) -> int:
+        """The layers with experts: all of a sparse model's but its
+        leading dense ones; 0 for a dense model."""
+        if self.num_experts <= 1:
+            return 0
+        return self.num_layers - self.moe_first_dense_layers
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def qk_head_dim(self) -> int:
+        """A query (and expanded key) head's width under latent
+        attention: what the scores are scaled by."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
     def layer_period(self) -> Tuple[Optional[str], ...]:

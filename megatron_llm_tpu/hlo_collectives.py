@@ -121,7 +121,8 @@ def mesh_groups(mesh_shape: Dict[str, int], axes: Sequence[str]) -> Groups:
 # arrive in an instruction's ``op_name`` as path components; a row's
 # ``scope`` is the innermost of these its ``op_name`` holds
 SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
-          "moe_combine", "qk_norm", "dsa_indexer", "attention", "mlp",
+          "moe_combine", "moe_shared", "qk_norm", "dsa_indexer",
+          "mla_absorb", "mla_expand", "attention", "mlp",
           "embedding", "lm_head", "transformer_layer")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _SCOPE_CORE = re.compile(r"^(?:\w+\()*([\w.\-]+)\)*$")

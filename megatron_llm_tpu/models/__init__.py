@@ -12,6 +12,7 @@ from megatron_llm_tpu.models.mixtral import MixtralModel, mixtral_config
 from megatron_llm_tpu.models.olmoe import OlmoeModel, olmoe_config
 from megatron_llm_tpu.models.keye import KeyeModel, keye_config
 from megatron_llm_tpu.models.mellum import MellumModel, mellum_config
+from megatron_llm_tpu.models.kanana import KananaModel, kanana_config
 from megatron_llm_tpu.models.qwen2 import Qwen2Model, qwen2_config
 from megatron_llm_tpu.models.gemma import GemmaModel, gemma_config
 from megatron_llm_tpu.models.gpt_neox import GPTNeoXModel, gpt_neox_config
@@ -35,6 +36,7 @@ MODEL_REGISTRY = {
     "olmoe": OlmoeModel,
     "keye": KeyeModel,
     "mellum": MellumModel,
+    "kanana": KananaModel,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,
     "gpt_neox": GPTNeoXModel,

@@ -54,6 +54,19 @@ class GPTModel:
             raise ValueError(
                 "qk_norm normalises over the whole query/key projection "
                 "and is not implemented under tensor parallelism (tp > 1)")
+        # latent attention's one latent and one rotary key head are not
+        # sharded, and a pipeline stage's layers are taken to be of one
+        # kind: refused by name
+        if cfg.latent_attention or cfg.moe_first_dense_layers:
+            from megatron_llm_tpu import topology
+
+            pp = (topology.get_pipeline_model_parallel_world_size()
+                  if topology.model_parallel_is_initialized() else 1)
+            if not _vocab_unsharded() or pp > 1:
+                raise ValueError(
+                    "latent attention (kv_lora_rank) and leading dense "
+                    "layers (moe_first_dense_layers) are not implemented "
+                    "under tensor or pipeline parallelism (tp > 1, pp > 1)")
 
     # -- params ------------------------------------------------------------
     def init(self, key) -> dict:

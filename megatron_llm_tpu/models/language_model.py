@@ -93,16 +93,24 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
     including the decoder ``inter_attention`` block when present.  ``cfg``
     (when given) carries the resolved ``moe_expert_axis`` so MoE specs
     don't re-derive placement from the live mesh."""
+    attn = layers["attention"]
+    if "kv_down" in attn:
+        # latent attention is replicated (tp is refused)
+        attention_specs = {
+            **{name: _linear_spec(attn[name], None, None, stacked)
+               for name in ("query", "kv_down", "kv_up", "dense")},
+            "kv_norm": _norm_spec(attn["kv_norm"], stacked),
+        }
+    else:
+        attention_specs = {
+            "query_key_value": _linear_spec(
+                attn["query_key_value"], None, "heads", stacked
+            ),
+            "dense": _linear_spec(attn["dense"], "heads", None, stacked),
+        }
     layer_specs = {
         "input_norm": _norm_spec(layers["input_norm"], stacked),
-        "attention": {
-            "query_key_value": _linear_spec(
-                layers["attention"]["query_key_value"], None, "heads", stacked
-            ),
-            "dense": _linear_spec(
-                layers["attention"]["dense"], "heads", None, stacked
-            ),
-        },
+        "attention": attention_specs,
         "mlp": (
             moe_mlp_specs(layers["mlp"], stacked, cfg=cfg)
             if "experts" in layers["mlp"]
@@ -148,25 +156,24 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
 
 
 def transformer_stack_specs(stack_params, cfg=None) -> dict:
-    return {
+    specs = {
         "layers": transformer_layer_specs(stack_params["layers"], cfg=cfg),
         "final_norm": _norm_spec(stack_params["final_norm"], False),
     }
+    if "dense_layers" in stack_params:
+        # a sparse model's leading dense layers, stacked apart
+        specs["dense_layers"] = transformer_layer_specs(
+            stack_params["dense_layers"], cfg=cfg)
+    return specs
 
 
 def language_model_param_specs(params, cfg: TransformerConfig):
     """Logical-axis spec pytree matching ``init_language_model_params``
     (consumed by ``parallel.sharding.shard_params``)."""
-    norm_spec = _norm_spec
-    layer_specs = transformer_layer_specs(
-        params["transformer"]["layers"], cfg=cfg)
-
     specs = {
         "embedding": {"word": {"embedding": ("vocab", None)}},
-        "transformer": {
-            "layers": layer_specs,
-            "final_norm": norm_spec(params["transformer"]["final_norm"], False),
-        },
+        "transformer": transformer_stack_specs(params["transformer"],
+                                               cfg=cfg),
     }
     if "position" in params["embedding"]:
         specs["embedding"]["position"] = {"embedding": (None, None)}

@@ -37,7 +37,15 @@ Shared by both:
 * **The router** in fp32: softmax over all experts, the ``top_k``
   largest.  With ``cfg.norm_topk_prob`` (Mixtral: the softmax over the
   chosen experts) the chosen gates are renormalised to sum to 1; without
-  it (OLMoE) they are used as the softmax gives them.
+  it (OLMoE) they are used as the softmax gives them.  Its other forms
+  are data of the config (``_route``): ``moe_score_function='sigmoid'``
+  scores each expert by itself; with ``moe_choice_bias`` the ``top_k``
+  are chosen over score + a bias an expert (a buffer of the param tree,
+  ``router.choice_bias``) while the gates stay the scores;
+  ``moe_routed_scale`` multiplies the (renormalised) gates.
+* **The shared MLP** (``cfg.moe_shared_experts`` > 0): one ungated MLP
+  of that many experts' width that every token passes through, added to
+  the routed sum under the scope ``moe_shared``.
 * **Expert placement**: expert-stacked weights ``[E, ...]`` carry the
   ``'expert'`` logical axis, which the sharding rules map onto the ``dp``
   mesh axis (EP folded into dp, ``parallel/sharding.py``); the per-expert
@@ -62,7 +70,10 @@ import jax.numpy as jnp
 from megatron_llm_tpu.config import TransformerConfig
 from megatron_llm_tpu.ops.activations import apply_mlp_activation
 from megatron_llm_tpu.parallel.layers import (
+    column_parallel_linear,
+    init_linear_params,
     init_method_for,
+    row_parallel_linear,
     scaled_init_method_normal,
 )
 from megatron_llm_tpu.parallel.sharding import constrain
@@ -123,9 +134,17 @@ def _cfg_expert_axis(cfg: TransformerConfig):
     return "expert" if cfg.moe_expert_axis == "expert" else None
 
 
+# the spread a fresh model's choice bias is drawn at.  Published biases
+# are buffers moved by load balancing, and a checkpoint overwrites this;
+# drawn at zero, leaving the bias out of the choice would be a fault no
+# test or probe could see
+_CHOICE_BIAS_STD = 0.1
+
+
 def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
-    """{'router': {'kernel': [H, E]},
-        'experts': {'w_in': [E, H, (2x)F], 'w_out': [E, F, H]}}"""
+    """{'router': {'kernel': [H, E][, 'choice_bias': [E]]},
+        'experts': {'w_in': [E, H, (2x)F], 'w_out': [E, F, H]}
+        [, 'shared': a dense MLP's two linears at moe_shared_experts * F]}"""
     k_r, k_in, k_out = jax.random.split(key, 3)
     init = init_method_for(cfg)
     out_init = (
@@ -135,41 +154,94 @@ def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
     )
     E, H, F = cfg.num_experts, cfg.hidden_size, cfg.expert_hidden_size
     mult = 2 if cfg.glu_activation else 1
-    return {
+    params = {
         "router": {"kernel": init(k_r, (H, E), dtype)},
         "experts": {
             "w_in": init(k_in, (E, H, mult * F), dtype),
             "w_out": out_init(k_out, (E, F, H), dtype),
         },
     }
+    if cfg.moe_choice_bias:
+        params["router"]["choice_bias"] = (
+            _CHOICE_BIAS_STD * jax.random.normal(
+                jax.random.fold_in(k_r, 1), (E,), jnp.float32)).astype(dtype)
+    if cfg.moe_shared_experts:
+        k_si, k_so = jax.random.split(jax.random.fold_in(key, 1))
+        wide = cfg.moe_shared_experts * F
+        params["shared"] = {
+            "dense_h_to_4h": init_linear_params(
+                k_si, H, mult * wide, bias=False, init_method=init,
+                dtype=dtype),
+            "dense_4h_to_h": init_linear_params(
+                k_so, wide, H, bias=False, init_method=out_init,
+                dtype=dtype),
+        }
+    return params
 
 
 def moe_mlp_specs(params, stacked: bool = True, cfg=None) -> dict:
     lead = ("stage",) if stacked else ()
     E = params["experts"]["w_in"].shape[1 if stacked else 0]
     ex = _cfg_expert_axis(cfg) if cfg is not None else expert_axis(E)
-    return {
+    specs = {
         "router": {"kernel": lead + (None, None)},
         "experts": {
             "w_in": lead + (ex, None, "ffn"),
             "w_out": lead + (ex, "ffn", None),
         },
     }
+    if "choice_bias" in params["router"]:
+        specs["router"]["choice_bias"] = lead + (None,)
+    if "shared" in params:
+        specs["shared"] = {
+            "dense_h_to_4h": {"kernel": lead + (None, "ffn")},
+            "dense_4h_to_h": {"kernel": lead + ("ffn", None)},
+        }
+    return specs
 
 
 def _route(x: jax.Array, params, cfg: TransformerConfig):
     """x [..., h] -> (logits, probs [..., E], gates, idx [..., k]): the
-    router in fp32, the ``top_k`` largest of the softmax over all
-    experts, renormalised over the chosen ones only where the family
-    does (``cfg.norm_topk_prob``)."""
+    router in fp32.  The scores are the softmax over all experts or a
+    sigmoid an expert (``cfg.moe_score_function``); the ``top_k`` are the
+    largest scores, or with ``router.choice_bias`` the largest of score +
+    bias, and the gates are the chosen SCORES either way, renormalised
+    over the chosen ones only where the family does
+    (``cfg.norm_topk_prob``) and then times ``cfg.moe_routed_scale``."""
     wr = params["router"]["kernel"].astype(jnp.float32)
     logits = jnp.einsum("...h,he->...e", x.astype(jnp.float32), wr)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, cfg.moe_top_k)
+    if cfg.moe_score_function == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    if cfg.moe_choice_bias:
+        _, idx = jax.lax.top_k(
+            probs + params["router"]["choice_bias"].astype(jnp.float32),
+            cfg.moe_top_k)
+        gates = jnp.take_along_axis(probs, idx, axis=-1)
+    else:
+        gates, idx = jax.lax.top_k(probs, cfg.moe_top_k)
     if cfg.norm_topk_prob:
         gates = gates / jnp.maximum(
-            jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+            jnp.sum(gates, axis=-1, keepdims=True),
+            1e-20 if cfg.moe_score_function == "sigmoid" else 1e-9)
+    if cfg.moe_routed_scale != 1.0:
+        gates = gates * cfg.moe_routed_scale
     return logits, probs, gates, idx
+
+
+def _shared_mlp(x: jax.Array, params, cfg: TransformerConfig):
+    """The shared experts' MLP on x [b, s, h] (zeros' stand-in None for a
+    model without one): every token, no gate."""
+    if "shared" not in params:
+        return None
+    with jax.named_scope("moe_shared"):
+        mid = column_parallel_linear(
+            x, params["shared"]["dense_h_to_4h"], out_logical="ffn",
+            compute_dtype=cfg.compute_jnp_dtype)
+        return row_parallel_linear(
+            apply_mlp_activation(mid, cfg), params["shared"]["dense_4h_to_h"],
+            in_logical="ffn", compute_dtype=cfg.compute_jnp_dtype)
 
 
 def _aux_losses(logits, probs, frac):
@@ -278,9 +350,13 @@ def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
         y = y[back].reshape(T, k, h)
         out = jnp.einsum("tkh,tk->th", y, gates)
 
+    out = out.reshape(b, s, h)
+    shared = _shared_mlp(x, params, cfg)
+    if shared is not None:
+        out = out + shared.astype(jnp.float32)
     total = jnp.maximum(jnp.sum(counts), 1).astype(jnp.float32)
     aux = _aux_losses(logits, probs, counts.astype(jnp.float32) / total)
-    return out.reshape(b, s, h).astype(x.dtype), aux, counts
+    return out.astype(x.dtype), aux, counts
 
 
 def moe_mlp(
@@ -336,6 +412,9 @@ def moe_mlp(
 
     # --- combine (weighted un-dispatch) ---
     out = jnp.einsum("ebch,bsec->bsh", expert_out, combine.astype(cdtype))
+    shared = _shared_mlp(x, params, cfg)
+    if shared is not None:
+        out = out + shared.astype(out.dtype)
 
     frac = jnp.mean(oh.reshape(-1, E), axis=0)                 # [E], sums to 1
     aux = _aux_losses(logits, probs, frac)

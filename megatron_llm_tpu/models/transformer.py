@@ -65,7 +65,37 @@ def _qkv_out_dim(cfg: TransformerConfig) -> int:
     return ng * (qpg + 2) * cfg.head_dim
 
 
+def init_latent_attention_params(key, cfg: TransformerConfig, dtype):
+    """Latent attention's projections (``cfg.kv_lora_rank``), all
+    bias-free: ``query`` h -> heads x (nope + rope); ``kv_down`` h ->
+    latent + ONE rotary key; ``kv_norm`` the latent's RMSNorm scale;
+    ``kv_up`` latent -> heads x (nope keys + values), a head's keys
+    before its values; ``dense`` heads x values -> h."""
+    kq, kd, ku, ko = jax.random.split(key, 4)
+    init = init_method_for(cfg)
+    out_init = (
+        scaled_init_method_normal(cfg.init_method_std, cfg.num_layers)
+        if cfg.use_scaled_init_method
+        else init
+    )
+    nh, r = cfg.num_attention_heads, cfg.kv_lora_rank
+
+    def linear(k, n_in, n_out, method=init):
+        return init_linear_params(k, n_in, n_out, bias=False,
+                                  init_method=method, dtype=dtype)
+
+    return {
+        "query": linear(kq, cfg.hidden_size, nh * cfg.qk_head_dim),
+        "kv_down": linear(kd, cfg.hidden_size, r + cfg.qk_rope_head_dim),
+        "kv_norm": {"scale": jnp.ones((r,), dtype)},
+        "kv_up": linear(ku, r, nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "dense": linear(ko, nh * cfg.v_head_dim, cfg.hidden_size, out_init),
+    }
+
+
 def init_attention_params(key, cfg: TransformerConfig, dtype):
+    if cfg.latent_attention:
+        return init_latent_attention_params(key, cfg, dtype)
     k1, k2 = jax.random.split(key)
     init = init_method_for(cfg)
     out_init = (
@@ -173,9 +203,12 @@ def init_mlp_params(key, cfg: TransformerConfig, dtype):
     }
 
 
-def init_layer_params(key, cfg: TransformerConfig, dtype, layer_type: str = "encoder"):
+def init_layer_params(key, cfg: TransformerConfig, dtype,
+                      layer_type: str = "encoder", sparse: bool = True):
+    """``sparse`` False: a sparse model's leading dense layer, whose MLP
+    is the dense one of ``ffn_hidden_size``."""
     ka, km, kn = jax.random.split(key, 3)
-    if cfg.num_experts > 1:
+    if cfg.num_experts > 1 and sparse:
         from megatron_llm_tpu.models.moe import init_moe_mlp_params
 
         mlp_params = init_moe_mlp_params(km, cfg, dtype)
@@ -209,14 +242,25 @@ def init_layer_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
 def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "encoder"):
     """Layer-stacked params: every leaf gets a leading [num_layers] axis
     (scanned).  Reference builds a Python list of modules
-    (transformer.py:983-1014)."""
+    (transformer.py:983-1014).  A sparse model's leading dense layers
+    (``cfg.moe_first_dense_layers``) have other leaves, so they are
+    stacked apart, under ``dense_layers``, and ``layers`` holds the
+    sparse ones only."""
     keys = jax.random.split(key, cfg.num_layers)
-    layers = [init_layer_params(k, cfg, dtype, layer_type) for k in keys]
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *layers)
-    return {
-        "layers": stacked,
+    D = cfg.moe_first_dense_layers
+
+    def stack(ks, sparse):
+        layers = [init_layer_params(k, cfg, dtype, layer_type, sparse)
+                  for k in ks]
+        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *layers)
+
+    params = {
+        "layers": stack(keys[D:], True),
         "final_norm": init_norm_params(cfg.hidden_size, cfg.normalization, dtype),
     }
+    if D:
+        params["dense_layers"] = stack(keys[:D], False)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -322,6 +366,104 @@ def core_attention(
     return ctx.reshape(b, sq, nh, d)
 
 
+def latent_attention(
+    x: jax.Array,
+    params,
+    cfg: TransformerConfig,
+    *,
+    attention_mask: Optional[jax.Array],
+    position_ids: Optional[jax.Array],
+    dropout_key: Optional[jax.Array],
+    train: bool,
+    sequence_parallel: bool = False,
+    kv_cache=None,
+):
+    """Latent attention (``cfg.kv_lora_rank``; DeepSeek's MLA), ONE
+    function in two forms.
+
+    A token's keys and values of every head come from one latent ``c``
+    (``kv_down``'s first ``kv_lora_rank`` outputs, RMSNorm'd) through
+    ``kv_up``, and every head shares one rotary key ``k_rope``
+    (``kv_down``'s last ``qk_rope_head_dim``); a query head is
+    ``[q_nope ; q_rope]`` and only the rope parts rotate.  Scores
+    ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)``.
+
+    * **Expanded** (no cache: ``GPTModel``'s plain forward, training):
+      ``kv_up`` is applied to every token's latent (scope
+      ``mla_expand``) and heads of ``nope + rope`` attend values of
+      ``v_head_dim`` through XLA (``core_attention``, or the q-chunked
+      form at long sequences; the values ride at the keys' width).
+    * **Absorbed** (a ``PagedKVCache``: every engine program): ``kv_up``'s
+      key half is folded into the query and its value half is applied to
+      the attention's output (scope ``mla_absorb``), so the cache is read
+      as it lies: all heads attend ONE key ``[c ; k_rope]`` a token whose
+      first ``kv_lora_rank`` values are also its value
+      (``PagedKVCache.attend_latent``).
+
+    The legacy decode caches (contiguous, rolling, int8) are refused."""
+    b, s, _ = x.shape
+    cd = cfg.compute_jnp_dtype
+    nh, r = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if kv_cache is not None and not isinstance(kv_cache, PagedKVCache):
+        raise NotImplementedError(
+            "latent attention (kv_lora_rank) runs through the paged cache "
+            "or the plain forward, not the legacy decode caches")
+    q = column_parallel_linear(
+        x, params["query"], out_logical="heads",
+        sequence_parallel=sequence_parallel, compute_dtype=cd,
+    ).reshape(b, s, nh, dn + dr)
+    kv = column_parallel_linear(
+        x, params["kv_down"], out_logical=None,
+        sequence_parallel=sequence_parallel, compute_dtype=cd)
+    c = rms_norm(kv[..., :r], params["kv_norm"]["scale"],
+                 eps=cfg.layernorm_epsilon)
+    positions = position_ids
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    q_nope = q[..., :dn]
+    q_rope = apply_rotary_at(q[..., dn:], positions, cfg.rope_theta)
+    k_rope = apply_rotary_at(kv[..., None, r:], positions, cfg.rope_theta)
+    w_up = params["kv_up"]["kernel"].astype(cd).reshape(r, nh, dn + dv)
+
+    new_cache = None
+    if kv_cache is not None:
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_up[..., :dn])
+        ctx, new_cache = kv_cache.attend_latent(
+            q_lat, q_rope, c, k_rope[:, :, 0], 1.0 / math.sqrt(dn + dr))
+        with jax.named_scope("mla_absorb"):
+            ctx = jnp.einsum("bsnr,rnd->bsnd", ctx, w_up[..., dn:])
+    else:
+        with jax.named_scope("mla_expand"):
+            up = jnp.einsum("bsr,rnd->bsnd", c, w_up)
+        k = jnp.concatenate(
+            [up[..., :dn], jnp.broadcast_to(k_rope, (b, s, nh, dr))], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        # the values at the keys' width (zeros appended), so that the
+        # attention the repo has takes them; its scale is the queries'
+        v = jnp.pad(up[..., dn:], [(0, 0)] * 3 + [(0, dn + dr - dv)])
+        from megatron_llm_tpu.ops.chunked_attention import (
+            CHUNKED_ATTENTION_MIN_SEQ,
+            chunked_causal_attention,
+        )
+
+        if (attention_mask is None and s >= CHUNKED_ATTENTION_MIN_SEQ
+                and not (train and cfg.attention_dropout > 0.0)):
+            ctx = chunked_causal_attention(q, k, v, causal=True)
+        else:
+            ctx = core_attention(q, k, v, cfg, attention_mask, dropout_key,
+                                 train)
+        ctx = ctx[..., :dv]
+
+    out = row_parallel_linear(
+        ctx.reshape(b, s, nh * dv), params["dense"], in_logical="heads",
+        sequence_parallel=sequence_parallel, compute_dtype=cd)
+    if kv_cache is not None:
+        return out, new_cache
+    return out
+
+
 def attention(
     x: jax.Array,
     params,
@@ -344,6 +486,11 @@ def attention(
     (``text_generation/generation.py::init_kv_caches``).  ``layer_type``:
     this layer's, of a model with ``cfg.layer_types``: it decides the
     window and the rotary variant (``cfg.attention_of``)."""
+    if cfg.latent_attention:
+        return latent_attention(
+            x, params, cfg, attention_mask=attention_mask,
+            position_ids=position_ids, dropout_key=dropout_key, train=train,
+            sequence_parallel=sequence_parallel, kv_cache=kv_cache)
     window, yarn = cfg.attention_of(layer_type)
     mixed = column_parallel_linear(
         x, params["query_key_value"],
@@ -739,7 +886,7 @@ def transformer_layer(
 
     Returns the fixed-arity triple ``(out, new_cache, moe_aux)`` —
     ``new_cache`` is None when ``kv_cache`` is None, ``moe_aux`` is None
-    for dense (non-MoE) configs.  With ``moe_layer`` the experts' weights
+    for a layer with a dense MLP (what ``params['mlp']`` holds decides).  With ``moe_layer`` the experts' weights
     in ``params`` are every layer's, stacked, and this layer is that one
     of them (``moe_mlp_dropless``).  ``layer_type``: the layer's, of a
     model with ``cfg.layer_types`` (``attention`` says what it decides).
@@ -798,7 +945,8 @@ def transformer_layer(
     def run_mlp(inp):
         nonlocal new_cache
         with jax.named_scope("mlp"):
-            if cfg.num_experts <= 1:
+            if "experts" not in params["mlp"]:
+                # a dense model, or a sparse model's leading dense layer
                 return mlp(inp, params["mlp"], cfg,
                            sequence_parallel=sequence_parallel), None
             if train:
@@ -894,12 +1042,20 @@ def transformer_stack(
     (:1110-1176): 'uniform'/'block' -> full per-layer remat; 'selective' ->
     save-nothing-but-matmul-free recompute of core attention via policy.
 
+    A sparse model's leading dense layers (``dense_layers`` of the
+    params) run before the scan, which is over the sparse layers.
+
     A model with a layer type per layer (``cfg.layer_types``: one period
     of types) scans over PERIODS with a period's layers unrolled in the
     body, each of its own type, so the trace holds one period whatever
     the depth; a model of one type is one period of one layer."""
     layers = stack_params["layers"]
     L = cfg.num_layers
+    # a sparse model's leading dense layers: other leaves, so stacked
+    # apart (``init_stack_params``) and run before the scan; ``layers``
+    # holds the L - D that follow
+    dense = stack_params.get("dense_layers")
+    D = cfg.moe_first_dense_layers if dense is not None else 0
     period = cfg.layer_period
     P = len(period)
     # Per-layer dropout rates are traced (scanned) only for lima dropout;
@@ -910,6 +1066,9 @@ def transformer_stack(
     )
 
     moe_on = cfg.num_experts > 1
+    layer_kw = dict(
+        freqs=freqs, attention_mask=attention_mask, position_ids=position_ids,
+        sequence_parallel=sequence_parallel)
 
     @jax.named_scope("transformer_layer")
     def body(carry, scanned):
@@ -925,15 +1084,12 @@ def transformer_stack(
                 rate = None
             h, _, moe_aux = transformer_layer(
                 h, layer_p, cfg,
-                freqs=freqs, attention_mask=attention_mask,
-                position_ids=position_ids,
                 rng_key=key if rng_key is not None else None,
-                train=train, sequence_parallel=sequence_parallel,
-                hidden_dropout=rate,
+                train=train, hidden_dropout=rate,
                 encoder_output=encoder_output, enc_dec_mask=enc_dec_mask,
-                layer_type=layer_type,
+                layer_type=layer_type, **layer_kw,
             )
-            if moe_on:
+            if moe_aux is not None:
                 aux_acc = aux_acc + moe_aux
         return ((h, aux_acc) if moe_on else h), None
 
@@ -950,22 +1106,24 @@ def transformer_stack(
         new_caches = []
         h = x
         # a sparse model's experts go in whole, as the model stacks them,
-        # with the layer's index: models/moe.py says why, and decides
-        # what to do with them
+        # with the layer's index AMONG THE SPARSE LAYERS: models/moe.py
+        # says why, and decides what to do with them
         sliced = layers
         if moe_on:
             sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items()
                                         if k != "experts"}}
         for i in range(L):
-            layer_p = jax.tree_util.tree_map(lambda p: p[i], sliced)
-            if moe_on:
+            sparse = moe_on and i >= D
+            layer_p = jax.tree_util.tree_map(
+                lambda p: p[i - D], sliced) if i >= D else (
+                jax.tree_util.tree_map(lambda p: p[i], dense))
+            if sparse:
                 layer_p["mlp"]["experts"] = layers["mlp"]["experts"]
             h, c, _ = transformer_layer(
-                h, layer_p, cfg,
-                freqs=freqs, attention_mask=attention_mask,
-                position_ids=position_ids, rng_key=None, train=False,
-                sequence_parallel=sequence_parallel, kv_cache=kv_caches[i],
-                moe_layer=i if moe_on else None, layer_type=period[i % P],
+                h, layer_p, cfg, rng_key=None, train=False,
+                kv_cache=kv_caches[i],
+                moe_layer=i - D if sparse else None,
+                layer_type=period[i % P], **layer_kw,
             )
             new_caches.append(c)
         h = apply_norm(
@@ -974,10 +1132,20 @@ def transformer_stack(
         )
         return h, new_caches
 
+    for i in range(D):
+        x, _, _ = transformer_layer(
+            x, jax.tree_util.tree_map(lambda p: p[i], dense), cfg,
+            rng_key=layer_keys[i] if rng_key is not None else None,
+            train=train,
+            hidden_dropout=(dropout_rates[i] if dropout_rates is not None
+                            else None),
+            encoder_output=encoder_output, enc_dec_mask=enc_dec_mask,
+            **layer_kw,
+        )
     scanned = (
-        (layers, layer_keys, dropout_rates)
+        (layers, layer_keys[D:], dropout_rates[D:])
         if dropout_rates is not None
-        else (layers, layer_keys)
+        else (layers, layer_keys[D:])
     )
     if P > 1:
         # [L, ...] -> [L / P, P, ...]: the scan's step is a period

@@ -11,7 +11,15 @@ indexer's one key head, ``[num_blocks, block_size, index_width]``,
 written beside K and V at the same place (``index_width`` is the
 indexer's head size filled up with zeros to the TPU's 128 lanes: a
 narrower last dimension is laid out, and fetched, at that width
-anyway, and a slice of it cannot be addressed).  Whatever a pool holds, a page
+anyway, and a slice of it cannot be addressed).  A model with latent
+attention (``cfg.kv_lora_rank``) has ONE array a layer in place of K and
+V, ``latent_pages`` ``[num_blocks, block_size, latent_width]``: a
+token's row is its normed latent, then the one rotary key every head
+shares, then zeros up to whole lanes (512 + 64 -> 640 at the published
+widths: 1,280 B a token a layer in bf16 where 32 heads of keys of 192
+and values of 128 would hold 20,480), and the row is the key AND, in its
+first ``kv_lora_rank`` columns, the value of all the absorbed query
+heads (``attend_latent``).  Whatever a pool holds, a page
 of it is a page of every array: the page programs below are
 ``tree_map``s, so copy-on-write, the prefix cache's adoption and the
 host tier carry a page's indexer keys with its keys and values.  Block 0 is the
@@ -118,6 +126,13 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     dtype = dtype or cfg.compute_jnp_dtype
     indexed = cfg.dsa_index_heads > 0
     groups = layer_groups(cfg)
+    if cfg.latent_attention:
+        if quantized:
+            raise ValueError("latent attention (kv_lora_rank) is not "
+                             "implemented over the int8 KV pool")
+        shape = (num_blocks, block_size, latent_width(cfg))
+        return [{"latent_pages": jnp.zeros(shape, dtype)}
+                for _ in range(cfg.num_layers)]
     if indexed and quantized:
         raise ValueError("sparse attention (dsa_index_heads > 0) is not "
                          "implemented over the int8 KV pool")
@@ -145,6 +160,12 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
 
     return [pool(window_blocks if groups and groups[i] == WINDOW
                  else num_blocks) for i in range(cfg.num_layers)]
+
+
+def latent_width(cfg) -> int:
+    """The width of a latent pool's row: the latent and the rotary key,
+    filled up to whole lanes."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
 
 
 def _arrays(pool: dict):
@@ -252,15 +273,6 @@ class PagedKVCache:
                               self.valid_lens)
         n, d = k.shape[1], k.shape[3]
         quantized = "k_pages_q" in self.pool
-        P, bs = _arrays(self.pool)[0].shape[:2]
-        M = bt.shape[1]
-        j = jnp.arange(n)[None, :]
-        pos = ctx_lens[:, None] + j                          # [b, n] abs pos
-        blk = jnp.take_along_axis(bt, jnp.clip(pos // bs, 0, M - 1), axis=1)
-        # padded / inactive tokens (not live) land in garbage block 0
-        # (duplicate scatter indices there are fine)
-        dest = jnp.where(j < vlen[:, None], blk * bs + pos % bs, pos % bs)
-        dest = jnp.clip(dest, 0, P * bs - 1)
         if quantized:
             from megatron_llm_tpu.quantization import absmax_quantize_int8
 
@@ -273,12 +285,7 @@ class PagedKVCache:
         if index is not None:
             writes["index_pages"] = _to_width(
                 index[1], self.pool["index_pages"].shape[-1])
-        pool = {}
-        with jax.named_scope("kv_write"):
-            for name, val in writes.items():
-                a = self.pool[name]
-                flat = a.reshape((P * bs,) + a.shape[2:])
-                pool[name] = flat.at[dest].set(val).reshape(a.shape)
+        pool = self._write(writes, n)
         kp, vp, k_scales, v_scales = _arrays(pool)
         scale = 1.0 / math.sqrt(d)
         if index is not None:
@@ -302,6 +309,62 @@ class PagedKVCache:
         return ctx, dataclasses.replace(self, pool=pool,
                                         context_lens=ctx_lens + vlen)
 
+
+    def _write(self, writes: dict, n: int) -> dict:
+        """The pool with this call's rows ``writes[name]`` [b, n, ...]
+        scattered at ``context_lens ..`` of each row's table, under the
+        scope ``kv_write``."""
+        bt, ctx_lens, vlen = (self.block_tables, self.context_lens,
+                              self.valid_lens)
+        P, bs = next(iter(self.pool.values())).shape[:2]
+        M = bt.shape[1]
+        j = jnp.arange(n)[None, :]
+        pos = ctx_lens[:, None] + j                          # [b, n] abs pos
+        blk = jnp.take_along_axis(bt, jnp.clip(pos // bs, 0, M - 1), axis=1)
+        # padded / inactive tokens (not live) land in garbage block 0
+        # (duplicate scatter indices there are fine)
+        dest = jnp.where(j < vlen[:, None], blk * bs + pos % bs, pos % bs)
+        dest = jnp.clip(dest, 0, P * bs - 1)
+        pool = {}
+        with jax.named_scope("kv_write"):
+            for name, val in writes.items():
+                a = self.pool[name]
+                flat = a.reshape((P * bs,) + a.shape[2:])
+                pool[name] = flat.at[dest].set(val).reshape(a.shape)
+        return pool
+
+    def attend_latent(self, q_latent: jax.Array, q_rope: jax.Array,
+                      latent: jax.Array, k_rope: jax.Array, scale: float):
+        """A latent pool's ``attend``: write this call's rows
+        ``[latent [b, n, r] ; k_rope [b, n, dr] ; zeros]`` at
+        ``context_lens ..``, then attend the absorbed queries
+        ``[q_latent [b, n, nh, r] ; q_rope [b, n, nh, dr]]`` over the
+        row's history and the chunk's own causal prefix: one key a token
+        for every head, its first ``r`` columns the value.  Returns the
+        context IN THE LATENT ``[b, n, nh, r]`` (the caller applies the
+        value half of the up-projection) and the cache as the step
+        leaves it.  A decode step (``n == 1``) launches
+        ``mla_attention_decode``, a chunk ``mla_attention_prefill``; each
+        reads a live page once."""
+        from megatron_llm_tpu.ops.pallas import paged_attention as _pa
+
+        pages = self.pool["latent_pages"]
+        n, r, W = latent.shape[1], latent.shape[2], pages.shape[-1]
+        row = _to_width(jnp.concatenate([latent, k_rope], axis=-1), W)
+        pool = self._write({"latent_pages": row.astype(pages.dtype)}, n)
+        q = _to_width(jnp.concatenate([q_latent, q_rope], axis=-1), W)
+        kw = dict(valid_lens=self.valid_lens, value_width=r,
+                  softmax_scale=scale)
+        args = (pool["latent_pages"], self.block_tables, self.context_lens)
+        if self.kernel != "pallas":
+            ctx = _pa.dense_latent_attention(
+                q, *args, self.valid_lens, scale, r)
+        elif n == 1:
+            ctx = _pa.latent_attention_decode(q[:, 0], *args, **kw)[:, None]
+        else:
+            ctx = _pa.latent_attention_prefill(q, *args, **kw)
+        return ctx, dataclasses.replace(
+            self, pool=pool, context_lens=self.context_lens + self.valid_lens)
 
     def _attend_selected(self, q, pool, index, scale):
         """``attend``'s read for a pool with an indexer: scores over the
@@ -358,8 +421,7 @@ def pools_of(caches: List[PagedKVCache]) -> List[dict]:
 
 
 def routing_of(caches: List[PagedKVCache]) -> Optional[jax.Array]:
-    """[layers, E] int32 live assignments of a sparse model's step; None
-    for a dense model."""
-    if caches[0].moe_counts is None:
-        return None
-    return jnp.stack([c.moe_counts for c in caches])
+    """[sparse layers, E] int32 live assignments of a sparse model's
+    step (its leading dense layers leave none); None for a dense model."""
+    counts = [c.moe_counts for c in caches if c.moe_counts is not None]
+    return jnp.stack(counts) if counts else None

@@ -65,6 +65,17 @@ at 528 pages, 6.9 ms at 1,024), and 0.6 us for a live one.  A 64-token
 chunk: 16 / 30 / 69 / 137 us at 0 / 192 / 1,984 / 6,016 tokens of
 context (76 / 123 / 560 / 1,076 before).
 
+A LATENT pool (``ops/paged_kv.py``: one array ``[P, bs, W]`` a layer,
+a token's row its normed latent, then the one rotary key, then zeros up
+to the lanes) is read by the same walk (``latent_attention_decode`` /
+``latent_attention_prefill``, launched as ``mla_attention_decode`` /
+``mla_attention_prefill``): one kv group whose key is the whole row and
+whose value is the row's first ``value_width`` columns, so a page is
+fetched ONCE for scores and values, and every query head (absorbed
+queries of the row's width) attends it.  Its products are in the pool's
+dtype on a chunk too (fp32 accumulation; the probabilities are rounded
+to the pool's dtype for the second product, as a flash kernel's are).
+
 Dispatch mirrors ``flash_attention.py``: TPU backend -> kernel;
 otherwise -> the dense reference.  Interpret-mode tests run the kernel
 on CPU via the module-level ``_INTERPRET`` flag.  ``ops/paged_kv.py``
@@ -138,7 +149,8 @@ def dense_paged_attention(q, k_pages, v_pages, block_tables,
     block_tables = jnp.where(
         jnp.arange(M)[None, :] * bs < live[:, None], block_tables, 0)
     k = k_pages[block_tables].reshape(S, M * bs, g, d).astype(jnp.float32)
-    v = v_pages[block_tables].reshape(S, M * bs, g, d).astype(jnp.float32)
+    v = v_pages[block_tables].reshape(
+        S, M * bs, g, v_pages.shape[-1]).astype(jnp.float32)
     if k_scales is not None:
         k = k * k_scales[block_tables].reshape(S, M * bs, g, 1)
         v = v * v_scales[block_tables].reshape(S, M * bs, g, 1)
@@ -152,7 +164,7 @@ def dense_paged_attention(q, k_pages, v_pages, block_tables,
     scores = jnp.where(valid[:, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bgpst,btgd->bsgpd", probs, v)
-    return out.reshape(S, C, nh, d).astype(q.dtype)
+    return out.reshape(S, C, nh, v.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -170,8 +182,9 @@ def _valid_keys(key_pos, pos, window):
 
 def _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, rows):
     """One online-softmax update: fp32 scores ``sq`` [R, T] with their
-    validity and the fp32 values ``v`` [T, d] folded into the running
-    (m, l, acc) at scratch ``rows``."""
+    validity and the values ``v`` [T, d] (fp32, or a latent pool's own
+    dtype, to which the probabilities are then rounded) folded into the
+    running (m, l, acc) at scratch ``rows``."""
     sq = jnp.where(valid, sq, NEG_INF)
     m_prev = m_scr[rows]                              # [R, 1]
     m_new = jnp.maximum(m_prev, jnp.max(sq, axis=-1, keepdims=True))
@@ -179,7 +192,7 @@ def _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, rows):
     p = jnp.where(valid, jnp.exp(sq - m_new), 0.0)
     l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_scr[rows] = m_new
 
 
@@ -210,18 +223,24 @@ def _pages_per_block(block_size, g, d, dtype, M):
 
 
 def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
-               quantized, scale, window, qpg):
+               quantized, scale, window, qpg, value_width):
     """One (slot, q-block): walk pages ``first .. last`` of the slot's
     table in blocks of ``kp`` pages, block j+1 on its way from HBM while
     block j is computed.  Nothing of the table outside that range is
-    read."""
-    n_pool = 4 if quantized else 2
+    read.  ``value_width``: the pool is a latent one, ONE array of pages
+    ``[bs, W]`` whose rows are the keys of one kv group and whose first
+    ``value_width`` columns are the values."""
+    latent = value_width is not None
+    n_pool = 1 if latent else 4 if quantized else 2
     hbm = refs[:n_pool]                   # K, V[, K scales, V scales]
     o_ref = refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
     sem, m_scr, l_scr, acc_scr = refs[2 * n_pool + 1:]
     s = pl.program_id(0)
-    _, kp, bs, g, d = bufs[0].shape
+    if latent:
+        (_, kp, bs, d), g = bufs[0].shape, 1
+    else:
+        _, kp, bs, g, d = bufs[0].shape
     _, bq, nh, _ = q_ref.shape
     T = kp * bs                           # keys of a block
     lanes = T * g                         # (page, position, group) triples
@@ -271,7 +290,8 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
 
     # bf16 queries on bf16 pools multiply as they are (a product of two
     # bf16 is exact in the fp32 accumulator); anything else goes to fp32
-    native = bq == 1 and not quantized and bufs[0].dtype == q_ref.dtype
+    native = ((bq == 1 or latent) and not quantized
+              and bufs[0].dtype == q_ref.dtype)
     q = q_ref[0] if native else q_ref[0].astype(jnp.float32)  # [bq, nh, d]
 
     def dequantized(x, sc):
@@ -290,7 +310,17 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
             block_dma(j + 1, 1 - slot, True)
 
         block_dma(j, slot, False)
-        if quantized:
+        if latent:
+            # keys and values are one fetch: the rows, and their first
+            # columns.  Pages past the last live one are zeroed whole
+            k = bufs[0][slot].reshape(lanes, d)
+            k = jnp.where(
+                (first + j * kp) * bs + iota((lanes, 1), 0) <= top, k,
+                jnp.zeros_like(k))
+            if not native:
+                k = k.astype(jnp.float32)
+            v = k[:, :value_width]
+        elif quantized:
             k = dequantized(bufs[0][slot], bufs[2][slot])
             v = dequantized(bufs[1][slot], bufs[3][slot])
         else:
@@ -302,8 +332,9 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
         # buffer pages past the last live one hold what an earlier block
         # left there: their scores are masked below, and their values
         # zeroed here so that 0 x (whatever they are) adds nothing
-        v = jnp.where(
-            base + jax.lax.div(iota((lanes, 1), 0), g) <= top, v, 0.0)
+        if not latent:
+            v = jnp.where(
+                base + jax.lax.div(iota((lanes, 1), 0), g) <= top, v, 0.0)
         if bq == 1:
             # decode: all nh heads against all (key, group) pairs of the
             # block in ONE matmul.  Lane c of the scores is key c // g of
@@ -323,7 +354,7 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
             return carry
         # a chunk: the rows of one kv group [R, d] against that group's
         # keys [T, d]; flat row r is chunk row r // qpg, head r % qpg
-        k, v = k.reshape(T, g, d), v.reshape(T, g, d)
+        k, v = k.reshape(T, g, d), v.reshape(T, g, v.shape[-1])
         valid = _valid_keys(base + iota((R, T), 1),
                             ctx + q0 + jax.lax.div(iota((R, T), 0), qpg),
                             window)
@@ -342,31 +373,42 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
     else:
         outs = [_softmax_finish(l_scr, acc_scr,
                                 slice(grp * R, (grp + 1) * R)
-                                ).reshape(bq, qpg, d) for grp in range(g)]
+                                ).reshape(bq, qpg, acc_scr.shape[-1])
+                for grp in range(g)]
         out = outs[0] if g == 1 else jnp.concatenate(outs, axis=1)
     o_ref[0] = out.astype(o_ref.dtype)                  # [bq, nh, d]
 
 
 def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
                valid_lens, k_scales, v_scales, *, scale, window, block_q,
-               name, name_suffix=""):
+               name, name_suffix="", value_width=None):
     """q [S, C, nh, d] with block_q | C (decode is C == block_q == 1);
     the pools stay in HBM and the kernel fetches pages itself.  ``name``
     is the kernel's name in a profile (``name_suffix``, the caller's
     ``_window`` for a window group's walk, and ``_quant`` for the int8
     pools appended); ``valid_lens`` None = every slot has tokens in this
-    call."""
+    call.  ``value_width``: ``k_pages`` is a latent pool ``[P, bs, W]``
+    (``v_pages`` None) and the output is ``[S, C, nh, value_width]``."""
     if valid_lens is None:
         valid_lens = jnp.ones_like(context_lens)
     S, C, nh, d = q.shape
-    bs, g = k_pages.shape[1], k_pages.shape[2]
+    latent = value_width is not None
+    bs, g = k_pages.shape[1], 1 if latent else k_pages.shape[2]
+    dv = value_width if latent else d
     M = block_tables.shape[1]
     bq = block_q
     assert C % bq == 0, (C, bq)
     quantized = k_scales is not None
-    kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
-    pools = [k_pages, v_pages]
-    bufs = [pltpu.VMEM((2, kp, bs, g, d), k_pages.dtype)] * 2
+    if latent:
+        # a block of _BLOCK_TOKENS keys whatever the row's width: the
+        # scores' lanes are what a block is sized by here
+        kp = max(1, min(M, _BLOCK_TOKENS // bs))
+        pools = [k_pages]
+        bufs = [pltpu.VMEM((2, kp, bs, d), k_pages.dtype)]
+    else:
+        kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
+        pools = [k_pages, v_pages]
+        bufs = [pltpu.VMEM((2, kp, bs, g, d), k_pages.dtype)] * 2
     if quantized:
         # scales as [P, bs * g] rows, the order of a page's rows
         pools += [x.astype(jnp.float32).reshape(-1, bs * g)
@@ -382,21 +424,22 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
         in_specs=[pl.BlockSpec((1, bq, nh, d), q_map,
                                memory_space=pltpu.VMEM)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=pl.BlockSpec((1, bq, nh, d), q_map,
+        out_specs=pl.BlockSpec((1, bq, nh, dv), q_map,
                                memory_space=pltpu.VMEM),
         scratch_shapes=bufs + [
             pltpu.SemaphoreType.DMA((len(pools), 2)),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
-            pltpu.VMEM((bq * nh, d), jnp.float32),
+            pltpu.VMEM((bq * nh, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         functools.partial(_walk_body, quantized=quantized, scale=scale,
-                          window=window, qpg=nh // g),
+                          window=window, qpg=nh // g,
+                          value_width=value_width),
         name=name + name_suffix + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, C, nh, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, C, nh, dv), q.dtype),
         interpret=_INTERPRET,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       valid_lens.astype(jnp.int32), q, *pools)
@@ -493,3 +536,65 @@ def paged_attention_prefill(
         q, k_pages, v_pages, block_tables, context_lens, valid_lens,
         k_scales, v_scales, scale=softmax_scale, window=sliding_window,
         block_q=bq, name="paged_attention_prefill", name_suffix=name_suffix)
+
+
+# a latent chunk's q-block: (row, head) pairs, fewer than a chunk of
+# per-head keys takes because the fp32 accumulator is a latent wide
+_LATENT_BLOCK_ROWS = 1024
+
+
+def dense_latent_attention(q, pages, block_tables, context_lens, valid_lens,
+                           scale, value_width):
+    """The dense reference of the two entries below: every slot's table
+    gathered, one kv group whose values are the keys' first
+    ``value_width`` columns."""
+    keys = pages[:, :, None, :]
+    return dense_paged_attention(
+        q, keys, keys[..., :value_width], block_tables, context_lens,
+        valid_lens, None, None, scale, None)
+
+
+def latent_attention_decode(q, pages, block_tables, context_lens, *,
+                            valid_lens=None, value_width: int,
+                            softmax_scale: float):
+    """Ragged attention over a latent pool for one decode token a slot.
+
+    ``q`` [S, nh, W]: absorbed queries at the pool's row width (a row is
+    ``[latent ; rotary key ; zeros]``, the query ``[W_UK^T q_nope ;
+    q_rope ; anything]``); ``pages`` [P, bs, W]; tables and lengths as
+    :func:`paged_attention_decode`.  Returns ``[S, nh, value_width]``:
+    each head's probabilities over the rows' first ``value_width``
+    columns, every live page read once."""
+    assert q.ndim == 3 and pages.ndim == 3, (q.shape, pages.shape)
+    if not _use_pallas():
+        return dense_latent_attention(
+            q[:, None], pages, block_tables, context_lens, valid_lens,
+            softmax_scale, value_width)[:, 0]
+    return _walk_call(
+        q[:, None], pages, None, block_tables, context_lens, valid_lens,
+        None, None, scale=softmax_scale, window=None, block_q=1,
+        name="mla_attention_decode", value_width=value_width)[:, 0]
+
+
+def latent_attention_prefill(q, pages, block_tables, context_lens, *,
+                             valid_lens=None, value_width: int,
+                             softmax_scale: float,
+                             block_q: Optional[int] = None):
+    """Ragged attention over a latent pool for one chunk a slot: ``q``
+    [S, C, nh, W] at positions ``context_lens[s] ..`` (the chunk's own
+    rows already written), causal within the chunk on top of the paged
+    history, in the absorbed form like the decode entry.  Returns
+    ``[S, C, nh, value_width]``."""
+    assert q.ndim == 4 and pages.ndim == 3, (q.shape, pages.shape)
+    if not _use_pallas():
+        return dense_latent_attention(
+            q, pages, block_tables, context_lens, valid_lens, softmax_scale,
+            value_width)
+    C = q.shape[1]
+    bq = min(block_q or max(1, _LATENT_BLOCK_ROWS // q.shape[2]), C)
+    while C % bq:
+        bq -= 1
+    return _walk_call(
+        q, pages, None, block_tables, context_lens, valid_lens, None, None,
+        scale=softmax_scale, window=None, block_q=bq,
+        name="mla_attention_prefill", value_width=value_width)

@@ -105,6 +105,7 @@ from megatron_llm_tpu.serving.request import (
 )
 from megatron_llm_tpu.serving.loop_profiler import (
     DSA_FIELDS,
+    MLA_FIELDS,
     KV_FIELDS,
     MOE_FIELDS,
     DispatchRecord,
@@ -325,6 +326,18 @@ class InferenceEngine:
             raise ValueError(
                 "sparse attention (dsa_index_heads > 0) is not implemented "
                 "for the speculative verify step")
+        # latent attention: a pool of latents (ops/paged_kv.py) whose
+        # reads are the decode and the chunk walk only.  The prefix cache
+        # carries over: a page is a page of every array
+        self._latent = bool(mcfg.latent_attention)
+        if self._latent:
+            for on, what in ((self.speculative, "the speculative verify "
+                              "step"), (cfg.int8_kv_cache, "the int8 KV "
+                              "pool"), (cfg.host_cache_bytes > 0, "the "
+                              "host KV tier")):
+                if on:
+                    raise ValueError("latent attention (kv_lora_rank) is "
+                                     f"not implemented for {what}")
         # a layer type per layer: two groups of pools (ops/paged_kv.py),
         # the window group sized for every slot at its bound.  What the
         # second group is not built for is refused by name, and a prefix
@@ -423,7 +436,7 @@ class InferenceEngine:
             setattr(self, f, 0)
         # learned sparse attention, summed over launches (the record's
         # fields of the same names)
-        for f in DSA_FIELDS:
+        for f in DSA_FIELDS + MLA_FIELDS:
             setattr(self, f, 0)
         # the two groups of a model with a layer type per layer, summed
         # over launches (the record's fields of the same names), and a
@@ -1183,10 +1196,16 @@ class InferenceEngine:
 
     def _note_selection(self, d: DispatchRecord, sees, context_lens,
                         valid_lens, n: int) -> None:
-        """A launch of a model with a sparse-attention indexer: the keys
+        """A launch of a model with a latent pool: the keys its live
+        queries see (``MLA_FIELDS``).  A launch of a model with a
+        sparse-attention indexer: the keys
         its live queries see and attend and the blocks its choice counts
         over (from the arrays the program is handed: ``n`` queries a
         slot), on the record and in the running totals."""
+        if self._latent:
+            d.note_latent(sees, self.model.cfg.num_layers)
+            for f in MLA_FIELDS:
+                setattr(self, f, getattr(self, f) + getattr(d, f))
         if not self._dsa_topk:
             return
         d.note_selection(
@@ -1678,7 +1697,7 @@ class InferenceEngine:
             **{f: getattr(self, f) for f in MOE_FIELDS},
             **({"moe_expert_tiles": self.moe_expert_tiles}
                if self.moe_expert_tiles else {}),
-            **{f: getattr(self, f) for f in DSA_FIELDS},
+            **{f: getattr(self, f) for f in DSA_FIELDS + MLA_FIELDS},
             **{f: getattr(self, f) for f in KV_FIELDS},
             "engine_restarts": self.engine_restarts,
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,

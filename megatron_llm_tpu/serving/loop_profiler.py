@@ -112,6 +112,11 @@ MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
 DSA_FIELDS = ("dsa_keys_live", "dsa_keys_selected",
               "dsa_select_blocks_counted", "dsa_select_blocks_table")
 
+# a launch's account of latent attention (DispatchRecord's fields; all 0
+# for a model without a latent pool), which the engine also keeps running
+# totals of
+MLA_FIELDS = ("mla_keys_live", "mla_pairs", "mla_latents_expanded")
+
 # a launch's account of the two page groups of a model with a layer type
 # per layer (DispatchRecord's fields; all 0 for a model of one type),
 # which the engine also keeps running totals of
@@ -194,6 +199,15 @@ class DispatchRecord:
     # slot's whole table
     dsa_select_blocks_counted = 0
     dsa_select_blocks_table = 0
+    # latent attention (a model with a latent pool; 0 otherwise), summed
+    # over the layers: a decode launch's live keys (each live row's
+    # context and itself: rows of the pool its walk reads), a chunk's
+    # (query, key) pairs (for each live query the keys it sees), and the
+    # context tokens multiplied by the up-projection (0 in the absorbed
+    # form, which expands nothing)
+    mla_keys_live = 0
+    mla_pairs = 0
+    mla_latents_expanded = 0
     # a model with a layer type per layer (0 otherwise): window-group
     # pages given back to the allocator before this launch and pages it
     # took (each logical page of a context once: what one table a slot
@@ -293,9 +307,16 @@ class DispatchRecord:
             **{f: getattr(self, f) for f in MOE_FIELDS},
             "sampler_rows_drawn": self.sampler_rows_drawn,
             "sampler_rows_filtered": self.sampler_rows_filtered,
-            **{f: getattr(self, f) for f in DSA_FIELDS},
+            **{f: getattr(self, f) for f in DSA_FIELDS + MLA_FIELDS},
             **{f: getattr(self, f) for f in KV_FIELDS},
         }
+
+    def note_latent(self, sees, layers: int) -> None:
+        """``sees``: for each live query of the launch the keys it sees
+        (positions 0..its own), an int array the host made from what it
+        hands the program; the launch's kind says which count they are."""
+        field = "mla_pairs" if self.kind == "prefill" else "mla_keys_live"
+        setattr(self, field, layers * int(sees.sum()))
 
     def note_selection(self, sees, topk: int, layers: int, steps,
                        table_blocks: int) -> None:

@@ -619,7 +619,15 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    launches, both 0 for a model with no indexer), and every launch
 #    record carries the same two for that launch — see
 #    ops/pallas/dsa_attention.py ``select_blocks``
-TELEMETRY_SCHEMA_VERSION = 19
+# 20: latent attention's work: engine stats() / the engine block of
+#    /metrics gain mla_keys_live (decode launches: each live row's
+#    context and itself), mla_pairs (prefill launches: for each live
+#    query the keys it sees) and mla_latents_expanded (context tokens
+#    multiplied by the up-projection; 0 in the absorbed form), summed over
+#    layers and launches, all 0 for a model without a latent pool, and
+#    every launch record carries the same three for that launch — see
+#    serving/loop_profiler.py ``MLA_FIELDS``
+TELEMETRY_SCHEMA_VERSION = 20
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -1,0 +1,430 @@
+"""kanana-2 (``model_type`` ``deepseek_v3``: latent attention over a pool
+of latents, a sigmoid router with a choice bias and a scale, a shared
+MLP, a leading dense layer), against the benchmark's plain reference.
+
+Seeded random weights, CPU, float32 on both sides, small size: 3 layers
+(one dense, two sparse), hidden 128, 4 heads of 16 + 8 (values of 16)
+over a latent of 32 (narrower than the heads' 4 x 32 of keys and
+values), 8 experts of 64 at 3 a token and one shared expert, contexts
+of 5 to 156 tokens over pages of 8 and chunks of 16.  The reference is
+the file the benchmark's probe loads (``benchmarks/reference/kanana.py``:
+the EXPANDED form only), loaded here by path; the engine attends in the
+absorbed form.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from megatron_llm_tpu.models import moe
+from megatron_llm_tpu.models import transformer as tfm
+from megatron_llm_tpu.models.kanana import KananaModel, kanana_config
+from megatron_llm_tpu.models.language_model import language_model_forward
+from megatron_llm_tpu.ops import paged_kv
+from megatron_llm_tpu.ops.pallas import paged_attention as pa
+from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
+                                      SamplingParams)
+
+REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks", "reference")
+
+# float32 on both sides, the same mathematics summed in another order
+# (and in another FORM: absorbed against expanded); every named fault
+# moves the logits by whole tenths
+LOGIT_TOL = 2e-4
+BS, CHUNK = 8, 16
+FAULTS = ("softmax_router", "bias_left_out", "bias_in_gates", "no_scale",
+          "no_shared", "dense_layer_sparse", "no_latent_norm",
+          "scale_sqrt_nope", "rope_key_per_head", "rope_whole_head",
+          "float8")
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _ref_cfg(cfg):
+    return {"num_hidden_layers": cfg.num_layers,
+            "num_attention_heads": cfg.num_attention_heads,
+            "rms_norm_eps": cfg.layernorm_epsilon,
+            "num_experts_per_tok": cfg.moe_top_k,
+            "norm_topk_prob": cfg.norm_topk_prob,
+            "vocab_size": cfg.padded_vocab_size,
+            "first_k_dense_replace": cfg.moe_first_dense_layers,
+            "qk_nope_head_dim": cfg.qk_nope_head_dim,
+            "qk_rope_head_dim": cfg.qk_rope_head_dim,
+            "v_head_dim": cfg.v_head_dim,
+            "rope_theta": cfg.rope_theta,
+            "routed_scaling_factor": cfg.moe_routed_scale}
+
+
+def _shake(params, key):
+    """Seeded N(0, 0.02) weights make attention nearly uniform and every
+    norm's scale is 1 at init: larger projections and scales that differ
+    (``tests/test_mellum.py::_shake`` says why)."""
+    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+    out = []
+    for i, (path, leaf) in enumerate(leaves):
+        names = [getattr(p, "key", None) for p in path]
+        if "scale" in names:
+            leaf = leaf + 0.3 * jax.random.normal(
+                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
+        elif {"kernel", "w_in", "w_out"} & set(names):
+            leaf = leaf * (2.0 if "router" in names else 6.0)
+        out.append(leaf)
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+@pytest.fixture(scope="module")
+def family():
+    model = KananaModel(kanana_config("tiny", use_flash_attn=False))
+    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
+    cfg = _ref_cfg(model.cfg)
+    weights = _load("kanana_from_program").ProgramWeights(params, cfg)
+    return model, params, _load("kanana"), weights, cfg
+
+
+def _tokens(n, seed=3, vocab=512):
+    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
+
+
+def _engine(model, params, **kw):
+    kw = dict(dict(num_slots=2, block_size=BS, max_model_len=192,
+                   prefill_chunk=CHUNK), **kw)
+    return InferenceEngine(model, params, EngineConfig(**kw))
+
+
+def _serve(eng, prompt, new):
+    req = eng.submit(prompt, SamplingParams(max_new_tokens=new,
+                                            temperature=0.0))
+    while req.finish_reason is None:
+        assert eng.step()
+        eng.blocks.check_invariants()
+    return req
+
+
+@pytest.mark.parametrize("n", [5, 16, 17, 70])
+def test_full_forward_matches_the_reference(family, n):
+    """The program's plain (cache-less) forward, the EXPANDED form, the
+    dense layer before a scan over the sparse ones: logits at every
+    position against the reference."""
+    model, params, ref, weights, cfg = family
+    toks = _tokens(n)
+    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
+                           train=False)[0])
+    want = np.asarray(ref.forward_logits(weights, cfg, toks))
+    assert want.std() > 0.1
+    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+
+
+def _tapped(eng):
+    """The engine's programs with their logits kept
+    (``tests/test_mellum.py::_tapped``)."""
+    got = {}
+    prefill, decode = eng._prefill_step, eng._decode_step
+
+    def tapped_prefill(params, pages, tokens, start, valid, table):
+        out = prefill(params, pages, tokens, start, valid, table)
+        got[int(start) + int(valid) - 1] = np.asarray(out[0])
+        return out
+
+    def tapped_decode(params, pages, last, ctx, tables, active, *rest):
+        caches = paged_kv.step_caches(pages, tables, ctx, active,
+                                      eng.paged_kernel)
+        logits, _ = language_model_forward(
+            params, last[:, None], ctx[:, None], None, eng.model.cfg,
+            rng_key=None, train=False, kv_caches=caches)
+        for s in np.flatnonzero(np.asarray(active) > 0):
+            got[int(np.asarray(ctx)[s])] = np.asarray(logits[s, 0])
+        return decode(params, pages, last, ctx, tables, active, *rest)
+
+    eng._prefill_step, eng._decode_step = tapped_prefill, tapped_decode
+    return got
+
+
+@pytest.mark.parametrize("prompt,new,kernel", [
+    (5, 14, "off"), (64, 10, "off"), (150, 6, "off"), (45, 5, "on")])
+def test_the_engine_over_the_latent_pool_matches_one_full_forward(
+        family, prompt, new, kernel, monkeypatch):
+    """Chunked prefill then decode through the engine's own programs over
+    the latent pool, the ABSORBED form, against the reference's ONE full
+    forward in the expanded form: contexts of a page to twenty pages and
+    one to ten chunks, through the dense gather and (``on``) through the
+    walk's kernels in interpret mode."""
+    model, params, ref, weights, cfg = family
+    if kernel == "on":
+        monkeypatch.setattr(pa, "_INTERPRET", True)
+    eng = _engine(model, params, paged_kernel=kernel, prefill_kernel=kernel)
+    assert eng.paged_kernel == ("pallas" if kernel == "on" else "xla")
+    got = _tapped(eng)
+    toks = _tokens(prompt, seed=5)
+    req = _serve(eng, toks, new)
+    seq = toks + list(req.out_tokens)
+    want = np.asarray(ref.forward_logits(weights, cfg, seq))
+    rows = sorted(got)
+    assert rows[-1] == prompt + new - 2 and prompt - 1 in rows
+    assert len(rows) == -(-prompt // CHUNK) + new - 1
+    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
+                               atol=LOGIT_TOL, rtol=0)
+    # greedy: the engine's tokens are the reference's choices
+    assert list(req.out_tokens) == [int(t) for t in
+                                    want[prompt - 1:-1].argmax(-1)]
+
+
+def test_absorbed_and_expanded_agree_on_one_layer(family):
+    """ONE function in two forms: a layer's attention over a chunk
+    through the latent pool (the up-projection folded into the queries
+    and applied to the output) and with no cache (every latent
+    expanded), to 1e-5 in float32."""
+    model, params = family[:2]
+    cfg = model.cfg
+    p = jax.tree_util.tree_map(lambda a: a[1],
+                               params["transformer"]["layers"]["attention"])
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 40, cfg.hidden_size))
+    kw = dict(freqs=None, attention_mask=None, position_ids=None,
+              dropout_key=None, train=False)
+    expanded = tfm.attention(x, p, cfg, **kw)
+    pools = paged_kv.init_pools(cfg, 8, BS)
+    cache = paged_kv.step_caches(
+        pools[:1], jnp.arange(1, 7, dtype=jnp.int32)[None],
+        jnp.zeros(1, jnp.int32), jnp.full(1, 40, jnp.int32), "xla")[0]
+    absorbed, after = tfm.attention(x, p, cfg, kv_cache=cache, **kw)
+    assert np.abs(np.asarray(expanded)).max() > 0.1
+    np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
+                               atol=1e-5, rtol=0)
+    # what a token left in the pool: its normed latent, its rotary key,
+    # zeros up to the lanes
+    row = np.asarray(after.pool["latent_pages"][1, 0])
+    assert row.shape == (128,) and np.abs(row[:40]).min() > 0
+    assert (row[40:] == 0).all()
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_each_named_fault_fails_by_many_tolerances(family, fault):
+    model, params, ref, weights, cfg = family
+    toks = _tokens(70, seed=5)
+    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
+                           train=False)[0])
+    faulty = np.asarray(ref.forward_logits(weights, cfg, toks,
+                                           faults={fault}))
+    apart = np.abs(got - faulty).max(axis=-1)
+    assert apart[8:].max() > 100 * LOGIT_TOL, apart.max()
+
+
+def test_the_drawn_bias_turns_more_than_one_choice_in_ten(family):
+    """A fresh model's choice bias is drawn wide enough that leaving it
+    out turns a choice for more than one token in ten, a layer."""
+    model, params, ref, weights, cfg = family
+    toks = _tokens(150, seed=9)
+    with_bias, without = [], []
+    ref.forward_logits(weights, cfg, toks, routing=with_bias)
+    ref.forward_logits(weights, cfg, toks, routing=without,
+                       faults={"bias_left_out"})
+    assert len(with_bias) == model.cfg.num_sparse_layers == 2
+    # at the first sparse layer the two runs route the same inputs
+    turned = (np.sort(with_bias[0][0], axis=1)
+              != np.sort(without[0][0], axis=1)).any(axis=1)
+    assert turned.mean() > 0.1, turned.mean()
+
+
+def test_a_slot_is_reused_after_a_long_request(family):
+    """A request of 150 + 6 tokens, then a short one in the same slot:
+    the second answers as the plain forward does, over pages the first
+    filled with other latents."""
+    model, params = family[:2]
+    eng = _engine(model, params, num_slots=1, prefix_cache=False)
+    _serve(eng, _tokens(150, seed=7), 6)
+    assert eng.stats()["blocks_in_use"] == 0
+    prompt = _tokens(40, seed=8)
+    second = _serve(eng, prompt, 5)
+    toks = list(prompt)
+    for _ in range(5):
+        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
+        toks.append(int(jnp.argmax(logits[0, -1])))
+    assert toks[len(prompt):] == list(second.out_tokens)
+
+
+def test_page_programs_carry_a_latent_page(family):
+    """A page of the pool is a page of its one array: copy-on-write and
+    the fetch / load pair move a token's latent and rotary key."""
+    cfg = family[0].cfg
+    pools = paged_kv.init_pools(cfg, 6, BS)
+    key = jax.random.PRNGKey(0)
+    pools = jax.tree_util.tree_map(
+        lambda a: jax.random.normal(key, a.shape, a.dtype), pools)
+    copied = paged_kv.copy_page(pools, 2, 4)
+    loaded = paged_kv.load_page(pools, paged_kv.fetch_page(pools, 2), 5)
+    for layer in range(cfg.num_layers):
+        src = np.asarray(pools[layer]["latent_pages"][2])
+        assert np.abs(src).max() > 0
+        assert (np.asarray(copied[layer]["latent_pages"][4]) == src).all()
+        assert (np.asarray(loaded[layer]["latent_pages"][5]) == src).all()
+
+
+def test_a_prefix_is_adopted_and_a_shared_page_copied_on_write(family):
+    """The prefix cache carries over unchanged: a second request with the
+    first one's prompt adopts its latent pages and answers alike; a
+    third that shares all but its last token writes into a shared page's
+    copy (copy-on-write) and answers as the plain forward does."""
+    model, params = family[:2]
+    eng = _engine(model, params, max_model_len=96)
+    prompt = _tokens(41, seed=11)
+    first = list(_serve(eng, prompt, 6).out_tokens)
+    assert list(_serve(eng, prompt, 6).out_tokens) == first
+    stats = eng.stats()
+    assert stats["prefill_tokens_cached"] >= 32
+    other = prompt[:40] + [(prompt[40] + 1) % 500 + 1]
+    third = list(_serve(eng, other, 4).out_tokens)
+    toks = list(other)
+    for _ in range(4):
+        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
+        toks.append(int(jnp.argmax(logits[0, -1])))
+    assert toks[len(other):] == third
+    assert eng.stats()["prefill_tokens_cached"] > stats[
+        "prefill_tokens_cached"]
+
+
+def test_a_leading_dense_layer_scans_serves_and_counts_sparse_layers_only(
+        family):
+    """The stack keeps the dense layer's parameters apart; the plain
+    forward scans the SPARSE layers (one scan of L - 1 steps); the
+    engine's routing record and counters have a row a sparse layer."""
+    model, params = family[:2]
+    cfg = model.cfg
+    stack = params["transformer"]
+    assert set(stack) == {"dense_layers", "layers", "final_norm"}
+    assert "experts" not in stack["dense_layers"]["mlp"]
+    assert stack["dense_layers"]["mlp"]["dense_h_to_4h"]["kernel"].shape == (
+        1, cfg.hidden_size, 2 * cfg.ffn_hidden_size)
+    assert stack["layers"]["mlp"]["experts"]["w_in"].shape[:2] == (2, 8)
+    assert stack["layers"]["mlp"]["shared"]["dense_h_to_4h"][
+        "kernel"].shape == (2, cfg.hidden_size, 2 * cfg.expert_hidden_size)
+    jaxpr = jax.make_jaxpr(lambda p, t: model(p, t, train=False))(
+        params, jnp.zeros((1, 8), jnp.int32))
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1 and scans[0].params["length"] == 2
+    specs = model.param_specs(params)
+    assert (jax.tree_util.tree_structure(
+        jax.tree_util.tree_map(lambda a: 0, params))
+        == jax.tree_util.tree_structure(jax.tree_util.tree_map(
+            lambda s: 0, specs, is_leaf=lambda s: isinstance(s, tuple))))
+    eng = _engine(model, params, prefix_cache=False)
+    _serve(eng, _tokens(20, seed=4), 3)
+    records = eng.loop_profiler.records()
+    for r in records:
+        assert r.moe_expert_slots == 2 * cfg.num_experts
+    # 20 prompt tokens and 2 decode steps, 3 experts a token, 2 layers
+    assert eng.stats()["moe_assignments"] == (20 + 2) * 3 * 2
+
+
+def test_the_engine_counts_latent_attentions_keys_and_pairs(family):
+    """``mla_keys_live`` on a decode launch's record (each live row's
+    context and itself), ``mla_pairs`` on a chunk's (for each live query
+    the keys it sees), both summed over the layers, from the arrays the
+    host hands the program; nothing is expanded in the absorbed form."""
+    model, params = family[:2]
+    L = model.cfg.num_layers
+    eng = _engine(model, params, prefix_cache=False)
+    _serve(eng, _tokens(20, seed=4), 4)
+    records = eng.loop_profiler.records()
+    chunks = [r for r in records if r.kind == "prefill"]
+    steps = [r for r in records if r.kind == "decode"]
+    assert [r.mla_pairs for r in chunks] == [
+        L * sum(range(1, 17)), L * sum(range(17, 21))]
+    assert [r.mla_keys_live for r in steps] == [L * 21, L * 22, L * 23]
+    assert all(r.mla_keys_live == 0 for r in chunks)
+    assert all(r.mla_pairs == 0 for r in steps)
+    stats = eng.stats()
+    assert stats["mla_pairs"] == L * sum(range(1, 21))
+    assert stats["mla_keys_live"] == L * 66
+    assert stats["mla_latents_expanded"] == 0
+    assert "mla_pairs" in chunks[0].as_dict()
+
+
+def test_a_token_holds_at_most_1280_bytes_a_layer_at_the_published_widths():
+    full = kanana_config("30B-A3B", num_layers=8)
+    pools = jax.eval_shape(lambda: paged_kv.init_pools(
+        full, 12289, 16, dtype=jnp.bfloat16))
+    assert [tuple(p) for p in pools] == [("latent_pages",)] * 8
+    assert pools[0]["latent_pages"].shape == (12289, 16, 640)
+    assert paged_kv.block_bytes(pools) == 8 * 16 * 1280
+    # 32 heads of keys of 192 and values of 128 would hold 16 times that
+    assert 32 * (192 + 128) * 2 == 16 * 1280
+
+
+def test_what_a_latent_pool_does_not_support_is_refused_by_name(
+        family, monkeypatch):
+    model, params = family[:2]
+    with pytest.raises(ValueError, match="int8 KV pool"):
+        paged_kv.init_pools(model.cfg, 4, BS, quantized=True)
+    for kw, what in ((dict(int8_kv_cache=True), "int8 KV pool"),
+                     (dict(speculative=True, draft_k=2), "speculative"),
+                     (dict(host_cache_bytes=1 << 20), "host KV tier")):
+        with pytest.raises(ValueError, match=what):
+            _engine(model, params, max_model_len=32, **kw)
+    with pytest.raises(ValueError, match="q_lora_rank"):
+        kanana_config("tiny", q_lora_rank=64)
+    with pytest.raises(ValueError, match="group-limited"):
+        kanana_config("tiny", moe_n_group=2)
+    with pytest.raises(ValueError, match="sliding window"):
+        kanana_config("tiny", sliding_window_size=16)
+    with pytest.raises(ValueError, match="softmax|sigmoid"):
+        kanana_config("tiny", moe_score_function="tanh")
+    p = jax.tree_util.tree_map(lambda a: a[0],
+                               params["transformer"]["layers"]["attention"])
+    with pytest.raises(NotImplementedError, match="legacy decode caches"):
+        tfm.attention(jnp.zeros((1, 1, 128)), p, model.cfg, freqs=None,
+                      attention_mask=None, position_ids=None,
+                      dropout_key=None, train=False,
+                      kv_cache={"k": None, "v": None, "index": 0})
+    from megatron_llm_tpu.models import gpt
+
+    monkeypatch.setattr(gpt, "_vocab_unsharded", lambda: False)
+    with pytest.raises(ValueError, match="tensor or pipeline"):
+        KananaModel(kanana_config("tiny"))
+
+
+def test_the_family_wrapper_asserts_its_flags():
+    cfg = kanana_config("tiny")
+    for bad in (dict(norm_topk_prob=False), dict(kv_lora_rank=None),
+                dict(moe_score_function="softmax"),
+                dict(moe_choice_bias=False), dict(moe_shared_experts=0)):
+        with pytest.raises(AssertionError):
+            KananaModel(cfg.replace(**bad))
+    full = kanana_config("30B-A3B")
+    assert (full.num_layers, full.hidden_size, full.num_attention_heads,
+            full.num_attention_heads_kv) == (48, 2048, 32, 32)
+    assert (full.kv_lora_rank, full.qk_nope_head_dim, full.qk_rope_head_dim,
+            full.qk_head_dim, full.v_head_dim) == (512, 128, 64, 192, 128)
+    assert (full.num_experts, full.moe_top_k, full.expert_hidden_size,
+            full.ffn_hidden_size, full.moe_shared_experts,
+            full.moe_first_dense_layers) == (128, 6, 768, 6144, 2, 1)
+    assert (full.moe_routed_scale, full.moe_score_function,
+            full.rope_theta) == (2.448, "sigmoid", 1e6)
+    assert full.padded_vocab_size == 128256
+    # a layer's parameters: attention 26.35 M; the dense MLP 37.75 M; a
+    # sparse MLP's router 0.26 M (and 128 of bias), shared 9.44 M,
+    # experts 604.0 M
+    dense, sparse = (jax.eval_shape(
+        lambda k, s=s: tfm.init_layer_params(k, full, jnp.bfloat16,
+                                             sparse=s),
+        jax.random.PRNGKey(0)) for s in (False, True))
+
+    def size(tree):
+        return sum(x.size for x in jax.tree_util.tree_leaves(tree))
+
+    assert size(dense["attention"]) == size(sparse["attention"]) == (
+        2048 * 6144 + 2048 * 576 + 512 + 512 * 8192 + 4096 * 2048)
+    assert size(dense["mlp"]) == 3 * 2048 * 6144
+    assert size(sparse["mlp"]["router"]) == 2048 * 128 + 128
+    assert size(sparse["mlp"]["shared"]) == 3 * 2048 * 1536
+    assert size(sparse["mlp"]["experts"]) == 128 * 3 * 2048 * 768
+    assert moe._CHOICE_BIAS_STD > 0

@@ -573,6 +573,9 @@ KERNEL_NAMES = {
     # the same walk launched for the window group of a model with a layer
     # type per layer (PR 32), which has no int8 pool
     "paged_attention_decode_window", "paged_attention_prefill_window",
+    # the same walk over a latent pool (PR 36), which has neither an
+    # int8 pool nor a window group
+    "mla_attention_decode", "mla_attention_prefill",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -618,7 +621,8 @@ def test_every_kernel_and_program_carries_its_stable_name():
                     and node.func.id == "_walk_call"):
                 kw = {k.arg: k.value for k in node.keywords}
                 names |= {kw["name"].value + suffix
-                          for suffix in ("", "_quant", "_window")}
+                          for suffix in (("",) if "value_width" in kw else
+                                         ("", "_quant", "_window"))}
             # the sparse-attention kernels take theirs from the one entry
             # that calls them for the decode step and for a chunk
             if (isinstance(node, ast.Call)

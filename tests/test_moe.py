@@ -243,3 +243,106 @@ def test_zero1_shards_moe_expert_state(utils):
         assert "dp" in jax.tree_util.tree_leaves(list(w_in_spec)), w_in_spec
     finally:
         topology.destroy_model_parallel()
+
+
+# ---------------------------------------------------------------------------
+# the router's other forms: sigmoid scores, a choice bias, a scale; the
+# shared MLP
+# ---------------------------------------------------------------------------
+
+def _hand_router(bias, **kw):
+    """One token whose four router logits are given: the router's kernel
+    is the identity on the token's first four values."""
+    cfg = _cfg(num_experts=4, moe_top_k=2, hidden_size=4,
+               num_attention_heads=1, ffn_hidden_size=8,
+               moe_score_function="sigmoid", moe_choice_bias=bias is not None,
+               **kw)
+    p = {"router": {"kernel": jnp.eye(4)}}
+    if bias is not None:
+        p["router"]["choice_bias"] = jnp.asarray(bias, jnp.float32)
+    return cfg, p
+
+
+def test_sigmoid_choice_the_bias_turns_the_choice_and_not_the_gate():
+    """A hand-worked token: logits (2, 1, 0, -1), scores sigmoid of them
+    (0.881, 0.731, 0.5, 0.269).  Without a bias experts 0 and 1 are
+    chosen.  A bias of +0.5 on expert 3 lifts it to 0.769, over expert 1:
+    experts 0 and 3 are chosen, and the gates are their SCORES, 0.881 and
+    0.269 (not 0.769), renormalised to 0.766 and 0.234."""
+    from megatron_llm_tpu.models.moe import _route
+
+    x = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    s = 1 / (1 + np.exp(-np.asarray([2.0, 1.0, 0.0, -1.0])))
+    cfg, p = _hand_router(None)
+    _, probs, gates, idx = _route(x, p, cfg)
+    np.testing.assert_allclose(np.asarray(probs[0]), s, rtol=1e-6)
+    assert np.asarray(idx[0]).tolist() == [0, 1]
+    np.testing.assert_allclose(np.asarray(gates[0]), s[:2] / s[:2].sum(),
+                               rtol=1e-6)
+    cfg, p = _hand_router([0.0, 0.0, 0.0, 0.5])
+    _, _, gates, idx = _route(x, p, cfg)
+    assert np.asarray(idx[0]).tolist() == [0, 3]
+    np.testing.assert_allclose(np.asarray(gates[0]),
+                               s[[0, 3]] / s[[0, 3]].sum(), rtol=1e-6)
+    assert abs(float(gates[0, 1]) - 0.2339) < 1e-3
+    # as the scores give them where the family does not renormalise
+    cfg, p = _hand_router([0.0, 0.0, 0.0, 0.5], norm_topk_prob=False)
+    np.testing.assert_allclose(np.asarray(_route(x, p, cfg)[2][0]),
+                               s[[0, 3]], rtol=1e-6)
+
+
+def test_the_routed_scale_multiplies_the_renormalised_gates():
+    from megatron_llm_tpu.models.moe import _route
+
+    x = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    cfg, p = _hand_router([0.0, 0.0, 0.0, 0.5], moe_routed_scale=2.448)
+    gates = np.asarray(_route(x, p, cfg)[2][0])
+    np.testing.assert_allclose(gates.sum(), 2.448, rtol=1e-6)
+    np.testing.assert_allclose(gates[1] / gates[0], 0.26894 / 0.88080,
+                               rtol=1e-4)
+
+
+def test_a_softmax_models_routing_is_bit_for_bit_what_it_was():
+    """A model that sets none of the new fields routes by the lines it
+    always did: the softmax over all experts, its ``top_k`` largest,
+    renormalised; and its parameters hold no bias and no shared MLP."""
+    from megatron_llm_tpu.models.moe import _route
+
+    cfg = _cfg()
+    p = init_moe_mlp_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    assert set(p) == {"router", "experts"} and set(p["router"]) == {"kernel"}
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
+    logits, probs, gates, idx = _route(x, p, cfg)
+    want_probs = jax.nn.softmax(
+        jnp.einsum("...h,he->...e", x, p["router"]["kernel"]), axis=-1)
+    want_gates, want_idx = jax.lax.top_k(want_probs, 2)
+    want_gates = want_gates / jnp.maximum(
+        jnp.sum(want_gates, axis=-1, keepdims=True), 1e-9)
+    assert (np.asarray(probs) == np.asarray(want_probs)).all()
+    assert (np.asarray(idx) == np.asarray(want_idx)).all()
+    assert (np.asarray(gates) == np.asarray(want_gates)).all()
+
+
+@pytest.mark.parametrize("train", [False, True])
+def test_the_shared_mlp_is_counted_once(train):
+    """With ``moe_shared_experts`` the layer's output is the routed sum
+    plus ONE ungated MLP of that many experts' width over every token, on
+    the dropless path and on the capacity path alike."""
+    from megatron_llm_tpu.models.moe import moe_mlp_dropless
+
+    cfg = _cfg(moe_shared_experts=2)
+    p = init_moe_mlp_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    assert p["shared"]["dense_h_to_4h"]["kernel"].shape == (32, 2 * 2 * 64)
+    assert p["shared"]["dense_4h_to_h"]["kernel"].shape == (2 * 64, 32)
+    p["shared"] = jax.tree_util.tree_map(lambda w: w * 20.0, p["shared"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
+    run = ((lambda q, c: moe_mlp(x, q, c)[0]) if train else
+           (lambda q, c: moe_mlp_dropless(x, q, c)[0]))
+    routed = run({k: v for k, v in p.items() if k != "shared"},
+                 cfg.replace(moe_shared_experts=0))
+    shared = dense_mlp(x, p["shared"], cfg)
+    assert np.abs(np.asarray(shared)).max() > 0.05
+    np.testing.assert_allclose(np.asarray(run(p, cfg)),
+                               np.asarray(routed + shared), atol=1e-5)
+    specs = moe_mlp_specs(p, stacked=False, cfg=cfg)
+    assert specs["shared"]["dense_h_to_4h"]["kernel"] == (None, "ffn")

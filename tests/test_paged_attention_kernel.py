@@ -928,3 +928,103 @@ def test_prefill_q_block_is_held_to_the_scratch_it_needs():
     finally:
         pa._walk_call = real
     assert seen == [64, 64, 64, 5, 128]
+
+
+# ---------------------------------------------------------------------------
+# a latent pool: one array of rows, every head attends the row and takes
+# its first columns for values
+# ---------------------------------------------------------------------------
+
+def _latent_case(rng, lens, M, W=24, nh=3, C=1):
+    """Rows [S, M*BS, W] scattered into a pool [P, BS, W] through ragged
+    tables, garbage in every page nobody owns; queries [S, C, nh, W]."""
+    S = len(lens)
+    rows = rng.standard_normal((S, M * BS, W)).astype(np.float32)
+    pages = (rng.standard_normal((1 + S * M, BS, W)) * 100.0).astype(
+        np.float32)
+    bt = np.zeros((S, M), np.int32)
+    nxt = 1
+    for s in range(S):
+        for j in range((int(lens[s]) + C - 1) // BS + 1):
+            bt[s, j] = nxt
+            pages[nxt] = rows[s, j * BS:(j + 1) * BS]
+            nxt += 1
+    q = rng.standard_normal((S, C, nh, W)).astype(np.float32)
+    return q, rows, pages, bt
+
+
+def _latent_oracle(q, rows, lens, scale, dv):
+    """Per-(slot, row, head) softmax over the rows up to the query's own
+    position, values the rows' first ``dv`` columns."""
+    S, C, nh, _ = q.shape
+    out = np.zeros((S, C, nh, dv), np.float32)
+    for s in range(S):
+        for j in range(C):
+            keys = rows[s, :lens[s] + j + 1]
+            for h in range(nh):
+                sc = keys @ q[s, j, h] * scale
+                p = np.exp(sc - sc.max())
+                out[s, j, h] = (p / p.sum()) @ keys[:, :dv]
+    return out
+
+
+@pytest.mark.parametrize("blocks", ["one_block", "two_page_blocks"])
+def test_latent_decode_matches_oracle_and_reference(blocks, monkeypatch):
+    """The walk over a latent pool: ONE array fetched, scores over a
+    row's whole width and values its first columns, over ragged contexts
+    and (two pages a block) across block boundaries; a slot that is not
+    decoding reads nothing."""
+    if blocks == "two_page_blocks":
+        monkeypatch.setattr(pa, "_BLOCK_TOKENS", 2 * BS)
+    lens = np.asarray([0, 5, 17, 37], np.int32)
+    q, rows, pages, bt = _latent_case(np.random.default_rng(0), lens, 6)
+    want = _latent_oracle(q, rows, lens, 0.3, 16)[:, 0]
+    args = [jnp.asarray(a) for a in (q[:, 0], pages, bt, lens)]
+    got = pa.latent_attention_decode(*args, value_width=16,
+                                     softmax_scale=0.3)
+    assert got.shape == (4, 3, 16)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
+    dense = pa.dense_latent_attention(jnp.asarray(q), *args[1:], None, 0.3,
+                                      16)[:, 0]
+    np.testing.assert_allclose(np.asarray(dense), want, atol=2e-5, rtol=1e-5)
+    active = jnp.asarray([1, 0, 1, 0], jnp.int32)
+    some = np.asarray(pa.latent_attention_decode(
+        *args, valid_lens=active, value_width=16, softmax_scale=0.3))
+    np.testing.assert_allclose(some[[0, 2]], want[[0, 2]], atol=2e-5,
+                               rtol=1e-5)
+    assert (some[[1, 3]] == 0).all()
+
+
+@pytest.mark.parametrize("block_q", [None, 4])
+def test_latent_prefill_chunk_matches_oracle(block_q, two_page_blocks):
+    """A chunk of 8 rows a slot over histories of 0 to 29 tokens: causal
+    within the chunk on top of the paged history, q-blocks of the whole
+    chunk and of 4 rows."""
+    lens = np.asarray([0, 3, 16, 29], np.int32)
+    q, rows, pages, bt = _latent_case(np.random.default_rng(1), lens, 6,
+                                      C=8)
+    want = _latent_oracle(q, rows, lens, 0.25, 16)
+    got = pa.latent_attention_prefill(
+        *(jnp.asarray(a) for a in (q, pages, bt, lens)), value_width=16,
+        softmax_scale=0.25, block_q=block_q)
+    assert got.shape == (4, 8, 3, 16)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
+
+
+def test_latent_walk_in_bf16_multiplies_the_pool_as_it_lies():
+    """bf16 queries over a bf16 pool: the products are the pool's own
+    dtype on a chunk too, the probabilities rounded to it for the second
+    product; within bf16's rounding of the float32 oracle."""
+    lens = np.asarray([21], np.int32)
+    q, rows, pages, bt = _latent_case(np.random.default_rng(2), lens, 5,
+                                      C=8)
+    q16, p16 = (jnp.asarray(a, jnp.bfloat16) for a in (q, pages))
+    want = _latent_oracle(
+        np.asarray(q16, np.float32),
+        np.asarray(jnp.asarray(rows, jnp.bfloat16), np.float32), lens, 0.25,
+        16)
+    got = pa.latent_attention_prefill(
+        q16, p16, jnp.asarray(bt), jnp.asarray(lens), value_width=16,
+        softmax_scale=0.25)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=0.03)

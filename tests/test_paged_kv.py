@@ -259,3 +259,80 @@ def test_a_one_type_window_model_allocates_attends_and_frees_as_before():
                                   np.zeros(2, np.int32), np.ones(2, np.int32),
                                   "xla", eng._layer_groups)
     assert {c.group for c in caches} == {paged_kv.FULL}
+
+
+# ---------------------------------------------------------------------------
+# a latent pool: one array a layer, a token's latent and rotary key
+# ---------------------------------------------------------------------------
+
+def _kanana_cfg():
+    from megatron_llm_tpu.models.kanana import kanana_config
+
+    return kanana_config("tiny", use_flash_attn=False)
+
+
+def test_a_latent_pool_is_one_array_of_rows_a_layer():
+    """``init_pools`` for a model with latent attention: ONE array a
+    layer, a row the latent and the rotary key filled up to whole lanes
+    (32 + 8 -> 128), whatever the KV head count; ``block_bytes`` and
+    ``array_shapes`` follow from the array; its name occurs in the one
+    module that owns the pool; the int8 pool is refused; a model without
+    ``kv_lora_rank`` has the pools it always had."""
+    import jax.numpy as jnp
+
+    cfg = _kanana_cfg()
+    assert paged_kv.latent_width(cfg) == 128
+    pools = paged_kv.init_pools(cfg, 5, 8, dtype=jnp.bfloat16)
+    assert len(pools) == cfg.num_layers
+    assert {k: (v.shape, v.dtype.name) for k, v in pools[0].items()} == {
+        "latent_pages": ((5, 8, 128), "bfloat16")}
+    assert paged_kv.block_bytes(pools) == cfg.num_layers * 8 * 128 * 2
+    assert paged_kv.array_shapes(pools) == {
+        ("bfloat16", (5, 8, 128)), ("bfloat16", (40, 128))}
+    assert _grep(r"[\"']latent_pages", "megatron_llm_tpu") == [
+        "megatron_llm_tpu/ops/paged_kv.py"]
+    with pytest.raises(ValueError, match="int8 KV pool"):
+        paged_kv.init_pools(cfg, 5, 8, quantized=True)
+    dense = paged_kv.init_pools(llama_config("tiny"), 5, 8)
+    assert set(dense[0]) == {"k_pages", "v_pages"}
+    assert paged_kv.layer_groups(cfg) is None
+
+
+@pytest.mark.parametrize("n,ctx", [(1, 11), (6, 5)])
+def test_attend_latent_writes_a_row_and_reads_it_back(n, ctx):
+    """``attend_latent`` writes ``[latent ; rotary key ; zeros]`` at the
+    row's next positions of its table and attends the absorbed queries
+    over history and chunk; rows that are not live go to the garbage
+    page; the context comes back in the latent."""
+    import jax.numpy as jnp
+
+    cfg = _kanana_cfg()
+    r, dr, nh = cfg.kv_lora_rank, cfg.qk_rope_head_dim, 4
+    rng = np.random.default_rng(0)
+    pool = {"latent_pages": jnp.asarray(
+        rng.standard_normal((6, 8, 128)), jnp.float32)}
+    tables = jnp.asarray([[3, 1, 4, 0]], jnp.int32)
+    cache = paged_kv.PagedKVCache(
+        pool, tables, jnp.asarray([ctx], jnp.int32),
+        jnp.asarray([n], jnp.int32), kernel="xla")
+    lat, rope = (jnp.asarray(rng.standard_normal((1, n, w)), jnp.float32)
+                 for w in (r, dr))
+    ql, qr = (jnp.asarray(rng.standard_normal((1, n, nh, w)), jnp.float32)
+              for w in (r, dr))
+    out, after = cache.attend_latent(ql, qr, lat, rope, 0.2)
+    assert out.shape == (1, n, nh, r)
+    assert int(after.context_lens[0]) == ctx + n
+    pages = np.asarray(after.pool["latent_pages"])
+    order = np.asarray(tables[0])
+    for j in range(n):
+        page, at = order[(ctx + j) // 8], (ctx + j) % 8
+        np.testing.assert_array_equal(pages[page, at, :r], lat[0, j])
+        np.testing.assert_array_equal(pages[page, at, r:r + dr], rope[0, j])
+        assert (pages[page, at, r + dr:] == 0).all()
+    # the last query against every key up to itself, by hand
+    keys = pages[order].reshape(-1, 128)[:ctx + n]
+    q = np.concatenate([ql[0, -1], qr[0, -1]], axis=-1)      # [nh, r + dr]
+    sc = q @ keys[:, :r + dr].T * 0.2
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    want = (p / p.sum(-1, keepdims=True)) @ keys[:, :r]
+    np.testing.assert_allclose(np.asarray(out[0, -1]), want, atol=1e-5)

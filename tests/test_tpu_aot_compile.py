@@ -161,7 +161,30 @@ def _paged_window(q_tokens, slots, tokens=33792, page=16):
                 ((slots, tokens // page), jnp.int32), ((slots,), jnp.int32)]
 
 
+def _latent(q_tokens, slots, tokens=32768, page=16):
+    """Kanana's latent pool at the cell's shapes: 32 absorbed query heads
+    over rows of 640 (a latent of 512, a rotary key of 64, zeros) whose
+    first 512 columns are the values, 16 slots of up to 32,768 tokens (a
+    table of 2,048 entries) over 12,289 pages; the decode step and the
+    [1, 512] chunk."""
+    from megatron_llm_tpu.ops.pallas import paged_attention as pa
+
+    def fn(q, pages, tables, lens, valid):
+        kw = dict(valid_lens=valid, value_width=512, softmax_scale=192 ** -0.5)
+        if q_tokens == 1:
+            return pa.latent_attention_decode(q[:, 0], pages, tables, lens,
+                                              **kw)
+        return pa.latent_attention_prefill(q, pages, tables, lens, **kw)
+
+    return fn, [((slots, q_tokens, 32, 640), BF16),
+                ((12289, page, 640), BF16),
+                ((slots, tokens // page), jnp.int32), ((slots,), jnp.int32),
+                ((slots,), jnp.int32)]
+
+
 CASES = {
+    "latent_decode_16_slots": lambda: _latent(1, 16),
+    "latent_prefill_chunk_512": lambda: _latent(512, 1),
     "paged_window_decode_32_slots": lambda: _paged_window(1, 32),
     "paged_window_prefill_chunk_512": lambda: _paged_window(512, 1),
     "moe_experts_mellum_32_rows": lambda: _experts(32 * 8, 64, 2304, 896),
