@@ -393,12 +393,22 @@ def latent_attention(
       ``mla_expand``) and heads of ``nope + rope`` attend values of
       ``v_head_dim`` through XLA (``core_attention``, or the q-chunked
       form at long sequences; the values ride at the keys' width).
-    * **Absorbed** (a ``PagedKVCache``: every engine program): ``kv_up``'s
-      key half is folded into the query and its value half is applied to
-      the attention's output (scope ``mla_absorb``), so the cache is read
-      as it lies: all heads attend ONE key ``[c ; k_rope]`` a token whose
-      first ``kv_lora_rank`` values are also its value
-      (``PagedKVCache.attend_latent``).
+      A ``PagedKVCache``'s CHUNK on the kernel path is expanded too,
+      but inside the kernel (``mla_attention_prefill``: each block of the
+      pool's latents meets each head's slice of ``kv_up`` in VMEM and
+      the head's ``[q_nope ; q_rope]`` attends keys of ``nope + rope``
+      and values of ``v_head_dim`` that never reach HBM; half the
+      absorbed chunk's operations at a chunk of 512, fewer from about
+      170 live rows on).  ``PagedKVCache.expands_latents`` decides, by
+      the query length and the path.
+    * **Absorbed** (a ``PagedKVCache``'s decode step,
+      ``mla_attention_decode``, and the dense fallback, a chunk's too):
+      ``kv_up``'s key half is folded into the query and its value half
+      is applied to the attention's output (scope ``mla_absorb``), so
+      the cache is read as it lies: all heads attend ONE key ``[c ;
+      k_rope]`` a token whose first ``kv_lora_rank`` values are also its
+      value (``PagedKVCache.attend_latent``); bytes bound a step, and
+      expanding would read no fewer.
 
     The legacy decode caches (contiguous, rolling, int8) are refused."""
     b, s, _ = x.shape
@@ -428,12 +438,17 @@ def latent_attention(
 
     new_cache = None
     if kv_cache is not None:
-        with jax.named_scope("mla_absorb"):
-            q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_up[..., :dn])
-        ctx, new_cache = kv_cache.attend_latent(
-            q_lat, q_rope, c, k_rope[:, :, 0], 1.0 / math.sqrt(dn + dr))
-        with jax.named_scope("mla_absorb"):
-            ctx = jnp.einsum("bsnr,rnd->bsnd", ctx, w_up[..., dn:])
+        scale = 1.0 / math.sqrt(dn + dr)
+        if kv_cache.expands_latents(s):
+            ctx, new_cache = kv_cache.attend_latent(
+                q_nope, q_rope, c, k_rope[:, :, 0], scale, kv_up=w_up)
+        else:
+            with jax.named_scope("mla_absorb"):
+                q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_up[..., :dn])
+            ctx, new_cache = kv_cache.attend_latent(
+                q_lat, q_rope, c, k_rope[:, :, 0], scale)
+            with jax.named_scope("mla_absorb"):
+                ctx = jnp.einsum("bsnr,rnd->bsnd", ctx, w_up[..., dn:])
     else:
         with jax.named_scope("mla_expand"):
             up = jnp.einsum("bsr,rnd->bsnd", c, w_up)

@@ -19,7 +19,9 @@ shares, then zeros up to whole lanes (512 + 64 -> 640 at the published
 widths: 1,280 B a token a layer in bf16 where 32 heads of keys of 192
 and values of 128 would hold 20,480), and the row is the key AND, in its
 first ``kv_lora_rank`` columns, the value of all the absorbed query
-heads (``attend_latent``).  Whatever a pool holds, a page
+heads of a decode step, while a chunk's kernel expands each block of
+rows into per-head keys and values in VMEM (``attend_latent``,
+``expands_latents``).  Whatever a pool holds, a page
 of it is a page of every array: the page programs below are
 ``tree_map``s, so copy-on-write, the prefix cache's adoption and the
 host tier carry a page's indexer keys with its keys and values.  Block 0 is the
@@ -90,6 +92,17 @@ def resolve_kernel(requested: str, one_device: bool) -> str:
             and (requested == "on" or one_device)):
         return "pallas"
     return "xla"
+
+
+def expands_latents(kernel: str, n: int) -> bool:
+    """Whether a latent pool's read of ``n`` queries a row on the resolved
+    path ``kernel`` is in the EXPANDED form: a chunk (``n > 1``) on the
+    kernel path (``mla_attention_prefill``).  A decode step and the dense
+    fallback are absorbed.  The cache asks it to pick the read and the
+    engine to count what its launches expand."""
+    from megatron_llm_tpu.ops.pallas import paged_attention as _pa
+
+    return n > 1 and kernel == "pallas" and _pa.kernel_available()
 
 
 FULL, WINDOW = "full", "window"
@@ -333,36 +346,55 @@ class PagedKVCache:
                 pool[name] = flat.at[dest].set(val).reshape(a.shape)
         return pool
 
-    def attend_latent(self, q_latent: jax.Array, q_rope: jax.Array,
-                      latent: jax.Array, k_rope: jax.Array, scale: float):
+    def expands_latents(self, n: int) -> bool:
+        """Whether ``attend_latent`` reads ``n`` queries a row in the
+        EXPANDED form (:func:`expands_latents`)."""
+        return expands_latents(self.kernel, n)
+
+    def attend_latent(self, q_nope: jax.Array, q_rope: jax.Array,
+                      latent: jax.Array, k_rope: jax.Array, scale: float,
+                      kv_up: Optional[jax.Array] = None):
         """A latent pool's ``attend``: write this call's rows
         ``[latent [b, n, r] ; k_rope [b, n, dr] ; zeros]`` at
-        ``context_lens ..``, then attend the absorbed queries
-        ``[q_latent [b, n, nh, r] ; q_rope [b, n, nh, dr]]`` over the
-        row's history and the chunk's own causal prefix: one key a token
-        for every head, its first ``r`` columns the value.  Returns the
-        context IN THE LATENT ``[b, n, nh, r]`` (the caller applies the
-        value half of the up-projection) and the cache as the step
-        leaves it.  A decode step (``n == 1``) launches
-        ``mla_attention_decode``, a chunk ``mla_attention_prefill``; each
-        reads a live page once."""
+        ``context_lens ..``, then attend over the row's history and the
+        chunk's own causal prefix, in one of two forms (the caller asks
+        ``expands_latents`` which):
+
+        * ABSORBED (``kv_up`` None: a decode step, ``mla_attention_decode``,
+          and the dense fallback): ``q_nope`` is the query with the
+          up-projection's key half folded in ``[b, n, nh, r]``; every
+          head attends ONE key a token, the row, whose first ``r`` columns
+          are also its value, and the context comes back IN THE LATENT
+          ``[b, n, nh, r]`` (the caller applies the value half).
+        * EXPANDED (``kv_up`` [r, nh, dn + dv]: a chunk on the kernel
+          path, ``mla_attention_prefill``): ``q_nope`` [b, n, nh, dn] as
+          the model made it; the kernel expands each block of latents
+          into per-head keys and values in VMEM and the context comes
+          back per head ``[b, n, nh, dv]``.
+
+        Either reads a live page once (a head group).  Returns the context
+        and the cache as the step leaves it."""
         from megatron_llm_tpu.ops.pallas import paged_attention as _pa
 
         pages = self.pool["latent_pages"]
         n, r, W = latent.shape[1], latent.shape[2], pages.shape[-1]
+        assert (kv_up is not None) == self.expands_latents(n)
         row = _to_width(jnp.concatenate([latent, k_rope], axis=-1), W)
         pool = self._write({"latent_pages": row.astype(pages.dtype)}, n)
-        q = _to_width(jnp.concatenate([q_latent, q_rope], axis=-1), W)
-        kw = dict(valid_lens=self.valid_lens, value_width=r,
-                  softmax_scale=scale)
         args = (pool["latent_pages"], self.block_tables, self.context_lens)
-        if self.kernel != "pallas":
-            ctx = _pa.dense_latent_attention(
-                q, *args, self.valid_lens, scale, r)
-        elif n == 1:
-            ctx = _pa.latent_attention_decode(q[:, 0], *args, **kw)[:, None]
+        if kv_up is not None:
+            ctx = _pa.latent_attention_prefill(
+                q_nope, q_rope, kv_up, *args, valid_lens=self.valid_lens,
+                softmax_scale=scale)
         else:
-            ctx = _pa.latent_attention_prefill(q, *args, **kw)
+            q = _to_width(jnp.concatenate([q_nope, q_rope], axis=-1), W)
+            if self.kernel != "pallas" or n > 1:
+                ctx = _pa.dense_latent_attention(
+                    q, *args, self.valid_lens, scale, r)
+            else:
+                ctx = _pa.latent_attention_decode(
+                    q[:, 0], *args, valid_lens=self.valid_lens,
+                    value_width=r, softmax_scale=scale)[:, None]
         return ctx, dataclasses.replace(
             self, pool=pool, context_lens=self.context_lens + self.valid_lens)
 

@@ -67,14 +67,25 @@ context (76 / 123 / 560 / 1,076 before).
 
 A LATENT pool (``ops/paged_kv.py``: one array ``[P, bs, W]`` a layer,
 a token's row its normed latent, then the one rotary key, then zeros up
-to the lanes) is read by the same walk (``latent_attention_decode`` /
-``latent_attention_prefill``, launched as ``mla_attention_decode`` /
-``mla_attention_prefill``): one kv group whose key is the whole row and
+to the lanes) has two reads.  A decode step takes the walk above in the
+ABSORBED form (``latent_attention_decode``, launched as
+``mla_attention_decode``): one kv group whose key is the whole row and
 whose value is the row's first ``value_width`` columns, so a page is
 fetched ONCE for scores and values, and every query head (absorbed
-queries of the row's width) attends it.  Its products are in the pool's
-dtype on a chunk too (fp32 accumulation; the probabilities are rounded
-to the pool's dtype for the second product, as a flash kernel's are).
+queries of the row's width) attends it; bytes bound it.  A chunk takes a
+walk of its own in the EXPANDED form (``latent_attention_prefill``,
+launched as ``mla_attention_prefill``; ``_chunk_body``): the same
+prefetched table and double-buffered blocks of live pages, but each
+block of latents is multiplied by each head's slice of the
+up-projection IN VMEM and the head's queries ``[q_nope ; q_rope]``
+attend per-head keys ``[k_nope ; the row's rotary key]`` and values that
+never reach HBM.  The whole chunk is one q-block, so a context token is
+expanded once a chunk a head: 2 x 32 x (192 + 128) operations a (query,
+key) pair and 2 x 512 x 8,192 a context token, 36,864 a pair at a chunk
+of 512 where the absorbed chunk multiplies 73,728; below about 170 live
+rows a chunk the absorbed form would do fewer.  Products take the pool's
+dtype with fp32 accumulation; the expanded keys and values and the
+probabilities are rounded to it between them, as a flash kernel's are.
 
 Dispatch mirrors ``flash_attention.py``: TPU backend -> kernel;
 otherwise -> the dense reference.  Interpret-mode tests run the kernel
@@ -227,9 +238,9 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
     """One (slot, q-block): walk pages ``first .. last`` of the slot's
     table in blocks of ``kp`` pages, block j+1 on its way from HBM while
     block j is computed.  Nothing of the table outside that range is
-    read.  ``value_width``: the pool is a latent one, ONE array of pages
-    ``[bs, W]`` whose rows are the keys of one kv group and whose first
-    ``value_width`` columns are the values."""
+    read.  ``value_width`` (a decode step's only): the pool is a latent
+    one, ONE array of pages ``[bs, W]`` whose rows are the keys of one kv
+    group and whose first ``value_width`` columns are the values."""
     latent = value_width is not None
     n_pool = 1 if latent else 4 if quantized else 2
     hbm = refs[:n_pool]                   # K, V[, K scales, V scales]
@@ -290,8 +301,7 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
 
     # bf16 queries on bf16 pools multiply as they are (a product of two
     # bf16 is exact in the fp32 accumulator); anything else goes to fp32
-    native = ((bq == 1 or latent) and not quantized
-              and bufs[0].dtype == q_ref.dtype)
+    native = bq == 1 and not quantized and bufs[0].dtype == q_ref.dtype
     q = q_ref[0] if native else q_ref[0].astype(jnp.float32)  # [bq, nh, d]
 
     def dequantized(x, sc):
@@ -354,7 +364,7 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
             return carry
         # a chunk: the rows of one kv group [R, d] against that group's
         # keys [T, d]; flat row r is chunk row r // qpg, head r % qpg
-        k, v = k.reshape(T, g, d), v.reshape(T, g, v.shape[-1])
+        k, v = k.reshape(T, g, d), v.reshape(T, g, d)
         valid = _valid_keys(base + iota((R, T), 1),
                             ctx + q0 + jax.lax.div(iota((R, T), 0), qpg),
                             window)
@@ -373,8 +383,7 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
     else:
         outs = [_softmax_finish(l_scr, acc_scr,
                                 slice(grp * R, (grp + 1) * R)
-                                ).reshape(bq, qpg, acc_scr.shape[-1])
-                for grp in range(g)]
+                                ).reshape(bq, qpg, d) for grp in range(g)]
         out = outs[0] if g == 1 else jnp.concatenate(outs, axis=1)
     o_ref[0] = out.astype(o_ref.dtype)                  # [bq, nh, d]
 
@@ -387,8 +396,9 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
     is the kernel's name in a profile (``name_suffix``, the caller's
     ``_window`` for a window group's walk, and ``_quant`` for the int8
     pools appended); ``valid_lens`` None = every slot has tokens in this
-    call.  ``value_width``: ``k_pages`` is a latent pool ``[P, bs, W]``
-    (``v_pages`` None) and the output is ``[S, C, nh, value_width]``."""
+    call.  ``value_width`` (with ``block_q`` 1: the absorbed decode step):
+    ``k_pages`` is a latent pool ``[P, bs, W]`` (``v_pages`` None) and the
+    output is ``[S, C, nh, value_width]``."""
     if valid_lens is None:
         valid_lens = jnp.ones_like(context_lens)
     S, C, nh, d = q.shape
@@ -538,16 +548,11 @@ def paged_attention_prefill(
         block_q=bq, name="paged_attention_prefill", name_suffix=name_suffix)
 
 
-# a latent chunk's q-block: (row, head) pairs, fewer than a chunk of
-# per-head keys takes because the fp32 accumulator is a latent wide
-_LATENT_BLOCK_ROWS = 1024
-
-
 def dense_latent_attention(q, pages, block_tables, context_lens, valid_lens,
                            scale, value_width):
-    """The dense reference of the two entries below: every slot's table
-    gathered, one kv group whose values are the keys' first
-    ``value_width`` columns."""
+    """The dense reference of the ABSORBED form, a step's or a chunk's
+    (``q`` [S, C, nh, W]): every slot's table gathered, one kv group
+    whose values are the keys' first ``value_width`` columns."""
     keys = pages[:, :, None, :]
     return dense_paged_attention(
         q, keys, keys[..., :value_width], block_tables, context_lens,
@@ -576,25 +581,242 @@ def latent_attention_decode(q, pages, block_tables, context_lens, *,
         name="mla_attention_decode", value_width=value_width)[:, 0]
 
 
-def latent_attention_prefill(q, pages, block_tables, context_lens, *,
-                             valid_lens=None, value_width: int,
-                             softmax_scale: float,
-                             block_q: Optional[int] = None):
-    """Ragged attention over a latent pool for one chunk a slot: ``q``
-    [S, C, nh, W] at positions ``context_lens[s] ..`` (the chunk's own
-    rows already written), causal within the chunk on top of the paged
-    history, in the absorbed form like the decode entry.  Returns
-    ``[S, C, nh, value_width]``."""
-    assert q.ndim == 4 and pages.ndim == 3, (q.shape, pages.shape)
-    if not _use_pallas():
-        return dense_latent_attention(
-            q, pages, block_tables, context_lens, valid_lens, softmax_scale,
-            value_width)
-    C = q.shape[1]
-    bq = min(block_q or max(1, _LATENT_BLOCK_ROWS // q.shape[2]), C)
-    while C % bq:
-        bq -= 1
-    return _walk_call(
-        q, pages, None, block_tables, context_lens, valid_lens, None, None,
-        scale=softmax_scale, window=None, block_q=bq,
-        name="mla_attention_prefill", value_width=value_width)
+# ---------------------------------------------------------------------------
+# a latent pool's chunk: the EXPANDED form, in a walk of its own
+# ---------------------------------------------------------------------------
+
+# keys of one block of latents (64 pages of 16), and heads of one step of
+# the loop over a group's heads: two heads' chains (expansion, scores,
+# softmax, values) in one body, so that one's products run under the
+# other's exponentials.  What a (slot, head group) may hold in VMEM, and
+# the limit the compiler is given for it (a v5e has 128 MiB; a kernel
+# gets 16 unless it asks).  Measured on a v5e at the served widths, a
+# [1, 512] chunk over 10,240 tokens of context (the absorbed walk this
+# replaces: 2.82 ms): blocks of 512 keys 1.97 ms, 1,024 1.59, 2,048 1.75
+# (half a block of dead keys a chunk); two heads a step 1.49, four 1.49,
+# eight 1.59; groups of 4 / 8 / 16 / 32 heads 1.60 / 1.49 / 1.44 / 1.42
+_CHUNK_BLOCK_TOKENS = 1024
+_CHUNK_HEADS_A_STEP = 2
+_CHUNK_VMEM_BYTES = 48 * 1024 * 1024
+_CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def _chunk_heads(nh, C, T, W, dq, dkv, dv, r, itemsize) -> int:
+    """Heads of one grid step of the chunk's walk: the most (a divisor of
+    ``nh``) whose queries, up-projection and output (each double-buffered
+    by the pipeline), fp32 accumulator and running max and sum (a lane
+    each, a whole tile wide in VMEM) fit ``_CHUNK_VMEM_BYTES`` beside the
+    two blocks of latents and the temporaries of the heads in flight
+    (expanded keys and values, scores and probabilities, in fp32 and
+    rounded).  At the served widths 2 MB a head beside 15.6 MB: 16 heads,
+    and the latents are fetched twice a layer (27 MB at a context of
+    10k, 0.03 ms)."""
+    a_head = (2 * C * _lanes(dq) * itemsize + 2 * r * _lanes(dkv) * itemsize
+              + 2 * C * _lanes(dv) * itemsize + C * _lanes(dv) * 4
+              + 2 * C * 128 * 4)
+    shared = 2 * T * _lanes(W) * itemsize + _CHUNK_HEADS_A_STEP * (
+        T * _lanes(dkv) * (4 + itemsize) + C * _lanes(T) * (4 + 4 + itemsize))
+    fit = max(1, (_CHUNK_VMEM_BYTES - shared) // a_head)
+    return max(h for h in range(1, nh + 1) if nh % h == 0 and h <= fit)
+
+
+def _chunk_body(bt_ref, cl_ref, vl_ref, q_ref, w_ref, pages_ref, o_ref,
+                buf, sem, m_scr, l_scr, acc_scr, *, scale, nope):
+    """One (slot, head group) of a latent pool's chunk: walk the slot's
+    live pages in blocks of ``kp`` pages (block j+1 on its way from HBM
+    while block j is computed) and, for each block of latents and each
+    head of the group, expand the head's no-rope keys and its values
+    through its slice of the up-projection IN VMEM, score the head's
+    queries against ``[k_nope ; the block's one rotary key]`` and fold
+    probabilities times values into the head's running (m, l, acc)."""
+    s = pl.program_id(0)
+    _, hg, C, dq = q_ref.shape
+    rank = w_ref.shape[1]
+    _, kp, bs, W = buf.shape
+    T = kp * bs                               # keys of a block
+    step = _CHUNK_HEADS_A_STEP if hg % _CHUNK_HEADS_A_STEP == 0 else 1
+    ctx, live = cl_ref[s], vl_ref[s]
+    # newest key a live row attends; a slot with no token in this call
+    # walks nothing: no fetch, zeros out
+    top = jnp.minimum(ctx + live, bt_ref.shape[1] * bs) - 1
+    last = jnp.maximum(top, 0) // bs
+    nblk = jnp.where(live > 0, last // kp + 1, 0)
+    # blocks whose every key every row attends (keys 0 .. ctx): all their
+    # pages are live and nothing of them is masked
+    whole = jnp.minimum((ctx + 1) // T, nblk)
+
+    def block_dma(j, slot, start):
+        p0 = j * kp
+
+        def page_dma(i, carry):
+            cp = pltpu.make_async_copy(pages_ref.at[bt_ref[s, p0 + i]],
+                                       buf.at[slot, i], sem.at[slot])
+            cp.start() if start else cp.wait()
+            return carry
+
+        # the last block stops at the last live page
+        jax.lax.fori_loop(0, jnp.minimum(kp, last - p0 + 1), page_dma, 0)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(nblk > 0)
+    def _first_block():
+        block_dma(0, 0, True)
+
+    def iota(shape, dim):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+    nt = (((1,), (1,)), ((), ()))             # [R, d] x [T, d] -> [R, T]
+
+    def block(j, carry, masked):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < nblk)
+        def _next_block():
+            block_dma(j + 1, 1 - slot, True)
+
+        block_dma(j, slot, False)
+        rows = buf[slot].reshape(T, W)
+        if masked:
+            # pages past the last live one hold what an earlier block
+            # left there: zeroed whole, so that 0 x (whatever) adds nothing
+            rows = jnp.where(j * T + iota((T, 1), 0) <= top, rows,
+                             jnp.zeros_like(rows))
+            valid = _valid_keys(j * T + iota((C, T), 1),
+                                ctx + iota((C, T), 0), None)
+        rows = rows.astype(q_ref.dtype)
+        latents, k_rope = rows[:, :rank], rows[:, rank:rank + dq - nope]
+
+        def heads(i, carry):
+            hs = [i * step + u for u in range(step)]
+            # the expansion, rounded to the operands' dtype as the
+            # cache-less forward's is
+            kvs = [jax.lax.dot(latents, w_ref[h],
+                               preferred_element_type=jnp.float32
+                               ).astype(q_ref.dtype) for h in hs]  # [T, dkv]
+            sqs = []
+            for h, kv in zip(hs, kvs):
+                q = q_ref[0, h]                                   # [C, dq]
+                sq = (jax.lax.dot_general(
+                    q[:, :nope], kv[:, :nope], nt,
+                    preferred_element_type=jnp.float32)
+                    + jax.lax.dot_general(
+                        q[:, nope:], k_rope, nt,
+                        preferred_element_type=jnp.float32)) * scale
+                if masked:
+                    sq = jnp.where(valid, sq, NEG_INF)
+                sqs.append(sq)
+            # key 0 is in block 0 and every row attends it, so a row's
+            # running max is finite from the first block on and a masked
+            # score's exponential is exactly 0
+            for h, kv, sq in zip(hs, kvs, sqs):
+                m_prev = m_scr[h]                                 # [C, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(sq, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(sq - m_new)
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(
+                    p.astype(kv.dtype), kv[:, nope:],
+                    preferred_element_type=jnp.float32)
+                m_scr[h] = m_new
+            return carry
+
+        return jax.lax.fori_loop(0, hg // step, heads, carry)
+
+    jax.lax.fori_loop(0, whole, functools.partial(block, masked=False), 0)
+    jax.lax.fori_loop(whole, nblk, functools.partial(block, masked=True), 0)
+
+    def finish(h, carry):
+        o_ref[0, h] = _softmax_finish(l_scr, acc_scr, h).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, hg, finish, 0)
+
+
+# a jit of its own inside the engine's programs: the layers of a program
+# call it with one set of shapes, so the kernel is traced and lowered once
+# a program and not once a layer
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "kp", "hg", "interpret"))
+def _chunk_call(q_nope, q_rope, kv_up, pages, block_tables, context_lens,
+                valid_lens, *, scale, kp, hg, interpret):
+    """``kp`` pages a block of latents, ``hg`` heads a grid step."""
+    S, C, nh, nope = q_nope.shape
+    r, _, dkv = kv_up.shape
+    dq, dv = nope + q_rope.shape[-1], dkv - nope
+    bs, W = pages.shape[1:]
+    # head-major operands: a head's queries [C, dq] and its slice of the
+    # up-projection [r, dkv] are whole tiles of the kernel's blocks
+    q = jnp.transpose(jnp.concatenate([q_nope, q_rope], axis=-1),
+                      (0, 2, 1, 3))
+    w = jnp.transpose(kv_up, (1, 0, 2)).astype(q.dtype)
+
+    def head_map(s, g, bt_ref, cl_ref, vl_ref):
+        return (s, g, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, nh // hg),
+        in_specs=[
+            pl.BlockSpec((1, hg, C, dq), head_map, memory_space=pltpu.VMEM),
+            pl.BlockSpec((hg, r, dkv), lambda s, g, *_: (g, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, hg, C, dv), head_map,
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, kp, bs, W), pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((hg, C, 1), jnp.float32),
+            pltpu.VMEM((hg, C, 1), jnp.float32),
+            pltpu.VMEM((hg, C, dv), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_chunk_body, scale=scale, nope=nope),
+        name="mla_attention_prefill",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, nh, C, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      valid_lens.astype(jnp.int32), q, w, pages)
+    return jnp.transpose(out, (0, 2, 1, 3))
+
+
+def latent_attention_prefill(q_nope, q_rope, kv_up, pages, block_tables,
+                             context_lens, *, valid_lens=None,
+                             softmax_scale: float):
+    """Ragged attention over a latent pool for one chunk a slot, in the
+    EXPANDED form: ``q_nope`` [S, C, nh, dn] and ``q_rope`` [S, C, nh, dr]
+    at positions ``context_lens[s] ..`` (the chunk's own rows already
+    written), ``kv_up`` [r, nh, dn + dv] the up-projection, ``pages``
+    [P, bs, W] rows of ``[latent r ; rotary key dr ; zeros]``.  Every live
+    page is fetched once a head group and each head's keys ``[k_nope ;
+    k_rope]`` and values exist in VMEM only; causal within the chunk on
+    top of the paged history.  The kernel alone: there is no dense form
+    of it (``PagedKVCache.attend_latent`` takes it where
+    :func:`kernel_available`, and the absorbed reference elsewhere).
+    Returns ``[S, C, nh, dv]`` in the queries' dtype."""
+    assert q_nope.ndim == 4 and pages.ndim == 3, (q_nope.shape, pages.shape)
+    assert _use_pallas()
+    S, C, nh, nope = q_nope.shape
+    if valid_lens is None:
+        valid_lens = jnp.full_like(context_lens, C)
+    r, _, dkv = kv_up.shape
+    bs, W = pages.shape[1:]
+    kp = max(1, min(block_tables.shape[1], _CHUNK_BLOCK_TOKENS // bs))
+    hg = _chunk_heads(nh, C, kp * bs, W, nope + q_rope.shape[-1], dkv,
+                      dkv - nope, r, jnp.dtype(q_nope.dtype).itemsize)
+    return _chunk_call(q_nope, q_rope, kv_up, pages, block_tables,
+                       context_lens, valid_lens, scale=softmax_scale, kp=kp,
+                       hg=hg, interpret=_INTERPRET)

@@ -1203,7 +1203,11 @@ class InferenceEngine:
         over (from the arrays the program is handed: ``n`` queries a
         slot), on the record and in the running totals."""
         if self._latent:
-            d.note_latent(sees, self.model.cfg.num_layers)
+            expanded = 0
+            if paged_kv.expands_latents(self.prefill_kernel, n):
+                live = valid_lens > 0
+                expanded = int((context_lens + valid_lens)[live].sum())
+            d.note_latent(sees, self.model.cfg.num_layers, expanded)
             for f in MLA_FIELDS:
                 setattr(self, f, getattr(self, f) + getattr(d, f))
         if not self._dsa_topk:

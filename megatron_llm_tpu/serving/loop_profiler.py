@@ -203,8 +203,9 @@ class DispatchRecord:
     # over the layers: a decode launch's live keys (each live row's
     # context and itself: rows of the pool its walk reads), a chunk's
     # (query, key) pairs (for each live query the keys it sees), and the
-    # context tokens multiplied by the up-projection (0 in the absorbed
-    # form, which expands nothing)
+    # context tokens (history and chunk) its walk multiplies by the
+    # up-projection: a chunk on the kernel path, the EXPANDED form; 0 on
+    # a decode launch and where the dense fallback runs, both absorbed
     mla_keys_live = 0
     mla_pairs = 0
     mla_latents_expanded = 0
@@ -311,12 +312,16 @@ class DispatchRecord:
             **{f: getattr(self, f) for f in KV_FIELDS},
         }
 
-    def note_latent(self, sees, layers: int) -> None:
+    def note_latent(self, sees, layers: int, expanded: int = 0) -> None:
         """``sees``: for each live query of the launch the keys it sees
         (positions 0..its own), an int array the host made from what it
-        hands the program; the launch's kind says which count they are."""
+        hands the program; the launch's kind says which count they are.
+        ``expanded``: the context tokens (history and chunk) of the rows
+        whose chunk the expanded walk reads, 0 where the read is
+        absorbed."""
         field = "mla_pairs" if self.kind == "prefill" else "mla_keys_live"
         setattr(self, field, layers * int(sees.sum()))
+        self.mla_latents_expanded = layers * expanded
 
     def note_selection(self, sees, topk: int, layers: int, steps,
                        table_blocks: int) -> None:

@@ -622,8 +622,11 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 # 20: latent attention's work: engine stats() / the engine block of
 #    /metrics gain mla_keys_live (decode launches: each live row's
 #    context and itself), mla_pairs (prefill launches: for each live
-#    query the keys it sees) and mla_latents_expanded (context tokens
-#    multiplied by the up-projection; 0 in the absorbed form), summed over
+#    query the keys it sees) and mla_latents_expanded (prefill launches
+#    whose chunk the expanded kernel reads, mla_attention_prefill: the
+#    context tokens, history and chunk, it multiplies by the
+#    up-projection; 0 on a decode launch and where the dense fallback
+#    runs, which are absorbed), summed over
 #    layers and launches, all 0 for a model without a latent pool, and
 #    every launch record carries the same three for that launch — see
 #    serving/loop_profiler.py ``MLA_FIELDS``

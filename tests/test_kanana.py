@@ -8,8 +8,9 @@ over a latent of 32 (narrower than the heads' 4 x 32 of keys and
 values), 8 experts of 64 at 3 a token and one shared expert, contexts
 of 5 to 156 tokens over pages of 8 and chunks of 16.  The reference is
 the file the benchmark's probe loads (``benchmarks/reference/kanana.py``:
-the EXPANDED form only), loaded here by path; the engine attends in the
-absorbed form.
+the EXPANDED form only), loaded here by path; the engine's decode step
+and its dense fallback attend in the absorbed form, its chunk on the
+kernel path in the expanded one, inside the kernel.
 """
 
 import importlib.util
@@ -325,14 +326,22 @@ def test_a_leading_dense_layer_scans_serves_and_counts_sparse_layers_only(
     assert eng.stats()["moe_assignments"] == (20 + 2) * 3 * 2
 
 
-def test_the_engine_counts_latent_attentions_keys_and_pairs(family):
+@pytest.mark.parametrize("kernel", ["off", "on"])
+def test_the_engine_counts_latent_attentions_keys_and_pairs(family, kernel,
+                                                            monkeypatch):
     """``mla_keys_live`` on a decode launch's record (each live row's
     context and itself), ``mla_pairs`` on a chunk's (for each live query
-    the keys it sees), both summed over the layers, from the arrays the
-    host hands the program; nothing is expanded in the absorbed form."""
+    the keys it sees), ``mla_latents_expanded`` on a chunk's that the
+    expanded kernel reads (its context, history and chunk; 0 through the
+    dense fallback, which is absorbed, and on every decode launch), all
+    summed over the layers, from the arrays the host hands the program."""
     model, params = family[:2]
     L = model.cfg.num_layers
-    eng = _engine(model, params, prefix_cache=False)
+    if kernel == "on":
+        monkeypatch.setattr(pa, "_INTERPRET", True)
+    eng = _engine(model, params, prefix_cache=False, paged_kernel=kernel,
+                  prefill_kernel=kernel)
+    assert pa.kernel_available() == (kernel == "on")
     _serve(eng, _tokens(20, seed=4), 4)
     records = eng.loop_profiler.records()
     chunks = [r for r in records if r.kind == "prefill"]
@@ -342,11 +351,67 @@ def test_the_engine_counts_latent_attentions_keys_and_pairs(family):
     assert [r.mla_keys_live for r in steps] == [L * 21, L * 22, L * 23]
     assert all(r.mla_keys_live == 0 for r in chunks)
     assert all(r.mla_pairs == 0 for r in steps)
+    expanded = [L * 16, L * 20] if kernel == "on" else [0, 0]
+    assert [r.mla_latents_expanded for r in chunks] == expanded
+    assert all(r.mla_latents_expanded == 0 for r in steps)
     stats = eng.stats()
     assert stats["mla_pairs"] == L * sum(range(1, 21))
     assert stats["mla_keys_live"] == L * 66
-    assert stats["mla_latents_expanded"] == 0
+    assert stats["mla_latents_expanded"] == sum(expanded)
+    assert chunks[1].as_dict()["mla_latents_expanded"] == expanded[1]
     assert "mla_pairs" in chunks[0].as_dict()
+
+
+def test_a_model_without_a_latent_pool_expands_nothing(monkeypatch):
+    """The kernel path of a model with per-head keys and values: the
+    three latent counters stay 0 on every record and in ``stats()``."""
+    from megatron_llm_tpu.models.llama import LlamaModel, llama_config
+
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    model = LlamaModel(llama_config(
+        "tiny", num_layers=2, hidden_size=64, num_attention_heads=4,
+        seq_length=64, max_position_embeddings=64, padded_vocab_size=512,
+        use_flash_attn=False))
+    eng = _engine(model, model.init(jax.random.PRNGKey(0)),
+                  max_model_len=64)
+    assert eng.prefill_kernel == "pallas"
+    _serve(eng, _tokens(20, seed=4), 3)
+    assert {f: eng.stats()[f] for f in
+            ("mla_keys_live", "mla_pairs", "mla_latents_expanded")} == {
+        "mla_keys_live": 0, "mla_pairs": 0, "mla_latents_expanded": 0}
+    assert all(r.mla_latents_expanded == 0
+               for r in eng.loop_profiler.records())
+
+
+def test_a_chunk_of_several_rows_over_adopted_pages_is_the_plain_forward(
+        family, monkeypatch):
+    """The expanded kernel in the engine's own chunk program, over a
+    context it did not write in this request: a second request adopts
+    the first one's latent pages (32 tokens of a shared prefix) and its
+    chunks, of 16 and of 7 live rows, start on top of them; the logits
+    of each chunk's last row are the cache-less forward's."""
+    model, params, ref, weights, cfg = family
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    eng = _engine(model, params, max_model_len=96, paged_kernel="on",
+                  prefill_kernel="on")
+    shared = _tokens(36, seed=13)
+    _serve(eng, shared + _tokens(5, seed=14), 2)
+    got = _tapped(eng)
+    toks = shared + _tokens(19, seed=15)
+    before = eng.stats()["mla_latents_expanded"]
+    req = _serve(eng, toks, 3)
+    assert req.cached_prompt_tokens == 32
+    chunks = [r for r in eng.loop_profiler.records()
+              if r.kind == "prefill" and r.requests == (req.id,)]
+    assert [(r.start, r.valid) for r in chunks] == [(32, 16), (48, 7)]
+    L = model.cfg.num_layers
+    assert eng.stats()["mla_latents_expanded"] - before == L * (48 + 55)
+    seq = toks + list(req.out_tokens)
+    want = np.asarray(ref.forward_logits(weights, cfg, seq))
+    rows = sorted(got)
+    assert rows[:2] == [47, 54]
+    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
+                               atol=LOGIT_TOL, rtol=0)
 
 
 def test_a_token_holds_at_most_1280_bytes_a_layer_at_the_published_widths():

@@ -631,7 +631,8 @@ def test_every_kernel_and_program_carries_its_stable_name():
                                          "_masked_walk")):
                 kw = {k.arg: k.value for k in node.keywords}
                 names.add(kw["name"].value)
-    assert calls == 13
+    # 13 until the latent chunk got a walk of its own (mla_attention_prefill)
+    assert calls == 14
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

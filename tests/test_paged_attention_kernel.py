@@ -935,9 +935,10 @@ def test_prefill_q_block_is_held_to_the_scratch_it_needs():
 # its first columns for values
 # ---------------------------------------------------------------------------
 
-def _latent_case(rng, lens, M, W=24, nh=3, C=1):
+def _latent_case(rng, lens, M, W=24, nh=3):
     """Rows [S, M*BS, W] scattered into a pool [P, BS, W] through ragged
-    tables, garbage in every page nobody owns; queries [S, C, nh, W]."""
+    tables, garbage in every page nobody owns; a decode step's queries
+    [S, 1, nh, W]."""
     S = len(lens)
     rows = rng.standard_normal((S, M * BS, W)).astype(np.float32)
     pages = (rng.standard_normal((1 + S * M, BS, W)) * 100.0).astype(
@@ -945,11 +946,11 @@ def _latent_case(rng, lens, M, W=24, nh=3, C=1):
     bt = np.zeros((S, M), np.int32)
     nxt = 1
     for s in range(S):
-        for j in range((int(lens[s]) + C - 1) // BS + 1):
+        for j in range(int(lens[s]) // BS + 1):
             bt[s, j] = nxt
             pages[nxt] = rows[s, j * BS:(j + 1) * BS]
             nxt += 1
-    q = rng.standard_normal((S, C, nh, W)).astype(np.float32)
+    q = rng.standard_normal((S, 1, nh, W)).astype(np.float32)
     return q, rows, pages, bt
 
 
@@ -995,36 +996,150 @@ def test_latent_decode_matches_oracle_and_reference(blocks, monkeypatch):
     assert (some[[1, 3]] == 0).all()
 
 
-@pytest.mark.parametrize("block_q", [None, 4])
-def test_latent_prefill_chunk_matches_oracle(block_q, two_page_blocks):
-    """A chunk of 8 rows a slot over histories of 0 to 29 tokens: causal
-    within the chunk on top of the paged history, q-blocks of the whole
-    chunk and of 4 rows."""
-    lens = np.asarray([0, 3, 16, 29], np.int32)
-    q, rows, pages, bt = _latent_case(np.random.default_rng(1), lens, 6,
-                                      C=8)
-    want = _latent_oracle(q, rows, lens, 0.25, 16)
+def _absorbed(q_nope, q_rope, w, pages, bt, lens, valid, scale):
+    """The expanded chunk's answer by the ABSORBED form in float32
+    through the dense gather: the up-projection's key half folded into
+    the queries, its value half applied to the latent context."""
+    nope, W = q_nope.shape[-1], pages.shape[-1]
+    f32 = [jnp.asarray(a, jnp.float32) for a in (q_nope, q_rope, w, pages)]
+    q_lat = jnp.einsum("scnd,rnd->scnr", f32[0], f32[2][..., :nope])
+    q = jnp.concatenate([q_lat, f32[1]], axis=-1)
+    q = jnp.pad(q, [(0, 0)] * 3 + [(0, W - q.shape[-1])])
+    ctx = pa.dense_latent_attention(
+        q, f32[3], jnp.asarray(bt), jnp.asarray(lens),
+        None if valid is None else jnp.asarray(valid), scale, w.shape[0])
+    return np.asarray(jnp.einsum("scnr,rnd->scnd", ctx, f32[2][..., nope:]))
+
+
+def _expanded_case(rng, ctx, live, C, M, r, dr, nh, nope, dv, W, bs=BS):
+    """A latent pool ``[P, bs, W]`` of rows ``[latent r ; rotary key dr ;
+    zeros]`` behind ragged tables (a slot's live pages are those of its
+    ``ctx + live`` tokens; every other table entry points at a page of
+    NaN, the garbage block 0 holds large values), queries of ``nope`` and
+    ``dr`` a head and an up-projection ``[r, nh, nope + dv]``."""
+    S = len(ctx)
+    pages = np.zeros((2 + S * M, bs, W), np.float32)
+    pages[0] = rng.standard_normal((bs, W)) * 100.0
+    pages[1] = np.nan
+    bt = np.ones((S, M), np.int32)
+    nxt = 2
+    for s in range(S):
+        for j in range(-(-(int(ctx[s]) + int(live[s])) // bs)):
+            bt[s, j] = nxt
+            pages[nxt, :, :r + dr] = rng.standard_normal((bs, r + dr))
+            nxt += 1
+    # queries wide enough that a softmax over a thousand keys is peaked
+    q_nope = 3.0 * rng.standard_normal((S, C, nh, nope)).astype(np.float32)
+    q_rope = 3.0 * rng.standard_normal((S, C, nh, dr)).astype(np.float32)
+    w = (rng.standard_normal((r, nh, nope + dv)) / np.sqrt(r)).astype(
+        np.float32)
+    return q_nope, q_rope, w, pages, bt
+
+
+# the published head widths (kanana-2-30b-a3b: 32 heads of 128 + 64 over
+# a latent of 512 in rows of 640, values of 128), pages of 16
+PUBLISHED = dict(r=512, dr=64, nh=32, nope=128, dv=128, W=640, bs=16)
+
+
+@pytest.mark.parametrize("case", [
+    "chunk_512_over_blocks", "first_chunk", "ragged_past_the_table",
+    "a_slot_without_tokens", "bf16"])
+def test_the_expanded_latent_chunk_at_the_published_widths(case):
+    """``mla_attention_prefill``: each block of latents expanded into
+    per-head keys and values inside the kernel, against the absorbed form
+    through the dense gather.  A chunk of 512 live rows over a context of
+    three blocks of 1,024 whose last page is partial; the first chunk (no
+    context); a ragged chunk whose padded rows point past the table's
+    end; a slot with no token beside a live one (zeros out, its table of
+    NaN pages never fetched); bf16 operands with fp32 accumulators."""
+    dtype, valid = jnp.float32, None
+    if case == "chunk_512_over_blocks":
+        ctx, C, M = [2200], 512, 176          # 2,712 tokens: 169.5 pages
+    elif case == "first_chunk":
+        ctx, C, M = [0], 128, 12
+    elif case == "ragged_past_the_table":
+        ctx, C, M, valid = [600], 128, 40, [40]     # the table ends at 640
+    elif case == "a_slot_without_tokens":
+        ctx, C, M, valid = [70, 530], 64, 40, [0, 64]
+    else:
+        ctx, C, M, dtype = [520], 128, 48, jnp.bfloat16
+    live = valid if valid is not None else [C] * len(ctx)
+    ctx, live = np.asarray(ctx, np.int32), np.asarray(live, np.int32)
+    q_nope, q_rope, w, pages, bt = _expanded_case(
+        np.random.default_rng(len(case)), ctx, live, C, M, **PUBLISHED)
+    cast = [jnp.asarray(a, dtype) for a in (q_nope, q_rope, w, pages)]
     got = pa.latent_attention_prefill(
-        *(jnp.asarray(a) for a in (q, pages, bt, lens)), value_width=16,
-        softmax_scale=0.25, block_q=block_q)
-    assert got.shape == (4, 8, 3, 16)
+        *cast, jnp.asarray(bt), jnp.asarray(ctx),
+        valid_lens=None if valid is None else jnp.asarray(live),
+        softmax_scale=192 ** -0.5)
+    assert got.shape == (len(ctx), C, 32, 128) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    # the reference reads the whole table: the NaN pages become the
+    # garbage block, which the mask drops
+    want = _absorbed(*cast, np.where(bt == 1, 0, bt), ctx, live, 192 ** -0.5)
+    assert np.abs(want).max() > 0.5
+    tol = dict(atol=2e-5, rtol=1e-5) if dtype == jnp.float32 else dict(
+        atol=0.03, rtol=0)
+    for s in range(len(ctx)):
+        np.testing.assert_allclose(got[s, :live[s]], want[s, :live[s]], **tol)
+        assert np.isfinite(got[s]).all()
+        if live[s] == 0:
+            assert (got[s] == 0).all()
+
+
+@pytest.mark.parametrize("heads", ["all_heads", "a_head_a_step"])
+def test_latent_prefill_chunk_matches_oracle(heads, monkeypatch):
+    """A chunk of 8 rows a slot over histories of 0 to 29 tokens at a
+    tiny width, blocks of two pages: causal within the chunk on top of
+    the paged history, across block boundaries; every head under one
+    fetch and a head a grid step."""
+    monkeypatch.setattr(pa, "_CHUNK_BLOCK_TOKENS", 2 * BS)
+    if heads == "a_head_a_step":
+        monkeypatch.setattr(pa, "_CHUNK_VMEM_BYTES", 0)
+    ctx = np.asarray([0, 3, 16, 29], np.int32)
+    dims = dict(r=16, dr=4, nh=3, nope=8, dv=12, W=24)
+    q_nope, q_rope, w, pages, bt = _expanded_case(
+        np.random.default_rng(1), ctx, np.full(4, 8), 8, 6, **dims)
+    seen = []
+    real = pa._chunk_heads
+    monkeypatch.setattr(pa, "_chunk_heads",
+                        lambda *a: seen.append(real(*a)) or seen[-1])
+    got = pa.latent_attention_prefill(
+        *(jnp.asarray(a) for a in (q_nope, q_rope, w, pages, bt, ctx)),
+        softmax_scale=0.25)
+    assert seen == [1 if heads == "a_head_a_step" else 3]
+    assert got.shape == (4, 8, 3, 12)
+    want = _absorbed(q_nope, q_rope, w, pages, np.where(bt == 1, 0, bt), ctx,
+                     None, 0.25)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
 
 
-def test_latent_walk_in_bf16_multiplies_the_pool_as_it_lies():
-    """bf16 queries over a bf16 pool: the products are the pool's own
-    dtype on a chunk too, the probabilities rounded to it for the second
-    product; within bf16's rounding of the float32 oracle."""
-    lens = np.asarray([21], np.int32)
-    q, rows, pages, bt = _latent_case(np.random.default_rng(2), lens, 5,
-                                      C=8)
-    q16, p16 = (jnp.asarray(a, jnp.bfloat16) for a in (q, pages))
-    want = _latent_oracle(
-        np.asarray(q16, np.float32),
-        np.asarray(jnp.asarray(rows, jnp.bfloat16), np.float32), lens, 0.25,
-        16)
+def test_latent_chunk_in_bf16_rounds_the_expansion_to_the_operands_dtype():
+    """bf16 queries over a bf16 pool: every product takes bf16 operands
+    and accumulates in fp32, the expanded keys and values and the
+    probabilities rounded to bf16 between them, and the answer is the
+    same mathematics in float32 with those three roundings put in."""
+    ctx = np.asarray([21], np.int32)
+    dims = dict(r=16, dr=4, nh=3, nope=8, dv=12, W=24)
+    q_nope, q_rope, w, pages, bt = _expanded_case(
+        np.random.default_rng(2), ctx, np.full(1, 8), 8, 5, **dims)
+    b16 = [jnp.asarray(a, jnp.bfloat16) for a in (q_nope, q_rope, w, pages)]
     got = pa.latent_attention_prefill(
-        q16, p16, jnp.asarray(bt), jnp.asarray(lens), value_width=16,
-        softmax_scale=0.25)
+        *b16, jnp.asarray(bt), jnp.asarray(ctx), softmax_scale=0.25)
     assert got.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=0.03)
+    qn, qr, w32, p32 = (np.asarray(a, np.float32) for a in b16)
+
+    def rounded(a):
+        return np.asarray(jnp.asarray(a, jnp.bfloat16), np.float32)
+
+    rows = p32[bt[0]].reshape(-1, 24)[:29]
+    kv = rounded(np.einsum("tr,rnd->tnd", rows[:, :16], w32))
+    want = np.zeros((8, 3, 12), np.float32)
+    for j in range(8):
+        for h in range(3):
+            sc = (kv[:22 + j, h, :8] @ qn[0, j, h]
+                  + rows[:22 + j, 16:20] @ qr[0, j, h]) * 0.25
+            p = np.exp(sc - sc.max())
+            want[j, h] = rounded(p) @ kv[:22 + j, h, 8:] / p.sum()
+    np.testing.assert_allclose(np.asarray(got[0], np.float32), want,
+                               atol=0.02)

@@ -298,6 +298,55 @@ def test_a_latent_pool_is_one_array_of_rows_a_layer():
     assert paged_kv.layer_groups(cfg) is None
 
 
+@pytest.mark.parametrize("kernel,n,interpret,want", [
+    ("pallas", 6, True, True), ("pallas", 1, True, False),
+    ("xla", 6, True, False), ("pallas", 6, False, False)])
+def test_a_chunk_on_the_kernel_path_and_nothing_else_expands_its_latents(
+        kernel, n, interpret, want, monkeypatch):
+    """The choice of form is by what the code sees: the query length and
+    the resolved path (off the chip the kernel is there in interpret mode
+    only).  Where it expands, ``attend_latent`` takes the model's own
+    ``q_nope`` and the up-projection and answers per head what the
+    absorbed read answers through the dense gather."""
+    import jax.numpy as jnp
+
+    from megatron_llm_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_INTERPRET", interpret)
+    assert paged_kv.expands_latents(kernel, n) is want
+    cfg = _kanana_cfg()
+    r, dr, dn, dv, nh = (cfg.kv_lora_rank, cfg.qk_rope_head_dim,
+                         cfg.qk_nope_head_dim, cfg.v_head_dim, 4)
+    rng = np.random.default_rng(1)
+    pool = {"latent_pages": jnp.asarray(
+        rng.standard_normal((6, 8, 128)), jnp.float32)}
+    tables = jnp.asarray([[3, 1, 4, 0]], jnp.int32)
+    lens = (jnp.asarray([5], jnp.int32), jnp.asarray([n], jnp.int32))
+    cache = paged_kv.PagedKVCache(pool, tables, *lens, kernel=kernel)
+    assert cache.expands_latents(n) is want
+    lat, rope = (jnp.asarray(rng.standard_normal((1, n, w)), jnp.float32)
+                 for w in (r, dr))
+    qn, qr = (jnp.asarray(rng.standard_normal((1, n, nh, w)), jnp.float32)
+              for w in (dn, dr))
+    w_up = jnp.asarray(rng.standard_normal((r, nh, dn + dv)) / r ** 0.5,
+                       jnp.float32)
+    if not want:
+        with pytest.raises(AssertionError):
+            cache.attend_latent(qn, qr, lat, rope, 0.2, kv_up=w_up)
+        return
+    got, after = cache.attend_latent(qn, qr, lat, rope, 0.2, kv_up=w_up)
+    assert got.shape == (1, n, nh, dv)
+    dense = paged_kv.PagedKVCache(pool, tables, *lens, kernel="xla")
+    q_lat = jnp.einsum("bsnd,rnd->bsnr", qn, w_up[..., :dn])
+    ctx, after_dense = dense.attend_latent(q_lat, qr, lat, rope, 0.2)
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(jnp.einsum("bsnr,rnd->bsnd", ctx, w_up[..., dn:])),
+        atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(after.pool["latent_pages"]),
+                                  np.asarray(after_dense.pool["latent_pages"]))
+
+
 @pytest.mark.parametrize("n,ctx", [(1, 11), (6, 5)])
 def test_attend_latent_writes_a_row_and_reads_it_back(n, ctx):
     """``attend_latent`` writes ``[latent ; rotary key ; zeros]`` at the

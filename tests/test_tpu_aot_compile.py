@@ -162,24 +162,33 @@ def _paged_window(q_tokens, slots, tokens=33792, page=16):
 
 
 def _latent(q_tokens, slots, tokens=32768, page=16):
-    """Kanana's latent pool at the cell's shapes: 32 absorbed query heads
-    over rows of 640 (a latent of 512, a rotary key of 64, zeros) whose
-    first 512 columns are the values, 16 slots of up to 32,768 tokens (a
-    table of 2,048 entries) over 12,289 pages; the decode step and the
-    [1, 512] chunk."""
+    """Kanana's latent pool at the cell's shapes: 32 query heads over rows
+    of 640 (a latent of 512, a rotary key of 64, zeros), 16 slots of up
+    to 32,768 tokens (a table of 2,048 entries) over 12,289 pages.  The
+    decode step: absorbed queries of the row's width, the values the
+    rows' first 512 columns.  The [1, 512] chunk: queries of 128 + 64 a
+    head and the up-projection [512, 32, 128 + 128], each block of
+    latents expanded in the kernel."""
     from megatron_llm_tpu.ops.pallas import paged_attention as pa
 
-    def fn(q, pages, tables, lens, valid):
-        kw = dict(valid_lens=valid, value_width=512, softmax_scale=192 ** -0.5)
-        if q_tokens == 1:
-            return pa.latent_attention_decode(q[:, 0], pages, tables, lens,
-                                              **kw)
-        return pa.latent_attention_prefill(q, pages, tables, lens, **kw)
+    pool = [((12289, page, 640), BF16), ((slots, tokens // page), jnp.int32),
+            ((slots,), jnp.int32), ((slots,), jnp.int32)]
+    if q_tokens == 1:
+        def step(q, pages, tables, lens, valid):
+            return pa.latent_attention_decode(
+                q, pages, tables, lens, valid_lens=valid, value_width=512,
+                softmax_scale=192 ** -0.5)
 
-    return fn, [((slots, q_tokens, 32, 640), BF16),
-                ((12289, page, 640), BF16),
-                ((slots, tokens // page), jnp.int32), ((slots,), jnp.int32),
-                ((slots,), jnp.int32)]
+        return step, [((slots, 32, 640), BF16)] + pool
+
+    def chunk(q_nope, q_rope, kv_up, pages, tables, lens, valid):
+        return pa.latent_attention_prefill(
+            q_nope, q_rope, kv_up, pages, tables, lens, valid_lens=valid,
+            softmax_scale=192 ** -0.5)
+
+    return chunk, [((slots, q_tokens, 32, 128), BF16),
+                   ((slots, q_tokens, 32, 64), BF16),
+                   ((512, 32, 256), BF16)] + pool
 
 
 CASES = {
