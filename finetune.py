@@ -110,6 +110,19 @@ MODEL_DEFAULTS = {
                    qk_nope_head_dim=128, qk_rope_head_dim=64,
                    v_head_dim=128, rope_theta=1e6, layernorm_epsilon=1e-6,
                    hidden_dropout=0.0, attention_dropout=0.0),
+    # granite-4.0-h-small (model_type granitemoehybrid): nine Mamba-2
+    # mixers to each attention layer with no position embedding, 72
+    # experts with a shared MLP, four multipliers, a tied head
+    "granite": dict(position_embedding_type="none", glu_activation="swiglu",
+                    use_rms_norm=True, use_bias=False, tie_embed_logits=True,
+                    num_experts=72, moe_top_k=10, norm_topk_prob=1,
+                    moe_shared_experts=2, kv_channels=128,
+                    layer_types=["mamba"] * 5 + ["attention"]
+                    + ["mamba"] * 4,
+                    attention_multiplier=0.0078125, embedding_multiplier=12.0,
+                    residual_multiplier=0.22, logits_scaling=16.0,
+                    layernorm_epsilon=1e-5,
+                    hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,
@@ -323,6 +336,19 @@ _CKPT_ARG_MAP = {
     "qk_nope_head_dim": "qk_nope_head_dim",
     "qk_rope_head_dim": "qk_rope_head_dim",
     "v_head_dim": "v_head_dim",
+    # granite's share of experts, state-space sizes and multipliers
+    "moe_router_experts": "moe_router_experts",
+    "moe_experts_first": "moe_experts_first",
+    "mamba_n_heads": "mamba_n_heads",
+    "mamba_d_head": "mamba_d_head",
+    "mamba_d_state": "mamba_d_state",
+    "mamba_n_groups": "mamba_n_groups",
+    "mamba_d_conv": "mamba_d_conv",
+    "mamba_chunk_size": "mamba_chunk_size",
+    "mamba_conv_bias": "mamba_conv_bias",
+    "attention_multiplier": "attention_multiplier",
+    "residual_multiplier": "residual_multiplier",
+    "logits_scaling": "logits_scaling",
     # forward-math fields of a model with a layer type per layer
     "layer_types": "layer_types",
     "rope_yarn_scaling": "rope_yarn_scaling",

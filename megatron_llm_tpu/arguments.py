@@ -102,6 +102,11 @@ def _add_network_size_args(parser):
     g.add_argument("--moe_first_dense_layers", type=int, default=0,
                    help="leading layers that keep a dense MLP of "
                         "--ffn_hidden_size (first_k_dense_replace)")
+    g.add_argument("--moe_router_experts", type=int, default=None,
+                   help="the experts the router scores where this chip "
+                        "holds a share of them: --num_experts contiguous "
+                        "ones from --moe_experts_first on (inference)")
+    g.add_argument("--moe_experts_first", type=int, default=0)
     g.add_argument("--moe_n_group", type=int, default=1)
     g.add_argument("--moe_topk_group", type=int, default=1)
     g.add_argument("--kv_lora_rank", type=int, default=None,
@@ -123,7 +128,7 @@ def _add_network_size_args(parser):
     g.add_argument("--make_vocab_size_divisible_by", type=int, default=128)
     g.add_argument("--padded_vocab_size", type=int, default=None)
     g.add_argument("--position_embedding_type", type=str, default="learned_absolute",
-                   choices=["learned_absolute", "rotary"])
+                   choices=["learned_absolute", "rotary", "none"])
     g.add_argument("--rope_scaling_factor", type=float, default=1.0)
     g.add_argument("--rope_theta", type=float, default=10000.0)
     g.add_argument("--rope_llama3_scaling", type=float, nargs=4,
@@ -156,7 +161,24 @@ def _add_network_size_args(parser):
     g.add_argument("--layer_types", type=str, nargs="+", default=None,
                    help="one period of layer types, repeated over the "
                         "depth: sliding (--sliding_window_size keys) or "
-                        "full (e.g. sliding sliding sliding full)")
+                        "full (e.g. sliding sliding sliding full); or a "
+                        "hybrid's mamba and attention")
+    g.add_argument("--mamba_n_heads", type=int, default=128,
+                   help="heads of a 'mamba' layer's state-space mixer")
+    g.add_argument("--mamba_d_head", type=int, default=64)
+    g.add_argument("--mamba_d_state", type=int, default=128)
+    g.add_argument("--mamba_n_groups", type=int, default=1)
+    g.add_argument("--mamba_d_conv", type=int, default=4)
+    g.add_argument("--mamba_chunk_size", type=int, default=256,
+                   help="the chunked scan's block (changes no result)")
+    g.add_argument("--mamba_conv_bias", type=int, default=1, choices=[0, 1])
+    g.add_argument("--attention_multiplier", type=float, default=None,
+                   help="attention scores times this in place of "
+                        "1/sqrt(head_dim)")
+    g.add_argument("--residual_multiplier", type=float, default=1.0,
+                   help="both residual branches times this")
+    g.add_argument("--logits_scaling", type=float, default=1.0,
+                   help="the logits divided by this")
     g.add_argument("--add_qkv_bias", action="store_true",
                    help="bias on the QKV projection only (Qwen2-style)")
     g.add_argument("--qk_norm", action="store_true",
@@ -1051,6 +1073,18 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
             getattr(args, "moe_first_dense_layers", 0) or 0),
         moe_n_group=int(getattr(args, "moe_n_group", 1)),
         moe_topk_group=int(getattr(args, "moe_topk_group", 1)),
+        moe_router_experts=getattr(args, "moe_router_experts", None),
+        moe_experts_first=int(getattr(args, "moe_experts_first", 0) or 0),
+        mamba_n_heads=int(getattr(args, "mamba_n_heads", 128)),
+        mamba_d_head=int(getattr(args, "mamba_d_head", 64)),
+        mamba_d_state=int(getattr(args, "mamba_d_state", 128)),
+        mamba_n_groups=int(getattr(args, "mamba_n_groups", 1)),
+        mamba_d_conv=int(getattr(args, "mamba_d_conv", 4)),
+        mamba_chunk_size=int(getattr(args, "mamba_chunk_size", 256)),
+        mamba_conv_bias=bool(getattr(args, "mamba_conv_bias", 1)),
+        attention_multiplier=getattr(args, "attention_multiplier", None),
+        residual_multiplier=float(getattr(args, "residual_multiplier", 1.0)),
+        logits_scaling=float(getattr(args, "logits_scaling", 1.0)),
         kv_lora_rank=getattr(args, "kv_lora_rank", None),
         q_lora_rank=getattr(args, "q_lora_rank", None),
         qk_nope_head_dim=int(getattr(args, "qk_nope_head_dim", 128)),

@@ -22,6 +22,10 @@ class PositionEmbeddingType(str, Enum):
     # reference: megatron/model/enums.py:20-23
     rotary = "rotary"
     learned_absolute = "learned_absolute"
+    # no position embedding at all ("nope"): nothing rotates, nothing is
+    # added; what orders the tokens is the causal mask and, in a hybrid,
+    # the recurrence of its state-space layers
+    none = "none"
 
 
 class AttnMaskType(str, Enum):
@@ -87,8 +91,11 @@ class ParallelConfig:
         )
 
 
-# what a layer type may be (TransformerConfig.layer_types)
-LAYER_TYPES = ("sliding", "full")
+# what a layer type may be (TransformerConfig.layer_types): two windows
+# of attention, and the two mixers of a hybrid as its config publishes
+# them ('attention' attends every key, as 'full' does; 'mamba' is a
+# Mamba-2 state-space mixer, models/mamba.py)
+LAYER_TYPES = ("sliding", "full", "mamba", "attention")
 
 
 @dataclass(frozen=True)
@@ -247,6 +254,37 @@ class TransformerConfig:
     # ``ffn_hidden_size`` (``first_k_dense_replace``); their parameters
     # are stacked apart from the sparse layers' (``dense_layers``)
     moe_first_dense_layers: int = 0
+    # ONE CHIP'S SHARE of a layer whose experts are spread over several:
+    # the router scores ``moe_router_experts`` (None: ``num_experts``)
+    # and the layer holds the ``num_experts`` contiguous ones from
+    # ``moe_experts_first`` on (``params['experts']`` is ``[num_experts,
+    # ...]``).  A choice that falls on an expert held elsewhere is
+    # computed elsewhere: here it is routed nowhere and its gate, which
+    # was normalised over ALL the token's choices, is dropped with it
+    # (models/moe.py).  Inference only
+    moe_router_experts: Optional[int] = None
+    moe_experts_first: int = 0
+
+    # Mamba-2 state-space mixers (the 'mamba' layer type; models/mamba.py):
+    # an inner width of ``mamba_n_heads * mamba_d_head``, one state of
+    # ``[mamba_d_head, mamba_d_state]`` a head, ``mamba_n_groups`` groups
+    # of heads sharing B and C, a causal depthwise convolution of
+    # ``mamba_d_conv`` taps over x, B and C; ``mamba_chunk_size`` is the
+    # chunked scan's block (an algorithm's parameter: no result changes)
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    # muP-style multipliers (Granite): attention scores times this in
+    # place of 1/sqrt(head_dim) (None: 1/sqrt(head_dim)); both residual
+    # branches times ``residual_multiplier``; the logits divided by
+    # ``logits_scaling``.  ``embedding_multiplier`` is further down
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # latent attention (DeepSeek's MLA; on when ``kv_lora_rank`` is set):
     # keys and values are expanded from ONE latent of ``kv_lora_rank`` a
@@ -360,6 +398,42 @@ class TransformerConfig:
             if self.dsa_index_heads > 0 or self.rope_sections is not None:
                 raise ValueError("layer_types are not implemented with "
                                  "sparse attention or sectioned rope")
+            if "mamba" in types:
+                # what a state-space layer is not made to work with
+                if set(types) - {"mamba", "attention"}:
+                    raise ValueError(
+                        "a 'mamba' layer type goes with 'attention' "
+                        f"layers only, got {types!r}")
+                if self.mamba_n_heads % self.mamba_n_groups or min(
+                        self.mamba_n_heads, self.mamba_d_head,
+                        self.mamba_d_state, self.mamba_n_groups,
+                        self.mamba_chunk_size) < 1 or self.mamba_d_conv < 2:
+                    raise ValueError(
+                        "state-space layers need positive mamba sizes, "
+                        "mamba_d_conv >= 2 and whole groups of heads")
+                for on, what in (
+                        (self.add_bias_linear, "linear biases"),
+                        (self.parallel_attn, "parallel_attn"),
+                        (self.use_post_ln, "post-LN"),
+                        (self.kv_lora_rank is not None,
+                         "latent attention")):
+                    if on:
+                        raise ValueError("state-space layers ('mamba') "
+                                         f"are not implemented with {what}")
+        if self.moe_router_experts is not None or self.moe_experts_first:
+            routed = self.moe_router_experts or self.num_experts
+            if self.num_experts <= 1 or not (
+                    0 <= self.moe_experts_first
+                    and self.moe_experts_first + self.num_experts <= routed):
+                raise ValueError(
+                    f"the experts held (num_experts={self.num_experts} "
+                    f"from moe_experts_first={self.moe_experts_first}) must "
+                    f"lie among the moe_router_experts={routed} the router "
+                    "scores")
+            if not (1 <= self.moe_top_k <= routed):
+                raise ValueError(
+                    f"moe_top_k ({self.moe_top_k}) must be in "
+                    f"[1, moe_router_experts={routed}]")
         if self.rope_yarn_scaling is not None:
             f, orig, fast, slow, att = self.rope_yarn_scaling
             object.__setattr__(self, "rope_yarn_scaling", (
@@ -432,10 +506,10 @@ class TransformerConfig:
             if self.add_bias_linear:
                 raise ValueError("MoE experts do not support linear biases "
                                  "(set add_bias_linear=False)")
-            if not (1 <= self.moe_top_k <= self.num_experts):
+            if not (1 <= self.moe_top_k <= self.routed_experts):
                 raise ValueError(
                     f"moe_top_k ({self.moe_top_k}) must be in "
-                    f"[1, num_experts={self.num_experts}]")
+                    f"[1, num_experts={self.routed_experts}]")
             if self.moe_expert_axis not in ("auto", "expert", "replicated"):
                 raise ValueError(
                     f"moe_expert_axis must be auto|expert|replicated, got "
@@ -457,6 +531,52 @@ class TransformerConfig:
         if self.num_experts <= 1:
             return 0
         return self.num_layers - self.moe_first_dense_layers
+
+    @property
+    def routed_experts(self) -> int:
+        """The experts the router scores: all the layer's, of which this
+        chip may hold a share (``moe_router_experts``)."""
+        return self.moe_router_experts or self.num_experts
+
+    @property
+    def holds_a_share(self) -> bool:
+        """Whether the layer holds a share of the experts its router
+        scores, and not all of them."""
+        return self.routed_experts != self.num_experts
+
+    @property
+    def state_space(self) -> bool:
+        """Whether some layer's mixer is a state-space one."""
+        return self.layer_types is not None and "mamba" in self.layer_types
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """The channels the convolution runs over: x, B and C."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def mixer_counts(self) -> dict:
+        """How many layers there are of each mixer kind ('mamba',
+        'attention') in a stack whose kinds' parameters are stacked
+        apart; empty for a stack whose layers all hold the same leaves."""
+        if not self.state_space:
+            return {}
+        reps = self.num_layers // len(self.layer_types)
+        return {k: reps * self.layer_types.count(k)
+                for k in ("mamba", "attention")
+                if k in self.layer_types}
+
+    def mixer_index(self, layer: int) -> tuple:
+        """(kind, index among the layers of that kind) of layer
+        ``layer`` of a stack with state-space layers."""
+        P = len(self.layer_types)
+        kind = self.layer_types[layer % P]
+        return kind, ((layer // P) * self.layer_types.count(kind)
+                      + self.layer_types[:layer % P].count(kind))
 
     @property
     def latent_attention(self) -> bool:

@@ -122,7 +122,9 @@ def mesh_groups(mesh_shape: Dict[str, int], axes: Sequence[str]) -> Groups:
 # ``scope`` is the innermost of these its ``op_name`` holds
 SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
           "moe_combine", "moe_shared", "qk_norm", "dsa_indexer",
-          "mla_absorb", "mla_expand", "attention", "mlp",
+          "mla_absorb", "mla_expand", "ssm_in_proj", "ssm_conv", "ssm_scan",
+          "ssm_step", "ssm_gate_norm", "ssm_out_proj", "mamba",
+          "attention", "mlp",
           "embedding", "lm_head", "transformer_layer")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _SCOPE_CORE = re.compile(r"^(?:\w+\()*([\w.\-]+)\)*$")
@@ -364,6 +366,8 @@ class ProgramTable:
       keeps) has exactly the dtype and shape of one of ``pool``'s arrays
       (``(numpy dtype name, shape)`` each): decided from the text and the
       shapes, never from a name;
+    * ``ssm_state``: the same of one of ``state``'s arrays (a
+      state-space layer's arrays a slot, which are no pages);
     * else its ``scope`` (``""`` where it has none);
     * ``edge``: for a collective, ``edge_of`` its groups on ``mesh_shape``
       (an ordered ``{axis: size}``), so that an all-reduce the compiler
@@ -373,15 +377,19 @@ class ProgramTable:
 
     def __init__(self, name: str, rows: List[dict],
                  pool: Iterable[Tuple[str, Tuple[int, ...]]] = (),
-                 mesh_shape: Optional[Dict[str, int]] = None):
+                 mesh_shape: Optional[Dict[str, int]] = None,
+                 state: Iterable[Tuple[str, Tuple[int, ...]]] = ()):
         self.name, self.rows = name, rows
         self._by_name = {r["name"]: r for r in rows}
         pool = frozenset((_HLO_DTYPE.get(d, d), tuple(sh)) for d, sh in pool)
+        state = frozenset((_HLO_DTYPE.get(d, d), tuple(sh))
+                          for d, sh in state)
         for r in rows:
             r["program"] = name
             r["edge"] = (edge_of(r["groups"], mesh_shape)
                          if mesh_shape and "family" in r else "")
             r["role"] = ("kv_pool" if pool and self._moves(r, pool)
+                         else "ssm_state" if state and self._moves(r, state)
                          else r["scope"])
         for r in rows:      # an asynchronous collective's two halves
             if "wraps" in r:

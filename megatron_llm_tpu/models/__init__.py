@@ -24,6 +24,14 @@ from megatron_llm_tpu.models.classification import (
     MultipleChoiceModel,
 )
 
+def _granite(cfg):
+    """The Granite hybrid, imported when first built: a process that
+    serves another family pays nothing for it at start-up."""
+    from megatron_llm_tpu.models.granite import GraniteModel
+
+    return GraniteModel(cfg)
+
+
 MODEL_REGISTRY = {
     "gpt": GPTModel,
     "llama": LlamaModel,
@@ -37,6 +45,7 @@ MODEL_REGISTRY = {
     "keye": KeyeModel,
     "mellum": MellumModel,
     "kanana": KananaModel,
+    "granite": _granite,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,
     "gpt_neox": GPTNeoXModel,

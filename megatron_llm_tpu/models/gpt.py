@@ -57,16 +57,20 @@ class GPTModel:
         # latent attention's one latent and one rotary key head are not
         # sharded, and a pipeline stage's layers are taken to be of one
         # kind: refused by name
-        if cfg.latent_attention or cfg.moe_first_dense_layers:
+        if (cfg.latent_attention or cfg.moe_first_dense_layers
+                or cfg.state_space or cfg.holds_a_share):
             from megatron_llm_tpu import topology
 
             pp = (topology.get_pipeline_model_parallel_world_size()
                   if topology.model_parallel_is_initialized() else 1)
             if not _vocab_unsharded() or pp > 1:
                 raise ValueError(
-                    "latent attention (kv_lora_rank) and leading dense "
-                    "layers (moe_first_dense_layers) are not implemented "
-                    "under tensor or pipeline parallelism (tp > 1, pp > 1)")
+                    "latent attention (kv_lora_rank), leading dense "
+                    "layers (moe_first_dense_layers), state-space layers "
+                    "('mamba' among layer_types) and a share of the "
+                    "router's experts (moe_router_experts) are not "
+                    "implemented under tensor or pipeline parallelism "
+                    "(tp > 1, pp > 1)")
 
     # -- params ------------------------------------------------------------
     def init(self, key) -> dict:

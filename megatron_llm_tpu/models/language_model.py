@@ -93,8 +93,11 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
     including the decoder ``inter_attention`` block when present.  ``cfg``
     (when given) carries the resolved ``moe_expert_axis`` so MoE specs
     don't re-derive placement from the live mesh."""
-    attn = layers["attention"]
-    if "kv_down" in attn:
+    attn = layers.get("attention")
+    if attn is None:
+        # a stack of state-space layers alone has no attention leaves
+        attention_specs = None
+    elif "kv_down" in attn:
         # latent attention is replicated (tp is refused)
         attention_specs = {
             **{name: _linear_spec(attn[name], None, None, stacked)
@@ -110,7 +113,13 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
         }
     layer_specs = {
         "input_norm": _norm_spec(layers["input_norm"], stacked),
-        "attention": attention_specs,
+        **({} if attention_specs is None
+           else {"attention": attention_specs}),
+        # a state-space mixer is replicated (tp is refused)
+        **({"mamba": jax.tree_util.tree_map(
+            lambda a: (("stage",) if stacked else ())
+            + (None,) * (a.ndim - int(stacked)), layers["mamba"])}
+           if "mamba" in layers else {}),
         "mlp": (
             moe_mlp_specs(layers["mlp"], stacked, cfg=cfg)
             if "experts" in layers["mlp"]
@@ -125,10 +134,10 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
         ),
     }
     for name in ("q_norm", "k_norm"):
-        if name in layers["attention"]:
+        if attn is not None and name in attn:
             layer_specs["attention"][name] = _norm_spec(
                 layers["attention"][name], stacked)
-    if "indexer" in layers["attention"]:
+    if attn is not None and "indexer" in attn:
         # the sparse-attention indexer is replicated (tp is refused)
         ix = layers["attention"]["indexer"]
         layer_specs["attention"]["indexer"] = {
@@ -387,6 +396,8 @@ def language_model_forward(
             sequence_parallel=sequence_parallel,
             compute_dtype=cfg.compute_jnp_dtype,
         )
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if kv_caches is not None:
         return logits, new_caches
     return (logits, moe_aux) if cfg.num_experts > 1 else logits

@@ -46,6 +46,17 @@ Shared by both:
 * **The shared MLP** (``cfg.moe_shared_experts`` > 0): one ungated MLP
   of that many experts' width that every token passes through, added to
   the routed sum under the scope ``moe_shared``.
+* **One chip's share of a layer's experts**
+  (``cfg.moe_router_experts`` / ``cfg.moe_experts_first``; inference
+  only): the router scores ALL the layer's experts and the layer holds
+  ``cfg.num_experts`` contiguous ones of them.  What is routed: every
+  token over all the router's outputs, gates normalised over all its
+  ``top_k`` choices.  What is computed: the choices that fall on a held
+  expert, under those gates.  What is dropped: the others, which the
+  chip that holds them computes; summed over the chips' shares, with the
+  shared MLP once, that is the uncut layer
+  (``tests/test_moe.py::test_the_halves_sum_to_the_whole``).  The
+  histogram stays the ROUTER's, over all its outputs.
 * **Expert placement**: expert-stacked weights ``[E, ...]`` carry the
   ``'expert'`` logical axis, which the sharding rules map onto the ``dp``
   mesh axis (EP folded into dp, ``parallel/sharding.py``); the per-expert
@@ -155,7 +166,8 @@ def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
     E, H, F = cfg.num_experts, cfg.hidden_size, cfg.expert_hidden_size
     mult = 2 if cfg.glu_activation else 1
     params = {
-        "router": {"kernel": init(k_r, (H, E), dtype)},
+        # the router scores every expert of the layer, held here or not
+        "router": {"kernel": init(k_r, (H, cfg.routed_experts), dtype)},
         "experts": {
             "w_in": init(k_in, (E, H, mult * F), dtype),
             "w_out": out_init(k_out, (E, F, H), dtype),
@@ -164,7 +176,8 @@ def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
     if cfg.moe_choice_bias:
         params["router"]["choice_bias"] = (
             _CHOICE_BIAS_STD * jax.random.normal(
-                jax.random.fold_in(k_r, 1), (E,), jnp.float32)).astype(dtype)
+                jax.random.fold_in(k_r, 1), (cfg.routed_experts,),
+                jnp.float32)).astype(dtype)
     if cfg.moe_shared_experts:
         k_si, k_so = jax.random.split(jax.random.fold_in(key, 1))
         wide = cfg.moe_shared_experts * F
@@ -305,6 +318,14 @@ def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
     ``counts`` and get a zero output.  ``counts[e]`` is the number of
     live (token, choice) assignments expert e received.
 
+    Where the layer holds a SHARE of the experts its router scores
+    (``cfg.holds_a_share``), ``counts`` is still the router's histogram,
+    over all ``cfg.routed_experts``; an assignment whose expert is not
+    held goes where a dead token's goes (index ``E``, behind every real
+    group, gate 0), so the sort, the grouped matmuls and the gather run
+    over the held groups alone, under the gates the router gave over all
+    the token's choices.
+
     With ``layer`` (a static index: the paged-cache loop of
     ``transformer_stack``, so every engine program) ``params['experts']``
     holds the weights of EVERY layer, stacked ``[L, E, ...]`` as the model
@@ -312,6 +333,7 @@ def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
     decides how it is taken).
     """
     E, k = cfg.num_experts, cfg.moe_top_k
+    R = cfg.routed_experts          # E, unless the layer holds a share
     b, s, h = x.shape
     T = b * s
     cdtype = cfg.compute_jnp_dtype
@@ -321,13 +343,23 @@ def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
         logits, probs, gates, idx = _route(xf, params, cfg)    # [T, k]
         if live is not None:
             alive = live.reshape(T, 1)
-            # E is no expert: it sorts behind every real assignment
-            idx = jnp.where(alive, idx, E)
+            # R is no expert: it sorts behind every real assignment
+            idx = jnp.where(alive, idx, R)
             gates = jnp.where(alive, gates, 0.0)
         flat = idx.reshape(T * k)
         counts = jnp.sum(
-            flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
-            axis=0, dtype=jnp.int32)                           # [E]
+            flat[:, None] == jnp.arange(R, dtype=flat.dtype)[None, :],
+            axis=0, dtype=jnp.int32)                           # [R]
+        routed_counts = counts
+        if cfg.holds_a_share:
+            # the held experts' own numbering; the others sort behind
+            # (a dead token's R lies beyond them too)
+            here = idx - cfg.moe_experts_first
+            mine = (here >= 0) & (here < E)
+            gates = jnp.where(mine, gates, 0.0)
+            flat = jnp.where(mine, here, E).reshape(T * k)
+            counts = routed_counts[
+                cfg.moe_experts_first:cfg.moe_experts_first + E]
 
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(flat, stable=True)                 # [T*k]
@@ -354,9 +386,10 @@ def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
     shared = _shared_mlp(x, params, cfg)
     if shared is not None:
         out = out + shared.astype(jnp.float32)
-    total = jnp.maximum(jnp.sum(counts), 1).astype(jnp.float32)
-    aux = _aux_losses(logits, probs, counts.astype(jnp.float32) / total)
-    return out.astype(x.dtype), aux, counts
+    total = jnp.maximum(jnp.sum(routed_counts), 1).astype(jnp.float32)
+    aux = _aux_losses(logits, probs,
+                      routed_counts.astype(jnp.float32) / total)
+    return out.astype(x.dtype), aux, routed_counts
 
 
 def moe_mlp(
@@ -371,6 +404,10 @@ def moe_mlp(
       -> dispatch mask [b, s*k, E, c] -> expert batches [E, b, c, h]
       -> per-expert FFN (tp-sharded) -> combine back to [b, s, h].
     """
+    if cfg.holds_a_share:
+        raise NotImplementedError(
+            "a share of the router's experts (moe_router_experts) is not "
+            "implemented for the capacity einsum (training)")
     E, k = cfg.num_experts, cfg.moe_top_k
     b, s, h = x.shape
     c = moe_capacity(cfg, s)

@@ -206,7 +206,9 @@ def init_mlp_params(key, cfg: TransformerConfig, dtype):
 def init_layer_params(key, cfg: TransformerConfig, dtype,
                       layer_type: str = "encoder", sparse: bool = True):
     """``sparse`` False: a sparse model's leading dense layer, whose MLP
-    is the dense one of ``ffn_hidden_size``."""
+    is the dense one of ``ffn_hidden_size``.  For a stack with
+    state-space layers the mixer is left out: the two kinds have other
+    leaves and are stacked apart (``init_stack_params``)."""
     ka, km, kn = jax.random.split(key, 3)
     if cfg.num_experts > 1 and sparse:
         from megatron_llm_tpu.models.moe import init_moe_mlp_params
@@ -216,9 +218,10 @@ def init_layer_params(key, cfg: TransformerConfig, dtype,
         mlp_params = init_mlp_params(km, cfg, dtype)
     params = {
         "input_norm": init_norm_params(cfg.hidden_size, cfg.normalization, dtype),
-        "attention": init_attention_params(ka, cfg, dtype),
         "mlp": mlp_params,
     }
+    if not cfg.state_space:
+        params["attention"] = init_attention_params(ka, cfg, dtype)
     if not cfg.parallel_attn:
         # pre-MLP norm (reference: post_attention_layernorm)
         params["post_attention_norm"] = init_norm_params(
@@ -245,7 +248,10 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
     (transformer.py:983-1014).  A sparse model's leading dense layers
     (``cfg.moe_first_dense_layers``) have other leaves, so they are
     stacked apart, under ``dense_layers``, and ``layers`` holds the
-    sparse ones only."""
+    sparse ones only.  So are the mixers of a stack with state-space
+    layers (``cfg.state_space``): ``layers['mamba']`` and
+    ``layers['attention']`` hold the layers of each kind in their order,
+    under the norms and the MLP that every layer has."""
     keys = jax.random.split(key, cfg.num_layers)
     D = cfg.moe_first_dense_layers
 
@@ -260,6 +266,16 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
     }
     if D:
         params["dense_layers"] = stack(keys[:D], False)
+    for kind in cfg.mixer_counts:
+        init = init_attention_params
+        if kind == "mamba":
+            from megatron_llm_tpu.models.mamba import init_mamba_params as init
+        # the key the layer's attention would have had
+        mixers = [init(jax.random.split(k, 3)[0], cfg, dtype)
+                  for i, k in enumerate(keys)
+                  if cfg.mixer_index(i)[0] == kind]
+        params["layers"][kind] = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *mixers)
     return params
 
 
@@ -327,18 +343,20 @@ def core_attention(
     dropout_key: Optional[jax.Array],
     train: bool,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Unfused attention (reference ``CoreAttention``, transformer.py:144-277):
     scaled QK^T -> scale-mask-softmax -> dropout -> PV.  GQA contracts
     group-shared K/V without materialising the head broadcast
     (the reference broadcasts K/V to all Q heads, :458-465).  With no
     ``attention_mask`` the mask is causal, within ``window`` keys if
-    given."""
+    given.  ``scale`` multiplies the scores (None: ``1 / sqrt(d)``)."""
     b, sq, nh, d = q.shape
     ng = k.shape[2]
     qpg = nh // ng
     sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
 
     qg = q.reshape(b, sq, ng, qpg, d)
     # scores: [b, ng, qpg, sq, sk]
@@ -507,6 +525,11 @@ def attention(
             position_ids=position_ids, dropout_key=dropout_key, train=train,
             sequence_parallel=sequence_parallel, kv_cache=kv_cache)
     window, yarn = cfg.attention_of(layer_type)
+    # what the scores are multiplied by: a family's own multiplier, else
+    # 1 / sqrt(head_dim)
+    scale = (1.0 / math.sqrt(cfg.head_dim)
+             if cfg.attention_multiplier is None
+             else float(cfg.attention_multiplier))
     mixed = column_parallel_linear(
         x, params["query_key_value"],
         out_logical="heads",
@@ -531,7 +554,9 @@ def attention(
                          eps=cfg.layernorm_epsilon)
 
     index = None
-    if (cfg.rope_sections is not None or cfg.dsa_index_heads > 0
+    if cfg.position_embedding_type == PositionEmbeddingType.none:
+        pass    # nothing rotates and nothing is added, on any path
+    elif (cfg.rope_sections is not None or cfg.dsa_index_heads > 0
             or cfg.layer_types is not None):
         # positions are taken as given, with no table: [b, s], or
         # [streams, b, s] for the sectioned embedding (a text token's
@@ -560,7 +585,8 @@ def attention(
         # the serving engine's paged cache (ops/paged_kv.py owns it):
         # scatter this call's K/V (and the indexer's keys) into the
         # pool, attend through the path the cache carries
-        paged_ctx, new_cache = kv_cache.attend(q, k, v, window, index=index)
+        paged_ctx, new_cache = kv_cache.attend(q, k, v, window, index=index,
+                                               scale=scale)
     elif index is not None:
         # the cache-less forward selects too: what tier-1 holds the
         # paged programs against
@@ -706,7 +732,7 @@ def attention(
                 q, k, v,
                 causal=True,
                 sliding_window=window,
-                softmax_scale=1.0 / math.sqrt(cfg.head_dim),
+                softmax_scale=scale,
             )
         elif algo == "zigzag" and (q.shape[1] // cp_size) % 2 == 0:
             from megatron_llm_tpu.parallel.zigzag_ring import (
@@ -717,14 +743,14 @@ def attention(
                 q, k, v,
                 causal=True,
                 sliding_window=window,
-                softmax_scale=1.0 / math.sqrt(cfg.head_dim),
+                softmax_scale=scale,
             )
         else:
             ctx = context_parallel_attention(
                 q, k, v,
                 causal=True,
                 sliding_window=window,
-                softmax_scale=1.0 / math.sqrt(cfg.head_dim),
+                softmax_scale=scale,
             )
     elif use_flash:
         from megatron_llm_tpu.ops.pallas.flash_attention import (
@@ -737,7 +763,7 @@ def attention(
             q, k, v,
             causal=True,
             sliding_window=window,
-            softmax_scale=1.0 / math.sqrt(cfg.head_dim),
+            softmax_scale=scale,
         )
     else:
         from megatron_llm_tpu.ops.chunked_attention import (
@@ -755,11 +781,11 @@ def attention(
                 q, k, v,
                 causal=True,
                 sliding_window=window,
-                softmax_scale=1.0 / math.sqrt(cfg.head_dim),
+                softmax_scale=scale,
             )
         else:
             ctx = core_attention(q, k, v, cfg, attention_mask, dropout_key,
-                                 train, window)
+                                 train, window, scale)
 
     b, s = ctx.shape[:2]
     ctx = ctx.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
@@ -904,7 +930,10 @@ def transformer_layer(
     for a layer with a dense MLP (what ``params['mlp']`` holds decides).  With ``moe_layer`` the experts' weights
     in ``params`` are every layer's, stacked, and this layer is that one
     of them (``moe_mlp_dropless``).  ``layer_type``: the layer's, of a
-    model with ``cfg.layer_types`` (``attention`` says what it decides).
+    model with ``cfg.layer_types`` (``attention`` says what it decides);
+    a ``'mamba'`` layer's mixer is ``models/mamba.py::mamba_mixer`` over
+    ``params['mamba']`` in place of attention.  Both residual branches
+    are multiplied by ``cfg.residual_multiplier``.
     """
     is_decoder = "inter_attention" in params and encoder_output is not None
     if is_decoder and cfg.parallel_attn:
@@ -942,13 +971,33 @@ def transformer_layer(
         kv_cache=kv_cache, layer_type=layer_type,
     )
     # named_scope: trace-time profiler annotation (telemetry.py --profile)
-    with jax.named_scope("attention"):
-        if kv_cache is not None:
-            attn_out, new_cache = attention(ln_out, params["attention"], cfg,
-                                            **attn_kw)
-        else:
-            attn_out = attention(ln_out, params["attention"], cfg, **attn_kw)
+    if layer_type == "mamba":
+        from megatron_llm_tpu.models.mamba import mamba_mixer
+
+        if train or attention_mask is not None:
+            raise NotImplementedError(
+                "state-space layers ('mamba') are not implemented for "
+                "training (no backward through the chunked scan is held "
+                "to anything) nor under an explicit attention mask (packed "
+                "documents would need the state reset at each boundary)")
+        with jax.named_scope("mamba"):
+            attn_out = mamba_mixer(ln_out, params["mamba"], cfg,
+                                   kv_cache=kv_cache)
             new_cache = None
+            if kv_cache is not None:
+                attn_out, new_cache = attn_out
+    else:
+        with jax.named_scope("attention"):
+            if kv_cache is not None:
+                attn_out, new_cache = attention(
+                    ln_out, params["attention"], cfg, **attn_kw)
+            else:
+                attn_out = attention(ln_out, params["attention"], cfg,
+                                     **attn_kw)
+                new_cache = None
+    if cfg.residual_multiplier != 1.0:
+        attn_out = attn_out * jnp.asarray(cfg.residual_multiplier,
+                                          attn_out.dtype)
 
     # MoE (num_experts > 1) replaces the dense MLP and adds a routing aux
     # loss threaded up through the stack scan (models/moe.py): the
@@ -1013,6 +1062,9 @@ def transformer_layer(
             if not cfg.use_post_ln else h
         )
     mlp_out, moe_aux = run_mlp(ln2)
+    if cfg.residual_multiplier != 1.0:
+        mlp_out = mlp_out * jnp.asarray(cfg.residual_multiplier,
+                                        mlp_out.dtype)
     out = residual + _dropout(mlp_out, hidden_dropout, k_h2, train)
     if cfg.use_post_ln:
         out = norm(
@@ -1063,9 +1115,18 @@ def transformer_stack(
     A model with a layer type per layer (``cfg.layer_types``: one period
     of types) scans over PERIODS with a period's layers unrolled in the
     body, each of its own type, so the trace holds one period whatever
-    the depth; a model of one type is one period of one layer."""
+    the depth; a model of one type is one period of one layer.  Where
+    the period's mixers are of two kinds (``cfg.state_space``) their
+    parameters are stacked apart (``init_stack_params``) and a layer
+    takes its own by its index AMONG ITS KIND, in the scan and in the
+    serving loop alike."""
     layers = stack_params["layers"]
     L = cfg.num_layers
+    # the mixers of a stack with state-space layers, by kind, apart from
+    # the leaves every layer has
+    mixers = {k: layers[k] for k in cfg.mixer_counts}
+    if mixers:
+        layers = {k: v for k, v in layers.items() if k not in mixers}
     # a sparse model's leading dense layers: other leaves, so stacked
     # apart (``init_stack_params``) and run before the scan; ``layers``
     # holds the L - D that follow
@@ -1088,6 +1149,7 @@ def transformer_stack(
     @jax.named_scope("transformer_layer")
     def body(carry, scanned):
         h, aux_acc = carry if moe_on else (carry, None)
+        scanned, mixers_p = scanned if mixers else (scanned, None)
         for j, layer_type in enumerate(period):
             # a period's layer j: scanned leaves are [P, ...] there
             one = (scanned if P == 1 else
@@ -1097,6 +1159,11 @@ def transformer_stack(
             else:
                 layer_p, key = one
                 rate = None
+            if mixers:
+                # its mixer: the period's layers of its kind before it
+                at = period[:j].count(layer_type)
+                layer_p = {**layer_p, layer_type: jax.tree_util.tree_map(
+                    lambda a: a[at], mixers_p[layer_type])}
             h, _, moe_aux = transformer_layer(
                 h, layer_p, cfg,
                 rng_key=key if rng_key is not None else None,
@@ -1134,6 +1201,10 @@ def transformer_stack(
                 jax.tree_util.tree_map(lambda p: p[i], dense))
             if sparse:
                 layer_p["mlp"]["experts"] = layers["mlp"]["experts"]
+            if mixers:
+                kind, at = cfg.mixer_index(i)
+                layer_p[kind] = jax.tree_util.tree_map(
+                    lambda p: p[at], mixers[kind])
             h, c, _ = transformer_layer(
                 h, layer_p, cfg, rng_key=None, train=False,
                 kv_cache=kv_caches[i],
@@ -1166,6 +1237,12 @@ def transformer_stack(
         # [L, ...] -> [L / P, P, ...]: the scan's step is a period
         scanned = jax.tree_util.tree_map(
             lambda a: a.reshape((L // P, P) + a.shape[1:]), scanned)
+    if mixers:
+        # a kind's [its layers, ...] -> [L / P, its layers a period, ...]
+        scanned = (scanned, {
+            k: jax.tree_util.tree_map(
+                lambda a: a.reshape((L // P, -1) + a.shape[1:]), m)
+            for k, m in mixers.items()})
     init_carry = (x, jnp.zeros((2,), jnp.float32)) if moe_on else x
     carry, _ = jax.lax.scan(body, init_carry, scanned)
     h, moe_aux = carry if moe_on else (carry, None)

@@ -43,6 +43,22 @@ layer OF ONE GROUP; ``layer_groups`` says which layer is of which.  A
 model of one layer type has one group and its pools are as they always
 were, whatever its window.
 
+A model with state-space layers (``cfg.state_space``) has a THIRD kind
+of per-request state, group ``state``: a ``mamba`` layer keeps no keys
+and no pages but two arrays of fixed size a request, indexed by SLOT:
+``conv_state`` ``[slots + 1, d_conv - 1, conv_dim]`` (the last columns
+before the convolution; the taps lie in the sublanes: a last dimension
+of 3 would be laid out at 128 lanes) and ``ssm_state`` ``[slots + 1,
+heads, d_head, d_state]`` in float32 (``SSM_STATE_DTYPE``: a recurrence
+multiplied and added to over a request's every token).  The last row is
+the garbage row an idle row writes to, as page 0 is.  A launch whose
+``context_lens`` is 0 reads zeros whatever the slot held
+(``read_state``), so a slot is reused with no clearing launch.  Its
+attention layers are of the ``full`` group.  The page programs,
+copy-on-write and ``block_bytes`` are for pools WITH pages:
+``paged_pools`` picks them, and ``array_shapes`` gives the state's
+arrays a list of their own.
+
 A :class:`PagedKVCache` is what the model is handed for one step of one
 layer: the pool plus the step's state (block tables, context lengths,
 valid lengths) and, as STATIC data, the path that reads the pool:
@@ -105,17 +121,22 @@ def expands_latents(kernel: str, n: int) -> bool:
     return n > 1 and kernel == "pallas" and _pa.kernel_available()
 
 
-FULL, WINDOW = "full", "window"
+FULL, WINDOW, STATE = "full", "window", "state"
+# the recurrent state's dtype: an ASSUMPTION (the published config gives
+# no cache dtype), float32 because it is multiplied and added to at every
+# token of a request
+SSM_STATE_DTYPE = jnp.float32
+_GROUP_OF = {"sliding": WINDOW, "mamba": STATE}
 
 
 def layer_groups(cfg) -> Optional[tuple]:
     """The pool group of each layer of a model with a layer type per
-    layer (``FULL`` | ``WINDOW``); None for a model of one type, which
-    has one group."""
+    layer (``FULL`` | ``WINDOW`` | ``STATE``); None for a model of one
+    type, which has one group."""
     if cfg.layer_types is None:
         return None
     period = cfg.layer_period
-    return tuple(WINDOW if period[i % len(period)] == "sliding" else FULL
+    return tuple(_GROUP_OF.get(period[i % len(period)], FULL)
                  for i in range(cfg.num_layers))
 
 
@@ -128,14 +149,16 @@ def window_pages_bound(window: int, chunk: int, block_size: int) -> int:
 
 def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                quantized: bool = False,
-               window_blocks: Optional[int] = None) -> List[dict]:
+               window_blocks: Optional[int] = None,
+               num_slots: Optional[int] = None) -> List[dict]:
     """One pool a layer for a model of config ``cfg``: keys and values in
     the compute dtype, or int8 with fp32 scales when ``quantized`` (halves
     the KV bytes a decode step reads, against bf16); with a
     sparse-attention indexer, its keys beside them (``index_pages``).  A
     layer's pool is sized by its group: ``window_blocks`` for the window
     group of a model with a layer type per layer, ``num_blocks`` for
-    every other layer."""
+    every other layer; a state-space layer's is its two arrays a slot,
+    ``num_slots`` of them and the garbage row."""
     dtype = dtype or cfg.compute_jnp_dtype
     indexed = cfg.dsa_index_heads > 0
     groups = layer_groups(cfg)
@@ -155,6 +178,18 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     if groups is not None and WINDOW in groups and not window_blocks:
         raise ValueError("a model with sliding layers among its "
                          "layer_types needs window_blocks")
+    if groups is not None and STATE in groups and not num_slots:
+        raise ValueError("a model with state-space layers among its "
+                         "layer_types needs num_slots")
+
+    def state():
+        return {
+            "conv_state": jnp.zeros(
+                (num_slots + 1, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim),
+                dtype),
+            "ssm_state": jnp.zeros(
+                (num_slots + 1, cfg.mamba_n_heads, cfg.mamba_d_head,
+                 cfg.mamba_d_state), SSM_STATE_DTYPE)}
 
     def pool(blocks):
         shape = (blocks, block_size, cfg.num_query_groups, cfg.head_dim)
@@ -171,8 +206,35 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                 dtype)
         return kv
 
-    return [pool(window_blocks if groups and groups[i] == WINDOW
+    return [state() if groups and groups[i] == STATE else
+            pool(window_blocks if groups and groups[i] == WINDOW
                  else num_blocks) for i in range(cfg.num_layers)]
+
+
+def is_state(pool: dict) -> bool:
+    """Whether a layer's pool is a state-space layer's (arrays a slot,
+    no pages)."""
+    return "ssm_state" in pool
+
+
+def paged_pools(pools) -> List[dict]:
+    """The pools that have pages: what the page programs, copy-on-write
+    and ``block_bytes`` run over."""
+    return [p for p in pools if not is_state(p)]
+
+
+def with_paged(pools, paged) -> List[dict]:
+    """``pools`` with its paged pools replaced by ``paged``, in order."""
+    paged = iter(paged)
+    return [p if is_state(p) else next(paged) for p in pools]
+
+
+def state_bytes_per_slot(pools) -> int:
+    """Bytes one slot's recurrent state takes over the state-space
+    layers of ``pools`` (0 for a model with none)."""
+    return sum(math.prod(a.shape[1:]) * a.dtype.itemsize
+               for p in pools if is_state(p)
+               for a in jax.tree_util.tree_leaves(p))
 
 
 def latent_width(cfg) -> int:
@@ -190,22 +252,37 @@ def _arrays(pool: dict):
 
 
 def block_bytes(pools) -> int:
-    """Bytes of one block across every layer's pool (of a model with two
-    groups: across the layers of ``pools``, which the caller picks by
-    group)."""
+    """Bytes of one block across every layer's pool that has pages (of a
+    model with two groups of pages: across the layers of ``pools``,
+    which the caller picks by group)."""
     return sum(math.prod(a.shape[1:]) * a.dtype.itemsize
-               for a in jax.tree_util.tree_leaves(pools))
+               for a in jax.tree_util.tree_leaves(paged_pools(pools)))
 
 
 def array_shapes(pools) -> set:
     """``(dtype name, shape)`` of every array of every layer's pool, as
     allocated ``[blocks, block_size, ...]`` and as ``attend`` writes it,
     one row a token ``[blocks * block_size, ...]``: what an instruction of
-    a compiled program is held against to say that it moves the pool."""
+    a compiled program is held against to say that it moves the pool.
+    A state-space layer's arrays are not among them: ``state_shapes``."""
     out = set()
-    for a in jax.tree_util.tree_leaves(pools):
+    for a in jax.tree_util.tree_leaves(paged_pools(pools)):
         out.add((a.dtype.name, tuple(a.shape)))
         out.add((a.dtype.name, (a.shape[0] * a.shape[1],) + a.shape[2:]))
+    return out
+
+
+def state_shapes(pools) -> set:
+    """``(dtype name, shape)`` of every array of every state-space
+    layer's pool, as allocated and as a decode step writes its live rows
+    (without the garbage row): the role ``ssm_state`` of a compiled
+    program's instructions."""
+    out = set()
+    for p in pools:
+        if is_state(p):
+            for a in p.values():
+                out.add((a.dtype.name, tuple(a.shape)))
+                out.add((a.dtype.name, (a.shape[0] - 1,) + a.shape[1:]))
     return out
 
 
@@ -247,7 +324,10 @@ class PagedKVCache:
     type): ``block_tables`` is that group's table, and the window
     group's walk launches under its own kernel names
     (``paged_attention_decode_window``, ``paged_attention_prefill_window``)
-    so that a trace tells the two kinds of attention apart."""
+    so that a trace tells the two kinds of attention apart.  A
+    state-space layer's cache (group ``STATE``) holds its two arrays a
+    slot and ``slots`` [b], each row's slot (None: row s is slot s, the
+    decode step); its ``block_tables`` is not read."""
 
     pool: dict
     block_tables: jax.Array
@@ -256,13 +336,52 @@ class PagedKVCache:
     kernel: str = dataclasses.field(metadata=dict(static=True))
     moe_counts: Optional[jax.Array] = None
     group: str = dataclasses.field(default=FULL, metadata=dict(static=True))
+    slots: Optional[jax.Array] = None
+
+    def read_state(self):
+        """A state-space layer's (``conv_state`` [b, d_conv - 1,
+        conv_dim], ``ssm_state`` [b, heads, d_head, d_state]) as each row
+        finds them: its slot's, or zeros where the row's
+        ``context_lens`` is 0 (a request's first launch, whatever the
+        slot held)."""
+        b = self.context_lens.shape[0]
+        fresh = self.context_lens == 0
+
+        def rows(a):
+            a = a[:b] if self.slots is None else a[self.slots]
+            return jnp.where(fresh.reshape((b,) + (1,) * (a.ndim - 1)),
+                             jnp.zeros((), a.dtype), a)
+
+        return rows(self.pool["conv_state"]), rows(self.pool["ssm_state"])
+
+    def write_state(self, conv_state: jax.Array, ssm_state: jax.Array):
+        """The cache as a state-space layer's call leaves it: each live
+        row's state written at its slot (an idle row's, ``valid_lens``
+        0, at the garbage row), ``context_lens`` advanced."""
+        b = self.context_lens.shape[0]
+        live = self.valid_lens > 0
+        new = {"conv_state": conv_state, "ssm_state": ssm_state}
+        pool = {}
+        for name, a in self.pool.items():
+            val = new[name].astype(a.dtype)
+            if self.slots is None:
+                # row s is slot s: one pass over the live rows
+                keep = live.reshape((b,) + (1,) * (a.ndim - 1))
+                pool[name] = jnp.concatenate(
+                    [jnp.where(keep, val, a[:b]), a[b:]])
+            else:
+                dest = jnp.where(live, self.slots, a.shape[0] - 1)
+                pool[name] = a.at[dest].set(val)
+        return dataclasses.replace(
+            self, pool=pool, context_lens=self.context_lens + self.valid_lens)
 
     def live(self, n: int) -> jax.Array:
         """[b, n] bool: which of this call's n tokens a row are real."""
         return jnp.arange(n)[None, :] < self.valid_lens[:, None]
 
     def attend(self, q: jax.Array, k: jax.Array, v: jax.Array,
-               sliding_window: Optional[int], index=None):
+               sliding_window: Optional[int], index=None,
+               scale: Optional[float] = None):
         """Write this call's keys and values ``[b, n, g, d]`` at
         ``context_lens ..``, then attend ``q`` [b, n, nh, d] over the
         row's history and the chunk's own causal prefix.  Returns the
@@ -275,7 +394,8 @@ class PagedKVCache:
         key [b, n, di], head weights [b, n, Hi], topk)``.  The key is
         written beside K and V, and each query then attends only the
         ``topk`` keys its indexer scores highest over the same range
-        (``ops/dsa.py`` says exactly which)."""
+        (``ops/dsa.py`` says exactly which).  ``scale`` multiplies the
+        scores (None: ``1 / sqrt(d)``)."""
         from megatron_llm_tpu.ops.pallas import paged_attention as _pa
 
         if (index is not None) != ("index_pages" in self.pool):
@@ -300,7 +420,8 @@ class PagedKVCache:
                 index[1], self.pool["index_pages"].shape[-1])
         pool = self._write(writes, n)
         kp, vp, k_scales, v_scales = _arrays(pool)
-        scale = 1.0 / math.sqrt(d)
+        if scale is None:
+            scale = 1.0 / math.sqrt(d)
         if index is not None:
             ctx = self._attend_selected(q, pool, index, scale)
         elif self.kernel == "pallas":
@@ -437,13 +558,18 @@ def step_caches(pools, block_tables, context_lens, valid_lens,
     is what ``auto`` means for a program on one device: the kernel where
     it can run (whoever jits for several devices says ``'xla'``).  With
     ``groups`` (``layer_groups``) ``block_tables`` is a dict of a table a
-    group and each layer carries its own group's."""
+    group and each layer carries its own group's.  A state-space layer
+    reads no table: its entry ``block_tables[STATE]``, where there is
+    one, is ``[b]`` each row's SLOT (a prefill chunk's; a decode step has
+    none: row s is slot s)."""
     kernel = kernel or resolve_kernel("auto", one_device=True)
     if groups is None:
         return [PagedKVCache(p, block_tables, context_lens, valid_lens,
                              kernel=kernel) for p in pools]
-    return [PagedKVCache(p, block_tables[g], context_lens, valid_lens,
-                         kernel=kernel, group=g)
+    return [PagedKVCache(p, block_tables[FULL if g == STATE else g],
+                         context_lens, valid_lens, kernel=kernel, group=g,
+                         slots=block_tables.get(STATE) if g == STATE
+                         else None)
             for p, g in zip(pools, groups)]
 
 

@@ -108,6 +108,7 @@ from megatron_llm_tpu.serving.loop_profiler import (
     MLA_FIELDS,
     KV_FIELDS,
     MOE_FIELDS,
+    SSM_FIELDS,
     DispatchRecord,
     LoopProfiler,
     RequestSpan,
@@ -344,17 +345,39 @@ class InferenceEngine:
         # is not adopted: its window pages are not kept
         self._layer_groups = paged_kv.layer_groups(mcfg)
         self._window_bound = self._window_blocks = 0
+        self._state_layers = 0
+        # the experts this chip holds among those its router scores
+        # (None: all), for the launch records
+        self._experts_held = (
+            slice(mcfg.moe_experts_first,
+                  mcfg.moe_experts_first + mcfg.num_experts)
+            if mcfg.holds_a_share else None)
         if self._layer_groups is not None:
+            self._state_layers = self._layer_groups.count(paged_kv.STATE)
+            whose = ("state-space layers ('mamba' among layer_types) are"
+                     if self._state_layers else
+                     "a layer type per layer (layer_types) is")
             for on, what in ((self.speculative, "the speculative verify "
                               "step"), (cfg.int8_kv_cache, "the int8 KV "
                               "pool"), (cfg.host_cache_bytes > 0, "the "
                               "host KV tier")):
                 if on:
-                    raise ValueError("a layer type per layer (layer_types) "
-                                     f"is not implemented for {what}")
+                    raise ValueError(f"{whose} not implemented for {what}")
+            # state-space layers: a third kind of per-request state, two
+            # arrays a SLOT beside the pages (ops/paged_kv.py).  No
+            # snapshot of a state is kept, so a request that gave its
+            # slot up could not take its state back: preemption is
+            # refused, and a prefix is not adopted
+            if self._state_layers and cfg.preemption:
+                raise ValueError(
+                    "state-space layers ('mamba' among layer_types) are "
+                    "not implemented for preemption (no snapshot of a "
+                    "request's state is kept): set preemption off "
+                    "(--serve_preemption=0)")
             if cfg.prefix_cache:
                 print(" * a layer type per layer: the prefix cache adopts "
-                      "nothing (a prefix's window pages are not kept)",
+                      "nothing (a prefix's window pages, and a state-space "
+                      "layer's state at its end, are not kept)",
                       flush=True)
                 cfg.prefix_cache = False
             if paged_kv.WINDOW in self._layer_groups:
@@ -397,6 +420,8 @@ class InferenceEngine:
         # device→host spill source and its host→device swap-in.  src /
         # dst are traced int32 scalars and a host page has fixed shapes,
         # so one compile each (at warmup) covers every event
+        # (over the pools that HAVE pages: a state-space layer's arrays
+        # are indexed by slot and no page program sees them)
         self._cow_copy = _program(paged_kv.copy_page, "engine_cow_copy")
         self._fetch_block = _program(paged_kv.fetch_page,
                                      "engine_fetch_block")
@@ -441,7 +466,7 @@ class InferenceEngine:
         # the two groups of a model with a layer type per layer, summed
         # over launches (the record's fields of the same names), and a
         # block's bytes over the layers of each group (full, window)
-        for f in KV_FIELDS:
+        for f in KV_FIELDS + SSM_FIELDS:
             setattr(self, f, 0)
         groups = self._layer_groups or ()
         self._group_block_bytes = tuple(
@@ -493,6 +518,10 @@ class InferenceEngine:
                 # queued spills reference the abandoned pool; resident
                 # host entries and counters survive the restart
                 self.host_cache.on_pool_reset()
+        pages = paged_kv.init_pools(
+            self.model.cfg, self._num_blocks, cfg.block_size,
+            quantized=cfg.int8_kv_cache, window_blocks=self._window_blocks,
+            num_slots=cfg.num_slots)
         window = None
         if self._window_blocks:
             window = WindowGroup(
@@ -503,7 +532,9 @@ class InferenceEngine:
                               cfg.num_slots, self._max_blocks_per_slot,
                               prefix_cache=cfg.prefix_cache,
                               observatory=self.cache_observatory,
-                              host_cache=self.host_cache, window=window)
+                              host_cache=self.host_cache, window=window,
+                              state_bytes_per_slot=(
+                                  paged_kv.state_bytes_per_slot(pages)))
         sched = Scheduler(self.queue, blocks, cfg.max_model_len,
                           draft_k=self.draft_k)
         if carry is not None:
@@ -529,10 +560,7 @@ class InferenceEngine:
             gen=gen,
             blocks=blocks,
             scheduler=sched,
-            pages=paged_kv.init_pools(self.model.cfg, self._num_blocks,
-                                      cfg.block_size,
-                                      quantized=cfg.int8_kv_cache,
-                                      window_blocks=self._window_blocks),
+            pages=pages,
             last_tokens=np.zeros(S, np.int32),
             context_lens=np.zeros(S, np.int32),
             active=np.zeros(S, np.int32),
@@ -1021,7 +1049,25 @@ class InferenceEngine:
         tables = {paged_kv.FULL: full}
         if st.blocks.window is not None:
             tables[paged_kv.WINDOW] = st.blocks.window.tables[rows].copy()
+        if self._state_layers and rows != slice(None):
+            # a state-space layer's "table": each row's slot (a decode
+            # step takes every slot, row s is slot s, and carries none)
+            tables[paged_kv.STATE] = np.arange(
+                self.config.num_slots, dtype=np.int32)[rows]
         return tables
+
+    def _note_state(self, st: _EngineState, d: DispatchRecord, rows: int,
+                    tokens: int) -> None:
+        """A launch of a model with state-space layers: the rows whose
+        state it advances and the tokens it scans, on the record and in
+        the running totals (``SSM_FIELDS``)."""
+        if not self._state_layers:
+            return
+        d.note_state(rows, tokens, self._state_layers,
+                     len(st.scheduler.active)
+                     * st.blocks.state_bytes_per_slot)
+        for f in SSM_FIELDS:
+            setattr(self, f, getattr(self, f) + getattr(d, f))
 
     def _window_advance(self, st: _EngineState, d: DispatchRecord,
                         writes) -> None:
@@ -1051,8 +1097,14 @@ class InferenceEngine:
         res = st.blocks.ensure_writable(slot, block_idx)
         if res is not None:
             new_b, src_b = res
-            st.pages = self._cow_copy(st.pages, np.int32(src_b),
-                                      np.int32(new_b))
+            st.pages = self._copy_page(st.pages, src_b, new_b)
+
+    def _copy_page(self, pages, src: int, dst: int):
+        """``pages`` with page ``src`` of every pool that has pages
+        copied to ``dst``."""
+        copied = self._cow_copy(paged_kv.paged_pools(pages), np.int32(src),
+                                np.int32(dst))
+        return paged_kv.with_paged(pages, copied)
 
     def _swap_in(self, st: _EngineState, req: Request) -> None:
         """Replay the slot's host-tier hits: one fixed-shape
@@ -1119,6 +1171,7 @@ class InferenceEngine:
         d.traces = (req.trace_id,) if req.trace_id else ()
         self._note_selection(d, start + 1 + np.arange(valid),
                              np.asarray([start]), np.asarray([valid]), C)
+        self._note_state(st, d, 1, valid)
         d.mark("build_inputs")
         finite = True
         last_logits, st.pages, routing = self._prefill_step(
@@ -1190,7 +1243,7 @@ class InferenceEngine:
         running totals."""
         if counts is None:
             return
-        d.note_routing(counts)
+        d.note_routing(counts, self._experts_held)
         for f in MOE_FIELDS:
             setattr(self, f, getattr(self, f) + getattr(d, f))
 
@@ -1237,6 +1290,7 @@ class InferenceEngine:
         d.sampler_rows_filtered = int(filtered.sum())
         self._note_selection(d, st.context_lens[slots] + 1,
                              st.context_lens, st.active, 1)
+        self._note_state(st, d, len(slots), len(slots))
         self.sample_draw_steps += d.sampler_rows_drawn > 0
         self.sample_sort_steps += d.sampler_rows_filtered > 0
 
@@ -1570,7 +1624,7 @@ class InferenceEngine:
                 raise TimeoutError("engine warmup did not converge")
         # compile the copy-on-write page copy (garbage -> garbage is a
         # no-op) so a later COW event can't trip the recompile detector
-        st.pages = self._cow_copy(st.pages, np.int32(0), np.int32(0))
+        st.pages = self._copy_page(st.pages, 0, 0)
         if self.host_cache is not None:
             # compile the host-tier pair the same way: gather the
             # garbage page to host, scatter it straight back — both
@@ -1607,7 +1661,7 @@ class InferenceEngine:
                     sharding=pages.sharding),
                 st.keys[0], st.top_ks[0], st.top_ps[0], st.temps[0],
                 st.ban_a[0], st.ban_b[0], zero),
-            "engine_cow_copy": (st.pages, zero, zero),
+            "engine_cow_copy": (paged_kv.paged_pools(st.pages), zero, zero),
         }
         if self.speculative:
             found["engine_verify"] = (
@@ -1647,6 +1701,7 @@ class InferenceEngine:
         if not self.warmed_up:
             return {}
         pool = paged_kv.array_shapes(self._st.pages)
+        state = paged_kv.state_shapes(self._st.pages)
         tables = {}
         for name, args in self._program_arguments().items():
             args = jax.tree_util.tree_map(_abstract, args)
@@ -1656,7 +1711,8 @@ class InferenceEngine:
                                                    sharding=sharding), args)
             text = self._jitted[name].lower(*args).compile().as_text()
             tables[name] = hlo_collectives.ProgramTable(
-                name, hlo_collectives.instructions(text), pool=pool)
+                name, hlo_collectives.instructions(text), pool=pool,
+                state=state)
         if sharding is None:
             self._program_tables = self.loop_profiler.programs = tables
         return tables
@@ -1702,7 +1758,7 @@ class InferenceEngine:
             **({"moe_expert_tiles": self.moe_expert_tiles}
                if self.moe_expert_tiles else {}),
             **{f: getattr(self, f) for f in DSA_FIELDS + MLA_FIELDS},
-            **{f: getattr(self, f) for f in KV_FIELDS},
+            **{f: getattr(self, f) for f in KV_FIELDS + SSM_FIELDS},
             "engine_restarts": self.engine_restarts,
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,
             "loop": self.loop_profiler.stats(),

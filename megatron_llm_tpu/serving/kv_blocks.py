@@ -261,7 +261,8 @@ class BlockManager:
     def __init__(self, num_blocks: int, block_size: int, num_slots: int,
                  max_blocks_per_slot: int, prefix_cache: bool = False,
                  observatory: Optional[CacheObservatory] = None,
-                 host_cache=None, window: Optional[WindowGroup] = None):
+                 host_cache=None, window: Optional[WindowGroup] = None,
+                 state_bytes_per_slot: int = 0):
         assert num_blocks >= 2, "need at least one block beyond the garbage"
         assert block_size >= 1 and num_slots >= 1
         if window is not None and (prefix_cache or host_cache is not None):
@@ -270,6 +271,16 @@ class BlockManager:
             raise ValueError("a window group adopts no prefix: it needs "
                              "prefix_cache off and no host tier")
         self.window = window
+        # a model with state-space layers: what a slot's recurrent state
+        # takes (ops/paged_kv.py's ``state`` group).  A slot IS its
+        # state's place, so admission needs nothing beyond the free slot
+        # it always needed; the bytes are for stats()
+        self.state_bytes_per_slot = int(state_bytes_per_slot)
+        if self.state_bytes_per_slot and (prefix_cache
+                                          or host_cache is not None):
+            raise ValueError("a model with state-space layers adopts no "
+                             "prefix (a prefix's state is not kept): it "
+                             "needs prefix_cache off and no host tier")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_slots = int(num_slots)
@@ -749,14 +760,23 @@ class BlockManager:
                     "window_blocks_free": self.window.pages_free(),
                     "window_pages_returned": self.window.pages_returned,
                     "window_pages_spanned": self.window.pages_spanned}),
+                **({} if not self.state_bytes_per_slot else {
+                    "state_bytes_per_slot": self.state_bytes_per_slot,
+                    "state_bytes_held": self.state_bytes_per_slot * (
+                        self.num_slots - len(self._free_slots))}),
             }
 
     def cache_stats(self) -> Dict[str, object]:
         """The observatory's ``cache`` block (heat top-K, miss causes,
         eviction forensics, ghost-tier projections) — nested under
         ``cache`` in engine stats()/metrics; scalar leaves flatten into
-        the Prometheus exposition and fleet-sum across replicas."""
-        return self.observatory.stats()
+        the Prometheus exposition and fleet-sum across replicas.  A
+        model with state-space layers says that it adopts nothing."""
+        stats = self.observatory.stats()
+        if self.state_bytes_per_slot:
+            stats = {**stats, "adopts_no_prefix":
+                     "a prefix's recurrent state is not kept"}
+        return stats
 
     def check_invariants(self) -> None:
         """Debug/test hook: every usable block is in exactly one of

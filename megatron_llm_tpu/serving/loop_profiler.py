@@ -104,7 +104,7 @@ LOOP_PHASE_BUCKETS = (
 # a launch's routing counts (DispatchRecord.note_routing), which the
 # engine also keeps running totals of
 MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
-              "moe_busiest_expert_assignments")
+              "moe_busiest_expert_assignments", "moe_assignments_held")
 
 # a launch's account of learned sparse attention (DispatchRecord's fields;
 # all 0 for a model with no indexer), which the engine also keeps running
@@ -116,6 +116,11 @@ DSA_FIELDS = ("dsa_keys_live", "dsa_keys_selected",
 # for a model without a latent pool), which the engine also keeps running
 # totals of
 MLA_FIELDS = ("mla_keys_live", "mla_pairs", "mla_latents_expanded")
+
+# a launch's account of its state-space layers (DispatchRecord's fields;
+# all 0 for a model with none), which the engine also keeps running
+# totals of
+SSM_FIELDS = ("ssm_rows_live", "ssm_tokens", "ssm_state_bytes_held")
 
 # a launch's account of the two page groups of a model with a layer type
 # per layer (DispatchRecord's fields; all 0 for a model of one type),
@@ -182,6 +187,17 @@ class DispatchRecord:
     moe_experts_touched = 0
     moe_expert_slots = 0
     moe_busiest_expert_assignments = 0
+    # of the live assignments, those that fell on an expert this chip
+    # holds (all of them unless the layer holds a share of its router's
+    # experts: ``cfg.moe_router_experts``)
+    moe_assignments_held = 0
+    # state-space layers (a model with 'mamba' layers; 0 otherwise): live
+    # rows x layers whose state the launch advances; tokens scanned x
+    # layers; bytes of recurrent state the admitted requests hold as the
+    # launch begins (slots in use x a slot's state over the layers)
+    ssm_rows_live = 0
+    ssm_tokens = 0
+    ssm_state_bytes_held = 0
     # the sampler's work in a decode/verify launch: live rows that are
     # not greedy (any makes the step draw), and of those the rows with an
     # active top-k or top-p (any makes the step sort)
@@ -309,8 +325,17 @@ class DispatchRecord:
             "sampler_rows_drawn": self.sampler_rows_drawn,
             "sampler_rows_filtered": self.sampler_rows_filtered,
             **{f: getattr(self, f) for f in DSA_FIELDS + MLA_FIELDS},
-            **{f: getattr(self, f) for f in KV_FIELDS},
+            **{f: getattr(self, f) for f in KV_FIELDS + SSM_FIELDS},
         }
+
+    def note_state(self, rows: int, tokens: int, layers: int,
+                   held_bytes: int) -> None:
+        """A launch of a model with ``layers`` state-space layers: the
+        live rows whose state it advances, the tokens it scans (a decode
+        launch: one a live row), and the bytes of state held."""
+        self.ssm_rows_live = layers * int(rows)
+        self.ssm_tokens = layers * int(tokens)
+        self.ssm_state_bytes_held = int(held_bytes)
 
     def note_latent(self, sees, layers: int, expanded: int = 0) -> None:
         """``sees``: for each live query of the launch the keys it sees
@@ -335,10 +360,15 @@ class DispatchRecord:
         self.dsa_select_blocks_counted = layers * int(steps.sum())
         self.dsa_select_blocks_table = layers * steps.size * table_blocks
 
-    def note_routing(self, counts) -> None:
+    def note_routing(self, counts, held: Optional[slice] = None) -> None:
         """``counts`` [layers, E]: the launch's histogram of live
-        assignments, as its program returned it."""
+        assignments over the experts the router scores, as its program
+        returned it; ``held``: the experts among them this chip holds
+        (None: all)."""
         self.moe_assignments = int(counts.sum())
+        self.moe_assignments_held = (
+            self.moe_assignments if held is None
+            else int(counts[:, held].sum()))
         self.moe_experts_touched = int((counts > 0).sum())
         self.moe_expert_slots = int(counts.size)
         self.moe_busiest_expert_assignments = int(counts.max(axis=1).sum())

@@ -630,7 +630,17 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    layers and launches, all 0 for a model without a latent pool, and
 #    every launch record carries the same three for that launch — see
 #    serving/loop_profiler.py ``MLA_FIELDS``
-TELEMETRY_SCHEMA_VERSION = 20
+# 21: state-space layers and a share of the experts: engine stats() / the
+#    engine block of /metrics gain ssm_rows_live (live rows x state-space
+#    layers whose state a launch advances), ssm_tokens (tokens scanned x
+#    those layers), ssm_state_bytes_held (bytes of recurrent state the
+#    admitted requests hold, summed over launches as the others are) and
+#    moe_assignments_held (of moe_assignments, those on an expert this
+#    chip holds: equal unless moe_router_experts is set), and every launch
+#    record carries the same four for that launch; blocks stats() gain
+#    state_bytes_per_slot / state_bytes_held — see
+#    serving/loop_profiler.py ``SSM_FIELDS``
+TELEMETRY_SCHEMA_VERSION = 21
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -346,3 +346,152 @@ def test_the_shared_mlp_is_counted_once(train):
                                np.asarray(routed + shared), atol=1e-5)
     specs = moe_mlp_specs(p, stacked=False, cfg=cfg)
     assert specs["shared"]["dense_h_to_4h"]["kernel"] == (None, "ffn")
+
+
+# ---------------------------------------------------------------------------
+# one chip's share of a layer's experts (cfg.moe_router_experts)
+# ---------------------------------------------------------------------------
+
+def _share(full_cfg, full, first, held):
+    """(config, parameters) of the layer that holds experts
+    ``first .. first + held`` of ``full``'s."""
+    cfg = full_cfg.replace(num_experts=held,
+                           moe_router_experts=full_cfg.num_experts,
+                           moe_experts_first=first)
+    p = dict(full, experts=jax.tree_util.tree_map(
+        lambda w: w[first:first + held], full["experts"]))
+    return cfg, p
+
+
+@pytest.mark.parametrize("parts", [(4, 4), (2, 6), (3, 3, 2)])
+def test_the_halves_sum_to_the_whole(parts):
+    """THE SHARE TEST: the routed sums of the shares of a layer's experts
+    (each computed by a layer that holds only its own, under the gates the
+    router gave over ALL the token's choices) plus the shared MLP ONCE are
+    the uncut layer, the program's and the plain reference's alike; with
+    some tokens not live."""
+    import importlib.util
+    import os
+
+    from megatron_llm_tpu.models.moe import moe_mlp_dropless
+
+    cfg = _cfg(num_experts=8, moe_top_k=3, moe_shared_experts=2)
+    full = init_moe_mlp_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    full = jax.tree_util.tree_map(lambda w: w * 6.0, full)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32))
+    live = jnp.arange(24)[None, :] < jnp.asarray([24, 17])[:, None]
+    whole, _, counts = moe_mlp_dropless(x, full, cfg, live)
+    shared = jnp.where(live[..., None], dense_mlp(x, full["shared"], cfg),
+                       0.0)
+    total, first, seen = 0.0, 0, 0
+    for held in parts:
+        c, p = _share(cfg, full, first, held)
+        assert p["experts"]["w_in"].shape[0] == held
+        out, _, router_counts = moe_mlp_dropless(x, p, c, live)
+        # the histogram stays the ROUTER's, over all eight
+        assert (np.asarray(router_counts) == np.asarray(counts)).all()
+        out = jnp.where(live[..., None], out, 0.0) - shared
+        assert np.abs(np.asarray(out)).max() > 0.05
+        total = total + out
+        seen += int(np.asarray(counts)[first:first + held].sum())
+        first += held
+    assert seen == int(np.asarray(counts).sum()) == 3 * (24 + 17)
+    whole = jnp.where(live[..., None], whole, 0.0)
+    np.testing.assert_allclose(np.asarray(total + shared),
+                               np.asarray(whole), atol=2e-5)
+    # and the uncut REFERENCE's layer (benchmarks/reference/granite.py),
+    # every expert computed, on the live tokens of the first row
+    spec = importlib.util.spec_from_file_location(
+        "ref_granite", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", "reference", "granite.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+
+    class Weights:
+        @staticmethod
+        def expert(i, e):
+            w_in = full["experts"]["w_in"][e]
+            return {"w1": w_in[:, :64], "w3": w_in[:, 64:],
+                    "w2": full["experts"]["w_out"][e]}
+
+    sh_in = full["shared"]["dense_h_to_4h"]["kernel"]
+    w = {"ffn_norm": jnp.ones((32,)), "gate": full["router"]["kernel"],
+         "shared_w1": sh_in[:, :128], "shared_w3": sh_in[:, 128:],
+         "shared_w2": full["shared"]["dense_4h_to_h"]["kernel"]}
+    # the reference norms its input: hand it the normed rows
+    xn = ref.rms_norm(x[0], w["ffn_norm"], 1e-5)
+    uncut, _, chose, _ = ref.moe_out(
+        x[0], w, Weights, {"num_experts_per_tok": 3, "rms_norm_eps": 1e-5,
+                           "num_local_experts": 8}, 0, {}, frozenset(),
+        held=range(8))
+    mine, _, _ = moe_mlp_dropless(xn[None], full, cfg)
+    np.testing.assert_allclose(np.asarray(mine[0]), np.asarray(uncut),
+                               atol=2e-5)
+    assert chose.shape == (24, 3)
+
+
+def test_a_layer_that_holds_all_its_experts_traces_todays_program():
+    """``moe_router_experts`` None, or equal to ``num_experts``: the
+    dropless layer's program is, equation for equation, the one a
+    configuration without the field traces."""
+    from megatron_llm_tpu.models.moe import moe_mlp_dropless
+
+    cfg = _cfg(num_experts=8, moe_top_k=3)
+    p = init_moe_mlp_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jnp.zeros((2, 8, 32))
+    live = jnp.ones((2, 8), bool)
+    plain = str(jax.make_jaxpr(
+        lambda q: moe_mlp_dropless(x, q, cfg, live))(p))
+    same = str(jax.make_jaxpr(lambda q: moe_mlp_dropless(
+        x, q, cfg.replace(moe_router_experts=8), live))(p))
+    assert plain == same and not cfg.holds_a_share
+    share, ps = _share(cfg, p, 2, 4)
+    assert share.holds_a_share and share.routed_experts == 8
+    assert str(jax.make_jaxpr(lambda q: moe_mlp_dropless(
+        x, q, share, live))(ps)) != plain
+
+
+def test_a_share_keeps_the_gates_of_all_the_choices_and_refuses_training():
+    """A held choice's gate is what the router gave it over ALL the
+    token's choices (no renormalising over the held ones), a token none
+    of whose choices is held gets the shared MLP alone, and the capacity
+    einsum refuses the share by name."""
+    from megatron_llm_tpu.models.moe import moe_mlp_dropless
+
+    cfg = _cfg(num_experts=4, moe_top_k=2)
+    full = init_moe_mlp_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    full = dict(full, experts=jax.tree_util.tree_map(lambda w: w * 6.0,
+                                                     full["experts"]))
+    # a router that always chooses experts 0 and 2, logits 2a and a
+    full["router"] = {"kernel": jnp.zeros((32, 4)).at[0].set(
+        jnp.asarray([2.0, -5.0, 1.0, -5.0]))}
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 3, 32)) * 0.1
+    x = x.at[..., 0].set(jnp.asarray([1.0, 2.0, 3.0]))
+    c01, p01 = _share(cfg, full, 0, 2)
+    c23, p23 = _share(cfg, full, 2, 2)
+    c13, p13 = _share(cfg, full, 1, 2)
+    whole, _, counts = moe_mlp_dropless(x, full, cfg)
+    assert np.asarray(counts).tolist() == [3, 0, 3, 0]
+    first = moe_mlp_dropless(x, p01, c01)[0]
+    second = moe_mlp_dropless(x, p23, c23)[0]
+    np.testing.assert_allclose(np.asarray(first + second),
+                               np.asarray(whole), atol=1e-6)
+    # experts 1-2 hold the second choice only: the same as experts 2-3
+    np.testing.assert_allclose(np.asarray(moe_mlp_dropless(x, p13, c13)[0]),
+                               np.asarray(second), atol=1e-6)
+    # expert 0 alone under a gate of 1, times the gate the router gave it
+    # over BOTH choices (softmax of 2 and 1), not over the held one
+    alone = cfg.replace(num_experts=2, moe_top_k=1)
+    only0 = moe_mlp_dropless(x, dict(p01, router={
+        "kernel": full["router"]["kernel"][:, :2]}), alone)[0]
+    gate = jax.nn.softmax(jnp.asarray([1.0, 2.0, 3.0])[:, None]
+                          * jnp.asarray([2.0, 1.0])[None, :], axis=-1)[:, 0]
+    assert np.abs(np.asarray(first)).max() > 0.01
+    np.testing.assert_allclose(np.asarray(first),
+                               np.asarray(gate[None, :, None] * only0),
+                               atol=1e-6)
+    with pytest.raises(NotImplementedError, match="moe_router_experts"):
+        moe_mlp(x, p01, c01)
+    with pytest.raises(ValueError, match="must lie among"):
+        cfg.replace(moe_router_experts=4, moe_experts_first=3)

@@ -385,3 +385,84 @@ def test_attend_latent_writes_a_row_and_reads_it_back(n, ctx):
     p = np.exp(sc - sc.max(-1, keepdims=True))
     want = (p / p.sum(-1, keepdims=True)) @ keys[:, :r]
     np.testing.assert_allclose(np.asarray(out[0, -1]), want, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# a third kind of per-request state: a state-space layer's arrays a slot
+# ---------------------------------------------------------------------------
+
+def _granite_cfg(**kw):
+    from megatron_llm_tpu.models.granite import granite_config
+
+    return granite_config("tiny", use_flash_attn=False, **kw)
+
+
+def test_a_state_space_layer_is_of_the_state_group():
+    cfg = _granite_cfg()
+    groups = paged_kv.layer_groups(cfg)
+    assert groups == (paged_kv.STATE, paged_kv.STATE, paged_kv.FULL,
+                      paged_kv.STATE) * 2
+    pools = paged_kv.init_pools(cfg, 5, 8, num_slots=3)
+    assert [sorted(p) for p in pools] == [
+        ["conv_state", "ssm_state"], ["conv_state", "ssm_state"],
+        ["k_pages", "v_pages"], ["conv_state", "ssm_state"]] * 2
+    # slots + 1 rows: the last is the garbage row
+    assert pools[0]["ssm_state"].shape == (4, 8, 32, 16)
+    assert pools[0]["conv_state"].shape == (4, 3, 8 * 32 + 2 * 16)
+    assert pools[2]["k_pages"].shape == (5, 8, 2, 32)
+    # the page programs and a block's bytes are the paged pools' alone
+    paged = paged_kv.paged_pools(pools)
+    assert len(paged) == 2 and not any(paged_kv.is_state(p) for p in paged)
+    assert paged_kv.block_bytes(pools) == paged_kv.block_bytes(paged) == (
+        2 * 2 * 8 * 2 * 32 * 4)
+    copied = paged_kv.with_paged(pools, paged_kv.copy_page(paged, 1, 2))
+    assert [paged_kv.is_state(p) for p in copied] == [
+        paged_kv.is_state(p) for p in pools]
+    assert copied[0] is pools[0]
+    assert paged_kv.state_bytes_per_slot(pools) == 6 * 4 * (
+        8 * 32 * 16 + 3 * 288)
+    assert not paged_kv.array_shapes(pools) & paged_kv.state_shapes(pools)
+    # a model without state-space layers has none of it
+    llama = paged_kv.init_pools(llama_config("tiny"), 5, 8)
+    assert paged_kv.paged_pools(llama) == llama
+    assert paged_kv.state_bytes_per_slot(llama) == 0
+    assert paged_kv.state_shapes(llama) == set()
+
+
+@pytest.mark.parametrize("slots", [None, [2, 0]])
+def test_a_states_rows_are_read_and_written_by_slot(slots):
+    """``read_state`` gives a row its slot's arrays, zeros where its
+    ``context_lens`` is 0; ``write_state`` writes a live row at its slot
+    and an idle one at the garbage row.  A decode step's rows ARE the
+    slots (``slots`` None), a chunk's carry theirs."""
+    cfg = _granite_cfg()
+    pool = jax.tree_util.tree_map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(0), a.shape, a.dtype),
+        paged_kv.init_pools(cfg, 5, 8, num_slots=3)[0])
+    rows = [0, 1] if slots is None else slots
+    tables = {paged_kv.FULL: np.zeros((2, 2), np.int32)}
+    if slots is not None:
+        tables[paged_kv.STATE] = np.asarray(slots, np.int32)
+    cache = paged_kv.step_caches(
+        [pool], tables, np.asarray([0, 9], np.int32),
+        np.asarray([1, 0], np.int32), "xla", (paged_kv.STATE,))[0]
+    assert cache.group == paged_kv.STATE
+    conv, ssm = cache.read_state()
+    assert (np.asarray(ssm[0]) == 0).all() and (np.asarray(conv[0]) == 0).all()
+    assert (np.asarray(ssm[1]) == np.asarray(pool["ssm_state"][rows[1]])).all()
+    after = cache.write_state(conv + 1.0, ssm + 1.0)
+    got = np.asarray(after.pool["ssm_state"])
+    assert (got[rows[0]] == 1.0).all()                  # the live row's slot
+    others = [s for s in range(3) if s != rows[0]]      # the idle row's kept
+    assert (got[others] == np.asarray(pool["ssm_state"])[others]).all()
+    assert np.asarray(after.context_lens).tolist() == [1, 9]
+    assert after.pool["conv_state"].dtype == pool["conv_state"].dtype
+
+
+def test_the_states_names_have_one_owner():
+    """As the pages' names: a state-space layer's arrays are named in
+    ``ops/paged_kv.py`` and nowhere else in the package (the ROLE
+    ``ssm_state`` of a compiled program's rows is ``hlo_collectives``'s
+    word, not a key of the pool)."""
+    assert _grep(r"\[[\"'](ssm|conv)_state[\"']\]|[\"']conv_state[\"']",
+                 "megatron_llm_tpu") == ["megatron_llm_tpu/ops/paged_kv.py"]

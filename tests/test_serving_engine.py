@@ -349,7 +349,7 @@ def test_request_done_schema_golden(engine, tmp_path):
     the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 20
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 21
     captured = []
     engine.request_done_hook = captured.append
     stream = telemetry.TelemetryStream(str(tmp_path))
@@ -689,3 +689,79 @@ def test_engine_prefill_kernel_token_identity(model_and_params):
     finally:
         pa._INTERPRET = old
     assert outs[0] == outs[1]
+
+
+def test_granite_serves_through_the_server_cli():
+    """``--model_name=granite`` through ``tools/run_text_generation_server
+    .py`` and the engine, with no side script: a hybrid of state-space and
+    attention layers that holds half its router's experts answers over
+    HTTP, and says what state its slots hold."""
+    import json as _json
+    import os
+    import socket
+    import subprocess
+    import sys
+    import urllib.request
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.Popen(
+        [sys.executable,
+         os.path.join(root, "tools", "run_text_generation_server.py"),
+         "--model_name=granite", "--num_layers=4", "--hidden_size=64",
+         "--num_attention_heads=4", "--num_attention_heads_kv=2",
+         "--kv_channels=16", "--ffn_hidden_size=32", "--num_experts=4",
+         "--moe_router_experts=8", "--moe_experts_first=4", "--moe_top_k=3",
+         "--layer_types", "mamba", "attention", "--mamba_n_heads=4",
+         "--mamba_d_head=32", "--mamba_d_state=16", "--mamba_chunk_size=16",
+         "--attention_multiplier=0.0625", "--seq_length=128",
+         "--max_position_embeddings=128", "--micro_batch_size=1",
+         "--global_batch_size=1", "--tokenizer_type=NullTokenizer",
+         "--vocab_size=255", "--serve_engine", "--serve_num_slots=2",
+         "--serve_prefill_chunk=16", "--serve_preemption=0",
+         "--serve_alerts=0", f"--port={port}", "--host=127.0.0.1"],
+        cwd=root, env=env, stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, text=True)
+    chunks = []
+    drain = threading.Thread(
+        target=lambda: chunks.extend(iter(proc.stdout.readline, "")),
+        daemon=True)
+    drain.start()
+    out = metrics = last = None
+    try:
+        body = _json.dumps({"prompts": [" ".join(
+            str(3 + i % 200) for i in range(40))],
+            "tokens_to_generate": 5}).encode()
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{port}/api", data=body,
+            headers={"Content-Type": "application/json"}, method="PUT")
+        deadline = time.time() + 540
+        while time.time() < deadline and proc.poll() is None:
+            try:
+                with urllib.request.urlopen(req, timeout=30) as r:
+                    out = _json.loads(r.read())
+                with urllib.request.urlopen(
+                        f"http://127.0.0.1:{port}/metrics", timeout=30) as r:
+                    metrics = r.read().decode()
+                break
+            except Exception as e:  # server still compiling/binding
+                last = e
+                time.sleep(3)
+    finally:
+        proc.terminate()
+        try:
+            proc.wait(timeout=30)
+        except Exception:
+            proc.kill()
+        drain.join(timeout=10)
+    text = "".join(chunks)
+    assert out is not None, f"server never answered: {last}\n{text[-3000:]}"
+    assert len(out["text"][0].split()) == 45, out
+    assert "adopts nothing" in text
+    # three chunks and four steps over two state-space layers
+    assert "ssm_tokens" in metrics and "moe_assignments_held" in metrics

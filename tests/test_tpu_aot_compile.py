@@ -27,6 +27,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -246,12 +247,16 @@ CASES = {
 SAMPLER = "sampler_64_slots_50304_logits"
 SELECTION = "dsa_kernels_by_name"
 ENGINE_TABLES = "engine_tables_tiny_sparse_model"
+GRANITE = "granite_cell_programs"
 
 
-def _compile_all():
+def _compile_all(only_granite: bool = False):
     """The child: compile every case for one described v5e device and
     print ``{case: true | false | "error"}`` (or ``{"skip": why}`` where
-    this jaxlib cannot describe the topology)."""
+    this jaxlib cannot describe the topology).  The Granite cell's two
+    programs take a child of their own (``only_granite``): at their real
+    sizes they cost as much as all the kernels together, and a test's
+    time limit covers its fixture."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -262,6 +267,9 @@ def _compile_all():
         print(json.dumps({"skip": f"{type(e).__name__}: {e}"[:300]}))
         return
     chip = SingleDeviceSharding(topo.devices[0])
+    if only_granite:
+        print(json.dumps({GRANITE: _granite_programs(chip)}))
+        return
     found = {}
     for case in sorted(CASES):
         fn, shapes = CASES[case]()
@@ -338,15 +346,60 @@ def _engine_tables(chip):
             os.environ["MLT_FORCE_PALLAS"] = forced
 
 
-@pytest.fixture(scope="module")
-def compiled():
+def _granite_programs(chip):
+    """``engine_prefill`` and ``engine_decode`` of the benchmark's Granite
+    cell (one period of nine state-space layers and one attention layer
+    at the published widths, 36 of 72 experts, half the vocabulary, 24
+    slots of state and 13,313 pages) over abstract weights, compiled for
+    the described chip: what each holds, and which of the mixer's scopes
+    and kernels reach the optimised text."""
+    from megatron_llm_tpu.models.granite import GraniteModel, granite_config
+    from megatron_llm_tpu.ops import paged_kv
+    from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
+
+    try:
+        model = GraniteModel(granite_config(
+            "h-small", num_layers=10, num_experts=36, moe_router_experts=72,
+            padded_vocab_size=50176, params_dtype="bf16",
+            compute_dtype="bf16", seq_length=17408))
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        eng = InferenceEngine(model, params, EngineConfig(
+            num_slots=24, block_size=16, num_blocks=13313,
+            max_model_len=17408, prefill_chunk=512, preemption=False,
+            paged_kernel="on", prefill_kernel="on"))
+        found = {"state_bytes_per_slot": paged_kv.state_bytes_per_slot(
+            eng._st.pages), "pool_bytes": eng.kv_pool_bytes}
+        for name, args in eng._program_arguments().items():
+            if name not in ("engine_prefill", "engine_decode"):
+                continue
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=chip), args)
+            comp = eng._jitted[name].lower(*args).compile()
+            m, text = comp.memory_analysis(), comp.as_text()
+            found[name] = {
+                "argument_bytes": m.argument_size_in_bytes,
+                "output_bytes": m.output_size_in_bytes,
+                "temp_bytes": m.temp_size_in_bytes,
+                "kernels": sorted(set(re.findall(
+                    r"(paged_attention_\w+?|moe_experts\w*?)(?:\.\d+)? = ",
+                    text))),
+                "scopes": sorted({s for s in (
+                    "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
+                    "ssm_gate_norm", "ssm_out_proj") if f"/{s}/" in text})}
+        return found
+    except Exception as e:      # noqa: BLE001 - the compiler's refusal
+        return f"{type(e).__name__}: {e}"[:2000]
+
+
+def _child(*argv):
     # code that asks jax.default_backend() sees the CPU under such a
     # compile and would take its jnp branch; steer it here, in the test
     env = dict(os.environ, JAX_PLATFORMS="cpu", MLT_FORCE_PALLAS="1",
                PYTHONPATH=ROOT)
     env.setdefault("TPU_LOG_DIR", "disabled")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    proc = subprocess.run([sys.executable, os.path.abspath(__file__)],
+    proc = subprocess.run([sys.executable, os.path.abspath(__file__), *argv],
                           env=env, capture_output=True, text=True,
                           timeout=600)
     lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
@@ -356,6 +409,16 @@ def compiled():
         pytest.skip(f"cannot describe a v5e:2x2 topology here: "
                     f"{found['skip']}")
     return found
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    return _child()
+
+
+@pytest.fixture(scope="module")
+def granite_compiled():
+    return _child("granite")
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -409,5 +472,31 @@ def test_engine_tables_of_programs_compiled_for_v5e(compiled):
     assert set(programs["engine_sample_first"]["scopes"]) == {"sampler"}
 
 
+@pytest.mark.time_limit(900)
+def test_the_granite_cells_programs_compile_and_fit_a_v5e(granite_compiled):
+    """The cell's two programs at its real sizes, for a described v5e:
+    both compile with the attention layer's walk as a kernel and the
+    state-space mixer's scopes in the text, and what each holds (the
+    weights, the pools and the state held twice, nothing donated) fits
+    the chip's 16 GB with room for the probe."""
+    found = granite_compiled[GRANITE]
+    assert isinstance(found, dict), found
+    assert found["state_bytes_per_slot"] == 9 * (128 * 64 * 128 * 4
+                                                 + 3 * 8448 * 2)
+    state = 25 * found["state_bytes_per_slot"]
+    assert found["pool_bytes"] == state + 13313 * 16 * 4096
+    for name, recurrence in (("engine_prefill", "ssm_scan"),
+                             ("engine_decode", "ssm_step")):
+        got = found[name]
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"])
+        # 9.51 GB of weights; the pools in and out
+        assert 11.2e9 < got["argument_bytes"] < 11.5e9, (name, got)
+        assert got["output_bytes"] >= found["pool_bytes"], (name, got)
+        assert held < 14.5e9, (name, held)
+        assert recurrence in got["scopes"] and "ssm_in_proj" in got["scopes"]
+        assert any(k.startswith("paged_attention") for k in got["kernels"])
+
+
 if __name__ == "__main__":
-    _compile_all()
+    _compile_all(only_granite=sys.argv[1:] == ["granite"])
