@@ -447,7 +447,10 @@ class PagedKVCache:
     def _write(self, writes: dict, n: int) -> dict:
         """The pool with this call's rows ``writes[name]`` [b, n, ...]
         scattered at ``context_lens ..`` of each row's table, under the
-        scope ``kv_write``."""
+        scope ``kv_write``.  The scatter is at (page, row) of the pool
+        in its own shape: through a one-row-a-token reshape the TPU's
+        compiler moved a pool array it could have written in place
+        through fast memory and back, whole (PERF.md section 6, PR 41)."""
         bt, ctx_lens, vlen = (self.block_tables, self.context_lens,
                               self.valid_lens)
         P, bs = next(iter(self.pool.values())).shape[:2]
@@ -463,8 +466,7 @@ class PagedKVCache:
         with jax.named_scope("kv_write"):
             for name, val in writes.items():
                 a = self.pool[name]
-                flat = a.reshape((P * bs,) + a.shape[2:])
-                pool[name] = flat.at[dest].set(val).reshape(a.shape)
+                pool[name] = a.at[dest // bs, dest % bs].set(val)
         return pool
 
     def expands_latents(self, n: int) -> bool:

@@ -43,6 +43,16 @@ on admission are numpy row writes — because a stray
 compile a fresh tiny executable per distinct slot index and trip the
 recompile detector.
 
+Who owns the pool: the programs of a decode step (``decode_step``,
+``verify_step``) take the pool DONATED and write it in place, so a step
+copies no array of it; when the launch returns, the arrays it was given
+are deleted and ``st.pages`` is the pool it gave back (``_launch_step``).
+The chunk and the page programs are lent the pool.  ``st.pages``
+therefore belongs to the engine's thread: readers off it (the host
+tier's spill, ``program_tables``) go through the state's ``pool_lock``
+and keep no array, and a launch that raises holding the pool ends in
+``restart()``.
+
 Resilience (serving/resilience.py; docs/guide/fault_tolerance.md):
 
 * **Non-finite sentinel** — the decode step and first-token sampler
@@ -72,7 +82,7 @@ from __future__ import annotations
 
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -188,14 +198,16 @@ class EngineConfig:
     host_cache_bytes: int = 0
 
 
-def _program(fn, name: str):
+def _program(fn, name: str, owns: Tuple[int, ...] = ()):
     """``fn`` jitted under a stable name: the XLA module is
     ``jit_<name>`` in every profile and compile log, whatever the
-    method behind it is called."""
+    method behind it is called.  ``owns`` are the arguments the program
+    is given for good (donated): it may write them in place, and the
+    caller's arrays are deleted when the launch returns."""
     def program(*args):
         return fn(*args)
     program.__name__ = program.__qualname__ = name
-    return jax.jit(program)
+    return jax.jit(program, donate_argnums=owns)
 
 
 def _abstract(x) -> jax.ShapeDtypeStruct:
@@ -225,7 +237,14 @@ class _EngineState:
     reference to the OLD state object, so whatever it writes when (if)
     it finally wakes up lands in abandoned arrays; request-visible
     effects are additionally gated on ``st is self._st`` after every
-    dispatch."""
+    dispatch.
+
+    ``pages`` belongs to the engine's thread.  A decode step's launch
+    CONSUMES the pool it is given (``_launch_step``: the arrays are
+    deleted, ``pages`` is rebound to the program's output), so whoever
+    reads ``pages`` off that thread does it under ``pool_lock``, which
+    the launch holds from the call to the rebinding, and keeps no
+    reference past the lock."""
 
     gen: int
     blocks: BlockManager
@@ -240,6 +259,7 @@ class _EngineState:
     ban_a: np.ndarray
     ban_b: np.ndarray
     keys: np.ndarray
+    pool_lock: threading.Lock = field(default_factory=threading.Lock)
 
 
 class InferenceEngine:
@@ -411,8 +431,14 @@ class InferenceEngine:
             self._st.blocks.attach_host_cache(self.host_cache)
             self.host_cache.start()
 
-        self._decode_step = _program(self._decode_impl, "engine_decode")
-        self._verify_step = _program(self._verify_impl, "engine_verify")
+        # the programs of a decode step own the pool (argument 1): the
+        # step writes its rows in place, where a program that is only
+        # lent the pool copies every array of it first.  The chunk and
+        # the page programs are lent theirs
+        self._decode_step = _program(self._decode_impl, "engine_decode",
+                                     owns=(1,))
+        self._verify_step = _program(self._verify_impl, "engine_verify",
+                                     owns=(1,))
         self._prefill_step = _program(self._prefill_impl, "engine_prefill")
         self._sample_first = _program(self._sample_first_impl,
                                       "engine_sample_first")
@@ -680,17 +706,31 @@ class InferenceEngine:
 
     def _spill_fetch(self, manager, block: int):
         """host_cache spill-thread callback: device→host copy of one
-        page.  Runs on the spill thread with no locks held; the
-        abandoned-manager guard keeps a post-restart queue drain from
-        reading the fresh pool through a stale block id.  Reading live
-        pages without a lock is safe: the spill tier only fetches
-        digest-registered pages, whose content is frozen (COW and
-        eviction both unregister first), and the caller re-validates
-        the (block, epoch) mapping after this returns."""
+        page.  Runs on the spill thread; the abandoned-manager guard
+        keeps a post-restart queue drain from reading the fresh pool
+        through a stale block id.  A decode step consumes the pool it
+        is given, so the read is SERIALISED with the launches: the
+        gather is dispatched from the pool as it stands under
+        ``pool_lock`` (which a launch holds until ``st.pages`` is the
+        pool that replaced it), and what it returns is an array of its
+        own, fetched with the lock released.  Which pool is read does
+        not matter: the spill tier only fetches digest-registered
+        pages, whose content is frozen (COW and eviction both
+        unregister first), and the caller re-validates the (block,
+        epoch) mapping after this returns."""
         st = self._st
         if st.blocks is not manager:
             return None
-        return jax.device_get(self._fetch_block(st.pages, np.int32(block)))
+        # a state whose thread is wedged inside a launch never gives the
+        # lock back: give up on it once a restart has replaced it
+        while not st.pool_lock.acquire(timeout=0.05):
+            if st is not self._st:
+                return None
+        try:
+            page = self._fetch_block(st.pages, np.int32(block))
+        finally:
+            st.pool_lock.release()
+        return jax.device_get(page)
 
     def _sample_first_impl(self, logits, key, top_k, top_p, temp,
                            ban_a, ban_b, last_prompt_tok):
@@ -808,7 +848,16 @@ class InferenceEngine:
             try:
                 did_work = self.step(st)
             except Exception as e:  # noqa: BLE001 - engine must survive
-                self._fail_all(st, f"{type(e).__name__}: {e}")
+                msg = f"{type(e).__name__}: {e}"
+                if self._pool_consumed(st):
+                    # the launch that raised had taken its pool: this
+                    # state can launch nothing more, so it goes the way
+                    # of a wedged one (a fresh pool, requests requeued)
+                    if st is self._st:
+                        self.restart("a launch raised holding the pool: "
+                                     + msg)
+                    return
+                self._fail_all(st, msg)
                 did_work = False
             if st is not self._st:
                 return              # restarted under our feet: stand down
@@ -817,6 +866,13 @@ class InferenceEngine:
             if not did_work:
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
+
+    @staticmethod
+    def _pool_consumed(st: _EngineState) -> bool:
+        """Whether a decode step's launch took ``st.pages`` and raised
+        before it gave a pool back."""
+        return any(a.is_deleted()
+                   for a in jax.tree_util.tree_leaves(st.pages))
 
     def _fail_all(self, st: _EngineState, msg: str) -> None:
         st.active[:] = 0
@@ -1294,6 +1350,19 @@ class InferenceEngine:
         self.sample_draw_steps += d.sampler_rows_drawn > 0
         self.sample_sort_steps += d.sampler_rows_filtered > 0
 
+    def _launch_step(self, st: _EngineState, step, *args):
+        """Launch ``step`` (the decode or the verify program) on
+        ``st.pages``, which the program OWNS: the arrays it is given are
+        deleted when it returns and ``st.pages`` becomes the pool it
+        gives back, under ``pool_lock`` so that no reader off this
+        thread finds the pool between the two.  Returns the step's other
+        results.  A launch that raises in between leaves ``st.pages``
+        consumed (``_pool_consumed``), which ``_loop`` answers with a
+        restart."""
+        with st.pool_lock:
+            tokens, st.pages, *rest = step(self.params, st.pages, *args)
+        return (tokens, *rest)
+
     def _run_decode(self, st: _EngineState, slots: List[int],
                     d: DispatchRecord) -> None:
         bs = self.config.block_size
@@ -1305,8 +1374,8 @@ class InferenceEngine:
         self._window_advance(
             st, d, [(s, int(st.context_lens[s]), 1) for s in slots])
         d.mark("build_inputs")
-        next_tokens, st.pages, new_keys, finite, routing = self._decode_step(
-            self.params, st.pages, st.last_tokens,
+        next_tokens, new_keys, finite, routing = self._launch_step(
+            st, self._decode_step, st.last_tokens,
             st.context_lens, self._tables(st),
             st.active, st.temps, st.top_ks, st.top_ps,
             st.ban_a, st.ban_b, st.keys)
@@ -1408,8 +1477,8 @@ class InferenceEngine:
         self._note_batch(st, disp, slots, decoding)
         disp.drafted = int(draft_lens.sum())
         disp.mark("build_inputs")
-        emit, st.pages, new_keys, finite, routing = self._verify_step(
-            self.params, st.pages, verify_tokens, st.context_lens,
+        emit, new_keys, finite, routing = self._launch_step(
+            st, self._verify_step, verify_tokens, st.context_lens,
             self._tables(st), vlens, st.temps, st.top_ks,
             st.top_ps, st.ban_a, st.ban_b, st.keys)
         disp.mark("dispatch")
@@ -1639,6 +1708,14 @@ class InferenceEngine:
         self.loop_profiler.stall_armed = True
         tracing.instant("engine_warm", "serve")
 
+    def _abstract_pool(self):
+        """The pool in its abstract form (shapes, dtypes, placement),
+        taken between launches: a decode step consumes the arrays, and
+        whoever describes the pool keeps none."""
+        st = self._st
+        with st.pool_lock:
+            return jax.tree_util.tree_map(_abstract, st.pages)
+
     def _program_arguments(self) -> Dict[str, tuple]:
         """What each program warm-up compiled is launched with, as the
         launches' call sites build it from the state (the shapes are
@@ -1648,10 +1725,11 @@ class InferenceEngine:
         S, zero = cfg.num_slots, np.int32(0)
         per_slot = (st.temps, st.top_ks, st.top_ps, st.ban_a, st.ban_b,
                     st.keys)
-        pages = _abstract(jax.tree_util.tree_leaves(st.pages)[0])
+        pool = self._abstract_pool()
+        pages = jax.tree_util.tree_leaves(pool)[0]
         found = {
             "engine_prefill": (
-                self.params, st.pages,
+                self.params, pool,
                 np.zeros((1, cfg.prefill_chunk), np.int32), zero, zero,
                 self._tables(st, slice(0, 1))),
             # a chunk's last logits: the prefill program's output
@@ -1661,23 +1739,22 @@ class InferenceEngine:
                     sharding=pages.sharding),
                 st.keys[0], st.top_ks[0], st.top_ps[0], st.temps[0],
                 st.ban_a[0], st.ban_b[0], zero),
-            "engine_cow_copy": (paged_kv.paged_pools(st.pages), zero, zero),
+            "engine_cow_copy": (paged_kv.paged_pools(pool), zero, zero),
         }
         if self.speculative:
             found["engine_verify"] = (
-                self.params, st.pages,
+                self.params, pool,
                 np.zeros((S, self.draft_k + 1), np.int32), st.context_lens,
                 self._tables(st), st.active) + per_slot
         else:
             found["engine_decode"] = (
-                self.params, st.pages, st.last_tokens, st.context_lens,
+                self.params, pool, st.last_tokens, st.context_lens,
                 self._tables(st), st.active) + per_slot
         if self.host_cache is not None:
             page = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
-                st.pages)
-            found["engine_fetch_block"] = (st.pages, zero)
-            found["engine_host_load"] = (st.pages, page, zero)
+                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), pool)
+            found["engine_fetch_block"] = (pool, zero)
+            found["engine_host_load"] = (pool, page, zero)
         return found
 
     def program_tables(self, sharding=None) -> Dict[str, Any]:
@@ -1700,8 +1777,9 @@ class InferenceEngine:
             return self._program_tables
         if not self.warmed_up:
             return {}
-        pool = paged_kv.array_shapes(self._st.pages)
-        state = paged_kv.state_shapes(self._st.pages)
+        pages = self._abstract_pool()
+        pool = paged_kv.array_shapes(pages)
+        state = paged_kv.state_shapes(pages)
         tables = {}
         for name, args in self._program_arguments().items():
             args = jax.tree_util.tree_map(_abstract, args)

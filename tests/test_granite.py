@@ -493,27 +493,27 @@ def test_the_flags_lower_into_the_config():
 # the standing families' programs
 # ---------------------------------------------------------------------------
 
-# What ``tests/_program_fingerprints.py`` printed at PR 37's commit
-# (2bc6275) AND prints at this PR's: the Mamba sizes, the multipliers, the
-# third group of state and the share of experts are all off by default, so
-# no standing family traces another program.  (The compiled text of the
-# six standing serving configurations at their benchmark sizes, for a
-# described v5e, was held to the parent's line for line by a scratch
-# script: CHANGES.md, PR 40.)  A PR that MEANS to change a family's
-# program runs the script and records what it prints here.
+# What ``tests/_program_fingerprints.py`` prints since PR 41, which MEANT
+# to change every family's programs: the cache's write keeps the pool's
+# own shape (``paged_kv.PagedKVCache._write`` scatters at (page, row) and
+# no longer through a one-row-a-token reshape), so that a decode step
+# that owns its pool writes it in place.  Nothing else of a program
+# moved: PR 40's hashes held from PR 37 (2bc6275) to PR 40.  A PR that
+# MEANS to change a family's program runs the script and records what it
+# prints here.
 TRACED = {
-    "mistral": {"engine_prefill": "040e09e69353cbc6",
-                "engine_decode": "2644d0547c9b3a92"},
-    "mixtral": {"engine_prefill": "39fb942aa9e8f29a",
-                "engine_decode": "c72cdb0bb7923994"},
-    "olmoe": {"engine_prefill": "0c9e2677c36c0caf",
-              "engine_decode": "08eafa1f56fb2a13"},
-    "keye": {"engine_prefill": "bed130a1553f324e",
-             "engine_decode": "ad0017cdbece3ad6"},
-    "mellum": {"engine_prefill": "58de4f86ce10a6bd",
-               "engine_decode": "21c2bfa51ad4a521"},
-    "kanana": {"engine_prefill": "253e26c96e82941f",
-               "engine_decode": "d2bfb4ec0cd8ed92"},
+    "mistral": {"engine_prefill": "97f7c403d8d97874",
+                "engine_decode": "b97f9efa5d5c72cc"},
+    "mixtral": {"engine_prefill": "16dc68e888901332",
+                "engine_decode": "a0d16f2e4a17d671"},
+    "olmoe": {"engine_prefill": "ec12cf1b1f8b4824",
+              "engine_decode": "ef8a70800862ad50"},
+    "keye": {"engine_prefill": "8eafd00d20ed2ba5",
+             "engine_decode": "9dc6e3ea2bf61e94"},
+    "mellum": {"engine_prefill": "3b7515947a69f378",
+               "engine_decode": "f3e74e8dd2f03ffd"},
+    "kanana": {"engine_prefill": "7bc4fba0abd4ca5a",
+               "engine_decode": "b16ff525cecab742"},
 }
 
 

@@ -411,12 +411,16 @@ def test_the_engines_tables_cost_nothing_until_asked_for(kind):
     decode = tables["engine_decode"]
     copies = [r for r in decode.rows
               if r["role"] == "kv_pool" and r["root"] in H.COPIES]
-    # the pool enters as a parameter that is not donated: every array of
-    # it is copied whole, so the bytes are whole arrays'
+    # the step owns the pool (it enters donated): no array of it is
+    # copied, the scatter writes its rows in place
     array = {a.nbytes for a in jax.tree_util.tree_leaves(eng._st.pages)}
-    assert copies and len(array) == 1
-    assert decode.kv_pool_copy_bytes() % array.pop() == 0
-    assert decode.kv_pool_copy_bytes() >= pool
+    assert copies == [] and len(array) == 1
+    assert decode.kv_pool_copy_bytes() < array.pop()
+    # the chunk is lent the pool: every array of it is copied whole
+    chunk = tables["engine_prefill"]
+    assert [r for r in chunk.rows
+            if r["role"] == "kv_pool" and r["root"] in H.COPIES]
+    assert chunk.kv_pool_copy_bytes() >= pool
     stats = eng.stats()["programs"]
     assert stats["engine_decode"] == decode.summary()
     assert stats["engine_decode"]["kv_pool_copy_bytes_per_launch"] == \
@@ -425,7 +429,8 @@ def test_the_engines_tables_cost_nothing_until_asked_for(kind):
     assert {"sampler", "kv_write", "attention", "embedding",
             "lm_head"} <= scopes
     assert set(stats["engine_sample_first"]["scopes"]) == {"sampler"}
-    assert stats["engine_cow_copy"]["kv_pool_copy_bytes_per_launch"] > 0
+    assert stats["engine_prefill"]["kv_pool_copy_bytes_per_launch"] >= pool
+    assert stats["engine_cow_copy"]["kv_pool_copy_bytes_per_launch"] >= pool
     if kind == "sparse":    # everything of its mlp is in an inner scope
         assert {"moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                 "qk_norm"} <= scopes
@@ -467,7 +472,14 @@ def test_every_program_warm_up_compiled_has_its_table_with_no_compile(
                                       "engine_cow_copy"}
     step = tables["engine_verify" if "speculative" in kw
                   else "engine_decode"]
-    assert step.kv_pool_copy_bytes() >= eng.kv_pool_bytes
+    # the step owns the pool and copies none of it; the chunk and the
+    # page copy are lent theirs and copy it whole
+    array = min(a.nbytes for a in jax.tree_util.tree_leaves(eng._st.pages))
+    assert step.kv_pool_copy_bytes() < array
+    assert not [r for r in step.rows
+                if r["role"] == "kv_pool" and r["root"] in H.COPIES]
+    for lent in ("engine_prefill", "engine_cow_copy"):
+        assert tables[lent].kv_pool_copy_bytes() >= eng.kv_pool_bytes, lent
     assert "sampler" in step.summary()["scopes"]
 
 
