@@ -34,14 +34,22 @@ sampler; ``warmup()`` compiles all three, after which
 ``tracing.RecompileDetector.mark_steady()`` holds (asserted in
 tests/test_serving_engine.py).
 
-Host/device split: the engine keeps ALL mutable per-slot state
-(last tokens, context lengths, sampling knobs, PRNG key chains) as host
-numpy arrays and passes them whole into the jitted calls.  Nothing
-touches jnp outside the three compiled programs — even per-slot updates
-on admission are numpy row writes — because a stray
+Host/device split: the host's numpy arrays are the truth of every
+per-slot value the scheduler reads (last tokens, context lengths, the
+sampling knobs, the key a slot was given), and per-slot updates on
+admission are numpy row writes: a stray
 ``device_array.at[python_int].set()`` or ``array[slot:slot+1]`` would
 compile a fresh tiny executable per distinct slot index and trip the
-recompile detector.
+recompile detector.  What changes every step (last tokens, context
+lengths, the tables, the live mask) is handed to the programs as host
+arrays, whole.  What changes only when the HOST writes it lives on the
+device between launches (``_EngineState``): the five sampling arrays,
+uploaded again only after a write made the device's copy stale, and the
+PRNG key chain, which the decode and verify programs advance where it
+lies and give back; a key the host itself sets (an admission's seed,
+what sampling a first token left) reaches the chain as that one row's
+update inside the next launch.  A launch's results set out for the host
+together and the host waits for them once (``_read``).
 
 Who owns the pool: the programs of a decode step (``decode_step``,
 ``verify_step``) take the pool DONATED and write it in place, so a step
@@ -89,6 +97,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from megatron_llm_tpu import hlo_collectives, telemetry, tracing
 from megatron_llm_tpu.models.language_model import language_model_forward
@@ -221,6 +230,37 @@ def _abstract(x) -> jax.ShapeDtypeStruct:
                                 sharding=x.sharding if placed else None)
 
 
+def _beside(params):
+    """Where the engine places the small arrays its programs read beside
+    ``params``: replicated over the mesh (on the device) the weights were
+    placed on, so that what the engine uploads and what a program gives
+    back are arrays of one kind and a launch never meets a second one
+    (which would be a second executable).  None, the default device and
+    not committed, where the weights were never placed: a program's
+    results are then not committed either."""
+    for leaf in jax.tree_util.tree_leaves(params):
+        if isinstance(leaf, jax.Array) and leaf.committed:
+            where = leaf.sharding
+            if isinstance(where, NamedSharding):
+                return NamedSharding(where.mesh, PartitionSpec())
+            if len(where.device_set) == 1:
+                return SingleDeviceSharding(next(iter(where.device_set)))
+            break
+    return None
+
+
+def _host_arrays(args) -> int:
+    """The arrays among ``args`` (tables counted one each) that are the
+    host's, which a launch uploads."""
+    return sum(not isinstance(leaf, jax.Array)
+               for leaf in jax.tree_util.tree_leaves(args))
+
+
+# the per-slot sampling arrays of ``_EngineState`` that live on the device
+# between launches, in the order the step programs take them
+_SAMPLING = ("temps", "top_ks", "top_ps", "ban_a", "ban_b")
+
+
 def _key_from_seed(seed: int) -> np.ndarray:
     # the two raw uint32 words of jax.random.PRNGKey(seed), built without
     # a device computation: PRNGKey(int) embeds the seed as a compile
@@ -244,7 +284,28 @@ class _EngineState:
     deleted, ``pages`` is rebound to the program's output), so whoever
     reads ``pages`` off that thread does it under ``pool_lock``, which
     the launch holds from the call to the rebinding, and keeps no
-    reference past the lock."""
+    reference past the lock.
+
+    What lives on the device between launches, and who may write it
+    (the engine's thread alone, like every array here):
+
+    * ``placed``: the device's copies of the sampling arrays
+      (``_SAMPLING``) by name.  The host arrays stay the truth; whoever
+      writes a slot's value there DROPS the name from ``placed``
+      (``stale``), and the next launch uploads what is missing, once.
+    * ``key_chain`` [S, 2]: every slot's PRNG key.  Only the decode and
+      verify programs write it: a launch is handed the chain and gives
+      back the next one, advanced for the slots that decoded.  The host
+      never reads it.  Where the host itself sets a slot's key
+      (``give_key``: an admission's seed, the key sampling a first token
+      left) it writes its own ``keys`` and marks the row in
+      ``keys_given``; the next launch uploads both and the program puts
+      those rows into the chain before it splits.  ``keys[s]`` is
+      therefore the key of a slot that has not decoded since it was
+      given one, which is all ``_sample_first`` needs, and says nothing
+      of a slot that has.
+    * ``no_keys_given``: the ``(keys, keys_given)`` a launch is handed
+      when the host gave no key since the last one: zeros, placed once."""
 
     gen: int
     blocks: BlockManager
@@ -259,7 +320,24 @@ class _EngineState:
     ban_a: np.ndarray
     ban_b: np.ndarray
     keys: np.ndarray
+    keys_given: np.ndarray
+    key_chain: Any
+    no_keys_given: Tuple[Any, Any]
+    placed: Dict[str, Any] = field(default_factory=dict)
     pool_lock: threading.Lock = field(default_factory=threading.Lock)
+
+    def stale(self, *names: str) -> None:
+        """The host wrote a slot's value of the sampling arrays
+        ``names`` (all of them if none is named): the device's copies
+        are uploaded again before the next launch reads them."""
+        for name in names or _SAMPLING:
+            self.placed.pop(name, None)
+
+    def give_key(self, slot: int, key) -> None:
+        """The host sets ``slot``'s key: one row's update of the chain,
+        made inside the next launch."""
+        self.keys[slot] = key
+        self.keys_given[slot] = True
 
 
 class InferenceEngine:
@@ -312,6 +390,8 @@ class InferenceEngine:
         one_device = len({d for leaf in jax.tree_util.tree_leaves(params)
                           if isinstance(leaf, jax.Array)
                           for d in leaf.devices()}) <= 1
+        # where the per-slot arrays that stay on the device are placed
+        self._beside = _beside(params)
         self.paged_kernel = paged_kv.resolve_kernel(cfg.paged_kernel,
                                                     one_device)
         self.prefill_kernel = paged_kv.resolve_kernel(cfg.prefill_kernel,
@@ -582,6 +662,7 @@ class InferenceEngine:
             blocks.prefix_cache_host_hits = ob.prefix_cache_host_hits
             blocks.cow_copies = ob.cow_copies
         S = cfg.num_slots
+        keys, given = np.zeros((S, 2), np.uint32), np.zeros(S, bool)
         return _EngineState(
             gen=gen,
             blocks=blocks,
@@ -595,8 +676,35 @@ class InferenceEngine:
             top_ps=np.zeros(S, np.float32),
             ban_a=np.full(S, -1, np.int32),
             ban_b=np.full(S, -1, np.int32),
-            keys=np.zeros((S, 2), np.uint32),
+            keys=keys,
+            keys_given=given,
+            key_chain=self._place(keys),
+            no_keys_given=(self._place(keys), self._place(given)),
         )
+
+    def _place(self, host: np.ndarray):
+        """A copy of the host's array on the device, where the programs'
+        own results lie (``_beside``).  Of a copy: a CPU device may keep
+        the buffer it is handed, and the host writes its arrays on."""
+        return jax.device_put(host.copy(), self._beside)
+
+    def _resident(self, st: _EngineState, d: DispatchRecord) -> tuple:
+        """What a decode or verify launch takes after the arrays that
+        change every step, none of it the host's: the sampling arrays
+        (those a write made stale uploaded first), the key chain, and
+        the keys the host gave since the last launch with the rows they
+        are for.  Uploads are counted on ``d``."""
+        for name in _SAMPLING:
+            if name not in st.placed:
+                st.placed[name] = self._place(getattr(st, name))
+                d.host_uploads += 1
+        given = st.no_keys_given
+        if st.keys_given.any():
+            given = self._place(st.keys), self._place(st.keys_given)
+            st.keys_given[:] = False
+            d.host_uploads += 2
+        return (*(st.placed[name] for name in _SAMPLING), st.key_chain,
+                *given)
 
     # current-state views (the HTTP server, tools and tests address the
     # engine, not a state generation)
@@ -612,9 +720,23 @@ class InferenceEngine:
     # jitted device programs (fixed shapes; everything traced)
     # ------------------------------------------------------------------
 
+    @staticmethod
+    def _split_keys(keys, given, keys_given, live):
+        """One step of the key chain ``keys`` [S, 2]: the rows the host
+        gave since the last launch (``keys_given``) take ``given``'s key
+        first; every slot's key is split once, and the chain advances
+        ONLY for a slot that decodes this step (``live``): a slot
+        mid-prefill keeps its admission-time seed key, so a request's
+        sample stream depends on its seed alone, not on batch-mates'
+        decode traffic.  Returns the keys to sample with and the chain
+        as the next launch takes it."""
+        keys = jnp.where(keys_given[:, None], given, keys)
+        sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [S, 2, 2]
+        return sub[:, 0], jnp.where(live[:, None], sub[:, 1], keys)
+
     def _decode_impl(self, params, pages, last_tokens, context_lens,
                      block_tables, active, temps, top_ks, top_ps,
-                     ban_a, ban_b, keys):
+                     ban_a, ban_b, keys, given_keys, keys_given):
         tokens = last_tokens[:, None]                       # [S, 1]
         positions = context_lens[:, None]                   # [S, 1]
         caches = paged_kv.step_caches(pages, block_tables, context_lens,
@@ -635,16 +757,17 @@ class InferenceEngine:
         banned = (ban_a >= 0) & (last_tokens == ban_a)
         hit = jnp.arange(V)[None, :] == jnp.clip(ban_b, 0, V - 1)[:, None]
         logits = jnp.where(banned[:, None] & hit, NEG_INF, logits)
-        sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [S, 2, 2]
+        draw, chain = self._split_keys(keys, given_keys, keys_given,
+                                       active > 0)
         with jax.named_scope("sampler"):
-            next_tokens = sample_batched(logits, sub[:, 0], top_ks, top_ps,
+            next_tokens = sample_batched(logits, draw, top_ks, top_ps,
                                          temps, active > 0)
-        return (next_tokens, paged_kv.pools_of(new_caches), sub[:, 1],
+        return (next_tokens, paged_kv.pools_of(new_caches), chain,
                 finite, paged_kv.routing_of(new_caches))
 
     def _verify_impl(self, params, pages, tokens, context_lens,
                      block_tables, vlens, temps, top_ks, top_ps,
-                     ban_a, ban_b, keys):
+                     ban_a, ban_b, keys, given_keys, keys_given):
         """Speculative [S, K+1] verify step — the decode program when
         ``speculative`` is on.  Row s carries ``[last_token, draft_1..
         draft_L, pad]`` with ``vlens[s] = 1 + L`` (0 for inactive
@@ -679,13 +802,14 @@ class InferenceEngine:
         hit = (jnp.arange(V)[None, None, :]
                == jnp.clip(ban_b, 0, V - 1)[:, None, None])
         logits = jnp.where(banned[:, :, None] & hit, NEG_INF, logits)
-        sub = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+        draw, chain = self._split_keys(keys, given_keys, keys_given,
+                                       vlens > 0)
         with jax.named_scope("sampler"):
-            first = sample_batched(logits[:, 0, :], sub[:, 0], top_ks,
+            first = sample_batched(logits[:, 0, :], draw, top_ks,
                                    top_ps, temps, vlens > 0)
         emit = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         emit = emit.at[:, 0].set(first.astype(jnp.int32))
-        return (emit, paged_kv.pools_of(new_caches), sub[:, 1], finite,
+        return (emit, paged_kv.pools_of(new_caches), chain, finite,
                 paged_kv.routing_of(new_caches))
 
     def _prefill_impl(self, params, pages, tokens, start_pos, valid_len,
@@ -1017,7 +1141,8 @@ class InferenceEngine:
         st.top_ps[s] = sp.top_p
         st.ban_a[s] = sp.ban_pair[0] if sp.ban_pair else -1
         st.ban_b[s] = sp.ban_pair[1] if sp.ban_pair else -1
-        st.keys[s] = _key_from_seed(sp.seed)
+        st.stale()
+        st.give_key(s, _key_from_seed(sp.seed))
         st.active[s] = 0            # stays masked until prefill done
         st.context_lens[s] = 0
         self.prefill_tokens_submitted += len(req.prompt_tokens)
@@ -1230,27 +1355,32 @@ class InferenceEngine:
         self._note_state(st, d, 1, valid)
         d.mark("build_inputs")
         finite = True
+        handed = (toks, np.int32(start), np.int32(valid), table)
+        d.host_uploads += _host_arrays(handed)
         last_logits, st.pages, routing = self._prefill_step(
-            self.params, st.pages, toks, np.int32(start),
-            np.int32(valid), table)
-        self._start_routing_copy(routing)
+            self.params, st.pages, *handed)
         done = start + valid >= len(ptoks)
         if done:
-            tok, new_key, finite = self._sample_first(
-                last_logits, st.keys[req.slot],
-                st.top_ks[req.slot], st.top_ps[req.slot],
-                st.temps[req.slot], st.ban_a[req.slot],
-                st.ban_b[req.slot],
-                np.int32(ptoks[-1]))
+            # the slot has not decoded since the host gave it its key, so
+            # the host's row IS its key (_EngineState)
+            first = (st.keys[req.slot],
+                     st.top_ks[req.slot], st.top_ps[req.slot],
+                     st.temps[req.slot], st.ban_a[req.slot],
+                     st.ban_b[req.slot],
+                     np.int32(ptoks[-1]))
+            d.host_uploads += _host_arrays(first)
+            tok, new_key, finite = self._sample_first(last_logits, *first)
             d.mark("dispatch")
+            tok, new_key, finite, routing = self._read(
+                d, tok, new_key, finite, routing)
             tok = int(tok)
             finite = bool(finite)
-            st.keys[req.slot] = np.asarray(new_key)
+            st.give_key(req.slot, new_key)
         else:
             d.mark("dispatch")
-            jax.block_until_ready(st.pages[0])
-        if routing is not None:
-            routing = np.asarray(routing)
+            # a chunk that is not its prompt's last: nothing of it is
+            # read but the histogram, and the wait is for the chunk
+            (routing,) = self._read(d, routing, until=st.pages[0])
         d.mark("fetch")
         self._note_routing(d, routing)
         if st is not self._st:
@@ -1287,12 +1417,17 @@ class InferenceEngine:
     # -- decode ---------------------------------------------------------
 
     @staticmethod
-    def _start_routing_copy(counts) -> None:
-        """A sparse model's histogram sets out for the host as its launch
-        returns, so the read after the launch's own reads finds it there
-        and waits for nothing."""
-        if counts is not None:
-            counts.copy_to_host_async()
+    def _read(d: DispatchRecord, *results, until=None) -> tuple:
+        """A launch's results on the host (None stays None), one wait:
+        every one of them sets out for the host as the launch returns,
+        so none starts its trip only when the one before it is home.
+        ``until``: an array the launch gives that nobody reads, waited
+        for all the same."""
+        d.host_reads += 1
+        home = jax.device_get(results)
+        if until is not None:
+            jax.block_until_ready(until)
+        return home
 
     def _note_routing(self, d: DispatchRecord, counts) -> None:
         """A sparse model's launch: its routing on the record and in the
@@ -1350,18 +1485,30 @@ class InferenceEngine:
         self.sample_draw_steps += d.sampler_rows_drawn > 0
         self.sample_sort_steps += d.sampler_rows_filtered > 0
 
-    def _launch_step(self, st: _EngineState, step, *args):
+    def _launch_step(self, st: _EngineState, d: DispatchRecord, step,
+                     *per_step):
         """Launch ``step`` (the decode or the verify program) on
         ``st.pages``, which the program OWNS: the arrays it is given are
         deleted when it returns and ``st.pages`` becomes the pool it
         gives back, under ``pool_lock`` so that no reader off this
-        thread finds the pool between the two.  Returns the step's other
-        results.  A launch that raises in between leaves ``st.pages``
-        consumed (``_pool_consumed``), which ``_loop`` answers with a
-        restart."""
+        thread finds the pool between the two.  ``per_step`` are the
+        host's arrays that change every step; what does not is already
+        on the device (``_resident``), and the key chain the program
+        gives back stays there as the next launch's.  Returns the
+        step's tokens and its per-slot ``finite`` flags (the host's own
+        copy), read together with its routing histogram.  A launch that
+        raises in between leaves ``st.pages`` consumed
+        (``_pool_consumed``), which ``_loop`` answers with a restart."""
+        resident = self._resident(st, d)
+        d.host_uploads += _host_arrays(per_step)
         with st.pool_lock:
-            tokens, st.pages, *rest = step(self.params, st.pages, *args)
-        return (tokens, *rest)
+            tokens, st.pages, st.key_chain, finite, routing = step(
+                self.params, st.pages, *per_step, *resident)
+        d.mark("dispatch")
+        tokens, finite, routing = self._read(d, tokens, finite, routing)
+        d.mark("fetch")
+        self._note_routing(d, routing)
+        return tokens, finite.copy()
 
     def _run_decode(self, st: _EngineState, slots: List[int],
                     d: DispatchRecord) -> None:
@@ -1374,25 +1521,9 @@ class InferenceEngine:
         self._window_advance(
             st, d, [(s, int(st.context_lens[s]), 1) for s in slots])
         d.mark("build_inputs")
-        next_tokens, new_keys, finite, routing = self._launch_step(
-            st, self._decode_step, st.last_tokens,
-            st.context_lens, self._tables(st),
-            st.active, st.temps, st.top_ks, st.top_ps,
-            st.ban_a, st.ban_b, st.keys)
-        d.mark("dispatch")
-        self._start_routing_copy(routing)
-        next_tokens = np.asarray(next_tokens)
-        new_keys = np.asarray(new_keys)
-        finite = np.asarray(finite).copy()
-        if routing is not None:
-            routing = np.asarray(routing)
-        d.mark("fetch")
-        self._note_routing(d, routing)
-        # key chains advance ONLY for decoding slots: a slot mid-prefill
-        # keeps its admission-time seed key, so a request's sample stream
-        # depends on its seed alone, not on batch-mates' decode traffic
-        for s in slots:
-            st.keys[s] = new_keys[s]
+        next_tokens, finite = self._launch_step(
+            st, d, self._decode_step, st.last_tokens, st.context_lens,
+            self._tables(st), st.active)
         if st is not self._st:
             self.loop_profiler.finish(d)
             return          # engine restarted mid-dispatch: stale state
@@ -1431,6 +1562,7 @@ class InferenceEngine:
             sp = req.sampling
             if sp.top_p_decay > 0.0:
                 st.top_ps[s] = sp.top_p_at(len(req.out_tokens) + 1)
+                st.stale("top_ps")
             self._emit_and_check(st, req, tok)
         self.loop_profiler.finish(d)
 
@@ -1477,24 +1609,12 @@ class InferenceEngine:
         self._note_batch(st, disp, slots, decoding)
         disp.drafted = int(draft_lens.sum())
         disp.mark("build_inputs")
-        emit, new_keys, finite, routing = self._launch_step(
-            st, self._verify_step, verify_tokens, st.context_lens,
-            self._tables(st), vlens, st.temps, st.top_ks,
-            st.top_ps, st.ban_a, st.ban_b, st.keys)
-        disp.mark("dispatch")
-        self._start_routing_copy(routing)
-        emit = np.asarray(emit)
-        new_keys = np.asarray(new_keys)
-        finite = np.asarray(finite).copy()
-        if routing is not None:
-            routing = np.asarray(routing)
-        disp.mark("fetch")
-        self._note_routing(disp, routing)
-        # same key discipline as the plain decode step: exactly one
-        # split per decoding slot per step, so a sampled slot's stream
-        # is bit-identical spec-on vs spec-off
-        for s in slots:
-            st.keys[s] = new_keys[s]
+        # the key discipline is the plain decode step's (_split_keys):
+        # exactly one split per decoding slot per step, so a sampled
+        # slot's stream is bit-identical spec-on vs spec-off
+        emit, finite = self._launch_step(
+            st, disp, self._verify_step, verify_tokens, st.context_lens,
+            self._tables(st), vlens)
         if st is not self._st:
             self.loop_profiler.finish(disp)
             return          # engine restarted mid-dispatch: stale state
@@ -1540,6 +1660,7 @@ class InferenceEngine:
                 committed += 1
                 if sp.top_p_decay > 0.0:
                     st.top_ps[s] = sp.top_p_at(len(req.out_tokens) + 1)
+                    st.stale("top_ps")
                 self._emit_and_check(st, req, tok)
                 if req.state == RequestState.DONE:
                     break       # stop token mid-chain: drop the rest
@@ -1723,8 +1844,15 @@ class InferenceEngine:
         ``program_tables`` to lower from."""
         st, cfg = self._st, self.config
         S, zero = cfg.num_slots, np.int32(0)
-        per_slot = (st.temps, st.top_ks, st.top_ps, st.ban_a, st.ban_b,
-                    st.keys)
+
+        def placed(host):
+            return jax.ShapeDtypeStruct(host.shape, host.dtype,
+                                        sharding=self._beside)
+        # what _resident hands a step: the host's arrays as _place lays
+        # them (and as the chain comes back from a step), none read here
+        resident = (*(placed(getattr(st, name)) for name in _SAMPLING),
+                    placed(st.keys), placed(st.keys),
+                    placed(st.keys_given))
         pool = self._abstract_pool()
         pages = jax.tree_util.tree_leaves(pool)[0]
         found = {
@@ -1745,11 +1873,11 @@ class InferenceEngine:
             found["engine_verify"] = (
                 self.params, pool,
                 np.zeros((S, self.draft_k + 1), np.int32), st.context_lens,
-                self._tables(st), st.active) + per_slot
+                self._tables(st), st.active) + resident
         else:
             found["engine_decode"] = (
                 self.params, pool, st.last_tokens, st.context_lens,
-                self._tables(st), st.active) + per_slot
+                self._tables(st), st.active) + resident
         if self.host_cache is not None:
             page = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), pool)

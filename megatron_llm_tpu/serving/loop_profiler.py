@@ -12,7 +12,8 @@ launch —
 * ``dispatch``     the jitted call, until it RETURNS: argument upload
                    and enqueue (a last prefill chunk's first-token
                    sampler call included),
-* ``fetch``        until the last blocking read of the outputs returns,
+* ``fetch``        until the last of the launch's results is home (they
+                   set out together as the launch returns),
 * ``emit``         token commits, stream writes, retirement, telemetry,
 
 — and ``gap`` is the time between one launch's ``finish`` and the next
@@ -128,6 +129,10 @@ SSM_FIELDS = ("ssm_rows_live", "ssm_tokens", "ssm_state_bytes_held")
 KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
              "kv_held_bytes", "kv_full_pages_held", "kv_live_tokens")
 
+# what a launch moved between host and device (DispatchRecord's fields),
+# which the profiler also keeps running totals of
+HOST_FIELDS = ("host_uploads", "host_reads")
+
 # the compiled programs whose operations run inside a launch of each
 # kind (a last prefill chunk samples its first token in the same launch),
 # and the page programs, which run while the next launch's inputs are
@@ -236,6 +241,13 @@ class DispatchRecord:
     kv_held_bytes = 0
     kv_full_pages_held = 0
     kv_live_tokens = 0
+    # what the launch moved between host and device: host arrays handed
+    # to its programs (each table counts one; an array that lives on the
+    # device counts only in the launch that uploads it again after a
+    # write), and the times the host waited on results (those of one
+    # wait set out for the host together)
+    host_uploads = 0
+    host_reads = 0
     # the requests (and their trace ids) this launch served: the spans
     # that caused it
     requests: Tuple[int, ...] = ()
@@ -326,6 +338,7 @@ class DispatchRecord:
             "sampler_rows_filtered": self.sampler_rows_filtered,
             **{f: getattr(self, f) for f in DSA_FIELDS + MLA_FIELDS},
             **{f: getattr(self, f) for f in KV_FIELDS + SSM_FIELDS},
+            **{f: getattr(self, f) for f in HOST_FIELDS},
         }
 
     def note_state(self, rows: int, tokens: int, layers: int,
@@ -444,6 +457,8 @@ class LoopProfiler:
         "_phase_counts": "_lock",
         "_phase_launches": "_lock",
         "stalls": "_lock",
+        "host_uploads": "_lock",
+        "host_reads": "_lock",
         "_ring": "_lock",
         "_requests": "_lock",
         "_emitted_at_dispatches": "_lock",
@@ -474,6 +489,8 @@ class LoopProfiler:
                               for _ in LOOP_PHASES]
         self._phase_launches = [0] * len(LOOP_PHASES)
         self.stalls = 0
+        self.host_uploads = 0       # HOST_FIELDS, summed over launches
+        self.host_reads = 0
         # armed by the engine after warmup(): compile-time gaps between
         # warmup dispatches are expected, not stalls
         self.stall_armed = False
@@ -538,6 +555,8 @@ class LoopProfiler:
                     self._phase_launches[i] += 1
             if stalled:
                 self.stalls += 1
+            self.host_uploads += d.host_uploads
+            self.host_reads += d.host_reads
             self._ring.append(d)
         self._seq = d.seq + 1
         self._last_end = now
@@ -646,6 +665,7 @@ class LoopProfiler:
             gap = self.gap_secs
             phase_secs = dict(self.phase_secs)
             stalls = self.stalls
+            moved = {f: getattr(self, f) for f in HOST_FIELDS}
             counts = [list(c) for c in self._phase_counts]
             timed = list(self._phase_launches)
         snaps = {p: telemetry.histogram_snapshot(
@@ -672,6 +692,7 @@ class LoopProfiler:
             "wait_pct": wait_pct,
             "host_bubble_pct": bubble_pct,
             "stalls": stalls,
+            **moved,
             "window": {
                 "dispatches": len(recent),
                 "wall_secs": round(w_wall, 6),

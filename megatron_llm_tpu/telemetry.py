@@ -640,7 +640,17 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    record carries the same four for that launch; blocks stats() gain
 #    state_bytes_per_slot / state_bytes_held — see
 #    serving/loop_profiler.py ``SSM_FIELDS``
-TELEMETRY_SCHEMA_VERSION = 21
+# 22: what a launch moves between host and device: every launch record of
+#    the loop profiler's ring and of a postmortem bundle gains
+#    host_uploads (host arrays handed to the launch's programs, each
+#    table one; the sampling arrays and the key chain live on the device
+#    and count only in the launch that uploads them again after a write)
+#    and host_reads (times the host waited on the launch's results, which
+#    set out for the host together), and the loop block of stats() /
+#    engine_loop_stats their totals under the same names — see
+#    serving/loop_profiler.py ``HOST_FIELDS`` and serving/engine.py
+#    ``_EngineState``
+TELEMETRY_SCHEMA_VERSION = 22
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -348,7 +348,7 @@ def test_alert_transition_schema13_golden(tmp_path):
     test AND the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 21
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 22
     stream = telemetry.TelemetryStream(str(tmp_path))
 
     def sink(payload):
@@ -372,7 +372,7 @@ def test_alert_transition_schema13_golden(tmp_path):
         "schema", "kind", "time_unix", "event", "rule", "scope", "state",
         "severity", "value", "threshold", "window_secs", "since_unix",
         "bundle"))
-    assert rec["schema"] == 21
+    assert rec["schema"] == 22
     assert rec["kind"] == "serve"
     assert rec["rule"] == "qd"
     assert rec["scope"] == "replica"
@@ -728,7 +728,7 @@ def test_alert_chaos_two_replica_fleet(tmp_path):
            if '"alert_transition"' in line]
     states = [t["state"] for t in trs if t["rule"] == "error_rate"]
     assert states == ["firing", "resolved"]
-    assert all(t["schema"] == 21 and t["kind"] == "serve" for t in trs)
+    assert all(t["schema"] == 22 and t["kind"] == "serve" for t in trs)
     assert trs[0]["bundle"] == bundle
 
     # 6) serve_report renders the incident, correlated with the restart

@@ -493,27 +493,28 @@ def test_the_flags_lower_into_the_config():
 # the standing families' programs
 # ---------------------------------------------------------------------------
 
-# What ``tests/_program_fingerprints.py`` prints since PR 41, which MEANT
-# to change every family's programs: the cache's write keeps the pool's
-# own shape (``paged_kv.PagedKVCache._write`` scatters at (page, row) and
-# no longer through a one-row-a-token reshape), so that a decode step
-# that owns its pool writes it in place.  Nothing else of a program
-# moved: PR 40's hashes held from PR 37 (2bc6275) to PR 40.  A PR that
-# MEANS to change a family's program runs the script and records what it
-# prints here.
+# What ``tests/_program_fingerprints.py`` prints since PR 42, which MEANT
+# to change every family's decode step and nothing of a chunk
+# (``engine_prefill`` is PR 41's hash in every family): the step takes
+# the key chain with the keys the host gave since the last launch and
+# the rows they are for, and gives the chain back advanced only for the
+# slots that decoded (``InferenceEngine._split_keys``: two selects over
+# [S, 2] words).  PR 41 had changed both programs (the cache's write
+# keeps the pool's own shape).  A PR that MEANS to change a family's
+# program runs the script and records what it prints here.
 TRACED = {
     "mistral": {"engine_prefill": "97f7c403d8d97874",
-                "engine_decode": "b97f9efa5d5c72cc"},
+                "engine_decode": "1271bc7d18f7d88f"},
     "mixtral": {"engine_prefill": "16dc68e888901332",
-                "engine_decode": "a0d16f2e4a17d671"},
+                "engine_decode": "a5fbd77f197edc89"},
     "olmoe": {"engine_prefill": "ec12cf1b1f8b4824",
-              "engine_decode": "ef8a70800862ad50"},
+              "engine_decode": "cd0e4834fc1d6c4a"},
     "keye": {"engine_prefill": "8eafd00d20ed2ba5",
-             "engine_decode": "9dc6e3ea2bf61e94"},
+             "engine_decode": "0b2ddbd39a7462a8"},
     "mellum": {"engine_prefill": "3b7515947a69f378",
-               "engine_decode": "f3e74e8dd2f03ffd"},
+               "engine_decode": "0168b534430f68e2"},
     "kanana": {"engine_prefill": "7bc4fba0abd4ca5a",
-               "engine_decode": "b16ff525cecab742"},
+               "engine_decode": "d819cd2b833bd7c6"},
 }
 
 

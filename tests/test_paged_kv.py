@@ -82,7 +82,8 @@ def test_the_engine_resolves_each_program_once(tiny, monkeypatch, decode,
     tables = np.zeros((S, eng._max_blocks_per_slot), i32)
     knobs = (np.ones(S, np.float32), np.zeros(S, i32),
              np.zeros(S, np.float32), np.full(S, -1, i32),
-             np.full(S, -1, i32), np.zeros((S, 2), np.uint32))
+             np.full(S, -1, i32), np.zeros((S, 2), np.uint32),
+             np.zeros((S, 2), np.uint32), np.zeros(S, bool))
     jaxprs = {
         "decode": jax.make_jaxpr(eng._decode_step)(
             eng.params, st.pages, np.zeros(S, i32), np.zeros(S, i32),
