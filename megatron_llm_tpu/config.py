@@ -98,6 +98,131 @@ class ParallelConfig:
 LAYER_TYPES = ("sliding", "full", "mamba", "attention")
 
 
+# --- what runs with what ------------------------------------------------
+# Whether a model's mechanism A runs with B, another mechanism or a
+# feature of the runtime, is answered HERE and nowhere else: ``RUNS_WITH``
+# has a row for each mechanism, of what it does not run with, and
+# ``refusal`` reads it.  A new mechanism adds its predicate and its row;
+# the guards INSIDE a mechanism (a layer handed a cache it cannot use)
+# stay at the point that would otherwise compute garbage.
+#
+# The runtime's features, by the names the sentences use.  Whoever turns
+# one on asks ``refusal`` with it: ``serving/engine.py`` (its
+# EngineConfig's), ``ops/paged_kv.py::init_pools`` (the int8 pool),
+# ``models/gpt.py`` (the parallelism the mesh has in force).
+VERIFY_STEP = "the speculative verify step"
+INT8_POOL = "the int8 KV pool"
+HOST_TIER = "the host KV tier"
+PREEMPTION = "preemption"
+PREFIX_CACHE = "the prefix cache"
+TENSOR_PARALLEL = "tensor parallelism (tp > 1)"
+MODEL_PARALLEL = "tensor or pipeline parallelism (tp > 1, pp > 1)"
+FEATURES = (VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION, PREFIX_CACHE,
+            TENSOR_PARALLEL, MODEL_PARALLEL)
+# the features a model that does not run with them is not refused but
+# runs WITHOUT: whoever serves it turns the feature off and says so
+TURNED_OFF = (PREFIX_CACHE,)
+
+# What a config may have, by the names the sentences use, each with its
+# predicate over the config (as ``__post_init__`` has normalised it).
+SPARSE = "sparse attention (dsa_index_heads > 0)"
+LATENT = "latent attention (kv_lora_rank)"
+TYPED = "a layer type per layer (layer_types)"
+STATE_SPACE = "state-space layers ('mamba' among layer_types)"
+FIRST_DENSE = "leading dense layers (moe_first_dense_layers)"
+SHARE = "a share of the router's experts (moe_router_experts)"
+EXPERTS = "experts (num_experts > 1)"
+QK_NORM_WHOLE = "qk_norm (over the whole projection)"
+QK_NORM_PER_HEAD = "qk_norm_per_head"
+SLIDING = "a sliding window (sliding_window_size)"
+NOT_ROTARY = "a position embedding that is not rotary"
+SECTIONED = "sectioned rope (rope_sections)"
+ROPE_SCALING = "rope scaling"
+BIASES = "linear biases (add_bias_linear)"
+QKV_BIAS = "a bias on the QKV projections (add_qkv_bias)"
+PARALLEL_ATTN = "parallel_attn"
+POST_LN = "post-LN (use_post_ln)"
+OTHER_TYPES = "layer types other than 'mamba' and 'attention'"
+HAS = {
+    SPARSE: lambda c: c.dsa_index_heads > 0,
+    LATENT: lambda c: c.kv_lora_rank is not None,
+    TYPED: lambda c: c.layer_types is not None,
+    STATE_SPACE: lambda c: c.state_space,
+    FIRST_DENSE: lambda c: c.moe_first_dense_layers > 0,
+    SHARE: lambda c: c.holds_a_share,
+    EXPERTS: lambda c: c.num_experts > 1,
+    QK_NORM_WHOLE: lambda c: c.qk_norm,
+    QK_NORM_PER_HEAD: lambda c: c.qk_norm_per_head,
+    SLIDING: lambda c: c.sliding_window_size is not None,
+    NOT_ROTARY: lambda c: (c.position_embedding_type
+                           != PositionEmbeddingType.rotary),
+    SECTIONED: lambda c: c.rope_sections is not None,
+    ROPE_SCALING: lambda c: (c.rope_yarn_scaling is not None
+                             or c.rope_llama3_scaling is not None
+                             or c.rope_scaling_factor != 1.0),
+    BIASES: lambda c: c.add_bias_linear,
+    QKV_BIAS: lambda c: c.add_qkv_bias,
+    PARALLEL_ATTN: lambda c: c.parallel_attn,
+    POST_LN: lambda c: c.use_post_ln,
+    OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
+                                - {"mamba", "attention"}),
+}
+
+# THE TABLE: what a model has, and everything it does not run with.  A
+# config is told the first square it falls in, so a row that says more
+# of a model (state-space layers) stands before the row that says less
+# (a layer type per layer).
+RUNS_WITH = (
+    (SPARSE, (SLIDING, NOT_ROTARY, VERIFY_STEP, INT8_POOL,
+              TENSOR_PARALLEL)),
+    (STATE_SPACE, (OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
+                   VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
+                   MODEL_PARALLEL)),
+    (TYPED, (SPARSE, SECTIONED, VERIFY_STEP, INT8_POOL, HOST_TIER,
+             PREFIX_CACHE, MODEL_PARALLEL)),
+    (FIRST_DENSE, (TYPED, MODEL_PARALLEL)),
+    (LATENT, (NOT_ROTARY, SLIDING, TYPED, SPARSE, QK_NORM_WHOLE,
+              QK_NORM_PER_HEAD, SECTIONED, ROPE_SCALING, BIASES, QKV_BIAS,
+              PARALLEL_ATTN, VERIFY_STEP, INT8_POOL, HOST_TIER,
+              MODEL_PARALLEL)),
+    (QK_NORM_WHOLE, (QK_NORM_PER_HEAD, TENSOR_PARALLEL)),
+    (EXPERTS, (BIASES,)),
+    (SHARE, (MODEL_PARALLEL,)),
+)
+# how a square's sentence ends, where it says more than the two names
+TAILS = {
+    (STATE_SPACE, OTHER_TYPES):
+        " (a 'mamba' layer type goes with 'attention' layers only)",
+    (STATE_SPACE, PREEMPTION):
+        " (no snapshot of a request's state is kept): set preemption off "
+        "(--serve_preemption=0)",
+    (TYPED, PREFIX_CACHE):
+        " adopts nothing (a prefix's window pages, and a state-space "
+        "layer's state at its end, are not kept)",
+    (QK_NORM_WHOLE, QK_NORM_PER_HEAD): " (two forms of one norm: choose one)",
+    (QK_NORM_WHOLE, TENSOR_PARALLEL):
+        " (its mean square is over all the heads, which tensor parallelism "
+        "splits)",
+    (EXPERTS, BIASES): ": set add_bias_linear=False",
+}
+
+
+def refusal(cfg, features=()) -> Optional[str]:
+    """What the model config ``cfg`` is told of the first square of
+    ``RUNS_WITH`` it falls in, given the runtime ``features`` that are on
+    (names of ``FEATURES``; none: the model against itself), or None: it
+    runs with all of them."""
+    for has, whats in RUNS_WITH:
+        if not HAS[has](cfg):
+            continue
+        for what in whats:
+            if what in features if what in FEATURES else HAS[what](cfg):
+                said = what if what in TURNED_OFF else (
+                    f"not implemented with {what}")
+                return f"{has}: {said}{TAILS.get((has, what), '')}"
+    return None
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     """Architecture hyper-parameters.
@@ -369,19 +494,12 @@ class TransformerConfig:
             raise ValueError(
                 f"context_parallel_algo must be ring|ulysses|zigzag, got "
                 f"{self.context_parallel_algo!r}")
-        if self.dsa_index_heads > 0:
-            # what the selection does not support is refused by name
-            if self.dsa_topk < 1 or self.dsa_index_head_dim % 2:
-                raise ValueError(
-                    f"sparse attention needs dsa_topk >= 1 and an even "
-                    f"dsa_index_head_dim, got {self.dsa_topk} and "
-                    f"{self.dsa_index_head_dim}")
-            if self.sliding_window_size is not None:
-                raise ValueError("sparse attention (dsa_index_heads > 0) "
-                                 "is not implemented with a sliding window")
-            if self.position_embedding_type != PositionEmbeddingType.rotary:
-                raise ValueError("sparse attention (dsa_index_heads > 0) "
-                                 "needs the rotary position embedding")
+        if self.dsa_index_heads > 0 and (
+                self.dsa_topk < 1 or self.dsa_index_head_dim % 2):
+            raise ValueError(
+                f"sparse attention needs dsa_topk >= 1 and an even "
+                f"dsa_index_head_dim, got {self.dsa_topk} and "
+                f"{self.dsa_index_head_dim}")
         if self.layer_types is not None:
             types = tuple(str(t) for t in self.layer_types)
             object.__setattr__(self, "layer_types", types)
@@ -395,31 +513,14 @@ class TransformerConfig:
             if "sliding" in types and self.sliding_window_size is None:
                 raise ValueError("a 'sliding' layer type needs "
                                  "sliding_window_size")
-            if self.dsa_index_heads > 0 or self.rope_sections is not None:
-                raise ValueError("layer_types are not implemented with "
-                                 "sparse attention or sectioned rope")
-            if "mamba" in types:
-                # what a state-space layer is not made to work with
-                if set(types) - {"mamba", "attention"}:
-                    raise ValueError(
-                        "a 'mamba' layer type goes with 'attention' "
-                        f"layers only, got {types!r}")
-                if self.mamba_n_heads % self.mamba_n_groups or min(
+            if "mamba" in types and (
+                    self.mamba_n_heads % self.mamba_n_groups or min(
                         self.mamba_n_heads, self.mamba_d_head,
                         self.mamba_d_state, self.mamba_n_groups,
-                        self.mamba_chunk_size) < 1 or self.mamba_d_conv < 2:
-                    raise ValueError(
-                        "state-space layers need positive mamba sizes, "
-                        "mamba_d_conv >= 2 and whole groups of heads")
-                for on, what in (
-                        (self.add_bias_linear, "linear biases"),
-                        (self.parallel_attn, "parallel_attn"),
-                        (self.use_post_ln, "post-LN"),
-                        (self.kv_lora_rank is not None,
-                         "latent attention")):
-                    if on:
-                        raise ValueError("state-space layers ('mamba') "
-                                         f"are not implemented with {what}")
+                        self.mamba_chunk_size) < 1 or self.mamba_d_conv < 2):
+                raise ValueError(
+                    "state-space layers need positive mamba sizes, "
+                    "mamba_d_conv >= 2 and whole groups of heads")
         if self.moe_router_experts is not None or self.moe_experts_first:
             routed = self.moe_router_experts or self.num_experts
             if self.num_experts <= 1 or not (
@@ -430,10 +531,6 @@ class TransformerConfig:
                     f"from moe_experts_first={self.moe_experts_first}) must "
                     f"lie among the moe_router_experts={routed} the router "
                     "scores")
-            if not (1 <= self.moe_top_k <= routed):
-                raise ValueError(
-                    f"moe_top_k ({self.moe_top_k}) must be in "
-                    f"[1, moe_router_experts={routed}]")
         if self.rope_yarn_scaling is not None:
             f, orig, fast, slow, att = self.rope_yarn_scaling
             object.__setattr__(self, "rope_yarn_scaling", (
@@ -445,10 +542,6 @@ class TransformerConfig:
                     set(self.rope_yarn_layer_types) - set(self.layer_types)):
                 raise ValueError("rope_yarn_layer_types names types of "
                                  "layer_types")
-        if self.qk_norm and self.qk_norm_per_head:
-            raise ValueError("qk_norm (over the whole projection) and "
-                             "qk_norm_per_head are two forms of one norm: "
-                             "choose one")
         if self.rope_sections is not None:
             object.__setattr__(self, "rope_sections",
                                tuple(int(x) for x in self.rope_sections))
@@ -473,47 +566,26 @@ class TransformerConfig:
             raise ValueError(
                 f"moe_first_dense_layers ({self.moe_first_dense_layers}) "
                 f"must leave a sparse layer of {self.num_layers}")
-        if self.moe_first_dense_layers and self.layer_types is not None:
-            raise ValueError("moe_first_dense_layers is not implemented "
-                             "with a layer type per layer (layer_types)")
-        if self.kv_lora_rank is not None:
-            # what latent attention is not made to work with, by name
-            if self.position_embedding_type != PositionEmbeddingType.rotary:
-                raise ValueError("latent attention (kv_lora_rank) needs "
-                                 "the rotary position embedding")
-            if self.qk_rope_head_dim % 2 or min(
+        if self.kv_lora_rank is not None and (
+                self.qk_rope_head_dim % 2 or min(
                     self.kv_lora_rank, self.qk_nope_head_dim,
-                    self.qk_rope_head_dim, self.v_head_dim) < 1:
-                raise ValueError("latent attention needs positive widths "
-                                 "and an even qk_rope_head_dim")
-            for on, what in (
-                    (self.sliding_window_size is not None,
-                     "a sliding window"),
-                    (self.layer_types is not None, "layer_types"),
-                    (self.dsa_index_heads > 0, "sparse attention"),
-                    (self.qk_norm or self.qk_norm_per_head, "QK-norm"),
-                    (self.rope_sections is not None, "sectioned rope"),
-                    (self.rope_yarn_scaling is not None
-                     or self.rope_llama3_scaling is not None
-                     or self.rope_scaling_factor != 1.0, "rope scaling"),
-                    (self.add_bias_linear or self.add_qkv_bias,
-                     "linear biases"),
-                    (self.parallel_attn, "parallel_attn")):
-                if on:
-                    raise ValueError("latent attention (kv_lora_rank) is "
-                                     f"not implemented with {what}")
+                    self.qk_rope_head_dim, self.v_head_dim) < 1):
+            raise ValueError("latent attention needs positive widths "
+                             "and an even qk_rope_head_dim")
         if self.num_experts > 1:
-            if self.add_bias_linear:
-                raise ValueError("MoE experts do not support linear biases "
-                                 "(set add_bias_linear=False)")
             if not (1 <= self.moe_top_k <= self.routed_experts):
                 raise ValueError(
-                    f"moe_top_k ({self.moe_top_k}) must be in "
-                    f"[1, num_experts={self.routed_experts}]")
+                    f"moe_top_k ({self.moe_top_k}) must be in [1, "
+                    f"{self.routed_experts}], the experts the router scores")
             if self.moe_expert_axis not in ("auto", "expert", "replicated"):
                 raise ValueError(
                     f"moe_expert_axis must be auto|expert|replicated, got "
                     f"{self.moe_expert_axis!r}")
+        # what this model's mechanisms are not made to work with each
+        # other: the table's squares with no feature of the runtime on
+        said = refusal(self)
+        if said:
+            raise ValueError(said)
 
     # convenience ------------------------------------------------------
     @property

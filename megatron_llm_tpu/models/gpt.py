@@ -13,7 +13,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from megatron_llm_tpu.config import TransformerConfig
+from megatron_llm_tpu.config import (
+    MODEL_PARALLEL,
+    TENSOR_PARALLEL,
+    TransformerConfig,
+    refusal,
+)
 from megatron_llm_tpu.models.language_model import (
     init_language_model_params,
     language_model_forward,
@@ -38,6 +43,18 @@ def _vocab_unsharded() -> bool:
         return True                       # single-device path
 
 
+def parallelism_in_force() -> tuple:
+    """The features of ``config.RUNS_WITH`` that the mesh as it stands
+    turns on: what a model is refused under is asked of the table."""
+    from megatron_llm_tpu import topology
+
+    tp = not _vocab_unsharded()
+    pp = (topology.model_parallel_is_initialized()
+          and topology.get_pipeline_model_parallel_world_size() > 1)
+    return ((TENSOR_PARALLEL,) if tp else ()) + (
+        (MODEL_PARALLEL,) if tp or pp else ())
+
+
 class GPTModel:
     """Functional model: holds only the (hashable) config; params live in a
     pytree owned by the caller."""
@@ -48,29 +65,9 @@ class GPTModel:
         # pin the MoE expert-dim placement to the mesh as it stands NOW, so
         # spec time and trace time agree even across a mesh re-init
         self.cfg = resolve_expert_axis(cfg)
-        # QK-norm takes its mean square over the WHOLE projection, which
-        # tensor parallelism splits by heads: refused, not reduced
-        if cfg.qk_norm and not _vocab_unsharded():
-            raise ValueError(
-                "qk_norm normalises over the whole query/key projection "
-                "and is not implemented under tensor parallelism (tp > 1)")
-        # latent attention's one latent and one rotary key head are not
-        # sharded, and a pipeline stage's layers are taken to be of one
-        # kind: refused by name
-        if (cfg.latent_attention or cfg.moe_first_dense_layers
-                or cfg.state_space or cfg.holds_a_share):
-            from megatron_llm_tpu import topology
-
-            pp = (topology.get_pipeline_model_parallel_world_size()
-                  if topology.model_parallel_is_initialized() else 1)
-            if not _vocab_unsharded() or pp > 1:
-                raise ValueError(
-                    "latent attention (kv_lora_rank), leading dense "
-                    "layers (moe_first_dense_layers), state-space layers "
-                    "('mamba' among layer_types) and a share of the "
-                    "router's experts (moe_router_experts) are not "
-                    "implemented under tensor or pipeline parallelism "
-                    "(tp > 1, pp > 1)")
+        said = refusal(cfg, parallelism_in_force())
+        if said:
+            raise ValueError(said)
 
     # -- params ------------------------------------------------------------
     def init(self, key) -> dict:

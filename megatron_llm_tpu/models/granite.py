@@ -27,13 +27,11 @@ for a HYBRID stack:
 
 Tied head, RMSNorm, no bias but the convolution's.
 
-Refused by name: tensor and pipeline parallelism (``GPTModel``), training
-and an explicit attention mask (``transformer_layer``: no backward
-through the chunked scan is held to anything, and packed documents would
-need the state reset), the legacy decode caches (``mamba_mixer``); the
-serving engine refuses preemption, the verify step, the int8 pool and
-the host tier for a model with state-space layers and adopts no prefix
-(``serving/engine.py``).
+What state-space layers do not run with is rows of
+``config.RUNS_WITH``.  Refused inside the mechanism: training and an
+explicit attention mask (``transformer_layer``: no backward through the
+chunked scan is held to anything, and packed documents would need the
+state reset), the legacy decode caches (``mamba_mixer``).
 """
 
 from __future__ import annotations

@@ -23,13 +23,10 @@ assert-the-architecture-flags pattern of ``olmoe.py`` / ``keye.py`` /
 No compressed query (``q_lora_rank``), no group-limited routing, no
 multi-token-prediction head, no bias, untied head.
 
-What these are not made to work with is refused at construction: tensor
-and pipeline parallelism (``GPTModel``: the latent and the one rotary key
-head are not sharded, and a pipeline stage's layers are taken to be of
-one kind); the serving engine refuses the int8 pool, the speculative
-verify step and the host KV tier for a latent pool
-(``serving/engine.py``), and the legacy rolling / contiguous decode
-caches are refused by ``latent_attention`` itself.
+What these do not run with is rows of ``config.RUNS_WITH`` (the latent
+and the one rotary key head are not sharded, and a pipeline stage's
+layers are taken to be of one kind); the legacy rolling / contiguous
+decode caches are refused by ``latent_attention`` itself.
 """
 
 from __future__ import annotations

@@ -13,15 +13,19 @@ renormalised to sum to 1, and learned sparse attention
 position and the query attends its 2,048 best).  The vision tower is
 not built: requests are text.  No shared expert, no bias, untied head.
 
-What the selection does not support is refused here, at construction:
-tensor parallelism (the indexer's one key head and the per-query choice
-are not sharded).  The serving engine refuses the int8 pool and the
-speculative verify step for the same reason (``serving/engine.py``).
+What sparse attention does not run with (tensor parallelism: the
+indexer's one key head and the per-query choice are not sharded) is a
+row of ``config.RUNS_WITH``.
 """
 
 from __future__ import annotations
 
-from megatron_llm_tpu.config import TransformerConfig, PositionEmbeddingType
+from megatron_llm_tpu.config import (
+    TENSOR_PARALLEL,
+    PositionEmbeddingType,
+    TransformerConfig,
+    refusal,
+)
 from megatron_llm_tpu.models.gpt import GPTModel, _vocab_unsharded
 
 
@@ -39,10 +43,10 @@ class KeyeModel(GPTModel):
         assert cfg.dsa_index_heads > 0, \
             "keye attends through its sparse-attention indexer"
         assert cfg.sliding_window_size is None
+        # asked of the mesh under this module's own name (GPTModel asks
+        # again under its own): tests stand a sharded mesh in here
         if not _vocab_unsharded():
-            raise ValueError(
-                "sparse attention (dsa_index_heads > 0) is not implemented "
-                "under tensor parallelism (tp > 1)")
+            raise ValueError(refusal(cfg, (TENSOR_PARALLEL,)))
         super().__init__(cfg)
 
 

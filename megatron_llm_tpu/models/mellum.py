@@ -13,18 +13,21 @@ attention (32 query and 4 key-value heads of 128 over a hidden size of
 gates renormalised to sum to 1.  No shared expert, no bias, untied head.
 The published multi-token-prediction head is not built.
 
-What the pattern does not support is refused here, at construction:
-tensor parallelism (not tried) and pipeline parallelism (a stage's
-layers would need the pattern's phase; ``parallel/pipeline.py`` gives
-its layers no type).  The serving engine refuses the int8 pool, the
-speculative verify step and the host tier for such a model, and adopts
-no prefix (``serving/engine.py``); the legacy rolling decode cache
-asserts a window on every layer.
+What a layer type per layer does not run with is a row of
+``config.RUNS_WITH`` (tensor parallelism was not tried; a pipeline
+stage's layers would need the pattern's phase, and
+``parallel/pipeline.py`` gives its layers no type); the legacy rolling
+decode cache asserts a window on every layer.
 """
 
 from __future__ import annotations
 
-from megatron_llm_tpu.config import TransformerConfig, PositionEmbeddingType
+from megatron_llm_tpu.config import (
+    MODEL_PARALLEL,
+    PositionEmbeddingType,
+    TransformerConfig,
+    refusal,
+)
 from megatron_llm_tpu.models.gpt import GPTModel, _vocab_unsharded
 
 
@@ -40,14 +43,11 @@ class MellumModel(GPTModel):
         assert not (cfg.qk_norm or cfg.qk_norm_per_head)
         assert cfg.layer_types is not None, \
             "mellum's layers are of two types (layer_types)"
-        from megatron_llm_tpu import topology
-
-        pp = (topology.get_pipeline_model_parallel_world_size()
-              if topology.model_parallel_is_initialized() else 1)
-        if not _vocab_unsharded() or pp > 1:
-            raise ValueError(
-                "a layer type per layer (layer_types) is not implemented "
-                "under tensor or pipeline parallelism (tp > 1, pp > 1)")
+        # asked of the mesh under this module's own name (GPTModel asks
+        # again under its own, and of the pipeline): tests stand a
+        # sharded mesh in here
+        if not _vocab_unsharded():
+            raise ValueError(refusal(cfg, (MODEL_PARALLEL,)))
         super().__init__(cfg)
 
 

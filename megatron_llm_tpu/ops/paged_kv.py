@@ -29,9 +29,11 @@ reserved garbage block: padded chunk tokens and idle slots write there
 and nobody reads it unmasked.  All slots share the pool, so HBM is
 sized for aggregate traffic, not ``num_slots x max_len`` (the ragged
 paged-attention memory model, arXiv:2604.15464).  The engine keeps the
-pools (a list, one a layer) as an opaque pytree; ``init_pools``,
-``block_bytes`` and the three page programs' bodies (``copy_page``,
-``fetch_page``, ``load_page``) are all it needs of them.
+pools (a list, one a layer) as an opaque pytree and ONE :class:`CachePlan`
+of its model's cache (``plan``): the pools to make, the tables a program
+takes, what a launch counts.  ``block_bytes`` and the three page
+programs' bodies (``copy_page``, ``fetch_page``, ``load_page``) are all
+it needs beside that.
 
 A model with a layer type per layer (``cfg.layer_types``) has TWO GROUPS
 of pools: its ``full`` layers keep a request's pages for its whole
@@ -77,10 +79,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import List, Optional
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from megatron_llm_tpu.config import INT8_POOL, refusal
 
 KERNEL_MODES = ("auto", "on", "off")
 _LANES = 128
@@ -162,19 +167,13 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     dtype = dtype or cfg.compute_jnp_dtype
     indexed = cfg.dsa_index_heads > 0
     groups = layer_groups(cfg)
+    said = quantized and refusal(cfg, (INT8_POOL,))
+    if said:
+        raise ValueError(said)
     if cfg.latent_attention:
-        if quantized:
-            raise ValueError("latent attention (kv_lora_rank) is not "
-                             "implemented over the int8 KV pool")
         shape = (num_blocks, block_size, latent_width(cfg))
         return [{"latent_pages": jnp.zeros(shape, dtype)}
                 for _ in range(cfg.num_layers)]
-    if indexed and quantized:
-        raise ValueError("sparse attention (dsa_index_heads > 0) is not "
-                         "implemented over the int8 KV pool")
-    if groups is not None and quantized:
-        raise ValueError("a layer type per layer (layer_types) is not "
-                         "implemented over the int8 KV pool")
     if groups is not None and WINDOW in groups and not window_blocks:
         raise ValueError("a model with sliding layers among its "
                          "layer_types needs window_blocks")
@@ -574,6 +573,143 @@ def step_caches(pools, block_tables, context_lens, valid_lens,
                          else None)
             for p, g in zip(pools, groups)]
 
+
+@dataclasses.dataclass(frozen=True)
+class CachePlan:
+    """What the serving engine holds of its model's cache, worked out
+    ONCE (``plan``): this module's functions given their arguments.  The
+    engine asks it for the pools, the tables a program takes and a
+    launch's account, and knows nothing else of what a model keeps
+    between launches."""
+
+    cfg: Any
+    block_size: int
+    num_slots: int
+    prefill_kernel: str
+    # ``layer_groups(cfg)``: what ``step_caches`` takes
+    groups: Optional[tuple]
+    # what ``serving/kv_blocks.py::WindowGroup`` is built with, its pool's
+    # blocks first (every slot at its bound); None without a window group
+    window: Optional[tuple]
+    # a block's bytes over the layers of the full and the window group,
+    # and a slot's recurrent state over the state-space layers
+    group_block_bytes: tuple
+    state_bytes_per_slot: int
+    # learned sparse attention: the keys of a block its choice counts in
+    # and the blocks of a slot's table (0 and 0 without an indexer)
+    dsa_block_keys: int
+    dsa_table_blocks: int
+
+    def init_pools(self, num_blocks: int, quantized: bool = False):
+        return init_pools(self.cfg, num_blocks, self.block_size,
+                          quantized=quantized, num_slots=self.num_slots,
+                          window_blocks=self.window and self.window[0])
+
+    def tables(self, blocks, rows=slice(None)):
+        """What a program takes as ``block_tables``: rows ``rows`` of the
+        slots' table of the block manager ``blocks``, or of each group's
+        where there are groups."""
+        full = blocks.tables[rows].copy()
+        if self.groups is None:
+            return full
+        tables = {FULL: full}
+        if blocks.window is not None:
+            tables[WINDOW] = blocks.window.tables[rows].copy()
+        if STATE in self.groups and rows != slice(None):
+            # a state-space layer's "table": each row's slot (a decode
+            # step takes every slot, row s is slot s, and carries none)
+            tables[STATE] = np.arange(self.num_slots, dtype=np.int32)[rows]
+        return tables
+
+    def account(self, d, context_lens, valid_lens, n: int,
+                admitted: int) -> None:
+        """The cache's counters of one launch on its record ``d``
+        (``serving/loop_profiler.py``: ``DSA_FIELDS``, ``MLA_FIELDS``,
+        ``SSM_FIELDS``), from the host arrays its program is handed:
+        each row's ``context_lens`` and ``valid_lens`` (0: an idle row)
+        of ``n`` queries a row; ``admitted``: the requests that hold a
+        slot.  Returns at once for a model with no such mechanism."""
+        cfg, layers = self.cfg, self.cfg.num_layers
+        state_layers = self.groups.count(STATE) if self.groups else 0
+        if not (cfg.latent_attention or self.dsa_block_keys or state_layers):
+            return
+        live = valid_lens > 0
+        ctx, val = context_lens[live], valid_lens[live]
+        if state_layers:
+            d.ssm_rows_live = state_layers * len(val)
+            d.ssm_tokens = state_layers * int(val.sum())
+            d.ssm_state_bytes_held = admitted * self.state_bytes_per_slot
+        if not (cfg.latent_attention or self.dsa_block_keys):
+            return
+        # for each live query the keys it sees: positions 0..its own
+        first = np.cumsum(val) - val
+        sees = np.repeat(ctx + 1 - first, val) + np.arange(val.sum())
+        if cfg.latent_attention:
+            # the launch's kind says which count they are: a chunk's
+            # (query, key) pairs, a decode step's live keys
+            field = "mla_pairs" if d.kind == "prefill" else "mla_keys_live"
+            setattr(d, field, layers * int(sees.sum()))
+            if expands_latents(self.prefill_kernel, n):
+                d.mla_latents_expanded = layers * int((ctx + val).sum())
+        if self.dsa_block_keys:
+            from megatron_llm_tpu.ops.pallas import dsa_attention as _dsa
+
+            steps = _dsa.select_blocks(context_lens, valid_lens, n,
+                                       self.dsa_block_keys, xp=np)
+            d.dsa_keys_live = layers * int(sees.sum())
+            d.dsa_keys_selected = layers * int(
+                sees.clip(max=cfg.dsa_topk).sum())
+            d.dsa_select_blocks_counted = layers * int(steps.sum())
+            d.dsa_select_blocks_table = (layers * steps.size
+                                         * self.dsa_table_blocks)
+
+    def account_routing(self, d, counts) -> None:
+        """A sparse model's launch on its record (``MOE_FIELDS``) from
+        ``counts`` [layers, E], the histogram of live assignments over
+        the experts the router scores as the program returned it (None:
+        a dense model)."""
+        if counts is None:
+            return
+        cfg = self.cfg
+        d.moe_assignments = d.moe_assignments_held = int(counts.sum())
+        if cfg.holds_a_share:
+            first = cfg.moe_experts_first
+            d.moe_assignments_held = int(
+                counts[:, first:first + cfg.num_experts].sum())
+        d.moe_experts_touched = int((counts > 0).sum())
+        d.moe_expert_slots = int(counts.size)
+        d.moe_busiest_expert_assignments = int(counts.max(axis=1).sum())
+
+
+def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
+         prefill_chunk: int, prefill_kernel: str) -> CachePlan:
+    """The :class:`CachePlan` of a model of config ``cfg`` served from
+    pages of ``block_size`` tokens, ``num_slots`` slots of
+    ``max_blocks_per_slot`` pages and chunks of ``prefill_chunk``."""
+    groups = layer_groups(cfg)
+    window, dsa_block_keys, dsa_table_blocks = None, 0, 0
+    if groups is not None and WINDOW in groups:
+        size = int(cfg.sliding_window_size)
+        bound = window_pages_bound(size, prefill_chunk, block_size)
+        window = (num_slots * min(bound, max_blocks_per_slot) + 1,
+                  block_size, num_slots, max_blocks_per_slot, size, bound)
+    if cfg.dsa_index_heads > 0:
+        from megatron_llm_tpu.ops.pallas import dsa_attention as _dsa
+
+        dsa_block_keys = _dsa.block_keys(
+            block_size, cfg.num_query_groups, cfg.head_dim,
+            cfg.compute_jnp_dtype, max_blocks_per_slot)
+        dsa_table_blocks = -(-max_blocks_per_slot * block_size
+                             // dsa_block_keys)
+    # the pools' shapes, nothing allocated: a block's and a slot's bytes
+    # do not depend on how many blocks there are
+    pools = jax.eval_shape(lambda: init_pools(
+        cfg, 2, block_size, window_blocks=2, num_slots=num_slots))
+    return CachePlan(
+        cfg, block_size, num_slots, prefill_kernel, groups, window,
+        tuple(block_bytes([p for p, g in zip(pools, groups or ())
+                           if g == which]) for which in (FULL, WINDOW)),
+        state_bytes_per_slot(pools), dsa_block_keys, dsa_table_blocks)
 
 def pools_of(caches: List[PagedKVCache]) -> List[dict]:
     """The pools as a step left them."""

@@ -50,6 +50,7 @@ Interpret-mode tests run the kernel on the CPU via ``_INTERPRET``.
 from __future__ import annotations
 
 import functools
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -123,6 +124,20 @@ def describe(k: int, n: int, dtype) -> dict:
     return {"k": k, "n": n, "tk": tk, "tn": tn,
             "steps_per_visit": (k // tk) * (n // tn),
             "vmem_bytes": vmem_bytes(_TILE_ROWS, tk, tn, dtype)}
+
+
+def moe_expert_tiles(mcfg) -> Optional[Dict[str, Dict[str, int]]]:
+    """The blocks the experts' grouped matmul takes at a sparse model's
+    widths (``tiles``, a function of the operands' shapes): for ``w_in``
+    [H, (2x)F] and ``w_out`` [F, H] the widths, the block and the grid
+    steps a visit.  None for a dense model."""
+    if mcfg.num_experts <= 1:
+        return None
+    H, F = mcfg.hidden_size, mcfg.expert_hidden_size
+    wide = (2 if mcfg.glu_activation else 1) * F
+    dtype = mcfg.compute_jnp_dtype
+    return {"w_in": describe(H, wide, dtype),
+            "w_out": describe(F, H, dtype)}
 
 
 def _visits(group_sizes: jax.Array, row_tiles: int, tm: int, rows: int):

@@ -99,10 +99,11 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from megatron_llm_tpu import config as model_config
 from megatron_llm_tpu import hlo_collectives, telemetry, tracing
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.ops import paged_kv
-from megatron_llm_tpu.ops.pallas import dsa_attention, grouped_matmul
+from megatron_llm_tpu.ops.pallas import grouped_matmul
 from megatron_llm_tpu.serving.cache_observatory import CacheObservatory
 from megatron_llm_tpu.serving.drafter import draft_budget, lookup_draft
 from megatron_llm_tpu.serving.kv_blocks import (
@@ -123,11 +124,6 @@ from megatron_llm_tpu.serving.request import (
     SamplingParams,
 )
 from megatron_llm_tpu.serving.loop_profiler import (
-    DSA_FIELDS,
-    MLA_FIELDS,
-    KV_FIELDS,
-    MOE_FIELDS,
-    SSM_FIELDS,
     DispatchRecord,
     LoopProfiler,
     RequestSpan,
@@ -142,21 +138,6 @@ from megatron_llm_tpu.text_generation.sampling import (
     rows_asking,
     sample_batched,
 )
-
-
-def moe_expert_tiles(mcfg) -> Optional[Dict[str, Dict[str, int]]]:
-    """The blocks the experts' grouped matmul takes at a sparse model's
-    widths (``ops/pallas/grouped_matmul.py::tiles``, a function of the
-    operands' shapes): for ``w_in`` [H, (2x)F] and ``w_out`` [F, H] the
-    widths, the block and the grid steps a visit.  None for a dense
-    model."""
-    if mcfg.num_experts <= 1:
-        return None
-    H, F = mcfg.hidden_size, mcfg.expert_hidden_size
-    wide = (2 if mcfg.glu_activation else 1) * F
-    dtype = mcfg.compute_jnp_dtype
-    return {"w_in": grouped_matmul.describe(H, wide, dtype),
-            "w_out": grouped_matmul.describe(F, H, dtype)}
 
 
 @dataclass
@@ -398,7 +379,7 @@ class InferenceEngine:
                                                       one_device)
         # a sparse model's expert blocks: static like the two above
         # (stats()['moe_expert_tiles'], left out for a dense model)
-        self.moe_expert_tiles = moe_expert_tiles(mcfg)
+        self.moe_expert_tiles = grouped_matmul.moe_expert_tiles(mcfg)
         # the speculative [S, K+1] verify forward is another small-n
         # prefill call, so it rides the resolved PREFILL path.  draft_k
         # is a compiled shape: flipping it later would recompile, so it
@@ -408,85 +389,27 @@ class InferenceEngine:
                              f"got {cfg.draft_k}")
         self.speculative = bool(cfg.speculative)
         self.draft_k = int(cfg.draft_k) if self.speculative else 0
-        # learned sparse attention: each query attends its indexer's
-        # top-k, which the [S, K+1] verify step's reads do not implement
-        # (nor the int8 pool: ops/paged_kv.py::init_pools refuses it)
-        self._dsa_topk = (int(mcfg.dsa_topk) if mcfg.dsa_index_heads > 0
-                          else 0)
-        # for the launch records: the keys of a block the choice counts
-        # in, and the blocks of a slot's table
-        self._dsa_block_keys = self._dsa_table_blocks = 0
-        if self._dsa_topk:
-            self._dsa_block_keys = dsa_attention.block_keys(
-                cfg.block_size, mcfg.num_query_groups, mcfg.head_dim,
-                mcfg.compute_jnp_dtype, self._max_blocks_per_slot)
-            self._dsa_table_blocks = -(
-                -self._max_blocks_per_slot * cfg.block_size
-                // self._dsa_block_keys)
-        if self._dsa_topk and self.speculative:
-            raise ValueError(
-                "sparse attention (dsa_index_heads > 0) is not implemented "
-                "for the speculative verify step")
-        # latent attention: a pool of latents (ops/paged_kv.py) whose
-        # reads are the decode and the chunk walk only.  The prefix cache
-        # carries over: a page is a page of every array
-        self._latent = bool(mcfg.latent_attention)
-        if self._latent:
-            for on, what in ((self.speculative, "the speculative verify "
-                              "step"), (cfg.int8_kv_cache, "the int8 KV "
-                              "pool"), (cfg.host_cache_bytes > 0, "the "
-                              "host KV tier")):
-                if on:
-                    raise ValueError("latent attention (kv_lora_rank) is "
-                                     f"not implemented for {what}")
-        # a layer type per layer: two groups of pools (ops/paged_kv.py),
-        # the window group sized for every slot at its bound.  What the
-        # second group is not built for is refused by name, and a prefix
-        # is not adopted: its window pages are not kept
-        self._layer_groups = paged_kv.layer_groups(mcfg)
-        self._window_bound = self._window_blocks = 0
-        self._state_layers = 0
-        # the experts this chip holds among those its router scores
-        # (None: all), for the launch records
-        self._experts_held = (
-            slice(mcfg.moe_experts_first,
-                  mcfg.moe_experts_first + mcfg.num_experts)
-            if mcfg.holds_a_share else None)
-        if self._layer_groups is not None:
-            self._state_layers = self._layer_groups.count(paged_kv.STATE)
-            whose = ("state-space layers ('mamba' among layer_types) are"
-                     if self._state_layers else
-                     "a layer type per layer (layer_types) is")
-            for on, what in ((self.speculative, "the speculative verify "
-                              "step"), (cfg.int8_kv_cache, "the int8 KV "
-                              "pool"), (cfg.host_cache_bytes > 0, "the "
-                              "host KV tier")):
-                if on:
-                    raise ValueError(f"{whose} not implemented for {what}")
-            # state-space layers: a third kind of per-request state, two
-            # arrays a SLOT beside the pages (ops/paged_kv.py).  No
-            # snapshot of a state is kept, so a request that gave its
-            # slot up could not take its state back: preemption is
-            # refused, and a prefix is not adopted
-            if self._state_layers and cfg.preemption:
-                raise ValueError(
-                    "state-space layers ('mamba' among layer_types) are "
-                    "not implemented for preemption (no snapshot of a "
-                    "request's state is kept): set preemption off "
-                    "(--serve_preemption=0)")
-            if cfg.prefix_cache:
-                print(" * a layer type per layer: the prefix cache adopts "
-                      "nothing (a prefix's window pages, and a state-space "
-                      "layer's state at its end, are not kept)",
-                      flush=True)
-                cfg.prefix_cache = False
-            if paged_kv.WINDOW in self._layer_groups:
-                self._window_bound = paged_kv.window_pages_bound(
-                    int(mcfg.sliding_window_size), cfg.prefill_chunk,
-                    cfg.block_size)
-                self._window_blocks = (
-                    cfg.num_slots * min(self._window_bound,
-                                        self._max_blocks_per_slot) + 1)
+        # what this model does not run with, of what this engine turns
+        # on, is asked of the one table (config.RUNS_WITH); the prefix
+        # cache is the table's one column that is turned off, not refused
+        said = model_config.refusal(mcfg, [what for on, what in (
+            (self.speculative, model_config.VERIFY_STEP),
+            (cfg.int8_kv_cache, model_config.INT8_POOL),
+            (cfg.host_cache_bytes > 0, model_config.HOST_TIER),
+            (cfg.preemption, model_config.PREEMPTION)) if on])
+        if said:
+            raise ValueError(said)
+        said = cfg.prefix_cache and model_config.refusal(
+            mcfg, (model_config.PREFIX_CACHE,))
+        if said:
+            print(f" * {said}", flush=True)
+            cfg.prefix_cache = False
+        # everything this engine knows of its model's cache (the groups
+        # of pools, a window group's sizes, the tables a program takes,
+        # what a launch counts): ops/paged_kv.py's plan
+        self._cache = paged_kv.plan(
+            mcfg, cfg.block_size, cfg.num_slots, self._max_blocks_per_slot,
+            cfg.prefill_chunk, self.prefill_kernel)
 
         # cache observatory (serving/cache_observatory.py): per-prefix
         # heat, eviction forensics, ghost capacity tiers.  Engine-
@@ -561,24 +484,6 @@ class InferenceEngine:
         self.occupancy_sum = 0          # sum of active slots over decode steps
         self.drafted_tokens = 0         # prompt-lookup proposals verified
         self.accepted_tokens = 0        # proposals committed by verify
-        # routing of a sparse model, summed over launches (loop_profiler's
-        # MOE_FIELDS: the same four are on every launch's record)
-        for f in MOE_FIELDS:
-            setattr(self, f, 0)
-        # learned sparse attention, summed over launches (the record's
-        # fields of the same names)
-        for f in DSA_FIELDS + MLA_FIELDS:
-            setattr(self, f, 0)
-        # the two groups of a model with a layer type per layer, summed
-        # over launches (the record's fields of the same names), and a
-        # block's bytes over the layers of each group (full, window)
-        for f in KV_FIELDS + SSM_FIELDS:
-            setattr(self, f, 0)
-        groups = self._layer_groups or ()
-        self._group_block_bytes = tuple(
-            paged_kv.block_bytes([p for p, g in zip(self._st.pages, groups)
-                                  if g == which])
-            for which in (paged_kv.FULL, paged_kv.WINDOW))
         self.prefill_secs = 0.0
         self.decode_secs = 0.0
         self.finished: Dict[str, int] = {}
@@ -624,23 +529,14 @@ class InferenceEngine:
                 # queued spills reference the abandoned pool; resident
                 # host entries and counters survive the restart
                 self.host_cache.on_pool_reset()
-        pages = paged_kv.init_pools(
-            self.model.cfg, self._num_blocks, cfg.block_size,
-            quantized=cfg.int8_kv_cache, window_blocks=self._window_blocks,
-            num_slots=cfg.num_slots)
-        window = None
-        if self._window_blocks:
-            window = WindowGroup(
-                self._window_blocks, cfg.block_size, cfg.num_slots,
-                self._max_blocks_per_slot,
-                int(self.model.cfg.sliding_window_size), self._window_bound)
-        blocks = BlockManager(self._num_blocks, cfg.block_size,
-                              cfg.num_slots, self._max_blocks_per_slot,
-                              prefix_cache=cfg.prefix_cache,
-                              observatory=self.cache_observatory,
-                              host_cache=self.host_cache, window=window,
-                              state_bytes_per_slot=(
-                                  paged_kv.state_bytes_per_slot(pages)))
+        pages = self._cache.init_pools(self._num_blocks,
+                                       quantized=cfg.int8_kv_cache)
+        blocks = BlockManager(
+            self._num_blocks, cfg.block_size, cfg.num_slots,
+            self._max_blocks_per_slot, prefix_cache=cfg.prefix_cache,
+            observatory=self.cache_observatory, host_cache=self.host_cache,
+            window=self._cache.window and WindowGroup(*self._cache.window),
+            state_bytes_per_slot=self._cache.state_bytes_per_slot)
         sched = Scheduler(self.queue, blocks, cfg.max_model_len,
                           draft_k=self.draft_k)
         if carry is not None:
@@ -705,6 +601,12 @@ class InferenceEngine:
             d.host_uploads += 2
         return (*(st.placed[name] for name in _SAMPLING), st.key_chain,
                 *given)
+
+    @property
+    def _layer_groups(self) -> Optional[tuple]:
+        """The pool group of each layer (the plan's): what
+        ``paged_kv.step_caches`` takes beside the pools."""
+        return self._cache.groups
 
     # current-state views (the HTTP server, tools and tests address the
     # engine, not a state generation)
@@ -1221,55 +1123,24 @@ class InferenceEngine:
 
     # -- prefill --------------------------------------------------------
 
-    def _tables(self, st: _EngineState, rows=slice(None)):
-        """What a program takes as ``block_tables``: rows ``rows`` of the
-        slots' table, or of each group's where there are two."""
-        full = st.blocks.tables[rows].copy()
-        if self._layer_groups is None:
-            return full
-        tables = {paged_kv.FULL: full}
-        if st.blocks.window is not None:
-            tables[paged_kv.WINDOW] = st.blocks.window.tables[rows].copy()
-        if self._state_layers and rows != slice(None):
-            # a state-space layer's "table": each row's slot (a decode
-            # step takes every slot, row s is slot s, and carries none)
-            tables[paged_kv.STATE] = np.arange(
-                self.config.num_slots, dtype=np.int32)[rows]
-        return tables
-
-    def _note_state(self, st: _EngineState, d: DispatchRecord, rows: int,
-                    tokens: int) -> None:
-        """A launch of a model with state-space layers: the rows whose
-        state it advances and the tokens it scans, on the record and in
-        the running totals (``SSM_FIELDS``)."""
-        if not self._state_layers:
-            return
-        d.note_state(rows, tokens, self._state_layers,
-                     len(st.scheduler.active)
-                     * st.blocks.state_bytes_per_slot)
-        for f in SSM_FIELDS:
-            setattr(self, f, getattr(self, f) + getattr(d, f))
-
     def _window_advance(self, st: _EngineState, d: DispatchRecord,
                         writes) -> None:
         """Before a launch of a model with a window group: for each
         (slot, start, n) of ``writes`` take the window pages the launch
         writes and give back those behind its window
         (``WindowGroup.advance_locked``); then what the pool holds against the
-        tokens it holds them for, on the record and in the totals."""
+        tokens it holds them for, on the record."""
         if st.blocks.window is None:
             return
         with TraceAnnotation("loop.kv_release", seq=d.seq):
             (d.kv_window_pages_returned, d.kv_window_pages_spanned,
              d.kv_full_pages_held, held) = st.blocks.window_advance(writes)
-            full_bytes, window_bytes = self._group_block_bytes
+            full_bytes, window_bytes = self._cache.group_block_bytes
             d.kv_held_bytes = (d.kv_full_pages_held * full_bytes
                                + held * window_bytes)
             d.kv_live_tokens = sum(
                 max(int(st.context_lens[r.slot]), r.prefill_pos)
                 for r in st.scheduler.active.values())
-        for f in KV_FIELDS:
-            setattr(self, f, getattr(self, f) + getattr(d, f))
 
     def _writable(self, st: _EngineState, slot: int, block_idx: int) -> None:
         """Copy-on-write barrier before a device write into a slot's
@@ -1345,14 +1216,14 @@ class InferenceEngine:
         for bi in range(start // bs, (start + valid - 1) // bs + 1):
             self._writable(st, req.slot, bi)
         self._window_advance(st, d, [(req.slot, start, valid)])
-        table = self._tables(st, slice(req.slot, req.slot + 1))
+        table = self._cache.tables(st.blocks,
+                                   slice(req.slot, req.slot + 1))
         d.start, d.valid = start, valid
         d.cached_tokens = req.cached_prompt_tokens
         d.requests = (req.id,)
         d.traces = (req.trace_id,) if req.trace_id else ()
-        self._note_selection(d, start + 1 + np.arange(valid),
-                             np.asarray([start]), np.asarray([valid]), C)
-        self._note_state(st, d, 1, valid)
+        self._cache.account(d, np.asarray([start]), np.asarray([valid]), C,
+                            len(st.scheduler.active))
         d.mark("build_inputs")
         finite = True
         handed = (toks, np.int32(start), np.int32(valid), table)
@@ -1382,7 +1253,7 @@ class InferenceEngine:
             # read but the histogram, and the wait is for the chunk
             (routing,) = self._read(d, routing, until=st.pages[0])
         d.mark("fetch")
-        self._note_routing(d, routing)
+        self._cache.account_routing(d, routing)
         if st is not self._st:
             self.loop_profiler.finish(d)
             return          # engine restarted mid-dispatch: stale state
@@ -1429,41 +1300,6 @@ class InferenceEngine:
             jax.block_until_ready(until)
         return home
 
-    def _note_routing(self, d: DispatchRecord, counts) -> None:
-        """A sparse model's launch: its routing on the record and in the
-        running totals."""
-        if counts is None:
-            return
-        d.note_routing(counts, self._experts_held)
-        for f in MOE_FIELDS:
-            setattr(self, f, getattr(self, f) + getattr(d, f))
-
-    def _note_selection(self, d: DispatchRecord, sees, context_lens,
-                        valid_lens, n: int) -> None:
-        """A launch of a model with a latent pool: the keys its live
-        queries see (``MLA_FIELDS``).  A launch of a model with a
-        sparse-attention indexer: the keys
-        its live queries see and attend and the blocks its choice counts
-        over (from the arrays the program is handed: ``n`` queries a
-        slot), on the record and in the running totals."""
-        if self._latent:
-            expanded = 0
-            if paged_kv.expands_latents(self.prefill_kernel, n):
-                live = valid_lens > 0
-                expanded = int((context_lens + valid_lens)[live].sum())
-            d.note_latent(sees, self.model.cfg.num_layers, expanded)
-            for f in MLA_FIELDS:
-                setattr(self, f, getattr(self, f) + getattr(d, f))
-        if not self._dsa_topk:
-            return
-        d.note_selection(
-            sees, self._dsa_topk, self.model.cfg.num_layers,
-            dsa_attention.select_blocks(context_lens, valid_lens, n,
-                                        self._dsa_block_keys, xp=np),
-            self._dsa_table_blocks)
-        for f in DSA_FIELDS:
-            setattr(self, f, getattr(self, f) + getattr(d, f))
-
     def _note_batch(self, st: _EngineState, d: DispatchRecord,
                     slots: List[int], decoding: List[Request]) -> None:
         """What a decode/verify launch works on, for its record.  The
@@ -1479,9 +1315,8 @@ class InferenceEngine:
             self.model.cfg.padded_vocab_size)
         d.sampler_rows_drawn = int(drawn.sum())
         d.sampler_rows_filtered = int(filtered.sum())
-        self._note_selection(d, st.context_lens[slots] + 1,
-                             st.context_lens, st.active, 1)
-        self._note_state(st, d, len(slots), len(slots))
+        self._cache.account(d, st.context_lens, st.active, 1,
+                            len(st.scheduler.active))
         self.sample_draw_steps += d.sampler_rows_drawn > 0
         self.sample_sort_steps += d.sampler_rows_filtered > 0
 
@@ -1507,7 +1342,7 @@ class InferenceEngine:
         d.mark("dispatch")
         tokens, finite, routing = self._read(d, tokens, finite, routing)
         d.mark("fetch")
-        self._note_routing(d, routing)
+        self._cache.account_routing(d, routing)
         return tokens, finite.copy()
 
     def _run_decode(self, st: _EngineState, slots: List[int],
@@ -1523,7 +1358,7 @@ class InferenceEngine:
         d.mark("build_inputs")
         next_tokens, finite = self._launch_step(
             st, d, self._decode_step, st.last_tokens, st.context_lens,
-            self._tables(st), st.active)
+            self._cache.tables(st.blocks), st.active)
         if st is not self._st:
             self.loop_profiler.finish(d)
             return          # engine restarted mid-dispatch: stale state
@@ -1614,7 +1449,7 @@ class InferenceEngine:
         # slot's stream is bit-identical spec-on vs spec-off
         emit, finite = self._launch_step(
             st, disp, self._verify_step, verify_tokens, st.context_lens,
-            self._tables(st), vlens)
+            self._cache.tables(st.blocks), vlens)
         if st is not self._st:
             self.loop_profiler.finish(disp)
             return          # engine restarted mid-dispatch: stale state
@@ -1859,7 +1694,7 @@ class InferenceEngine:
             "engine_prefill": (
                 self.params, pool,
                 np.zeros((1, cfg.prefill_chunk), np.int32), zero, zero,
-                self._tables(st, slice(0, 1))),
+                self._cache.tables(st.blocks, slice(0, 1))),
             # a chunk's last logits: the prefill program's output
             "engine_sample_first": (
                 jax.ShapeDtypeStruct(
@@ -1873,11 +1708,11 @@ class InferenceEngine:
             found["engine_verify"] = (
                 self.params, pool,
                 np.zeros((S, self.draft_k + 1), np.int32), st.context_lens,
-                self._tables(st), st.active) + resident
+                self._cache.tables(st.blocks), st.active) + resident
         else:
             found["engine_decode"] = (
                 self.params, pool, st.last_tokens, st.context_lens,
-                self._tables(st), st.active) + resident
+                self._cache.tables(st.blocks), st.active) + resident
         if self.host_cache is not None:
             page = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), pool)
@@ -1940,6 +1775,7 @@ class InferenceEngine:
         with self._finished_lock:
             finished = dict(self.finished)
         dec = max(self.decode_steps, 1)
+        loop = self.loop_profiler.stats()
         s.update({
             "decode_steps": self.decode_steps,
             "sample_draw_steps": self.sample_draw_steps,
@@ -1960,14 +1796,15 @@ class InferenceEngine:
             "draft_k": self.draft_k,
             "drafted_tokens": self.drafted_tokens,
             "accepted_tokens": self.accepted_tokens,
-            **{f: getattr(self, f) for f in MOE_FIELDS},
+            # what the launches counted, summed where they finish (the
+            # profiler's totals); those the 'loop' block carries stay there
+            **{f: n for f, n in self.loop_profiler.totals().items()
+               if f not in loop},
             **({"moe_expert_tiles": self.moe_expert_tiles}
                if self.moe_expert_tiles else {}),
-            **{f: getattr(self, f) for f in DSA_FIELDS + MLA_FIELDS},
-            **{f: getattr(self, f) for f in KV_FIELDS + SSM_FIELDS},
             "engine_restarts": self.engine_restarts,
             "slots_evicted_nonfinite": self.slots_evicted_nonfinite,
-            "loop": self.loop_profiler.stats(),
+            "loop": loop,
             "cache": self.cache_observatory.stats(),
             # the programs' instruction tables, once program_tables() has
             # been asked for; beside them the pool's bytes, which

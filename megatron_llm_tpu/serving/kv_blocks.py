@@ -265,22 +265,20 @@ class BlockManager:
                  state_bytes_per_slot: int = 0):
         assert num_blocks >= 2, "need at least one block beyond the garbage"
         assert block_size >= 1 and num_slots >= 1
-        if window is not None and (prefix_cache or host_cache is not None):
-            # a prefix is whole only with the window group's pages of its
-            # last window, which are not kept: nothing is adopted
-            raise ValueError("a window group adopts no prefix: it needs "
-                             "prefix_cache off and no host tier")
         self.window = window
         # a model with state-space layers: what a slot's recurrent state
         # takes (ops/paged_kv.py's ``state`` group).  A slot IS its
         # state's place, so admission needs nothing beyond the free slot
         # it always needed; the bytes are for stats()
         self.state_bytes_per_slot = int(state_bytes_per_slot)
-        if self.state_bytes_per_slot and (prefix_cache
-                                          or host_cache is not None):
-            raise ValueError("a model with state-space layers adopts no "
-                             "prefix (a prefix's state is not kept): it "
-                             "needs prefix_cache off and no host tier")
+        if (window is not None or self.state_bytes_per_slot) and (
+                prefix_cache or host_cache is not None):
+            # a prefix is whole only with the window group's pages of its
+            # last window and a state-space layer's state at its end,
+            # which are not kept: nothing is adopted
+            raise ValueError("a model with a window group or state-space "
+                             "layers adopts no prefix: it needs "
+                             "prefix_cache off and no host tier")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_slots = int(num_slots)

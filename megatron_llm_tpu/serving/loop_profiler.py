@@ -102,36 +102,69 @@ LOOP_PHASE_BUCKETS = (
     0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
 )
 
-# a launch's routing counts (DispatchRecord.note_routing), which the
-# engine also keeps running totals of
+# What a launch counts, by who fills it (all 0 where the model has no such
+# mechanism).  Each name is an attribute of the launch's DispatchRecord,
+# a key of its ``as_dict()`` and a running total of the profiler
+# (``LoopProfiler.totals``): a new counter is one name here, filled by
+# who knows it, and nothing else (``telemetry.TELEMETRY_SCHEMA_VERSION``
+# says why it is no change of schema).
+#
+# routing of a sparse model's launch, summed over its layers, from the
+# histogram its program returns (``ops/paged_kv.py::CachePlan.
+# account_routing``): live (token, choice) assignments; experts that
+# received at least one; experts there are (layers x E); each layer's
+# largest count of assignments to one expert; and of the live
+# assignments those that fell on an expert this chip holds (all of them
+# unless the layer holds a share of its router's experts:
+# ``cfg.moe_router_experts``)
 MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
               "moe_busiest_expert_assignments", "moe_assignments_held")
 
-# a launch's account of learned sparse attention (DispatchRecord's fields;
-# all 0 for a model with no indexer), which the engine also keeps running
-# totals of
+# learned sparse attention (a model with an indexer), summed over the
+# launch's live queries and the layers (``CachePlan.account``): the keys
+# each query sees (its context and itself), and the same with each term
+# cut at the top-k, which is what it attends; the choice's work: blocks
+# of keys its select steps count over (each step stops at its slot's
+# last live block), summed over the launch's steps and the layers, and
+# the same had every step counted its slot's whole table
 DSA_FIELDS = ("dsa_keys_live", "dsa_keys_selected",
               "dsa_select_blocks_counted", "dsa_select_blocks_table")
 
-# a launch's account of latent attention (DispatchRecord's fields; all 0
-# for a model without a latent pool), which the engine also keeps running
-# totals of
+# latent attention (a model with a latent pool), summed over the layers
+# (``CachePlan.account``): a decode launch's live keys (each live row's
+# context and itself: rows of the pool its walk reads), a chunk's
+# (query, key) pairs (for each live query the keys it sees), and the
+# context tokens (history and chunk) its walk multiplies by the
+# up-projection: a chunk on the kernel path, the EXPANDED form; 0 on a
+# decode launch and where the dense fallback runs, both absorbed
 MLA_FIELDS = ("mla_keys_live", "mla_pairs", "mla_latents_expanded")
 
-# a launch's account of its state-space layers (DispatchRecord's fields;
-# all 0 for a model with none), which the engine also keeps running
-# totals of
+# state-space layers (a model with 'mamba' layers; ``CachePlan.account``):
+# live rows x layers whose state the launch advances; tokens scanned x
+# layers; bytes of recurrent state the admitted requests hold as the
+# launch begins (slots in use x a slot's state over the layers)
 SSM_FIELDS = ("ssm_rows_live", "ssm_tokens", "ssm_state_bytes_held")
 
-# a launch's account of the two page groups of a model with a layer type
-# per layer (DispatchRecord's fields; all 0 for a model of one type),
-# which the engine also keeps running totals of
+# a model with a layer type per layer (the engine's ``_window_advance``):
+# window-group pages given back to the allocator before this launch and
+# pages it took (each logical page of a context once: what one table a
+# slot would have kept); bytes of the pages the running requests hold in
+# both groups, the full group's pages among them, and the tokens those
+# requests have in the cache, all as the launch begins
 KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
              "kv_held_bytes", "kv_full_pages_held", "kv_live_tokens")
 
-# what a launch moved between host and device (DispatchRecord's fields),
-# which the profiler also keeps running totals of
+# what the launch moved between host and device (the engine's launch
+# paths): host arrays handed to its programs (each table counts one; an
+# array that lives on the device counts only in the launch that uploads
+# it again after a write), and the times the host waited on results
+# (those of one wait set out for the host together)
 HOST_FIELDS = ("host_uploads", "host_reads")
+
+# the ONE declaration of the counted fields: what ``finish`` sums,
+# ``as_dict()`` carries and ``totals()`` gives goes through it
+COUNTED_FIELDS = (MOE_FIELDS + DSA_FIELDS + MLA_FIELDS + SSM_FIELDS
+                  + KV_FIELDS + HOST_FIELDS)
 
 # the compiled programs whose operations run inside a launch of each
 # kind (a last prefill chunk samples its first token in the same launch),
@@ -184,70 +217,11 @@ class DispatchRecord:
     valid = 0
     cached_tokens = 0
     drafted = 0
-    # routing of a sparse model's launch, summed over its layers (all 0
-    # for a dense model): live (token, choice) assignments; experts that
-    # received at least one; experts there are (layers x E); and each
-    # layer's largest count of assignments to one expert
-    moe_assignments = 0
-    moe_experts_touched = 0
-    moe_expert_slots = 0
-    moe_busiest_expert_assignments = 0
-    # of the live assignments, those that fell on an expert this chip
-    # holds (all of them unless the layer holds a share of its router's
-    # experts: ``cfg.moe_router_experts``)
-    moe_assignments_held = 0
-    # state-space layers (a model with 'mamba' layers; 0 otherwise): live
-    # rows x layers whose state the launch advances; tokens scanned x
-    # layers; bytes of recurrent state the admitted requests hold as the
-    # launch begins (slots in use x a slot's state over the layers)
-    ssm_rows_live = 0
-    ssm_tokens = 0
-    ssm_state_bytes_held = 0
     # the sampler's work in a decode/verify launch: live rows that are
     # not greedy (any makes the step draw), and of those the rows with an
     # active top-k or top-p (any makes the step sort)
     sampler_rows_drawn = 0
     sampler_rows_filtered = 0
-    # learned sparse attention (a model with an indexer; 0 otherwise),
-    # summed over the launch's live queries and the layers: the keys each
-    # query sees (its context and itself), and the same with each term
-    # cut at the top-k, which is what it attends
-    dsa_keys_live = 0
-    dsa_keys_selected = 0
-    # the choice's work: blocks of keys its select steps count over (each
-    # step stops at its slot's last live block), summed over the launch's
-    # steps and the layers, and the same had every step counted its
-    # slot's whole table
-    dsa_select_blocks_counted = 0
-    dsa_select_blocks_table = 0
-    # latent attention (a model with a latent pool; 0 otherwise), summed
-    # over the layers: a decode launch's live keys (each live row's
-    # context and itself: rows of the pool its walk reads), a chunk's
-    # (query, key) pairs (for each live query the keys it sees), and the
-    # context tokens (history and chunk) its walk multiplies by the
-    # up-projection: a chunk on the kernel path, the EXPANDED form; 0 on
-    # a decode launch and where the dense fallback runs, both absorbed
-    mla_keys_live = 0
-    mla_pairs = 0
-    mla_latents_expanded = 0
-    # a model with a layer type per layer (0 otherwise): window-group
-    # pages given back to the allocator before this launch and pages it
-    # took (each logical page of a context once: what one table a slot
-    # would have kept); bytes of the pages the running requests hold in
-    # both groups, the full group's pages among them, and the tokens
-    # those requests have in the cache, all as the launch begins
-    kv_window_pages_returned = 0
-    kv_window_pages_spanned = 0
-    kv_held_bytes = 0
-    kv_full_pages_held = 0
-    kv_live_tokens = 0
-    # what the launch moved between host and device: host arrays handed
-    # to its programs (each table counts one; an array that lives on the
-    # device counts only in the launch that uploads it again after a
-    # write), and the times the host waited on results (those of one
-    # wait set out for the host together)
-    host_uploads = 0
-    host_reads = 0
     # the requests (and their trace ids) this launch served: the spans
     # that caused it
     requests: Tuple[int, ...] = ()
@@ -333,58 +307,16 @@ class DispatchRecord:
             "request": self.request, "start": self.start,
             "valid": self.valid, "requests": list(self.requests),
             "traces": list(self.traces),
-            **{f: getattr(self, f) for f in MOE_FIELDS},
             "sampler_rows_drawn": self.sampler_rows_drawn,
             "sampler_rows_filtered": self.sampler_rows_filtered,
-            **{f: getattr(self, f) for f in DSA_FIELDS + MLA_FIELDS},
-            **{f: getattr(self, f) for f in KV_FIELDS + SSM_FIELDS},
-            **{f: getattr(self, f) for f in HOST_FIELDS},
+            **{f: getattr(self, f) for f in COUNTED_FIELDS},
         }
 
-    def note_state(self, rows: int, tokens: int, layers: int,
-                   held_bytes: int) -> None:
-        """A launch of a model with ``layers`` state-space layers: the
-        live rows whose state it advances, the tokens it scans (a decode
-        launch: one a live row), and the bytes of state held."""
-        self.ssm_rows_live = layers * int(rows)
-        self.ssm_tokens = layers * int(tokens)
-        self.ssm_state_bytes_held = int(held_bytes)
 
-    def note_latent(self, sees, layers: int, expanded: int = 0) -> None:
-        """``sees``: for each live query of the launch the keys it sees
-        (positions 0..its own), an int array the host made from what it
-        hands the program; the launch's kind says which count they are.
-        ``expanded``: the context tokens (history and chunk) of the rows
-        whose chunk the expanded walk reads, 0 where the read is
-        absorbed."""
-        field = "mla_pairs" if self.kind == "prefill" else "mla_keys_live"
-        setattr(self, field, layers * int(sees.sum()))
-        self.mla_latents_expanded = layers * expanded
-
-    def note_selection(self, sees, topk: int, layers: int, steps,
-                       table_blocks: int) -> None:
-        """``sees``: for each live query of the launch the keys it sees
-        (positions 0..its own); ``steps``: for each select step of one
-        layer the blocks it counts over, of the ``table_blocks`` a slot's
-        table holds: int arrays the host made from what it hands the
-        program."""
-        self.dsa_keys_live = layers * int(sees.sum())
-        self.dsa_keys_selected = layers * int(sees.clip(max=topk).sum())
-        self.dsa_select_blocks_counted = layers * int(steps.sum())
-        self.dsa_select_blocks_table = layers * steps.size * table_blocks
-
-    def note_routing(self, counts, held: Optional[slice] = None) -> None:
-        """``counts`` [layers, E]: the launch's histogram of live
-        assignments over the experts the router scores, as its program
-        returned it; ``held``: the experts among them this chip holds
-        (None: all)."""
-        self.moe_assignments = int(counts.sum())
-        self.moe_assignments_held = (
-            self.moe_assignments if held is None
-            else int(counts[:, held].sum()))
-        self.moe_experts_touched = int((counts > 0).sum())
-        self.moe_expert_slots = int(counts.size)
-        self.moe_busiest_expert_assignments = int(counts.max(axis=1).sum())
+# every counted field is an attribute of a record, 0 until who knows it
+# fills it (COUNTED_FIELDS says who)
+for _f in COUNTED_FIELDS:
+    setattr(DispatchRecord, _f, 0)
 
 
 # The profilers of this process's newest engines, so that a reader which
@@ -457,8 +389,7 @@ class LoopProfiler:
         "_phase_counts": "_lock",
         "_phase_launches": "_lock",
         "stalls": "_lock",
-        "host_uploads": "_lock",
-        "host_reads": "_lock",
+        "_totals": "_lock",
         "_ring": "_lock",
         "_requests": "_lock",
         "_emitted_at_dispatches": "_lock",
@@ -489,8 +420,8 @@ class LoopProfiler:
                               for _ in LOOP_PHASES]
         self._phase_launches = [0] * len(LOOP_PHASES)
         self.stalls = 0
-        self.host_uploads = 0       # HOST_FIELDS, summed over launches
-        self.host_reads = 0
+        # every counted field of the launches that finished, summed
+        self._totals = dict.fromkeys(COUNTED_FIELDS, 0)
         # armed by the engine after warmup(): compile-time gaps between
         # warmup dispatches are expected, not stalls
         self.stall_armed = False
@@ -555,8 +486,9 @@ class LoopProfiler:
                     self._phase_launches[i] += 1
             if stalled:
                 self.stalls += 1
-            self.host_uploads += d.host_uploads
-            self.host_reads += d.host_reads
+            totals = self._totals
+            for f in COUNTED_FIELDS:
+                totals[f] += getattr(d, f)
             self._ring.append(d)
         self._seq = d.seq + 1
         self._last_end = now
@@ -623,6 +555,12 @@ class LoopProfiler:
                 return list(self._ring)
             return list(islice(reversed(self._ring), last))[::-1]
 
+    def totals(self) -> Dict[str, int]:
+        """Every counted field (``COUNTED_FIELDS``) summed over the
+        launches that finished: what the engine's ``stats()`` reports."""
+        with self._lock:
+            return dict(self._totals)
+
     def program_tables(self) -> Dict[str, Any]:
         """``programs``, built first if nobody has asked yet (the
         engine's ``program_tables``: a reader's cost, after the launches
@@ -665,7 +603,7 @@ class LoopProfiler:
             gap = self.gap_secs
             phase_secs = dict(self.phase_secs)
             stalls = self.stalls
-            moved = {f: getattr(self, f) for f in HOST_FIELDS}
+            moved = {f: self._totals[f] for f in HOST_FIELDS}
             counts = [list(c) for c in self._phase_counts]
             timed = list(self._phase_launches)
         snaps = {p: telemetry.histogram_snapshot(

@@ -5,6 +5,7 @@ another test left behind, one CPU device) it prints ``{family: {program:
 hash}}``; ``tests/test_granite.py`` holds what it prints to the hashes
 recorded when the family's programs were last meant to change."""
 import hashlib
+import importlib
 import json
 import os
 import re
@@ -14,17 +15,21 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-FAMILIES = ("mistral", "mixtral", "olmoe", "keye", "mellum", "kanana")
+FAMILIES = ("mistral", "mixtral", "olmoe", "keye", "mellum", "kanana",
+            "granite")
 
 
 def fingerprints(name: str) -> dict:
     import jax
 
-    from megatron_llm_tpu import models
+    from megatron_llm_tpu.models import MODEL_REGISTRY
     from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
 
-    model = getattr(models, name.capitalize() + "Model")(
-        getattr(models, name + "_config")("tiny", use_flash_attn=False))
+    # through the registry: a family built lazily (granite) is no
+    # attribute of the package
+    config = getattr(importlib.import_module(
+        "megatron_llm_tpu.models." + name), name + "_config")
+    model = MODEL_REGISTRY[name](config("tiny", use_flash_attn=False))
     eng = InferenceEngine(
         model, model.init(jax.random.PRNGKey(0)),
         EngineConfig(num_slots=2, block_size=16, max_model_len=64,

@@ -372,7 +372,7 @@ def test_alert_transition_schema13_golden(tmp_path):
         "schema", "kind", "time_unix", "event", "rule", "scope", "state",
         "severity", "value", "threshold", "window_secs", "since_unix",
         "bundle"))
-    assert rec["schema"] == 22
+    assert rec["schema"] == telemetry.TELEMETRY_SCHEMA_VERSION
     assert rec["kind"] == "serve"
     assert rec["rule"] == "qd"
     assert rec["scope"] == "replica"
@@ -614,6 +614,7 @@ def test_alert_chaos_two_replica_fleet(tmp_path):
     schema-13 JSONL, and serve_top; the postmortem bundle is readable
     on disk; serve_report renders the incident correlated with the
     watchdog engine restart."""
+    from megatron_llm_tpu import telemetry
     from megatron_llm_tpu.serving.router import ReplicaRouter, RouterServer
     import serve_top as st
     import serve_report as sr
@@ -728,7 +729,8 @@ def test_alert_chaos_two_replica_fleet(tmp_path):
            if '"alert_transition"' in line]
     states = [t["state"] for t in trs if t["rule"] == "error_rate"]
     assert states == ["firing", "resolved"]
-    assert all(t["schema"] == 22 and t["kind"] == "serve" for t in trs)
+    assert all(t["schema"] == telemetry.TELEMETRY_SCHEMA_VERSION
+               and t["kind"] == "serve" for t in trs)
     assert trs[0]["bundle"] == bundle
 
     # 6) serve_report renders the incident, correlated with the restart

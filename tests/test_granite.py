@@ -515,6 +515,8 @@ TRACED = {
                "engine_decode": "0168b534430f68e2"},
     "kanana": {"engine_prefill": "7bc4fba0abd4ca5a",
                "engine_decode": "d819cd2b833bd7c6"},
+    "granite": {"engine_prefill": "2796d5c8ed6aa848",
+                "engine_decode": "db18b83c412ce327"},
 }
 
 

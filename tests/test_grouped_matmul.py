@@ -17,7 +17,7 @@ from megatron_llm_tpu.models.mellum import mellum_config
 from megatron_llm_tpu.models.mixtral import mixtral_config
 from megatron_llm_tpu.models.olmoe import olmoe_config
 from megatron_llm_tpu.ops.pallas import grouped_matmul as gm
-from megatron_llm_tpu.serving.engine import moe_expert_tiles
+from megatron_llm_tpu.ops.pallas.grouped_matmul import moe_expert_tiles
 
 BF16 = jnp.bfloat16
 

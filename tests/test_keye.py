@@ -356,7 +356,7 @@ def test_engine_counts_the_blocks_the_choice_counts_over(family, monkeypatch):
     eng = InferenceEngine(model, params, EngineConfig(
         num_slots=4, block_size=8, max_model_len=64, prefill_chunk=16,
         prefix_cache=False))
-    assert eng._dsa_block_keys == 16
+    assert eng._cache.dsa_block_keys == 16
     req = eng.submit(_tokens(40, seed=9),
                      SamplingParams(max_new_tokens=3, temperature=0.0))
     while req.finish_reason is None:
@@ -437,18 +437,26 @@ def test_the_benchmarks_counted_share_reads_the_records_two_fields(
         "layer": metric["layer"], "moves": metric["moves"],
         "workloads": metric["cells"]}
 
+    # the cache's plan fills a record from what the launch is handed: a
+    # chunk of 16 at context 16 is one select step through the blocks
+    # that hold 32 keys, of a table of 64 tokens
+    cfg = keye_config("tiny")
+    plan = paged_kv.plan(cfg, 8, 4, 8, 16, "xla")
+    counted = cfg.num_layers * -(-32 // plan.dsa_block_keys)
+    table = cfg.num_layers * -(-64 // plan.dsa_block_keys)
     rec = DispatchRecord(lambda: 0.0, 0, 0.0, 0.0)
-    rec.note_selection(np.asarray([5]), TOPK, 2, np.asarray([[2, 2]]), 6)
+    rec.kind = "prefill"
+    plan.account(rec, np.asarray([16]), np.asarray([16]), 16, 1)
     for field in (params["numerator"], params["denominator"]):
         assert field in rec.as_dict()
     assert (rec.dsa_select_blocks_counted, rec.dsa_select_blocks_table) == (
-        8, 24)
+        counted, table)
 
     monkeypatch.syspath_prepend(bench)
     ratio = importlib.import_module("harness.spec").load_module(
         "sources", "loop_record_ratio")
     fields = (params["numerator"], params["denominator"])
-    assert ratio.sums([rec, rec], *fields) == (16, 48)
+    assert ratio.sums([rec, rec], *fields) == (2 * counted, 2 * table)
     parents = types.SimpleNamespace(dsa_keys_live=7, dsa_keys_selected=5)
     assert ratio.sums([parents], *fields) is None
     assert ratio.sums([rec, parents], *fields) is None

@@ -486,6 +486,94 @@ def test_one_timer_per_launch_counters_are_the_records(speculative):
         assert last.phase_start("dispatch") < s.first_token <= last.end
 
 
+def _groups():
+    from megatron_llm_tpu.serving import loop_profiler as lp
+
+    return {name: getattr(lp, name) for name in (
+        "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS", "KV_FIELDS",
+        "HOST_FIELDS")}
+
+
+@pytest.mark.parametrize("group", sorted(_groups()))
+def test_a_counted_field_is_summed_where_the_launch_finishes(group):
+    """The counted fields have ONE declaration, the six groups' tuples
+    together: a record that sets every one of them, finished twice, is in
+    ``totals()`` twice, field by field, ``as_dict()`` carries each, and a
+    record that set none counts nothing."""
+    from megatron_llm_tpu.serving.loop_profiler import (COUNTED_FIELDS,
+                                                        DispatchRecord)
+
+    assert sorted(COUNTED_FIELDS) == sorted(sum(_groups().values(), ()))
+    clock = _Clock()
+    prof = LoopProfiler(clock=clock)
+    assert prof.totals() == dict.fromkeys(COUNTED_FIELDS, 0)
+    for _ in range(2):
+        d = prof.begin()
+        for i, f in enumerate(COUNTED_FIELDS):
+            assert getattr(DispatchRecord, f) == 0
+            setattr(d, f, 3 + i)
+        clock.tick(0.001)
+        prof.finish(d)
+        prof.finish(prof.begin())       # a launch that counted nothing
+    totals = prof.totals()
+    for f in _groups()[group]:
+        want = 3 + COUNTED_FIELDS.index(f)
+        assert totals[f] == 2 * want
+        assert totals[f] == sum(getattr(r, f) for r in prof.records())
+        assert prof.records()[0].as_dict()[f] == want
+    # the loop block of stats() carries the host's two and no other
+    loop = prof.stats()
+    assert {f: loop[f] for f in _groups()["HOST_FIELDS"]} == {
+        f: totals[f] for f in _groups()["HOST_FIELDS"]}
+    assert not set(loop) & (set(COUNTED_FIELDS)
+                            - set(_groups()["HOST_FIELDS"]))
+
+
+@pytest.mark.parametrize("family", ["granite", "keye", "kanana", "mellum",
+                                    "olmoe", "mistral"])
+def test_every_counter_of_stats_is_the_sum_over_the_ring(family):
+    """After a few launches of a tiny engine every counted key of
+    ``stats()`` (and the host's two in its ``loop`` block) equals the sum
+    of that field over ``loop_profiler.records()``: the engine keeps no
+    total of its own."""
+    import importlib
+
+    import jax
+
+    from megatron_llm_tpu.models import MODEL_REGISTRY
+    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
+                                          SamplingParams)
+    from megatron_llm_tpu.serving.loop_profiler import (COUNTED_FIELDS,
+                                                        HOST_FIELDS)
+
+    config = getattr(importlib.import_module(
+        "megatron_llm_tpu.models." + family), family + "_config")
+    model = MODEL_REGISTRY[family](config("tiny", use_flash_attn=False))
+    eng = InferenceEngine(model, model.init(jax.random.PRNGKey(0)),
+                          EngineConfig(num_slots=3, block_size=8,
+                                       max_model_len=96, prefill_chunk=16,
+                                       preemption=False))
+    reqs = [eng.submit([(5 * i + j) % 500 + 1 for j in range(n)],
+                       SamplingParams(max_new_tokens=4, temperature=0.0))
+            for i, n in enumerate((21, 37, 9))]
+    while any(r.finish_reason is None for r in reqs):
+        assert eng.step()
+    stats, records = eng.stats(), eng.loop_profiler.records()
+    assert len(records) == stats["loop"]["dispatches"] > 6
+    moved = 0
+    for f in COUNTED_FIELDS:
+        where = stats["loop"] if f in HOST_FIELDS else stats
+        assert where[f] == sum(getattr(r, f) for r in records), f
+        assert not hasattr(eng, f), f
+        moved += where[f] > 0
+    # each family counts what its mechanisms are, and nothing else
+    counted = {f.split("_")[0] for f in COUNTED_FIELDS if stats.get(f)}
+    assert counted == {"granite": {"moe", "ssm"}, "keye": {"moe", "dsa"},
+                       "kanana": {"moe", "mla"}, "mellum": {"moe", "kv"},
+                       "olmoe": {"moe"}, "mistral": set()}[family]
+    assert moved >= 2
+
+
 def test_registry_yields_the_live_profiler_without_the_engine():
     from megatron_llm_tpu.serving.loop_profiler import live_profilers
 

@@ -239,7 +239,7 @@ def test_a_one_type_window_model_allocates_attends_and_frees_as_before():
     while req.finish_reason is None:
         assert eng.step()
         eng.blocks.check_invariants()
-        tables = eng._tables(eng._st)
+        tables = eng._cache.tables(eng.blocks)
         assert isinstance(tables, np.ndarray)
         assert (tables == eng.blocks.tables).all()
         if req.finish_reason is None:

@@ -1,0 +1,151 @@
+"""What runs with what has ONE home (``config.RUNS_WITH``): every square
+of the table, taken from the table itself, is held to the sentence the
+one reader (``config.refusal``) gives and to whoever asks it: the
+config's own ``__post_init__`` for a model against itself, the serving
+engine for the features its ``EngineConfig`` turns on,
+``paged_kv.init_pools`` for the int8 pool, ``GPTModel`` for the
+parallelism in force.
+"""
+
+import copy
+import functools
+import importlib
+
+import jax
+import pytest
+
+from megatron_llm_tpu import config as C
+from megatron_llm_tpu.models import MODEL_REGISTRY, gpt
+from megatron_llm_tpu.ops import paged_kv
+from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
+
+SQUARES = [(has, what) for has, whats in C.RUNS_WITH for what in whats]
+
+# a served family that has each mechanism, and how a config (or an
+# engine) is given each thing a mechanism does not run with
+FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
+          C.FIRST_DENSE: "kanana", C.LATENT: "kanana",
+          C.QK_NORM_WHOLE: "olmoe", C.EXPERTS: "olmoe", C.SHARE: "granite"}
+GIVEN = {
+    C.SLIDING: dict(sliding_window_size=16),
+    C.NOT_ROTARY: dict(position_embedding_type="learned_absolute"),
+    C.OTHER_TYPES: dict(layer_types=("mamba", "sliding"),
+                        sliding_window_size=16),
+    C.BIASES: dict(add_bias_linear=True),
+    C.QKV_BIAS: dict(add_qkv_bias=True),
+    C.PARALLEL_ATTN: dict(parallel_attn=True),
+    C.POST_LN: dict(use_post_ln=True),
+    C.LATENT: dict(kv_lora_rank=32),
+    C.SPARSE: dict(dsa_index_heads=4),
+    C.SECTIONED: dict(rope_sections=(4, 6, 6)),
+    C.TYPED: dict(layer_types=("full",)),
+    C.QK_NORM_WHOLE: dict(qk_norm=True),
+    C.QK_NORM_PER_HEAD: dict(qk_norm_per_head=True),
+    C.ROPE_SCALING: dict(rope_scaling_factor=2.0),
+}
+TURNS_ON = {
+    C.VERIFY_STEP: dict(speculative=True, draft_k=2),
+    C.INT8_POOL: dict(int8_kv_cache=True),
+    C.HOST_TIER: dict(host_cache_bytes=1 << 20),
+    C.PREEMPTION: dict(preemption=True),
+    C.PREFIX_CACHE: dict(prefix_cache=True),
+}
+
+
+def _config(family, **overrides):
+    module = importlib.import_module("megatron_llm_tpu.models." + family)
+    return getattr(module, family + "_config")(
+        "tiny", use_flash_attn=False, **overrides)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(family):
+    model = MODEL_REGISTRY[family](_config(family))
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _engine(family, **kw):
+    kw = {"preemption": False, **kw}
+    return InferenceEngine(*_model(family), EngineConfig(
+        num_slots=2, block_size=8, max_model_len=32, prefill_chunk=16, **kw))
+
+
+@pytest.mark.parametrize("has,what", SQUARES,
+                         ids=[f"{h.split(' (')[0]} x {w.split(' (')[0]}"
+                              for h, w in SQUARES])
+def test_a_square_of_the_table_is_told_by_whoever_asks(has, what,
+                                                       monkeypatch, capsys):
+    family = FAMILY[has]
+    cfg = _config(family)
+    assert C.HAS[has](cfg)
+    # the config with ``what`` on (a feature, or a field set as no
+    # constructor would leave it) against this one square
+    both, on = copy.copy(cfg), (what,) if what in C.FEATURES else ()
+    for field, value in GIVEN.get(what, {}).items():
+        object.__setattr__(both, field, value)
+    monkeypatch.setattr(C, "RUNS_WITH", ((has, (what,)),))
+    assert C.refusal(cfg) is None
+    tail = C.TAILS.get((has, what), "")
+    assert C.refusal(both, on) == (
+        f"{has}: {what}{tail}" if what in C.TURNED_OFF
+        else f"{has}: not implemented with {what}{tail}")
+    monkeypatch.undo()
+    # against the whole table: the family is told the first square it
+    # falls in with ``what``, and a plain decoder none
+    said = C.refusal(cfg, on)
+    assert C.refusal(cfg) is None
+    assert C.refusal(_config("mistral"), C.FEATURES) is None
+
+    if what not in C.FEATURES:
+        # a model against itself: the constructor asks
+        with pytest.raises(ValueError, match="not implemented with"):
+            _config(family, **GIVEN[what])
+    elif what in C.TURNED_OFF:
+        # the engine says so and runs without
+        eng = _engine(family, **TURNS_ON[what])
+        assert said in capsys.readouterr().out
+        assert not eng.config.prefix_cache
+        assert not eng.blocks.prefix_cache_enabled
+    elif what in TURNS_ON:
+        with pytest.raises(ValueError) as raised:
+            _engine(family, **TURNS_ON[what])
+        assert str(raised.value) == said
+    else:
+        # the mesh's: GPTModel asks with what is in force
+        monkeypatch.setattr(gpt, "_vocab_unsharded", lambda: False)
+        assert gpt.parallelism_in_force() == (C.TENSOR_PARALLEL,
+                                              C.MODEL_PARALLEL)
+        with pytest.raises(ValueError) as raised:
+            MODEL_REGISTRY[family](cfg)
+        assert str(raised.value) == said
+    if what in C.FEATURES:
+        assert what in said
+
+
+@pytest.mark.parametrize("family", ["kanana", "keye", "mellum", "granite"])
+def test_the_pool_and_the_engine_refuse_int8_in_one_sentence(family):
+    """``init_pools(quantized=True)`` and the engine ask the same table,
+    so a latent, an indexed, a grouped model and one with state-space
+    layers are each told ONE sentence by both (two wordings before)."""
+    model, _ = _model(family)
+    with pytest.raises(ValueError) as pool:
+        paged_kv.init_pools(model.cfg, 4, 8, quantized=True,
+                            window_blocks=4, num_slots=2)
+    with pytest.raises(ValueError) as engine:
+        _engine(family, int8_kv_cache=True)
+    assert str(pool.value) == str(engine.value) == C.refusal(
+        model.cfg, (C.INT8_POOL,))
+    assert "int8 KV pool" in str(pool.value)
+
+
+def test_no_other_module_words_a_refusal_of_its_own():
+    """The engine, ``init_pools`` and ``GPTModel`` hold no sentence with
+    "not implemented" of their own."""
+    import inspect
+
+    from megatron_llm_tpu.serving import engine
+
+    for source in (inspect.getsource(engine),
+                   inspect.getsource(paged_kv.init_pools),
+                   inspect.getsource(gpt)):
+        assert "not implemented" not in source
