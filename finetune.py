@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,nemotron_h,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -123,6 +123,22 @@ MODEL_DEFAULTS = {
                     residual_multiplier=0.22, logits_scaling=16.0,
                     layernorm_epsilon=1e-5,
                     hidden_dropout=0.0, attention_dropout=0.0),
+    # nemotron-3-nano (model_type nemotron_h): 52 layers of ONE sublayer
+    # each by the published pattern (M a Mamba-2 mixer of eight groups,
+    # * attention with no position embedding, E ungated relu^2 experts
+    # under a sigmoid router with a shared MLP), an untied head
+    "nemotron_h": dict(position_embedding_type="none", mlp_activation="relu2",
+                       use_rms_norm=True, use_bias=False,
+                       tie_embed_logits=False, num_experts=128, moe_top_k=6,
+                       norm_topk_prob=1, moe_score_function="sigmoid",
+                       moe_choice_bias=1, moe_routed_scale=2.5,
+                       moe_shared_experts=2, kv_channels=128,
+                       hybrid_override_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*E"
+                       "MEMEM*EMEMEMEM*EMEMEMEME",
+                       mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+                       mamba_n_groups=8, mamba_chunk_size=128,
+                       layernorm_epsilon=1e-5,
+                       hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,
@@ -346,6 +362,8 @@ _CKPT_ARG_MAP = {
     "mamba_d_conv": "mamba_d_conv",
     "mamba_chunk_size": "mamba_chunk_size",
     "mamba_conv_bias": "mamba_conv_bias",
+    # nemotron_h's ungated MLPs
+    "mlp_activation": "mlp_activation",
     "attention_multiplier": "attention_multiplier",
     "residual_multiplier": "residual_multiplier",
     "logits_scaling": "logits_scaling",

@@ -17,7 +17,12 @@ import argparse
 import os
 from typing import Callable, Optional
 
-from megatron_llm_tpu.config import ParallelConfig, TrainConfig, TransformerConfig
+from megatron_llm_tpu.config import (
+    ParallelConfig,
+    TrainConfig,
+    TransformerConfig,
+    pattern_layer_types,
+)
 
 
 def build_base_parser() -> argparse.ArgumentParser:
@@ -93,6 +98,9 @@ def _add_network_size_args(parser):
     g.add_argument("--moe_choice_bias", type=int, default=0, choices=[0, 1],
                    help="a bias an expert added to the scores for the "
                         "choice only (e_score_correction_bias)")
+    g.add_argument("--moe_choice_bias_std", type=float, default=None,
+                   help="the spread a fresh model's choice bias is drawn "
+                        "at (default 0.1; a checkpoint overwrites it)")
     g.add_argument("--moe_routed_scale", type=float, default=1.0,
                    help="the chosen gates times this "
                         "(routed_scaling_factor)")
@@ -162,7 +170,12 @@ def _add_network_size_args(parser):
                    help="one period of layer types, repeated over the "
                         "depth: sliding (--sliding_window_size keys) or "
                         "full (e.g. sliding sliding sliding full); or a "
-                        "hybrid's mamba and attention")
+                        "hybrid's mamba and attention (with moe: a "
+                        "layer is one sublayer, an expert layer alone)")
+    g.add_argument("--hybrid_override_pattern", type=str, default=None,
+                   help="a letter a layer in place of --layer_types: M a "
+                        "Mamba-2 mixer, * an attention mixer, E an "
+                        "expert layer; the whole depth is one period")
     g.add_argument("--mamba_n_heads", type=int, default=128,
                    help="heads of a 'mamba' layer's state-space mixer")
     g.add_argument("--mamba_d_head", type=int, default=64)
@@ -210,6 +223,10 @@ def _add_network_size_args(parser):
                    choices=["tanh", "exact"],
                    help="non-GLU MLP gelu: tanh-approximate (GPT-2) or "
                         "exact erf (Falcon/NeoX)")
+    g.add_argument("--mlp_activation", default="gelu",
+                   choices=["gelu", "relu2"],
+                   help="the ungated MLP's nonlinearity: a gelu "
+                        "(--gelu_variant) or relu(x)^2")
     g.add_argument("--no_tie_embed_logits", action="store_false",
                    dest="tie_embed_logits")
 
@@ -1021,8 +1038,10 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         rope_yarn_layer_types=(tuple(args.rope_yarn_layer_types)
                                if getattr(args, "rope_yarn_layer_types", None)
                                else None),
-        layer_types=(tuple(args.layer_types)
-                     if getattr(args, "layer_types", None) else None),
+        layer_types=(
+            tuple(args.layer_types) if getattr(args, "layer_types", None)
+            else pattern_layer_types(args.hybrid_override_pattern)
+            if getattr(args, "hybrid_override_pattern", None) else None),
         tie_embed_logits=args.tie_embed_logits,
         normalization="rmsnorm" if args.use_rms_norm else "layernorm",
         layernorm_epsilon=args.layernorm_epsilon,
@@ -1057,6 +1076,7 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         embedding_multiplier=getattr(args, "embedding_multiplier", None),
         rotary_percent=getattr(args, "rotary_percent", 1.0),
         gelu_variant=getattr(args, "gelu_variant", "tanh"),
+        mlp_activation=getattr(args, "mlp_activation", "gelu"),
         qk_norm=bool(getattr(args, "qk_norm", False)),
         norm_topk_prob=bool(getattr(args, "norm_topk_prob", True)),
         qk_norm_per_head=bool(getattr(args, "qk_norm_per_head", False)),
@@ -1067,6 +1087,7 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
                        if getattr(args, "rope_sections", None) else None),
         moe_score_function=getattr(args, "moe_score_function", "softmax"),
         moe_choice_bias=bool(getattr(args, "moe_choice_bias", 0)),
+        moe_choice_bias_std=getattr(args, "moe_choice_bias_std", None),
         moe_routed_scale=float(getattr(args, "moe_routed_scale", 1.0)),
         moe_shared_experts=int(getattr(args, "moe_shared_experts", 0) or 0),
         moe_first_dense_layers=int(

@@ -94,8 +94,29 @@ class ParallelConfig:
 # what a layer type may be (TransformerConfig.layer_types): two windows
 # of attention, and the two mixers of a hybrid as its config publishes
 # them ('attention' attends every key, as 'full' does; 'mamba' is a
-# Mamba-2 state-space mixer, models/mamba.py)
-LAYER_TYPES = ("sliding", "full", "mamba", "attention")
+# Mamba-2 state-space mixer, models/mamba.py).  'moe' is an expert layer
+# ALONE: with it among the types every layer is ONE sublayer under ONE
+# norm, a mixer or an expert layer (``one_sublayer``)
+LAYER_TYPES = ("sliding", "full", "mamba", "attention", "moe")
+# the letters of a published ``hybrid_override_pattern``, a layer each
+PATTERN_LETTERS = {"M": "mamba", "*": "attention", "E": "moe"}
+
+
+def pattern_layer_types(pattern: str) -> Tuple[str, ...]:
+    """A published ``hybrid_override_pattern`` (a letter a layer: ``M`` a
+    Mamba-2 mixer, ``*`` an attention mixer, ``E`` an expert layer) as
+    ``layer_types``, every layer of the depth in ONE period: such a
+    pattern need not repeat."""
+    if "-" in pattern:
+        raise ValueError(
+            "a dense MLP layer ('-' in hybrid_override_pattern) is not "
+            "implemented: the layer kinds are M (mamba), * (attention) "
+            "and E (moe)")
+    unknown = sorted(set(pattern) - set(PATTERN_LETTERS))
+    if unknown or not pattern:
+        raise ValueError(f"hybrid_override_pattern is letters of "
+                         f"{'|'.join(PATTERN_LETTERS)}, got {pattern!r}")
+    return tuple(PATTERN_LETTERS[c] for c in pattern)
 
 
 # --- what runs with what ------------------------------------------------
@@ -109,7 +130,8 @@ LAYER_TYPES = ("sliding", "full", "mamba", "attention")
 # The runtime's features, by the names the sentences use.  Whoever turns
 # one on asks ``refusal`` with it: ``serving/engine.py`` (its
 # EngineConfig's), ``ops/paged_kv.py::init_pools`` (the int8 pool),
-# ``models/gpt.py`` (the parallelism the mesh has in force).
+# ``models/gpt.py`` (the parallelism the mesh has in force),
+# ``models/transformer.py::transformer_stack`` (training).
 VERIFY_STEP = "the speculative verify step"
 INT8_POOL = "the int8 KV pool"
 HOST_TIER = "the host KV tier"
@@ -117,8 +139,9 @@ PREEMPTION = "preemption"
 PREFIX_CACHE = "the prefix cache"
 TENSOR_PARALLEL = "tensor parallelism (tp > 1)"
 MODEL_PARALLEL = "tensor or pipeline parallelism (tp > 1, pp > 1)"
+TRAINING = "training"
 FEATURES = (VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION, PREFIX_CACHE,
-            TENSOR_PARALLEL, MODEL_PARALLEL)
+            TENSOR_PARALLEL, MODEL_PARALLEL, TRAINING)
 # the features a model that does not run with them is not refused but
 # runs WITHOUT: whoever serves it turns the feature off and says so
 TURNED_OFF = (PREFIX_CACHE,)
@@ -129,6 +152,7 @@ SPARSE = "sparse attention (dsa_index_heads > 0)"
 LATENT = "latent attention (kv_lora_rank)"
 TYPED = "a layer type per layer (layer_types)"
 STATE_SPACE = "state-space layers ('mamba' among layer_types)"
+ONE_SUBLAYER = "layers of one sublayer ('moe' among layer_types)"
 FIRST_DENSE = "leading dense layers (moe_first_dense_layers)"
 SHARE = "a share of the router's experts (moe_router_experts)"
 EXPERTS = "experts (num_experts > 1)"
@@ -142,12 +166,13 @@ BIASES = "linear biases (add_bias_linear)"
 QKV_BIAS = "a bias on the QKV projections (add_qkv_bias)"
 PARALLEL_ATTN = "parallel_attn"
 POST_LN = "post-LN (use_post_ln)"
-OTHER_TYPES = "layer types other than 'mamba' and 'attention'"
+OTHER_TYPES = "layer types other than 'mamba', 'attention' and 'moe'"
 HAS = {
     SPARSE: lambda c: c.dsa_index_heads > 0,
     LATENT: lambda c: c.kv_lora_rank is not None,
     TYPED: lambda c: c.layer_types is not None,
     STATE_SPACE: lambda c: c.state_space,
+    ONE_SUBLAYER: lambda c: c.one_sublayer,
     FIRST_DENSE: lambda c: c.moe_first_dense_layers > 0,
     SHARE: lambda c: c.holds_a_share,
     EXPERTS: lambda c: c.num_experts > 1,
@@ -165,7 +190,7 @@ HAS = {
     PARALLEL_ATTN: lambda c: c.parallel_attn,
     POST_LN: lambda c: c.use_post_ln,
     OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
-                                - {"mamba", "attention"}),
+                                - {"mamba", "attention", "moe"}),
 }
 
 # THE TABLE: what a model has, and everything it does not run with.  A
@@ -175,6 +200,8 @@ HAS = {
 RUNS_WITH = (
     (SPARSE, (SLIDING, NOT_ROTARY, VERIFY_STEP, INT8_POOL,
               TENSOR_PARALLEL)),
+    (ONE_SUBLAYER, (OTHER_TYPES, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
+                    PREFIX_CACHE)),
     (STATE_SPACE, (OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
                    VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
                    MODEL_PARALLEL)),
@@ -191,8 +218,19 @@ RUNS_WITH = (
 )
 # how a square's sentence ends, where it says more than the two names
 TAILS = {
+    (ONE_SUBLAYER, OTHER_TYPES):
+        " (a 'moe' layer type goes with 'mamba' and 'attention' layers)",
+    (ONE_SUBLAYER, TRAINING):
+        " (the capacity einsum holds no share of the experts, and no "
+        "backward through such a stack is held to anything)",
+    (ONE_SUBLAYER, PREEMPTION):
+        " (no snapshot of a request's state is kept): set preemption off "
+        "(--serve_preemption=0)",
+    (ONE_SUBLAYER, PREFIX_CACHE):
+        " adopts nothing (a state-space layer's state at a prefix's end "
+        "is not kept)",
     (STATE_SPACE, OTHER_TYPES):
-        " (a 'mamba' layer type goes with 'attention' layers only)",
+        " (a 'mamba' layer type goes with 'moe' and 'attention' layers only)",
     (STATE_SPACE, PREEMPTION):
         " (no snapshot of a request's state is kept): set preemption off "
         "(--serve_preemption=0)",
@@ -282,6 +320,10 @@ class TransformerConfig:
     # non-GLU MLP activation: 'tanh' = approximate gelu (GPT-2/Megatron
     # bias-gelu fusion polynomial), 'exact' = erf gelu (Falcon / F.gelu)
     gelu_variant: str = "tanh"
+    # the ungated MLP's nonlinearity where it is no gelu: 'relu2' =
+    # relu(x)^2 (two matrices an MLP: experts, a shared MLP and a dense
+    # MLP alike; ops/activations.py)
+    mlp_activation: str = "gelu"
     # bias toggles (reference: --use_bias / --no_bias in arguments.py)
     add_bias_linear: bool = True
     # Falcon-style parallel attention+MLP (reference: transformer.py:635-664)
@@ -294,7 +336,9 @@ class TransformerConfig:
     # a layer type per layer, as data: ONE period of types, repeated over
     # the depth ('sliding': a query attends the sliding_window_size keys
     # up to itself; 'full': every key up to itself).  None: the stack is
-    # one period of one type, the window (if any) on every layer
+    # one period of one type, the window (if any) on every layer.  With
+    # 'moe' among them a layer is ONE sublayer, ``x + f(norm(x))``: a
+    # 'mamba' or 'attention' layer has no MLP, a 'moe' layer no mixer
     layer_types: Optional[Tuple[str, ...]] = None
 
     # --- dropout / init ---
@@ -369,6 +413,13 @@ class TransformerConfig:
     # a buffer ``[E]`` a layer in the param tree) that is added to the
     # scores for the CHOICE of the top-k only; the gates are the scores
     moe_choice_bias: bool = False
+    # the spread a FRESH model's choice bias is drawn at (a checkpoint
+    # overwrites the buffer); None: ``models/moe.py::_CHOICE_BIAS_STD``,
+    # 0.1.  A published bias is what balancing the experts' load moved
+    # it to, so a drawn one UNBALANCES a random router: at 0.1 a decode
+    # step of 64 rows touches 34-39 of 64 held experts by the seed, at
+    # 0.02 51-53 and at zero 53-54 (PERF.md section 6, PR 44)
+    moe_choice_bias_std: Optional[float] = None
     # the chosen gates (after ``norm_topk_prob``) times this
     moe_routed_scale: float = 1.0
     # shared experts: ONE ungated MLP of ``moe_shared_experts`` times an
@@ -510,6 +561,8 @@ class TransformerConfig:
                 raise ValueError(
                     f"num_layers ({self.num_layers}) must be whole periods "
                     f"of the {len(types)} layer_types")
+            if "moe" in types and self.num_experts <= 1:
+                raise ValueError("a 'moe' layer type needs num_experts > 1")
             if "sliding" in types and self.sliding_window_size is None:
                 raise ValueError("a 'sliding' layer type needs "
                                  "sliding_window_size")
@@ -552,6 +605,12 @@ class TransformerConfig:
             raise ValueError("group-limited routing (moe_n_group / "
                              "moe_topk_group other than 1) is not "
                              "implemented")
+        if self.mlp_activation not in ("gelu", "relu2") or (
+                self.mlp_activation != "gelu" and self.glu_activation):
+            raise ValueError(
+                f"mlp_activation is gelu|relu2 and ungated (no "
+                f"glu_activation), got {self.mlp_activation!r} with "
+                f"glu_activation={self.glu_activation!r}")
         if self.moe_score_function not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_score_function must be softmax|sigmoid, got "
@@ -599,9 +658,12 @@ class TransformerConfig:
     @property
     def num_sparse_layers(self) -> int:
         """The layers with experts: all of a sparse model's but its
-        leading dense ones; 0 for a dense model."""
+        leading dense ones, or the 'moe' layers of a stack of one
+        sublayer a layer; 0 for a dense model."""
         if self.num_experts <= 1:
             return 0
+        if self.one_sublayer:
+            return self.mixer_counts["moe"]
         return self.num_layers - self.moe_first_dense_layers
 
     @property
@@ -622,6 +684,12 @@ class TransformerConfig:
         return self.layer_types is not None and "mamba" in self.layer_types
 
     @property
+    def one_sublayer(self) -> bool:
+        """Whether a layer is ONE sublayer under one norm (a mixer or an
+        expert layer alone): a stack with 'moe' among its layer types."""
+        return self.layer_types is not None and "moe" in self.layer_types
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_n_heads * self.mamba_d_head
 
@@ -632,19 +700,20 @@ class TransformerConfig:
 
     @property
     def mixer_counts(self) -> dict:
-        """How many layers there are of each mixer kind ('mamba',
-        'attention') in a stack whose kinds' parameters are stacked
-        apart; empty for a stack whose layers all hold the same leaves."""
-        if not self.state_space:
+        """How many layers there are of each kind ('mamba', 'attention',
+        and the expert layers 'moe' of a stack of one sublayer a layer)
+        in a stack whose kinds' parameters are stacked apart; empty for
+        a stack whose layers all hold the same leaves."""
+        if not (self.state_space or self.one_sublayer):
             return {}
         reps = self.num_layers // len(self.layer_types)
         return {k: reps * self.layer_types.count(k)
-                for k in ("mamba", "attention")
+                for k in ("mamba", "attention", "moe")
                 if k in self.layer_types}
 
     def mixer_index(self, layer: int) -> tuple:
         """(kind, index among the layers of that kind) of layer
-        ``layer`` of a stack with state-space layers."""
+        ``layer`` of a stack whose kinds are stacked apart."""
         P = len(self.layer_types)
         kind = self.layer_types[layer % P]
         return kind, ((layer // P) * self.layer_types.count(kind)

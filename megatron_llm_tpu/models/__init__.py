@@ -32,6 +32,13 @@ def _granite(cfg):
     return GraniteModel(cfg)
 
 
+def _nemotron_h(cfg):
+    """The Nemotron-H hybrid, imported when first built, as Granite."""
+    from megatron_llm_tpu.models.nemotron_h import NemotronHModel
+
+    return NemotronHModel(cfg)
+
+
 MODEL_REGISTRY = {
     "gpt": GPTModel,
     "llama": LlamaModel,
@@ -46,6 +53,7 @@ MODEL_REGISTRY = {
     "mellum": MellumModel,
     "kanana": KananaModel,
     "granite": _granite,
+    "nemotron_h": _nemotron_h,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,
     "gpt_neox": GPTNeoXModel,

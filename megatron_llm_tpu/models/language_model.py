@@ -120,7 +120,12 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
             lambda a: (("stage",) if stacked else ())
             + (None,) * (a.ndim - int(stacked)), layers["mamba"])}
            if "mamba" in layers else {}),
-        "mlp": (
+    }
+    if "moe" in layers:
+        # the expert layers of a stack of one sublayer a layer
+        layer_specs["moe"] = moe_mlp_specs(layers["moe"], stacked, cfg=cfg)
+    else:
+        layer_specs["mlp"] = (
             moe_mlp_specs(layers["mlp"], stacked, cfg=cfg)
             if "experts" in layers["mlp"]
             else {
@@ -131,8 +136,7 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
                     layers["mlp"]["dense_4h_to_h"], "ffn", None, stacked
                 ),
             }
-        ),
-    }
+        )
     for name in ("q_norm", "k_norm"):
         if attn is not None and name in attn:
             layer_specs["attention"][name] = _norm_spec(

@@ -16,7 +16,9 @@ but TWO arrays of fixed size a layer, whatever its length:
    a head;
 4. ``S_t = exp(delta_t A) S_{t-1} + delta_t (x_t outer B_t)``, a state
    ``[d_head, d_state]`` a head; ``y_t = S_t C_t + D x_t``;
-5. ``y = RMSNorm_w(y * silu(z))`` over the whole inner width, then
+5. ``y = RMSNorm_w(y * silu(z))``, the mean square over each of the
+   ``n_groups`` groups' channels apart (the whole inner width where
+   there is one group) under one weight of the inner width, then
    ``y W_out`` (no bias).
 
 The carried state: the last ``d_conv - 1`` columns of ``xBC`` BEFORE the
@@ -169,6 +171,20 @@ def chunked_scan(x: jax.Array, delta: jax.Array, A: jax.Array,
     return y.reshape(b, c * Q, nh, dh)[:, :n], last
 
 
+def gated_group_norm(y: jax.Array, scale: jax.Array, groups: int,
+                     eps: float) -> jax.Array:
+    """Step 5's norm of ``y`` [..., d_inner] (float32, already gated):
+    each of the ``groups`` groups of ``d_inner / groups`` channels
+    divided by its own root mean square, then the weight of the whole
+    width.  One group is ``rms_norm`` itself, the same operations."""
+    if groups == 1:
+        return rms_norm(y, scale, eps=eps)
+    grouped = y.reshape(y.shape[:-1] + (groups, y.shape[-1] // groups))
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(jnp.square(grouped), axis=-1, keepdims=True) + eps)
+    return grouped.reshape(y.shape) * scale.astype(y.dtype)
+
+
 def mamba_mixer(h: jax.Array, params, cfg: TransformerConfig, *,
                 kv_cache=None):
     """``h`` [b, n, hidden] (the layer's normed input) -> the mixer's
@@ -242,7 +258,8 @@ def mamba_mixer(h: jax.Array, params, cfg: TransformerConfig, *,
 
     with jax.named_scope("ssm_gate_norm"):
         y = y.reshape(b, n, di) * jax.nn.silu(z.astype(jnp.float32))
-        y = rms_norm(y, params["norm"]["scale"], eps=cfg.layernorm_epsilon)
+        y = gated_group_norm(y, params["norm"]["scale"], g,
+                             cfg.layernorm_epsilon)
     with jax.named_scope("ssm_out_proj"):
         out = y.astype(cd) @ params["out_proj"]["kernel"].astype(cd)
     if kv_cache is not None:

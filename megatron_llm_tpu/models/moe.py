@@ -145,17 +145,40 @@ def _cfg_expert_axis(cfg: TransformerConfig):
     return "expert" if cfg.moe_expert_axis == "expert" else None
 
 
-# the spread a fresh model's choice bias is drawn at.  Published biases
-# are buffers moved by load balancing, and a checkpoint overwrites this;
-# drawn at zero, leaving the bias out of the choice would be a fault no
-# test or probe could see
+# the spread a fresh model's choice bias is drawn at, unless the config
+# gives another (``moe_choice_bias_std``).  Published biases are buffers
+# moved by load balancing, and a checkpoint overwrites this; drawn at
+# zero, leaving the bias out of the choice would be a fault no test or
+# probe could see
 _CHOICE_BIAS_STD = 0.1
+_LANES = 128
+
+
+def laid_width(cfg: TransformerConfig) -> int:
+    """The columns ``experts['w_in']`` is LAID OUT at: an expert's width,
+    or, for an ungated expert whose width is over one row of lanes and no
+    multiple of it (1856 = 14.5 x 128), the next multiple, the columns
+    past the width zeros.  A layout and no width of the model: what
+    those columns give is dropped before ``w_out``, so the mathematics
+    is that of the width.  Why: an array ``[.., H, F]`` whose ``F`` is
+    not whole lanes and whose ``H`` is lies on the TPU with ``H`` minor,
+    and the program then copies ALL the experts into the kernel's layout
+    at every launch (3.83 GB a decode step at 6 layers x 64 experts of
+    2688 x 1856, 13.9 ms a layer where the laid-out width takes 1.81:
+    PERF.md section 6, PR 44).  A GLU's doubled first projection is
+    split at its middle and keeps its width."""
+    F = cfg.expert_hidden_size
+    if cfg.glu_activation or F <= _LANES or F % _LANES == 0:
+        return F
+    return -(-F // _LANES) * _LANES
 
 
 def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
     """{'router': {'kernel': [H, E][, 'choice_bias': [E]]},
         'experts': {'w_in': [E, H, (2x)F], 'w_out': [E, F, H]}
-        [, 'shared': a dense MLP's two linears at moe_shared_experts * F]}"""
+        [, 'shared': a dense MLP's two linears at moe_shared_experts * F]}
+    (``w_in``'s last dimension is ``laid_width``: ``F`` but for an
+    ungated width that is not whole lanes)."""
     k_r, k_in, k_out = jax.random.split(key, 3)
     init = init_method_for(cfg)
     out_init = (
@@ -169,13 +192,17 @@ def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
         # the router scores every expert of the layer, held here or not
         "router": {"kernel": init(k_r, (H, cfg.routed_experts), dtype)},
         "experts": {
-            "w_in": init(k_in, (E, H, mult * F), dtype),
+            # zeros past the width where it is laid out wider (a GLU's
+            # ``laid_width`` is its own: nothing is added)
+            "w_in": jnp.pad(init(k_in, (E, H, mult * F), dtype),
+                            ((0, 0), (0, 0), (0, laid_width(cfg) - F))),
             "w_out": out_init(k_out, (E, F, H), dtype),
         },
     }
     if cfg.moe_choice_bias:
         params["router"]["choice_bias"] = (
-            _CHOICE_BIAS_STD * jax.random.normal(
+            (_CHOICE_BIAS_STD if cfg.moe_choice_bias_std is None
+             else cfg.moe_choice_bias_std) * jax.random.normal(
                 jax.random.fold_in(k_r, 1), (cfg.routed_experts,),
                 jnp.float32)).astype(dtype)
     if cfg.moe_shared_experts:
@@ -372,6 +399,9 @@ def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
         w_in = dequantize_weight(experts, "w_in", cdtype)
         w_out = dequantize_weight(experts, "w_out", cdtype)
         mid = apply_mlp_activation(_grouped_matmul(rows, w_in, sizes), cfg)
+        if mid.shape[-1] != w_out.shape[-2]:
+            # the columns of a laid-out width (``laid_width``): zeros
+            mid = mid[:, :w_out.shape[-2]]
         y = _grouped_matmul(mid, w_out, sizes)                 # [T*k, h]
 
     with jax.named_scope("moe_combine"):
@@ -444,6 +474,8 @@ def moe_mlp(
     mid = jnp.einsum("ebch,ehf->ebcf", expert_in, w_in)
     mid = constrain(mid, ex, None, None, "ffn")
     mid = apply_mlp_activation(mid, cfg)
+    if mid.shape[-1] != w_out.shape[-2]:
+        mid = mid[..., :w_out.shape[-2]]
     expert_out = jnp.einsum("ebcf,efh->ebch", mid, w_out)
     expert_out = constrain(expert_out, ex, None, None, None)
 

@@ -31,7 +31,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from megatron_llm_tpu.config import TransformerConfig, PositionEmbeddingType
+from megatron_llm_tpu.config import (
+    TRAINING,
+    PositionEmbeddingType,
+    TransformerConfig,
+    refusal,
+)
 from megatron_llm_tpu.ops.activations import apply_mlp_activation
 from megatron_llm_tpu.models.moe import moe_mlp, moe_mlp_dropless
 from megatron_llm_tpu.ops.layernorm import (apply_norm, init_norm_params,
@@ -208,8 +213,13 @@ def init_layer_params(key, cfg: TransformerConfig, dtype,
     """``sparse`` False: a sparse model's leading dense layer, whose MLP
     is the dense one of ``ffn_hidden_size``.  For a stack with
     state-space layers the mixer is left out: the two kinds have other
-    leaves and are stacked apart (``init_stack_params``)."""
+    leaves and are stacked apart (``init_stack_params``).  A layer of ONE
+    sublayer (``cfg.one_sublayer``) holds its one norm here and nothing
+    else: its mixer or its experts are stacked apart by kind."""
     ka, km, kn = jax.random.split(key, 3)
+    if cfg.one_sublayer:
+        return {"input_norm": init_norm_params(
+            cfg.hidden_size, cfg.normalization, dtype)}
     if cfg.num_experts > 1 and sparse:
         from megatron_llm_tpu.models.moe import init_moe_mlp_params
 
@@ -251,7 +261,10 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
     sparse ones only.  So are the mixers of a stack with state-space
     layers (``cfg.state_space``): ``layers['mamba']`` and
     ``layers['attention']`` hold the layers of each kind in their order,
-    under the norms and the MLP that every layer has."""
+    under the norms and the MLP that every layer has; in a stack of one
+    sublayer a layer (``cfg.one_sublayer``) so is the third kind,
+    ``layers['moe']`` (what ``layers['mlp']`` holds elsewhere, over the
+    expert layers alone), under the ONE norm every layer has."""
     keys = jax.random.split(key, cfg.num_layers)
     D = cfg.moe_first_dense_layers
 
@@ -267,11 +280,15 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
     if D:
         params["dense_layers"] = stack(keys[:D], False)
     for kind in cfg.mixer_counts:
-        init = init_attention_params
+        init, at = init_attention_params, 0
         if kind == "mamba":
             from megatron_llm_tpu.models.mamba import init_mamba_params as init
-        # the key the layer's attention would have had
-        mixers = [init(jax.random.split(k, 3)[0], cfg, dtype)
+        elif kind == "moe":
+            from megatron_llm_tpu.models.moe import (
+                init_moe_mlp_params as init)
+            at = 1
+        # the key the layer's attention (its MLP) would have had
+        mixers = [init(jax.random.split(k, 3)[at], cfg, dtype)
                   for i, k in enumerate(keys)
                   if cfg.mixer_index(i)[0] == kind]
         params["layers"][kind] = jax.tree_util.tree_map(
@@ -933,7 +950,12 @@ def transformer_layer(
     model with ``cfg.layer_types`` (``attention`` says what it decides);
     a ``'mamba'`` layer's mixer is ``models/mamba.py::mamba_mixer`` over
     ``params['mamba']`` in place of attention.  Both residual branches
-    are multiplied by ``cfg.residual_multiplier``.
+    are multiplied by ``cfg.residual_multiplier``.  In a stack of ONE
+    sublayer a layer (``cfg.one_sublayer``) the layer is
+    ``x + f(input_norm(x))``, ``f`` its mixer or, for the type
+    ``'moe'``, the expert layer over ``params['moe']``, whose cache (a
+    ``PagedKVCache`` of no pool) carries the step's live rows in and the
+    routing histogram out.
     """
     is_decoder = "inter_attention" in params and encoder_output is not None
     if is_decoder and cfg.parallel_attn:
@@ -971,7 +993,10 @@ def transformer_layer(
         kv_cache=kv_cache, layer_type=layer_type,
     )
     # named_scope: trace-time profiler annotation (telemetry.py --profile)
-    if layer_type == "mamba":
+    if layer_type == "moe":
+        # an expert layer alone: no mixer, and the cache goes through
+        attn_out, new_cache = None, kv_cache
+    elif layer_type == "mamba":
         from megatron_llm_tpu.models.mamba import mamba_mixer
 
         if train or attention_mask is not None:
@@ -998,6 +1023,10 @@ def transformer_layer(
     if cfg.residual_multiplier != 1.0:
         attn_out = attn_out * jnp.asarray(cfg.residual_multiplier,
                                           attn_out.dtype)
+    if cfg.one_sublayer and attn_out is not None:
+        # a mixer layer of a stack of one sublayer a layer ends here
+        return residual + attn_out, new_cache, None
+    mlp_params = params["moe" if cfg.one_sublayer else "mlp"]
 
     # MoE (num_experts > 1) replaces the dense MLP and adds a routing aux
     # loss threaded up through the stack scan (models/moe.py): the
@@ -1009,20 +1038,28 @@ def transformer_layer(
     def run_mlp(inp):
         nonlocal new_cache
         with jax.named_scope("mlp"):
-            if "experts" not in params["mlp"]:
+            if "experts" not in mlp_params:
                 # a dense model, or a sparse model's leading dense layer
-                return mlp(inp, params["mlp"], cfg,
+                return mlp(inp, mlp_params, cfg,
                            sequence_parallel=sequence_parallel), None
             if train:
-                return moe_mlp(inp, params["mlp"], cfg)
+                return moe_mlp(inp, mlp_params, cfg)
             paged = isinstance(new_cache, PagedKVCache)
             live = new_cache.live(inp.shape[1]) if paged else None
-            out, aux, counts = moe_mlp_dropless(inp, params["mlp"], cfg,
+            out, aux, counts = moe_mlp_dropless(inp, mlp_params, cfg,
                                                 live, moe_layer)
             if paged:
                 new_cache = dataclasses.replace(new_cache,
                                                 moe_counts=counts)
             return out, aux
+
+    if cfg.one_sublayer:
+        # an expert layer alone, under the layer's one norm
+        mlp_out, moe_aux = run_mlp(ln_out)
+        if cfg.residual_multiplier != 1.0:
+            mlp_out = mlp_out * jnp.asarray(cfg.residual_multiplier,
+                                            mlp_out.dtype)
+        return residual + mlp_out, new_cache, moe_aux
 
     if cfg.parallel_attn:
         # Falcon: mlp feeds from the same (or its own) LN output; single
@@ -1119,11 +1156,17 @@ def transformer_stack(
     the period's mixers are of two kinds (``cfg.state_space``) their
     parameters are stacked apart (``init_stack_params``) and a layer
     takes its own by its index AMONG ITS KIND, in the scan and in the
-    serving loop alike."""
+    serving loop alike; so are the three kinds of a stack of one
+    sublayer a layer (``cfg.one_sublayer``), its expert layers among
+    them."""
     layers = stack_params["layers"]
     L = cfg.num_layers
-    # the mixers of a stack with state-space layers, by kind, apart from
-    # the leaves every layer has
+    said = train and refusal(cfg, (TRAINING,))
+    if said:
+        raise NotImplementedError(said)
+    # the mixers of a stack with state-space layers (the three kinds of a
+    # stack of one sublayer a layer), by kind, apart from the leaves
+    # every layer has
     mixers = {k: layers[k] for k in cfg.mixer_counts}
     if mixers:
         layers = {k: v for k, v in layers.items() if k not in mixers}
@@ -1191,11 +1234,12 @@ def transformer_stack(
         # with the layer's index AMONG THE SPARSE LAYERS: models/moe.py
         # says why, and decides what to do with them
         sliced = layers
-        if moe_on:
+        if moe_on and not cfg.one_sublayer:
             sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items()
                                         if k != "experts"}}
         for i in range(L):
-            sparse = moe_on and i >= D
+            sparse = moe_on and i >= D and not cfg.one_sublayer
+            moe_layer = i - D if sparse else None
             layer_p = jax.tree_util.tree_map(
                 lambda p: p[i - D], sliced) if i >= D else (
                 jax.tree_util.tree_map(lambda p: p[i], dense))
@@ -1203,12 +1247,16 @@ def transformer_stack(
                 layer_p["mlp"]["experts"] = layers["mlp"]["experts"]
             if mixers:
                 kind, at = cfg.mixer_index(i)
-                layer_p[kind] = jax.tree_util.tree_map(
-                    lambda p: p[at], mixers[kind])
+                own = dict(mixers[kind])
+                # an expert layer alone: the experts whole, as above,
+                # this layer ``at`` of them
+                whole = {"experts": own.pop("experts")} if kind == "moe" else {}
+                moe_layer = at if whole else moe_layer
+                layer_p[kind] = {**jax.tree_util.tree_map(
+                    lambda p: p[at], own), **whole}
             h, c, _ = transformer_layer(
                 h, layer_p, cfg, rng_key=None, train=False,
-                kv_cache=kv_caches[i],
-                moe_layer=i - D if sparse else None,
+                kv_cache=kv_caches[i], moe_layer=moe_layer,
                 layer_type=period[i % P], **layer_kw,
             )
             new_caches.append(c)

@@ -71,11 +71,15 @@ def glu_activation(name: str, x: jax.Array) -> jax.Array:
 
 def apply_mlp_activation(h: jax.Array, cfg) -> jax.Array:
     """The MLP nonlinearity selected by config — GLU family (halves the
-    doubled first projection) or a gelu variant ('exact' = erf gelu for
-    Falcon, else the GPT-2/Megatron tanh polynomial).  Shared by the dense
-    MLP (models/transformer.py) and the MoE experts (models/moe.py)."""
+    doubled first projection), the ungated ``relu(x)^2``
+    (``mlp_activation='relu2'``) or a gelu variant ('exact' = erf gelu
+    for Falcon, else the GPT-2/Megatron tanh polynomial).  Shared by the
+    dense MLP (models/transformer.py), the MoE experts and their shared
+    MLP (models/moe.py)."""
     if cfg.glu_activation:
         return GLU_ACTIVATIONS[cfg.glu_activation](h)
+    if cfg.mlp_activation == "relu2":
+        return squared_relu(h)
     if cfg.gelu_variant == "exact":
         return jax.nn.gelu(h, approximate=False)
     return gelu(h)

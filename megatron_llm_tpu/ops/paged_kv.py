@@ -61,6 +61,15 @@ copy-on-write and ``block_bytes`` are for pools WITH pages:
 ``paged_pools`` picks them, and ``array_shapes`` gives the state's
 arrays a list of their own.
 
+In a stack of ONE sublayer a layer (``cfg.one_sublayer``) an expert
+layer (type ``moe``) keeps NOTHING between launches: its group is
+``NONE`` and its pool ``{}``, a pytree of no arrays, so the list of
+pools stays one entry a layer and no program moves a byte for it.  Its
+:class:`PagedKVCache` carries the step's live rows in (``valid_lens``)
+and the layer's routing histogram out (``moe_counts``), which a mixer
+layer of such a stack leaves None: the histogram's rows are the expert
+layers.
+
 A :class:`PagedKVCache` is what the model is handed for one step of one
 layer: the pool plus the step's state (block tables, context lengths,
 valid lengths) and, as STATIC data, the path that reads the pool:
@@ -126,18 +135,19 @@ def expands_latents(kernel: str, n: int) -> bool:
     return n > 1 and kernel == "pallas" and _pa.kernel_available()
 
 
-FULL, WINDOW, STATE = "full", "window", "state"
+FULL, WINDOW, STATE, NONE = "full", "window", "state", "none"
 # the recurrent state's dtype: an ASSUMPTION (the published config gives
 # no cache dtype), float32 because it is multiplied and added to at every
 # token of a request
 SSM_STATE_DTYPE = jnp.float32
-_GROUP_OF = {"sliding": WINDOW, "mamba": STATE}
+_GROUP_OF = {"sliding": WINDOW, "mamba": STATE, "moe": NONE}
 
 
 def layer_groups(cfg) -> Optional[tuple]:
     """The pool group of each layer of a model with a layer type per
-    layer (``FULL`` | ``WINDOW`` | ``STATE``); None for a model of one
-    type, which has one group."""
+    layer (``FULL`` | ``WINDOW`` | ``STATE``, or ``NONE`` for an expert
+    layer alone, which keeps nothing); None for a model of one type,
+    which has one group."""
     if cfg.layer_types is None:
         return None
     period = cfg.layer_period
@@ -205,7 +215,8 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                 dtype)
         return kv
 
-    return [state() if groups and groups[i] == STATE else
+    return [{} if groups and groups[i] == NONE else
+            state() if groups and groups[i] == STATE else
             pool(window_blocks if groups and groups[i] == WINDOW
                  else num_blocks) for i in range(cfg.num_layers)]
 
@@ -219,13 +230,13 @@ def is_state(pool: dict) -> bool:
 def paged_pools(pools) -> List[dict]:
     """The pools that have pages: what the page programs, copy-on-write
     and ``block_bytes`` run over."""
-    return [p for p in pools if not is_state(p)]
+    return [p for p in pools if p and not is_state(p)]
 
 
 def with_paged(pools, paged) -> List[dict]:
     """``pools`` with its paged pools replaced by ``paged``, in order."""
     paged = iter(paged)
-    return [p if is_state(p) else next(paged) for p in pools]
+    return [next(paged) if p and not is_state(p) else p for p in pools]
 
 
 def state_bytes_per_slot(pools) -> int:
@@ -562,12 +573,13 @@ def step_caches(pools, block_tables, context_lens, valid_lens,
     group and each layer carries its own group's.  A state-space layer
     reads no table: its entry ``block_tables[STATE]``, where there is
     one, is ``[b]`` each row's SLOT (a prefill chunk's; a decode step has
-    none: row s is slot s)."""
+    none: row s is slot s).  An expert layer alone (``NONE``) reads
+    none either."""
     kernel = kernel or resolve_kernel("auto", one_device=True)
     if groups is None:
         return [PagedKVCache(p, block_tables, context_lens, valid_lens,
                              kernel=kernel) for p in pools]
-    return [PagedKVCache(p, block_tables[FULL if g == STATE else g],
+    return [PagedKVCache(p, block_tables[FULL if g in (STATE, NONE) else g],
                          context_lens, valid_lens, kernel=kernel, group=g,
                          slots=block_tables.get(STATE) if g == STATE
                          else None)
@@ -599,6 +611,9 @@ class CachePlan:
     # and the blocks of a slot's table (0 and 0 without an indexer)
     dsa_block_keys: int
     dsa_table_blocks: int
+    # a layer whose pool holds arrays (an expert layer alone holds none):
+    # what a launch is waited for by
+    first_pool: int = 0
 
     def init_pools(self, num_blocks: int, quantized: bool = False):
         return init_pools(self.cfg, num_blocks, self.block_size,
@@ -670,13 +685,14 @@ class CachePlan:
         a dense model)."""
         if counts is None:
             return
-        cfg = self.cfg
-        d.moe_assignments = d.moe_assignments_held = int(counts.sum())
+        cfg, held = self.cfg, counts
         if cfg.holds_a_share:
             first = cfg.moe_experts_first
-            d.moe_assignments_held = int(
-                counts[:, first:first + cfg.num_experts].sum())
+            held = counts[:, first:first + cfg.num_experts]
+        d.moe_assignments = int(counts.sum())
+        d.moe_assignments_held = int(held.sum())
         d.moe_experts_touched = int((counts > 0).sum())
+        d.moe_experts_touched_held = int((held > 0).sum())
         d.moe_expert_slots = int(counts.size)
         d.moe_busiest_expert_assignments = int(counts.max(axis=1).sum())
 
@@ -709,7 +725,8 @@ def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
         cfg, block_size, num_slots, prefill_kernel, groups, window,
         tuple(block_bytes([p for p, g in zip(pools, groups or ())
                            if g == which]) for which in (FULL, WINDOW)),
-        state_bytes_per_slot(pools), dsa_block_keys, dsa_table_blocks)
+        state_bytes_per_slot(pools), dsa_block_keys, dsa_table_blocks,
+        next((i for i, g in enumerate(groups or ()) if g != NONE), 0))
 
 def pools_of(caches: List[PagedKVCache]) -> List[dict]:
     """The pools as a step left them."""

@@ -130,11 +130,15 @@ def moe_expert_tiles(mcfg) -> Optional[Dict[str, Dict[str, int]]]:
     """The blocks the experts' grouped matmul takes at a sparse model's
     widths (``tiles``, a function of the operands' shapes): for ``w_in``
     [H, (2x)F] and ``w_out`` [F, H] the widths, the block and the grid
-    steps a visit.  None for a dense model."""
+    steps a visit.  None for a dense model.  ``w_in``'s ``n`` is the
+    width it is LAID OUT at (``models/moe.py::laid_width``: 1920 for an
+    ungated width of 1856), ``w_out``'s ``k`` the width itself."""
     if mcfg.num_experts <= 1:
         return None
+    from megatron_llm_tpu.models.moe import laid_width
+
     H, F = mcfg.hidden_size, mcfg.expert_hidden_size
-    wide = (2 if mcfg.glu_activation else 1) * F
+    wide = 2 * F if mcfg.glu_activation else laid_width(mcfg)
     dtype = mcfg.compute_jnp_dtype
     return {"w_in": describe(H, wide, dtype),
             "w_out": describe(F, H, dtype)}

@@ -1180,7 +1180,7 @@ class InferenceEngine:
                 break
             st.pages = self._host_load(st.pages, data, np.int32(block))
             loaded.append((block, digest))
-        jax.block_until_ready(st.pages[0])
+        jax.block_until_ready(st.pages[self._cache.first_pool])
         secs = time.perf_counter() - t0
         if valid_blocks is not None:
             cached = valid_blocks * self.config.block_size
@@ -1251,7 +1251,8 @@ class InferenceEngine:
             d.mark("dispatch")
             # a chunk that is not its prompt's last: nothing of it is
             # read but the histogram, and the wait is for the chunk
-            (routing,) = self._read(d, routing, until=st.pages[0])
+            (routing,) = self._read(
+                d, routing, until=st.pages[self._cache.first_pool])
         d.mark("fetch")
         self._cache.account_routing(d, routing)
         if st is not self._st:
@@ -1657,7 +1658,7 @@ class InferenceEngine:
             garbage = jax.device_get(
                 self._fetch_block(st.pages, np.int32(0)))
             st.pages = self._host_load(st.pages, garbage, np.int32(0))
-        jax.block_until_ready(st.pages[0])
+        jax.block_until_ready(st.pages[self._cache.first_pool])
         self.warmed_up = True
         # compile-time gaps between warmup dispatches are expected —
         # only steady-state dispatch gaps count as loop stalls

@@ -116,9 +116,11 @@ LOOP_PHASE_BUCKETS = (
 # largest count of assignments to one expert; and of the live
 # assignments those that fell on an expert this chip holds (all of them
 # unless the layer holds a share of its router's experts:
-# ``cfg.moe_router_experts``)
+# ``cfg.moe_router_experts``), and of the experts this chip holds those
+# that received at least one: the matrices its grouped matmul reads
 MOE_FIELDS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
-              "moe_busiest_expert_assignments", "moe_assignments_held")
+              "moe_busiest_expert_assignments", "moe_assignments_held",
+              "moe_experts_touched_held")
 
 # learned sparse attention (a model with an indexer), summed over the
 # launch's live queries and the layers (``CachePlan.account``): the keys

@@ -650,14 +650,21 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    engine_loop_stats their totals under the same names — see
 #    serving/loop_profiler.py ``HOST_FIELDS`` and serving/engine.py
 #    ``_EngineState``
+# 23: the held experts a launch touches: engine stats() / the engine block
+#    of /metrics gain moe_experts_touched_held (of the experts THIS CHIP
+#    HOLDS, those with at least one live assignment, summed over the
+#    expert layers and launches; moe_experts_touched counts over all the
+#    router scores, and the two are equal unless moe_router_experts is
+#    set), and every launch record carries the same for that launch —
+#    see serving/loop_profiler.py ``MOE_FIELDS``
 # The rule from here on: a key RENAMED, REMOVED or changed in meaning is a
 # change of schema, and so is any change to request_done's keys (the
 # lint's ratchet, analysis/telemetry_schema.py).  A counter ADDED to a
 # launch record, to stats() or to engine_loop_stats is NOT (15-16 and
-# 18-22 above were bumped for one by habit): readers take keys by name
+# 18-23 above were bumped for one by habit): readers take keys by name
 # and ignore the rest, and a launch's counters have one declaration
 # (serving/loop_profiler.py ``COUNTED_FIELDS``) that says what each is.
-TELEMETRY_SCHEMA_VERSION = 22
+TELEMETRY_SCHEMA_VERSION = 23
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

@@ -517,6 +517,11 @@ TRACED = {
                "engine_decode": "d819cd2b833bd7c6"},
     "granite": {"engine_prefill": "2796d5c8ed6aa848",
                 "engine_decode": "db18b83c412ce327"},
+    # PR 44's family, recorded on its own tree: the fourteen above are
+    # unchanged by it (layers of one sublayer, the gated norm by group
+    # and the laid-out width touch no older family's program)
+    "nemotron_h": {"engine_prefill": "65599463356e0eee",
+                   "engine_decode": "3b65a4a8f2bd932a"},
 }
 
 

@@ -4,7 +4,7 @@ one reader (``config.refusal``) gives and to whoever asks it: the
 config's own ``__post_init__`` for a model against itself, the serving
 engine for the features its ``EngineConfig`` turns on,
 ``paged_kv.init_pools`` for the int8 pool, ``GPTModel`` for the
-parallelism in force.
+parallelism in force, ``transformer_stack`` for training.
 """
 
 import copy
@@ -24,13 +24,16 @@ SQUARES = [(has, what) for has, whats in C.RUNS_WITH for what in whats]
 # a served family that has each mechanism, and how a config (or an
 # engine) is given each thing a mechanism does not run with
 FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
+          C.ONE_SUBLAYER: "nemotron_h",
           C.FIRST_DENSE: "kanana", C.LATENT: "kanana",
           C.QK_NORM_WHOLE: "olmoe", C.EXPERTS: "olmoe", C.SHARE: "granite"}
 GIVEN = {
     C.SLIDING: dict(sliding_window_size=16),
     C.NOT_ROTARY: dict(position_embedding_type="learned_absolute"),
-    C.OTHER_TYPES: dict(layer_types=("mamba", "sliding"),
-                        sliding_window_size=16),
+    # the family's own period with its last layer a window layer
+    C.OTHER_TYPES: lambda cfg: dict(
+        layer_types=cfg.layer_types[:-1] + ("sliding",),
+        sliding_window_size=16),
     C.BIASES: dict(add_bias_linear=True),
     C.QKV_BIAS: dict(add_qkv_bias=True),
     C.PARALLEL_ATTN: dict(parallel_attn=True),
@@ -81,7 +84,9 @@ def test_a_square_of_the_table_is_told_by_whoever_asks(has, what,
     # the config with ``what`` on (a feature, or a field set as no
     # constructor would leave it) against this one square
     both, on = copy.copy(cfg), (what,) if what in C.FEATURES else ()
-    for field, value in GIVEN.get(what, {}).items():
+    given = GIVEN.get(what, {})
+    given = given(cfg) if callable(given) else given
+    for field, value in given.items():
         object.__setattr__(both, field, value)
     monkeypatch.setattr(C, "RUNS_WITH", ((has, (what,)),))
     assert C.refusal(cfg) is None
@@ -99,7 +104,7 @@ def test_a_square_of_the_table_is_told_by_whoever_asks(has, what,
     if what not in C.FEATURES:
         # a model against itself: the constructor asks
         with pytest.raises(ValueError, match="not implemented with"):
-            _config(family, **GIVEN[what])
+            _config(family, **given)
     elif what in C.TURNED_OFF:
         # the engine says so and runs without
         eng = _engine(family, **TURNS_ON[what])
@@ -109,6 +114,12 @@ def test_a_square_of_the_table_is_told_by_whoever_asks(has, what,
     elif what in TURNS_ON:
         with pytest.raises(ValueError) as raised:
             _engine(family, **TURNS_ON[what])
+        assert str(raised.value) == said
+    elif what == C.TRAINING:
+        # the stack asks when it is run to train
+        model, params = _model(family)
+        with pytest.raises(NotImplementedError) as raised:
+            model(params, jax.numpy.ones((1, 8), "int32"), train=True)
         assert str(raised.value) == said
     else:
         # the mesh's: GPTModel asks with what is in force

@@ -349,7 +349,7 @@ def test_request_done_schema_golden(engine, tmp_path):
     the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 22
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 23
     captured = []
     engine.request_done_hook = captured.append
     stream = telemetry.TelemetryStream(str(tmp_path))

@@ -248,15 +248,16 @@ SAMPLER = "sampler_64_slots_50304_logits"
 SELECTION = "dsa_kernels_by_name"
 ENGINE_TABLES = "engine_tables_tiny_sparse_model"
 GRANITE = "granite_cell_programs"
+NEMOTRON = "nemotron_cell_programs"
 
 
-def _compile_all(only_granite: bool = False):
+def _compile_all(only: str = ""):
     """The child: compile every case for one described v5e device and
     print ``{case: true | false | "error"}`` (or ``{"skip": why}`` where
     this jaxlib cannot describe the topology).  The Granite cell's two
-    programs take a child of their own (``only_granite``): at their real
-    sizes they cost as much as all the kernels together, and a test's
-    time limit covers its fixture."""
+    programs, and the Nemotron cell's, take a child each (``only``): at
+    their real sizes they cost as much as all the kernels together, and a
+    test's time limit covers its fixture."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -267,8 +268,10 @@ def _compile_all(only_granite: bool = False):
         print(json.dumps({"skip": f"{type(e).__name__}: {e}"[:300]}))
         return
     chip = SingleDeviceSharding(topo.devices[0])
-    if only_granite:
-        print(json.dumps({GRANITE: _granite_programs(chip)}))
+    if only:
+        programs = {"granite": (GRANITE, _granite_programs),
+                    "nemotron": (NEMOTRON, _nemotron_programs)}[only]
+        print(json.dumps({programs[0]: programs[1](chip)}))
         return
     found = {}
     for case in sorted(CASES):
@@ -354,21 +357,48 @@ def _granite_programs(chip):
     the described chip: what each holds, and which of the mixer's scopes
     and kernels reach the optimised text."""
     from megatron_llm_tpu.models.granite import GraniteModel, granite_config
+
+    return _cell_programs(chip, lambda: GraniteModel(granite_config(
+        "h-small", num_layers=10, num_experts=36, moe_router_experts=72,
+        padded_vocab_size=50176, params_dtype="bf16",
+        compute_dtype="bf16", seq_length=17408)), dict(
+        num_slots=24, num_blocks=13313, max_model_len=17408))
+
+
+def _nemotron_programs(chip):
+    """The same of the benchmark's Nemotron cell: the published pattern's
+    first 14 layers (six Mamba-2 mixers of eight groups, two attention
+    layers, six expert layers alone) at the published widths, 64 of 128
+    experts of 2688 x 1856, half the vocabulary, 64 slots of state and
+    24,577 pages."""
+    from megatron_llm_tpu.config import pattern_layer_types
+    from megatron_llm_tpu.models.nemotron_h import (NANO_PATTERN,
+                                                    NemotronHModel,
+                                                    nemotron_h_config)
+
+    return _cell_programs(chip, lambda: NemotronHModel(nemotron_h_config(
+        "nano-30b-a3b", num_layers=14,
+        layer_types=pattern_layer_types(NANO_PATTERN[:14]), num_experts=64,
+        moe_router_experts=128, padded_vocab_size=65536,
+        params_dtype="bf16", compute_dtype="bf16", seq_length=6144)), dict(
+        num_slots=64, num_blocks=24577, max_model_len=6144))
+
+
+def _cell_programs(chip, build, engine):
     from megatron_llm_tpu.ops import paged_kv
     from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
 
     try:
-        model = GraniteModel(granite_config(
-            "h-small", num_layers=10, num_experts=36, moe_router_experts=72,
-            padded_vocab_size=50176, params_dtype="bf16",
-            compute_dtype="bf16", seq_length=17408))
+        model = build()
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         eng = InferenceEngine(model, params, EngineConfig(
-            num_slots=24, block_size=16, num_blocks=13313,
-            max_model_len=17408, prefill_chunk=512, preemption=False,
-            paged_kernel="on", prefill_kernel="on"))
+            block_size=16, prefill_chunk=512, preemption=False,
+            paged_kernel="on", prefill_kernel="on", **engine))
         found = {"state_bytes_per_slot": paged_kv.state_bytes_per_slot(
-            eng._st.pages), "pool_bytes": eng.kv_pool_bytes}
+            eng._st.pages), "pool_bytes": eng.kv_pool_bytes,
+            "parameters": sum(a.size for a in
+                              jax.tree_util.tree_leaves(params)),
+            "moe_expert_tiles": eng.moe_expert_tiles}
         for name, args in eng._program_arguments().items():
             if name not in ("engine_prefill", "engine_decode"):
                 continue
@@ -420,6 +450,11 @@ def compiled():
 @pytest.fixture(scope="module")
 def granite_compiled():
     return _child("granite")
+
+
+@pytest.fixture(scope="module")
+def nemotron_compiled():
+    return _child("nemotron")
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -511,5 +546,41 @@ def test_the_granite_cells_programs_compile_and_fit_a_v5e(granite_compiled):
         assert any(k.startswith("paged_attention") for k in got["kernels"])
 
 
+@pytest.mark.time_limit(900)
+def test_the_nemotron_cells_programs_compile_and_fit_a_v5e(nemotron_compiled):
+    """The Nemotron cell's two programs at its real sizes, for a
+    described v5e: both compile with the experts' grouped matmul at 2688
+    x 1856 (a block the whole 1856 wide) and the attention layers' walk
+    (16 query heads a KV head) as kernels and the mixer's scopes in the
+    text, and what each holds fits the chip's 16 GB with room for the
+    probe."""
+    found = nemotron_compiled[NEMOTRON]
+    assert isinstance(found, dict), found
+    assert found["state_bytes_per_slot"] == 6 * (64 * 64 * 128 * 4
+                                                 + 3 * 6144 * 2)
+    assert found["pool_bytes"] == (65 * found["state_bytes_per_slot"]
+                                   + 24577 * 16 * 2048)
+    # 4,584.9 M parameters at the published widths (9.17 GB in bf16) and
+    # 66.1 M zeros of the experts' first matrices' layout (1856 -> 1920)
+    assert found["parameters"] == 4_584_903_936 + 6 * 64 * 2688 * 64
+    tiles = found["moe_expert_tiles"]
+    assert (tiles["w_in"]["n"], tiles["w_out"]["tk"]) == (1920, 1856)
+    for name, recurrence in (("engine_prefill", "ssm_scan"),
+                             ("engine_decode", "ssm_step")):
+        got = found[name]
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"])
+        assert got["output_bytes"] >= found["pool_bytes"], (name, got)
+        if name == "engine_decode":
+            assert got["alias_bytes"] >= found["pool_bytes"], (name, got)
+        assert held - got["alias_bytes"] < 13.5e9, (name, held)
+        # no launch moves the experts into another layout (3.83 GB of
+        # temporaries a launch at a 1856-wide last dimension)
+        assert got["temp_bytes"] < 0.5e9, (name, got)
+        assert recurrence in got["scopes"] and "ssm_in_proj" in got["scopes"]
+        assert "moe_experts" in got["kernels"], got["kernels"]
+        assert any(k.startswith("paged_attention") for k in got["kernels"])
+
+
 if __name__ == "__main__":
-    _compile_all(only_granite=sys.argv[1:] == ["granite"])
+    _compile_all(only=(sys.argv[1:] or [""])[0])
