@@ -39,9 +39,16 @@ convolution, ``[d_conv - 1, conv_dim]`` (compute dtype), and ``S``,
   and adds nothing, and the convolution's columns are taken at the row's
   last VALID tokens;
 * a **step** ``[S, 1, h]`` (the decode program, scope ``ssm_step``): the
-  recurrence itself, once.
+  recurrence itself, once.  Where the cache's path is ``'pallas'`` it is
+  ONE kernel (``ops/pallas/ssm_step.py``, launched as
+  ``ssm_state_step``) that updates the state pool in place and moves the
+  live rows only, each row's state read once and written once; the
+  ``'xla'`` path (the CPU, a mesh of several devices, and what the
+  kernel's tests compare against) reads every row's state, advances it
+  and writes every slot back.  ``PagedKVCache.step_state`` is both.
 
-XLA only: a first version (ROADMAP R6 lists the kernels).
+The chunk's scan, the convolution and the projections are XLA's (ROADMAP
+S19 has what is left).
 """
 
 from __future__ import annotations
@@ -243,13 +250,12 @@ def mamba_mixer(h: jax.Array, params, cfg: TransformerConfig, *,
     if kv_cache is not None and n == 1:
         with jax.named_scope("ssm_step"):
             d1 = delta[:, 0]                                # [b, nh]
-            x1 = x[:, 0].astype(jnp.float32)                # [b, nh, dh]
-            B1 = jnp.repeat(Bm[:, 0], nh // g, axis=1).astype(jnp.float32)
-            C1 = jnp.repeat(Cm[:, 0], nh // g, axis=1).astype(jnp.float32)
-            new_state = (jnp.exp(d1 * A)[..., None, None] * state
-                         + (d1[..., None] * x1)[..., None]
-                         * B1[:, :, None, :])
-            y = jnp.einsum("bhdn,bhn->bhd", new_state, C1)[:, None]
+            # the cache advances its own state (the kernel in place, or
+            # every row read and put back: PagedKVCache.step_state)
+            y, kv_cache = kv_cache.step_state(
+                jnp.exp(d1 * A), d1[..., None] * x[:, 0].astype(jnp.float32),
+                Bm[:, 0].astype(jnp.float32), Cm[:, 0].astype(jnp.float32))
+            y, new_state = y[:, None], None
     else:
         with jax.named_scope("ssm_scan"):
             y, new_state = chunked_scan(x, delta, A, Bm, Cm, state,

@@ -55,8 +55,11 @@ heads, d_head, d_state]`` in float32 (``SSM_STATE_DTYPE``: a recurrence
 multiplied and added to over a request's every token).  The last row is
 the garbage row an idle row writes to, as page 0 is.  A launch whose
 ``context_lens`` is 0 reads zeros whatever the slot held
-(``read_state``), so a slot is reused with no clearing launch.  Its
-attention layers are of the ``full`` group.  The page programs,
+(``read_state``), so a slot is reused with no clearing launch.  A
+decode step's recurrence is the cache's (``step_state``), on its two
+paths as attention is: the in-place kernel over the live rows
+(``ops/pallas/ssm_step.py``) or every row read, advanced and put back.
+Its attention layers are of the ``full`` group.  The page programs,
 copy-on-write and ``block_bytes`` are for pools WITH pages:
 ``paged_pools`` picks them, and ``array_shapes`` gives the state's
 arrays a list of their own.
@@ -348,40 +351,69 @@ class PagedKVCache:
     group: str = dataclasses.field(default=FULL, metadata=dict(static=True))
     slots: Optional[jax.Array] = None
 
+    def _rows(self, a: jax.Array, fresh: jax.Array) -> jax.Array:
+        """Array ``a`` of a state-space layer's pool as each row finds
+        it: its slot's, or zeros where the row is ``fresh`` (its
+        ``context_lens`` is 0: a request's first launch, whatever the
+        slot held)."""
+        b = fresh.shape[0]
+        a = a[:b] if self.slots is None else a[self.slots]
+        return jnp.where(fresh.reshape((b,) + (1,) * (a.ndim - 1)),
+                         jnp.zeros((), a.dtype), a)
+
+    def _put(self, a: jax.Array, val: jax.Array,
+             live: jax.Array) -> jax.Array:
+        """Array ``a`` of a state-space layer's pool with each ``live``
+        row's ``val`` written at its slot (an idle row's, ``valid_lens``
+        0, at the garbage row)."""
+        b = live.shape[0]
+        val = val.astype(a.dtype)
+        if self.slots is None:
+            # row s is slot s: one pass over the live rows
+            keep = live.reshape((b,) + (1,) * (a.ndim - 1))
+            return jnp.concatenate([jnp.where(keep, val, a[:b]), a[b:]])
+        return a.at[jnp.where(live, self.slots, a.shape[0] - 1)].set(val)
+
     def read_state(self):
         """A state-space layer's (``conv_state`` [b, d_conv - 1,
         conv_dim], ``ssm_state`` [b, heads, d_head, d_state]) as each row
-        finds them: its slot's, or zeros where the row's
-        ``context_lens`` is 0 (a request's first launch, whatever the
-        slot held)."""
-        b = self.context_lens.shape[0]
+        finds them (``_rows``)."""
         fresh = self.context_lens == 0
+        return (self._rows(self.pool["conv_state"], fresh),
+                self._rows(self.pool["ssm_state"], fresh))
 
-        def rows(a):
-            a = a[:b] if self.slots is None else a[self.slots]
-            return jnp.where(fresh.reshape((b,) + (1,) * (a.ndim - 1)),
-                             jnp.zeros((), a.dtype), a)
+    def step_state(self, decay, dx, B, C):
+        """One token of a state-space layer's recurrence on every live
+        row's ``ssm_state`` (``ops/pallas/ssm_step.py`` has the operands):
+        ``y`` [b, heads, d_head] float32 and the cache with the state
+        WRITTEN (``write_state`` then takes the columns alone).  On the
+        ``'pallas'`` path of a decode step (row s is slot s) the kernel
+        updates the pool in place and moves live rows only; otherwise
+        every row's state is read, advanced and put back."""
+        from megatron_llm_tpu.ops.pallas import ssm_step as _ssm
 
-        return rows(self.pool["conv_state"]), rows(self.pool["ssm_state"])
+        pool = self.pool["ssm_state"]
+        live, fresh = self.valid_lens > 0, self.context_lens == 0
+        if self.kernel == "pallas" and self.slots is None:
+            y, pool = _ssm.ssm_state_step(pool, decay, dx, B, C, live, fresh)
+        else:
+            y, new = _ssm.dense_ssm_step(self._rows(pool, fresh), decay, dx,
+                                         B, C)
+            pool = self._put(pool, new, live)
+        return y, dataclasses.replace(
+            self, pool={**self.pool, "ssm_state": pool})
 
-    def write_state(self, conv_state: jax.Array, ssm_state: jax.Array):
+    def write_state(self, conv_state: jax.Array,
+                    ssm_state: Optional[jax.Array] = None):
         """The cache as a state-space layer's call leaves it: each live
-        row's state written at its slot (an idle row's, ``valid_lens``
-        0, at the garbage row), ``context_lens`` advanced."""
-        b = self.context_lens.shape[0]
+        row's state written at its slot (``_put``; ``ssm_state`` None:
+        ``step_state`` has written it), ``context_lens`` advanced."""
         live = self.valid_lens > 0
-        new = {"conv_state": conv_state, "ssm_state": ssm_state}
-        pool = {}
-        for name, a in self.pool.items():
-            val = new[name].astype(a.dtype)
-            if self.slots is None:
-                # row s is slot s: one pass over the live rows
-                keep = live.reshape((b,) + (1,) * (a.ndim - 1))
-                pool[name] = jnp.concatenate(
-                    [jnp.where(keep, val, a[:b]), a[b:]])
-            else:
-                dest = jnp.where(live, self.slots, a.shape[0] - 1)
-                pool[name] = a.at[dest].set(val)
+        pool = dict(self.pool, conv_state=self._put(
+            self.pool["conv_state"], conv_state, live))
+        if ssm_state is not None:
+            pool["ssm_state"] = self._put(self.pool["ssm_state"], ssm_state,
+                                          live)
         return dataclasses.replace(
             self, pool=pool, context_lens=self.context_lens + self.valid_lens)
 
@@ -597,7 +629,10 @@ class CachePlan:
     cfg: Any
     block_size: int
     num_slots: int
+    # the engine's resolved paths (``resolve_kernel``) of a chunk and of
+    # a decode step: what a launch counts depends on which one ran
     prefill_kernel: str
+    paged_kernel: str
     # ``layer_groups(cfg)``: what ``step_caches`` takes
     groups: Optional[tuple]
     # what ``serving/kv_blocks.py::WindowGroup`` is built with, its pool's
@@ -652,6 +687,12 @@ class CachePlan:
         ctx, val = context_lens[live], valid_lens[live]
         if state_layers:
             d.ssm_rows_live = state_layers * len(val)
+            if d.kind != "prefill":
+                # the step's kernel moves the live rows' state, the XLA
+                # step every slot's and the garbage row's
+                d.ssm_rows_moved = (
+                    d.ssm_rows_live if self.paged_kernel == "pallas"
+                    else state_layers * (self.num_slots + 1))
             d.ssm_tokens = state_layers * int(val.sum())
             d.ssm_state_bytes_held = admitted * self.state_bytes_per_slot
         if not (cfg.latent_attention or self.dsa_block_keys):
@@ -698,10 +739,13 @@ class CachePlan:
 
 
 def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
-         prefill_chunk: int, prefill_kernel: str) -> CachePlan:
+         prefill_chunk: int, prefill_kernel: str,
+         paged_kernel: str) -> CachePlan:
     """The :class:`CachePlan` of a model of config ``cfg`` served from
     pages of ``block_size`` tokens, ``num_slots`` slots of
-    ``max_blocks_per_slot`` pages and chunks of ``prefill_chunk``."""
+    ``max_blocks_per_slot`` pages and chunks of ``prefill_chunk``, the
+    chunk on the resolved path ``prefill_kernel`` and the decode step on
+    ``paged_kernel``."""
     groups = layer_groups(cfg)
     window, dsa_block_keys, dsa_table_blocks = None, 0, 0
     if groups is not None and WINDOW in groups:
@@ -722,7 +766,8 @@ def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
     pools = jax.eval_shape(lambda: init_pools(
         cfg, 2, block_size, window_blocks=2, num_slots=num_slots))
     return CachePlan(
-        cfg, block_size, num_slots, prefill_kernel, groups, window,
+        cfg, block_size, num_slots, prefill_kernel, paged_kernel, groups,
+        window,
         tuple(block_bytes([p for p, g in zip(pools, groups or ())
                            if g == which]) for which in (FULL, WINDOW)),
         state_bytes_per_slot(pools), dsa_block_keys, dsa_table_blocks,

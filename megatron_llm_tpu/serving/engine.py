@@ -409,7 +409,7 @@ class InferenceEngine:
         # what a launch counts): ops/paged_kv.py's plan
         self._cache = paged_kv.plan(
             mcfg, cfg.block_size, cfg.num_slots, self._max_blocks_per_slot,
-            cfg.prefill_chunk, self.prefill_kernel)
+            cfg.prefill_chunk, self.prefill_kernel, self.paged_kernel)
 
         # cache observatory (serving/cache_observatory.py): per-prefix
         # heat, eviction forensics, ghost capacity tiers.  Engine-

@@ -142,10 +142,14 @@ DSA_FIELDS = ("dsa_keys_live", "dsa_keys_selected",
 MLA_FIELDS = ("mla_keys_live", "mla_pairs", "mla_latents_expanded")
 
 # state-space layers (a model with 'mamba' layers; ``CachePlan.account``):
-# live rows x layers whose state the launch advances; tokens scanned x
+# live rows x layers whose state the launch advances; the rows x layers
+# whose state a DECODE launch's program reads and writes (the step's
+# kernel, ``ops/pallas/ssm_step.py``: the live rows; the XLA step: every
+# slot and the garbage row; 0 on a prefill launch); tokens scanned x
 # layers; bytes of recurrent state the admitted requests hold as the
 # launch begins (slots in use x a slot's state over the layers)
-SSM_FIELDS = ("ssm_rows_live", "ssm_tokens", "ssm_state_bytes_held")
+SSM_FIELDS = ("ssm_rows_live", "ssm_rows_moved", "ssm_tokens",
+              "ssm_state_bytes_held")
 
 # a model with a layer type per layer (the engine's ``_window_advance``):
 # window-group pages given back to the allocator before this launch and

@@ -307,11 +307,24 @@ def test_a_state_kept_in_bf16_is_not_the_references(family, monkeypatch):
     assert min(apart) > 1e-3, apart
 
 
-def test_the_engine_counts_what_its_state_space_layers_do(family):
+@pytest.mark.parametrize("kernel", ["off", "on"])
+def test_the_engine_counts_what_its_state_space_layers_do(family, kernel,
+                                                          monkeypatch):
     model, params = family[:2]
-    eng = _engine(model, params)
+    monkeypatch.setattr(pa, "_INTERPRET", kernel == "on")
+    eng = _engine(model, params, paged_kernel=kernel, prefill_kernel=kernel)
     _serve(eng, _tokens(70, seed=5), 4)
     s = eng.stats()
+    # whose state a decode launch's program reads and writes: the step's
+    # kernel the one live row's, the XLA step both slots' and the garbage
+    # row's, a layer; a prefill launch counts none
+    assert s["ssm_rows_moved"] == 3 * 6 * (1 if kernel == "on" else 2 + 1)
+    steps = [r for r in eng.loop_profiler.records() if r.kind == "decode"]
+    assert len(steps) == 3 and all(
+        r.ssm_rows_moved == (r.ssm_rows_live if kernel == "on" else 18)
+        for r in steps)
+    assert not any(r.ssm_rows_moved for r in eng.loop_profiler.records()
+                   if r.kind == "prefill")
     per_slot = 6 * (8 * 32 * 16 * 4 + 3 * (8 * 32 + 2 * 16) * 4)
     assert eng.blocks.stats()["state_bytes_per_slot"] == per_slot
     # 3 chunks and 3 steps, one live row each, six state-space layers
@@ -515,13 +528,20 @@ TRACED = {
                "engine_decode": "0168b534430f68e2"},
     "kanana": {"engine_prefill": "7bc4fba0abd4ca5a",
                "engine_decode": "d819cd2b833bd7c6"},
+    # PR 45 MEANT the decode step of the two families with state-space
+    # layers and nothing else (the fourteen other hashes are PR 44's):
+    # the step's recurrence and its write of ``ssm_state`` are the
+    # cache's (``PagedKVCache.step_state``: here, on the CPU, the XLA
+    # path's same operations, traced inside the mixer's ``ssm_step``
+    # scope and not after its output projection; on one chip the
+    # in-place kernel ``ssm_state_step``)
     "granite": {"engine_prefill": "2796d5c8ed6aa848",
-                "engine_decode": "db18b83c412ce327"},
-    # PR 44's family, recorded on its own tree: the fourteen above are
+                "engine_decode": "3241e7dd0820c5bb"},
+    # PR 44's family, recorded on its own tree: the fourteen above it are
     # unchanged by it (layers of one sublayer, the gated norm by group
     # and the laid-out width touch no older family's program)
     "nemotron_h": {"engine_prefill": "65599463356e0eee",
-                   "engine_decode": "3b65a4a8f2bd932a"},
+                   "engine_decode": "9c7475441b611670"},
 }
 
 

@@ -441,7 +441,7 @@ def test_the_benchmarks_counted_share_reads_the_records_two_fields(
     # chunk of 16 at context 16 is one select step through the blocks
     # that hold 32 keys, of a table of 64 tokens
     cfg = keye_config("tiny")
-    plan = paged_kv.plan(cfg, 8, 4, 8, 16, "xla")
+    plan = paged_kv.plan(cfg, 8, 4, 8, 16, "xla", "xla")
     counted = cfg.num_layers * -(-32 // plan.dsa_block_keys)
     table = cfg.num_layers * -(-64 // plan.dsa_block_keys)
     rec = DispatchRecord(lambda: 0.0, 0, 0.0, 0.0)

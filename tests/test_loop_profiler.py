@@ -664,6 +664,9 @@ KERNEL_NAMES = {
     # the same walk over a latent pool (PR 36), which has neither an
     # int8 pool nor a window group
     "mla_attention_decode", "mla_attention_prefill",
+    # the decode step's state-space recurrence, in place over the live
+    # rows (PR 45), under the scope ``ssm_step``
+    "ssm_state_step",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -719,8 +722,9 @@ def test_every_kernel_and_program_carries_its_stable_name():
                                          "_masked_walk")):
                 kw = {k.arg: k.value for k in node.keywords}
                 names.add(kw["name"].value)
-    # 13 until the latent chunk got a walk of its own (mla_attention_prefill)
-    assert calls == 14
+    # 13 until the latent chunk got a walk of its own
+    # (mla_attention_prefill), 14 until the state's step got a kernel
+    assert calls == 15
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

@@ -88,7 +88,8 @@ def layer():
     return cfg, params
 
 
-def _cache(cfg, slots, context, valid, rows=None, pool=None, total=3):
+def _cache(cfg, slots, context, valid, rows=None, pool=None, total=3,
+           kernel="xla"):
     pool = pool or paged_kv.init_pools(
         cfg, 4, 8, num_slots=total)[0]
     assert paged_kv.is_state(pool)
@@ -97,13 +98,19 @@ def _cache(cfg, slots, context, valid, rows=None, pool=None, total=3):
         tables[paged_kv.STATE] = jnp.asarray(rows, jnp.int32)
     return paged_kv.step_caches(
         [pool], tables, jnp.asarray(context, jnp.int32),
-        jnp.asarray(valid, jnp.int32), "xla", (paged_kv.STATE,))[0]
+        jnp.asarray(valid, jnp.int32), kernel, (paged_kv.STATE,))[0]
 
 
-def test_chunks_then_steps_are_the_whole_sequence(layer):
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_chunks_then_steps_are_the_whole_sequence(layer, kernel, monkeypatch):
     """ONE function in two forms: a sequence through chunks of 16 (the
     last one padded) and then steps, the state carried in the pool,
-    against the cache-less chunk from zeros over the whole of it."""
+    against the cache-less chunk from zeros over the whole of it.  The
+    steps on either path: XLA's, and the in-place kernel's
+    (``ops/pallas/ssm_step.py``, interpret mode)."""
+    from megatron_llm_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_INTERPRET", kernel == "pallas")
     cfg, params = layer
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 50, cfg.hidden_size))
     whole = np.asarray(mamba.mamba_mixer(h, params, cfg))
@@ -120,7 +127,8 @@ def test_chunks_then_steps_are_the_whole_sequence(layer):
     for t in range(40, 50):                     # then steps, slot 1 of 3
         got, cache = mamba.mamba_mixer(
             jnp.tile(h[:, t:t + 1], (3, 1, 1)), params, cfg,
-            kv_cache=_cache(cfg, 3, [0, t, 0], [0, 1, 0], pool=pool))
+            kv_cache=_cache(cfg, 3, [0, t, 0], [0, 1, 0], pool=pool,
+                            kernel=kernel))
         pool = cache.pool
         out.append(np.asarray(got[1:2]))
     np.testing.assert_allclose(np.concatenate(out, axis=1), whole,

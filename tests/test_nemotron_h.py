@@ -366,10 +366,17 @@ def test_one_group_is_todays_norm_bit_for_bit():
     assert np.array_equal(np.asarray(one(y)), np.asarray(today(y)))
 
 
-def test_the_scan_and_the_step_share_b_and_c_by_group(family):
+@pytest.mark.parametrize("steps", [None, "xla", "pallas"], ids=[
+    "the_scan_alone", "then_the_xla_step", "then_the_steps_kernel"])
+def test_the_scan_and_the_step_share_b_and_c_by_group(family, steps,
+                                                      monkeypatch):
     """Head i uses group i // (heads / groups), in the chunked scan and
     in the step alike: a mixer layer alone against the reference's
-    recurrence, and the fault that gives every head group 0's."""
+    recurrence, and the fault that gives every head group 0's.  The
+    whole sequence as one chunk, or its first 32 tokens as a chunk into
+    slot 1 of 3 and the last 8 as steps beside two idle rows, on the XLA
+    path and through the in-place kernel (interpret mode)."""
+    monkeypatch.setattr(pa, "_INTERPRET", steps == "pallas")
     model, params, ref, weights, cfg = family
     w = weights.layer(0)
     hn = jax.random.normal(jax.random.PRNGKey(6), (40, 128), jnp.float32)
@@ -383,7 +390,32 @@ def test_the_scan_and_the_step_share_b_and_c_by_group(family):
                              faults=frozenset({"group_zero"}))
     own = jax.tree_util.tree_map(
         lambda a: a[0], params["transformer"]["layers"]["mamba"])
-    got = mamba.mamba_mixer(hn[None], own, model.cfg)[0]
+    if steps is None:
+        got = mamba.mamba_mixer(hn[None], own, model.cfg)[0]
+    else:
+        pool = next(p for p in paged_kv.init_pools(
+            model.cfg, 4, BS, num_slots=3) if paged_kv.is_state(p))
+
+        def cache(context, valid, rows=None):
+            tables = {paged_kv.FULL: jnp.zeros((len(context), 2), jnp.int32)}
+            if rows is not None:
+                tables[paged_kv.STATE] = jnp.asarray(rows, jnp.int32)
+            return paged_kv.step_caches(
+                [pool], tables, jnp.asarray(context, jnp.int32),
+                jnp.asarray(valid, jnp.int32), steps, (paged_kv.STATE,))[0]
+
+        out, new = mamba.mamba_mixer(hn[None, :32], own, model.cfg,
+                                     kv_cache=cache([0], [32], rows=[1]))
+        got = [out[0]]
+        for t in range(32, 40):
+            pool = new.pool
+            out, new = mamba.mamba_mixer(
+                jnp.tile(hn[None, t:t + 1], (3, 1, 1)), own, model.cfg,
+                kv_cache=cache([0, t, 0], [0, 1, 0]))
+            got.append(out[1])
+        got = jnp.concatenate(got)
+        # the idle rows' slots are as they were allocated
+        assert not np.asarray(new.pool["ssm_state"])[[0, 2]].any()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
                                rtol=0)
     assert np.abs(np.asarray(got) - np.asarray(wrong)).max() > 1e-2
@@ -639,13 +671,36 @@ def test_every_square_of_the_new_row_is_refused_by_name(family, what):
         assert str(raised.value) == said
 
 
+@pytest.mark.parametrize("step_kernel,moved", [("xla", 6 * 5),
+                                               ("pallas", 6 * 2)])
+def test_the_rows_a_step_moves_against_a_hand_count(family, step_kernel,
+                                                    moved):
+    """``ssm_rows_moved``: the rows x state-space layers whose state a
+    decode launch's program reads and writes.  Four slots of which two
+    decode: the step's kernel moves those two a layer, the XLA step all
+    four and the garbage row; a chunk's launch counts none."""
+    plan = paged_kv.plan(family[0].cfg, BS, 4, 8, CHUNK, "xla", step_kernel)
+
+    class Record:
+        kind = "decode"
+
+    d = Record()
+    plan.account(d, np.array([9, 0, 40, 0]), np.array([1, 0, 1, 0]), 1, 3)
+    assert (d.ssm_rows_live, d.ssm_rows_moved, d.ssm_tokens) == (12, moved, 12)
+    d = Record()
+    d.kind = "prefill"
+    plan.account(d, np.array([32]), np.array([20]), CHUNK, 3)
+    assert (d.ssm_rows_live, d.ssm_tokens) == (6, 120)
+    assert not hasattr(d, "ssm_rows_moved")
+
+
 def test_the_held_experts_touched_against_a_hand_count(family):
     """``moe_experts_touched_held``: of the experts this chip holds
     (the router's 2-5), those with at least one live assignment, summed
     over the expert layers; ``moe_experts_touched`` counts over all
     eight and cannot say it."""
     model = family[0]
-    plan = paged_kv.plan(model.cfg, BS, 2, 8, CHUNK, "xla")
+    plan = paged_kv.plan(model.cfg, BS, 2, 8, CHUNK, "xla", "xla")
 
     class Record:
         pass
@@ -675,5 +730,5 @@ def test_the_held_experts_touched_against_a_hand_count(family):
     whole = nemotron_h_config("tiny", use_flash_attn=False, num_experts=8,
                               moe_router_experts=None)
     d = Record()
-    paged_kv.plan(whole, BS, 2, 8, CHUNK, "xla").account_routing(d, counts)
+    paged_kv.plan(whole, BS, 2, 8, CHUNK, "xla", "xla").account_routing(d, counts)
     assert d.moe_experts_touched_held == d.moe_experts_touched == 11

@@ -117,6 +117,21 @@ def _experts(rows, experts, hidden, ffn, layers=8):
                 ((groups, ffn, hidden), BF16), ((groups,), jnp.int32)]
 
 
+def _state_step(rows, heads, groups, d_head=64, d_state=128):
+    """A decode step's state-space recurrence in place over a pool of
+    ``rows`` slots and the garbage row, at the two served widths
+    (Nemotron-3-Nano's 64 heads in eight groups: a row one block of 2
+    MiB; Granite-4.0-H-Small's 128 in one: two)."""
+    from megatron_llm_tpu.ops.pallas.ssm_step import ssm_state_step
+
+    f32 = jnp.float32
+    return ssm_state_step, [
+        ((rows + 1, heads, d_head, d_state), f32), ((rows, heads), f32),
+        ((rows, heads, d_head), f32), ((rows, groups, d_state), f32),
+        ((rows, groups, d_state), f32), ((rows,), jnp.bool_),
+        ((rows,), jnp.bool_)]
+
+
 def _selected(q_tokens, slots, tokens=33792, page=16):
     """Keye's sparse attention through the paged pool (scores, choice,
     attention under it: ``ops/pallas/dsa_attention.py``) at the cell's
@@ -200,6 +215,8 @@ CASES = {
     "moe_experts_mellum_32_rows": lambda: _experts(32 * 8, 64, 2304, 896),
     "moe_experts_mellum_chunk_512_rows":
         lambda: _experts(512 * 8, 64, 2304, 896),
+    "ssm_state_step_nemotron_64_rows": lambda: _state_step(64, 64, 8),
+    "ssm_state_step_granite_24_rows": lambda: _state_step(24, 128, 1),
     "dsa_selected_decode_8_slots": lambda: _selected(1, 8),
     "dsa_selected_prefill_chunk_512": lambda: _selected(512, 1),
     "moe_experts_keye_8_rows": lambda: _experts(8 * 8, 128, 2048, 768,
@@ -384,7 +401,13 @@ def _nemotron_programs(chip):
         num_slots=64, num_blocks=24577, max_model_len=6144))
 
 
+# rows of a compiled program that move or compute nothing
+_NO_WORK = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+            "custom-call")
+
+
 def _cell_programs(chip, build, engine):
+    from megatron_llm_tpu import hlo_collectives
     from megatron_llm_tpu.ops import paged_kv
     from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
 
@@ -394,6 +417,9 @@ def _cell_programs(chip, build, engine):
         eng = InferenceEngine(model, params, EngineConfig(
             block_size=16, prefill_chunk=512, preemption=False,
             paged_kernel="on", prefill_kernel="on", **engine))
+        # the recurrent state's shapes (the columns' have three dimensions)
+        state = {(hlo_collectives._HLO_DTYPE[d], sh) for d, sh in
+                 paged_kv.state_shapes(eng._st.pages) if len(sh) == 4}
         found = {"state_bytes_per_slot": paged_kv.state_bytes_per_slot(
             eng._st.pages), "pool_bytes": eng.kv_pool_bytes,
             "parameters": sum(a.size for a in
@@ -407,14 +433,21 @@ def _cell_programs(chip, build, engine):
                     np.shape(a), a.dtype, sharding=chip), args)
             comp = eng._jitted[name].lower(*args).compile()
             m, text = comp.memory_analysis(), comp.as_text()
+            # what else than the step's kernel writes an array of the
+            # recurrent state's shape (every slot's, or every row's)
+            rewrites = sorted(
+                f"{r['scope']} {r['root']}" for r in
+                hlo_collectives.instructions(text)
+                if r["opcode"] not in _NO_WORK and state & set(r["shapes"]))
             found[name] = {
                 "argument_bytes": m.argument_size_in_bytes,
                 "output_bytes": m.output_size_in_bytes,
                 "alias_bytes": m.alias_size_in_bytes,
                 "temp_bytes": m.temp_size_in_bytes,
+                "state_rewrites": rewrites,
                 "kernels": sorted(set(re.findall(
-                    r"(paged_attention_\w+?|moe_experts\w*?)(?:\.\d+)? = ",
-                    text))),
+                    r"(paged_attention_\w+?|moe_experts\w*?|ssm_state_step)"
+                    r"(?:\.\d+)? = ", text))),
                 "scopes": sorted({s for s in (
                     "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
                     "ssm_gate_norm", "ssm_out_proj") if f"/{s}/" in text})}
@@ -544,6 +577,20 @@ def test_the_granite_cells_programs_compile_and_fit_a_v5e(granite_compiled):
         assert held - got["alias_bytes"] < 14.5e9, (name, held)
         assert recurrence in got["scopes"] and "ssm_in_proj" in got["scopes"]
         assert any(k.startswith("paged_attention") for k in got["kernels"])
+        _the_step_is_the_kernel(name, got)
+
+
+def _the_step_is_the_kernel(name, got):
+    """The decode step advances the recurrent state by the in-place
+    kernel and by nothing else: no pad, maximum, select or copy of an
+    array of the state's shape is left in it.  A chunk has no such
+    kernel (its scan and its write of the state are XLA's)."""
+    if name == "engine_decode":
+        assert "ssm_state_step" in got["kernels"], got["kernels"]
+        assert got["state_rewrites"] == [], got["state_rewrites"]
+    else:
+        assert "ssm_state_step" not in got["kernels"], got["kernels"]
+        assert got["state_rewrites"], name
 
 
 @pytest.mark.time_limit(900)
@@ -580,6 +627,7 @@ def test_the_nemotron_cells_programs_compile_and_fit_a_v5e(nemotron_compiled):
         assert recurrence in got["scopes"] and "ssm_in_proj" in got["scopes"]
         assert "moe_experts" in got["kernels"], got["kernels"]
         assert any(k.startswith("paged_attention") for k in got["kernels"])
+        _the_step_is_the_kernel(name, got)
 
 
 if __name__ == "__main__":
