@@ -11,7 +11,8 @@ or the other by its ``train`` argument and nothing else (no flag):
   assignments are sorted by expert, the expert matrices run as grouped
   matrix multiplications over the sorted rows (the Pallas kernel of
   ``ops/pallas/grouped_matmul.py`` on a TPU, ``jax.lax.ragged_dot``
-  elsewhere) and the results are gathered back and weighted by the gates.  No
+  elsewhere) and the results are gathered back, once and in their own
+  dtype, and summed under the gates in fp32 (scope ``moe_combine``).  No
   token is dropped at any routing; a token that is not live (a slot
   that is not decoding, the padding of a short chunk: ``live`` false) is
   sent to no expert; the groups of experts nobody chose are empty.  The
@@ -405,12 +406,27 @@ def moe_mlp_dropless(x: jax.Array, params, cfg: TransformerConfig,
         y = _grouped_matmul(mid, w_out, sizes)                 # [T*k, h]
 
     with jax.named_scope("moe_combine"):
+        # each row of y moves ONCE, in y's own dtype, to where its token's
+        # j-th choice lies (choice-major: row j * T + t), and the k
+        # choices are added in turn, cast and gated inside the one fused
+        # sum: nothing in float32 has T * k rows.  A [T, k, h] array
+        # would be padded to 16 choices on the chip wherever k is not 8
         slot = jnp.arange(T * k, dtype=order.dtype)
-        routed = slot < jnp.sum(counts)
-        y = jnp.where(routed[:, None], y.astype(jnp.float32), 0.0)
-        back = jnp.zeros_like(order).at[order].set(slot)       # inverse
-        y = y[back].reshape(T, k, h)
-        out = jnp.einsum("tkh,tk->th", y, gates)
+        # the inverse of the sort: where assignment t * k + j went
+        there = (order % k) * T + order // k
+        back = jnp.zeros_like(order).at[there].set(slot)       # [k*T]
+        y = y[back]                                            # [k*T, h]
+
+        def choice(j):
+            gate = gates[:, j, None]
+            term = y[j * T:(j + 1) * T].astype(jnp.float32) * gate
+            # a choice sent nowhere has gate 0 (moe_route) and its row,
+            # past sum(counts), came back undefined (_grouped_matmul)
+            return jnp.where(gate != 0.0, term, 0.0)
+
+        out = choice(0)
+        for j in range(1, k):
+            out = out + choice(j)
 
     out = out.reshape(b, s, h)
     shared = _shared_mlp(x, params, cfg)

@@ -10,3 +10,12 @@ def sorts_and_their_guards(hlo_text):
     ``conditional``'s branch."""
     return ["conditional" in row["under"] for row in instructions(hlo_text)
             if row["opcode"] == "sort"]
+
+
+def lowered_text(lowered):
+    """The text of a LOWERED program (``jax.jit(f).lower(...)``: the
+    source's own operations, nothing fused yet) in the form
+    ``instructions`` reads; ``lowered.as_text(dialect="hlo")`` prints a
+    computation's header without its signature, which it does not."""
+    return lowered.compiler_ir(dialect="hlo").as_hlo_module().to_string()
+

@@ -518,30 +518,25 @@ def test_the_flags_lower_into_the_config():
 TRACED = {
     "mistral": {"engine_prefill": "97f7c403d8d97874",
                 "engine_decode": "1271bc7d18f7d88f"},
-    "mixtral": {"engine_prefill": "16dc68e888901332",
-                "engine_decode": "a5fbd77f197edc89"},
-    "olmoe": {"engine_prefill": "ec12cf1b1f8b4824",
-              "engine_decode": "cd0e4834fc1d6c4a"},
-    "keye": {"engine_prefill": "8eafd00d20ed2ba5",
-             "engine_decode": "0b2ddbd39a7462a8"},
-    "mellum": {"engine_prefill": "3b7515947a69f378",
-               "engine_decode": "0168b534430f68e2"},
-    "kanana": {"engine_prefill": "7bc4fba0abd4ca5a",
-               "engine_decode": "d819cd2b833bd7c6"},
-    # PR 45 MEANT the decode step of the two families with state-space
-    # layers and nothing else (the fourteen other hashes are PR 44's):
-    # the step's recurrence and its write of ``ssm_state`` are the
-    # cache's (``PagedKVCache.step_state``: here, on the CPU, the XLA
-    # path's same operations, traced inside the mixer's ``ssm_step``
-    # scope and not after its output projection; on one chip the
-    # in-place kernel ``ssm_state_step``)
-    "granite": {"engine_prefill": "2796d5c8ed6aa848",
-                "engine_decode": "3241e7dd0820c5bb"},
-    # PR 44's family, recorded on its own tree: the fourteen above it are
-    # unchanged by it (layers of one sublayer, the gated norm by group
-    # and the laid-out width touch no older family's program)
-    "nemotron_h": {"engine_prefill": "65599463356e0eee",
-                   "engine_decode": "9c7475441b611670"},
+    # PR 46 MEANT both programs of the seven sparse families and nothing
+    # else (the dense family's are PR 44's): the dropless layer's combine
+    # gathers the experts' rows once, in their own dtype, and adds the
+    # choices in turn under the gates (``models/moe.py``, scope
+    # ``moe_combine``)
+    "mixtral": {"engine_prefill": "0c7d499804bf34eb",
+                "engine_decode": "d108ab43dbc7ce8b"},
+    "olmoe": {"engine_prefill": "8cd66b78f8c91346",
+              "engine_decode": "971de090d62b6706"},
+    "keye": {"engine_prefill": "e0d8c3ceab2845ae",
+             "engine_decode": "3c0e2663546ddcad"},
+    "mellum": {"engine_prefill": "8f4a67e3658e0723",
+               "engine_decode": "1ac80699ca25a3e1"},
+    "kanana": {"engine_prefill": "d425b4f959dbedb1",
+               "engine_decode": "9dbbd4c35a3d9c06"},
+    "granite": {"engine_prefill": "b0d8abbf1f607c6b",
+                "engine_decode": "76573d9f960581e5"},
+    "nemotron_h": {"engine_prefill": "30c8c54ee38f7897",
+                   "engine_decode": "b085bbfea1bc2bd2"},
 }
 
 

@@ -3,13 +3,14 @@ model/trainer integration (TPU-native extension — the reference has no MoE,
 SURVEY §2.2 "expert parallel: absent")."""
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from megatron_llm_tpu.config import TransformerConfig
+from megatron_llm_tpu.config import DTYPES, TransformerConfig
 from megatron_llm_tpu.models.moe import (
     init_moe_mlp_params,
     moe_capacity,
@@ -495,3 +496,125 @@ def test_a_share_keeps_the_gates_of_all_the_choices_and_refuses_training():
         moe_mlp(x, p01, c01)
     with pytest.raises(ValueError, match="must lie among"):
         cfg.replace(moe_router_experts=4, moe_experts_first=3)
+
+
+# ---------------------------------------------------------------------------
+# the combine: the experts' rows back to their tokens, summed under the gates
+# ---------------------------------------------------------------------------
+
+def _combine_case(k, some_dead, share, shared, dtype, monkeypatch):
+    """A dropless layer run eagerly with ``_grouped_matmul`` patched to
+    fill the rows past ``sum(group_sizes)`` with NaN and to keep what it
+    returned -> (out [T, h] fp32, the plain reference [T, h] fp32, the
+    tokens that must come out as the shared MLP alone)."""
+    from megatron_llm_tpu.models import moe
+
+    cfg = _cfg(num_experts=8, moe_top_k=k, compute_dtype=dtype,
+               params_dtype=dtype, moe_shared_experts=2 if shared else 0)
+    p = init_moe_mlp_params(jax.random.PRNGKey(0), cfg, DTYPES[dtype])
+    p = jax.tree_util.tree_map(lambda w: w * 6.0, p)
+    if share:
+        cfg, p = _share(cfg, p, 2, 3)       # experts 2, 3, 4 of the eight
+    b, s = 2, 12
+    T = b * s
+    x = jax.random.normal(jax.random.PRNGKey(1), (b, s, 32))
+    live = (jnp.arange(s)[None, :] < jnp.asarray([s, 7])[:, None]
+            if some_dead else None)
+
+    returned = []
+    real = moe._grouped_matmul
+
+    def poisoned(rows, weights, group_sizes):
+        y = real(rows, weights, group_sizes)
+        past = jnp.arange(rows.shape[0]) >= jnp.sum(group_sizes)
+        returned.append(jnp.where(past[:, None], jnp.nan, y))
+        return returned[-1]
+
+    monkeypatch.setattr(moe, "_grouped_matmul", poisoned)
+    out, _, _ = moe.moe_mlp_dropless(x, p, cfg, live)
+    monkeypatch.undo()
+    y = np.asarray(returned[1].astype(jnp.float32))         # [T*k, h] sorted
+    assert y.shape == (T * k, 32) and returned[1].dtype == DTYPES[dtype]
+
+    # the routing, by hand: who is live, whose expert is held, the order
+    _, _, gates, idx = moe._route(x.reshape(T, 32), p, cfg)
+    gates, idx = np.asarray(gates), np.asarray(idx)
+    alive = (np.ones(T, bool) if live is None
+             else np.asarray(live).reshape(T))
+    first = cfg.moe_experts_first if share else 0
+    held = alive[:, None] & (idx >= first) & (idx < first + cfg.num_experts)
+    key = np.where(held, idx - first, cfg.num_experts + 1).reshape(T * k)
+    where = np.empty(T * k, int)
+    where[np.argsort(key, kind="stable")] = np.arange(T * k)
+    assert np.isnan(y[held.sum():]).all() and np.isfinite(
+        y[:held.sum()]).all()
+    want = np.zeros((T, 32), np.float32)
+    for j in range(k):                      # a choice at a time, in fp32
+        for t in range(T):
+            if held[t, j]:
+                want[t] += gates[t, j] * y[where[t * k + j]]
+    assert gates.dtype == np.float32 and want.dtype == np.float32
+    alone = ~held.any(axis=1)
+    if shared:
+        want += np.asarray(dense_mlp(x, p["shared"], cfg).astype(
+            jnp.float32)).reshape(T, 32)
+    assert out.dtype == jnp.float32
+    return np.asarray(out).reshape(T, 32), want, alone
+
+
+@pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+@pytest.mark.parametrize("shared", [False, True])
+@pytest.mark.parametrize("share", [False, True])
+@pytest.mark.parametrize("some_dead", [False, True])
+@pytest.mark.parametrize("k", [1, 2, 3, 6])
+def test_the_combine_is_the_gated_sum_of_the_rows_the_experts_returned(
+        k, some_dead, share, shared, dtype, monkeypatch):
+    """THE COMBINE TEST: the routed sum is ``sum_j gates[t, j] *
+    float32(y_of(t, j))`` over the rows the second grouped matmul
+    returned, in ITS dtype, to fp32 rounding (only the order of a sum of
+    ``k`` fp32 terms is the program's own); a dead token and a token none
+    of whose experts is held come out as the shared MLP alone, EXACT
+    zeros without one, whatever lies in the rows no group owns."""
+    out, want, alone = _combine_case(k, some_dead, share, shared, dtype,
+                                     monkeypatch)
+    assert np.isfinite(out).all()
+    scale = np.abs(want).max()
+    assert scale > 0.05
+    np.testing.assert_allclose(out, want, rtol=0, atol=4e-7 * k * scale)
+    if some_dead or (share and k <= 2):
+        assert alone.any()
+    if not shared:
+        assert (out[alone] == 0.0).all()
+    else:
+        assert (out[alone] == want[alone]).all()
+
+
+def test_nothing_in_float32_has_a_row_an_assignment_under_moe_combine():
+    """THE MECHANISM: in the lowered program of a bf16 layer no fp32 array
+    under the scope ``moe_combine`` has ``T * k`` rows or the shape ``[T,
+    k, h]`` in either order (the largest there is ``[T, h]``, the sum),
+    and the rows move once, as ``[k * T, h]`` in the experts' dtype."""
+    from _hlo_text import lowered_text
+    from megatron_llm_tpu.hlo_collectives import instructions
+    from megatron_llm_tpu.models.moe import moe_mlp_dropless
+
+    cfg = _cfg(num_experts=8, moe_top_k=3, compute_dtype="bf16",
+               params_dtype="bf16", moe_shared_experts=1)
+    p = init_moe_mlp_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    b, s, h, k = 2, 20, 32, 3
+    T = b * s
+    x = jnp.zeros((b, s, h), jnp.bfloat16)
+    live = jnp.ones((b, s), bool)
+    rows = [r for r in instructions(lowered_text(jax.jit(
+        lambda q: moe_mlp_dropless(x, q, cfg, live)).lower(p)))
+            if r["scope"] == "moe_combine"]
+    assert len(rows) > 3 * k
+    fp32 = [r for r in rows if r["dtype"] == "f32"]
+    assert max(math.prod(r["shape"]) for r in fp32) == T * h
+    assert any(r["shape"] == (T, h) for r in fp32)
+    for r in fp32:
+        assert T * k not in r["shape"], r
+        assert not {T, k, h} <= set(r["shape"]), r
+    moved = [r for r in rows if r["shape"] == (k * T, h)]
+    assert [r["opcode"] for r in moved] == ["gather"]
+    assert moved[0]["dtype"] == "bf16"
