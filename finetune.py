@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,nemotron_h,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -110,6 +110,22 @@ MODEL_DEFAULTS = {
                    qk_nope_head_dim=128, qk_rope_head_dim=64,
                    v_head_dim=128, rope_theta=1e6, layernorm_epsilon=1e-6,
                    hidden_dropout=0.0, attention_dropout=0.0),
+    # Trinity-Mini (model_type afmoe): a gate on the attention output,
+    # four norms a layer, window layers that rotate beside full layers
+    # that carry no positions, two leading dense layers inside the typed
+    # stack, Kanana's router form with one shared expert
+    "trinity": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                    use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                    num_experts=128, moe_top_k=8, norm_topk_prob=1,
+                    moe_score_function="sigmoid", moe_choice_bias=1,
+                    moe_routed_scale=2.826, moe_shared_experts=1,
+                    moe_first_dense_layers=2, qk_norm_per_head=True,
+                    attention_output_gate=True, sublayer_output_norm=True,
+                    kv_channels=128, rope_theta=10000.0,
+                    layernorm_epsilon=1e-5, sliding_window_size=2048,
+                    layer_types=["sliding", "sliding", "sliding", "full"],
+                    rope_layer_types=["sliding"],
+                    hidden_dropout=0.0, attention_dropout=0.0),
     # granite-4.0-h-small (model_type granitemoehybrid): nine Mamba-2
     # mixers to each attention layer with no position embedding, 72
     # experts with a shared MLP, four multipliers, a tied head
@@ -180,10 +196,11 @@ def extra_args(parser):
 
 
 def model_provider(args):
-    if args.model_name == "gemma" and \
+    if args.model_name in ("gemma", "trinity") and \
             getattr(args, "embedding_multiplier", None) is None:
-        # gemma's sqrt(hidden) embedding normalizer depends on the
-        # parsed hidden size, so the static preset table can't carry it
+        # gemma's sqrt(hidden) embedding normalizer (and afmoe's
+        # mup_enabled) depends on the parsed hidden size, so the static
+        # preset table can't carry it
         import math
 
         args.embedding_multiplier = math.sqrt(args.hidden_size)
@@ -371,6 +388,11 @@ _CKPT_ARG_MAP = {
     "layer_types": "layer_types",
     "rope_yarn_scaling": "rope_yarn_scaling",
     "rope_yarn_layer_types": "rope_yarn_layer_types",
+    "rope_layer_types": "rope_layer_types",
+    # trinity's gate widens the fused QKV kernel, its output norms add
+    # two leaves a layer
+    "attention_output_gate": "attention_output_gate",
+    "sublayer_output_norm": "sublayer_output_norm",
     # qwen2's QKV-only bias changes the param tree like the MoE fields do
     "add_qkv_bias": "add_qkv_bias",
     # gemma's embedding normalizer changes forward math, not the tree

@@ -153,6 +153,16 @@ def _add_network_size_args(parser):
     g.add_argument("--rope_yarn_layer_types", type=str, nargs="+",
                    default=None,
                    help="the --layer_types YaRN applies to (default: all)")
+    g.add_argument("--rope_layer_types", type=str, nargs="+", default=None,
+                   help="the --layer_types that rotate (default: all); a "
+                        "layer of another type carries no positions")
+    g.add_argument("--attention_output_gate", action="store_true",
+                   help="the attention's output times sigmoid(gate(u)) "
+                        "before its output projection, gate a fourth "
+                        "projection of the layer's normed input (afmoe)")
+    g.add_argument("--sublayer_output_norm", action="store_true",
+                   help="four norms a layer: each sublayer's output is "
+                        "normed as well as its input, x + norm(f(norm(x)))")
     g.add_argument("--layernorm_epsilon", type=float, default=1e-5)
     g.add_argument("--use_rms_norm", action="store_true")
     g.add_argument("--use_post_ln", action="store_true")
@@ -1038,6 +1048,13 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         rope_yarn_layer_types=(tuple(args.rope_yarn_layer_types)
                                if getattr(args, "rope_yarn_layer_types", None)
                                else None),
+        rope_layer_types=(tuple(args.rope_layer_types)
+                          if getattr(args, "rope_layer_types", None)
+                          else None),
+        attention_output_gate=bool(
+            getattr(args, "attention_output_gate", False)),
+        sublayer_output_norm=bool(
+            getattr(args, "sublayer_output_norm", False)),
         layer_types=(
             tuple(args.layer_types) if getattr(args, "layer_types", None)
             else pattern_layer_types(args.hybrid_override_pattern)

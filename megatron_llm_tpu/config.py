@@ -131,7 +131,8 @@ def pattern_layer_types(pattern: str) -> Tuple[str, ...]:
 # one on asks ``refusal`` with it: ``serving/engine.py`` (its
 # EngineConfig's), ``ops/paged_kv.py::init_pools`` (the int8 pool),
 # ``models/gpt.py`` (the parallelism the mesh has in force),
-# ``models/transformer.py::transformer_stack`` (training).
+# ``models/transformer.py::transformer_stack`` (training),
+# ``text_generation/generation.py::init_kv_caches`` (the rolling cache).
 VERIFY_STEP = "the speculative verify step"
 INT8_POOL = "the int8 KV pool"
 HOST_TIER = "the host KV tier"
@@ -140,8 +141,9 @@ PREFIX_CACHE = "the prefix cache"
 TENSOR_PARALLEL = "tensor parallelism (tp > 1)"
 MODEL_PARALLEL = "tensor or pipeline parallelism (tp > 1, pp > 1)"
 TRAINING = "training"
+ROLLING_CACHE = "the legacy rolling decode cache"
 FEATURES = (VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION, PREFIX_CACHE,
-            TENSOR_PARALLEL, MODEL_PARALLEL, TRAINING)
+            TENSOR_PARALLEL, MODEL_PARALLEL, TRAINING, ROLLING_CACHE)
 # the features a model that does not run with them is not refused but
 # runs WITHOUT: whoever serves it turns the feature off and says so
 TURNED_OFF = (PREFIX_CACHE,)
@@ -166,6 +168,9 @@ BIASES = "linear biases (add_bias_linear)"
 QKV_BIAS = "a bias on the QKV projections (add_qkv_bias)"
 PARALLEL_ATTN = "parallel_attn"
 POST_LN = "post-LN (use_post_ln)"
+GATE = "an attention output gate (attention_output_gate)"
+OUTPUT_NORMS = "norms on both sublayers' outputs (sublayer_output_norm)"
+ROPE_TYPES = "layer types that do not rotate (rope_layer_types)"
 OTHER_TYPES = "layer types other than 'mamba', 'attention' and 'moe'"
 HAS = {
     SPARSE: lambda c: c.dsa_index_heads > 0,
@@ -189,6 +194,9 @@ HAS = {
     QKV_BIAS: lambda c: c.add_qkv_bias,
     PARALLEL_ATTN: lambda c: c.parallel_attn,
     POST_LN: lambda c: c.use_post_ln,
+    GATE: lambda c: c.attention_output_gate,
+    OUTPUT_NORMS: lambda c: c.sublayer_output_norm,
+    ROPE_TYPES: lambda c: c.rope_layer_types is not None,
     OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
                                 - {"mamba", "attention", "moe"}),
 }
@@ -205,9 +213,14 @@ RUNS_WITH = (
     (STATE_SPACE, (OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
                    VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
                    MODEL_PARALLEL)),
+    (GATE, (LATENT, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL,
+            HOST_TIER)),
+    (OUTPUT_NORMS, (ONE_SUBLAYER, PARALLEL_ATTN, POST_LN, TRAINING,
+                    MODEL_PARALLEL, VERIFY_STEP, INT8_POOL, HOST_TIER)),
+    (ROPE_TYPES, (NOT_ROTARY, TRAINING)),
     (TYPED, (SPARSE, SECTIONED, VERIFY_STEP, INT8_POOL, HOST_TIER,
-             PREFIX_CACHE, MODEL_PARALLEL)),
-    (FIRST_DENSE, (TYPED, MODEL_PARALLEL)),
+             PREFIX_CACHE, MODEL_PARALLEL, ROLLING_CACHE)),
+    (FIRST_DENSE, (STATE_SPACE, ONE_SUBLAYER, MODEL_PARALLEL)),
     (LATENT, (NOT_ROTARY, SLIDING, TYPED, SPARSE, QK_NORM_WHOLE,
               QK_NORM_PER_HEAD, SECTIONED, ROPE_SCALING, BIASES, QKV_BIAS,
               PARALLEL_ATTN, VERIFY_STEP, INT8_POOL, HOST_TIER,
@@ -234,9 +247,32 @@ TAILS = {
     (STATE_SPACE, PREEMPTION):
         " (no snapshot of a request's state is kept): set preemption off "
         "(--serve_preemption=0)",
+    (GATE, LATENT):
+        " (latent_attention has no fourth projection and no product)",
+    (GATE, TRAINING): " (no backward through the gate is held to anything)",
+    (OUTPUT_NORMS, ONE_SUBLAYER):
+        " (a layer of one sublayer has one norm, on its input)",
+    (OUTPUT_NORMS, PARALLEL_ATTN):
+        " (one residual add of both sublayers: which output would be "
+        "normed?)",
+    (OUTPUT_NORMS, POST_LN): " (use_post_ln norms the STREAM after the add)",
+    (OUTPUT_NORMS, TRAINING):
+        " (no backward through the output norms is held to anything)",
+    (ROPE_TYPES, NOT_ROTARY): " (nothing rotates there, on any layer)",
+    (ROPE_TYPES, TRAINING):
+        " (no backward through a stack that rotates on some layers only "
+        "is held to anything)",
+    (FIRST_DENSE, STATE_SPACE):
+        " (the mixers are stacked by kind over ALL layers, the dense MLPs "
+        "apart from the sparse ones: two ways of counting a layer)",
+    (FIRST_DENSE, ONE_SUBLAYER):
+        " (a layer of one sublayer has no MLP to keep dense)",
     (TYPED, PREFIX_CACHE):
         " adopts nothing (a prefix's window pages, and a state-space "
         "layer's state at its end, are not kept)",
+    (TYPED, ROLLING_CACHE):
+        " (a ring of window slots on EVERY layer: a layer that sees every "
+        "key has none)",
     (QK_NORM_WHOLE, QK_NORM_PER_HEAD): " (two forms of one norm: choose one)",
     (QK_NORM_WHOLE, TENSOR_PARALLEL):
         " (its mean square is over all the heads, which tensor parallelism "
@@ -300,6 +336,11 @@ class TransformerConfig:
     # plain embedding
     rope_yarn_scaling: Optional[Tuple[float, int, float, float, float]] = None
     rope_yarn_layer_types: Optional[Tuple[str, ...]] = None
+    # the layer types whose queries and keys rotate (None: every type);
+    # a layer of another type carries NO positions: what orders its keys
+    # is the causal mask alone (afmoe: the 'sliding' layers rotate, the
+    # 'full' ones do not)
+    rope_layer_types: Optional[Tuple[str, ...]] = None
     # reference: --no_tie_embed_logits -> untied lm_head
     # (megatron/model/language_model.py:436-457)
     tie_embed_logits: bool = True
@@ -340,6 +381,17 @@ class TransformerConfig:
     # 'moe' among them a layer is ONE sublayer, ``x + f(norm(x))``: a
     # 'mamba' or 'attention' layer has no MLP, a 'moe' layer no mixer
     layer_types: Optional[Tuple[str, ...]] = None
+    # the attention's output (before its output projection) times
+    # ``sigmoid(gate(u))``, u the layer's normed input: a fourth
+    # projection ``[hidden, heads x head_dim]`` (afmoe's ``gate_proj``),
+    # fused into ``query_key_value`` a key-value group at a time
+    attention_output_gate: bool = False
+    # FOUR norms a layer: each sublayer's OUTPUT is normed as well as its
+    # input, ``x + norm(f(norm(x)))`` for attention and for the MLP (a
+    # "sandwich").  The two further leaves are ``attention_output_norm``
+    # and ``mlp_output_norm``; ``post_attention_norm`` stays what it is
+    # everywhere, the norm BEFORE the MLP
+    sublayer_output_norm: bool = False
 
     # --- dropout / init ---
     hidden_dropout: float = 0.1
@@ -422,9 +474,10 @@ class TransformerConfig:
     moe_choice_bias_std: Optional[float] = None
     # the chosen gates (after ``norm_topk_prob``) times this
     moe_routed_scale: float = 1.0
-    # shared experts: ONE ungated MLP of ``moe_shared_experts`` times an
-    # expert's width that every token passes through, added to the routed
-    # sum.  0: none
+    # shared experts: ONE MLP of ``moe_shared_experts`` times an expert's
+    # width (gated where the experts are) that every token passes
+    # through, not weighted by the router, added to the routed sum.
+    # 0: none
     moe_shared_experts: int = 0
     # the first layers of a sparse model that keep a dense MLP of
     # ``ffn_hidden_size`` (``first_k_dense_replace``); their parameters
@@ -507,8 +560,9 @@ class TransformerConfig:
     # QKV-projection-only bias (Qwen2-style: attention in-projections
     # carry biases while every other linear is bias-free)
     add_qkv_bias: bool = False
-    # scale the word-embedding output by this factor (Gemma multiplies by
-    # sqrt(hidden_size); the tied LM head uses the UNSCALED table)
+    # scale the word-embedding output by this factor (Gemma and afmoe's
+    # ``mup_enabled`` multiply by sqrt(hidden_size), Granite by a
+    # published number; a tied LM head uses the UNSCALED table)
     embedding_multiplier: Optional[float] = None
     # fraction of each head's dims that rotate (GPT-NeoX/Pythia
     # rotary_pct; 1.0 = full rotary)
@@ -594,6 +648,13 @@ class TransformerConfig:
             if self.layer_types is None or (
                     set(self.rope_yarn_layer_types) - set(self.layer_types)):
                 raise ValueError("rope_yarn_layer_types names types of "
+                                 "layer_types")
+        if self.rope_layer_types is not None:
+            object.__setattr__(self, "rope_layer_types", tuple(
+                str(t) for t in self.rope_layer_types))
+            if self.layer_types is None or (
+                    set(self.rope_layer_types) - set(self.layer_types)):
+                raise ValueError("rope_layer_types names types of "
                                  "layer_types")
         if self.rope_sections is not None:
             object.__setattr__(self, "rope_sections",
@@ -752,6 +813,14 @@ class TransformerConfig:
         return (self.sliding_window_size if layer_type == "sliding" else None,
                 self.rope_yarn_scaling
                 if yarn_on is None or layer_type in yarn_on else None)
+
+    def rotates(self, layer_type: Optional[str]) -> bool:
+        """Whether a layer of ``layer_type`` rotates its queries and keys
+        (``rope_layer_types``; with none named, every type does).  Beside
+        ``attention_of`` and not a third value of it: the benchmark's
+        accepted files compare that method's result with a PAIR."""
+        return (self.rope_layer_types is None
+                or layer_type in self.rope_layer_types)
 
     @property
     def num_query_groups(self) -> int:

@@ -124,6 +124,7 @@ SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
           "moe_combine", "moe_shared", "qk_norm", "dsa_indexer",
           "mla_absorb", "mla_expand", "ssm_in_proj", "ssm_conv", "ssm_scan",
           "ssm_step", "ssm_gate_norm", "ssm_out_proj", "mamba",
+          "attn_gate", "post_attn_norm", "post_mlp_norm",
           "attention", "mlp",
           "embedding", "lm_head", "transformer_layer")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')

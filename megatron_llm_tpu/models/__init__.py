@@ -13,6 +13,7 @@ from megatron_llm_tpu.models.olmoe import OlmoeModel, olmoe_config
 from megatron_llm_tpu.models.keye import KeyeModel, keye_config
 from megatron_llm_tpu.models.mellum import MellumModel, mellum_config
 from megatron_llm_tpu.models.kanana import KananaModel, kanana_config
+from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
 from megatron_llm_tpu.models.qwen2 import Qwen2Model, qwen2_config
 from megatron_llm_tpu.models.gemma import GemmaModel, gemma_config
 from megatron_llm_tpu.models.gpt_neox import GPTNeoXModel, gpt_neox_config
@@ -52,6 +53,7 @@ MODEL_REGISTRY = {
     "keye": KeyeModel,
     "mellum": MellumModel,
     "kanana": KananaModel,
+    "trinity": TrinityModel,
     "granite": _granite,
     "nemotron_h": _nemotron_h,
     "qwen2": Qwen2Model,

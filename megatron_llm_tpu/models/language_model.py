@@ -153,8 +153,9 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
         layer_specs["post_attention_norm"] = _norm_spec(
             layers["post_attention_norm"], stacked
         )
-    if "mlp_norm" in layers:
-        layer_specs["mlp_norm"] = _norm_spec(layers["mlp_norm"], stacked)
+    for name in ("mlp_norm", "attention_output_norm", "mlp_output_norm"):
+        if name in layers:
+            layer_specs[name] = _norm_spec(layers[name], stacked)
     if "inter_attention" in layers:
         ia = layers["inter_attention"]
         layer_specs["inter_attention"] = {

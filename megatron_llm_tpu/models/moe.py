@@ -44,9 +44,10 @@ Shared by both:
   are chosen over score + a bias an expert (a buffer of the param tree,
   ``router.choice_bias``) while the gates stay the scores;
   ``moe_routed_scale`` multiplies the (renormalised) gates.
-* **The shared MLP** (``cfg.moe_shared_experts`` > 0): one ungated MLP
-  of that many experts' width that every token passes through, added to
-  the routed sum under the scope ``moe_shared``.
+* **The shared MLP** (``cfg.moe_shared_experts`` > 0): one MLP of that
+  many experts' width (gated under a GLU like any other: built at
+  ``mult * wide``) that every token passes through, NOT WEIGHTED BY THE
+  ROUTER, added to the routed sum under the scope ``moe_shared``.
 * **One chip's share of a layer's experts**
   (``cfg.moe_router_experts`` / ``cfg.moe_experts_first``; inference
   only): the router scores ALL the layer's experts and the layer holds
@@ -273,7 +274,7 @@ def _route(x: jax.Array, params, cfg: TransformerConfig):
 
 def _shared_mlp(x: jax.Array, params, cfg: TransformerConfig):
     """The shared experts' MLP on x [b, s, h] (zeros' stand-in None for a
-    model without one): every token, no gate."""
+    model without one): every token, not weighted by the router."""
     if "shared" not in params:
         return None
     with jax.named_scope("moe_shared"):

@@ -65,9 +65,13 @@ from megatron_llm_tpu.parallel.sharding import constrain
 # ---------------------------------------------------------------------------
 
 def _qkv_out_dim(cfg: TransformerConfig) -> int:
+    """The fused projection's width: a key-value group's query heads, its
+    key head, its value head and, under ``cfg.attention_output_gate``,
+    its query heads' gates."""
     ng = cfg.num_query_groups
     qpg = cfg.num_attention_heads // ng
-    return ng * (qpg + 2) * cfg.head_dim
+    gates = qpg if cfg.attention_output_gate else 0
+    return ng * (qpg + 2 + gates) * cfg.head_dim
 
 
 def init_latent_attention_params(key, cfg: TransformerConfig, dtype):
@@ -237,6 +241,14 @@ def init_layer_params(key, cfg: TransformerConfig, dtype,
         params["post_attention_norm"] = init_norm_params(
             cfg.hidden_size, cfg.normalization, dtype
         )
+    if cfg.sublayer_output_norm:
+        # the norms of each sublayer's OUTPUT, x + norm(f(norm(x))): HF
+        # afmoe calls the first ``post_attention_layernorm`` and the norm
+        # before the MLP ``pre_mlp_layernorm``; here ``post_attention_norm``
+        # is the norm before the MLP, as everywhere in this tree
+        for name in ("attention_output_norm", "mlp_output_norm"):
+            params[name] = init_norm_params(
+                cfg.hidden_size, cfg.normalization, dtype)
     if cfg.parallel_layernorm:
         # Falcon-40B separate LN for the MLP branch (transformer.py:804-845)
         params["mlp_norm"] = init_norm_params(
@@ -303,16 +315,23 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
 def _split_qkv(mixed: jax.Array, cfg: TransformerConfig):
     """mixed: [b, s, ng*(qpg+2)*d] in Megatron grouped layout ->
     q [b, s, nh, d], k [b, s, ng, d], v [b, s, ng, d]
-    (reference: transformer.py:458-465)."""
+    (reference: transformer.py:458-465), and the output gate's
+    pre-activations [b, s, nh, d] (None without
+    ``cfg.attention_output_gate``): a group's last qpg heads of its
+    2 qpg + 2, so the gate rides the ONE read of the normed input that
+    the projection makes, and a group's columns stay together under the
+    'heads' axis."""
     b, s, _ = mixed.shape
     ng = cfg.num_query_groups
     qpg = cfg.num_attention_heads // ng
     d = cfg.head_dim
-    mixed = mixed.reshape(b, s, ng, qpg + 2, d)
+    mixed = mixed.reshape(b, s, ng, -1, d)
     q = mixed[:, :, :, :qpg, :].reshape(b, s, ng * qpg, d)
     k = mixed[:, :, :, qpg, :]
     v = mixed[:, :, :, qpg + 1, :]
-    return q, k, v
+    gate = (mixed[:, :, :, qpg + 2:, :].reshape(b, s, ng * qpg, d)
+            if cfg.attention_output_gate else None)
+    return q, k, v, gate
 
 
 def _projection_rms_norm(x: jax.Array, scale: jax.Array, eps: float):
@@ -553,7 +572,7 @@ def attention(
         sequence_parallel=sequence_parallel,
         compute_dtype=cfg.compute_jnp_dtype,
     )
-    q, k, v = _split_qkv(mixed, cfg)
+    q, k, v, gate = _split_qkv(mixed, cfg)
 
     if cfg.qk_norm:
         with jax.named_scope("qk_norm"):
@@ -571,8 +590,11 @@ def attention(
                          eps=cfg.layernorm_epsilon)
 
     index = None
-    if cfg.position_embedding_type == PositionEmbeddingType.none:
-        pass    # nothing rotates and nothing is added, on any path
+    if (cfg.position_embedding_type == PositionEmbeddingType.none
+            or not cfg.rotates(layer_type)):
+        # nothing rotates and nothing is added, on any path: the model's
+        # every layer, or the layers of this type (cfg.rope_layer_types)
+        pass
     elif (cfg.rope_sections is not None or cfg.dsa_index_heads > 0
             or cfg.layer_types is not None):
         # positions are taken as given, with no table: [b, s], or
@@ -805,6 +827,13 @@ def attention(
                                  train, window, scale)
 
     b, s = ctx.shape[:2]
+    if gate is not None:
+        # every path above ends here: the paged cache's, the legacy
+        # caches', flash, ring, chunked and the plain one
+        with jax.named_scope("attn_gate"):
+            ctx = ctx.reshape(b, s, cfg.num_attention_heads, cfg.head_dim)
+            ctx = ctx * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(ctx.dtype)
     ctx = ctx.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
     out = row_parallel_linear(
         ctx, params["dense"],
@@ -1020,6 +1049,10 @@ def transformer_layer(
                 attn_out = attention(ln_out, params["attention"], cfg,
                                      **attn_kw)
                 new_cache = None
+    if cfg.sublayer_output_norm:
+        # x + norm(f(norm(x))): the mixer's OUTPUT is normed too
+        with jax.named_scope("post_attn_norm"):
+            attn_out = norm(attn_out, params["attention_output_norm"])
     if cfg.residual_multiplier != 1.0:
         attn_out = attn_out * jnp.asarray(cfg.residual_multiplier,
                                           attn_out.dtype)
@@ -1099,6 +1132,9 @@ def transformer_layer(
             if not cfg.use_post_ln else h
         )
     mlp_out, moe_aux = run_mlp(ln2)
+    if cfg.sublayer_output_norm:
+        with jax.named_scope("post_mlp_norm"):
+            mlp_out = norm(mlp_out, params["mlp_output_norm"])
     if cfg.residual_multiplier != 1.0:
         mlp_out = mlp_out * jnp.asarray(cfg.residual_multiplier,
                                         mlp_out.dtype)
@@ -1147,7 +1183,11 @@ def transformer_stack(
     save-nothing-but-matmul-free recompute of core attention via policy.
 
     A sparse model's leading dense layers (``dense_layers`` of the
-    params) run before the scan, which is over the sparse layers.
+    params) run before the scan, which is over the sparse layers; under
+    ``cfg.layer_types`` each is of the type its index in the WHOLE stack
+    gives it, and the sparse layers that finish the period the dense
+    ones began run before the scan too, which then starts at a period's
+    first layer.
 
     A model with a layer type per layer (``cfg.layer_types``: one period
     of types) scans over PERIODS with a period's layers unrolled in the
@@ -1266,32 +1306,47 @@ def transformer_stack(
         )
         return h, new_caches
 
-    for i in range(D):
-        x, _, _ = transformer_layer(
-            x, jax.tree_util.tree_map(lambda p: p[i], dense), cfg,
+    # the layers before the first period boundary that the scan starts
+    # at, unrolled, each of its own type: the leading dense layers (they
+    # attend like any layer of their type), then the ``head`` sparse
+    # layers that finish the period the dense ones began (afmoe: 2 dense
+    # and 2 sparse, then whole periods of 4)
+    head = (P - D % P) % P
+    aux_head = jnp.zeros((2,), jnp.float32)
+    for i in range(D + head):
+        stacked, at = (dense, i) if i < D else (layers, i - D)
+        x, _, moe_aux = transformer_layer(
+            x, jax.tree_util.tree_map(lambda p: p[at], stacked), cfg,
             rng_key=layer_keys[i] if rng_key is not None else None,
             train=train,
             hidden_dropout=(dropout_rates[i] if dropout_rates is not None
                             else None),
             encoder_output=encoder_output, enc_dec_mask=enc_dec_mask,
-            **layer_kw,
+            layer_type=period[i % P], **layer_kw,
         )
+        if moe_aux is not None:
+            aux_head = aux_head + moe_aux
+    first = D + head
     scanned = (
         (layers, layer_keys[D:], dropout_rates[D:])
         if dropout_rates is not None
         else (layers, layer_keys[D:])
     )
+    if head:
+        scanned = jax.tree_util.tree_map(lambda a: a[head:], scanned)
     if P > 1:
-        # [L, ...] -> [L / P, P, ...]: the scan's step is a period
+        # [L - first, ...] -> [(L - first) / P, P, ...]: the scan's step
+        # is a period
         scanned = jax.tree_util.tree_map(
-            lambda a: a.reshape((L // P, P) + a.shape[1:]), scanned)
+            lambda a: a.reshape(((L - first) // P, P) + a.shape[1:]),
+            scanned)
     if mixers:
         # a kind's [its layers, ...] -> [L / P, its layers a period, ...]
         scanned = (scanned, {
             k: jax.tree_util.tree_map(
                 lambda a: a.reshape((L // P, -1) + a.shape[1:]), m)
             for k, m in mixers.items()})
-    init_carry = (x, jnp.zeros((2,), jnp.float32)) if moe_on else x
+    init_carry = (x, aux_head) if moe_on else x
     carry, _ = jax.lax.scan(body, init_carry, scanned)
     h, moe_aux = carry if moe_on else (carry, None)
     h = apply_norm(

@@ -21,7 +21,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from megatron_llm_tpu.config import TransformerConfig
+from megatron_llm_tpu.config import (ROLLING_CACHE, TransformerConfig,
+                                     refusal)
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.models.transformer import rotary_freqs
 from megatron_llm_tpu.text_generation.sampling import modify_logits, sample
@@ -41,6 +42,9 @@ def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
     ring is written after (models/transformer.py rolling branch)."""
     dtype = dtype or cfg.compute_jnp_dtype
     ng, d = cfg.num_query_groups, cfg.head_dim
+    said = rolling and refusal(cfg, (ROLLING_CACHE,))
+    if said:
+        raise ValueError(said)
     if rolling:
         assert cfg.sliding_window_size is not None, \
             "rolling caches need a sliding-window model"

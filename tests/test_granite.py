@@ -537,6 +537,11 @@ TRACED = {
                 "engine_decode": "76573d9f960581e5"},
     "nemotron_h": {"engine_prefill": "30c8c54ee38f7897",
                    "engine_decode": "b085bbfea1bc2bd2"},
+    # PR 47 brought this family and changed no other's: the gate, the
+    # output norms and the types that rotate are off for every other
+    # model, whose programs are the ones above
+    "trinity": {"engine_prefill": "0c33c9c2556bb82a",
+                "engine_decode": "a30d0d0cca6cdb4d"},
 }
 
 

@@ -4,7 +4,8 @@ one reader (``config.refusal``) gives and to whoever asks it: the
 config's own ``__post_init__`` for a model against itself, the serving
 engine for the features its ``EngineConfig`` turns on,
 ``paged_kv.init_pools`` for the int8 pool, ``GPTModel`` for the
-parallelism in force, ``transformer_stack`` for training.
+parallelism in force, ``transformer_stack`` for training,
+``init_kv_caches`` for the legacy rolling cache.
 """
 
 import copy
@@ -25,6 +26,8 @@ SQUARES = [(has, what) for has, whats in C.RUNS_WITH for what in whats]
 # engine) is given each thing a mechanism does not run with
 FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
           C.ONE_SUBLAYER: "nemotron_h",
+          C.GATE: "trinity", C.OUTPUT_NORMS: "trinity",
+          C.ROPE_TYPES: "trinity",
           C.FIRST_DENSE: "kanana", C.LATENT: "kanana",
           C.QK_NORM_WHOLE: "olmoe", C.EXPERTS: "olmoe", C.SHARE: "granite"}
 GIVEN = {
@@ -45,6 +48,14 @@ GIVEN = {
     C.QK_NORM_WHOLE: dict(qk_norm=True),
     C.QK_NORM_PER_HEAD: dict(qk_norm_per_head=True),
     C.ROPE_SCALING: dict(rope_scaling_factor=2.0),
+    # a period of the depth's parity whose mixers are of two kinds, or
+    # whose layers are one sublayer each; no type is left to rotate
+    C.STATE_SPACE: lambda cfg: dict(
+        layer_types=("mamba", "attention") + ("mamba",) * (
+            cfg.num_layers % 2), rope_layer_types=None),
+    C.ONE_SUBLAYER: lambda cfg: dict(
+        layer_types=("attention", "moe") + ("attention",) * (
+            cfg.num_layers % 2), rope_layer_types=None),
 }
 TURNS_ON = {
     C.VERIFY_STEP: dict(speculative=True, draft_k=2),
@@ -114,6 +125,14 @@ def test_a_square_of_the_table_is_told_by_whoever_asks(has, what,
     elif what in TURNS_ON:
         with pytest.raises(ValueError) as raised:
             _engine(family, **TURNS_ON[what])
+        assert str(raised.value) == said
+    elif what == C.ROLLING_CACHE:
+        # the legacy decode stack asks when it makes the ring
+        from megatron_llm_tpu.text_generation.generation import (
+            init_kv_caches)
+
+        with pytest.raises(ValueError) as raised:
+            init_kv_caches(cfg, 1, 32, rolling=True)
         assert str(raised.value) == said
     elif what == C.TRAINING:
         # the stack asks when it is run to train

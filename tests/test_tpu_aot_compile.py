@@ -266,6 +266,7 @@ SELECTION = "dsa_kernels_by_name"
 ENGINE_TABLES = "engine_tables_tiny_sparse_model"
 GRANITE = "granite_cell_programs"
 NEMOTRON = "nemotron_cell_programs"
+TRINITY = "trinity_cell_programs"
 
 
 def _compile_all(only: str = ""):
@@ -287,7 +288,8 @@ def _compile_all(only: str = ""):
     chip = SingleDeviceSharding(topo.devices[0])
     if only:
         programs = {"granite": (GRANITE, _granite_programs),
-                    "nemotron": (NEMOTRON, _nemotron_programs)}[only]
+                    "nemotron": (NEMOTRON, _nemotron_programs),
+                    "trinity": (TRINITY, _trinity_programs)}[only]
         print(json.dumps({programs[0]: programs[1](chip)}))
         return
     found = {}
@@ -401,6 +403,20 @@ def _nemotron_programs(chip):
         num_slots=64, num_blocks=24577, max_model_len=6144))
 
 
+def _trinity_programs(chip):
+    """The same of the benchmark's Trinity cell: the published model's
+    first 8 layers (two dense, six sparse, the period twice) at the
+    published widths, 64 of 128 experts of 2048 x 1024, the whole
+    vocabulary, 48 slots over a full group of 32,769 pages and a window
+    group of 48 x 161."""
+    from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
+
+    return _cell_programs(chip, lambda: TrinityModel(trinity_config(
+        "mini", num_layers=8, num_experts=64, moe_router_experts=128,
+        params_dtype="bf16", compute_dtype="bf16", seq_length=20992)), dict(
+        num_slots=48, num_blocks=32769, max_model_len=20992))
+
+
 # rows of a compiled program that move or compute nothing
 _NO_WORK = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "custom-call")
@@ -450,7 +466,9 @@ def _cell_programs(chip, build, engine):
                     r"(?:\.\d+)? = ", text))),
                 "scopes": sorted({s for s in (
                     "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
-                    "ssm_gate_norm", "ssm_out_proj") if f"/{s}/" in text})}
+                    "ssm_gate_norm", "ssm_out_proj", "attn_gate",
+                    "post_attn_norm", "post_mlp_norm")
+                    if f"/{s}/" in text})}
         return found
     except Exception as e:      # noqa: BLE001 - the compiler's refusal
         return f"{type(e).__name__}: {e}"[:2000]
@@ -488,6 +506,11 @@ def granite_compiled():
 @pytest.fixture(scope="module")
 def nemotron_compiled():
     return _child("nemotron")
+
+
+@pytest.fixture(scope="module")
+def trinity_compiled():
+    return _child("trinity")
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -628,6 +651,42 @@ def test_the_nemotron_cells_programs_compile_and_fit_a_v5e(nemotron_compiled):
         assert "moe_experts" in got["kernels"], got["kernels"]
         assert any(k.startswith("paged_attention") for k in got["kernels"])
         _the_step_is_the_kernel(name, got)
+
+
+def test_the_trinity_cells_programs_compile_and_fit_a_v5e(trinity_compiled):
+    """The Trinity cell's two programs at its real sizes, for a described
+    v5e: both compile with the experts' grouped matmul at 2048 x 1024,
+    BOTH groups' walks as kernels and the gate's and the output norms'
+    scopes in the text; the step owns its pools and gives them back, the
+    chunk holds them twice, and that fits the chip's 16 GB."""
+    found = trinity_compiled[TRINITY]
+    assert isinstance(found, dict), found
+    assert found["state_bytes_per_slot"] == 0
+    # a full group of 32,769 pages over 2 layers, a window group of
+    # 48 x 161 + 1 over 6, 16 tokens of 2,048 B a page and a layer
+    assert found["pool_bytes"] == (32769 * 2 + (48 * 161 + 1) * 6) * 16 * 2048
+    # ISSUE 47's arithmetic: 2 x 65.0 M + 6 x 436.3 M + 820.0 M
+    assert found["parameters"] == 3_568_898_816
+    tiles = found["moe_expert_tiles"]
+    assert (tiles["w_in"]["k"], tiles["w_in"]["n"]) == (2048, 2048)
+    assert (tiles["w_out"]["k"], tiles["w_out"]["n"]) == (1024, 2048)
+    for name, walk in (("engine_prefill", "paged_attention_prefill"),
+                       ("engine_decode", "paged_attention_decode")):
+        got = found[name]
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"])
+        assert got["output_bytes"] >= found["pool_bytes"], (name, got)
+        if name == "engine_decode":
+            assert got["alias_bytes"] >= found["pool_bytes"], (name, got)
+        else:
+            assert got["alias_bytes"] == 0, (name, got)
+        # 7.14 GB of weights and 3.67 GB of pools, the chunk's twice
+        assert held - got["alias_bytes"] < 14.8e9, (name, held)
+        assert got["temp_bytes"] < 0.5e9, (name, got)
+        assert {"attn_gate", "post_attn_norm", "post_mlp_norm"} <= set(
+            got["scopes"]), got["scopes"]
+        assert {"moe_experts", walk, walk + "_window"} <= set(
+            got["kernels"]), got["kernels"]
 
 
 if __name__ == "__main__":
