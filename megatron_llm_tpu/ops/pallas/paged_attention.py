@@ -29,18 +29,38 @@ Shape contract (the serving engine's paged programs):
 * ``valid_lens`` — ``[S]`` int32: this call's real tokens per slot.  A
   slot with 0 is skipped: no fetch, a row of zeros.
 
-Kernel structure: grid ``(slot, q-block)``; NO grid axis runs over the
-table.  A (slot, q-block) attends pages ``first .. last`` of its row
-(``last`` from the newest query position, ``first`` from the sliding
+Kernel structure: grid ``(slot, q-block)``, sequential; NO grid axis runs
+over the table.  A (slot, q-block) attends pages ``first .. last`` of its
+row (``last`` from the newest query position, ``first`` from the sliding
 window, 0 without one) and loops over them with a dynamic trip count in
 compute blocks of ``kp`` pages (16 pages = 256 keys for bf16 pages of
 ``[16, 8, 128]``, worked out from the shapes so that two blocks of K and
 two of V are 2 MiB of VMEM).  Each page of a block is one
-``make_async_copy`` from the pool into a double buffer, block j+1 in
-flight while block j is computed; the last block fetches only up to
-``last`` and masks the rest by position.  Table entries outside
-``first .. last`` are never read.  fp32 online softmax (m, l, acc in
-VMEM scratch) carries across blocks.
+``make_async_copy`` a pool from the pool into a double buffer, block j+1
+in flight while block j is computed.  A block whose pages are all live
+(every block but a walk's last) is ISSUED from a loop of static trip
+count; only the last, partial one keeps a dynamic loop.  A whole block
+is AWAITED once: a DMA semaphore counts bytes, so ONE descriptor the
+size of the buffer half awaits all its pages' arrivals, whichever pages
+they were; the partial block awaits its pages one by one.  While a walk's last block
+is computed the NEXT grid step's first block is already on its way (the
+scalars are prefetched, so step t reads step t+1's lengths and table
+row); which buffer half it went to is carried in SMEM scratch.  The
+grid's first step starts its own first block, a step that walks nothing
+its successor's, the last step none; a slot with ``valid_lens`` 0
+fetches nothing, and table entries outside ``first .. last`` of a live
+slot are never read.  A decode step runs the loop by twos over STATIC
+halves, the block in half 0 then the block in half 1 (a walk whose block
+0 lies in half 1 skips the first turn's first): with the issue loop
+unrolled, every descriptor's destination is then a constant of the
+program, which halves what a descriptor costs the scalar core.  A chunk
+keeps one loop over dynamic halves: the paired loop reads faster for the
+kernel alone and no faster in the cells (below).  A block that no mask can
+touch (wholly at or below the q-block's oldest row, wholly inside its
+newest row's window: every block but a walk's last and a window walk's
+first) skips the position masks and the zeroing of dead pages' values;
+the others mask by position.  fp32 online softmax (m, l, acc in VMEM
+scratch) carries across blocks.
 
 One fetch serves every query head.  Decode multiplies all ``nh`` heads
 against all ``(key, kv group)`` pairs of a block in one matmul
@@ -51,19 +71,53 @@ a ``[32, 2048]`` block.  A chunk's rows would make that waste real, so
 with ``block_q > 1`` each group's rows ``[block_q * nh/g, d]`` meet that
 group's keys ``[256, d]``.
 
-What it costs (TPU v5e, bf16, ``[32 slots, 528 pages]``, window 4096;
-one chip run of PR 25, the kernel alone, 16 calls chained): time follows
-the live pages and not the table.  Decode: 63 us a call for 14 rows
-holding 346 pages (22 MB of keys and values: 27 us at 819 GB/s), 239 us
-for 32 rows holding 2,053 pages (131 MB: 160 us), 61 us for two rows of
-6,000 and 3,000 tokens; the same at 1,024 pages a row (69 / 242 / 62).
-That is about 0.1 us a live page on top of some 25 us a call (in the
-dense chat cell's trace a call reads 24 us at 4 rows of 500 tokens),
-where the grid over ``(slot, q-block, page)`` that this replaced took
-0.15-0.17 us for every entry of the table, live or dead (3.7 ms a call
-at 528 pages, 6.9 ms at 1,024), and 0.6 us for a live one.  A 64-token
-chunk: 16 / 30 / 69 / 137 us at 0 / 192 / 1,984 / 6,016 tokens of
-context (76 / 123 / 560 / 1,076 before).
+What it costs (TPU v5e, bf16, pages of 16 tokens; chip runs of PR 48,
+the kernel alone, 20 calls chained, PR 47's tree -> this one; the
+outputs bit for bit the same): time follows the live pages and not the
+table, and a live page costs about the same whatever its bytes.
+
+===================================  ======  ============  ==============  ============
+rows x mean live pages (K + V bytes)  pages   us a call     us a live page  of 819 GB/s
+===================================  ======  ============  ==============  ============
+32 x 65, 8 kv heads (64 KiB)          2,065   276 -> 234    0.134 -> 0.113  60 -> 71%
+48 x 456, 4 kv heads (32 KiB)        21,908  2,495 -> 1,570  0.114 -> 0.072  35 -> 56%
+48 x 126, the same under a window     6,025   784 -> 557    0.130 -> 0.092  31 -> 43%
+  of 2,048
+64 x 127, 2 kv heads (16 KiB)         8,096   976 -> 708    0.121 -> 0.088  17 -> 23%
+16 x 1,063, a latent pool (20 KiB)   17,011  1,038 -> 620   0.061 -> 0.036  41 -> 69%
+===================================  ======  ============  ==============  ============
+
+Split at the second row before the change (PERF.md section 6, PR 48): the
+block's arithmetic alone 0.072 us a page (0.057 with the masks skipped
+where none can bite), the fetches alone 0.049 (the bytes' own time, and
+the same from ONE descriptor a block out of a contiguous pool: the DMA
+engine charges nothing by the descriptor), the two together 0.114: what
+a descriptor costs is the SCALAR CORE'S time to build it, which does not
+run under the block's matmuls.  Unrolling the issue loop or awaiting a
+block once gains a twentieth each while a descriptor's destination is
+worked out at run time; with both buffer halves static the same loop
+costs half.  Of a 20-call chain's us a call some 25 are the timed run's
+own launch; chained 200 times, 32 idle slots are 10.0 us a call before
+and 12.5 after (0.08 us more a skipped step: it reads its successor's
+lengths, so that a live slot's first block is on its way a step early)
+and 4 rows of 500 tokens among 32 slots 26.7 and 28.0.  A chunk's
+q-blocks are bound by their products: 64 tokens over 1,984 of context
+64.9 us (67.7 before, chained 200 times), 512 over 8,192 at 4 kv heads
+1.50 ms (1.65; 0.49 under a window of 2,048, 0.51 before), 512 over
+2,048 at 2 kv heads 0.43 ms (0.47), the K + 1 verify step of 32 rows
+0.67 ms (0.73).  Under the decode step's paired loop the same chunks
+read 62.9 us, 1.39 ms (0.45), 0.43 and 0.64 alone, but no cell showed
+the gain (``trinity-mini-serve.agent-16k`` 7,058-7,071 tokens/s with it,
+6,692-7,146 over six seeds without), a chunk's kernel compiles in 10.1 s
+where this one takes 7.4, and tier-1 ran into its time limit with the
+interpreted kernel laid out twice more (PERF.md section 6, PR 48): a
+chunk keeps the single loop.  Laid out
+more than once (a decode step's block four times: two halves, each whole
+or masked; a chunk's twice), a kernel costs the host more at every
+start: ``paged_decode_4_kv_heads_48_slots`` of
+``tests/test_tpu_aot_compile.py`` 0.1 -> 0.7 s to trace and lower and
+0.7 -> 2.3 s to compile for a described v5e, the 512-token chunk at 4 kv
+heads 0.2 -> 0.3 and 4.1 -> 7.4 (this sandbox's CPU, PR 48).
 
 A LATENT pool (``ops/paged_kv.py``: one array ``[P, bs, W]`` a layer,
 a token's row its normed latent, then the one rotary key, then zeros up
@@ -193,14 +247,17 @@ def _valid_keys(key_pos, pos, window):
 
 def _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, rows):
     """One online-softmax update: fp32 scores ``sq`` [R, T] with their
-    validity and the values ``v`` [T, d] (fp32, or a latent pool's own
-    dtype, to which the probabilities are then rounded) folded into the
-    running (m, l, acc) at scratch ``rows``."""
-    sq = jnp.where(valid, sq, NEG_INF)
+    validity (None: every score counts) and the values ``v`` [T, d] (fp32,
+    or a latent pool's own dtype, to which the probabilities are then
+    rounded) folded into the running (m, l, acc) at scratch ``rows``."""
+    if valid is not None:
+        sq = jnp.where(valid, sq, NEG_INF)
     m_prev = m_scr[rows]                              # [R, 1]
     m_new = jnp.maximum(m_prev, jnp.max(sq, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(sq - m_new), 0.0)
+    p = jnp.exp(sq - m_new)
+    if valid is not None:
+        p = jnp.where(valid, p, 0.0)
     l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
@@ -237,17 +294,18 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
                quantized, scale, window, qpg, value_width):
     """One (slot, q-block): walk pages ``first .. last`` of the slot's
     table in blocks of ``kp`` pages, block j+1 on its way from HBM while
-    block j is computed.  Nothing of the table outside that range is
-    read.  ``value_width`` (a decode step's only): the pool is a latent
-    one, ONE array of pages ``[bs, W]`` whose rows are the keys of one kv
-    group and whose first ``value_width`` columns are the values."""
+    block j is computed, and the NEXT grid step's first block while the
+    last one is.  Nothing of the table outside that range is read.
+    ``value_width`` (a decode step's only): the pool is a latent one, ONE
+    array of pages ``[bs, W]`` whose rows are the keys of one kv group and
+    whose first ``value_width`` columns are the values."""
     latent = value_width is not None
     n_pool = 1 if latent else 4 if quantized else 2
     hbm = refs[:n_pool]                   # K, V[, K scales, V scales]
     o_ref = refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
-    sem, m_scr, l_scr, acc_scr = refs[2 * n_pool + 1:]
-    s = pl.program_id(0)
+    sem, half_ref, m_scr, l_scr, acc_scr = refs[2 * n_pool + 1:]
+    s, qi = pl.program_id(0), pl.program_id(1)
     if latent:
         (_, kp, bs, d), g = bufs[0].shape, 1
     else:
@@ -257,44 +315,116 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
     lanes = T * g                         # (page, position, group) triples
     R = bq * qpg                          # query rows of one kv group
 
-    ctx = cl_ref[s]                       # keys cached before this call
-    q0 = pl.program_id(1) * bq            # first chunk row of the q-block
-    # newest key any row attends (padded tail rows of a chunk may point
-    # past the table: it ends where the table ends)
-    top = jnp.minimum(ctx + q0 + bq, bt_ref.shape[1] * bs) - 1
-    last = top // bs
-    if window is None:
-        first = 0
-    else:
-        first = jnp.maximum(ctx + q0 - window + 1, 0) // bs
-    # a slot with no token in this call walks nothing: no fetch, zeros out
-    nblk = jnp.where(vl_ref[s] > 0, (last - first) // kp + 1, 0)
+    def span(s, qi):
+        """What q-block ``qi`` of slot ``s`` walks: its first and last
+        page, in how many blocks, and the newest key any row attends."""
+        # padded tail rows of a chunk may point past the table: the walk
+        # ends where the table ends
+        top = jnp.minimum(cl_ref[s] + (qi + 1) * bq,
+                          bt_ref.shape[1] * bs) - 1
+        last = top // bs
+        if window is None:
+            first = 0
+        else:
+            first = jnp.maximum(cl_ref[s] + qi * bq - window + 1, 0) // bs
+        # a slot with no token in this call walks nothing: no fetch,
+        # zeros out
+        nblk = jnp.where(vl_ref[s] > 0, (last - first) // kp + 1, 0)
+        return first, last, nblk, top
 
-    def block_dma(j, slot, start):
+    ctx = cl_ref[s]                       # keys cached before this call
+    q0 = qi * bq                          # first chunk row of the q-block
+    first, last, nblk, top = span(s, qi)
+    # the grid step after this one (the slot's next q-block, else the
+    # next slot's first; the grid's last step has none) and what it walks
+    wrap = qi + 1 == pl.num_programs(1)
+    s_next = jnp.where(wrap, s + 1, s)
+    more = s_next < pl.num_programs(0)
+    s_next = jnp.where(more, s_next, s)
+    first_next, last_next, nblk_next, _ = span(
+        s_next, jnp.where(wrap, 0, qi + 1))
+    nblk_next = jnp.where(more, nblk_next, 0)
+    # the buffer half that holds this step's block 0: the step before
+    # left word of it, having put the block on its way
+    opening = (s == 0) & (qi == 0)
+    half0 = jnp.where(opening, 0, half_ref[0])
+
+    def live_pages(first, last, j):
+        # the last block stops at the last live page
+        return jnp.minimum(kp, last - (first + j * kp) + 1)
+
+    def page_copy(which, page, half, i):
+        # a page of keys or values; of scales, one row
+        src, dst = ((hbm[which].at[page], bufs[which].at[half, i])
+                    if which < 2 else
+                    (hbm[which].at[pl.ds(page, 1)],
+                     bufs[which].at[half, pl.ds(i, 1)]))
+        return pltpu.make_async_copy(src, dst, sem.at[which, half])
+
+    def start_block(s, first, last, j, half):
+        """Put block ``j`` of a walk ``first .. last`` of slot ``s`` on
+        its way into buffer ``half``.  Into a STATIC half, a block whose
+        pages are all live (every block but a walk's last) is issued from
+        a loop of static trip count, unrolled: every descriptor's
+        destination is a constant and neighbouring pages' table reads and
+        address sums share bundles.  The partial block, and a block into
+        a half known only at run time, keep the dynamic loop."""
         p0 = first + j * kp
 
-        def page_dma(i, carry):
+        def page_start(i, carry=0):
             page = bt_ref[s, p0 + i]
             for which in range(n_pool):
-                # a page of keys or values; of scales, one row
-                src, dst = ((hbm[which].at[page], bufs[which].at[slot, i])
-                            if which < 2 else
-                            (hbm[which].at[pl.ds(page, 1)],
-                             bufs[which].at[slot, pl.ds(i, 1)]))
-                cp = pltpu.make_async_copy(src, dst, sem.at[which, slot])
-                cp.start() if start else cp.wait()
+                page_copy(which, page, half, i).start()
             return carry
 
-        # the last block stops at the last live page
-        jax.lax.fori_loop(0, jnp.minimum(kp, last - p0 + 1), page_dma, 0)
+        live = live_pages(first, last, j)
+        if not isinstance(half, int):
+            jax.lax.fori_loop(0, live, page_start, 0)
+            return
+
+        @pl.when(live == kp)
+        def _whole():
+            for i in range(kp):
+                page_start(i)
+
+        @pl.when(live < kp)
+        def _partial():
+            jax.lax.fori_loop(0, live, page_start, 0)
+
+    def wait_block(live, half):
+        """Await the ``live`` pages of the block in buffer ``half``.  A DMA
+        semaphore counts bytes, so ONE descriptor the size of the buffer
+        half awaits a whole block's pages whichever they were; the partial
+        block awaits its pages one by one."""
+        @pl.when(live == kp)
+        def _whole():
+            for which in range(n_pool):
+                pltpu.make_async_copy(hbm[which].at[pl.ds(0, kp)],
+                                      bufs[which].at[half],
+                                      sem.at[which, half]).wait()
+
+        @pl.when(live < kp)
+        def _partial():
+            def page_wait(i, carry):
+                for which in range(n_pool):
+                    page_copy(which, 0, half, i).wait()
+                return carry
+
+            jax.lax.fori_loop(0, live, page_wait, 0)
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(nblk > 0)
-    def _first_block():
-        block_dma(0, 0, True)
+    # a block 0 that no step has started: this step's, on the grid's
+    # first step; the next step's, where this one walks nothing
+    mine = opening & (nblk > 0)
+
+    @pl.when(mine | ((nblk == 0) & (nblk_next > 0)))
+    def _unstarted_block():
+        start_block(jnp.where(mine, s, s_next),
+                    jnp.where(mine, first, first_next),
+                    jnp.where(mine, last, last_next), 0, half0)
 
     def iota(shape, dim):
         return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
@@ -312,62 +442,67 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
         return jnp.concatenate(
             [x[i] * col[:, i:i + 1] for i in range(kp)], axis=0)
 
-    def block(j, carry):
-        slot = jax.lax.rem(j, 2)
+    if bq == 1:
+        # decode: all nh heads against all (key, group) pairs of the
+        # block in ONE matmul.  Lane c of the scores is key c // g of
+        # the block and kv group c % g, and head h keeps the lanes of
+        # its own group: the pages are used as they lie, each key
+        # crosses the MXU once as it would a group at a time, and the
+        # mask costs g times the exponentials of a tiny block.  A
+        # constant of the shapes (with one group, every lane)
+        lane = iota((nh, lanes), 1)
+        own = None if g == 1 else (
+            jax.lax.rem(lane, g) == jax.lax.div(iota((nh, lanes), 0), qpg))
 
-        @pl.when(j + 1 < nblk)
-        def _next_block():
-            block_dma(j + 1, 1 - slot, True)
-
-        block_dma(j, slot, False)
+    def attend(base, half, masked):
+        """Fold the block in buffer ``half``, whose first key stands at
+        position ``base``, into the running softmax.  ``masked``: some key
+        of it is dropped for some row (it lies past a row's position,
+        behind a row's window, or on a page past the last live one); a
+        block that no mask can touch skips them all."""
         if latent:
             # keys and values are one fetch: the rows, and their first
             # columns.  Pages past the last live one are zeroed whole
-            k = bufs[0][slot].reshape(lanes, d)
-            k = jnp.where(
-                (first + j * kp) * bs + iota((lanes, 1), 0) <= top, k,
-                jnp.zeros_like(k))
+            k = bufs[0][half].reshape(lanes, d)
+            if masked:
+                k = jnp.where(base + iota((lanes, 1), 0) <= top, k,
+                              jnp.zeros_like(k))
             if not native:
                 k = k.astype(jnp.float32)
             v = k[:, :value_width]
         elif quantized:
-            k = dequantized(bufs[0][slot], bufs[2][slot])
-            v = dequantized(bufs[1][slot], bufs[3][slot])
+            k = dequantized(bufs[0][half], bufs[2][half])
+            v = dequantized(bufs[1][half], bufs[3][half])
         else:
-            k = bufs[0][slot].reshape(lanes, d)
-            v = bufs[1][slot].reshape(lanes, d).astype(jnp.float32)
+            k = bufs[0][half].reshape(lanes, d)
+            v = bufs[1][half].reshape(lanes, d).astype(jnp.float32)
             if not native:
                 k = k.astype(jnp.float32)
-        base = (first + j * kp) * bs                    # block's first key
         # buffer pages past the last live one hold what an earlier block
         # left there: their scores are masked below, and their values
         # zeroed here so that 0 x (whatever they are) adds nothing
-        if not latent:
+        if masked and not latent:
             v = jnp.where(
                 base + jax.lax.div(iota((lanes, 1), 0), g) <= top, v, 0.0)
         if bq == 1:
-            # decode: all nh heads against all (key, group) pairs of the
-            # block in ONE matmul.  Lane c of the scores is key c // g of
-            # the block and kv group c % g, and head h keeps the lanes of
-            # its own group: the pages are used as they lie, each key
-            # crosses the MXU once as it would a group at a time, and the
-            # mask costs g times the exponentials of a tiny block
-            lane = iota((nh, lanes), 1)
-            own = jax.lax.rem(lane, g) == jax.lax.div(iota((nh, lanes), 0),
-                                                      qpg)
-            valid = own & _valid_keys(base + jax.lax.div(lane, g), ctx,
-                                      window)
+            valid = own
+            if masked:
+                valid = _valid_keys(base + jax.lax.div(lane, g), ctx, window)
+                if own is not None:
+                    valid &= own
             sq = jax.lax.dot_general(
                 q[0], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [nh, lanes]
             _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, slice(None))
-            return carry
+            return
         # a chunk: the rows of one kv group [R, d] against that group's
         # keys [T, d]; flat row r is chunk row r // qpg, head r % qpg
         k, v = k.reshape(T, g, d), v.reshape(T, g, d)
-        valid = _valid_keys(base + iota((R, T), 1),
-                            ctx + q0 + jax.lax.div(iota((R, T), 0), qpg),
-                            window)
+        valid = None
+        if masked:
+            valid = _valid_keys(
+                base + iota((R, T), 1),
+                ctx + q0 + jax.lax.div(iota((R, T), 0), qpg), window)
         for grp in range(g):
             q2 = q[:, grp * qpg:(grp + 1) * qpg, :].reshape(R, d)
             sq = jax.lax.dot_general(
@@ -375,9 +510,68 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
                 preferred_element_type=jnp.float32) * scale     # [R, T]
             _softmax_block(sq, valid, v[:, grp, :], m_scr, l_scr, acc_scr,
                            slice(grp * R, (grp + 1) * R))
-        return carry
 
-    jax.lax.fori_loop(0, nblk, block, 0)
+    def block(j, half):
+        """Block ``j`` of this walk, in buffer ``half``."""
+        # into the other half: this walk's next block, or after its last
+        # the next grid step's first
+        inner = j + 1 < nblk
+
+        @pl.when(inner | (nblk_next > 0))
+        def _next_block():
+            start_block(jnp.where(inner, s, s_next),
+                        jnp.where(inner, first, first_next),
+                        jnp.where(inner, last, last_next),
+                        jnp.where(inner, j + 1, 0), 1 - half)
+
+        wait_block(live_pages(first, last, j), half)
+        # every row attends every key of the block: none is newer than
+        # the q-block's oldest row nor behind its newest row's window
+        # (a walk's last block, and a window walk's first, are not such)
+        base = (first + j * kp) * bs                    # block's first key
+        whole = base + T - 1 <= jnp.minimum(ctx + q0, top)
+        if window is not None:
+            whole &= base > ctx + q0 + bq - 1 - window
+
+        @pl.when(whole)
+        def _whole():
+            attend(base, half, masked=False)
+
+        @pl.when(jnp.logical_not(whole))
+        def _masked():
+            attend(base, half, masked=True)
+
+    if bq == 1:
+        # a decode step's block is cheap enough for its fetch to show, and
+        # a descriptor into a half known only at run time costs the scalar
+        # core twice a constant one's (PERF.md section 6, PR 48): the loop
+        # runs by twos, the block in half 0 then the block in half 1, and a
+        # walk whose block 0 lies in half 1 skips the first turn's first
+        def pair(i, carry):
+            j = 2 * i - half0
+
+            @pl.when(j >= 0)
+            def _in_half_0():
+                block(j, 0)
+
+            @pl.when(j + 1 < nblk)
+            def _in_half_1():
+                block(j + 1, 1)
+
+            return carry
+
+        jax.lax.fori_loop(0, (nblk + half0 + 1) // 2, pair, 0)
+    else:
+        # a chunk keeps one loop, the half worked out as it goes: under the
+        # paired loop its kernel reads 2 to 8% faster alone, the cells that
+        # run it no faster, and it takes 1.4 times as long to compile (the
+        # module's docstring)
+        def one(j, carry):
+            block(j, jax.lax.rem(half0 + j, 2))
+            return carry
+
+        jax.lax.fori_loop(0, nblk, one, 0)
+    half_ref[0] = jax.lax.rem(half0 + nblk, 2)
     if bq == 1:
         out = _softmax_finish(l_scr, acc_scr, slice(None))[None]
     else:
@@ -401,22 +595,47 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
     output is ``[S, C, nh, value_width]``."""
     if valid_lens is None:
         valid_lens = jnp.ones_like(context_lens)
+    bs, M = k_pages.shape[1], block_tables.shape[1]
+    if value_width is not None:
+        # a block of _BLOCK_TOKENS keys whatever the row's width: the
+        # scores' lanes are what a block is sized by here
+        kp = max(1, min(M, _BLOCK_TOKENS // bs))
+    else:
+        kp = _pages_per_block(bs, k_pages.shape[2], q.shape[-1],
+                              k_pages.dtype, M)
+    return _walk_kernel(
+        q, k_pages, v_pages, block_tables, context_lens, valid_lens,
+        k_scales, v_scales, scale=scale, window=window, block_q=block_q,
+        name=name + name_suffix, value_width=value_width, kp=kp,
+        interpret=_INTERPRET)
+
+
+# a jit of its own inside the engine's programs, as the latent chunk's
+# below: the layers of a program that call it with one set of shapes share
+# ONE traced and lowered kernel.  A decode step's kernel, its issue loop
+# unrolled over two buffer halves, takes 0.7 s to trace and lower where the
+# old one took 0.1 (the module's docstring), and an engine warms a dozen
+# programs of eight such layers: 22 s of set-up with every program in the
+# compile cache
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "window", "block_q", "name", "value_width", "kp", "interpret"))
+def _walk_kernel(q, k_pages, v_pages, block_tables, context_lens,
+                 valid_lens, k_scales, v_scales, *, scale, window, block_q,
+                 name, value_width, kp, interpret):
+    """``kp`` pages a compute block."""
     S, C, nh, d = q.shape
     latent = value_width is not None
     bs, g = k_pages.shape[1], 1 if latent else k_pages.shape[2]
     dv = value_width if latent else d
-    M = block_tables.shape[1]
     bq = block_q
     assert C % bq == 0, (C, bq)
+    # a whole block is awaited by one descriptor of kp pages of the pool
+    assert k_pages.shape[0] >= kp, (k_pages.shape, kp)
     quantized = k_scales is not None
     if latent:
-        # a block of _BLOCK_TOKENS keys whatever the row's width: the
-        # scores' lanes are what a block is sized by here
-        kp = max(1, min(M, _BLOCK_TOKENS // bs))
         pools = [k_pages]
         bufs = [pltpu.VMEM((2, kp, bs, d), k_pages.dtype)]
     else:
-        kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
         pools = [k_pages, v_pages]
         bufs = [pltpu.VMEM((2, kp, bs, g, d), k_pages.dtype)] * 2
     if quantized:
@@ -438,6 +657,7 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
                                memory_space=pltpu.VMEM),
         scratch_shapes=bufs + [
             pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
             pltpu.VMEM((bq * nh, dv), jnp.float32),
@@ -447,10 +667,10 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
         functools.partial(_walk_body, quantized=quantized, scale=scale,
                           window=window, qpg=nh // g,
                           value_width=value_width),
-        name=name + name_suffix + ("_quant" if quantized else ""),
+        name=name + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, dv), q.dtype),
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       valid_lens.astype(jnp.int32), q, *pools)
 

@@ -98,8 +98,11 @@ def _tokens(n, seed=3, vocab=512):
 
 
 def _engine(model, params, **kw):
+    # a long deadline, not the default 120 s: a request served through an
+    # interpreted kernel must not expire by the wall clock of a loaded
+    # machine, and a hang must still fail
     kw = dict(dict(num_slots=2, block_size=BS, max_model_len=192,
-                   prefill_chunk=CHUNK), **kw)
+                   prefill_chunk=CHUNK, default_deadline_secs=600.0), **kw)
     return InferenceEngine(model, params, EngineConfig(**kw))
 
 

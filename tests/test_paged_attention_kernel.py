@@ -1143,3 +1143,143 @@ def test_latent_chunk_in_bf16_rounds_the_expansion_to_the_operands_dtype():
             want[j, h] = rounded(p) @ kv[:22 + j, h, 8:] / p.sum()
     np.testing.assert_allclose(np.asarray(got[0], np.float32), want,
                                atol=0.02)
+
+
+# ---------------------------------------------------------------------------
+# the first block of a grid step is put on its way by the step before it
+# (which buffer half holds it is carried across steps): what that makes new
+# ---------------------------------------------------------------------------
+
+def _dead_from(bt, tokens, page, skipped=None):
+    """The table with every entry past a slot's live tokens, and the whole
+    row of a slot that takes no part, pointed at ``page``."""
+    live = -(-np.asarray(tokens) // BS)
+    if skipped is not None:
+        live = np.where(skipped, 0, live)
+    dead = np.arange(bt.shape[1])[None, :] >= live[:, None]
+    return np.where(dead, page, bt).astype(np.int32)
+
+
+def _handed_decode(rng, M, lens, vlen, window, int8=False):
+    lens, vlen = np.asarray(lens, np.int32), np.asarray(vlen, np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_case(rng, len(lens), M, BS, 2, 4, D,
+                                              lens)
+    P, kw = kp.shape[0], {}
+    want = _oracle(q, k_lin, v_lin, lens, 1.0 / math.sqrt(D), window)
+    if int8:
+        kp, ks = absmax_quantize_int8(jnp.asarray(kp), axis=-1)
+        vp, vs = absmax_quantize_int8(jnp.asarray(vp), axis=-1)
+        ks, vs = np.array(ks), np.array(vs)
+        want = np.asarray(_dense(
+            jnp.asarray(q), kp, vp, jnp.asarray(bt), jnp.asarray(lens),
+            jnp.asarray(ks), jnp.asarray(vs), 1.0 / math.sqrt(D), window))
+        ks[P - 2], vs[P - 2], ks[P - 3], vs[P - 3] = np.nan, np.nan, 1e30, 1e30
+        kw = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
+    else:
+        kp[P - 2], vp[P - 2], kp[P - 3], vp[P - 3] = np.nan, np.nan, 1e30, -1e30
+    outs = []
+    for page in (P - 2, P - 3):
+        table = _dead_from(bt, lens + 1, page, vlen == 0)
+        if window is not None:
+            table = _behind_the_window_gone(table, lens, window, BS, page)
+        outs.append(_decode(q, kp, vp, table, lens, sliding_window=window,
+                            valid_lens=jnp.asarray(vlen), **kw))
+    return outs, want, vlen
+
+
+def _handed_chunk(rng, M, ctx, vlen, window, block_q):
+    ctx, vlen = np.asarray(ctx, np.int32), np.asarray(vlen, np.int32)
+    q, k_lin, v_lin, kp, vp, bt = _build_prefill_case(
+        rng, len(ctx), M, BS, 2, 4, D, ctx, C)
+    P = kp.shape[0]
+    kp[P - 2], vp[P - 2], kp[P - 3], vp[P - 3] = np.nan, np.nan, 1e30, -1e30
+    outs = []
+    for page in (P - 2, P - 3):
+        table = _dead_from(bt, ctx + C, page, vlen == 0)
+        if window is not None:
+            table = _behind_the_window_gone(table, ctx, window, BS, page)
+        outs.append(np.asarray(pa.paged_attention_prefill(
+            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(table), jnp.asarray(ctx), sliding_window=window,
+            valid_lens=jnp.asarray(vlen), block_q=block_q)))
+    return outs, _prefill_oracle(q, k_lin, v_lin, ctx, 1.0 / math.sqrt(D),
+                                 window), vlen
+
+
+def _handed_latent(rng, M, lens, vlen):
+    lens, vlen = np.asarray(lens, np.int32), np.asarray(vlen, np.int32)
+    q, rows, pages, bt = _latent_case(rng, lens, M)
+    P = pages.shape[0]
+    pages[P - 2], pages[P - 3] = np.nan, 1e30
+    outs = [np.asarray(pa.latent_attention_decode(
+        jnp.asarray(q[:, 0]), jnp.asarray(pages),
+        jnp.asarray(_dead_from(bt, lens + 1, page, vlen == 0)),
+        jnp.asarray(lens), valid_lens=jnp.asarray(vlen), value_width=16,
+        softmax_scale=0.3)) for page in (P - 2, P - 3)]
+    return outs, _latent_oracle(q, rows, lens, 0.3, 16)[:, 0], vlen
+
+
+# blocks are two pages of 8 tokens: contexts of 40, 17, 33, 9 walk 3, 2, 3
+# and 1 blocks, the last of each partial or exactly full
+HANDED = {
+    "a_live_slot_between_two_skipped":
+        lambda r: _handed_decode(r, LONG_M, [40, 17, 33, 9], [0, 1, 0, 0],
+                                 None),
+    "a_skipped_slot_between_two_live":
+        lambda r: _handed_decode(r, LONG_M, [40, 17, 33, 9], [1, 0, 1, 1],
+                                 None),
+    "every_walk_one_partial_block":
+        lambda r: _handed_decode(r, LONG_M, [3, 0, 7, 5], [1, 1, 1, 1],
+                                 None),
+    "walks_of_one_whole_block_and_of_an_odd_count":
+        lambda r: _handed_decode(r, LONG_M, [15, 47, 15, 31], [1, 1, 1, 1],
+                                 None),
+    "the_last_slot_after_skipped_ones":
+        lambda r: _handed_decode(r, LONG_M, [9, 17, 33, 61], [1, 0, 0, 1],
+                                 None),
+    "the_last_slot_alone":
+        lambda r: _handed_decode(r, LONG_M, [9, 17, 33, 61], [0, 0, 0, 1],
+                                 None),
+    "the_last_slot_skipped":
+        lambda r: _handed_decode(r, LONG_M, [9, 17, 33, 61], [1, 1, 1, 0],
+                                 None),
+    # slot 0 walks all 8 pages of its table; slot 1's window of 20 opens
+    # at key 26, inside page 3 (a block is two pages from there on)
+    "a_window_walk_mid_block_after_a_whole_table":
+        lambda r: _handed_decode(r, 8, [63, 45, 63, 38], [1, 1, 1, 1], 20),
+    "a_whole_table_then_a_short_walk":
+        lambda r: _handed_decode(r, 8, [63, 2, 63, 63], [1, 1, 0, 1], None),
+    "a_chunk_of_two_q_blocks_in_two_slots":
+        lambda r: _handed_chunk(r, MP, [3, 17], [C, C], None, 8),
+    "a_chunk_of_two_q_blocks_under_a_window":
+        lambda r: _handed_chunk(r, 8, [33, 0, 24], [C, C, 7], 5, 8),
+    "a_chunk_of_four_q_blocks_one_slot_skipped":
+        lambda r: _handed_chunk(r, MP, [0, 8, 17, 3], [C, 0, C, 9], None, 4),
+    "the_latent_pool":
+        lambda r: _handed_latent(r, 6, [37, 5, 0, 17], [1, 0, 1, 1]),
+    "the_latent_pool_last_slot_skipped":
+        lambda r: _handed_latent(r, 6, [16, 37, 15, 5], [1, 1, 1, 0]),
+    "the_int8_pools":
+        lambda r: _handed_decode(r, LONG_M, [40, 17, 33, 9], [1, 0, 1, 1],
+                                 None, int8=True),
+    "the_int8_pools_under_a_window":
+        lambda r: _handed_decode(r, LONG_M, [40, 17, 33, 61], [1, 1, 0, 1],
+                                 12, int8=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HANDED))
+def test_the_first_block_is_handed_across_grid_steps(case, two_page_blocks):
+    """Each grid step's first block is started by the step before it (the
+    grid's first step starts its own, a step that walks nothing starts its
+    successor's, the last step starts none).  Live rows are the oracle's,
+    skipped ones zeros, and tables whose dead entries (past a slot's live
+    tokens, behind its window, a skipped slot's whole row) point at a page
+    of NaN and at a page of 1e30 give bit-equal outputs: no step fetches
+    for its successor anything the successor would not fetch itself."""
+    outs, want, vlen = HANDED[case](np.random.default_rng(len(case)))
+    assert np.isfinite(outs[0]).all()
+    assert np.array_equal(outs[0], outs[1])
+    assert not outs[0][vlen == 0].any()
+    np.testing.assert_allclose(outs[0][vlen > 0], want[vlen > 0], atol=2e-5,
+                               rtol=2e-5)

@@ -72,17 +72,20 @@ def _layer_norm(rows):
 
 
 def _paged(q_tokens, slots, page=16, int8=False, window=None,
-           heads=HEADS, kv_heads=KV_HEADS):
+           heads=HEADS, kv_heads=KV_HEADS, tokens=MAX_LEN):
     """Decode (q_tokens=1), a prefill chunk or the K+1 verify step over a
     pool sized as the engine sizes it: full backing for 8 slots of 4096
     tokens plus the garbage page.  ``heads`` / ``kv_heads`` 16 / 16 is
-    OLMoE's layout: one query head a key-value group."""
+    OLMoE's layout: one query head a key-value group; 32 / 4 with 48
+    slots of up to 20,992 ``tokens`` (a table of 1,312 entries, the walk's
+    largest in SMEM) and a window of 2,048 is Trinity-Mini's two groups,
+    32 / 2 with 64 slots of 6,144 Nemotron-3-Nano's."""
     from megatron_llm_tpu.ops.pallas import paged_attention as pa
 
     pages = SLOTS * (MAX_LEN // page) + 1
     pool = ((pages, page, kv_heads, HEAD_DIM), jnp.int8 if int8 else BF16)
     scale = ((pages, page, kv_heads), jnp.float32)
-    shapes = [pool, pool, ((slots, MAX_LEN // page), jnp.int32),
+    shapes = [pool, pool, ((slots, tokens // page), jnp.int32),
               ((slots,), jnp.int32)] + ([scale, scale] if int8 else [])
 
     def fn(q, k_pages, v_pages, tables, lens, k_scales=None, v_scales=None):
@@ -249,6 +252,16 @@ CASES = {
         lambda: _paged(1, 64, heads=16, kv_heads=16),
     "paged_prefill_chunk_64_16_kv_heads":
         lambda: _paged(64, 1, heads=16, kv_heads=16),
+    "paged_decode_4_kv_heads_48_slots":
+        lambda: _paged(1, 48, kv_heads=4, tokens=20992),
+    "paged_decode_4_kv_heads_48_slots_window_2048":
+        lambda: _paged(1, 48, kv_heads=4, tokens=20992, window=2048),
+    "paged_prefill_chunk_512_4_kv_heads":
+        lambda: _paged(512, 1, kv_heads=4, tokens=20992),
+    "paged_decode_2_kv_heads_64_slots":
+        lambda: _paged(1, 64, kv_heads=2, tokens=6144),
+    "paged_prefill_chunk_512_2_kv_heads":
+        lambda: _paged(512, 1, kv_heads=2, tokens=6144),
     "moe_experts_olmoe_512_rows": lambda: _experts(512, 64, 2048, 1024),
     "moe_experts_olmoe_verify_320_rows":
         lambda: _experts(8 * 5 * 8, 64, 2048, 1024),
