@@ -18,6 +18,10 @@ Usage mirrors the reference (``docs/guide/getting_started.md``):
 
 from __future__ import annotations
 
+import time
+
+_FIRST_STAMP = time.perf_counter()  # before the imports: tracing's timeline
+
 import os
 import sys
 
@@ -25,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from megatron_llm_tpu import checkpointing, topology
+from megatron_llm_tpu import checkpointing, topology, tracing
 from megatron_llm_tpu.data.data_samplers import place_host_batch
 from megatron_llm_tpu.arguments import (
     parallel_config_from_args,
@@ -43,6 +47,8 @@ from megatron_llm_tpu.optimizer import (
 from megatron_llm_tpu.parallel import sharding as sh
 from megatron_llm_tpu.training import pretrain
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+tracing.startup_completed("imports", _FIRST_STAMP, time.perf_counter())
 
 MODEL_DEFAULTS = {
     # reference: finetune.py model_provider asserts + weights tables
@@ -446,12 +452,13 @@ def main():
     resilience = build_resilience(args)
 
     mesh = topology.get_mesh()
-    model = model_provider(args)
     # built before the checkpoint load so the startup restore lands in
     # the trace (--trace_dir opens a checkpoint_load span)
     from megatron_llm_tpu.telemetry import build_telemetry
 
-    telemetry = build_telemetry(args, model)
+    with tracing.startup_span("build_model", model_name=args.model_name):
+        model = model_provider(args)
+        telemetry = build_telemetry(args, model)
     tc = train_config_from_args(args)
     pc = parallel_config_from_args(args)
     num_micro = args.global_batch_size // (
@@ -464,6 +471,7 @@ def main():
     opt_state = None
     consumed_samples = 0
     if args.load:
+        t_load = time.perf_counter()
         # abstract template (shapes + current-mesh shardings, no device
         # memory) makes the orbax restore direct-to-device on THIS mesh —
         # i.e. load-time resharding even when the checkpoint was written
@@ -498,8 +506,11 @@ def main():
                 multislice.announce_elastic_resume(
                     args.load, args, start_iteration, consumed_samples,
                     stream=getattr(telemetry, "stream", None))
+        tracing.startup_completed("load_checkpoint", t_load,
+                                  time.perf_counter())
     if params is None:
-        params = sh.init_params(model, jax.random.PRNGKey(args.seed))
+        with tracing.startup_span("init_params"):
+            params = sh.init_params(model, jax.random.PRNGKey(args.seed))
 
     # interleaved VPP trains with the layer stack in stage-major order;
     # checkpoints stay in natural order (see pipeline.permute_layer_stack)
@@ -508,13 +519,17 @@ def main():
         convert_opt_state_layout,
         convert_params_layout,
     )
-    params = convert_params_layout(
-        params, args.num_layers, pc.pipeline_model_parallel_size, vpp,
-        to_stage_major=True)
-    opt_state = convert_opt_state_layout(
-        opt_state, args.num_layers, pc.pipeline_model_parallel_size, vpp,
-        to_stage_major=True)
-    params = sh.shard_params(params, model.param_specs(params))
+    with tracing.startup_span("shard_params"):
+        params = convert_params_layout(
+            params, args.num_layers, pc.pipeline_model_parallel_size, vpp,
+            to_stage_major=True)
+        opt_state = convert_opt_state_layout(
+            opt_state, args.num_layers, pc.pipeline_model_parallel_size, vpp,
+            to_stage_major=True)
+        params = sh.shard_params(params, model.param_specs(params))
+        if args.fp16 or args.bf16:
+            dt = jnp.float16 if args.fp16 else jnp.bfloat16
+            params = jax.tree_util.tree_map(lambda p: p.astype(dt), params)
 
     def save_natural(save_dir, it_, params_, opt_state_, scheduler_=None):
         if lora_base is not None:
@@ -539,10 +554,6 @@ def main():
             consumed_samples=get_counters().get("samples", 0),
             async_save=getattr(args, "async_save", False),
         )
-
-    if args.fp16 or args.bf16:
-        dt = jnp.float16 if args.fp16 else jnp.bfloat16
-        params = jax.tree_util.tree_map(lambda p: p.astype(dt), params)
 
     # LoRA: swap the trainable tree for low-rank adapters over the
     # frozen (already sharded + cast) base
@@ -569,8 +580,9 @@ def main():
         print(f" > LoRA rank {args.lora_rank}: {n_ad/1e6:.2f}M adapter "
               f"params trainable, base frozen", flush=True)
 
-    train_iter, eval_iter = build_data_iterator(
-        args, mesh, num_micro, consumed_samples=consumed_samples)
+    with tracing.startup_span("build_data"):
+        train_iter, eval_iter = build_data_iterator(
+            args, mesh, num_micro, consumed_samples=consumed_samples)
 
     optimizer = MegatronOptimizer(
         tc, params_dtype=jax.tree_util.tree_leaves(params)[0].dtype
@@ -650,10 +662,13 @@ def main():
         from megatron_llm_tpu.parallel.pipeline import (
             build_pipeline_train_step,
         )
-        custom_step = build_pipeline_train_step(
-            model, optimizer, pc, num_micro,
-            layer_stats=args.log_layer_stats_interval > 0)
-        opt_state = opt_state or optimizer.init(params)
+        with tracing.startup_span("build_train_step"):
+            custom_step = build_pipeline_train_step(
+                model, optimizer, pc, num_micro,
+                layer_stats=args.log_layer_stats_interval > 0)
+        if not opt_state:
+            with tracing.startup_span("build_optimizer"):
+                opt_state = optimizer.init(params)
     from megatron_llm_tpu.timers import Timers
 
     # metrics writer: wandb (or its JSONL offline fallback) and/or a

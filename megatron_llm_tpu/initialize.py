@@ -16,7 +16,7 @@ from typing import Callable, Optional
 
 import jax
 
-from megatron_llm_tpu import arguments, global_vars, topology
+from megatron_llm_tpu import arguments, global_vars, topology, tracing
 from megatron_llm_tpu.timers import Timers
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -62,14 +62,24 @@ def initialize_megatron(
     args_list=None,
 ):
     """Parse + validate args, build the mesh, set globals.  Returns args."""
+    with tracing.startup_span("initialize"):
+        return _initialize(extra_args_provider, args_defaults,
+                           ignore_unknown_args, args_list)
+
+
+def _initialize(extra_args_provider, args_defaults, ignore_unknown_args,
+                args_list):
     args = arguments.parse_args(
         extra_args_provider, args_defaults, ignore_unknown_args, args_list
     )
 
     # multi-host bootstrap over DCN (no-op single host); it must precede
-    # the first backend query, which select_platform makes
-    topology.initialize_distributed()
-    if select_platform(args.device) != "cpu":
+    # the first backend query, which select_platform makes and which
+    # brings the backend up
+    with tracing.startup_span("runtime_init"):
+        topology.initialize_distributed()
+        backend = select_platform(args.device)
+    if backend != "cpu":
         # an accelerator's programs take minutes to compile; XLA:CPU's
         # are cheap, and cached ones are tied to the host's CPU features
         enable_compile_cache()

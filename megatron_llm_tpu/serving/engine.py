@@ -346,6 +346,7 @@ class InferenceEngine:
     }
 
     def __init__(self, model, params, config: Optional[EngineConfig] = None):
+        t_init = time.perf_counter()
         self.model = model
         self.params = params
         self.config = cfg = config or EngineConfig()
@@ -513,6 +514,10 @@ class InferenceEngine:
         self._thread: Optional[threading.Thread] = None
         self._wake = threading.Event()
         self._submit_lock = threading.Lock()
+        # the pools, the tables and the placed slot state, on the
+        # start-up timeline
+        tracing.startup_completed("engine_init", t_init,
+                                  time.perf_counter())
 
     def _new_state(self, gen: int,
                    carry: Optional[_EngineState] = None) -> _EngineState:
@@ -818,6 +823,9 @@ class InferenceEngine:
     def start(self) -> "InferenceEngine":
         with self._restart_lock:
             assert self._thread is None, "engine already started"
+            # "ready": the start-up timeline closes here and prints its
+            # line (nothing where no span was opened since the last)
+            tracing.startup_ready()
             self._running = True
             if self.config.watchdog_secs > 0 and self._watchdog is None:
                 self._watchdog = EngineWatchdog(
@@ -1635,6 +1643,10 @@ class InferenceEngine:
         ``tracing.RecompileDetector.mark_steady()`` — after this, serving
         arbitrary requests triggers zero compiles."""
         assert self._thread is None, "warm up before start()"
+        with tracing.startup_span("warmup"):
+            self._warmup()
+
+    def _warmup(self) -> None:
         st = self._st
         prompt = [1] * min(self.config.prefill_chunk + 1,
                            max(self.config.max_model_len - 4, 1))
@@ -1643,21 +1655,41 @@ class InferenceEngine:
         req._pc_submit = time.perf_counter()
         self.queue.put(req)
         deadline = time.monotonic() + 300.0
+        # a launch that traces one of the loop's programs for the first
+        # time is a child of the start-up timeline's ``warmup`` under the
+        # program's name: its trace, lowering, compile or cache load and
+        # its first execution
+        rows = tracing.compile_ledger().programs
+
+        def traced() -> Dict[str, int]:
+            return {p: (rows.get(p) or (0,))[0] for p in self._jitted}
+
+        named: set = set()
         while req.state != RequestState.DONE:
+            before, t0 = traced(), time.perf_counter()
             if not self.step(st):
                 break
+            new = [p for p, n in traced().items() if n > before[p]]
+            if new:
+                name = next((p for p in new if p not in named), new[0])
+                named.add(name)
+                tracing.startup_completed(
+                    "warmup." + name, t0, time.perf_counter(), traced=new)
             if time.monotonic() > deadline:
                 raise TimeoutError("engine warmup did not converge")
         # compile the copy-on-write page copy (garbage -> garbage is a
         # no-op) so a later COW event can't trip the recompile detector
-        st.pages = self._copy_page(st.pages, 0, 0)
+        with tracing.startup_span("warmup.engine_cow_copy"):
+            st.pages = self._copy_page(st.pages, 0, 0)
         if self.host_cache is not None:
             # compile the host-tier pair the same way: gather the
             # garbage page to host, scatter it straight back — both
             # no-ops, after which spills and swap-ins are compile-free
-            garbage = jax.device_get(
-                self._fetch_block(st.pages, np.int32(0)))
-            st.pages = self._host_load(st.pages, garbage, np.int32(0))
+            with tracing.startup_span("warmup.engine_fetch_block"):
+                garbage = jax.device_get(
+                    self._fetch_block(st.pages, np.int32(0)))
+            with tracing.startup_span("warmup.engine_host_load"):
+                st.pages = self._host_load(st.pages, garbage, np.int32(0))
         jax.block_until_ready(st.pages[self._cache.first_pool])
         self.warmed_up = True
         # compile-time gaps between warmup dispatches are expected —
@@ -1811,6 +1843,8 @@ class InferenceEngine:
             # been asked for; beside them the pool's bytes, which
             # kv_pool_copy_bytes_per_launch is held against
             "kv_pool_bytes": self.kv_pool_bytes,
+            # the start-up timeline's summary, as its line printed it
+            "startup": tracing.startup_summary(),
             "programs": (None if self._program_tables is None else
                          {name: t.summary()
                           for name, t in self._program_tables.items()}),

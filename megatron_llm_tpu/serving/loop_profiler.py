@@ -59,17 +59,24 @@ it on (guarded by ``test_engine_zero_recompiles_after_warmup``).
 Other surfaces, as before: cumulative per-phase seconds and mergeable
 phase histograms (``stats()``, the engine block of ``/metrics``), a
 rollup over the last ``WINDOW_DISPATCHES`` launches, the periodic
-``engine_loop_stats`` JSONL record, and the dispatch-gap stall detector
-(armed after warmup so compile gaps never count).
+``engine_loop_stats`` JSONL record, and the stall detector (armed after
+warmup so compile gaps never count): a launch whose gap passes the
+threshold, or whose own ``dispatch`` + ``fetch`` passes three times its
+kind's running median, with what it lost the time to beside it
+(``compile_secs`` from the compile ledger of ``tracing.py``, ``gc_secs``
+from the interpreter's collector).
 """
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
+import weakref
 from bisect import bisect_left
 from collections import deque
 from itertools import islice
+from statistics import median
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
@@ -90,9 +97,18 @@ _NEXT = {True: (1, 2, 3, 4, 5, 5), False: (2, 2, 3, 4, 5, 5)}
 # drain at 80 launches a second fit several times over
 RING_SIZE = 65536
 REQUEST_RING_SIZE = 16384
-# the "recent" rollup of stats() (and the alert rule on it) and the
-# postmortem bundle look at this many launches, whatever the ring holds
+# the "recent" rollup of stats() (and the alert rule on it), the
+# postmortem bundle and the stall detector's running medians look at this
+# many launches, whatever the ring holds
 WINDOW_DISPATCHES = 512
+# a launch is slow when its dispatch + fetch passes this many times the
+# median of its kind's over the window, and this many seconds; the
+# medians are taken anew every so many launches, from a kind's launches
+# in the window if it has this many
+SLOW_FACTOR = 3.0
+SLOW_MIN_SECS = 0.05
+_MEDIANS_EVERY = 256
+_MEDIAN_MIN_LAUNCHES = 8
 
 # Host phases run far below DEFAULT_LATENCY_BUCKETS' 1 ms floor, so the
 # loop histograms get their own fixed bounds (fleet-mergeable: fixed
@@ -164,8 +180,13 @@ KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
 # paths): host arrays handed to its programs (each table counts one; an
 # array that lives on the device counts only in the launch that uploads
 # it again after a write), and the times the host waited on results
-# (those of one wait set out for the host together)
-HOST_FIELDS = ("host_uploads", "host_reads")
+# (those of one wait set out for the host together); and what the host
+# lost inside the launch: the union seconds of the compile ledger's
+# events (a trace, a lowering, a backend compile, a cache load) that
+# ended on the launch's thread between its ``begin`` and its finish, and
+# the seconds the interpreter spent in garbage collections that ended
+# there (both 0.0 in a sound steady state)
+HOST_FIELDS = ("host_uploads", "host_reads", "compile_secs", "gc_secs")
 
 # the ONE declaration of the counted fields: what ``finish`` sums,
 # ``as_dict()`` carries and ``totals()`` gives goes through it
@@ -232,6 +253,9 @@ class DispatchRecord:
     # that caused it
     requests: Tuple[int, ...] = ()
     traces: Tuple[str, ...] = ()
+    # what the gap before this launch lost to compiles and collections
+    gap_compile_secs = 0.0
+    gap_gc_secs = 0.0
     _at = 0                     # index of the last phase marked
 
     def __init__(self, clock, seq: int, begin: float, gap_secs: float):
@@ -307,6 +331,8 @@ class DispatchRecord:
         return {
             "seq": self.seq, "kind": self.kind, "begin": self.t[0],
             "wall_secs": self.wall_secs, "gap_secs": self.gap_secs,
+            "gap_compile_secs": self.gap_compile_secs,
+            "gap_gc_secs": self.gap_gc_secs,
             "wait_secs": self.wait_secs,
             "phases": {p: self.phase_secs(p) for p in LOOP_PHASES},
             "rows": self.rows, "context_tokens": self.context_tokens,
@@ -322,7 +348,7 @@ class DispatchRecord:
 # every counted field is an attribute of a record, 0 until who knows it
 # fills it (COUNTED_FIELDS says who)
 for _f in COUNTED_FIELDS:
-    setattr(DispatchRecord, _f, 0)
+    setattr(DispatchRecord, _f, 0.0 if _f.endswith("_secs") else 0)
 
 
 # The profilers of this process's newest engines, so that a reader which
@@ -337,6 +363,55 @@ _LIVE_LOCK = threading.Lock()
 # function that builds the program's table, and the table once built
 _PROGRAM_SOURCES: Dict[str, Callable[[], Any]] = {}
 _PROGRAM_TABLES: Dict[str, Any] = {}
+
+
+# The profilers there are, weakly, for the two callbacks below: a tuple
+# replaced whole when a profiler is made, so that the compile ledger's
+# listener (on the compiling thread) and the collector's callback (on
+# whichever thread set the collection off) walk it with no lock.
+_WATCHING: tuple = ()
+_gc_began = 0.0
+
+
+def _watch(profiler: "LoopProfiler") -> None:
+    """Let the two callbacks reach ``profiler``; the first call installs
+    them (one listener of the ledger, one ``gc.callbacks`` entry)."""
+    global _WATCHING
+    with _LIVE_LOCK:
+        if not _WATCHING:
+            ledger = tracing.compile_ledger()
+            ledger.listeners += (_on_compile,)
+            gc.callbacks.append(_on_gc)
+        _WATCHING = tuple(r for r in _WATCHING if r() is not None) + (
+            weakref.ref(profiler),)
+
+
+def _on_compile(kind: str, start: float, end: float, tid: int) -> None:
+    """The ledger heard an event end on thread ``tid``: it is the open
+    launch's (or the gap's) of the profiler whose loop runs there."""
+    for ref in _WATCHING:
+        p = ref()
+        if p is not None and p._thread == tid:
+            p._credit_compile(start, end)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """A collection stops every thread of the interpreter, whichever set
+    it off: its seconds go to each profiler's open launch, or to the gap
+    it is in."""
+    global _gc_began
+    if phase == "start":
+        _gc_began = time.perf_counter()
+        return
+    secs = time.perf_counter() - _gc_began
+    for ref in _WATCHING:
+        p = ref()
+        if p is not None:
+            d = p._open
+            if d is not None:
+                d.gc_secs += secs
+            else:
+                p._gap_gc += secs
 
 
 def live_profilers() -> List["LoopProfiler"]:
@@ -384,8 +459,11 @@ class LoopProfiler:
     # lint-enforced (graft-race TH001): the rollup counters are written
     # by the engine loop (finish) and read by /metrics handler threads
     # (stats), so every access goes through _lock.  _last_end, _seq,
-    # _gap_note and stall_armed are engine-loop/warmup-thread only
-    # (single writer, never read across roots).
+    # _gap_note, stall_armed and _wait_medians are engine-loop/warmup-
+    # thread only (single writer, never read across roots).  _open,
+    # _thread, _gap_compile, _gap_gc and _credited are written by the
+    # loop and by the two callbacks above, which take no lock on
+    # purpose: under the GIL a credit is lost at worst.
     _lock_protected_ = {
         "dispatches": "_lock",
         "dispatches_by_kind": "_lock",
@@ -434,6 +512,19 @@ class LoopProfiler:
         self._last_end: Optional[float] = None
         self._seq = 0
         self._gap_note: Optional[TraceAnnotation] = None
+        # the open launch and the thread its loop runs on, for the two
+        # callbacks; what they credited while none was open (the gap's
+        # account, handed to the next launch); and the ledger's intervals
+        # credited since the last begin or finish, newest last (an inner
+        # trace ends before the one it lies in: a union, not a sum)
+        self._open: Optional[DispatchRecord] = None
+        self._thread = 0
+        self._gap_compile = 0.0
+        self._gap_gc = 0.0
+        self._credited: List[Tuple[float, float]] = []
+        # the stall detector's running median of dispatch + fetch by
+        # kind (None: too few launches of the kind in the window)
+        self._wait_medians: Dict[str, Optional[float]] = {}
         self._emitted_at_dispatches = 0
         self._emitted_at_time = self._clock()
         # the programs' instruction tables by program name, and what
@@ -442,6 +533,7 @@ class LoopProfiler:
         self.program_source: Optional[Callable[[], Dict[str, Any]]] = None
         with _LIVE_LOCK:
             _LIVE.append(self)
+        _watch(self)
 
     # -- per-launch protocol (engine loop thread only) ------------------
 
@@ -456,7 +548,15 @@ class LoopProfiler:
             note.__exit__(None, None, None)
         last = self._last_end
         gap = max(now - last, 0.0) if last is not None else 0.0
-        return DispatchRecord(self._clock, self._seq, now, gap)
+        d = DispatchRecord(self._clock, self._seq, now, gap)
+        if self._gap_compile or self._gap_gc:
+            d.gap_compile_secs, d.gap_gc_secs = (self._gap_compile,
+                                                 self._gap_gc)
+            self._gap_compile = self._gap_gc = 0.0
+            del self._credited[:]
+        self._thread = threading.get_ident()
+        self._open = d
+        return d
 
     def idle(self, d: Optional[DispatchRecord] = None) -> None:
         """The scheduler had no action: ``d`` is no launch, and the
@@ -464,6 +564,34 @@ class LoopProfiler:
         if d is not None:
             d._note.__exit__(None, None, None)
         self._last_end = None
+        self._open = None
+        self._gap_compile = self._gap_gc = 0.0
+        del self._credited[:]
+
+    def _credit_compile(self, start: float, end: float) -> None:
+        """An event of the compile ledger ended on the loop's thread:
+        its seconds, less what was already credited inside it, go to the
+        open launch, or to the gap's account."""
+        secs = end - start
+        credited = self._credited
+        while credited and credited[-1][1] > start:
+            s, e = credited.pop()
+            secs -= e - s
+        credited.append((start, end))
+        d = self._open
+        if d is not None:
+            d.compile_secs += secs
+        else:
+            self._gap_compile += secs
+
+    def _take_medians(self, recent: List[DispatchRecord]) -> None:
+        by_kind: Dict[str, List[float]] = {}
+        for r in recent:
+            by_kind.setdefault(r.kind, []).append(
+                r.t[_FETCH + 1] - r.t[_DISPATCH])
+        self._wait_medians = {
+            k: median(v) if len(v) >= _MEDIAN_MIN_LAUNCHES else None
+            for k, v in by_kind.items()}
 
     def finish(self, d: DispatchRecord) -> None:
         """Close the record: the tail since the last mark goes to
@@ -472,9 +600,21 @@ class LoopProfiler:
         raises — the engine loop must survive any telemetry trouble."""
         now = self._clock()
         d._close(now)
+        self._open = None
+        if d.compile_secs:
+            del self._credited[:]
         t = d.t
-        stalled = (self.stall_armed
-                   and d.gap_secs > self.stall_threshold_secs)
+        # a stall: the gap before the launch over the threshold, or its
+        # own dispatch + fetch over SLOW_FACTOR times its kind's running
+        # median (slow_over) and SLOW_MIN_SECS
+        stalled, slow_over, recent = False, None, None
+        if self.stall_armed:
+            stalled = d.gap_secs > self.stall_threshold_secs
+            wait = t[_FETCH + 1] - t[_DISPATCH]
+            if wait > SLOW_MIN_SECS:
+                med = self._wait_medians.get(d.kind)
+                if med is not None and wait > SLOW_FACTOR * med:
+                    stalled, slow_over = True, med
         with self._lock:
             self.dispatches += 1
             n = self.dispatches
@@ -496,6 +636,12 @@ class LoopProfiler:
             for f in COUNTED_FIELDS:
                 totals[f] += getattr(d, f)
             self._ring.append(d)
+            if n % (_MEDIANS_EVERY if n > WINDOW_DISPATCHES
+                    else _MEDIAN_MIN_LAUNCHES) == 0:
+                recent = list(islice(reversed(self._ring),
+                                     WINDOW_DISPATCHES))
+        if recent is not None:
+            self._take_medians(recent)
         self._seq = d.seq + 1
         self._last_end = now
         self._gap_note = TraceAnnotation("loop.gap", seq=self._seq)
@@ -509,7 +655,12 @@ class LoopProfiler:
                                "gap_secs": round(d.gap_secs, 6),
                                "threshold_secs": self.stall_threshold_secs,
                                "dispatch": n,
-                               "dispatch_kind": d.kind})
+                               "dispatch_kind": d.kind,
+                               "seq": d.seq,
+                               "wait_secs": round(d.wait_secs, 6),
+                               "wait_median_secs": slow_over,
+                               "compile_secs": round(d.compile_secs, 6),
+                               "gc_secs": round(d.gc_secs, 6)})
             except Exception:   # noqa: BLE001 - diagnostics never kill
                 pass
         tracer = tracing.get_tracer()
@@ -561,7 +712,7 @@ class LoopProfiler:
                 return list(self._ring)
             return list(islice(reversed(self._ring), last))[::-1]
 
-    def totals(self) -> Dict[str, int]:
+    def totals(self) -> Dict[str, float]:
         """Every counted field (``COUNTED_FIELDS``) summed over the
         launches that finished: what the engine's ``stats()`` reports."""
         with self._lock:

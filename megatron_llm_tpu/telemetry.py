@@ -657,6 +657,18 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 #    router scores, and the two are equal unless moe_router_experts is
 #    set), and every launch record carries the same for that launch —
 #    see serving/loop_profiler.py ``MOE_FIELDS``
+# 24: a key changed in meaning: ``stalls`` of the loop block of stats() /
+#    engine_loop_stats also counts a launch whose own dispatch + fetch
+#    passed 3 times its kind's running median and 50 ms (it counted
+#    gaps over the threshold only), and the flight recorder's loop_stall
+#    entry says which (seq, wait_secs, wait_median_secs, compile_secs,
+#    gc_secs).  Added with it, and no change by the rule below: a launch
+#    record's compile_secs / gc_secs / gap_compile_secs / gap_gc_secs
+#    (serving/loop_profiler.py ``HOST_FIELDS``), stats()['startup'], and
+#    the ``startup`` record, one a process at "ready" (kind "startup":
+#    wall_secs, spans, children, compile_secs by the ledger's kinds,
+#    compile_union_secs, top_programs, cache_hits, cache_misses — see
+#    tracing.py ``startup_ready``); a recompile entry carries ``program``
 # The rule from here on: a key RENAMED, REMOVED or changed in meaning is a
 # change of schema, and so is any change to request_done's keys (the
 # lint's ratchet, analysis/telemetry_schema.py).  A counter ADDED to a
@@ -664,7 +676,7 @@ def _wants_prometheus(path: str, accept: str) -> bool:
 # 18-23 above were bumped for one by habit): readers take keys by name
 # and ignore the rest, and a launch's counters have one declaration
 # (serving/loop_profiler.py ``COUNTED_FIELDS``) that says what each is.
-TELEMETRY_SCHEMA_VERSION = 23
+TELEMETRY_SCHEMA_VERSION = 24
 STREAM_FILENAME = "telemetry.jsonl"
 FLIGHT_RECORDER_FILENAME = "flight_recorder.json"
 

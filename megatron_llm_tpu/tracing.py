@@ -23,15 +23,32 @@ layer — host-side only, nothing enters the jitted step:
   in the JSONL stream, ``run_summary()`` and the wandb/TB finish
   summary.
 
-* **RecompileDetector** — a ``jax.monitoring`` duration-event listener
-  on ``/jax/core/compile/backend_compile_duration``: every XLA compile
-  is timestamped; compiles after ``mark_steady()`` (the loop calls it
-  once the first step has compiled) are *recompiles* — the silent
-  step-time killer (a shape or layout leak retraces the whole step).
-  On jax builds without ``jax.monitoring`` the detector degrades to a
-  step-time-outlier heuristic (``observe_step_time``).  Recompiles
-  count in ``counters['recompiles']`` and emit trace spans + flight-
-  recorder entries.
+* **CompileLedger** — the module's ONE ``jax.monitoring`` listener,
+  installed when the module is imported and always on: every trace
+  (``jaxpr_trace_duration``), lowering (``jaxpr_to_mlir_module_duration``),
+  backend compile (``backend_compile_duration``) and persistent-cache load
+  (``cache_retrieval_time_sec``) is kept as an event ``(kind, program,
+  start, end, thread)`` on ``perf_counter`` and summed by program.  An
+  inner jit is traced inside its caller's trace and a cache load lies
+  inside its backend "compile", so seconds over a stretch of time are
+  the UNION of the events' intervals (``union_secs``), never their sum;
+  a program's row keeps its own sums.  Its cost is per compile event: a
+  steady state has none.
+
+* **RecompileDetector** — a reader of the ledger: every backend compile
+  it hears is timestamped and named; compiles after ``mark_steady()``
+  (the loop calls it once the first step has compiled) are *recompiles*
+  — the silent step-time killer (a shape or layout leak retraces the
+  whole step).  Recompiles count in ``counters['recompiles']`` and emit
+  trace spans + flight-recorder entries that carry the program's name.
+
+* **The start-up timeline** — ``startup_span(name)`` around each piece
+  of host work between process start and "ready" (imports, initialize,
+  build_model, ... warmup / first_step), on the same ``perf_counter``
+  as the launch ring and the request spans.  ``startup_ready()`` closes
+  it, prints one line and writes one ``startup`` JSONL record: each
+  top-level span's seconds, the ledger's union seconds by kind, and the
+  programs with the most trace + lower seconds with their counts.
 
 * **StragglerDetector** — at log boundaries the driver allgathers
   per-host section times (the ``timers.py`` ``process_allgather`` path)
@@ -73,10 +90,6 @@ GOODPUT_CATEGORIES = ("step", "compile", "checkpoint", "eval", "rewind",
 _GOODPUT_SET = frozenset(GOODPUT_CATEGORIES)
 
 TRACE_FILENAME = "trace.json"
-
-# the jax.monitoring duration event XLA emits once per backend compile
-# (fires on shape-change retraces too; silent on cache hits)
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 # ---------------------------------------------------------------------------
@@ -296,32 +309,169 @@ class SpanTracer:
 
 
 # ---------------------------------------------------------------------------
+# The compile ledger
+# ---------------------------------------------------------------------------
+
+COMPILE_KINDS = ("trace", "lower", "backend", "cache_load")
+# jax reports a "trace" for every call of a jitted function that misses
+# the C++ dispatch cache, a hit in its trace cache too: some 10 us for a
+# small function and a few tens for a program of hundreds of arguments,
+# where the smallest function traced anew takes 300-500.  A trace shorter
+# than this is such a hit: it counts in its program's row (``trace_hit``)
+# and is no event
+TRACE_HIT_SECS = 250e-6
+_KIND_INDEX = {k: i for i, k in enumerate(COMPILE_KINDS + ("trace_hit",))}
+# the jax.monitoring duration events the ledger keeps, by its kinds (a
+# backend event fires on shape-change retraces too, and on a hit in the
+# persistent cache, where it is the load and a few milliseconds more)
+_EVENT_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
+def union_secs(intervals) -> float:
+    """Seconds covered by ``(start, end)`` intervals, each instant once:
+    a trace inside a trace, or a cache load inside its backend
+    "compile", counts once."""
+    covered, hi = 0.0, float("-inf")
+    for s, e in sorted(intervals):
+        if e > hi:
+            covered += e - max(s, hi)
+            hi = e
+    return covered
+
+
+class CompileLedger:
+    """What jax traced, lowered, compiled and loaded from its persistent
+    cache, heard from ``jax.monitoring``: a bounded list of events
+    ``(kind, program, start, end, thread)`` on ``perf_counter`` (the
+    first ``capacity``; later ones still count in the rows and in
+    ``dropped``), and a row per program: count and seconds of each kind
+    (and of ``trace_hit``: see ``TRACE_HIT_SECS``).
+    A row's seconds are its events' own sums (an outer program's trace
+    includes its inner jits'); over a stretch of time ask ``secs()``,
+    which is a union.
+
+    ``hear`` runs on the compiling thread as the timed block ends, so it
+    takes no lock and formats nothing: an append and a dictionary update
+    under the GIL."""
+
+    def __init__(self, capacity: int = 65_536):
+        self.capacity = int(capacity)
+        self.events: List[tuple] = []
+        self.dropped = 0
+        # program -> [count, seconds] per kind, in _KIND_INDEX's order
+        self.programs: Dict[str, List[float]] = {}
+        self.cache_hits = 0
+        self.cache_misses = 0
+        # thread -> index in ``events`` of a cache load that waits for
+        # the name of the backend event it lies inside
+        self._loads: Dict[int, int] = {}
+        # called with every event as hear() was: the launch ring
+        self.listeners: tuple = ()
+
+    def hear(self, kind: str, name: str, duration: float) -> None:
+        end = time.perf_counter()
+        if kind == "trace" and duration < TRACE_HIT_SECS:
+            self._count("trace_hit", name, duration)
+            return
+        start = end - duration
+        tid = threading.get_ident()
+        events = self.events
+        if len(events) < self.capacity:
+            if kind == "cache_load":
+                # nameless ("" here): it takes the name of the backend
+                # event that follows it on its thread
+                self._loads[tid] = len(events)
+            events.append((kind, name, start, end, tid))
+        else:
+            self.dropped += 1
+        if kind != "cache_load":
+            self._count(kind, name, duration)
+            if kind == "backend":
+                at = self._loads.pop(tid, None)
+                if at is not None:
+                    _, _, s, e, _ = events[at]
+                    events[at] = ("cache_load", name, s, e, tid)
+                    self._count("cache_load", name, e - s)
+        for fn in self.listeners:
+            fn(kind, start, end, tid)
+
+    def _count(self, kind: str, name: str, duration: float) -> None:
+        row = self.programs.get(name)
+        if row is None:
+            row = self.programs[name] = [0, 0.0] * len(_KIND_INDEX)
+        i = 2 * _KIND_INDEX[kind]
+        row[i] += 1
+        row[i + 1] += duration
+
+    # -- reading (any thread) -------------------------------------------
+
+    def between(self, t0: float, t1: float) -> List[tuple]:
+        """The events that overlap ``[t0, t1]``, oldest end first."""
+        return [ev for ev in list(self.events)
+                if ev[3] > t0 and ev[2] < t1]
+
+    @staticmethod
+    def secs(events, t0: float = float("-inf"), t1: float = float("inf"),
+             kinds=None) -> float:
+        """Union seconds of ``events`` (of ``kinds``) inside
+        ``[t0, t1]``, on the wall clock."""
+        return union_secs(
+            (max(s, t0), min(e, t1)) for kind, _, s, e, _ in events
+            if e > t0 and s < t1 and (kinds is None or kind in kinds))
+
+    def rows(self) -> Dict[str, Dict[str, float]]:
+        """A JSON-able copy of the rows: ``{program: {trace: n,
+        trace_secs: s, lower: ..., backend: ..., cache_load: ...}}``."""
+        out = {}
+        for name, row in list(self.programs.items()):
+            out[name] = r = {}
+            for kind, i in _KIND_INDEX.items():
+                r[kind] = int(row[2 * i])
+                r[kind + "_secs"] = float(row[2 * i + 1])
+        return out
+
+
+def top_programs(events, n: int = 5) -> List[Dict[str, Any]]:
+    """The ``n`` programs with the most trace + lower seconds among
+    ``events``, with how many times each was traced and lowered."""
+    rows: Dict[str, List[float]] = {}
+    for kind, name, s, e, _ in events:
+        if kind in ("trace", "lower"):
+            row = rows.setdefault(name, [0, 0, 0.0])
+            row[kind == "lower"] += 1
+            row[2] += e - s
+    top = sorted(rows.items(), key=lambda kv: -kv[1][2])[:n]
+    return [{"program": name, "traced": int(r[0]), "lowered": int(r[1]),
+             "trace_lower_secs": round(r[2], 6)} for name, r in top]
+
+
+_LEDGER = CompileLedger()
+
+
+def compile_ledger() -> CompileLedger:
+    return _LEDGER
+
+
+# ---------------------------------------------------------------------------
 # Recompile detection
 # ---------------------------------------------------------------------------
 
 class RecompileDetector:
-    """Counts and timestamps XLA compiles; compiles after
-    ``mark_steady()`` are recompiles (MegaScale's "why did step time
-    spike" class).  ``pause()``/``resume()`` bracket phases where a
+    """Counts and timestamps the backend compiles the ledger hears while
+    it is installed; compiles after ``mark_steady()`` are recompiles
+    (MegaScale's "why did step time spike" class) and carry the
+    program's name.  ``pause()``/``resume()`` bracket phases where a
     fresh compile is *expected* (eval's forward-only program, a skipped
-    iteration's program) so they never count as recompiles.
-
-    With ``use_monitoring`` (default on any jax that has
-    ``jax.monitoring``) detection is exact — the listener hears every
-    backend compile.  The fallback flags steady-state step times beyond
-    ``outlier_factor`` x the rolling median as *suspected* recompiles."""
+    iteration's program) so they never count as recompiles."""
 
     def __init__(self, tracer: Optional[SpanTracer] = None,
-                 max_events: int = 256,
-                 use_monitoring: Optional[bool] = None,
-                 outlier_factor: float = 3.0,
-                 outlier_window: int = 32):
-        if use_monitoring is None:
-            use_monitoring = hasattr(jax, "monitoring") and hasattr(
-                jax.monitoring, "register_event_duration_secs_listener")
-        self.use_monitoring = bool(use_monitoring)
+                 max_events: int = 256):
         self.tracer = tracer
-        self.outlier_factor = float(outlier_factor)
         self.compiles = 0                   # every compile heard
         self.recompiles = 0                 # compiles while steady
         self.compile_secs_total = 0.0
@@ -330,14 +480,11 @@ class RecompileDetector:
         self._paused = 0
         self._pending_n = 0
         self._pending_secs = 0.0
-        self._recent: deque = deque(maxlen=max(int(outlier_window), 4))
         self._lock = threading.Lock()
 
-    # -- exact path (jax.monitoring) ------------------------------------
-
-    def on_compile(self, duration_secs: float) -> None:
-        """Called by the module-level jax.monitoring listener at each
-        backend-compile completion."""
+    def on_compile(self, duration_secs: float, program: str = "") -> None:
+        """Called by the ledger's listener at each backend-compile
+        completion."""
         now = time.perf_counter()
         with self._lock:
             if self._paused:
@@ -352,56 +499,28 @@ class RecompileDetector:
                 get_counters()["recompiles"] += 1
                 self.events.append({
                     "kind": "recompile", "secs": float(duration_secs),
-                    "time_unix": time.time(),
+                    "program": program, "time_unix": time.time(),
                 })
         if self.tracer is not None:
             self.tracer.completed(
                 "recompile" if is_recompile else "backend_compile",
                 "compile", start=now - duration_secs,
-                dur_secs=duration_secs)
+                dur_secs=duration_secs, program=program)
         if is_recompile:
-            print(f" [tracing] RECOMPILE detected: backend compile "
-                  f"{duration_secs:.2f}s after steady state — a shape/"
-                  f"layout change retraced the step", flush=True)
+            print(f" [tracing] RECOMPILE detected: backend compile of "
+                  f"{program or '?'} {duration_secs:.2f}s after steady "
+                  f"state — a shape/layout change retraced the step",
+                  flush=True)
             try:
                 from megatron_llm_tpu import telemetry
 
                 fr = telemetry.get_flight_recorder()
                 if fr is not None:
                     fr.record({"kind": "recompile", "time_unix": time.time(),
-                               "secs": float(duration_secs)})
+                               "secs": float(duration_secs),
+                               "program": program})
             except Exception:
                 pass
-
-    # -- fallback path (no jax.monitoring) ------------------------------
-
-    def observe_step_time(self, secs: float) -> bool:
-        """Outlier fallback: a steady-state step beyond
-        ``outlier_factor`` x the rolling median is a *suspected*
-        recompile.  No-op (False) when the exact listener is active."""
-        if self.use_monitoring:
-            return False
-        with self._lock:
-            baseline = list(self._recent)
-            suspected = (self._steady and not self._paused
-                         and len(baseline) >= 4
-                         and secs > self.outlier_factor * median(baseline))
-            if suspected:
-                self.recompiles += 1
-                get_counters()["recompiles"] += 1
-                self.events.append({
-                    "kind": "suspected_recompile", "secs": float(secs),
-                    "time_unix": time.time(),
-                })
-            else:
-                self._recent.append(float(secs))
-        if suspected:
-            if self.tracer is not None:
-                self.tracer.instant("suspected_recompile", "compile",
-                                    step_secs=float(secs))
-            print(f" [tracing] suspected recompile: step took {secs:.2f}s "
-                  f"vs rolling median {median(baseline):.2f}s", flush=True)
-        return suspected
 
     # -- driver hooks ---------------------------------------------------
 
@@ -428,30 +547,167 @@ class RecompileDetector:
         return n, secs
 
 
-# One listener forever (jax.monitoring has no unregister); it dispatches
-# to whichever detector is currently installed and is a cheap no-op
-# otherwise, so tests can install/uninstall freely.
+# One listener forever (jax.monitoring has no unregister), registered as
+# the module is imported: it feeds the ledger, and hands a backend
+# compile to whichever detector is currently installed, so tests can
+# install/uninstall freely.
 _ACTIVE_DETECTOR: Optional[RecompileDetector] = None
-_LISTENER_REGISTERED = False
 
 
 def _monitor_callback(event: str, duration: float, **kw) -> None:
-    d = _ACTIVE_DETECTOR
-    if d is not None and event == _COMPILE_EVENT:
-        try:
-            d.on_compile(float(duration))
-        except Exception:
-            pass                    # diagnostics must never break a compile
+    kind = _EVENT_KINDS.get(event)
+    if kind is None:
+        return
+    try:
+        # a trace is reported under the function's name, its lowering
+        # and compile under the module's, ``jit(<name>)``
+        name = kw.get("fun_name") or ""
+        if name.endswith(")"):
+            name = name[name.find("(") + 1:-1]
+        duration = float(duration)
+        _LEDGER.hear(kind, name, duration)
+        d = _ACTIVE_DETECTOR
+        if d is not None and kind == "backend":
+            d.on_compile(duration, name)
+    except Exception:
+        pass                        # diagnostics must never break a compile
+
+
+def _monitor_event(event: str, **kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _LEDGER.cache_hits += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        _LEDGER.cache_misses += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_monitor_callback)
+jax.monitoring.register_event_listener(_monitor_event)
 
 
 def install_detector(detector: Optional[RecompileDetector]) -> None:
-    global _ACTIVE_DETECTOR, _LISTENER_REGISTERED
+    global _ACTIVE_DETECTOR
     _ACTIVE_DETECTOR = detector
-    if (detector is not None and detector.use_monitoring
-            and not _LISTENER_REGISTERED):
-        jax.monitoring.register_event_duration_secs_listener(
-            _monitor_callback)
-        _LISTENER_REGISTERED = True
+
+
+# ---------------------------------------------------------------------------
+# The start-up timeline
+# ---------------------------------------------------------------------------
+
+# the spans of the start-up under way, ``(name, t0, t1, fields)`` on
+# perf_counter, and the last one that reached "ready" (what
+# ``startup_timeline()`` gives): a few dozen entries a process, always on
+_STARTUP: List[tuple] = []
+_STARTUP_ROOM = 512
+_STARTUP_DONE: Optional[Dict[str, Any]] = None
+
+
+def startup_begin() -> None:
+    """A start-up begins: forget the spans of an earlier one that never
+    got ready (an engine built and never started)."""
+    del _STARTUP[:]
+
+
+def startup_completed(name: str, t0: float, t1: float, **fields) -> None:
+    """An already-finished piece of the start-up.  An entry module
+    hands in ``imports`` this way, after its import block, from the
+    ``perf_counter`` stamp its FIRST statement took (this module imports
+    jax, so it cannot take that stamp itself)."""
+    if len(_STARTUP) < _STARTUP_ROOM:
+        _STARTUP.append((name, t0, t1, fields))
+
+
+@contextmanager
+def startup_span(name: str, **fields):
+    """A piece of the start-up, host side: kept on the timeline, which
+    at "ready" also goes to the SpanTracer where one is installed, under
+    category ``startup`` (the first pieces end before there is one)."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        startup_completed(name, t0, time.perf_counter(), **fields)
+
+
+def _top_level(spans: List[tuple]) -> List[tuple]:
+    """The spans that lie inside no other."""
+    return [a for a in spans
+            if not any(b is not a and b[1] <= a[1] and a[2] <= b[2]
+                       and (b[2] - b[1]) > (a[2] - a[1]) for b in spans)]
+
+
+def startup_ready(printer=print) -> Optional[Dict[str, Any]]:
+    """The program can serve or step: close the timeline at this
+    instant, print its one line, write its ``startup`` record to the
+    JSONL stream and keep it for ``startup_timeline()``.  None (and
+    nothing printed) when no span was opened since the last one."""
+    global _STARTUP_DONE
+    if not _STARTUP:
+        return None
+    ready = time.perf_counter()
+    spans = sorted(_STARTUP, key=lambda s: (s[1], -s[2]))
+    del _STARTUP[:]
+    first = spans[0][1]
+    events = _LEDGER.between(first, ready)
+    secs = {k: round(_LEDGER.secs(events, first, ready, (k,)), 6)
+            for k in COMPILE_KINDS}
+    summary = {
+        "wall_secs": round(ready - first, 6),
+        "spans": {}, "children": {}, "compile_secs": secs,
+        "compile_union_secs": round(_LEDGER.secs(events, first, ready), 6),
+        "top_programs": top_programs(events),
+        "cache_hits": _LEDGER.cache_hits,
+        "cache_misses": _LEDGER.cache_misses,
+    }
+    top = _top_level(spans)
+    for span in spans:
+        name, t0, t1, _ = span
+        into = summary["spans" if span in top else "children"]
+        into[name] = round(into.get(name, 0.0) + t1 - t0, 6)
+    _STARTUP_DONE = {"first": first, "ready": ready, "spans": spans,
+                     "events": events, "summary": summary}
+    t = _ACTIVE
+    if t is not None:
+        for name, t0, t1, fields in spans:
+            t.tracer.completed(name, "startup", start=t0, dur_secs=t1 - t0,
+                               **fields)
+    if printer is not None:
+        printer(startup_line(summary))
+    try:
+        from megatron_llm_tpu import telemetry
+
+        stream = telemetry.get_stream()
+        if stream is not None:
+            stream.emit({"kind": "startup", **summary})
+    except Exception:
+        pass
+    instant("ready", "startup")
+    return summary
+
+
+def startup_line(summary: Dict[str, Any]) -> str:
+    """The one line printed at "ready"."""
+    spans = " ".join(f"{n} {s:.2f}" for n, s in summary["spans"].items())
+    kinds = " ".join(f"{k} {s:.2f}"
+                     for k, s in summary["compile_secs"].items())
+    top = ", ".join(
+        f"{p['program']} x{p['traced']}/{p['lowered']} "
+        f"{p['trace_lower_secs']:.2f} s" for p in summary["top_programs"])
+    return (f" [startup] ready after {summary['wall_secs']:.2f} s | "
+            f"spans (s): {spans} | compile union (s): {kinds} | most "
+            f"trace + lower (traced/lowered): {top or '-'}")
+
+
+def startup_timeline() -> Optional[Dict[str, Any]]:
+    """The last start-up that reached "ready": ``first`` (its first
+    stamp) and ``ready`` on perf_counter, its ``spans`` ``(name, t0, t1,
+    fields)`` by start, the ledger's ``events`` between the two, and the
+    ``summary`` that was printed.  None before any."""
+    return _STARTUP_DONE
+
+
+def startup_summary() -> Optional[Dict[str, Any]]:
+    done = _STARTUP_DONE
+    return done["summary"] if done is not None else None
 
 
 # ---------------------------------------------------------------------------

@@ -642,7 +642,8 @@ def pretrain(
             train_cfg, params_dtype=jax.tree_util.tree_leaves(params)[0].dtype
         )
     if opt_state is None:
-        opt_state = optimizer.init(params)
+        with tracing.startup_span("build_optimizer"):
+            opt_state = optimizer.init(params)
     if scheduler is None:
         # NB: `x if x is not None else y`, not `or` — an explicit 0.0
         # start/end weight decay is a legitimate ramp-from-zero config
@@ -666,18 +667,19 @@ def pretrain(
         raise ValueError(
             "eval_iterator is not supported with a custom train_step "
             "(no forward-only program exists for it)")
-    if not custom_step:
-        train_step = build_train_step(
-            model, optimizer, parallel_cfg, num_micro, loss_func,
-            log_num_zeros_in_grad=log_num_zeros_in_grad,
-            log_layer_stats=log_layer_stats_interval > 0,
+    with tracing.startup_span("build_train_step"):
+        if not custom_step:
+            train_step = build_train_step(
+                model, optimizer, parallel_cfg, num_micro, loss_func,
+                log_num_zeros_in_grad=log_num_zeros_in_grad,
+                log_layer_stats=log_layer_stats_interval > 0,
+            )
+        eval_step = (
+            build_train_step(model, optimizer, parallel_cfg, num_micro,
+                             loss_func, forward_only=True)
+            if eval_iterator is not None
+            else None
         )
-    eval_step = (
-        build_train_step(model, optimizer, parallel_cfg, num_micro, loss_func,
-                         forward_only=True)
-        if eval_iterator is not None
-        else None
-    )
 
     base_key = mrandom.base_key(train_cfg.seed)
     counters = get_counters()
@@ -690,6 +692,10 @@ def pretrain(
     # (mutable cell because _save below also accumulates into it)
     non_train = [0.0]
     skip_step = None  # forward-only step, compiled lazily on first skip
+    # the start-up timeline (tracing.startup_span): the first call of the
+    # step until its loss is home is ``first_step``, and the program is
+    # "ready" at the second's entry
+    steps_called = 0
     ls_names = None   # health group names, resolved on first stats fetch
 
     def _layer_stats_record(ls_dev):
@@ -794,19 +800,24 @@ def pretrain(
                     recompile.resume()
             else:
                 timers("train-step", log_level=1).start()
+                if steps_called == 1:
+                    tracing.startup_ready()
                 t_step0 = time.perf_counter()
                 with tracing.span("step", "step", iteration=iteration + 1):
                     params, opt_state, metrics = train_step(
                         params, opt_state, batch, step_key, lr, wd
                     )
-                step_secs = time.perf_counter() - t_step0
+                    if steps_called == 0:
+                        jax.block_until_ready(metrics["lm loss"])
+                        tracing.startup_completed(
+                            "first_step", t_step0, time.perf_counter())
+                steps_called += 1
                 timers("train-step").stop()
                 # a compile that ran inside the dispatch span is not
                 # productive step time — reattribute it to 'compile'
                 _, csecs = recompile.drain()
                 if csecs > 0.0 and trace is not None:
                     trace.tracer.goodput.move("step", "compile", csecs)
-                recompile.observe_step_time(step_secs)
             if watchdog is not None:
                 watchdog.resume()   # (re)arms; first arm is post-compile
             iteration += 1
@@ -1120,6 +1131,8 @@ def pretrain(
         # SystemExit), or an exception — flushes in-flight async
         # saves so a durable checkpoint always gets its tracker
         root_span.__exit__(None, None, None)
+        if steps_called == 1:
+            tracing.startup_ready()     # a run of one step is ready too
         if trace is None:
             tracing.install_detector(None)
         if watchdog is not None:

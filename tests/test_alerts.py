@@ -348,7 +348,7 @@ def test_alert_transition_schema13_golden(tmp_path):
     test AND the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 23
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 24
     stream = telemetry.TelemetryStream(str(tmp_path))
 
     def sink(payload):

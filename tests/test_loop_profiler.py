@@ -521,7 +521,7 @@ def test_a_counted_field_is_summed_where_the_launch_finishes(group):
         assert totals[f] == 2 * want
         assert totals[f] == sum(getattr(r, f) for r in prof.records())
         assert prof.records()[0].as_dict()[f] == want
-    # the loop block of stats() carries the host's two and no other
+    # the loop block of stats() carries the host's four and no other
     loop = prof.stats()
     assert {f: loop[f] for f in _groups()["HOST_FIELDS"]} == {
         f: totals[f] for f in _groups()["HOST_FIELDS"]}
@@ -572,6 +572,196 @@ def test_every_counter_of_stats_is_the_sum_over_the_ring(family):
                        "kanana": {"moe", "mla"}, "mellum": {"moe", "kv"},
                        "olmoe": {"moe"}, "mistral": set()}[family]
     assert moved >= 2
+
+
+def test_the_two_lost_time_fields_go_where_every_counted_field_goes():
+    """``compile_secs`` and ``gc_secs`` are two more names of
+    HOST_FIELDS and nothing else: the record, ``as_dict()``, ``totals()``
+    and the loop block of ``stats()`` carry them by the one
+    declaration."""
+    from megatron_llm_tpu.serving.loop_profiler import (COUNTED_FIELDS,
+                                                        HOST_FIELDS)
+
+    assert {"compile_secs", "gc_secs"} <= set(HOST_FIELDS) <= set(
+        COUNTED_FIELDS)
+    clock = _Clock()
+    prof = LoopProfiler(clock=clock)
+    d = prof.begin()
+    assert d.compile_secs == 0.0 and d.gc_secs == 0.0
+    d.compile_secs, d.gc_secs = 0.25, 0.5
+    prof.finish(d)
+    prof.finish(prof.begin())
+    for where in (d.as_dict(), prof.totals(), prof.stats()):
+        assert (where["compile_secs"], where["gc_secs"]) == (0.25, 0.5)
+    idle = prof.records()[1].as_dict()
+    assert (idle["compile_secs"], idle["gc_secs"]) == (0.0, 0.0)
+    assert (idle["gap_compile_secs"], idle["gap_gc_secs"]) == (0.0, 0.0)
+
+
+def test_a_forced_collection_lands_in_its_launch_and_in_no_other():
+    import gc
+
+    prof = LoopProfiler()
+    other = LoopProfiler()              # a second engine's, with no launch
+    gc.disable()                        # no collection but the forced ones
+    try:
+        prof.finish(prof.begin())
+        d = prof.begin()
+        gc.collect()
+        prof.finish(d)
+        prof.finish(prof.begin())
+        gc.collect()                    # in the gap: the NEXT record's
+        g = prof.begin()
+        prof.finish(g)
+        prof.idle()
+        gc.collect()                    # the engine waits for work: nobody's
+        prof.idle(prof.begin())
+        last = prof.begin()
+        prof.finish(last)
+    finally:
+        gc.enable()
+    recs = prof.records()
+    assert d.gc_secs > 0.0 and g.gap_gc_secs > 0.0
+    assert [r.gc_secs for r in recs if r is not d] == [0.0] * 4
+    assert [r.gap_gc_secs for r in recs if r is not g] == [0.0] * 4
+    assert prof.totals()["gc_secs"] == d.gc_secs
+    assert other.totals()["gc_secs"] == 0.0 and other._gap_gc > 0.0
+
+
+def test_compile_events_are_a_union_on_the_launchs_thread():
+    """The ledger's listener credits the open launch of the profiler
+    whose loop runs on the compiling thread: nested events once, another
+    thread's not at all, and with no launch open the gap's account."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+
+    prof = LoopProfiler()
+    prof.finish(prof.begin())
+    d = prof.begin()
+
+    @jax.jit
+    def inner_of_a_launch(x):
+        return jnp.cos(x) @ x
+
+    t0 = time.perf_counter()
+    jax.jit(lambda x: inner_of_a_launch(x) * 3.0)(
+        jnp.ones((5, 5))).block_until_ready()
+    took = time.perf_counter() - t0
+    elsewhere = threading.Thread(target=lambda: jax.jit(
+        lambda x: x - 7.0)(jnp.ones((3,))).block_until_ready())
+    elsewhere.start()
+    elsewhere.join()
+    prof.finish(d)
+    events = tracing.compile_ledger().between(t0, t0 + took)
+    mine = [e for e in events if e[4] == threading.get_ident()]
+    assert 0.0 < d.compile_secs <= took
+    assert d.compile_secs == pytest.approx(
+        tracing.CompileLedger.secs(mine), rel=1e-6)
+    assert d.compile_secs < sum(e[3] - e[2] for e in mine)    # nested
+    # in the gap: the next record's gap_compile_secs
+    jax.jit(lambda x: x + 11.0)(jnp.ones((2,))).block_until_ready()
+    g = prof.begin()
+    prof.finish(g)
+    assert g.gap_compile_secs > 0.0 and g.compile_secs == 0.0
+    assert prof.totals()["compile_secs"] == d.compile_secs
+
+
+def test_a_launch_far_over_its_kinds_median_is_a_stall(tmp_path):
+    """The stall detector learns a launch's own length: dispatch + fetch
+    over 3 times the running median of its kind and over 50 ms counts,
+    with what it lost the time to in its flight-recorder entry."""
+    from megatron_llm_tpu.serving import loop_profiler as lp
+
+    stream = telemetry.TelemetryStream(str(tmp_path))
+    telemetry.install_stream(stream)
+    try:
+        clock = _Clock()
+        prof = LoopProfiler(clock=clock)
+        # warm-up: long launches (compiles), not armed, never stalls
+        for _ in range(2):
+            _dispatch(prof, clock, dispatch=5.0)
+        assert prof.stalls == 0
+        prof.stall_armed = True
+        for _ in range(lp._MEDIAN_MIN_LAUNCHES):
+            _dispatch(prof, clock, dispatch=0.004, fetch=0.016)
+            _dispatch(prof, clock, kind="prefill", dispatch=0.1, fetch=0.1)
+        assert prof.stalls == 0
+        # 3x the median but under 50 ms: no stall; a prefill chunk of its
+        # usual 200 ms: no stall
+        _dispatch(prof, clock, dispatch=0.004, fetch=0.044)
+        _dispatch(prof, clock, kind="prefill", dispatch=0.1, fetch=0.12)
+        assert prof.stalls == 0
+        d = prof.begin()
+        d.kind = "decode"
+        d.mark("build_inputs")
+        clock.tick(0.3)
+        d.mark("dispatch")
+        d.compile_secs, d.gc_secs = 0.2, 0.05
+        d.mark("fetch")
+        prof.finish(d)
+        assert prof.stalls == 1
+        (rec,) = [r for r in stream.flight_recorder.records()
+                  if r.get("kind") == "loop_stall"]
+        assert rec["seq"] == d.seq and rec["dispatch_kind"] == "decode"
+        assert rec["wait_secs"] == pytest.approx(0.3)
+        assert rec["wait_median_secs"] == pytest.approx(0.02)
+        assert (rec["compile_secs"], rec["gc_secs"]) == (0.2, 0.05)
+        assert rec["gap_secs"] == 0.0
+    finally:
+        telemetry.install_stream(None)
+        stream.close()
+
+
+def test_an_engines_launches_say_what_they_compiled_and_when_they_slept(
+        tmp_path):
+    """A fresh engine's first chunk compiles its program inside the
+    launch (``compile_secs`` > 0); warm-up's launches never count as
+    stalls; the steady launches after it have exactly 0.0; and a launch
+    made slow by a sleeping program counts in ``stalls``."""
+    from megatron_llm_tpu.serving import loop_profiler as lp
+
+    stream = telemetry.TelemetryStream(str(tmp_path))
+    telemetry.install_stream(stream)
+    try:
+        tracing.startup_begin()
+        eng = _tiny_engine()
+        prof = eng.loop_profiler
+        warm = prof.records()
+        assert warm[0].kind == "prefill" and warm[0].compile_secs > 0.0
+        assert warm[0].compile_secs <= warm[0].wall_secs
+        assert prof.stalls == 0 and prof.stall_armed
+        # the timeline named warm-up's children by the programs traced
+        _serve(eng, n=2, prompt=20, new=lp._MEDIAN_MIN_LAUNCHES + 2)
+        kids = set(eng.stats()["startup"]["children"])
+        assert {"warmup.engine_prefill", "warmup.engine_sample_first",
+                "warmup.engine_decode", "warmup.engine_cow_copy"} <= kids
+        steady = [r for r in prof.records() if r.seq > warm[-1].seq]
+        assert len(steady) > lp._MEDIAN_MIN_LAUNCHES
+        assert [r.compile_secs for r in steady] == [0.0] * len(steady)
+        assert prof.totals()["compile_secs"] == pytest.approx(
+            sum(r.compile_secs for r in warm))
+        program, slept = eng._decode_step, []
+
+        def sleepy(*args):
+            if not slept:
+                slept.append(eng.loop_profiler._seq)
+                time.sleep(0.4)
+            return program(*args)
+
+        eng._decode_step = sleepy
+        before = prof.stalls
+        _serve(eng, n=1, prompt=12, new=4)
+        assert prof.stalls > before
+        found = [r for r in stream.flight_recorder.records()
+                 if r.get("kind") == "loop_stall" and r["seq"] == slept[0]]
+        assert found and found[0]["wait_secs"] >= 0.4
+        assert found[0]["compile_secs"] == 0.0
+        assert found[0]["wait_median_secs"] * lp.SLOW_FACTOR < 0.4
+    finally:
+        telemetry.install_stream(None)
+        stream.close()
 
 
 def test_registry_yields_the_live_profiler_without_the_engine():

@@ -308,6 +308,10 @@ def test_engine_zero_recompiles_after_warmup(engine, model_and_params,
             sentinel.evaluate()     # pump the alert evaluator mid-traffic
         assert det.recompiles == 0, \
             f"{det.recompiles} recompiles after warmup: {list(det.events)}"
+        # and no launch of the traffic traced, lowered or loaded anything
+        # (the launch ring's compile_secs, from the same ledger)
+        served = engine.loop_profiler.records()[-20:]
+        assert [r.compile_secs for r in served] == [0.0] * len(served)
         assert sentinel.counters["evaluations"] == 10
         assert not sentinel.snapshot()["firing"]
         # the observability stack saw every request while staying free
@@ -349,7 +353,7 @@ def test_request_done_schema_golden(engine, tmp_path):
     the schema history comment in telemetry.py)."""
     from megatron_llm_tpu import telemetry
 
-    assert telemetry.TELEMETRY_SCHEMA_VERSION == 23
+    assert telemetry.TELEMETRY_SCHEMA_VERSION == 24
     captured = []
     engine.request_done_hook = captured.append
     stream = telemetry.TelemetryStream(str(tmp_path))

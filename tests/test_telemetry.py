@@ -248,7 +248,8 @@ def test_structured_stream_schema_and_profiler_xplane(utils, tmp_path):
     # the flight recorder saw both per-iteration dispatch entries and the
     # full log records
     kinds = {rec["kind"] for rec in tel.stream.flight_recorder.records()}
-    assert kinds == {"dispatch", "log"}
+    # (and, since PR 49, the one ``startup`` record written at "ready")
+    assert kinds == {"dispatch", "log", "startup"}
     # run aggregates for the wandb/TB finish() summary
     s = tel.stream.summary()
     assert s["log_boundaries"] == 4 and s["mean_mfu"] is None

@@ -8,6 +8,7 @@ rewind/rescue spans under injected faults, and the generation server's
 /metrics + /health endpoints."""
 
 import argparse
+import functools
 import importlib.util
 import json
 import os
@@ -273,27 +274,33 @@ def test_straggler_no_flag_cases():
 
 def test_recompile_counting_on_forced_shape_change():
     """A second input shape after mark_steady() retraces the jitted fn;
-    the jax.monitoring listener counts it as a recompile (>= 1 — the
-    backend may also compile auxiliary constant programs)."""
-    if not (hasattr(jax, "monitoring") and hasattr(
-            jax.monitoring, "register_event_duration_secs_listener")):
-        pytest.skip("jax.monitoring not available")
+    the detector, a reader of the compile ledger, counts it as a
+    recompile (>= 1 — the backend may also compile auxiliary constant
+    programs) and carries the program's name."""
     tr = SpanTracer()
     det = RecompileDetector(tracer=tr)
-    assert det.use_monitoring
     install_detector(det)
+    ledger = tracing.compile_ledger()
     try:
-        f = jax.jit(lambda x: x * 2.0 + 1.0)
+        def reshaped(x):
+            return x * 2.0 + 1.0
+        f = jax.jit(reshaped)
         f(jnp.ones((4,))).block_until_ready()        # expected compile
         assert det.compiles >= 1 and det.recompiles == 0
+        row = dict(ledger.rows()["reshaped"])
+        assert (row["trace"], row["lower"], row["backend"]) == (1, 1, 1)
         det.mark_steady()
         f(jnp.ones((8,))).block_until_ready()        # forced retrace
         assert det.recompiles >= 1
         assert get_counters()["recompiles"] == det.recompiles
         assert det.events and det.events[-1]["kind"] == "recompile"
-        names = {e["name"] for e in tr.chrome_trace()["traceEvents"]
-                 if e["ph"] == "X"}
-        assert "recompile" in names
+        assert det.events[-1]["program"] == "reshaped"
+        # the new shape is a trace + lower + backend under the same name
+        row = ledger.rows()["reshaped"]
+        assert (row["trace"], row["lower"], row["backend"]) == (2, 2, 2)
+        named = [e for e in tr.chrome_trace()["traceEvents"]
+                 if e["ph"] == "X" and e["name"] == "recompile"]
+        assert named and named[-1]["args"]["program"] == "reshaped"
         n, secs = det.drain()
         assert n == det.compiles and secs >= 0.0
         assert det.drain() == (0, 0.0)
@@ -302,9 +309,6 @@ def test_recompile_counting_on_forced_shape_change():
 
 
 def test_recompile_pause_suppresses_expected_compiles():
-    if not (hasattr(jax, "monitoring") and hasattr(
-            jax.monitoring, "register_event_duration_secs_listener")):
-        pytest.skip("jax.monitoring not available")
     det = RecompileDetector()
     install_detector(det)
     try:
@@ -317,23 +321,183 @@ def test_recompile_pause_suppresses_expected_compiles():
         install_detector(None)
 
 
-def test_recompile_outlier_fallback():
-    """Without jax.monitoring, a steady-state step beyond 3x the rolling
-    median is a *suspected* recompile."""
+# ---------------------------------------------------------------------------
+# The compile ledger
+# ---------------------------------------------------------------------------
+
+def _kernel(x, scale):
+    for _ in range(6):          # enough work that a trace is no "hit"
+        x = jnp.tanh(x) * scale + 1.0
+    return x
+
+
+_kernel_entry = jax.jit(_kernel, static_argnames=("scale",))
+
+
+@pytest.mark.parametrize("jitted_entry", [False, True])
+def test_ledger_names_a_kernel_traced_once_a_layer(jitted_entry):
+    """PR 48's finding as a test: a kernel that a model calls in a
+    Python loop of N layers through a jit made anew at every call is
+    traced N times under its name; with ONE jitted entry it is traced
+    once (the other layers hit jax's trace cache: ``trace_hit``)."""
+    layers = 6
+    ledger = tracing.compile_ledger()
+    name = "_kernel"
+    before = dict(ledger.rows().get(name) or {"trace": 0, "trace_hit": 0})
+    mark = len(ledger.events)
+
+    def model(x):
+        for _ in range(layers):
+            if jitted_entry:
+                x = _kernel_entry(x, scale=2.0)
+            else:
+                x = jax.jit(functools.partial(_kernel, scale=2.0))(x)
+        return x
+
+    shape = (3, 5 + int(jitted_entry))
+    jax.jit(model)(jnp.ones(shape)).block_until_ready()
+    row = ledger.rows()[name]
+    traced = row["trace"] - before["trace"]
+    events = [e for e in ledger.events[mark:]
+              if e[0] == "trace" and e[1] == name]
+    assert len(events) == traced
+    if jitted_entry:
+        assert 1 <= traced < layers
+        assert row["trace_hit"] - before["trace_hit"] >= layers - traced
+    else:
+        assert traced == layers
+    # every event is on perf_counter, ends in the past, and is no hit
+    now = time.perf_counter()
+    assert all(e[2] < e[3] <= now
+               and e[3] - e[2] >= tracing.TRACE_HIT_SECS for e in events)
+
+
+def test_ledger_union_against_sum_for_a_nested_jit():
+    """An inner jit is traced inside its caller's trace: the seconds of
+    a stretch are the union of the events' intervals, which the sum of
+    the events (and so of the programs' rows) exceeds."""
+    ledger = tracing.compile_ledger()
+
+    @jax.jit
+    def inner_for_union(x):
+        for _ in range(20):
+            x = jnp.sin(x) @ x
+        return x
+
+    def outer_for_union(x):
+        return inner_for_union(x) + 1.0
+
+    t0 = time.perf_counter()
+    jax.jit(outer_for_union)(jnp.ones((7, 7))).block_until_ready()
+    t1 = time.perf_counter()
+    events = ledger.between(t0, t1)
+    traces = {e[1]: e for e in events if e[0] == "trace"}
+    inner, outer = traces["inner_for_union"], traces["outer_for_union"]
+    assert outer[2] <= inner[2] and inner[3] <= outer[3]      # nested
+    both = [e for e in events if e[1] in ("inner_for_union",
+                                          "outer_for_union")]
+    union = ledger.secs(both, t0, t1, ("trace",))
+    summed = sum(e[3] - e[2] for e in both if e[0] == "trace")
+    assert union == pytest.approx(outer[3] - outer[2])
+    assert summed == pytest.approx(union + inner[3] - inner[2])
+    # the union of everything never exceeds the stretch it lies in
+    assert 0.0 < ledger.secs(events, t0, t1) <= t1 - t0
+    assert tracing.top_programs(both)[0]["program"] == "outer_for_union"
+
+
+def test_union_secs_counts_each_instant_once():
+    assert tracing.union_secs([]) == 0.0
+    assert tracing.union_secs([(0, 4), (1, 2), (3, 6), (8, 9)]) == 7
+    # a cache load inside its backend "compile", cut to a stretch
+    events = [("cache_load", "p", 1.0, 1.5, 1), ("backend", "p", 0.9, 1.6, 1),
+              ("trace", "q", 2.0, 3.0, 1)]
+    secs = tracing.CompileLedger.secs
+    assert secs(events) == pytest.approx(1.7)
+    assert secs(events, kinds=("cache_load",)) == pytest.approx(0.5)
+    assert secs(events, 1.2, 2.5) == pytest.approx(0.4 + 0.5)
+
+
+def test_ledger_names_a_cache_load_after_its_backend_event():
+    """A load from the persistent cache is reported with no name, inside
+    the backend event that follows it on its thread."""
+    ledger = tracing.CompileLedger(capacity=8)
+    heard = []
+    ledger.listeners += (lambda *a: heard.append(a),)
+    ledger.hear("trace", "prog", 0.004)
+    ledger.hear("trace", "prog", tracing.TRACE_HIT_SECS / 4)    # a hit
+    ledger.hear("lower", "prog", 0.002)
+    ledger.hear("cache_load", "", 0.010)
+    ledger.hear("backend", "prog", 0.011)
+    kinds = [(e[0], e[1]) for e in ledger.events]
+    assert kinds == [("trace", "prog"), ("lower", "prog"),
+                     ("cache_load", "prog"), ("backend", "prog")]
+    row = ledger.rows()["prog"]
+    assert (row["trace"], row["trace_hit"], row["lower"], row["backend"],
+            row["cache_load"]) == (1, 1, 1, 1, 1)
+    assert row["cache_load_secs"] == pytest.approx(0.010)
+    assert [h[0] for h in heard] == ["trace", "lower", "cache_load",
+                                     "backend"]
+    # full: later events still count in the rows
+    for _ in range(6):
+        ledger.hear("lower", "prog", 0.001)
+    assert len(ledger.events) == 8 and ledger.dropped == 2
+    assert ledger.rows()["prog"]["lower"] == 7
+
+
+# ---------------------------------------------------------------------------
+# The start-up timeline
+# ---------------------------------------------------------------------------
+
+def test_startup_timeline_spans_line_and_record(tmp_path):
+    stream = telemetry.TelemetryStream(str(tmp_path))
+    telemetry.install_stream(stream)
     tr = SpanTracer()
-    det = RecompileDetector(tracer=tr, use_monitoring=False)
-    for _ in range(5):
-        assert not det.observe_step_time(0.1)        # builds the baseline
-    det.mark_steady()
-    assert not det.observe_step_time(0.12)           # normal jitter
-    assert det.observe_step_time(1.0)                # 10x the median
-    assert det.recompiles == 1
-    assert get_counters()["recompiles"] == 1
-    assert det.events[-1]["kind"] == "suspected_recompile"
-    assert [e for e in tr.chrome_trace()["traceEvents"]
-            if e["ph"] == "i" and e["name"] == "suspected_recompile"]
-    # the exact path no-ops the fallback entirely
-    assert not RecompileDetector(use_monitoring=True).observe_step_time(99)
+    tracing.startup_begin()
+    t_first = time.perf_counter()
+    time.sleep(0.002)
+    tracing.startup_completed("imports", t_first, time.perf_counter())
+    with tracing.startup_span("initialize"):
+        with tracing.startup_span("runtime_init"):
+            time.sleep(0.002)
+    # a tracer installed now is handed what ended before it
+    install_tracing(Tracing(tracer=tr))
+    with tracing.startup_span("build_model", model_name="toy"):
+        jax.jit(lambda x: x * 5.0 - 2.0)(jnp.ones((6,))).block_until_ready()
+    lines = []
+    summary = tracing.startup_ready(printer=lines.append)
+    stream.close()
+    tl = tracing.startup_timeline()
+    assert tl["summary"] is summary is tracing.startup_summary()
+    names = [s[0] for s in tl["spans"]]
+    assert names == ["imports", "initialize", "runtime_init", "build_model"]
+    assert tl["first"] == t_first and tl["ready"] <= time.perf_counter()
+    # top level: ordered, not overlapping, inside [first, ready]
+    top = tracing._top_level(tl["spans"])
+    assert [s[0] for s in top] == ["imports", "initialize", "build_model"]
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+    assert list(summary["spans"]) == ["imports", "initialize", "build_model"]
+    # the ledger's events, cut to the timeline; their union inside a
+    # span never exceeds the span
+    assert tl["events"] and all(e[3] > tl["first"] and e[2] < tl["ready"]
+                                for e in tl["events"])
+    for _, t0, t1, _ in tl["spans"]:
+        assert tracing.CompileLedger.secs(tl["events"], t0, t1) <= t1 - t0
+    assert summary["compile_secs"]["trace"] > 0.0
+    assert summary["compile_union_secs"] <= summary["spans"]["build_model"]
+    assert summary["top_programs"][0]["traced"] >= 1
+    assert len(lines) == 1 and lines[0].startswith(" [startup] ready after")
+    assert "build_model" in lines[0]
+    recs = [json.loads(l) for l in
+            open(os.path.join(str(tmp_path), "telemetry.jsonl"))]
+    started = [r for r in recs if r["kind"] == "startup"]
+    assert len(started) == 1
+    assert started[0]["spans"] == summary["spans"]
+    cats = {e["name"]: e["cat"] for e in tr.chrome_trace()["traceEvents"]
+            if e["ph"] in ("X", "i")}
+    assert {cats[n] for n in names + ["ready"]} == {"startup"}
+    # nothing opened since: no second line, and the timeline stays
+    assert tracing.startup_ready(printer=lines.append) is None
+    assert len(lines) == 1 and tracing.startup_timeline() is tl
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,10 @@
 """Load a checkpoint and serve generation over REST
 (reference: tools/run_text_generation_server.py)."""
 
+import time
+
+_FIRST_STAMP = time.perf_counter()  # before the imports: tracing's timeline
+
 import os
 import sys
 
@@ -9,13 +13,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-from megatron_llm_tpu import checkpointing, global_vars
+from megatron_llm_tpu import checkpointing, global_vars, telemetry, tracing
 from megatron_llm_tpu.arguments import transformer_config_from_args
 from megatron_llm_tpu.initialize import initialize_megatron
 from megatron_llm_tpu.models import MODEL_REGISTRY
 from megatron_llm_tpu.parallel import sharding as sh
+from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
 from megatron_llm_tpu.text_generation_server import (
     MegatronServer, build_server_alerts)
+
+tracing.startup_completed("imports", _FIRST_STAMP, time.perf_counter())
 
 
 def extra_args(parser):
@@ -43,7 +50,6 @@ def build_server(args, argv):
     # JSONL (analyze offline with tools/serve_report.py), --trace_dir
     # records Chrome spans with per-request trace ids (merge with the
     # router's file via tools/trace_report.py --merge)
-    from megatron_llm_tpu import telemetry, tracing
     if args.structured_log_dir:
         telemetry.install_stream(
             telemetry.TelemetryStream(args.structured_log_dir))
@@ -53,34 +59,40 @@ def build_server(args, argv):
     # same per-model presets and derivations as finetune.py: the CLI is
     # self-sufficient (--model_name=llama2 implies rotary/swiglu/
     # rmsnorm/no-bias; gemma gets its sqrt(hidden) embedding scale)
-    from finetune import MODEL_DEFAULTS, _apply_model_defaults, model_provider
-    if args.model_name in MODEL_DEFAULTS:
-        _apply_model_defaults(args, argv)
-        model = model_provider(args)
-    else:
-        model = MODEL_REGISTRY[args.model_name](
-            transformer_config_from_args(args)
-        )
+    # (a family's lazy imports happen inside build_model and count there)
+    with tracing.startup_span("build_model", model_name=args.model_name):
+        from finetune import (MODEL_DEFAULTS, _apply_model_defaults,
+                              model_provider)
+        if args.model_name in MODEL_DEFAULTS:
+            _apply_model_defaults(args, argv)
+            model = model_provider(args)
+        else:
+            model = MODEL_REGISTRY[args.model_name](
+                transformer_config_from_args(args)
+            )
     if args.load:
-        params, _, _ = checkpointing.load_checkpoint(args.load, finetune=True)
+        with tracing.startup_span("load_checkpoint"):
+            params, _, _ = checkpointing.load_checkpoint(args.load,
+                                                         finetune=True)
     else:
         print(" no --load given: serving a randomly initialized model")
-        params = sh.init_params(model, jax.random.PRNGKey(args.seed))
-    specs = model.param_specs(params)
-    if args.int8_weights:
-        from megatron_llm_tpu.quantization import (
-            quantize_linear_weights_int8, quantize_param_specs,
-            quantized_weight_bytes)
-        params = quantize_linear_weights_int8(params)
-        specs = quantize_param_specs(specs, params)
-        qb, fb = quantized_weight_bytes(params)
-        print(f" int8 weights: {qb/1e6:.1f} MB int8 + {fb/1e6:.1f} MB float")
-    params = sh.shard_params(params, specs)
+        with tracing.startup_span("init_params"):
+            params = sh.init_params(model, jax.random.PRNGKey(args.seed))
+    with tracing.startup_span("shard_params"):
+        specs = model.param_specs(params)
+        if args.int8_weights:
+            from megatron_llm_tpu.quantization import (
+                quantize_linear_weights_int8, quantize_param_specs,
+                quantized_weight_bytes)
+            params = quantize_linear_weights_int8(params)
+            specs = quantize_param_specs(specs, params)
+            qb, fb = quantized_weight_bytes(params)
+            print(f" int8 weights: {qb/1e6:.1f} MB int8 + "
+                  f"{fb/1e6:.1f} MB float")
+        params = sh.shard_params(params, specs)
     tokenizer = global_vars.get_tokenizer()
     engine = None
     if args.serve_engine:
-        from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
-
         engine = InferenceEngine(model, params, EngineConfig(
             num_slots=args.serve_num_slots,
             block_size=args.serve_block_size,
@@ -111,7 +123,6 @@ def build_server(args, argv):
                 if engine.speculative else "off")
         print(f" * speculative decoding: {spec}", flush=True)
         engine.warmup()
-        from megatron_llm_tpu import tracing
         tr = tracing.get_tracing()
         if tr is not None and tr.recompile is not None:
             tr.recompile.mark_steady()
@@ -122,6 +133,9 @@ def build_server(args, argv):
                             log_requests=args.log_requests,
                             max_prompts=args.serve_max_prompts,
                             max_tokens=args.serve_max_tokens)
+    # without an engine the server itself is what is ready (with one, the
+    # timeline closed at engine.start() and this is nothing)
+    tracing.startup_ready()
     # SLO sentinel (serving/alerts.py): burn-rate + threshold alerting
     # over this replica's own /metrics, postmortem bundles under
     # <structured_log_dir>/incidents, transitions on the JSONL stream

@@ -133,6 +133,7 @@ def _load(path: str):
     if not os.path.exists(path):
         raise FileNotFoundError(f"no serve log at {path}")
     records, events, fleet, loop, cache, alerts = [], [], [], [], [], []
+    startup = []
     with open(path) as f:
         for line in f:
             try:
@@ -149,6 +150,8 @@ def _load(path: str):
                     and rec.get("event") in FLEET_EVENTS:
                 fleet.append(rec)
                 continue
+            if rec.get("kind") == "startup":
+                startup.append(rec)
             if rec.get("kind") != "serve":
                 continue
             if rec.get("event") == "request_done":
@@ -159,7 +162,7 @@ def _load(path: str):
                 cache.append(rec)
             elif rec.get("event") in RESILIENCE_EVENTS:
                 events.append(rec)
-    return records, events, fleet, loop, cache, alerts
+    return records, events, fleet, loop, cache, alerts, startup
 
 
 def _percentile(values: List[float], q: float) -> Optional[float]:
@@ -497,8 +500,10 @@ def analyze(paths: List[str], ttft_slo: float = 1.0,
     loop_per_path: List[List[Dict]] = []
     cache_per_path: List[List[Dict]] = []
     all_alerts: List[Dict] = []
+    all_startup: List[Dict] = []
     for p in paths:
-        records, events, fleet, loop, cache, alerts = _load(p)
+        records, events, fleet, loop, cache, alerts, startup = _load(p)
+        all_startup.extend(startup)
         all_records.extend(records)
         all_events.extend(events)
         all_fleet.extend(fleet)
@@ -558,6 +563,10 @@ def analyze(paths: List[str], ttft_slo: float = 1.0,
                                             all_events)
     if per_replica:
         out["replicas"] = per_replica
+    if all_startup:
+        # the one ``startup`` record a replica writes at "ready"
+        # (tracing.startup_ready): its spans and the compile ledger's sums
+        out["startup"] = all_startup
     return out
 
 
@@ -911,6 +920,18 @@ def render(report: Dict) -> str:
                         if s['slo']['joint_attained'] is not None
                         else "-") + "):")
         lines += _latency_lines(s)
+
+    for st in report.get("startup") or []:
+        spans = " ".join(f"{n} {v:.2f}" for n, v in st["spans"].items())
+        kinds = " ".join(f"{k} {v:.2f}"
+                         for k, v in st["compile_secs"].items())
+        lines.append(f"\nstart-up: ready after {st['wall_secs']:.2f}s | "
+                     f"spans (s): {spans}")
+        lines.append(f"  compile union (s): {kinds}")
+        for p in st.get("top_programs") or []:
+            lines.append(f"  {p['trace_lower_secs']:8.2f}s trace + lower  "
+                         f"{p['program']} (traced x{p['traced']}, lowered "
+                         f"x{p['lowered']})")
     return "\n".join(lines)
 
 

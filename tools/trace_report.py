@@ -113,11 +113,25 @@ def recompile_timeline(trace: Dict) -> List[Dict]:
     for e in spans(trace):
         if e.get("name") == "recompile":
             out.append({"at_secs": e["ts"] / 1e6,
-                        "compile_secs": e.get("dur", 0.0) / 1e6})
-    for e in instants(trace, "suspected_recompile"):
-        out.append({"at_secs": e["ts"] / 1e6, "suspected": True,
-                    "step_secs": (e.get("args") or {}).get("step_secs")})
+                        "compile_secs": e.get("dur", 0.0) / 1e6,
+                        "program": (e.get("args") or {}).get("program")})
     return sorted(out, key=lambda r: r["at_secs"])
+
+
+def startup_spans(trace: Dict) -> List[Dict]:
+    """The start-up timeline (category ``startup``: tracing.startup_span)
+    by start, each with how deep it lies inside the others."""
+    xs = sorted((e for e in spans(trace) if e.get("cat") == "startup"),
+                key=lambda e: (e["ts"], -e.get("dur", 0.0)))
+    out = []
+    for e in xs:
+        end = e["ts"] + e.get("dur", 0.0)
+        depth = sum(1 for o in xs if o is not e and o["ts"] <= e["ts"]
+                    and end <= o["ts"] + o.get("dur", 0.0)
+                    and o.get("dur", 0.0) > e.get("dur", 0.0))
+        out.append({"name": e["name"], "at_secs": e["ts"] / 1e6,
+                    "secs": e.get("dur", 0.0) / 1e6, "depth": depth})
+    return out
 
 
 def straggler_timeline(trace: Dict) -> List[Dict]:
@@ -272,16 +286,19 @@ def render(trace: Dict, top_n: int, trend: List[Dict]) -> str:
                          f"{s['name']} [{s['category']}] "
                          f"@ {s['start_secs']:.2f}s{extra}")
 
+    boot = startup_spans(trace)
+    if boot:
+        lines.append("\nstart-up timeline (first stamp to ready):")
+        for b in boot:
+            lines.append(f"  @ {b['at_secs']:8.2f}s {b['secs']:8.2f}s  "
+                         f"{'  ' * b['depth']}{b['name']}")
+
     rec = recompile_timeline(trace)
     lines.append(f"\nrecompiles: {other.get('recompiles', len(rec))}")
     for r in rec:
-        if r.get("suspected"):
-            lines.append(f"  @ {r['at_secs']:.2f}s suspected (step "
-                         f"{(r.get('step_secs') or 0.0):.2f}s, outlier "
-                         f"heuristic)")
-        else:
-            lines.append(f"  @ {r['at_secs']:.2f}s backend compile "
-                         f"{r['compile_secs']:.2f}s after steady state")
+        lines.append(f"  @ {r['at_secs']:.2f}s backend compile of "
+                     f"{r.get('program') or '?'} "
+                     f"{r['compile_secs']:.2f}s after steady state")
 
     st = straggler_timeline(trace)
     lines.append(f"\nstraggler events: {other.get('straggler_events', len(st))}")
