@@ -44,7 +44,8 @@ from megatron_llm_tpu.optimizer import (
     MegatronOptimizer,
     OptimizerParamScheduler,
 )
-from megatron_llm_tpu.parallel import sharding as sh
+from megatron_llm_tpu.optimizer.optimizer import map_param_trees
+from megatron_llm_tpu.parallel import glu_pairs, sharding as sh
 from megatron_llm_tpu.training import pretrain
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -508,9 +509,28 @@ def main():
                     stream=getattr(telemetry, "stream", None))
         tracing.startup_completed("load_checkpoint", t_load,
                                   time.perf_counter())
+    # the trainer's form of the tree (parallel/glu_pairs.py): under tp a
+    # gated MLP's first projection is held with gate / up an axis of its
+    # own; checkpoints (load above, save_checkpoint) keep the flat form
+    lora_targets = tuple(
+        t for t in args.lora_targets.split(",") if t) if args.lora_rank else ()
+    # (a projection that will carry LoRA leaves stays flat)
+    form = (None if glu_pairs.FIRST in lora_targets
+            else glu_pairs.for_trainer)
     if params is None:
         with tracing.startup_span("init_params"):
-            params = sh.init_params(model, jax.random.PRNGKey(args.seed))
+            params = sh.init_params(model, jax.random.PRNGKey(args.seed),
+                                    form=form)
+    elif form is not None:
+        params = form(params)
+    if sh.axis_size("ffn") > 1:
+        n_paired, n_flat = glu_pairs.count(params)
+        print(f" gated first projections held [gate, up] paired over tp: "
+              f"{n_paired} leaves ({n_flat} left flat: LoRA leaves or int8 "
+              f"scales)", flush=True)
+        if getattr(telemetry, "stream", None) is not None:
+            telemetry.stream.emit({"kind": "glu_pairs", "paired": n_paired,
+                                   "left_flat": n_flat})
 
     # interleaved VPP trains with the layer stack in stage-major order;
     # checkpoints stay in natural order (see pipeline.permute_layer_stack)
@@ -573,8 +593,7 @@ def main():
         model = LoraAdapter(model, lora_base)
         lora = model.init_lora(
             args.lora_rank, jax.random.PRNGKey(args.seed + 1),
-            alpha=args.lora_alpha,
-            targets=tuple(t for t in args.lora_targets.split(",") if t))
+            alpha=args.lora_alpha, targets=lora_targets)
         params = sh.shard_params(lora, model.param_specs(lora))
         n_ad = model.num_params(params)
         print(f" > LoRA rank {args.lora_rank}: {n_ad/1e6:.2f}M adapter "
@@ -611,8 +630,8 @@ def main():
     if args.load and start_iteration and not args.finetune:
         opt_template = jax.eval_shape(
             lambda p: optimizer.init(convert_params_layout(
-                p, args.num_layers, pc.pipeline_model_parallel_size, vpp,
-                to_stage_major=False)),
+                glu_pairs.flat(p), args.num_layers,
+                pc.pipeline_model_parallel_size, vpp, to_stage_major=False)),
             params)
         _, loaded_opt, _ = checkpointing.load_checkpoint(
             args.load, load_params=False,
@@ -622,6 +641,8 @@ def main():
             staged = convert_opt_state_layout(
                 loaded_opt, args.num_layers,
                 pc.pipeline_model_parallel_size, vpp, to_stage_major=True)
+            if form is not None:
+                staged = map_param_trees(form, staged)
             # re-place restored leaves where a fresh init would put them:
             # param-shaped moments/masters follow the params' shardings
             # (zeros_like preserves sharding); scalar step / grad-scaler

@@ -42,6 +42,8 @@ import numpy as np
 
 from megatron_llm_tpu import tracing
 from megatron_llm_tpu.global_vars import get_counters
+from megatron_llm_tpu.optimizer.optimizer import map_param_trees
+from megatron_llm_tpu.parallel import glu_pairs
 
 CHECKPOINT_VERSION = 4.0  # reference latest is 3.0; 4.0 marks the TPU layout
 
@@ -308,6 +310,11 @@ def save_checkpoint(
     tmp_dir = final_dir.with_name(final_dir.name + ".tmp")
     final_dir.parent.mkdir(parents=True, exist_ok=True)
 
+    # a checkpoint holds the public form of the tree whatever the trainer
+    # held (parallel/glu_pairs.py): it loads at any tp, in the serving
+    # engine and in weights_conversion/*
+    params = glu_pairs.flat(params)
+    opt_state = map_param_trees(glu_pairs.flat, opt_state)
     opt_tree = _opt_state_to_tree(opt_state) if opt_state is not None else None
     manifest = {"model": _tree_manifest(params),
                 "optim": _tree_manifest(opt_tree)}

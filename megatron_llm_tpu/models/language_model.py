@@ -77,9 +77,13 @@ def init_language_model_params(key, cfg: TransformerConfig, dtype=None):
 
 def _linear_spec(p, in_ax, out_ax, stacked):
     lead = ("stage",) if stacked else ()
-    spec = {"kernel": lead + (in_ax, out_ax)}
+    # a gated MLP's first projection held paired, [.., 2, in, out]
+    # (parallel/glu_pairs.py): the pair axis is nobody's shard
+    kernel = p.get("kernel")
+    pair = (None,) if getattr(kernel, "ndim", 0) == len(lead) + 3 else ()
+    spec = {"kernel": lead + pair + (in_ax, out_ax)}
     if "bias" in p:
-        spec["bias"] = lead + (out_ax,)
+        spec["bias"] = lead + pair + (out_ax,)
     return spec
 
 

@@ -909,14 +909,21 @@ def mlp(
     sequence_parallel: bool = False,
 ) -> jax.Array:
     """Reference ``ParallelMLP`` (transformer.py:77-141): column-parallel
-    h->ffn (doubled under GLU), activation, row-parallel ffn->h."""
+    h->ffn (doubled under GLU), activation, row-parallel ffn->h.
+
+    The first projection's kernel says by its rank where a GLU's two
+    halves live (``parallel/glu_pairs.py``): flat ``[h, 2F]`` stored
+    ``[gate | up]``, or the trainer's ``[2, h, F]`` with the pair an axis
+    of its own (the product then has it too, ``[..., 2, F]``), so that
+    under tp a shard of ``F`` holds both halves of its columns and
+    ``act(gate) * up`` moves nothing."""
     h = column_parallel_linear(
         x, params["dense_h_to_4h"],
         out_logical="ffn",
         sequence_parallel=sequence_parallel,
         compute_dtype=cfg.compute_jnp_dtype,
     )
-    h = apply_mlp_activation(h, cfg)
+    h = apply_mlp_activation(h, cfg, paired=h.ndim > x.ndim)
     return row_parallel_linear(
         h, params["dense_4h_to_h"],
         in_logical="ffn",

@@ -35,25 +35,32 @@ def _split2(x: jax.Array):
     return jnp.split(x, 2, axis=-1)
 
 
-def liglu(x: jax.Array) -> jax.Array:
+def _pair2(x: jax.Array):
+    """``_split2``'s sibling for a product whose gate / up pair is an axis
+    of its own, ``[..., 2, F]`` (``parallel/glu_pairs.py``): the halves by
+    index, which a shard of ``F`` takes locally."""
+    return x[..., 0, :], x[..., 1, :]
+
+
+def liglu(x: jax.Array, halves=_split2) -> jax.Array:
     # reference: glu_activations.py (LiGLU: linear gate)
-    a, b = _split2(x)
+    a, b = halves(x)
     return a * b
 
 
-def geglu(x: jax.Array) -> jax.Array:
-    a, b = _split2(x)
+def geglu(x: jax.Array, halves=_split2) -> jax.Array:
+    a, b = halves(x)
     return gelu(a) * b
 
 
-def reglu(x: jax.Array) -> jax.Array:
-    a, b = _split2(x)
+def reglu(x: jax.Array, halves=_split2) -> jax.Array:
+    a, b = halves(x)
     return jax.nn.relu(a) * b
 
 
-def swiglu(x: jax.Array) -> jax.Array:
+def swiglu(x: jax.Array, halves=_split2) -> jax.Array:
     # reference: glu_activations.py:38-42 (silu(a) * b)
-    a, b = _split2(x)
+    a, b = halves(x)
     return jax.nn.silu(a) * b
 
 
@@ -69,15 +76,17 @@ def glu_activation(name: str, x: jax.Array) -> jax.Array:
     return GLU_ACTIVATIONS[name](x)
 
 
-def apply_mlp_activation(h: jax.Array, cfg) -> jax.Array:
+def apply_mlp_activation(h: jax.Array, cfg, paired: bool = False) -> jax.Array:
     """The MLP nonlinearity selected by config — GLU family (halves the
-    doubled first projection), the ungated ``relu(x)^2``
+    doubled first projection: its last axis, or with ``paired`` the pair
+    axis before it), the ungated ``relu(x)^2``
     (``mlp_activation='relu2'``) or a gelu variant ('exact' = erf gelu
     for Falcon, else the GPT-2/Megatron tanh polynomial).  Shared by the
     dense MLP (models/transformer.py), the MoE experts and their shared
     MLP (models/moe.py)."""
     if cfg.glu_activation:
-        return GLU_ACTIVATIONS[cfg.glu_activation](h)
+        return GLU_ACTIVATIONS[cfg.glu_activation](
+            h, _pair2 if paired else _split2)
     if cfg.mlp_activation == "relu2":
         return squared_relu(h)
     if cfg.gelu_variant == "exact":

@@ -38,6 +38,19 @@ class OptimizerState(NamedTuple):
     grad_scaler: GradScalerState
 
 
+def map_param_trees(fn, state: Optional[OptimizerState]):
+    """``fn`` over every params-shaped tree of an optimizer state (master
+    weights and moments; None where the optimizer keeps none): a change of
+    the parameters' layout or form that the state has to follow."""
+    if state is None:
+        return None
+    return state._replace(
+        master_params=fn(state.master_params),
+        exp_avg=fn(state.exp_avg),
+        exp_avg_sq=fn(state.exp_avg_sq),
+    )
+
+
 def _no_weight_decay(path, leaf) -> bool:
     """WD applies to matmul weights only — biases and norm scales are
     excluded (reference: _get_params_for_weight_decay_optimization in

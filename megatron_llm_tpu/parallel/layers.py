@@ -148,6 +148,10 @@ def column_parallel_linear(
     ``sequence_parallel`` the incoming x is sequence-sharded and GSPMD
     all-gathers it (the reference's explicit fwd all-gather,
     layers.py:225-243).
+
+    A kernel of rank 3 is a gated MLP's first projection held paired,
+    ``[2, h, F]`` (``parallel/glu_pairs.py``; never with LoRA leaves or
+    int8 scales): y is ``[..., 2, F]``, ``out_logical`` on ``F``.
     """
     kernel = dequantize_kernel(params, compute_dtype)
     bias = params.get("bias")
@@ -155,10 +159,14 @@ def column_parallel_linear(
         bias = bias.astype(compute_dtype) if bias is not None else None
     if sequence_parallel:
         x = constrain(x, "batch", "seq_tp", None)
-    y = jnp.einsum("...h,hf->...f", x, kernel)
-    if "lora_A" in params:
-        y = y + _lora_delta(x, params)
-    y = constrain(y, "batch", "seq", out_logical)
+    if kernel.ndim == 3:
+        y = jnp.einsum("...h,ghf->...gf", x, kernel)
+        y = constrain(y, "batch", "seq", None, out_logical)
+    else:
+        y = jnp.einsum("...h,hf->...f", x, kernel)
+        if "lora_A" in params:
+            y = y + _lora_delta(x, params)
+        y = constrain(y, "batch", "seq", out_logical)
     if bias is not None and not skip_bias_add:
         y = y + bias
     if skip_bias_add:

@@ -86,6 +86,7 @@ from megatron_llm_tpu.models.language_model import (
 from megatron_llm_tpu.models.transformer import rotary_freqs, transformer_layer
 from megatron_llm_tpu.ops.cross_entropy import vocab_parallel_cross_entropy
 from megatron_llm_tpu.ops.layernorm import apply_norm
+from megatron_llm_tpu.optimizer.optimizer import map_param_trees
 from megatron_llm_tpu.parallel.layers import parallel_lm_logits
 
 # ---------------------------------------------------------------------------
@@ -150,18 +151,12 @@ def convert_opt_state_layout(opt_state, num_layers: int, pp: int, vpp: int,
                              *, to_stage_major: bool):
     """Apply ``convert_params_layout`` to every params-shaped tree inside
     an ``OptimizerState`` (exp_avg / exp_avg_sq / master_params)."""
-    if vpp <= 1 or opt_state is None:
+    if vpp <= 1:
         return opt_state
-
-    def conv(tree):
-        return convert_params_layout(tree, num_layers, pp, vpp,
-                                     to_stage_major=to_stage_major)
-
-    return opt_state._replace(
-        exp_avg=conv(opt_state.exp_avg),
-        exp_avg_sq=conv(opt_state.exp_avg_sq),
-        master_params=conv(opt_state.master_params),
-    )
+    return map_param_trees(
+        lambda tree: convert_params_layout(
+            tree, num_layers, pp, vpp, to_stage_major=to_stage_major),
+        opt_state)
 
 
 # ---------------------------------------------------------------------------

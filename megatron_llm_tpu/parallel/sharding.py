@@ -25,6 +25,7 @@ Logical axes used across the framework:
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -76,6 +77,17 @@ def logical_to_mesh(
 
 def _mesh() -> Optional[Mesh]:
     return topology._MESH
+
+
+def axis_size(logical: str, rules=None) -> int:
+    """Over how many devices of the live mesh a logical axis is sharded
+    (1 with no mesh)."""
+    mesh = _mesh()
+    if mesh is None:
+        return 1
+    entry = logical_to_mesh((logical,), rules)[0]
+    axes = entry if isinstance(entry, tuple) else (entry,)
+    return math.prod(mesh.shape[a] for a in axes if a is not None)
 
 
 def _auto_axes_of(spec: P) -> P:
@@ -138,16 +150,23 @@ def make_shardings(specs, rules=None, mesh: Optional[Mesh] = None):
     )
 
 
-def init_params(model, key):
+def init_params(model, key, form=None):
     """``model.init(key)`` with every leaf born on its own shards.
 
     An eager init materializes the whole model on the default device
     (layer by layer, then once more for the stack) before
     ``shard_params`` spreads it; under ``jit`` with the output shardings
-    of ``model.param_specs`` no device ever holds more than its share."""
-    abstract = jax.eval_shape(model.init, key)
+    of ``model.param_specs`` no device ever holds more than its share.
+    ``form`` (a tree -> tree function, the trainer's
+    ``glu_pairs.for_trainer``) is applied inside the same program, and
+    the leaves are born on the shards of the form it gives."""
+    init = model.init
+    if form is not None:
+        def init(k):    # (the compile ledger's name for it, as model.init's)
+            return form(model.init(k))
+    abstract = jax.eval_shape(init, key)
     shardings = make_shardings(model.param_specs(abstract))
-    return jax.jit(model.init, out_shardings=shardings)(key)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def shard_params(params, specs, rules=None, mesh: Optional[Mesh] = None):

@@ -19,7 +19,7 @@ from megatron_llm_tpu import hlo_collectives, topology
 from megatron_llm_tpu.config import ParallelConfig, TrainConfig
 from megatron_llm_tpu.optimizer import MegatronOptimizer
 from megatron_llm_tpu.optimizer.optimizer import global_grad_norm
-from megatron_llm_tpu.parallel import sharding as sh
+from megatron_llm_tpu.parallel import glu_pairs, sharding as sh
 from megatron_llm_tpu.training import build_train_step, default_loss_func
 
 
@@ -33,18 +33,24 @@ def _mesh(tp, dp):
 # where the reduction sits
 # ---------------------------------------------------------------------------
 
-def _cell_step(num_micro=4, seq=128):
+def _cell_step(num_micro=4, seq=128, **model_kw):
     """The benchmark's training cell at its rehearsal widths (``benchmarks/
     configs/mistral-7b-train-tp2dp2.json``), in bf16 as on the chip: tp 2
-    with sequence parallelism x dp 2, 2 scanned layers."""
+    with sequence parallelism x dp 2, 2 scanned layers; the parameters in
+    the form ``finetune.py`` holds them in."""
+    from megatron_llm_tpu.models.gpt import GPTModel
     from megatron_llm_tpu.models.mistral import MistralModel, mistral_config
 
     mesh = _mesh(tp=2, dp=2)
-    model = MistralModel(mistral_config(
+    cfg = mistral_config(
         "tiny", padded_vocab_size=512, seq_length=seq,
         max_position_embeddings=512, params_dtype="bf16",
-        compute_dtype="bf16", recompute_granularity="selective"))
-    params = sh.init_params(model, jax.random.PRNGKey(0))
+        compute_dtype="bf16", recompute_granularity="selective",
+        **model_kw)
+    # (MistralModel asserts its family's own flags: swiglu among them)
+    model = (GPTModel if model_kw else MistralModel)(cfg)
+    params = sh.init_params(model, jax.random.PRNGKey(0),
+                            form=glu_pairs.for_trainer)
     tc = TrainConfig(micro_batch_size=1, global_batch_size=2 * num_micro,
                      lr=1e-4, bf16=True)
     pc = ParallelConfig(tensor_model_parallel_size=2, data_parallel_size=2,
@@ -78,6 +84,40 @@ def test_no_dp_reduction_inside_a_loop_and_fp32_after():
     assert all(r["dtypes"] == ["f32"] for r in over_dp), over_dp
     n_leaves = len(jax.tree_util.tree_leaves(args[0]))
     assert sum(r["calls"] for r in over_dp) <= n_leaves
+
+
+@pytest.mark.parametrize("activation", [
+    "liglu", "geglu", "reglu", "swiglu",
+    # a non-gated MLP has no pair: its first projection stays flat
+    None,
+])
+def test_a_gated_mlp_trades_nothing_over_tp(activation):
+    """The step's tensor-parallel edge (``parallel/glu_pairs.py``, PR 50):
+    held flat, ``[gate | up]`` in contiguous shards of ``2F``, a gated
+    MLP's halves met through 24 all-to-alls (op_name ``.../mlp/
+    concatenate``) and 32 permutes (``.../mlp/split``) a step here; held
+    paired, every collective left over tp is sequence parallelism's or the
+    loss's, at the counts they had."""
+    mesh, step, args = _cell_step(glu_activation=activation)
+    first = args[0]["transformer"]["layers"]["mlp"]["dense_h_to_4h"]
+    assert glu_pairs.count(args[0]) == ((1, 0) if activation else (0, 0))
+    assert first["kernel"].ndim == (4 if activation else 3)
+    rows = hlo_collectives.collectives(
+        step.lower(*args).compile().as_text())
+    tp = hlo_collectives.mesh_groups(dict(mesh.shape), ["tp"])
+    over_tp = [r for r in rows if r["groups"] == tp]
+    moved = [r for r in over_tp
+             if r["family"] in ("all-to-all", "collective-permute")]
+    assert not [r for r in moved if "/mlp/" in r["op_name"]], moved
+    assert not moved, moved
+    calls = {}
+    for r in over_tp:
+        key = (r["family"], r["loops"])
+        calls[key] = calls.get(key, 0) + r["calls"]
+    assert calls == {
+        ("all-gather", (4, 2)): 64, ("all-gather", (4,)): 8,
+        ("all-reduce", (4, 2)): 40, ("all-reduce", (4,)): 20,
+        ("all-reduce", ()): 2}, calls
 
 
 def test_the_step_logs_what_its_compiled_text_counts(tmp_path):

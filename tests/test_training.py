@@ -9,13 +9,15 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from megatron_llm_tpu import checkpointing, topology
 from megatron_llm_tpu.config import ParallelConfig, TrainConfig
 from megatron_llm_tpu.models.llama import LlamaModel, llama_config
 from megatron_llm_tpu.optimizer import MegatronOptimizer
-from megatron_llm_tpu.parallel import sharding as sh
+from megatron_llm_tpu.optimizer.optimizer import map_param_trees
+from megatron_llm_tpu.parallel import glu_pairs, sharding as sh
 from megatron_llm_tpu.training import build_train_step, pretrain
 
 
@@ -59,8 +61,15 @@ def test_loss_decreases(utils):
     assert final < 2.0, f"loss did not decrease: {final}"
 
 
-def test_checkpoint_resume_exact(utils):
+@pytest.mark.parametrize("paired", [False, True], ids=["flat", "paired"])
+def test_checkpoint_resume_exact(utils, paired):
+    """``paired``: the tree as finetune.py holds it at tp 2
+    (parallel/glu_pairs.py), saved flat, killed, loaded flat and paired
+    again: the run goes on bit for bit."""
     cfg, model, params, mesh, it = _setup(utils)
+    if paired:
+        params = glu_pairs.for_trainer(params)
+        assert glu_pairs.count(params) == (1, 0)
     tc = TrainConfig(micro_batch_size=2, global_batch_size=16, train_iters=4,
                      lr=1e-3, optimizer="adam", seed=5)
     pc = ParallelConfig(tensor_model_parallel_size=2, data_parallel_size=4,
@@ -72,24 +81,38 @@ def test_checkpoint_resume_exact(utils):
         p2, o2, _ = pretrain(model, params, dataclasses.replace(tc, train_iters=2),
                              pc, it(), log_interval=0)
         checkpointing.save_checkpoint(d, 2, p2, o2)
+
+        # abstract templates of the flat tree the checkpoint holds
+        # (shape/dtype/sharding metadata, read before p2's buffers are
+        # donated)
+        def tmpl(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=x.sharding),
+                glu_pairs.flat(tree))
+
+        p_tmpl, o_tmpl = tmpl(p2), map_param_trees(tmpl, o2)
         p4a, _, _ = pretrain(model, p2, tc, pc, it(), log_interval=0,
                              start_iteration=2, opt_state=o2)
 
-        # load from checkpoint and run the same 2 iters (abstract template:
-        # shape/dtype/sharding metadata survives donation of p2's buffers)
-        tmpl = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=x.sharding), p2)
+        # load from checkpoint and run the same 2 iters
         pl, ol, meta = checkpointing.load_checkpoint(
-            d, params_template=tmpl, opt_state_template=o2)
+            d, params_template=p_tmpl, opt_state_template=o_tmpl)
         assert meta["iteration"] == 2
+        assert glu_pairs.count(pl) == (0, 1)
+        if paired:
+            pl = glu_pairs.for_trainer(pl)
+            ol = map_param_trees(glu_pairs.for_trainer, ol)
         pl = sh.shard_params(pl, model.param_specs(pl))
         p4b, _, _ = pretrain(model, pl, tc, pc, it(), log_interval=0,
                              start_iteration=2, opt_state=ol)
 
         for a, b in zip(jax.tree_util.tree_leaves(p4a),
                         jax.tree_util.tree_leaves(p4b)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(jnp.asarray(b)),
-                                       atol=1e-6)
+            if paired:
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            else:
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(jnp.asarray(b)), atol=1e-6)
     finally:
         shutil.rmtree(d)

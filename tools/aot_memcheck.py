@@ -225,7 +225,10 @@ def run_config(name: str, hlo_dir: str = "") -> dict:
     model = _model_for(spec)
     cfg = model.cfg
     key = jax.random.PRNGKey(0)
-    params_shape = jax.eval_shape(model.init, key)
+    # the tree in the form finetune.py trains it in
+    from megatron_llm_tpu.parallel import glu_pairs
+    params_shape = jax.eval_shape(
+        lambda k: glu_pairs.for_trainer(model.init(k)), key)
     n_params = sum(
         int(np_.size) for np_ in jax.tree_util.tree_leaves(params_shape))
     pspecs = model.param_specs(params_shape)
