@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,lfm2,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -40,6 +40,7 @@ from megatron_llm_tpu.dist_signal_handler import DistributedSignalHandler
 from megatron_llm_tpu.global_vars import get_counters
 from megatron_llm_tpu.initialize import initialize_megatron
 from megatron_llm_tpu.models import MODEL_REGISTRY
+from megatron_llm_tpu.models.lfm2 import PUBLISHED_LAYER_TYPES
 from megatron_llm_tpu.optimizer import (
     MegatronOptimizer,
     OptimizerParamScheduler,
@@ -162,6 +163,21 @@ MODEL_DEFAULTS = {
                        mamba_n_groups=8, mamba_chunk_size=128,
                        layernorm_epsilon=1e-5,
                        hidden_dropout=0.0, attention_dropout=0.0),
+    # LFM2-8B-A1B (model_type lfm2_moe): gated short convolutions of
+    # three taps beside rotating attention of 64-wide heads by a pattern
+    # that does not repeat, two leading dense layers, 32 experts under a
+    # sigmoid router whose gates are divided by their sum + 1e-6, a tied
+    # head
+    "lfm2": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                 use_rms_norm=True, use_bias=False, tie_embed_logits=True,
+                 num_experts=32, moe_top_k=4, norm_topk_prob=1,
+                 moe_score_function="sigmoid", moe_choice_bias=1,
+                 moe_routed_scale=1.0, moe_gate_norm_eps=1e-6,
+                 moe_gate_norm_added=1, moe_first_dense_layers=2,
+                 qk_norm_per_head=True, kv_channels=64, rope_theta=1e6,
+                 conv_taps=3, conv_mixer_bias=0, layernorm_epsilon=1e-5,
+                 layer_types=list(PUBLISHED_LAYER_TYPES),
+                 hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,
@@ -388,6 +404,11 @@ _CKPT_ARG_MAP = {
     "mamba_conv_bias": "mamba_conv_bias",
     # nemotron_h's ungated MLPs
     "mlp_activation": "mlp_activation",
+    # lfm2's convolution and its router's normaliser
+    "conv_taps": "conv_taps",
+    "conv_mixer_bias": "conv_mixer_bias",
+    "moe_gate_norm_eps": "moe_gate_norm_eps",
+    "moe_gate_norm_added": "moe_gate_norm_added",
     "attention_multiplier": "attention_multiplier",
     "residual_multiplier": "residual_multiplier",
     "logits_scaling": "logits_scaling",

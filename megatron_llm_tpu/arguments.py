@@ -101,6 +101,14 @@ def _add_network_size_args(parser):
     g.add_argument("--moe_choice_bias_std", type=float, default=None,
                    help="the spread a fresh model's choice bias is drawn "
                         "at (default 0.1; a checkpoint overwrites it)")
+    g.add_argument("--moe_gate_norm_eps", type=float, default=None,
+                   help="what guards --norm_topk_prob's division "
+                        "(default: 1e-20 under a sigmoid router, 1e-9 "
+                        "under a softmax)")
+    g.add_argument("--moe_gate_norm_added", type=int, default=0,
+                   choices=[0, 1],
+                   help="1: the chosen scores' sum PLUS the epsilon "
+                        "(lfm2); 0: the larger of the two")
     g.add_argument("--moe_routed_scale", type=float, default=1.0,
                    help="the chosen gates times this "
                         "(routed_scaling_factor)")
@@ -181,7 +189,9 @@ def _add_network_size_args(parser):
                         "depth: sliding (--sliding_window_size keys) or "
                         "full (e.g. sliding sliding sliding full); or a "
                         "hybrid's mamba and attention (with moe: a "
-                        "layer is one sublayer, an expert layer alone)")
+                        "layer is one sublayer, an expert layer alone), "
+                        "or conv and attention, every layer of the depth "
+                        "spelled out where the pattern does not repeat")
     g.add_argument("--hybrid_override_pattern", type=str, default=None,
                    help="a letter a layer in place of --layer_types: M a "
                         "Mamba-2 mixer, * an attention mixer, E an "
@@ -195,6 +205,11 @@ def _add_network_size_args(parser):
     g.add_argument("--mamba_chunk_size", type=int, default=256,
                    help="the chunked scan's block (changes no result)")
     g.add_argument("--mamba_conv_bias", type=int, default=1, choices=[0, 1])
+    g.add_argument("--conv_taps", type=int, default=3,
+                   help="taps a channel of a 'conv' layer's gated short "
+                        "convolution (conv_L_cache)")
+    g.add_argument("--conv_mixer_bias", type=int, default=0, choices=[0, 1],
+                   help="a bias a channel on that convolution (conv_bias)")
     g.add_argument("--attention_multiplier", type=float, default=None,
                    help="attention scores times this in place of "
                         "1/sqrt(head_dim)")
@@ -1120,6 +1135,10 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         mamba_d_conv=int(getattr(args, "mamba_d_conv", 4)),
         mamba_chunk_size=int(getattr(args, "mamba_chunk_size", 256)),
         mamba_conv_bias=bool(getattr(args, "mamba_conv_bias", 1)),
+        conv_taps=int(getattr(args, "conv_taps", 3)),
+        conv_mixer_bias=bool(getattr(args, "conv_mixer_bias", 0)),
+        moe_gate_norm_eps=getattr(args, "moe_gate_norm_eps", None),
+        moe_gate_norm_added=bool(getattr(args, "moe_gate_norm_added", 0)),
         attention_multiplier=getattr(args, "attention_multiplier", None),
         residual_multiplier=float(getattr(args, "residual_multiplier", 1.0)),
         logits_scaling=float(getattr(args, "logits_scaling", 1.0)),

@@ -96,8 +96,14 @@ class ParallelConfig:
 # them ('attention' attends every key, as 'full' does; 'mamba' is a
 # Mamba-2 state-space mixer, models/mamba.py).  'moe' is an expert layer
 # ALONE: with it among the types every layer is ONE sublayer under ONE
-# norm, a mixer or an expert layer (``one_sublayer``)
-LAYER_TYPES = ("sliding", "full", "mamba", "attention", "moe")
+# norm, a mixer or an expert layer (``one_sublayer``).  'conv' is a gated
+# short convolution (models/short_conv.py; lfm2's ``conv`` beside its
+# ``full_attention``, which is 'attention' here): the other mixer that
+# carries a state from token to token
+LAYER_TYPES = ("sliding", "full", "mamba", "attention", "moe", "conv")
+# the layer types whose mixer carries a STATE a request in a slot and no
+# pages (``TransformerConfig.state_layer``: what ops/paged_kv.py asks)
+STATE_TYPES = ("mamba", "conv")
 # the letters of a published ``hybrid_override_pattern``, a layer each
 PATTERN_LETTERS = {"M": "mamba", "*": "attention", "E": "moe"}
 
@@ -154,6 +160,7 @@ SPARSE = "sparse attention (dsa_index_heads > 0)"
 LATENT = "latent attention (kv_lora_rank)"
 TYPED = "a layer type per layer (layer_types)"
 STATE_SPACE = "state-space layers ('mamba' among layer_types)"
+SHORT_CONV = "gated short-convolution layers ('conv' among layer_types)"
 ONE_SUBLAYER = "layers of one sublayer ('moe' among layer_types)"
 FIRST_DENSE = "leading dense layers (moe_first_dense_layers)"
 SHARE = "a share of the router's experts (moe_router_experts)"
@@ -172,11 +179,13 @@ GATE = "an attention output gate (attention_output_gate)"
 OUTPUT_NORMS = "norms on both sublayers' outputs (sublayer_output_norm)"
 ROPE_TYPES = "layer types that do not rotate (rope_layer_types)"
 OTHER_TYPES = "layer types other than 'mamba', 'attention' and 'moe'"
+CONV_OTHER_TYPES = "layer types other than 'conv' and 'attention'"
 HAS = {
     SPARSE: lambda c: c.dsa_index_heads > 0,
     LATENT: lambda c: c.kv_lora_rank is not None,
     TYPED: lambda c: c.layer_types is not None,
     STATE_SPACE: lambda c: c.state_space,
+    SHORT_CONV: lambda c: c.short_conv,
     ONE_SUBLAYER: lambda c: c.one_sublayer,
     FIRST_DENSE: lambda c: c.moe_first_dense_layers > 0,
     SHARE: lambda c: c.holds_a_share,
@@ -199,6 +208,8 @@ HAS = {
     ROPE_TYPES: lambda c: c.rope_layer_types is not None,
     OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
                                 - {"mamba", "attention", "moe"}),
+    CONV_OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
+                                     - {"conv", "attention"}),
 }
 
 # THE TABLE: what a model has, and everything it does not run with.  A
@@ -210,6 +221,9 @@ RUNS_WITH = (
               TENSOR_PARALLEL)),
     (ONE_SUBLAYER, (OTHER_TYPES, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
                     PREFIX_CACHE)),
+    (SHORT_CONV, (CONV_OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
+                  GATE, OUTPUT_NORMS, TRAINING, MODEL_PARALLEL, VERIFY_STEP,
+                  INT8_POOL, HOST_TIER, PREEMPTION, PREFIX_CACHE)),
     (STATE_SPACE, (OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
                    VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
                    MODEL_PARALLEL)),
@@ -220,7 +234,7 @@ RUNS_WITH = (
     (ROPE_TYPES, (NOT_ROTARY, TRAINING)),
     (TYPED, (SPARSE, SECTIONED, VERIFY_STEP, INT8_POOL, HOST_TIER,
              PREFIX_CACHE, MODEL_PARALLEL, ROLLING_CACHE)),
-    (FIRST_DENSE, (STATE_SPACE, ONE_SUBLAYER, MODEL_PARALLEL)),
+    (FIRST_DENSE, (ONE_SUBLAYER, MODEL_PARALLEL)),
     (LATENT, (NOT_ROTARY, SLIDING, TYPED, SPARSE, QK_NORM_WHOLE,
               QK_NORM_PER_HEAD, SECTIONED, ROPE_SCALING, BIASES, QKV_BIAS,
               PARALLEL_ATTN, VERIFY_STEP, INT8_POOL, HOST_TIER,
@@ -247,6 +261,30 @@ TAILS = {
     (STATE_SPACE, PREEMPTION):
         " (no snapshot of a request's state is kept): set preemption off "
         "(--serve_preemption=0)",
+    (SHORT_CONV, CONV_OTHER_TYPES):
+        " (a 'conv' layer type goes with 'attention' layers only: a stack "
+        "that holds 'mamba' and 'conv' together, two states of two shapes "
+        "a slot, is held to nothing)",
+    (SHORT_CONV, TRAINING):
+        " (no backward through the carried columns is held to anything, "
+        "and packed documents would need them reset at each boundary)",
+    (SHORT_CONV, MODEL_PARALLEL):
+        " (the convolution's channels and its slot's columns would be "
+        "split with the hidden width, and a stage would hold the tail of "
+        "a pattern that does not repeat)",
+    (SHORT_CONV, VERIFY_STEP):
+        " (a rejected draft's columns would have to be taken back)",
+    (SHORT_CONV, INT8_POOL):
+        " (two 64-wide heads share a 128-lane row of the pool, and the "
+        "int8 pool's scales are a row's)",
+    (SHORT_CONV, HOST_TIER):
+        " (a page of keys without the columns at its end resumes nothing)",
+    (SHORT_CONV, PREEMPTION):
+        " (no snapshot of a request's columns is kept): set preemption "
+        "off (--serve_preemption=0)",
+    (SHORT_CONV, PREFIX_CACHE):
+        " adopts nothing (a convolution layer's columns at a prefix's end "
+        "are not kept)",
     (GATE, LATENT):
         " (latent_attention has no fourth projection and no product)",
     (GATE, TRAINING): " (no backward through the gate is held to anything)",
@@ -262,9 +300,6 @@ TAILS = {
     (ROPE_TYPES, TRAINING):
         " (no backward through a stack that rotates on some layers only "
         "is held to anything)",
-    (FIRST_DENSE, STATE_SPACE):
-        " (the mixers are stacked by kind over ALL layers, the dense MLPs "
-        "apart from the sparse ones: two ways of counting a layer)",
     (FIRST_DENSE, ONE_SUBLAYER):
         " (a layer of one sublayer has no MLP to keep dense)",
     (TYPED, PREFIX_CACHE):
@@ -472,6 +507,12 @@ class TransformerConfig:
     # step of 64 rows touches 34-39 of 64 held experts by the seed, at
     # 0.02 51-53 and at zero 53-54 (PERF.md section 6, PR 44)
     moe_choice_bias_std: Optional[float] = None
+    # how ``norm_topk_prob`` guards its division: the chosen scores' sum
+    # plus this (``moe_gate_norm_added``: lfm2's ``sum + 1e-6``) or the
+    # larger of the two (every older family).  None: 1e-20 under a
+    # sigmoid router and 1e-9 under a softmax, what those families run
+    moe_gate_norm_eps: Optional[float] = None
+    moe_gate_norm_added: bool = False
     # the chosen gates (after ``norm_topk_prob``) times this
     moe_routed_scale: float = 1.0
     # shared experts: ONE MLP of ``moe_shared_experts`` times an expert's
@@ -507,6 +548,13 @@ class TransformerConfig:
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
     mamba_conv_bias: bool = True
+    # gated short-convolution mixers (the 'conv' layer type;
+    # models/short_conv.py): a causal depthwise convolution of
+    # ``conv_taps`` taps a channel over ``B * X``, the hidden width wide,
+    # with a bias a channel or none.  Fields of their own: a stack could
+    # set ``mamba_d_conv`` beside them
+    conv_taps: int = 3
+    conv_mixer_bias: bool = False
     # muP-style multipliers (Granite): attention scores times this in
     # place of 1/sqrt(head_dim) (None: 1/sqrt(head_dim)); both residual
     # branches times ``residual_multiplier``; the logits divided by
@@ -628,6 +676,9 @@ class TransformerConfig:
                 raise ValueError(
                     "state-space layers need positive mamba sizes, "
                     "mamba_d_conv >= 2 and whole groups of heads")
+            if "conv" in types and self.conv_taps < 2:
+                raise ValueError("gated short-convolution layers need "
+                                 "conv_taps >= 2 (a column to carry)")
         if self.moe_router_experts is not None or self.moe_experts_first:
             routed = self.moe_router_experts or self.num_experts
             if self.num_experts <= 1 or not (
@@ -676,6 +727,10 @@ class TransformerConfig:
             raise ValueError(
                 f"moe_score_function must be softmax|sigmoid, got "
                 f"{self.moe_score_function!r}")
+        if self.moe_gate_norm_eps is None:
+            object.__setattr__(
+                self, "moe_gate_norm_eps",
+                1e-20 if self.moe_score_function == "sigmoid" else 1e-9)
         if self.num_experts <= 1 and (
                 self.moe_shared_experts or self.moe_first_dense_layers
                 or self.moe_choice_bias):
@@ -745,6 +800,23 @@ class TransformerConfig:
         return self.layer_types is not None and "mamba" in self.layer_types
 
     @property
+    def short_conv(self) -> bool:
+        """Whether some layer's mixer is a gated short convolution."""
+        return self.layer_types is not None and "conv" in self.layer_types
+
+    @staticmethod
+    def state_layer(layer_type: Optional[str]) -> bool:
+        """Whether a layer of ``layer_type`` carries a STATE a request
+        (arrays a slot, no pages): a 'mamba' or a 'conv' mixer."""
+        return layer_type in STATE_TYPES
+
+    @property
+    def mixers_by_kind(self) -> bool:
+        """Whether the stack's mixers are of several kinds, with other
+        leaves each, and so stacked apart by kind (``mixer_counts``)."""
+        return self.state_space or self.short_conv or self.one_sublayer
+
+    @property
     def one_sublayer(self) -> bool:
         """Whether a layer is ONE sublayer under one norm (a mixer or an
         expert layer alone): a stack with 'moe' among its layer types."""
@@ -761,16 +833,25 @@ class TransformerConfig:
 
     @property
     def mixer_counts(self) -> dict:
-        """How many layers there are of each kind ('mamba', 'attention',
-        and the expert layers 'moe' of a stack of one sublayer a layer)
-        in a stack whose kinds' parameters are stacked apart; empty for
-        a stack whose layers all hold the same leaves."""
-        if not (self.state_space or self.one_sublayer):
+        """How many layers there are of each kind ('mamba', 'conv',
+        'attention', and the expert layers 'moe' of a stack of one
+        sublayer a layer) in a stack whose kinds' parameters are stacked
+        apart; empty for a stack whose layers all hold the same leaves.
+        EVERY layer of the depth counts, a sparse model's leading dense
+        layers among them: only their MLP is stacked apart
+        (``dense_layers``), their mixer is a member of its kind's stack
+        by its index in the whole depth."""
+        if not self.mixers_by_kind:
             return {}
         reps = self.num_layers // len(self.layer_types)
         return {k: reps * self.layer_types.count(k)
-                for k in ("mamba", "attention", "moe")
+                for k in ("mamba", "conv", "attention", "moe")
                 if k in self.layer_types}
+
+    def layer_type(self, layer: int) -> Optional[str]:
+        """The type of layer ``layer`` of the depth (None for a stack of
+        one type)."""
+        return self.layer_period[layer % len(self.layer_period)]
 
     def mixer_index(self, layer: int) -> tuple:
         """(kind, index among the layers of that kind) of layer

@@ -124,6 +124,7 @@ SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
           "moe_combine", "moe_shared", "qk_norm", "dsa_indexer",
           "mla_absorb", "mla_expand", "ssm_in_proj", "ssm_conv", "ssm_scan",
           "ssm_step", "ssm_gate_norm", "ssm_out_proj", "mamba",
+          "conv_in_proj", "short_conv", "conv_out_proj",
           "attn_gate", "post_attn_norm", "post_mlp_norm",
           "attention", "mlp",
           "embedding", "lm_head", "transformer_layer")

@@ -14,6 +14,7 @@ from megatron_llm_tpu.models.keye import KeyeModel, keye_config
 from megatron_llm_tpu.models.mellum import MellumModel, mellum_config
 from megatron_llm_tpu.models.kanana import KananaModel, kanana_config
 from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
+from megatron_llm_tpu.models.lfm2 import Lfm2Model, lfm2_config
 from megatron_llm_tpu.models.qwen2 import Qwen2Model, qwen2_config
 from megatron_llm_tpu.models.gemma import GemmaModel, gemma_config
 from megatron_llm_tpu.models.gpt_neox import GPTNeoXModel, gpt_neox_config
@@ -56,6 +57,7 @@ MODEL_REGISTRY = {
     "trinity": TrinityModel,
     "granite": _granite,
     "nemotron_h": _nemotron_h,
+    "lfm2": Lfm2Model,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,
     "gpt_neox": GPTNeoXModel,

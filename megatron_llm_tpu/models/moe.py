@@ -249,7 +249,9 @@ def _route(x: jax.Array, params, cfg: TransformerConfig):
     largest scores, or with ``router.choice_bias`` the largest of score +
     bias, and the gates are the chosen SCORES either way, renormalised
     over the chosen ones only where the family does
-    (``cfg.norm_topk_prob``) and then times ``cfg.moe_routed_scale``."""
+    (``cfg.norm_topk_prob``, the division guarded as
+    ``cfg.moe_gate_norm_eps`` / ``cfg.moe_gate_norm_added`` say) and then
+    times ``cfg.moe_routed_scale``."""
     wr = params["router"]["kernel"].astype(jnp.float32)
     logits = jnp.einsum("...h,he->...e", x.astype(jnp.float32), wr)
     if cfg.moe_score_function == "sigmoid":
@@ -264,9 +266,12 @@ def _route(x: jax.Array, params, cfg: TransformerConfig):
     else:
         gates, idx = jax.lax.top_k(probs, cfg.moe_top_k)
     if cfg.norm_topk_prob:
-        gates = gates / jnp.maximum(
-            jnp.sum(gates, axis=-1, keepdims=True),
-            1e-20 if cfg.moe_score_function == "sigmoid" else 1e-9)
+        # the division's guard is the family's, as data: the sum plus an
+        # epsilon, or the larger of the two
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total + cfg.moe_gate_norm_eps
+                         if cfg.moe_gate_norm_added
+                         else jnp.maximum(total, cfg.moe_gate_norm_eps))
     if cfg.moe_routed_scale != 1.0:
         gates = gates * cfg.moe_routed_scale
     return logits, probs, gates, idx

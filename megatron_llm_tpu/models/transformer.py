@@ -215,9 +215,10 @@ def init_mlp_params(key, cfg: TransformerConfig, dtype):
 def init_layer_params(key, cfg: TransformerConfig, dtype,
                       layer_type: str = "encoder", sparse: bool = True):
     """``sparse`` False: a sparse model's leading dense layer, whose MLP
-    is the dense one of ``ffn_hidden_size``.  For a stack with
-    state-space layers the mixer is left out: the two kinds have other
-    leaves and are stacked apart (``init_stack_params``).  A layer of ONE
+    is the dense one of ``ffn_hidden_size``.  For a stack whose mixers
+    are of several kinds (state-space or gated short-convolution layers
+    beside attention) the mixer is left out: the kinds have other leaves
+    and are stacked apart (``init_stack_params``).  A layer of ONE
     sublayer (``cfg.one_sublayer``) holds its one norm here and nothing
     else: its mixer or its experts are stacked apart by kind."""
     ka, km, kn = jax.random.split(key, 3)
@@ -234,7 +235,7 @@ def init_layer_params(key, cfg: TransformerConfig, dtype,
         "input_norm": init_norm_params(cfg.hidden_size, cfg.normalization, dtype),
         "mlp": mlp_params,
     }
-    if not cfg.state_space:
+    if not cfg.mixers_by_kind:
         params["attention"] = init_attention_params(ka, cfg, dtype)
     if not cfg.parallel_attn:
         # pre-MLP norm (reference: post_attention_layernorm)
@@ -270,10 +271,13 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
     (transformer.py:983-1014).  A sparse model's leading dense layers
     (``cfg.moe_first_dense_layers``) have other leaves, so they are
     stacked apart, under ``dense_layers``, and ``layers`` holds the
-    sparse ones only.  So are the mixers of a stack with state-space
-    layers (``cfg.state_space``): ``layers['mamba']`` and
-    ``layers['attention']`` hold the layers of each kind in their order,
-    under the norms and the MLP that every layer has; in a stack of one
+    sparse ones only.  So are the mixers of a stack with state-space or
+    gated short-convolution layers (``cfg.mixers_by_kind``):
+    ``layers['mamba']`` (``layers['conv']``) and ``layers['attention']``
+    hold the layers of each kind in their order over the WHOLE depth,
+    under the norms and the MLP that every layer has: a leading dense
+    layer's mixer is a member of its kind's stack like any other, and
+    only its norms and its MLP live under ``dense_layers``; in a stack of one
     sublayer a layer (``cfg.one_sublayer``) so is the third kind,
     ``layers['moe']`` (what ``layers['mlp']`` holds elsewhere, over the
     expert layers alone), under the ONE norm every layer has."""
@@ -295,6 +299,9 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
         init, at = init_attention_params, 0
         if kind == "mamba":
             from megatron_llm_tpu.models.mamba import init_mamba_params as init
+        elif kind == "conv":
+            from megatron_llm_tpu.models.short_conv import (
+                init_short_conv_params as init)
         elif kind == "moe":
             from megatron_llm_tpu.models.moe import (
                 init_moe_mlp_params as init)
@@ -985,7 +992,8 @@ def transformer_layer(
     of them (``moe_mlp_dropless``).  ``layer_type``: the layer's, of a
     model with ``cfg.layer_types`` (``attention`` says what it decides);
     a ``'mamba'`` layer's mixer is ``models/mamba.py::mamba_mixer`` over
-    ``params['mamba']`` in place of attention.  Both residual branches
+    ``params['mamba']`` in place of attention, a ``'conv'`` layer's
+    ``models/short_conv.py::short_conv_mixer`` over ``params['conv']``.  Both residual branches
     are multiplied by ``cfg.residual_multiplier``.  In a stack of ONE
     sublayer a layer (``cfg.one_sublayer``) the layer is
     ``x + f(input_norm(x))``, ``f`` its mixer or, for the type
@@ -1047,6 +1055,20 @@ def transformer_layer(
             new_cache = None
             if kv_cache is not None:
                 attn_out, new_cache = attn_out
+    elif layer_type == "conv":
+        from megatron_llm_tpu.models.short_conv import short_conv_mixer
+
+        if attention_mask is not None:
+            raise NotImplementedError(
+                "gated short-convolution layers ('conv') are not "
+                "implemented under an explicit attention mask (packed "
+                "documents would need the carried columns reset at each "
+                "boundary)")
+        attn_out = short_conv_mixer(ln_out, params["conv"], cfg,
+                                    kv_cache=kv_cache)
+        new_cache = None
+        if kv_cache is not None:
+            attn_out, new_cache = attn_out
     else:
         with jax.named_scope("attention"):
             if kv_cache is not None:
@@ -1200,12 +1222,13 @@ def transformer_stack(
     of types) scans over PERIODS with a period's layers unrolled in the
     body, each of its own type, so the trace holds one period whatever
     the depth; a model of one type is one period of one layer.  Where
-    the period's mixers are of two kinds (``cfg.state_space``) their
+    the period's mixers are of two kinds (``cfg.mixers_by_kind``) their
     parameters are stacked apart (``init_stack_params``) and a layer
-    takes its own by its index AMONG ITS KIND, in the scan and in the
-    serving loop alike; so are the three kinds of a stack of one
-    sublayer a layer (``cfg.one_sublayer``), its expert layers among
-    them."""
+    takes its own by its index AMONG ITS KIND over the WHOLE depth
+    (``cfg.mixer_index``), in the layers before the scan, in the scan
+    and in the serving loop alike, a leading dense layer too; so are
+    the three kinds of a stack of one sublayer a layer
+    (``cfg.one_sublayer``), its expert layers among them."""
     layers = stack_params["layers"]
     L = cfg.num_layers
     said = train and refusal(cfg, (TRAINING,))
@@ -1322,8 +1345,13 @@ def transformer_stack(
     aux_head = jnp.zeros((2,), jnp.float32)
     for i in range(D + head):
         stacked, at = (dense, i) if i < D else (layers, i - D)
+        layer_p = jax.tree_util.tree_map(lambda p: p[at], stacked)
+        if mixers:
+            kind, own = cfg.mixer_index(i)
+            layer_p[kind] = jax.tree_util.tree_map(lambda p: p[own],
+                                                   mixers[kind])
         x, _, moe_aux = transformer_layer(
-            x, jax.tree_util.tree_map(lambda p: p[at], stacked), cfg,
+            x, layer_p, cfg,
             rng_key=layer_keys[i] if rng_key is not None else None,
             train=train,
             hidden_dropout=(dropout_rates[i] if dropout_rates is not None
@@ -1348,13 +1376,18 @@ def transformer_stack(
             lambda a: a.reshape(((L - first) // P, P) + a.shape[1:]),
             scanned)
     if mixers:
-        # a kind's [its layers, ...] -> [L / P, its layers a period, ...]
+        # a kind's [its layers after the first, ...] -> [periods, its
+        # layers a period, ...]
+        before = [cfg.mixer_index(i)[0] for i in range(first)]
         scanned = (scanned, {
             k: jax.tree_util.tree_map(
-                lambda a: a.reshape((L // P, -1) + a.shape[1:]), m)
+                lambda a: (a[before.count(k):] if first else a).reshape(
+                    ((L - first) // P, period.count(k)) + a.shape[1:]), m)
             for k, m in mixers.items()})
     init_carry = (x, aux_head) if moe_on else x
-    carry, _ = jax.lax.scan(body, init_carry, scanned)
+    carry = init_carry
+    if first < L:
+        carry, _ = jax.lax.scan(body, init_carry, scanned)
     h, moe_aux = carry if moe_on else (carry, None)
     h = apply_norm(
         h, stack_params["final_norm"], cfg.normalization,

@@ -62,7 +62,24 @@ paths as attention is: the in-place kernel over the live rows
 Its attention layers are of the ``full`` group.  The page programs,
 copy-on-write and ``block_bytes`` are for pools WITH pages:
 ``paged_pools`` picks them, and ``array_shapes`` gives the state's
-arrays a list of their own.
+arrays a list of their own.  Which arrays a slot holds is the layer's
+KIND's (``cfg.state_layer``): a gated short convolution (``conv``,
+``models/short_conv.py``) keeps ONE, ``conv_state`` ``[slots + 1, taps -
+1, hidden]`` in the compute dtype, and no ``ssm_state``; ``read_state``,
+``write_state``, the zeros at a first launch and the bytes a slot follow
+the arrays that are there.
+
+HEADS OF HALF A LANE ROW (``head_dim`` 64) lie TWO A ROW: the pool is
+``[num_blocks, block_size, groups / 2, 128]``, which is the token's
+contiguous ``[groups, 64]`` as it stands, so it holds 2 x groups x 64
+values a token and not twice that (an array whose last dimension is 64 is
+laid out, and fetched, at 128 lanes, and the walk cannot slice it:
+``heads_a_row``).  ``attend`` writes through the same free reshape; the
+kernel walks the pool as one of ``groups / 2`` heads of 128, each query
+head's 64 values set in its own half of a row of 128 beside zeros (its
+scores are then its own key head's alone) and its own half of the
+128-wide output kept; the dense path reads the pool through the reshape
+back.
 
 In a stack of ONE sublayer a layer (``cfg.one_sublayer``) an expert
 layer (type ``moe``) keeps NOTHING between launches: its group is
@@ -143,7 +160,11 @@ FULL, WINDOW, STATE, NONE = "full", "window", "state", "none"
 # no cache dtype), float32 because it is multiplied and added to at every
 # token of a request
 SSM_STATE_DTYPE = jnp.float32
-_GROUP_OF = {"sliding": WINDOW, "mamba": STATE, "moe": NONE}
+_GROUP_OF = {"sliding": WINDOW, "moe": NONE}
+# the arrays a slot of the STATE group may hold, in the order
+# ``read_state`` gives and ``write_state`` takes them; which of them a
+# layer's pool has is its kind's (``init_pools``)
+_STATE_ARRAYS = ("conv_state", "ssm_state")
 
 
 def layer_groups(cfg) -> Optional[tuple]:
@@ -153,9 +174,17 @@ def layer_groups(cfg) -> Optional[tuple]:
     which has one group."""
     if cfg.layer_types is None:
         return None
-    period = cfg.layer_period
-    return tuple(_GROUP_OF.get(period[i % len(period)], FULL)
-                 for i in range(cfg.num_layers))
+    types = (cfg.layer_type(i) for i in range(cfg.num_layers))
+    return tuple(STATE if cfg.state_layer(t) else _GROUP_OF.get(t, FULL)
+                 for t in types)
+
+
+def heads_a_row(cfg) -> int:
+    """Key (or value) heads of one token that share a 128-lane row of the
+    pool: 2 for heads of half a row (``head_dim`` 64) in pairs, else 1
+    (a head is a row, or rows, of its own)."""
+    return 2 if (2 * cfg.head_dim == _LANES
+                 and cfg.num_query_groups % 2 == 0) else 1
 
 
 def window_pages_bound(window: int, chunk: int, block_size: int) -> int:
@@ -175,8 +204,9 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     sparse-attention indexer, its keys beside them (``index_pages``).  A
     layer's pool is sized by its group: ``window_blocks`` for the window
     group of a model with a layer type per layer, ``num_blocks`` for
-    every other layer; a state-space layer's is its two arrays a slot,
-    ``num_slots`` of them and the garbage row."""
+    every other layer; a layer that carries a state holds its kind's
+    arrays a slot (a state-space layer two, a gated short convolution
+    one), ``num_slots`` of them and the garbage row."""
     dtype = dtype or cfg.compute_jnp_dtype
     indexed = cfg.dsa_index_heads > 0
     groups = layer_groups(cfg)
@@ -194,7 +224,10 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
         raise ValueError("a model with state-space layers among its "
                          "layer_types needs num_slots")
 
-    def state():
+    def state(kind):
+        if kind == "conv":
+            return {"conv_state": jnp.zeros(
+                (num_slots + 1, cfg.conv_taps - 1, cfg.hidden_size), dtype)}
         return {
             "conv_state": jnp.zeros(
                 (num_slots + 1, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim),
@@ -203,8 +236,11 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                 (num_slots + 1, cfg.mamba_n_heads, cfg.mamba_d_head,
                  cfg.mamba_d_state), SSM_STATE_DTYPE)}
 
+    pack = 1 if quantized or indexed else heads_a_row(cfg)
+
     def pool(blocks):
-        shape = (blocks, block_size, cfg.num_query_groups, cfg.head_dim)
+        shape = (blocks, block_size, cfg.num_query_groups // pack,
+                 cfg.head_dim * pack)
         if quantized:
             return {"k_pages_q": jnp.zeros(shape, jnp.int8),
                     "k_pages_scale": jnp.ones(shape[:3], jnp.float32),
@@ -219,15 +255,15 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
         return kv
 
     return [{} if groups and groups[i] == NONE else
-            state() if groups and groups[i] == STATE else
+            state(cfg.layer_type(i)) if groups and groups[i] == STATE else
             pool(window_blocks if groups and groups[i] == WINDOW
                  else num_blocks) for i in range(cfg.num_layers)]
 
 
 def is_state(pool: dict) -> bool:
-    """Whether a layer's pool is a state-space layer's (arrays a slot,
+    """Whether a layer's pool is a state-carrying layer's (arrays a slot,
     no pages)."""
-    return "ssm_state" in pool
+    return "conv_state" in pool
 
 
 def paged_pools(pools) -> List[dict]:
@@ -374,13 +410,15 @@ class PagedKVCache:
             return jnp.concatenate([jnp.where(keep, val, a[:b]), a[b:]])
         return a.at[jnp.where(live, self.slots, a.shape[0] - 1)].set(val)
 
-    def read_state(self):
-        """A state-space layer's (``conv_state`` [b, d_conv - 1,
-        conv_dim], ``ssm_state`` [b, heads, d_head, d_state]) as each row
-        finds them (``_rows``)."""
+    def read_state(self) -> tuple:
+        """A state-carrying layer's arrays as each row finds them
+        (``_rows``), in ``_STATE_ARRAYS``' order: a state-space layer's
+        (``conv_state`` [b, d_conv - 1, conv_dim], ``ssm_state`` [b,
+        heads, d_head, d_state]), a gated short convolution's
+        (``conv_state`` [b, taps - 1, hidden],) alone."""
         fresh = self.context_lens == 0
-        return (self._rows(self.pool["conv_state"], fresh),
-                self._rows(self.pool["ssm_state"], fresh))
+        return tuple(self._rows(self.pool[name], fresh)
+                     for name in _STATE_ARRAYS if name in self.pool)
 
     def step_state(self, decay, dx, B, C):
         """One token of a state-space layer's recurrence on every live
@@ -403,17 +441,18 @@ class PagedKVCache:
         return y, dataclasses.replace(
             self, pool={**self.pool, "ssm_state": pool})
 
-    def write_state(self, conv_state: jax.Array,
-                    ssm_state: Optional[jax.Array] = None):
-        """The cache as a state-space layer's call leaves it: each live
-        row's state written at its slot (``_put``; ``ssm_state`` None:
-        ``step_state`` has written it), ``context_lens`` advanced."""
+    def write_state(self, *arrays):
+        """The cache as a state-carrying layer's call leaves it: each
+        live row's ``arrays`` (in ``read_state``'s order) written at its
+        slot (``_put``; one left out or None stays as it is:
+        ``step_state`` has written ``ssm_state``), ``context_lens``
+        advanced."""
         live = self.valid_lens > 0
-        pool = dict(self.pool, conv_state=self._put(
-            self.pool["conv_state"], conv_state, live))
-        if ssm_state is not None:
-            pool["ssm_state"] = self._put(self.pool["ssm_state"], ssm_state,
-                                          live)
+        pool = dict(self.pool)
+        names = [name for name in _STATE_ARRAYS if name in self.pool]
+        for name, val in zip(names, arrays):
+            if val is not None:
+                pool[name] = self._put(self.pool[name], val, live)
         return dataclasses.replace(
             self, pool=pool, context_lens=self.context_lens + self.valid_lens)
 
@@ -448,6 +487,11 @@ class PagedKVCache:
                               self.valid_lens)
         n, d = k.shape[1], k.shape[3]
         quantized = "k_pages_q" in self.pool
+        # heads of half a lane row lie two a row of the pool (module
+        # docstring): a token's [g, d] IS its [g / pack, pack * d]
+        pack = 1 if quantized else self.pool["k_pages"].shape[-1] // d
+        if pack > 1:
+            k, v = (a.reshape(a.shape[:2] + (-1, pack * d)) for a in (k, v))
         if quantized:
             from megatron_llm_tpu.quantization import absmax_quantize_int8
 
@@ -472,13 +516,20 @@ class PagedKVCache:
             kw = dict(valid_lens=vlen, k_scales=k_scales, v_scales=v_scales,
                       softmax_scale=scale, sliding_window=sliding_window,
                       name_suffix="_window" if self.group == WINDOW else "")
+            if pack > 1:
+                q = _in_own_part(q, kp.shape[2], pack)
             if n == 1:
                 ctx = _pa.paged_attention_decode(
                     q[:, 0], kp, vp, bt, ctx_lens, **kw)[:, None]
             else:
                 ctx = _pa.paged_attention_prefill(
                     q, kp, vp, bt, ctx_lens, **kw)
+            if pack > 1:
+                ctx = _own_part(ctx, kp.shape[2], pack)
         else:
+            if pack > 1:
+                # the pool as [blocks, block_size, g, d]: the same bytes
+                kp, vp = (a.reshape(a.shape[:2] + (-1, d)) for a in (kp, vp))
             ctx = _pa.dense_paged_attention(
                 q, kp, vp, bt, ctx_lens, vlen, k_scales, v_scales,
                 scale, sliding_window)
@@ -595,6 +646,31 @@ class PagedKVCache:
             scale)
 
 
+def _in_own_part(q: jax.Array, rows: int, pack: int) -> jax.Array:
+    """Queries ``[b, n, nh, d]`` for a pool whose ``rows`` rows a token
+    hold ``pack`` key heads each: ``[b, n, nh, pack * d]``, a head's
+    values in the part of the row its own key head lies in (key head
+    ``gh`` is part ``gh % pack`` of row ``gh // pack``) and zeros in the
+    others, so that its product with a row is its own key head's alone.
+    The walk then takes the pool as ``rows`` heads of ``pack * d``."""
+    b, n, nh, d = q.shape
+    qpg = nh // (rows * pack)
+    q = q.reshape(b, n, rows, pack, qpg, 1, d)
+    own = jnp.eye(pack, dtype=q.dtype).reshape(1, 1, 1, pack, 1, pack, 1)
+    return (q * own).reshape(b, n, nh, pack * d)
+
+
+def _own_part(ctx: jax.Array, rows: int, pack: int) -> jax.Array:
+    """What ``_in_own_part``'s queries attended, ``[b, n, nh, pack * d]``
+    (each head against ``pack`` value heads side by side), cut to the
+    head's own value head: ``[b, n, nh, d]``."""
+    b, n, nh, wide = ctx.shape
+    qpg = nh // (rows * pack)
+    ctx = ctx.reshape(b, n, rows, pack, qpg, pack, wide // pack)
+    return jnp.stack([ctx[:, :, :, p, :, p] for p in range(pack)],
+                     axis=3).reshape(b, n, nh, wide // pack)
+
+
 def step_caches(pools, block_tables, context_lens, valid_lens,
                 kernel: Optional[str] = None,
                 groups: Optional[tuple] = None) -> List[PagedKVCache]:
@@ -639,7 +715,7 @@ class CachePlan:
     # blocks first (every slot at its bound); None without a window group
     window: Optional[tuple]
     # a block's bytes over the layers of the full and the window group,
-    # and a slot's recurrent state over the state-space layers
+    # and a slot's state over the layers that carry one
     group_block_bytes: tuple
     state_bytes_per_slot: int
     # learned sparse attention: the keys of a block its choice counts in
@@ -649,6 +725,9 @@ class CachePlan:
     # a layer whose pool holds arrays (an expert layer alone holds none):
     # what a launch is waited for by
     first_pool: int = 0
+    # the layer type of each layer of the STATE group, in order: what a
+    # launch's counters of state are counted by
+    state_kinds: tuple = ()
 
     def init_pools(self, num_blocks: int, quantized: bool = False):
         return init_pools(self.cfg, num_blocks, self.block_size,
@@ -680,11 +759,19 @@ class CachePlan:
         of ``n`` queries a row; ``admitted``: the requests that hold a
         slot.  Returns at once for a model with no such mechanism."""
         cfg, layers = self.cfg, self.cfg.num_layers
-        state_layers = self.groups.count(STATE) if self.groups else 0
-        if not (cfg.latent_attention or self.dsa_block_keys or state_layers):
+        state_layers, conv_layers = (self.state_kinds.count(k)
+                                     for k in ("mamba", "conv"))
+        if not (cfg.latent_attention or self.dsa_block_keys
+                or self.state_kinds):
             return
         live = valid_lens > 0
         ctx, val = context_lens[live], valid_lens[live]
+        if self.state_kinds:
+            # the state group's bytes, whatever the kind that holds them
+            d.ssm_state_bytes_held = admitted * self.state_bytes_per_slot
+        if conv_layers:
+            d.conv_rows_live = conv_layers * len(val)
+            d.conv_tokens = conv_layers * int(val.sum())
         if state_layers:
             d.ssm_rows_live = state_layers * len(val)
             if d.kind != "prefill":
@@ -694,7 +781,6 @@ class CachePlan:
                     d.ssm_rows_live if self.paged_kernel == "pallas"
                     else state_layers * (self.num_slots + 1))
             d.ssm_tokens = state_layers * int(val.sum())
-            d.ssm_state_bytes_held = admitted * self.state_bytes_per_slot
         if not (cfg.latent_attention or self.dsa_block_keys):
             return
         # for each live query the keys it sees: positions 0..its own
@@ -771,7 +857,9 @@ def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
         tuple(block_bytes([p for p, g in zip(pools, groups or ())
                            if g == which]) for which in (FULL, WINDOW)),
         state_bytes_per_slot(pools), dsa_block_keys, dsa_table_blocks,
-        next((i for i, g in enumerate(groups or ()) if g != NONE), 0))
+        next((i for i, g in enumerate(groups or ()) if g != NONE), 0),
+        tuple(cfg.layer_type(i) for i, g in enumerate(groups or ())
+              if g == STATE))
 
 def pools_of(caches: List[PagedKVCache]) -> List[dict]:
     """The pools as a step left them."""

@@ -162,10 +162,16 @@ MLA_FIELDS = ("mla_keys_live", "mla_pairs", "mla_latents_expanded")
 # whose state a DECODE launch's program reads and writes (the step's
 # kernel, ``ops/pallas/ssm_step.py``: the live rows; the XLA step: every
 # slot and the garbage row; 0 on a prefill launch); tokens scanned x
-# layers; bytes of recurrent state the admitted requests hold as the
-# launch begins (slots in use x a slot's state over the layers)
+# layers; bytes of state the admitted requests hold in the STATE group
+# as the launch begins (slots in use x a slot's state over the layers
+# that carry one, whatever their kind)
 SSM_FIELDS = ("ssm_rows_live", "ssm_rows_moved", "ssm_tokens",
               "ssm_state_bytes_held")
+
+# gated short-convolution layers (a model with 'conv' layers;
+# ``CachePlan.account``): live rows x layers whose columns the launch
+# reads and writes; tokens convolved x layers
+CONV_FIELDS = ("conv_rows_live", "conv_tokens")
 
 # a model with a layer type per layer (the engine's ``_window_advance``):
 # window-group pages given back to the allocator before this launch and
@@ -191,7 +197,7 @@ HOST_FIELDS = ("host_uploads", "host_reads", "compile_secs", "gc_secs")
 # the ONE declaration of the counted fields: what ``finish`` sums,
 # ``as_dict()`` carries and ``totals()`` gives goes through it
 COUNTED_FIELDS = (MOE_FIELDS + DSA_FIELDS + MLA_FIELDS + SSM_FIELDS
-                  + KV_FIELDS + HOST_FIELDS)
+                  + CONV_FIELDS + KV_FIELDS + HOST_FIELDS)
 
 # the compiled programs whose operations run inside a launch of each
 # kind (a last prefill chunk samples its first token in the same launch),

@@ -546,6 +546,12 @@ TRACED = {
     # model, whose programs are the ones above
     "trinity": {"engine_prefill": "0c33c9c2556bb82a",
                 "engine_decode": "a30d0d0cca6cdb4d"},
+    # PR 51 brought this family and changed no other's: the state group's
+    # arrays by the layer's kind, the router's normaliser as data and the
+    # pool's two-heads-a-row layout at 64-wide heads leave every program
+    # above as it was
+    "lfm2": {"engine_prefill": "c08e0b51f0aec84b",
+             "engine_decode": "913f73debd40aff4"},
 }
 
 

@@ -490,13 +490,13 @@ def _groups():
     from megatron_llm_tpu.serving import loop_profiler as lp
 
     return {name: getattr(lp, name) for name in (
-        "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS", "KV_FIELDS",
-        "HOST_FIELDS")}
+        "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS",
+        "CONV_FIELDS", "KV_FIELDS", "HOST_FIELDS")}
 
 
 @pytest.mark.parametrize("group", sorted(_groups()))
 def test_a_counted_field_is_summed_where_the_launch_finishes(group):
-    """The counted fields have ONE declaration, the six groups' tuples
+    """The counted fields have ONE declaration, the seven groups' tuples
     together: a record that sets every one of them, finished twice, is in
     ``totals()`` twice, field by field, ``as_dict()`` carries each, and a
     record that set none counts nothing."""

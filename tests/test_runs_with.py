@@ -25,6 +25,7 @@ SQUARES = [(has, what) for has, whats in C.RUNS_WITH for what in whats]
 # a served family that has each mechanism, and how a config (or an
 # engine) is given each thing a mechanism does not run with
 FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
+          C.SHORT_CONV: "lfm2",
           C.ONE_SUBLAYER: "nemotron_h",
           C.GATE: "trinity", C.OUTPUT_NORMS: "trinity",
           C.ROPE_TYPES: "trinity",
@@ -37,6 +38,12 @@ GIVEN = {
     C.OTHER_TYPES: lambda cfg: dict(
         layer_types=cfg.layer_types[:-1] + ("sliding",),
         sliding_window_size=16),
+    # the same for a stack of 'conv' and 'attention' layers
+    C.CONV_OTHER_TYPES: lambda cfg: dict(
+        layer_types=cfg.layer_types[:-1] + ("sliding",),
+        sliding_window_size=16),
+    C.GATE: dict(attention_output_gate=True),
+    C.OUTPUT_NORMS: dict(sublayer_output_norm=True),
     C.BIASES: dict(add_bias_linear=True),
     C.QKV_BIAS: dict(add_qkv_bias=True),
     C.PARALLEL_ATTN: dict(parallel_attn=True),
@@ -152,7 +159,64 @@ def test_a_square_of_the_table_is_told_by_whoever_asks(has, what,
         assert what in said
 
 
-@pytest.mark.parametrize("family", ["kanana", "keye", "mellum", "granite"])
+def test_a_conv_stack_with_mamba_layers_is_refused_by_name():
+    """``mamba`` and ``conv`` in one stack: two states of two shapes a
+    slot, held to nothing, so the constructor says so."""
+    with pytest.raises(ValueError, match="'conv' layer type goes with "
+                                         "'attention' layers only"):
+        _config("lfm2", layer_types=("conv", "mamba") * 4)
+
+
+@pytest.mark.parametrize("family,dense", [("granite", 1), ("granite", 3),
+                                          ("lfm2", 2)])
+def test_leading_dense_layers_run_in_a_stack_whose_mixers_are_by_kind(
+        family, dense):
+    """``FIRST_DENSE`` beside a state stack is no square of the table any
+    more: the config is built, and the cache-less forward (the dense
+    layers and the rest of their period unrolled, then whole periods
+    scanned) and the engine's own programs (a mixer by
+    ``cfg.mixer_index`` over the whole depth) count a layer the same
+    way: one prompt's last logits and its greedy continuation agree."""
+    import numpy as np
+
+    from megatron_llm_tpu.serving import SamplingParams
+
+    cfg = _config(family, moe_first_dense_layers=dense)
+    assert C.HAS[C.FIRST_DENSE](cfg) and cfg.mixers_by_kind
+    assert C.refusal(cfg) is None
+    model = MODEL_REGISTRY[family](cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_leaves(params["transformer"]["dense_layers"])[
+        0].shape[0] == dense
+    kinds = [cfg.mixer_index(i)[0] for i in range(cfg.num_layers)]
+    for kind, n in cfg.mixer_counts.items():
+        assert kinds.count(kind) == n == jax.tree_util.tree_leaves(
+            params["transformer"]["layers"][kind])[0].shape[0]
+    eng = InferenceEngine(model, params, EngineConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16,
+        preemption=False, default_deadline_secs=600.0))
+    inner, got = eng._prefill_step, {}
+
+    def tapped(params, pages, tokens, start, valid, table):
+        out = inner(params, pages, tokens, start, valid, table)
+        got[int(start) + int(valid) - 1] = np.asarray(out[0])
+        return out
+
+    eng._prefill_step = tapped
+    prompt = np.random.default_rng(3).integers(1, 500, 21).tolist()
+    req = eng.submit(prompt, SamplingParams(max_new_tokens=4,
+                                            temperature=0.0))
+    while req.finish_reason is None:
+        assert eng.step()
+    seq = prompt + list(req.out_tokens)[:-1]
+    want = np.asarray(model(params, jax.numpy.asarray([seq], "int32"),
+                            train=False)[0])
+    np.testing.assert_allclose(got[20], want[20], atol=2e-4, rtol=0)
+    assert list(req.out_tokens) == want[20:].argmax(-1).tolist()
+
+
+@pytest.mark.parametrize("family", ["kanana", "keye", "mellum", "granite",
+                                    "lfm2"])
 def test_the_pool_and_the_engine_refuse_int8_in_one_sentence(family):
     """``init_pools(quantized=True)`` and the engine ask the same table,
     so a latent, an indexed, a grouped model and one with state-space

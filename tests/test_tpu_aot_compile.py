@@ -100,6 +100,32 @@ def _paged(q_tokens, slots, page=16, int8=False, window=None,
     return fn, [((slots, q_tokens, heads, HEAD_DIM), BF16)] + shapes
 
 
+def _paged_packed(q_tokens, slots, heads=32, kv_heads=8, head_dim=64,
+                  tokens=4352, pages=16385, page=16):
+    """The same over a pool of 64-WIDE heads as ``ops/paged_kv.py`` holds
+    it, two heads a 128-lane row, through ``PagedKVCache.attend`` (the
+    write, the queries set in their own half of a row, the walk, the own
+    half of its output): LFM2's 32 / 8 heads of 64, 128 slots of up to
+    4,352 tokens over 16,385 pages.  Straight through the walk at
+    ``[pages, 16, 8, 64]`` Mosaic refuses both kernels ("Slice shape along
+    dimension 3 must be aligned to tiling (128), but is 64")."""
+    from megatron_llm_tpu.ops import paged_kv
+
+    pool = ((pages, page, kv_heads // 2, 2 * head_dim), BF16)
+    kv = ((slots, q_tokens, kv_heads, head_dim), BF16)
+
+    def fn(q, k, v, k_pages, v_pages, tables, lens, valid):
+        cache = paged_kv.PagedKVCache(
+            {"k_pages": k_pages, "v_pages": v_pages}, tables, lens, valid,
+            kernel="pallas")
+        ctx, cache = cache.attend(q, k, v, None)
+        return ctx, cache.pool
+
+    return fn, [((slots, q_tokens, heads, head_dim), BF16), kv, kv, pool,
+                pool, ((slots, tokens // page), jnp.int32),
+                ((slots,), jnp.int32), ((slots,), jnp.int32)]
+
+
 def _experts(rows, experts, hidden, ffn, layers=8):
     """The dropless expert layer's two grouped matmuls over a model's
     stacked experts (``models/moe.py``) at the four served widths
@@ -262,6 +288,10 @@ CASES = {
         lambda: _paged(1, 64, kv_heads=2, tokens=6144),
     "paged_prefill_chunk_512_2_kv_heads":
         lambda: _paged(512, 1, kv_heads=2, tokens=6144),
+    "paged_decode_8_kv_heads_of_64_128_slots":
+        lambda: _paged_packed(1, 128),
+    "paged_prefill_chunk_512_8_kv_heads_of_64":
+        lambda: _paged_packed(512, 1),
     "moe_experts_olmoe_512_rows": lambda: _experts(512, 64, 2048, 1024),
     "moe_experts_olmoe_verify_320_rows":
         lambda: _experts(8 * 5 * 8, 64, 2048, 1024),
@@ -280,6 +310,7 @@ ENGINE_TABLES = "engine_tables_tiny_sparse_model"
 GRANITE = "granite_cell_programs"
 NEMOTRON = "nemotron_cell_programs"
 TRINITY = "trinity_cell_programs"
+LFM2 = "lfm2_cell_programs"
 
 
 def _compile_all(only: str = ""):
@@ -302,7 +333,8 @@ def _compile_all(only: str = ""):
     if only:
         programs = {"granite": (GRANITE, _granite_programs),
                     "nemotron": (NEMOTRON, _nemotron_programs),
-                    "trinity": (TRINITY, _trinity_programs)}[only]
+                    "trinity": (TRINITY, _trinity_programs),
+                    "lfm2": (LFM2, _lfm2_programs)}[only]
         print(json.dumps({programs[0]: programs[1](chip)}))
         return
     found = {}
@@ -430,6 +462,21 @@ def _trinity_programs(chip):
         num_slots=48, num_blocks=32769, max_model_len=20992))
 
 
+def _lfm2_programs(chip):
+    """The same of the benchmark's LFM2 cell: the published model's first
+    14 layers as they stand (both dense layers, eleven gated short
+    convolutions and three attention layers of 64-wide heads) at the
+    published widths, all 32 experts of 2048 x 1792, the whole vocabulary
+    under a tied head, 128 slots of state and 16,385 pages."""
+    from megatron_llm_tpu.models.lfm2 import (PUBLISHED_LAYER_TYPES,
+                                              Lfm2Model, lfm2_config)
+
+    return _cell_programs(chip, lambda: Lfm2Model(lfm2_config(
+        "8b-a1b", num_layers=14, layer_types=PUBLISHED_LAYER_TYPES[:14],
+        params_dtype="bf16", compute_dtype="bf16", seq_length=4352)), dict(
+        num_slots=128, num_blocks=16385, max_model_len=4352))
+
+
 # rows of a compiled program that move or compute nothing
 _NO_WORK = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "custom-call")
@@ -480,7 +527,8 @@ def _cell_programs(chip, build, engine):
                 "scopes": sorted({s for s in (
                     "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
                     "ssm_gate_norm", "ssm_out_proj", "attn_gate",
-                    "post_attn_norm", "post_mlp_norm")
+                    "post_attn_norm", "post_mlp_norm", "conv_in_proj",
+                    "short_conv", "conv_out_proj")
                     if f"/{s}/" in text})}
         return found
     except Exception as e:      # noqa: BLE001 - the compiler's refusal
@@ -524,6 +572,11 @@ def nemotron_compiled():
 @pytest.fixture(scope="module")
 def trinity_compiled():
     return _child("trinity")
+
+
+@pytest.fixture(scope="module")
+def lfm2_compiled():
+    return _child("lfm2")
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -700,6 +753,46 @@ def test_the_trinity_cells_programs_compile_and_fit_a_v5e(trinity_compiled):
             got["scopes"]), got["scopes"]
         assert {"moe_experts", walk, walk + "_window"} <= set(
             got["kernels"]), got["kernels"]
+
+
+@pytest.mark.time_limit(900)
+def test_the_lfm2_cells_programs_compile_and_fit_a_v5e(lfm2_compiled):
+    """The LFM2 cell's two programs at its real sizes, for a described
+    v5e: both compile with the experts' grouped matmul at 2048 x 1792 and
+    BOTH walks as kernels over a pool of 64-wide heads held two a row
+    (2,048 B a token an attention layer, not the 4,096 a last dimension
+    of 64 is laid out at), the three scopes of the convolution's mixer in
+    the text; the step owns its pools and gives them back, the chunk
+    holds them twice, and that fits the chip's 15.75 GB at 128 slots."""
+    found = lfm2_compiled[LFM2]
+    assert isinstance(found, dict), found
+    # eleven conv layers of two columns of 2,048 in bf16 a slot
+    assert found["state_bytes_per_slot"] == 11 * 2 * 2048 * 2 == 90112
+    # 16,385 pages of 16 tokens of 2,048 B over three attention layers,
+    # and 128 slots and the garbage row of state
+    assert found["pool_bytes"] == (16385 * 3 * 16 * 2048 + 129 * 90112)
+    # ISSUE 51's arithmetic: 2 x 60.8 M + 9 x 369.2 M + 3 x 362.9 M +
+    # 134.2 M, the head tied
+    assert found["parameters"] == 4_667_077_376
+    tiles = found["moe_expert_tiles"]
+    assert (tiles["w_in"]["k"], tiles["w_in"]["n"]) == (2048, 3584)
+    assert (tiles["w_out"]["k"], tiles["w_out"]["n"]) == (1792, 2048)
+    for name, walk in (("engine_prefill", "paged_attention_prefill"),
+                       ("engine_decode", "paged_attention_decode")):
+        got = found[name]
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"])
+        assert got["output_bytes"] >= found["pool_bytes"], (name, got)
+        if name == "engine_decode":
+            assert got["alias_bytes"] >= found["pool_bytes"], (name, got)
+        else:
+            assert got["alias_bytes"] == 0, (name, got)
+        # 9.33 GB of weights and 1.62 GB of pools, the chunk's twice
+        assert held - got["alias_bytes"] < 15.75e9 * 0.85, (name, held)
+        assert got["temp_bytes"] < 0.5e9, (name, got)
+        assert {"conv_in_proj", "short_conv", "conv_out_proj"} <= set(
+            got["scopes"]), got["scopes"]
+        assert {"moe_experts", walk} <= set(got["kernels"]), got["kernels"]
 
 
 if __name__ == "__main__":
