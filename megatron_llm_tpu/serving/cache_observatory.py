@@ -73,7 +73,8 @@ class _GhostTier:
     the oracle test pins it to zero."""
 
     __slots__ = ("mult", "capacity", "free", "table", "lru", "slots",
-                 "hits", "misses", "hit_tokens", "evictions", "overflows")
+                 "hits", "misses", "hit_tokens", "adopted", "evictions",
+                 "overflows")
 
     def __init__(self, mult: int, usable_blocks: int):
         self.mult = int(mult)
@@ -89,6 +90,7 @@ class _GhostTier:
         self.hits = 0
         self.misses = 0
         self.hit_tokens = 0
+        self.adopted = 0        # the part of hits found when prefill began
         self.evictions = 0
         self.overflows = 0      # budget exhausted (never with mult >= 1)
 
@@ -143,6 +145,34 @@ class _GhostTier:
             items.append(None)
         self.slots[slot] = items
         self.hit_tokens += len(matched) * block_size
+
+    def adopt_locked(self, slot: int, digests: Sequence[bytes], first: int,
+                     block_size: int) -> None:
+        """adopt_committed(): from block ``first`` of the slot, every
+        private block whose digest this tier holds is given up for a
+        reference to it, up to the first it does not hold.  A block the
+        tier already holds by reference (its admission matched further
+        than the real one's) is walked over."""
+        items = self.slots.get(slot)
+        if items is None:
+            return
+        n = 0
+        for i in range(first, min(len(digests), len(items))):
+            if items[i] is not None:
+                continue
+            d = digests[i]
+            rc = self.table.get(d)
+            if rc is None:
+                break
+            if rc == 0:
+                self.lru.pop(d, None)
+            self.table[d] = rc + 1
+            items[i] = d
+            self.free += 1
+            n += 1
+        self.hits += n
+        self.adopted += n
+        self.hit_tokens += n * block_size
 
     def commit_locked(self, slot: int, digests: Sequence[bytes]) -> List[str]:
         """_commit_locked: register fully written private blocks; an
@@ -233,6 +263,7 @@ class _GhostTier:
             "hits": self.hits,
             "misses": self.misses,
             "hit_tokens": self.hit_tokens,
+            "adopted_at_prefill": self.adopted,
             "evictions": self.evictions,
             "entries": len(self.table),
             "hit_rate": round(self.hits / probes, 4) if probes else None,
@@ -275,6 +306,7 @@ class CacheObservatory:
         "hits": "_lock",
         "misses": "_lock",
         "hit_tokens": "_lock",
+        "adopted_at_prefill": "_lock",
         "miss_cold": "_lock",
         "miss_evicted": "_lock",
         "evictions_capacity": "_lock",
@@ -332,6 +364,10 @@ class CacheObservatory:
         self.hits = 0               # shadow of the real manager's counter
         self.misses = 0
         self.hit_tokens = 0
+        # the part of hits (and of probes: a block still missing when
+        # prefill begins was counted a miss at admission, not again)
+        # that BlockManager.adopt_committed found, in blocks
+        self.adopted_at_prefill = 0
         self.miss_cold = 0          # digest never seen in the ledger
         self.miss_evicted = 0       # the evicted-then-wanted regret counter
         self.evictions_capacity = 0
@@ -443,6 +479,27 @@ class CacheObservatory:
                 matched = token.ghost_matched.get(t.mult, []) \
                     if token is not None else []
                 t.admit_locked(slot, matched, n_blocks, self.block_size)
+
+    def record_adopt(self, slot: int, digests: Sequence[bytes], first: int,
+                     refcounts: Sequence[int]) -> None:
+        """adopt_committed() took ``len(refcounts)`` blocks from
+        ``digests[first]`` on by reference as the slot's prefill reached
+        them: hits, heat and residency as an admission's match counts
+        them, and every ghost tier adopts what IT holds from there."""
+        with self._lock:
+            n = len(refcounts)
+            self.probes += n
+            self.hits += n
+            self.adopted_at_prefill += n
+            self.hit_tokens += n * self.block_size
+            for d, rc in zip(digests[first:first + n], refcounts):
+                e = self._heat_touch_locked(d)
+                e["hits"] += 1
+                e["hit_tokens"] += self.block_size
+                e["residency"] += int(rc)
+                e["peak_refcount"] = max(e["peak_refcount"], int(rc))
+            for t in self._tiers:
+                t.adopt_locked(slot, digests, first, self.block_size)
 
     def record_commit(self, slot: int, digests: Sequence[bytes],
                       real_actions: Sequence[str] = ()) -> None:
@@ -588,6 +645,7 @@ class CacheObservatory:
                 "hits": self.hits,
                 "misses": self.misses,
                 "hit_tokens": self.hit_tokens,
+                "adopted_at_prefill": self.adopted_at_prefill,
                 "hit_rate": (round(self.hits / probes, 4)
                              if probes else None),
                 "host_hits": self.host_hits,
@@ -657,7 +715,10 @@ class CacheObservatory:
                     assert e["hits"] == 0 or key in self._seen, \
                         f"heat entry {key} hit but never registered"
             for t in self._tiers:
-                assert t.hits + t.misses == self.probes, \
+                # admission's probes are every tier's; what each found
+                # when prefill began is its own
+                assert t.hits + t.misses - t.adopted \
+                    == self.probes - self.adopted_at_prefill, \
                     f"ghost x{t.mult} probed a different stream"
                 assert t.overflows == 0, \
                     f"ghost x{t.mult} budget overflow"

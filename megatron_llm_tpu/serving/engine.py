@@ -482,6 +482,9 @@ class InferenceEngine:
         self.prefill_tokens_submitted = 0   # prompt tokens admitted
         self.prefill_tokens_computed = 0    # actually ran through prefill
         self.prefill_tokens_cached = 0      # adopted from the prefix cache
+        # ... of which when a request's prefill began (_adopt_committed);
+        # the rest at admission
+        self.prefill_tokens_cached_at_prefill = 0
         self.occupancy_sum = 0          # sum of active slots over decode steps
         self.drafted_tokens = 0         # prompt-lookup proposals verified
         self.accepted_tokens = 0        # proposals committed by verify
@@ -1072,7 +1075,7 @@ class InferenceEngine:
                         cached_prompt_tokens=req.cached_prompt_tokens)
         if req.cached_prompt_tokens > 0:
             tracing.instant("prefix_cache_hit", "serve", request=req.id,
-                            trace=req.trace_id,
+                            trace=req.trace_id, at="admission",
                             tokens=req.cached_prompt_tokens)
 
     # -- pool-pressure preemption ---------------------------------------
@@ -1126,7 +1129,7 @@ class InferenceEngine:
                          "request": victim.id, "trace_id": victim.trace_id,
                          "generated": len(victim.out_tokens),
                          "n_written": n_written})
-        st.scheduler.preempt(victim, token_ids=victim.context_tokens(),
+        st.scheduler.preempt(victim, token_ids=victim.chain,
                              n_written=n_written)
 
     # -- prefill --------------------------------------------------------
@@ -1204,12 +1207,35 @@ class InferenceEngine:
                         trace=req.trace_id, blocks=len(loaded),
                         secs=round(secs, 6))
 
+    def _adopt_committed(self, st: _EngineState, req: Request) -> None:
+        """The prefix cache asked again, before every chunk: admission
+        matched ``req`` against what the cache held then, and requests
+        admitted beside it (a document's other questions, a system
+        prompt's other users) have committed pages since.  What the
+        cache holds NOW from ``req.prefill_pos`` on is adopted
+        (``BlockManager.adopt_committed``) and prefill goes on behind
+        it.  A miss is one dictionary lookup; a model that adopts no
+        prefix returns at once.  The block tables are read off the host
+        at every launch (``CachePlan.tables``), so no copy of them on
+        the device goes stale."""
+        adopted = st.blocks.adopt_committed(req.slot, req.chain,
+                                            req.prefill_pos)
+        if not adopted:
+            return
+        req.prefill_pos += adopted
+        req.cached_prompt_tokens += adopted
+        self.prefill_tokens_cached += adopted
+        self.prefill_tokens_cached_at_prefill += adopted
+        tracing.instant("prefix_cache_hit", "serve", request=req.id,
+                        trace=req.trace_id, at="prefill", tokens=adopted)
+
     def _run_prefill_chunk(self, st: _EngineState, req: Request,
                            d: DispatchRecord) -> None:
         if self.host_cache is not None:
             # consume pending host-tier swap-ins first (no-op after the
             # slot's first chunk); accounted to the build_inputs bucket
             self._swap_in(st, req)
+        self._adopt_committed(st, req)
         C = self.config.prefill_chunk
         # prefill over the full context — prompt plus anything generated
         # before a preemption/restart requeued this request (identical to
@@ -1274,7 +1300,7 @@ class InferenceEngine:
         req.prefill_pos = start + valid
         # freshly filled full blocks become shareable right away, so a
         # burst of same-prefix requests hits even mid-prefill
-        st.blocks.commit_prefix(req.slot, ptoks, req.prefill_pos)
+        st.blocks.commit_prefix(req.slot, req.chain, req.prefill_pos)
         if not done:
             self.loop_profiler.finish(d)
             return
@@ -1563,7 +1589,7 @@ class InferenceEngine:
             st.active[s] = 0
         if req.finish_reason == FINISH_NONFINITE:
             n_written = 0   # poisoned KV: register nothing for reuse
-        st.scheduler.evict(req, token_ids=req.tokens, n_written=n_written)
+        st.scheduler.evict(req, token_ids=req.chain, n_written=n_written)
         self._count_finish(req.finish_reason)
         # the request's own span, on the launches' clock: kept beside
         # them always, and written to the SpanTracer when one is there
@@ -1818,6 +1844,8 @@ class InferenceEngine:
             "prefill_tokens_submitted": self.prefill_tokens_submitted,
             "prefill_tokens_computed": self.prefill_tokens_computed,
             "prefill_tokens_cached": self.prefill_tokens_cached,
+            "prefill_tokens_cached_at_prefill":
+                self.prefill_tokens_cached_at_prefill,
             "mean_batch_occupancy": self.occupancy_sum / dec,
             "prefill_secs": round(self.prefill_secs, 6),
             "decode_secs": round(self.decode_secs, 6),

@@ -63,6 +63,13 @@ one launch's tokens and a page; fewer for a short request), so a running
 request never finds it empty.  A model of one layer type has no window
 group and holds its pages as above, whatever its window.
 
+When the cache is asked: at admission (``alloc``: the longest cached
+prefix of the prompt, both tiers) and again whenever a request's prefill
+is about to compute a block (``adopt_committed``: what requests admitted
+beside it have committed since, the device's cache only).  Both take the
+request's chain digests from the :class:`TokenChain` it carries, hashed
+once a request however often admission refuses it.
+
 Hierarchical tier (``host_cache``, serving/host_cache.py): with a host
 spill tier attached, registrations and parkings additionally enqueue an
 asynchronous device→host page copy, and the admission match extends its
@@ -88,7 +95,12 @@ GARBAGE_BLOCK = 0
 
 
 class NoCapacity(Exception):
-    """Not enough free blocks / slots for the requested admission."""
+    """Not enough free blocks / slots for the requested admission.
+    ``version`` and ``first_missing`` are the pool's state the refusal
+    rests on (``BlockManager.refusal_stands``)."""
+
+    version = -1
+    first_missing: Optional[bytes] = None
 
 
 def digest_link(prev: bytes, payload: bytes) -> bytes:
@@ -107,18 +119,75 @@ def digest_link(prev: bytes, payload: bytes) -> bytes:
 
 
 def chain_block_digests(token_ids: Sequence[int], block_size: int,
-                        n_blocks: int) -> List[bytes]:
+                        n_blocks: int,
+                        known: Sequence[bytes] = ()) -> List[bytes]:
     """Rolling 128-bit digests for the first ``n_blocks`` full blocks of
     ``token_ids``: digest i commits to every token in blocks 0..i, so a
-    cache hit on digest i implies the whole prefix matches."""
-    out: List[bytes] = []
-    prev = b""
-    for i in range(n_blocks):
-        chunk = token_ids[i * block_size:(i + 1) * block_size]
-        prev = digest_link(
-            prev, np.asarray(list(chunk), np.int64).tobytes())
-        out.append(prev)
+    cache hit on digest i implies the whole prefix matches.  ``known``
+    is the chain's beginning where a caller has it already: only the
+    blocks after it are hashed."""
+    out: List[bytes] = list(known[:n_blocks])
+    have = len(out)
+    if have < n_blocks:
+        ids = np.asarray(token_ids[have * block_size:n_blocks * block_size],
+                         np.int64)
+        prev = out[-1] if out else b""
+        for i in range(n_blocks - have):
+            prev = digest_link(
+                prev, ids[i * block_size:(i + 1) * block_size].tobytes())
+            out.append(prev)
     return out
+
+
+class TokenChain:
+    """A token sequence that only grows at its end (a request's prompt,
+    then what it generates) with the chain digests of its full blocks.
+    The digests are a function of the tokens alone, so each block is
+    hashed ONCE, when a digest of it is first asked for, and a longer
+    sequence extends the chain instead of redoing it.  A ``Request``
+    carries one (``Request.chain``); the block manager's doors wrap a
+    bare token sequence in one (:func:`token_chain`)."""
+
+    # lint-enforced (graft-lint locks/LD002): whoever registers or
+    # releases a request's pages extends its chain, the engine's thread
+    # and a drain alike
+    _lock_protected_ = {"_digests": "_lock", "_block_size": "_lock"}
+
+    __slots__ = ("_parts", "_block_size", "_digests", "_lock")
+
+    def __init__(self, *parts: Sequence[int]):
+        # the sequence is ``parts`` one after another, held by reference:
+        # the last may grow at its end (a request's prompt, its answer)
+        self._parts = parts
+        self._lock = threading.Lock()
+        self._block_size = 0
+        self._digests: List[bytes] = []
+
+    def __len__(self) -> int:
+        return sum(len(p) for p in self._parts)
+
+    def _tokens(self) -> Sequence[int]:
+        if len(self._parts) == 1:
+            return self._parts[0]
+        return [t for p in self._parts for t in p]
+
+    def digests(self, block_size: int, n_blocks: int) -> List[bytes]:
+        """The chain digests of the first ``n_blocks`` full blocks."""
+        with self._lock:
+            if block_size != self._block_size:
+                self._block_size, self._digests = block_size, []
+            if len(self._digests) < n_blocks:
+                self._digests = chain_block_digests(
+                    self._tokens(), block_size, n_blocks, self._digests)
+            return self._digests[:n_blocks]
+
+
+def token_chain(token_ids) -> TokenChain:
+    """``token_ids`` as a :class:`TokenChain`: the one a request carries
+    as it is, a bare sequence wrapped for this call."""
+    if isinstance(token_ids, TokenChain):
+        return token_ids
+    return TokenChain(token_ids)
 
 
 AFFINITY_CHAR_BLOCK = 64
@@ -255,7 +324,7 @@ class BlockManager:
         "_block_epoch", "host_cache",
         "prefix_cache_hits", "prefix_cache_misses",
         "prefix_cache_evictions", "prefix_cache_hit_tokens",
-        "prefix_cache_host_hits", "cow_copies", "window",
+        "prefix_cache_host_hits", "cow_copies", "window", "_version",
     )
 
     def __init__(self, num_blocks: int, block_size: int, num_slots: int,
@@ -328,6 +397,10 @@ class BlockManager:
         self.prefix_cache_hit_tokens = 0
         self.prefix_cache_host_hits = 0             # host-tier subset
         self.cow_copies = 0
+        # counts the times blocks or slots came back (free,
+        # adopt_committed, a window group's advance): with the first
+        # digest a refused request missed, what refusal_stands asks
+        self._version = 0
 
     def attach_host_cache(self, host_cache) -> None:
         """Wire the host spill tier after construction (the engine
@@ -349,6 +422,17 @@ class BlockManager:
                     and n <= self.max_blocks_per_slot
                     and self._window_admits_locked(n))
 
+    def refusal_stands(self, refusal: NoCapacity) -> bool:
+        """Whether ``alloc`` would refuse again the request it raised
+        ``refusal`` for: nothing came back to the pool since, and the
+        first block of its prefix that the cache lacked is still
+        lacking, so its match is no longer.  One lookup where a retry
+        walks the request's whole prefix; the scheduler asks before
+        every launch while the queue's head waits."""
+        with self._lock:
+            return (refusal.version == self._version
+                    and refusal.first_missing not in self._cache)
+
     def _window_admits_locked(self, n_blocks: int) -> bool:
         w = self.window
         return w is None or w.reservation(n_blocks) <= w.available()
@@ -368,6 +452,7 @@ class BlockManager:
             returned = sum(w.advance_locked(slot, start, n)
                            for slot, start, n in writes
                            if slot in self._slot_blocks)
+            self._version += bool(returned)
             full = self.num_blocks - 1 - len(self._free_blocks) - len(
                 self._lru)
             return returned, w.pages_spanned - spanned, full, w.pages_held()
@@ -415,10 +500,15 @@ class BlockManager:
                 return None
             return b, self._block_epoch.get(b, 0)
 
-    def _match_prefix_locked(self, prompt_tokens: Sequence[int]):
-        """Longest run of cached blocks covering the prompt, capped so at
-        least one prompt token stays uncached (the engine needs a real
-        prefill step to produce the first-token logits).
+    def _match_cap(self, n_tokens: int) -> int:
+        """The blocks of an ``n_tokens`` context a request may adopt: at
+        least one token stays to be computed (the engine needs a real
+        prefill step to produce the first-token logits)."""
+        return max((int(n_tokens) - 1) // self.block_size, 0)
+
+    def _match_prefix_locked(self, digests: Sequence[bytes]):
+        """Longest run of cached blocks under ``digests``, the chain
+        digests of the blocks a context may adopt (``_match_cap``).
 
         With a host spill tier attached the digest walk continues past
         the HBM match into the tier: host-resident digests are pinned
@@ -428,10 +518,8 @@ class BlockManager:
         ``(matched_blocks, host_digests, token)`` where token is the
         observatory's match record (heat + miss causes + ghost-tier
         lookups over the same digests)."""
-        cap = (len(prompt_tokens) - 1) // self.block_size
-        if cap <= 0:
+        if not digests:
             return [], [], None
-        digests = chain_block_digests(prompt_tokens, self.block_size, cap)
         matched: List[int] = []
         for d in digests:
             b = self._cache.get(d)
@@ -456,10 +544,13 @@ class BlockManager:
         the slot id.  Raises ``NoCapacity`` when slots or blocks run
         out (the scheduler leaves the request queued and retries).
 
-        With ``prompt_tokens`` and prefix caching enabled, the longest
-        cached prefix is adopted by reference (refcount++) and only the
-        remainder is allocated fresh; ``slot_cached_tokens(slot)``
-        reports how many prompt tokens the slot got for free."""
+        With ``prompt_tokens`` (a token sequence, or the
+        :class:`TokenChain` a request carries, whose digests are hashed
+        once however often the request is refused) and prefix caching
+        enabled, the longest cached prefix is adopted by reference
+        (refcount++) and only the remainder is allocated fresh;
+        ``slot_cached_tokens(slot)`` reports how many prompt tokens the
+        slot got for free."""
         n = self.blocks_needed(total_tokens)
         if n > self.max_blocks_per_slot:
             raise ValueError(
@@ -470,9 +561,13 @@ class BlockManager:
             matched: List[int] = []
             host_digests: List[bytes] = []
             mtoken = None
+            digests: List[bytes] = []
             if self.prefix_cache_enabled and prompt_tokens is not None:
+                chain = token_chain(prompt_tokens)
+                digests = chain.digests(self.block_size,
+                                        self._match_cap(len(chain)))
                 matched, host_digests, mtoken = \
-                    self._match_prefix_locked(prompt_tokens)
+                    self._match_prefix_locked(digests)
             n_fresh = n - len(matched)
             # matched blocks parked in the LRU are consumed by the match
             # itself — they are NOT available to _take_block_locked, so
@@ -486,12 +581,16 @@ class BlockManager:
                     # the pinned host entries will not be consumed —
                     # release them before the retry path gives up
                     self.host_cache.unpin(host_digests)
-                raise NoCapacity(
+                refusal = NoCapacity(
                     f"no capacity: {len(self._free_slots)} free slots, "
                     f"{avail} free/evictable blocks, need {n_fresh}"
                     + ("" if self.window is None else
                        f"; window group {self.window.available()} free, "
                        f"need {self.window.reservation(n)}"))
+                refusal.version = self._version
+                if len(matched) < len(digests):
+                    refusal.first_missing = digests[len(matched)]
+                raise refusal
             slot = self._free_slots.pop()
             if self.window is not None:
                 self.window.admit_locked(slot, n)
@@ -594,21 +693,26 @@ class BlockManager:
                 return 0
             return sum(1 for b in blocks if self._refcounts.get(b, 1) <= 1)
 
-    def _commit_locked(self, slot: int, blocks: List[int],
-                       token_ids: Sequence[int], n_written: int) -> None:
-        """Register every fully written, not-yet-registered block under
-        its chain digest so later admissions can share it.  A digest that
-        already maps to another block keeps its canonical entry (the
-        duplicate stays private)."""
+    def _written_digests(self, blocks: List[int], token_ids,
+                         n_written: int) -> List[bytes]:
+        """The chain digests of the blocks of ``blocks`` that
+        ``n_written`` tokens of ``token_ids`` fill."""
         full = min(max(int(n_written), 0) // self.block_size, len(blocks))
         if full <= 0:
+            return []
+        return token_chain(token_ids).digests(self.block_size, full)
+
+    def _commit_locked(self, slot: int, blocks: List[int],
+                       digests: Sequence[bytes]) -> None:
+        """Register every not-yet-registered block of the fully written
+        ones (``digests``: their chain digests, block 0 first) so later
+        matches can share it.  A digest that already maps to another
+        block keeps its canonical entry (the duplicate stays private)."""
+        if not digests:
             return
-        digests = chain_block_digests(token_ids, self.block_size, full)
         actions: List[str] = []     # reg/live/parked, per digest (the
         # observatory's cross-capacity inclusion audit reads these)
-        for i in range(full):
-            b = blocks[i]
-            d = digests[i]
+        for b, d in zip(blocks, digests):
             if b in self._block_hash:
                 actions.append("live")
                 continue
@@ -635,7 +739,62 @@ class BlockManager:
         with self._lock:
             blocks = self._slot_blocks.get(slot)
             if blocks is not None:
-                self._commit_locked(slot, blocks, token_ids, n_written)
+                self._commit_locked(slot, blocks, self._written_digests(
+                    blocks, token_ids, n_written))
+
+    def adopt_committed(self, slot: int, token_ids,
+                        n_written: int) -> int:
+        """The match when prefill begins: called by the engine before it
+        computes the tokens of ``slot`` from ``n_written`` on.  Admission
+        matched the prompt against what the cache held THEN; requests
+        admitted together commit their pages afterwards, chunk by chunk.
+        So from the block at ``n_written`` (a block boundary, or nothing
+        is adopted) every consecutive chain digest of ``token_ids`` that
+        the cache holds NOW under another block is adopted as admission
+        would have adopted it: the block is taken by reference
+        (refcount++, out of the reusable list if parked there), the
+        slot's table is repointed, and the slot's own reserved, unwritten
+        block goes back to the free list.  Admission's cap holds (at
+        least one token is computed) and only the device's cache is
+        asked: the host tier stays admission's.  Returns the tokens
+        adopted; 0 with prefix caching off."""
+        bs = self.block_size
+        if not self.prefix_cache_enabled or n_written % bs:
+            return 0
+        with self._lock:
+            blocks = self._slot_blocks.get(slot)
+            if blocks is None:
+                return 0
+            chain = token_chain(token_ids)
+            first = n_written // bs
+            cap = min(self._match_cap(len(chain)), len(blocks))
+            if first >= cap:
+                return 0
+            digests = chain.digests(bs, cap)
+            adopted_rcs: List[int] = []
+            for i in range(first, cap):
+                b = self._cache.get(digests[i])
+                own = blocks[i]
+                if b is None or b == own:
+                    break
+                rc = self._refcounts.get(b, 0)
+                if rc == 0:
+                    self._lru.pop(b, None)      # leave the reusable list
+                self._refcounts[b] = rc + 1
+                adopted_rcs.append(rc + 1)
+                blocks[i] = b
+                self.tables[slot, i] = b
+                del self._refcounts[own]
+                self._free_blocks.append(own)
+            n = len(adopted_rcs)
+            if n == 0:
+                return 0
+            self._version += 1
+            self._slot_cached[slot] = self._slot_cached.get(slot, 0) + n * bs
+            self.prefix_cache_hits += n
+            self.prefix_cache_hit_tokens += n * bs
+            self.observatory.record_adopt(slot, digests, first, adopted_rcs)
+            return n * bs
 
     def ensure_writable(self, slot: int, block_idx: int
                         ) -> Optional[Tuple[int, Optional[int]]]:
@@ -696,9 +855,11 @@ class BlockManager:
             blocks = self._slot_blocks.pop(slot, None)
             if blocks is None:
                 return
+            self._version += 1
             if (self.prefix_cache_enabled and token_ids is not None
                     and n_written > 0):
-                self._commit_locked(slot, blocks, token_ids, n_written)
+                self._commit_locked(slot, blocks, self._written_digests(
+                    blocks, token_ids, n_written))
             for b in blocks:
                 rc = self._refcounts.get(b, 1) - 1
                 if rc > 0:

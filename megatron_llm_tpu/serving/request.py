@@ -19,6 +19,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Iterator, List, Optional, Sequence, Tuple
 
+from megatron_llm_tpu.serving.kv_blocks import TokenChain
+
 _REQ_IDS = itertools.count()
 
 # terminal finish reasons
@@ -108,7 +110,15 @@ class Request:
         self.trace_id = trace_id
         self.prompt_tokens: List[int] = [int(t) for t in prompt_tokens]
         self.sampling = sampling
-        self.out_tokens: List[int] = []
+        out_tokens: List[int] = []
+        self.out_tokens = out_tokens
+        # what the prefix cache is asked with, at admission (every retry
+        # of a refused head included), before each prefill chunk and at
+        # every registration: the chain digests of context_tokens(), each
+        # block hashed once, the chain extended as the answer grows (it
+        # holds the two lists themselves: they are appended to, never
+        # replaced)
+        self.chain = TokenChain(self.prompt_tokens, out_tokens)
         self.state = RequestState.QUEUED
         self.finish_reason: Optional[str] = None
         self.error: Optional[str] = None

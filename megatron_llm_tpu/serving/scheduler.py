@@ -58,6 +58,10 @@ class Scheduler:
         self.draft_k = int(draft_k)
         self.active: Dict[int, Request] = {}     # slot -> request
         self._last_was_prefill = False
+        # the queue's head as last refused (its id, the refusal):
+        # admit() runs before every launch, and while the refusal stands
+        # (BlockManager.refusal_stands) the answer would be the same
+        self._refused: Optional[Tuple[int, NoCapacity]] = None
         # counters surfaced through engine stats / ServerMetrics
         self.admitted = 0
         self.rejected_len = 0
@@ -120,13 +124,19 @@ class Scheduler:
                 self.deadline_evictions += 1
                 head._finish(FINISH_DEADLINE)
                 continue
+            if (self._refused is not None and self._refused[0] == head.id
+                    and self.blocks.refusal_stands(self._refused[1])):
+                break
             try:
                 # prefix-match over the full context (prompt + anything
                 # generated before a preemption) so a requeued victim
                 # re-adopts its own just-registered pages
                 slot = self.blocks.alloc(self.total_tokens(head),
-                                         prompt_tokens=head.context_tokens())
-            except (NoCapacity, ValueError):
+                                         prompt_tokens=head.chain)
+            except NoCapacity as refusal:
+                self._refused = (head.id, refusal)
+                break
+            except ValueError:
                 break
             self.queue.pop()
             head.slot = slot
