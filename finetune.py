@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,lfm2,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,lfm2,brumby,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -178,6 +178,16 @@ MODEL_DEFAULTS = {
                  conv_taps=3, conv_mixer_bias=0, layernorm_epsilon=1e-5,
                  layer_types=list(PUBLISHED_LAYER_TYPES),
                  hidden_dropout=0.0, attention_dropout=0.0),
+    # Brumby-14B-Base (model_type brumby): a Qwen3-14B-shaped trunk whose
+    # every layer's mixer is a power retention of degree 2 (no key and no
+    # value kept: a recurrent state a key-value head), per-head QK-norm,
+    # no bias, an untied head
+    "brumby": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                   use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                   qk_norm_per_head=True, kv_channels=128, rope_theta=1e6,
+                   layer_types=["retention"],
+                   layernorm_epsilon=1e-6,
+                   hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,

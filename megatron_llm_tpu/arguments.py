@@ -191,7 +191,8 @@ def _add_network_size_args(parser):
                         "hybrid's mamba and attention (with moe: a "
                         "layer is one sublayer, an expert layer alone), "
                         "or conv and attention, every layer of the depth "
-                        "spelled out where the pattern does not repeat")
+                        "spelled out where the pattern does not repeat; or "
+                        "retention alone (power-retention layers)")
     g.add_argument("--hybrid_override_pattern", type=str, default=None,
                    help="a letter a layer in place of --layer_types: M a "
                         "Mamba-2 mixer, * an attention mixer, E an "

@@ -99,11 +99,14 @@ class ParallelConfig:
 # norm, a mixer or an expert layer (``one_sublayer``).  'conv' is a gated
 # short convolution (models/short_conv.py; lfm2's ``conv`` beside its
 # ``full_attention``, which is 'attention' here): the other mixer that
-# carries a state from token to token
-LAYER_TYPES = ("sliding", "full", "mamba", "attention", "moe", "conv")
+# carries a state from token to token.  'retention' is a power-retention
+# mixer (models/retention.py; brumby's every layer): attention's
+# projections over a recurrent state a key-value head and no key kept
+LAYER_TYPES = ("sliding", "full", "mamba", "attention", "moe", "conv",
+               "retention")
 # the layer types whose mixer carries a STATE a request in a slot and no
 # pages (``TransformerConfig.state_layer``: what ops/paged_kv.py asks)
-STATE_TYPES = ("mamba", "conv")
+STATE_TYPES = ("mamba", "conv", "retention")
 # the letters of a published ``hybrid_override_pattern``, a layer each
 PATTERN_LETTERS = {"M": "mamba", "*": "attention", "E": "moe"}
 
@@ -161,6 +164,7 @@ LATENT = "latent attention (kv_lora_rank)"
 TYPED = "a layer type per layer (layer_types)"
 STATE_SPACE = "state-space layers ('mamba' among layer_types)"
 SHORT_CONV = "gated short-convolution layers ('conv' among layer_types)"
+RETENTION = "power-retention layers ('retention' among layer_types)"
 ONE_SUBLAYER = "layers of one sublayer ('moe' among layer_types)"
 FIRST_DENSE = "leading dense layers (moe_first_dense_layers)"
 SHARE = "a share of the router's experts (moe_router_experts)"
@@ -180,12 +184,14 @@ OUTPUT_NORMS = "norms on both sublayers' outputs (sublayer_output_norm)"
 ROPE_TYPES = "layer types that do not rotate (rope_layer_types)"
 OTHER_TYPES = "layer types other than 'mamba', 'attention' and 'moe'"
 CONV_OTHER_TYPES = "layer types other than 'conv' and 'attention'"
+RETENTION_OTHER_TYPES = "layer types other than 'retention'"
 HAS = {
     SPARSE: lambda c: c.dsa_index_heads > 0,
     LATENT: lambda c: c.kv_lora_rank is not None,
     TYPED: lambda c: c.layer_types is not None,
     STATE_SPACE: lambda c: c.state_space,
     SHORT_CONV: lambda c: c.short_conv,
+    RETENTION: lambda c: c.retention,
     ONE_SUBLAYER: lambda c: c.one_sublayer,
     FIRST_DENSE: lambda c: c.moe_first_dense_layers > 0,
     SHARE: lambda c: c.holds_a_share,
@@ -210,6 +216,8 @@ HAS = {
                                 - {"mamba", "attention", "moe"}),
     CONV_OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
                                      - {"conv", "attention"}),
+    RETENTION_OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
+                                          - {"retention"}),
 }
 
 # THE TABLE: what a model has, and everything it does not run with.  A
@@ -221,6 +229,10 @@ RUNS_WITH = (
               TENSOR_PARALLEL)),
     (ONE_SUBLAYER, (OTHER_TYPES, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
                     PREFIX_CACHE)),
+    (RETENTION, (RETENTION_OTHER_TYPES, BIASES, QKV_BIAS, PARALLEL_ATTN,
+                 POST_LN, LATENT, SPARSE, SLIDING, GATE, OUTPUT_NORMS,
+                 EXPERTS, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL,
+                 HOST_TIER, PREEMPTION, PREFIX_CACHE)),
     (SHORT_CONV, (CONV_OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
                   GATE, OUTPUT_NORMS, TRAINING, MODEL_PARALLEL, VERIFY_STEP,
                   INT8_POOL, HOST_TIER, PREEMPTION, PREFIX_CACHE)),
@@ -261,6 +273,32 @@ TAILS = {
     (STATE_SPACE, PREEMPTION):
         " (no snapshot of a request's state is kept): set preemption off "
         "(--serve_preemption=0)",
+    (RETENTION, RETENTION_OTHER_TYPES):
+        " (a stack that mixes a state of this size with pages, or with "
+        "'mamba' or 'conv' layers' states, is held to nothing: every "
+        "layer of the depth is 'retention')",
+    (RETENTION, SLIDING):
+        " (a recurrent state forgets by its gates, not by a window)",
+    (RETENTION, EXPERTS):
+        " (the published stack is dense; a sparse MLP beside a state of "
+        "this size is held to nothing)",
+    (RETENTION, TRAINING):
+        " (no backward through the chunk form is held to anything, and "
+        "packed documents would need the state reset at each boundary)",
+    (RETENTION, MODEL_PARALLEL):
+        " (the state would be split by key-value head, and a stage would "
+        "hold its layers' states alone)",
+    (RETENTION, VERIFY_STEP):
+        " (a rejected draft's updates of the state would have to be "
+        "taken back)",
+    (RETENTION, INT8_POOL): " (there is no key and no value to quantise)",
+    (RETENTION, HOST_TIER): " (there is no page to spill)",
+    (RETENTION, PREEMPTION):
+        " (no snapshot of a request's state is kept): set preemption off "
+        "(--serve_preemption=0)",
+    (RETENTION, PREFIX_CACHE):
+        " adopts nothing (a retention layer's state at a prefix's end is "
+        "not kept)",
     (SHORT_CONV, CONV_OTHER_TYPES):
         " (a 'conv' layer type goes with 'attention' layers only: a stack "
         "that holds 'mamba' and 'conv' together, two states of two shapes "
@@ -679,6 +717,9 @@ class TransformerConfig:
             if "conv" in types and self.conv_taps < 2:
                 raise ValueError("gated short-convolution layers need "
                                  "conv_taps >= 2 (a column to carry)")
+            if "retention" in types and self.head_dim % 8:
+                raise ValueError("power-retention layers need a head_dim "
+                                 "of whole sublanes (a multiple of 8)")
         if self.moe_router_experts is not None or self.moe_experts_first:
             routed = self.moe_router_experts or self.num_experts
             if self.num_experts <= 1 or not (
@@ -804,17 +845,33 @@ class TransformerConfig:
         """Whether some layer's mixer is a gated short convolution."""
         return self.layer_types is not None and "conv" in self.layer_types
 
+    @property
+    def retention(self) -> bool:
+        """Whether some layer's mixer is a power retention."""
+        return (self.layer_types is not None
+                and "retention" in self.layer_types)
+
+    @property
+    def retention_phi_rows(self) -> int:
+        """Rows of ``phi``, the map with ``phi(x) . phi(y) = (x . y)^2``,
+        in the layout ``models/retention.py`` chose: ``head_dim / 2 + 1``
+        rotations of ``head_dim`` products each (8,320 at 128, where the
+        least a symmetric square takes is 8,256)."""
+        return (self.head_dim // 2 + 1) * self.head_dim
+
     @staticmethod
     def state_layer(layer_type: Optional[str]) -> bool:
         """Whether a layer of ``layer_type`` carries a STATE a request
-        (arrays a slot, no pages): a 'mamba' or a 'conv' mixer."""
+        (arrays a slot, no pages): a 'mamba', a 'conv' or a 'retention'
+        mixer."""
         return layer_type in STATE_TYPES
 
     @property
     def mixers_by_kind(self) -> bool:
         """Whether the stack's mixers are of several kinds, with other
         leaves each, and so stacked apart by kind (``mixer_counts``)."""
-        return self.state_space or self.short_conv or self.one_sublayer
+        return (self.state_space or self.short_conv or self.retention
+                or self.one_sublayer)
 
     @property
     def one_sublayer(self) -> bool:
@@ -834,7 +891,7 @@ class TransformerConfig:
     @property
     def mixer_counts(self) -> dict:
         """How many layers there are of each kind ('mamba', 'conv',
-        'attention', and the expert layers 'moe' of a stack of one
+        'retention', 'attention', and the expert layers 'moe' of a stack of one
         sublayer a layer) in a stack whose kinds' parameters are stacked
         apart; empty for a stack whose layers all hold the same leaves.
         EVERY layer of the depth counts, a sparse model's leading dense
@@ -845,7 +902,7 @@ class TransformerConfig:
             return {}
         reps = self.num_layers // len(self.layer_types)
         return {k: reps * self.layer_types.count(k)
-                for k in ("mamba", "conv", "attention", "moe")
+                for k in ("mamba", "conv", "retention", "attention", "moe")
                 if k in self.layer_types}
 
     def layer_type(self, layer: int) -> Optional[str]:

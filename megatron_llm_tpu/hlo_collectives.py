@@ -125,6 +125,7 @@ SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
           "mla_absorb", "mla_expand", "ssm_in_proj", "ssm_conv", "ssm_scan",
           "ssm_step", "ssm_gate_norm", "ssm_out_proj", "mamba",
           "conv_in_proj", "short_conv", "conv_out_proj",
+          "retention_gate", "retention_chunk", "retention_step",
           "attn_gate", "post_attn_norm", "post_mlp_norm",
           "attention", "mlp",
           "embedding", "lm_head", "transformer_layer")

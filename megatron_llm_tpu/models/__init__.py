@@ -15,6 +15,7 @@ from megatron_llm_tpu.models.mellum import MellumModel, mellum_config
 from megatron_llm_tpu.models.kanana import KananaModel, kanana_config
 from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
 from megatron_llm_tpu.models.lfm2 import Lfm2Model, lfm2_config
+from megatron_llm_tpu.models.brumby import BrumbyModel, brumby_config
 from megatron_llm_tpu.models.qwen2 import Qwen2Model, qwen2_config
 from megatron_llm_tpu.models.gemma import GemmaModel, gemma_config
 from megatron_llm_tpu.models.gpt_neox import GPTNeoXModel, gpt_neox_config
@@ -58,6 +59,7 @@ MODEL_REGISTRY = {
     "granite": _granite,
     "nemotron_h": _nemotron_h,
     "lfm2": Lfm2Model,
+    "brumby": BrumbyModel,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,
     "gpt_neox": GPTNeoXModel,

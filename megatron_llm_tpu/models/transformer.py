@@ -302,6 +302,9 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
         elif kind == "conv":
             from megatron_llm_tpu.models.short_conv import (
                 init_short_conv_params as init)
+        elif kind == "retention":
+            from megatron_llm_tpu.models.retention import (
+                init_retention_params as init)
         elif kind == "moe":
             from megatron_llm_tpu.models.moe import (
                 init_moe_mlp_params as init)
@@ -540,6 +543,68 @@ def latent_attention(
     return out
 
 
+def qkv_heads(x: jax.Array, params, cfg: TransformerConfig, *,
+              freqs: Optional[tuple], position_ids: Optional[jax.Array],
+              sequence_parallel: bool = False,
+              layer_type: Optional[str] = None):
+    """A layer's queries, keys and values as its mixer takes them: the
+    packed projection split by head (``q`` [b, s, heads, d], ``k`` and
+    ``v`` [b, s, groups, d], the output gate or None), the QK-norm the
+    config has, the rotary variant of ``layer_type``.  What ``attention``
+    and a power-retention layer (``models/retention.py``) share.  Also
+    the positions the rotation took as given (None where it read a table
+    or nothing rotates): the sparse-attention indexer rotates by them."""
+    _, yarn = cfg.attention_of(layer_type)
+    mixed = column_parallel_linear(
+        x, params["query_key_value"],
+        out_logical="heads",
+        sequence_parallel=sequence_parallel,
+        compute_dtype=cfg.compute_jnp_dtype,
+    )
+    q, k, v, gate = _split_qkv(mixed, cfg)
+
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _projection_rms_norm(q, params["q_norm"]["scale"],
+                                     cfg.layernorm_epsilon)
+            k = _projection_rms_norm(k, params["k_norm"]["scale"],
+                                     cfg.layernorm_epsilon)
+
+    elif cfg.qk_norm_per_head:
+        with jax.named_scope("qk_norm"):
+            # each head by itself: the norm over the last axis
+            q = rms_norm(q, params["q_norm"]["scale"],
+                         eps=cfg.layernorm_epsilon)
+            k = rms_norm(k, params["k_norm"]["scale"],
+                         eps=cfg.layernorm_epsilon)
+
+    positions = None
+    if (cfg.position_embedding_type == PositionEmbeddingType.none
+            or not cfg.rotates(layer_type)):
+        # nothing rotates and nothing is added, on any path: the model's
+        # every layer, or the layers of this type (cfg.rope_layer_types)
+        pass
+    elif (cfg.rope_sections is not None or cfg.dsa_index_heads > 0
+            or cfg.layer_types is not None):
+        # positions are taken as given, with no table: [b, s], or
+        # [streams, b, s] for the sectioned embedding (a text token's
+        # streams coincide and this is the plain embedding); a layer
+        # type's own variant (YaRN or none) with no table a type
+        positions = position_ids
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(q.shape[1])[None],
+                                         q.shape[:2])
+        q = apply_rotary_at(q, positions, cfg.rope_theta, cfg.rope_sections,
+                            yarn)
+        k = apply_rotary_at(k, positions, cfg.rope_theta, cfg.rope_sections,
+                            yarn)
+    elif cfg.position_embedding_type == PositionEmbeddingType.rotary and freqs is not None:
+        cos, sin = freqs
+        q = apply_rotary_emb(q, cos, sin, position_ids)
+        k = apply_rotary_emb(k, cos, sin, position_ids)
+    return q, k, v, gate, positions
+
+
 def attention(
     x: jax.Array,
     params,
@@ -567,63 +632,20 @@ def attention(
             x, params, cfg, attention_mask=attention_mask,
             position_ids=position_ids, dropout_key=dropout_key, train=train,
             sequence_parallel=sequence_parallel, kv_cache=kv_cache)
-    window, yarn = cfg.attention_of(layer_type)
+    window, _ = cfg.attention_of(layer_type)
     # what the scores are multiplied by: a family's own multiplier, else
     # 1 / sqrt(head_dim)
     scale = (1.0 / math.sqrt(cfg.head_dim)
              if cfg.attention_multiplier is None
              else float(cfg.attention_multiplier))
-    mixed = column_parallel_linear(
-        x, params["query_key_value"],
-        out_logical="heads",
-        sequence_parallel=sequence_parallel,
-        compute_dtype=cfg.compute_jnp_dtype,
-    )
-    q, k, v, gate = _split_qkv(mixed, cfg)
-
-    if cfg.qk_norm:
-        with jax.named_scope("qk_norm"):
-            q = _projection_rms_norm(q, params["q_norm"]["scale"],
-                                     cfg.layernorm_epsilon)
-            k = _projection_rms_norm(k, params["k_norm"]["scale"],
-                                     cfg.layernorm_epsilon)
-
-    elif cfg.qk_norm_per_head:
-        with jax.named_scope("qk_norm"):
-            # each head by itself: the norm over the last axis
-            q = rms_norm(q, params["q_norm"]["scale"],
-                         eps=cfg.layernorm_epsilon)
-            k = rms_norm(k, params["k_norm"]["scale"],
-                         eps=cfg.layernorm_epsilon)
-
+    q, k, v, gate, positions = qkv_heads(
+        x, params, cfg, freqs=freqs, position_ids=position_ids,
+        sequence_parallel=sequence_parallel, layer_type=layer_type)
     index = None
-    if (cfg.position_embedding_type == PositionEmbeddingType.none
-            or not cfg.rotates(layer_type)):
-        # nothing rotates and nothing is added, on any path: the model's
-        # every layer, or the layers of this type (cfg.rope_layer_types)
-        pass
-    elif (cfg.rope_sections is not None or cfg.dsa_index_heads > 0
-            or cfg.layer_types is not None):
-        # positions are taken as given, with no table: [b, s], or
-        # [streams, b, s] for the sectioned embedding (a text token's
-        # streams coincide and this is the plain embedding); a layer
-        # type's own variant (YaRN or none) with no table a type
-        positions = position_ids
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(q.shape[1])[None],
-                                         q.shape[:2])
-        q = apply_rotary_at(q, positions, cfg.rope_theta, cfg.rope_sections,
-                            yarn)
-        k = apply_rotary_at(k, positions, cfg.rope_theta, cfg.rope_sections,
-                            yarn)
-        if cfg.dsa_index_heads > 0:
-            with jax.named_scope("dsa_indexer"):
-                index = indexer_projections(x, params["indexer"], cfg,
-                                            positions)
-    elif cfg.position_embedding_type == PositionEmbeddingType.rotary and freqs is not None:
-        cos, sin = freqs
-        q = apply_rotary_emb(q, cos, sin, position_ids)
-        k = apply_rotary_emb(k, cos, sin, position_ids)
+    if cfg.dsa_index_heads > 0 and positions is not None:
+        with jax.named_scope("dsa_indexer"):
+            index = indexer_projections(x, params["indexer"], cfg,
+                                        positions)
 
     new_cache = None
     paged_ctx = None
@@ -993,7 +1015,9 @@ def transformer_layer(
     model with ``cfg.layer_types`` (``attention`` says what it decides);
     a ``'mamba'`` layer's mixer is ``models/mamba.py::mamba_mixer`` over
     ``params['mamba']`` in place of attention, a ``'conv'`` layer's
-    ``models/short_conv.py::short_conv_mixer`` over ``params['conv']``.  Both residual branches
+    ``models/short_conv.py::short_conv_mixer`` over ``params['conv']``, a
+    ``'retention'`` layer's ``models/retention.py::retention_mixer`` over
+    ``params['retention']``.  Both residual branches
     are multiplied by ``cfg.residual_multiplier``.  In a stack of ONE
     sublayer a layer (``cfg.one_sublayer``) the layer is
     ``x + f(input_norm(x))``, ``f`` its mixer or, for the type
@@ -1055,6 +1079,22 @@ def transformer_layer(
             new_cache = None
             if kv_cache is not None:
                 attn_out, new_cache = attn_out
+    elif layer_type == "retention":
+        from megatron_llm_tpu.models.retention import retention_mixer
+
+        if attention_mask is not None:
+            raise NotImplementedError(
+                "power-retention layers ('retention') are not implemented "
+                "under an explicit attention mask (packed documents would "
+                "need the state reset at each boundary)")
+        # the four projections are attention's, and so is their scope
+        with jax.named_scope("attention"):
+            attn_out = retention_mixer(
+                ln_out, params["retention"], cfg, freqs=freqs,
+                position_ids=position_ids, kv_cache=kv_cache)
+        new_cache = None
+        if kv_cache is not None:
+            attn_out, new_cache = attn_out
     elif layer_type == "conv":
         from megatron_llm_tpu.models.short_conv import short_conv_mixer
 

@@ -164,7 +164,7 @@ _GROUP_OF = {"sliding": WINDOW, "moe": NONE}
 # the arrays a slot of the STATE group may hold, in the order
 # ``read_state`` gives and ``write_state`` takes them; which of them a
 # layer's pool has is its kind's (``init_pools``)
-_STATE_ARRAYS = ("conv_state", "ssm_state")
+_STATE_ARRAYS = ("conv_state", "ssm_state", "ret_state", "ret_sum")
 
 
 def layer_groups(cfg) -> Optional[tuple]:
@@ -206,7 +206,8 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     group of a model with a layer type per layer, ``num_blocks`` for
     every other layer; a layer that carries a state holds its kind's
     arrays a slot (a state-space layer two, a gated short convolution
-    one), ``num_slots`` of them and the garbage row."""
+    one, a power retention its state and its normaliser), ``num_slots``
+    of them and the garbage row."""
     dtype = dtype or cfg.compute_jnp_dtype
     indexed = cfg.dsa_index_heads > 0
     groups = layer_groups(cfg)
@@ -225,6 +226,15 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                          "layer_types needs num_slots")
 
     def state(kind):
+        if kind == "retention":
+            # a key-value head's state, a [value, a] tile a rotation of
+            # phi, and its normaliser (models/retention.py has the layout)
+            g, d = cfg.num_query_groups, cfg.head_dim
+            O = cfg.retention_phi_rows // d
+            return {"ret_state": jnp.zeros((num_slots + 1, g, O, d, d),
+                                           SSM_STATE_DTYPE),
+                    "ret_sum": jnp.zeros((num_slots + 1, g, O, d),
+                                         SSM_STATE_DTYPE)}
         if kind == "conv":
             return {"conv_state": jnp.zeros(
                 (num_slots + 1, cfg.conv_taps - 1, cfg.hidden_size), dtype)}
@@ -263,7 +273,7 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
 def is_state(pool: dict) -> bool:
     """Whether a layer's pool is a state-carrying layer's (arrays a slot,
     no pages)."""
-    return "conv_state" in pool
+    return any(name in pool for name in _STATE_ARRAYS)
 
 
 def paged_pools(pools) -> List[dict]:
@@ -415,7 +425,9 @@ class PagedKVCache:
         (``_rows``), in ``_STATE_ARRAYS``' order: a state-space layer's
         (``conv_state`` [b, d_conv - 1, conv_dim], ``ssm_state`` [b,
         heads, d_head, d_state]), a gated short convolution's
-        (``conv_state`` [b, taps - 1, hidden],) alone."""
+        (``conv_state`` [b, taps - 1, hidden],) alone, a power
+        retention's (``ret_state`` [b, g, O, d, d], ``ret_sum`` [b, g,
+        O, d])."""
         fresh = self.context_lens == 0
         return tuple(self._rows(self.pool[name], fresh)
                      for name in _STATE_ARRAYS if name in self.pool)
@@ -441,12 +453,39 @@ class PagedKVCache:
         return y, dataclasses.replace(
             self, pool={**self.pool, "ssm_state": pool})
 
+    def step_retention(self, q, k, v, a):
+        """One token of a power-retention layer's recurrence on every
+        live row's ``ret_state`` and ``ret_sum``
+        (``ops/pallas/retention_step.py`` has the operands): numerators
+        [b, g, r, d] and normalisers [b, g, r] in float32 and the cache
+        with both arrays WRITTEN (``write_state()`` then only advances
+        the lengths).  On the ``'pallas'`` path of a decode step (row s
+        is slot s) the kernel updates ``ret_state`` in place and moves
+        live rows only; otherwise every row's state is read, advanced
+        and put back.  The normaliser, a 128th of the bytes, is XLA's on
+        both."""
+        from megatron_llm_tpu.ops.pallas import retention_step as _ret
+
+        S, z = self.pool["ret_state"], self.pool["ret_sum"]
+        live, fresh = self.valid_lens > 0, self.context_lens == 0
+        a = a.astype(jnp.float32)
+        if self.kernel == "pallas" and self.slots is None:
+            num, S = _ret.retention_state_step(S, q, k, v, a, live, fresh)
+            den, z_new = _ret.dense_sum_step(self._rows(z, fresh), q, k, a)
+        else:
+            num, den, S_new, z_new = _ret.dense_retention_step(
+                self._rows(S, fresh), self._rows(z, fresh), q, k, v, a)
+            S = self._put(S, S_new, live)
+        return num, den, dataclasses.replace(
+            self, pool={**self.pool, "ret_state": S,
+                        "ret_sum": self._put(z, z_new, live)})
+
     def write_state(self, *arrays):
         """The cache as a state-carrying layer's call leaves it: each
         live row's ``arrays`` (in ``read_state``'s order) written at its
         slot (``_put``; one left out or None stays as it is:
-        ``step_state`` has written ``ssm_state``), ``context_lens``
-        advanced."""
+        ``step_state`` has written ``ssm_state``, ``step_retention``
+        both of its arrays), ``context_lens`` advanced."""
         live = self.valid_lens > 0
         pool = dict(self.pool)
         names = [name for name in _STATE_ARRAYS if name in self.pool]
@@ -687,7 +726,8 @@ def step_caches(pools, block_tables, context_lens, valid_lens,
     if groups is None:
         return [PagedKVCache(p, block_tables, context_lens, valid_lens,
                              kernel=kernel) for p in pools]
-    return [PagedKVCache(p, block_tables[FULL if g in (STATE, NONE) else g],
+    return [PagedKVCache(p, block_tables.get(FULL) if g in (STATE, NONE)
+                         else block_tables[g],
                          context_lens, valid_lens, kernel=kernel, group=g,
                          slots=block_tables.get(STATE) if g == STATE
                          else None)
@@ -729,6 +769,14 @@ class CachePlan:
     # launch's counters of state are counted by
     state_kinds: tuple = ()
 
+    @property
+    def paged(self) -> bool:
+        """Whether some layer keeps pages (a model of one type always; a
+        typed stack whose every layer carries a state, or nothing, has
+        none): what admission, the page programs and the tables ask."""
+        return self.groups is None or any(
+            g in (FULL, WINDOW) for g in self.groups)
+
     def init_pools(self, num_blocks: int, quantized: bool = False):
         return init_pools(self.cfg, num_blocks, self.block_size,
                           quantized=quantized, num_slots=self.num_slots,
@@ -738,10 +786,10 @@ class CachePlan:
         """What a program takes as ``block_tables``: rows ``rows`` of the
         slots' table of the block manager ``blocks``, or of each group's
         where there are groups."""
-        full = blocks.tables[rows].copy()
         if self.groups is None:
-            return full
-        tables = {FULL: full}
+            return blocks.tables[rows].copy()
+        # a table a group that has pages: none is built that nobody reads
+        tables = {FULL: blocks.tables[rows].copy()} if self.paged else {}
         if blocks.window is not None:
             tables[WINDOW] = blocks.window.tables[rows].copy()
         if STATE in self.groups and rows != slice(None):
@@ -754,13 +802,15 @@ class CachePlan:
                 admitted: int) -> None:
         """The cache's counters of one launch on its record ``d``
         (``serving/loop_profiler.py``: ``DSA_FIELDS``, ``MLA_FIELDS``,
-        ``SSM_FIELDS``), from the host arrays its program is handed:
+        ``SSM_FIELDS``, ``CONV_FIELDS``, ``RETENTION_FIELDS``), from the
+        host arrays its program is handed:
         each row's ``context_lens`` and ``valid_lens`` (0: an idle row)
         of ``n`` queries a row; ``admitted``: the requests that hold a
         slot.  Returns at once for a model with no such mechanism."""
         cfg, layers = self.cfg, self.cfg.num_layers
-        state_layers, conv_layers = (self.state_kinds.count(k)
-                                     for k in ("mamba", "conv"))
+        state_layers, conv_layers, ret_layers = (
+            self.state_kinds.count(k)
+            for k in ("mamba", "conv", "retention"))
         if not (cfg.latent_attention or self.dsa_block_keys
                 or self.state_kinds):
             return
@@ -772,6 +822,15 @@ class CachePlan:
         if conv_layers:
             d.conv_rows_live = conv_layers * len(val)
             d.conv_tokens = conv_layers * int(val.sum())
+        if ret_layers:
+            d.retention_rows_live = ret_layers * len(val)
+            if d.kind != "prefill":
+                # as a state-space layer's: the kernel moves the live
+                # rows' state, the XLA step every slot's
+                d.retention_rows_moved = (
+                    d.retention_rows_live if self.paged_kernel == "pallas"
+                    else ret_layers * (self.num_slots + 1))
+            d.retention_tokens = ret_layers * int(val.sum())
         if state_layers:
             d.ssm_rows_live = state_layers * len(val)
             if d.kind != "prefill":

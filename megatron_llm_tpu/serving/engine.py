@@ -356,9 +356,6 @@ class InferenceEngine:
         cfg.max_model_len = min(cfg.max_model_len,
                                 int(mcfg.max_position_embeddings))
         self._max_blocks_per_slot = -(-cfg.max_model_len // cfg.block_size)
-        self._num_blocks = derive_num_blocks(
-            cfg.num_slots, cfg.block_size, cfg.max_model_len,
-            cfg.num_blocks or None)
         self.queue = RequestQueue(cfg.max_queue_depth)
 
         # which path reads the pool is resolved ONCE, per program: it is
@@ -411,6 +408,18 @@ class InferenceEngine:
         self._cache = paged_kv.plan(
             mcfg, cfg.block_size, cfg.num_slots, self._max_blocks_per_slot,
             cfg.prefill_chunk, self.prefill_kernel, self.paged_kernel)
+        # a model with no paged layer has no block to count: admission is
+        # by slots (kv_blocks.BlockManager), and --serve_num_blocks,
+        # which sizes a pool of pages, sizes nothing: the pool is the
+        # garbage block alone
+        if cfg.num_blocks and not self._cache.paged:
+            raise ValueError(
+                "num_blocks (--serve_num_blocks) sizes a pool of pages, and "
+                "no layer of this model keeps pages: it is admitted by "
+                "slots (--serve_num_slots)")
+        self._num_blocks = 1 if not self._cache.paged else derive_num_blocks(
+            cfg.num_slots, cfg.block_size, cfg.max_model_len,
+            cfg.num_blocks or None)
 
         # cache observatory (serving/cache_observatory.py): per-prefix
         # heat, eviction forensics, ghost capacity tiers.  Engine-
@@ -437,13 +446,19 @@ class InferenceEngine:
 
         # the programs of a decode step own the pool (argument 1): the
         # step writes its rows in place, where a program that is only
-        # lent the pool copies every array of it first.  The chunk and
-        # the page programs are lent theirs
+        # lent the pool copies every array of it first.  The page
+        # programs are lent theirs, and so is the chunk of a model with
+        # pages, which somebody else may be reading (adoption,
+        # copy-on-write, the host tier).  A pool with NO page has no such
+        # reader: the chunk is given it for good and writes one slot's
+        # state where it lies
         self._decode_step = _program(self._decode_impl, "engine_decode",
                                      owns=(1,))
         self._verify_step = _program(self._verify_impl, "engine_verify",
                                      owns=(1,))
-        self._prefill_step = _program(self._prefill_impl, "engine_prefill")
+        self._prefill_step = _program(
+            self._prefill_impl, "engine_prefill",
+            owns=() if self._cache.paged else (1,))
         self._sample_first = _program(self._sample_first_impl,
                                       "engine_sample_first")
         # the three page programs: copy-on-write, the host tier's
@@ -458,15 +473,17 @@ class InferenceEngine:
         self._host_load = _program(paged_kv.load_page, "engine_host_load")
         # for program_tables(): the jitted programs themselves by the
         # names their XLA modules carry (the attributes may be wrapped),
-        # and the tables once somebody has asked
+        # and the tables once somebody has asked; the page programs only
+        # where there are pages
         self._jitted = {
             "engine_decode": self._decode_step,
             "engine_verify": self._verify_step,
             "engine_prefill": self._prefill_step,
             "engine_sample_first": self._sample_first,
-            "engine_cow_copy": self._cow_copy,
-            "engine_fetch_block": self._fetch_block,
-            "engine_host_load": self._host_load}
+            **({"engine_cow_copy": self._cow_copy,
+                "engine_fetch_block": self._fetch_block,
+                "engine_host_load": self._host_load}
+               if self._cache.paged else {})}
         self._program_tables: Optional[Dict[str, Any]] = None
         self.kv_pool_bytes = sum(
             a.nbytes for a in jax.tree_util.tree_leaves(self._st.pages))
@@ -544,7 +561,8 @@ class InferenceEngine:
             self._max_blocks_per_slot, prefix_cache=cfg.prefix_cache,
             observatory=self.cache_observatory, host_cache=self.host_cache,
             window=self._cache.window and WindowGroup(*self._cache.window),
-            state_bytes_per_slot=self._cache.state_bytes_per_slot)
+            state_bytes_per_slot=self._cache.state_bytes_per_slot,
+            paged=self._cache.paged)
         sched = Scheduler(self.queue, blocks, cfg.max_model_len,
                           draft_k=self.draft_k)
         if carry is not None:
@@ -1156,7 +1174,10 @@ class InferenceEngine:
     def _writable(self, st: _EngineState, slot: int, block_idx: int) -> None:
         """Copy-on-write barrier before a device write into a slot's
         logical page: if the block manager swaps in a private copy,
-        mirror the page contents on device."""
+        mirror the page contents on device.  A model with no page has
+        none to make writable."""
+        if not self._cache.paged:
+            return
         res = st.blocks.ensure_writable(slot, block_idx)
         if res is not None:
             new_b, src_b = res
@@ -1262,8 +1283,11 @@ class InferenceEngine:
         finite = True
         handed = (toks, np.int32(start), np.int32(valid), table)
         d.host_uploads += _host_arrays(handed)
-        last_logits, st.pages, routing = self._prefill_step(
-            self.params, st.pages, *handed)
+        # under the lock a decode step's launch holds: where the chunk
+        # owns its pool (no paged layer) it consumes it as that step does
+        with st.pool_lock:
+            last_logits, st.pages, routing = self._prefill_step(
+                self.params, st.pages, *handed)
         done = start + valid >= len(ptoks)
         if done:
             # the slot has not decoded since the host gave it its key, so
@@ -1705,8 +1729,9 @@ class InferenceEngine:
                 raise TimeoutError("engine warmup did not converge")
         # compile the copy-on-write page copy (garbage -> garbage is a
         # no-op) so a later COW event can't trip the recompile detector
-        with tracing.startup_span("warmup.engine_cow_copy"):
-            st.pages = self._copy_page(st.pages, 0, 0)
+        if self._cache.paged:
+            with tracing.startup_span("warmup.engine_cow_copy"):
+                st.pages = self._copy_page(st.pages, 0, 0)
         if self.host_cache is not None:
             # compile the host-tier pair the same way: gather the
             # garbage page to host, scatter it straight back — both
@@ -1761,8 +1786,10 @@ class InferenceEngine:
                     sharding=pages.sharding),
                 st.keys[0], st.top_ks[0], st.top_ps[0], st.temps[0],
                 st.ban_a[0], st.ban_b[0], zero),
-            "engine_cow_copy": (paged_kv.paged_pools(pool), zero, zero),
         }
+        if self._cache.paged:
+            found["engine_cow_copy"] = (paged_kv.paged_pools(pool), zero,
+                                        zero)
         if self.speculative:
             found["engine_verify"] = (
                 self.params, pool,

@@ -331,8 +331,16 @@ class BlockManager:
                  max_blocks_per_slot: int, prefix_cache: bool = False,
                  observatory: Optional[CacheObservatory] = None,
                  host_cache=None, window: Optional[WindowGroup] = None,
-                 state_bytes_per_slot: int = 0):
-        assert num_blocks >= 2, "need at least one block beyond the garbage"
+                 state_bytes_per_slot: int = 0, paged: bool = True):
+        # a model with NO paged layer (``paged`` False: every layer
+        # carries a state a slot, or nothing) has no block to hand out:
+        # a request needs none, its slot's table has no entry, and
+        # admission is by free slots alone
+        self.paged = bool(paged)
+        if not self.paged:
+            num_blocks, max_blocks_per_slot = 1, 0
+        assert num_blocks >= 2 or not self.paged, \
+            "need at least one block beyond the garbage"
         assert block_size >= 1 and num_slots >= 1
         self.window = window
         # a model with state-space layers: what a slot's recurrent state
@@ -412,6 +420,8 @@ class BlockManager:
     # -- capacity -------------------------------------------------------
 
     def blocks_needed(self, total_tokens: int) -> int:
+        if not self.paged:
+            return 0
         return -(-max(int(total_tokens), 1) // self.block_size)
 
     def can_admit(self, total_tokens: int) -> bool:

@@ -1,0 +1,377 @@
+"""Brumby-14B-Base (``model_type`` ``brumby``: every layer a power
+retention of degree 2, no key and no value kept, a recurrent state a
+key-value head shared by its query heads, the first pool with no page),
+against the benchmark's plain reference.
+
+Seeded random weights, CPU, float32 on both sides, tiny widths with
+grouped queries kept: 4 query heads over 2 key-value heads of 16, so
+``phi`` has 9 rotations of 16 (144 rows in the program's layout, where
+the least a symmetric square of 16 takes is 136).  The reference is the
+file the benchmark's probe loads (``benchmarks/reference/brumby.py``:
+the QUADRATIC form, no ``phi``, no state, no chunk), loaded here by
+path; the engine is held to it by the probe's own comparison
+(``brumby_probe.py``: tapped logits and one layer's carried state).
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from megatron_llm_tpu.models import retention
+from megatron_llm_tpu.models.brumby import BrumbyModel, brumby_config
+from megatron_llm_tpu.models.mistral import MistralModel, mistral_config
+from megatron_llm_tpu.ops import paged_kv
+from megatron_llm_tpu.ops.pallas import paged_attention as pa
+from megatron_llm_tpu.ops.pallas import retention_step as rs
+from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
+                                      SamplingParams)
+
+REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks", "reference")
+REF_CFG = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+               rope_theta=1e6, rms_norm_eps=1e-6, num_hidden_layers=2,
+               intermediate_size=128, vocab_size=256,
+               bytes={"phi_rows": 144})
+# float32 on both sides, the same sums in another order
+TOL = 2e-5
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return _load("brumby"), _load("brumby_from_program"), _load(
+        "brumby_probe")
+
+
+@pytest.fixture(scope="module")
+def served():
+    model = BrumbyModel(brumby_config("tiny", use_flash_attn=False))
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _operands(b, n, g=2, r=2, d=16, seed=0, gate_shift=0.0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (b, n, g, r, d))
+    k = jax.random.normal(ks[1], (b, n, g, d))
+    v = jax.random.normal(ks[2], (b, n, g, d))
+    a = jax.nn.log_sigmoid(jax.random.normal(ks[3], (b, n, g)) + gate_shift)
+    return q, k, v, a
+
+
+def _zeros(b, g=2, d=16):
+    O = rs.rotations(d)
+    return jnp.zeros((b, g, O, d, d)), jnp.zeros((b, g, O, d))
+
+
+def _quadratic(q, k, v, a):
+    """o_t = sum_j exp(A_t - A_j) (q_t . k_j)^2 v_j / the same sum."""
+    n = q.shape[1]
+    A = jnp.cumsum(a, axis=1)
+    seen = jnp.tril(jnp.ones((n, n), bool))[None, :, :, None]
+    qk = jnp.einsum("btgrd,bsgd->btsgr", q, k, precision="highest")
+    w = jnp.where(seen, jnp.exp(jnp.where(
+        seen, A[:, :, None] - A[:, None], 0.0)), 0.0)[..., None] * qk ** 2
+    return jnp.einsum("btsgr,bsgd->btgrd", w, v,
+                      precision="highest") / w.sum(2)[..., None]
+
+
+def _chunk(q, k, v, a, S, z):
+    with jax.default_matmul_precision("highest"):
+        num, den, S, z = retention.retention_chunk(q, k, v, a, S, z,
+                                                   jnp.float32)
+    return num / den[..., None], S, z
+
+
+@pytest.mark.parametrize("d", [8, 16, 128])
+def test_phi_of_two_vectors_multiplies_to_their_products_square(d):
+    x, y = jax.random.normal(jax.random.PRNGKey(d), (2, 5, d))
+    assert rs.phi(x).shape == (5, d // 2 + 1, d)
+    np.testing.assert_allclose((rs.phi(x) * rs.phi(y)).sum((-1, -2)),
+                               (x * y).sum(-1) ** retention.DEGREE,
+                               rtol=2e-5, atol=1e-5)
+
+
+def test_the_chunk_is_the_quadratic_form_and_two_chunks_are_one():
+    q, k, v, a = _operands(2, 300)
+    whole, S, z = _chunk(q, k, v, a, *_zeros(2))
+    np.testing.assert_allclose(whole, _quadratic(q, k, v, a), atol=TOL)
+    first, S1, z1 = _chunk(q[:, :130], k[:, :130], v[:, :130], a[:, :130],
+                           *_zeros(2))
+    rest, S2, z2 = _chunk(q[:, 130:], k[:, 130:], v[:, 130:], a[:, 130:],
+                          S1, z1)
+    np.testing.assert_allclose(jnp.concatenate([first, rest], 1), whole,
+                               atol=TOL)
+    np.testing.assert_allclose(S2, S, atol=TOL * float(jnp.abs(S).max()))
+    np.testing.assert_allclose(z2, z, atol=TOL * float(jnp.abs(z).max()))
+
+
+def test_steps_one_by_one_are_the_chunk():
+    q, k, v, a = _operands(2, 40, seed=1)
+    whole, S, z = _chunk(q, k, v, a, *_zeros(2))
+    Ss, zs = _zeros(2)
+    for t in range(40):
+        num, den, Ss, zs = rs.dense_retention_step(
+            Ss, zs, q[:, t], k[:, t], v[:, t], a[:, t])
+        np.testing.assert_allclose(num / den[..., None], whole[:, t],
+                                   atol=TOL)
+    np.testing.assert_allclose(Ss, S, atol=TOL * float(jnp.abs(S).max()))
+    np.testing.assert_allclose(zs, z, atol=TOL * float(jnp.abs(z).max()))
+
+
+def test_gates_near_zero_over_a_long_row_do_not_underflow():
+    """exp(a) of 1e-9 a token over 260 tokens: a product of the decays is
+    0 in float32 after five, the differences of the running sum are not."""
+    q, k, v, a = _operands(1, 260, seed=2, gate_shift=-20.0)
+    assert float(a.max()) < -15 and float(jnp.cumsum(a, 1).min()) < -4000
+    out, S, z = _chunk(q, k, v, a, *_zeros(1))
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(S).all())
+    np.testing.assert_allclose(out, _quadratic(q, k, v, a), atol=TOL)
+
+
+def test_a_key_value_heads_state_is_read_by_its_own_query_heads_alone():
+    """Another key-value head 0: exactly its query heads' outputs move,
+    in the chunk and in the step."""
+    q, k, v, a = _operands(1, 20, seed=3)
+    other = k.at[:, :, 0].set(k[:, ::-1, 0])
+    one, S, z = _chunk(q, k, v, a, *_zeros(1))
+    two, S2, z2 = _chunk(q, other, v, a, *_zeros(1))
+    assert float(jnp.abs(one[:, :, 0] - two[:, :, 0]).max()) > 1e-2
+    np.testing.assert_array_equal(one[:, :, 1], two[:, :, 1])
+    step = [rs.dense_retention_step(s_, z_, q[:, 19], k[:, 19], v[:, 19],
+                                    a[:, 19])[0]
+            for s_, z_ in ((S, z), (S2, z2))]
+    assert float(jnp.abs(step[0][:, 0] - step[1][:, 0]).max()) > 1e-2
+    np.testing.assert_array_equal(step[0][:, 1], step[1][:, 1])
+
+
+def _caches(cfg, pools, slots, context, valid, kernel="xla"):
+    tables = {} if slots is None else {
+        paged_kv.STATE: jnp.asarray(slots, jnp.int32)}
+    return paged_kv.step_caches(
+        pools, tables, jnp.asarray(context, jnp.int32),
+        jnp.asarray(valid, jnp.int32), kernel, paged_kv.layer_groups(cfg))
+
+
+def test_padding_and_idle_rows_leave_the_state_exact(served):
+    """A chunk of two rows, one of 5 real tokens of 12 and one idle: the
+    first row's slot holds the state of its 5 tokens (a padded token
+    neither decays nor adds), the idle row's slot keeps its own, and a
+    row whose context is 0 starts from zeros whatever its slot held."""
+    model, params = served
+    cfg = model.cfg
+    layer = jax.tree_util.tree_map(
+        lambda p: p[0], params["transformer"]["layers"]["retention"])
+    pools = paged_kv.init_pools(cfg, 1, 8, num_slots=3)
+    dirty = jax.tree_util.tree_map(lambda x: x + 0.5, pools[0])
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 12, cfg.hidden_size))
+    pos = jnp.broadcast_to(jnp.arange(12)[None], (2, 12))
+
+    def run(pool, rows, slots, context, valid):
+        cache = _caches(cfg, [pool], slots, context, valid)[0]
+        with jax.default_matmul_precision("highest"):
+            return retention.retention_mixer(
+                rows, layer, cfg, kv_cache=cache,
+                position_ids=pos[:rows.shape[0], :rows.shape[1]])
+
+    out, cache = run(dirty, h, [2, 0], [0, 7], [5, 0])
+    alone, only = run(pools[0], h[:1, :5], [1], [0], [5])
+    np.testing.assert_allclose(out[0, :5], alone[0], atol=TOL)
+    for name in ("ret_state", "ret_sum"):
+        np.testing.assert_allclose(cache.pool[name][2], only.pool[name][1],
+                                   atol=TOL)
+        np.testing.assert_array_equal(cache.pool[name][0], dirty[name][0])
+        np.testing.assert_array_equal(cache.pool[name][1], dirty[name][1])
+    assert cache.context_lens.tolist() == [5, 7]
+
+
+@pytest.mark.parametrize("block_bytes", [1 << 20, 3 * 16 * 16 * 4])
+def test_the_steps_kernel_is_the_dense_step_on_live_rows(block_bytes,
+                                                         monkeypatch):
+    """``retention_state_step`` under interpret against
+    ``dense_retention_step``: live rows advanced, a fresh row from zeros,
+    idle rows, a slot no row has and the garbage row bit for bit; one
+    block a head and three."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    monkeypatch.setattr(rs, "_BLOCK_BYTES", block_bytes)
+    jax.clear_caches()
+    g, r, d, slots = 2, 2, 16, 5
+    O = rs.rotations(d)
+    assert O // rs.rotation_block(O, d) == (1 if block_bytes > 4096 else 3)
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    pool = jax.random.normal(ks[0], (slots + 2, g, O, d, d))
+    q = jax.random.normal(ks[1], (slots, g, r, d))
+    k, v = jax.random.normal(ks[2], (2, slots, g, d))
+    a = jax.nn.log_sigmoid(jax.random.normal(ks[3], (slots, g)))
+    live = jnp.array([True, False, True, True, False])
+    fresh = jnp.array([False, False, True, False, True])
+    num, after = rs.retention_state_step(pool, q, k, v, a, live, fresh)
+    before = jnp.where(fresh[:, None, None, None, None], 0.0, pool[:slots])
+    want, _, S, _ = rs.dense_retention_step(
+        before, jnp.zeros((slots, g, O, d)), q, k, v, a)
+    rows = np.flatnonzero(live)
+    np.testing.assert_allclose(num[rows], want[rows], atol=TOL * 10)
+    np.testing.assert_allclose(after[rows], S[rows], atol=1e-6)
+    np.testing.assert_array_equal(num[~np.asarray(live)], 0.0)
+    for idle in (1, 4, 5, 6):
+        np.testing.assert_array_equal(after[idle], pool[idle])
+    jax.clear_caches()
+
+
+def test_the_plain_forward_is_the_references(ref, served):
+    plain, from_program, _ = ref
+    model, params = served
+    tokens = np.random.default_rng(0).integers(1, 255, size=150)
+    want = plain.forward_logits(from_program.ProgramWeights(params, REF_CFG),
+                                REF_CFG, tokens)
+    with jax.default_matmul_precision("highest"):
+        got = model(params, jnp.asarray(tokens)[None], train=False)
+    np.testing.assert_allclose(got[0], want, atol=TOL)
+    assert float(jnp.std(want)) > 0.1
+
+
+def _engine(served, kernel="off", **kw):
+    model, params = served
+    return InferenceEngine(model, params, EngineConfig(**{**dict(
+        num_slots=3, block_size=8, max_model_len=256, prefill_chunk=32,
+        preemption=False, paged_kernel=kernel, prefill_kernel=kernel,
+        default_deadline_secs=600.0), **kw}))
+
+
+PROBE = dict(prompt_tokens=80, answer_tokens=12, tapped_chunks=[2, 3],
+             state_layer=0, live_rows=3, margin=1e-3,
+             logits_apart_tolerance=1e-4, decode_median_tolerance=1e-4,
+             position_apart_tolerance=1e-4,
+             state_apart_tolerance=1e-4, sum_apart_tolerance=1e-4,
+             state_float32_share_floor=0.9)
+# each named fault of the reference moves the tapped logits by hundredths
+# of their deviation at least; float8 and bf16 are the precisions below
+FAULTS = ("degree_one", "no_normaliser", "no_sqrt2", "no_gate",
+          "own_term_decayed", "sum_not_decayed", "state_dropped_at_chunks",
+          "kv_neighbour", "no_rope", "no_qk_norm", "bf16")
+
+
+@pytest.fixture(scope="module")
+def probed(ref, served):
+    """The probe's sequence served by a started engine on the XLA path,
+    tapped: what ``engine_against_reference`` reads, once for the sound
+    reference and every faulty one."""
+    _, from_program, probe = ref
+    eng = _engine(served).start()
+    try:
+        prompt = np.random.default_rng(1).integers(1, 255, size=80).tolist()
+        req = eng.submit(prompt, SamplingParams(max_new_tokens=12,
+                                                temperature=0.0))
+        req.result(timeout=300)
+        tokens = np.asarray(prompt + list(req.out_tokens)[:-1], np.int32)
+        run = probe.engine_run(eng, tokens, 80, [32, 64], 0, live_rows=3)
+    finally:
+        eng.stop()
+    weights = from_program.ProgramWeights(served[1], REF_CFG)
+    return eng, weights, tokens, run
+
+
+@pytest.mark.time_limit(600)
+@pytest.mark.parametrize("fault", ("sound",) + FAULTS)
+def test_the_engines_logits_and_state_are_the_references(ref, probed, fault):
+    """Chunks of 32 then steps through the state group: the ENGINE's own
+    logits at the first rows of two chunks, the prompt's last row and
+    every decode step, and the state its first layer's slot is left
+    with, against the reference's full forward; a slot taken again (the
+    prefixes reuse the first request's) starts from zeros.  Every named
+    fault of the reference is told by the same limits."""
+    eng, weights, tokens, run = probed
+    report, within, _, _ = ref[2].engine_against_reference(
+        eng, weights, {**REF_CFG, "fault_chunk": 32}, PROBE, tokens,
+        run=run, faults=frozenset([fault]) - {"sound"})
+    assert within == (fault == "sound"), report
+    if fault == "sound":
+        assert report["state"]["state_apart"] < 1e-5, report["state"]
+
+
+@pytest.mark.time_limit(600)
+def test_the_engine_on_the_kernel_path_is_the_references(ref, served,
+                                                         monkeypatch):
+    """The same through the step's kernel in interpret mode: rows moved
+    are the live rows."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    _, from_program, probe = ref
+    eng = _engine(served, "on").start()
+    try:
+        prompt = np.random.default_rng(2).integers(1, 255, size=80).tolist()
+        req = eng.submit(prompt, SamplingParams(max_new_tokens=12,
+                                                temperature=0.0))
+        req.result(timeout=300)
+        tokens = np.asarray(prompt + list(req.out_tokens)[:-1], np.int32)
+        report, within, _, _ = probe.engine_against_reference(
+            eng, from_program.ProgramWeights(served[1], REF_CFG), REF_CFG,
+            PROBE, tokens)
+    finally:
+        eng.stop()
+    assert within, report
+    stats = eng.stats()
+    assert eng.paged_kernel == "pallas"
+    rows_live = sum(r.retention_rows_live
+                    for r in eng.loop_profiler.records()
+                    if r.kind == "decode")
+    assert stats["retention_rows_moved"] == rows_live > 0
+    # steps of one, two and three live rows, each held to the reference
+    assert report["rows"]["live_rows"] == 3, report["rows"]
+    assert set(report["rows"]["steps_by_live_rows"]) == {"1", "2", "3"}
+    assert report["rows"]["states_held"] == 2
+    assert stats["retention_tokens"] \
+        == 2 * stats["prefill_tokens_computed"] + rows_live
+
+
+def test_a_model_with_no_paged_layer_is_admitted_by_slots(served):
+    """No group has pages: no block is counted, no table built, no page
+    program compiled, three long requests take the three slots and a
+    fourth waits for a slot and for nothing else; the chunk OWNS the pool
+    it writes (a paged model's is lent its pool)."""
+    eng = _engine(served)
+    assert not eng._cache.paged and eng._cache.tables(eng.blocks) == {}
+    assert set(eng._cache.tables(eng.blocks, slice(0, 1))) == {
+        paged_kv.STATE}
+    assert eng._num_blocks == 1 and paged_kv.paged_pools(eng._st.pages) == []
+    assert not any(name in eng._jitted for name in (
+        "engine_cow_copy", "engine_fetch_block", "engine_host_load"))
+    reqs = [eng.submit([1 + i] * 200, SamplingParams(max_new_tokens=3,
+                                                     temperature=0.0))
+            for i in range(4)]
+    held = eng._st.pages
+    assert eng.step()
+    assert sorted(eng.scheduler.active) == [0, 1, 2]
+    assert eng.queue.depth() == 1
+    assert all(a.is_deleted() for a in jax.tree_util.tree_leaves(held))
+    stats = eng.blocks.stats()
+    assert (stats["blocks_total"], stats["slots_in_use"]) == (0, 3)
+    assert stats["state_bytes_held"] == 3 * eng._cache.state_bytes_per_slot
+    while any(r.finish_reason is None for r in reqs):
+        assert eng.step()
+    eng.blocks.check_invariants()
+    eng.warmup()
+    assert set(eng.program_tables()) == {"engine_prefill",
+                                         "engine_sample_first",
+                                         "engine_decode"}
+    with pytest.raises(ValueError, match="sizes a pool of pages"):
+        _engine(served, num_blocks=64)
+    # a model with pages: its chunk is lent the pool and deletes nothing
+    model = MistralModel(mistral_config("tiny", use_flash_attn=False))
+    paged = InferenceEngine(model, model.init(jax.random.PRNGKey(0)),
+                            EngineConfig(num_slots=2, block_size=8,
+                                         max_model_len=64, prefill_chunk=16))
+    assert paged._cache.paged and "engine_cow_copy" in paged._jitted
+    paged.submit([1] * 20, SamplingParams(max_new_tokens=2))
+    held = paged._st.pages
+    assert paged.step()
+    assert not any(a.is_deleted() for a in jax.tree_util.tree_leaves(held))

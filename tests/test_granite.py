@@ -552,6 +552,12 @@ TRACED = {
     # above as it was
     "lfm2": {"engine_prefill": "c08e0b51f0aec84b",
              "engine_decode": "913f73debd40aff4"},
+    # PR 54 brought this family and changed no other's: the queries, keys
+    # and values of an attention layer come from ``qkv_heads`` now, which
+    # a retention layer calls too, the same operations in the same order;
+    # a paged model's chunk is still lent its pool
+    "brumby": {"engine_prefill": "9c113f3ea93d1713",
+               "engine_decode": "d8a3caf89969587f"},
 }
 
 

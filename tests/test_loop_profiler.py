@@ -491,7 +491,7 @@ def _groups():
 
     return {name: getattr(lp, name) for name in (
         "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS",
-        "CONV_FIELDS", "KV_FIELDS", "HOST_FIELDS")}
+        "CONV_FIELDS", "RETENTION_FIELDS", "KV_FIELDS", "HOST_FIELDS")}
 
 
 @pytest.mark.parametrize("group", sorted(_groups()))
@@ -856,7 +856,7 @@ KERNEL_NAMES = {
     "mla_attention_decode", "mla_attention_prefill",
     # the decode step's state-space recurrence, in place over the live
     # rows (PR 45), under the scope ``ssm_step``
-    "ssm_state_step",
+    "ssm_state_step", "retention_state_step",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -913,8 +913,9 @@ def test_every_kernel_and_program_carries_its_stable_name():
                 kw = {k.arg: k.value for k in node.keywords}
                 names.add(kw["name"].value)
     # 13 until the latent chunk got a walk of its own
-    # (mla_attention_prefill), 14 until the state's step got a kernel
-    assert calls == 15
+    # (mla_attention_prefill), 14 until the state's step got a kernel,
+    # 15 until a retention layer's did
+    assert calls == 16
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

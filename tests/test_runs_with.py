@@ -25,7 +25,7 @@ SQUARES = [(has, what) for has, whats in C.RUNS_WITH for what in whats]
 # a served family that has each mechanism, and how a config (or an
 # engine) is given each thing a mechanism does not run with
 FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
-          C.SHORT_CONV: "lfm2",
+          C.SHORT_CONV: "lfm2", C.RETENTION: "brumby",
           C.ONE_SUBLAYER: "nemotron_h",
           C.GATE: "trinity", C.OUTPUT_NORMS: "trinity",
           C.ROPE_TYPES: "trinity",
@@ -42,6 +42,10 @@ GIVEN = {
     C.CONV_OTHER_TYPES: lambda cfg: dict(
         layer_types=cfg.layer_types[:-1] + ("sliding",),
         sliding_window_size=16),
+    # a retention stack with its last layer an attention layer; experts
+    # beside the state
+    C.RETENTION_OTHER_TYPES: dict(layer_types=("retention", "attention")),
+    C.EXPERTS: dict(num_experts=4),
     C.GATE: dict(attention_output_gate=True),
     C.OUTPUT_NORMS: dict(sublayer_output_norm=True),
     C.BIASES: dict(add_bias_linear=True),
@@ -215,8 +219,18 @@ def test_leading_dense_layers_run_in_a_stack_whose_mixers_are_by_kind(
     assert list(req.out_tokens) == want[20:].argmax(-1).tolist()
 
 
+def test_a_retention_stack_with_another_layer_type_is_refused_by_name():
+    """Pages, or another kind's state, beside a state of this size: held
+    to nothing, so the constructor says so."""
+    for other in ("attention", "mamba", "conv", "sliding"):
+        with pytest.raises(ValueError, match="every layer of the depth is "
+                                             "'retention'"):
+            _config("brumby", layer_types=("retention", other),
+                    sliding_window_size=16)
+
+
 @pytest.mark.parametrize("family", ["kanana", "keye", "mellum", "granite",
-                                    "lfm2"])
+                                    "lfm2", "brumby"])
 def test_the_pool_and_the_engine_refuse_int8_in_one_sentence(family):
     """``init_pools(quantized=True)`` and the engine ask the same table,
     so a latent, an indexed, a grouped model and one with state-space

@@ -311,6 +311,7 @@ GRANITE = "granite_cell_programs"
 NEMOTRON = "nemotron_cell_programs"
 TRINITY = "trinity_cell_programs"
 LFM2 = "lfm2_cell_programs"
+BRUMBY = "brumby_cell_programs"
 
 
 def _compile_all(only: str = ""):
@@ -334,7 +335,8 @@ def _compile_all(only: str = ""):
         programs = {"granite": (GRANITE, _granite_programs),
                     "nemotron": (NEMOTRON, _nemotron_programs),
                     "trinity": (TRINITY, _trinity_programs),
-                    "lfm2": (LFM2, _lfm2_programs)}[only]
+                    "lfm2": (LFM2, _lfm2_programs),
+                    "brumby": (BRUMBY, _brumby_programs)}[only]
         print(json.dumps({programs[0]: programs[1](chip)}))
         return
     found = {}
@@ -477,6 +479,19 @@ def _lfm2_programs(chip):
         num_slots=128, num_blocks=16385, max_model_len=4352))
 
 
+def _brumby_programs(chip):
+    """The same of the benchmark's Brumby cell: 8 of the published 40
+    layers, every one a power retention, at the published widths (40
+    query heads over 8 key-value heads of 128, an MLP of 17,408), the
+    whole vocabulary under an untied head, 16 slots of state and NO
+    page."""
+    from megatron_llm_tpu.models.brumby import BrumbyModel, brumby_config
+
+    return _cell_programs(chip, lambda: BrumbyModel(brumby_config(
+        "14b", num_layers=8, params_dtype="bf16", compute_dtype="bf16",
+        seq_length=17920)), dict(num_slots=16, max_model_len=17920))
+
+
 # rows of a compiled program that move or compute nothing
 _NO_WORK = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "custom-call")
@@ -493,9 +508,13 @@ def _cell_programs(chip, build, engine):
         eng = InferenceEngine(model, params, EngineConfig(
             block_size=16, prefill_chunk=512, preemption=False,
             paged_kernel="on", prefill_kernel="on", **engine))
-        # the recurrent state's shapes (the columns' have three dimensions)
-        state = {(hlo_collectives._HLO_DTYPE[d], sh) for d, sh in
-                 paged_kv.state_shapes(eng._st.pages) if len(sh) == 4}
+        # the recurrent state's shapes (a state-space layer's
+        # ``ssm_state``, a retention layer's ``ret_state``), every slot's
+        # or every row's
+        state = {(hlo_collectives._HLO_DTYPE[a.dtype.name], sh)
+                 for p in eng._st.pages for name, a in p.items()
+                 if name in ("ssm_state", "ret_state")
+                 for sh in (tuple(a.shape), (a.shape[0] - 1,) + a.shape[1:])}
         found = {"state_bytes_per_slot": paged_kv.state_bytes_per_slot(
             eng._st.pages), "pool_bytes": eng.kv_pool_bytes,
             "parameters": sum(a.size for a in
@@ -522,13 +541,15 @@ def _cell_programs(chip, build, engine):
                 "temp_bytes": m.temp_size_in_bytes,
                 "state_rewrites": rewrites,
                 "kernels": sorted(set(re.findall(
-                    r"(paged_attention_\w+?|moe_experts\w*?|ssm_state_step)"
+                    r"(paged_attention_\w+?|moe_experts\w*?|ssm_state_step"
+                    r"|retention_state_step)"
                     r"(?:\.\d+)? = ", text))),
                 "scopes": sorted({s for s in (
                     "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
                     "ssm_gate_norm", "ssm_out_proj", "attn_gate",
                     "post_attn_norm", "post_mlp_norm", "conv_in_proj",
-                    "short_conv", "conv_out_proj")
+                    "short_conv", "conv_out_proj", "retention_gate",
+                    "retention_chunk", "retention_step")
                     if f"/{s}/" in text})}
         return found
     except Exception as e:      # noqa: BLE001 - the compiler's refusal
@@ -577,6 +598,11 @@ def trinity_compiled():
 @pytest.fixture(scope="module")
 def lfm2_compiled():
     return _child("lfm2")
+
+
+@pytest.fixture(scope="module")
+def brumby_compiled():
+    return _child("brumby")
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -793,6 +819,43 @@ def test_the_lfm2_cells_programs_compile_and_fit_a_v5e(lfm2_compiled):
         assert {"conv_in_proj", "short_conv", "conv_out_proj"} <= set(
             got["scopes"]), got["scopes"]
         assert {"moe_experts", walk} <= set(got["kernels"]), got["kernels"]
+
+
+@pytest.mark.time_limit(900)
+def test_the_brumby_cells_programs_compile_and_fit_a_v5e(brumby_compiled):
+    """The Brumby cell's two programs at its real sizes (8 retention
+    layers at the published widths, the whole vocabulary, 16 slots, no
+    page), for a described v5e: the decode step holds a Mosaic call for
+    the recurrence (``retention_state_step``) and rewrites no array of
+    the state's shape outside it; the chunk OWNS its pool, so both
+    programs alias the whole state group, the chunk writes one slot's
+    state where it lies (a dynamic-update-slice a layer, no copy) and
+    weights, one state and a chunk's temporaries fit the chip's 15.75
+    GB."""
+    found = brumby_compiled[BRUMBY]
+    assert isinstance(found, dict), found
+    # 8 key-value heads of 65 rotations of [128, 128] and [128], float32
+    layer = 8 * (65 * 128 * 128 + 65 * 128) * 4
+    assert found["state_bytes_per_slot"] == 8 * layer == 274_759_680
+    # 16 slots and the garbage row, and nothing else: no page
+    assert found["pool_bytes"] == 17 * 8 * layer
+    # ISSUE 54's arithmetic: 8 x 330.35 M + 2 x 777.9 M + the final norm
+    assert found["parameters"] == 4_198_652_928
+    for name, scope in (("engine_prefill", "retention_chunk"),
+                        ("engine_decode", "retention_step")):
+        got = found[name]
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"])
+        assert got["alias_bytes"] >= found["pool_bytes"], (name, got)
+        # 8.40 GB of weights, 4.67 GB of state held ONCE, and under 1 GB
+        # of temporaries
+        assert held - got["alias_bytes"] < 15.75e9 * 0.9, (name, held)
+        assert got["temp_bytes"] < 1.0e9, (name, got)
+        assert {"retention_gate", scope} <= set(got["scopes"]), got["scopes"]
+    assert found["engine_decode"]["kernels"] == ["retention_state_step"]
+    assert found["engine_decode"]["state_rewrites"] == []
+    assert found["engine_prefill"]["state_rewrites"] == [
+        "retention_chunk dynamic-update-slice"] * 8
 
 
 if __name__ == "__main__":
