@@ -40,19 +40,33 @@ are:
 
 * a **chunk** ``[b, n, h]`` from a given ``(S, z)`` (a prefill chunk of
   the serving engine; the cache-less forward, from zeros), scope
-  ``retention_chunk``: BLOCKS OF :data:`BLOCK` (128) ROWS; inside a block
-  ``(q k^T)^2`` under the causal mask and the gates' decay ``exp(A_t -
-  A_j)`` (``A`` the running sum of ``a`` inside the block in float32:
-  differences, never a product of ``exp``s), across blocks ``phi(q)^T
-  S`` and ``S <- exp(A_end) S + sum_j exp(A_end - A_j) phi(k_j) v_j^T``,
-  a ``lax.scan`` over blocks so that ``phi`` of ONE block is live at a
-  time; numerator and normaliser are carried together and divided once.
-  ``phi``'s products are taken in float32, rounded once to the compute
-  dtype a rotation at a time (``_phi_held``; ``short_conv._held``: a
-  rounding no fusion drops) for the MXU, which reads ``S`` and ``z`` in
-  the compute dtype too, and accumulated in float32.  Exact under padding: a token past a row's
-  ``valid_len`` has ``a = 0`` and ``k = 0``, so it neither decays nor
-  adds, and an idle row keeps its own state;
+  ``retention_chunk``, ON TWO PATHS as the step is: under a cache whose
+  resolved ``kernel`` is ``'pallas'`` (the engine's ``prefill_kernel``:
+  one device, Pallas available) ONE kernel that walks the chunk's blocks
+  over the slot's state where it lies and forms ``phi`` in VMEM
+  (``PagedKVCache.chunk_retention``, ``ops/pallas/retention_chunk.py``,
+  launched as ``retention_state_chunk``); everywhere else (no cache:
+  tests, ``verify_correctness.py``, whatever differentiates through the
+  model; a cache whose ``kernel`` is ``'xla'``: the CPU, a program
+  partitioned over several devices) :func:`retention_chunk` in
+  ``jax.numpy`` between ``read_state`` and ``write_state``, which is
+  also what the kernel's tests compare against.  The algebra and the
+  rounding points are the same on both: BLOCKS OF :data:`BLOCK` (128)
+  ROWS; inside a block ``(q k^T)^2`` under the causal mask and the
+  gates' decay ``exp(A_t - A_j)`` (``A`` the running sum of ``a`` inside
+  the block in float32: differences, never a product of ``exp``s),
+  across blocks ``phi(q)^T S`` and ``S <- exp(A_end) S + sum_j exp(A_end
+  - A_j) phi(k_j) v_j^T``, the blocks in order (XLA's form a
+  ``lax.scan`` so that ``phi`` of ONE block is live at a time, the
+  kernel's the innermost axis of its grid); numerator and normaliser are
+  carried together and divided once.  ``phi``'s products are taken in
+  float32, rounded once to the compute dtype a rotation at a time
+  (``_phi_held``; ``short_conv._held``: a rounding no fusion drops; the
+  kernel's cast, which Mosaic does as given) for the MXU, which reads
+  ``S`` and ``z`` in the compute dtype too, and accumulated in float32.
+  Exact under padding: a token past a row's ``valid_len`` has ``a = 0``
+  and ``k = 0``, so it neither decays nor adds, and an idle row keeps
+  its own state;
 * a **step** ``[S, 1, h]`` (the decode program), scope
   ``retention_step``: ``PagedKVCache.step_retention``, which on the
   ``'pallas'`` path is ONE in-place kernel over the live rows
@@ -71,6 +85,7 @@ import jax.numpy as jnp
 
 from megatron_llm_tpu.config import TransformerConfig
 from megatron_llm_tpu.models.short_conv import _held
+from megatron_llm_tpu.ops.pallas import retention_chunk as _chunk_kernel
 from megatron_llm_tpu.ops.pallas import retention_step as _step
 from megatron_llm_tpu.parallel.layers import (
     init_linear_params,
@@ -85,8 +100,9 @@ DEGREE = 2
 
 # rows of a chunk's block: the quadratic form is BLOCK x BLOCK a head,
 # phi of a block's queries [BLOCK, heads, rotations, d] (a chunk of 512 is
-# four blocks; what the model leaves free, so no flag)
-BLOCK = 128
+# four blocks; what the model leaves free, so no flag); 128, the chunk's
+# kernel's and XLA's form's alike
+BLOCK = _chunk_kernel.BLOCK
 
 
 def init_retention_params(key, cfg: TransformerConfig, dtype):
@@ -223,19 +239,27 @@ def retention_mixer(h: jax.Array, params, cfg: TransformerConfig, *,
             kv_cache = kv_cache.write_state()
     else:
         with jax.named_scope("retention_chunk"):
-            if kv_cache is not None:
-                S, z = kv_cache.read_state()
-                valid = kv_cache.valid_lens
-            else:
+            in_kernel = kv_cache is not None and kv_cache.kernel == "pallas"
+            if kv_cache is None:
                 S = jnp.zeros((b, g, O, d, d), jnp.float32)
                 z = jnp.zeros((b, g, O, d), jnp.float32)
                 valid = jnp.full((b,), n, jnp.int32)
+            else:
+                valid = kv_cache.valid_lens
+                if not in_kernel:
+                    S, z = kv_cache.read_state()
             live = (jnp.arange(n)[None, :] < valid[:, None])[..., None]
-            num, den, S, z = retention_chunk(
-                q, jnp.where(live[..., None], k, jnp.zeros((), k.dtype)), v,
-                jnp.where(live, a, 0.0), S, z, cd)
+            if in_kernel:
+                # the cache advances its own state, where it lies
+                # (PagedKVCache.chunk_retention)
+                num, den, kv_cache = kv_cache.chunk_retention(q, k, v, a, cd)
+                kv_cache = kv_cache.write_state()
+            else:
+                num, den, S, z = retention_chunk(
+                    q, jnp.where(live[..., None], k, jnp.zeros((), k.dtype)),
+                    v, jnp.where(live, a, 0.0), S, z, cd)
             out = num / jnp.where(live[..., None], den, 1.0)[..., None]
-            if kv_cache is not None:
+            if kv_cache is not None and not in_kernel:
                 kv_cache = kv_cache.write_state(S, z)
     out = row_parallel_linear(
         out.astype(cd).reshape(b, n, nh * d), params["dense"],

@@ -480,12 +480,38 @@ class PagedKVCache:
             self, pool={**self.pool, "ret_state": S,
                         "ret_sum": self._put(z, z_new, live)})
 
+    def chunk_retention(self, q, k, v, a, cdtype):
+        """A chunk of a power-retention layer's recurrence on the
+        ``'pallas'`` path (``ops/pallas/retention_chunk.py`` has the
+        operands): ONE kernel that walks the chunk's blocks over each
+        row's ``ret_state`` where it lies in the pool and forms ``phi``
+        in VMEM.  Numerators [b, n, g, r, d] and normalisers [b, n, g,
+        r] in float32 and the cache with both arrays WRITTEN
+        (``write_state()`` then only advances the lengths).  The
+        normaliser, a 128th of the bytes, goes in as ``read_state``
+        gives the rows' (the state's own rows are not asked for, so
+        nothing gathers them) and is put back by ``_put``, as around the
+        step's kernel.  On the ``'xla'`` path the mixer runs
+        ``retention.retention_chunk`` between ``read_state`` and
+        ``write_state``."""
+        from megatron_llm_tpu.ops.pallas import retention_chunk as _ret
+
+        slots = (jnp.arange(q.shape[0], dtype=jnp.int32)
+                 if self.slots is None else self.slots)
+        num, den, S, z_new = _ret.retention_state_chunk(
+            self.pool["ret_state"], self.read_state()[1], q, k, v, a, slots,
+            self.valid_lens, self.context_lens == 0, cdtype)
+        return num, den, dataclasses.replace(
+            self, pool={**self.pool, "ret_state": S, "ret_sum": self._put(
+                self.pool["ret_sum"], z_new, self.valid_lens > 0)})
+
     def write_state(self, *arrays):
         """The cache as a state-carrying layer's call leaves it: each
         live row's ``arrays`` (in ``read_state``'s order) written at its
         slot (``_put``; one left out or None stays as it is:
         ``step_state`` has written ``ssm_state``, ``step_retention``
-        both of its arrays), ``context_lens`` advanced."""
+        and ``chunk_retention`` both of their arrays), ``context_lens``
+        advanced."""
         live = self.valid_lens > 0
         pool = dict(self.pool)
         names = [name for name in _STATE_ARRAYS if name in self.pool]
@@ -831,6 +857,9 @@ class CachePlan:
                     d.retention_rows_live if self.paged_kernel == "pallas"
                     else ret_layers * (self.num_slots + 1))
             d.retention_tokens = ret_layers * int(val.sum())
+            if d.kind == "prefill" and self.prefill_kernel == "pallas":
+                # the chunk ran in ops/pallas/retention_chunk.py's kernel
+                d.retention_chunk_tokens_kernel = d.retention_tokens
         if state_layers:
             d.ssm_rows_live = state_layers * len(val)
             if d.kind != "prefill":

@@ -228,6 +228,205 @@ def test_the_steps_kernel_is_the_dense_step_on_live_rows(block_bytes,
     jax.clear_caches()
 
 
+def _pool(slots, g=2, d=16, seed=7):
+    """A state group's two arrays of one layer, every slot dirty."""
+    O = rs.rotations(d)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (jax.random.normal(ks[0], (slots + 1, g, O, d, d)),
+            jax.random.normal(ks[1], (slots + 1, g, O, d)))
+
+
+def _xla_rows(q, k, v, a, state, sums, slots, valid, fresh):
+    """What the mixer does on the XLA path: the rows' state read (zeros
+    where fresh), the tokens past ``valid`` neither decaying nor adding,
+    ``retention_chunk``."""
+    n = q.shape[1]
+    live = (jnp.arange(n)[None] < jnp.asarray(valid)[:, None])[..., None]
+    new = jnp.asarray(fresh)
+    S = jnp.where(new[:, None, None, None, None], 0.0,
+                  state[jnp.asarray(slots)])
+    z = jnp.where(new[:, None, None, None], 0.0, sums[jnp.asarray(slots)])
+    with jax.default_matmul_precision("highest"):
+        return retention.retention_chunk(
+            q, jnp.where(live[..., None], k, 0.0), v, jnp.where(live, a, 0.0),
+            S, z, jnp.float32)
+
+
+def _in_the_kernel(q, k, v, a, state, sums, slots, valid, fresh):
+    """The same through ``PagedKVCache.chunk_retention`` on the kernel
+    path (a fresh row's context is 0): numerators, normalisers and the
+    pool's two arrays as the call leaves them."""
+    cache = paged_kv.PagedKVCache(
+        {"ret_state": state, "ret_sum": sums}, None,
+        jnp.where(jnp.asarray(fresh), 0, 7).astype(jnp.int32),
+        jnp.asarray(valid, jnp.int32), kernel="pallas",
+        group=paged_kv.STATE, slots=jnp.asarray(slots, jnp.int32))
+    num, den, cache = cache.chunk_retention(q, k, v, a, jnp.float32)
+    return num, den, cache.pool["ret_state"], cache.pool["ret_sum"]
+
+
+# rows' lengths, slots, real tokens and who starts here, over a pool of
+# five dirty slots and the garbage row; r query heads a key-value head
+CHUNK_CASES = {
+    "one_block_from_zeros": dict(n=128, slots=[2], valid=[128],
+                                 fresh=[True]),
+    "four_blocks_from_a_drawn_state": dict(n=512, slots=[4], valid=[512],
+                                           fresh=[False]),
+    "a_length_no_multiple_of_the_block": dict(n=300, slots=[0], valid=[300],
+                                              fresh=[False]),
+    "a_fresh_row_over_a_dirty_slot_beside_a_carried_one": dict(
+        n=130, slots=[3, 1], valid=[130, 130], fresh=[True, False]),
+    "an_idle_row_and_padding_past_valid_len": dict(
+        n=300, slots=[1, 3, 0], valid=[45, 0, 200],
+        fresh=[False, True, True]),
+    "a_chunk_shorter_than_a_block": dict(n=12, slots=[2, 0], valid=[5, 12],
+                                         fresh=[True, False]),
+    "gates_near_zero_over_a_long_row": dict(
+        n=260, slots=[1], valid=[260], fresh=[False], gate_shift=-20.0),
+    "five_query_heads_a_key_value_head": dict(
+        n=140, slots=[0], valid=[140], fresh=[False], r=5),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_the_chunks_kernel_is_retention_chunk_on_the_slots_state(
+        case, monkeypatch):
+    """``retention_state_chunk`` under interpret, through the cache that
+    calls it, against ``retention_chunk`` on the rows' own state:
+    numerators and
+    normalisers at the real tokens, the state and the sums a live row
+    leaves in its slot; a fresh row starts from zeros whatever its slot
+    held; an idle row's slot, every slot no row has and the garbage row
+    bit for bit."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    c = dict(CHUNK_CASES[case])
+    n, slots, valid, fresh = c["n"], c["slots"], c["valid"], c["fresh"]
+    b, r = len(slots), c.get("r", 2)
+    q, k, v, a = _operands(b, n, r=r, seed=11,
+                           gate_shift=c.get("gate_shift", 0.0))
+    state, sums = _pool(5)
+    num, den, S, z = _in_the_kernel(q, k, v, a, state, sums, slots, valid,
+                                    fresh)
+    wnum, wden, wS, wz = _xla_rows(q, k, v, a, state, sums, slots, valid,
+                                   fresh)
+    assert num.shape == wnum.shape and den.shape == wden.shape
+    real = np.arange(n)[None] < np.asarray(valid)[:, None]
+    assert bool(jnp.isfinite(num).all()) and bool(jnp.isfinite(S).all())
+    np.testing.assert_allclose(
+        (num / den[..., None])[real], (wnum / wden[..., None])[real],
+        rtol=1e-5, atol=TOL)
+    np.testing.assert_allclose(num[real], wnum[real],
+                               atol=TOL * float(jnp.abs(wnum).max()))
+    np.testing.assert_allclose(den[real], wden[real],
+                               atol=TOL * float(jnp.abs(wden).max()))
+    for row, slot in enumerate(slots):
+        if valid[row]:
+            np.testing.assert_allclose(
+                S[slot], wS[row], atol=TOL * float(jnp.abs(wS).max()))
+            np.testing.assert_allclose(
+                z[slot], wz[row], atol=TOL * float(jnp.abs(wz).max()))
+    untouched = [s_ for s_ in range(5) if s_ not in
+                 [slot for row, slot in enumerate(slots) if valid[row]]]
+    for slot in untouched:
+        np.testing.assert_array_equal(S[slot], state[slot])
+        np.testing.assert_array_equal(z[slot], sums[slot])
+    # the kernel writes a live row's slot and nothing else (an idle
+    # row's sums go to the garbage row, as on the XLA path)
+    np.testing.assert_array_equal(S[5], state[5])
+
+
+def test_in_the_kernel_a_heads_state_is_read_by_its_own_five_heads_alone(
+        monkeypatch):
+    """Another key-value head 0 through the chunk's kernel: exactly its
+    five query heads' outputs and its own state move."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    q, k, v, a = _operands(1, 200, r=5, seed=12)
+    other = k.at[:, :, 0].set(k[:, ::-1, 0])
+    state, sums = _pool(1)
+    one = _in_the_kernel(q, k, v, a, state, sums, [0], [200], [False])
+    two = _in_the_kernel(q, other, v, a, state, sums, [0], [200], [False])
+    out = [num / den[..., None] for num, den, _, _ in (one, two)]
+    assert out[0].shape == (1, 200, 2, 5, 16)
+    assert float(jnp.abs(out[0][:, :, 0] - out[1][:, :, 0]).min(
+        axis=(0, 1, 3)).max()) > 0      # every one of the five moved
+    assert float(jnp.abs(out[0][:, :, 0] - out[1][:, :, 0]).max()) > 1e-2
+    np.testing.assert_array_equal(out[0][:, :, 1], out[1][:, :, 1])
+    assert float(jnp.abs(one[2][0, 0] - two[2][0, 0]).max()) > 1e-2
+    np.testing.assert_array_equal(one[2][0, 1], two[2][0, 1])
+    np.testing.assert_array_equal(one[3][0, 1], two[3][0, 1])
+
+
+def test_chunks_in_the_kernel_then_steps_in_the_steps_are_one_long_chunk(
+        monkeypatch):
+    """Two rows through ``PagedKVCache`` on the kernel path: a chunk of
+    150 tokens from zeros over dirty slots, one of 70 over what it left,
+    then six steps through the step's kernel (row s is slot s), against
+    ONE chunk of 226 tokens in XLA's form; the caches' lengths
+    advance."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    q, k, v, a = _operands(2, 226, seed=13)
+    whole, S, z = _chunk(q, k, v, a, *_zeros(2))
+    state, sums = _pool(2)
+    cfg = brumby_config("tiny", use_flash_attn=False)
+    pools = [{"ret_state": state, "ret_sum": sums}]
+    outs, context = [], 0
+    for lo, hi in ((0, 150), (150, 220)):
+        cache = _caches(cfg, pools, [0, 1], [context] * 2, [hi - lo] * 2,
+                        kernel="pallas")[0]
+        num, den, cache = cache.chunk_retention(
+            q[:, lo:hi], k[:, lo:hi], v[:, lo:hi], a[:, lo:hi], jnp.float32)
+        cache = cache.write_state()
+        outs.append(num / den[..., None])
+        pools, context = [cache.pool], hi
+        assert cache.context_lens.tolist() == [hi, hi]
+    for t in range(220, 226):
+        cache = _caches(cfg, pools, None, [t] * 2, [1] * 2,
+                        kernel="pallas")[0]
+        num, den, cache = cache.step_retention(q[:, t], k[:, t], v[:, t],
+                                               a[:, t])
+        outs.append((num / den[..., None])[:, None])
+        pools = [cache.write_state().pool]
+    np.testing.assert_allclose(jnp.concatenate(outs, axis=1), whole,
+                               atol=TOL)
+    np.testing.assert_allclose(pools[0]["ret_state"][:2], S,
+                               atol=TOL * float(jnp.abs(S).max()))
+    np.testing.assert_allclose(pools[0]["ret_sum"][:2], z,
+                               atol=TOL * float(jnp.abs(z).max()))
+    np.testing.assert_array_equal(pools[0]["ret_state"][2], state[2])
+
+
+@pytest.mark.parametrize("path", ["no_cache", "xla", "pallas"])
+def test_the_kernel_is_taken_only_under_a_cache_on_the_pallas_path(
+        path, served, monkeypatch):
+    """The cache-less forward (what differentiates through the model)
+    and a cache whose ``kernel`` is ``'xla'`` (the CPU, several devices)
+    trace no ``pallas_call``; a cache on the ``'pallas'`` path traces the
+    chunk's kernel once a layer."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    model, params = served
+    cfg = model.cfg
+    tokens = jnp.ones((1, 40), jnp.int32)
+    if path == "no_cache":
+        text = str(jax.make_jaxpr(
+            lambda p: model(p, tokens, train=False))(params))
+    else:
+        pools = paged_kv.init_pools(cfg, 1, 8, num_slots=2)
+
+        def chunk(p, pools):
+            from megatron_llm_tpu.models.language_model import (
+                language_model_forward)
+
+            caches = _caches(cfg, pools, [1], [0], [40], kernel=path)
+            return language_model_forward(
+                p, tokens, jnp.arange(40)[None], None, cfg, rng_key=None,
+                train=False, kv_caches=caches)[0]
+
+        text = str(jax.make_jaxpr(chunk)(params, pools))
+    calls = text.count("retention_state_chunk")
+    assert ("pallas_call" in text) == (path == "pallas"), path
+    assert (calls > 0) == (path == "pallas"), (path, calls)
+
+
 def test_the_plain_forward_is_the_references(ref, served):
     plain, from_program, _ = ref
     model, params = served
@@ -302,8 +501,9 @@ def test_the_engines_logits_and_state_are_the_references(ref, probed, fault):
 @pytest.mark.time_limit(600)
 def test_the_engine_on_the_kernel_path_is_the_references(ref, served,
                                                          monkeypatch):
-    """The same through the step's kernel in interpret mode: rows moved
-    are the live rows."""
+    """The same through the chunk's kernel and the step's in interpret
+    mode (the probe's own comparison over chunks of 32 then steps): rows
+    moved are the live rows, chunk tokens the kernel's."""
     monkeypatch.setattr(pa, "_INTERPRET", True)
     _, from_program, probe = ref
     eng = _engine(served, "on").start()
@@ -331,6 +531,24 @@ def test_the_engine_on_the_kernel_path_is_the_references(ref, served,
     assert report["rows"]["states_held"] == 2
     assert stats["retention_tokens"] \
         == 2 * stats["prefill_tokens_computed"] + rows_live
+    # the chunks ran in the chunk's kernel, every token of every layer
+    assert eng.prefill_kernel == "pallas"
+    chunks = [r for r in eng.loop_profiler.records() if r.kind == "prefill"]
+    assert chunks and all(
+        r.retention_chunk_tokens_kernel == r.retention_tokens > 0
+        for r in chunks)
+    assert stats["retention_chunk_tokens_kernel"] \
+        == 2 * stats["prefill_tokens_computed"]
+    assert not any(r.retention_chunk_tokens_kernel
+                   for r in eng.loop_profiler.records() if r.kind == "decode")
+
+
+def test_on_the_xla_path_no_chunk_token_is_the_kernels(probed):
+    eng = probed[0]
+    stats = eng.stats()
+    assert eng.prefill_kernel == "xla"
+    assert stats["retention_tokens"] > 0
+    assert stats["retention_chunk_tokens_kernel"] == 0
 
 
 def test_a_model_with_no_paged_layer_is_admitted_by_slots(served):

@@ -857,6 +857,9 @@ KERNEL_NAMES = {
     # the decode step's state-space recurrence, in place over the live
     # rows (PR 45), under the scope ``ssm_step``
     "ssm_state_step", "retention_state_step",
+    # a prefill chunk's power retention, phi formed in VMEM over the
+    # slot's state where it lies (PR 55), under ``retention_chunk``
+    "retention_state_chunk",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -914,8 +917,8 @@ def test_every_kernel_and_program_carries_its_stable_name():
                 names.add(kw["name"].value)
     # 13 until the latent chunk got a walk of its own
     # (mla_attention_prefill), 14 until the state's step got a kernel,
-    # 15 until a retention layer's did
-    assert calls == 16
+    # 15 until a retention layer's did, 16 until its chunk's
+    assert calls == 17
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

@@ -161,6 +161,26 @@ def _state_step(rows, heads, groups, d_head=64, d_state=128):
         ((rows,), jnp.bool_)]
 
 
+def _retention_chunk(rows, tokens, slots=16, g=8, r=5, d=128):
+    """A prefill chunk's power retention in place over a state group of
+    ``slots`` slots and the garbage row at Brumby's widths (8 key-value
+    heads of 5 query heads of 128, 65 rotations: a head's state 4.26 MB,
+    in and out through the pipeline's two buffers each): the cell's
+    ``[1, 512]`` chunk, four blocks, and rows whose length is no multiple
+    of the block."""
+    import functools
+
+    from megatron_llm_tpu.ops.pallas.retention_chunk import (
+        retention_state_chunk)
+
+    f32, O = jnp.float32, d // 2 + 1
+    return functools.partial(retention_state_chunk, cdtype=BF16), [
+        ((slots + 1, g, O, d, d), f32), ((rows, g, O, d), f32),
+        ((rows, tokens, g, r, d), BF16), ((rows, tokens, g, d), BF16),
+        ((rows, tokens, g, d), BF16), ((rows, tokens, g), f32),
+        ((rows,), jnp.int32), ((rows,), jnp.int32), ((rows,), jnp.bool_)]
+
+
 def _selected(q_tokens, slots, tokens=33792, page=16):
     """Keye's sparse attention through the paged pool (scores, choice,
     attention under it: ``ops/pallas/dsa_attention.py``) at the cell's
@@ -246,6 +266,9 @@ CASES = {
         lambda: _experts(512 * 8, 64, 2304, 896),
     "ssm_state_step_nemotron_64_rows": lambda: _state_step(64, 64, 8),
     "ssm_state_step_granite_24_rows": lambda: _state_step(24, 128, 1),
+    "retention_chunk_brumby_512_tokens": lambda: _retention_chunk(1, 512),
+    "retention_chunk_brumby_two_rows_of_300":
+        lambda: _retention_chunk(2, 300),
     "dsa_selected_decode_8_slots": lambda: _selected(1, 8),
     "dsa_selected_prefill_chunk_512": lambda: _selected(512, 1),
     "moe_experts_keye_8_rows": lambda: _experts(8 * 8, 128, 2048, 768,
@@ -530,11 +553,18 @@ def _cell_programs(chip, build, engine):
             m, text = comp.memory_analysis(), comp.as_text()
             # what else than the step's kernel writes an array of the
             # recurrent state's shape (every slot's, or every row's)
+            rows = hlo_collectives.instructions(text)
             rewrites = sorted(
-                f"{r['scope']} {r['root']}" for r in
-                hlo_collectives.instructions(text)
+                f"{r['scope']} {r['root']}" for r in rows
                 if r["opcode"] not in _NO_WORK and state & set(r["shapes"]))
             found[name] = {
+                # a block's phi: a compute-dtype array whose last
+                # dimensions are a head size of 128's 65 rotations
+                "phi_arrays": sorted({
+                    f"{dt}{list(sh)}" for r in rows for dt, sh in r["shapes"]
+                    if dt == "bf16" and tuple(sh[-2:]) == (65, 128)}),
+                "retention_chunk_calls": len(re.findall(
+                    r"retention_state_chunk(?:\.\d+)? = ", text)),
                 "argument_bytes": m.argument_size_in_bytes,
                 "output_bytes": m.output_size_in_bytes,
                 "alias_bytes": m.alias_size_in_bytes,
@@ -542,7 +572,7 @@ def _cell_programs(chip, build, engine):
                 "state_rewrites": rewrites,
                 "kernels": sorted(set(re.findall(
                     r"(paged_attention_\w+?|moe_experts\w*?|ssm_state_step"
-                    r"|retention_state_step)"
+                    r"|retention_state_step|retention_state_chunk)"
                     r"(?:\.\d+)? = ", text))),
                 "scopes": sorted({s for s in (
                     "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
@@ -826,12 +856,14 @@ def test_the_brumby_cells_programs_compile_and_fit_a_v5e(brumby_compiled):
     """The Brumby cell's two programs at its real sizes (8 retention
     layers at the published widths, the whole vocabulary, 16 slots, no
     page), for a described v5e: the decode step holds a Mosaic call for
-    the recurrence (``retention_state_step``) and rewrites no array of
-    the state's shape outside it; the chunk OWNS its pool, so both
-    programs alias the whole state group, the chunk writes one slot's
-    state where it lies (a dynamic-update-slice a layer, no copy) and
-    weights, one state and a chunk's temporaries fit the chip's 15.75
-    GB."""
+    the recurrence (``retention_state_step``), the chunk one a layer
+    (``retention_state_chunk``, PR 55: before it the chunk was XLA's,
+    wrote one slot's state by a dynamic-update-slice a layer and held a
+    block's ``phi``, ``bf16[1, 128, 8, 5, 65, 128]``, among 0.64 GB of
+    temporaries), and neither rewrites an array of the state's shape
+    outside its kernel; the chunk OWNS its pool, so both programs alias
+    the whole state group, and weights, one state and a chunk's
+    temporaries fit the chip's 15.75 GB."""
     found = brumby_compiled[BRUMBY]
     assert isinstance(found, dict), found
     # 8 key-value heads of 65 rotations of [128, 128] and [128], float32
@@ -854,8 +886,16 @@ def test_the_brumby_cells_programs_compile_and_fit_a_v5e(brumby_compiled):
         assert {"retention_gate", scope} <= set(got["scopes"]), got["scopes"]
     assert found["engine_decode"]["kernels"] == ["retention_state_step"]
     assert found["engine_decode"]["state_rewrites"] == []
-    assert found["engine_prefill"]["state_rewrites"] == [
-        "retention_chunk dynamic-update-slice"] * 8
+    chunk = found["engine_prefill"]
+    assert chunk["kernels"] == ["retention_state_chunk"]
+    # once a layer, the slot written in place, and no phi in HBM
+    assert chunk["retention_chunk_calls"] == 8, chunk
+    assert found["engine_decode"]["retention_chunk_calls"] == 0
+    assert chunk["state_rewrites"] == [], chunk
+    assert chunk["phi_arrays"] == [], chunk
+    # 0.606 GB (the logits of 512 rows are 0.31 of it) where XLA's chunk
+    # held 0.64
+    assert chunk["temp_bytes"] < 0.62e9, chunk
 
 
 if __name__ == "__main__":
