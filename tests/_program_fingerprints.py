@@ -2,8 +2,9 @@
 for a tiny engine of the family, the jaxpr of its prefill chunk and of
 its decode step, hashed.  Run as a script (a process of its own: no mesh
 another test left behind, one CPU device) it prints ``{family: {program:
-hash}}``; ``tests/test_granite.py`` holds what it prints to the hashes
-recorded when the family's programs were last meant to change."""
+hash}}``; ``tests/test_program_fingerprints.py`` holds what it prints to
+the hashes recorded when the family's programs were last meant to
+change."""
 import hashlib
 import importlib
 import json

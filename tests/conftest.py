@@ -7,6 +7,7 @@ expose 8 virtual CPU devices, so every TP/PP/DP/SP test runs in CI with no
 hardware.
 """
 
+import collections
 import faulthandler
 import os
 
@@ -77,6 +78,28 @@ def pytest_configure(config):
 
 
 _REAL_STDERR = pytest.StashKey[int]()
+
+# test-seconds by file (set-up, call and teardown, as the junit XML counts
+# them), in the process that reports: under xdist the controller, which
+# is handed every worker's reports
+_SECONDS = collections.Counter()
+
+
+def pytest_runtest_logreport(report):
+    _SECONDS[report.nodeid.split("::")[0]] += report.duration
+
+
+def pytest_terminal_summary(terminalreporter):
+    """What the run cost, in one line of its log: the sum of test-seconds
+    and the five dearest files (``--dist loadfile`` puts a file on one
+    worker, so a file is also the unit of balance).  ROADMAP.md's D13
+    holds the suite to its time limit by this line."""
+    if _SECONDS:
+        dearest = ", ".join(f"{name} {secs:.0f}"
+                            for name, secs in _SECONDS.most_common(5))
+        terminalreporter.write_line(
+            f"test-seconds: {sum(_SECONDS.values()):.0f} over "
+            f"{len(_SECONDS)} files; dearest: {dearest}")
 
 
 @pytest.hookimpl(wrapper=True, tryfirst=True)
@@ -161,3 +184,22 @@ def _clear_jax_caches_per_module():
     clearing keeps each module's footprint what it is when run alone."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def kept_engines():
+    """This module's engines of the served families' tiny models
+    (``tests/_family.py::Engines``): an engine of a shape is built once a
+    module, not once a test."""
+    import _family
+
+    kept = _family.Engines()
+    yield kept
+    kept.drop()
+
+
+@pytest.fixture
+def engines(kept_engines, monkeypatch):
+    """The module's engines for one test: a tap or interpret mode laid
+    over one is undone when the test ends."""
+    return kept_engines.during(monkeypatch)

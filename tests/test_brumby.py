@@ -13,51 +13,33 @@ path; the engine is held to it by the probe's own comparison
 (``brumby_probe.py``: tapped logits and one layer's carried state).
 """
 
-import importlib.util
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _family
+from _family import kernels
 from megatron_llm_tpu.models import retention
-from megatron_llm_tpu.models.brumby import BrumbyModel, brumby_config
+from megatron_llm_tpu.models.brumby import brumby_config
 from megatron_llm_tpu.models.mistral import MistralModel, mistral_config
 from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.ops.pallas import paged_attention as pa
 from megatron_llm_tpu.ops.pallas import retention_step as rs
-from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                      SamplingParams)
+from megatron_llm_tpu.serving import SamplingParams
 
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
-REF_CFG = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-               rope_theta=1e6, rms_norm_eps=1e-6, num_hidden_layers=2,
-               intermediate_size=128, vocab_size=256,
-               bytes={"phi_rows": 144})
 # float32 on both sides, the same sums in another order
-TOL = 2e-5
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+TOL = _family.FAMILIES["brumby"].tol
 
 
 @pytest.fixture(scope="module")
-def ref():
-    return _load("brumby"), _load("brumby_from_program"), _load(
-        "brumby_probe")
+def family():
+    return _family.built("brumby")
 
 
 @pytest.fixture(scope="module")
-def served():
-    model = BrumbyModel(brumby_config("tiny", use_flash_attn=False))
-    return model, model.init(jax.random.PRNGKey(0))
+def served(family):
+    return family.model, family.params
 
 
 def _operands(b, n, g=2, r=2, d=16, seed=0, gate_shift=0.0):
@@ -427,24 +409,8 @@ def test_the_kernel_is_taken_only_under_a_cache_on_the_pallas_path(
     assert (calls > 0) == (path == "pallas"), (path, calls)
 
 
-def test_the_plain_forward_is_the_references(ref, served):
-    plain, from_program, _ = ref
-    model, params = served
-    tokens = np.random.default_rng(0).integers(1, 255, size=150)
-    want = plain.forward_logits(from_program.ProgramWeights(params, REF_CFG),
-                                REF_CFG, tokens)
-    with jax.default_matmul_precision("highest"):
-        got = model(params, jnp.asarray(tokens)[None], train=False)
-    np.testing.assert_allclose(got[0], want, atol=TOL)
-    assert float(jnp.std(want)) > 0.1
-
-
-def _engine(served, kernel="off", **kw):
-    model, params = served
-    return InferenceEngine(model, params, EngineConfig(**{**dict(
-        num_slots=3, block_size=8, max_model_len=256, prefill_chunk=32,
-        preemption=False, paged_kernel=kernel, prefill_kernel=kernel,
-        default_deadline_secs=600.0), **kw}))
+def test_the_plain_forward_is_the_references():
+    _family.full_forward_is_the_references("brumby", 150, seed=0)
 
 
 PROBE = dict(prompt_tokens=80, answer_tokens=12, tapped_chunks=[2, 3],
@@ -460,69 +426,66 @@ FAULTS = ("degree_one", "no_normaliser", "no_sqrt2", "no_gate",
           "kv_neighbour", "no_rope", "no_qk_norm", "bf16")
 
 
+brumby_probe = _family.load("brumby_probe")
+
+
 @pytest.fixture(scope="module")
-def probed(ref, served):
+def probed(kept_engines):
     """The probe's sequence served by a started engine on the XLA path,
     tapped: what ``engine_against_reference`` reads, once for the sound
     reference and every faulty one."""
-    _, from_program, probe = ref
-    eng = _engine(served).start()
+    eng = kept_engines("brumby", **kernels("off")).start()
     try:
-        prompt = np.random.default_rng(1).integers(1, 255, size=80).tolist()
+        prompt = _family.tokens(80, seed=1, vocab=256)
         req = eng.submit(prompt, SamplingParams(max_new_tokens=12,
                                                 temperature=0.0))
         req.result(timeout=300)
         tokens = np.asarray(prompt + list(req.out_tokens)[:-1], np.int32)
-        run = probe.engine_run(eng, tokens, 80, [32, 64], 0, live_rows=3)
+        run = brumby_probe.engine_run(eng, tokens, 80, [32, 64], 0,
+                                      live_rows=3)
     finally:
         eng.stop()
-    weights = from_program.ProgramWeights(served[1], REF_CFG)
-    return eng, weights, tokens, run
+    return eng, tokens, run
 
 
 @pytest.mark.time_limit(600)
 @pytest.mark.parametrize("fault", ("sound",) + FAULTS)
-def test_the_engines_logits_and_state_are_the_references(ref, probed, fault):
+def test_the_engines_logits_and_state_are_the_references(family, probed,
+                                                        fault):
     """Chunks of 32 then steps through the state group: the ENGINE's own
     logits at the first rows of two chunks, the prompt's last row and
     every decode step, and the state its first layer's slot is left
     with, against the reference's full forward; a slot taken again (the
     prefixes reuse the first request's) starts from zeros.  Every named
     fault of the reference is told by the same limits."""
-    eng, weights, tokens, run = probed
-    report, within, _, _ = ref[2].engine_against_reference(
-        eng, weights, {**REF_CFG, "fault_chunk": 32}, PROBE, tokens,
-        run=run, faults=frozenset([fault]) - {"sound"})
+    eng, tokens, run = probed
+    report, within, _, _ = brumby_probe.engine_against_reference(
+        eng, family.weights, {**family.cfg, "fault_chunk": 32}, PROBE,
+        tokens, run=run, faults=frozenset([fault]) - {"sound"})
     assert within == (fault == "sound"), report
     if fault == "sound":
         assert report["state"]["state_apart"] < 1e-5, report["state"]
 
 
 @pytest.mark.time_limit(600)
-def test_the_engine_on_the_kernel_path_is_the_references(ref, served,
-                                                         monkeypatch):
+def test_the_engine_on_the_kernel_path_is_the_references(family, engines):
     """The same through the chunk's kernel and the step's in interpret
-    mode (the probe's own comparison over chunks of 32 then steps): rows
-    moved are the live rows, chunk tokens the kernel's."""
-    monkeypatch.setattr(pa, "_INTERPRET", True)
-    _, from_program, probe = ref
-    eng = _engine(served, "on").start()
+    mode: the standing question (chunks of 32 then steps stepped by hand,
+    the logits and the first layer's state and sums in the slot), then
+    the probe's own comparison of what that request decoded: rows moved
+    are the live rows, chunk tokens the kernel's."""
+    eng, since, seq = _family.chunked_prefill_then_decode_is_one_forward(
+        engines, "brumby", 80, 12, "on", seed=2)
+    eng.start()
     try:
-        prompt = np.random.default_rng(2).integers(1, 255, size=80).tolist()
-        req = eng.submit(prompt, SamplingParams(max_new_tokens=12,
-                                                temperature=0.0))
-        req.result(timeout=300)
-        tokens = np.asarray(prompt + list(req.out_tokens)[:-1], np.int32)
-        report, within, _, _ = probe.engine_against_reference(
-            eng, from_program.ProgramWeights(served[1], REF_CFG), REF_CFG,
-            PROBE, tokens)
+        report, within, _, _ = brumby_probe.engine_against_reference(
+            eng, family.weights, family.cfg, PROBE, np.asarray(seq, np.int32))
     finally:
         eng.stop()
     assert within, report
-    stats = eng.stats()
+    stats, records = since()
     assert eng.paged_kernel == "pallas"
-    rows_live = sum(r.retention_rows_live
-                    for r in eng.loop_profiler.records()
+    rows_live = sum(r.retention_rows_live for r in records
                     if r.kind == "decode")
     assert stats["retention_rows_moved"] == rows_live > 0
     # steps of one, two and three live rows, each held to the reference
@@ -533,14 +496,14 @@ def test_the_engine_on_the_kernel_path_is_the_references(ref, served,
         == 2 * stats["prefill_tokens_computed"] + rows_live
     # the chunks ran in the chunk's kernel, every token of every layer
     assert eng.prefill_kernel == "pallas"
-    chunks = [r for r in eng.loop_profiler.records() if r.kind == "prefill"]
+    chunks = [r for r in records if r.kind == "prefill"]
     assert chunks and all(
         r.retention_chunk_tokens_kernel == r.retention_tokens > 0
         for r in chunks)
     assert stats["retention_chunk_tokens_kernel"] \
         == 2 * stats["prefill_tokens_computed"]
-    assert not any(r.retention_chunk_tokens_kernel
-                   for r in eng.loop_profiler.records() if r.kind == "decode")
+    assert not any(r.retention_chunk_tokens_kernel for r in records
+                   if r.kind == "decode")
 
 
 def test_on_the_xla_path_no_chunk_token_is_the_kernels(probed):
@@ -551,12 +514,12 @@ def test_on_the_xla_path_no_chunk_token_is_the_kernels(probed):
     assert stats["retention_chunk_tokens_kernel"] == 0
 
 
-def test_a_model_with_no_paged_layer_is_admitted_by_slots(served):
+def test_a_model_with_no_paged_layer_is_admitted_by_slots(engines):
     """No group has pages: no block is counted, no table built, no page
     program compiled, three long requests take the three slots and a
     fourth waits for a slot and for nothing else; the chunk OWNS the pool
     it writes (a paged model's is lent its pool)."""
-    eng = _engine(served)
+    eng = engines("brumby", **kernels("off"))
     assert not eng._cache.paged and eng._cache.tables(eng.blocks) == {}
     assert set(eng._cache.tables(eng.blocks, slice(0, 1))) == {
         paged_kv.STATE}
@@ -582,12 +545,11 @@ def test_a_model_with_no_paged_layer_is_admitted_by_slots(served):
                                          "engine_sample_first",
                                          "engine_decode"}
     with pytest.raises(ValueError, match="sizes a pool of pages"):
-        _engine(served, num_blocks=64)
+        engines.fresh("brumby", num_blocks=64)
     # a model with pages: its chunk is lent the pool and deletes nothing
     model = MistralModel(mistral_config("tiny", use_flash_attn=False))
-    paged = InferenceEngine(model, model.init(jax.random.PRNGKey(0)),
-                            EngineConfig(num_slots=2, block_size=8,
-                                         max_model_len=64, prefill_chunk=16))
+    paged = _family.engine(model, model.init(jax.random.PRNGKey(0)),
+                           max_model_len=64, prefill_chunk=16)
     assert paged._cache.paged and "engine_cow_copy" in paged._jitted
     paged.submit([1] * 20, SamplingParams(max_new_tokens=2))
     held = paged._st.pages

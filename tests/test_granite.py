@@ -10,208 +10,67 @@ the router's 8 experts of which 4 are held at 3 a token, a shared MLP;
 contexts of 5 to 156 tokens over pages of 8 and chunks of 32.  The
 reference is the file the benchmark's probe loads
 (``benchmarks/reference/granite.py``: the recurrence one token at a time,
-no chunk, no cache), loaded here by path.
+no chunk, no cache), loaded here by path.  The family's row, the helpers
+and the three standing questions are ``tests/_family.py``'s.
 """
-
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _family
+from _family import BS, kernels, serve, tokens
 from megatron_llm_tpu.config import PositionEmbeddingType
 from megatron_llm_tpu.models import transformer as tfm
 from megatron_llm_tpu.models.granite import GraniteModel, granite_config
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.ops import paged_kv
-from megatron_llm_tpu.ops.pallas import paged_attention as pa
-from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                      SamplingParams)
-
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
+from megatron_llm_tpu.serving import SamplingParams
 
 # float32 on both sides, the same mathematics summed in another order (a
 # chunked scan and a step against a recurrence over tokens); the logits'
 # deviation is some 0.3 and every named fault moves them by hundredths
-LOGIT_TOL = 5e-5
-BS, CHUNK = 8, 32
+LOGIT_TOL = _family.FAMILIES["granite"].tol
 FAULTS = ("embedding_one", "residual_one", "logits_one", "scale_sqrt_head",
           "rope_on", "no_D", "gate_after_norm", "silu_second",
           "no_conv_bias", "no_shared", "state_dropped_at_chunks", "float8")
 
 
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _ref_cfg(cfg):
-    period = list(cfg.layer_types)
-    return {"num_hidden_layers": cfg.num_layers,
-            "layer_types": period * (cfg.num_layers // len(period)),
-            "num_attention_heads": cfg.num_attention_heads,
-            "num_key_value_heads": cfg.num_attention_heads_kv,
-            "rms_norm_eps": cfg.layernorm_epsilon,
-            "embedding_multiplier": cfg.embedding_multiplier,
-            "residual_multiplier": cfg.residual_multiplier,
-            "logits_scaling": cfg.logits_scaling,
-            "attention_multiplier": cfg.attention_multiplier,
-            "mamba_n_heads": cfg.mamba_n_heads,
-            "mamba_d_head": cfg.mamba_d_head,
-            "mamba_d_state": cfg.mamba_d_state,
-            "mamba_n_groups": cfg.mamba_n_groups,
-            "mamba_d_conv": cfg.mamba_d_conv,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "num_local_experts": cfg.num_experts,
-            "experts_first": cfg.moe_experts_first,
-            "vocab_size": cfg.padded_vocab_size,
-            "fault_chunk": CHUNK}
-
-
-def _shake(params, key):
-    """Seeded N(0, 0.02) weights make attention nearly uniform and every
-    norm's scale is 1 at init: larger projections and scales that differ
-    (``tests/test_mellum.py::_shake`` says why).  The convolution's taps
-    are drawn wide as they are."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        names = [getattr(p, "key", None) for p in path]
-        if "scale" in names:
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
-        elif "embedding" in names:
-            leaf = leaf * 8.0
-        elif {"kernel", "w_in", "w_out"} & set(names) and "conv" not in names:
-            leaf = leaf * (2.0 if "router" in names else 6.0)
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, out)
-
-
-# the share starts at the router's expert 2: experts 2-5 of 8 are held
-TINY = dict(use_flash_attn=False, moe_experts_first=2)
-
-
 @pytest.fixture(scope="module")
 def family():
-    model = GraniteModel(granite_config("tiny", **TINY))
-    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-    cfg = _ref_cfg(model.cfg)
-    weights = _load("granite_from_program").ProgramWeights(params, cfg)
-    return model, params, _load("granite"), weights, cfg
-
-
-def _tokens(n, seed=3, vocab=512):
-    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
-
-
-def _engine(model, params, **kw):
-    # a long deadline, not the default 120 s: a request served through an
-    # interpreted kernel must not expire by the wall clock of a loaded
-    # machine, and a hang must still fail
-    kw = dict(dict(num_slots=2, block_size=BS, max_model_len=192,
-                   prefill_chunk=CHUNK, preemption=False,
-                   default_deadline_secs=600.0), **kw)
-    return InferenceEngine(model, params, EngineConfig(**kw))
-
-
-def _serve(eng, prompt, new):
-    req = eng.submit(prompt, SamplingParams(max_new_tokens=new,
-                                            temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-    return req
+    return _family.built("granite")
 
 
 @pytest.mark.parametrize("n", [5, 16, 17, 70])
-def test_full_forward_matches_the_reference(family, n):
+def test_full_forward_matches_the_reference(n):
     """The program's plain (cache-less) forward, a scan over PERIODS with
     each layer's mixer taken by its index among its kind: logits at every
     position against the reference."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(n)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    assert want.std() > 0.1
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
-
-
-def _tapped(eng):
-    """The engine's programs with their logits kept
-    (``tests/test_mellum.py::_tapped``)."""
-    got = {}
-    prefill, decode = eng._prefill_step, eng._decode_step
-
-    def tapped_prefill(params, pages, tokens, start, valid, table):
-        out = prefill(params, pages, tokens, start, valid, table)
-        got[int(start) + int(valid) - 1] = np.asarray(out[0])
-        return out
-
-    def tapped_decode(params, pages, last, ctx, tables, active, *rest):
-        caches = paged_kv.step_caches(pages, tables, ctx, active,
-                                      eng.paged_kernel, eng._layer_groups)
-        logits, _ = language_model_forward(
-            params, last[:, None], ctx[:, None], None, eng.model.cfg,
-            rng_key=None, train=False, kv_caches=caches)
-        for s in np.flatnonzero(np.asarray(active) > 0):
-            got[int(np.asarray(ctx)[s])] = np.asarray(logits[s, 0])
-        return decode(params, pages, last, ctx, tables, active, *rest)
-
-    eng._prefill_step, eng._decode_step = tapped_prefill, tapped_decode
-    return got
+    _family.full_forward_is_the_references("granite", n)
 
 
 @pytest.mark.parametrize("prompt,new,kernel", [
     (5, 14, "off"), (64, 10, "off"), (150, 6, "off"), (45, 5, "on")])
 def test_the_engine_over_the_state_group_matches_one_full_forward(
-        family, prompt, new, kernel, monkeypatch):
+        engines, prompt, new, kernel):
     """Chunked prefill (chunks of 32, the last one padded) then decode
     through the engine's own programs, the state carried in its slot
     across every chunk boundary and step, against the reference's ONE
-    forward: logits at every chunk's last row and every step; the
-    attention layers through the dense gather and (``on``) through the
-    walk's kernels in interpret mode."""
-    model, params, ref, weights, cfg = family
-    if kernel == "on":
-        monkeypatch.setattr(pa, "_INTERPRET", True)
-    eng = _engine(model, params, paged_kernel=kernel, prefill_kernel=kernel)
-    assert eng.paged_kernel == ("pallas" if kernel == "on" else "xla")
-    got = _tapped(eng)
-    toks = _tokens(prompt, seed=5)
-    req = _serve(eng, toks, new)
-    seq = toks + list(req.out_tokens)
-    want = np.asarray(ref.forward_logits(weights, cfg, seq))
-    rows = sorted(got)
-    assert rows[-1] == prompt + new - 2 and prompt - 1 in rows
-    assert len(rows) == -(-prompt // CHUNK) + new - 1
-    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
-                               atol=LOGIT_TOL, rtol=0)
-    assert list(req.out_tokens) == [int(t) for t in
-                                    want[prompt - 1:-1].argmax(-1)]
+    forward: logits at every chunk's last row and every step, and the
+    state the slot is left with; the attention layers through the dense
+    gather and (``on``) through the walk's kernels in interpret mode."""
+    _family.chunked_prefill_then_decode_is_one_forward(
+        engines, "granite", prompt, new, kernel)
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_each_named_fault_fails_by_many_tolerances(family, fault):
+def test_each_named_fault_fails_by_many_tolerances(fault):
     """Each multiplier left at 1, the rotation left on, the score scale
     1/sqrt(d), ``D`` dropped, the gate moved behind the norm, an
     expert's ``silu`` on the other half, the bias or the shared MLP left
     out, a chunk's state not handed on, float8."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(70, seed=5)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    faulty = np.asarray(ref.forward_logits(weights, cfg, toks,
-                                           faults={fault}))
-    apart = np.abs(got - faulty).max(axis=-1)
-    assert apart[40:].max() > 100 * LOGIT_TOL, apart.max()
+    _family.a_named_fault_is_told("granite", fault)
 
 
 def test_the_rotation_left_on_in_the_program_fails(family):
@@ -220,115 +79,83 @@ def test_the_rotation_left_on_in_the_program_fails(family):
     model, params, ref, weights, cfg = family
     rotary = model.cfg.replace(
         position_embedding_type=PositionEmbeddingType.rotary)
-    toks = _tokens(70, seed=5)
+    toks = tokens(70, seed=5)
     got = np.asarray(language_model_forward(
         params, jnp.asarray([toks], jnp.int32), None, None, rotary)[0][0])
     want = np.asarray(ref.forward_logits(weights, cfg, toks))
     assert np.abs(got - want).max() > 100 * LOGIT_TOL
 
 
-def test_a_slot_is_reused_by_a_second_request(family):
+def test_a_slot_is_reused_by_a_second_request(engines):
     """A request of 150 + 6 tokens, then a short one in the same slot
     with no clearing launch: the second answers as a fresh engine does,
     logits and all."""
-    model, params = family[:2]
-    eng = _engine(model, params, num_slots=1)
-    _serve(eng, _tokens(150, seed=7), 6)
-    assert np.abs(np.asarray(eng._st.pages[0]["ssm_state"][0])).max() > 0
-    got = _tapped(eng)
-    prompt = _tokens(40, seed=8)
-    second = _serve(eng, prompt, 5)
-    fresh_eng = _engine(model, params, num_slots=1)
-    fresh = _tapped(fresh_eng)
-    again = _serve(fresh_eng, prompt, 5)
-    assert list(second.out_tokens) == list(again.out_tokens)
-    assert sorted(got) == sorted(fresh)
-    for t in got:
-        np.testing.assert_allclose(got[t], fresh[t], atol=1e-6, rtol=0)
+    _family.a_slot_is_reused(engines, "granite", **kernels("off"))
 
 
-def test_two_requests_decode_side_by_side(family):
+def test_two_requests_decode_side_by_side(family, engines):
     """Continuous batching over the state: two requests in two slots,
     one admitted while the other decodes, each as if alone."""
     model, params, ref, weights, cfg = family
-    eng = _engine(model, params)
-    a = eng.submit(_tokens(70, seed=1), SamplingParams(max_new_tokens=12,
+    eng = engines("granite", **kernels("off"))
+    a = eng.submit(tokens(70, seed=1), SamplingParams(max_new_tokens=12,
                                                       temperature=0.0))
     for _ in range(6):
         eng.step()
-    b = eng.submit(_tokens(37, seed=2), SamplingParams(max_new_tokens=8,
+    b = eng.submit(tokens(37, seed=2), SamplingParams(max_new_tokens=8,
                                                       temperature=0.0))
     while a.finish_reason is None or b.finish_reason is None:
         assert eng.step()
     for req, seed, n in ((a, 1, 70), (b, 2, 37)):
-        seq = _tokens(n, seed=seed) + list(req.out_tokens)
+        seq = tokens(n, seed=seed) + list(req.out_tokens)
         want = np.asarray(ref.forward_logits(weights, cfg, seq))
         assert list(req.out_tokens) == [int(t) for t in
                                         want[n - 1:-1].argmax(-1)]
 
 
-def _states_apart(eng, ref, weights, cfg, seq):
-    """Each state-space layer's state in slot 0 against the state the
-    reference's recurrence is left with by ``seq``: the root mean
-    square of the difference over the reference's."""
-    states = []
-    ref.forward_logits(weights, cfg, seq, rows=[len(seq) - 1], states=states)
-    mine = [np.asarray(p["ssm_state"][0], np.float32)
-            for p in eng._st.pages if paged_kv.is_state(p)]
-    assert len(mine) == len(states) == 6
-    return [float(np.linalg.norm(a - np.asarray(b))
-                  / np.linalg.norm(np.asarray(b)))
-            for a, b in zip(mine, states)]
-
-
 @pytest.mark.parametrize("prompt,new", [(70, 6), (150, 3)])
-def test_a_finished_requests_slot_holds_the_references_state(family, prompt,
+def test_a_finished_requests_slot_holds_the_references_state(engines, prompt,
                                                              new):
     """What the benchmark's probe reads: after a request of chunks (the
     last one padded) and steps, its slot holds the state the reference
     is left with by the prompt and every answer token but the last."""
-    model, params, ref, weights, cfg = family
-    eng = _engine(model, params, num_slots=1)
-    toks = _tokens(prompt, seed=9)
-    req = _serve(eng, toks, new)
-    apart = _states_apart(eng, ref, weights, cfg,
-                          toks + list(req.out_tokens)[:-1])
-    assert max(apart) < 1e-5, apart
+    eng = engines("granite", **kernels("off"))
+    toks = tokens(prompt, seed=9)
+    req = serve(eng, toks, new)
+    apart = _family.state_apart("granite", eng, req.slot,
+                                toks + list(req.out_tokens)[:-1])
+    assert len(apart) == 6 and max(apart) < 1e-5, apart
 
 
-def test_a_state_kept_in_bf16_is_not_the_references(family, monkeypatch):
+def test_a_state_kept_in_bf16_is_not_the_references(engines, monkeypatch):
     """The ASSUMPTION of a float32 state, from the other side: rounded
     to bf16 in its slot at every launch, the state stands hundreds of
     float32 tolerances from the reference's."""
-    model, params, ref, weights, cfg = family
     monkeypatch.setattr(paged_kv, "SSM_STATE_DTYPE", jnp.bfloat16)
-    eng = _engine(model, params, num_slots=1)
+    eng = engines.fresh("granite", num_slots=1)
     assert eng._st.pages[0]["ssm_state"].dtype == jnp.bfloat16
-    toks = _tokens(70, seed=9)
-    req = _serve(eng, toks, 6)
-    apart = _states_apart(eng, ref, weights, cfg,
-                          toks + list(req.out_tokens)[:-1])
+    toks = tokens(70, seed=9)
+    req = serve(eng, toks, 6)
+    apart = _family.state_apart("granite", eng, req.slot,
+                                toks + list(req.out_tokens)[:-1])
     assert min(apart) > 1e-3, apart
 
 
 @pytest.mark.parametrize("kernel", ["off", "on"])
-def test_the_engine_counts_what_its_state_space_layers_do(family, kernel,
-                                                          monkeypatch):
-    model, params = family[:2]
-    monkeypatch.setattr(pa, "_INTERPRET", kernel == "on")
-    eng = _engine(model, params, paged_kernel=kernel, prefill_kernel=kernel)
-    _serve(eng, _tokens(70, seed=5), 4)
-    s = eng.stats()
+def test_the_engine_counts_what_its_state_space_layers_do(engines, kernel):
+    eng = engines("granite", **kernels(kernel))
+    since = _family.counted(eng)
+    serve(eng, tokens(70, seed=5), 4)
+    s, records = since()
     # whose state a decode launch's program reads and writes: the step's
     # kernel the one live row's, the XLA step both slots' and the garbage
     # row's, a layer; a prefill launch counts none
     assert s["ssm_rows_moved"] == 3 * 6 * (1 if kernel == "on" else 2 + 1)
-    steps = [r for r in eng.loop_profiler.records() if r.kind == "decode"]
+    steps = [r for r in records if r.kind == "decode"]
     assert len(steps) == 3 and all(
         r.ssm_rows_moved == (r.ssm_rows_live if kernel == "on" else 18)
         for r in steps)
-    assert not any(r.ssm_rows_moved for r in eng.loop_profiler.records()
-                   if r.kind == "prefill")
+    assert not any(r.ssm_rows_moved for r in records if r.kind == "prefill")
     per_slot = 6 * (8 * 32 * 16 * 4 + 3 * (8 * 32 + 2 * 16) * 4)
     assert eng.blocks.stats()["state_bytes_per_slot"] == per_slot
     # 3 chunks and 3 steps, one live row each, six state-space layers
@@ -338,7 +165,7 @@ def test_the_engine_counts_what_its_state_space_layers_do(family, kernel,
     # experts 2-5 of the router's 8 are held: about half the assignments
     assert s["moe_assignments"] == 8 * 3 * (70 + 3)
     assert 0.3 < s["moe_assignments_held"] / s["moe_assignments"] < 0.7
-    rec = eng.loop_profiler.records()[-1]
+    rec = records[-1]
     assert rec.ssm_rows_live == 6 and rec.ssm_tokens == 6
     assert rec.moe_assignments_held <= rec.moe_assignments == 8 * 3
     assert "a prefix's recurrent state" in eng.blocks.cache_stats()[
@@ -346,11 +173,10 @@ def test_the_engine_counts_what_its_state_space_layers_do(family, kernel,
     assert s["prefill_tokens_cached"] == 0
 
 
-def test_the_page_programs_do_not_see_the_state(family):
+def test_the_page_programs_do_not_see_the_state(engines):
     """Copy-on-write and ``block_bytes`` run over the attention layers'
     pools only; the state's arrays have a role of their own."""
-    model, params = family[:2]
-    eng = _engine(model, params)
+    eng = engines("granite", **kernels("off"))
     pages = eng._st.pages
     assert [paged_kv.is_state(p) for p in pages] == [
         True, True, False, True] * 2
@@ -372,9 +198,8 @@ def test_the_page_programs_do_not_see_the_state(family):
 
 
 def test_the_programs_tables_give_the_state_a_role_and_the_mixer_scopes(
-        family):
-    model, params = family[:2]
-    eng = _engine(model, params)
+        engines):
+    eng = engines("granite", **kernels("off"))
     eng.warmup()
     tables = eng.program_tables()
     for name in ("engine_prefill", "engine_decode"):
@@ -389,14 +214,14 @@ def test_the_programs_tables_give_the_state_a_role_and_the_mixer_scopes(
 
 
 def test_what_state_space_layers_do_not_support_is_refused_by_name(
-        family, monkeypatch):
+        family, engines, monkeypatch):
     model, params = family[:2]
     for kw, what in ((dict(preemption=True), "preemption"),
                      (dict(int8_kv_cache=True), "int8 KV pool"),
                      (dict(speculative=True, draft_k=2), "speculative"),
                      (dict(host_cache_bytes=1 << 20), "host KV tier")):
         with pytest.raises(ValueError, match="state-space.*" + what):
-            _engine(model, params, max_model_len=32, **kw)
+            engines.fresh("granite", max_model_len=32, **kw)
     with pytest.raises(ValueError, match="int8 KV pool"):
         paged_kv.init_pools(model.cfg, 4, BS, quantized=True, num_slots=2)
     with pytest.raises(ValueError, match="num_slots"):
@@ -406,7 +231,7 @@ def test_what_state_space_layers_do_not_support_is_refused_by_name(
     with pytest.raises(ValueError, match="adopts no prefix"):
         BlockManager(8, BS, 2, 4, prefix_cache=True,
                      state_bytes_per_slot=1024)
-    toks = jnp.asarray([_tokens(16)], jnp.int32)
+    toks = jnp.asarray([tokens(16)], jnp.int32)
     with pytest.raises(NotImplementedError, match="training"):
         model(params, toks, train=True)
     with pytest.raises(NotImplementedError, match="attention mask"):
@@ -504,80 +329,3 @@ def test_the_flags_lower_into_the_config():
     assert (cfg.attention_multiplier, cfg.embedding_multiplier,
             cfg.residual_multiplier, cfg.logits_scaling) == (
                 0.03125, 12.0, 0.22, 16.0)
-
-
-# ---------------------------------------------------------------------------
-# the standing families' programs
-# ---------------------------------------------------------------------------
-
-# What ``tests/_program_fingerprints.py`` prints since PR 42, which MEANT
-# to change every family's decode step and nothing of a chunk
-# (``engine_prefill`` is PR 41's hash in every family): the step takes
-# the key chain with the keys the host gave since the last launch and
-# the rows they are for, and gives the chain back advanced only for the
-# slots that decoded (``InferenceEngine._split_keys``: two selects over
-# [S, 2] words).  PR 41 had changed both programs (the cache's write
-# keeps the pool's own shape).  A PR that MEANS to change a family's
-# program runs the script and records what it prints here.
-TRACED = {
-    "mistral": {"engine_prefill": "97f7c403d8d97874",
-                "engine_decode": "1271bc7d18f7d88f"},
-    # PR 46 MEANT both programs of the seven sparse families and nothing
-    # else (the dense family's are PR 44's): the dropless layer's combine
-    # gathers the experts' rows once, in their own dtype, and adds the
-    # choices in turn under the gates (``models/moe.py``, scope
-    # ``moe_combine``)
-    "mixtral": {"engine_prefill": "0c7d499804bf34eb",
-                "engine_decode": "d108ab43dbc7ce8b"},
-    "olmoe": {"engine_prefill": "8cd66b78f8c91346",
-              "engine_decode": "971de090d62b6706"},
-    "keye": {"engine_prefill": "e0d8c3ceab2845ae",
-             "engine_decode": "3c0e2663546ddcad"},
-    "mellum": {"engine_prefill": "8f4a67e3658e0723",
-               "engine_decode": "1ac80699ca25a3e1"},
-    "kanana": {"engine_prefill": "d425b4f959dbedb1",
-               "engine_decode": "9dbbd4c35a3d9c06"},
-    "granite": {"engine_prefill": "b0d8abbf1f607c6b",
-                "engine_decode": "76573d9f960581e5"},
-    "nemotron_h": {"engine_prefill": "30c8c54ee38f7897",
-                   "engine_decode": "b085bbfea1bc2bd2"},
-    # PR 47 brought this family and changed no other's: the gate, the
-    # output norms and the types that rotate are off for every other
-    # model, whose programs are the ones above
-    "trinity": {"engine_prefill": "0c33c9c2556bb82a",
-                "engine_decode": "a30d0d0cca6cdb4d"},
-    # PR 51 brought this family and changed no other's: the state group's
-    # arrays by the layer's kind, the router's normaliser as data and the
-    # pool's two-heads-a-row layout at 64-wide heads leave every program
-    # above as it was
-    "lfm2": {"engine_prefill": "c08e0b51f0aec84b",
-             "engine_decode": "913f73debd40aff4"},
-    # PR 54 brought this family and changed no other's: the queries, keys
-    # and values of an attention layer come from ``qkv_heads`` now, which
-    # a retention layer calls too, the same operations in the same order;
-    # a paged model's chunk is still lent its pool
-    "brumby": {"engine_prefill": "9c113f3ea93d1713",
-               "engine_decode": "d8a3caf89969587f"},
-}
-
-
-@pytest.fixture(scope="module")
-def traced():
-    import json
-    import subprocess
-    import sys
-
-    proc = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(
-            os.path.abspath(__file__)), "_program_fingerprints.py")],
-        capture_output=True, text=True, timeout=280)
-    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
-    assert proc.returncode == 0 and lines, proc.stderr[-2000:]
-    return json.loads(lines[-1])
-
-
-@pytest.mark.parametrize("name", sorted(TRACED))
-def test_a_standing_family_traces_the_program_it_traced_before(traced, name):
-    assert traced[name] == TRACED[name], (
-        f"{name}'s engine programs are not the recorded ones: if that was "
-        "meant, record tests/_program_fingerprints.py's output in TRACED")

@@ -13,174 +13,55 @@ and its dense fallback attend in the absorbed form, its chunk on the
 kernel path in the expanded one, inside the kernel.
 """
 
-import importlib.util
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _family
+from _family import BS, is_greedy, kernels, serve, tokens
 from megatron_llm_tpu.models import moe
 from megatron_llm_tpu.models import transformer as tfm
 from megatron_llm_tpu.models.kanana import KananaModel, kanana_config
-from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.ops.pallas import paged_attention as pa
-from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                      SamplingParams)
-
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
 
 # float32 on both sides, the same mathematics summed in another order
 # (and in another FORM: absorbed against expanded); every named fault
 # moves the logits by whole tenths
-LOGIT_TOL = 2e-4
-BS, CHUNK = 8, 16
+LOGIT_TOL = _family.FAMILIES["kanana"].tol
 FAULTS = ("softmax_router", "bias_left_out", "bias_in_gates", "no_scale",
           "no_shared", "dense_layer_sparse", "no_latent_norm",
           "scale_sqrt_nope", "rope_key_per_head", "rope_whole_head",
           "float8")
 
 
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _ref_cfg(cfg):
-    return {"num_hidden_layers": cfg.num_layers,
-            "num_attention_heads": cfg.num_attention_heads,
-            "rms_norm_eps": cfg.layernorm_epsilon,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "norm_topk_prob": cfg.norm_topk_prob,
-            "vocab_size": cfg.padded_vocab_size,
-            "first_k_dense_replace": cfg.moe_first_dense_layers,
-            "qk_nope_head_dim": cfg.qk_nope_head_dim,
-            "qk_rope_head_dim": cfg.qk_rope_head_dim,
-            "v_head_dim": cfg.v_head_dim,
-            "rope_theta": cfg.rope_theta,
-            "routed_scaling_factor": cfg.moe_routed_scale}
-
-
-def _shake(params, key):
-    """Seeded N(0, 0.02) weights make attention nearly uniform and every
-    norm's scale is 1 at init: larger projections and scales that differ
-    (``tests/test_mellum.py::_shake`` says why)."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        names = [getattr(p, "key", None) for p in path]
-        if "scale" in names:
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
-        elif {"kernel", "w_in", "w_out"} & set(names):
-            leaf = leaf * (2.0 if "router" in names else 6.0)
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, out)
-
-
 @pytest.fixture(scope="module")
 def family():
-    model = KananaModel(kanana_config("tiny", use_flash_attn=False))
-    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-    cfg = _ref_cfg(model.cfg)
-    weights = _load("kanana_from_program").ProgramWeights(params, cfg)
-    return model, params, _load("kanana"), weights, cfg
-
-
-def _tokens(n, seed=3, vocab=512):
-    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
-
-
-def _engine(model, params, **kw):
-    # a long deadline, not the default 120 s: a request served through an
-    # interpreted kernel must not expire by the wall clock of a loaded
-    # machine, and a hang must still fail
-    kw = dict(dict(num_slots=2, block_size=BS, max_model_len=192,
-                   prefill_chunk=CHUNK, default_deadline_secs=600.0), **kw)
-    return InferenceEngine(model, params, EngineConfig(**kw))
-
-
-def _serve(eng, prompt, new):
-    req = eng.submit(prompt, SamplingParams(max_new_tokens=new,
-                                            temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-    return req
+    return _family.built("kanana")
 
 
 @pytest.mark.parametrize("n", [5, 16, 17, 70])
-def test_full_forward_matches_the_reference(family, n):
+def test_full_forward_matches_the_reference(n):
     """The program's plain (cache-less) forward, the EXPANDED form, the
     dense layer before a scan over the sparse ones: logits at every
     position against the reference."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(n)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    assert want.std() > 0.1
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
-
-
-def _tapped(eng):
-    """The engine's programs with their logits kept
-    (``tests/test_mellum.py::_tapped``)."""
-    got = {}
-    prefill, decode = eng._prefill_step, eng._decode_step
-
-    def tapped_prefill(params, pages, tokens, start, valid, table):
-        out = prefill(params, pages, tokens, start, valid, table)
-        got[int(start) + int(valid) - 1] = np.asarray(out[0])
-        return out
-
-    def tapped_decode(params, pages, last, ctx, tables, active, *rest):
-        caches = paged_kv.step_caches(pages, tables, ctx, active,
-                                      eng.paged_kernel)
-        logits, _ = language_model_forward(
-            params, last[:, None], ctx[:, None], None, eng.model.cfg,
-            rng_key=None, train=False, kv_caches=caches)
-        for s in np.flatnonzero(np.asarray(active) > 0):
-            got[int(np.asarray(ctx)[s])] = np.asarray(logits[s, 0])
-        return decode(params, pages, last, ctx, tables, active, *rest)
-
-    eng._prefill_step, eng._decode_step = tapped_prefill, tapped_decode
-    return got
+    _family.full_forward_is_the_references("kanana", n)
 
 
 @pytest.mark.parametrize("prompt,new,kernel", [
     (5, 14, "off"), (64, 10, "off"), (150, 6, "off"), (45, 5, "on")])
 def test_the_engine_over_the_latent_pool_matches_one_full_forward(
-        family, prompt, new, kernel, monkeypatch):
+        engines, prompt, new, kernel):
     """Chunked prefill then decode through the engine's own programs over
     the latent pool, the ABSORBED form, against the reference's ONE full
     forward in the expanded form: contexts of a page to twenty pages and
     one to ten chunks, through the dense gather and (``on``) through the
-    walk's kernels in interpret mode."""
-    model, params, ref, weights, cfg = family
-    if kernel == "on":
-        monkeypatch.setattr(pa, "_INTERPRET", True)
-    eng = _engine(model, params, paged_kernel=kernel, prefill_kernel=kernel)
-    assert eng.paged_kernel == ("pallas" if kernel == "on" else "xla")
-    got = _tapped(eng)
-    toks = _tokens(prompt, seed=5)
-    req = _serve(eng, toks, new)
-    seq = toks + list(req.out_tokens)
-    want = np.asarray(ref.forward_logits(weights, cfg, seq))
-    rows = sorted(got)
-    assert rows[-1] == prompt + new - 2 and prompt - 1 in rows
-    assert len(rows) == -(-prompt // CHUNK) + new - 1
-    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
-                               atol=LOGIT_TOL, rtol=0)
-    # greedy: the engine's tokens are the reference's choices
-    assert list(req.out_tokens) == [int(t) for t in
-                                    want[prompt - 1:-1].argmax(-1)]
+    walk's kernels in interpret mode.  The engine is the module's and its
+    prefix cache is on: a seed a prompt, or a later case would adopt an
+    earlier one's pages and compute fewer chunks than it counts."""
+    _family.chunked_prefill_then_decode_is_one_forward(
+        engines, "kanana", prompt, new, kernel, seed=prompt)
 
 
 def test_absorbed_and_expanded_agree_on_one_layer(family):
@@ -212,22 +93,15 @@ def test_absorbed_and_expanded_agree_on_one_layer(family):
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_each_named_fault_fails_by_many_tolerances(family, fault):
-    model, params, ref, weights, cfg = family
-    toks = _tokens(70, seed=5)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    faulty = np.asarray(ref.forward_logits(weights, cfg, toks,
-                                           faults={fault}))
-    apart = np.abs(got - faulty).max(axis=-1)
-    assert apart[8:].max() > 100 * LOGIT_TOL, apart.max()
+def test_each_named_fault_fails_by_many_tolerances(fault):
+    _family.a_named_fault_is_told("kanana", fault, beyond=8)
 
 
 def test_the_drawn_bias_turns_more_than_one_choice_in_ten(family):
     """A fresh model's choice bias is drawn wide enough that leaving it
     out turns a choice for more than one token in ten, a layer."""
     model, params, ref, weights, cfg = family
-    toks = _tokens(150, seed=9)
+    toks = tokens(150, seed=9)
     with_bias, without = [], []
     ref.forward_logits(weights, cfg, toks, routing=with_bias)
     ref.forward_logits(weights, cfg, toks, routing=without,
@@ -239,21 +113,12 @@ def test_the_drawn_bias_turns_more_than_one_choice_in_ten(family):
     assert turned.mean() > 0.1, turned.mean()
 
 
-def test_a_slot_is_reused_after_a_long_request(family):
+def test_a_slot_is_reused_after_a_long_request(engines):
     """A request of 150 + 6 tokens, then a short one in the same slot:
     the second answers as the plain forward does, over pages the first
     filled with other latents."""
-    model, params = family[:2]
-    eng = _engine(model, params, num_slots=1, prefix_cache=False)
-    _serve(eng, _tokens(150, seed=7), 6)
-    assert eng.stats()["blocks_in_use"] == 0
-    prompt = _tokens(40, seed=8)
-    second = _serve(eng, prompt, 5)
-    toks = list(prompt)
-    for _ in range(5):
-        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    assert toks[len(prompt):] == list(second.out_tokens)
+    _family.a_slot_is_reused(engines, "kanana", prefix_cache=False,
+                             **kernels("off"))
 
 
 def test_page_programs_carry_a_latent_page(family):
@@ -273,31 +138,26 @@ def test_page_programs_carry_a_latent_page(family):
         assert (np.asarray(loaded[layer]["latent_pages"][5]) == src).all()
 
 
-def test_a_prefix_is_adopted_and_a_shared_page_copied_on_write(family):
+def test_a_prefix_is_adopted_and_a_shared_page_copied_on_write(family,
+                                                               engines):
     """The prefix cache carries over unchanged: a second request with the
     first one's prompt adopts its latent pages and answers alike; a
     third that shares all but its last token writes into a shared page's
     copy (copy-on-write) and answers as the plain forward does."""
-    model, params = family[:2]
-    eng = _engine(model, params, max_model_len=96)
-    prompt = _tokens(41, seed=11)
-    first = list(_serve(eng, prompt, 6).out_tokens)
-    assert list(_serve(eng, prompt, 6).out_tokens) == first
+    eng = engines.fresh("kanana", max_model_len=96)
+    prompt = tokens(41, seed=11)
+    first = list(serve(eng, prompt, 6).out_tokens)
+    assert list(serve(eng, prompt, 6).out_tokens) == first
     stats = eng.stats()
     assert stats["prefill_tokens_cached"] >= 32
     other = prompt[:40] + [(prompt[40] + 1) % 500 + 1]
-    third = list(_serve(eng, other, 4).out_tokens)
-    toks = list(other)
-    for _ in range(4):
-        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    assert toks[len(other):] == third
+    assert is_greedy(*family[:2], other, serve(eng, other, 4).out_tokens)
     assert eng.stats()["prefill_tokens_cached"] > stats[
         "prefill_tokens_cached"]
 
 
 def test_a_leading_dense_layer_scans_serves_and_counts_sparse_layers_only(
-        family):
+        family, engines):
     """The stack keeps the dense layer's parameters apart; the plain
     forward scans the SPARSE layers (one scan of L - 1 steps); the
     engine's routing record and counters have a row a sparse layer."""
@@ -320,18 +180,19 @@ def test_a_leading_dense_layer_scans_serves_and_counts_sparse_layers_only(
         jax.tree_util.tree_map(lambda a: 0, params))
         == jax.tree_util.tree_structure(jax.tree_util.tree_map(
             lambda s: 0, specs, is_leaf=lambda s: isinstance(s, tuple))))
-    eng = _engine(model, params, prefix_cache=False)
-    _serve(eng, _tokens(20, seed=4), 3)
-    records = eng.loop_profiler.records()
+    eng = engines("kanana", prefix_cache=False, **kernels("off"))
+    since = _family.counted(eng)
+    serve(eng, tokens(20, seed=4), 3)
+    stats, records = since()
     for r in records:
         assert r.moe_expert_slots == 2 * cfg.num_experts
     # 20 prompt tokens and 2 decode steps, 3 experts a token, 2 layers
-    assert eng.stats()["moe_assignments"] == (20 + 2) * 3 * 2
+    assert stats["moe_assignments"] == (20 + 2) * 3 * 2
 
 
 @pytest.mark.parametrize("kernel", ["off", "on"])
-def test_the_engine_counts_latent_attentions_keys_and_pairs(family, kernel,
-                                                            monkeypatch):
+def test_the_engine_counts_latent_attentions_keys_and_pairs(family, engines,
+                                                            kernel):
     """``mla_keys_live`` on a decode launch's record (each live row's
     context and itself), ``mla_pairs`` on a chunk's (for each live query
     the keys it sees), ``mla_latents_expanded`` on a chunk's that the
@@ -340,13 +201,11 @@ def test_the_engine_counts_latent_attentions_keys_and_pairs(family, kernel,
     summed over the layers, from the arrays the host hands the program."""
     model, params = family[:2]
     L = model.cfg.num_layers
-    if kernel == "on":
-        monkeypatch.setattr(pa, "_INTERPRET", True)
-    eng = _engine(model, params, prefix_cache=False, paged_kernel=kernel,
-                  prefill_kernel=kernel)
+    eng = engines("kanana", prefix_cache=False, **kernels(kernel))
     assert pa.kernel_available() == (kernel == "on")
-    _serve(eng, _tokens(20, seed=4), 4)
-    records = eng.loop_profiler.records()
+    since = _family.counted(eng)
+    serve(eng, tokens(20, seed=4), 4)
+    stats, records = since()
     chunks = [r for r in records if r.kind == "prefill"]
     steps = [r for r in records if r.kind == "decode"]
     assert [r.mla_pairs for r in chunks] == [
@@ -357,7 +216,6 @@ def test_the_engine_counts_latent_attentions_keys_and_pairs(family, kernel,
     expanded = [L * 16, L * 20] if kernel == "on" else [0, 0]
     assert [r.mla_latents_expanded for r in chunks] == expanded
     assert all(r.mla_latents_expanded == 0 for r in steps)
-    stats = eng.stats()
     assert stats["mla_pairs"] == L * sum(range(1, 21))
     assert stats["mla_keys_live"] == L * 66
     assert stats["mla_latents_expanded"] == sum(expanded)
@@ -375,10 +233,10 @@ def test_a_model_without_a_latent_pool_expands_nothing(monkeypatch):
         "tiny", num_layers=2, hidden_size=64, num_attention_heads=4,
         seq_length=64, max_position_embeddings=64, padded_vocab_size=512,
         use_flash_attn=False))
-    eng = _engine(model, model.init(jax.random.PRNGKey(0)),
-                  max_model_len=64)
+    eng = _family.engine(model, model.init(jax.random.PRNGKey(0)),
+                         prefill_chunk=16, max_model_len=64)
     assert eng.prefill_kernel == "pallas"
-    _serve(eng, _tokens(20, seed=4), 3)
+    serve(eng, tokens(20, seed=4), 3)
     assert {f: eng.stats()[f] for f in
             ("mla_keys_live", "mla_pairs", "mla_latents_expanded")} == {
         "mla_keys_live": 0, "mla_pairs": 0, "mla_latents_expanded": 0}
@@ -387,22 +245,20 @@ def test_a_model_without_a_latent_pool_expands_nothing(monkeypatch):
 
 
 def test_a_chunk_of_several_rows_over_adopted_pages_is_the_plain_forward(
-        family, monkeypatch):
+        family, engines):
     """The expanded kernel in the engine's own chunk program, over a
     context it did not write in this request: a second request adopts
     the first one's latent pages (32 tokens of a shared prefix) and its
     chunks, of 16 and of 7 live rows, start on top of them; the logits
     of each chunk's last row are the cache-less forward's."""
     model, params, ref, weights, cfg = family
-    monkeypatch.setattr(pa, "_INTERPRET", True)
-    eng = _engine(model, params, max_model_len=96, paged_kernel="on",
-                  prefill_kernel="on")
-    shared = _tokens(36, seed=13)
-    _serve(eng, shared + _tokens(5, seed=14), 2)
-    got = _tapped(eng)
-    toks = shared + _tokens(19, seed=15)
+    eng = engines.fresh("kanana", max_model_len=96, **kernels("on"))
+    shared = tokens(36, seed=13)
+    serve(eng, shared + tokens(5, seed=14), 2)
+    got = engines.tapped(eng)
+    toks = shared + tokens(19, seed=15)
     before = eng.stats()["mla_latents_expanded"]
-    req = _serve(eng, toks, 3)
+    req = serve(eng, toks, 3)
     assert req.cached_prompt_tokens == 32
     chunks = [r for r in eng.loop_profiler.records()
               if r.kind == "prefill" and r.requests == (req.id,)]
@@ -429,7 +285,7 @@ def test_a_token_holds_at_most_1280_bytes_a_layer_at_the_published_widths():
 
 
 def test_what_a_latent_pool_does_not_support_is_refused_by_name(
-        family, monkeypatch):
+        family, engines, monkeypatch):
     model, params = family[:2]
     with pytest.raises(ValueError, match="int8 KV pool"):
         paged_kv.init_pools(model.cfg, 4, BS, quantized=True)
@@ -437,7 +293,7 @@ def test_what_a_latent_pool_does_not_support_is_refused_by_name(
                      (dict(speculative=True, draft_k=2), "speculative"),
                      (dict(host_cache_bytes=1 << 20), "host KV tier")):
         with pytest.raises(ValueError, match=what):
-            _engine(model, params, max_model_len=32, **kw)
+            engines.fresh("kanana", max_model_len=32, **kw)
     with pytest.raises(ValueError, match="q_lora_rank"):
         kanana_config("tiny", q_lora_rank=64)
     with pytest.raises(ValueError, match="group-limited"):

@@ -10,7 +10,7 @@ reference is the file the benchmark's probe loads
 (``benchmarks/reference/keye.py``), loaded here by path.
 """
 
-import importlib.util
+import functools
 import os
 
 import jax
@@ -18,88 +18,35 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _family
+from _family import BS, is_greedy, serve, tokens
 from megatron_llm_tpu.models import transformer as tfm
 from megatron_llm_tpu.models.keye import KeyeModel, keye_config
 from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.ops import dsa, paged_kv
 
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
-
 # float32 on both sides, the same mathematics summed in another order:
-# the logits (standard deviation 1.4 with the weights scaled below) read
-# 5e-6 apart at the worst position.  2e-4 leaves room for another
-# backend's order of summation; dense attention in the selection's place
-# moves them by whole units.
-LOGIT_TOL = 2e-4
+# the logits (standard deviation 1.4 with the weights scaled as the row
+# says) read 5e-6 apart at the worst position.  2e-4 leaves room for
+# another backend's order of summation; dense attention in the
+# selection's place moves them by whole units.
+ROW = _family.FAMILIES["keye"]
+LOGIT_TOL, CHUNK = ROW.tol, ROW.chunk
 TOPK = 8
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _ref_cfg(cfg):
-    return {"num_hidden_layers": cfg.num_layers,
-            "num_attention_heads": cfg.num_attention_heads,
-            "num_key_value_heads": cfg.num_attention_heads_kv,
-            "rope_theta": cfg.rope_theta,
-            "rms_norm_eps": cfg.layernorm_epsilon,
-            "num_local_experts": cfg.num_experts,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "vocab_size": cfg.padded_vocab_size,
-            "sa_config": {"topk": cfg.dsa_topk},
-            "rope_scaling": {"mrope_section": list(cfg.rope_sections)}}
-
-
-def _shake(params, key):
-    """Seeded N(0, 0.02) weights make attention nearly uniform and every
-    norm's scale is 1 at init: a test that must tell selected keys from
-    all keys, a norm a head from one over the projection, or a relabelled
-    scale from one left in place needs larger projections and scales
-    (and the LayerNorm's bias) that differ."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        names = [getattr(p, "key", None) for p in path]
-        if "scale" in names or "bias" in names:
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
-        elif "kernel" in names:
-            leaf = leaf * 6.0
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, out)
+M = 12
 
 
 @pytest.fixture(scope="module")
 def family():
-    model = KeyeModel(keye_config("tiny", use_flash_attn=False))
-    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-    cfg = _ref_cfg(model.cfg)
-    weights = _load("keye_from_program").ProgramWeights(params, cfg)
-    return model, params, _load("keye"), weights, cfg
-
-
-def _tokens(n, seed=3, vocab=512):
-    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
+    return _family.built("keye")
 
 
 @pytest.mark.parametrize("n", [5, 8, 9, 70])
-def test_full_forward_matches_the_reference(family, n):
+def test_full_forward_matches_the_reference(n):
     """The program's plain (cache-less) forward selects too: logits at
     every position against the reference, at contexts under the top-k
     (5), at it (8), one past it (9) and far past it (70)."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(n)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    assert want.std() > 0.1
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+    _family.full_forward_is_the_references("keye", n)
 
 
 def test_the_reference_reports_its_experts_and_takes_given_ones(family):
@@ -108,7 +55,7 @@ def test_the_reference_reports_its_experts_and_takes_given_ones(family):
     first rejected one at one position is the ``turned`` choice there, and
     moves that position's logits and no earlier one's."""
     _, _, ref, weights, cfg = family
-    toks = _tokens(20)
+    toks = tokens(20)
     routed, margins = [], []
     want = np.asarray(ref.forward_logits(weights, cfg, toks, routing=routed,
                                          router_margins=margins))
@@ -137,19 +84,25 @@ def test_the_reference_reports_its_experts_and_takes_given_ones(family):
     np.testing.assert_allclose(turned[:t], want[:t], atol=1e-6, rtol=0)
 
 
-BS, M, CHUNK = 8, 12, 16
+@functools.lru_cache(maxsize=None)
+def _paged_forward(model, kernel):
+    """A launch through the paged pool as one program a shape (run
+    eagerly it is compiled an operation at a time)."""
+    def forward(params, pages, toks, start, valid, bt):
+        caches = paged_kv.step_caches(pages, bt, start, valid, kernel)
+        positions = start[:, None] + jnp.arange(toks.shape[1])[None]
+        logits, caches = language_model_forward(
+            params, toks, positions, None, model.cfg, rng_key=None,
+            train=False, kv_caches=caches)
+        return logits, paged_kv.pools_of(caches)
+    return jax.jit(forward)
 
 
 def _paged_step(model, params, pages, toks, start, valid, bt, kernel="xla"):
-    S, n = toks.shape
-    caches = paged_kv.step_caches(
-        pages, bt, jnp.asarray(start, jnp.int32),
-        jnp.asarray(valid, jnp.int32), kernel)
-    positions = jnp.asarray(start, jnp.int32)[:, None] + jnp.arange(n)[None]
-    logits, caches = language_model_forward(
-        params, jnp.asarray(toks, jnp.int32), positions, None, model.cfg,
-        rng_key=None, train=False, kv_caches=caches)
-    return np.asarray(logits), paged_kv.pools_of(caches)
+    logits, pages = _paged_forward(model, kernel)(
+        params, pages, jnp.asarray(toks, jnp.int32),
+        jnp.asarray(start, jnp.int32), jnp.asarray(valid, jnp.int32), bt)
+    return np.asarray(logits), pages
 
 
 def _prefill_then_decode(model, params, toks, prompt, kernel="xla"):
@@ -183,7 +136,7 @@ def test_chunked_prefill_then_decode_matches_one_full_forward(
     under the top-k whose decode steps cross it (5 -> 12), a prompt whose
     first chunk straddles it (37), a long one (67)."""
     model, params, ref, weights, cfg = family
-    toks = _tokens(total, seed=5)
+    toks = tokens(total, seed=5)
     want = np.asarray(ref.forward_logits(weights, cfg, toks))
     got = _prefill_then_decode(model, params, toks, prompt)
     np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
@@ -194,7 +147,7 @@ def test_dense_attention_in_the_selections_place_fails(family):
     reference's ``dense`` fault) is hundreds of tolerances apart once the
     context passes the top-k, and equal before it."""
     model, params, ref, weights, cfg = family
-    toks = _tokens(40, seed=5)
+    toks = tokens(40, seed=5)
     got = _prefill_then_decode(model, params, toks, 37)
     dense = np.asarray(ref.forward_logits(weights, cfg, toks,
                                           faults={"dense"}))
@@ -280,64 +233,44 @@ def test_page_programs_carry_the_indexers_keys(family):
             assert (np.asarray(page[layer][name]) == src).all()
 
 
-def test_a_prefix_cache_adoption_carries_the_indexers_keys(family):
+def test_a_prefix_cache_adoption_carries_the_indexers_keys(family, engines):
     """Through the engine: a second request with the first one's prompt
     adopts its pages (the prefix cache), indexer keys and all, and
     answers with the same tokens as the first, which prefilled them."""
-    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                          SamplingParams)
-
-    model, params = family[:2]
-    eng = InferenceEngine(model, params, EngineConfig(
-        num_slots=2, block_size=8, max_model_len=96, prefill_chunk=16))
-    prompt = _tokens(41, seed=11)
-    answers = []
-    for _ in range(2):
-        req = eng.submit(prompt, SamplingParams(max_new_tokens=6,
-                                                temperature=0.0))
-        while req.finish_reason is None:
-            assert eng.step()
-        answers.append(list(req.out_tokens))
+    eng = engines.fresh("keye")
+    prompt = tokens(41, seed=11)
+    answers = [list(serve(eng, prompt, 6).out_tokens) for _ in range(2)]
     assert eng.stats()["prefill_tokens_cached"] >= 32
     assert answers[0] == answers[1]
     # and the engine's answer is the plain forward's greedy continuation
-    toks = list(prompt)
-    for _ in range(6):
-        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    assert toks[len(prompt):] == answers[0]
+    assert is_greedy(*family[:2], prompt, answers[0])
 
 
-def test_engine_counts_the_keys_each_query_sees_and_selects(family):
+COUNTS = dict(num_slots=4, max_model_len=64, prefix_cache=False)
+
+
+def test_engine_counts_the_keys_each_query_sees_and_selects(family, engines):
     """``dsa_keys_live`` / ``dsa_keys_selected`` on every launch record:
     the host's count, from the arrays it hands the program, of the
     context each live query sees, summed over rows and layers, and the
     same with each term cut at the top-k; ``stats()`` keeps the totals."""
-    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                          SamplingParams)
-
-    model, params = family[:2]
-    L = model.cfg.num_layers
-    eng = InferenceEngine(model, params, EngineConfig(
-        num_slots=4, block_size=8, max_model_len=64, prefill_chunk=16,
-        prefix_cache=False))
-    req = eng.submit(_tokens(21, seed=9),
-                     SamplingParams(max_new_tokens=3, temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-    records = eng.loop_profiler.records()
+    L = family.model.cfg.num_layers
+    eng = engines("keye", **COUNTS)
+    since = _family.counted(eng)
+    serve(eng, tokens(21, seed=9), 3)
+    stats, records = since()
     assert [r.kind for r in records] == ["prefill"] * 2 + ["decode"] * 2
     sees = [range(1, 17), range(17, 22), [22], [23]]
     for r, seen in zip(records, sees):
         assert r.dsa_keys_live == L * sum(seen)
         assert r.dsa_keys_selected == L * sum(min(t, TOPK) for t in seen)
-    stats = eng.stats()
     assert stats["dsa_keys_live"] == sum(r.dsa_keys_live for r in records)
     assert stats["dsa_keys_selected"] == sum(r.dsa_keys_selected
                                              for r in records)
 
 
-def test_engine_counts_the_blocks_the_choice_counts_over(family, monkeypatch):
+def test_engine_counts_the_blocks_the_choice_counts_over(family, engines,
+                                                        monkeypatch):
     """``dsa_select_blocks_counted`` / ``dsa_select_blocks_table`` on every
     launch record: for each select step of the launch the blocks of keys
     it is given (``ops/pallas/dsa_attention.py::select_blocks``: a chunk's
@@ -347,20 +280,13 @@ def test_engine_counts_the_blocks_the_choice_counts_over(family, monkeypatch):
     ``stats()``.  Blocks of 16 keys here, so a table of 64 tokens is 4."""
     from megatron_llm_tpu.ops.pallas import dsa_attention
     from megatron_llm_tpu.ops.pallas import paged_attention as pa
-    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                          SamplingParams)
 
+    # the plan takes its blocks of keys at construction: a new engine
     monkeypatch.setattr(pa, "_BLOCK_TOKENS", 16)
-    model, params = family[:2]
-    L = model.cfg.num_layers
-    eng = InferenceEngine(model, params, EngineConfig(
-        num_slots=4, block_size=8, max_model_len=64, prefill_chunk=16,
-        prefix_cache=False))
+    L = family.model.cfg.num_layers
+    eng = engines.fresh("keye", **COUNTS)
     assert eng._cache.dsa_block_keys == 16
-    req = eng.submit(_tokens(40, seed=9),
-                     SamplingParams(max_new_tokens=3, temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
+    serve(eng, tokens(40, seed=9), 3)
     records = eng.loop_profiler.records()
     assert [r.kind for r in records] == ["prefill"] * 3 + ["decode"] * 2
     # chunks (0, 16), (16, 16), (32, 8): one select step each (16 queries
@@ -386,17 +312,11 @@ def test_engine_counts_the_blocks_the_choice_counts_over(family, monkeypatch):
 
 def test_a_model_that_selects_nothing_counts_no_blocks():
     from megatron_llm_tpu.models.llama import LlamaModel, llama_config
-    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                          SamplingParams)
 
     model = LlamaModel(llama_config("tiny", use_flash_attn=False))
-    eng = InferenceEngine(model, model.init(jax.random.PRNGKey(0)),
-                          EngineConfig(num_slots=2, block_size=8,
-                                       max_model_len=32, prefill_chunk=16))
-    req = eng.submit(_tokens(9, seed=2, vocab=model.cfg.padded_vocab_size),
-                     SamplingParams(max_new_tokens=2, temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
+    eng = _family.engine(model, model.init(jax.random.PRNGKey(0)),
+                         max_model_len=32, prefill_chunk=CHUNK)
+    serve(eng, tokens(9, seed=2, vocab=model.cfg.padded_vocab_size), 2)
     for r in eng.loop_profiler.records():
         assert (r.dsa_select_blocks_counted, r.dsa_select_blocks_table,
                 r.dsa_keys_live) == (0, 0, 0)
@@ -418,7 +338,7 @@ def test_the_benchmarks_counted_share_reads_the_records_two_fields(
 
     from megatron_llm_tpu.serving.loop_profiler import DispatchRecord
 
-    bench = os.path.dirname(REFERENCE)
+    bench = os.path.dirname(_family.REFERENCE)
     with open(os.path.join(bench, "layer_metrics",
                            "dsa_select_counted_pct.json")) as f:
         metric = json.load(f)
@@ -462,20 +382,15 @@ def test_the_benchmarks_counted_share_reads_the_records_two_fields(
     assert ratio.sums([rec, parents], *fields) is None
 
 
-def test_what_the_selection_does_not_support_is_refused_by_name(family):
-    from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
-
-    model, params = family[:2]
+def test_what_the_selection_does_not_support_is_refused_by_name(family,
+                                                                engines):
+    model = family.model
     with pytest.raises(ValueError, match="int8 KV pool"):
         paged_kv.init_pools(model.cfg, 4, BS, quantized=True)
     with pytest.raises(ValueError, match="int8 KV pool"):
-        InferenceEngine(model, params, EngineConfig(
-            num_slots=2, block_size=8, max_model_len=32, prefill_chunk=16,
-            int8_kv_cache=True))
+        engines.fresh("keye", max_model_len=32, int8_kv_cache=True)
     with pytest.raises(ValueError, match="speculative"):
-        InferenceEngine(model, params, EngineConfig(
-            num_slots=2, block_size=8, max_model_len=32, prefill_chunk=16,
-            speculative=True, draft_k=2))
+        engines.fresh("keye", max_model_len=32, speculative=True, draft_k=2)
     with pytest.raises(ValueError, match="sliding window"):
         keye_config("tiny", sliding_window_size=16)
     with pytest.raises(ValueError, match="choose one"):
@@ -531,7 +446,7 @@ def test_per_head_qk_norm_is_not_the_whole_projection_norm(family):
     """On the same weights the whole-projection form (OLMoE's) moves the
     logits by hundreds of tolerances: the comparison tells them apart."""
     model, params, ref, weights, cfg = family
-    toks = _tokens(24)
+    toks = tokens(24)
     want = np.asarray(ref.forward_logits(weights, cfg, toks))
     whole = np.asarray(ref.forward_logits(weights, cfg, toks,
                                           faults={"whole_qk_norm"}))

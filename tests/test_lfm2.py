@@ -16,140 +16,37 @@ cache), loaded here by path.
 """
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _family
+from _family import BS, kernels, serve, tokens
 from megatron_llm_tpu.config import PositionEmbeddingType
 from megatron_llm_tpu.models import moe
 from megatron_llm_tpu.models.language_model import language_model_forward
-from megatron_llm_tpu.models.lfm2 import Lfm2Model, lfm2_config
+from megatron_llm_tpu.models.lfm2 import lfm2_config
 from megatron_llm_tpu.models.short_conv import (init_short_conv_params,
                                                 short_conv_mixer)
 from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.ops.pallas import paged_attention as pa
-from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                      SamplingParams)
-
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
+from megatron_llm_tpu.serving import SamplingParams
 
 # float32 on both sides, the same mathematics summed in another order;
 # the logits' deviation is some 0.5 and every named fault moves them by
 # hundredths at least
-LOGIT_TOL = 1e-4
-BS, CHUNK = 8, 32
+LOGIT_TOL = _family.FAMILIES["lfm2"].tol
 FAULTS = ("taps_reversed", "state_dropped_at_chunks", "bc_swapped",
           "conv_activation", "bias_in_gates", "no_qk_norm", "no_rope",
           "kv_neighbour", "dense_layer_sparse", "float8")
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _ref_cfg(cfg):
-    names = {"conv": "conv", "attention": "full_attention"}
-    return {"num_hidden_layers": cfg.num_layers,
-            "layer_types": [names[t] for t in cfg.layer_types],
-            "num_attention_heads": cfg.num_attention_heads,
-            "num_key_value_heads": cfg.num_attention_heads_kv,
-            "norm_eps": cfg.layernorm_epsilon,
-            "rope_theta": cfg.rope_theta,
-            "conv_L_cache": cfg.conv_taps,
-            "num_dense_layers": cfg.moe_first_dense_layers,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "routed_scaling_factor": cfg.moe_routed_scale,
-            "vocab_size": cfg.padded_vocab_size,
-            "fault_chunk": CHUNK}
-
-
-def _shake(params, key):
-    """Seeded N(0, 0.02) weights make attention nearly uniform and every
-    norm's scale is 1 at init: larger projections and scales that differ
-    (``tests/test_mellum.py::_shake`` says why).  The convolution's taps
-    are drawn wide as they are; the choice bias wide enough to turn
-    choices."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        names = [getattr(p, "key", None) for p in path]
-        if "scale" in names:
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
-        elif "embedding" in names:
-            leaf = leaf * 8.0
-        elif ({"kernel", "w_in", "w_out"} & set(names)
-              and "conv" not in names[-2:]):
-            leaf = leaf * (2.0 if "router" in names else 6.0)
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, out)
-
-
 TINY = dict(use_flash_attn=False)
 
 
 @pytest.fixture(scope="module")
 def family():
-    model = Lfm2Model(lfm2_config("tiny", **TINY))
-    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-    cfg = _ref_cfg(model.cfg)
-    weights = _load("lfm2_from_program").ProgramWeights(params, cfg)
-    return model, params, _load("lfm2"), weights, cfg
-
-
-def _tokens(n, seed=3, vocab=512):
-    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
-
-
-def _engine(model, params, **kw):
-    # a long deadline (tests/test_granite.py::_engine says why)
-    kw = dict(dict(num_slots=2, block_size=BS, max_model_len=192,
-                   prefill_chunk=CHUNK, preemption=False,
-                   default_deadline_secs=600.0), **kw)
-    return InferenceEngine(model, params, EngineConfig(**kw))
-
-
-def _serve(eng, prompt, new):
-    req = eng.submit(prompt, SamplingParams(max_new_tokens=new,
-                                            temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-    return req
-
-
-def _tapped(eng):
-    """The engine's programs with their logits kept
-    (``tests/test_granite.py::_tapped``)."""
-    got = {}
-    prefill, decode = eng._prefill_step, eng._decode_step
-
-    def tapped_prefill(params, pages, tokens, start, valid, table):
-        out = prefill(params, pages, tokens, start, valid, table)
-        got[int(start) + int(valid) - 1] = np.asarray(out[0])
-        return out
-
-    def tapped_decode(params, pages, last, ctx, tables, active, *rest):
-        caches = paged_kv.step_caches(pages, tables, ctx, active,
-                                      eng.paged_kernel, eng._layer_groups)
-        logits, _ = language_model_forward(
-            params, last[:, None], ctx[:, None], None, eng.model.cfg,
-            rng_key=None, train=False, kv_caches=caches)
-        for s in np.flatnonzero(np.asarray(active) > 0):
-            got[int(np.asarray(ctx)[s])] = np.asarray(logits[s, 0])
-        return decode(params, pages, last, ctx, tables, active, *rest)
-
-    eng._prefill_step, eng._decode_step = tapped_prefill, tapped_decode
-    return got
+    return _family.built("lfm2")
 
 
 # --- the mixer alone --------------------------------------------------------
@@ -261,68 +158,32 @@ def test_padding_rows_and_idle_rows_are_exact():
 # --- the stack --------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [5, 17, 70])
-def test_full_forward_matches_the_reference(family, n):
+def test_full_forward_matches_the_reference(n):
     """The program's plain (cache-less) forward: the two dense layers and
     the six sparse ones of ONE period that does not repeat, each layer's
     mixer taken by its index among its kind over the WHOLE depth: logits
     at every position against the reference."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(n)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    assert want.std() > 0.1
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+    _family.full_forward_is_the_references("lfm2", n)
 
 
 @pytest.mark.parametrize("prompt,new,kernel", [
     (64, 10, "off"), (150, 6, "off"), (45, 5, "on")])
 def test_the_engine_over_pool_and_state_group_matches_one_full_forward(
-        family, prompt, new, kernel, monkeypatch):
+        engines, prompt, new, kernel):
     """Chunked prefill (chunks of 32, the last one padded) then decode
     through the engine's own programs, the columns carried in their slot
     across every chunk boundary and step and the keys of 64 two heads a
     row of the pool, against the reference's ONE forward: LOGITS at every
-    chunk's last row and every step; the attention layers through the
-    dense gather and (``on``) through both walks' kernels in interpret
-    mode."""
-    model, params, ref, weights, cfg = family
-    if kernel == "on":
-        monkeypatch.setattr(pa, "_INTERPRET", True)
-    eng = _engine(model, params, paged_kernel=kernel, prefill_kernel=kernel)
-    assert eng.paged_kernel == ("pallas" if kernel == "on" else "xla")
-    got = _tapped(eng)
-    toks = _tokens(prompt, seed=5)
-    req = _serve(eng, toks, new)
-    seq = toks + list(req.out_tokens)
-    states = []
-    want = np.asarray(ref.forward_logits(weights, cfg, seq[:-1],
-                                         states=states))
-    rows = sorted(got)
-    assert rows[-1] == prompt + new - 2 and prompt - 1 in rows
-    assert len(rows) == -(-prompt // CHUNK) + new - 1
-    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
-                               atol=LOGIT_TOL, rtol=0)
-    assert list(req.out_tokens) == [int(t) for t in
-                                    want[prompt - 1:].argmax(-1)]
-    # and the columns each conv layer leaves in the slot
-    mine = [np.asarray(p["conv_state"][0]) for p in eng._st.pages
-            if paged_kv.is_state(p)]
-    assert len(mine) == len(states) == 6
-    for a, b in zip(mine, states):
-        np.testing.assert_allclose(a, np.asarray(b), atol=LOGIT_TOL, rtol=0)
+    chunk's last row and every step, and the columns each conv layer
+    leaves in the slot; the attention layers through the dense gather
+    and (``on``) through both walks' kernels in interpret mode."""
+    _family.chunked_prefill_then_decode_is_one_forward(
+        engines, "lfm2", prompt, new, kernel)
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_each_named_fault_fails_by_many_tolerances(family, fault):
-    model, params, ref, weights, cfg = family
-    toks = _tokens(70, seed=5)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    faulty = np.asarray(ref.forward_logits(weights, cfg, toks,
-                                           faults={fault}))
-    apart = np.abs(got - faulty).max(axis=-1)
-    assert apart[40:].max() > 100 * LOGIT_TOL, apart.max()
+def test_each_named_fault_fails_by_many_tolerances(fault):
+    _family.a_named_fault_is_told("lfm2", fault)
 
 
 def test_the_gates_are_over_their_sum_plus_epsilon(family):
@@ -356,39 +217,26 @@ def test_the_older_routers_keep_their_normaliser(name, eps):
     assert cfg.moe_gate_norm_eps == eps and not cfg.moe_gate_norm_added
 
 
-def test_a_slot_freed_and_taken_again_starts_from_zeros(family):
+def test_a_slot_freed_and_taken_again_starts_from_zeros(engines):
     """A request of 150 + 6 tokens, then a short one in the same slot
     with no clearing launch: the second answers as a fresh engine does,
     logits and all."""
-    model, params = family[:2]
-    eng = _engine(model, params, num_slots=1)
-    _serve(eng, _tokens(150, seed=7), 6)
-    assert np.abs(np.asarray(eng._st.pages[0]["conv_state"][0])).max() > 0
-    got = _tapped(eng)
-    prompt = _tokens(40, seed=8)
-    second = _serve(eng, prompt, 5)
-    fresh_eng = _engine(model, params, num_slots=1)
-    fresh = _tapped(fresh_eng)
-    again = _serve(fresh_eng, prompt, 5)
-    assert list(second.out_tokens) == list(again.out_tokens)
-    assert sorted(got) == sorted(fresh)
-    for t in got:
-        np.testing.assert_allclose(got[t], fresh[t], atol=1e-6, rtol=0)
+    _family.a_slot_is_reused(engines, "lfm2", **kernels("off"))
 
 
-def test_two_requests_decode_side_by_side(family):
+def test_two_requests_decode_side_by_side(family, engines):
     model, params, ref, weights, cfg = family
-    eng = _engine(model, params)
-    a = eng.submit(_tokens(70, seed=1), SamplingParams(max_new_tokens=12,
+    eng = engines("lfm2", **kernels("off"))
+    a = eng.submit(tokens(70, seed=1), SamplingParams(max_new_tokens=12,
                                                       temperature=0.0))
     for _ in range(6):
         eng.step()
-    b = eng.submit(_tokens(37, seed=2), SamplingParams(max_new_tokens=8,
+    b = eng.submit(tokens(37, seed=2), SamplingParams(max_new_tokens=8,
                                                       temperature=0.0))
     while a.finish_reason is None or b.finish_reason is None:
         assert eng.step()
     for req, seed, n in ((a, 1, 70), (b, 2, 37)):
-        seq = _tokens(n, seed=seed) + list(req.out_tokens)
+        seq = tokens(n, seed=seed) + list(req.out_tokens)
         want = np.asarray(ref.forward_logits(weights, cfg, seq))
         assert list(req.out_tokens) == [int(t) for t in
                                         want[n - 1:-1].argmax(-1)]
@@ -440,7 +288,7 @@ def test_two_dense_layers_in_a_stack_by_kind_give_every_layer_its_own_weights(
     swapped = jax.tree_util.tree_map(lambda x: x, params)
     swapped["transformer"]["layers"][kind] = jax.tree_util.tree_map(
         lambda x: x[jnp.asarray(perm)], params["transformer"]["layers"][kind])
-    toks = _tokens(20, seed=11)
+    toks = tokens(20, seed=11)
     before = _layer_outputs(model, params, toks)
     after = _layer_outputs(model, swapped, toks)
     first = owners[a]
@@ -461,7 +309,7 @@ def test_the_plain_forward_is_the_serving_loops_layers(family):
     """The cache-less forward's logits are the final norm and the tied
     head over the layer-by-layer stream."""
     model, params, ref, weights, cfg = family
-    toks = _tokens(20, seed=11)
+    toks = tokens(20, seed=11)
     x = _layer_outputs(model, params, toks)[-1]
     want = np.asarray(ref.head_block(
         jnp.asarray(x[0]), weights.final_norm(),
@@ -475,7 +323,7 @@ def test_the_program_without_rotation_is_not_the_reference(family):
     model, params, ref, weights, cfg = family
     still = model.cfg.replace(
         position_embedding_type=PositionEmbeddingType.none)
-    toks = _tokens(70, seed=5)
+    toks = tokens(70, seed=5)
     got = np.asarray(language_model_forward(
         params, jnp.asarray([toks], jnp.int32), None, None, still)[0][0])
     want = np.asarray(ref.forward_logits(weights, cfg, toks))
@@ -547,15 +395,15 @@ def test_the_paged_pool_at_64_wide_heads_is_dense_attention(n, kernel,
                                    rtol=0)
 
 
-def test_the_engine_counts_what_its_conv_layers_do(family):
-    model, params = family[:2]
-    eng = _engine(model, params)
-    _serve(eng, _tokens(70, seed=5), 4)
-    s = eng.stats()
+def test_the_engine_counts_what_its_conv_layers_do(engines):
+    eng = engines("lfm2", **kernels("off"))
+    since = _family.counted(eng)
+    serve(eng, tokens(70, seed=5), 4)
+    s, records = since()
     # six conv layers: three chunks of one live row, three steps of one
     assert s["conv_rows_live"] == 6 * (3 + 3)
     assert s["conv_tokens"] == 6 * (70 + 3)
     assert s["ssm_rows_live"] == s["ssm_tokens"] == 0
-    held = [r.ssm_state_bytes_held for r in eng.loop_profiler.records()]
+    held = [r.ssm_state_bytes_held for r in records]
     assert set(held) == {6 * 2 * 128 * 4}
     assert eng._cache.state_bytes_per_slot == 6 * 2 * 128 * 4

@@ -9,112 +9,39 @@ a token.  The reference is the file the benchmark's probe loads
 (``benchmarks/reference/mellum.py``), loaded here by path.
 """
 
-import importlib.util
-import os
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
+import _family
+from _family import BS, serve, tokens
 from megatron_llm_tpu.models import transformer as tfm
-from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.models.mellum import MellumModel, mellum_config
 from megatron_llm_tpu.ops import paged_kv
-from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                      SamplingParams)
-
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
+from megatron_llm_tpu.serving import SamplingParams
 
 # float32 on both sides, the same mathematics summed in another order;
 # every named fault moves the logits by whole tenths
-LOGIT_TOL = 2e-4
+ROW = _family.FAMILIES["mellum"]
+LOGIT_TOL, CHUNK = ROW.tol, ROW.chunk
 WINDOW = 16
+BOUND = paged_kv.window_pages_bound(WINDOW, CHUNK, BS)      # 5 pages
 FAULTS = ("all_full", "all_window", "plain_rope", "no_attention_factor",
           "gates_as_they_are", "float8")
 
 
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _ref_cfg(cfg):
-    f, orig, fast, slow, att = cfg.rope_yarn_scaling
-    names = {"sliding": "sliding_attention", "full": "full_attention"}
-    period = [names[t] for t in cfg.layer_types]
-    return {"num_hidden_layers": cfg.num_layers,
-            "num_attention_heads": cfg.num_attention_heads,
-            "num_key_value_heads": cfg.num_attention_heads_kv,
-            "rms_norm_eps": cfg.layernorm_epsilon,
-            "num_experts": cfg.num_experts,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "norm_topk_prob": cfg.norm_topk_prob,
-            "vocab_size": cfg.padded_vocab_size,
-            "sliding_window": cfg.sliding_window_size,
-            "layer_types": period * (cfg.num_layers // len(period)),
-            "mlp_layer_types": ["sparse"] * cfg.num_layers,
-            "rope_parameters": {
-                "full_attention": {
-                    "rope_type": "yarn", "rope_theta": cfg.rope_theta,
-                    "factor": f, "original_max_position_embeddings": orig,
-                    "beta_fast": fast, "beta_slow": slow,
-                    "attention_factor": att},
-                "sliding_attention": {"rope_type": "default",
-                                      "rope_theta": cfg.rope_theta}}}
-
-
-def _shake(params, key):
-    """Seeded N(0, 0.02) weights make attention nearly uniform and every
-    norm's scale is 1 at init: a test that must tell a window from the
-    whole context, or one rotary variant from another, needs larger
-    projections and scales that differ."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        names = [getattr(p, "key", None) for p in path]
-        if "scale" in names:
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
-        elif {"kernel", "w_in", "w_out"} & set(names):
-            # the experts too, or the MLP adds next to nothing; the
-            # router less: gates that are nearly one-hot would hide
-            # whether the chosen ones are renormalised
-            leaf = leaf * (2.0 if "router" in names else 6.0)
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, out)
-
-
 @pytest.fixture(scope="module")
 def family():
-    model = MellumModel(mellum_config("tiny", use_flash_attn=False))
-    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-    cfg = _ref_cfg(model.cfg)
-    weights = _load("mellum_from_program").ProgramWeights(params, cfg)
-    return model, params, _load("mellum"), weights, cfg
-
-
-def _tokens(n, seed=3, vocab=512):
-    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
+    return _family.built("mellum")
 
 
 @pytest.mark.parametrize("n", [5, 16, 17, 70])
-def test_full_forward_matches_the_reference(family, n):
+def test_full_forward_matches_the_reference(n):
     """The program's plain (cache-less) forward, a scan over periods of
     four layers: logits at every position against the reference, at
     contexts under the window (5), at it (16), one past it (17) and
     several windows long (70)."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(n)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    assert want.std() > 0.1
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+    _family.full_forward_is_the_references("mellum", n)
 
 
 def test_the_trace_holds_one_period_whatever_the_depth():
@@ -134,157 +61,99 @@ def test_the_trace_holds_one_period_whatever_the_depth():
     assert eqns(8) == eqns(4)
 
 
-BS, CHUNK = 8, 16
-BOUND = paged_kv.window_pages_bound(WINDOW, CHUNK, BS)      # 5 pages
-
-
-def _serve(model, params, prompt, new, **kw):
-    """One request through the engine, stepped by hand, the block
-    manager's invariants checked after every step.  Returns the engine
-    and the request."""
-    eng = InferenceEngine(model, params, EngineConfig(
-        num_slots=2, block_size=BS, max_model_len=192, prefill_chunk=CHUNK,
-        **kw))
-    req = eng.submit(prompt, SamplingParams(max_new_tokens=new,
-                                            temperature=0.0))
-    held = []
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-        held.append(eng.blocks.stats()["window_blocks_in_use"])
-    assert max(held) <= BOUND
-    return eng, req
-
-
-def _tapped(eng):
-    """The engine's programs with their logits kept: the prefill step
-    returns its chunk's last live row; the decode step is run without its
-    sampler on the step's own arguments, as the benchmark's probe does."""
-    got = {}
-    prefill, decode = eng._prefill_step, eng._decode_step
-
-    def tapped_prefill(params, pages, tokens, start, valid, table):
-        out = prefill(params, pages, tokens, start, valid, table)
-        got[int(start) + int(valid) - 1] = np.asarray(out[0])
-        return out
-
-    def tapped_decode(params, pages, last, ctx, tables, active, *rest):
-        caches = paged_kv.step_caches(pages, tables, ctx, active,
-                                      eng.paged_kernel, eng._layer_groups)
-        logits, _ = language_model_forward(
-            params, last[:, None], ctx[:, None], None, eng.model.cfg,
-            rng_key=None, train=False, kv_caches=caches)
-        for s in np.flatnonzero(np.asarray(active) > 0):
-            got[int(np.asarray(ctx)[s])] = np.asarray(logits[s, 0])
-        return decode(params, pages, last, ctx, tables, active, *rest)
-
-    eng._prefill_step, eng._decode_step = tapped_prefill, tapped_decode
-    return got
+def _within_the_bound(held):
+    """``each_step`` of a served request: the window pages in use."""
+    return lambda eng, req: held.append(
+        eng.blocks.stats()["window_blocks_in_use"])
 
 
 @pytest.mark.parametrize("prompt,new", [(5, 14), (64, 10), (150, 6)])
 def test_the_engine_over_two_groups_matches_one_full_forward(
-        family, prompt, new):
+        engines, prompt, new):
     """Chunked prefill then decode through the engine's own programs
     over the two-group cache against the reference's ONE full forward:
     a prompt under the window whose decode steps cross it (5 -> 19), a
     prompt that ends exactly on a page's and the window's edge (64 = 4
     windows = 8 pages), one of nine windows (150).  Window pages have
     gone back to the allocator before most compared positions."""
-    model, params, ref, weights, cfg = family
-    eng = InferenceEngine(model, params, EngineConfig(
-        num_slots=2, block_size=BS, max_model_len=192, prefill_chunk=CHUNK))
-    got = _tapped(eng)
-    toks = _tokens(prompt, seed=5)
-    req = eng.submit(toks, SamplingParams(max_new_tokens=new,
-                                          temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-    seq = toks + list(req.out_tokens)
-    want = np.asarray(ref.forward_logits(weights, cfg, seq))
-    rows = sorted(got)
-    assert rows[-1] == prompt + new - 2 and prompt - 1 in rows
-    assert len(rows) == -(-prompt // CHUNK) + new - 1
-    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
-                               atol=LOGIT_TOL, rtol=0)
-    # greedy: the engine's tokens are the reference's choices
-    assert list(req.out_tokens) == [int(t) for t in
-                                    want[prompt - 1:-1].argmax(-1)]
+    _, since, _ = _family.chunked_prefill_then_decode_is_one_forward(
+        engines, "mellum", prompt, new)
     if prompt > 2 * WINDOW:
-        assert eng.stats()["kv_window_pages_returned"] > 0
+        assert since()[0]["kv_window_pages_returned"] > 0
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_each_named_fault_fails_by_many_tolerances(family, fault):
+def test_each_named_fault_fails_by_many_tolerances(fault):
     """The same comparison against each FAULTY reference, at a context of
     nine windows: every one is whole tenths of a logit away."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(150, seed=5)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    faulty = np.asarray(ref.forward_logits(weights, cfg, toks,
-                                           faults={fault}))
-    apart = np.abs(got - faulty).max(axis=-1)
-    assert apart[2 * WINDOW:].max() > 100 * LOGIT_TOL, apart.max()
-    if fault in ("all_full",):
+    apart = _family.a_named_fault_is_told("mellum", fault, n=150,
+                                          beyond=2 * WINDOW)
+    if fault == "all_full":
         # nothing lies behind a window yet
         assert apart[:WINDOW].max() < LOGIT_TOL
 
 
-def test_window_pages_go_back_and_a_slot_is_reused(family):
+def test_window_pages_go_back_and_a_slot_is_reused(family, engines):
     """A request of 150 + 6 tokens holds at most the bound of window
     pages at any step (5 of the 20 its context spans) while the full
     group keeps all 20; its pages go back, and a second request in the
     reused slot answers as the plain forward does."""
-    model, params = family[:2]
-    eng, req = _serve(model, params, _tokens(150, seed=7), 6)
-    stats = eng.stats()
-    assert stats["window_blocks_in_use"] == 0 == stats["blocks_in_use"]
-    assert stats["window_blocks_total"] == 2 * BOUND
-    spanned, returned = (stats["kv_window_pages_spanned"],
-                         stats["kv_window_pages_returned"])
-    assert spanned == -(-(150 + 5) // BS) == 20
-    assert spanned - BOUND <= returned < spanned
-    records = eng.loop_profiler.records()
-    assert sum(r.kv_window_pages_returned for r in records) == returned
-    assert all(r.kv_held_bytes > 0 for r in records)
-    # at the last launch: 20 full pages on 2 layers, <= 5 window pages on 6
-    per_layer_page = BS * 2 * model.cfg.num_query_groups * model.cfg.head_dim * 4
-    last = records[-1]
-    assert last.kv_full_pages_held == 20
-    assert last.kv_held_bytes <= (20 * 2 + BOUND * 6) * per_layer_page
-    assert last.kv_live_tokens == 150 + 4    # as the launch begins
-    # one table a slot would hold 8 layers of every page
-    assert last.kv_held_bytes < 20 * 8 * per_layer_page / 2
-    second = eng.submit(_tokens(40, seed=8),
-                        SamplingParams(max_new_tokens=5, temperature=0.0))
-    while second.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-    toks = _tokens(40, seed=8)
-    for _ in range(5):
-        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    assert toks[40:] == list(second.out_tokens)
+    cfg = family.model.cfg
+
+    def first(eng, since):
+        stats, records = since()
+        assert stats["window_blocks_in_use"] == 0 == stats["blocks_in_use"]
+        assert eng.stats()["window_blocks_total"] == 2 * BOUND
+        spanned, returned = (stats["kv_window_pages_spanned"],
+                             stats["kv_window_pages_returned"])
+        assert spanned == -(-(150 + 5) // BS) == 20
+        assert spanned - BOUND <= returned < spanned
+        assert sum(r.kv_window_pages_returned for r in records) == returned
+        assert all(r.kv_held_bytes > 0 for r in records)
+        # at the last launch: 20 full pages on 2 layers, <= 5 window
+        # pages on 6
+        per_layer_page = BS * 2 * cfg.num_query_groups * cfg.head_dim * 4
+        last = records[-1]
+        assert last.kv_full_pages_held == 20
+        assert last.kv_held_bytes <= (20 * 2 + BOUND * 6) * per_layer_page
+        assert last.kv_live_tokens == 150 + 4    # as the launch begins
+        # one table a slot would hold 8 layers of every page
+        assert last.kv_held_bytes < 20 * 8 * per_layer_page / 2
+
+    _family.a_slot_is_reused(engines, "mellum", first)
 
 
-def test_two_requests_share_the_window_group(family):
+def test_two_requests_share_the_window_group(engines):
     """Two long requests decode side by side, each within its bound, the
     invariants held after every step; both answer as when alone."""
-    model, params = family[:2]
-    alone = [list(_serve(model, params, _tokens(n, seed=s), 8)[1].out_tokens)
-             for n, s in ((90, 11), (70, 12))]
-    eng = InferenceEngine(model, params, EngineConfig(
-        num_slots=2, block_size=BS, max_model_len=192, prefill_chunk=CHUNK))
-    reqs = [eng.submit(_tokens(n, seed=s),
+    eng, sized = engines("mellum"), ((90, 11), (70, 12))
+    alone = []
+    for n, s in sized:
+        held = []
+        alone.append(list(serve(eng, tokens(n, seed=s), 8,
+                                _within_the_bound(held)).out_tokens))
+        assert max(held) <= BOUND
+    reqs = [eng.submit(tokens(n, seed=s),
                        SamplingParams(max_new_tokens=8, temperature=0.0))
-            for n, s in ((90, 11), (70, 12))]
+            for n, s in sized]
     while any(r.finish_reason is None for r in reqs):
         assert eng.step()
         eng.blocks.check_invariants()
         assert eng.blocks.stats()["window_blocks_in_use"] <= 2 * BOUND
     assert [list(r.out_tokens) for r in reqs] == alone
+
+
+def test_an_engine_a_test_left_with_work_is_not_handed_on(engines):
+    """The harness's own promise (``tests/_family.py::Engines``): the
+    module's engine of a shape comes back as long as it is drained, and a
+    test that dies with a request in it costs its neighbours a new
+    engine, not their result."""
+    eng = engines("mellum", num_slots=1)
+    assert engines("mellum", num_slots=1) is eng
+    eng.submit(tokens(5), SamplingParams(max_new_tokens=2))
+    again = engines("mellum", num_slots=1)
+    assert again is not eng and not again.scheduler.has_work()
+    assert engines("mellum", num_slots=1) is again
 
 
 def test_the_pools_are_sized_by_group_and_named_in_the_kernels(family):
@@ -309,24 +178,18 @@ def test_the_pools_are_sized_by_group_and_named_in_the_kernels(family):
     assert paged_kv.layer_groups(mistral_config("tiny")) is None
 
 
-def test_what_the_pattern_does_not_support_is_refused_by_name(family,
+def test_what_the_pattern_does_not_support_is_refused_by_name(family, engines,
                                                               capsys):
-    model, params = family[:2]
-    small = dict(num_slots=2, block_size=8, max_model_len=32,
-                 prefill_chunk=16)
+    model = family.model
     with pytest.raises(ValueError, match="int8 KV pool"):
         paged_kv.init_pools(model.cfg, 4, BS, quantized=True,
                             window_blocks=4)
-    with pytest.raises(ValueError, match="int8 KV pool"):
-        InferenceEngine(model, params, EngineConfig(int8_kv_cache=True,
-                                                    **small))
-    with pytest.raises(ValueError, match="speculative"):
-        InferenceEngine(model, params, EngineConfig(speculative=True,
-                                                    draft_k=2, **small))
-    with pytest.raises(ValueError, match="host KV tier"):
-        InferenceEngine(model, params, EngineConfig(host_cache_bytes=1 << 20,
-                                                    **small))
-    eng = InferenceEngine(model, params, EngineConfig(**small))
+    for kw, what in ((dict(int8_kv_cache=True), "int8 KV pool"),
+                     (dict(speculative=True, draft_k=2), "speculative"),
+                     (dict(host_cache_bytes=1 << 20), "host KV tier")):
+        with pytest.raises(ValueError, match=what):
+            engines.fresh("mellum", max_model_len=32, **kw)
+    eng = engines.fresh("mellum", max_model_len=32)
     assert "the prefix cache adopts nothing" in capsys.readouterr().out
     assert not eng.blocks.prefix_cache_enabled
     with pytest.raises(ValueError, match="whole periods"):

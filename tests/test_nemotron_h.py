@@ -15,14 +15,13 @@ recurrence one token at a time, an expert at a time, no chunk, no
 cache), loaded here by path.
 """
 
-import importlib.util
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _family
+from _family import BS, kernels, serve, tokens
 from megatron_llm_tpu import config as C
 from megatron_llm_tpu.models import mamba, moe
 from megatron_llm_tpu.models.language_model import language_model_forward
@@ -32,18 +31,14 @@ from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.ops.layernorm import rms_norm
 from megatron_llm_tpu.ops.pallas import grouped_matmul as gm
 from megatron_llm_tpu.ops.pallas import paged_attention as pa
-from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                      SamplingParams)
-
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
+from megatron_llm_tpu.serving import SamplingParams
 
 # float32 on both sides, the same mathematics summed in another order (a
 # chunked scan and a step against a recurrence over tokens, a grouped
 # matmul against an expert at a time); the logits' deviation is some 0.5
 # and every named fault moves them by hundredths
-LOGIT_TOL = 1e-4
-BS, CHUNK = 8, 32
+ROW = _family.FAMILIES["nemotron_h"]
+LOGIT_TOL, CHUNK = ROW.tol, ROW.chunk
 FAULTS = ("norm_whole", "group_zero", "expert_swiglu", "expert_relu",
           "bias_in_gates", "no_scale", "rope_on", "no_shared",
           "second_norm", "no_D", "gate_after_norm", "no_conv_bias",
@@ -51,90 +46,9 @@ FAULTS = ("norm_whole", "group_zero", "expert_swiglu", "expert_relu",
 TINY_PATTERN = "MEMEM*EMEMEM*E"
 
 
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _ref_cfg(cfg):
-    letters = {v: k for k, v in C.PATTERN_LETTERS.items()}
-    return {"num_hidden_layers": cfg.num_layers,
-            "hybrid_override_pattern": "".join(
-                letters[t] for t in cfg.layer_types),
-            "num_attention_heads": cfg.num_attention_heads,
-            "num_key_value_heads": cfg.num_attention_heads_kv,
-            "layer_norm_epsilon": cfg.layernorm_epsilon,
-            "rope_theta": cfg.rope_theta,
-            "mamba_num_heads": cfg.mamba_n_heads,
-            "mamba_head_dim": cfg.mamba_d_head,
-            "ssm_state_size": cfg.mamba_d_state,
-            "n_groups": cfg.mamba_n_groups,
-            "conv_kernel": cfg.mamba_d_conv,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "n_routed_experts": cfg.num_experts,
-            "routed_scaling_factor": cfg.moe_routed_scale,
-            "experts_first": cfg.moe_experts_first,
-            "vocab_size": cfg.padded_vocab_size,
-            "fault_chunk": CHUNK}
-
-
-def _shake(params, key):
-    """Seeded N(0, 0.02) weights make attention nearly uniform and every
-    norm's scale is 1 at init: larger projections and scales that differ
-    (``tests/test_mellum.py::_shake`` says why).  The convolution's taps
-    and the choice bias are drawn wide as they are."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        names = [getattr(p, "key", None) for p in path]
-        if "scale" in names:
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
-        elif "embedding" in names or "lm_head" in names:
-            leaf = leaf * 8.0
-        elif {"kernel", "w_in", "w_out"} & set(names) and "conv" not in names:
-            leaf = leaf * (2.0 if "router" in names else 6.0)
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, out)
-
-
-# the share starts at the router's expert 2: experts 2-5 of 8 are held
-TINY = dict(use_flash_attn=False, moe_experts_first=2)
-
-
 @pytest.fixture(scope="module")
 def family():
-    model = NemotronHModel(nemotron_h_config("tiny", **TINY))
-    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-    cfg = _ref_cfg(model.cfg)
-    weights = _load("nemotron_h_from_program").ProgramWeights(params, cfg)
-    return model, params, _load("nemotron_h"), weights, cfg
-
-
-def _tokens(n, seed=3, vocab=512):
-    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
-
-
-def _engine(model, params, **kw):
-    # a long deadline, not the default 120 s: a request served through an
-    # interpreted kernel must not expire by the wall clock of a loaded
-    # machine, and a hang must still fail
-    kw = dict(dict(num_slots=2, block_size=BS, max_model_len=192,
-                   prefill_chunk=CHUNK, preemption=False,
-                   default_deadline_secs=600.0), **kw)
-    return InferenceEngine(model, params, EngineConfig(**kw))
-
-
-def _serve(eng, prompt, new):
-    req = eng.submit(prompt, SamplingParams(max_new_tokens=new,
-                                            temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-    return req
+    return _family.built("nemotron_h")
 
 
 def test_the_tiny_preset_is_the_pattern_and_its_params_are_by_kind(family):
@@ -164,83 +78,36 @@ def test_the_tiny_preset_is_the_pattern_and_its_params_are_by_kind(family):
 
 
 @pytest.mark.parametrize("n", [5, 16, 17, 70])
-def test_full_forward_matches_the_reference(family, n):
+def test_full_forward_matches_the_reference(n):
     """The program's plain (cache-less) forward, a scan over the one
     period with each layer's sublayer taken by its index among its kind:
     logits at every position against the reference."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(n)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    assert want.std() > 0.1
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
-
-
-def _tapped(eng):
-    """The engine's programs with their logits kept
-    (``tests/test_mellum.py::_tapped``)."""
-    got = {}
-    prefill, decode = eng._prefill_step, eng._decode_step
-
-    def tapped_prefill(params, pages, tokens, start, valid, table):
-        out = prefill(params, pages, tokens, start, valid, table)
-        got[int(start) + int(valid) - 1] = np.asarray(out[0])
-        return out
-
-    def tapped_decode(params, pages, last, ctx, tables, active, *rest):
-        caches = paged_kv.step_caches(pages, tables, ctx, active,
-                                      eng.paged_kernel, eng._layer_groups)
-        logits, _ = language_model_forward(
-            params, last[:, None], ctx[:, None], None, eng.model.cfg,
-            rng_key=None, train=False, kv_caches=caches)
-        for s in np.flatnonzero(np.asarray(active) > 0):
-            got[(s, int(np.asarray(ctx)[s]))] = np.asarray(logits[s, 0])
-        return decode(params, pages, last, ctx, tables, active, *rest)
-
-    eng._prefill_step, eng._decode_step = tapped_prefill, tapped_decode
-    return got
+    _family.full_forward_is_the_references("nemotron_h", n)
 
 
 @pytest.mark.parametrize("prompt,new,kernel", [
     (5, 14, "off"), (64, 10, "off"), (150, 6, "off"), (45, 5, "on")])
 def test_the_engine_through_its_cache_matches_one_full_forward(
-        family, prompt, new, kernel, monkeypatch):
+        engines, prompt, new, kernel):
     """Chunked prefill (chunks of 32, the last one padded) then decode
     through the engine's own programs, the state carried in its slot
     across every chunk boundary and step and the expert layers with no
     cache entry, against the reference's ONE forward: logits at every
-    chunk's last row and every step; the attention layers through the
-    dense gather and (``on``) through the walk's kernels, the experts
-    through the grouped matmul's kernel, in interpret mode."""
-    model, params, ref, weights, cfg = family
-    if kernel == "on":
-        monkeypatch.setattr(pa, "_INTERPRET", True)
-        monkeypatch.setattr(gm, "_INTERPRET", True)
-    eng = _engine(model, params, paged_kernel=kernel, prefill_kernel=kernel)
-    assert eng.paged_kernel == ("pallas" if kernel == "on" else "xla")
-    got = _tapped(eng)
-    toks = _tokens(prompt, seed=5)
-    req = _serve(eng, toks, new)
-    seq = toks + list(req.out_tokens)
-    want = np.asarray(ref.forward_logits(weights, cfg, seq))
-    got = {(t[1] if isinstance(t, tuple) else t): v for t, v in got.items()}
-    rows = sorted(got)
-    assert rows[-1] == prompt + new - 2 and prompt - 1 in rows
-    assert len(rows) == -(-prompt // CHUNK) + new - 1
-    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
-                               atol=LOGIT_TOL, rtol=0)
-    assert list(req.out_tokens) == [int(t) for t in
-                                    want[prompt - 1:-1].argmax(-1)]
+    chunk's last row and every step, and the state the slot is left
+    with; the attention layers through the dense gather and (``on``)
+    through the walk's kernels, the experts through the grouped matmul's
+    kernel, in interpret mode."""
+    _family.chunked_prefill_then_decode_is_one_forward(
+        engines, "nemotron_h", prompt, new, kernel)
 
 
-def test_the_pools_hold_nothing_for_an_expert_layer(family):
+def test_the_pools_hold_nothing_for_an_expert_layer(family, engines):
     """A 'moe' layer has no cache entry: its pool is a pytree of no
     arrays, its group ``NONE``; the FULL group is the 2 attention
     layers, the STATE group the 6 mixers, and the routing histogram has
     a row an EXPERT layer."""
     model, params = family[:2]
-    eng = _engine(model, params)
+    eng = engines("nemotron_h", **kernels("off"))
     groups = eng._layer_groups
     assert groups == tuple({"M": paged_kv.STATE, "*": paged_kv.FULL,
                             "E": paged_kv.NONE}[c] for c in TINY_PATTERN)
@@ -254,47 +121,41 @@ def test_the_pools_hold_nothing_for_an_expert_layer(family):
     caches = paged_kv.step_caches(
         pools, eng._cache.tables(eng.blocks), jnp.zeros((2,), jnp.int32),
         jnp.ones((2,), jnp.int32), "xla", groups)
-    _, new = language_model_forward(
+    _, new = jax.jit(lambda params, caches: language_model_forward(
         params, jnp.ones((2, 1), jnp.int32), jnp.zeros((2, 1), jnp.int32),
-        None, model.cfg, rng_key=None, train=False, kv_caches=caches)
+        None, model.cfg, rng_key=None, train=False, kv_caches=caches))(
+            params, caches)
     assert [c.moe_counts is not None for c in new] == [
         g == paged_kv.NONE for g in groups]
     assert paged_kv.routing_of(new).shape == (6, 8)
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_each_named_fault_fails_by_many_tolerances(family, fault):
+def test_each_named_fault_fails_by_many_tolerances(fault):
     """The gated norm over the whole width, group 0's B and C for every
     head, a gated or a plain-relu expert, the bias in the gates, the
     scale left out, a rotation, the shared MLP left out, a second norm
     on a mixer layer, and Granite's (``D``, the gate's place, the
     convolution's bias, a chunk's state not handed on, float8)."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(70, seed=5)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    faulty = np.asarray(ref.forward_logits(weights, cfg, toks,
-                                           faults={fault}))
-    apart = np.abs(got - faulty).max(axis=-1)
-    assert apart[40:].max() > 100 * LOGIT_TOL, apart.max()
+    _family.a_named_fault_is_told("nemotron_h", fault)
 
 
-def test_rows_join_and_leave_and_a_slot_is_reused(family):
+def test_rows_join_and_leave_and_a_slot_is_reused(family, engines):
     """Continuous batching over the state and the pages: a request
     admitted while another decodes, the first leaving while the second
     goes on, a third taking the freed slot with no clearing launch: each
     decodes as if alone, held to the reference's logits at every step."""
     model, params, ref, weights, cfg = family
-    eng = _engine(model, params)
-    got = _tapped(eng)
+    eng = engines("nemotron_h", **kernels("off"))
+    got = engines.tapped(eng, by_slot=True)
     sp = lambda n: SamplingParams(max_new_tokens=n, temperature=0.0)
-    a = eng.submit(_tokens(70, seed=1), sp(6))
+    a = eng.submit(tokens(70, seed=1), sp(6))
     for _ in range(5):
         eng.step()
-    b = eng.submit(_tokens(37, seed=2), sp(14))
+    b = eng.submit(tokens(37, seed=2), sp(14))
     while a.finish_reason is None:
         assert eng.step()
-    c = eng.submit(_tokens(21, seed=4), sp(5))
+    c = eng.submit(tokens(21, seed=4), sp(5))
     while b.finish_reason is None or c.finish_reason is None:
         assert eng.step()
         eng.blocks.check_invariants()
@@ -305,7 +166,7 @@ def test_rows_join_and_leave_and_a_slot_is_reused(family):
         by_step.setdefault(s, []).append(t)
     assert len(by_step) == 2
     for req, seed, n in ((a, 1, 70), (b, 2, 37), (c, 4, 21)):
-        seq = _tokens(n, seed=seed) + list(req.out_tokens)
+        seq = tokens(n, seed=seed) + list(req.out_tokens)
         want = np.asarray(ref.forward_logits(weights, cfg, seq))
         assert list(req.out_tokens) == [int(t) for t in
                                         want[n - 1:-1].argmax(-1)]
@@ -315,31 +176,24 @@ def test_rows_join_and_leave_and_a_slot_is_reused(family):
     slot_a = [s for s, ts in by_step.items() if 70 in ts]
     slot_c = [s for s, ts in by_step.items() if 21 in ts]
     assert slot_a == slot_c
-    seq = _tokens(21, seed=4) + list(c.out_tokens)
+    seq = tokens(21, seed=4) + list(c.out_tokens)
     want = np.asarray(ref.forward_logits(weights, cfg, seq))
     for t in range(21, 21 + 4):
         np.testing.assert_allclose(got[(slot_c[0], t)], want[t],
                                    atol=LOGIT_TOL, rtol=0)
 
 
-def test_a_finished_requests_slot_holds_the_references_state(family):
+def test_a_finished_requests_slot_holds_the_references_state(engines):
     """What the benchmark's probe reads: after a request of chunks (the
     last one padded) and steps, its slot holds the state the reference
     is left with by the prompt and every answer token but the last, in
     every state-space layer."""
-    model, params, ref, weights, cfg = family
-    eng = _engine(model, params, num_slots=1)
-    toks = _tokens(70, seed=9)
-    req = _serve(eng, toks, 6)
-    seq = toks + list(req.out_tokens)[:-1]
-    states = []
-    ref.forward_logits(weights, cfg, seq, rows=[len(seq) - 1], states=states)
-    mine = [np.asarray(p["ssm_state"][0], np.float32)
-            for p in eng._st.pages if paged_kv.is_state(p)]
-    assert len(mine) == len(states) == 6
-    for a, b in zip(mine, states):
-        assert (np.linalg.norm(a - np.asarray(b))
-                / np.linalg.norm(np.asarray(b))) < 1e-5
+    eng = engines("nemotron_h", **kernels("off"))
+    toks = tokens(70, seed=9)
+    req = serve(eng, toks, 6)
+    apart = _family.state_apart("nemotron_h", eng, req.slot,
+                                toks + list(req.out_tokens)[:-1])
+    assert len(apart) == 6 and max(apart) < 1e-5, apart
 
 
 # ---------------------------------------------------------------------------
@@ -465,12 +319,12 @@ def test_the_shares_add_up_to_the_uncut_layer(family):
     model, params, ref = family[:3]
     whole_cfg = nemotron_h_config("tiny", use_flash_attn=False,
                                   num_experts=8, moe_router_experts=None)
-    whole = _shake(NemotronHModel(whole_cfg).init(jax.random.PRNGKey(0)),
-                   jax.random.PRNGKey(1))
+    whole = _family.shaken("nemotron_h", NemotronHModel(whole_cfg))
     layer = _moe_layer(whole, 0)
     hn = jax.random.normal(jax.random.PRNGKey(8), (1, 60, 128), jnp.float32)
-    rcfg = {**_ref_cfg(whole_cfg), "experts_first": 0}
-    weights = _load("nemotron_h_from_program").ProgramWeights(whole, rcfg)
+    rcfg = {**ROW.ref_cfg(whole_cfg, CHUNK), "experts_first": 0}
+    weights = _family.load("nemotron_h_from_program").ProgramWeights(whole,
+                                                                     rcfg)
     want = ref.moe_out(hn[0], weights.layer(1), weights, rcfg, 1, {},
                        frozenset(), held=range(8))[0]
     shared = moe._shared_mlp(hn, layer, whole_cfg)
@@ -553,14 +407,14 @@ def test_a_laid_out_width_computes_the_width(family):
     ref = family[2]
     cfg = nemotron_h_config("tiny", use_flash_attn=False, num_experts=8,
                             moe_router_experts=None, ffn_hidden_size=160)
-    params = _shake(NemotronHModel(cfg).init(jax.random.PRNGKey(0)),
-                    jax.random.PRNGKey(1))
+    params = _family.shaken("nemotron_h", NemotronHModel(cfg))
     layer = _moe_layer(params, 0)
     w_in = layer["experts"]["w_in"]
     assert w_in.shape == (8, 128, 256) and not np.asarray(w_in[..., 160:]).any()
     assert np.asarray(w_in[..., :160]).all()
-    rcfg = _ref_cfg(cfg)
-    weights = _load("nemotron_h_from_program").ProgramWeights(params, rcfg)
+    rcfg = ROW.ref_cfg(cfg, CHUNK)
+    weights = _family.load("nemotron_h_from_program").ProgramWeights(params,
+                                                                     rcfg)
     assert weights.expert(1, 3)["w_up"].shape == (128, 160)
     hn = jax.random.normal(jax.random.PRNGKey(9), (1, 40, 128), jnp.float32)
     want = ref.moe_out(hn[0], weights.layer(1), weights, rcfg, 1, {},
@@ -630,11 +484,13 @@ def test_the_flags_carry_the_pattern():
     assert 0.05 < wide.std() < 0.2 and moe._CHOICE_BIAS_STD == 0.1
 
 
-ROW = dict(C.RUNS_WITH)[C.ONE_SUBLAYER]
+SQUARES = dict(C.RUNS_WITH)[C.ONE_SUBLAYER]
 
 
-@pytest.mark.parametrize("what", ROW, ids=[w.split(" (")[0] for w in ROW])
-def test_every_square_of_the_new_row_is_refused_by_name(family, what):
+@pytest.mark.parametrize("what", SQUARES,
+                         ids=[w.split(" (")[0] for w in SQUARES])
+def test_every_square_of_the_new_row_is_refused_by_name(family, engines,
+                                                        what):
     """Training, tensor and pipeline parallelism, the verify step, the
     int8 pool, the host tier, preemption; the prefix cache turned off;
     another layer type beside the three: each one sentence of
@@ -663,7 +519,7 @@ def test_every_square_of_the_new_row_is_refused_by_name(family, what):
             mp.undo()
         assert str(raised.value) == said
     elif what == C.PREFIX_CACHE:
-        eng = _engine(model, params, prefix_cache=True)
+        eng = engines.fresh("nemotron_h", prefix_cache=True)
         assert not eng.config.prefix_cache
     else:
         on = {C.VERIFY_STEP: dict(speculative=True, draft_k=2),
@@ -671,7 +527,7 @@ def test_every_square_of_the_new_row_is_refused_by_name(family, what):
               C.HOST_TIER: dict(host_cache_bytes=1 << 20),
               C.PREEMPTION: dict(preemption=True)}[what]
         with pytest.raises(ValueError) as raised:
-            _engine(model, params, **on)
+            engines.fresh("nemotron_h", **on)
         assert str(raised.value) == said
 
 
@@ -698,7 +554,7 @@ def test_the_rows_a_step_moves_against_a_hand_count(family, step_kernel,
     assert not hasattr(d, "ssm_rows_moved")
 
 
-def test_the_held_experts_touched_against_a_hand_count(family):
+def test_the_held_experts_touched_against_a_hand_count(family, engines):
     """``moe_experts_touched_held``: of the experts this chip holds
     (the router's 2-5), those with at least one live assignment, summed
     over the expert layers; ``moe_experts_touched`` counts over all
@@ -720,14 +576,13 @@ def test_the_held_experts_touched_against_a_hand_count(family):
     assert d.moe_assignments == 18 and d.moe_assignments_held == 3 + 0 + 4
     assert d.moe_expert_slots == 48
     # and through an engine: the total is every launch's, in stats()
-    model, params = family[:2]
-    eng = _engine(model, params)
-    _serve(eng, _tokens(40, seed=6), 4)
-    stats = eng.stats()
+    eng = engines("nemotron_h", **kernels("off"))
+    since = _family.counted(eng)
+    serve(eng, tokens(40, seed=6), 4)
+    stats, records = since()
     assert 0 < stats["moe_experts_touched_held"] <= stats[
         "moe_experts_touched"]
     assert stats["moe_experts_touched_held"] <= 6 * 4 * (2 + 3)
-    records = eng.loop_profiler.records()
     assert stats["moe_experts_touched_held"] == sum(
         r.moe_experts_touched_held for r in records)
     # a whole model holds every expert it touches

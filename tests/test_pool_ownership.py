@@ -18,8 +18,8 @@ import jax
 import numpy as np
 import pytest
 
+import _family
 from megatron_llm_tpu import models
-from megatron_llm_tpu.models.granite import GraniteModel, granite_config
 from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
                                       SamplingParams)
 from megatron_llm_tpu.serving import engine as engine_mod
@@ -30,11 +30,12 @@ GREEDY = dict(temperature=0.0)
 
 
 def _model(family):
-    if family == "granite":
-        return GraniteModel(granite_config("tiny", use_flash_attn=False,
-                                           moe_experts_first=2))
-    return getattr(models, family.capitalize() + "Model")(
+    """(model, params): the harness's where the family has a row."""
+    if family in _family.FAMILIES:
+        return _family.built(family)[:2]
+    model = getattr(models, family.capitalize() + "Model")(
         getattr(models, family + "_config")("tiny", use_flash_attn=False))
+    return model, model.init(jax.random.PRNGKey(0))
 
 
 def _engine(model, params, **kw):
@@ -47,8 +48,7 @@ def _engine(model, params, **kw):
 
 @pytest.fixture(scope="module")
 def dense():
-    model = _model("mistral")
-    return model, model.init(jax.random.PRNGKey(0))
+    return _model("mistral")
 
 
 def _leaves(pages):
@@ -142,8 +142,7 @@ def _lend(eng):
 ], ids=["dense", "sparse", "latent_pool", "two_groups", "state_space",
         "int8_pool", "speculative"])
 def test_served_as_programs_built_without_donation_serve(family, kw):
-    model = _model(family)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = _model(family)
     prompts = _prompts(model.cfg.padded_vocab_size)
     owned, lent = _engine(model, params, **kw), _lend(
         _engine(model, params, **kw))

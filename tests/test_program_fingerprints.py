@@ -1,0 +1,82 @@
+"""The standing served families trace the programs they traced before:
+``tests/_program_fingerprints.py`` (a process of its own) hashes the jaxpr
+of each family's prefill chunk and decode step, and ``TRACED`` holds what
+it printed when the family's programs were last MEANT to change.  A PR
+that adds a family records its two hashes here; a PR that means to
+change a family's program records the new ones and says so.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+# What ``tests/_program_fingerprints.py`` prints since PR 42, which MEANT
+# to change every family's decode step and nothing of a chunk
+# (``engine_prefill`` is PR 41's hash in every family): the step takes
+# the key chain with the keys the host gave since the last launch and
+# the rows they are for, and gives the chain back advanced only for the
+# slots that decoded (``InferenceEngine._split_keys``: two selects over
+# [S, 2] words).  PR 41 had changed both programs (the cache's write
+# keeps the pool's own shape).  A PR that MEANS to change a family's
+# program runs the script and records what it prints here.
+TRACED = {
+    "mistral": {"engine_prefill": "97f7c403d8d97874",
+                "engine_decode": "1271bc7d18f7d88f"},
+    # PR 46 MEANT both programs of the seven sparse families and nothing
+    # else (the dense family's are PR 44's): the dropless layer's combine
+    # gathers the experts' rows once, in their own dtype, and adds the
+    # choices in turn under the gates (``models/moe.py``, scope
+    # ``moe_combine``)
+    "mixtral": {"engine_prefill": "0c7d499804bf34eb",
+                "engine_decode": "d108ab43dbc7ce8b"},
+    "olmoe": {"engine_prefill": "8cd66b78f8c91346",
+              "engine_decode": "971de090d62b6706"},
+    "keye": {"engine_prefill": "e0d8c3ceab2845ae",
+             "engine_decode": "3c0e2663546ddcad"},
+    "mellum": {"engine_prefill": "8f4a67e3658e0723",
+               "engine_decode": "1ac80699ca25a3e1"},
+    "kanana": {"engine_prefill": "d425b4f959dbedb1",
+               "engine_decode": "9dbbd4c35a3d9c06"},
+    "granite": {"engine_prefill": "b0d8abbf1f607c6b",
+                "engine_decode": "76573d9f960581e5"},
+    "nemotron_h": {"engine_prefill": "30c8c54ee38f7897",
+                   "engine_decode": "b085bbfea1bc2bd2"},
+    # PR 47 brought this family and changed no other's: the gate, the
+    # output norms and the types that rotate are off for every other
+    # model, whose programs are the ones above
+    "trinity": {"engine_prefill": "0c33c9c2556bb82a",
+                "engine_decode": "a30d0d0cca6cdb4d"},
+    # PR 51 brought this family and changed no other's: the state group's
+    # arrays by the layer's kind, the router's normaliser as data and the
+    # pool's two-heads-a-row layout at 64-wide heads leave every program
+    # above as it was
+    "lfm2": {"engine_prefill": "c08e0b51f0aec84b",
+             "engine_decode": "913f73debd40aff4"},
+    # PR 54 brought this family and changed no other's: the queries, keys
+    # and values of an attention layer come from ``qkv_heads`` now, which
+    # a retention layer calls too, the same operations in the same order;
+    # a paged model's chunk is still lent its pool
+    "brumby": {"engine_prefill": "9c113f3ea93d1713",
+               "engine_decode": "d8a3caf89969587f"},
+}
+
+
+@pytest.fixture(scope="module")
+def traced():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(os.path.dirname(
+            os.path.abspath(__file__)), "_program_fingerprints.py")],
+        capture_output=True, text=True, timeout=280)
+    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0 and lines, proc.stderr[-2000:]
+    return json.loads(lines[-1])
+
+
+@pytest.mark.parametrize("name", sorted(TRACED))
+def test_a_standing_family_traces_the_program_it_traced_before(traced, name):
+    assert traced[name] == TRACED[name], (
+        f"{name}'s engine programs are not the recorded ones: if that was "
+        "meant, record tests/_program_fingerprints.py's output in TRACED")

@@ -15,6 +15,7 @@ import importlib
 import jax
 import pytest
 
+import _family
 from megatron_llm_tpu import config as C
 from megatron_llm_tpu.models import MODEL_REGISTRY, gpt
 from megatron_llm_tpu.ops import paged_kv
@@ -85,6 +86,9 @@ def _config(family, **overrides):
 
 @functools.lru_cache(maxsize=None)
 def _model(family):
+    """(model, params): the harness's where the family has a row."""
+    if family in _family.FAMILIES:
+        return _family.built(family)[:2]
     model = MODEL_REGISTRY[family](_config(family))
     return model, model.init(jax.random.PRNGKey(0))
 
@@ -183,8 +187,6 @@ def test_leading_dense_layers_run_in_a_stack_whose_mixers_are_by_kind(
     way: one prompt's last logits and its greedy continuation agree."""
     import numpy as np
 
-    from megatron_llm_tpu.serving import SamplingParams
-
     cfg = _config(family, moe_first_dense_layers=dense)
     assert C.HAS[C.FIRST_DENSE](cfg) and cfg.mixers_by_kind
     assert C.refusal(cfg) is None
@@ -196,9 +198,8 @@ def test_leading_dense_layers_run_in_a_stack_whose_mixers_are_by_kind(
     for kind, n in cfg.mixer_counts.items():
         assert kinds.count(kind) == n == jax.tree_util.tree_leaves(
             params["transformer"]["layers"][kind])[0].shape[0]
-    eng = InferenceEngine(model, params, EngineConfig(
-        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16,
-        preemption=False, default_deadline_secs=600.0))
+    eng = _family.engine(model, params, max_model_len=64, prefill_chunk=16,
+                         preemption=False)
     inner, got = eng._prefill_step, {}
 
     def tapped(params, pages, tokens, start, valid, table):
@@ -206,15 +207,12 @@ def test_leading_dense_layers_run_in_a_stack_whose_mixers_are_by_kind(
         got[int(start) + int(valid) - 1] = np.asarray(out[0])
         return out
 
-    eng._prefill_step = tapped
-    prompt = np.random.default_rng(3).integers(1, 500, 21).tolist()
-    req = eng.submit(prompt, SamplingParams(max_new_tokens=4,
-                                            temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
+    eng._prefill_step = tapped      # the chunks' rows alone: no step's
+    prompt = _family.tokens(21, seed=3, vocab=501)
+    req = _family.serve(eng, prompt, 4)
     seq = prompt + list(req.out_tokens)[:-1]
-    want = np.asarray(model(params, jax.numpy.asarray([seq], "int32"),
-                            train=False)[0])
+    want = np.asarray(jax.jit(lambda p, t: model(p, t, train=False))(
+        params, jax.numpy.asarray([seq], "int32"))[0])
     np.testing.assert_allclose(got[20], want[20], atol=2e-4, rtol=0)
     assert list(req.out_tokens) == want[20:].argmax(-1).tolist()
 

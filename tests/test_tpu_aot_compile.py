@@ -340,10 +340,9 @@ BRUMBY = "brumby_cell_programs"
 def _compile_all(only: str = ""):
     """The child: compile every case for one described v5e device and
     print ``{case: true | false | "error"}`` (or ``{"skip": why}`` where
-    this jaxlib cannot describe the topology).  The Granite cell's two
-    programs, and the Nemotron cell's, take a child each (``only``): at
-    their real sizes they cost as much as all the kernels together, and a
-    test's time limit covers its fixture."""
+    this jaxlib cannot describe the topology).  A cell's two programs
+    (``only``: a name of ``CELLS``) take a child each, under ``-m slow``:
+    at their real sizes they cost as much as all the kernels together."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -355,12 +354,8 @@ def _compile_all(only: str = ""):
         return
     chip = SingleDeviceSharding(topo.devices[0])
     if only:
-        programs = {"granite": (GRANITE, _granite_programs),
-                    "nemotron": (NEMOTRON, _nemotron_programs),
-                    "trinity": (TRINITY, _trinity_programs),
-                    "lfm2": (LFM2, _lfm2_programs),
-                    "brumby": (BRUMBY, _brumby_programs)}[only]
-        print(json.dumps({programs[0]: programs[1](chip)}))
+        key, cell = CELLS[only]
+        print(json.dumps({key: _cell_programs(chip, *cell())}))
         return
     found = {}
     for case in sorted(CASES):
@@ -438,23 +433,23 @@ def _engine_tables(chip):
             os.environ["MLT_FORCE_PALLAS"] = forced
 
 
-def _granite_programs(chip):
-    """``engine_prefill`` and ``engine_decode`` of the benchmark's Granite
-    cell (one period of nine state-space layers and one attention layer
-    at the published widths, 36 of 72 experts, half the vocabulary, 24
-    slots of state and 13,313 pages) over abstract weights, compiled for
-    the described chip: what each holds, and which of the mixer's scopes
-    and kernels reach the optimised text."""
+def _granite_cell():
+    """The benchmark's Granite cell: one period of nine state-space layers
+    and one attention layer at the published widths, 36 of 72 experts,
+    half the vocabulary, 24 slots of state and 13,313 pages.  (How to
+    build its model, its engine's keywords): what ``_cell_plan`` counts
+    with no compiler and ``_cell_programs`` compiles for a described
+    chip."""
     from megatron_llm_tpu.models.granite import GraniteModel, granite_config
 
-    return _cell_programs(chip, lambda: GraniteModel(granite_config(
+    return lambda: GraniteModel(granite_config(
         "h-small", num_layers=10, num_experts=36, moe_router_experts=72,
         padded_vocab_size=50176, params_dtype="bf16",
         compute_dtype="bf16", seq_length=17408)), dict(
-        num_slots=24, num_blocks=13313, max_model_len=17408))
+        num_slots=24, num_blocks=13313, max_model_len=17408)
 
 
-def _nemotron_programs(chip):
+def _nemotron_cell():
     """The same of the benchmark's Nemotron cell: the published pattern's
     first 14 layers (six Mamba-2 mixers of eight groups, two attention
     layers, six expert layers alone) at the published widths, 64 of 128
@@ -465,15 +460,15 @@ def _nemotron_programs(chip):
                                                     NemotronHModel,
                                                     nemotron_h_config)
 
-    return _cell_programs(chip, lambda: NemotronHModel(nemotron_h_config(
+    return lambda: NemotronHModel(nemotron_h_config(
         "nano-30b-a3b", num_layers=14,
         layer_types=pattern_layer_types(NANO_PATTERN[:14]), num_experts=64,
         moe_router_experts=128, padded_vocab_size=65536,
         params_dtype="bf16", compute_dtype="bf16", seq_length=6144)), dict(
-        num_slots=64, num_blocks=24577, max_model_len=6144))
+        num_slots=64, num_blocks=24577, max_model_len=6144)
 
 
-def _trinity_programs(chip):
+def _trinity_cell():
     """The same of the benchmark's Trinity cell: the published model's
     first 8 layers (two dense, six sparse, the period twice) at the
     published widths, 64 of 128 experts of 2048 x 1024, the whole
@@ -481,13 +476,13 @@ def _trinity_programs(chip):
     group of 48 x 161."""
     from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
 
-    return _cell_programs(chip, lambda: TrinityModel(trinity_config(
+    return lambda: TrinityModel(trinity_config(
         "mini", num_layers=8, num_experts=64, moe_router_experts=128,
         params_dtype="bf16", compute_dtype="bf16", seq_length=20992)), dict(
-        num_slots=48, num_blocks=32769, max_model_len=20992))
+        num_slots=48, num_blocks=32769, max_model_len=20992)
 
 
-def _lfm2_programs(chip):
+def _lfm2_cell():
     """The same of the benchmark's LFM2 cell: the published model's first
     14 layers as they stand (both dense layers, eleven gated short
     convolutions and three attention layers of 64-wide heads) at the
@@ -496,13 +491,13 @@ def _lfm2_programs(chip):
     from megatron_llm_tpu.models.lfm2 import (PUBLISHED_LAYER_TYPES,
                                               Lfm2Model, lfm2_config)
 
-    return _cell_programs(chip, lambda: Lfm2Model(lfm2_config(
+    return lambda: Lfm2Model(lfm2_config(
         "8b-a1b", num_layers=14, layer_types=PUBLISHED_LAYER_TYPES[:14],
         params_dtype="bf16", compute_dtype="bf16", seq_length=4352)), dict(
-        num_slots=128, num_blocks=16385, max_model_len=4352))
+        num_slots=128, num_blocks=16385, max_model_len=4352)
 
 
-def _brumby_programs(chip):
+def _brumby_cell():
     """The same of the benchmark's Brumby cell: 8 of the published 40
     layers, every one a power retention, at the published widths (40
     query heads over 8 key-value heads of 128, an MLP of 17,408), the
@@ -510,9 +505,21 @@ def _brumby_programs(chip):
     page."""
     from megatron_llm_tpu.models.brumby import BrumbyModel, brumby_config
 
-    return _cell_programs(chip, lambda: BrumbyModel(brumby_config(
+    return lambda: BrumbyModel(brumby_config(
         "14b", num_layers=8, params_dtype="bf16", compute_dtype="bf16",
-        seq_length=17920)), dict(num_slots=16, max_model_len=17920))
+        seq_length=17920)), dict(num_slots=16, max_model_len=17920)
+
+
+# a cell's name among the child's arguments, its key in what the child
+# prints, and the cell
+CELLS = {"granite": (GRANITE, _granite_cell),
+         "nemotron": (NEMOTRON, _nemotron_cell),
+         "trinity": (TRINITY, _trinity_cell),
+         "lfm2": (LFM2, _lfm2_cell),
+         "brumby": (BRUMBY, _brumby_cell)}
+# every cell's engine beside its own keywords
+_CELL_ENGINE = dict(block_size=16, prefill_chunk=512, preemption=False,
+                    paged_kernel="on", prefill_kernel="on")
 
 
 # rows of a compiled program that move or compute nothing
@@ -520,7 +527,39 @@ _NO_WORK = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "custom-call")
 
 
+def _cell_plan(build, engine):
+    """What the engine's plan knows of a cell with no compiler, no pool
+    and no weight (``ops/paged_kv.py::plan`` and ``init_pools`` under
+    ``jax.eval_shape``, ``model.init`` likewise): a slot's state, the
+    pools' bytes as the engine sizes them, the parameters and the
+    experts' tiles, under the names ``_cell_programs`` gives what the
+    engine itself holds."""
+    from megatron_llm_tpu.ops import paged_kv
+    from megatron_llm_tpu.ops.pallas import grouped_matmul
+    from megatron_llm_tpu.serving import EngineConfig
+    from megatron_llm_tpu.serving.kv_blocks import derive_num_blocks
+
+    model = build()
+    cfg = EngineConfig(**_CELL_ENGINE, **engine)
+    length = min(cfg.max_model_len, model.cfg.max_position_embeddings)
+    plan = paged_kv.plan(model.cfg, cfg.block_size, cfg.num_slots,
+                         -(-length // cfg.block_size), cfg.prefill_chunk,
+                         "pallas", "pallas")
+    blocks = derive_num_blocks(cfg.num_slots, cfg.block_size, length,
+                               cfg.num_blocks or None) if plan.paged else 1
+    leaves = jax.tree_util.tree_leaves
+    return {"state_bytes_per_slot": plan.state_bytes_per_slot,
+            "pool_bytes": sum(a.size * a.dtype.itemsize for a in leaves(
+                jax.eval_shape(lambda: plan.init_pools(blocks)))),
+            "parameters": sum(a.size for a in leaves(jax.eval_shape(
+                model.init, jax.random.PRNGKey(0)))),
+            "moe_expert_tiles": grouped_matmul.moe_expert_tiles(model.cfg)}
+
+
 def _cell_programs(chip, build, engine):
+    """``engine_prefill`` and ``engine_decode`` of a cell over abstract
+    weights, compiled for the described chip: what each holds, and which
+    of the mixers' scopes and kernels reach the optimised text."""
     from megatron_llm_tpu import hlo_collectives
     from megatron_llm_tpu.ops import paged_kv
     from megatron_llm_tpu.serving import EngineConfig, InferenceEngine
@@ -528,9 +567,8 @@ def _cell_programs(chip, build, engine):
     try:
         model = build()
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        eng = InferenceEngine(model, params, EngineConfig(
-            block_size=16, prefill_chunk=512, preemption=False,
-            paged_kernel="on", prefill_kernel="on", **engine))
+        eng = InferenceEngine(model, params, EngineConfig(**_CELL_ENGINE,
+                                                          **engine))
         # the recurrent state's shapes (a state-space layer's
         # ``ssm_state``, a retention layer's ``ret_state``), every slot's
         # or every row's
@@ -610,29 +648,16 @@ def compiled():
     return _child()
 
 
-@pytest.fixture(scope="module")
-def granite_compiled():
-    return _child("granite")
-
-
-@pytest.fixture(scope="module")
-def nemotron_compiled():
-    return _child("nemotron")
-
-
-@pytest.fixture(scope="module")
-def trinity_compiled():
-    return _child("trinity")
-
-
-@pytest.fixture(scope="module")
-def lfm2_compiled():
-    return _child("lfm2")
-
-
-@pytest.fixture(scope="module")
-def brumby_compiled():
-    return _child("brumby")
+def _cell_compiled(name):
+    """A cell's two programs as its child compiled them, and what the
+    ENGINE it built says of its bytes and counts: the plan's own, which
+    the cell's tier-1 test holds to the hand count."""
+    key, cell = CELLS[name]
+    found = _child(name)[key]
+    assert isinstance(found, dict), found
+    plan = _cell_plan(*cell())
+    assert {k: found[k] for k in plan} == plan
+    return found
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -691,20 +716,38 @@ def test_engine_tables_of_programs_compiled_for_v5e(compiled):
     assert set(programs["engine_sample_first"]["scopes"]) == {"sampler"}
 
 
+# Each cell is asked twice.  What the engine's plan knows with no compiler
+# (a slot's state, the pools, the parameters, the experts' tiles, each
+# against its hand count) is tier-1's, under the name the whole test had
+# when no PR saw a chip.  What needs the TPU's compiler is ``slow``: the
+# driver compiles and runs every cell on a real v5e for the parent and
+# the change of every PR, and a program that does not compile or fit is
+# a ``witness`` line of the ledger.  A ``model_config`` PR runs its own
+# (``-m slow -k "cells_programs and <family>"``) before it asks for the
+# chip.
+
+def test_the_granite_cells_programs_compile_and_fit_a_v5e():
+    """The Granite cell's bytes as the engine's plan counts them: nine
+    state-space layers' state a slot (float32) and three columns of the
+    convolution's width, 24 slots and the garbage row of them beside
+    13,313 pages of one attention layer."""
+    found = _cell_plan(*_granite_cell())
+    assert found["state_bytes_per_slot"] == 9 * (128 * 64 * 128 * 4
+                                                 + 3 * 8448 * 2)
+    state = 25 * found["state_bytes_per_slot"]
+    assert found["pool_bytes"] == state + 13313 * 16 * 4096
+
+
+@pytest.mark.slow
 @pytest.mark.time_limit(900)
-def test_the_granite_cells_programs_compile_and_fit_a_v5e(granite_compiled):
+def test_the_granite_cells_programs_compile_for_a_described_v5e():
     """The cell's two programs at its real sizes, for a described v5e:
     both compile with the attention layer's walk as a kernel and the
     state-space mixer's scopes in the text, and what each holds (the
     weights, the pools and the state; the chunk holds them twice, the
     step owns its own and gives them back) fits the chip's 16 GB with
     room for the probe."""
-    found = granite_compiled[GRANITE]
-    assert isinstance(found, dict), found
-    assert found["state_bytes_per_slot"] == 9 * (128 * 64 * 128 * 4
-                                                 + 3 * 8448 * 2)
-    state = 25 * found["state_bytes_per_slot"]
-    assert found["pool_bytes"] == state + 13313 * 16 * 4096
+    found = _cell_compiled("granite")
     for name, recurrence in (("engine_prefill", "ssm_scan"),
                              ("engine_decode", "ssm_step")):
         got = found[name]
@@ -738,16 +781,13 @@ def _the_step_is_the_kernel(name, got):
         assert got["state_rewrites"], name
 
 
-@pytest.mark.time_limit(900)
-def test_the_nemotron_cells_programs_compile_and_fit_a_v5e(nemotron_compiled):
-    """The Nemotron cell's two programs at its real sizes, for a
-    described v5e: both compile with the experts' grouped matmul at 2688
-    x 1856 (a block the whole 1856 wide) and the attention layers' walk
-    (16 query heads a KV head) as kernels and the mixer's scopes in the
-    text, and what each holds fits the chip's 16 GB with room for the
-    probe."""
-    found = nemotron_compiled[NEMOTRON]
-    assert isinstance(found, dict), found
+def test_the_nemotron_cells_programs_compile_and_fit_a_v5e():
+    """The Nemotron cell's bytes and counts as the engine's plan has
+    them: six Mamba-2 mixers' state a slot, 64 slots and the garbage row
+    beside 24,577 pages of two attention layers, the parameters at the
+    published widths, the experts' grouped matmul at 2688 x 1856 (a
+    block the whole 1856 wide, ``w_in`` laid out at 1920)."""
+    found = _cell_plan(*_nemotron_cell())
     assert found["state_bytes_per_slot"] == 6 * (64 * 64 * 128 * 4
                                                  + 3 * 6144 * 2)
     assert found["pool_bytes"] == (65 * found["state_bytes_per_slot"]
@@ -757,6 +797,17 @@ def test_the_nemotron_cells_programs_compile_and_fit_a_v5e(nemotron_compiled):
     assert found["parameters"] == 4_584_903_936 + 6 * 64 * 2688 * 64
     tiles = found["moe_expert_tiles"]
     assert (tiles["w_in"]["n"], tiles["w_out"]["tk"]) == (1920, 1856)
+
+
+@pytest.mark.slow
+@pytest.mark.time_limit(900)
+def test_the_nemotron_cells_programs_compile_for_a_described_v5e():
+    """The Nemotron cell's two programs at its real sizes, for a
+    described v5e: both compile with the experts' grouped matmul and the
+    attention layers' walk (16 query heads a KV head) as kernels and the
+    mixer's scopes in the text, and what each holds fits the chip's 16
+    GB with room for the probe."""
+    found = _cell_compiled("nemotron")
     for name, recurrence in (("engine_prefill", "ssm_scan"),
                              ("engine_decode", "ssm_step")):
         got = found[name]
@@ -775,14 +826,11 @@ def test_the_nemotron_cells_programs_compile_and_fit_a_v5e(nemotron_compiled):
         _the_step_is_the_kernel(name, got)
 
 
-def test_the_trinity_cells_programs_compile_and_fit_a_v5e(trinity_compiled):
-    """The Trinity cell's two programs at its real sizes, for a described
-    v5e: both compile with the experts' grouped matmul at 2048 x 1024,
-    BOTH groups' walks as kernels and the gate's and the output norms'
-    scopes in the text; the step owns its pools and gives them back, the
-    chunk holds them twice, and that fits the chip's 16 GB."""
-    found = trinity_compiled[TRINITY]
-    assert isinstance(found, dict), found
+def test_the_trinity_cells_programs_compile_and_fit_a_v5e():
+    """The Trinity cell's bytes and counts as the engine's plan has
+    them: no state, two groups of pages, ISSUE 47's parameters, the
+    experts' grouped matmul at 2048 x 1024."""
+    found = _cell_plan(*_trinity_cell())
     assert found["state_bytes_per_slot"] == 0
     # a full group of 32,769 pages over 2 layers, a window group of
     # 48 x 161 + 1 over 6, 16 tokens of 2,048 B a page and a layer
@@ -792,6 +840,17 @@ def test_the_trinity_cells_programs_compile_and_fit_a_v5e(trinity_compiled):
     tiles = found["moe_expert_tiles"]
     assert (tiles["w_in"]["k"], tiles["w_in"]["n"]) == (2048, 2048)
     assert (tiles["w_out"]["k"], tiles["w_out"]["n"]) == (1024, 2048)
+
+
+@pytest.mark.slow
+@pytest.mark.time_limit(900)
+def test_the_trinity_cells_programs_compile_for_a_described_v5e():
+    """The Trinity cell's two programs at its real sizes, for a described
+    v5e: both compile with the experts' grouped matmul, BOTH groups'
+    walks as kernels and the gate's and the output norms' scopes in the
+    text; the step owns its pools and gives them back, the chunk holds
+    them twice, and that fits the chip's 16 GB."""
+    found = _cell_compiled("trinity")
     for name, walk in (("engine_prefill", "paged_attention_prefill"),
                        ("engine_decode", "paged_attention_decode")):
         got = found[name]
@@ -811,17 +870,13 @@ def test_the_trinity_cells_programs_compile_and_fit_a_v5e(trinity_compiled):
             got["kernels"]), got["kernels"]
 
 
-@pytest.mark.time_limit(900)
-def test_the_lfm2_cells_programs_compile_and_fit_a_v5e(lfm2_compiled):
-    """The LFM2 cell's two programs at its real sizes, for a described
-    v5e: both compile with the experts' grouped matmul at 2048 x 1792 and
-    BOTH walks as kernels over a pool of 64-wide heads held two a row
-    (2,048 B a token an attention layer, not the 4,096 a last dimension
-    of 64 is laid out at), the three scopes of the convolution's mixer in
-    the text; the step owns its pools and gives them back, the chunk
-    holds them twice, and that fits the chip's 15.75 GB at 128 slots."""
-    found = lfm2_compiled[LFM2]
-    assert isinstance(found, dict), found
+def test_the_lfm2_cells_programs_compile_and_fit_a_v5e():
+    """The LFM2 cell's bytes and counts as the engine's plan has them: a
+    pool of 64-wide heads held two a row (2,048 B a token an attention
+    layer, not the 4,096 a last dimension of 64 is laid out at), eleven
+    conv layers' columns a slot, ISSUE 51's parameters, the experts'
+    grouped matmul at 2048 x 1792."""
+    found = _cell_plan(*_lfm2_cell())
     # eleven conv layers of two columns of 2,048 in bf16 a slot
     assert found["state_bytes_per_slot"] == 11 * 2 * 2048 * 2 == 90112
     # 16,385 pages of 16 tokens of 2,048 B over three attention layers,
@@ -833,6 +888,18 @@ def test_the_lfm2_cells_programs_compile_and_fit_a_v5e(lfm2_compiled):
     tiles = found["moe_expert_tiles"]
     assert (tiles["w_in"]["k"], tiles["w_in"]["n"]) == (2048, 3584)
     assert (tiles["w_out"]["k"], tiles["w_out"]["n"]) == (1792, 2048)
+
+
+@pytest.mark.slow
+@pytest.mark.time_limit(900)
+def test_the_lfm2_cells_programs_compile_for_a_described_v5e():
+    """The LFM2 cell's two programs at its real sizes, for a described
+    v5e: both compile with the experts' grouped matmul and BOTH walks as
+    kernels over the packed pool, the three scopes of the convolution's
+    mixer in the text; the step owns its pools and gives them back, the
+    chunk holds them twice, and that fits the chip's 15.75 GB at 128
+    slots."""
+    found = _cell_compiled("lfm2")
     for name, walk in (("engine_prefill", "paged_attention_prefill"),
                        ("engine_decode", "paged_attention_decode")):
         got = found[name]
@@ -851,21 +918,12 @@ def test_the_lfm2_cells_programs_compile_and_fit_a_v5e(lfm2_compiled):
         assert {"moe_experts", walk} <= set(got["kernels"]), got["kernels"]
 
 
-@pytest.mark.time_limit(900)
-def test_the_brumby_cells_programs_compile_and_fit_a_v5e(brumby_compiled):
-    """The Brumby cell's two programs at its real sizes (8 retention
-    layers at the published widths, the whole vocabulary, 16 slots, no
-    page), for a described v5e: the decode step holds a Mosaic call for
-    the recurrence (``retention_state_step``), the chunk one a layer
-    (``retention_state_chunk``, PR 55: before it the chunk was XLA's,
-    wrote one slot's state by a dynamic-update-slice a layer and held a
-    block's ``phi``, ``bf16[1, 128, 8, 5, 65, 128]``, among 0.64 GB of
-    temporaries), and neither rewrites an array of the state's shape
-    outside its kernel; the chunk OWNS its pool, so both programs alias
-    the whole state group, and weights, one state and a chunk's
-    temporaries fit the chip's 15.75 GB."""
-    found = brumby_compiled[BRUMBY]
-    assert isinstance(found, dict), found
+def test_the_brumby_cells_programs_compile_and_fit_a_v5e():
+    """The Brumby cell's bytes and counts as the engine's plan has them
+    (8 retention layers at the published widths, the whole vocabulary, 16
+    slots, no page): 4.67 GB of state that ``jax.eval_shape`` lays out
+    and nobody allocates."""
+    found = _cell_plan(*_brumby_cell())
     # 8 key-value heads of 65 rotations of [128, 128] and [128], float32
     layer = 8 * (65 * 128 * 128 + 65 * 128) * 4
     assert found["state_bytes_per_slot"] == 8 * layer == 274_759_680
@@ -873,6 +931,23 @@ def test_the_brumby_cells_programs_compile_and_fit_a_v5e(brumby_compiled):
     assert found["pool_bytes"] == 17 * 8 * layer
     # ISSUE 54's arithmetic: 8 x 330.35 M + 2 x 777.9 M + the final norm
     assert found["parameters"] == 4_198_652_928
+    assert found["moe_expert_tiles"] is None
+
+
+@pytest.mark.slow
+@pytest.mark.time_limit(900)
+def test_the_brumby_cells_programs_compile_for_a_described_v5e():
+    """The Brumby cell's two programs at its real sizes, for a described
+    v5e: the decode step holds a Mosaic call for the recurrence
+    (``retention_state_step``), the chunk one a layer
+    (``retention_state_chunk``, PR 55: before it the chunk was XLA's,
+    wrote one slot's state by a dynamic-update-slice a layer and held a
+    block's ``phi``, ``bf16[1, 128, 8, 5, 65, 128]``, among 0.64 GB of
+    temporaries), and neither rewrites an array of the state's shape
+    outside its kernel; the chunk OWNS its pool, so both programs alias
+    the whole state group, and weights, one state and a chunk's
+    temporaries fit the chip's 15.75 GB."""
+    found = _cell_compiled("brumby")
     for name, scope in (("engine_prefill", "retention_chunk"),
                         ("engine_decode", "retention_step")):
         got = found[name]

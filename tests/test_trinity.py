@@ -14,26 +14,21 @@ reference is the file the benchmark's probe loads
 (``benchmarks/reference/trinity.py``), loaded here by path.
 """
 
-import importlib.util
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _family
+from _family import BS, serve, tokens
 from megatron_llm_tpu import config as C
 from megatron_llm_tpu.models import moe
 from megatron_llm_tpu.models import transformer as tfm
-from megatron_llm_tpu.models.language_model import language_model_forward
 from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
 from megatron_llm_tpu.ops import paged_kv
-from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
-                                      SamplingParams)
-
-REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "reference")
+from megatron_llm_tpu.serving import SamplingParams
 
 # float32 on both sides, the same mathematics summed in another order.
 # Mellum's and Kanana's files hold 2e-4; here every sublayer's output is
@@ -41,61 +36,18 @@ REFERENCE = os.path.join(os.path.dirname(os.path.dirname(
 # in a small output is carried at full weight into the stream (read: up
 # to 1.6e-4 at depth 12), and the limit stands at 5e-4.  Every named
 # fault moves the logits by whole tenths
-LOGIT_TOL = 5e-4
+ROW = _family.FAMILIES["trinity"]
+LOGIT_TOL, CHUNK = ROW.tol, ROW.chunk
 WINDOW = 16
+BOUND = paged_kv.window_pages_bound(WINDOW, CHUNK, BS)      # 5 pages
 FAULTS = ("no_gate", "full_rotates", "no_output_norms", "no_scale",
           "bias_in_gates", "no_multiplier", "dense_layer_sparse",
           "all_full", "no_shared", "no_qk_norm", "float8")
-NAMES = {"sliding": "sliding_attention", "full": "full_attention"}
-# (depth, experts held of the router's 8): the cell's own shape, which
-# every test walks, and a deeper one for the two comparisons
-SIZES = {"depth8_half": (8, 4), "depth12_whole": (12, 8)}
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name, os.path.join(REFERENCE, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _ref_cfg(cfg):
-    period = [NAMES[t] for t in cfg.layer_types]
-    return {"num_hidden_layers": cfg.num_layers,
-            "hidden_size": cfg.hidden_size,
-            "num_attention_heads": cfg.num_attention_heads,
-            "num_key_value_heads": cfg.num_attention_heads_kv,
-            "rms_norm_eps": cfg.layernorm_epsilon,
-            "rope_theta": cfg.rope_theta,
-            "sliding_window": cfg.sliding_window_size,
-            "layer_types": period * (cfg.num_layers // len(period)),
-            "num_dense_layers": cfg.moe_first_dense_layers,
-            "num_experts": cfg.num_experts,
-            "experts_first": cfg.moe_experts_first,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "route_norm": cfg.norm_topk_prob,
-            "route_scale": cfg.moe_routed_scale,
-            "mup_enabled": True,
-            "vocab_size": cfg.padded_vocab_size}
-
-
-def _shake(params, key):
-    """Seeded N(0, 0.02) weights make attention nearly uniform and every
-    norm's scale is 1 at init: larger projections and scales that differ
-    (``tests/test_mellum.py::_shake`` says why).  The choice bias stays
-    as wide as it was drawn."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        names = [getattr(p, "key", None) for p in path]
-        if "scale" in names:
-            leaf = leaf + 0.3 * jax.random.normal(
-                jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
-        elif {"kernel", "w_in", "w_out"} & set(names):
-            leaf = leaf * (2.0 if "router" in names else 6.0)
-        out.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, out)
+# the row's two sizes: the cell's own shape (depth 8 holding experts 0-3
+# of the router's 8, as the benchmark's cell holds 0-63 of 128), which
+# every test walks, and a deeper one (two scanned periods, all 8 held)
+# for the two comparisons
+SIZES = {"family": None, "deep": "depth12_whole"}
 
 
 def _config(depth, held, **kw):
@@ -104,36 +56,13 @@ def _config(depth, held, **kw):
                           **share, **kw)
 
 
-def _family(size):
-    model = TrinityModel(_config(*SIZES[size]))
-    params = _shake(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-    cfg = _ref_cfg(model.cfg)
-    weights = _load("trinity_from_program").ProgramWeights(params, cfg)
-    return model, params, _load("trinity"), weights, cfg
-
-
 @pytest.fixture(scope="module")
 def family():
-    return _family("depth8_half")
-
-
-@pytest.fixture(scope="module")
-def deep():
-    return _family("depth12_whole")
-
-
-@pytest.fixture
-def either(request):
-    return request.getfixturevalue(request.param)
-
-
-def _tokens(n, seed=3, vocab=512):
-    return np.random.default_rng(seed).integers(1, vocab - 1, n).tolist()
+    return _family.built("trinity")
 
 
 @pytest.mark.parametrize("either,n", [("family", 5), ("family", 17),
-                                      ("family", 70), ("deep", 70)],
-                         indirect=["either"])
+                                      ("family", 70), ("deep", 70)])
 def test_full_forward_matches_the_reference(either, n):
     """The program's plain (cache-less) forward: two dense layers of the
     types their indices give them, the sparse layers before the first
@@ -141,13 +70,7 @@ def test_full_forward_matches_the_reference(either, n):
     position against the reference, at contexts under the window (5),
     one past it (17) and several windows long (70); at depth 12 two
     periods are scanned."""
-    model, params, ref, weights, cfg = either
-    toks = _tokens(n)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    want = np.asarray(ref.forward_logits(weights, cfg, toks))
-    assert want.std() > 0.1
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+    _family.full_forward_is_the_references("trinity", n, SIZES[either])
 
 
 def test_the_scan_starts_at_a_period_boundary_and_holds_one_period():
@@ -168,99 +91,42 @@ def test_the_scan_starts_at_a_period_boundary_and_holds_one_period():
     assert eqns(8) == eqns(12) == eqns(32)
 
 
-BS, CHUNK = 8, 16
-BOUND = paged_kv.window_pages_bound(WINDOW, CHUNK, BS)      # 5 pages
-
-
-def _engine(model, params, **kw):
-    kw = dict(dict(num_slots=2, block_size=BS, max_model_len=192,
-                   prefill_chunk=CHUNK), **kw)
-    return InferenceEngine(model, params, EngineConfig(**kw))
-
-
-def _tapped(eng):
-    """The engine's programs with their logits kept: the prefill step
-    returns its chunk's last live row; the decode step is run without its
-    sampler on the step's own arguments, as the benchmark's probe does."""
-    got = {}
-    prefill, decode = eng._prefill_step, eng._decode_step
-
-    def tapped_prefill(params, pages, tokens, start, valid, table):
-        out = prefill(params, pages, tokens, start, valid, table)
-        got[int(start) + int(valid) - 1] = np.asarray(out[0])
-        return out
-
-    def tapped_decode(params, pages, last, ctx, tables, active, *rest):
-        caches = paged_kv.step_caches(pages, tables, ctx, active,
-                                      eng.paged_kernel, eng._layer_groups)
-        logits, _ = language_model_forward(
-            params, last[:, None], ctx[:, None], None, eng.model.cfg,
-            rng_key=None, train=False, kv_caches=caches)
-        for s in np.flatnonzero(np.asarray(active) > 0):
-            got[int(np.asarray(ctx)[s])] = np.asarray(logits[s, 0])
-        return decode(params, pages, last, ctx, tables, active, *rest)
-
-    eng._prefill_step, eng._decode_step = tapped_prefill, tapped_decode
-    return got
-
-
 @pytest.mark.parametrize("either,prompt,new", [
     ("family", 5, 14), ("family", 64, 10), ("family", 150, 6),
-    ("deep", 150, 6)], indirect=["either"])
+    ("deep", 150, 6)])
 def test_the_engine_over_two_groups_matches_one_full_forward(
-        either, prompt, new):
+        engines, either, prompt, new):
     """Chunked prefill then decode through the engine's own programs
     over the two-group pool against the reference's ONE full forward: a
     prompt under the window whose decode steps cross it, one that ends on
     a page's and the window's edge, one of nine windows.  Window pages
     have gone back before most compared positions, and the full layers,
     which carry no positions, see keys far behind any window."""
-    model, params, ref, weights, cfg = either
-    eng = _engine(model, params)
-    got = _tapped(eng)
-    toks = _tokens(prompt, seed=5)
-    req = eng.submit(toks, SamplingParams(max_new_tokens=new,
-                                          temperature=0.0))
     held = []
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
-        held.append(eng.blocks.stats()["window_blocks_in_use"])
+    eng, since, _ = _family.chunked_prefill_then_decode_is_one_forward(
+        engines, "trinity", prompt, new, size=SIZES[either],
+        each_step=lambda eng, req: held.append(
+            eng.blocks.stats()["window_blocks_in_use"]))
     assert max(held) <= BOUND
-    seq = toks + list(req.out_tokens)
-    want = np.asarray(ref.forward_logits(weights, cfg, seq))
-    rows = sorted(got)
-    assert rows[-1] == prompt + new - 2 and prompt - 1 in rows
-    assert len(rows) == -(-prompt // CHUNK) + new - 1
-    np.testing.assert_allclose(np.stack([got[t] for t in rows]), want[rows],
-                               atol=LOGIT_TOL, rtol=0)
-    # greedy: the engine's tokens are the reference's choices
-    assert list(req.out_tokens) == [int(t) for t in
-                                    want[prompt - 1:-1].argmax(-1)]
-    stats = eng.stats()
+    stats, _ = since()
     if prompt > 2 * WINDOW:
         assert stats["kv_window_pages_returned"] > 0
     # the router's histogram is over all 8, the held count over the share
-    sparse = model.cfg.num_sparse_layers
-    assert stats["moe_assignments"] == (prompt + new - 1) * 4 * sparse
-    if model.cfg.holds_a_share:
+    cfg = eng.model.cfg
+    assert stats["moe_assignments"] == (
+        prompt + new - 1) * 4 * cfg.num_sparse_layers
+    if cfg.holds_a_share:
         assert 0 < stats["moe_assignments_held"] < stats["moe_assignments"]
     else:
         assert stats["moe_assignments_held"] == stats["moe_assignments"]
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_each_named_fault_fails_by_many_tolerances(family, fault):
+def test_each_named_fault_fails_by_many_tolerances(fault):
     """The same comparison against each FAULTY reference, at a context of
     nine windows: every one is far beyond a hundred tolerances."""
-    model, params, ref, weights, cfg = family
-    toks = _tokens(150, seed=5)
-    got = np.asarray(model(params, jnp.asarray([toks], jnp.int32),
-                           train=False)[0])
-    faulty = np.asarray(ref.forward_logits(weights, cfg, toks,
-                                           faults={fault}))
-    apart = np.abs(got - faulty).max(axis=-1)
-    assert apart[2 * WINDOW:].max() > 100 * LOGIT_TOL, apart.max()
+    apart = _family.a_named_fault_is_told("trinity", fault, n=150,
+                                          beyond=2 * WINDOW)
     if fault == "all_full":
         # nothing lies behind a window yet
         assert apart[:WINDOW].max() < LOGIT_TOL
@@ -273,14 +139,15 @@ def test_a_uniform_scale_of_a_sublayers_output_is_hidden_by_its_norm(family):
     hundredths) lets through, a hundredth of what any named fault
     does."""
     model, params = family[:2]
-    toks = jnp.asarray([_tokens(40)], jnp.int32)
+    toks = jnp.asarray([tokens(40)], jnp.int32)
     doubled = jax.tree_util.tree_map(lambda a: a, params)
     for stack in ("layers", "dense_layers"):
         dense = doubled["transformer"][stack]["attention"]["dense"]
         dense["kernel"] = dense["kernel"] * 2.0
-    np.testing.assert_allclose(
-        np.asarray(model(doubled, toks, train=False)),
-        np.asarray(model(params, toks, train=False)), atol=5e-3, rtol=0)
+    forward = jax.jit(lambda p: model(p, toks, train=False))
+    np.testing.assert_allclose(np.asarray(forward(doubled)),
+                               np.asarray(forward(params)), atol=5e-3,
+                               rtol=0)
 
 
 def _sparse_layer(params, j):
@@ -296,11 +163,10 @@ def test_the_two_shares_sum_to_the_uncut_layer_before_its_output_norm():
     and the shares normed apart do not sum to the normed layer, so a
     deployment exchanges the experts' outputs, not the layer's."""
     whole_cfg = _config(8, 8)
-    whole = _shake(TrinityModel(whole_cfg).init(jax.random.PRNGKey(0)),
-                   jax.random.PRNGKey(1))
-    ref = _load("trinity")
-    rcfg = _ref_cfg(whole_cfg)
-    weights = _load("trinity_from_program").ProgramWeights(whole, rcfg)
+    whole = _family.shaken("trinity", TrinityModel(whole_cfg))
+    ref = _family.load("trinity")
+    rcfg = ROW.ref_cfg(whole_cfg, CHUNK)
+    weights = _family.load("trinity_from_program").ProgramWeights(whole, rcfg)
     layer = _sparse_layer(whole, 0)
     m = jax.random.normal(jax.random.PRNGKey(8), (1, 60, 128), jnp.float32)
     want = np.asarray(ref.moe_out(m[0], weights.layer(2), weights, rcfg, 2,
@@ -335,7 +201,7 @@ def test_the_two_shares_sum_to_the_uncut_layer_before_its_output_norm():
     assert apart > 0.1
 
 
-def test_a_dense_layers_pages_are_of_its_types_group(family):
+def test_a_dense_layers_pages_are_of_its_types_group(family, engines):
     """A layer's group comes from its index in the WHOLE stack, the dense
     layers included: they attend, so they hold pages; layers 0 and 1 are
     window layers, layer 3 (sparse) the first full one."""
@@ -348,14 +214,10 @@ def test_a_dense_layers_pages_are_of_its_types_group(family):
     pools = paged_kv.init_pools(cfg, 41, BS, window_blocks=11)
     assert [p["k_pages"].shape[0] for p in pools] == [11, 11, 11, 41] * (
         L // 4)
-    eng = _engine(model, params)
+    eng = engines("trinity")
     assert eng._layer_groups == groups
     assert eng.stats()["window_blocks_total"] == 2 * BOUND
-    req = eng.submit(_tokens(100, seed=7),
-                     SamplingParams(max_new_tokens=4, temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-        eng.blocks.check_invariants()
+    serve(eng, tokens(100, seed=7), 4)
     last = eng.loop_profiler.records()[-1]
     # 13 full pages on a quarter of the layers, <= 5 window pages on the
     # others, the dense layers' among them
@@ -365,15 +227,14 @@ def test_a_dense_layers_pages_are_of_its_types_group(family):
         13 * (L // 4) + BOUND * (3 * L // 4)) * page
 
 
-def test_a_slot_is_reused_and_two_requests_run_side_by_side(family):
+def test_a_slot_is_reused_and_two_requests_run_side_by_side(engines):
     """Two long requests decode side by side, each within its bound of
     window pages, the invariants held after every step; then each alone
     in a reused slot answers as it did beside the other."""
-    model, params = family[:2]
-    eng = _engine(model, params)
+    eng = engines("trinity")
 
-    def serve(*sized):
-        reqs = [eng.submit(_tokens(n, seed=s),
+    def side_by_side(*sized):
+        reqs = [eng.submit(tokens(n, seed=s),
                            SamplingParams(max_new_tokens=6, temperature=0.0))
                 for n, s in sized]
         while any(r.finish_reason is None for r in reqs):
@@ -383,9 +244,9 @@ def test_a_slot_is_reused_and_two_requests_run_side_by_side(family):
                 len(sized) * BOUND)
         return [list(r.out_tokens) for r in reqs]
 
-    both = serve((90, 11), (70, 12))
+    both = side_by_side((90, 11), (70, 12))
     assert eng.stats()["window_blocks_in_use"] == 0
-    assert serve((90, 11)) + serve((70, 12)) == both
+    assert side_by_side((90, 11)) + side_by_side((70, 12)) == both
 
 
 def test_the_legacy_contiguous_cache_reaches_the_gate_and_the_norms(family):
@@ -396,7 +257,7 @@ def test_the_legacy_contiguous_cache_reaches_the_gate_and_the_norms(family):
         _forward_with_cache, init_kv_caches)
 
     model, params = family[:2]
-    toks = jnp.asarray([_tokens(23, seed=9)], jnp.int32)
+    toks = jnp.asarray([tokens(23, seed=9)], jnp.int32)
     want = np.asarray(model(params, toks, train=False))
     caches = init_kv_caches(model.cfg, 1, 32)
     part, caches = _forward_with_cache(model, params, toks[:, :20], caches, 0)
@@ -456,20 +317,13 @@ def test_an_untyped_gated_model_is_refused_by_its_own_rows():
         said = C.refusal(cfg, (what,))
         assert said.startswith(C.GATE) and what in said
         with pytest.raises(ValueError) as raised:
-            _engine(model, params, **on)
+            _family.engine(model, params, prefill_chunk=CHUNK, **on)
         assert str(raised.value) == said
         assert C.refusal(cfg.replace(attention_output_gate=False),
                          (what,)).startswith(C.OUTPUT_NORMS)
-    eng = _engine(model, params)
-    req = eng.submit(_tokens(20), SamplingParams(max_new_tokens=3,
-                                                 temperature=0.0))
-    while req.finish_reason is None:
-        assert eng.step()
-    toks = _tokens(20)
-    for _ in range(3):
-        logits = model(params, jnp.asarray([toks], jnp.int32), train=False)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    assert toks[20:] == list(req.out_tokens)
+    eng = _family.engine(model, params, prefill_chunk=CHUNK)
+    assert _family.is_greedy(model, params, tokens(20),
+                             serve(eng, tokens(20), 3).out_tokens)
 
 
 def test_what_the_config_does_not_take_is_said_by_name():
