@@ -4,8 +4,8 @@ attention under that choice, for the decode step and the prefill chunk.
 
 ``ops/dsa.py`` says what is computed (and is the dense path these
 kernels are tested against); ``ops/paged_kv.py::PagedKVCache.attend``
-is the one caller.  Three kernels, five names on a profile's
-``XLA Ops`` line:
+is the one caller.  Two kernels of this module's and the shared paged
+walk under a mask, six names on a profile's ``XLA Ops`` line:
 
 * ``dsa_index_scores_decode`` / ``dsa_index_scores_prefill``: a walk
   over each row's LIVE pages of the indexer's pool (``index_pages``
@@ -33,9 +33,15 @@ is the one caller.  Three kernels, five names on a profile's
   mask holds whatever the buffer held, and the walks below never fetch
   it.  Never ``approx_max_k``.
 * ``paged_attention_sparse_decode`` / ``paged_attention_prefill_masked``:
-  ``paged_attention.py``'s walk over the row's live pages of K and V
-  with the mask applied to the scores of every block before the online
-  softmax, so the query attends the chosen keys and no other.
+  NOT a kernel of this module: ``paged_attention.py``'s own walk
+  (``_walk_call(..., mask=)``) over the row's live pages of K and V,
+  the mask applied to the scores of every block before the online
+  softmax, so the query attends the chosen keys and no other.  Its
+  fetches, its hand-over of a grid step's first block and its blocks
+  that skip the position masks are the shared walk's as they stand (a
+  copy of that walk lived here until PR 57 and had none of them: 1,043
+  -> 665 us a decode call of 8 rows of 14-21 thousand keys on a v5e,
+  bit for bit the same outputs).
 
 Why the decode step walks every live page of K and V and masks, rather
 than gathering the chosen tokens out of the pages: a token's keys and
@@ -67,7 +73,7 @@ from jax.experimental.pallas import tpu as pltpu
 from megatron_llm_tpu.ops import dsa as _dsa
 from megatron_llm_tpu.ops.pallas import paged_attention as _pa
 from megatron_llm_tpu.ops.pallas.paged_attention import (
-    NEG_INF, _pages_per_block, _softmax_block, _softmax_finish)
+    NEG_INF, _pages_per_block)
 
 _TILE = 8                    # rows of a vector register
 _SELECT_ROWS = 32            # queries a chunk's select step holds in VMEM
@@ -355,183 +361,7 @@ def _select_call(n_live, row_pos, scores, *, topk, name, interpret, group):
 
 
 # ---------------------------------------------------------------------------
-# paged attention under the mask: paged_attention.py's walk, plus a mask
-# ---------------------------------------------------------------------------
-
-def _masked_walk_body(bt_ref, cl_ref, vl_ref, q_ref, mask_ref, k_hbm, v_hbm,
-                      o_ref, kbuf, vbuf, mbuf, sem, m_scr, l_scr, acc_scr,
-                      *, scale, decode):
-    """One (row, q-block): walk pages 0 .. last of the row's table in
-    blocks of ``kp`` pages, block j+1 on its way while block j is
-    computed.  Decode: ``q_ref`` [1, 1, nh, d] and the row's whole mask
-    ``mask_ref`` [1, nblk, TB * g] in VMEM (lane c of a block is key
-    c // g, group c % g).  A chunk: ``q_ref`` [1, g, qpg, bq, d], and the
-    mask stays in HBM ``[S, nblk, C, TB]``, its block fetched with the
-    pages."""
-    s = pl.program_id(0)
-    _, kp, bs, g, d = kbuf.shape
-    T = kp * bs
-    lanes = T * g
-    if decode:
-        bq, (nh, qpg) = 1, (q_ref.shape[2], q_ref.shape[2] // g)
-    else:
-        _, _, qpg, bq, _ = q_ref.shape
-    R = bq * qpg
-    ctx, n = cl_ref[s], vl_ref[s]
-    q0 = pl.program_id(1) * bq
-    # newest key a LIVE row of the q-block attends: the mask beyond its
-    # block was never written (``_select`` stops at the slot's live blocks)
-    top = jnp.minimum(ctx + jnp.minimum(q0 + bq, n), bt_ref.shape[1] * bs) - 1
-    last = top // bs
-    nblk = jnp.where(n > q0, last // kp + 1, 0)
-
-    def block_dma(j, slot, start):
-        p0 = j * kp
-
-        def page_dma(i, carry):
-            page = bt_ref[s, p0 + i]
-            for which, (hbm, buf) in enumerate(((k_hbm, kbuf),
-                                                (v_hbm, vbuf))):
-                cp = pltpu.make_async_copy(hbm.at[page], buf.at[slot, i],
-                                           sem.at[which, slot])
-                cp.start() if start else cp.wait()
-            return carry
-
-        jax.lax.fori_loop(0, jnp.minimum(kp, last - p0 + 1), page_dma, 0)
-        if not decode:
-            cp = pltpu.make_async_copy(
-                mask_ref.at[s, j, pl.ds(q0, bq)], mbuf.at[slot],
-                sem.at[2, slot])
-            cp.start() if start else cp.wait()
-
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(nblk > 0)
-    def _first_block():
-        block_dma(0, 0, True)
-
-    native = decode and kbuf.dtype == q_ref.dtype
-    q = q_ref[0] if native else q_ref[0].astype(jnp.float32)
-
-    def block(j, carry):
-        slot = jax.lax.rem(j, 2)
-
-        @pl.when(j + 1 < nblk)
-        def _next_block():
-            block_dma(j + 1, 1 - slot, True)
-
-        block_dma(j, slot, False)
-        k = kbuf[slot].reshape(lanes, d)
-        v = vbuf[slot].reshape(lanes, d).astype(jnp.float32)
-        if not native:
-            k = k.astype(jnp.float32)
-        base = j * kp * bs
-        # pages of the buffer past the last live one hold what an earlier
-        # block left there: masked below, and zeroed here so that
-        # 0 x (whatever they are) adds nothing
-        v = jnp.where(base + jax.lax.div(_iota((lanes, 1), 0), g) <= top,
-                      v, 0.0)
-        if decode:
-            lane = _iota((nh, lanes), 1)
-            own = jax.lax.rem(lane, g) == jax.lax.div(_iota((nh, lanes), 0),
-                                                      qpg)
-            chosen = mask_ref[0, pl.ds(j, 1), :] > 0.5 * NEG_INF
-            valid = own & chosen & (base + jax.lax.div(lane, g) <= ctx)
-            sq = jax.lax.dot_general(
-                q[0], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, slice(None))
-            return carry
-        # a chunk: the rows of one kv group [qpg * bq, d], head-major
-        # (flat row f is head f // bq of the group, chunk row f % bq),
-        # against that group's keys [T, d]
-        k, v = k.reshape(T, g, d), v.reshape(T, g, d)
-        chosen = jnp.concatenate([mbuf[slot]] * qpg,
-                                 axis=0) > 0.5 * NEG_INF         # [R, T]
-        valid = chosen & (base + _iota((R, T), 1)
-                          <= ctx + q0 + jax.lax.rem(_iota((R, T), 0), bq))
-        for grp in range(g):
-            sq = jax.lax.dot_general(
-                q[grp].reshape(R, d), k[:, grp, :],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale     # [R, T]
-            _softmax_block(sq, valid, v[:, grp, :], m_scr, l_scr, acc_scr,
-                           slice(grp * R, (grp + 1) * R))
-        return carry
-
-    jax.lax.fori_loop(0, nblk, block, 0)
-    if decode:
-        o_ref[0] = _softmax_finish(l_scr, acc_scr, slice(None))[None].astype(
-            o_ref.dtype)
-    else:
-        for grp in range(g):
-            o_ref[0, grp] = _softmax_finish(
-                l_scr, acc_scr, slice(grp * R, (grp + 1) * R)
-            ).reshape(qpg, bq, d).astype(o_ref.dtype)
-
-
-def _masked_walk(q, mask, k_pages, v_pages, block_tables, context_lens,
-                 valid_lens, *, kp, scale, name):
-    """Decode: q [S, 1, nh, d], mask [S, nblk, TB * g].  A chunk: q
-    [S, C, nh, d], mask [S, nblk, C, TB].  Returns q's shape."""
-    S, C, nh, d = q.shape
-    bs, g = k_pages.shape[1], k_pages.shape[2]
-    qpg = nh // g
-    decode = C == 1
-    T = kp * bs
-    if decode:
-        bq = 1
-        q_in, q_block = q, (1, 1, nh, d)
-        q_map = lambda s, qi, *_: (s, qi, 0, 0)
-        mask_spec = pl.BlockSpec((1,) + mask.shape[1:],
-                                 lambda s, qi, *_: (s, 0, 0),
-                                 memory_space=pltpu.VMEM)
-        out_shape = q.shape
-    else:
-        bq = min(_PREFILL_BLOCK_Q, C)
-        while C % bq:
-            bq -= 1
-        # [S, g, qpg, C, d]: a group's rows head-major, so that a block's
-        # [qpg, bq, d] is [qpg * bq, d] as it lies
-        q_in = jnp.transpose(q.reshape(S, C, g, qpg, d), (0, 2, 3, 1, 4))
-        q_block = (1, g, qpg, bq, d)
-        q_map = lambda s, qi, *_: (s, 0, 0, qi, 0)
-        mask_spec = pl.BlockSpec(memory_space=pl.ANY)
-        out_shape = q_in.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, C // bq),
-        in_specs=[pl.BlockSpec(q_block, q_map, memory_space=pltpu.VMEM),
-                  mask_spec,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(q_block, q_map, memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, kp, bs, g, d), k_pages.dtype),
-            pltpu.VMEM((2, kp, bs, g, d), v_pages.dtype),
-            pltpu.VMEM((2, bq, T), jnp.float32),
-            pltpu.SemaphoreType.DMA((3, 2)),
-            pltpu.VMEM((bq * nh, 1), jnp.float32),
-            pltpu.VMEM((bq * nh, 1), jnp.float32),
-            pltpu.VMEM((bq * nh, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_masked_walk_body, scale=scale, decode=decode),
-        name=name, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
-        compiler_params=_params(), interpret=_pa._INTERPRET,
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      valid_lens.astype(jnp.int32), q_in, mask, k_pages, v_pages)
-    if decode:
-        return out
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(S, C, nh, d)
-
-
-# ---------------------------------------------------------------------------
-# the public entry: scores, choice, attention
+# the public entry: scores, choice, and the shared walk under the choice
 # ---------------------------------------------------------------------------
 
 def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
@@ -570,9 +400,10 @@ def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
         # blocks past a step's ``n_live`` ride along unwritten; the walk
         # stops at each row's own last live page
         mask = jnp.repeat(jnp.transpose(mask, (1, 0, 2)), g, axis=-1)
-        return _masked_walk(q, mask, k_pages, v_pages, *tables, kp=kp,
-                            scale=softmax_scale,
-                            name="paged_attention_sparse_decode")
+        return _pa._walk_call(
+            q, k_pages, v_pages, *tables, None, None, scale=softmax_scale,
+            window=None, block_q=1, name="paged_attention_sparse_decode",
+            mask=mask)
     pad = -n % rows
     if pad:
         q, iq, iw = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
@@ -582,7 +413,11 @@ def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
                            name="dsa_index_scores_prefill")
     mask = _select(scores, row_pos, n_live, topk=topk,
                    name="dsa_select_prefill")
-    out = _masked_walk(q, mask, k_pages, v_pages, *tables, kp=kp,
-                       scale=softmax_scale,
-                       name="paged_attention_prefill_masked")
+    bq = min(_PREFILL_BLOCK_Q, n + pad)
+    while (n + pad) % bq:       # q-blocks tile the padded chunk exactly
+        bq -= 1
+    out = _pa._walk_call(
+        q, k_pages, v_pages, *tables, None, None, scale=softmax_scale,
+        window=None, block_q=bq, name="paged_attention_prefill_masked",
+        mask=mask)
     return out[:, :n]

@@ -85,6 +85,8 @@ rows x mean live pages (K + V bytes)  pages   us a call     us a live page  of 8
   of 2,048
 64 x 127, 2 kv heads (16 KiB)         8,096   976 -> 708    0.121 -> 0.088  17 -> 23%
 16 x 1,063, a latent pool (20 KiB)   17,011  1,038 -> 620   0.061 -> 0.036  41 -> 69%
+8 x 1,151 under a mask of chosen      9,205  1,043 -> 665   0.113 -> 0.072  35 -> 55%
+  keys, 4 kv heads (PR 57, below)
 ===================================  ======  ============  ==============  ============
 
 Split at the second row before the change (PERF.md section 6, PR 48): the
@@ -118,6 +120,31 @@ start: ``paged_decode_4_kv_heads_48_slots`` of
 ``tests/test_tpu_aot_compile.py`` 0.1 -> 0.7 s to trace and lower and
 0.7 -> 2.3 s to compile for a described v5e, the 512-token chunk at 4 kv
 heads 0.2 -> 0.3 and 4.1 -> 7.4 (this sandbox's CPU, PR 48).
+
+Under a MASK of chosen keys (``_walk_call(..., mask=)``: the learned
+sparse attention of ``dsa_attention.py``, which until PR 57 kept a copy
+of this walk as it stood before PR 48) a query attends a key only if
+the mask says so too.  A decode step holds its row's whole mask
+``[blocks, T * g]`` in VMEM; a chunk's stays in HBM ``[S, blocks, C,
+T]`` and a block's ``[bq, T]`` slice rides with the block's pages on a
+semaphore row of its own, the next grid step's first slice with that
+step's first block.  A block that no position mask can touch asks the
+chosen lanes and nothing else.  The walk ends at the block of the
+slot's last LIVE query (past it nobody wrote the mask), and a q-block
+with no live row walks nothing.  Without a mask the traced kernel is
+what it was, equation for equation.  Chip runs of PR 57, the kernel
+alone at Keye's shapes (pages ``[16, 4, 128]`` bf16, 32 heads, a table
+of 2,112, top-k 2,048), 20 calls chained, the copy -> this walk, the
+outputs bit for bit the same: 8 rows of 14-21 thousand keys at one
+query 1,043 -> 665 us a call (794 with every block taken as an edge
+block: the fetches' share of the gain is two thirds), 5 live rows of 8
+594 -> 387; a ``[1, 512]`` chunk over 17,200 keys 2,685 -> 2,606 (its
+q-blocks of 128 rows of 32 heads are bound by their fp32 products; the
+rows stay in the order above, ``r // qpg``, and the mask's slice is
+spread over a group's heads by a sublane broadcast once a block), 300
+live rows over 9,000 1,152 -> 1,110.  The decode kernel under a mask
+takes 1.7 s to trace and lower in a cold process where the copy took
+0.2, once a program.
 
 A LATENT pool (``ops/paged_kv.py``: one array ``[P, bs, W]`` a layer,
 a token's row its normed latent, then the one rotary key, then zeros up
@@ -291,26 +318,37 @@ def _pages_per_block(block_size, g, d, dtype, M):
 
 
 def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
-               quantized, scale, window, qpg, value_width):
+               quantized, scale, window, qpg, value_width, masked):
     """One (slot, q-block): walk pages ``first .. last`` of the slot's
     table in blocks of ``kp`` pages, block j+1 on its way from HBM while
     block j is computed, and the NEXT grid step's first block while the
     last one is.  Nothing of the table outside that range is read.
     ``value_width`` (a decode step's only): the pool is a latent one, ONE
     array of pages ``[bs, W]`` whose rows are the keys of one kv group and
-    whose first ``value_width`` columns are the values."""
+    whose first ``value_width`` columns are the values.  ``masked``: a
+    mask of CHOSEN keys comes after the queries (``_walk_kernel`` has its
+    two layouts), and a query attends a key only if the mask says so too;
+    the walk then ends at the block of the slot's last LIVE query, past
+    which nobody wrote the mask."""
     latent = value_width is not None
     n_pool = 1 if latent else 4 if quantized else 2
+    _, bq, nh, _ = q_ref.shape
+    mask_ref = mbuf = None
+    if masked:
+        mask_ref, refs = refs[0], refs[1:]
     hbm = refs[:n_pool]                   # K, V[, K scales, V scales]
     o_ref = refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
-    sem, half_ref, m_scr, l_scr, acc_scr = refs[2 * n_pool + 1:]
+    scratch = refs[2 * n_pool + 1:]
+    if masked and bq > 1:
+        # a chunk's mask stays in HBM: a block's slice comes with its pages
+        mbuf, scratch = scratch[0], scratch[1:]
+    sem, half_ref, m_scr, l_scr, acc_scr = scratch
     s, qi = pl.program_id(0), pl.program_id(1)
     if latent:
         (_, kp, bs, d), g = bufs[0].shape, 1
     else:
         _, kp, bs, g, d = bufs[0].shape
-    _, bq, nh, _ = q_ref.shape
     T = kp * bs                           # keys of a block
     lanes = T * g                         # (page, position, group) triples
     R = bq * qpg                          # query rows of one kv group
@@ -320,16 +358,24 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
         page, in how many blocks, and the newest key any row attends."""
         # padded tail rows of a chunk may point past the table: the walk
         # ends where the table ends
-        top = jnp.minimum(cl_ref[s] + (qi + 1) * bq,
-                          bt_ref.shape[1] * bs) - 1
+        if masked:
+            # and at the newest key a LIVE row attends: the mask past its
+            # block holds whatever the buffer held
+            top = jnp.minimum(
+                cl_ref[s] + jnp.minimum((qi + 1) * bq, vl_ref[s]),
+                bt_ref.shape[1] * bs) - 1
+        else:
+            top = jnp.minimum(cl_ref[s] + (qi + 1) * bq,
+                              bt_ref.shape[1] * bs) - 1
         last = top // bs
         if window is None:
             first = 0
         else:
             first = jnp.maximum(cl_ref[s] + qi * bq - window + 1, 0) // bs
         # a slot with no token in this call walks nothing: no fetch,
-        # zeros out
-        nblk = jnp.where(vl_ref[s] > 0, (last - first) // kp + 1, 0)
+        # zeros out (under a mask: a q-block with no live row)
+        nblk = jnp.where(vl_ref[s] > (qi * bq if masked else 0),
+                         (last - first) // kp + 1, 0)
         return first, last, nblk, top
 
     ctx = cl_ref[s]                       # keys cached before this call
@@ -341,8 +387,8 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
     s_next = jnp.where(wrap, s + 1, s)
     more = s_next < pl.num_programs(0)
     s_next = jnp.where(more, s_next, s)
-    first_next, last_next, nblk_next, _ = span(
-        s_next, jnp.where(wrap, 0, qi + 1))
+    qi_next = jnp.where(wrap, 0, qi + 1)
+    first_next, last_next, nblk_next, _ = span(s_next, qi_next)
     nblk_next = jnp.where(more, nblk_next, 0)
     # the buffer half that holds this step's block 0: the step before
     # left word of it, having put the block on its way
@@ -361,14 +407,23 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
                      bufs[which].at[half, pl.ds(i, 1)]))
         return pltpu.make_async_copy(src, dst, sem.at[which, half])
 
-    def start_block(s, first, last, j, half):
+    def mask_copy(s, qi, j, half):
+        # block j's slice of q-block qi's rows of a chunk's mask
+        return pltpu.make_async_copy(
+            mask_ref.at[s, j, pl.ds(qi * bq, bq)], mbuf.at[half],
+            sem.at[n_pool, half])
+
+    def start_block(s, first, last, j, half, qi=None):
         """Put block ``j`` of a walk ``first .. last`` of slot ``s`` on
         its way into buffer ``half``.  Into a STATIC half, a block whose
         pages are all live (every block but a walk's last) is issued from
         a loop of static trip count, unrolled: every descriptor's
         destination is a constant and neighbouring pages' table reads and
         address sums share bundles.  The partial block, and a block into
-        a half known only at run time, keep the dynamic loop."""
+        a half known only at run time, keep the dynamic loop.  Under a
+        chunk's mask the slice of q-block ``qi`` rides with the pages."""
+        if mbuf is not None:
+            mask_copy(s, qi, j, half).start()
         p0 = first + j * kp
 
         def page_start(i, carry=0):
@@ -396,6 +451,9 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
         semaphore counts bytes, so ONE descriptor the size of the buffer
         half awaits a whole block's pages whichever they were; the partial
         block awaits its pages one by one."""
+        if mbuf is not None:
+            mask_copy(0, 0, 0, half).wait()
+
         @pl.when(live == kp)
         def _whole():
             for which in range(n_pool):
@@ -412,6 +470,10 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
 
             jax.lax.fori_loop(0, live, page_wait, 0)
 
+    def q_block(mine):
+        # whose slice of a chunk's mask: this step's or the next's
+        return None if mbuf is None else jnp.where(mine, qi, qi_next)
+
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
@@ -424,7 +486,8 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
     def _unstarted_block():
         start_block(jnp.where(mine, s, s_next),
                     jnp.where(mine, first, first_next),
-                    jnp.where(mine, last, last_next), 0, half0)
+                    jnp.where(mine, last, last_next), 0, half0,
+                    q_block(mine))
 
     def iota(shape, dim):
         return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
@@ -454,17 +517,18 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
         own = None if g == 1 else (
             jax.lax.rem(lane, g) == jax.lax.div(iota((nh, lanes), 0), qpg))
 
-    def attend(base, half, masked):
-        """Fold the block in buffer ``half``, whose first key stands at
-        position ``base``, into the running softmax.  ``masked``: some key
-        of it is dropped for some row (it lies past a row's position,
-        behind a row's window, or on a page past the last live one); a
-        block that no mask can touch skips them all."""
+    def attend(j, base, half, edge):
+        """Fold block ``j`` in buffer ``half``, whose first key stands at
+        position ``base``, into the running softmax.  ``edge``: some key
+        of it is dropped for some row by where it stands (past a row's
+        position, behind a row's window, or on a page past the last live
+        one); a block that none of these can touch skips them all, and
+        under a mask of chosen keys asks nothing else."""
         if latent:
             # keys and values are one fetch: the rows, and their first
             # columns.  Pages past the last live one are zeroed whole
             k = bufs[0][half].reshape(lanes, d)
-            if masked:
+            if edge:
                 k = jnp.where(base + iota((lanes, 1), 0) <= top, k,
                               jnp.zeros_like(k))
             if not native:
@@ -481,15 +545,24 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
         # buffer pages past the last live one hold what an earlier block
         # left there: their scores are masked below, and their values
         # zeroed here so that 0 x (whatever they are) adds nothing
-        if masked and not latent:
+        if edge and not latent:
             v = jnp.where(
                 base + jax.lax.div(iota((lanes, 1), 0), g) <= top, v, 0.0)
+
+        def both(a, b):
+            return b if a is None else a if b is None else a & b
+
         if bq == 1:
             valid = own
             if masked:
-                valid = _valid_keys(base + jax.lax.div(lane, g), ctx, window)
-                if own is not None:
-                    valid &= own
+                # the row's whole mask is here: block j's lanes, as the
+                # scores' (key c // g, group c % g)
+                valid = both(own,
+                             mask_ref[0, pl.ds(j, 1), :] > 0.5 * NEG_INF)
+            if edge:
+                valid = both(
+                    _valid_keys(base + jax.lax.div(lane, g), ctx, window),
+                    valid)
             sq = jax.lax.dot_general(
                 q[0], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [nh, lanes]
@@ -500,9 +573,14 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
         k, v = k.reshape(T, g, d), v.reshape(T, g, d)
         valid = None
         if masked:
-            valid = _valid_keys(
+            # a chunk row's choice, for each of the group's heads
+            valid = jnp.broadcast_to(
+                mbuf[half][:, None, :], (bq, qpg, T)).reshape(R, T) \
+                > 0.5 * NEG_INF
+        if edge:
+            valid = both(_valid_keys(
                 base + iota((R, T), 1),
-                ctx + q0 + jax.lax.div(iota((R, T), 0), qpg), window)
+                ctx + q0 + jax.lax.div(iota((R, T), 0), qpg), window), valid)
         for grp in range(g):
             q2 = q[:, grp * qpg:(grp + 1) * qpg, :].reshape(R, d)
             sq = jax.lax.dot_general(
@@ -522,7 +600,8 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
             start_block(jnp.where(inner, s, s_next),
                         jnp.where(inner, first, first_next),
                         jnp.where(inner, last, last_next),
-                        jnp.where(inner, j + 1, 0), 1 - half)
+                        jnp.where(inner, j + 1, 0), 1 - half,
+                        q_block(inner))
 
         wait_block(live_pages(first, last, j), half)
         # every row attends every key of the block: none is newer than
@@ -535,11 +614,11 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
 
         @pl.when(whole)
         def _whole():
-            attend(base, half, masked=False)
+            attend(j, base, half, edge=False)
 
         @pl.when(jnp.logical_not(whole))
-        def _masked():
-            attend(base, half, masked=True)
+        def _edge():
+            attend(j, base, half, edge=True)
 
     if bq == 1:
         # a decode step's block is cheap enough for its fetch to show, and
@@ -584,7 +663,7 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
 
 def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
                valid_lens, k_scales, v_scales, *, scale, window, block_q,
-               name, name_suffix="", value_width=None):
+               name, name_suffix="", value_width=None, mask=None):
     """q [S, C, nh, d] with block_q | C (decode is C == block_q == 1);
     the pools stay in HBM and the kernel fetches pages itself.  ``name``
     is the kernel's name in a profile (``name_suffix``, the caller's
@@ -592,7 +671,13 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
     pools appended); ``valid_lens`` None = every slot has tokens in this
     call.  ``value_width`` (with ``block_q`` 1: the absorbed decode step):
     ``k_pages`` is a latent pool ``[P, bs, W]`` (``v_pages`` None) and the
-    output is ``[S, C, nh, value_width]``."""
+    output is ``[S, C, nh, value_width]``.  ``mask`` (over a bf16 pool of
+    K and V with no window): fp32, above ``NEG_INF / 2`` where the query
+    may attend the key, in blocks of the walk's own ``kp * bs`` keys; a
+    decode step's ``[S, blocks, kp * bs * g]`` (lane c of a block is key
+    c // g, group c % g, as the scores'), a chunk's ``[S, blocks, C,
+    kp * bs]``.  Blocks past a slot's last live query's may hold
+    anything: ``valid_lens`` must then be given."""
     if valid_lens is None:
         valid_lens = jnp.ones_like(context_lens)
     bs, M = k_pages.shape[1], block_tables.shape[1]
@@ -605,9 +690,9 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
                               k_pages.dtype, M)
     return _walk_kernel(
         q, k_pages, v_pages, block_tables, context_lens, valid_lens,
-        k_scales, v_scales, scale=scale, window=window, block_q=block_q,
-        name=name + name_suffix, value_width=value_width, kp=kp,
-        interpret=_INTERPRET)
+        k_scales, v_scales, mask, scale=scale, window=window,
+        block_q=block_q, name=name + name_suffix, value_width=value_width,
+        kp=kp, interpret=_INTERPRET)
 
 
 # a jit of its own inside the engine's programs, as the latent chunk's
@@ -620,8 +705,8 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
 @functools.partial(jax.jit, static_argnames=(
     "scale", "window", "block_q", "name", "value_width", "kp", "interpret"))
 def _walk_kernel(q, k_pages, v_pages, block_tables, context_lens,
-                 valid_lens, k_scales, v_scales, *, scale, window, block_q,
-                 name, value_width, kp, interpret):
+                 valid_lens, k_scales, v_scales, mask=None, *, scale, window,
+                 block_q, name, value_width, kp, interpret):
     """``kp`` pages a compute block."""
     S, C, nh, d = q.shape
     latent = value_width is not None
@@ -644,6 +729,28 @@ def _walk_kernel(q, k_pages, v_pages, block_tables, context_lens,
                   for x in (k_scales, v_scales)]
         bufs += [pltpu.VMEM((2, kp, bs * g), jnp.float32)] * 2
 
+    # a mask of chosen keys: the row's whole mask in VMEM for a decode
+    # step, a chunk's left in HBM and fetched a block's slice at a time on
+    # a semaphore row of its own.  Its q-block is as large as the caller
+    # says (the selection's chunk: 128 rows of every head, 4 MiB of fp32
+    # scratch and as much again of a group's scores), so the kernel asks
+    # for the VMEM the latent chunk's asks for
+    masks, mask_specs, extra = [], [], {}
+    if mask is not None:
+        assert not (latent or quantized) and window is None, name
+        blocks = -(-block_tables.shape[1] // kp)
+        masks, extra = [mask], dict(compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT))
+        if bq == 1:
+            assert mask.shape == (S, blocks, kp * bs * g), mask.shape
+            mask_specs = [pl.BlockSpec((1, blocks, kp * bs * g),
+                                       lambda s, qi, *_: (s, 0, 0),
+                                       memory_space=pltpu.VMEM)]
+        else:
+            assert mask.shape == (S, blocks, C, kp * bs), mask.shape
+            mask_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+            bufs = bufs + [pltpu.VMEM((2, bq, kp * bs), jnp.float32)]
+
     def q_map(s, qi, bt_ref, cl_ref, vl_ref):
         return (s, qi, 0, 0)
 
@@ -651,12 +758,12 @@ def _walk_kernel(q, k_pages, v_pages, block_tables, context_lens,
         num_scalar_prefetch=3,
         grid=(S, C // bq),
         in_specs=[pl.BlockSpec((1, bq, nh, d), q_map,
-                               memory_space=pltpu.VMEM)]
+                               memory_space=pltpu.VMEM)] + mask_specs
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec((1, bq, nh, dv), q_map,
                                memory_space=pltpu.VMEM),
         scratch_shapes=bufs + [
-            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SemaphoreType.DMA((len(bufs), 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
             pltpu.VMEM((bq * nh, 1), jnp.float32),
@@ -666,13 +773,13 @@ def _walk_kernel(q, k_pages, v_pages, block_tables, context_lens,
     return pl.pallas_call(
         functools.partial(_walk_body, quantized=quantized, scale=scale,
                           window=window, qpg=nh // g,
-                          value_width=value_width),
+                          value_width=value_width, masked=bool(masks)),
         name=name + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, dv), q.dtype),
-        interpret=interpret,
+        interpret=interpret, **extra,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      valid_lens.astype(jnp.int32), q, *pools)
+      valid_lens.astype(jnp.int32), q, *masks, *pools)
 
 
 # ---------------------------------------------------------------------------

@@ -898,27 +898,29 @@ def test_every_kernel_and_program_carries_its_stable_name():
                                  f"with no name="
             if isinstance(kw["name"], ast.Constant):
                 names.add(kw["name"].value)
-        # the paged walk takes its name from its two public entries
+        # the paged walk takes its name from whoever calls it: its two
+        # public entries, the latent pool's decode step (no int8 pool, no
+        # window group) and, under a mask of chosen keys, the selection
+        # (``dsa_attention.py``, since PR 57: neither of those either)
         for node in ast.walk(tree):
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Name)
-                    and node.func.id == "_walk_call"):
-                kw = {k.arg: k.value for k in node.keywords}
+            if not isinstance(node, ast.Call):
+                continue
+            called = getattr(node.func, "id", getattr(node.func, "attr", ""))
+            kw = {k.arg: k.value for k in node.keywords}
+            if called == "_walk_call":
                 names |= {kw["name"].value + suffix
-                          for suffix in (("",) if "value_width" in kw else
+                          for suffix in (("",) if {"value_width", "mask"}
+                                         & set(kw) else
                                          ("", "_quant", "_window"))}
-            # the sparse-attention kernels take theirs from the one entry
+            # the selection's own kernels take theirs from the one entry
             # that calls them for the decode step and for a chunk
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Name)
-                    and node.func.id in ("_index_scores", "_select",
-                                         "_masked_walk")):
-                kw = {k.arg: k.value for k in node.keywords}
+            if called in ("_index_scores", "_select"):
                 names.add(kw["name"].value)
     # 13 until the latent chunk got a walk of its own
     # (mla_attention_prefill), 14 until the state's step got a kernel,
-    # 15 until a retention layer's did, 16 until its chunk's
-    assert calls == 17
+    # 15 until a retention layer's did, 16 until its chunk's, 17 until
+    # the selection's attention went into the shared walk
+    assert calls == 16
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

@@ -694,6 +694,14 @@ DECODE_BATCHES = {
     # a context ending on a block's edge and one key past it (the new key
     # is the block's last, then the next block's first)
     "a_blocks_edge": lambda topk: ([62, 63, 64, 0], [1, 1, 1, 0]),
+    # a slot with no token in the call between two live ones, twice: the
+    # step that walks nothing puts its successor's first block on its way
+    # (the shared walk's hand-over, under the mask since PR 57)
+    "idle_between_live": lambda topk: ([150, 60, 100, 9, 175],
+                                       [1, 0, 1, 0, 1]),
+    # contexts that end mid-page, on a page's last key, on the next page's
+    # first and on the table's last, the top-k larger than the first three
+    "mid_page_and_edges": lambda topk: ([7, 15, 16, 40, 190], [1] * 5),
 }
 
 
@@ -727,6 +735,8 @@ def test_selected_decode_kernels_match_the_dense_path(topk, ties, batch,
     (8, 24), (9, 24),     # ends on a block's edge, and one key past it
     (40, 24),             # ends on an edge, straddling two blocks before it
     (168, 24),            # through the table's last key
+    (180, 12),            # the padded tail rows point past the table
+    (186, 5),             # and the last live row ends mid-page before it
 ])
 def test_selected_prefill_kernels_match_the_dense_path(ctx, valid, ties,
                                                        two_page_blocks,
@@ -744,6 +754,50 @@ def test_selected_prefill_kernels_match_the_dense_path(ctx, valid, ties,
     _assert_the_choice_is_select_masks(choices, 8)
     (_, _, n_live, _), = choices
     assert n_live.tolist() == [[-(-(ctx + valid) // 16)]]
+
+
+CHUNK_BATCHES = {
+    # three slots, the middle one with no token in the call: its grid
+    # steps walk nothing and hand the next slot's first block and mask
+    # slice on
+    "idle_between_live": ([70, 30, 150], [24, 0, 11]),
+    # a slot whose later q-blocks hold no live row, before a live slot
+    "dead_q_blocks_then_live": ([40, 9], [5, 24]),
+    # padded tail rows past the table's end, then a slot from key 0 whose
+    # rows straddle the top-k
+    "tail_past_the_table": ([180, 0], [12, 24]),
+    # idle slots first and last, the live one ending on a block's edge
+    "idle_first_and_last": ([5, 104, 60], [0, 24, 0]),
+}
+
+
+@pytest.mark.parametrize("block_q", [8, 32])
+@pytest.mark.parametrize("batch", sorted(CHUNK_BATCHES))
+def test_selected_chunks_of_several_slots_and_q_blocks(batch, block_q,
+                                                       monkeypatch,
+                                                       two_page_blocks,
+                                                       choices):
+    """Chunks of 24 rows (padded to 32) a slot, in q-blocks of 8 rows and
+    in one: every (slot, q-block) fetches its own slice of the mask with
+    its pages, a q-block with no live row walks nothing, and the grid
+    step before a live one has put that one's first block AND mask slice
+    in flight, whether or not it walked anything itself."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention
+
+    monkeypatch.setattr(dsa_attention, "_PREFILL_BLOCK_Q", block_q)
+    rng = np.random.default_rng(8)
+    ctx, valid = CHUNK_BATCHES[batch]
+    case = _selected_case(rng, len(ctx), 24, 12, 16, 2, 4, 32, 4, ctx, valid,
+                          False)
+    got, want = _selected_both(case, 8, 32)
+    for s, v in enumerate(valid):
+        np.testing.assert_allclose(got[s, :v], want[s, :v], atol=2e-5,
+                                   rtol=1e-5)
+        assert np.abs(got[s, v:]).max(initial=0.0) == 0.0
+    _assert_the_choice_is_select_masks(choices, 8)
+    (_, _, n_live, _), = choices
+    assert n_live.tolist() == [[-(-(c + v) // 16) if v else 0]
+                               for c, v in zip(ctx, valid)]
 
 
 def test_selected_attention_is_not_dense_attention(two_page_blocks):
@@ -805,6 +859,7 @@ def test_selection_never_reads_scores_nobody_wrote(n, poison, monkeypatch,
     (1, [20, 21], [0, 0]),
     (24, [70], [11]),
     (24, [9], [24]),
+    (24, [70, 30, 150], [24, 0, 11]),
 ])
 def test_attention_never_reads_a_mask_block_the_choice_did_not_write(
         n, ctx, valid, poison, monkeypatch, two_page_blocks):
@@ -1283,3 +1338,68 @@ def test_the_first_block_is_handed_across_grid_steps(case, two_page_blocks):
     assert not outs[0][vlen == 0].any()
     np.testing.assert_allclose(outs[0][vlen > 0], want[vlen > 0], atol=2e-5,
                                rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the walk WITHOUT a mask traces the kernel it traced before (PR 57: the
+# families' program fingerprints run on the CPU's dense path and never saw
+# a kernel; a mask, like a window or a latent pool, is a trace-time fact
+# and must leave every other caller's kernel alone)
+# ---------------------------------------------------------------------------
+
+def _walk_shapes(name):
+    """-> (entry, abstract arguments) of one caller of the shared walk."""
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    i32, P, bs, M = jnp.int32, 129, 16, 40
+    g = 1 if "latent" in name else 8 if "int8" in name else 4
+    pool = sd((P, bs, g, 128), jnp.int8 if "int8" in name else jnp.bfloat16)
+    rows = 2 if "chunk" in name else 4
+    q = sd((rows, 64, 32, 128) if "chunk" in name else (rows, 32, 128))
+    tables = (sd((rows, M), i32), sd((rows,), i32))
+    window = 64 if "window" in name else None
+    if "latent" in name:
+        return (lambda q, p, t, l, v: pa.latent_attention_decode(
+            q, p, t, l, valid_lens=v, value_width=512, softmax_scale=0.07),
+            (sd((rows, 32, 640)), sd((P, bs, 640))) + tables
+            + (sd((rows,), i32),))
+    entry = (pa.paged_attention_prefill if "chunk" in name
+             else pa.paged_attention_decode)
+    if "int8" in name:
+        scales = sd((P, bs, g), jnp.float32)
+        return (lambda q, k, v, t, l, ks, vs: entry(
+            q, k, v, t, l, k_scales=ks, v_scales=vs),
+            (q, pool, pool) + tables + (scales, scales))
+    return (lambda q, k, v, t, l, vl: entry(
+        q, k, v, t, l, valid_lens=vl, sliding_window=window,
+        name_suffix="_window" if window else ""),
+        (q, pool, pool) + tables + (sd((rows,), i32),))
+
+
+# what these print since PR 48 (the walk's fetches); PR 57 added the mask
+# and left them as they were.  A PR that MEANS to change the unmasked walk
+# records the new ones and says so
+WALKS_TRACED = {
+    "decode": "16db4304c1057fe7",
+    "decode_window": "a70da5ea1f17f85a",
+    "decode_int8": "fbb9b1183fe730bb",
+    "decode_latent": "70b89cd7c5203819",
+    "chunk": "7479aafa9b3c88c2",
+    "chunk_window": "48cfbab705730bbb",
+    "chunk_int8": "2524827b255e897b",
+}
+
+
+@pytest.mark.parametrize("name", sorted(WALKS_TRACED))
+def test_the_walk_without_a_mask_traces_the_kernel_it_traced_before(name):
+    import hashlib
+    import re
+
+    fn, args = _walk_shapes(name)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    assert "pallas_call" in text
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == WALKS_TRACED[name], (
+        f"the unmasked walk's kernel ({name}) is not the recorded one, "
+        f"{got}: if that was meant, record it in WALKS_TRACED")

@@ -34,6 +34,12 @@ TRACED = {
                 "engine_decode": "d108ab43dbc7ce8b"},
     "olmoe": {"engine_prefill": "8cd66b78f8c91346",
               "engine_decode": "971de090d62b6706"},
+    # PR 57 MEANT Keye's attention under the choice (the shared paged walk
+    # with a mask in place of ``dsa_attention.py``'s own) and these two
+    # stand all the same: this script traces on the CPU, where every
+    # family takes the dense path and no kernel is in the jaxpr.  What
+    # the walk's OTHER callers trace is held kernel by kernel in
+    # ``tests/test_paged_attention_kernel.py::WALKS_TRACED``
     "keye": {"engine_prefill": "e0d8c3ceab2845ae",
              "engine_decode": "3c0e2663546ddcad"},
     "mellum": {"engine_prefill": "8f4a67e3658e0723",

@@ -182,8 +182,10 @@ def _retention_chunk(rows, tokens, slots=16, g=8, r=5, d=128):
 
 
 def _selected(q_tokens, slots, tokens=33792, page=16):
-    """Keye's sparse attention through the paged pool (scores, choice,
-    attention under it: ``ops/pallas/dsa_attention.py``) at the cell's
+    """Keye's sparse attention through the paged pool (scores and choice:
+    ``ops/pallas/dsa_attention.py``; attention under the choice: the
+    shared walk of ``paged_attention.py`` with a mask, under the
+    selection's two kernel names, since PR 57) at the cell's
     shapes: 32 query and 4 kv heads of 128, an indexer of 16 heads of 64
     (held 128 wide, as the pool holds its keys),
     top-k 2,048, 8 slots of up to 33,792 tokens over a pool of 8,193
