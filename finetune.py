@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,lfm2,brumby,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,lfm2,brumby,qwen3_next,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -188,6 +188,22 @@ MODEL_DEFAULTS = {
                    layer_types=["retention"],
                    layernorm_epsilon=1e-6,
                    hidden_dropout=0.0, attention_dropout=0.0),
+    # Qwen3-Next-80B-A3B (model_type qwen3_next): three gated delta-rule
+    # layers to every gated attention layer of 256-wide heads that rotate
+    # in their first quarter, 512 experts chosen ten a token by a softmax
+    # router beside a shared expert under its own sigmoid gate, an untied
+    # head
+    "qwen3_next": dict(position_embedding_type="rotary",
+                       glu_activation="swiglu", use_rms_norm=True,
+                       use_bias=False, tie_embed_logits=False,
+                       num_experts=512, moe_top_k=10, norm_topk_prob=1,
+                       moe_shared_experts=1, moe_shared_expert_gate=True,
+                       qk_norm_per_head=True, attention_output_gate=True,
+                       kv_channels=256, rotary_percent=0.25, rope_theta=1e7,
+                       layer_types=["gated_delta", "gated_delta",
+                                    "gated_delta", "attention"],
+                       layernorm_epsilon=1e-6,
+                       hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,

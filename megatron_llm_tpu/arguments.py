@@ -115,6 +115,9 @@ def _add_network_size_args(parser):
     g.add_argument("--moe_shared_experts", type=int, default=0,
                    help="an ungated MLP of this many experts' width that "
                         "every token passes through (n_shared_experts)")
+    g.add_argument("--moe_shared_expert_gate", action="store_true",
+                   help="the shared MLP's output times sigmoid(x w_s), "
+                        "one gate a token (shared_expert_gate)")
     g.add_argument("--moe_first_dense_layers", type=int, default=0,
                    help="leading layers that keep a dense MLP of "
                         "--ffn_hidden_size (first_k_dense_replace)")
@@ -192,7 +195,8 @@ def _add_network_size_args(parser):
                         "layer is one sublayer, an expert layer alone), "
                         "or conv and attention, every layer of the depth "
                         "spelled out where the pattern does not repeat; or "
-                        "retention alone (power-retention layers)")
+                        "retention alone (power-retention layers); or "
+                        "gated_delta and attention (delta-rule layers)")
     g.add_argument("--hybrid_override_pattern", type=str, default=None,
                    help="a letter a layer in place of --layer_types: M a "
                         "Mamba-2 mixer, * an attention mixer, E an "
@@ -211,6 +215,17 @@ def _add_network_size_args(parser):
                         "convolution (conv_L_cache)")
     g.add_argument("--conv_mixer_bias", type=int, default=0, choices=[0, 1],
                    help="a bias a channel on that convolution (conv_bias)")
+    g.add_argument("--delta_key_heads", type=int, default=16,
+                   help="query/key heads of a 'gated_delta' layer's "
+                        "delta-rule mixer (linear_num_key_heads)")
+    g.add_argument("--delta_value_heads", type=int, default=32,
+                   help="its value heads, a state each "
+                        "(linear_num_value_heads)")
+    g.add_argument("--delta_key_dim", type=int, default=128)
+    g.add_argument("--delta_value_dim", type=int, default=128)
+    g.add_argument("--delta_conv_taps", type=int, default=4,
+                   help="taps a channel of its convolution over q, k and "
+                        "v (linear_conv_kernel_dim)")
     g.add_argument("--attention_multiplier", type=float, default=None,
                    help="attention scores times this in place of "
                         "1/sqrt(head_dim)")
@@ -1138,6 +1153,13 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         mamba_conv_bias=bool(getattr(args, "mamba_conv_bias", 1)),
         conv_taps=int(getattr(args, "conv_taps", 3)),
         conv_mixer_bias=bool(getattr(args, "conv_mixer_bias", 0)),
+        delta_key_heads=int(getattr(args, "delta_key_heads", 16)),
+        delta_value_heads=int(getattr(args, "delta_value_heads", 32)),
+        delta_key_dim=int(getattr(args, "delta_key_dim", 128)),
+        delta_value_dim=int(getattr(args, "delta_value_dim", 128)),
+        delta_conv_taps=int(getattr(args, "delta_conv_taps", 4)),
+        moe_shared_expert_gate=bool(
+            getattr(args, "moe_shared_expert_gate", False)),
         moe_gate_norm_eps=getattr(args, "moe_gate_norm_eps", None),
         moe_gate_norm_added=bool(getattr(args, "moe_gate_norm_added", 0)),
         attention_multiplier=getattr(args, "attention_multiplier", None),

@@ -101,12 +101,17 @@ class ParallelConfig:
 # ``full_attention``, which is 'attention' here): the other mixer that
 # carries a state from token to token.  'retention' is a power-retention
 # mixer (models/retention.py; brumby's every layer): attention's
-# projections over a recurrent state a key-value head and no key kept
+# projections over a recurrent state a key-value head and no key kept.
+# 'gated_delta' is a gated delta-rule mixer (models/gated_delta.py;
+# qwen3_next's ``linear_attention`` beside its ``full_attention``, which
+# is 'attention' here): a state a value head that is UPDATED BY WHAT IT
+# HOLDS (what it already answers for a key is taken off before the key's
+# value is written), behind a short convolution as Mamba's
 LAYER_TYPES = ("sliding", "full", "mamba", "attention", "moe", "conv",
-               "retention")
+               "retention", "gated_delta")
 # the layer types whose mixer carries a STATE a request in a slot and no
 # pages (``TransformerConfig.state_layer``: what ops/paged_kv.py asks)
-STATE_TYPES = ("mamba", "conv", "retention")
+STATE_TYPES = ("mamba", "conv", "retention", "gated_delta")
 # the letters of a published ``hybrid_override_pattern``, a layer each
 PATTERN_LETTERS = {"M": "mamba", "*": "attention", "E": "moe"}
 
@@ -165,6 +170,7 @@ TYPED = "a layer type per layer (layer_types)"
 STATE_SPACE = "state-space layers ('mamba' among layer_types)"
 SHORT_CONV = "gated short-convolution layers ('conv' among layer_types)"
 RETENTION = "power-retention layers ('retention' among layer_types)"
+GATED_DELTA = "gated delta-rule layers ('gated_delta' among layer_types)"
 ONE_SUBLAYER = "layers of one sublayer ('moe' among layer_types)"
 FIRST_DENSE = "leading dense layers (moe_first_dense_layers)"
 SHARE = "a share of the router's experts (moe_router_experts)"
@@ -185,6 +191,7 @@ ROPE_TYPES = "layer types that do not rotate (rope_layer_types)"
 OTHER_TYPES = "layer types other than 'mamba', 'attention' and 'moe'"
 CONV_OTHER_TYPES = "layer types other than 'conv' and 'attention'"
 RETENTION_OTHER_TYPES = "layer types other than 'retention'"
+DELTA_OTHER_TYPES = "layer types other than 'gated_delta' and 'attention'"
 HAS = {
     SPARSE: lambda c: c.dsa_index_heads > 0,
     LATENT: lambda c: c.kv_lora_rank is not None,
@@ -192,6 +199,7 @@ HAS = {
     STATE_SPACE: lambda c: c.state_space,
     SHORT_CONV: lambda c: c.short_conv,
     RETENTION: lambda c: c.retention,
+    GATED_DELTA: lambda c: c.gated_delta,
     ONE_SUBLAYER: lambda c: c.one_sublayer,
     FIRST_DENSE: lambda c: c.moe_first_dense_layers > 0,
     SHARE: lambda c: c.holds_a_share,
@@ -218,6 +226,8 @@ HAS = {
                                      - {"conv", "attention"}),
     RETENTION_OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
                                           - {"retention"}),
+    DELTA_OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
+                                      - {"gated_delta", "attention"}),
 }
 
 # THE TABLE: what a model has, and everything it does not run with.  A
@@ -233,6 +243,10 @@ RUNS_WITH = (
                  POST_LN, LATENT, SPARSE, SLIDING, GATE, OUTPUT_NORMS,
                  EXPERTS, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL,
                  HOST_TIER, PREEMPTION, PREFIX_CACHE)),
+    (GATED_DELTA, (DELTA_OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN,
+                   LATENT, SPARSE, SLIDING, OUTPUT_NORMS, TRAINING,
+                   MODEL_PARALLEL, VERIFY_STEP, INT8_POOL, HOST_TIER,
+                   PREEMPTION, PREFIX_CACHE)),
     (SHORT_CONV, (CONV_OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
                   GATE, OUTPUT_NORMS, TRAINING, MODEL_PARALLEL, VERIFY_STEP,
                   INT8_POOL, HOST_TIER, PREEMPTION, PREFIX_CACHE)),
@@ -298,6 +312,33 @@ TAILS = {
         "(--serve_preemption=0)",
     (RETENTION, PREFIX_CACHE):
         " adopts nothing (a retention layer's state at a prefix's end is "
+        "not kept)",
+    (GATED_DELTA, DELTA_OTHER_TYPES):
+        " (a 'gated_delta' layer type goes with 'attention' layers only: "
+        "a stack that holds it beside 'mamba', 'conv' or 'retention' "
+        "layers, two states of two shapes a slot, or beside a window "
+        "group, is held to nothing)",
+    (GATED_DELTA, SLIDING):
+        " (a recurrent state forgets by its gates, not by a window)",
+    (GATED_DELTA, TRAINING):
+        " (no backward through the triangular solve of the chunk form is "
+        "held to anything, and packed documents would need the state "
+        "reset at each boundary)",
+    (GATED_DELTA, MODEL_PARALLEL):
+        " (the state and the convolution's channels would be split by "
+        "value head, and a stage would hold its layers' states alone)",
+    (GATED_DELTA, VERIFY_STEP):
+        " (a rejected draft's updates were written OVER the state they "
+        "read: they would have to be taken back)",
+    (GATED_DELTA, INT8_POOL):
+        " (the int8 pool's scales are a page's, and a state is no page)",
+    (GATED_DELTA, HOST_TIER):
+        " (a page of keys without the state at its end resumes nothing)",
+    (GATED_DELTA, PREEMPTION):
+        " (no snapshot of a request's state is kept): set preemption off "
+        "(--serve_preemption=0)",
+    (GATED_DELTA, PREFIX_CACHE):
+        " adopts nothing (a delta-rule layer's state at a prefix's end is "
         "not kept)",
     (SHORT_CONV, CONV_OTHER_TYPES):
         " (a 'conv' layer type goes with 'attention' layers only: a stack "
@@ -558,6 +599,10 @@ class TransformerConfig:
     # through, not weighted by the router, added to the routed sum.
     # 0: none
     moe_shared_experts: int = 0
+    # the shared MLP's output times ``sigmoid(x w_s)``, ONE gate a token
+    # (``w_s`` [hidden, 1], a leaf of the ``shared`` subtree; qwen3_next's
+    # ``shared_expert_gate``), in float32
+    moe_shared_expert_gate: bool = False
     # the first layers of a sparse model that keep a dense MLP of
     # ``ffn_hidden_size`` (``first_k_dense_replace``); their parameters
     # are stacked apart from the sparse layers' (``dense_layers``)
@@ -593,6 +638,18 @@ class TransformerConfig:
     # set ``mamba_d_conv`` beside them
     conv_taps: int = 3
     conv_mixer_bias: bool = False
+    # gated delta-rule mixers (the 'gated_delta' layer type;
+    # models/gated_delta.py): ``delta_key_heads`` query/key heads of
+    # ``delta_key_dim`` serving ``delta_value_heads`` value heads of
+    # ``delta_value_dim`` (key head j serves value heads j * r .. j * r +
+    # r - 1, r their ratio), one state ``[delta_key_dim,
+    # delta_value_dim]`` a value head, a causal depthwise convolution of
+    # ``delta_conv_taps`` taps over q, k and v with no bias
+    delta_key_heads: int = 16
+    delta_value_heads: int = 32
+    delta_key_dim: int = 128
+    delta_value_dim: int = 128
+    delta_conv_taps: int = 4
     # muP-style multipliers (Granite): attention scores times this in
     # place of 1/sqrt(head_dim) (None: 1/sqrt(head_dim)); both residual
     # branches times ``residual_multiplier``; the logits divided by
@@ -720,6 +777,15 @@ class TransformerConfig:
             if "retention" in types and self.head_dim % 8:
                 raise ValueError("power-retention layers need a head_dim "
                                  "of whole sublanes (a multiple of 8)")
+            if "gated_delta" in types and (
+                    min(self.delta_key_heads, self.delta_value_heads,
+                        self.delta_key_dim, self.delta_value_dim) < 1
+                    or self.delta_value_heads % self.delta_key_heads
+                    or self.delta_conv_taps < 2):
+                raise ValueError(
+                    "gated delta-rule layers need positive delta sizes, "
+                    "delta_conv_taps >= 2 and whole key heads of value "
+                    "heads")
         if self.moe_router_experts is not None or self.moe_experts_first:
             routed = self.moe_router_experts or self.num_experts
             if self.num_experts <= 1 or not (
@@ -777,6 +843,9 @@ class TransformerConfig:
                 or self.moe_choice_bias):
             raise ValueError("moe_shared_experts, moe_first_dense_layers and "
                              "moe_choice_bias need num_experts > 1")
+        if self.moe_shared_expert_gate and not self.moe_shared_experts:
+            raise ValueError("moe_shared_expert_gate gates the shared MLP: "
+                             "it needs moe_shared_experts > 0")
         if self.moe_first_dense_layers and not (
                 0 < self.moe_first_dense_layers < self.num_layers):
             raise ValueError(
@@ -852,6 +921,19 @@ class TransformerConfig:
                 and "retention" in self.layer_types)
 
     @property
+    def gated_delta(self) -> bool:
+        """Whether some layer's mixer is a gated delta rule."""
+        return (self.layer_types is not None
+                and "gated_delta" in self.layer_types)
+
+    @property
+    def delta_conv_dim(self) -> int:
+        """The channels a delta-rule layer's convolution runs over: its
+        queries, keys and values side by side."""
+        return (2 * self.delta_key_heads * self.delta_key_dim
+                + self.delta_value_heads * self.delta_value_dim)
+
+    @property
     def retention_phi_rows(self) -> int:
         """Rows of ``phi``, the map with ``phi(x) . phi(y) = (x . y)^2``,
         in the layout ``models/retention.py`` chose: ``head_dim / 2 + 1``
@@ -862,8 +944,8 @@ class TransformerConfig:
     @staticmethod
     def state_layer(layer_type: Optional[str]) -> bool:
         """Whether a layer of ``layer_type`` carries a STATE a request
-        (arrays a slot, no pages): a 'mamba', a 'conv' or a 'retention'
-        mixer."""
+        (arrays a slot, no pages): a 'mamba', a 'conv', a 'retention' or
+        a 'gated_delta' mixer."""
         return layer_type in STATE_TYPES
 
     @property
@@ -871,7 +953,7 @@ class TransformerConfig:
         """Whether the stack's mixers are of several kinds, with other
         leaves each, and so stacked apart by kind (``mixer_counts``)."""
         return (self.state_space or self.short_conv or self.retention
-                or self.one_sublayer)
+                or self.gated_delta or self.one_sublayer)
 
     @property
     def one_sublayer(self) -> bool:
@@ -891,9 +973,9 @@ class TransformerConfig:
     @property
     def mixer_counts(self) -> dict:
         """How many layers there are of each kind ('mamba', 'conv',
-        'retention', 'attention', and the expert layers 'moe' of a stack of one
-        sublayer a layer) in a stack whose kinds' parameters are stacked
-        apart; empty for a stack whose layers all hold the same leaves.
+        'retention', 'gated_delta', 'attention', and the expert layers
+        'moe' of a stack of one sublayer a layer) in a stack whose kinds'
+        parameters are stacked apart; empty for a stack whose layers all hold the same leaves.
         EVERY layer of the depth counts, a sparse model's leading dense
         layers among them: only their MLP is stacked apart
         (``dense_layers``), their mixer is a member of its kind's stack
@@ -902,7 +984,8 @@ class TransformerConfig:
             return {}
         reps = self.num_layers // len(self.layer_types)
         return {k: reps * self.layer_types.count(k)
-                for k in ("mamba", "conv", "retention", "attention", "moe")
+                for k in ("mamba", "conv", "retention", "gated_delta",
+                          "attention", "moe")
                 if k in self.layer_types}
 
     def layer_type(self, layer: int) -> Optional[str]:

@@ -126,6 +126,8 @@ SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
           "ssm_step", "ssm_gate_norm", "ssm_out_proj", "mamba",
           "conv_in_proj", "short_conv", "conv_out_proj",
           "retention_gate", "retention_chunk", "retention_step",
+          "delta_proj", "delta_conv", "delta_gate", "delta_chunk",
+          "delta_step", "delta_norm",
           "attn_gate", "post_attn_norm", "post_mlp_norm",
           "attention", "mlp",
           "embedding", "lm_head", "transformer_layer")

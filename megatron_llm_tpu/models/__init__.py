@@ -42,6 +42,13 @@ def _nemotron_h(cfg):
     return NemotronHModel(cfg)
 
 
+def _qwen3_next(cfg):
+    """The Qwen3-Next hybrid, imported when first built, as Granite."""
+    from megatron_llm_tpu.models.qwen3_next import Qwen3NextModel
+
+    return Qwen3NextModel(cfg)
+
+
 MODEL_REGISTRY = {
     "gpt": GPTModel,
     "llama": LlamaModel,
@@ -60,6 +67,7 @@ MODEL_REGISTRY = {
     "nemotron_h": _nemotron_h,
     "lfm2": Lfm2Model,
     "brumby": BrumbyModel,
+    "qwen3_next": _qwen3_next,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,
     "gpt_neox": GPTNeoXModel,

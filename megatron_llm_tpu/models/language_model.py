@@ -119,12 +119,13 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
         "input_norm": _norm_spec(layers["input_norm"], stacked),
         **({} if attention_specs is None
            else {"attention": attention_specs}),
-        # a state-space, short-convolution or retention mixer is
-        # replicated (tp is refused)
+        # a state-space, short-convolution, retention or delta-rule
+        # mixer is replicated (tp is refused)
         **{kind: jax.tree_util.tree_map(
             lambda a: (("stage",) if stacked else ())
             + (None,) * (a.ndim - int(stacked)), layers[kind])
-           for kind in ("mamba", "conv", "retention") if kind in layers},
+           for kind in ("mamba", "conv", "retention", "gated_delta")
+           if kind in layers},
     }
     if "moe" in layers:
         # the expert layers of a stack of one sublayer a layer

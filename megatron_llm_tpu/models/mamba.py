@@ -192,6 +192,29 @@ def gated_group_norm(y: jax.Array, scale: jax.Array, groups: int,
     return grouped.reshape(y.shape) * scale.astype(y.dtype)
 
 
+def causal_conv_silu(x: jax.Array, conv_state: jax.Array, conv: dict,
+                     valid: jax.Array, cd):
+    """Step 2: ``silu`` of the causal depthwise convolution of ``x`` [b,
+    n, channels] (compute dtype ``cd``) behind the ``K - 1`` columns
+    ``conv_state`` [b, K - 1, channels] a row carried in, under ``conv``
+    (``kernel`` [channels, K] and a ``bias`` a channel or none), taps
+    applied in float32.  Also the columns BEFORE the convolution at each
+    row's last ``valid`` tokens, ``ext[valid : valid + K - 1]``: what
+    the row carries on (an idle row, ``valid`` 0, keeps its own).  What a
+    state-space layer does to ``xBC`` and a delta-rule layer
+    (``models/gated_delta.py``) to its queries, keys and values."""
+    n, K = x.shape[1], conv["kernel"].shape[1]
+    w = conv["kernel"].astype(jnp.float32)                  # [channels, K]
+    ext = jnp.concatenate([conv_state.astype(cd), x], axis=1)
+    acc = conv.get("bias", jnp.zeros(())).astype(
+        jnp.float32) + sum(
+        ext[:, j:j + n].astype(jnp.float32) * w[:, j] for j in range(K))
+    y = jax.nn.silu(acc).astype(cd)
+    new_conv = jax.vmap(lambda e, v: jax.lax.dynamic_slice_in_dim(
+        e, v, K - 1, axis=0))(ext, valid)
+    return y, new_conv
+
+
 def mamba_mixer(h: jax.Array, params, cfg: TransformerConfig, *,
                 kv_cache=None):
     """``h`` [b, n, hidden] (the layer's normed input) -> the mixer's
@@ -228,16 +251,8 @@ def mamba_mixer(h: jax.Array, params, cfg: TransformerConfig, *,
         valid = jnp.full((b,), n, jnp.int32)
 
     with jax.named_scope("ssm_conv"):
-        w = params["conv"]["kernel"].astype(jnp.float32)    # [cdim, K]
-        ext = jnp.concatenate([conv_state.astype(cd), xBC], axis=1)
-        acc = params["conv"].get("bias", jnp.zeros(())).astype(
-            jnp.float32) + sum(
-            ext[:, j:j + n].astype(jnp.float32) * w[:, j] for j in range(K))
-        xBC = jax.nn.silu(acc).astype(cd)
-        # the columns before the convolution at the row's last valid
-        # tokens: ext[valid : valid + K - 1] (an idle row keeps its own)
-        new_conv = jax.vmap(lambda e, v: jax.lax.dynamic_slice_in_dim(
-            e, v, K - 1, axis=0))(ext, valid)
+        xBC, new_conv = causal_conv_silu(xBC, conv_state, params["conv"],
+                                         valid, cd)
 
     x = xBC[..., :di].reshape(b, n, nh, dh)
     Bm = xBC[..., di:di + g * ds].reshape(b, n, g, ds)

@@ -47,7 +47,9 @@ Shared by both:
 * **The shared MLP** (``cfg.moe_shared_experts`` > 0): one MLP of that
   many experts' width (gated under a GLU like any other: built at
   ``mult * wide``) that every token passes through, NOT WEIGHTED BY THE
-  ROUTER, added to the routed sum under the scope ``moe_shared``.
+  ROUTER, added to the routed sum under the scope ``moe_shared``; with
+  ``cfg.moe_shared_expert_gate`` times ``sigmoid(x w_s)``, one gate a
+  token of its own (qwen3_next's ``shared_expert_gate``).
 * **One chip's share of a layer's experts**
   (``cfg.moe_router_experts`` / ``cfg.moe_experts_first``; inference
   only): the router scores ALL the layer's experts and the layer holds
@@ -178,7 +180,8 @@ def laid_width(cfg: TransformerConfig) -> int:
 def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
     """{'router': {'kernel': [H, E][, 'choice_bias': [E]]},
         'experts': {'w_in': [E, H, (2x)F], 'w_out': [E, F, H]}
-        [, 'shared': a dense MLP's two linears at moe_shared_experts * F]}
+        [, 'shared': a dense MLP's two linears at moe_shared_experts * F
+           (, 'gate': {'kernel': [H, 1]} with moe_shared_expert_gate)]}
     (``w_in``'s last dimension is ``laid_width``: ``F`` but for an
     ungated width that is not whole lanes)."""
     k_r, k_in, k_out = jax.random.split(key, 3)
@@ -218,6 +221,10 @@ def init_moe_mlp_params(key, cfg: TransformerConfig, dtype):
                 k_so, wide, H, bias=False, init_method=out_init,
                 dtype=dtype),
         }
+        if cfg.moe_shared_expert_gate:
+            params["shared"]["gate"] = init_linear_params(
+                jax.random.fold_in(key, 2), H, 1, bias=False,
+                init_method=init, dtype=dtype)
     return params
 
 
@@ -239,6 +246,8 @@ def moe_mlp_specs(params, stacked: bool = True, cfg=None) -> dict:
             "dense_h_to_4h": {"kernel": lead + (None, "ffn")},
             "dense_4h_to_h": {"kernel": lead + ("ffn", None)},
         }
+        if "gate" in params["shared"]:
+            specs["shared"]["gate"] = {"kernel": lead + (None, None)}
     return specs
 
 
@@ -279,16 +288,23 @@ def _route(x: jax.Array, params, cfg: TransformerConfig):
 
 def _shared_mlp(x: jax.Array, params, cfg: TransformerConfig):
     """The shared experts' MLP on x [b, s, h] (zeros' stand-in None for a
-    model without one): every token, not weighted by the router."""
+    model without one): every token, not weighted by the router; with
+    ``cfg.moe_shared_expert_gate`` under its OWN gate, ``sigmoid(x
+    w_s)`` a token in float32 (then the output is float32)."""
     if "shared" not in params:
         return None
     with jax.named_scope("moe_shared"):
         mid = column_parallel_linear(
             x, params["shared"]["dense_h_to_4h"], out_logical="ffn",
             compute_dtype=cfg.compute_jnp_dtype)
-        return row_parallel_linear(
+        out = row_parallel_linear(
             apply_mlp_activation(mid, cfg), params["shared"]["dense_4h_to_h"],
             in_logical="ffn", compute_dtype=cfg.compute_jnp_dtype)
+        if cfg.moe_shared_expert_gate:
+            w_s = params["shared"]["gate"]["kernel"].astype(jnp.float32)
+            out = out.astype(jnp.float32) * jax.nn.sigmoid(
+                x.astype(jnp.float32) @ w_s)
+        return out
 
 
 def _aux_losses(logits, probs, frac):

@@ -305,6 +305,9 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
         elif kind == "retention":
             from megatron_llm_tpu.models.retention import (
                 init_retention_params as init)
+        elif kind == "gated_delta":
+            from megatron_llm_tpu.models.gated_delta import (
+                init_gated_delta_params as init)
         elif kind == "moe":
             from megatron_llm_tpu.models.moe import (
                 init_moe_mlp_params as init)
@@ -594,10 +597,15 @@ def qkv_heads(x: jax.Array, params, cfg: TransformerConfig, *,
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(q.shape[1])[None],
                                          q.shape[:2])
+        # a head's first rot_d dimensions rotate (rotary_percent, as
+        # ``rotary_freqs`` reads it for a model of one type): all of them
+        # at 1.0
+        rot_d = int(cfg.head_dim * cfg.rotary_percent)
+        rot_d -= rot_d % 2
         q = apply_rotary_at(q, positions, cfg.rope_theta, cfg.rope_sections,
-                            yarn)
+                            yarn, rot_d)
         k = apply_rotary_at(k, positions, cfg.rope_theta, cfg.rope_sections,
-                            yarn)
+                            yarn, rot_d)
     elif cfg.position_embedding_type == PositionEmbeddingType.rotary and freqs is not None:
         cos, sin = freqs
         q = apply_rotary_emb(q, cos, sin, position_ids)
@@ -1017,7 +1025,9 @@ def transformer_layer(
     ``params['mamba']`` in place of attention, a ``'conv'`` layer's
     ``models/short_conv.py::short_conv_mixer`` over ``params['conv']``, a
     ``'retention'`` layer's ``models/retention.py::retention_mixer`` over
-    ``params['retention']``.  Both residual branches
+    ``params['retention']``, a ``'gated_delta'`` layer's
+    ``models/gated_delta.py::gated_delta_mixer`` over
+    ``params['gated_delta']``.  Both residual branches
     are multiplied by ``cfg.residual_multiplier``.  In a stack of ONE
     sublayer a layer (``cfg.one_sublayer``) the layer is
     ``x + f(input_norm(x))``, ``f`` its mixer or, for the type
@@ -1092,6 +1102,19 @@ def transformer_layer(
             attn_out = retention_mixer(
                 ln_out, params["retention"], cfg, freqs=freqs,
                 position_ids=position_ids, kv_cache=kv_cache)
+        new_cache = None
+        if kv_cache is not None:
+            attn_out, new_cache = attn_out
+    elif layer_type == "gated_delta":
+        from megatron_llm_tpu.models.gated_delta import gated_delta_mixer
+
+        if attention_mask is not None:
+            raise NotImplementedError(
+                "gated delta-rule layers ('gated_delta') are not "
+                "implemented under an explicit attention mask (packed "
+                "documents would need the state reset at each boundary)")
+        attn_out = gated_delta_mixer(ln_out, params["gated_delta"], cfg,
+                                     kv_cache=kv_cache)
         new_cache = None
         if kv_cache is not None:
             attn_out, new_cache = attn_out

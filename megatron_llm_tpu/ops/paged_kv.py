@@ -164,7 +164,8 @@ _GROUP_OF = {"sliding": WINDOW, "moe": NONE}
 # the arrays a slot of the STATE group may hold, in the order
 # ``read_state`` gives and ``write_state`` takes them; which of them a
 # layer's pool has is its kind's (``init_pools``)
-_STATE_ARRAYS = ("conv_state", "ssm_state", "ret_state", "ret_sum")
+_STATE_ARRAYS = ("conv_state", "ssm_state", "ret_state", "ret_sum",
+                 "delta_state")
 
 
 def layer_groups(cfg) -> Optional[tuple]:
@@ -206,8 +207,9 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     group of a model with a layer type per layer, ``num_blocks`` for
     every other layer; a layer that carries a state holds its kind's
     arrays a slot (a state-space layer two, a gated short convolution
-    one, a power retention its state and its normaliser), ``num_slots``
-    of them and the garbage row."""
+    one, a power retention its state and its normaliser, a gated delta
+    rule its convolution's columns and its state), ``num_slots`` of them
+    and the garbage row."""
     dtype = dtype or cfg.compute_jnp_dtype
     indexed = cfg.dsa_index_heads > 0
     groups = layer_groups(cfg)
@@ -238,6 +240,17 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
         if kind == "conv":
             return {"conv_state": jnp.zeros(
                 (num_slots + 1, cfg.conv_taps - 1, cfg.hidden_size), dtype)}
+        if kind == "gated_delta":
+            # the columns of [q | k | v] before the convolution, and a
+            # value head's [key, value] state (models/gated_delta.py)
+            return {
+                "conv_state": jnp.zeros(
+                    (num_slots + 1, cfg.delta_conv_taps - 1,
+                     cfg.delta_conv_dim), dtype),
+                "delta_state": jnp.zeros(
+                    (num_slots + 1, cfg.delta_value_heads,
+                     cfg.delta_key_dim, cfg.delta_value_dim),
+                    SSM_STATE_DTYPE)}
         return {
             "conv_state": jnp.zeros(
                 (num_slots + 1, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim),
@@ -427,7 +440,8 @@ class PagedKVCache:
         heads, d_head, d_state]), a gated short convolution's
         (``conv_state`` [b, taps - 1, hidden],) alone, a power
         retention's (``ret_state`` [b, g, O, d, d], ``ret_sum`` [b, g,
-        O, d])."""
+        O, d]), a gated delta rule's (``conv_state`` [b, taps - 1,
+        channels], ``delta_state`` [b, value heads, d_key, d_value])."""
         fresh = self.context_lens == 0
         return tuple(self._rows(self.pool[name], fresh)
                      for name in _STATE_ARRAYS if name in self.pool)
@@ -452,6 +466,29 @@ class PagedKVCache:
             pool = self._put(pool, new, live)
         return y, dataclasses.replace(
             self, pool={**self.pool, "ssm_state": pool})
+
+    def step_delta(self, q, k, v, g, beta):
+        """One token of a gated delta-rule layer's recurrence on every
+        live row's ``delta_state`` (``ops/pallas/delta_step.py`` has the
+        operands): ``o`` [b, value heads, d_value] float32 and the cache
+        with the state WRITTEN (``write_state`` then takes the columns
+        alone).  On the ``'pallas'`` path of a decode step (row s is slot
+        s) the kernel updates the pool in place and moves live rows
+        only; otherwise every row's state is read, advanced and put
+        back."""
+        from megatron_llm_tpu.ops.pallas import delta_step as _delta
+
+        pool = self.pool["delta_state"]
+        live, fresh = self.valid_lens > 0, self.context_lens == 0
+        if self.kernel == "pallas" and self.slots is None:
+            o, pool = _delta.delta_state_step(pool, q, k, v, g, beta, live,
+                                              fresh)
+        else:
+            o, new = _delta.dense_gated_delta_step(
+                self._rows(pool, fresh), q, k, v, g, beta)
+            pool = self._put(pool, new, live)
+        return o, dataclasses.replace(
+            self, pool={**self.pool, "delta_state": pool})
 
     def step_retention(self, q, k, v, a):
         """One token of a power-retention layer's recurrence on every
@@ -509,9 +546,9 @@ class PagedKVCache:
         """The cache as a state-carrying layer's call leaves it: each
         live row's ``arrays`` (in ``read_state``'s order) written at its
         slot (``_put``; one left out or None stays as it is:
-        ``step_state`` has written ``ssm_state``, ``step_retention``
-        and ``chunk_retention`` both of their arrays), ``context_lens``
-        advanced."""
+        ``step_state`` has written ``ssm_state``, ``step_delta``
+        ``delta_state``, ``step_retention`` and ``chunk_retention`` both
+        of their arrays), ``context_lens`` advanced."""
         live = self.valid_lens > 0
         pool = dict(self.pool)
         names = [name for name in _STATE_ARRAYS if name in self.pool]
@@ -828,15 +865,15 @@ class CachePlan:
                 admitted: int) -> None:
         """The cache's counters of one launch on its record ``d``
         (``serving/loop_profiler.py``: ``DSA_FIELDS``, ``MLA_FIELDS``,
-        ``SSM_FIELDS``, ``CONV_FIELDS``, ``RETENTION_FIELDS``), from the
-        host arrays its program is handed:
+        ``SSM_FIELDS``, ``CONV_FIELDS``, ``RETENTION_FIELDS``,
+        ``DELTA_FIELDS``), from the host arrays its program is handed:
         each row's ``context_lens`` and ``valid_lens`` (0: an idle row)
         of ``n`` queries a row; ``admitted``: the requests that hold a
         slot.  Returns at once for a model with no such mechanism."""
         cfg, layers = self.cfg, self.cfg.num_layers
-        state_layers, conv_layers, ret_layers = (
+        state_layers, conv_layers, ret_layers, delta_layers = (
             self.state_kinds.count(k)
-            for k in ("mamba", "conv", "retention"))
+            for k in ("mamba", "conv", "retention", "gated_delta"))
         if not (cfg.latent_attention or self.dsa_block_keys
                 or self.state_kinds):
             return
@@ -860,6 +897,15 @@ class CachePlan:
             if d.kind == "prefill" and self.prefill_kernel == "pallas":
                 # the chunk ran in ops/pallas/retention_chunk.py's kernel
                 d.retention_chunk_tokens_kernel = d.retention_tokens
+        if delta_layers:
+            d.delta_rows_live = delta_layers * len(val)
+            if d.kind != "prefill":
+                # as a state-space layer's: the kernel moves the live
+                # rows' state, the XLA step every slot's
+                d.delta_rows_moved = (
+                    d.delta_rows_live if self.paged_kernel == "pallas"
+                    else delta_layers * (self.num_slots + 1))
+            d.delta_tokens = delta_layers * int(val.sum())
         if state_layers:
             d.ssm_rows_live = state_layers * len(val)
             if d.kind != "prefill":

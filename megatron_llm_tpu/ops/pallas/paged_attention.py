@@ -196,8 +196,13 @@ _PREFILL_BLOCK_Q = 128
 # them, and at 128 rows of 32 heads of 128 the compiler refuses the
 # kernel (21.5 MB of the 16 MB a kernel may use on a v5e).  A chunk of
 # 64 rows of 32 heads, the most any chunk had until [1, 512] chunks came
-# here, is exactly this many
+# here, is exactly this many.  The scratch grows with a head's WIDTH too:
+# the count is of heads of 128 lanes, and a head of two lane rows (256)
+# has half as many (128 rows of 16 heads of 256 were refused at 16.8 MB:
+# ``paged_prefill_chunk_512_2_kv_heads_of_256`` of
+# tests/test_tpu_aot_compile.py)
 _PREFILL_BLOCK_ROWS = 2048
+_LANES = 128
 
 
 def _use_pallas() -> bool:
@@ -865,8 +870,10 @@ def paged_attention_prefill(
             q, k_pages, v_pages, block_tables, context_lens, valid_lens,
             k_scales, v_scales, softmax_scale, sliding_window)
     C = q.shape[1]
-    bq = min(block_q or max(1, min(_PREFILL_BLOCK_Q,
-                                   _PREFILL_BLOCK_ROWS // q.shape[2])), C)
+    lane_rows = max(1, -(-q.shape[3] // _LANES))
+    bq = min(block_q or max(1, min(
+        _PREFILL_BLOCK_Q,
+        _PREFILL_BLOCK_ROWS // (q.shape[2] * lane_rows))), C)
     while C % bq:       # q-blocks must tile the chunk exactly; static
         bq -= 1         # (power-of-two chunks keep the full block size)
     return _walk_call(

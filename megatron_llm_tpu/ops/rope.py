@@ -204,16 +204,23 @@ def apply_rotary_at(
     theta: float,
     sections: Optional[Sequence[int]] = None,
     yarn: Optional[Sequence[float]] = None,
+    rot_d: Optional[int] = None,
 ) -> jax.Array:
     """Rotate the interleaved pairs of ``x`` [..., s, heads, d] at
     explicit positions, with no table: pair i turns by
-    ``position * theta^(-2i/d)``.  ``position_ids`` [..., s], or
-    ``[streams, ..., s]`` with ``sections``: pair i then follows the
+    ``position * theta^(-2i/d)``.  ``rot_d`` (even, under ``d``): only a
+    head's first ``rot_d`` dimensions rotate, as a head of that width
+    would, and the rest pass (a partial rotary, ``rotary_percent``).
+    ``position_ids`` [..., s], or ``[streams, ..., s]`` with ``sections``: pair i then follows the
     stream its section names (the sectioned rotary embedding of
     multimodal models).  Streams that coincide, as a text token's do,
     give the plain embedding.  ``yarn``: as :func:`precompute_freqs_cis`
     takes it."""
     d = x.shape[-1]
+    if rot_d is not None and rot_d < d:
+        turned = apply_rotary_at(x[..., :rot_d], position_ids, theta,
+                                 sections, yarn)
+        return jnp.concatenate([turned, x[..., rot_d:]], axis=-1)
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     inv, mult = _yarn(inv, theta, yarn)
     pos = position_ids.astype(jnp.float32)

@@ -186,6 +186,14 @@ CONV_FIELDS = ("conv_rows_live", "conv_tokens")
 RETENTION_FIELDS = ("retention_rows_live", "retention_rows_moved",
                     "retention_tokens", "retention_chunk_tokens_kernel")
 
+# gated delta-rule layers (a model with 'gated_delta' layers;
+# ``CachePlan.account``): live rows x layers; rows x layers whose state a
+# DECODE launch's program moves (the step's kernel,
+# ``ops/pallas/delta_step.py``: the live rows; the XLA step: every slot
+# and the garbage row; 0 on a prefill launch); tokens x layers.  The
+# state's bytes are ``ssm_state_bytes_held``, the state group's
+DELTA_FIELDS = ("delta_rows_live", "delta_rows_moved", "delta_tokens")
+
 # a model with a layer type per layer (the engine's ``_window_advance``):
 # window-group pages given back to the allocator before this launch and
 # pages it took (each logical page of a context once: what one table a
@@ -210,7 +218,8 @@ HOST_FIELDS = ("host_uploads", "host_reads", "compile_secs", "gc_secs")
 # the ONE declaration of the counted fields: what ``finish`` sums,
 # ``as_dict()`` carries and ``totals()`` gives goes through it
 COUNTED_FIELDS = (MOE_FIELDS + DSA_FIELDS + MLA_FIELDS + SSM_FIELDS
-                  + CONV_FIELDS + RETENTION_FIELDS + KV_FIELDS + HOST_FIELDS)
+                  + CONV_FIELDS + RETENTION_FIELDS + DELTA_FIELDS + KV_FIELDS
+                  + HOST_FIELDS)
 
 # the compiled programs whose operations run inside a launch of each
 # kind (a last prefill chunk samples its first token in the same launch),

@@ -200,6 +200,27 @@ def _brumby_cfg(cfg, chunk):
                 bytes={"phi_rows": (cfg.head_dim // 2 + 1) * cfg.head_dim})
 
 
+def _qwen3_next_cfg(cfg, chunk):
+    return {"num_hidden_layers": cfg.num_layers,
+            "full_attention_interval": len(cfg.layer_types),
+            "num_attention_heads": cfg.num_attention_heads,
+            "num_key_value_heads": cfg.num_attention_heads_kv,
+            "head_dim": cfg.head_dim,
+            "partial_rotary_factor": cfg.rotary_percent,
+            "rope_theta": cfg.rope_theta,
+            "rms_norm_eps": cfg.layernorm_epsilon,
+            "linear_num_key_heads": cfg.delta_key_heads,
+            "linear_num_value_heads": cfg.delta_value_heads,
+            "linear_key_head_dim": cfg.delta_key_dim,
+            "linear_value_head_dim": cfg.delta_value_dim,
+            "linear_conv_kernel_dim": cfg.delta_conv_taps,
+            "num_experts": cfg.num_experts,
+            "experts_first": cfg.moe_experts_first,
+            "num_experts_per_tok": cfg.moe_top_k,
+            "vocab_size": cfg.padded_vocab_size,
+            "fault_chunk": chunk}
+
+
 def _kanana_cfg(cfg, chunk):
     return {"num_hidden_layers": cfg.num_layers,
             "num_attention_heads": cfg.num_attention_heads,
@@ -346,6 +367,11 @@ FAMILIES = {
                      precision="highest", state=_retention_state,
                      engine=dict(num_slots=3, max_model_len=256,
                                  preemption=False)),
+    # experts 4-11 of the router's 16: a share that starts mid-way
+    "qwen3_next": Family(_qwen3_next_cfg, 2e-4,
+                         tiny=dict(moe_experts_first=4), shake=_WIDE,
+                         engine=dict(preemption=False),
+                         state=_slot_arrays("delta_state")),
     "kanana": Family(_kanana_cfg, 2e-4, chunk=16),
     "keye": Family(_keye_cfg, 2e-4, chunk=16,
                    shake=dict(noisy=("scale", "bias"), kernels=("kernel",),

@@ -17,7 +17,8 @@ os.environ.pop("XLA_FLAGS", None)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 FAMILIES = ("mistral", "mixtral", "olmoe", "keye", "mellum", "kanana",
-            "granite", "nemotron_h", "trinity", "lfm2", "brumby")
+            "granite", "nemotron_h", "trinity", "lfm2", "brumby",
+            "qwen3_next")
 
 
 def fingerprints(name: str) -> dict:

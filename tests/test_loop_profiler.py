@@ -491,7 +491,8 @@ def _groups():
 
     return {name: getattr(lp, name) for name in (
         "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS",
-        "CONV_FIELDS", "RETENTION_FIELDS", "KV_FIELDS", "HOST_FIELDS")}
+        "CONV_FIELDS", "RETENTION_FIELDS", "DELTA_FIELDS", "KV_FIELDS",
+        "HOST_FIELDS")}
 
 
 @pytest.mark.parametrize("group", sorted(_groups()))
@@ -860,6 +861,10 @@ KERNEL_NAMES = {
     # a prefill chunk's power retention, phi formed in VMEM over the
     # slot's state where it lies (PR 55), under ``retention_chunk``
     "retention_state_chunk",
+    # the decode step's gated delta rule, in place over the live rows
+    # (PR 58), under ``delta_step``: the first caller of the walker
+    # written once (``delta_step.walk_live_rows``)
+    "delta_state_step",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -916,11 +921,16 @@ def test_every_kernel_and_program_carries_its_stable_name():
             # that calls them for the decode step and for a chunk
             if called in ("_index_scores", "_select"):
                 names.add(kw["name"].value)
+            # the live rows' walker takes its name from the step that
+            # gives it its per-block update
+            if called == "walk_live_rows":
+                names.add(kw["name"].value)
     # 13 until the latent chunk got a walk of its own
     # (mla_attention_prefill), 14 until the state's step got a kernel,
     # 15 until a retention layer's did, 16 until its chunk's, 17 until
-    # the selection's attention went into the shared walk
-    assert calls == 16
+    # the selection's attention went into the shared walk, 16 until the
+    # delta rule's step brought the live rows' walker
+    assert calls == 17
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

@@ -27,6 +27,7 @@ SQUARES = [(has, what) for has, whats in C.RUNS_WITH for what in whats]
 # engine) is given each thing a mechanism does not run with
 FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
           C.SHORT_CONV: "lfm2", C.RETENTION: "brumby",
+          C.GATED_DELTA: "qwen3_next",
           C.ONE_SUBLAYER: "nemotron_h",
           C.GATE: "trinity", C.OUTPUT_NORMS: "trinity",
           C.ROPE_TYPES: "trinity",
@@ -41,6 +42,10 @@ GIVEN = {
         sliding_window_size=16),
     # the same for a stack of 'conv' and 'attention' layers
     C.CONV_OTHER_TYPES: lambda cfg: dict(
+        layer_types=cfg.layer_types[:-1] + ("sliding",),
+        sliding_window_size=16),
+    # the same for a stack of 'gated_delta' and 'attention' layers
+    C.DELTA_OTHER_TYPES: lambda cfg: dict(
         layer_types=cfg.layer_types[:-1] + ("sliding",),
         sliding_window_size=16),
     # a retention stack with its last layer an attention layer; experts
@@ -227,8 +232,28 @@ def test_a_retention_stack_with_another_layer_type_is_refused_by_name():
                     sliding_window_size=16)
 
 
+@pytest.mark.parametrize("other", ["mamba", "conv", "retention", "sliding",
+                                   "full", "moe"])
+def test_a_delta_stack_with_another_layer_type_is_refused_by_name(other):
+    """A 'gated_delta' layer goes with 'attention' layers only: another
+    kind's state beside it (two states of two shapes a slot), a window
+    group or an expert layer alone is held to nothing, so the
+    constructor says so, whatever row of the table comes first."""
+    with pytest.raises(ValueError, match="not implemented with"):
+        _config("qwen3_next",
+                layer_types=("gated_delta", "gated_delta", other,
+                             "attention"), sliding_window_size=16)
+    # and said by the delta row's own sentence where no earlier row
+    # speaks (an expert layer alone is ONE_SUBLAYER's, which comes first)
+    cfg = copy.copy(_config("qwen3_next"))
+    object.__setattr__(cfg, "layer_types", ("gated_delta", other))
+    said = C.refusal(cfg)
+    assert ("'gated_delta' layer type goes with 'attention' layers only"
+            in said) == (other not in ("moe", "retention")), said
+
+
 @pytest.mark.parametrize("family", ["kanana", "keye", "mellum", "granite",
-                                    "lfm2", "brumby"])
+                                    "lfm2", "brumby", "qwen3_next"])
 def test_the_pool_and_the_engine_refuse_int8_in_one_sentence(family):
     """``init_pools(quantized=True)`` and the engine ask the same table,
     so a latent, an indexed, a grouped model and one with state-space

@@ -72,18 +72,21 @@ def _layer_norm(rows):
 
 
 def _paged(q_tokens, slots, page=16, int8=False, window=None,
-           heads=HEADS, kv_heads=KV_HEADS, tokens=MAX_LEN):
+           heads=HEADS, kv_heads=KV_HEADS, tokens=MAX_LEN,
+           head_dim=HEAD_DIM):
     """Decode (q_tokens=1), a prefill chunk or the K+1 verify step over a
     pool sized as the engine sizes it: full backing for 8 slots of 4096
     tokens plus the garbage page.  ``heads`` / ``kv_heads`` 16 / 16 is
     OLMoE's layout: one query head a key-value group; 32 / 4 with 48
     slots of up to 20,992 ``tokens`` (a table of 1,312 entries, the walk's
     largest in SMEM) and a window of 2,048 is Trinity-Mini's two groups,
-    32 / 2 with 64 slots of 6,144 Nemotron-3-Nano's."""
+    32 / 2 with 64 slots of 6,144 Nemotron-3-Nano's; 16 / 2 at a
+    ``head_dim`` of 256, a head two lane rows, with 32 slots of 33,792
+    Qwen3-Next's."""
     from megatron_llm_tpu.ops.pallas import paged_attention as pa
 
     pages = SLOTS * (MAX_LEN // page) + 1
-    pool = ((pages, page, kv_heads, HEAD_DIM), jnp.int8 if int8 else BF16)
+    pool = ((pages, page, kv_heads, head_dim), jnp.int8 if int8 else BF16)
     scale = ((pages, page, kv_heads), jnp.float32)
     shapes = [pool, pool, ((slots, tokens // page), jnp.int32),
               ((slots,), jnp.int32)] + ([scale, scale] if int8 else [])
@@ -97,7 +100,7 @@ def _paged(q_tokens, slots, page=16, int8=False, window=None,
         return pa.paged_attention_prefill(q, k_pages, v_pages, tables, lens,
                                           **kw)
 
-    return fn, [((slots, q_tokens, heads, HEAD_DIM), BF16)] + shapes
+    return fn, [((slots, q_tokens, heads, head_dim), BF16)] + shapes
 
 
 def _paged_packed(q_tokens, slots, heads=32, kv_heads=8, head_dim=64,
@@ -159,6 +162,20 @@ def _state_step(rows, heads, groups, d_head=64, d_state=128):
         ((rows, heads, d_head), f32), ((rows, groups, d_state), f32),
         ((rows, groups, d_state), f32), ((rows,), jnp.bool_),
         ((rows,), jnp.bool_)]
+
+
+def _delta_step(rows, key_heads=16, value_heads=32, d=128):
+    """A decode step's gated delta rule in place over a pool of ``rows``
+    slots and the garbage row at Qwen3-Next's widths (32 value heads of
+    [128, 128] float32: a row one block of 2 MiB)."""
+    from megatron_llm_tpu.ops.pallas.delta_step import delta_state_step
+
+    f32 = jnp.float32
+    return delta_state_step, [
+        ((rows + 1, value_heads, d, d), f32), ((rows, key_heads, d), BF16),
+        ((rows, key_heads, d), BF16), ((rows, value_heads, d), BF16),
+        ((rows, value_heads), f32), ((rows, value_heads), f32),
+        ((rows,), jnp.bool_), ((rows,), jnp.bool_)]
 
 
 def _retention_chunk(rows, tokens, slots=16, g=8, r=5, d=128):
@@ -268,6 +285,17 @@ CASES = {
         lambda: _experts(512 * 8, 64, 2304, 896),
     "ssm_state_step_nemotron_64_rows": lambda: _state_step(64, 64, 8),
     "ssm_state_step_granite_24_rows": lambda: _state_step(24, 128, 1),
+    "delta_state_step_qwen3_next_32_rows": lambda: _delta_step(32),
+    "paged_decode_2_kv_heads_of_256_32_slots":
+        lambda: _paged(1, 32, heads=16, kv_heads=2, tokens=33792,
+                       head_dim=256),
+    "paged_prefill_chunk_512_2_kv_heads_of_256":
+        lambda: _paged(512, 1, heads=16, kv_heads=2, tokens=33792,
+                       head_dim=256),
+    "moe_experts_qwen3_next_32_rows":
+        lambda: _experts(32 * 10, 128, 2048, 512),
+    "moe_experts_qwen3_next_chunk_512_rows":
+        lambda: _experts(512 * 10, 128, 2048, 512),
     "retention_chunk_brumby_512_tokens": lambda: _retention_chunk(1, 512),
     "retention_chunk_brumby_two_rows_of_300":
         lambda: _retention_chunk(2, 300),
@@ -337,6 +365,7 @@ NEMOTRON = "nemotron_cell_programs"
 TRINITY = "trinity_cell_programs"
 LFM2 = "lfm2_cell_programs"
 BRUMBY = "brumby_cell_programs"
+QWEN3_NEXT = "qwen3_next_cell_programs"
 
 
 def _compile_all(only: str = ""):
@@ -512,13 +541,29 @@ def _brumby_cell():
         seq_length=17920)), dict(num_slots=16, max_model_len=17920)
 
 
+def _qwen3_next_cell():
+    """The same of the benchmark's Qwen3-Next cell: 8 of the published 48
+    layers (two periods: three gated delta-rule layers and a gated
+    attention layer of 2 key-value heads of 256, twice), 128 of 512
+    experts of width 512 held, the whole vocabulary under an untied head,
+    32 slots of state and a pool of 32,769 pages."""
+    from megatron_llm_tpu.models.qwen3_next import (Qwen3NextModel,
+                                                    qwen3_next_config)
+
+    return lambda: Qwen3NextModel(qwen3_next_config(
+        "80b-a3b", num_layers=8, num_experts=128, moe_router_experts=512,
+        params_dtype="bf16", compute_dtype="bf16", seq_length=33792)), dict(
+            num_slots=32, num_blocks=32769, max_model_len=33792)
+
+
 # a cell's name among the child's arguments, its key in what the child
 # prints, and the cell
 CELLS = {"granite": (GRANITE, _granite_cell),
          "nemotron": (NEMOTRON, _nemotron_cell),
          "trinity": (TRINITY, _trinity_cell),
          "lfm2": (LFM2, _lfm2_cell),
-         "brumby": (BRUMBY, _brumby_cell)}
+         "brumby": (BRUMBY, _brumby_cell),
+         "qwen3_next": (QWEN3_NEXT, _qwen3_next_cell)}
 # every cell's engine beside its own keywords
 _CELL_ENGINE = dict(block_size=16, prefill_chunk=512, preemption=False,
                     paged_kernel="on", prefill_kernel="on")
@@ -576,7 +621,7 @@ def _cell_programs(chip, build, engine):
         # or every row's
         state = {(hlo_collectives._HLO_DTYPE[a.dtype.name], sh)
                  for p in eng._st.pages for name, a in p.items()
-                 if name in ("ssm_state", "ret_state")
+                 if name in ("ssm_state", "ret_state", "delta_state")
                  for sh in (tuple(a.shape), (a.shape[0] - 1,) + a.shape[1:])}
         found = {"state_bytes_per_slot": paged_kv.state_bytes_per_slot(
             eng._st.pages), "pool_bytes": eng.kv_pool_bytes,
@@ -612,14 +657,17 @@ def _cell_programs(chip, build, engine):
                 "state_rewrites": rewrites,
                 "kernels": sorted(set(re.findall(
                     r"(paged_attention_\w+?|moe_experts\w*?|ssm_state_step"
-                    r"|retention_state_step|retention_state_chunk)"
+                    r"|retention_state_step|retention_state_chunk"
+                    r"|delta_state_step)"
                     r"(?:\.\d+)? = ", text))),
                 "scopes": sorted({s for s in (
                     "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
                     "ssm_gate_norm", "ssm_out_proj", "attn_gate",
                     "post_attn_norm", "post_mlp_norm", "conv_in_proj",
                     "short_conv", "conv_out_proj", "retention_gate",
-                    "retention_chunk", "retention_step")
+                    "retention_chunk", "retention_step", "delta_proj",
+                    "delta_conv", "delta_gate", "delta_chunk", "delta_step",
+                    "delta_norm")
                     if f"/{s}/" in text})}
         return found
     except Exception as e:      # noqa: BLE001 - the compiler's refusal
@@ -973,6 +1021,59 @@ def test_the_brumby_cells_programs_compile_for_a_described_v5e():
     # 0.606 GB (the logits of 512 rows are 0.31 of it) where XLA's chunk
     # held 0.64
     assert chunk["temp_bytes"] < 0.62e9, chunk
+
+
+def test_the_qwen3_next_cells_programs_compile_and_fit_a_v5e():
+    """The Qwen3-Next cell's bytes and counts as the engine's plan has
+    them (8 layers at the published widths: six gated delta-rule layers
+    and two attention layers of 2 key-value heads of 256, 128 of 512
+    experts, the whole vocabulary, 32 slots and 32,769 pages): ISSUE
+    58's arithmetic, which ``jax.eval_shape`` lays out and nobody
+    allocates."""
+    found = _cell_plan(*_qwen3_next_cell())
+    # six layers of S [32, 128, 128] float32 and three columns of 8,192
+    assert found["state_bytes_per_slot"] == 6 * (32 * 128 * 128 * 4
+                                                 + 3 * 8192 * 2) == 12_877_824
+    # 33 rows of state, and pages of 4,096 B a token over two layers
+    assert found["pool_bytes"] == (33 * 12_877_824
+                                   + 32769 * 16 * 2 * 2 * 256 * 2 * 2)
+    # 8 x 406.85 M + 2 x 27.26 M + 6 x 33.72 M + 2 x 311.2 M + a norm
+    assert found["parameters"] == 4_133_998_720
+    # an expert's three matrices are one tile each way: a visit reads
+    # whole matrices
+    tiles = found["moe_expert_tiles"]
+    assert (tiles["w_in"]["tk"], tiles["w_in"]["tn"]) == (2048, 1024)
+    assert (tiles["w_out"]["tk"], tiles["w_out"]["tn"]) == (512, 2048)
+
+
+@pytest.mark.slow
+@pytest.mark.time_limit(900)
+def test_the_qwen3_next_cells_programs_compile_for_a_described_v5e():
+    """The Qwen3-Next cell's two programs at its real sizes, for a
+    described v5e: the decode step holds a Mosaic call for the delta
+    rule (``delta_state_step``), for the experts and for the walk at
+    pages of ``[16, 2, 256]``, owns its pool and rewrites no array of
+    the state's shape outside the step's kernel; the chunk (XLA's delta
+    rule) is LENT the pool, as every paged model's, holds it twice and
+    still fits the chip's 15.75 GB."""
+    found = _cell_compiled("qwen3_next")
+    step, chunk = found["engine_decode"], found["engine_prefill"]
+    assert step["kernels"] == ["delta_state_step", "moe_experts",
+                               "paged_attention_decode"]
+    assert chunk["kernels"] == ["moe_experts", "paged_attention_prefill"]
+    assert step["alias_bytes"] >= found["pool_bytes"], step
+    assert step["state_rewrites"] == [], step
+    assert chunk["alias_bytes"] == 0
+    for name, got, scope in (("engine_prefill", chunk, "delta_chunk"),
+                             ("engine_decode", step, "delta_step")):
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"] - got["alias_bytes"])
+        # 8.27 GB of weights, 2.57 GB of pool (twice in a chunk) and
+        # under 0.3 GB of temporaries
+        assert held < 15.75e9 * 0.9, (name, held)
+        assert got["temp_bytes"] < 0.3e9, (name, got)
+        assert {"delta_proj", "delta_conv", "delta_gate", "delta_norm",
+                "attn_gate", scope} <= set(got["scopes"]), got["scopes"]
 
 
 if __name__ == "__main__":
