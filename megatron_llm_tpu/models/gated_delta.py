@@ -65,7 +65,16 @@ are:
   row's ``valid_len`` has ``g = 0`` and ``beta = 0``, so it neither
   decays nor writes, the state a row leaves is that at its last VALID
   token, an idle row keeps its own, and the convolution's columns are
-  the last VALID ones;
+  the last VALID ones.  Under a ``PagedKVCache`` whose resolved
+  ``kernel`` is ``'pallas'`` (the engine's ``prefill_kernel``: the rule
+  ``models/retention.py`` follows) the same algebra, block for block and
+  rounding for rounding, is ONE kernel
+  (``ops/pallas/delta_chunk.py``, launched as ``delta_state_chunk``)
+  that solves a block's systems by blocks on the MXU beside the heads'
+  state held in VMEM and writes nothing between the gates and ``o`` to
+  HBM; the cache-less forward and an ``'xla'`` cache run
+  :func:`gated_delta_chunk`, one algorithm on two back ends chosen by
+  what the cache already resolved;
 * a **step** ``[S, 1, h]`` (the decode program), scope ``delta_step``:
   ``PagedKVCache.step_delta``, which on the ``'pallas'`` path is ONE
   in-place kernel over the live rows (``ops/pallas/delta_step.py``,
@@ -74,9 +83,9 @@ are:
 
 Scopes: ``delta_proj`` (the two input projections and ``W_out``),
 ``delta_conv``, ``delta_gate`` (``beta``, ``g``, the l2 norms),
-``delta_chunk``, ``delta_step``, ``delta_norm``.  Everything but the
-step's kernel is XLA's (PERF.md's open questions have what a kernel for
-the chunk would replace).
+``delta_chunk``, ``delta_step``, ``delta_norm``.  The projections, the
+convolution, the gates and the norm are XLA's; the recurrence is a
+kernel in both forms on the ``'pallas'`` path.
 """
 
 from __future__ import annotations
@@ -90,6 +99,7 @@ from megatron_llm_tpu.config import TransformerConfig
 from megatron_llm_tpu.models.mamba import causal_conv_silu
 from megatron_llm_tpu.models.short_conv import _held
 from megatron_llm_tpu.ops.layernorm import rms_norm
+from megatron_llm_tpu.ops.pallas.delta_chunk import BLOCK, delta_state_chunk
 from megatron_llm_tpu.ops.pallas.delta_step import for_value_heads
 from megatron_llm_tpu.parallel.layers import (
     init_linear_params,
@@ -97,10 +107,6 @@ from megatron_llm_tpu.parallel.layers import (
     scaled_init_method_normal,
 )
 
-# rows of a chunk's block: the triangular system is BLOCK x BLOCK a value
-# head (a chunk of 512 is eight blocks); 64 is the published code's, and
-# what the model leaves free, so no flag
-BLOCK = 64
 # the l2 norm's guard, the published kernels': x * rsqrt(sum x^2 + eps)
 _L2_EPS = 1e-6
 # the half-lives a FRESH model's heads are drawn between, in tokens (at a
@@ -308,7 +314,10 @@ def gated_delta_mixer(h: jax.Array, params, cfg: TransformerConfig, *,
             o, new_state = o[:, None], None
     else:
         with jax.named_scope("delta_chunk"):
-            o, new_state = gated_delta_chunk(q, k, v, g, beta, state, cd)
+            # one algorithm on two back ends, by what the cache resolved
+            in_kernel = kv_cache is not None and kv_cache.kernel == "pallas"
+            chunk = delta_state_chunk if in_kernel else gated_delta_chunk
+            o, new_state = chunk(q, k, v, g, beta, state, cd)
 
     with jax.named_scope("delta_norm"):
         y = gated_norm(o, z, params["norm"]["scale"],

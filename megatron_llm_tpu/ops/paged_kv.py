@@ -906,6 +906,9 @@ class CachePlan:
                     d.delta_rows_live if self.paged_kernel == "pallas"
                     else delta_layers * (self.num_slots + 1))
             d.delta_tokens = delta_layers * int(val.sum())
+            if d.kind == "prefill" and self.prefill_kernel == "pallas":
+                # the chunk ran in ops/pallas/delta_chunk.py's kernel
+                d.delta_chunk_tokens_kernel = d.delta_tokens
         if state_layers:
             d.ssm_rows_live = state_layers * len(val)
             if d.kind != "prefill":

@@ -190,9 +190,13 @@ RETENTION_FIELDS = ("retention_rows_live", "retention_rows_moved",
 # ``CachePlan.account``): live rows x layers; rows x layers whose state a
 # DECODE launch's program moves (the step's kernel,
 # ``ops/pallas/delta_step.py``: the live rows; the XLA step: every slot
-# and the garbage row; 0 on a prefill launch); tokens x layers.  The
-# state's bytes are ``ssm_state_bytes_held``, the state group's
-DELTA_FIELDS = ("delta_rows_live", "delta_rows_moved", "delta_tokens")
+# and the garbage row; 0 on a prefill launch); tokens x layers; and of a
+# PREFILL launch's tokens x layers those whose chunk ran in the kernel
+# (``ops/pallas/delta_chunk.py``: all of them on the ``'pallas'`` path, 0
+# on XLA's, as ``retention_chunk_tokens_kernel``).  The state's bytes are
+# ``ssm_state_bytes_held``, the state group's
+DELTA_FIELDS = ("delta_rows_live", "delta_rows_moved", "delta_tokens",
+                "delta_chunk_tokens_kernel")
 
 # a model with a layer type per layer (the engine's ``_window_advance``):
 # window-group pages given back to the allocator before this launch and

@@ -865,6 +865,10 @@ KERNEL_NAMES = {
     # (PR 58), under ``delta_step``: the first caller of the walker
     # written once (``delta_step.walk_live_rows``)
     "delta_state_step",
+    # a prefill chunk's gated delta rule, a block's systems solved on
+    # the MXU beside the heads' state in VMEM (PR 59), under
+    # ``delta_chunk``
+    "delta_state_chunk",
 }
 PROGRAM_NAMES = {
     "_decode_step": "engine_decode", "_verify_step": "engine_verify",
@@ -929,8 +933,9 @@ def test_every_kernel_and_program_carries_its_stable_name():
     # (mla_attention_prefill), 14 until the state's step got a kernel,
     # 15 until a retention layer's did, 16 until its chunk's, 17 until
     # the selection's attention went into the shared walk, 16 until the
-    # delta rule's step brought the live rows' walker
-    assert calls == 17
+    # delta rule's step brought the live rows' walker, 17 until its
+    # chunk got a kernel
+    assert calls == 18
     assert names == KERNEL_NAMES
 
     eng = _tiny_engine()

@@ -25,11 +25,12 @@ import pytest
 import _family
 from _family import BS, kernels, load, serve, tokens
 from megatron_llm_tpu import config as C
-from megatron_llm_tpu.models.gated_delta import (BLOCK, gated_delta_mixer,
+from megatron_llm_tpu.models.gated_delta import (BLOCK, gated_delta_chunk,
+                                                 gated_delta_mixer,
                                                  init_gated_delta_params)
 from megatron_llm_tpu.models.qwen3_next import qwen3_next_config
 from megatron_llm_tpu.ops import paged_kv
-from megatron_llm_tpu.ops.pallas import delta_step
+from megatron_llm_tpu.ops.pallas import delta_chunk, delta_step
 from megatron_llm_tpu.ops.pallas import paged_attention as pa
 from megatron_llm_tpu.ops.rope import apply_rotary_at
 
@@ -202,6 +203,241 @@ def test_the_steps_kernel_is_the_dense_step(monkeypatch):
     assert not np.asarray(o[1]).any()
 
 
+# --- the chunk's kernel ------------------------------------------------------
+
+def _operands(b, n, *, kh=2, r=2, dk=16, dv=16, seed=0, parallel=0.0,
+              gate=None, beta=None, drawn=True):
+    """A chunk's operands as the mixer forms them: ``q`` scaled and ``k``
+    of unit length a head, gates by head; ``parallel``: how much of every
+    key is one common direction; ``gate`` / ``beta``: the same for every
+    token."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    hv = kh * r
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    q = unit(jax.random.normal(ks[0], (b, n, kh, dk))) * dk ** -0.5
+    k = jax.random.normal(ks[1], (b, n, kh, dk))
+    k = unit((1 - parallel) * k
+             + parallel * 4.0 * jax.random.normal(ks[6], (b, 1, kh, dk)))
+    v = jax.random.normal(ks[2], (b, n, hv, dv))
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, n, hv), minval=-5.0,
+                                    maxval=0.0))
+    bt = jax.nn.sigmoid(2.0 * jax.random.normal(ks[4], (b, n, hv)))
+    if gate is not None:
+        g = jnp.full_like(g, gate)
+    if beta is not None:
+        bt = jnp.full_like(bt, beta)
+    S = (jax.random.normal(ks[5], (b, hv, dk, dv)) if drawn
+         else jnp.zeros((b, hv, dk, dv)))
+    return q, k, v, g, bt, S
+
+
+def _padded(g, beta, valid):
+    """The gates as the mixer hands them on: 0 past a row's real tokens."""
+    live = (jnp.arange(g.shape[1])[None] < jnp.asarray(valid)[:, None])
+    return jnp.where(live[..., None], g, 0.0), jnp.where(live[..., None],
+                                                         beta, 0.0)
+
+
+@jax.jit
+def _a_token_at_a_time(q, k, v, g, beta, S):
+    """The recurrence itself in float32 (``dense_gated_delta_step``, every
+    product at full precision): ``o`` and the state after the last
+    token."""
+    def token(S, x):
+        o, S = delta_step.dense_gated_delta_step(S, *x)
+        return S, o
+
+    S, o = jax.lax.scan(token, S, tuple(
+        jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), S
+
+
+# a chunk's length, each row's real tokens, whether the rows start from
+# zeros or a drawn state
+CHUNK_CASES = {
+    "one_block_from_zeros": dict(n=BLOCK, drawn=False),
+    "eight_blocks_from_a_drawn_state": dict(n=8 * BLOCK),
+    "a_length_no_multiple_of_the_block": dict(n=3 * BLOCK + 7),
+    "a_chunk_shorter_than_a_block": dict(n=12, valid=[5, 12]),
+    "padding_past_valid_len_and_an_idle_row": dict(
+        n=2 * BLOCK + 30, valid=[BLOCK + 11, 0, 2 * BLOCK + 30]),
+    "one_value_head_a_key_head": dict(n=BLOCK + 3, r=1),
+    "four_value_heads_a_key_head": dict(n=BLOCK + 3, r=4, kh=1),
+    "wider_values_than_keys": dict(n=2 * BLOCK, dv=32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_the_chunks_kernel_is_gated_delta_chunk_and_the_recurrence(
+        case, monkeypatch):
+    """``delta_state_chunk`` under interpret against ``gated_delta_chunk``
+    on the same rows' state, and both against the recurrence a token at
+    a time in float32: ``o`` at the real tokens, the state a row leaves;
+    an idle row's state bit for bit."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    c = dict(CHUNK_CASES[case])
+    n, valid = c.pop("n"), c.pop("valid", None)
+    b = len(valid) if valid else 2
+    valid = valid or [n] * b
+    q, k, v, g, beta, S = _operands(b, n, seed=21, **c)
+    g, beta = _padded(g, beta, valid)
+    o, new = delta_chunk.delta_state_chunk(q, k, v, g, beta, S, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        wo, wnew = gated_delta_chunk(q, k, v, g, beta, S, jnp.float32)
+    assert o.shape == wo.shape and new.shape == wnew.shape
+    assert o.dtype == new.dtype == jnp.float32
+    real = np.arange(n)[None] < np.asarray(valid)[:, None]
+    np.testing.assert_allclose(o[real], wo[real], atol=2e-5)
+    np.testing.assert_allclose(new, wnew, atol=2e-5)
+    ro, rnew = _a_token_at_a_time(q, k, v, g, beta, S)
+    np.testing.assert_allclose(o[real], ro[real], atol=5e-5)
+    np.testing.assert_allclose(new, rnew, atol=5e-5)
+    for row, tokens_ in enumerate(valid):
+        if not tokens_:
+            np.testing.assert_array_equal(new[row], S[row])
+
+
+@pytest.mark.parametrize("cdtype", ["float32", "bfloat16"])
+def test_the_worst_conditioned_system_is_solved_as_well_as_xla_solves_it(
+        cdtype, monkeypatch):
+    """Gates near 0 with ``beta`` near 1 over nearly parallel keys: ``A``
+    is nearly the full strictly lower triangle of ones, the worst ``I +
+    A`` the delta rule forms.  The kernel's inverse by blocks stands no
+    further from the recurrence in float32 than 1.5 times XLA's forward
+    substitution does."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    cd = jnp.dtype(cdtype)
+    q, k, v, g, beta, S = _operands(2, 4 * BLOCK, seed=22, parallel=0.9,
+                                    gate=-1e-4, beta=0.999)
+    # what both forms are handed: operands in the compute dtype
+    q, k, v = (x.astype(cd).astype(jnp.float32) for x in (q, k, v))
+    assert float(jnp.einsum("bnhd,bmhd->bhnm", k, k).min()) > 0.85
+    ro, rnew = _a_token_at_a_time(q, k, v, g, beta, S)
+    apart = {}
+    for name, chunk in (("kernel", delta_chunk.delta_state_chunk),
+                        ("xla", gated_delta_chunk)):
+        with jax.default_matmul_precision("highest"):
+            o, new = chunk(q.astype(cd), k.astype(cd), v.astype(cd), g,
+                           beta, S, cd)
+        assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(new).all())
+        apart[name] = (float(jnp.abs(o - ro).max()),
+                       float(jnp.abs(new - rnew).max()))
+    floor = 1e-5 if cd == jnp.float32 else 0.0
+    for mine, xlas in zip(apart["kernel"], apart["xla"]):
+        assert mine <= 1.5 * max(xlas, floor), apart
+    assert apart["kernel"][0] < (1e-4 if cd == jnp.float32 else 5e-2), apart
+
+
+def test_in_the_kernel_a_key_heads_two_value_heads_read_it_alone(
+        monkeypatch):
+    """Another key head 0 through the chunk's kernel: exactly its two
+    value heads' outputs and states move, key head 1's not a bit."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    q, k, v, g, beta, S = _operands(1, BLOCK + 20, seed=23)
+    other = k.at[:, :, 0].set(k[:, ::-1, 0])
+    one = delta_chunk.delta_state_chunk(q, k, v, g, beta, S, jnp.float32)
+    two = delta_chunk.delta_state_chunk(q, other, v, g, beta, S, jnp.float32)
+    assert one[0].shape == (1, BLOCK + 20, 4, 16)
+    # the value heads' axis of ``o`` and of the state
+    for a, b, heads in zip(one, two, (2, 1)):
+        moved = jnp.abs(a - b).max(axis=tuple(
+            i for i in range(a.ndim) if i != heads))
+        assert float(moved[:2].min()) > 1e-3, moved
+        assert not np.asarray(moved[2:]).any(), moved
+
+
+def test_chunks_in_the_kernel_then_steps_in_the_steps_are_one_long_chunk(
+        monkeypatch):
+    """Two rows through the mixer under a cache on the kernel path: a
+    chunk of 150 tokens from zeros over dirty slots, one of 70 over what
+    it left, then six steps through ``delta_state_step`` (row s is slot
+    s), against ONE chunk of 226 tokens in XLA's form with no cache; the
+    slots are left with the recurrence's state."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    cfg, p = _mixer()
+    h = _unit(jax.random.normal(jax.random.PRNGKey(24), (2, 226, 64)))
+    whole = gated_delta_mixer(h, p, cfg)
+    pool = paged_kv.init_pools(cfg, 4, BS, num_slots=2)[0]
+    pool = {name: jax.random.normal(jax.random.PRNGKey(25), a.shape
+                                    ).astype(a.dtype)
+            for name, a in pool.items()}
+    dirty = pool["delta_state"][2]
+    outs, context = [], 0
+    for lo, hi in ((0, 150), (150, 220)):
+        out, c = gated_delta_mixer(
+            h[:, lo:hi], p, cfg, kv_cache=_cache(
+                cfg, 2, [context] * 2, [hi - lo] * 2, rows=[0, 1],
+                pool=pool, kernel="pallas"))
+        outs.append(out)
+        pool, context = c.pool, hi
+        assert c.context_lens.tolist() == [hi, hi]
+    for t in range(220, 226):
+        out, c = gated_delta_mixer(
+            h[:, t:t + 1], p, cfg, kv_cache=_cache(
+                cfg, 2, [t] * 2, [1] * 2, pool=pool, kernel="pallas"))
+        outs.append(out)
+        pool = c.pool
+    np.testing.assert_allclose(jnp.concatenate(outs, axis=1), whole,
+                               atol=2e-5, rtol=0)
+    for b in range(2):
+        S = _token_by_token(h[b], p, cfg)[1]
+        np.testing.assert_allclose(pool["delta_state"][b], S, atol=2e-5)
+    np.testing.assert_array_equal(pool["delta_state"][2], dirty)
+
+
+def test_the_mixer_on_the_kernel_path_is_the_mixer_on_xlas(monkeypatch):
+    """The chunk branch under a ``'pallas'`` cache against the same under
+    an ``'xla'`` one and the reference's recurrence: a row padded past
+    its valid tokens beside an idle row, whose slot is left bit for bit."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    cfg, p = _mixer()
+    n, valid = 2 * BLOCK, BLOCK + 11
+    h = _unit(jax.random.normal(jax.random.PRNGKey(26), (2, n, 64)))
+    pool = paged_kv.init_pools(cfg, 4, BS, num_slots=2)[0]
+    held = jax.random.normal(jax.random.PRNGKey(27),
+                             pool["delta_state"].shape[1:])
+    pool = {**pool, "delta_state": pool["delta_state"].at[1].set(held)}
+    got = {kernel: gated_delta_mixer(
+        h, p, cfg, kv_cache=_cache(cfg, 2, [0, 7], [valid, 0], rows=[0, 1],
+                                   pool=pool, kernel=kernel))
+        for kernel in ("pallas", "xla")}
+    (out, c), (xout, xc) = got["pallas"], got["xla"]
+    np.testing.assert_allclose(out[0, :valid], xout[0, :valid], atol=2e-5)
+    np.testing.assert_allclose(c.pool["delta_state"][0],
+                               xc.pool["delta_state"][0], atol=2e-5)
+    want, S, tail = _token_by_token(h[0, :valid], p, cfg)
+    np.testing.assert_allclose(out[0, :valid], want, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(c.pool["delta_state"][0], S, atol=2e-5)
+    np.testing.assert_allclose(c.pool["conv_state"][0], tail, atol=1e-5)
+    np.testing.assert_array_equal(c.pool["delta_state"][1], held)
+    assert np.asarray(c.context_lens).tolist() == [valid, 7]
+
+
+@pytest.mark.parametrize("path", ["no_cache", "xla", "pallas"])
+def test_the_chunks_kernel_is_taken_only_under_a_cache_on_the_pallas_path(
+        path, monkeypatch):
+    """The cache-less forward (what differentiates through the model)
+    and a cache whose ``kernel`` is ``'xla'`` (the CPU, several devices)
+    trace no ``pallas_call`` and keep ``triangular_solve``; a cache on
+    the ``'pallas'`` path traces ``delta_state_chunk`` and no solve."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    cfg, p = _mixer()
+    h = jnp.ones((1, 40, 64))
+    if path == "no_cache":
+        text = str(jax.make_jaxpr(lambda p: gated_delta_mixer(h, p, cfg))(p))
+    else:
+        cache = _cache(cfg, 2, [0], [40], rows=[1], kernel=path)
+        text = str(jax.make_jaxpr(
+            lambda p, c: gated_delta_mixer(h, p, cfg, kv_cache=c)[0])(
+                p, cache))
+    assert ("pallas_call" in text) == (path == "pallas"), path
+    assert ("delta_state_chunk" in text) == (path == "pallas"), path
+    assert ("triangular_solve" in text) == (path != "pallas"), path
+
+
 # --- the stack --------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [5, 16, 17, 70])
@@ -235,6 +471,17 @@ def test_the_engine_over_pool_and_state_group_matches_one_full_forward(
     moved = 1 if kernel == "on" else eng.config.num_slots + 1
     assert stats["delta_rows_moved"] == 6 * moved * (new - 1)
     assert stats["ssm_state_bytes_held"] > 0
+    # the chunks ran in the chunk's kernel, every token of every layer,
+    # or none of them; a step's tokens are never the chunk's
+    chunks = [r for r in records if r.kind == "prefill"]
+    assert eng.prefill_kernel == ("pallas" if kernel == "on" else "xla")
+    assert len(chunks) == -(-prompt // 32)
+    assert all(r.delta_tokens > 0 and r.delta_chunk_tokens_kernel
+               == (r.delta_tokens if kernel == "on" else 0) for r in chunks)
+    assert stats["delta_chunk_tokens_kernel"] \
+        == (6 * prompt if kernel == "on" else 0)
+    assert not any(r.delta_chunk_tokens_kernel for r in records
+                   if r.kind != "prefill")
 
 
 @pytest.mark.parametrize("fault", FAULTS)

@@ -178,6 +178,25 @@ def _delta_step(rows, key_heads=16, value_heads=32, d=128):
         ((rows,), jnp.bool_), ((rows,), jnp.bool_)]
 
 
+def _delta_chunk(rows, tokens, key_heads=16, value_heads=32, d=128):
+    """A prefill chunk's gated delta rule at Qwen3-Next's widths (16 key
+    heads serving 32 value heads of [128, 128] float32; a key head's two
+    value heads a program, 128 rows of systems): the cell's ``[1, 512]``
+    chunk, eight blocks, and rows whose length is no multiple of the
+    block."""
+    import functools
+
+    from megatron_llm_tpu.ops.pallas.delta_chunk import delta_state_chunk
+
+    f32 = jnp.float32
+    return functools.partial(delta_state_chunk, cdtype=BF16), [
+        ((rows, tokens, key_heads, d), BF16),
+        ((rows, tokens, key_heads, d), BF16),
+        ((rows, tokens, value_heads, d), BF16),
+        ((rows, tokens, value_heads), f32), ((rows, tokens, value_heads), f32),
+        ((rows, value_heads, d, d), f32)]
+
+
 def _retention_chunk(rows, tokens, slots=16, g=8, r=5, d=128):
     """A prefill chunk's power retention in place over a state group of
     ``slots`` slots and the garbage row at Brumby's widths (8 key-value
@@ -286,6 +305,8 @@ CASES = {
     "ssm_state_step_nemotron_64_rows": lambda: _state_step(64, 64, 8),
     "ssm_state_step_granite_24_rows": lambda: _state_step(24, 128, 1),
     "delta_state_step_qwen3_next_32_rows": lambda: _delta_step(32),
+    "delta_chunk_qwen3_next_512_tokens": lambda: _delta_chunk(1, 512),
+    "delta_chunk_qwen3_next_two_rows_of_300": lambda: _delta_chunk(2, 300),
     "paged_decode_2_kv_heads_of_256_32_slots":
         lambda: _paged(1, 32, heads=16, kv_heads=2, tokens=33792,
                        head_dim=256),
@@ -650,6 +671,15 @@ def _cell_programs(chip, build, engine):
                     if dt == "bf16" and tuple(sh[-2:]) == (65, 128)}),
                 "retention_chunk_calls": len(re.findall(
                     r"retention_state_chunk(?:\.\d+)? = ", text)),
+                "delta_chunk_calls": len(re.findall(
+                    r"delta_state_chunk(?:\.\d+)? = ", text)),
+                # XLA's form of the delta chunk: its solve, and a
+                # block's right side and solution [.., 64, 256] float32
+                "triangular_solves": len(re.findall(
+                    r"triangular.solve", text, re.I)),
+                "solve_arrays": sorted({
+                    f"{dt}{list(sh)}" for r in rows for dt, sh in r["shapes"]
+                    if dt == "f32" and tuple(sh[-2:]) == (64, 256)}),
                 "argument_bytes": m.argument_size_in_bytes,
                 "output_bytes": m.output_size_in_bytes,
                 "alias_bytes": m.alias_size_in_bytes,
@@ -658,7 +688,7 @@ def _cell_programs(chip, build, engine):
                 "kernels": sorted(set(re.findall(
                     r"(paged_attention_\w+?|moe_experts\w*?|ssm_state_step"
                     r"|retention_state_step|retention_state_chunk"
-                    r"|delta_state_step)"
+                    r"|delta_state_step|delta_state_chunk)"
                     r"(?:\.\d+)? = ", text))),
                 "scopes": sorted({s for s in (
                     "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
@@ -1053,14 +1083,22 @@ def test_the_qwen3_next_cells_programs_compile_for_a_described_v5e():
     described v5e: the decode step holds a Mosaic call for the delta
     rule (``delta_state_step``), for the experts and for the walk at
     pages of ``[16, 2, 256]``, owns its pool and rewrites no array of
-    the state's shape outside the step's kernel; the chunk (XLA's delta
-    rule) is LENT the pool, as every paged model's, holds it twice and
-    still fits the chip's 15.75 GB."""
+    the state's shape outside the step's kernel; the chunk holds one
+    Mosaic call a delta layer (``delta_state_chunk``, PR 59: before it
+    the chunk was XLA's, a ``triangular_solve`` and ``f32[1, 8, 32, 64,
+    256]`` right sides and solutions in HBM), is LENT the pool, as every
+    paged model's, holds it twice and still fits the chip's 15.75 GB."""
     found = _cell_compiled("qwen3_next")
     step, chunk = found["engine_decode"], found["engine_prefill"]
     assert step["kernels"] == ["delta_state_step", "moe_experts",
                                "paged_attention_decode"]
-    assert chunk["kernels"] == ["moe_experts", "paged_attention_prefill"]
+    assert chunk["kernels"] == ["delta_state_chunk", "moe_experts",
+                                "paged_attention_prefill"]
+    # once a delta layer, no solve of XLA's and nothing of its shape
+    assert chunk["delta_chunk_calls"] == 6, chunk
+    assert step["delta_chunk_calls"] == 0
+    assert chunk["triangular_solves"] == 0, chunk
+    assert chunk["solve_arrays"] == [], chunk
     assert step["alias_bytes"] >= found["pool_bytes"], step
     assert step["state_rewrites"] == [], step
     assert chunk["alias_bytes"] == 0
