@@ -339,6 +339,23 @@ def lm_head_weight(params) -> jax.Array:
     return params["embedding"]["word"]["embedding"]
 
 
+def lm_head_logits(params, h: jax.Array, cfg: TransformerConfig, *,
+                   sequence_parallel: bool = False) -> jax.Array:
+    """The output head over final hidden states ``h`` [..., H] -> logits
+    [..., V] (vocab-sharded under tp): what ``language_model_forward``
+    ends with, and what a caller that took ``compute_logits=False`` runs
+    on the rows it reads (the serving engine's prefill chunk: one)."""
+    with jax.named_scope("lm_head"):
+        logits = parallel_lm_logits(
+            h, lm_head_weight(params),
+            sequence_parallel=sequence_parallel,
+            compute_dtype=cfg.compute_jnp_dtype,
+        )
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    return logits
+
+
 def language_model_forward(
     params,
     tokens: jax.Array,
@@ -400,15 +417,8 @@ def language_model_forward(
             return h, new_caches
         return (h, moe_aux) if cfg.num_experts > 1 else h
 
-    head = lm_head_weight(params)
-    with jax.named_scope("lm_head"):
-        logits = parallel_lm_logits(
-            h, head,
-            sequence_parallel=sequence_parallel,
-            compute_dtype=cfg.compute_jnp_dtype,
-        )
-        if cfg.logits_scaling != 1.0:
-            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    logits = lm_head_logits(params, h, cfg,
+                            sequence_parallel=sequence_parallel)
     if kv_caches is not None:
         return logits, new_caches
     return (logits, moe_aux) if cfg.num_experts > 1 else logits

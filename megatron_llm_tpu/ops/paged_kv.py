@@ -156,6 +156,9 @@ def expands_latents(kernel: str, n: int) -> bool:
 
 
 FULL, WINDOW, STATE, NONE = "full", "window", "state", "none"
+# beside the groups' tables in what a prefill CHUNK is handed
+# (``CachePlan.chunk_tables``): whether the chunk ends its context
+LAST = "last"
 # the recurrent state's dtype: an ASSUMPTION (the published config gives
 # no cache dtype), float32 because it is multiplied and added to at every
 # token of a request
@@ -860,6 +863,25 @@ class CachePlan:
             # step takes every slot, row s is slot s, and carries none)
             tables[STATE] = np.arange(self.num_slots, dtype=np.int32)[rows]
         return tables
+
+    def chunk_tables(self, blocks, slot: int, last: bool) -> dict:
+        """What a prefill CHUNK of slot ``slot`` takes as ``block_tables``:
+        the slot's row of each group's table under the group's name (a
+        model of one type: ``{FULL: table}``; ``STATE`` the slot), and
+        under ``LAST`` a 0-d bool, whether the chunk ends its request's
+        context: the one chunk whose logits somebody reads."""
+        tables = self.tables(blocks, slice(slot, slot + 1))
+        if self.groups is None:
+            tables = {FULL: tables}
+        return {**tables, LAST: np.bool_(last)}
+
+    def chunk_given(self, handed: dict) -> tuple:
+        """(what ``step_caches`` takes as ``block_tables``, whether the
+        chunk ends its context) of what ``chunk_tables`` built, inside
+        the chunk's program."""
+        tables = {g: t for g, t in handed.items() if g != LAST}
+        return (tables[FULL] if self.groups is None else tables,
+                handed[LAST])
 
     def account(self, d, context_lens, valid_lens, n: int,
                 admitted: int) -> None:

@@ -27,7 +27,11 @@ opaque pytree):
 * ``prefill_step`` — ``[1, prefill_chunk]`` tokens of one request's
   prompt.  Chunking fixes the shape (one compile for any prompt length)
   and bounds how long a long prompt can stall decode: the scheduler
-  strictly alternates chunks with decode steps.
+  strictly alternates chunks with decode steps.  The output head runs
+  once a request and for one row: the host says with the chunk's tables
+  (``CachePlan.chunk_tables``) whether the chunk ends its context, and
+  only that chunk multiplies its last live row by the head; every other
+  chunk's logits are zeros nobody reads.
 
 Steady state is exactly these two programs plus a ``[1, V]`` first-token
 sampler; ``warmup()`` compiles all three, after which
@@ -101,7 +105,10 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from megatron_llm_tpu import config as model_config
 from megatron_llm_tpu import hlo_collectives, telemetry, tracing
-from megatron_llm_tpu.models.language_model import language_model_forward
+from megatron_llm_tpu.models.language_model import (
+    language_model_forward,
+    lm_head_logits,
+)
 from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.ops.pallas import grouped_matmul
 from megatron_llm_tpu.serving.cache_observatory import CacheObservatory
@@ -744,16 +751,25 @@ class InferenceEngine:
                       block_table):
         C = tokens.shape[1]
         positions = (start_pos + jnp.arange(C))[None, :]    # [1, C]
+        cfg = self.model.cfg
+        block_table, last = self._cache.chunk_given(block_table)
         caches = paged_kv.step_caches(
             pages, block_table, jnp.full((1,), start_pos, jnp.int32),
             jnp.full((1,), valid_len, jnp.int32), self.prefill_kernel,
             self._layer_groups)
-        logits, new_caches = language_model_forward(
-            params, tokens, positions, None, self.model.cfg,
-            rng_key=None, train=False, kv_caches=caches)
-        last = jax.lax.dynamic_index_in_dim(
-            logits[0], valid_len - 1, axis=0, keepdims=False)
-        return (last.astype(jnp.float32), paged_kv.pools_of(new_caches),
+        h, new_caches = language_model_forward(
+            params, tokens, positions, None, cfg, rng_key=None,
+            train=False, kv_caches=caches, compute_logits=False)
+        # the head once a request and for one row: only the chunk that
+        # ends its context has its logits read, and of them the row of
+        # its last live token; every other chunk gives zeros
+        row = jax.lax.dynamic_slice_in_dim(h, valid_len - 1, 1, axis=1)
+        logits = jax.lax.cond(
+            last,
+            lambda: lm_head_logits(params, row, cfg)[0, 0].astype(
+                jnp.float32),
+            lambda: jnp.zeros((cfg.padded_vocab_size,), jnp.float32))
+        return (logits, paged_kv.pools_of(new_caches),
                 paged_kv.routing_of(new_caches))
 
     def _spill_fetch(self, manager, block: int):
@@ -1271,9 +1287,11 @@ class InferenceEngine:
         for bi in range(start // bs, (start + valid - 1) // bs + 1):
             self._writable(st, req.slot, bi)
         self._window_advance(st, d, [(req.slot, start, valid)])
-        table = self._cache.tables(st.blocks,
-                                   slice(req.slot, req.slot + 1))
+        # whether this chunk ends the context: the one that runs the head
+        done = start + valid >= len(ptoks)
+        table = self._cache.chunk_tables(st.blocks, req.slot, done)
         d.start, d.valid = start, valid
+        d.prefill_head_rows = int(done)
         d.cached_tokens = req.cached_prompt_tokens
         d.requests = (req.id,)
         d.traces = (req.trace_id,) if req.trace_id else ()
@@ -1288,7 +1306,6 @@ class InferenceEngine:
         with st.pool_lock:
             last_logits, st.pages, routing = self._prefill_step(
                 self.params, st.pages, *handed)
-        done = start + valid >= len(ptoks)
         if done:
             # the slot has not decoded since the host gave it its key, so
             # the host's row IS its key (_EngineState)
@@ -1778,7 +1795,7 @@ class InferenceEngine:
             "engine_prefill": (
                 self.params, pool,
                 np.zeros((1, cfg.prefill_chunk), np.int32), zero, zero,
-                self._cache.tables(st.blocks, slice(0, 1))),
+                self._cache.chunk_tables(st.blocks, 0, False)),
             # a chunk's last logits: the prefill program's output
             "engine_sample_first": (
                 jax.ShapeDtypeStruct(
@@ -1862,11 +1879,15 @@ class InferenceEngine:
             finished = dict(self.finished)
         dec = max(self.decode_steps, 1)
         loop = self.loop_profiler.stats()
+        totals = self.loop_profiler.totals()
         s.update({
             "decode_steps": self.decode_steps,
             "sample_draw_steps": self.sample_draw_steps,
             "sample_sort_steps": self.sample_sort_steps,
             "prefill_chunks": self.prefill_chunks,
+            # of those, the chunks that ended their context and ran the
+            # output head (one a request prefilled to its end)
+            "prefill_heads": totals["prefill_head_rows"],
             "tokens_generated": self.tokens_generated,
             "prefill_tokens_submitted": self.prefill_tokens_submitted,
             "prefill_tokens_computed": self.prefill_tokens_computed,
@@ -1886,8 +1907,7 @@ class InferenceEngine:
             "accepted_tokens": self.accepted_tokens,
             # what the launches counted, summed where they finish (the
             # profiler's totals); those the 'loop' block carries stay there
-            **{f: n for f, n in self.loop_profiler.totals().items()
-               if f not in loop},
+            **{f: n for f, n in totals.items() if f not in loop},
             **({"moe_expert_tiles": self.moe_expert_tiles}
                if self.moe_expert_tiles else {}),
             "engine_restarts": self.engine_restarts,

@@ -207,6 +207,12 @@ DELTA_FIELDS = ("delta_rows_live", "delta_rows_moved", "delta_tokens",
 KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
              "kv_held_bytes", "kv_full_pages_held", "kv_live_tokens")
 
+# a prefill chunk's output head (the engine's ``_run_prefill_chunk``): the
+# rows it multiplied by the ``[V, H]`` head: 1 for the chunk that ends
+# its request's context, whose last live row the first token is sampled
+# from, 0 for every other chunk, which runs no head
+PREFILL_FIELDS = ("prefill_head_rows",)
+
 # what the launch moved between host and device (the engine's launch
 # paths): host arrays handed to its programs (each table counts one; an
 # array that lives on the device counts only in the launch that uploads
@@ -223,7 +229,7 @@ HOST_FIELDS = ("host_uploads", "host_reads", "compile_secs", "gc_secs")
 # ``as_dict()`` carries and ``totals()`` gives goes through it
 COUNTED_FIELDS = (MOE_FIELDS + DSA_FIELDS + MLA_FIELDS + SSM_FIELDS
                   + CONV_FIELDS + RETENTION_FIELDS + DELTA_FIELDS + KV_FIELDS
-                  + HOST_FIELDS)
+                  + PREFILL_FIELDS + HOST_FIELDS)
 
 # the compiled programs whose operations run inside a launch of each
 # kind (a last prefill chunk samples its first token in the same launch),

@@ -566,10 +566,13 @@ class Engines:
     def tapped(self, eng, by_slot=False) -> dict:
         """The engine's programs with their logits kept, for this test:
         the prefill step returns its chunk's last live row, kept by its
-        position; the decode step is run without its sampler on the
-        step's own arguments, as the benchmark's probe does, each live
-        row kept by its position (``by_slot``: by its slot and position,
-        where rows of two requests may stand at one)."""
+        position (the tap tells EVERY chunk it ends its context, so each
+        runs the head the engine asks only of a request's last:
+        ``CachePlan.chunk_tables``); the decode step is run without its
+        sampler on the step's own arguments, as the benchmark's probe
+        does, each live row kept by its position (``by_slot``: by its
+        slot and position, where rows of two requests may stand at
+        one)."""
         got = {}
         prefill, decode = eng._prefill_step, eng._decode_step
         step_logits = self.step_logits.get(id(eng))
@@ -588,7 +591,8 @@ class Engines:
             step_logits = self.step_logits[id(eng)] = jax.jit(step_logits)
 
         def tapped_prefill(params, pages, toks, start, valid, table):
-            out = prefill(params, pages, toks, start, valid, table)
+            out = prefill(params, pages, toks, start, valid,
+                          {**table, paged_kv.LAST: np.bool_(True)})
             got[int(start) + int(valid) - 1] = np.asarray(out[0])
             return out
 

@@ -492,12 +492,12 @@ def _groups():
     return {name: getattr(lp, name) for name in (
         "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS",
         "CONV_FIELDS", "RETENTION_FIELDS", "DELTA_FIELDS", "KV_FIELDS",
-        "HOST_FIELDS")}
+        "PREFILL_FIELDS", "HOST_FIELDS")}
 
 
 @pytest.mark.parametrize("group", sorted(_groups()))
 def test_a_counted_field_is_summed_where_the_launch_finishes(group):
-    """The counted fields have ONE declaration, the seven groups' tuples
+    """The counted fields have ONE declaration, the groups' tuples
     together: a record that sets every one of them, finished twice, is in
     ``totals()`` twice, field by field, ``as_dict()`` carries each, and a
     record that set none counts nothing."""
@@ -567,11 +567,15 @@ def test_every_counter_of_stats_is_the_sum_over_the_ring(family):
         assert where[f] == sum(getattr(r, f) for r in records), f
         assert not hasattr(eng, f), f
         moved += where[f] > 0
-    # each family counts what its mechanisms are, and nothing else
+    # each family counts what its mechanisms are and the one thing every
+    # family's chunks count (the head's rows: a request, a head), and
+    # nothing else
     counted = {f.split("_")[0] for f in COUNTED_FIELDS if stats.get(f)}
-    assert counted == {"granite": {"moe", "ssm"}, "keye": {"moe", "dsa"},
-                       "kanana": {"moe", "mla"}, "mellum": {"moe", "kv"},
-                       "olmoe": {"moe"}, "mistral": set()}[family]
+    assert counted - {"prefill"} == {
+        "granite": {"moe", "ssm"}, "keye": {"moe", "dsa"},
+        "kanana": {"moe", "mla"}, "mellum": {"moe", "kv"},
+        "olmoe": {"moe"}, "mistral": set()}[family]
+    assert stats["prefill_head_rows"] == stats["prefill_heads"] == len(reqs)
     assert moved >= 2
 
 

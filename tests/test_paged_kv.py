@@ -93,7 +93,7 @@ def test_the_engine_resolves_each_program_once(tiny, monkeypatch, decode,
             tables, np.ones(S, i32), *knobs),
         "prefill": jax.make_jaxpr(eng._prefill_step)(
             eng.params, st.pages, np.zeros((1, 16), i32), i32(0), i32(16),
-            tables[:1]),
+            {paged_kv.FULL: tables[:1], paged_kv.LAST: np.bool_(True)}),
     }
     has_kernel = {k: "pallas_call" in str(j) for k, j in jaxprs.items()}
     assert has_kernel == {"decode": decode == "on",

@@ -112,7 +112,7 @@ def test_a_chunk_and_the_page_programs_are_lent_the_pool(dense):
         before = _leaves(st.pages)
         eng._prefill_step(eng.params, st.pages, np.zeros((1, 16), np.int32),
                           np.int32(0), np.int32(0),
-                          eng._cache.tables(st.blocks, slice(0, 1)))
+                          eng._cache.chunk_tables(st.blocks, 0, True))
         eng._copy_page(st.pages, 0, 0)
         page = eng._fetch_block(st.pages, np.int32(0))
         eng._host_load(st.pages, page, np.int32(0))

@@ -22,17 +22,23 @@ import pytest
 # [S, 2] words).  PR 41 had changed both programs (the cache's write
 # keeps the pool's own shape).  A PR that MEANS to change a family's
 # program runs the script and records what it prints here.
+#
+# PR 60 MEANT every family's ``engine_prefill`` and no ``engine_decode``
+# (all twelve decode hashes are the ones recorded before it): a chunk
+# takes the stack's hidden states (``compute_logits=False``), and the
+# output head (``language_model.lm_head_logits``) runs on its one live
+# last row inside a ``cond`` on ``CachePlan.chunk_tables``'s ``LAST``.
 TRACED = {
-    "mistral": {"engine_prefill": "97f7c403d8d97874",
+    "mistral": {"engine_prefill": "e89f5bbb8546232a",
                 "engine_decode": "1271bc7d18f7d88f"},
     # PR 46 MEANT both programs of the seven sparse families and nothing
     # else (the dense family's are PR 44's): the dropless layer's combine
     # gathers the experts' rows once, in their own dtype, and adds the
     # choices in turn under the gates (``models/moe.py``, scope
     # ``moe_combine``)
-    "mixtral": {"engine_prefill": "0c7d499804bf34eb",
+    "mixtral": {"engine_prefill": "c42e7642cd9827a6",
                 "engine_decode": "d108ab43dbc7ce8b"},
-    "olmoe": {"engine_prefill": "8cd66b78f8c91346",
+    "olmoe": {"engine_prefill": "072d0c6c379aad9b",
               "engine_decode": "971de090d62b6706"},
     # PR 57 MEANT Keye's attention under the choice (the shared paged walk
     # with a mask in place of ``dsa_attention.py``'s own) and these two
@@ -40,39 +46,39 @@ TRACED = {
     # family takes the dense path and no kernel is in the jaxpr.  What
     # the walk's OTHER callers trace is held kernel by kernel in
     # ``tests/test_paged_attention_kernel.py::WALKS_TRACED``
-    "keye": {"engine_prefill": "e0d8c3ceab2845ae",
+    "keye": {"engine_prefill": "9719fbef0eb96345",
              "engine_decode": "3c0e2663546ddcad"},
-    "mellum": {"engine_prefill": "8f4a67e3658e0723",
+    "mellum": {"engine_prefill": "c863267696bf4033",
                "engine_decode": "1ac80699ca25a3e1"},
-    "kanana": {"engine_prefill": "d425b4f959dbedb1",
+    "kanana": {"engine_prefill": "d64ba14dd7389876",
                "engine_decode": "9dbbd4c35a3d9c06"},
-    "granite": {"engine_prefill": "b0d8abbf1f607c6b",
+    "granite": {"engine_prefill": "46639ba62aa5adb6",
                 "engine_decode": "76573d9f960581e5"},
-    "nemotron_h": {"engine_prefill": "30c8c54ee38f7897",
+    "nemotron_h": {"engine_prefill": "8fe97af4846e763a",
                    "engine_decode": "b085bbfea1bc2bd2"},
     # PR 47 brought this family and changed no other's: the gate, the
     # output norms and the types that rotate are off for every other
     # model, whose programs are the ones above
-    "trinity": {"engine_prefill": "0c33c9c2556bb82a",
+    "trinity": {"engine_prefill": "af57bfee8a1f2b1d",
                 "engine_decode": "a30d0d0cca6cdb4d"},
     # PR 51 brought this family and changed no other's: the state group's
     # arrays by the layer's kind, the router's normaliser as data and the
     # pool's two-heads-a-row layout at 64-wide heads leave every program
     # above as it was
-    "lfm2": {"engine_prefill": "c08e0b51f0aec84b",
+    "lfm2": {"engine_prefill": "99c411012370ea36",
              "engine_decode": "913f73debd40aff4"},
     # PR 54 brought this family and changed no other's: the queries, keys
     # and values of an attention layer come from ``qkv_heads`` now, which
     # a retention layer calls too, the same operations in the same order;
     # a paged model's chunk is still lent its pool
-    "brumby": {"engine_prefill": "9c113f3ea93d1713",
+    "brumby": {"engine_prefill": "12a9bb620075f7ef",
                "engine_decode": "d8a3caf89969587f"},
     # PR 58 brought this family and changed no other's: Mamba's
     # convolution is a function both kinds call (``causal_conv_silu``: the
     # same operations in the same order), the shared MLP's gate, the
     # partial rotary of a typed layer and the chunk's q-block by a head's
     # lane rows are off, or as they were, for every model above
-    "qwen3_next": {"engine_prefill": "2df61eb5395e5b09",
+    "qwen3_next": {"engine_prefill": "84eb24dbccf04c14",
                    "engine_decode": "bb999ef74dc237e8"},
 }
 

@@ -180,9 +180,10 @@ def test_a_launch_uploads_what_changed_and_waits_once(dense, kw, step):
     first = eng.submit(_prompt(19, 1), sp)      # two chunks of 16
     _run(eng, [first])
     # tokens, context lengths, the one table, the live mask: what a step
-    # is handed every time; a chunk's tokens, start, length and table,
-    # and with its prompt's last the sampler's seven scalars
-    per_step, chunk, sampler = 4, 4, 7
+    # is handed every time; a chunk's tokens, start, length, table and
+    # whether it ends its context, and with its prompt's last the
+    # sampler's seven scalars
+    per_step, chunk, sampler = 4, 5, 7
     assert _moved(eng) == [
         ("prefill", chunk, 1),
         ("prefill", chunk + sampler, 1),

@@ -14,6 +14,7 @@ import pytest
 
 from megatron_llm_tpu import tracing
 from megatron_llm_tpu.models.llama import LlamaModel, llama_config
+from megatron_llm_tpu.ops import paged_kv
 from megatron_llm_tpu.serving import (
     BlockManager,
     EngineConfig,
@@ -769,3 +770,201 @@ def test_granite_serves_through_the_server_cli():
     assert "adopts nothing" in text
     # three chunks and four steps over two state-space layers
     assert "ssm_tokens" in metrics and "moe_assignments_held" in metrics
+
+
+# ---------------------------------------------------------------------------
+# the output head once a request and for one row (PR 60): a chunk that
+# does not end its context runs no head, the one that does runs it on its
+# last live row.  One family of each form of a chunk's tables: ``mistral``
+# (one type: ``{FULL: table}``) and ``granite`` (a typed stack with
+# ``STATE``, and a ``logits_scaling``)
+# ---------------------------------------------------------------------------
+
+HEAD_FAMILIES = ["mistral", "granite"]
+# the old chunk multiplied all C rows by the head and kept one; a product
+# of one row adds its terms in another order: float32 on the CPU, logits
+# of a few units
+HEAD_ATOL = 2e-5
+
+
+@pytest.fixture(scope="module")
+def head_engines():
+    """A hand-stepped tiny engine a family, chunks of 16, built once."""
+    import _family
+    from megatron_llm_tpu.models import MODEL_REGISTRY
+    from megatron_llm_tpu.models.mistral import mistral_config
+
+    kept = {}
+
+    def get(name):
+        if name not in kept:
+            if name == "mistral":
+                model = MODEL_REGISTRY[name](mistral_config(
+                    "tiny", use_flash_attn=False))
+                params, kw = model.init(jax.random.PRNGKey(0)), {}
+            else:
+                b = _family.built(name)
+                model, params = b.model, b.params
+                kw = _family.FAMILIES[name].engine
+            kept[name] = _family.engine(model, params, prefill_chunk=16,
+                                        **kw)
+        return kept[name]
+
+    return get
+
+
+def _head_prompt(eng, n, seed):
+    import _family
+
+    return _family.tokens(n, seed, int(eng.model.cfg.padded_vocab_size))
+
+
+def _old_chunk(eng):
+    """The chunk's program as it was before PR 60: the forward WITH its
+    logits over all C rows, then row ``valid - 1``; on the arguments a
+    launch hands ``engine._prefill_step`` today."""
+    from megatron_llm_tpu.models.language_model import (
+        language_model_forward)
+
+    def program(params, pages, tokens, start, valid, table):
+        table, _ = eng._cache.chunk_given(table)
+        positions = (start + jnp.arange(tokens.shape[1]))[None, :]
+        caches = paged_kv.step_caches(
+            pages, table, jnp.full((1,), start, jnp.int32),
+            jnp.full((1,), valid, jnp.int32), eng.prefill_kernel,
+            eng._layer_groups)
+        logits, new = language_model_forward(
+            params, tokens, positions, None, eng.model.cfg, rng_key=None,
+            train=False, kv_caches=caches)
+        last = jax.lax.dynamic_index_in_dim(logits[0], valid - 1, axis=0,
+                                            keepdims=False)
+        return (last.astype(jnp.float32), paged_kv.pools_of(new),
+                paged_kv.routing_of(new))
+
+    return jax.jit(program)
+
+
+def _dots_to_vocab(jaxpr, V, inside=()):
+    """(the primitives a ``dot_general`` whose result is ``V`` wide lies
+    under, its result's shape), through every inner jaxpr."""
+    for eqn in jaxpr.eqns:
+        shape = eqn.outvars[0].aval.shape
+        if eqn.primitive.name == "dot_general" and shape[-1:] == (V,):
+            yield inside, shape
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots_to_vocab(sub, V, inside + (eqn.primitive.name,))
+
+
+@pytest.mark.parametrize("family", HEAD_FAMILIES)
+def test_a_chunks_program_multiplies_one_row_by_the_head(head_engines,
+                                                         family):
+    """The jaxpr of ``engine_prefill`` holds no product of C rows by V
+    columns: the only ``dot_general`` with a V-wide result sits inside
+    the ``cond`` and has one row.  The decode step's still has a row a
+    slot, outside any ``cond``."""
+    eng = head_engines(family)
+    V, args = int(eng.model.cfg.padded_vocab_size), eng._program_arguments()
+    assert V not in (eng.model.cfg.hidden_size, eng.config.prefill_chunk)
+    chunk = jax.make_jaxpr(eng._prefill_impl)(*args["engine_prefill"])
+    assert list(_dots_to_vocab(chunk.jaxpr, V)) == [(("cond",), (1, 1, V))]
+    # and nothing else of the program is C rows by V columns
+    C = eng.config.prefill_chunk
+    assert f"{C},{V}]" not in str(chunk)
+    step = jax.make_jaxpr(eng._decode_impl)(*args["engine_decode"])
+    assert list(_dots_to_vocab(step.jaxpr, V)) == [
+        ((), (eng.config.num_slots, 1, V))]
+
+
+@pytest.mark.parametrize("family", HEAD_FAMILIES)
+def test_the_last_chunks_one_row_is_the_old_chunks_row(head_engines, family,
+                                                       monkeypatch):
+    """A prompt of four chunks (the last of 5 live rows): the ``[V]``
+    logits handed to ``engine_sample_first`` are the old program's row
+    ``valid - 1`` on the same arguments within ``HEAD_ATOL``, the first
+    token is its argmax, and the greedy answer is the one the engine gives
+    with the OLD program in the chunk's place."""
+    import _family
+
+    eng = head_engines(family)
+    prompt = _head_prompt(eng, 53, seed=11)
+    old, new_step = _old_chunk(eng), eng._prefill_step
+    olds, sampled = [], []
+
+    def tapped(params, pages, tokens, start, valid, table):
+        olds.append(np.asarray(
+            old(params, pages, tokens, start, valid, table)[0]))
+        return new_step(params, pages, tokens, start, valid, table)
+
+    first = eng._sample_first
+    monkeypatch.setattr(eng, "_prefill_step", tapped)
+    monkeypatch.setattr(eng, "_sample_first", lambda logits, *rest: (
+        sampled.append(np.asarray(logits)), first(logits, *rest))[1])
+    answer = list(_family.serve(eng, prompt, 6).out_tokens)
+    assert len(olds) == 4 and len(sampled) == 1
+    assert np.abs(olds[-1]).max() > 0.1
+    np.testing.assert_allclose(sampled[0], olds[-1], atol=HEAD_ATOL, rtol=0)
+    assert answer[0] == int(olds[-1].argmax())
+    # the tree before the change: every chunk's logits, the same answer
+    monkeypatch.setattr(eng, "_prefill_step", old)
+    monkeypatch.setattr(eng, "_sample_first", first)
+    assert list(_family.serve(eng, prompt, 6).out_tokens) == answer
+
+
+@pytest.mark.parametrize("family", HEAD_FAMILIES)
+def test_only_the_chunk_that_ends_its_context_runs_the_head(head_engines,
+                                                            family,
+                                                            monkeypatch):
+    """Three requests of three, one and two chunks: a chunk that is not
+    its context's last returns zeros and counts ``prefill_head_rows`` 0,
+    the last returns logits and counts 1, and ``stats()['prefill_heads']``
+    is the requests prefilled."""
+    import _family
+
+    eng = head_engines(family)
+    inner, got = eng._prefill_step, []
+
+    def tapped(params, pages, tokens, start, valid, table):
+        out = inner(params, pages, tokens, start, valid, table)
+        got.append((bool(table[paged_kv.LAST]), np.asarray(out[0])))
+        return out
+
+    monkeypatch.setattr(eng, "_prefill_step", tapped)
+    since = _family.counted(eng)
+    for n, seed in ((40, 21), (9, 22), (32, 23)):
+        _family.serve(eng, _head_prompt(eng, n, seed), 3)
+    stats, records = since()
+    chunks = [r for r in records if r.kind == "prefill"]
+    assert [last for last, _ in got] == [False, False, True, True,
+                                         False, True]
+    assert [r.prefill_head_rows for r in chunks] == [0, 0, 1, 1, 0, 1]
+    for last, logits in got:
+        assert logits.shape == (eng.model.cfg.padded_vocab_size,)
+        assert bool(np.abs(logits).max() > 0.1) == last
+        assert last or not logits.any()
+    assert stats["prefill_chunks"] == 6
+    assert stats["prefill_heads"] == stats["prefill_head_rows"] == 3
+
+
+def test_a_request_preempted_mid_prefill_still_ends_on_a_head(head_engines):
+    """A request of four chunks preempted after its second and requeued
+    prefills its context again (its own pages adopted) and ends on a
+    chunk that runs the head: the answer is the plain forward's."""
+    import _family
+
+    eng = head_engines("mistral")
+    prompt = _head_prompt(eng, 60, seed=31)
+    since = _family.counted(eng)
+    req = eng.submit(prompt, SamplingParams(max_new_tokens=5,
+                                            temperature=0.0))
+    assert eng.step() and eng.step() and req.prefill_pos == 32
+    eng._preempt(eng._st, req)
+    assert req.slot is None and req.preempt_count == 1
+    while req.finish_reason is None:
+        assert eng.step()
+    assert _family.is_greedy(eng.model, eng.params, prompt, req.out_tokens)
+    stats, records = since()
+    heads = [r.prefill_head_rows for r in records if r.kind == "prefill"]
+    assert heads[:2] == [0, 0] and heads[-1] == 1 and sum(heads) == 1
+    assert stats["prefill_heads"] == 1
+    assert not any(r.prefill_head_rows for r in records
+                   if r.kind != "prefill")
