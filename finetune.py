@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,trinity,nemotron_h,lfm2,brumby,qwen3_next,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,glm5,trinity,nemotron_h,lfm2,brumby,qwen3_next,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -118,6 +118,22 @@ MODEL_DEFAULTS = {
                    qk_nope_head_dim=128, qk_rope_head_dim=64,
                    v_head_dim=128, rope_theta=1e6, layernorm_epsilon=1e-6,
                    hidden_dropout=0.0, attention_dropout=0.0),
+    # GLM-5 (model_type glm_moe_dsa): kanana's layer behind a compressed
+    # query, with keye's indexer choosing each query's latents (its
+    # queries from the compressed query, half of its head rotating),
+    # three leading dense layers, one shared expert
+    "glm5": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                 use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                 num_experts=256, moe_top_k=8, norm_topk_prob=1,
+                 moe_score_function="sigmoid", moe_choice_bias=1,
+                 moe_routed_scale=2.5, moe_shared_experts=1,
+                 moe_first_dense_layers=3, kv_lora_rank=512,
+                 q_lora_rank=2048, qk_nope_head_dim=192,
+                 qk_rope_head_dim=64, v_head_dim=256, dsa_index_heads=32,
+                 dsa_index_head_dim=128, dsa_index_rope_dim=64,
+                 dsa_index_query="compressed", dsa_topk=2048,
+                 rope_theta=1e6, layernorm_epsilon=1e-5,
+                 hidden_dropout=0.0, attention_dropout=0.0),
     # Trinity-Mini (model_type afmoe): a gate on the attention output,
     # four norms a layer, window layers that rotate beside full layers
     # that carry no positions, two leading dense layers inside the typed
@@ -407,6 +423,8 @@ _CKPT_ARG_MAP = {
     "dsa_index_heads": "dsa_index_heads",
     "dsa_index_head_dim": "dsa_index_head_dim",
     "dsa_topk": "dsa_topk",
+    "dsa_index_rope_dim": "dsa_index_rope_dim",
+    "dsa_index_query": "dsa_index_query",
     "rope_sections": "rope_sections",
     # kanana's router, shared MLP, dense layers and latent attention
     "moe_score_function": "moe_score_function",
@@ -415,6 +433,7 @@ _CKPT_ARG_MAP = {
     "moe_shared_experts": "moe_shared_experts",
     "moe_first_dense_layers": "moe_first_dense_layers",
     "kv_lora_rank": "kv_lora_rank",
+    "q_lora_rank": "q_lora_rank",
     "qk_nope_head_dim": "qk_nope_head_dim",
     "qk_rope_head_dim": "qk_rope_head_dim",
     "v_head_dim": "v_head_dim",

@@ -131,7 +131,10 @@ def _add_network_size_args(parser):
     g.add_argument("--kv_lora_rank", type=int, default=None,
                    help="latent attention: the width of the latent that "
                         "keys and values are expanded from, and cached")
-    g.add_argument("--q_lora_rank", type=int, default=None)
+    g.add_argument("--q_lora_rank", type=int, default=None,
+                   help="latent attention's compressed query: the width "
+                        "between the query's two projections (a norm "
+                        "between them)")
     g.add_argument("--qk_nope_head_dim", type=int, default=128)
     g.add_argument("--qk_rope_head_dim", type=int, default=64)
     g.add_argument("--v_head_dim", type=int, default=128)
@@ -248,6 +251,14 @@ def _add_network_size_args(parser):
     g.add_argument("--dsa_index_head_dim", type=int, default=64)
     g.add_argument("--dsa_topk", type=int, default=2048,
                    help="keys a query attends under the indexer's choice")
+    g.add_argument("--dsa_index_rope_dim", type=int, default=None,
+                   help="the first so many dimensions of an indexer head "
+                        "rotate and the rest pass (default: all of them)")
+    g.add_argument("--dsa_index_query", default="input",
+                   choices=["input", "compressed"],
+                   help="what the indexer's query projection reads: the "
+                        "layer's normed input, or the compressed query "
+                        "(--q_lora_rank)")
     g.add_argument("--rope_sections", type=int, nargs="+", default=None,
                    help="rotary frequency pairs dealt to position streams "
                         "(mrope_section, e.g. 16 24 24)")
@@ -1131,6 +1142,8 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
         dsa_index_heads=int(getattr(args, "dsa_index_heads", 0) or 0),
         dsa_index_head_dim=int(getattr(args, "dsa_index_head_dim", 64)),
         dsa_topk=int(getattr(args, "dsa_topk", 2048)),
+        dsa_index_rope_dim=getattr(args, "dsa_index_rope_dim", None),
+        dsa_index_query=getattr(args, "dsa_index_query", "input"),
         rope_sections=(tuple(args.rope_sections)
                        if getattr(args, "rope_sections", None) else None),
         moe_score_function=getattr(args, "moe_score_function", "softmax"),

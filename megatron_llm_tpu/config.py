@@ -166,6 +166,8 @@ TURNED_OFF = (PREFIX_CACHE,)
 # predicate over the config (as ``__post_init__`` has normalised it).
 SPARSE = "sparse attention (dsa_index_heads > 0)"
 LATENT = "latent attention (kv_lora_rank)"
+SPARSE_LATENT = ("the selection over latents (dsa_index_heads > 0 with "
+                 "kv_lora_rank)")
 TYPED = "a layer type per layer (layer_types)"
 STATE_SPACE = "state-space layers ('mamba' among layer_types)"
 SHORT_CONV = "gated short-convolution layers ('conv' among layer_types)"
@@ -195,6 +197,8 @@ DELTA_OTHER_TYPES = "layer types other than 'gated_delta' and 'attention'"
 HAS = {
     SPARSE: lambda c: c.dsa_index_heads > 0,
     LATENT: lambda c: c.kv_lora_rank is not None,
+    SPARSE_LATENT: lambda c: (c.dsa_index_heads > 0
+                              and c.kv_lora_rank is not None),
     TYPED: lambda c: c.layer_types is not None,
     STATE_SPACE: lambda c: c.state_space,
     SHORT_CONV: lambda c: c.short_conv,
@@ -235,6 +239,7 @@ HAS = {
 # of a model (state-space layers) stands before the row that says less
 # (a layer type per layer).
 RUNS_WITH = (
+    (SPARSE_LATENT, (TRAINING,)),
     (SPARSE, (SLIDING, NOT_ROTARY, VERIFY_STEP, INT8_POOL,
               TENSOR_PARALLEL)),
     (ONE_SUBLAYER, (OTHER_TYPES, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
@@ -261,7 +266,7 @@ RUNS_WITH = (
     (TYPED, (SPARSE, SECTIONED, VERIFY_STEP, INT8_POOL, HOST_TIER,
              PREFIX_CACHE, MODEL_PARALLEL, ROLLING_CACHE)),
     (FIRST_DENSE, (ONE_SUBLAYER, MODEL_PARALLEL)),
-    (LATENT, (NOT_ROTARY, SLIDING, TYPED, SPARSE, QK_NORM_WHOLE,
+    (LATENT, (NOT_ROTARY, SLIDING, TYPED, QK_NORM_WHOLE,
               QK_NORM_PER_HEAD, SECTIONED, ROPE_SCALING, BIASES, QKV_BIAS,
               PARALLEL_ATTN, VERIFY_STEP, INT8_POOL, HOST_TIER,
               MODEL_PARALLEL)),
@@ -271,6 +276,9 @@ RUNS_WITH = (
 )
 # how a square's sentence ends, where it says more than the two names
 TAILS = {
+    (SPARSE_LATENT, TRAINING):
+        " (no backward through a choice made inside latent attention is "
+        "held to anything)",
     (ONE_SUBLAYER, OTHER_TYPES):
         " (a 'moe' layer type goes with 'mamba' and 'attention' layers)",
     (ONE_SUBLAYER, TRAINING):
@@ -665,9 +673,11 @@ class TransformerConfig:
     # ``qk_nope_head_dim + qk_rope_head_dim`` wide (only the rotary part
     # rotates), a value head ``v_head_dim``.  The paged cache holds the
     # latent and the rotary key, not heads (``ops/paged_kv.py``).
-    # ``q_lora_rank`` (a compressed query) and group-limited routing
-    # (``moe_n_group`` / ``moe_topk_group``) are named so that a
-    # published config that sets them is refused by name
+    # ``q_lora_rank`` (a compressed query): the query's projection is
+    # two, h -> ``q_lora_rank`` and, after an RMSNorm of its own, ->
+    # heads x (nope + rope).  Group-limited routing (``moe_n_group`` /
+    # ``moe_topk_group``) is named so that a published config that sets
+    # it is refused by name
     kv_lora_rank: Optional[int] = None
     q_lora_rank: Optional[int] = None
     qk_nope_head_dim: int = 128
@@ -695,6 +705,12 @@ class TransformerConfig:
     dsa_index_heads: int = 0
     dsa_index_head_dim: int = 64
     dsa_topk: int = 2048
+    # how many of an indexer head's (and of its key's) first dimensions
+    # rotate, the rest passing as they are (None: all of them), and what
+    # the indexer's QUERY projection reads: the layer's normed ``input``,
+    # or the ``compressed`` query (``q_lora_rank``'s normed output)
+    dsa_index_rope_dim: Optional[int] = None
+    dsa_index_query: str = "input"
     # rotary frequency pairs dealt to several position streams in
     # sections (``mrope_section``: temporal, height, width); a text
     # token's positions coincide and the embedding is the plain one
@@ -817,9 +833,25 @@ class TransformerConfig:
         if self.rope_sections is not None:
             object.__setattr__(self, "rope_sections",
                                tuple(int(x) for x in self.rope_sections))
-        if self.q_lora_rank is not None:
-            raise ValueError("q_lora_rank (a compressed query projection) "
-                             "is not implemented: leave it unset")
+        if self.q_lora_rank is not None and (
+                self.kv_lora_rank is None or self.q_lora_rank < 1):
+            raise ValueError("q_lora_rank (a compressed query) is latent "
+                             "attention's: it needs kv_lora_rank and a "
+                             "positive width")
+        if self.dsa_index_query not in ("input", "compressed") or (
+                self.dsa_index_query == "compressed"
+                and self.q_lora_rank is None):
+            raise ValueError(
+                f"dsa_index_query is input|compressed, and compressed "
+                f"needs q_lora_rank, got {self.dsa_index_query!r} with "
+                f"q_lora_rank={self.q_lora_rank}")
+        if self.dsa_index_rope_dim is not None and not (
+                0 < self.dsa_index_rope_dim <= self.dsa_index_head_dim
+                and self.dsa_index_rope_dim % 2 == 0):
+            raise ValueError(
+                f"dsa_index_rope_dim ({self.dsa_index_rope_dim}) is even "
+                f"and within dsa_index_head_dim "
+                f"({self.dsa_index_head_dim})")
         if self.moe_n_group != 1 or self.moe_topk_group != 1:
             raise ValueError("group-limited routing (moe_n_group / "
                              "moe_topk_group other than 1) is not "

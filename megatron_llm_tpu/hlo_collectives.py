@@ -122,7 +122,8 @@ def mesh_groups(mesh_shape: Dict[str, int], axes: Sequence[str]) -> Groups:
 # ``scope`` is the innermost of these its ``op_name`` holds
 SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
           "moe_combine", "moe_shared", "qk_norm", "dsa_indexer",
-          "mla_absorb", "mla_expand", "ssm_in_proj", "ssm_conv", "ssm_scan",
+          "mla_absorb", "mla_expand", "mla_query_down", "mla_query_up",
+          "ssm_in_proj", "ssm_conv", "ssm_scan",
           "ssm_step", "ssm_gate_norm", "ssm_out_proj", "mamba",
           "conv_in_proj", "short_conv", "conv_out_proj",
           "retention_gate", "retention_chunk", "retention_step",

@@ -13,6 +13,7 @@ from megatron_llm_tpu.models.olmoe import OlmoeModel, olmoe_config
 from megatron_llm_tpu.models.keye import KeyeModel, keye_config
 from megatron_llm_tpu.models.mellum import MellumModel, mellum_config
 from megatron_llm_tpu.models.kanana import KananaModel, kanana_config
+from megatron_llm_tpu.models.glm5 import Glm5Model, glm5_config
 from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
 from megatron_llm_tpu.models.lfm2 import Lfm2Model, lfm2_config
 from megatron_llm_tpu.models.brumby import BrumbyModel, brumby_config
@@ -62,6 +63,7 @@ MODEL_REGISTRY = {
     "keye": KeyeModel,
     "mellum": MellumModel,
     "kanana": KananaModel,
+    "glm5": Glm5Model,
     "trinity": TrinityModel,
     "granite": _granite,
     "nemotron_h": _nemotron_h,

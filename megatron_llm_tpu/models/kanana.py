@@ -20,8 +20,10 @@ assert-the-architecture-flags pattern of ``olmoe.py`` / ``keye.py`` /
 * **one leading dense layer** of width 6144
   (``moe_first_dense_layers``), stacked apart from the sparse ones.
 
-No compressed query (``q_lora_rank``), no group-limited routing, no
-multi-token-prediction head, no bias, untied head.
+The query is ONE projection (``q_lora_rank`` null as published; a
+compressed query is built where a config sets it, ``models/glm5.py``),
+no group-limited routing, no multi-token-prediction head, no bias,
+untied head.
 
 What these do not run with is rows of ``config.RUNS_WITH`` (the latent
 and the one rotary key head are not sharded, and a pipeline stage's

@@ -105,8 +105,10 @@ def transformer_layer_specs(layers, stacked: bool = True, cfg=None) -> dict:
         # latent attention is replicated (tp is refused)
         attention_specs = {
             **{name: _linear_spec(attn[name], None, None, stacked)
-               for name in ("query", "kv_down", "kv_up", "dense")},
-            "kv_norm": _norm_spec(attn["kv_norm"], stacked),
+               for name in ("query", "query_down", "kv_down", "kv_up",
+                            "dense") if name in attn},
+            **{name: _norm_spec(attn[name], stacked)
+               for name in ("kv_norm", "query_norm") if name in attn},
         }
     else:
         attention_specs = {

@@ -79,7 +79,11 @@ def init_latent_attention_params(key, cfg: TransformerConfig, dtype):
     bias-free: ``query`` h -> heads x (nope + rope); ``kv_down`` h ->
     latent + ONE rotary key; ``kv_norm`` the latent's RMSNorm scale;
     ``kv_up`` latent -> heads x (nope keys + values), a head's keys
-    before its values; ``dense`` heads x values -> h."""
+    before its values; ``dense`` heads x values -> h.  With a compressed
+    query (``cfg.q_lora_rank``) the query's projection is two,
+    ``query_down`` h -> q_lora_rank and ``query`` q_lora_rank -> heads x
+    (nope + rope), ``query_norm`` the RMSNorm scale between them; with a
+    sparse-attention indexer, its leaves under ``indexer``."""
     kq, kd, ku, ko = jax.random.split(key, 4)
     init = init_method_for(cfg)
     out_init = (
@@ -93,12 +97,43 @@ def init_latent_attention_params(key, cfg: TransformerConfig, dtype):
         return init_linear_params(k, n_in, n_out, bias=False,
                                   init_method=method, dtype=dtype)
 
-    return {
-        "query": linear(kq, cfg.hidden_size, nh * cfg.qk_head_dim),
+    params = {
+        "query": linear(kq, cfg.q_lora_rank or cfg.hidden_size,
+                        nh * cfg.qk_head_dim),
         "kv_down": linear(kd, cfg.hidden_size, r + cfg.qk_rope_head_dim),
         "kv_norm": {"scale": jnp.ones((r,), dtype)},
         "kv_up": linear(ku, r, nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
         "dense": linear(ko, nh * cfg.v_head_dim, cfg.hidden_size, out_init),
+    }
+    if cfg.q_lora_rank is not None:
+        params["query_down"] = linear(jax.random.fold_in(kq, 1),
+                                      cfg.hidden_size, cfg.q_lora_rank)
+        params["query_norm"] = {"scale": jnp.ones((cfg.q_lora_rank,), dtype)}
+    if cfg.dsa_index_heads > 0:
+        params["indexer"] = init_indexer_params(
+            jax.random.split(jax.random.fold_in(kd, 1), 3), cfg, dtype, init)
+    return params
+
+
+def init_indexer_params(keys, cfg: TransformerConfig, dtype, init):
+    """The sparse-attention indexer's three bias-free projections (its
+    heads' queries, its one key head, a weight a head) and the key's
+    LayerNorm.  The key and the weights read the layer's normed input;
+    the queries read it too, or the compressed query
+    (``cfg.dsa_index_query``)."""
+    hi, di = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+    q_in = (cfg.q_lora_rank if cfg.dsa_index_query == "compressed"
+            else cfg.hidden_size)
+    return {
+        "query": init_linear_params(keys[0], q_in, hi * di, bias=False,
+                                    init_method=init, dtype=dtype),
+        "key": init_linear_params(keys[1], cfg.hidden_size, di, bias=False,
+                                  init_method=init, dtype=dtype),
+        "weights": init_linear_params(keys[2], cfg.hidden_size, hi,
+                                      bias=False, init_method=init,
+                                      dtype=dtype),
+        "key_norm": {"scale": jnp.ones((di,), dtype),
+                     "bias": jnp.zeros((di,), dtype)},
     }
 
 
@@ -139,24 +174,8 @@ def init_attention_params(key, cfg: TransformerConfig, dtype):
         params["q_norm"] = {"scale": jnp.ones((cfg.head_dim,), dtype)}
         params["k_norm"] = {"scale": jnp.ones((cfg.head_dim,), dtype)}
     if cfg.dsa_index_heads > 0:
-        # the sparse-attention indexer reads the layer's normed input
-        # through three bias-free projections: its heads' queries, its
-        # one key head (LayerNorm'd), a weight a head
-        ki = jax.random.split(k1, 4)[1:]
-        hi, di = cfg.dsa_index_heads, cfg.dsa_index_head_dim
-        params["indexer"] = {
-            "query": init_linear_params(ki[0], cfg.hidden_size, hi * di,
-                                        bias=False, init_method=init,
-                                        dtype=dtype),
-            "key": init_linear_params(ki[1], cfg.hidden_size, di,
-                                      bias=False, init_method=init,
-                                      dtype=dtype),
-            "weights": init_linear_params(ki[2], cfg.hidden_size, hi,
-                                          bias=False, init_method=init,
-                                          dtype=dtype),
-            "key_norm": {"scale": jnp.ones((di,), dtype),
-                         "bias": jnp.zeros((di,), dtype)},
-        }
+        params["indexer"] = init_indexer_params(
+            jax.random.split(k1, 4)[1:], cfg, dtype, init)
     return params
 
 
@@ -360,26 +379,33 @@ def _projection_rms_norm(x: jax.Array, scale: jax.Array, eps: float):
 
 
 def indexer_projections(x: jax.Array, params, cfg: TransformerConfig,
-                        positions: jax.Array):
+                        positions: jax.Array,
+                        query_input: Optional[jax.Array] = None):
     """The sparse-attention indexer's query heads ``[b, s, Hi, di]`` and
-    one key head ``[b, s, di]`` (both rotated at ``positions`` [b, s],
-    compute dtype) and its head weights ``[b, s, Hi]`` (fp32, with the
-    two scale factors ``Hi^-1/2`` and ``di^-1/2`` folded in) from the
-    layer's normed input ``x`` [b, s, h]; and, with them, the top-k: what
-    ``PagedKVCache.attend`` takes as ``index``."""
+    one key head ``[b, s, di]`` (both rotated at ``positions`` [b, s] in
+    their first ``cfg.dsa_index_rope_dim`` dimensions, all of them where
+    that is None; compute dtype) and its head weights ``[b, s, Hi]``
+    (fp32, with the two scale factors ``Hi^-1/2`` and ``di^-1/2`` folded
+    in) from the layer's normed input ``x`` [b, s, h], the queries from
+    ``query_input`` where one is given (the compressed query); and, with
+    them, the top-k: what ``PagedKVCache.attend`` and ``attend_latent``
+    take as ``index``."""
     b, s, _ = x.shape
     hi, di = cfg.dsa_index_heads, cfg.dsa_index_head_dim
     cd = cfg.compute_jnp_dtype
     xc = x.astype(cd)
-    iq = (xc @ params["query"]["kernel"].astype(cd)).reshape(b, s, hi, di)
+    xq = xc if query_input is None else query_input.astype(cd)
+    iq = (xq @ params["query"]["kernel"].astype(cd)).reshape(b, s, hi, di)
     ik = layer_norm(xc @ params["key"]["kernel"].astype(cd),
                     params["key_norm"]["scale"], params["key_norm"]["bias"],
                     eps=cfg.layernorm_epsilon)
     iw = (xc @ params["weights"]["kernel"].astype(cd)).astype(jnp.float32)
     iw = iw * (hi ** -0.5) * (di ** -0.5)
-    iq = apply_rotary_at(iq, positions, cfg.rope_theta, cfg.rope_sections)
+    iq = apply_rotary_at(iq, positions, cfg.rope_theta, cfg.rope_sections,
+                         rot_d=cfg.dsa_index_rope_dim)
     ik = apply_rotary_at(ik[:, :, None, :], positions, cfg.rope_theta,
-                         cfg.rope_sections)[:, :, 0, :]
+                         cfg.rope_sections,
+                         rot_d=cfg.dsa_index_rope_dim)[:, :, 0, :]
     return iq, ik, iw, cfg.dsa_topk
 
 
@@ -477,6 +503,20 @@ def latent_attention(
       value (``PagedKVCache.attend_latent``); bytes bound a step, and
       expanding would read no fewer.
 
+    A COMPRESSED QUERY (``cfg.q_lora_rank``): the queries come from the
+    normed input through two projections with an RMSNorm of its own
+    between them (scopes ``mla_query_down``, which holds the norm, and
+    ``mla_query_up``).
+
+    THE SELECTION OVER LATENTS (``cfg.dsa_index_heads``; GLM-5's
+    ``glm_moe_dsa``): an indexer (``indexer_projections``, its queries
+    from the compressed query where ``cfg.dsa_index_query`` says so)
+    chooses each query's ``dsa_topk`` positions and the query attends
+    those latents only, in whichever form: the cache writes the
+    indexer's key beside the latent row and reads under the choice
+    (``PagedKVCache.attend_latent(index=)``), and the cache-less forward
+    attends the expanded heads under ``ops/dsa.py``'s mask.
+
     The legacy decode caches (contiguous, rolling, int8) are refused."""
     b, s, _ = x.shape
     cd = cfg.compute_jnp_dtype
@@ -486,10 +526,23 @@ def latent_attention(
         raise NotImplementedError(
             "latent attention (kv_lora_rank) runs through the paged cache "
             "or the plain forward, not the legacy decode caches")
-    q = column_parallel_linear(
-        x, params["query"], out_logical="heads",
-        sequence_parallel=sequence_parallel, compute_dtype=cd,
-    ).reshape(b, s, nh, dn + dr)
+    c_q = None
+    if cfg.q_lora_rank is not None:
+        with jax.named_scope("mla_query_down"):
+            c_q = rms_norm(
+                column_parallel_linear(
+                    x, params["query_down"], out_logical=None,
+                    sequence_parallel=sequence_parallel, compute_dtype=cd),
+                params["query_norm"]["scale"], eps=cfg.layernorm_epsilon)
+        with jax.named_scope("mla_query_up"):
+            q = column_parallel_linear(
+                c_q, params["query"], out_logical="heads",
+                compute_dtype=cd).reshape(b, s, nh, dn + dr)
+    else:
+        q = column_parallel_linear(
+            x, params["query"], out_logical="heads",
+            sequence_parallel=sequence_parallel, compute_dtype=cd,
+        ).reshape(b, s, nh, dn + dr)
     kv = column_parallel_linear(
         x, params["kv_down"], out_logical=None,
         sequence_parallel=sequence_parallel, compute_dtype=cd)
@@ -502,18 +555,26 @@ def latent_attention(
     q_rope = apply_rotary_at(q[..., dn:], positions, cfg.rope_theta)
     k_rope = apply_rotary_at(kv[..., None, r:], positions, cfg.rope_theta)
     w_up = params["kv_up"]["kernel"].astype(cd).reshape(r, nh, dn + dv)
+    index = None
+    if cfg.dsa_index_heads > 0:
+        with jax.named_scope("dsa_indexer"):
+            index = indexer_projections(
+                x, params["indexer"], cfg, positions,
+                query_input=(c_q if cfg.dsa_index_query == "compressed"
+                             else None))
 
     new_cache = None
     if kv_cache is not None:
         scale = 1.0 / math.sqrt(dn + dr)
         if kv_cache.expands_latents(s):
             ctx, new_cache = kv_cache.attend_latent(
-                q_nope, q_rope, c, k_rope[:, :, 0], scale, kv_up=w_up)
+                q_nope, q_rope, c, k_rope[:, :, 0], scale, kv_up=w_up,
+                index=index)
         else:
             with jax.named_scope("mla_absorb"):
                 q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_up[..., :dn])
             ctx, new_cache = kv_cache.attend_latent(
-                q_lat, q_rope, c, k_rope[:, :, 0], scale)
+                q_lat, q_rope, c, k_rope[:, :, 0], scale, index=index)
             with jax.named_scope("mla_absorb"):
                 ctx = jnp.einsum("bsnr,rnd->bsnd", ctx, w_up[..., dn:])
     else:
@@ -530,7 +591,18 @@ def latent_attention(
             chunked_causal_attention,
         )
 
-        if (attention_mask is None and s >= CHUNKED_ATTENTION_MIN_SEQ
+        if index is not None:
+            # the cache-less forward selects too: what tier-1 holds the
+            # paged programs against
+            if attention_mask is not None:
+                raise NotImplementedError(
+                    "sparse attention (dsa_index_heads > 0) runs through "
+                    "the paged cache or the plain causal forward, not an "
+                    "explicit attention mask")
+            from megatron_llm_tpu.ops.dsa import causal_selected_attention
+
+            ctx = causal_selected_attention(q, k, v, *index)
+        elif (attention_mask is None and s >= CHUNKED_ATTENTION_MIN_SEQ
                 and not (train and cfg.attention_dropout > 0.0)):
             ctx = chunked_causal_attention(q, k, v, causal=True)
         else:

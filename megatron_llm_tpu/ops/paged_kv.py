@@ -21,7 +21,14 @@ and values of 128 would hold 20,480), and the row is the key AND, in its
 first ``kv_lora_rank`` columns, the value of all the absorbed query
 heads of a decode step, while a chunk's kernel expands each block of
 rows into per-head keys and values in VMEM (``attend_latent``,
-``expands_latents``).  Whatever a pool holds, a page
+``expands_latents``).  A latent model WITH an indexer (the selection
+over latents) has TWO arrays a layer, ``latent_pages`` and
+``index_pages`` ``[num_blocks, block_size, index_width]``, the indexer's
+key written beside the row at the same place (640 + 128 values at the
+published widths: 1,536 B a token a layer where 64 heads of keys and
+values of 256 would hold 65,536), and each query attends the rows its
+indexer chooses, in the same two forms under a mask
+(``_attend_latent_selected``).  Whatever a pool holds, a page
 of it is a page of every array: the page programs below are
 ``tree_map``s, so copy-on-write, the prefix cache's adoption and the
 host tier carry a page's indexer keys with its keys and values.  Block 0 is the
@@ -219,10 +226,18 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     said = quantized and refusal(cfg, (INT8_POOL,))
     if said:
         raise ValueError(said)
+    index_width = -(-cfg.dsa_index_head_dim // _LANES) * _LANES
     if cfg.latent_attention:
         shape = (num_blocks, block_size, latent_width(cfg))
-        return [{"latent_pages": jnp.zeros(shape, dtype)}
-                for _ in range(cfg.num_layers)]
+
+        def latent_pool():
+            pool = {"latent_pages": jnp.zeros(shape, dtype)}
+            if indexed:
+                pool["index_pages"] = jnp.zeros(shape[:2] + (index_width,),
+                                                dtype)
+            return pool
+
+        return [latent_pool() for _ in range(cfg.num_layers)]
     if groups is not None and WINDOW in groups and not window_blocks:
         raise ValueError("a model with sliding layers among its "
                          "layer_types needs window_blocks")
@@ -275,9 +290,7 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
         kv = {"k_pages": jnp.zeros(shape, dtype),
               "v_pages": jnp.zeros(shape, dtype)}
         if indexed:
-            kv["index_pages"] = jnp.zeros(
-                shape[:2] + (-(-cfg.dsa_index_head_dim // _LANES) * _LANES,),
-                dtype)
+            kv["index_pages"] = jnp.zeros(shape[:2] + (index_width,), dtype)
         return kv
 
     return [{} if groups and groups[i] == NONE else
@@ -674,7 +687,7 @@ class PagedKVCache:
 
     def attend_latent(self, q_nope: jax.Array, q_rope: jax.Array,
                       latent: jax.Array, k_rope: jax.Array, scale: float,
-                      kv_up: Optional[jax.Array] = None):
+                      kv_up: Optional[jax.Array] = None, index=None):
         """A latent pool's ``attend``: write this call's rows
         ``[latent [b, n, r] ; k_rope [b, n, dr] ; zeros]`` at
         ``context_lens ..``, then attend over the row's history and the
@@ -694,16 +707,33 @@ class PagedKVCache:
           back per head ``[b, n, nh, dv]``.
 
         Either reads a live page once (a head group).  Returns the context
-        and the cache as the step leaves it."""
+        and the cache as the step leaves it.
+
+        ``index`` (a pool with ``index_pages`` needs it, no other takes
+        it; as ``attend``'s): the indexer's key is written beside the
+        row, at the same place of ``index_pages``, and each query then
+        attends only the ``topk`` rows its indexer scores highest, in
+        the same two forms (``_attend_latent_selected``)."""
         from megatron_llm_tpu.ops.pallas import paged_attention as _pa
 
+        if (index is not None) != ("index_pages" in self.pool):
+            raise ValueError("a pool with index_pages, and only such a "
+                             "pool, is attended through an indexer")
         pages = self.pool["latent_pages"]
         n, r, W = latent.shape[1], latent.shape[2], pages.shape[-1]
         assert (kv_up is not None) == self.expands_latents(n)
         row = _to_width(jnp.concatenate([latent, k_rope], axis=-1), W)
-        pool = self._write({"latent_pages": row.astype(pages.dtype)}, n)
+        writes = {"latent_pages": row.astype(pages.dtype)}
+        if index is not None:
+            ip = self.pool["index_pages"]
+            writes["index_pages"] = _to_width(index[1],
+                                              ip.shape[-1]).astype(ip.dtype)
+        pool = self._write(writes, n)
         args = (pool["latent_pages"], self.block_tables, self.context_lens)
-        if kv_up is not None:
+        if index is not None:
+            ctx = self._attend_latent_selected(q_nope, q_rope, pool, index,
+                                               scale, r, kv_up)
+        elif kv_up is not None:
             ctx = _pa.latent_attention_prefill(
                 q_nope, q_rope, kv_up, *args, valid_lens=self.valid_lens,
                 softmax_scale=scale)
@@ -718,6 +748,45 @@ class PagedKVCache:
                     value_width=r, softmax_scale=scale)[:, None]
         return ctx, dataclasses.replace(
             self, pool=pool, context_lens=self.context_lens + self.valid_lens)
+
+    def _attend_latent_selected(self, q_nope, q_rope, pool, index, scale,
+                                r, kv_up):
+        """``attend_latent``'s read under an indexer's choice: scores
+        over the row's live pages of ``index_pages``, the choice, and
+        latent attention over the chosen rows: the kernels' two forms
+        (a decode step absorbed, ``mla_attention_sparse_decode``; a
+        chunk expanded in its kernel, ``mla_attention_prefill_masked``),
+        or the dense path, absorbed: every row's table gathered (live
+        pages only) and masked."""
+        iq, _, iw, topk = index
+        pages, ip = pool["latent_pages"], pool["index_pages"]
+        iq = _to_width(iq, ip.shape[-1])
+        bt, ctx_lens, vlen = (self.block_tables, self.context_lens,
+                              self.valid_lens)
+        S, n = q_nope.shape[:2]
+        W = pages.shape[-1]
+        # the chunk's kernel takes the queries as the model made them;
+        # every other read the absorbed ones at the pool's row width
+        q = (q_nope if kv_up is not None else
+             _to_width(jnp.concatenate([q_nope, q_rope], axis=-1), W))
+        if self.kernel == "pallas" and (kv_up is not None or n == 1):
+            from megatron_llm_tpu.ops.pallas import dsa_attention as _dsa
+
+            return _dsa.paged_selected_latent_attention(
+                q, q_rope, kv_up, iq, iw, pages, ip, bt, ctx_lens, vlen,
+                topk=topk, softmax_scale=scale, value_width=r)
+        from megatron_llm_tpu.ops import dsa as _dsa
+
+        assert kv_up is None
+        bs, M = pages.shape[1], bt.shape[1]
+        live = ctx_lens + vlen
+        bt = jnp.where(jnp.arange(M)[None, :] * bs < live[:, None], bt, 0)
+        rows = pages[bt].reshape(S, M * bs, 1, W)
+        pos = ctx_lens[:, None] + jnp.arange(n)[None, :]
+        # a row is the key and, in its first ``r`` columns, the value
+        return _dsa.selected_attention(
+            q, rows, rows, iq, ip[bt].reshape(S, M * bs, -1), iw, pos, topk,
+            scale)[..., :r]
 
     def _attend_selected(self, q, pool, index, scale):
         """``attend``'s read for a pool with an indexer: scores over the
@@ -1001,9 +1070,11 @@ def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
     if cfg.dsa_index_heads > 0:
         from megatron_llm_tpu.ops.pallas import dsa_attention as _dsa
 
-        dsa_block_keys = _dsa.block_keys(
-            block_size, cfg.num_query_groups, cfg.head_dim,
-            cfg.compute_jnp_dtype, max_blocks_per_slot)
+        dsa_block_keys = (
+            _dsa.latent_block_keys(block_size, max_blocks_per_slot)
+            if cfg.latent_attention else
+            _dsa.block_keys(block_size, cfg.num_query_groups, cfg.head_dim,
+                            cfg.compute_jnp_dtype, max_blocks_per_slot))
         dsa_table_blocks = -(-max_blocks_per_slot * block_size
                              // dsa_block_keys)
     # the pools' shapes, nothing allocated: a block's and a slot's bytes

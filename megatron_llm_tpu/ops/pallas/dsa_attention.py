@@ -4,8 +4,9 @@ attention under that choice, for the decode step and the prefill chunk.
 
 ``ops/dsa.py`` says what is computed (and is the dense path these
 kernels are tested against); ``ops/paged_kv.py::PagedKVCache.attend``
-is the one caller.  Two kernels of this module's and the shared paged
-walk under a mask, six names on a profile's ``XLA Ops`` line:
+and ``attend_latent`` are the two callers.  Two kernels of this module's
+and the shared paged walk under a mask, six names on a profile's ``XLA
+Ops`` line (eight with a latent pool's, below):
 
 * ``dsa_index_scores_decode`` / ``dsa_index_scores_prefill``: a walk
   over each row's LIVE pages of the indexer's pool (``index_pages``
@@ -54,6 +55,26 @@ already trust; the mathematics are the same (``PERF.md`` section 6 has
 what the chip read).  Prefill attends densely under the mask for the
 reason the issue gives: gathering per query token would move 4 MB a
 token a layer.
+
+OVER A LATENT POOL (``paged_selected_latent_attention``: a model whose
+indexer chooses LATENT rows) the scores and the choice are the two
+kernels above as they stand, over ``index_pages`` in blocks of the
+latent walk's own 512 rows (``latent_block_keys``), and the attention is
+latent attention's two walks under the mask, two more names:
+``mla_attention_sparse_decode`` (the shared walk again: one kv group
+whose key is the whole row and whose value its first ``value_width``
+columns, every absorbed query head against it, a row's whole mask in
+VMEM) and ``mla_attention_prefill_masked`` (the latent chunk's own walk,
+``paged_attention.py::_chunk_body``: each block of latents expanded per
+head in VMEM, a block's ``[C, 512]`` slice of the mask riding with its
+pages).  The decode step WALKS every live page and masks for the reason
+above, at other numbers: a latent page of 16 rows is one descriptor of
+20 KB and costs the walk 0.036 us (the table in ``paged_attention.py``),
+2,048 chosen rows are 2,048 descriptors of 1.25 KB, so the walk issues
+fewer up to a context of 32k and twice as many at 64k, reads each row
+once for all 64 heads, and is the kernel the latent cells already trust
+(``PERF.md`` section 6, PR 61, has what the chip read; a step that
+gathers is its open question 3).
 
 Interpret-mode tests run these on the CPU via ``paged_attention``'s
 module-level ``_INTERPRET`` flag.
@@ -364,20 +385,23 @@ def _select_call(n_live, row_pos, scores, *, topk, name, interpret, group):
 # the public entry: scores, choice, and the shared walk under the choice
 # ---------------------------------------------------------------------------
 
-def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
-                             block_tables, context_lens, valid_lens, *,
-                             topk, softmax_scale):
-    """``q`` [S, n, nh, d] at positions ``context_lens[s] ..`` over the
-    pool (this call's keys, values and indexer keys already written),
-    each query attending the ``topk`` keys its indexer (``iq``
-    [S, n, Hi, di], ``iw`` [S, n, Hi]) scores highest over the positions
-    at or before its own.  ``n`` 1 is the decode step, whose rows are the
-    batch; ``n`` > 1 a chunk a row.  Returns [S, n, nh, d]."""
-    S, n, nh, d = q.shape
-    bs, g = k_pages.shape[1], k_pages.shape[2]
-    M = block_tables.shape[1]
-    kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
-    tables = (block_tables, context_lens, valid_lens)
+def latent_block_keys(block_size, table_pages):
+    """``block_keys`` of a LATENT pool: the rows of one block of the
+    absorbed decode walk, which the masked chunk's walk takes too."""
+    return block_size * _pa.latent_pages_per_block(block_size, table_pages)
+
+
+def _choice(iq, iw, index_pages, tables, n, kp, topk):
+    """The indexer's scores over each row's live pages of ``index_pages``
+    and the choice, in blocks of ``kp`` pages: the additive mask the
+    walks take (0 chosen, ``NEG_INF`` not).  ``n`` 1 (the decode step,
+    ``iq`` [S, 1, Hi, di]): ``[S, blocks, kp * bs]``.  A chunk (``iq``
+    [S, n, Hi, di] with ``n`` a multiple of ``select_rows(n)``: the
+    caller pads): ``[S, blocks, n, kp * bs]``.  Blocks past a step's
+    ``select_blocks`` hold whatever the buffer held."""
+    block_tables, context_lens, valid_lens = tables
+    S = iq.shape[0]
+    bs = index_pages.shape[1]
     n_live = select_blocks(context_lens, valid_lens, n, kp * bs)
     live = jnp.arange(n)[None, :] < valid_lens[:, None]           # [S, n]
     row_pos = jnp.where(live, context_lens[:, None] + jnp.arange(n)[None, :],
@@ -399,20 +423,46 @@ def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
                        topk=topk, name="dsa_select_decode")[0, :, :S]
         # blocks past a step's ``n_live`` ride along unwritten; the walk
         # stops at each row's own last live page
-        mask = jnp.repeat(jnp.transpose(mask, (1, 0, 2)), g, axis=-1)
+        return jnp.transpose(mask, (1, 0, 2))
+    scores = _index_scores(iq, iw, index_pages, *tables, kp=kp,
+                           name="dsa_index_scores_prefill")
+    return _select(scores, row_pos, n_live, topk=topk,
+                   name="dsa_select_prefill")
+
+
+def _padded_chunk(n, *arrays):
+    """``arrays`` [S, n, ...] with rows of zeros appended up to a multiple
+    of a chunk's ``select_rows``, and how many."""
+    pad = -n % select_rows(n)
+    if pad:
+        arrays = tuple(
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in arrays)
+    return pad, arrays
+
+
+def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
+                             block_tables, context_lens, valid_lens, *,
+                             topk, softmax_scale):
+    """``q`` [S, n, nh, d] at positions ``context_lens[s] ..`` over the
+    pool (this call's keys, values and indexer keys already written),
+    each query attending the ``topk`` keys its indexer (``iq``
+    [S, n, Hi, di], ``iw`` [S, n, Hi]) scores highest over the positions
+    at or before its own.  ``n`` 1 is the decode step, whose rows are the
+    batch; ``n`` > 1 a chunk a row.  Returns [S, n, nh, d]."""
+    S, n, nh, d = q.shape
+    bs, g = k_pages.shape[1], k_pages.shape[2]
+    M = block_tables.shape[1]
+    kp = _pages_per_block(bs, g, d, k_pages.dtype, M)
+    tables = (block_tables, context_lens, valid_lens)
+    if n == 1:
+        mask = _choice(iq, iw, index_pages, tables, n, kp, topk)
         return _pa._walk_call(
             q, k_pages, v_pages, *tables, None, None, scale=softmax_scale,
             window=None, block_q=1, name="paged_attention_sparse_decode",
-            mask=mask)
-    pad = -n % rows
-    if pad:
-        q, iq, iw = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                     for a in (q, iq, iw))
-        row_pos = jnp.pad(row_pos, ((0, 0), (0, pad)), constant_values=-1)
-    scores = _index_scores(iq, iw, index_pages, *tables, kp=kp,
-                           name="dsa_index_scores_prefill")
-    mask = _select(scores, row_pos, n_live, topk=topk,
-                   name="dsa_select_prefill")
+            mask=jnp.repeat(mask, g, axis=-1))
+    pad, (q, iq, iw) = _padded_chunk(n, q, iq, iw)
+    mask = _choice(iq, iw, index_pages, tables, n + pad, kp, topk)
     bq = min(_PREFILL_BLOCK_Q, n + pad)
     while (n + pad) % bq:       # q-blocks tile the padded chunk exactly
         bq -= 1
@@ -421,3 +471,37 @@ def paged_selected_attention(q, iq, iw, k_pages, v_pages, index_pages,
         window=None, block_q=bq, name="paged_attention_prefill_masked",
         mask=mask)
     return out[:, :n]
+
+
+def paged_selected_latent_attention(q, q_rope, kv_up, iq, iw, latent_pages,
+                                    index_pages, block_tables, context_lens,
+                                    valid_lens, *, topk, softmax_scale,
+                                    value_width):
+    """``paged_selected_attention`` over a LATENT pool (this call's rows
+    and indexer keys already written), in latent attention's two forms
+    (``paged_attention.py`` has both walks).  The decode step (``n`` 1),
+    ABSORBED: ``q`` [S, 1, nh, W] the absorbed queries at the pool's row
+    width, ``kv_up`` None; the shared walk under the mask, one kv group
+    whose key is the row and whose value its first ``value_width``
+    columns (``mla_attention_sparse_decode``); returns [S, 1, nh,
+    value_width].  A chunk, EXPANDED in its kernel: ``q`` [S, n, nh, dn]
+    the no-rope queries, ``q_rope`` [S, n, nh, dr], ``kv_up`` [r, nh, dn
+    + dv]; the latent chunk's walk with a block's slice of the mask
+    riding with its pages (``mla_attention_prefill_masked``); returns
+    [S, n, nh, dv]."""
+    n = q.shape[1]
+    bs, M = latent_pages.shape[1], block_tables.shape[1]
+    kp = _pa.latent_pages_per_block(bs, M)
+    tables = (block_tables, context_lens, valid_lens)
+    if kv_up is None:
+        assert n == 1, q.shape
+        mask = _choice(iq, iw, index_pages, tables, 1, kp, topk)
+        return _pa._walk_call(
+            q, latent_pages, None, *tables, None, None, scale=softmax_scale,
+            window=None, block_q=1, name="mla_attention_sparse_decode",
+            value_width=value_width, mask=mask)
+    pad, (q, q_rope, iq, iw) = _padded_chunk(n, q, q_rope, iq, iw)
+    mask = _choice(iq, iw, index_pages, tables, n + pad, kp, topk)
+    return _pa.latent_attention_prefill(
+        q, q_rope, kv_up, latent_pages, block_tables, context_lens,
+        valid_lens=valid_lens, softmax_scale=softmax_scale, mask=mask)[:, :n]

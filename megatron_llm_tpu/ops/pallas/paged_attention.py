@@ -322,6 +322,15 @@ def _pages_per_block(block_size, g, d, dtype, M):
                       _BLOCK_TOKENS // block_size))
 
 
+def latent_pages_per_block(block_size, M):
+    """Pages of one compute block of a LATENT pool's absorbed walk: a
+    block of ``_BLOCK_TOKENS`` keys whatever the row's width (the scores'
+    lanes are what a block is sized by there).  The selection over
+    latents counts, and masks, in blocks of this many pages
+    (``dsa_attention.py::latent_block_keys``)."""
+    return max(1, min(M, _BLOCK_TOKENS // block_size))
+
+
 def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
                quantized, scale, window, qpg, value_width, masked):
     """One (slot, q-block): walk pages ``first .. last`` of the slot's
@@ -682,14 +691,16 @@ def _walk_call(q, k_pages, v_pages, block_tables, context_lens,
     decode step's ``[S, blocks, kp * bs * g]`` (lane c of a block is key
     c // g, group c % g, as the scores'), a chunk's ``[S, blocks, C,
     kp * bs]``.  Blocks past a slot's last live query's may hold
-    anything: ``valid_lens`` must then be given."""
+    anything: ``valid_lens`` must then be given.  A LATENT pool's decode
+    step takes a mask too (the selection over latents,
+    ``mla_attention_sparse_decode``: one group, so a block's lanes are
+    its keys); a latent chunk's mask is its own walk's
+    (``latent_attention_prefill``)."""
     if valid_lens is None:
         valid_lens = jnp.ones_like(context_lens)
     bs, M = k_pages.shape[1], block_tables.shape[1]
     if value_width is not None:
-        # a block of _BLOCK_TOKENS keys whatever the row's width: the
-        # scores' lanes are what a block is sized by here
-        kp = max(1, min(M, _BLOCK_TOKENS // bs))
+        kp = latent_pages_per_block(bs, M)
     else:
         kp = _pages_per_block(bs, k_pages.shape[2], q.shape[-1],
                               k_pages.dtype, M)
@@ -742,7 +753,8 @@ def _walk_kernel(q, k_pages, v_pages, block_tables, context_lens,
     # for the VMEM the latent chunk's asks for
     masks, mask_specs, extra = [], [], {}
     if mask is not None:
-        assert not (latent or quantized) and window is None, name
+        assert not quantized and window is None, name
+        assert bq == 1 or not latent, name
         blocks = -(-block_tables.shape[1] // kp)
         masks, extra = [mask], dict(compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_CHUNK_VMEM_LIMIT))
@@ -939,7 +951,8 @@ def _lanes(n: int) -> int:
     return -(-n // 128) * 128
 
 
-def _chunk_heads(nh, C, T, W, dq, dkv, dv, r, itemsize) -> int:
+def _chunk_heads(nh, C, T, W, dq, dkv, dv, r, itemsize,
+                 mask_bytes: int = 0) -> int:
     """Heads of one grid step of the chunk's walk: the most (a divisor of
     ``nh``) whose queries, up-projection and output (each double-buffered
     by the pipeline), fp32 accumulator and running max and sum (a lane
@@ -954,19 +967,32 @@ def _chunk_heads(nh, C, T, W, dq, dkv, dv, r, itemsize) -> int:
               + 2 * C * 128 * 4)
     shared = 2 * T * _lanes(W) * itemsize + _CHUNK_HEADS_A_STEP * (
         T * _lanes(dkv) * (4 + itemsize) + C * _lanes(T) * (4 + 4 + itemsize))
-    fit = max(1, (_CHUNK_VMEM_BYTES - shared) // a_head)
+    fit = max(1, (_CHUNK_VMEM_BYTES - shared - mask_bytes) // a_head)
     return max(h for h in range(1, nh + 1) if nh % h == 0 and h <= fit)
 
 
-def _chunk_body(bt_ref, cl_ref, vl_ref, q_ref, w_ref, pages_ref, o_ref,
-                buf, sem, m_scr, l_scr, acc_scr, *, scale, nope):
+def _chunk_body(bt_ref, cl_ref, vl_ref, q_ref, w_ref, *refs, scale, nope,
+                chosen=False):
     """One (slot, head group) of a latent pool's chunk: walk the slot's
     live pages in blocks of ``kp`` pages (block j+1 on its way from HBM
     while block j is computed) and, for each block of latents and each
     head of the group, expand the head's no-rope keys and its values
     through its slice of the up-projection IN VMEM, score the head's
     queries against ``[k_nope ; the block's one rotary key]`` and fold
-    probabilities times values into the head's running (m, l, acc)."""
+    probabilities times values into the head's running (m, l, acc).
+    ``chosen``: a mask of CHOSEN keys ``[S, blocks, C, T]`` (fp32 in HBM,
+    above ``NEG_INF / 2`` where the query may attend the key; written
+    through the block of the slot's last live query, where this walk
+    ends too) comes after the up-projection, and a block's ``[C, T]``
+    slice rides with the block's pages on a semaphore of its own: a
+    query attends a key only if the mask says so too, and one that no
+    key of a block is chosen for (its first block's too: key 0 need not
+    be chosen) adds nothing there."""
+    if chosen:
+        (mask_ref, pages_ref, o_ref, buf, mbuf, sem, msem, m_scr, l_scr,
+         acc_scr) = refs
+    else:
+        pages_ref, o_ref, buf, sem, m_scr, l_scr, acc_scr = refs
     s = pl.program_id(0)
     _, hg, C, dq = q_ref.shape
     rank = w_ref.shape[1]
@@ -985,6 +1011,10 @@ def _chunk_body(bt_ref, cl_ref, vl_ref, q_ref, w_ref, pages_ref, o_ref,
 
     def block_dma(j, slot, start):
         p0 = j * kp
+        if chosen:
+            cp = pltpu.make_async_copy(mask_ref.at[s, j], mbuf.at[slot],
+                                       msem.at[slot])
+            cp.start() if start else cp.wait()
 
         def page_dma(i, carry):
             cp = pltpu.make_async_copy(pages_ref.at[bt_ref[s, p0 + i]],
@@ -1024,6 +1054,10 @@ def _chunk_body(bt_ref, cl_ref, vl_ref, q_ref, w_ref, pages_ref, o_ref,
                              jnp.zeros_like(rows))
             valid = _valid_keys(j * T + iota((C, T), 1),
                                 ctx + iota((C, T), 0), None)
+        if chosen:
+            # the choice is among the keys a query may see, so it is the
+            # one mask of an edge block too
+            valid = mbuf[slot] > 0.5 * NEG_INF
         rows = rows.astype(q_ref.dtype)
         latents, k_rope = rows[:, :rank], rows[:, rank:rank + dq - nope]
 
@@ -1043,18 +1077,21 @@ def _chunk_body(bt_ref, cl_ref, vl_ref, q_ref, w_ref, pages_ref, o_ref,
                     + jax.lax.dot_general(
                         q[:, nope:], k_rope, nt,
                         preferred_element_type=jnp.float32)) * scale
-                if masked:
+                if masked or chosen:
                     sq = jnp.where(valid, sq, NEG_INF)
                 sqs.append(sq)
             # key 0 is in block 0 and every row attends it, so a row's
             # running max is finite from the first block on and a masked
-            # score's exponential is exactly 0
+            # score's exponential is exactly 0 (under a choice key 0 may
+            # be left out: what is not chosen is zeroed by name)
             for h, kv, sq in zip(hs, kvs, sqs):
                 m_prev = m_scr[h]                                 # [C, 1]
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(sq, axis=-1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
                 p = jnp.exp(sq - m_new)
+                if chosen:
+                    p = jnp.where(valid, p, 0.0)
                 l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1,
                                                       keepdims=True)
                 acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(
@@ -1081,8 +1118,10 @@ def _chunk_body(bt_ref, cl_ref, vl_ref, q_ref, w_ref, pages_ref, o_ref,
 @functools.partial(jax.jit,
                    static_argnames=("scale", "kp", "hg", "interpret"))
 def _chunk_call(q_nope, q_rope, kv_up, pages, block_tables, context_lens,
-                valid_lens, *, scale, kp, hg, interpret):
-    """``kp`` pages a block of latents, ``hg`` heads a grid step."""
+                valid_lens, mask=None, *, scale, kp, hg, interpret):
+    """``kp`` pages a block of latents, ``hg`` heads a grid step; ``mask``
+    [S, blocks, C, kp * bs]: the walk under a choice of keys, launched
+    as ``mla_attention_prefill_masked``."""
     S, C, nh, nope = q_nope.shape
     r, _, dkv = kv_up.shape
     dq, dv = nope + q_rope.shape[-1], dkv - nope
@@ -1096,40 +1135,48 @@ def _chunk_call(q_nope, q_rope, kv_up, pages, block_tables, context_lens,
     def head_map(s, g, bt_ref, cl_ref, vl_ref):
         return (s, g, 0, 0)
 
+    chosen = mask is not None
+    masks, mask_bufs, mask_sems = [], [], []
+    if chosen:
+        assert mask.shape == (S, -(-block_tables.shape[1] // kp), C,
+                              kp * bs), (mask.shape, C, kp, bs)
+        masks = [mask]
+        mask_bufs = [pltpu.VMEM((2, C, kp * bs), jnp.float32)]
+        mask_sems = [pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, nh // hg),
         in_specs=[
             pl.BlockSpec((1, hg, C, dq), head_map, memory_space=pltpu.VMEM),
             pl.BlockSpec((hg, r, dkv), lambda s, g, *_: (g, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY)],
+                         memory_space=pltpu.VMEM)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(masks)),
         out_specs=pl.BlockSpec((1, hg, C, dv), head_map,
                                memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, kp, bs, W), pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+        scratch_shapes=[pltpu.VMEM((2, kp, bs, W), pages.dtype)] + mask_bufs
+        + [pltpu.SemaphoreType.DMA((2,))] + mask_sems + [
             pltpu.VMEM((hg, C, 1), jnp.float32),
             pltpu.VMEM((hg, C, 1), jnp.float32),
             pltpu.VMEM((hg, C, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_chunk_body, scale=scale, nope=nope),
-        name="mla_attention_prefill",
+        functools.partial(_chunk_body, scale=scale, nope=nope, chosen=chosen),
+        name="mla_attention_prefill" if not chosen else (
+            "mla_attention_prefill_masked"),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, C, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      valid_lens.astype(jnp.int32), q, w, pages)
+      valid_lens.astype(jnp.int32), q, w, *masks, pages)
     return jnp.transpose(out, (0, 2, 1, 3))
 
 
 def latent_attention_prefill(q_nope, q_rope, kv_up, pages, block_tables,
                              context_lens, *, valid_lens=None,
-                             softmax_scale: float):
+                             softmax_scale: float, mask=None):
     """Ragged attention over a latent pool for one chunk a slot, in the
     EXPANDED form: ``q_nope`` [S, C, nh, dn] and ``q_rope`` [S, C, nh, dr]
     at positions ``context_lens[s] ..`` (the chunk's own rows already
@@ -1140,17 +1187,31 @@ def latent_attention_prefill(q_nope, q_rope, kv_up, pages, block_tables,
     top of the paged history.  The kernel alone: there is no dense form
     of it (``PagedKVCache.attend_latent`` takes it where
     :func:`kernel_available`, and the absorbed reference elsewhere).
+    ``mask`` (the selection over latents): fp32 ``[S, blocks, C,
+    latent_pages_per_block * bs]``, above ``NEG_INF / 2`` where the
+    query may attend the key; the walk then takes the mask's blocks
+    (``valid_lens`` must be given: past the block of a slot's last live
+    query the mask holds anything) and launches as
+    ``mla_attention_prefill_masked``.
     Returns ``[S, C, nh, dv]`` in the queries' dtype."""
     assert q_nope.ndim == 4 and pages.ndim == 3, (q_nope.shape, pages.shape)
     assert _use_pallas()
     S, C, nh, nope = q_nope.shape
     if valid_lens is None:
+        assert mask is None
         valid_lens = jnp.full_like(context_lens, C)
     r, _, dkv = kv_up.shape
     bs, W = pages.shape[1:]
-    kp = max(1, min(block_tables.shape[1], _CHUNK_BLOCK_TOKENS // bs))
+    M = block_tables.shape[1]
+    # under a mask the blocks are the mask's: the absorbed walk's
+    kp = (latent_pages_per_block(bs, M) if mask is not None
+          else max(1, min(M, _CHUNK_BLOCK_TOKENS // bs)))
+    # and its two buffers stand beside the heads' in VMEM
+    room = {} if mask is None else dict(
+        mask_bytes=2 * C * _lanes(kp * bs) * 4)
     hg = _chunk_heads(nh, C, kp * bs, W, nope + q_rope.shape[-1], dkv,
-                      dkv - nope, r, jnp.dtype(q_nope.dtype).itemsize)
+                      dkv - nope, r, jnp.dtype(q_nope.dtype).itemsize,
+                      **room)
     return _chunk_call(q_nope, q_rope, kv_up, pages, block_tables,
-                       context_lens, valid_lens, scale=softmax_scale, kp=kp,
-                       hg=hg, interpret=_INTERPRET)
+                       context_lens, valid_lens, mask, scale=softmax_scale,
+                       kp=kp, hg=hg, interpret=_INTERPRET)

@@ -236,6 +236,25 @@ def _kanana_cfg(cfg, chunk):
             "routed_scaling_factor": cfg.moe_routed_scale}
 
 
+def _glm5_cfg(cfg, chunk):
+    return {"num_hidden_layers": cfg.num_layers,
+            "num_attention_heads": cfg.num_attention_heads,
+            "rms_norm_eps": cfg.layernorm_epsilon,
+            "num_experts_per_tok": cfg.moe_top_k,
+            "n_routed_experts": cfg.num_experts,
+            "experts_first": cfg.moe_experts_first,
+            "norm_topk_prob": cfg.norm_topk_prob,
+            "vocab_size": cfg.padded_vocab_size,
+            "first_k_dense_replace": cfg.moe_first_dense_layers,
+            "qk_nope_head_dim": cfg.qk_nope_head_dim,
+            "qk_rope_head_dim": cfg.qk_rope_head_dim,
+            "v_head_dim": cfg.v_head_dim,
+            "index_n_heads": cfg.dsa_index_heads,
+            "index_topk": cfg.dsa_topk,
+            "rope_parameters": {"rope_theta": cfg.rope_theta},
+            "routed_scaling_factor": cfg.moe_routed_scale}
+
+
 def _keye_cfg(cfg, chunk):
     return {"num_hidden_layers": cfg.num_layers,
             "num_attention_heads": cfg.num_attention_heads,
@@ -373,6 +392,14 @@ FAMILIES = {
                          engine=dict(preemption=False),
                          state=_slot_arrays("delta_state")),
     "kanana": Family(_kanana_cfg, 2e-4, chunk=16),
+    # experts 2-5 of the router's 8; the indexer's projections and norms
+    # shaken as Keye's, or every score is near zero and the choice is by
+    # position
+    "glm5": Family(_glm5_cfg, 2e-4, chunk=16,
+                   tiny=dict(num_experts=4, moe_router_experts=8,
+                             moe_experts_first=2),
+                   shake=dict(noisy=("scale", "bias")),
+                   engine=dict(max_model_len=192)),
     "keye": Family(_keye_cfg, 2e-4, chunk=16,
                    shake=dict(noisy=("scale", "bias"), kernels=("kernel",),
                               router=6.0),

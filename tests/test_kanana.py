@@ -294,8 +294,11 @@ def test_what_a_latent_pool_does_not_support_is_refused_by_name(
                      (dict(host_cache_bytes=1 << 20), "host KV tier")):
         with pytest.raises(ValueError, match=what):
             engines.fresh("kanana", max_model_len=32, **kw)
+    # a compressed query is built since PR 61 (models/glm5.py); what is
+    # refused is one with no latent behind it
+    assert kanana_config("tiny", q_lora_rank=64).q_lora_rank == 64
     with pytest.raises(ValueError, match="q_lora_rank"):
-        kanana_config("tiny", q_lora_rank=64)
+        kanana_config("tiny", kv_lora_rank=None, q_lora_rank=64)
     with pytest.raises(ValueError, match="group-limited"):
         kanana_config("tiny", moe_n_group=2)
     with pytest.raises(ValueError, match="sliding window"):

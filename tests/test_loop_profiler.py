@@ -859,6 +859,9 @@ KERNEL_NAMES = {
     # the same walk over a latent pool (PR 36), which has neither an
     # int8 pool nor a window group
     "mla_attention_decode", "mla_attention_prefill",
+    # and the same two under a mask of chosen rows (PR 61: the selection
+    # over latents)
+    "mla_attention_sparse_decode", "mla_attention_prefill_masked",
     # the decode step's state-space recurrence, in place over the live
     # rows (PR 45), under the scope ``ssm_step``
     "ssm_state_step", "retention_state_step",
@@ -911,6 +914,10 @@ def test_every_kernel_and_program_carries_its_stable_name():
                                  f"with no name="
             if isinstance(kw["name"], ast.Constant):
                 names.add(kw["name"].value)
+            # one call under either of two names (the latent chunk's walk,
+            # with and without a mask of chosen rows)
+            if isinstance(kw["name"], ast.IfExp):
+                names |= {kw["name"].body.value, kw["name"].orelse.value}
         # the paged walk takes its name from whoever calls it: its two
         # public entries, the latent pool's decode step (no int8 pool, no
         # window group) and, under a mask of chosen keys, the selection

@@ -80,6 +80,13 @@ TRACED = {
     # lane rows are off, or as they were, for every model above
     "qwen3_next": {"engine_prefill": "84eb24dbccf04c14",
                    "engine_decode": "bb999ef74dc237e8"},
+    # recorded by PR 61, which brought the family: a compressed query and
+    # the selection over latents; ``attend_latent``'s ``index`` is None
+    # without an indexer and ``rot_d`` None without
+    # ``dsa_index_rope_dim``, so Kanana's and Keye's above are as they
+    # were
+    "glm5": {"engine_prefill": "cd0bdca723895f61",
+             "engine_decode": "3f2a1ce8782fedb1"},
 }
 
 

@@ -32,6 +32,7 @@ FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
           C.GATE: "trinity", C.OUTPUT_NORMS: "trinity",
           C.ROPE_TYPES: "trinity",
           C.FIRST_DENSE: "kanana", C.LATENT: "kanana",
+          C.SPARSE_LATENT: "glm5",
           C.QK_NORM_WHOLE: "olmoe", C.EXPERTS: "olmoe", C.SHARE: "granite"}
 GIVEN = {
     C.SLIDING: dict(sliding_window_size=16),
@@ -252,8 +253,68 @@ def test_a_delta_stack_with_another_layer_type_is_refused_by_name(other):
             in said) == (other not in ("moe", "retention")), said
 
 
+@pytest.mark.parametrize("what,row", [
+    (C.VERIFY_STEP, C.SPARSE), (C.INT8_POOL, C.SPARSE),
+    (C.HOST_TIER, C.LATENT), (C.TRAINING, C.SPARSE_LATENT)])
+def test_the_selection_over_latents_runs_and_still_refuses_by_name(what,
+                                                                   row):
+    """``SPARSE`` has left ``LATENT``'s row: a model with both is built
+    and told nothing against itself.  What the pair does not run with is
+    still said by the row that says it of either alone (the verify step
+    and the int8 pool by sparse attention's, the host tier by latent
+    attention's), training by the pair's own."""
+    cfg = _config("glm5")
+    assert C.HAS[C.SPARSE](cfg) and C.HAS[C.LATENT](cfg)
+    assert C.refusal(cfg) is None
+    assert C.SPARSE not in dict(C.RUNS_WITH)[C.LATENT]
+    said = C.refusal(cfg, (what,))
+    assert said.startswith(f"{row}: not implemented with {what}"), said
+    if what in TURNS_ON:
+        with pytest.raises(ValueError) as raised:
+            _engine("glm5", **TURNS_ON[what])
+        assert str(raised.value) == said
+
+
+@pytest.mark.parametrize("parallel", [C.TENSOR_PARALLEL, C.MODEL_PARALLEL])
+def test_the_selection_over_latents_is_refused_on_a_sharded_mesh(
+        parallel, monkeypatch):
+    """Tensor parallelism by sparse attention's row, pipeline parallelism
+    by latent attention's: ``GPTModel`` asks with what is in force."""
+    cfg = _config("glm5")
+    assert parallel in dict(C.RUNS_WITH)[
+        C.SPARSE if parallel == C.TENSOR_PARALLEL else C.LATENT]
+    monkeypatch.setattr(gpt, "_vocab_unsharded", lambda: False)
+    with pytest.raises(ValueError) as raised:
+        MODEL_REGISTRY["glm5"](cfg)
+    assert str(raised.value) == C.refusal(cfg, gpt.parallelism_in_force())
+    assert "sparse attention" in str(raised.value)
+
+
+def test_a_compressed_query_is_built_and_its_indexer_reads_it():
+    """``q_lora_rank`` is a field that is built (it was refused by name
+    until PR 61): the query's two projections with a norm between, and an
+    indexer whose queries read the compressed query has a projection of
+    that width; what does not go together is said at construction."""
+    cfg = _config("glm5")
+    att = _model("glm5")[1]["transformer"]["layers"]["attention"]
+    assert att["query_down"]["kernel"].shape[1:] == (cfg.hidden_size,
+                                                      cfg.q_lora_rank)
+    assert att["query_norm"]["scale"].shape[1:] == (cfg.q_lora_rank,)
+    assert att["query"]["kernel"].shape[1] == cfg.q_lora_rank
+    assert att["indexer"]["query"]["kernel"].shape[1] == cfg.q_lora_rank
+    assert att["indexer"]["key"]["kernel"].shape[1] == cfg.hidden_size
+    with pytest.raises(ValueError, match="needs kv_lora_rank"):
+        _config("mistral", q_lora_rank=16)
+    with pytest.raises(ValueError, match="compressed needs q_lora_rank"):
+        _config("keye", dsa_index_query="compressed")
+    with pytest.raises(ValueError, match="dsa_index_rope_dim"):
+        _config("keye", dsa_index_rope_dim=3)
+    # an indexer that reads the layer's input beside a compressed query
+    assert _config("glm5", dsa_index_query="input").dsa_index_query == "input"
+
+
 @pytest.mark.parametrize("family", ["kanana", "keye", "mellum", "granite",
-                                    "lfm2", "brumby", "qwen3_next"])
+                                    "lfm2", "brumby", "qwen3_next", "glm5"])
 def test_the_pool_and_the_engine_refuse_int8_in_one_sentence(family):
     """``init_pools(quantized=True)`` and the engine ask the same table,
     so a latent, an indexed, a grouped model and one with state-space

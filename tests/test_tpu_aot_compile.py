@@ -294,7 +294,47 @@ def _latent(q_tokens, slots, tokens=32768, page=16):
                    ((512, 32, 256), BF16)] + pool
 
 
+def _selected_latent(q_tokens, slots, tokens=66560, page=16):
+    """GLM-5's selection over its latent pool at the cell's shapes: 64
+    query heads over rows of 640 (a latent of 512, a rotary key of 64,
+    zeros) beside indexer keys of 128, an indexer of 32 heads of 128,
+    top-k 2,048, 10 slots of up to 66,560 tokens (a table of 4,160
+    entries) over 20,481 pages.  The decode step: absorbed queries of the
+    row's width under the mask of chosen rows.  The [1, 512] chunk:
+    queries of 192 + 64 a head and the up-projection [512, 64, 192 +
+    256], each block of latents expanded in the kernel, a block's slice
+    of the mask riding with it."""
+    from megatron_llm_tpu.ops.pallas import dsa_attention as dsa
+
+    pool = [((20481, page, 640), BF16), ((20481, page, 128), BF16),
+            ((slots, tokens // page), jnp.int32), ((slots,), jnp.int32),
+            ((slots,), jnp.int32)]
+    index = [((slots, q_tokens, 32, 128), BF16),
+             ((slots, q_tokens, 32), jnp.float32)]
+    kw = dict(topk=2048, softmax_scale=256 ** -0.5, value_width=512)
+    if q_tokens == 1:
+        def step(q, iq, iw, pages, index_pages, tables, lens, valid):
+            return dsa.paged_selected_latent_attention(
+                q, None, None, iq, iw, pages, index_pages, tables, lens,
+                valid, **kw)
+
+        return step, [((slots, 1, 64, 640), BF16)] + index + pool
+
+    def chunk(q_nope, q_rope, kv_up, iq, iw, pages, index_pages, tables,
+              lens, valid):
+        return dsa.paged_selected_latent_attention(
+            q_nope, q_rope, kv_up, iq, iw, pages, index_pages, tables, lens,
+            valid, **kw)
+
+    return chunk, [((slots, q_tokens, 64, 192), BF16),
+                   ((slots, q_tokens, 64, 64), BF16),
+                   ((512, 64, 448), BF16)] + index + pool
+
+
 CASES = {
+    "dsa_selected_latent_decode_10_slots": lambda: _selected_latent(1, 10),
+    "dsa_selected_latent_prefill_chunk_512":
+        lambda: _selected_latent(512, 1),
     "latent_decode_16_slots": lambda: _latent(1, 16),
     "latent_prefill_chunk_512": lambda: _latent(512, 1),
     "paged_window_decode_32_slots": lambda: _paged_window(1, 32),
@@ -387,6 +427,7 @@ TRINITY = "trinity_cell_programs"
 LFM2 = "lfm2_cell_programs"
 BRUMBY = "brumby_cell_programs"
 QWEN3_NEXT = "qwen3_next_cell_programs"
+GLM5 = "glm5_cell_programs"
 
 
 def _compile_all(only: str = ""):
@@ -419,7 +460,7 @@ def _compile_all(only: str = ""):
             found[case] = "tpu_custom_call" in text
             if case.startswith("dsa_"):
                 found.setdefault(SELECTION, {})[case] = sorted(re.findall(
-                    r"^\s*(?:ROOT )?%?((?:dsa|paged_attention)_\w+?)"
+                    r"^\s*(?:ROOT )?%?((?:dsa|paged_attention|mla_attention)_\w+?)"
                     r"(?:\.\d+)? = ", text, re.M))
         except Exception as e:  # noqa: BLE001 - the compiler's refusal
             found[case] = f"{type(e).__name__}: {e}"[:2000]
@@ -577,6 +618,21 @@ def _qwen3_next_cell():
             num_slots=32, num_blocks=32769, max_model_len=33792)
 
 
+def _glm5_cell():
+    """The same of the benchmark's GLM-5 cell: 5 of the published 78
+    layers (one dense and four sparse), 16 of 256 experts of width 2048
+    held, an eighth of the vocabulary under an untied head, 10 slots of
+    up to 66,560 tokens and a pool of 20,481 pages of latent rows and
+    indexer keys."""
+    from megatron_llm_tpu.models.glm5 import Glm5Model, glm5_config
+
+    return lambda: Glm5Model(glm5_config(
+        "744B-A40B", num_layers=5, moe_first_dense_layers=1, num_experts=16,
+        moe_router_experts=256, padded_vocab_size=19456,
+        params_dtype="bf16", compute_dtype="bf16", seq_length=66560)), dict(
+            num_slots=10, num_blocks=20481, max_model_len=66560)
+
+
 # a cell's name among the child's arguments, its key in what the child
 # prints, and the cell
 CELLS = {"granite": (GRANITE, _granite_cell),
@@ -584,7 +640,8 @@ CELLS = {"granite": (GRANITE, _granite_cell),
          "trinity": (TRINITY, _trinity_cell),
          "lfm2": (LFM2, _lfm2_cell),
          "brumby": (BRUMBY, _brumby_cell),
-         "qwen3_next": (QWEN3_NEXT, _qwen3_next_cell)}
+         "qwen3_next": (QWEN3_NEXT, _qwen3_next_cell),
+         "glm5": (GLM5, _glm5_cell)}
 # every cell's engine beside its own keywords
 _CELL_ENGINE = dict(block_size=16, prefill_chunk=512, preemption=False,
                     paged_kernel="on", prefill_kernel="on")
@@ -686,7 +743,8 @@ def _cell_programs(chip, build, engine):
                 "temp_bytes": m.temp_size_in_bytes,
                 "state_rewrites": rewrites,
                 "kernels": sorted(set(re.findall(
-                    r"(paged_attention_\w+?|moe_experts\w*?|ssm_state_step"
+                    r"(paged_attention_\w+?|mla_attention_\w+?|dsa_\w+?"
+                    r"|moe_experts\w*?|ssm_state_step"
                     r"|retention_state_step|retention_state_chunk"
                     r"|delta_state_step|delta_state_chunk)"
                     r"(?:\.\d+)? = ", text))),
@@ -697,7 +755,8 @@ def _cell_programs(chip, build, engine):
                     "short_conv", "conv_out_proj", "retention_gate",
                     "retention_chunk", "retention_step", "delta_proj",
                     "delta_conv", "delta_gate", "delta_chunk", "delta_step",
-                    "delta_norm")
+                    "delta_norm", "mla_query_down", "mla_query_up",
+                    "mla_absorb", "dsa_indexer")
                     if f"/{s}/" in text})}
         return found
     except Exception as e:      # noqa: BLE001 - the compiler's refusal
@@ -751,8 +810,16 @@ def test_selection_is_one_kernel_a_name_for_v5e(compiled):
     """The decode step and the chunk each hold ONE scores walk, ONE
     choice and ONE walk under it, under the names a profile's ``XLA Ops``
     line (and the benchmark's ``dsa_*`` metrics) knows: the choice that
-    counts over a row's live blocks is one kernel, not a ladder of sizes."""
+    counts over a row's live blocks is one kernel, not a ladder of sizes.
+    Over a latent pool (PR 61) the same two of the selection and latent
+    attention's own two walks under the mask, by names of their own."""
     assert compiled[SELECTION] == {
+        "dsa_selected_latent_decode_10_slots": [
+            "dsa_index_scores_decode", "dsa_select_decode",
+            "mla_attention_sparse_decode"],
+        "dsa_selected_latent_prefill_chunk_512": [
+            "dsa_index_scores_prefill", "dsa_select_prefill",
+            "mla_attention_prefill_masked"],
         "dsa_selected_decode_8_slots": [
             "dsa_index_scores_decode", "dsa_select_decode",
             "paged_attention_sparse_decode"],
@@ -1112,6 +1179,64 @@ def test_the_qwen3_next_cells_programs_compile_for_a_described_v5e():
         assert got["temp_bytes"] < 0.3e9, (name, got)
         assert {"delta_proj", "delta_conv", "delta_gate", "delta_norm",
                 "attn_gate", scope} <= set(got["scopes"]), got["scopes"]
+
+
+def test_the_glm5_cells_programs_compile_and_fit_a_v5e():
+    """The GLM-5 cell's bytes and counts as the engine's plan has them (5
+    layers at the published widths: one dense and four sparse, 16 of 256
+    experts of 2048, an eighth of the vocabulary, 10 slots and 20,481
+    pages of two arrays): ISSUE 61's arithmetic, which ``jax.eval_shape``
+    lays out and nobody allocates."""
+    found = _cell_plan(*_glm5_cell())
+    assert found["state_bytes_per_slot"] == 0
+    # a token a layer: 1,280 B of latent row and 256 B of indexer key
+    assert found["pool_bytes"] == 20481 * 16 * 5 * (1280 + 256)
+    attention = (6144 * 2048 + 2048 + 2048 * 16384 + 6144 * 576 + 512
+                 + 512 * 64 * 448 + 16384 * 6144)
+    indexer = 2048 * 4096 + 6144 * 128 + 6144 * 32 + 2 * 128
+    sparse = (16 + 1) * 3 * 6144 * 2048 + 6144 * 256 + 256
+    # 400.9 M + 4 x 817.7 M + 239.1 M, and eleven norms of 6144
+    assert found["parameters"] == (
+        5 * (attention + indexer + 2 * 6144) + 3 * 6144 * 12288 + 4 * sparse
+        + 2 * 19456 * 6144 + 6144) == 3_910_812_416
+    # the widest held expert the grouped matmul has met: 6144 x 4096 in,
+    # 2048 x 6144 out
+    tiles = found["moe_expert_tiles"]
+    assert tiles["w_in"]["tk"] * tiles["w_in"]["tn"] * 2 <= 12 * 2 ** 20
+    assert tiles["w_out"]["tk"] * tiles["w_out"]["tn"] * 2 <= 12 * 2 ** 20
+
+
+@pytest.mark.slow
+@pytest.mark.time_limit(900)
+def test_the_glm5_cells_programs_compile_for_a_described_v5e():
+    """The GLM-5 cell's two programs at its real sizes, for a described
+    v5e: the decode step holds a Mosaic call for the indexer's scores,
+    for the choice, for the shared walk over latent rows under the mask
+    and for the experts, and owns its pool; the chunk holds the same
+    three of the selection under their chunk's names, the latent chunk's
+    own walk under the mask among them, is LENT the pool, holds it twice
+    and still fits the chip's 15.75 GB."""
+    found = _cell_compiled("glm5")
+    step, chunk = found["engine_decode"], found["engine_prefill"]
+    assert step["kernels"] == ["dsa_index_scores_decode",
+                               "dsa_select_decode",
+                               "mla_attention_sparse_decode", "moe_experts"]
+    assert chunk["kernels"] == ["dsa_index_scores_prefill",
+                                "dsa_select_prefill",
+                                "mla_attention_prefill_masked",
+                                "moe_experts"]
+    assert step["alias_bytes"] >= found["pool_bytes"], step
+    assert chunk["alias_bytes"] == 0
+    for name, got in (("engine_prefill", chunk), ("engine_decode", step)):
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"] - got["alias_bytes"])
+        # 7.82 GB of weights, 2.52 GB of pool (twice in a chunk), and a
+        # chunk's scores and mask over a table of 66,560 keys (0.27 GB)
+        assert held < 15.75e9 * 0.92, (name, held)
+        assert {"mla_query_down", "mla_query_up",
+                "dsa_indexer"} <= set(got["scopes"]), got["scopes"]
+    assert "mla_absorb" in step["scopes"]
+    assert "mla_absorb" not in chunk["scopes"]
 
 
 if __name__ == "__main__":
