@@ -903,6 +903,9 @@ class CachePlan:
     # the layer type of each layer of the STATE group, in order: what a
     # launch's counters of state are counted by
     state_kinds: tuple = ()
+    # whether the pools are int8 with fp32 scales: what ``init_pools``
+    # makes, and what a launch's walks multiply in
+    int8_pool: bool = False
 
     @property
     def paged(self) -> bool:
@@ -912,9 +915,9 @@ class CachePlan:
         return self.groups is None or any(
             g in (FULL, WINDOW) for g in self.groups)
 
-    def init_pools(self, num_blocks: int, quantized: bool = False):
+    def init_pools(self, num_blocks: int):
         return init_pools(self.cfg, num_blocks, self.block_size,
-                          quantized=quantized, num_slots=self.num_slots,
+                          quantized=self.int8_pool, num_slots=self.num_slots,
                           window_blocks=self.window and self.window[0])
 
     def tables(self, blocks, rows=slice(None)):
@@ -957,11 +960,28 @@ class CachePlan:
         """The cache's counters of one launch on its record ``d``
         (``serving/loop_profiler.py``: ``DSA_FIELDS``, ``MLA_FIELDS``,
         ``SSM_FIELDS``, ``CONV_FIELDS``, ``RETENTION_FIELDS``,
-        ``DELTA_FIELDS``), from the host arrays its program is handed:
-        each row's ``context_lens`` and ``valid_lens`` (0: an idle row)
-        of ``n`` queries a row; ``admitted``: the requests that hold a
-        slot.  Returns at once for a model with no such mechanism."""
+        ``DELTA_FIELDS``, ``WALK_FIELDS``), from the host arrays its
+        program is handed: each row's ``context_lens`` and ``valid_lens``
+        (0: an idle row) of ``n`` queries a row; ``admitted``: the
+        requests that hold a slot.  After the walks, returns at once for
+        a model with no other mechanism."""
         cfg, layers = self.cfg, self.cfg.num_layers
+        if self.paged and not cfg.latent_attention:
+            # a live row's walk a layer that keeps pages of K and V; in
+            # the pool's dtype where the kernel runs (a chunk and the
+            # verify step on one path, a decode step on the other) and
+            # its own test of the two dtypes says so
+            from megatron_llm_tpu.ops.pallas.paged_attention import (
+                native_operands)
+            walked = layers if self.groups is None else sum(
+                g in (FULL, WINDOW) for g in self.groups)
+            d.walks = walked * int((valid_lens > 0).sum())
+            kernel = (self.paged_kernel if d.kind == "decode"
+                      else self.prefill_kernel)
+            if kernel == "pallas" and native_operands(
+                    jnp.int8 if self.int8_pool else cfg.compute_jnp_dtype,
+                    cfg.compute_jnp_dtype):
+                d.walks_native = d.walks
         state_layers, conv_layers, ret_layers, delta_layers = (
             self.state_kinds.count(k)
             for k in ("mamba", "conv", "retention", "gated_delta"))
@@ -1054,12 +1074,12 @@ class CachePlan:
 
 def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
          prefill_chunk: int, prefill_kernel: str,
-         paged_kernel: str) -> CachePlan:
+         paged_kernel: str, int8_pool: bool = False) -> CachePlan:
     """The :class:`CachePlan` of a model of config ``cfg`` served from
     pages of ``block_size`` tokens, ``num_slots`` slots of
     ``max_blocks_per_slot`` pages and chunks of ``prefill_chunk``, the
     chunk on the resolved path ``prefill_kernel`` and the decode step on
-    ``paged_kernel``."""
+    ``paged_kernel``; ``int8_pool``: keys and values kept in int8."""
     groups = layer_groups(cfg)
     window, dsa_block_keys, dsa_table_blocks = None, 0, 0
     if groups is not None and WINDOW in groups:
@@ -1089,7 +1109,7 @@ def plan(cfg, block_size: int, num_slots: int, max_blocks_per_slot: int,
         state_bytes_per_slot(pools), dsa_block_keys, dsa_table_blocks,
         next((i for i, g in enumerate(groups or ()) if g != NONE), 0),
         tuple(cfg.layer_type(i) for i, g in enumerate(groups or ())
-              if g == STATE))
+              if g == STATE), int8_pool)
 
 def pools_of(caches: List[PagedKVCache]) -> List[dict]:
     """The pools as a step left them."""

@@ -62,6 +62,34 @@ first) skips the position masks and the zeroing of dead pages' values;
 the others mask by position.  fp32 online softmax (m, l, acc in VMEM
 scratch) carries across blocks.
 
+What the block MULTIPLIES (PR 62).  Where the pool's dtype is the
+queries' (``native_operands``; an int8 pool's is not, and its scales
+make the operands fp32), both products take their operands as they lie
+in the pool under an fp32 accumulator: ``q x k`` of bf16 by bf16 is
+exact there, and nothing of a block of K, of V or of the queries is
+widened.  What this replaced widened q, K and V to fp32 and multiplied
+fp32 by fp32, which on a v5e under Mosaic's default precision is ONE pass
+of bf16 operands: the old walk rounded ``p`` to bf16 once, and with one
+rounded term the new operands give the old kernels' outputs bit for bit
+(fifteen shapes, chip runs of PR 62).  In how many terms of the pool's
+dtype the fp32 probabilities meet the values follows from what bounds
+the block (``_value_terms``).  A chunk's block of 512 rows a kv group or
+more at heads of 128 or less is bound by the vector units and the MXU
+has room: ``p`` goes as TWO terms (``_weighted_values``: over a bf16
+pool ``p`` with its lower half cleared, which is a bf16 as it stands,
+and what that left, rounded), stacked ``[2R, T]`` so that V crosses the
+MXU once: 16 bits of ``p``, eight more than the walk ever had, and
+FASTER than one term, the more so the more rows (3% at 512, 14% at
+1,024), the stacked operand being laid out for the MXU once where a
+single term is converted on its way in.  Everywhere else ONE term, the
+bits the walk always gave: a decode step's ``nh`` rows and a q-block of
+256 rows (a 64-token chunk at 8 kv heads), which pay for every tile of V
+they load whatever streams past it (two terms cost them 2-3% and 9%);
+heads of 256, where the MXU bounds a chunk (137 GFLOP in 1.74 ms is 40%
+of the peak; two terms +26% there); a verify step's 20 rows, which are
+not whole tiles; a latent pool's walk, as its chunk.  m, l, acc, the
+exponentials and the masks are fp32 everywhere.
+
 One fetch serves every query head.  Decode multiplies all ``nh`` heads
 against all ``(key, kv group)`` pairs of a block in one matmul
 ``[nh, d] x [d, 256 * g]`` and masks, for each head, the lanes of the
@@ -71,7 +99,8 @@ a ``[32, 2048]`` block.  A chunk's rows would make that waste real, so
 with ``block_q > 1`` each group's rows ``[block_q * nh/g, d]`` meet that
 group's keys ``[256, d]``.
 
-What it costs (TPU v5e, bf16, pages of 16 tokens; chip runs of PR 48,
+What it costs (TPU v5e, bf16, pages of 16 tokens; PR 62's readings are
+the second table; chip runs of PR 48,
 the kernel alone, 20 calls chained, PR 47's tree -> this one; the
 outputs bit for bit the same): time follows the live pages and not the
 table, and a live page costs about the same whatever its bytes.
@@ -121,6 +150,59 @@ start: ``paged_decode_4_kv_heads_48_slots`` of
 0.7 -> 2.3 s to compile for a described v5e, the 512-token chunk at 4 kv
 heads 0.2 -> 0.3 and 4.1 -> 7.4 (this sandbox's CPU, PR 48).
 
+The same on PR 62's tree (chip runs of PR 62, the kernel alone, 20 calls
+chained, the least of 5, tables drawn anew so the pages differ a little;
+PR 61's tree -> this tree, whose decode step takes ONE term and whose
+outputs are PR 61's to the bit in all seven rows):
+
+===================================  ======  ================  ================
+rows x mean live pages               pages   us a call         us a live page
+===================================  ======  ================  ================
+32 x 63, 8 kv heads                   2,010    228 ->   226    0.113 -> 0.112
+48 x 420, 4 kv heads                 20,160  1,446 -> 1,426    0.072 -> 0.071
+48 x 129, the same under a window     6,176    569 ->   562    0.092 -> 0.091
+  of 2,048
+64 x 125, 2 kv heads                  8,018    702 ->   721    0.088 -> 0.090
+128 x 115, 4 kv heads                14,738  1,251 -> 1,229    0.085 -> 0.083
+32 x 1,085, 2 kv heads of 256        34,728  2,535 -> 2,616    0.073 -> 0.075
+8 x 1,055 under a mask of chosen      8,438    622 ->   603    0.074 -> 0.071
+  keys, 4 kv heads
+===================================  ======  ================  ================
+
+A decode step's block gains a percent or two from values that are not
+widened (five rows of seven; at 2 kv heads and at heads of 256 it loses
+2.7% and 3.2%, the same arithmetic, bit for bit, laid out otherwise by
+the compiler): its products were ONE pass of bf16 operands before, so
+the width of its operands is not what bounds it (PR 48's split stands:
+the block's arithmetic 0.057-0.072 us a page over fetches of 0.049).  A
+second term costs it 2-3% more (1,460 -> 1,487 at 4 kv heads, an
+earlier call of PR 62): its 32 rows fill a quarter of the MXU, which
+loads 16 tiles of K and 16 of V a block whatever streams past them.  The chunks, PR 61's
+tree -> one term -> two, the form this tree takes in capitals: 512 over
+8,192 at 4 kv heads (q-blocks of 512 rows a group against 512 keys)
+1,410 -> 1,352 -> 1,309 us TWO (69 GFLOP: 27% of 197 TFLOP/s; 486 ->
+471 -> 452 TWO under a window of 2,048), 512 over 2,048 at 2 kv heads
+(1,024 rows) 368 -> 345 -> 297 TWO, Keye's ``[1, 512]`` chunk over
+17,200 keys under its mask (1,024 rows) 2,609 -> 2,511 -> 2,157 TWO (300
+live rows over 9,000: 1,111 -> 1,077 -> 929 TWO); 64 tokens over 1,984
+of context at 8 kv heads (256 rows against 256 keys) 91.7 -> 87.1 ONE ->
+95.5; at heads of 256, where the MXU bounds the chunk (137 GFLOP in 1.74
+ms is 40% of the peak), 512 over 16,384 at 2 kv heads 1,739 -> 1,511 ONE
+(two terms 1,903 in an earlier call of PR 62: paid in full); the K + 1 verify step of 32 rows (20
+rows a group, not whole tiles) 655 -> 667 ONE (stacked 680; as two
+products of 20 rows 501, 23% under one product of the same rows, which
+nobody has explained: ROADMAP S20(7)).  What bounds a ``[R, T]`` block of
+a chunk at heads of 128 now is not its products and not the count of
+the vector units' operations either (the scale folded into the exponent
+under ``exp2`` and the second ``where`` dropped gave 1.5% and were left
+out; clearing ``p``'s lower half in place of rounding it, which saves a
+conversion back, 3%): what is left is the traffic of ``[R, T]`` arrays
+through VMEM between those operations and the lane reductions of the
+maximum and the sum.  A two-term chunk's kernel asks 20 MiB of VMEM
+(``_TWO_TERMS_VMEM_LIMIT``): the compiler's stack for it is 18.92 MiB
+where the fp32 walk's was 13.7 (compiled here for a described v5e), over
+the 16 a kernel gets unasked.
+
 Under a MASK of chosen keys (``_walk_call(..., mask=)``: the learned
 sparse attention of ``dsa_attention.py``, which until PR 57 kept a copy
 of this walk as it stood before PR 48) a query attends a key only if
@@ -139,7 +221,8 @@ outputs bit for bit the same: 8 rows of 14-21 thousand keys at one
 query 1,043 -> 665 us a call (794 with every block taken as an edge
 block: the fetches' share of the gain is two thirds), 5 live rows of 8
 594 -> 387; a ``[1, 512]`` chunk over 17,200 keys 2,685 -> 2,606 (its
-q-blocks of 128 rows of 32 heads are bound by their fp32 products; the
+q-blocks of 128 rows of 32 heads were taken to be bound by their fp32
+products, which PR 62 found to be one bf16 pass each; the
 rows stay in the order above, ``r // qpg``, and the mask's slice is
 spread over a group's heads by a sublane broadcast once a block), 300
 live rows over 9,000 1,152 -> 1,110.  The decode kernel under a mask
@@ -277,11 +360,12 @@ def _valid_keys(key_pos, pos, window):
     return valid
 
 
-def _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, rows):
+def _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, rows, terms=1):
     """One online-softmax update: fp32 scores ``sq`` [R, T] with their
     validity (None: every score counts) and the values ``v`` [T, d] (fp32,
-    or a latent pool's own dtype, to which the probabilities are then
-    rounded) folded into the running (m, l, acc) at scratch ``rows``."""
+    or a pool's own dtype, to which the probabilities are then rounded in
+    ``terms`` terms: :func:`_weighted_values`) folded into the running
+    (m, l, acc) at scratch ``rows``."""
     if valid is not None:
         sq = jnp.where(valid, sq, NEG_INF)
     m_prev = m_scr[rows]                              # [R, 1]
@@ -291,9 +375,68 @@ def _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, rows):
     if valid is not None:
         p = jnp.where(valid, p, 0.0)
     l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    acc_scr[rows] = acc_scr[rows] * alpha + _weighted_values(p, v, terms)
     m_scr[rows] = m_new
+
+
+def _weighted_values(p, v, terms):
+    """fp32 probabilities ``p`` [R, T] times values ``v`` [T, d] in the
+    values' dtype under an fp32 accumulator.  ONE term is ``p`` rounded
+    to that dtype (over bf16, 8 bits of it: what an fp32 product on a
+    v5e keeps of its operands anyway).  TWO terms keep 16: a bf16 IS the
+    upper half of an fp32, so over a bf16 pool the first term is ``p``
+    with its lower half cleared, exact as it stands, and the second what
+    that left, rounded; any other dtype takes the rounded ``p`` and what
+    the rounding left.  The terms go stacked ``[2R, T]`` (``R`` whole
+    tiles of that dtype: ``_value_terms``), so that ``v`` crosses the MXU
+    once."""
+    if terms == 1 or v.dtype == jnp.float32:
+        return jax.lax.dot(p.astype(v.dtype), v,
+                           preferred_element_type=jnp.float32)
+    if v.dtype == jnp.bfloat16:
+        hi = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(p, jnp.uint32)
+            & jnp.uint32(0xFFFF0000), jnp.float32)
+    else:
+        hi = p.astype(v.dtype).astype(jnp.float32)
+    both = jax.lax.dot(
+        jnp.concatenate([hi.astype(v.dtype), (p - hi).astype(v.dtype)],
+                        axis=0), v, preferred_element_type=jnp.float32)
+    return both[:p.shape[0]] + both[p.shape[0]:]
+
+
+def native_operands(pool_dtype, query_dtype) -> bool:
+    """Whether a walk's two products take their operands as they lie in
+    the pool: the pool's dtype is the queries' (an int8 pool's never is,
+    and its scales make the operands fp32).  What ``_walk_body`` tests,
+    and what ``ops/paged_kv.py::CachePlan.account`` counts by."""
+    return jnp.dtype(pool_dtype) == jnp.dtype(query_dtype)
+
+
+# the fewest rows of a block that carry the probabilities in two terms
+# (``_value_terms``; chip runs of PR 62, one term -> two, us a call, the
+# kernel alone): 256 rows a kv group against 256 keys 87.1 -> 95.5, 512
+# against 512 1,352 -> 1,309, 1,024 against 512 345 -> 297 and (under a
+# mask of chosen keys) 2,511 -> 2,157
+_TWO_TERM_ROWS = 512
+
+
+def _value_terms(native, latent, rows, d, dtype) -> int:
+    """In how many terms of the pool's dtype a block's probabilities
+    ``[rows, T]`` meet its values ``[T, d]``.  TWO where the block is a
+    chunk's many rows at heads of 128 or less: the vector units bound it,
+    the MXU has room, and the stacked operand is laid out for the MXU
+    once where a single term is converted on its way in, so two are
+    FASTER than one, the more so the more rows (``_TWO_TERM_ROWS``).
+    ONE, the bits the walk always gave, everywhere else: a decode step's
+    ``nh`` rows and a short q-block's, which pay for every tile of V
+    they load whatever streams past it; heads of 256, where the MXU
+    bounds the block; rows that are not whole tiles of the dtype (a
+    verify step's 20), which cannot be stacked; and a latent pool's walk,
+    which rounds ``p`` once as its chunk does."""
+    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+    return 2 if (native and not latent and d <= 128
+                 and rows >= _TWO_TERM_ROWS and rows % tile == 0) else 1
 
 
 def _softmax_finish(l_scr, acc_scr, rows):
@@ -506,10 +649,17 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
     def iota(shape, dim):
         return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
-    # bf16 queries on bf16 pools multiply as they are (a product of two
-    # bf16 is exact in the fp32 accumulator); anything else goes to fp32
-    native = bq == 1 and not quantized and bufs[0].dtype == q_ref.dtype
+    # bf16 queries on bf16 pools multiply as they are, a step's and a
+    # chunk's (a product of two bf16 is exact in the fp32 accumulator);
+    # anything else (int8 pools, whose scales are fp32) goes to fp32
+    native = native_operands(bufs[0].dtype, q_ref.dtype)
+    terms = _value_terms(native, latent, nh if bq == 1 else R, d,
+                         bufs[0].dtype)
     q = q_ref[0] if native else q_ref[0].astype(jnp.float32)  # [bq, nh, d]
+    # a chunk's rows by kv group, [R, d] each (flat row r is chunk row
+    # r // qpg, head r % qpg): cut out once a grid step, not once a block
+    q_rows = None if bq == 1 else [
+        q[:, grp * qpg:(grp + 1) * qpg, :].reshape(R, d) for grp in range(g)]
 
     def dequantized(x, sc):
         """int8 [kp, bs, g, d] times its scales [kp, bs * g] (a page's
@@ -553,9 +703,9 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
             v = dequantized(bufs[1][half], bufs[3][half])
         else:
             k = bufs[0][half].reshape(lanes, d)
-            v = bufs[1][half].reshape(lanes, d).astype(jnp.float32)
+            v = bufs[1][half].reshape(lanes, d)
             if not native:
-                k = k.astype(jnp.float32)
+                k, v = k.astype(jnp.float32), v.astype(jnp.float32)
         # buffer pages past the last live one hold what an earlier block
         # left there: their scores are masked below, and their values
         # zeroed here so that 0 x (whatever they are) adds nothing
@@ -580,10 +730,11 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
             sq = jax.lax.dot_general(
                 q[0], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [nh, lanes]
-            _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, slice(None))
+            _softmax_block(sq, valid, v, m_scr, l_scr, acc_scr, slice(None),
+                           terms)
             return
         # a chunk: the rows of one kv group [R, d] against that group's
-        # keys [T, d]; flat row r is chunk row r // qpg, head r % qpg
+        # keys [T, d]
         k, v = k.reshape(T, g, d), v.reshape(T, g, d)
         valid = None
         if masked:
@@ -596,12 +747,11 @@ def _walk_body(bt_ref, cl_ref, vl_ref, q_ref, *refs,
                 base + iota((R, T), 1),
                 ctx + q0 + jax.lax.div(iota((R, T), 0), qpg), window), valid)
         for grp in range(g):
-            q2 = q[:, grp * qpg:(grp + 1) * qpg, :].reshape(R, d)
             sq = jax.lax.dot_general(
-                q2, k[:, grp, :], (((1,), (1,)), ((), ())),
+                q_rows[grp], k[:, grp, :], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale     # [R, T]
             _softmax_block(sq, valid, v[:, grp, :], m_scr, l_scr, acc_scr,
-                           slice(grp * R, (grp + 1) * R))
+                           slice(grp * R, (grp + 1) * R), terms)
 
     def block(j, half):
         """Block ``j`` of this walk, in buffer ``half``."""
@@ -752,6 +902,12 @@ def _walk_kernel(q, k_pages, v_pages, block_tables, context_lens,
     # scratch and as much again of a group's scores), so the kernel asks
     # for the VMEM the latent chunk's asks for
     masks, mask_specs, extra = [], [], {}
+    if _value_terms(native_operands(k_pages.dtype, q.dtype), latent,
+                    nh if bq == 1 else bq * (nh // g), d,
+                    k_pages.dtype) == 2:
+        # the probabilities' two terms stand beside a block's scores
+        extra = dict(compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_TWO_TERMS_VMEM_LIMIT))
     if mask is not None:
         assert not quantized and window is None, name
         assert bq == 1 or not latent, name
@@ -945,6 +1101,15 @@ _CHUNK_BLOCK_TOKENS = 1024
 _CHUNK_HEADS_A_STEP = 2
 _CHUNK_VMEM_BYTES = 48 * 1024 * 1024
 _CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
+# what a paged chunk whose probabilities go in two terms is given, and so
+# HELD TO: tests/test_tpu_aot_compile.py compiles every such case under
+# it, and a stack that grows past it fails there.  The compiler's stack
+# for a q-block of 512 rows a kv group against 512 keys at 4 kv heads,
+# the largest of the unmasked served shapes (compiled for a described
+# v5e): 13.7 MB with fp32 operands (PR 61), 18.92 MiB with the pool's and
+# two terms (PR 62; 16.92 at 8 kv heads of 64), over the 16 a kernel gets
+# unasked.  A chunk under a mask asks ``_CHUNK_VMEM_LIMIT``, as it did
+_TWO_TERMS_VMEM_LIMIT = 20 * 1024 * 1024
 
 
 def _lanes(n: int) -> int:

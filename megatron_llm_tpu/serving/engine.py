@@ -414,7 +414,8 @@ class InferenceEngine:
         # what a launch counts): ops/paged_kv.py's plan
         self._cache = paged_kv.plan(
             mcfg, cfg.block_size, cfg.num_slots, self._max_blocks_per_slot,
-            cfg.prefill_chunk, self.prefill_kernel, self.paged_kernel)
+            cfg.prefill_chunk, self.prefill_kernel, self.paged_kernel,
+            cfg.int8_kv_cache)
         # a model with no paged layer has no block to count: admission is
         # by slots (kv_blocks.BlockManager), and --serve_num_blocks,
         # which sizes a pool of pages, sizes nothing: the pool is the
@@ -561,8 +562,7 @@ class InferenceEngine:
                 # queued spills reference the abandoned pool; resident
                 # host entries and counters survive the restart
                 self.host_cache.on_pool_reset()
-        pages = self._cache.init_pools(self._num_blocks,
-                                       quantized=cfg.int8_kv_cache)
+        pages = self._cache.init_pools(self._num_blocks)
         blocks = BlockManager(
             self._num_blocks, cfg.block_size, cfg.num_slots,
             self._max_blocks_per_slot, prefix_cache=cfg.prefix_cache,
@@ -1519,6 +1519,8 @@ class InferenceEngine:
                 self._writable(st, s, bi)
         self._note_batch(st, disp, slots, decoding)
         disp.drafted = int(draft_lens.sum())
+        self._cache.account(disp, st.context_lens, vlens, K + 1,
+                            len(st.scheduler.active))
         disp.mark("build_inputs")
         # the key discipline is the plain decode step's (_split_keys):
         # exactly one split per decoding slot per step, so a sampled

@@ -207,6 +207,19 @@ DELTA_FIELDS = ("delta_rows_live", "delta_rows_moved", "delta_tokens",
 KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
              "kv_held_bytes", "kv_full_pages_held", "kv_live_tokens")
 
+# the paged walk (a model whose layers keep pages of K and V;
+# ``CachePlan.account``): live rows x layers whose attention walks such a
+# pool in the launch, on either path; and of those the walks that
+# multiplied in the pool's dtype (the kernel, where its own
+# ``ops/pallas/paged_attention.py::native_operands`` of the pool's dtype
+# and the queries' says so; 0 on the dense path, which widens what it
+# gathers, and under an int8 pool, whose scales make the operands fp32).
+# A latent pool's walks are MLA_FIELDS'.  Since PR 62 the VERIFY launch
+# asks ``account`` too, so every group above that counts by live rows
+# (the selection's, the states') is filled for kind "verify" as for
+# "decode", where it read 0 before
+WALK_FIELDS = ("walks", "walks_native")
+
 # a prefill chunk's output head (the engine's ``_run_prefill_chunk``): the
 # rows it multiplied by the ``[V, H]`` head: 1 for the chunk that ends
 # its request's context, whose last live row the first token is sampled
@@ -229,7 +242,7 @@ HOST_FIELDS = ("host_uploads", "host_reads", "compile_secs", "gc_secs")
 # ``as_dict()`` carries and ``totals()`` gives goes through it
 COUNTED_FIELDS = (MOE_FIELDS + DSA_FIELDS + MLA_FIELDS + SSM_FIELDS
                   + CONV_FIELDS + RETENTION_FIELDS + DELTA_FIELDS + KV_FIELDS
-                  + PREFILL_FIELDS + HOST_FIELDS)
+                  + WALK_FIELDS + PREFILL_FIELDS + HOST_FIELDS)
 
 # the compiled programs whose operations run inside a launch of each
 # kind (a last prefill chunk samples its first token in the same launch),

@@ -492,7 +492,7 @@ def _groups():
     return {name: getattr(lp, name) for name in (
         "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS",
         "CONV_FIELDS", "RETENTION_FIELDS", "DELTA_FIELDS", "KV_FIELDS",
-        "PREFILL_FIELDS", "HOST_FIELDS")}
+        "WALK_FIELDS", "PREFILL_FIELDS", "HOST_FIELDS")}
 
 
 @pytest.mark.parametrize("group", sorted(_groups()))
@@ -572,9 +572,11 @@ def test_every_counter_of_stats_is_the_sum_over_the_ring(family):
     # nothing else
     counted = {f.split("_")[0] for f in COUNTED_FIELDS if stats.get(f)}
     assert counted - {"prefill"} == {
-        "granite": {"moe", "ssm"}, "keye": {"moe", "dsa"},
-        "kanana": {"moe", "mla"}, "mellum": {"moe", "kv"},
-        "olmoe": {"moe"}, "mistral": set()}[family]
+        "granite": {"moe", "ssm", "walks"}, "keye": {"moe", "dsa", "walks"},
+        "kanana": {"moe", "mla"}, "mellum": {"moe", "kv", "walks"},
+        "olmoe": {"moe", "walks"}, "mistral": {"walks"}}[family]
+    # the CPU runs the dense path: no walk multiplied in the pool's dtype
+    assert stats["walks_native"] == 0
     assert stats["prefill_head_rows"] == stats["prefill_heads"] == len(reqs)
     assert moved >= 2
 

@@ -12,6 +12,7 @@ cached-prefix tail chunks starting mid-page, windows shorter than the
 chunk, and multi-q-block grids."""
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -1341,6 +1342,261 @@ def test_the_first_block_is_handed_across_grid_steps(case, two_page_blocks):
 
 
 # ---------------------------------------------------------------------------
+# the walk in the pool's dtype (PR 62): bf16 queries over a bf16 pool
+# multiply as they lie under an fp32 accumulator, a step's and a chunk's;
+# the probabilities meet the values as TWO bf16 terms in a chunk's block
+# of many rows (``_TWO_TERM_ROWS``, set to 16 for these small shapes) at
+# heads of 128 or less, and as one (the bits the walk always gave on the
+# chip, where an fp32 product is one pass of bf16 operands) in a decode
+# step's, at heads of 256 and where the rows are not whole bf16 tiles
+# ---------------------------------------------------------------------------
+
+def _rounded(x):
+    """``x`` rounded to bf16, as float32."""
+    return np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32))
+
+
+# rows a slot (1: the decode step), query heads, kv groups, a head's width,
+# a window, the share of keys a mask chooses, a chunk's q-block
+NATIVE = {
+    "decode_one_kv_group": dict(n=1, nh=4, g=1),
+    "decode_four_kv_groups": dict(n=1, nh=8, g=4),
+    "decode_eight_kv_groups_under_a_window": dict(n=1, nh=8, g=8, window=37),
+    "decode_heads_of_256": dict(n=1, nh=4, g=2, d=256),
+    "decode_heads_of_64_two_a_lane_row": dict(n=1, nh=8, g=4, d=64),
+    "decode_under_a_mask_of_chosen_keys": dict(n=1, nh=8, g=4, chosen=0.4),
+    "chunk_of_three_q_blocks": dict(n=24, nh=8, g=4, block_q=8),
+    "chunk_one_kv_group_under_a_window": dict(n=16, nh=2, g=1, window=21,
+                                              block_q=8),
+    "chunk_of_eight_kv_groups": dict(n=16, nh=16, g=8, block_q=8),
+    "chunk_whose_rows_are_half_a_tile": dict(n=16, nh=8, g=8, block_q=8),
+    "chunk_heads_of_256": dict(n=16, nh=4, g=2, d=256, block_q=8),
+    "chunk_heads_of_64_two_a_lane_row": dict(n=16, nh=8, g=4, d=64,
+                                             block_q=8),
+    "chunk_under_a_mask_of_chosen_keys": dict(n=16, nh=8, g=4, chosen=0.4,
+                                              block_q=8),
+    "verify_step_of_five_rows": dict(n=5, nh=8, g=8, valid=[5, 0, 3]),
+}
+
+
+def _native_walk(name, monkeypatch):
+    """-> (the walk's output over a bf16 pool, float32 [S, n, nh, d]; the
+    same attention in float64 over the same bf16 numbers; the live rows
+    [S, n]).  Three slots of 70, 9 and 41 cached tokens in pages of 8,
+    blocks of four pages."""
+    case = dict(dict(d=128, window=None, chosen=None, block_q=None,
+                     valid=None), **NATIVE[name])
+    n, nh, g, d, window = (case[k] for k in ("n", "nh", "g", "d", "window"))
+    monkeypatch.setattr(pa, "_BLOCK_TOKENS", 4 * BS)
+    monkeypatch.setattr(pa, "_TWO_TERM_ROWS", 16)
+    rng = np.random.default_rng(sum(map(ord, name)))
+    ctx = np.asarray([70, 9, 41], np.int32)
+    valid = np.asarray(case["valid"] or [n] * 3, np.int32)
+    Mt = 12
+    q, k_lin, v_lin, kp, vp, bt = _build_prefill_case(
+        rng, 3, Mt, BS, g, nh, d, ctx, n)
+    q, k_lin, v_lin, kp, vp = (_rounded(a) for a in (q, k_lin, v_lin, kp, vp))
+    L = Mt * BS
+    kpos, qpos = np.arange(L), ctx[:, None] + np.arange(n)[None, :]
+    sees = kpos[None, None, :] <= qpos[:, :, None]              # [S, n, L]
+    if window is not None:
+        sees &= kpos[None, None, :] > qpos[:, :, None] - window
+    b16 = [jnp.asarray(a, jnp.bfloat16) for a in (q, kp, vp)]
+    tables = (jnp.asarray(bt), jnp.asarray(ctx), jnp.asarray(valid))
+    scale = 1.0 / math.sqrt(d)
+    if case["chosen"] is not None:
+        # a query's own key is always chosen: no live row attends nothing
+        chosen = rng.random((3, n, L)) < case["chosen"]
+        chosen |= kpos[None, None, :] == qpos[:, :, None]
+        sees &= chosen
+        per = pa._pages_per_block(BS, g, d, jnp.bfloat16, Mt) * BS
+        mask = np.where(chosen, 0.0, pa.NEG_INF).astype(np.float32)
+        mask = mask.reshape(3, n, L // per, per).transpose(0, 2, 1, 3)
+        if n == 1:      # lane c of a block is key c // g, group c % g
+            mask = np.repeat(mask[:, :, 0], g, axis=-1)
+        got = pa._walk_call(
+            b16[0], b16[1], b16[2], *tables, None, None, scale=scale,
+            window=None, block_q=case["block_q"] or n,
+            name="paged_attention_prefill_masked", mask=jnp.asarray(mask))
+    elif d == 64:
+        # two heads a lane row, as ``PagedKVCache.attend`` hands them on
+        pools = [a.reshape(a.shape[:2] + (g // 2, 128)) for a in b16[1:]]
+        wide = paged_kv._in_own_part(b16[0], g // 2, 2)
+        entry = (pa.paged_attention_decode if n == 1 else
+                 functools.partial(pa.paged_attention_prefill,
+                                   block_q=case["block_q"]))
+        got = entry(wide[:, 0] if n == 1 else wide, *pools, *tables[:2],
+                    valid_lens=tables[2], softmax_scale=scale)
+        got = paged_kv._own_part(got[:, None] if n == 1 else got, g // 2, 2)
+    elif n == 1:
+        got = pa.paged_attention_decode(
+            b16[0][:, 0], *b16[1:], *tables[:2], valid_lens=tables[2],
+            sliding_window=window)[:, None]
+    else:
+        got = pa.paged_attention_prefill(
+            *b16, *tables[:2], valid_lens=tables[2], sliding_window=window,
+            block_q=case["block_q"])
+    assert got.dtype == jnp.bfloat16
+    qg = q.astype(np.float64).reshape(3, n, g, nh // g, d)
+    sc = np.einsum("sjgpd,slgd->sjgpl", qg, k_lin.astype(np.float64)) * scale
+    sc = np.where(sees[:, :, None, None, :], sc, -np.inf)
+    pr = np.exp(sc - sc.max(axis=-1, keepdims=True))
+    want = np.einsum("sjgpl,slgd->sjgpd", pr / pr.sum(axis=-1, keepdims=True),
+                     v_lin.astype(np.float64)).reshape(3, n, nh, d)
+    return (np.asarray(got.astype(jnp.float32)), want,
+            np.arange(n)[None, :] < valid[:, None])
+
+
+def _two_terms(name):
+    """Whether ``pa._value_terms`` gives the case two terms: a q-block of
+    whole bf16 tiles of rows a kv group, 16 or more of them here, at
+    heads of 128 or less (64-wide heads lie two a row of 128 lanes)."""
+    case = dict(dict(d=128, block_q=1), **NATIVE[name])
+    rows = case["block_q"] * case["nh"] // (case["g"] // (128 // case["d"]
+                                                         or 1))
+    return case["d"] <= 128 and rows >= 16 and rows % 16 == 0
+
+
+@pytest.mark.parametrize("name", sorted(NATIVE))
+def test_the_walk_in_bf16_keeps_sixteen_bits_of_the_probabilities(
+        name, monkeypatch):
+    """Against the same attention in float64 over the same bf16 numbers:
+    the scores are exact products under an fp32 accumulator, and with the
+    probabilities in two bf16 terms what is left is the output's own
+    rounding, so all but a few elements in a hundred ARE the reference
+    rounded to bf16, to the bit.  One bf16 term (a decode step, heads of
+    256, the verify step's five rows a group, half a tile of rows) leaves
+    a third of them a unit off, inside the loose tolerance a bf16 kernel
+    is usually held to: this pins which form a shape takes."""
+    pa._walk_kernel.clear_cache()
+    try:
+        got, want, live = _native_walk(name, monkeypatch)
+    finally:
+        pa._walk_kernel.clear_cache()
+    np.testing.assert_allclose(got[live], want[live], atol=8e-3, rtol=8e-3)
+    equal = np.mean(got[live] == _rounded(want[live]))
+    assert equal > 0.98 if _two_terms(name) else 0.5 < equal < 0.9, equal
+    assert not got[~live.any(axis=1)].any()     # a slot with no token
+
+
+@pytest.mark.parametrize("name", ["chunk_of_eight_kv_groups",
+                                  "chunk_of_three_q_blocks"])
+def test_one_bf16_term_of_the_probabilities_is_told_from_two(name,
+                                                             monkeypatch):
+    """The same chunks with ``p`` rounded to bf16 ONCE: inside the loose
+    tolerance, and far from the share of bit-equal elements the two-term
+    walk is held to above."""
+    assert _two_terms(name)
+    monkeypatch.setattr(pa, "_value_terms", lambda *shape: 1)
+    pa._walk_kernel.clear_cache()
+    try:
+        got, want, live = _native_walk(name, monkeypatch)
+    finally:
+        pa._walk_kernel.clear_cache()
+    np.testing.assert_allclose(got[live], want[live], atol=8e-3, rtol=8e-3)
+    assert np.mean(got[live] == _rounded(want[live])) < 0.9
+
+
+def test_the_plan_counts_no_native_walk_under_an_int8_pool(model_and_params):
+    """The plan's own count, as the engine's launches ask it (the engine
+    hands it its ``int8_kv_cache``): three live rows of four, two layers,
+    and an int8 pool's walks multiply in fp32."""
+    from megatron_llm_tpu.serving.loop_profiler import LoopProfiler
+
+    d = LoopProfiler().begin()
+    paged_kv.plan(model_and_params[0].cfg, BS, 4, 8, 16, "pallas", "pallas",
+                  int8_pool=True).account(
+        d, np.asarray([5, 0, 9, 30]), np.asarray([1, 0, 1, 1]), 1, 3)
+    assert (d.walks, d.walks_native) == (6, 0)
+
+
+@pytest.mark.parametrize("case", ["kernels_on", "kernels_off", "verify_step"])
+def test_the_engine_counts_the_walks_that_multiplied_in_the_pools_dtype(
+        case, model_and_params):
+    """``walks`` is live rows x layers of every launch on either path;
+    ``walks_native`` all of them where the kernel walks a pool of the
+    queries' dtype (a chunk, a decode step, the verify step), none on the
+    dense path."""
+    from megatron_llm_tpu.serving import (EngineConfig, InferenceEngine,
+                                          SamplingParams)
+
+    model, params = model_and_params
+    kernel = "off" if case == "kernels_off" else "on"
+    eng = InferenceEngine(model, params, EngineConfig(
+        num_slots=2, block_size=BS, max_model_len=64, prefill_chunk=16,
+        paged_kernel=kernel, prefill_kernel=kernel,
+        speculative=case == "verify_step", draft_k=2))
+    reqs = [eng.submit([(7 * i + j) % 60 + 1 for j in range(n)],
+                       SamplingParams(max_new_tokens=3, temperature=0.0))
+            for i, n in enumerate((21, 9))]
+    while any(r.finish_reason is None for r in reqs):
+        assert eng.step()
+    stats, records = eng.stats(), eng.loop_profiler.records()
+    # a chunk is one live row, a step one a decoding request, two layers
+    assert stats["walks"] == sum(r.walks for r in records) >= 2 * (3 + 2)
+    assert {r.kind for r in records} == {
+        "prefill", "verify" if case == "verify_step" else "decode"}
+    assert all(r.walks > 0 for r in records)
+    assert stats["walks_native"] == (
+        stats["walks"] if case in ("kernels_on", "verify_step") else 0)
+
+
+def _kernel_equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_equations(sub)
+
+
+@pytest.mark.parametrize("name", ["decode", "decode_window", "chunk",
+                                  "chunk_window", "decode_int8",
+                                  "chunk_int8"])
+def test_the_walk_multiplies_in_the_pools_dtype(name):
+    """The kernel's jaxpr over a bf16 pool (blocks of 512 keys of 4 kv
+    heads of 128): every ``dot_general`` takes bf16 operands and gives
+    fp32, and no block of K or V (whole ``[2048, 128]``, by keys ``[512,
+    4, 128]`` or one group's ``[512, 128]``) is converted to fp32.  Over
+    an int8 pool what was there before: fp32 operands, each block
+    widened once."""
+    fn, args = _walk_shapes(name)
+    eqns = list(_kernel_equations(jax.make_jaxpr(fn)(*args).jaxpr))
+    dots = [tuple(str(v.aval.dtype) for v in e.invars)
+            + (str(e.outvars[0].aval.dtype),)
+            for e in eqns if e.primitive.name == "dot_general"]
+    widened = [e.invars[0].aval for e in eqns
+               if e.primitive.name == "convert_element_type"
+               and e.params["new_dtype"] == jnp.float32
+               and e.invars[0].aval.shape[-1:] == (128,)
+               and e.invars[0].aval.size >= 512 * 128]
+    # a decode step's block is laid out four times, a chunk's twice, each
+    # with its two products (a chunk's a kv group)
+    assert len(dots) >= 4
+    if "int8" in name:
+        assert set(dots) == {("float32",) * 3}
+        # (and a chunk's queries, which fp32 keys must meet in fp32)
+        assert {str(a.dtype) for a in widened
+                if a.shape[-2:] == (8, 128)} == {"int8"}
+        return
+    assert set(dots) == {("bfloat16", "bfloat16", "float32")}
+    assert widened == []
+    # the rows that meet the values (``p x v`` contracts the keys, its
+    # left operand's second dimension with its right's first) against the
+    # rows that met the keys: twice them for a chunk, whose probabilities
+    # go stacked in two terms, and the same for a decode step's one
+    rows = {kind: {e.invars[0].aval.shape[0] for e in eqns
+                   if e.primitive.name == "dot_general"
+                   and e.params["dimension_numbers"][0] == contracts}
+            for kind, contracts in (("q x k", ((1,), (1,))),
+                                    ("p x v", ((1,), (0,))))}
+    (met_keys,), (met_values,) = rows["q x k"], rows["p x v"]
+    assert met_values == (2 if "chunk" in name else 1) * met_keys
+
+
+# ---------------------------------------------------------------------------
 # the walk WITHOUT a mask traces the kernel it traced before (PR 57: the
 # families' program fingerprints run on the CPU's dense path and never saw
 # a kernel; a mask, like a window or a latent pool, is a trace-time fact
@@ -1379,15 +1635,23 @@ def _walk_shapes(name):
 
 # what these print since PR 48 (the walk's fetches); PR 57 added the mask
 # and left them as they were.  A PR that MEANS to change the unmasked walk
-# records the new ones and says so
+# records the new ones and says so.  PR 62 meant to: over a bf16 pool the
+# walk multiplies in the pool's dtype (a chunk's queries and keys are no
+# longer widened, no walk's values are; a chunk's probabilities go in two
+# bf16 terms and its kernel asks ``_TWO_TERMS_VMEM_LIMIT``), so the four
+# bf16 walks are re-recorded; a chunk's rows are cut by kv group once a
+# grid step and no longer once a block, which moves equations of the int8
+# chunk too (its arithmetic is what it was: fp32 operands, the test
+# above); the int8 step and the latent walk are the ones PR 48 recorded,
+# letter for letter
 WALKS_TRACED = {
-    "decode": "16db4304c1057fe7",
-    "decode_window": "a70da5ea1f17f85a",
+    "decode": "e1cc87579f76917b",
+    "decode_window": "903be56ce90465c7",
     "decode_int8": "fbb9b1183fe730bb",
     "decode_latent": "70b89cd7c5203819",
-    "chunk": "7479aafa9b3c88c2",
-    "chunk_window": "48cfbab705730bbb",
-    "chunk_int8": "2524827b255e897b",
+    "chunk": "fd73f8961c985883",
+    "chunk_window": "dc368a001ca5009f",
+    "chunk_int8": "96c238ac3a36f65f",
 }
 
 

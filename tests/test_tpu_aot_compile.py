@@ -420,6 +420,18 @@ CASES = {
 
 SAMPLER = "sampler_64_slots_50304_logits"
 SELECTION = "dsa_kernels_by_name"
+WALK_OPERANDS = "paged_walk_operands"
+# the shared walk over a bf16 pool of K and V (not the int8 pools, whose
+# scales make the operands fp32, nor a latent pool, whose walks are their
+# own lines)
+BF16_WALKS = sorted(
+    case for case in CASES
+    if case.startswith(("paged_", "dsa_selected_")) and "int8" not in case
+    and "latent" not in case)
+# bytes of HLO temporaries the parent's programs took (PR 61's tree,
+# compiled here): the walk's kernels hold theirs in VMEM and take none,
+# the selection's chunk its scores and its mask
+PARENT_TEMP_BYTES = {"dsa_selected_prefill_chunk_512": 138541056}
 ENGINE_TABLES = "engine_tables_tiny_sparse_model"
 GRANITE = "granite_cell_programs"
 NEMOTRON = "nemotron_cell_programs"
@@ -428,6 +440,37 @@ LFM2 = "lfm2_cell_programs"
 BRUMBY = "brumby_cell_programs"
 QWEN3_NEXT = "qwen3_next_cell_programs"
 GLM5 = "glm5_cell_programs"
+
+
+def _walk_operands(lowered_text, shapes):
+    """What the Mosaic kernels of a lowered program multiply and widen,
+    read off their own text (a ``tpu_custom_call`` carries its kernel as
+    MLIR bytecode): the operand types of every matmul, and every block of
+    K or V (a bf16 vector of 256 rows or more whose last dimension is the
+    pool's) that is extended to fp32."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    width = max(shape[-1] for shape, dtype in shapes
+                if len(shape) == 4 and dtype == BF16)
+    matmuls, widened = set(), []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           lowered_text):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True  # the text is all we read
+        with ctx:
+            text = str(ir.Module.parse(base64.b64decode(body)))
+        matmuls |= {" x ".join(t.rsplit("x", 1)[1] for t in m)
+                    for m in re.findall(
+            r'tpu\.matmul"[^\n]*: \(vector<([^>]+)>, vector<([^>]+)>,',
+            text)}
+        for dims in re.findall(
+                rf'arith\.extf"[^\n]*: \(vector<([0-9x]+)x{width}xbf16>\)',
+                text):
+            if np.prod([int(n) for n in dims.split("x")]) >= 256:
+                widened.append(f"{dims}x{width}")
+    return {"matmuls": sorted(matmuls), "widened": widened}
 
 
 def _compile_all(only: str = ""):
@@ -456,8 +499,14 @@ def _compile_all(only: str = ""):
         args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
                 for shape, dtype in shapes]
         try:
-            text = jax.jit(fn).lower(*args).compile().as_text()
+            lowered = jax.jit(fn).lower(*args)
+            compiled = lowered.compile()
+            text = compiled.as_text()
             found[case] = "tpu_custom_call" in text
+            if case in BF16_WALKS:
+                found.setdefault(WALK_OPERANDS, {})[case] = dict(
+                    _walk_operands(lowered.as_text(), shapes),
+                    temp_bytes=compiled.memory_analysis().temp_size_in_bytes)
             if case.startswith("dsa_"):
                 found.setdefault(SELECTION, {})[case] = sorted(re.findall(
                     r"^\s*(?:ROOT )?%?((?:dsa|paged_attention|mla_attention)_\w+?)"
@@ -804,6 +853,41 @@ def test_kernel_compiles_for_v5e(case, compiled):
     assert compiled[case] is True, (
         f"{case}: compiled without its Mosaic kernel" if not compiled[case]
         else f"{case}: the TPU compiler refused it: {compiled[case]}")
+
+
+@pytest.mark.parametrize("case", BF16_WALKS)
+def test_the_walk_multiplies_in_the_pools_dtype_for_v5e(case, compiled):
+    """PR 62: over a bf16 pool every matmul of the walk's kernel (and of
+    the selection's two beside it) takes bf16 operands, no block of K or
+    V is extended to fp32 in the chunk's text or the step's, and the
+    program takes no more HLO temporaries than the parent's did."""
+    found = compiled[WALK_OPERANDS][case]
+    assert found["matmuls"] == ["bf16 x bf16"], found
+    assert found["widened"] == [], found
+    assert found["temp_bytes"] <= PARENT_TEMP_BYTES.get(case, 0), found
+
+
+# the paged chunks whose q-block is 512 rows a kv group or more: with the
+# probabilities in two terms their kernel's stack on the chip (Mosaic's,
+# which ``temp_size_in_bytes`` above does not see) is 18.92 MiB (16.92 at
+# 8 kv heads of 64), over the 16 a kernel gets unasked
+TWO_TERM_STACKS = ["paged_prefill_chunk_512_4_kv_heads",
+                   "paged_prefill_chunk_512_8_kv_heads_of_64",
+                   "paged_window_prefill_chunk_512"]
+
+
+@pytest.mark.parametrize("case", TWO_TERM_STACKS)
+def test_a_two_term_chunks_stack_is_held_to_its_recorded_ceiling(
+        case, compiled):
+    """PR 62: a paged chunk that carries ``p`` in two terms asks the
+    compiler for ``_TWO_TERMS_VMEM_LIMIT`` and for no more, so the ask is
+    the ceiling: a block whose temporaries grow past it is refused HERE
+    ("Scoped allocation with size ... exceeded scoped vmem limit").  Who
+    raises the ceiling records the new stack beside it."""
+    from megatron_llm_tpu.ops.pallas import paged_attention as pa
+
+    assert pa._TWO_TERMS_VMEM_LIMIT == 20 * 1024 * 1024
+    assert compiled[case] is True, compiled[case]
 
 
 def test_selection_is_one_kernel_a_name_for_v5e(compiled):
