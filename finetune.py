@@ -2,7 +2,7 @@
 """Pretrain / finetune / instruct-tune GPT-family models on TPU.
 
 Reference: ``/root/reference/finetune.py`` — the fork's primary entry
-point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,glm5,trinity,nemotron_h,lfm2,brumby,qwen3_next,qwen2}``
+point: ``--model_name={gpt,llama,llama2,codellama,falcon,mistral,mixtral,olmoe,keye,mellum,kanana,glm5,trinity,nemotron_h,lfm2,brumby,qwen3_next,ouro,qwen2}``
 selects architecture defaults, data comes from packed GPT or instruction
 datasets, and the loop runs under 3-way parallelism.
 
@@ -220,6 +220,14 @@ MODEL_DEFAULTS = {
                                     "gated_delta", "attention"],
                        layernorm_epsilon=1e-6,
                        hidden_dropout=0.0, attention_dropout=0.0),
+    # Ouro-2.6B (model_type ouro): a llama-style stack of four norms a
+    # layer that runs ``loop_steps`` times over the same weights, the
+    # final norm after each pass and cache planes of its own a pass
+    "ouro": dict(position_embedding_type="rotary", glu_activation="swiglu",
+                 use_rms_norm=True, use_bias=False, tie_embed_logits=False,
+                 sublayer_output_norm=True, loop_steps=4, kv_channels=128,
+                 rope_theta=1e6, layernorm_epsilon=1e-6,
+                 hidden_dropout=0.0, attention_dropout=0.0),
     "qwen2": dict(position_embedding_type="rotary", glu_activation="swiglu",
                   use_rms_norm=True, use_bias=False, add_qkv_bias=True,
                   tie_embed_logits=False, rope_theta=1e6,
@@ -466,6 +474,9 @@ _CKPT_ARG_MAP = {
     # two leaves a layer
     "attention_output_gate": "attention_output_gate",
     "sublayer_output_norm": "sublayer_output_norm",
+    # ouro's passes: the exit gate is a leaf, and the passes are forward
+    # math
+    "loop_steps": "loop_steps",
     # qwen2's QKV-only bias changes the param tree like the MoE fields do
     "add_qkv_bias": "add_qkv_bias",
     # gemma's embedding normalizer changes forward math, not the tree

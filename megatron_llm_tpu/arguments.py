@@ -177,6 +177,11 @@ def _add_network_size_args(parser):
     g.add_argument("--sublayer_output_norm", action="store_true",
                    help="four norms a layer: each sublayer's output is "
                         "normed as well as its input, x + norm(f(norm(x)))")
+    g.add_argument("--loop_steps", type=int, default=1,
+                   help="a looped stack (ouro's total_ut_steps): the layers "
+                        "run this many times over the same weights, the "
+                        "final norm after each pass, and a token holds "
+                        "num_layers x loop_steps cache planes")
     g.add_argument("--layernorm_epsilon", type=float, default=1e-5)
     g.add_argument("--use_rms_norm", action="store_true")
     g.add_argument("--use_post_ln", action="store_true")
@@ -1097,6 +1102,7 @@ def transformer_config_from_args(args, model_name: Optional[str] = None
             getattr(args, "attention_output_gate", False)),
         sublayer_output_norm=bool(
             getattr(args, "sublayer_output_norm", False)),
+        loop_steps=int(getattr(args, "loop_steps", 1) or 1),
         layer_types=(
             tuple(args.layer_types) if getattr(args, "layer_types", None)
             else pattern_layer_types(args.hybrid_override_pattern)

@@ -189,6 +189,7 @@ PARALLEL_ATTN = "parallel_attn"
 POST_LN = "post-LN (use_post_ln)"
 GATE = "an attention output gate (attention_output_gate)"
 OUTPUT_NORMS = "norms on both sublayers' outputs (sublayer_output_norm)"
+LOOPED = "a stack run several times (loop_steps > 1)"
 ROPE_TYPES = "layer types that do not rotate (rope_layer_types)"
 OTHER_TYPES = "layer types other than 'mamba', 'attention' and 'moe'"
 CONV_OTHER_TYPES = "layer types other than 'conv' and 'attention'"
@@ -223,6 +224,7 @@ HAS = {
     POST_LN: lambda c: c.use_post_ln,
     GATE: lambda c: c.attention_output_gate,
     OUTPUT_NORMS: lambda c: c.sublayer_output_norm,
+    LOOPED: lambda c: c.loop_steps > 1,
     ROPE_TYPES: lambda c: c.rope_layer_types is not None,
     OTHER_TYPES: lambda c: bool(set(c.layer_types or ())
                                 - {"mamba", "attention", "moe"}),
@@ -258,6 +260,8 @@ RUNS_WITH = (
     (STATE_SPACE, (OTHER_TYPES, BIASES, PARALLEL_ATTN, POST_LN, LATENT,
                    VERIFY_STEP, INT8_POOL, HOST_TIER, PREEMPTION,
                    MODEL_PARALLEL)),
+    (LOOPED, (TYPED, LATENT, SPARSE, EXPERTS, TRAINING, MODEL_PARALLEL,
+              VERIFY_STEP, INT8_POOL, HOST_TIER)),
     (GATE, (LATENT, TRAINING, MODEL_PARALLEL, VERIFY_STEP, INT8_POOL,
             HOST_TIER)),
     (OUTPUT_NORMS, (ONE_SUBLAYER, PARALLEL_ATTN, POST_LN, TRAINING,
@@ -372,6 +376,28 @@ TAILS = {
     (SHORT_CONV, PREFIX_CACHE):
         " adopts nothing (a convolution layer's columns at a prefix's end "
         "are not kept)",
+    (LOOPED, TYPED):
+        " (a pass's planes of two page groups, or a state carried from "
+        "pass to pass, are held to nothing)",
+    (LOOPED, LATENT):
+        " (a latent pool a pass is held to nothing)",
+    (LOOPED, SPARSE):
+        " (an indexer's keys a pass are held to nothing)",
+    (LOOPED, EXPERTS):
+        " (the published stack is dense; a router asked once a pass is "
+        "held to nothing)",
+    (LOOPED, TRAINING):
+        " (the published objective weighs every pass's loss by the exit "
+        "distribution with an entropy term: it is not built)",
+    (LOOPED, MODEL_PARALLEL):
+        " (under pipeline stages a token goes round the ring once a pass: "
+        "the schedule is not built)",
+    (LOOPED, VERIFY_STEP):
+        " (a draft's rows in every pass's planes are held to nothing)",
+    (LOOPED, INT8_POOL):
+        " (no int8 plane of a pass is held to the reference)",
+    (LOOPED, HOST_TIER):
+        " (a page spilled with every pass's planes is held to nothing)",
     (GATE, LATENT):
         " (latent_attention has no fourth projection and no product)",
     (GATE, TRAINING): " (no backward through the gate is held to anything)",
@@ -514,6 +540,15 @@ class TransformerConfig:
     # and ``mlp_output_norm``; ``post_attention_norm`` stays what it is
     # everywhere, the norm BEFORE the MLP
     sublayer_output_norm: bool = False
+    # a LOOPED stack (ouro's ``total_ut_steps``): the ``num_layers`` layers
+    # run ``loop_steps`` times over the SAME weights, the final norm after
+    # EACH pass, and pass t attends pass t's keys and values alone: a
+    # token holds ``cache_layers`` planes.  After each pass an exit gate
+    # (``exit_gate`` of the stack's params) reads the normed stream;
+    # ``early_exit_threshold`` is the cumulative exit mass a token would
+    # leave at, and only 1.0 (every token runs every pass) is built
+    loop_steps: int = 1
+    early_exit_threshold: float = 1.0
 
     # --- dropout / init ---
     hidden_dropout: float = 0.1
@@ -802,6 +837,16 @@ class TransformerConfig:
                     "gated delta-rule layers need positive delta sizes, "
                     "delta_conv_taps >= 2 and whole key heads of value "
                     "heads")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps ({self.loop_steps}) is the times "
+                             "the stack runs: at least 1")
+        if self.early_exit_threshold != 1.0:
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold} is not "
+                "implemented: only 1.0, every token running every pass, is "
+                "(a token that leaves at an earlier pass writes no keys "
+                "into the later passes' planes, which the tokens after it "
+                "would read)")
         if self.moe_router_experts is not None or self.moe_experts_first:
             routed = self.moe_router_experts or self.num_experts
             if self.num_experts <= 1 or not (
@@ -908,6 +953,14 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.kv_channels
+
+    @property
+    def cache_layers(self) -> int:
+        """How many cache planes (a layer's keys and values, or whatever
+        its layer keeps) a token holds: one a layer a PASS.  The one place
+        that says so: whoever sizes a cache reads this, not
+        ``num_layers``."""
+        return self.num_layers * self.loop_steps
 
     @property
     def expert_hidden_size(self) -> int:

@@ -131,7 +131,8 @@ SCOPES = ("sampler", "kv_write", "moe_route", "moe_dispatch", "moe_experts",
           "delta_step", "delta_norm",
           "attn_gate", "post_attn_norm", "post_mlp_norm",
           "attention", "mlp",
-          "embedding", "lm_head", "transformer_layer")
+          "embedding", "lm_head", "transformer_layer",
+          "loop_pass_norm", "loop_pass")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _SCOPE_CORE = re.compile(r"^(?:\w+\()*([\w.\-]+)\)*$")
 _NAME = re.compile(r"^[A-Za-z_][\w.\-]*$")

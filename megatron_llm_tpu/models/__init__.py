@@ -17,6 +17,7 @@ from megatron_llm_tpu.models.glm5 import Glm5Model, glm5_config
 from megatron_llm_tpu.models.trinity import TrinityModel, trinity_config
 from megatron_llm_tpu.models.lfm2 import Lfm2Model, lfm2_config
 from megatron_llm_tpu.models.brumby import BrumbyModel, brumby_config
+from megatron_llm_tpu.models.ouro import OuroModel, ouro_config
 from megatron_llm_tpu.models.qwen2 import Qwen2Model, qwen2_config
 from megatron_llm_tpu.models.gemma import GemmaModel, gemma_config
 from megatron_llm_tpu.models.gpt_neox import GPTNeoXModel, gpt_neox_config
@@ -69,6 +70,7 @@ MODEL_REGISTRY = {
     "nemotron_h": _nemotron_h,
     "lfm2": Lfm2Model,
     "brumby": BrumbyModel,
+    "ouro": OuroModel,
     "qwen3_next": _qwen3_next,
     "qwen2": Qwen2Model,
     "gemma": GemmaModel,

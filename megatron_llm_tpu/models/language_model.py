@@ -186,6 +186,10 @@ def transformer_stack_specs(stack_params, cfg=None) -> dict:
         # a sparse model's leading dense layers, stacked apart
         specs["dense_layers"] = transformer_layer_specs(
             stack_params["dense_layers"], cfg=cfg)
+    if "exit_gate" in stack_params:
+        # a looped stack's exit gate: a vector of the hidden width and a
+        # scalar, on every device whole
+        specs["exit_gate"] = {"kernel": (None,), "bias": ()}
     return specs
 
 
@@ -431,7 +435,9 @@ def flops_per_token(cfg: TransformerConfig, seq_len: Optional[int] = None) -> fl
     language_model.py:370-384; 6ND approximation + attention term)."""
     s = seq_len or cfg.seq_length
     h = cfg.hidden_size
-    L = cfg.num_layers
+    # layer APPLICATIONS a token: a looped stack runs its layers
+    # ``loop_steps`` times
+    L = cfg.num_layers * cfg.loop_steps
     ffn = cfg.ffn_hidden_size
     ng = cfg.num_query_groups
     nh = cfg.num_attention_heads

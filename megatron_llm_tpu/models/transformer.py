@@ -24,7 +24,10 @@ TPU re-design highlights:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
+import itertools
 import math
 from typing import Optional
 
@@ -314,6 +317,16 @@ def init_stack_params(key, cfg: TransformerConfig, dtype, layer_type: str = "enc
     }
     if D:
         params["dense_layers"] = stack(keys[:D], False)
+    if cfg.loop_steps > 1:
+        # a looped stack's exit gate, a linear layer with a bias over the
+        # stream each pass's final norm leaves (hidden_size + 1
+        # parameters); its key is folded from the stack's, so the layers
+        # draw what they would without it
+        params["exit_gate"] = {
+            "kernel": (cfg.init_method_std * jax.random.normal(
+                jax.random.fold_in(key, cfg.num_layers),
+                (cfg.hidden_size,), jnp.float32)).astype(dtype),
+            "bias": jnp.zeros((), dtype)}
     for kind in cfg.mixer_counts:
         init, at = init_attention_params, 0
         if kind == "mamba":
@@ -1339,6 +1352,7 @@ def transformer_stack(
     kv_caches=None,
     encoder_output: Optional[jax.Array] = None,
     enc_dec_mask: Optional[jax.Array] = None,
+    return_exit: bool = False,
 ):
     """Scan the layer body over layer-stacked params (reference
     ``ParallelTransformer.forward``, transformer.py:1188-1282) and apply the
@@ -1363,9 +1377,17 @@ def transformer_stack(
     (``cfg.mixer_index``), in the layers before the scan, in the scan
     and in the serving loop alike, a leading dense layer too; so are
     the three kinds of a stack of one sublayer a layer
-    (``cfg.one_sublayer``), its expert layers among them."""
+    (``cfg.one_sublayer``), its expert layers among them.
+
+    A LOOPED stack (``cfg.loop_steps`` T > 1) runs all of that T times
+    over the same parameters, the final norm after EACH pass, whose
+    output is the next pass's input; with ``kv_caches`` pass t's layer i
+    threads cache ``t * L + i`` (``cfg.cache_layers`` of them), so a pass
+    attends its own keys and values alone.  ``return_exit`` (the scan
+    path): ``(h, gates)``, gates ``[T, b, s]`` float32 the exit gate's
+    sigmoid over each pass's normed stream (``exit_distribution``)."""
     layers = stack_params["layers"]
-    L = cfg.num_layers
+    L, T = cfg.num_layers, cfg.loop_steps
     said = train and refusal(cfg, (TRAINING,))
     if said:
         raise NotImplementedError(said)
@@ -1430,6 +1452,23 @@ def transformer_stack(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         )
 
+    def final_norm(h):
+        return apply_norm(
+            h, stack_params["final_norm"], cfg.normalization,
+            eps=cfg.layernorm_epsilon, fp32_compute=cfg.norm_in_fp32,
+        )
+
+    def looped(*names):
+        """The named scopes ``names`` of a looped stack, one inside the
+        other: ``loop_pass`` (which the program tables know) around
+        ``pass_<t>``, ``loop_pass_norm`` for the norm that ends EACH
+        pass.  None for a stack run once: its programs are what they
+        were."""
+        scopes = contextlib.ExitStack()
+        for name in names if T > 1 else ():
+            scopes.enter_context(jax.named_scope(name))
+        return scopes
+
     if kv_caches is not None:
         # inference path: python loop so each layer threads its own cache
         # (MoE aux, when present, is irrelevant at decode time and dropped)
@@ -1442,7 +1481,12 @@ def transformer_stack(
         if moe_on and not cfg.one_sublayer:
             sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items()
                                         if k != "experts"}}
-        for i in range(L):
+
+        @functools.cache
+        def layer_of(i):
+            """(layer i's parameters, its index among the layers with
+            experts or None): sliced when its first pass reaches it, and
+            the same slices for every later pass."""
             sparse = moe_on and i >= D and not cfg.one_sublayer
             moe_layer = i - D if sparse else None
             layer_p = jax.tree_util.tree_map(
@@ -1459,16 +1503,20 @@ def transformer_stack(
                 moe_layer = at if whole else moe_layer
                 layer_p[kind] = {**jax.tree_util.tree_map(
                     lambda p: p[at], own), **whole}
-            h, c, _ = transformer_layer(
-                h, layer_p, cfg, rng_key=None, train=False,
-                kv_cache=kv_caches[i], moe_layer=moe_layer,
-                layer_type=period[i % P], **layer_kw,
-            )
+            return layer_p, moe_layer
+
+        for t, i in itertools.product(range(T), range(L)):
+            layer_p, moe_layer = layer_of(i)
+            with looped("loop_pass", f"pass_{t}"):
+                h, c, _ = transformer_layer(
+                    h, layer_p, cfg, rng_key=None, train=False,
+                    kv_cache=kv_caches[t * L + i], moe_layer=moe_layer,
+                    layer_type=period[i % P], **layer_kw,
+                )
             new_caches.append(c)
-        h = apply_norm(
-            h, stack_params["final_norm"], cfg.normalization,
-            eps=cfg.layernorm_epsilon, fp32_compute=cfg.norm_in_fp32,
-        )
+            if i == L - 1:
+                with looped("loop_pass_norm"):
+                    h = final_norm(h)
         return h, new_caches
 
     # the layers before the first period boundary that the scan starts
@@ -1519,16 +1567,40 @@ def transformer_stack(
                 lambda a: (a[before.count(k):] if first else a).reshape(
                     ((L - first) // P, period.count(k)) + a.shape[1:]), m)
             for k, m in mixers.items()})
-    init_carry = (x, aux_head) if moe_on else x
-    carry = init_carry
-    if first < L:
-        carry, _ = jax.lax.scan(body, init_carry, scanned)
-    h, moe_aux = carry if moe_on else (carry, None)
-    h = apply_norm(
-        h, stack_params["final_norm"], cfg.normalization,
-        eps=cfg.layernorm_epsilon, fp32_compute=cfg.norm_in_fp32,
-    )
+    carry = (x, aux_head) if moe_on else x
+    gates = []
+    for t in range(T):
+        if first < L:
+            with looped("loop_pass", f"pass_{t}"):
+                carry, _ = jax.lax.scan(body, carry, scanned)
+        h, moe_aux = carry if moe_on else (carry, None)
+        with looped("loop_pass_norm"):
+            h = final_norm(h)
+        carry = (h, moe_aux) if moe_on else h
+        if return_exit:
+            gates.append(exit_gate(h, stack_params["exit_gate"]))
+    if return_exit:
+        return h, jnp.stack(gates)
     return (h, moe_aux) if moe_on else h
+
+
+def exit_gate(h: jax.Array, gate) -> jax.Array:
+    """A looped stack's exit gate over a pass's normed stream ``h``
+    ``[..., hidden]``: ``sigmoid(h . w + b)`` in float32, ``[...]``."""
+    return jax.nn.sigmoid(
+        jnp.einsum("...h,h->...", h.astype(jnp.float32),
+                   gate["kernel"].astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+        + gate["bias"].astype(jnp.float32))
+
+
+def exit_distribution(gates: jax.Array) -> jax.Array:
+    """The exit distribution over a looped stack's T passes from its
+    gates ``[T, ...]``: ``p_t = gate_t * prod_{i<t} (1 - gate_i)`` for
+    t < T and the last pass takes what is left, so they sum to 1."""
+    stay = jnp.cumprod(1.0 - gates[:-1], axis=0)
+    before = jnp.concatenate([jnp.ones_like(gates[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate([gates[:-1] * before, stay[-1:]], axis=0)
 
 
 def rotary_freqs(cfg: TransformerConfig, seq_len: Optional[int] = None):

@@ -185,7 +185,7 @@ def layer_groups(cfg) -> Optional[tuple]:
     which has one group."""
     if cfg.layer_types is None:
         return None
-    types = (cfg.layer_type(i) for i in range(cfg.num_layers))
+    types = (cfg.layer_type(i) for i in range(cfg.cache_layers))
     return tuple(STATE if cfg.state_layer(t) else _GROUP_OF.get(t, FULL)
                  for t in types)
 
@@ -209,7 +209,9 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                quantized: bool = False,
                window_blocks: Optional[int] = None,
                num_slots: Optional[int] = None) -> List[dict]:
-    """One pool a layer for a model of config ``cfg``: keys and values in
+    """One pool a layer A PASS (``cfg.cache_layers`` of them: pass t's
+    layer i holds pool ``t * num_layers + i``, and a page id names its
+    tokens in all of them) for a model of config ``cfg``: keys and values in
     the compute dtype, or int8 with fp32 scales when ``quantized`` (halves
     the KV bytes a decode step reads, against bf16); with a
     sparse-attention indexer, its keys beside them (``index_pages``).  A
@@ -237,7 +239,7 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
                                                 dtype)
             return pool
 
-        return [latent_pool() for _ in range(cfg.num_layers)]
+        return [latent_pool() for _ in range(cfg.cache_layers)]
     if groups is not None and WINDOW in groups and not window_blocks:
         raise ValueError("a model with sliding layers among its "
                          "layer_types needs window_blocks")
@@ -296,7 +298,7 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=None,
     return [{} if groups and groups[i] == NONE else
             state(cfg.layer_type(i)) if groups and groups[i] == STATE else
             pool(window_blocks if groups and groups[i] == WINDOW
-                 else num_blocks) for i in range(cfg.num_layers)]
+                 else num_blocks) for i in range(cfg.cache_layers)]
 
 
 def is_state(pool: dict) -> bool:
@@ -960,12 +962,15 @@ class CachePlan:
         """The cache's counters of one launch on its record ``d``
         (``serving/loop_profiler.py``: ``DSA_FIELDS``, ``MLA_FIELDS``,
         ``SSM_FIELDS``, ``CONV_FIELDS``, ``RETENTION_FIELDS``,
-        ``DELTA_FIELDS``, ``WALK_FIELDS``), from the host arrays its
+        ``DELTA_FIELDS``, ``WALK_FIELDS``, ``LOOP_FIELDS``), from the host arrays its
         program is handed: each row's ``context_lens`` and ``valid_lens``
         (0: an idle row) of ``n`` queries a row; ``admitted``: the
         requests that hold a slot.  After the walks, returns at once for
         a model with no other mechanism."""
-        cfg, layers = self.cfg, self.cfg.num_layers
+        cfg, layers = self.cfg, self.cfg.cache_layers
+        if cfg.loop_steps > 1:
+            # a looped stack: a layer's run a pass a live row
+            d.loop_layer_runs = layers * int((valid_lens > 0).sum())
         if self.paged and not cfg.latent_attention:
             # a live row's walk a layer that keeps pages of K and V; in
             # the pool's dtype where the kernel runs (a chunk and the

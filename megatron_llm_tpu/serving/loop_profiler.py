@@ -220,6 +220,12 @@ KV_FIELDS = ("kv_window_pages_returned", "kv_window_pages_spanned",
 # "decode", where it read 0 before
 WALK_FIELDS = ("walks", "walks_native")
 
+# a looped stack (a model with ``loop_steps`` > 1; ``CachePlan.account``):
+# layers x passes x live rows of the launch: the layer bodies its program
+# ran for them.  ``walks`` counts a layer A PASS too, so ``walks /
+# loop_layer_runs`` is 1 for such a model; 0 for a stack run once
+LOOP_FIELDS = ("loop_layer_runs",)
+
 # a prefill chunk's output head (the engine's ``_run_prefill_chunk``): the
 # rows it multiplied by the ``[V, H]`` head: 1 for the chunk that ends
 # its request's context, whose last live row the first token is sampled
@@ -242,7 +248,8 @@ HOST_FIELDS = ("host_uploads", "host_reads", "compile_secs", "gc_secs")
 # ``as_dict()`` carries and ``totals()`` gives goes through it
 COUNTED_FIELDS = (MOE_FIELDS + DSA_FIELDS + MLA_FIELDS + SSM_FIELDS
                   + CONV_FIELDS + RETENTION_FIELDS + DELTA_FIELDS + KV_FIELDS
-                  + WALK_FIELDS + PREFILL_FIELDS + HOST_FIELDS)
+                  + WALK_FIELDS + LOOP_FIELDS + PREFILL_FIELDS
+                  + HOST_FIELDS)
 
 # the compiled programs whose operations run inside a launch of each
 # kind (a last prefill chunk samples its first token in the same launch),

@@ -33,7 +33,8 @@ NEG_INF_LOGIT = -1e10
 def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
                    dtype=None, rolling: bool = False,
                    quantized: bool = False):
-    """Per-layer decode caches.  ``rolling=True`` (sliding-window models
+    """Per-layer decode caches, one a layer A PASS (``cfg.cache_layers``:
+    a looped stack's pass attends its own keys).  ``rolling=True`` (sliding-window models
     only) allocates a ring buffer of exactly ``sliding_window_size``
     slots instead of ``max_len`` — decode memory O(window) rather than
     O(total), a beyond-reference memory mode (the reference's inference
@@ -65,7 +66,7 @@ def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
                 "v_scale": jnp.ones((batch, size, ng), jnp.float32),
                 "index": jnp.int32(0),
             }
-            for _ in range(cfg.num_layers)
+            for _ in range(cfg.cache_layers)
         ]
     return [
         {
@@ -77,7 +78,7 @@ def init_kv_caches(cfg: TransformerConfig, batch: int, max_len: int,
             # carry doesn't trace it into a bool array
             **({"rolling": None} if rolling else {}),
         }
-        for _ in range(cfg.num_layers)
+        for _ in range(cfg.cache_layers)
     ]
 
 

@@ -221,6 +221,16 @@ def _qwen3_next_cfg(cfg, chunk):
             "fault_chunk": chunk}
 
 
+def _ouro_cfg(cfg, chunk):
+    return {"num_hidden_layers": cfg.num_layers,
+            "total_ut_steps": cfg.loop_steps,
+            "num_attention_heads": cfg.num_attention_heads,
+            "num_key_value_heads": cfg.num_attention_heads_kv,
+            "rms_norm_eps": cfg.layernorm_epsilon,
+            "rope_theta": cfg.rope_theta,
+            "vocab_size": cfg.padded_vocab_size}
+
+
 def _kanana_cfg(cfg, chunk):
     return {"num_hidden_layers": cfg.num_layers,
             "num_attention_heads": cfg.num_attention_heads,
@@ -405,6 +415,10 @@ FAMILIES = {
                               router=6.0),
                    engine=dict(max_model_len=96)),
     "mellum": Family(_mellum_cfg, 2e-4, chunk=16),
+    # three layers four times; the same three layers three times
+    "ouro": Family(_ouro_cfg, 2e-4, chunk=16, shake=_WIDE,
+                   sizes={"two_layers_three_passes": dict(num_layers=2,
+                                                          loop_steps=3)}),
 }
 
 

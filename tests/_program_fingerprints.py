@@ -18,7 +18,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 FAMILIES = ("mistral", "mixtral", "olmoe", "keye", "mellum", "kanana",
             "granite", "nemotron_h", "trinity", "lfm2", "brumby",
-            "qwen3_next", "glm5")
+            "qwen3_next", "glm5", "ouro")
 
 
 def fingerprints(name: str) -> dict:

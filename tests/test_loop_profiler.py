@@ -492,7 +492,7 @@ def _groups():
     return {name: getattr(lp, name) for name in (
         "MOE_FIELDS", "DSA_FIELDS", "MLA_FIELDS", "SSM_FIELDS",
         "CONV_FIELDS", "RETENTION_FIELDS", "DELTA_FIELDS", "KV_FIELDS",
-        "WALK_FIELDS", "PREFILL_FIELDS", "HOST_FIELDS")}
+        "WALK_FIELDS", "LOOP_FIELDS", "PREFILL_FIELDS", "HOST_FIELDS")}
 
 
 @pytest.mark.parametrize("group", sorted(_groups()))

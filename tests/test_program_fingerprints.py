@@ -87,6 +87,12 @@ TRACED = {
     # were
     "glm5": {"engine_prefill": "cd0bdca723895f61",
              "engine_decode": "3f2a1ce8782fedb1"},
+    # recorded by PR 63, which brought the family: a stack run
+    # ``loop_steps`` times over a pool a pass.  With ``loop_steps`` 1 the
+    # serving loop is one pass of no scope and ``init_pools`` builds
+    # ``num_layers`` pools, so every program above is as it was
+    "ouro": {"engine_prefill": "b3641cd2d3e4e285",
+             "engine_decode": "b008b4e8e9d4e81c"},
 }
 
 

@@ -30,7 +30,7 @@ FAMILY = {C.SPARSE: "keye", C.STATE_SPACE: "granite", C.TYPED: "mellum",
           C.GATED_DELTA: "qwen3_next",
           C.ONE_SUBLAYER: "nemotron_h",
           C.GATE: "trinity", C.OUTPUT_NORMS: "trinity",
-          C.ROPE_TYPES: "trinity",
+          C.ROPE_TYPES: "trinity", C.LOOPED: "ouro",
           C.FIRST_DENSE: "kanana", C.LATENT: "kanana",
           C.SPARSE_LATENT: "glm5",
           C.QK_NORM_WHOLE: "olmoe", C.EXPERTS: "olmoe", C.SHARE: "granite"}

@@ -440,6 +440,7 @@ LFM2 = "lfm2_cell_programs"
 BRUMBY = "brumby_cell_programs"
 QWEN3_NEXT = "qwen3_next_cell_programs"
 GLM5 = "glm5_cell_programs"
+OURO = "ouro_cell_programs"
 
 
 def _walk_operands(lowered_text, shapes):
@@ -682,6 +683,19 @@ def _glm5_cell():
             num_slots=10, num_blocks=20481, max_model_len=66560)
 
 
+def _ouro_cell():
+    """The same of the benchmark's Ouro cell: 12 of the published 48
+    layers, run four times over a pool a pass (48 pools), the whole
+    vocabulary under an untied head, 12 slots of up to 3,584 tokens and a
+    pool of 897 pages of 48 planes."""
+    from megatron_llm_tpu.models.ouro import OuroModel, ouro_config
+
+    return lambda: OuroModel(ouro_config(
+        "2.6B", num_layers=12, params_dtype="bf16", compute_dtype="bf16",
+        seq_length=3584)), dict(num_slots=12, num_blocks=897,
+                                max_model_len=3584)
+
+
 # a cell's name among the child's arguments, its key in what the child
 # prints, and the cell
 CELLS = {"granite": (GRANITE, _granite_cell),
@@ -690,7 +704,8 @@ CELLS = {"granite": (GRANITE, _granite_cell),
          "lfm2": (LFM2, _lfm2_cell),
          "brumby": (BRUMBY, _brumby_cell),
          "qwen3_next": (QWEN3_NEXT, _qwen3_next_cell),
-         "glm5": (GLM5, _glm5_cell)}
+         "glm5": (GLM5, _glm5_cell),
+         "ouro": (OURO, _ouro_cell)}
 # every cell's engine beside its own keywords
 _CELL_ENGINE = dict(block_size=16, prefill_chunk=512, preemption=False,
                     paged_kernel="on", prefill_kernel="on")
@@ -805,7 +820,8 @@ def _cell_programs(chip, build, engine):
                     "retention_chunk", "retention_step", "delta_proj",
                     "delta_conv", "delta_gate", "delta_chunk", "delta_step",
                     "delta_norm", "mla_query_down", "mla_query_up",
-                    "mla_absorb", "dsa_indexer")
+                    "mla_absorb", "dsa_indexer", "loop_pass",
+                    "loop_pass_norm")
                     if f"/{s}/" in text})}
         return found
     except Exception as e:      # noqa: BLE001 - the compiler's refusal
@@ -1321,6 +1337,46 @@ def test_the_glm5_cells_programs_compile_for_a_described_v5e():
                 "dsa_indexer"} <= set(got["scopes"]), got["scopes"]
     assert "mla_absorb" in step["scopes"]
     assert "mla_absorb" not in chunk["scopes"]
+
+
+def test_the_ouro_cells_programs_compile_and_fit_a_v5e():
+    """The Ouro cell's bytes and counts as the engine's plan has them (12
+    layers at the published widths run four times, the whole vocabulary,
+    12 slots and 897 pages of 48 planes): ISSUE 63's arithmetic, which
+    ``jax.eval_shape`` lays out and nobody allocates."""
+    found = _cell_plan(*_ouro_cell())
+    assert found["state_bytes_per_slot"] == 0
+    # a token a layer A PASS: 16 heads of 128 of K and of V in bf16
+    assert found["pool_bytes"] == 897 * 16 * 12 * 4 * 8192 == 5_643_436_032
+    layer = 4 * 2048 * 2048 + 3 * 2048 * 5632 + 4 * 2048
+    # twelve layers ONCE (the passes share them), embedding and head, the
+    # final norm and the exit gate's 2,049
+    assert found["parameters"] == (
+        12 * layer + 2 * 49152 * 2048 + 2048 + 2049) == 817_991_681
+    assert found["moe_expert_tiles"] is None
+
+
+@pytest.mark.slow
+@pytest.mark.time_limit(900)
+def test_the_ouro_cells_programs_compile_for_a_described_v5e():
+    """The Ouro cell's two programs at its real sizes, UNROLLED (48 layer
+    bodies a program), for a described v5e: each holds the paged walk's
+    kernel, the scope of a pass and of the norm that ends it; the decode
+    step owns its 48 pools, the chunk is LENT them, holds them twice and
+    still fits the chip's 15.75 GB."""
+    found = _cell_compiled("ouro")
+    step, chunk = found["engine_decode"], found["engine_prefill"]
+    assert step["kernels"] == ["paged_attention_decode"]
+    assert chunk["kernels"] == ["paged_attention_prefill"]
+    assert step["alias_bytes"] >= found["pool_bytes"], step
+    assert chunk["alias_bytes"] == 0
+    for name, got in (("engine_prefill", chunk), ("engine_decode", step)):
+        held = (got["argument_bytes"] + got["output_bytes"]
+                + got["temp_bytes"] - got["alias_bytes"])
+        # 1.64 GB of weights, 5.64 GB of pool (twice in a chunk)
+        assert held < 15.75e9 * 0.92, (name, held)
+        assert {"loop_pass", "loop_pass_norm", "post_attn_norm",
+                "post_mlp_norm"} <= set(got["scopes"]), got["scopes"]
 
 
 if __name__ == "__main__":
